@@ -20,19 +20,17 @@ import sys
 
 
 def main(argv: list[str] | None = None) -> int:
-    # CPU pinning BEFORE any jax-touching import (the load_rehearsal.py
-    # pattern): chaos scenarios are host-logic rehearsals, not device work
+    # CPU pinning BEFORE any jax-touching import: chaos scenarios are
+    # host-logic rehearsals, not device work, and the federation scenarios
+    # start worker children on the CPU — this process must sit there too
     if not os.environ.get("RUN_TPU_TESTS"):
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        os.environ["JAX_PLATFORMS"] = "cpu"
         flags = os.environ.get("XLA_FLAGS", "")
         if "xla_force_host_platform_device_count" not in flags:
             # the pool scenarios need >= 2 virtual devices; the PD-split
             # scenario (2 prefill + 1 decode replicas) needs >= 3
             os.environ["XLA_FLAGS"] = (
                 flags + " --xla_force_host_platform_device_count=4").strip()
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
 
     from .runner import run_scenario
     from .scenarios import BUILTIN_SCENARIOS, load_scenario_file, scenario_by_name

@@ -2077,6 +2077,9 @@ def _run_worker_host_crash_scenario(spec: dict) -> ScenarioResult:
     from ...runtime.federation import (FederatedServingPool, FederationConfig,
                                        WorkerRegistry, digest_chain)
 
+    from ...ops.platform import require_cpu
+
+    require_cpu("faultlab worker_host_crash")
     seed = int(spec.get("seed", 0))
     lease_ttl_s = float(spec.get("lease_ttl_s", 2.0))
     max_tokens = int((spec.get("load") or {}).get("max_tokens", 16))
@@ -2339,6 +2342,9 @@ def _run_fleet_doctor_shed_scenario(spec: dict) -> ScenarioResult:
     from ...modules.llm_gateway.grpc_service import model_ref_dict
     from ...modules.sdk import ModelInfo
 
+    from ...ops.platform import require_cpu
+
+    require_cpu("faultlab fleet_doctor_shed")
     seed = int(spec.get("seed", 0))
     lease_ttl_s = float(spec.get("lease_ttl_s", 4.0))
     delay_spec = spec.get("delay_spec", "delay(0.4)")

@@ -28,14 +28,6 @@ from typing import Any, Awaitable, Callable, Optional
 
 from aiohttp import web
 
-#: asyncio.timeout is 3.11+; on 3.10 fall back to async_timeout (an aiohttp
-#: dependency, identical async-CM semantics) so the whole HTTP surface isn't
-#: dead on older interpreters
-if hasattr(asyncio, "timeout"):
-    _timeout_cm = asyncio.timeout
-else:  # pragma: no cover — interpreter-version dependent
-    from async_timeout import timeout as _timeout_cm
-
 from ..modkit.errcat import ERR
 from ..modkit.errors import Problem, ProblemError
 from ..modkit.failpoints import failpoint_async
@@ -264,7 +256,7 @@ class RouteStackBuilder:
             try:
                 # asyncio.timeout over wait_for: no per-request wrapper Task
                 # (~50 µs saved on the hot path, same cancel semantics)
-                async with _timeout_cm(timeout_secs):
+                async with asyncio.timeout(timeout_secs):
                     return await inner(request)
             except asyncio.TimeoutError:
                 return _problem_response(

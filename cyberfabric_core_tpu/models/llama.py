@@ -296,8 +296,7 @@ def forward(
     # The cache rides the scan CARRY (not ys): XLA aliases while-loop carries
     # in place, so each layer writes only its [B, T] new tokens via scatter —
     # the ys formulation re-materialized the full layer cache every step,
-    # which at decode (T=1) cost a cache-sized HBM write per token
-    # (ROUND_NOTES r1 item 2: scan-carry cache copies).
+    # which at decode (T=1) cost a cache-sized HBM write per token.
     b_idx = jnp.arange(B, dtype=jnp.int32)[:, None]            # [B, 1]
     t_idx = cache_start[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
 
@@ -350,17 +349,16 @@ def _shard_mapped_attn(mesh, kernel_fn, q_spec, tail_specs):
     Paged Attention paper names. Head-major GQA grouping survives the
     split because consecutive q heads map to consecutive kv heads
     (requires num_kv_heads % tp == 0 — the engine gates on it).
-    check_rep=False: pallas_call defeats the replication checker. The ONE
+    check_vma=False: pallas_call defeats the replication checker. The ONE
     wrapping implementation both paged forwards share, so the specs cannot
     drift."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     kv_spec = P(None, None, "tp", None)
-    return shard_map(
+    return jax.shard_map(
         kernel_fn, mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec) + tuple(tail_specs),
-        out_specs=q_spec, check_rep=False)
+        out_specs=q_spec, check_vma=False)
 
 
 def forward_paged_decode(

@@ -33,11 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; accept either so
-#: the kernel loads against whichever toolchain the image bakes in
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 _NEG_INF = -1e30
 _LANES = 128  # f32 lane width; m/l scratch is lane-replicated
 
@@ -48,10 +43,12 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
     """One (batch, q_head, q_block, kv_block) program.
 
     Refs:
-      len_ref: [1] int32 in SMEM — valid length for this batch row
+      len_ref: [B] int32 in SMEM (scalar prefetch) — valid length per row. A
+               blocked (1,)-of-[B] SMEM operand only lowers at B == 1
       q_ref:   [1, 1, BQ, D]; k_ref/v_ref: [1, 1, BK, D]; o_ref: [1, 1, BQ, D]
       acc_ref: [BQ, D] f32 scratch; m_ref/l_ref: [BQ, LANES] f32 scratch
     """
+    valid_len = len_ref[pl.program_id(0)]
     qi = pl.program_id(2)
     ki = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -71,8 +68,8 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
     # *first* query row.
     relevant = jnp.logical_and(
         jnp.logical_and(k_start <= q_start + block_q - 1,
-                        k_start < len_ref[0]),
-        q_start < len_ref[0])  # q blocks fully past valid length: zeros
+                        k_start < valid_len),
+        q_start < valid_len)  # q blocks fully past valid length: zeros
     if sliding_window is not None:
         relevant = jnp.logical_and(
             relevant, k_start + block_k - 1 > q_start - sliding_window)
@@ -92,7 +89,6 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
             jnp.int32, (block_q, block_k), 0)
         k_pos = k_start + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
-        valid_len = len_ref[0]
         mask = (k_pos <= q_pos) & (k_pos < valid_len)
         if sliding_window is not None:
             mask = mask & (k_pos > q_pos - sliding_window)
@@ -164,7 +160,7 @@ def flash_self_attention(
     kh = k.transpose(0, 2, 1, 3)  # [B, Hkv, Tp, D]
     vh = v.transpose(0, 2, 1, 3)
 
-    def _kv_index(b, h, i, j):
+    def _kv_index(b, h, i, j, len_ref):
         # clamp j into the causally-relevant range for q block i so programs
         # whose body is skipped revisit the already-resident tile and Pallas
         # elides the HBM→VMEM copy (cuts ~half the KV reads; far more with a
@@ -176,28 +172,24 @@ def flash_self_attention(
             jj = jnp.maximum(jj, lo)
         return (b, h // G, jj, 0)
 
-    grid = (B, Hq, Tp // bq, Tp // bk)
+    q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j, len_ref: (b, h, i, 0))
+    kv_spec = pl.BlockSpec((1, 1, bk, D), _kv_index)
     out = pl.pallas_call(
         functools.partial(_flash_kernel, block_q=bq, block_k=bk,
                           sliding_window=sliding_window),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, h, i, j: (b,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, bk, D), _kv_index, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, bk, D), _kv_index, memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0),
-                               memory_space=pltpu.VMEM),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, Hq, Tp // bq, Tp // bk),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((bq, D), jnp.float32),
+                pltpu.VMEM((bq, _LANES), jnp.float32),
+                pltpu.VMEM((bq, _LANES), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((B, Hq, Tp, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq, D), jnp.float32),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),

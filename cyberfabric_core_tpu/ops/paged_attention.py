@@ -33,11 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; accept either so
-#: the kernel loads against whichever toolchain the image bakes in
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 _NEG_INF = -1e30
 _LANES = 128
 
@@ -252,7 +247,7 @@ def paged_decode_attention(
                           two_d_dots=two_d_dots),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -505,7 +500,7 @@ def ragged_paged_attention(
                           head_dim=D if two_d_dots else None),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -42,15 +42,10 @@ def _ring_attention_local(
     q_pos = my_idx * Tl + jnp.arange(Tl, dtype=jnp.int32)  # [Tl] global positions
 
     # online-softmax accumulators (f32), marked device-varying over the ring axis
-    # so the fori_loop carry type matches its (axis_index-dependent) outputs.
-    # pcast(to='varying') is the current spelling; fall back to the deprecated
-    # pvary on JAX versions that predate pcast.
-    if hasattr(jax.lax, "pcast"):
-        def _varying(x):
-            return jax.lax.pcast(x, to="varying", axis_name=axis_name)
-    else:  # pragma: no cover — older JAX
-        def _varying(x):
-            return jax.lax.pvary(x, axis_name)
+    # so the fori_loop carry type matches its (axis_index-dependent) outputs
+    def _varying(x):
+        return jax.lax.pcast(x, to="varying", axis_name=axis_name)
+
     acc = _varying(jnp.zeros((B, Tl, Hkv, G, D), jnp.float32))
     m = _varying(jnp.full((B, Tl, Hkv, G), _NEG_INF, jnp.float32))
     l = _varying(jnp.zeros((B, Tl, Hkv, G), jnp.float32))

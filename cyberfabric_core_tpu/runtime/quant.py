@@ -17,6 +17,7 @@ accept either a plain array or this dict.
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -82,24 +83,35 @@ def _quantize_embed(embed: jnp.ndarray) -> dict[str, jnp.ndarray]:
     return {"qe": q, "se": scale[:, 0].astype(jnp.float32)}
 
 
+@functools.partial(jax.jit, static_argnames=("shape", "dtype", "bits"))
+def _init_quantized_leaf(key: jax.Array, shape: tuple[int, ...], dtype,
+                         bits: int) -> dict[str, jnp.ndarray]:
+    """Sample one matmul weight and quantize it, one slice of the leading
+    (stacked-layer) axis at a time: the only full-size array is the intN
+    result. Op by op, the f32 copies ``quantize_weight`` makes of a 7B
+    model's [32, 4096, 14336] leaf are 7.5 GB each and exhaust a 16 GB chip."""
+    scale = jnp.asarray(1.0 / shape[-2] ** 0.5, dtype)
+
+    def one(k, shp):
+        return quantize_weight(jax.random.normal(k, shp, dtype) * scale, bits)
+
+    if len(shape) == 2:
+        return one(key, shape)
+    return jax.lax.map(lambda k: one(k, shape[1:]),
+                       jax.random.split(key, shape[0]))
+
+
 def init_params_quantized(cfg, key: jax.Array, dtype=jnp.bfloat16,
                           bits: int = 8) -> dict[str, Any]:
-    """Synthetic-weight init directly into W8/W4: each leaf is sampled in bf16,
-    quantized, and the bf16 original freed before the next — peak HBM is the
-    intN tree + ONE bf16 leaf, so an 8B model inits inside a 16 GB chip."""
-    from ..models import llama
-
+    """Synthetic-weight init directly into W8/W4: peak HBM is the intN tree
+    plus one layer's slice of one leaf, so a 7-8B model inits inside one
+    v5e chip."""
     H, I, V, L = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size, cfg.num_layers
     Dq, Dkv = cfg.num_heads * cfg.head_dim, cfg.num_kv_heads * cfg.head_dim
     keys = iter(jax.random.split(key, 16))
 
     def w(*shape):
-        scale = jnp.asarray(1.0 / (shape[-2] if len(shape) > 1 else shape[-1]) ** 0.5, dtype)
-        full = jax.random.normal(next(keys), shape, dtype) * scale
-        q = quantize_weight(full, bits)
-        q["q"].block_until_ready()
-        del full
-        return q
+        return _init_quantized_leaf(next(keys), shape, jnp.dtype(dtype), bits)
 
     layers: dict[str, Any] = {
         "attn_norm": jnp.ones((L, H), dtype),

@@ -1,21 +1,28 @@
-"""AOT TPU lowering proof (round-3 verdict item 2): the serving program set
-compiles for a real v5e topology on the CPU host, with the Pallas flash and
-ragged paged-attention kernels lowering through Mosaic (not interpret mode).
+"""What must hold before the chip: the kernels of the main path compile for a
+real v5e at a served model's widths, the compile cache can be placed from
+outside, and chip_smoke.py refuses to pass without a TPU — all cheap, and
+collected first, so tier-1 guards them. The whole-program AOT proofs below
+them (the serving set through runtime/aot_tpu.py, the scheduler's own
+programs at mistral-7b) take minutes and are marked ``slow``.
 
 Needs only the libtpu wheel (topology description), not a TPU device — so a
 tiling/lowering bug in ops/flash_attention.py or ops/paged_attention.py fails
-CI instead of waiting for hardware day. SURVEY §7 stage 3.
+CI instead of waiting for a chip run. SURVEY §7 stage 3.
 """
+
+import os
+import subprocess
+import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
-#: whole-module slow gate: every case here drives the full TPU AOT compiler
-#: (libtpu topology + Mosaic kernel lowering), minutes-scale per program —
-#: the AOT_TPU.json artifact and the TPU-day gate own this, not tier-1
-pytestmark = pytest.mark.slow
+REPO = Path(__file__).resolve().parents[1]
+slow = pytest.mark.slow
 
 
 def _topo_or_skip(name="v5e:2x2"):
@@ -27,6 +34,131 @@ def _topo_or_skip(name="v5e:2x2"):
         pytest.skip(f"TPU topology unavailable: {e}")
 
 
+# ---- kernels of the main path at mistral-7b's shapes (Hq 32, Hkv 8, D 128,
+# page 64, bf16, the worker's default max_batch 8 / max_seq_len 2048)
+
+@pytest.fixture()
+def one_chip():
+    """A described v5e chip to compile for, with the persistent compile cache
+    off: an entry written by a topology compile cannot be read back without
+    a chip, and the next compile would warn about it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    topo = _topo_or_skip()
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        sharding = SingleDeviceSharding(topo.devices[0])
+        yield lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                        sharding=sharding)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+_HQ, _HKV, _D, _PAGE, _B, _PMAX = 32, 8, 128, 64, 8, 32
+_N_PAGES = _B * _PMAX * 5 // 4 + 1   # the worker's default pool
+_WINDOW = 4096
+
+
+def _compiles_with_mosaic(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("batch", [1, 4])
+def test_flash_kernel_compiles_at_mistral_7b_shapes(one_chip, batch):
+    """One prompt, and a coalesced cold prefill of several: per-row lengths
+    as a blocked SMEM operand lowered at batch 1 only (first chip run)."""
+    from cyberfabric_core_tpu.ops.flash_attention import flash_self_attention
+
+    T = 512
+    _compiles_with_mosaic(
+        lambda q, k, v, n: flash_self_attention(
+            q, k, v, n, interpret=False, sliding_window=_WINDOW),
+        one_chip((batch, T, _HQ, _D), jnp.bfloat16),
+        one_chip((batch, T, _HKV, _D), jnp.bfloat16),
+        one_chip((batch, T, _HKV, _D), jnp.bfloat16),
+        one_chip((batch,), jnp.int32))
+
+
+def test_paged_decode_kernel_compiles_at_mistral_7b_shapes(one_chip):
+    from cyberfabric_core_tpu.ops.paged_attention import paged_decode_attention
+
+    pool = one_chip((_N_PAGES, _PAGE, _HKV, _D), jnp.bfloat16)
+    _compiles_with_mosaic(
+        lambda q, k, v, pt, n: paged_decode_attention(
+            q, k, v, pt, n, interpret=False, sliding_window=_WINDOW,
+            two_d_dots=True),
+        one_chip((_B, _HQ, _D), jnp.bfloat16), pool, pool,
+        one_chip((_B, _PMAX), jnp.int32), one_chip((_B,), jnp.int32))
+
+
+@pytest.mark.parametrize("q_width", [8, 64, 512])
+def test_ragged_kernel_compiles_at_mistral_7b_shapes(one_chip, q_width):
+    """Mixed q_len rows share one call; its width is the round's largest
+    prefill chunk bucket (8 = a speculative span, 512 = the chunk budget)."""
+    from cyberfabric_core_tpu.ops.paged_attention import ragged_paged_attention
+
+    pool = one_chip((_N_PAGES, _PAGE, _HKV, _D), jnp.bfloat16)
+    _compiles_with_mosaic(
+        lambda q, k, v, pt, h, n: ragged_paged_attention(
+            q, k, v, pt, h, n, interpret=False, sliding_window=_WINDOW,
+            two_d_dots=True),
+        one_chip((_B, q_width, _HQ, _D), jnp.bfloat16), pool, pool,
+        one_chip((_B, _PMAX), jnp.int32), one_chip((_B,), jnp.int32),
+        one_chip((_B,), jnp.int32))
+
+
+# ---- the compile cache and chip_smoke.py without a chip
+
+@pytest.mark.parametrize("case", ["from_env", "fixed_path", "cpu"])
+def test_compile_cache_dir_is_placed_from_outside(case, monkeypatch,
+                                                  tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set → no directory is set in code; unset →
+    the one fixed path inside the checkout on a TPU, nothing on the CPU."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from cyberfabric_core_tpu.ops import platform
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        if case == "from_env":
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert platform.enable_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir is None
+        elif case == "fixed_path":
+            monkeypatch.setattr(platform, "on_tpu", lambda: True)
+            fixed = str(REPO / ".jax_cache")
+            assert platform.enable_compile_cache() == fixed
+            assert jax.config.jax_compilation_cache_dir == fixed
+        else:
+            assert platform.enable_compile_cache() is None
+            assert jax.config.jax_compilation_cache_dir is None
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+        compilation_cache.reset_cache()
+
+
+def test_chip_smoke_fails_without_a_tpu():
+    """On the CPU the script exits non-zero and never prints the line the
+    driver reads as success."""
+    proc = subprocess.run(
+        [sys.executable, str(REPO / "chip_smoke.py")], cwd=REPO,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"}, capture_output=True,
+        text=True, timeout=300)
+    assert proc.returncode != 0, proc.stdout
+    assert '"ok"' not in proc.stdout
+    assert "JAX found no TPU" in proc.stderr
+
+
+# ---- whole programs (minutes each)
+
+@slow
 @pytest.mark.parametrize("quant", ["none", "int8", "int4"])
 def test_serving_set_compiles_for_v5e(quant):
     """Flash prefill + fused paged-decode chunk lower for the TPU target in
@@ -46,13 +178,12 @@ def test_serving_set_compiles_for_v5e(quant):
         assert "tpu_custom_call" in prog["custom_calls"], prog["name"]
 
 
+@slow
 def test_tp_sharded_prefill_compiles_for_v5e():
     """Megatron-style TP shardings + GSPMD collectives lower for the TPU
     mesh (tp=4 over the v5e:2x2 topology). Compiles ONLY the tp program
     (include_serving=False) — the serving set has its own test."""
     _topo_or_skip()
-    import jax.numpy as jnp
-
     from cyberfabric_core_tpu.models import llama
     from cyberfabric_core_tpu.models.configs import get_config
     from cyberfabric_core_tpu.runtime.aot_tpu import aot_compile
@@ -91,6 +222,7 @@ def test_serialize_without_out_dir_is_a_clear_error():
         aot_compile("tiny-llama", serialize=True)
 
 
+@slow
 def test_serialized_executable_roundtrip(tmp_path):
     """serialize=True writes deserializable TPU executables with digests —
     what a TPU host loads to skip compilation entirely."""
@@ -132,3 +264,90 @@ def test_compiled_kernels_context_forces_mosaic():
     with compiled_kernels():
         assert default_interpret() is False
     assert default_interpret() is on_cpu
+
+
+@slow
+@pytest.mark.parametrize("tp", [1, 4])
+def test_scheduler_programs_compile_for_v5e_at_mistral_7b(tp):
+    """The scheduler's OWN jitted programs (not the mirror in aot_tpu.py) —
+    ``_paged_decode_fn`` and ``_mixed_step_fn`` as ``_build_programs`` makes
+    them, with their donation — lowered from shapes for mistral-7b int8 at
+    the worker's default shape and page pool, on one described chip and as
+    tp=4 over the 2x2. Each holds a Mosaic call and fits the 15.75 GiB the
+    compiler budgets. About 25 s a program; a compile, not a chip run."""
+    from jax.sharding import NamedSharding, PartitionSpec as P, \
+        SingleDeviceSharding
+
+    from cyberfabric_core_tpu.models import get_config
+    from cyberfabric_core_tpu.ops.platform import compiled_kernels
+    from cyberfabric_core_tpu.ops.rope import rope_frequencies
+    from cyberfabric_core_tpu.parallel.feasibility import V5E_HBM_BYTES
+    from cyberfabric_core_tpu.parallel.mesh import MeshConfig, build_mesh
+    from cyberfabric_core_tpu.parallel.sharding import (
+        abstract_params, llama_page_pool_sharding, sharded_abstract_params)
+    from cyberfabric_core_tpu.runtime.engine import EngineConfig
+    from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
+
+    topo = _topo_or_skip()
+    n, max_seq = _B, _PMAX * _PAGE
+    cfg = get_config("mistral-7b")
+    # an engine with everything _build_programs reads and nothing allocated
+    eng = object.__new__(ContinuousBatchingEngine)
+    eng.config = EngineConfig(
+        model="mistral-7b", max_seq_len=max_seq, max_batch=n, decode_chunk=8,
+        quantization="int8", prefix_cache_pages=_N_PAGES,
+        prefix_page_size=_PAGE, tp=tp)
+    eng.model_config, eng.dtype, eng.paged = cfg, jnp.bfloat16, True
+    eng.spec_k, eng._spec_w = 0, 1
+    eng.rope_tables = rope_frequencies(
+        cfg.head_dim, max(cfg.max_position, max_seq), cfg.rope_theta)
+    if tp > 1:
+        eng.mesh = eng._attn_mesh = build_mesh(MeshConfig(dp=1, tp=tp),
+                                               topo.devices[:tp])
+        repl = NamedSharding(eng.mesh, P())
+        pool_sharding = llama_page_pool_sharding(cfg, eng.mesh)
+        params = sharded_abstract_params(cfg, eng.mesh, jnp.bfloat16, "int8")
+    else:
+        eng.mesh = eng._attn_mesh = None
+        repl = pool_sharding = SingleDeviceSharding(topo.devices[0])
+        params = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=repl),
+            abstract_params(cfg, jnp.bfloat16, "int8"))
+    with compiled_kernels():
+        eng._build_programs()
+
+    def sds(shape, dtype, sharding=repl):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def row(dtype):
+        return sds((n,), dtype)
+
+    i32, f32 = jnp.int32, jnp.float32
+    pool = sds((cfg.num_layers, _N_PAGES, _PAGE, cfg.num_kv_heads,
+                cfg.head_dim), jnp.bfloat16, pool_sharding)
+    table, keys = sds((n, _PMAX), i32), sds((n, 2), jnp.uint32)
+    stops = sds((n, eng.config.device_stop_width), i32)
+    sampling = (row(f32), row(f32), row(i32))
+    programs = {
+        "paged_decode_chunk": (eng._paged_decode_fn, (
+            params, pool, pool, table, row(i32), row(i32), row(bool),
+            row(bool), stops, row(i32), keys, *sampling)),
+        "mixed_step@64": (eng._mixed_step_fn, (
+            params, pool, pool, table, sds((n, 64), i32), row(i32), row(i32),
+            row(i32), row(i32), row(bool), row(bool), row(bool), row(bool),
+            row(i32), stops, row(i32), keys, *sampling)),
+    }
+    for name, (fn, args) in programs.items():
+        with compiled_kernels():
+            compiled = fn.lower(*args).compile()
+        mem = compiled.memory_analysis()
+        live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+        print(f"{name} tp={tp}: argument {mem.argument_size_in_bytes / 1e9:.2f}"
+              f" output {mem.output_size_in_bytes / 1e9:.2f} aliased "
+              f"{mem.alias_size_in_bytes / 1e9:.2f} temp "
+              f"{mem.temp_size_in_bytes / 1e9:.2f} GB per device")
+        assert "tpu_custom_call" in compiled.as_text(), name
+        assert mem.alias_size_in_bytes >= 2 * np.prod(
+            pool.sharding.shard_shape(pool.shape)) * 2, "pools not donated"
+        assert live < V5E_HBM_BYTES, (name, live)

@@ -83,15 +83,20 @@ def test_decode_references_shared_prefix_pages(scheds):
     sampling = SamplingParams(max_tokens=24)
 
     events = {0: threading.Event(), 1: threading.Event()}
+    first_token = threading.Event()
     chains: dict[int, list[int]] = {}
 
     def emit_for(i):
         def emit(ev):
+            first_token.set()
             if ev.finished:
                 events[i].set()
         return emit
 
     cached.submit(prefix + [90], sampling, emit_for(0))
+    # the prefix enters the radix tree when the first request's prefill ends:
+    # two requests admitted in the same mixed round are both cold
+    assert first_token.wait(120)
     cached.submit(prefix + [91], sampling, emit_for(1))
     # snapshot chains while both are in flight
     deadline = time.monotonic() + 60
