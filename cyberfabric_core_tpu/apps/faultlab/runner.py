@@ -617,8 +617,13 @@ def _run_selective_shed_scenario(spec: dict) -> ScenarioResult:
             "slow_window_s": 4.0, "min_samples": 3,
             # global shedding out of reach: selective shedding must carry
             "shed_after": 10 ** 6, "recover_after": 2,
+            # only the itl objective is under test: on a loaded CPU a cold
+            # compile or a queued request burns the shipped ttft and queue
+            # objectives before the fault is armed, so those are out of reach
             "objectives": {"itl_p99": {"threshold_ms": float(
-                spec.get("itl_threshold_ms", 30.0))}},
+                               spec.get("itl_threshold_ms", 30.0))},
+                           "ttft_p95": {"threshold_ms": 120000.0},
+                           "queue_wait_p95": {"threshold_ms": 120000.0}},
             "tenant_over_share": 1.5, "tenant_min_activity": 8,
             "tenant_shed_retry_after_s": 1.0,
             "stream_stall_s": 120.0, "round_stall_floor_s": 120.0,
@@ -675,33 +680,12 @@ def _run_selective_shed_scenario(spec: dict) -> ScenarioResult:
                 flood = [asyncio.ensure_future(
                     completion("heavy", f"flood {seed} {i}", 24))
                     for i in range(int(spec.get("heavy_requests", 16)))]
-                # wait for the doctor to attribute + shed the heavy tenant
-                shed_probe = None
+                # wait for the doctor to attribute the burn to the heavy
+                # tenant, on the surfaces that name it. Read them FIRST and
+                # while the flood runs: they are GETs, where a heavy probe
+                # sent now would queue behind the flood and come back when
+                # it is over, a window or two before the marks clear
                 deadline = time.monotonic() + 45.0
-                while time.monotonic() < deadline:
-                    st, headers, body = await completion(
-                        "heavy", f"shed probe {seed}", 8)
-                    if st == 429:
-                        shed_probe = {
-                            "status": st, "code": body.get("code"),
-                            "retry_after": headers.get("Retry-After")}
-                        break
-                    await asyncio.sleep(0.2)
-                out["heavy_shed_probe"] = shed_probe
-                # while the heavy tenant is shed, the light tenant serves
-                st, _, body = await completion("light", f"probe {seed}", 8)
-                out["light_during_shed"] = {
-                    "status": st,
-                    "text_matches": text_of(body)
-                    == out["light_baseline"]["text"]}
-                # global shedding never engaged: /readyz stays 200
-                async with s.get(f"{base}/readyz") as r:
-                    out["readyz_during_shed"] = r.status
-                # the shed set is rebuilt every eval pass and cleared the
-                # moment an evaluation reads clean — a single-shot read
-                # can race a momentary window droop, so POLL for the
-                # attribution markers while the burn is still armed
-                deadline = time.monotonic() + 15.0
                 while time.monotonic() < deadline:
                     async with s.get(f"{base}/v1/monitoring/slo",
                                      headers={"x-tenant-id": "light"}) as r:
@@ -716,7 +700,22 @@ def _run_selective_shed_scenario(spec: dict) -> ScenarioResult:
                     if out["shed_tenants"] == ["heavy"] and \
                             out["tenants_rows"].get("heavy") is True:
                         break
-                    await asyncio.sleep(0.2)
+                    await asyncio.sleep(0.1)
+                # the gateway sheds the marked tenant pre-enqueue
+                st, headers, body = await completion(
+                    "heavy", f"shed probe {seed}", 8)
+                out["heavy_shed_probe"] = {
+                    "status": st, "code": body.get("code"),
+                    "retry_after": headers.get("Retry-After")}
+                # while the heavy tenant is shed, the light tenant serves
+                st, _, body = await completion("light", f"probe {seed}", 8)
+                out["light_during_shed"] = {
+                    "status": st,
+                    "text_matches": text_of(body)
+                    == out["light_baseline"]["text"]}
+                # global shedding never engaged: /readyz stays 200
+                async with s.get(f"{base}/readyz") as r:
+                    out["readyz_during_shed"] = r.status
                 await _disarm_over_rest(s, base, "scheduler.readback")
                 flood_done = await asyncio.gather(*flood)
                 out["flood_status"] = sorted(
@@ -1746,7 +1745,12 @@ def _run_slo_burn_scenario(spec: dict) -> ScenarioResult:
             "eval_interval_s": 0.1, "fast_window_s": 2.0,
             "slow_window_s": 4.0, "min_samples": 3,
             "shed_after": 2, "recover_after": 2, "shed_retry_after_s": 1.0,
-            "objectives": {"itl_p99": {"threshold_ms": itl_threshold_ms}},
+            # only the itl objective is under test: on a loaded CPU a cold
+            # compile or a queued request burns the shipped ttft and queue
+            # objectives before the fault is armed, so those are out of reach
+            "objectives": {"itl_p99": {"threshold_ms": itl_threshold_ms},
+                           "ttft_p95": {"threshold_ms": 120000.0},
+                           "queue_wait_p95": {"threshold_ms": 120000.0}},
             # watchdogs quiet — this scenario is the SLO leg (the stall
             # scenario owns the watchdog leg)
             "stream_stall_s": 120.0, "round_stall_floor_s": 120.0,
@@ -1796,14 +1800,20 @@ def _run_slo_burn_scenario(spec: dict) -> ScenarioResult:
                 out["readyz_healthy"], _ = await readyz()
 
                 # phase B — arm the burn over the guarded control plane,
-                # then keep streams in flight while the state machine flips
+                # then keep streams in flight while the state machine flips:
+                # a second wave submitted WITH the first passes the gate
+                # while the state is still healthy (no faulted request has
+                # finished yet) and queues behind it for the four slots.
+                # Submitted after the first wave's answers, it raced the
+                # doctor, which sheds 0.2 s after those four samples land
                 await arm_over_rest(s, base, "scheduler.readback",
                                     delay_spec, seed=seed)
-                first_wave = await asyncio.gather(
-                    *[completion(p) for p in prompts])
-                out["first_wave_status"] = [st for st, _, _ in first_wave]
+                first = [asyncio.ensure_future(completion(p))
+                         for p in prompts]
                 inflight = [asyncio.ensure_future(completion(p))
                             for p in prompts]
+                first_wave = await asyncio.gather(*first)
+                out["first_wave_status"] = [st for st, _, _ in first_wave]
                 shed_status, shed_doc = None, {}
                 deadline = time.monotonic() + 30.0
                 while time.monotonic() < deadline:

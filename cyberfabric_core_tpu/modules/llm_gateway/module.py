@@ -15,6 +15,7 @@ POST/GET/DELETE /v1/jobs, POST/GET /v1/batches, media endpoints, GET /v1/realtim
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import datetime
 import json
 import uuid
@@ -139,6 +140,10 @@ def _migrate_0002(c):
     c.execute("ALTER TABLE llm_jobs ADD COLUMN principal TEXT")
     c.execute("ALTER TABLE llm_batches ADD COLUMN principal TEXT")
 
+
+#: websocket message types after which nothing more arrives
+_WS_ENDED = (aiohttp.WSMsgType.CLOSE, aiohttp.WSMsgType.CLOSING,
+             aiohttp.WSMsgType.CLOSED, aiohttp.WSMsgType.ERROR)
 
 _MIGRATIONS = [Migration("0001_llm_jobs", _migrate_0001),
                Migration("0002_job_principal", _migrate_0002)]
@@ -1136,135 +1141,205 @@ class LlmGatewayModule(Module, RestApiCapability, RunnableCapability,
         """WS /realtime (DESIGN.md:262-271): bidirectional session — client sends
         `{type: "chat.create", request: {...}}` frames, server streams
         `{type: "token", ...}` / `{type: "done", usage}` / `{type: "error"}`
-        events. Text modality now; the audio frames of the spec slot into the
-        same session protocol."""
+        events; binary frames fill an audio buffer that `audio.commit`
+        transcribes. Frames are served one at a time, in order. Every
+        `audio.commit` and `chat.create` ends with one terminal event that
+        carries the frame's `id` (docs/MODULES.md, "Realtime session")."""
         ctx: SecurityContext = request[SECURITY_CONTEXT_KEY]
         ws = web.WebSocketResponse(heartbeat=20.0)
         await ws.prepare(request)
         audio_buf = bytearray()  # realtime audio frames (PRD audio modality)
-        async for msg in ws:
-            if msg.type == aiohttp.WSMsgType.BINARY:
-                # binary frames append to the session's input audio buffer
-                # (the spec's input_audio_buffer.append, bytes instead of b64);
-                # bounded like every other input path
-                if len(audio_buf) + len(msg.data) > 16 * 1024 * 1024:
-                    await ws.send_json({"type": "error", "error": {
-                        "code": "audio_buffer_full",
-                        "detail": "audio buffer limit 16MiB; commit or clear"}})
+        # The socket is read for the whole session, also while a frame is
+        # served: aiohttp counts heartbeat pongs only inside receive(), so a
+        # frame served without reading (a cold compile, a slow provider) is
+        # cut by the server's own ping after heartbeat x 1.5 = 30 s and its
+        # next send raises with no event delivered. Frames the client sends
+        # ahead wait in `inbox`; past 16 of them reading pauses.
+        inbox: asyncio.Queue = asyncio.Queue(maxsize=16)
+        reader = asyncio.ensure_future(self._realtime_read(ws, inbox))
+        try:
+            while True:
+                msg = await inbox.get()
+                if msg.type in _WS_ENDED:
+                    break
+                if msg.type == aiohttp.WSMsgType.BINARY:
+                    # binary frames append to the session's input audio buffer
+                    # (the spec's input_audio_buffer.append, bytes instead of
+                    # b64); bounded like every other input path
+                    if len(audio_buf) + len(msg.data) > 16 * 1024 * 1024:
+                        await ws.send_json({"type": "error", "error": {
+                            "code": "audio_buffer_full",
+                            "detail": "audio buffer limit 16MiB; commit or clear"}})
+                        continue
+                    audio_buf.extend(msg.data)
+                    await ws.send_json({"type": "audio.appended",
+                                        "buffered_bytes": len(audio_buf)})
                     continue
-                audio_buf.extend(msg.data)
-                await ws.send_json({"type": "audio.appended",
-                                    "buffered_bytes": len(audio_buf)})
-                continue
-            if msg.type != aiohttp.WSMsgType.TEXT:
-                continue
-            try:
-                frame = json.loads(msg.data)
-            except json.JSONDecodeError:
-                await ws.send_json({"type": "error",
-                                    "error": {"code": "malformed_json"}})
-                continue
-            if frame.get("type") == "session.close":
-                break
-            if frame.get("type") == "audio.clear":
-                audio_buf.clear()
-                await ws.send_json({"type": "audio.cleared"})
-                continue
-            if frame.get("type") == "audio.commit":
-                # committed audio → STT via the provider adapter, transcript
-                # returned to the client (who typically folds it into the next
-                # chat.create) — the session protocol of DESIGN.md realtime
-                event_id = frame.get("id") or f"rt-{uuid.uuid4().hex[:12]}"
+                if msg.type != aiohttp.WSMsgType.TEXT:
+                    continue
                 try:
-                    if not audio_buf:
-                        raise ProblemError.bad_request("audio buffer is empty")
-                    self.usage.check_budget(ctx)
-                    model = await self.registry.resolve(
-                        ctx, frame.get("model") or "")
-                    out = await self._media_required().transcribe(
-                        ctx, model, bytes(audio_buf),
-                        frame.get("mime_type", "audio/wav"),
-                        {"language": frame.get("language")})
-                    self.usage.report(ctx, {"media_requests": 1,
-                                            "stt_bytes": len(audio_buf)})
+                    frame = json.loads(msg.data)
+                except json.JSONDecodeError:
+                    await ws.send_json({"type": "error",
+                                        "error": {"code": "malformed_json"}})
+                    continue
+                kind = frame.get("type")
+                if kind == "session.close":
+                    break
+                if kind == "audio.clear":
                     audio_buf.clear()
-                    # incremental transcript deltas (DESIGN.md realtime
-                    # surface): clients consume a uniform delta stream; the
-                    # relay chunks at word boundaries today, and a streaming
-                    # STT provider refines granularity without a protocol
-                    # change. The final `transcript` event stays authoritative.
-                    words = out["text"].split(" ")
-                    chunk_words = 8
-                    for wi in range(0, len(words), chunk_words):
-                        await ws.send_json({
-                            "type": "transcript.delta", "id": event_id,
-                            "delta": (" " if wi else "")
-                            + " ".join(words[wi:wi + chunk_words])})
-                    await ws.send_json({"type": "transcript", "id": event_id,
-                                        "text": out["text"],
-                                        "model_used": out["model_used"]})
-                except ProblemError as e:
-                    await ws.send_json({"type": "error", "id": event_id,
-                                        "error": e.problem.to_dict()})
-                continue
-            if frame.get("type") != "chat.create":
-                await ws.send_json({"type": "error", "error": {
-                    "code": "unknown_frame_type",
-                    "detail": f"{frame.get('type')!r}"}})
-                continue
-            body = frame.get("request") or {}
-            event_id = frame.get("id") or f"rt-{uuid.uuid4().hex[:12]}"
-            try:
-                validate_against(schemas.REQUEST, body)
-                self._check_load_shed(ctx)
-                self.usage.check_budget(ctx)
-                # WS frames carry no per-request header; the config default
-                # TTL still bounds each chat.create end-to-end (a vanished
-                # WS peer's frame cannot decode to max_tokens forever)
-                if self.default_deadline_ms > 0:
-                    body.setdefault("_deadline_ms", self.default_deadline_ms)
-                body.setdefault("_tenant_id", ctx.tenant_id)
-                models = await self._resolve_with_fallback(ctx, body)
-                _, model = models[0]
-                reply_parts: list[str] = []
-                async for chunk in self._chat_once(ctx, model, body):
-                    if chunk.text:
-                        reply_parts.append(chunk.text)
-                        await ws.send_json({"type": "token", "id": event_id,
-                                            "content": chunk.text})
-                    if chunk.finish_reason:
-                        usage = dict(chunk.usage or {})
-                        self.usage.report(ctx, usage)
-                        await ws.send_json({
-                            "type": "done", "id": event_id,
-                            "finish_reason": chunk.finish_reason,
-                            "usage": usage, "model_used": model.canonical_id})
-                # TTS out-leg (DESIGN.md:262-271 bidirectional audio loop):
-                # frame-level `response_audio` asks the session to speak the
-                # reply — audio.out.begin, binary frames, audio.out.done
-                audio_out = frame.get("response_audio")
-                if audio_out and reply_parts:
-                    tts_model = await self.registry.resolve(
-                        ctx, audio_out.get("model") or "")
-                    audio, mime = await self._media_required().speech_raw(
-                        ctx, tts_model, {
-                            "input": "".join(reply_parts),
-                            "voice": audio_out.get("voice", "alloy"),
-                            "response_format": audio_out.get("format", "mp3")})
-                    self.usage.report(ctx, {"media_requests": 1,
-                                            "tts_chars": len("".join(reply_parts))})
-                    await ws.send_json({"type": "audio.out.begin",
-                                        "id": event_id, "mime_type": mime,
-                                        "model_used": tts_model.canonical_id})
-                    for off in range(0, len(audio), 32768):
-                        await ws.send_bytes(audio[off:off + 32768])
-                    await ws.send_json({"type": "audio.out.done",
-                                        "id": event_id,
-                                        "bytes": len(audio)})
-            except ProblemError as e:
-                await ws.send_json({"type": "error", "id": event_id,
-                                    "error": e.problem.to_dict()})
+                    await ws.send_json({"type": "audio.cleared"})
+                    continue
+                event_id = frame.get("id") or f"rt-{uuid.uuid4().hex[:12]}"
+                if kind == "audio.commit":
+                    work = self._realtime_commit(ws, ctx, frame, event_id,
+                                                 audio_buf)
+                elif kind == "chat.create":
+                    work = self._realtime_chat(ws, ctx, frame, event_id)
+                else:
+                    await ws.send_json({"type": "error", "error": {
+                        "code": "unknown_frame_type", "detail": f"{kind!r}"}})
+                    continue
+                await self._realtime_serve(ws, event_id, work, reader)
+        finally:
+            reader.cancel()
         return ws
+
+    @staticmethod
+    async def _realtime_read(ws: web.WebSocketResponse,
+                             inbox: asyncio.Queue) -> None:
+        """Every message of the session into ``inbox``, the one that ends it
+        last."""
+        while True:
+            msg = await ws.receive()
+            await inbox.put(msg)
+            if msg.type in _WS_ENDED:
+                return
+
+    async def _realtime_serve(self, ws: web.WebSocketResponse, event_id: str,
+                              work, reader: asyncio.Future) -> None:
+        """One frame's work, ended the way the REST error mapping ends a
+        request: a ProblemError, or any other exception as
+        ``core.internal_error``, becomes the frame's ``error`` event. A peer
+        that leaves mid-frame (``reader`` done) cancels the work, and with it
+        the engine-side request."""
+        task = asyncio.ensure_future(work)
+        try:
+            await asyncio.wait({task, reader},
+                               return_when=asyncio.FIRST_COMPLETED)
+            if not task.done():
+                task.cancel()
+            await task
+            return
+        except ProblemError as e:
+            problem = e.problem
+        except ConnectionError:
+            return  # the peer is gone: nobody to tell
+        except asyncio.CancelledError:
+            if asyncio.current_task().cancelling():  # not ours: the handler's
+                task.cancel()
+                raise
+            return
+        except Exception:  # noqa: BLE001 — session boundary, as the gateway's
+            import logging
+            logging.getLogger("llm_gateway").exception(
+                "unhandled error in realtime frame %s", event_id)
+            problem = ERR.core.internal_error.problem()
+        with contextlib.suppress(ConnectionError):
+            await ws.send_json({"type": "error", "id": event_id,
+                                "error": problem.to_dict()})
+
+    async def _realtime_commit(self, ws, ctx: SecurityContext, frame: dict,
+                               event_id: str, audio_buf: bytearray) -> None:
+        """committed audio → STT via the provider adapter, transcript
+        returned to the client (who typically folds it into the next
+        chat.create) — the session protocol of DESIGN.md realtime. Ends
+        with ``transcript`` (or the caller's ``error``)."""
+        if not audio_buf:
+            raise ProblemError.bad_request("audio buffer is empty")
+        self.usage.check_budget(ctx)
+        model = await self.registry.resolve(ctx, frame.get("model") or "")
+        out = await self._media_required().transcribe(
+            ctx, model, bytes(audio_buf),
+            frame.get("mime_type", "audio/wav"),
+            {"language": frame.get("language")})
+        self.usage.report(ctx, {"media_requests": 1,
+                                "stt_bytes": len(audio_buf)})
+        audio_buf.clear()
+        # incremental transcript deltas (DESIGN.md realtime surface):
+        # clients consume a uniform delta stream; the relay chunks at word
+        # boundaries today, and a streaming STT provider refines granularity
+        # without a protocol change. The final `transcript` event stays
+        # authoritative.
+        words = out["text"].split(" ")
+        chunk_words = 8
+        for wi in range(0, len(words), chunk_words):
+            await ws.send_json({
+                "type": "transcript.delta", "id": event_id,
+                "delta": (" " if wi else "")
+                + " ".join(words[wi:wi + chunk_words])})
+        await ws.send_json({"type": "transcript", "id": event_id,
+                            "text": out["text"],
+                            "model_used": out["model_used"]})
+
+    async def _realtime_chat(self, ws, ctx: SecurityContext, frame: dict,
+                             event_id: str) -> None:
+        """One chat exchange: ``token`` events, then ``done``. With
+        ``response_audio`` the exchange goes on to speak the reply and ends
+        with exactly one of ``audio.out.done`` or the caller's ``error``: a
+        reply with no text (a lapsed deadline, an immediate stop) is
+        ``audio.out.done`` with ``bytes: 0`` and no ``audio.out.begin`` —
+        nothing was sent to the TTS provider and nothing billed."""
+        body = frame.get("request") or {}
+        validate_against(schemas.REQUEST, body)
+        self._check_load_shed(ctx)
+        self.usage.check_budget(ctx)
+        # WS frames carry no per-request header; the config default
+        # TTL still bounds each chat.create end-to-end (a vanished
+        # WS peer's frame cannot decode to max_tokens forever)
+        if self.default_deadline_ms > 0:
+            body.setdefault("_deadline_ms", self.default_deadline_ms)
+        body.setdefault("_tenant_id", ctx.tenant_id)
+        models = await self._resolve_with_fallback(ctx, body)
+        _, model = models[0]
+        reply_parts: list[str] = []
+        async for chunk in self._chat_once(ctx, model, body):
+            if chunk.text:
+                reply_parts.append(chunk.text)
+                await ws.send_json({"type": "token", "id": event_id,
+                                    "content": chunk.text})
+            if chunk.finish_reason:
+                usage = dict(chunk.usage or {})
+                self.usage.report(ctx, usage)
+                await ws.send_json({
+                    "type": "done", "id": event_id,
+                    "finish_reason": chunk.finish_reason,
+                    "usage": usage, "model_used": model.canonical_id})
+        # TTS out-leg (DESIGN.md:262-271 bidirectional audio loop):
+        # frame-level `response_audio` asks the session to speak the
+        # reply — audio.out.begin, binary frames, audio.out.done
+        audio_out = frame.get("response_audio")
+        if not audio_out:
+            return
+        reply = "".join(reply_parts)
+        audio = b""
+        if reply:
+            tts_model = await self.registry.resolve(
+                ctx, audio_out.get("model") or "")
+            audio, mime = await self._media_required().speech_raw(
+                ctx, tts_model, {
+                    "input": reply,
+                    "voice": audio_out.get("voice", "alloy"),
+                    "response_format": audio_out.get("format", "mp3")})
+            self.usage.report(ctx, {"media_requests": 1,
+                                    "tts_chars": len(reply)})
+            await ws.send_json({"type": "audio.out.begin",
+                                "id": event_id, "mime_type": mime,
+                                "model_used": tts_model.canonical_id})
+            for off in range(0, len(audio), 32768):
+                await ws.send_bytes(audio[off:off + 32768])
+        await ws.send_json({"type": "audio.out.done", "id": event_id,
+                            "bytes": len(audio)})
 
     # ------------------------------------------------------------- media (PRD FRs)
     def _get_media(self):

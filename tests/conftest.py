@@ -18,13 +18,142 @@ if not os.environ.get("RUN_TPU_TESTS"):
 
     assert jax.devices()[0].platform == "cpu", "tests must run on the virtual CPU mesh"
 
+import faulthandler  # noqa: E402
+import signal  # noqa: E402
+import sys  # noqa: E402
+
 import pytest  # noqa: E402
+
+#: The one time limit of every test, for each of its set-up, call and tear-down:
+#: about five times the slowest item measured under six workers (a 43 s
+#: module-scoped fixture, which counts against the item that builds it). A test
+#: that waits on a socket, a subprocess or a thread states its own, shorter one.
+TEST_TIME_LIMIT_S = 240.0
 
 
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "slow: long-running gates excluded from the tier-1 `-m 'not slow'` run")
+
+
+def _time_limited(item, phase):
+    """Fail `item` when one phase outlasts TEST_TIME_LIMIT_S and let the worker
+    go on. SIGALRM, because tests run in the main thread of each worker and
+    pytest-timeout is not installed where the driver runs. The alarm repeats:
+    asyncio's `Handle._run` logs and drops an exception raised inside a
+    callback, so a shot that lands there is followed by another."""
+    def on_alarm(signum, frame):
+        faulthandler.dump_traceback(file=sys.__stderr__)  # every thread, not only ours
+        pytest.fail(f"{item.nodeid}: {phase} passed the time limit of "
+                    f"{TEST_TIME_LIMIT_S:g} s", pytrace=True)
+
+    previous = signal.signal(signal.SIGALRM, on_alarm)
+    signal.setitimer(signal.ITIMER_REAL, TEST_TIME_LIMIT_S, TEST_TIME_LIMIT_S / 8)
+    try:
+        return (yield)
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_setup(item):
+    return (yield from _time_limited(item, "set-up"))
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_call(item):
+    return (yield from _time_limited(item, "call"))
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_teardown(item):
+    return (yield from _time_limited(item, "tear-down"))
+
+
+#: How long a test waits for one websocket message: a first chat of a cold server
+#: compiles for over 30 s beside five other workers.
+WS_RECEIVE_TIMEOUT_S = 120.0
+
+#: The doctor of every test server that does not state its own: latency
+#: objectives and watchdog floors past anything a test lives through, and an
+#: error budget that cannot reach `critical_burn`. At the shipped objectives a
+#: cold compile on a loaded CPU burns `ttft_p95` and the server sheds the
+#: test's own tenant (429). Tests about the SLO engine, the doctor or shedding
+#: give their server a `doctor` block of their own, which is left alone.
+_OUT_OF_REACH_S = 4 * TEST_TIME_LIMIT_S
+QUIET_DOCTOR = {
+    "objectives": {
+        "ttft_p95": {"threshold_ms": _OUT_OF_REACH_S * 1000},
+        "itl_p99": {"threshold_ms": _OUT_OF_REACH_S * 1000},
+        "queue_wait_p95": {"threshold_ms": _OUT_OF_REACH_S * 1000},
+        "error_rate": {"budget": 1.0},
+    },
+    "round_stall_floor_s": _OUT_OF_REACH_S, "stream_stall_s": _OUT_OF_REACH_S,
+    "queue_deadline_s": _OUT_OF_REACH_S, "loop_stall_s": _OUT_OF_REACH_S,
+}
+
+
+async def boot_stack(overrides, *, extra=None, db_manager=None):
+    """Boot the modules `overrides` names (or the `extra` registrations alone)
+    behind a gateway on an ephemeral port; returns `(runtime, base_url)`.
+    Where the stack boots `monitoring` without a `doctor` block it gets
+    QUIET_DOCTOR. The database is in memory unless `db_manager` says otherwise."""
+    import copy
+
+    from cyberfabric_core_tpu.modkit import (AppConfig, ClientHub, ModuleRegistry,
+                                             RunOptions)
+    from cyberfabric_core_tpu.modkit.db import DbManager
+    from cyberfabric_core_tpu.modkit.runtime import HostRuntime
+
+    overrides = copy.deepcopy(overrides)
+    if "monitoring" in overrides.get("modules", {}):
+        entry = overrides["modules"]["monitoring"]
+        entry.setdefault("config", {}).setdefault("doctor", QUIET_DOCTOR)
+    cfg = AppConfig.load_or_default(environ={}, cli_overrides=overrides)
+    if extra is None:
+        import cyberfabric_core_tpu.modules  # noqa: F401 — registers everything
+        registry = ModuleRegistry.discover_and_build(enabled=cfg.module_names())
+    else:
+        registry = ModuleRegistry.discover_and_build(extra=extra)
+    rt = HostRuntime(RunOptions(
+        config=cfg, registry=registry, client_hub=ClientHub(),
+        db_manager=db_manager or DbManager(in_memory=True)))
+    await rt.run_setup_phases()
+    port = registry.get("api_gateway").instance.bound_port
+    return rt, f"http://127.0.0.1:{port}"
+
+
+async def stop_stack(rt):
+    """Tear down what `boot_stack` booted (the oagw's client session first,
+    where the stack has one)."""
+    if "oagw" in rt.registry.names():
+        await rt.registry.get("oagw").instance.service.close()
+    rt.root_token.cancel()
+    await rt.run_stop_phase()
+
+
+async def ws_event(ws, seen, timeout=WS_RECEIVE_TIMEOUT_S):
+    """The next data message of a websocket: the parsed event of a text frame,
+    the bytes of a binary one. A socket that ends or stays silent for
+    `timeout` fails the test with `seen`, to which every message is added."""
+    import asyncio
+    import json
+
+    import aiohttp
+
+    try:
+        msg = await ws.receive(timeout=timeout)
+    except asyncio.TimeoutError:
+        pytest.fail(f"no websocket message within {timeout:g} s after {seen!r}")
+    if msg.type not in (aiohttp.WSMsgType.TEXT, aiohttp.WSMsgType.BINARY):
+        # CLOSE/CLOSING/CLOSED/ERROR: receive() would return the same at once,
+        # for ever (ping and pong never reach the caller)
+        pytest.fail(f"websocket ended with {msg!r} after {seen!r}")
+    event = json.loads(msg.data) if msg.type == aiohttp.WSMsgType.TEXT else msg.data
+    seen.append(event)
+    return event
 
 
 @pytest.fixture()

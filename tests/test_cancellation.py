@@ -338,6 +338,7 @@ def test_half_consumed_stream_cancels_engine_side():
     closed after one chunk — the SSE consumer vanished) must cancel the
     worker-side queue consumer AND the engine-side work, freeing the slot
     within a round instead of decoding to max_tokens."""
+    from cyberfabric_core_tpu.modkit import failpoints as fp
     from cyberfabric_core_tpu.modules.llm_gateway.worker import LocalTpuWorker
 
     async def go():
@@ -360,7 +361,15 @@ def test_half_consumed_stream_cancels_engine_side():
         sched.shutdown()
         return sched, stats
 
-    sched, stats = asyncio.run(go())
+    # 50 ms a readback keeps the engine decoding for over a second: on a loaded
+    # CPU it otherwise finishes its (short) answer before this thread is
+    # scheduled to close the generator, and a finished request is not cancelled
+    fp.configure(0)
+    fp.arm("scheduler.readback", "delay(0.05)")
+    try:
+        sched, stats = asyncio.run(go())
+    finally:
+        fp.disarm("scheduler.readback")
     assert stats["cancellations"].get("client_disconnect") == 1, stats
     assert stats["reclaimed_tokens"] > 0
     _assert_clean(sched)

@@ -13,6 +13,8 @@ import json
 import aiohttp
 import pytest
 
+from conftest import boot_stack, stop_stack
+
 
 def _config(home_dir):
     return {
@@ -34,25 +36,11 @@ def _config(home_dir):
 
 
 async def _boot(home_dir):
-    from cyberfabric_core_tpu.modkit import (AppConfig, ClientHub,
-                                             ModuleRegistry, RunOptions)
+    """A file-backed DbManager, so that a second boot finds the first one's rows."""
     from cyberfabric_core_tpu.modkit.db import DbManager
-    from cyberfabric_core_tpu.modkit.runtime import HostRuntime
-    import cyberfabric_core_tpu.modules  # noqa: F401
 
-    cfg = AppConfig.load_or_default(environ={}, cli_overrides=_config(home_dir))
-    registry = ModuleRegistry.discover_and_build(enabled=cfg.module_names())
-    rt = HostRuntime(RunOptions(
-        config=cfg, registry=registry, client_hub=ClientHub(),
-        db_manager=DbManager(home_dir=home_dir)))
-    await rt.run_setup_phases()
-    base = f"http://127.0.0.1:{registry.get('api_gateway').instance.bound_port}"
-    return rt, base
-
-
-async def _shutdown(rt):
-    rt.root_token.cancel()
-    await rt.run_stop_phase()
+    return await boot_stack(_config(home_dir),
+                            db_manager=DbManager(home_dir=home_dir))
 
 
 def test_jobs_and_batches_survive_restart(tmp_path):
@@ -95,7 +83,7 @@ def test_jobs_and_batches_survive_restart(tmp_path):
                 assert b["status"] == "completed", b
             return job["id"], batch["id"]
         finally:
-            await _shutdown(rt)
+            await stop_stack(rt)
 
     loop = asyncio.new_event_loop()
     try:
@@ -182,7 +170,7 @@ def test_jobs_and_batches_survive_restart(tmp_path):
                             if i["custom_id"] == "todo")
                 assert todo["result"] is not None
         finally:
-            await _shutdown(rt)
+            await stop_stack(rt)
 
     loop = asyncio.new_event_loop()
     try:

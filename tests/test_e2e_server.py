@@ -5,9 +5,12 @@ ephemeral port; the tiny models run on the CPU backend.
 
 import asyncio
 import json
+import time
 
 import aiohttp
 import pytest
+
+from conftest import boot_stack, stop_stack, ws_event
 
 BASE_CONFIG = {
     # sampled tracing: the observability e2e asserts one trace covers the
@@ -63,24 +66,10 @@ BASE_CONFIG = {
 @pytest.fixture(scope="module")
 def server():
     """Boot the whole stack once for this test module."""
-    from cyberfabric_core_tpu.modkit import AppConfig, ClientHub, ModuleRegistry, RunOptions
-    from cyberfabric_core_tpu.modkit.db import DbManager
-    from cyberfabric_core_tpu.modkit.registry import _REGISTRATIONS
-    from cyberfabric_core_tpu.modkit.runtime import HostRuntime
-    import cyberfabric_core_tpu.modules  # noqa: F401 — registers everything
-
-    cfg = AppConfig.load_or_default(environ={}, cli_overrides=BASE_CONFIG)
-    registry = ModuleRegistry.discover_and_build(enabled=cfg.module_names())
-    opts = RunOptions(config=cfg, registry=registry, client_hub=ClientHub(),
-                      db_manager=DbManager(in_memory=True))
-    rt = HostRuntime(opts)
-
     loop = asyncio.new_event_loop()
-    loop.run_until_complete(rt.run_setup_phases())
-    gw = registry.get("api_gateway").instance
-    yield loop, f"http://127.0.0.1:{gw.bound_port}"
-    rt.root_token.cancel()
-    loop.run_until_complete(rt.run_stop_phase())
+    rt, base = loop.run_until_complete(boot_stack(BASE_CONFIG))
+    yield loop, base
+    loop.run_until_complete(stop_stack(rt))
     loop.close()
 
 
@@ -97,6 +86,20 @@ def req(server, method, path, **kw):
                     return r.status, raw
 
     return loop.run_until_complete(go())
+
+
+def finished_record(server, request_id, timeout_s=10.0):
+    """The flight record of a request whose answer has arrived, once it is
+    closed: the scheduler emits the terminal token to the client before it
+    writes `finished` into the record, so a reader that is quick (or a
+    scheduler thread that is starved) sees the record still open."""
+    deadline = time.monotonic() + timeout_s
+    while True:
+        status, rec = req(server, "GET", f"/v1/monitoring/requests/{request_id}")
+        assert status == 200, rec
+        if rec["phase"] == "finished" or time.monotonic() > deadline:
+            return rec
+        time.sleep(0.05)
 
 
 # ---------------------------------------------------------------- chat (M1 slice)
@@ -154,23 +157,10 @@ def test_raw_completions_endpoint(server):
 
 
 # ----------------------------------------- cancellation & deadlines (PR 9)
-def _clear_doctor_shed():
-    """The doctor is process-global and this module's earlier traffic (cold
-    CPU compiles blowing ttft_p95, injected-preempt stalls) can leave it in
-    `shedding` by the time these tail tests run — pre-enqueue 429s for
-    reasons unrelated to what they assert. Reset its windows/state machine
-    (same config) so these tests measure the cancellation path, not the
-    accumulated burn of the whole module."""
-    from cyberfabric_core_tpu.modkit.doctor import default_doctor
-
-    default_doctor.configure(default_doctor.config)
-
-
 def test_deadline_header_validated_and_served(server):
     """X-Request-Deadline-Ms: garbage is a 400 problem; a generous budget
     serves normally (the deadline threads to the scheduler and never
     trips)."""
-    _clear_doctor_shed()
     status, body = req(server, "POST", "/v1/completions",
                        json={"model": "local::tiny-llama", "prompt": "hi",
                              "max_tokens": 4},
@@ -190,13 +180,24 @@ def test_sse_disconnect_aborts_engine_side(server):
     must cancel the request (visible as llm_cancellations_total
     {reason=client_disconnect} on /metrics) instead of decoding the
     remaining budget for a dead socket."""
-    _clear_doctor_shed()
     loop, base = server
+    # the engine must still be decoding when the client walks away: take a
+    # prompt whose greedy answer runs to the model's limit of 64 tokens (some
+    # stop within the first chunk, and a finished request is never cancelled)
+    for i in range(8):
+        prompt = f"stream then vanish {i}"
+        status, body = req(server, "POST", "/v1/completions", json={
+            "model": "local::tiny-llama", "prompt": prompt, "max_tokens": 400})
+        assert status == 200, body
+        if body["usage"]["output_tokens"] == 64:
+            break
+    else:
+        pytest.fail("no candidate prompt decodes to the limit")
 
     async def go():
         async with aiohttp.ClientSession() as s:
             resp = await s.post(base + "/v1/completions", json={
-                "model": "local::tiny-llama", "prompt": "stream then vanish",
+                "model": "local::tiny-llama", "prompt": prompt,
                 "max_tokens": 400, "stream": True})
             assert resp.status == 200
             await resp.content.readany()  # one frame is enough
@@ -585,14 +586,11 @@ def test_realtime_websocket(server):
                     "messages": [{"role": "user",
                                   "content": [{"type": "text", "text": "hi"}]}]}})
                 events = []
-                async for msg in ws:
-                    ev = json.loads(msg.data)
-                    events.append(ev)
-                    if ev["type"] in ("done", "error"):
-                        break
+                while not events or events[-1]["type"] not in ("done", "error"):
+                    await ws_event(ws, events)
                 # unknown frame type gets an error event, session stays open
                 await ws.send_json({"type": "bogus"})
-                err = json.loads((await ws.receive()).data)
+                err = await ws_event(ws, [])
                 await ws.send_json({"type": "session.close"})
                 return events, err
 
@@ -701,8 +699,7 @@ def test_flight_recorder_trace_e2e(server):
     assert llm_trace_ids == {gateway_spans[0].trace_id}
 
     # the engine keyed its timeline by the id the client sent
-    status, rec = req(server, "GET", "/v1/monitoring/requests/e2e-flight-1")
-    assert status == 200, rec
+    rec = finished_record(server, "e2e-flight-1")
     kinds = [e["event"] for e in rec["timeline"]]
     for expected in ("enqueued", "admitted", "prefill", "decode_chunk",
                      "finished"):
@@ -773,12 +770,10 @@ def test_monitoring_replicas_surface(server):
     single-engine entry with its supervisor state, the capacity census
     aggregates it, and the POST actions validate index/state as RFC-9457
     problems (a single engine has no pool to drain into)."""
-    # make sure the tiny-llama engine entry exists (lazy build); earlier
-    # chaos tests may have left the doctor shedding, so tolerate a 429 —
-    # the entry was already built by the chat tests either way
-    status, _ = req(server, "POST", "/v1/completions", json={
+    # make sure the tiny-llama engine entry exists (lazy build)
+    status, body = req(server, "POST", "/v1/completions", json={
         "model": "local::tiny-llama", "prompt": "warm", "max_tokens": 2})
-    assert status in (200, 429)
+    assert status == 200, body
     status, doc = req(server, "GET", "/v1/monitoring/replicas")
     assert status == 200, doc
     row = next(r for r in doc["replicas"]
@@ -822,8 +817,7 @@ def test_sse_stream_carries_request_id_header(server):
                 await r.read()
 
     loop.run_until_complete(go())
-    status, rec = req(server, "GET", "/v1/monitoring/requests/e2e-sse-rid")
-    assert status == 200 and rec["phase"] == "finished"
+    assert finished_record(server, "e2e-sse-rid")["phase"] == "finished"
 
 
 def test_user_settings_crud(server):

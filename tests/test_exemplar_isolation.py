@@ -14,6 +14,8 @@ import json
 import aiohttp
 import pytest
 
+from conftest import boot_stack, stop_stack
+
 TOKENS = {
     "tok-alice": {"subject": "alice", "tenant_id": "acme",
                   "roles": ["member"]},
@@ -39,38 +41,21 @@ AUTHZ_RULES = {
 
 @pytest.fixture(scope="module")
 def stack():
-    import cyberfabric_core_tpu.modules  # noqa: F401 — full inventory
-    from cyberfabric_core_tpu.modkit import (
-        AppConfig, ClientHub, ModuleRegistry, RunOptions)
-    from cyberfabric_core_tpu.modkit.db import DbManager
-    from cyberfabric_core_tpu.modkit.runtime import HostRuntime
-
-    async def boot():
-        cfg = AppConfig.load_or_default(environ={}, cli_overrides={"modules": {
-            "api_gateway": {"config": {"bind_addr": "127.0.0.1:0"}},
-            "tenant_resolver": {"config": {"tenants": {
-                "acme": {}, "evil-corp": {}}}},
-            "authn_resolver": {"config": {"mode": "static", "tokens": TOKENS}},
-            "authz_resolver": {"config": {"rules": AUTHZ_RULES}},
-            "types_registry": {}, "module_orchestrator": {},
-            "nodes_registry": {}, "model_registry": {},
-            "llm_gateway": {}, "file_storage": {}, "credstore": {},
-            "file_parser": {}, "serverless_runtime": {}, "monitoring": {},
-            "user_settings": {},
-        }})
-        registry = ModuleRegistry.discover_and_build(enabled=cfg.module_names())
-        rt = HostRuntime(RunOptions(config=cfg, registry=registry,
-                                    client_hub=ClientHub(),
-                                    db_manager=DbManager(in_memory=True)))
-        await rt.run_setup_phases()
-        gw = registry.get("api_gateway").instance
-        return rt, f"http://127.0.0.1:{gw.bound_port}"
-
     loop = asyncio.new_event_loop()
-    rt, base = loop.run_until_complete(boot())
+    rt, base = loop.run_until_complete(boot_stack({"modules": {
+        "api_gateway": {"config": {"bind_addr": "127.0.0.1:0"}},
+        "tenant_resolver": {"config": {"tenants": {
+            "acme": {}, "evil-corp": {}}}},
+        "authn_resolver": {"config": {"mode": "static", "tokens": TOKENS}},
+        "authz_resolver": {"config": {"rules": AUTHZ_RULES}},
+        "types_registry": {}, "module_orchestrator": {},
+        "nodes_registry": {}, "model_registry": {},
+        "llm_gateway": {}, "file_storage": {}, "credstore": {},
+        "file_parser": {}, "serverless_runtime": {}, "monitoring": {},
+        "user_settings": {},
+    }}))
     yield loop, base
-    rt.root_token.cancel()
-    loop.run_until_complete(rt.run_stop_phase())
+    loop.run_until_complete(stop_stack(rt))
     loop.close()
 
 

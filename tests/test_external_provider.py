@@ -9,13 +9,12 @@ import aiohttp
 import pytest
 from aiohttp import web
 
+from conftest import boot_stack, stop_stack
+
 
 @pytest.fixture()
 def stack(fresh_registry):
-    from cyberfabric_core_tpu.modkit import AppConfig, ClientHub, ModuleRegistry, RunOptions
-    from cyberfabric_core_tpu.modkit.db import DbManager
     from cyberfabric_core_tpu.modkit.registry import Registration
-    from cyberfabric_core_tpu.modkit.runtime import HostRuntime
     from cyberfabric_core_tpu.gateway.module import ApiGatewayModule
     from cyberfabric_core_tpu.modules.credstore import CredStoreModule
     from cyberfabric_core_tpu.modules.llm_gateway.module import LlmGatewayModule
@@ -63,7 +62,7 @@ def stack(fresh_registry):
         await site.start()
         mock_port = site._server.sockets[0].getsockname()[1]  # noqa: SLF001
 
-        cfg = AppConfig.load_or_default(environ={}, cli_overrides={"modules": {
+        rt, base = await boot_stack({"modules": {
             "api_gateway": {"config": {"bind_addr": "127.0.0.1:0",
                                        "auth_disabled": True}},
             "tenant_resolver": {}, "credstore": {}, "oagw": {"config": {
@@ -74,13 +73,7 @@ def stack(fresh_registry):
                             "provider_model_id": "gpt-x",
                             "approval_state": "approved", "managed": False}]}},
             "llm_gateway": {},
-        }})
-        registry = ModuleRegistry.discover_and_build(extra=regs)
-        rt = HostRuntime(RunOptions(config=cfg, registry=registry,
-                                    client_hub=ClientHub(),
-                                    db_manager=DbManager(in_memory=True)))
-        await rt.run_setup_phases()
-        base = f"http://127.0.0.1:{registry.get('api_gateway').instance.bound_port}"
+        }}, extra=regs)
 
         async with aiohttp.ClientSession() as s:
             # provider credential + upstream named by provider_slug
@@ -95,10 +88,7 @@ def stack(fresh_registry):
     loop = asyncio.new_event_loop()
     rt, runner, base = loop.run_until_complete(boot())
     yield loop, base, seen_requests
-    loop.run_until_complete(
-        rt.registry.get("oagw").instance.service.close())
-    rt.root_token.cancel()
-    loop.run_until_complete(rt.run_stop_phase())
+    loop.run_until_complete(stop_stack(rt))
     loop.run_until_complete(runner.cleanup())
     loop.close()
 

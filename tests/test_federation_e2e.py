@@ -24,7 +24,6 @@ lives in tests/test_federation.py and tests/test_fleetscope.py.
 """
 
 import asyncio
-import copy
 import json
 import os
 import signal
@@ -34,6 +33,8 @@ import time
 
 import aiohttp
 import pytest
+
+from conftest import boot_stack, stop_stack
 
 MODEL_KEY = "local::tiny-llama"
 # decode_chunk 2: itl_ms derives from gaps BETWEEN decode_chunk flight
@@ -74,18 +75,12 @@ CONFIG = {
                                 "eviction_interval_s": 0.5}},
         "llm_gateway": {"config": {"federation": {
             "enabled": True, "failover_backoff_s": 0.01, "seed": 0}}},
-        # CPU compiles and a DELIBERATE host kill would trip the doctor's
-        # SLO burn into load-shedding 429s — this e2e asserts routing and
-        # failover, not SLOs, so the GATEWAY doctor gets generous
-        # thresholds (allow_fault_injection is for the cross-host arm in
-        # the fleet-doctor test, where the fault fires in a WORKER)
-        "monitoring": {"config": {
-            "allow_fault_injection": True,
-            "doctor": {
-                "objectives": {"ttft_p95": {"threshold_ms": 120000.0,
-                                            "budget": 0.5}},
-                "stream_stall_s": 300.0, "round_stall_floor_s": 300.0,
-                "queue_deadline_s": 300.0, "shed_after": 1000}}},
+        # this e2e asserts routing and failover, not the GATEWAY's SLOs: its
+        # doctor is conftest's QUIET_DOCTOR, which neither CPU compiles nor
+        # the DELIBERATE host kill burn (allow_fault_injection is for the
+        # cross-host arm in the fleet-doctor test, where the fault fires in
+        # a WORKER)
+        "monitoring": {"config": {"allow_fault_injection": True}},
     }
 }
 
@@ -125,26 +120,13 @@ PROMPT_B = "federated e2e crash victim bravo " * 4
 @pytest.fixture(scope="module")
 def fed(tmp_path_factory):
     """Boot the gateway stack, then 2 worker subprocesses dialing its hub."""
-    from cyberfabric_core_tpu.modkit import (AppConfig, ClientHub,
-                                             ModuleRegistry, RunOptions)
-    from cyberfabric_core_tpu.modkit.db import DbManager
-    from cyberfabric_core_tpu.modkit.runtime import HostRuntime
     from cyberfabric_core_tpu.modules.llm_gateway.grpc_service import \
         model_ref_dict
     from cyberfabric_core_tpu.modules.sdk import ModelInfo
-    import cyberfabric_core_tpu.modules  # noqa: F401 — registers everything
 
-    cfg = AppConfig.load_or_default(environ={},
-                                    cli_overrides=copy.deepcopy(CONFIG))
-    registry = ModuleRegistry.discover_and_build(enabled=cfg.module_names())
-    opts = RunOptions(config=cfg, registry=registry, client_hub=ClientHub(),
-                      db_manager=DbManager(in_memory=True))
-    rt = HostRuntime(opts)
     loop = asyncio.new_event_loop()
-    loop.run_until_complete(rt.run_setup_phases())
-    gw = registry.get("api_gateway").instance
-    hub = registry.get("grpc_hub").instance
-    base = f"http://127.0.0.1:{gw.bound_port}"
+    rt, base = loop.run_until_complete(boot_stack(CONFIG))
+    hub = rt.registry.get("grpc_hub").instance
 
     model = ModelInfo(canonical_id=MODEL_KEY, provider_slug="local",
                       provider_model_id="tiny-llama", managed=True,
@@ -220,22 +202,8 @@ def fed(tmp_path_factory):
             p.wait(timeout=30)
             if p.stdout is not None:
                 p.stdout.close()
-        rt.root_token.cancel()
-        loop.run_until_complete(rt.run_stop_phase())
+        loop.run_until_complete(stop_stack(rt))
         loop.close()
-
-
-@pytest.fixture(autouse=True)
-def _clear_doctor_shed():
-    """The doctor is process-global; cold CPU compiles blowing ttft_p95 and
-    the DELIBERATE host kill in the crash test can leave it `shedding` —
-    pre-enqueue 429s for reasons unrelated to what these tests assert.
-    Reset its windows/state machine (same config) around every test."""
-    from cyberfabric_core_tpu.modkit.doctor import default_doctor
-
-    default_doctor.configure(default_doctor.config)
-    yield
-    default_doctor.configure(default_doctor.config)
 
 
 def req(fed, method, path, **kw):

@@ -9,15 +9,13 @@ import json
 import aiohttp
 import pytest
 
+from conftest import boot_stack, stop_stack
 from cyberfabric_core_tpu.modkit import (
-    AppConfig,
     Module,
-    ModuleRegistry,
     RestApiCapability,
     module,
 )
 from cyberfabric_core_tpu.modkit.errors import ProblemError
-from cyberfabric_core_tpu.modkit.runtime import HostRuntime, RunOptions
 from cyberfabric_core_tpu.modkit.security import SecurityContext
 from cyberfabric_core_tpu.modkit.sse import SSE_DONE, format_sse_json
 from cyberfabric_core_tpu.gateway.middleware import SECURITY_CONTEXT_KEY, AuthnApi
@@ -81,30 +79,16 @@ def gateway_app(fresh_registry):
             router.operation("GET", "/v1/slow", module="sample").public().handler(slow).register()
             router.operation("GET", "/v1/limited", module="sample").public().rate_limit(rps=0.0001, burst=2).handler(whoami).register()
 
-    async def boot():
-        cfg = AppConfig.load_or_default(
-            environ={},
-            cli_overrides={
-                "modules": {
-                    "api_gateway": {"config": {
-                        "bind_addr": "127.0.0.1:0", "auth_disabled": True,
-                        "timeout_secs": 0.5, "max_body_bytes": 2048,
-                    }},
-                    "sample": {},
-                }
-            },
-        )
-        reg = ModuleRegistry.discover_and_build(extra=[gw_reg])
-        rt = HostRuntime(RunOptions(config=cfg, registry=reg))
-        await rt.run_setup_phases()
-        gw = reg.get("api_gateway").instance
-        return rt, gw
-
     loop = asyncio.new_event_loop()
-    rt, gw = loop.run_until_complete(boot())
-    yield loop, f"http://127.0.0.1:{gw.bound_port}"
-    rt.root_token.cancel()
-    loop.run_until_complete(rt.run_stop_phase())
+    rt, base = loop.run_until_complete(boot_stack({"modules": {
+        "api_gateway": {"config": {
+            "bind_addr": "127.0.0.1:0", "auth_disabled": True,
+            "timeout_secs": 0.5, "max_body_bytes": 2048,
+        }},
+        "sample": {},
+    }}, extra=[gw_reg]))
+    yield loop, base
+    loop.run_until_complete(stop_stack(rt))
     loop.close()
 
 
@@ -293,22 +277,11 @@ def test_unknown_route_fails_closed_with_auth(fresh_registry):
             router.operation("GET", "/v1/secured", module="sample") \
                 .auth_required().handler(whoami).register()
 
-    async def boot():
-        cfg = AppConfig.load_or_default(
-            environ={},
-            cli_overrides={"modules": {
-                "api_gateway": {"config": {"bind_addr": "127.0.0.1:0"}},
-                "sample": {},
-            }},
-        )
-        reg = ModuleRegistry.discover_and_build(extra=[gw_reg])
-        rt = HostRuntime(RunOptions(config=cfg, registry=reg))
-        await rt.run_setup_phases()
-        return rt, reg.get("api_gateway").instance
-
     loop = asyncio.new_event_loop()
-    rt, gw = loop.run_until_complete(boot())
-    base = f"http://127.0.0.1:{gw.bound_port}"
+    rt, base = loop.run_until_complete(boot_stack({"modules": {
+        "api_gateway": {"config": {"bind_addr": "127.0.0.1:0"}},
+        "sample": {},
+    }}, extra=[gw_reg]))
     try:
         s_matched, _, _ = _req(loop, "GET", f"{base}/v1/secured")
         s_unmatched, _, _ = _req(loop, "GET", f"{base}/v1/does-not-exist")
@@ -318,8 +291,7 @@ def test_unknown_route_fails_closed_with_auth(fresh_registry):
         s_health, _, _ = _req(loop, "GET", f"{base}/healthz")
         assert s_health == 200
     finally:
-        rt.root_token.cancel()
-        loop.run_until_complete(rt.run_stop_phase())
+        loop.run_until_complete(stop_stack(rt))
         loop.close()
 
 
@@ -349,24 +321,13 @@ def test_cors_preflight_and_error_headers(fresh_registry):
             router.operation("POST", "/v1/only-post", module="sample") \
                 .public().handler(echo).register()
 
-    async def boot():
-        cfg = AppConfig.load_or_default(
-            environ={},
-            cli_overrides={"modules": {
-                "api_gateway": {"config": {
-                    "bind_addr": "127.0.0.1:0", "auth_disabled": True,
-                    "cors_allow_origin": "https://app.example"}},
-                "sample": {},
-            }},
-        )
-        reg = ModuleRegistry.discover_and_build(extra=[gw_reg])
-        rt = HostRuntime(RunOptions(config=cfg, registry=reg))
-        await rt.run_setup_phases()
-        return rt, reg.get("api_gateway").instance
-
     loop = asyncio.new_event_loop()
-    rt, gw = loop.run_until_complete(boot())
-    base = f"http://127.0.0.1:{gw.bound_port}"
+    rt, base = loop.run_until_complete(boot_stack({"modules": {
+        "api_gateway": {"config": {
+            "bind_addr": "127.0.0.1:0", "auth_disabled": True,
+            "cors_allow_origin": "https://app.example"}},
+        "sample": {},
+    }}, extra=[gw_reg]))
     try:
         # preflight against a POST-only route: 204 + CORS headers
         status, headers, _ = _req(loop, "OPTIONS", f"{base}/v1/only-post")
@@ -385,6 +346,5 @@ def test_cors_preflight_and_error_headers(fresh_registry):
         assert status == 404
         assert headers.get("Access-Control-Allow-Origin") == "https://app.example"
     finally:
-        rt.root_token.cancel()
-        loop.run_until_complete(rt.run_stop_phase())
+        loop.run_until_complete(stop_stack(rt))
         loop.close()

@@ -3,11 +3,12 @@ mock provider — llm-gateway PRD FRs :104-311, ADR-0003 media-via-FileStorage."
 
 import asyncio
 import base64
-import json
 
 import aiohttp
 import pytest
 from aiohttp import web
+
+from conftest import boot_stack, stop_stack, ws_event
 
 PNG = (b"\x89PNG\r\n\x1a\n" + b"\x00" * 16)
 MP3 = b"ID3fake-mp3-bytes" * 4
@@ -16,10 +17,7 @@ MP4 = b"\x00\x00\x00 ftypisom" + b"\x00" * 24
 
 @pytest.fixture()
 def stack(fresh_registry):
-    from cyberfabric_core_tpu.modkit import AppConfig, ClientHub, ModuleRegistry, RunOptions
-    from cyberfabric_core_tpu.modkit.db import DbManager
     from cyberfabric_core_tpu.modkit.registry import Registration
-    from cyberfabric_core_tpu.modkit.runtime import HostRuntime
     from cyberfabric_core_tpu.gateway.module import ApiGatewayModule
     from cyberfabric_core_tpu.modules.credstore import CredStoreModule
     from cyberfabric_core_tpu.modules.file_storage import FileStorageModule
@@ -98,7 +96,7 @@ def stack(fresh_registry):
         await site.start()
         mock_port = site._server.sockets[0].getsockname()[1]  # noqa: SLF001
 
-        cfg = AppConfig.load_or_default(environ={}, cli_overrides={"modules": {
+        rt, base = await boot_stack({"modules": {
             "api_gateway": {"config": {"bind_addr": "127.0.0.1:0",
                                        "auth_disabled": True}},
             "tenant_resolver": {}, "credstore": {}, "file_storage": {},
@@ -125,13 +123,7 @@ def stack(fresh_registry):
                      "engine_options": {"model_config": "tiny-llama"}},
                 ]}},
             "llm_gateway": {"config": {"video_poll_interval_s": 0.02}},
-        }})
-        registry = ModuleRegistry.discover_and_build(extra=regs)
-        rt = HostRuntime(RunOptions(config=cfg, registry=registry,
-                                    client_hub=ClientHub(),
-                                    db_manager=DbManager(in_memory=True)))
-        await rt.run_setup_phases()
-        base = f"http://127.0.0.1:{registry.get('api_gateway').instance.bound_port}"
+        }}, extra=regs)
         async with aiohttp.ClientSession() as s:
             await s.put(f"{base}/v1/credstore/secrets/media-key",
                         json={"value": "sk-media"})
@@ -144,9 +136,7 @@ def stack(fresh_registry):
     loop = asyncio.new_event_loop()
     rt, runner, base = loop.run_until_complete(boot())
     yield loop, base, seen
-    loop.run_until_complete(rt.registry.get("oagw").instance.service.close())
-    rt.root_token.cancel()
-    loop.run_until_complete(rt.run_stop_phase())
+    loop.run_until_complete(stop_stack(rt))
     loop.run_until_complete(runner.cleanup())
     loop.close()
 
@@ -251,18 +241,19 @@ def test_realtime_binary_audio_frames(stack):
     async def go():
         async with aiohttp.ClientSession() as s:
             async with s.ws_connect(f"{base}/v1/realtime") as ws:
+                seen_events = []
                 await ws.send_bytes(b"RIFF-chunk-1")
-                ack1 = await ws.receive_json()
+                ack1 = await ws_event(ws, seen_events)
                 await ws.send_bytes(b"-chunk-2")
-                ack2 = await ws.receive_json()
+                ack2 = await ws_event(ws, seen_events)
                 await ws.send_json({"type": "audio.commit",
                                     "model": "media-mock::whisper",
                                     "mime_type": "audio/wav"})
                 deltas = []
-                ev = await ws.receive_json()
+                ev = await ws_event(ws, seen_events)
                 while ev["type"] == "transcript.delta":
                     deltas.append(ev["delta"])
-                    ev = await ws.receive_json()
+                    ev = await ws_event(ws, seen_events)
                 await ws.send_json({"type": "session.close"})
                 return ack1, ack2, deltas, ev
 
@@ -286,16 +277,17 @@ def test_realtime_full_audio_loop(stack):
     async def go():
         async with aiohttp.ClientSession() as s:
             async with s.ws_connect(f"{base}/v1/realtime") as ws:
+                events = []  # every message so far: what a failed wait reports
                 # 1) audio in + commit → transcript
                 await ws.send_bytes(b"RIFF" + b"\x00" * 60)
-                assert (await ws.receive_json())["type"] == "audio.appended"
+                assert (await ws_event(ws, events))["type"] == "audio.appended"
                 await ws.send_json({"type": "audio.commit",
                                     "model": "media-mock::whisper"})
-                ev = await ws.receive_json()
+                ev = await ws_event(ws, events)
                 deltas = []
                 while ev["type"] == "transcript.delta":
                     deltas.append(ev["delta"])
-                    ev = await ws.receive_json()
+                    ev = await ws_event(ws, events)
                 assert ev["type"] == "transcript"
                 transcript_text = ev["text"]
 
@@ -312,14 +304,10 @@ def test_realtime_full_audio_loop(stack):
                 tokens, audio_out = [], bytearray()
                 begin = done = out_done = None
                 while out_done is None:
-                    msg = await ws.receive()
-                    if msg.type == aiohttp.WSMsgType.BINARY:
-                        audio_out.extend(msg.data)
-                        continue
-                    if msg.type != aiohttp.WSMsgType.TEXT:
-                        continue  # ping/pong frames
-                    ev = json.loads(msg.data)
-                    if ev["type"] == "token":
+                    ev = await ws_event(ws, events)
+                    if isinstance(ev, bytes):
+                        audio_out.extend(ev)
+                    elif ev["type"] == "token":
                         tokens.append(ev["content"])
                     elif ev["type"] == "done":
                         done = ev
@@ -344,6 +332,76 @@ def test_realtime_full_audio_loop(stack):
     tts_call = [s for s in seen if s["path"] == "speech"][-1]
     assert tts_call["body"]["voice"] == "nova"
     assert tts_call["body"]["input"] == "".join(tokens)
+
+
+@pytest.mark.parametrize("ending", ["empty_reply", "handler_exception",
+                                    "slow_reply"])
+def test_realtime_response_audio_ends_with_one_terminal_event(
+        stack, monkeypatch, ending):
+    """A `chat.create` frame that asked for `response_audio` ends with exactly
+    one of `audio.out.done` or `error` for its id, whatever the chat did: a
+    reply with no text is `audio.out.done` with `bytes: 0` (nothing sent to
+    the TTS provider), an exception that is no ProblemError is the REST
+    mapping's `internal_error`, and a reply slower than the server's heartbeat
+    and pong wait (here 0.4 s + 0.2 s) is not cut by them. The session
+    outlives each and closes clean."""
+    from cyberfabric_core_tpu.modules.llm_gateway import module as gateway_module
+    from cyberfabric_core_tpu.modules.sdk import ChatStreamChunk
+
+    async def chat_once(self, ctx, model, body, mode="chat"):
+        if ending == "handler_exception":
+            raise RuntimeError("boom")
+        if ending == "slow_reply":
+            await asyncio.sleep(1.5)
+            yield ChatStreamChunk(request_id="r", text="late")
+        yield ChatStreamChunk(request_id="r", finish_reason="stop",
+                              usage={"input_tokens": 3, "output_tokens": 0})
+
+    monkeypatch.setattr(gateway_module.LlmGatewayModule, "_chat_once", chat_once)
+    real_response = web.WebSocketResponse
+    monkeypatch.setattr(gateway_module.web, "WebSocketResponse",
+                        lambda heartbeat: real_response(heartbeat=0.4))
+    loop, base, seen = stack
+
+    async def go():
+        async with aiohttp.ClientSession() as s:
+            async with s.ws_connect(f"{base}/v1/realtime") as ws:
+                events = []
+                await ws.send_json({
+                    "type": "chat.create", "id": "quiet-1",
+                    "response_audio": {"model": "media-mock::tts-1"},
+                    "request": {
+                        "model": "local::tiny-llama", "max_tokens": 4,
+                        "messages": [{"role": "user", "content": [
+                            {"type": "text", "text": "say nothing"}]}]}})
+                # whatever follows the frame's events answers the next frame:
+                # nothing else was sent for quiet-1
+                await ws.send_json({"type": "bogus"})
+                ev = await ws_event(ws, events)
+                while isinstance(ev, bytes) or ev.get("id") == "quiet-1":
+                    ev = await ws_event(ws, events)
+                assert ev["error"]["code"] == "unknown_frame_type", events
+                await ws.send_json({"type": "session.close"})
+                closing = await ws.receive(timeout=30.0)
+                return events[:-1], closing, ws.close_code
+
+    events, closing, close_code = loop.run_until_complete(go())
+    if ending == "empty_reply":
+        assert [e["type"] for e in events] == ["done", "audio.out.done"], events
+        assert events[-1] == {"type": "audio.out.done", "id": "quiet-1",
+                              "bytes": 0}
+        assert not [s for s in seen if s["path"] == "speech"]
+    elif ending == "slow_reply":
+        assert [e if isinstance(e, bytes) else e["type"] for e in events] == [
+            "token", "done", "audio.out.begin", MP3, "audio.out.done"], events
+        assert events[-1]["bytes"] == len(MP3)
+    else:
+        assert [e["type"] for e in events] == ["error"], events
+        assert events[0]["id"] == "quiet-1"
+        assert events[0]["error"]["code"] == "internal_error"
+        assert events[0]["error"]["status"] == 500
+    assert closing.type == aiohttp.WSMsgType.CLOSE, closing
+    assert close_code == 1000
 
 
 def test_media_usage_reported(stack):

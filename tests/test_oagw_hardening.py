@@ -15,12 +15,9 @@ import aiohttp
 import pytest
 from aiohttp import web
 
-from cyberfabric_core_tpu.modkit import (
-    AppConfig, ClientHub, ModuleRegistry, RunOptions)
-from cyberfabric_core_tpu.modkit.db import DbManager
+from conftest import boot_stack, stop_stack
 from cyberfabric_core_tpu.modkit.jwt import encode_hs256
 from cyberfabric_core_tpu.modkit.registry import Registration
-from cyberfabric_core_tpu.modkit.runtime import HostRuntime
 
 
 @pytest.fixture(scope="module")
@@ -87,27 +84,20 @@ def stack():
                          ("db", "rest")),
             Registration("oagw", OagwModule, ("credstore",), ("db", "rest")),
         ]
-        cfg = AppConfig.load_or_default(environ={}, cli_overrides={"modules": {
+        rt, base = await boot_stack({"modules": {
             "api_gateway": {"config": {"bind_addr": "127.0.0.1:0",
                                        "auth_disabled": True}},
             "tenant_resolver": {}, "credstore": {},
             "oagw": {"config": {"allow_insecure_http": True,
                                 "allow_private_upstreams": True}},
-        }})
-        registry = ModuleRegistry.discover_and_build(extra=regs)
-        rt = HostRuntime(RunOptions(config=cfg, registry=registry,
-                                    client_hub=ClientHub(),
-                                    db_manager=DbManager(in_memory=True)))
-        await rt.run_setup_phases()
-        gw = registry.get("api_gateway").instance
-        return rt, runner, f"http://127.0.0.1:{gw.bound_port}", mock_port
+        }}, extra=regs)
+        return rt, runner, base, mock_port
 
     loop = asyncio.new_event_loop()
     rt, runner, base, mock_port = loop.run_until_complete(boot())
     yield loop, base, mock_port, state, rt
-    loop.run_until_complete(rt.registry.get("oagw").instance.service.close())
+    loop.run_until_complete(stop_stack(rt))
     loop.run_until_complete(runner.cleanup())
-    loop.run_until_complete(rt.run_stop_phase())
     loop.close()
     reg._REGISTRATIONS[:] = saved
 

@@ -6,16 +6,13 @@ import asyncio
 import aiohttp
 import pytest
 
+from conftest import boot_stack, stop_stack
+
 
 @pytest.fixture()
 def stack(tmp_path):
-    import cyberfabric_core_tpu.modules  # noqa: F401
-    from cyberfabric_core_tpu.modkit import AppConfig, ClientHub, ModuleRegistry, RunOptions
-    from cyberfabric_core_tpu.modkit.db import DbManager
-    from cyberfabric_core_tpu.modkit.runtime import HostRuntime
-
     async def boot():
-        cfg = AppConfig.load_or_default(environ={}, cli_overrides={
+        rt, base = await boot_stack({
             "server": {"home_dir": str(tmp_path)},
             "modules": {
                 "api_gateway": {"config": {"bind_addr": "127.0.0.1:0",
@@ -25,22 +22,13 @@ def stack(tmp_path):
                 "oagw": {"config": {"allow_insecure_http": True,
                                     "allow_private_upstreams": True}},
             }})
-        registry = ModuleRegistry.discover_and_build(enabled=cfg.module_names())
-        rt = HostRuntime(RunOptions(config=cfg, registry=registry,
-                                    client_hub=ClientHub(),
-                                    db_manager=DbManager(in_memory=True)))
-        await rt.run_setup_phases()
         await asyncio.sleep(0)  # let the rest-phase GTS provisioning task run
-        base = f"http://127.0.0.1:{registry.get('api_gateway').instance.bound_port}"
         return rt, base
 
     loop = asyncio.new_event_loop()
     rt, base = loop.run_until_complete(boot())
     yield loop, base
-    loop.run_until_complete(
-        rt.registry.get("oagw").instance.service.close())
-    rt.root_token.cancel()
-    loop.run_until_complete(rt.run_stop_phase())
+    loop.run_until_complete(stop_stack(rt))
     loop.close()
 
 

@@ -119,7 +119,13 @@ def test_cache_aware_placement_prefers_warm_replica():
         second = _run(pool, head + rng.integers(3, 900, 8).tolist())
         assert second["finish"] is not None
         assert pool.placement_hint_hits > hits_before
+        # the scheduler emits a stream's terminal token before it counts the
+        # request as completed: give its thread a moment to get there
+        deadline = time.monotonic() + 10.0
         served = pool.replicas[warm[0]].stats()
+        while served["requests_completed"] < 2 and time.monotonic() < deadline:
+            time.sleep(0.02)
+            served = pool.replicas[warm[0]].stats()
         assert served["requests_completed"] >= 2, \
             "second request was not routed to the warm replica"
         assert pool.stats()["placement_hint_hits"] > hits_before

@@ -144,7 +144,9 @@ def test_seeded_sampling_rides_spec_rounds_unchanged():
     """Sampled rows never speculate but DO share the ragged dispatch with
     speculating greedy rows — their per-token key streams (one split per
     emitted token) and therefore their tokens must be unchanged vs k=0."""
-    prompts = [[20, 21, 22] * 4, [5, 6, 7, 8] * 4]
+    # the greedy row is the one of _REP_PROMPTS whose continuation repeats
+    # (334 334 334 441 ...): a row whose tail never recurs never drafts
+    prompts = [[20, 21, 22] * 4, _REP_PROMPTS[1]]
     samp = [SamplingParams(max_tokens=30, temperature=0.8, seed=42),
             SamplingParams(max_tokens=48)]
     k0, _ = _run_streams(_cfg(), prompts, samp)
