@@ -605,18 +605,19 @@ def kernel_checks(jax, spec: dict, cfg) -> None:
         check_close(f"flash prefill T={T} row {b} (len {n})", out[b, :n],
                     ref[b, :n])
 
-    # one layer's page pool
-    n_pages = B * pmax + 1
-    k_pool, v_pool = (rnd(n_pages, page, Hkv, D), rnd(n_pages, page, Hkv, D))
+    # a two-layer page pool as the engine keeps it, read at its second layer
+    n_pages, layer = B * pmax + 1, 1
+    k_pool, v_pool = (rnd(2, n_pages, page, Hkv * D),
+                      rnd(2, n_pages, page, Hkv * D))
     table = jnp.asarray(own_pages(B, pmax))
-    k_dense, v_dense = paged_gather_dense(k_pool, v_pool, table)
+    k_dense, v_dense = paged_gather_dense(k_pool, v_pool, table, D, layer)
     cap = pmax * page
 
     # paged decode in the 2D-dot form a real compile uses
     lens = jnp.asarray(([1, page - 1, page, page + 1, cap // 3, cap // 2,
                          cap - 1, cap] * B)[:B], jnp.int32)
     q = rnd(B, Hq, D)
-    out = paged_decode_attention(q, k_pool, v_pool, table, lens,
+    out = paged_decode_attention(q, k_pool, v_pool, table, lens, layer,
                                  interpret=interpret, sliding_window=window,
                                  two_d_dots=True)
     ref = reference(q[:, None], k_dense, v_dense, (lens - 1)[:, None], lens)
@@ -631,7 +632,7 @@ def kernel_checks(jax, spec: dict, cfg) -> None:
                              cap - width, page - 1] * B)[:B], jnp.int32)
         q = rnd(B, width, Hq, D)
         out = ragged_paged_attention(q, k_pool, v_pool, table, hist, q_lens,
-                                     interpret=interpret,
+                                     layer, interpret=interpret,
                                      sliding_window=window, two_d_dots=True)
         pos = hist[:, None] + jnp.arange(width, dtype=jnp.int32)[None]
         ref = reference(q, k_dense, v_dense, pos, hist + q_lens)
@@ -698,8 +699,8 @@ def model_checks(jax, spec: dict, cfg) -> None:
     # pool, then paged decode steps
     @jax.jit
     def paged_run(params, ids, forced):
-        shape = (cfg.num_layers, B * pmax + 1, page, cfg.num_kv_heads,
-                 cfg.head_dim)
+        shape = (cfg.num_layers, B * pmax + 1, page,
+                 cfg.num_kv_heads * cfg.head_dim)
         pools = (jnp.zeros(shape, jnp.bfloat16), jnp.zeros(shape, jnp.bfloat16))
         table = own_pages(B, pmax)
         last = jnp.zeros((B, cfg.hidden_size), jnp.bfloat16)
@@ -750,8 +751,8 @@ def engine_logits(jax, engine, ids, lengths, forced):
     page = engine.config.prefix_page_size
     (B, T), steps = ids.shape, forced.shape[0]
     pmax = -(-(T + steps) // page)
-    shape = (cfg.num_layers, B * pmax + 1, page, cfg.num_kv_heads,
-             cfg.head_dim)
+    shape = (cfg.num_layers, B * pmax + 1, page,
+             cfg.num_kv_heads * cfg.head_dim)
     table, mesh = own_pages(B, pmax), engine._attn_mesh
 
     def run(params, rope, ids, lengths, forced):

@@ -337,13 +337,34 @@ def forward(
     return h, (k_cache, v_cache)
 
 
-PagedPools = tuple[jnp.ndarray, jnp.ndarray]  # (k, v): [L, N, page, Hkv, D]
+#: (k, v): [L, N, page, Hkv*D] — the two minor dimensions stored MERGED,
+#: head-major, which is the block the paged kernels read (on a tiled TPU
+#: layout merging them later is a copy of the pool, not a view)
+PagedPools = tuple[jnp.ndarray, jnp.ndarray]
+
+
+def _merged_pools(pools: PagedPools) -> tuple[PagedPools, tuple | None]:
+    """What ``forward_paged_*`` run on, and the shape to hand back. A 5-D
+    ``[L, N, page, Hkv, D]`` pool (benchmark/correctness.py builds them) is
+    merged once here, outside the layer scan, and un-merged on return by
+    :func:`_restore_pools`: one copy a call, the same scatter and kernels.
+    ROADMAP D12 deletes this entry once that caller builds merged pools."""
+    if pools[0].ndim == 4:
+        return pools, None
+    shape = pools[0].shape
+    return tuple(p.reshape(*shape[:3], -1) for p in pools), shape
+
+
+def _restore_pools(pools: PagedPools, shape: tuple | None) -> PagedPools:
+    return pools if shape is None else tuple(p.reshape(shape) for p in pools)
 
 
 def _shard_mapped_attn(mesh, kernel_fn, q_spec, tail_specs):
     """Wrap a paged-attention kernel call in shard_map over the mesh's tp
-    axis (kv heads sharded; ``tail_specs`` cover the replicated control
-    operands — page table, lengths/hist/q_lens). Mosaic kernels cannot be
+    axis (the stacked pools' merged head axis sharded: head-major order
+    makes a device's slice its contiguous Hkv/tp heads; ``tail_specs`` cover
+    the replicated control operands — page table, lengths/hist/q_lens — and
+    the layer index follows them). Mosaic kernels cannot be
     automatically partitioned by GSPMD — each device runs the kernel over
     ITS head slice, which is exactly the head-axis sharding the Ragged
     Paged Attention paper names. Head-major GQA grouping survives the
@@ -354,10 +375,10 @@ def _shard_mapped_attn(mesh, kernel_fn, q_spec, tail_specs):
     drift."""
     from jax.sharding import PartitionSpec as P
 
-    kv_spec = P(None, None, "tp", None)
+    kv_spec = P(None, None, None, "tp")
     return jax.shard_map(
         kernel_fn, mesh=mesh,
-        in_specs=(q_spec, kv_spec, kv_spec) + tuple(tail_specs),
+        in_specs=(q_spec, kv_spec, kv_spec) + tuple(tail_specs) + (P(),),
         out_specs=q_spec, check_vma=False)
 
 
@@ -395,6 +416,7 @@ def forward_paged_decode(
     cos_t, sin_t = rope_tables
     B = input_ids.shape[0]
     Hq, D = cfg.num_heads, cfg.head_dim
+    pools, caller_shape = _merged_pools(pools)
     page_size = pools[0].shape[2]
     positions = lengths[:, None]
 
@@ -417,28 +439,26 @@ def forward_paged_decode(
         q, kproj, vproj = _qkv_proj(lp, x, cfg, positions, cos_t, sin_t)
 
         # scatter the new token into each slot's tail page (inactive slots all
-        # target scratch page 0 — duplicate writes there are harmless)
+        # target scratch page 0 — duplicate writes there are harmless): B rows
+        # of Hkv*D, merged like the pool
         k_pool = k_pool.at[layer, pid, off].set(
-            kproj[:, 0].astype(k_pool.dtype))
+            kproj.reshape(B, -1).astype(k_pool.dtype))
         v_pool = v_pool.at[layer, pid, off].set(
-            vproj[:, 0].astype(v_pool.dtype))
+            vproj.reshape(B, -1).astype(v_pool.dtype))
+
+        # the kernel takes the stacked pools whole and picks the layer in its
+        # index map: a ``k_pool[layer]`` here would materialise 1/L of the pool
+        def attend(qq, kk, vv, pt, ln, ly):
+            return paged_decode_attention(
+                qq, kk, vv, pt, ln, ly, interpret=interpret,
+                sliding_window=cfg.sliding_window)
 
         if mesh is not None:
             from jax.sharding import PartitionSpec as P
 
-            attn = _shard_mapped_attn(
-                mesh,
-                lambda qq, kk, vv, pt, ln: paged_decode_attention(
-                    qq, kk, vv, pt, ln, interpret=interpret,
-                    sliding_window=cfg.sliding_window),
-                P(None, "tp", None), (P(None, None), P(None)),
-            )(q[:, 0], k_pool[layer], v_pool[layer], page_table,
-              lengths + 1)
-        else:
-            attn = paged_decode_attention(
-                q[:, 0], k_pool[layer], v_pool[layer], page_table,
-                lengths + 1,
-                interpret=interpret, sliding_window=cfg.sliding_window)
+            attend = _shard_mapped_attn(
+                mesh, attend, P(None, "tp", None), (P(None, None), P(None)))
+        attn = attend(q[:, 0], k_pool, v_pool, page_table, lengths + 1, layer)
         h = _attn_out(lp, h, attn.reshape(B, 1, Hq * D))
         h = _mlp_residual(lp, h, cfg)
         return (h, k_pool, v_pool), None
@@ -448,7 +468,7 @@ def forward_paged_decode(
         layer_body, (h, k_pool, v_pool),
         (params["layers"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))
     h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps, cfg.norm_weight_offset)
-    return h, (k_pool, v_pool)
+    return h, _restore_pools((k_pool, v_pool), caller_shape)
 
 
 def forward_paged_mixed(
@@ -484,6 +504,7 @@ def forward_paged_mixed(
     cos_t, sin_t = rope_tables
     B, Qmax = input_ids.shape
     Hq, D = cfg.num_heads, cfg.head_dim
+    pools, caller_shape = _merged_pools(pools)
     page_size = pools[0].shape[2]
 
     offs = jnp.arange(Qmax, dtype=jnp.int32)[None, :]          # [1, Qmax]
@@ -508,24 +529,23 @@ def forward_paged_mixed(
 
         # scatter the span's k/v BEFORE attending: within-span causality then
         # reads the chunk's earlier tokens back through the page chain
-        k_pool = k_pool.at[layer, pid, off].set(kproj.astype(k_pool.dtype))
-        v_pool = v_pool.at[layer, pid, off].set(vproj.astype(v_pool.dtype))
+        k_pool = k_pool.at[layer, pid, off].set(
+            kproj.reshape(B, Qmax, -1).astype(k_pool.dtype))
+        v_pool = v_pool.at[layer, pid, off].set(
+            vproj.reshape(B, Qmax, -1).astype(v_pool.dtype))
+
+        def attend(qq, kk, vv, pt, hh, ql, ly):
+            return ragged_paged_attention(
+                qq, kk, vv, pt, hh, ql, ly, interpret=interpret,
+                sliding_window=cfg.sliding_window)
 
         if mesh is not None:
             from jax.sharding import PartitionSpec as P
 
-            attn = _shard_mapped_attn(
-                mesh,
-                lambda qq, kk, vv, pt, hh, ql: ragged_paged_attention(
-                    qq, kk, vv, pt, hh, ql, interpret=interpret,
-                    sliding_window=cfg.sliding_window),
-                P(None, None, "tp", None),
-                (P(None, None), P(None), P(None)),
-            )(q, k_pool[layer], v_pool[layer], page_table, hist, q_lens)
-        else:
-            attn = ragged_paged_attention(
-                q, k_pool[layer], v_pool[layer], page_table, hist, q_lens,
-                interpret=interpret, sliding_window=cfg.sliding_window)
+            attend = _shard_mapped_attn(
+                mesh, attend, P(None, None, "tp", None),
+                (P(None, None), P(None), P(None)))
+        attn = attend(q, k_pool, v_pool, page_table, hist, q_lens, layer)
         h = _attn_out(lp, h, attn.reshape(B, Qmax, Hq * D))
         h = _mlp_residual(lp, h, cfg)
         return (h, k_pool, v_pool), None
@@ -535,7 +555,7 @@ def forward_paged_mixed(
         layer_body, (h, k_pool, v_pool),
         (params["layers"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))
     h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps, cfg.norm_weight_offset)
-    return h, (k_pool, v_pool)
+    return h, _restore_pools((k_pool, v_pool), caller_shape)
 
 
 def prefill_collect(

@@ -19,6 +19,14 @@ don't drag the whole window through HBM every step. Pages are shared
 cross-request (prefix cache) with zero copies: sharing is rows in the page
 table, exactly the PAPERS.md "ragged paged attention for TPU" direction.
 
+Both kernels take the STACKED pool as the pool keeps it — ``[L, N, page,
+Hkv*D]``, head-major on the merged minor axis (runtime/paged.py) — and the
+layer as one more scalar-prefetch operand: a block is ``(1, 1, page, Hkv*D)``
+at ``(layer, page_table[b, jj], 0, 0)``, so nothing pool-sized is sliced,
+reshaped or copied in front of the call. (On a tiled TPU layout a merge of
+the two minor dimensions is a physical copy, and a Mosaic call takes whole
+buffers, so ``pool[layer]`` materialises: PERF.md section 6, PR 25.)
+
 The reference has no decode path at all (inference is delegated to external
 providers — SURVEY §0); this kernel is TPU-first substrate for the
 llm-gateway local worker (BASELINE config #2: 64 concurrent streams).
@@ -42,8 +50,8 @@ def _banded_scores_2d(bands, k_of):
     dot_general (Mosaic's dot supports only 2D operands). ``bands`` is a
     list of (q_band [rows, D], kv_head) in head-major row order; ``k_of``
     maps a kv head to its [page, D] key slice — a REF-level lane slice of
-    the minor-merged [1, page, Hkv*D] block (the wrapper reshapes the pool
-    outside the kernel): value-level bf16 lane slices at non-zero tile
+    the minor-merged [1, 1, page, Hkv*D] block (the pool is stored merged):
+    value-level bf16 lane slices at non-zero tile
     offsets are an unlowerable relayout, ref-level sliced LOADS are not.
     The per-band results concatenate in f32 (bf16 sublane concats are an
     unsupported multi-row shift); each output element is the same
@@ -68,7 +76,7 @@ def _banded_weighted_v_2d(p, row_bands, v_of):
     return jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
 
 
-def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+def _paged_kernel(pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
                   acc_ref, m_ref, l_ref, *, page_size: int,
                   sliding_window: int | None = None,
                   two_d_dots: bool = False):
@@ -77,7 +85,8 @@ def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     Refs:
       pt_ref:  [B, Pmax] int32 SMEM (scalar prefetch) — page table
       len_ref: [B] int32 SMEM — valid kv length per slot (incl. current token)
-      q_ref:   [1, Hq, D] VMEM; k_ref/v_ref: [1, page, Hkv, D] VMEM
+      layer_ref: [1] int32 SMEM — read by the index maps only
+      q_ref:   [1, Hq, D] VMEM; k_ref/v_ref: [1, 1, page, Hkv*D] VMEM
       o_ref:   [1, Hq, D] VMEM
       acc_ref: [Hq, D] f32; m_ref/l_ref: [Hq, LANES] f32
 
@@ -110,7 +119,7 @@ def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         Hq, D = q.shape
 
         if two_d_dots:
-            # merged kv blocks ([1, page, Hkv*D]): each head is a REF-level
+            # merged kv blocks ([1, 1, page, Hkv*D]): each head is a REF-level
             # lane slice. q's rows are head-major but a bf16 SUBLANE band
             # slice is itself an unlowerable multi-row shift — so each kv
             # head dots the FULL q block against its key slice and the band
@@ -118,9 +127,9 @@ def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
             # lower fine). The retained elements are the same contractions
             # the batched dot computes: bitwise identical, a little
             # redundant MXU work on a tiny [Hq, D] operand.
-            Hkv = k_ref.shape[2] // D
+            Hkv = k_ref.shape[3] // D
             G = Hq // Hkv
-            k_of = lambda kv: k_ref[0, :, kv * D:(kv + 1) * D]  # noqa: E731
+            k_of = lambda kv: k_ref[0, 0, :, kv * D:(kv + 1) * D]  # noqa: E731
             scores = jnp.concatenate([
                 jax.lax.dot_general(
                     q, k_of(kv), (((1,), (1,)), ((), ())),
@@ -130,8 +139,8 @@ def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                     q, k_of(0), (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)  # [Hq, page]
         else:
-            k = k_ref[0]      # [page, Hkv, D]
-            Hkv = k.shape[1]
+            Hkv = k_ref.shape[3] // D
+            k = k_ref[0, 0].reshape(page_size, Hkv, D)
             G = Hq // Hkv
             qg = q.reshape(Hkv, G, D)
             kt = jnp.transpose(k, (1, 2, 0))        # [Hkv, D, page]
@@ -162,9 +171,9 @@ def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         if two_d_dots:
             pv = _banded_weighted_v_2d(
                 p, [(kv * G, G, kv) for kv in range(Hkv)],
-                lambda kv: v_ref[0, :, kv * D:(kv + 1) * D])
+                lambda kv: v_ref[0, 0, :, kv * D:(kv + 1) * D])
         else:
-            v = v_ref[0]
+            v = v_ref[0, 0].reshape(page_size, Hkv, D)
             pg = p.reshape(Hkv, G, page_size)
             vt = jnp.transpose(v, (1, 0, 2))                # [Hkv, page, D]
             pv = jax.lax.dot_general(
@@ -182,27 +191,33 @@ def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                                              "two_d_dots"))
 def paged_decode_attention(
     q: jnp.ndarray,           # [B, Hq, D] — one query token per slot
-    k_pool: jnp.ndarray,      # [N, page, Hkv, D] — one layer's page pool
+    k_pool: jnp.ndarray,      # [L, N, page, Hkv*D] — the stacked page pool
     v_pool: jnp.ndarray,
     page_table: jnp.ndarray,  # [B, Pmax] int32 physical page ids
     lengths: jnp.ndarray,     # [B] int32 valid kv length (incl. current token)
+    layer: jnp.ndarray | int = 0,  # scalar int32 — which layer's pages
     interpret: bool = False,
     sliding_window: int | None = None,
     two_d_dots: bool | None = None,
 ) -> jnp.ndarray:
-    """Returns [B, Hq, D] attention over each slot's paged history.
+    """Returns [B, Hq, D] attention over each slot's paged history in layer
+    ``layer`` of the pool. The pool operands reach the ``pallas_call`` as
+    they are passed: the layer and the page are both picked by the blocks'
+    index map.
 
     ``two_d_dots`` (default: on exactly when compiling for real — Mosaic's
     dot supports only 2D tensors) selects the unrolled per-kv-head 2D-dot
-    body; interpret mode keeps the batched form for tier-1 wall-clock. The
-    two are bitwise-identical (golden-pinned)."""
+    body, which lane-slices the merged block; interpret mode keeps the
+    batched form for tier-1 wall-clock and un-merges the loaded block, which
+    Mosaic could not lower and a CPU does for nothing. The two are
+    bitwise-identical (golden-pinned)."""
     if two_d_dots is None:
         two_d_dots = not interpret
     B, Hq, D = q.shape
-    _, page_size, Hkv, _ = k_pool.shape
+    _, _, page_size, HD = k_pool.shape
     Pmax = page_table.shape[1]
 
-    def _page_index(b, j, pt_ref, len_ref):
+    def _page_index(b, j, pt_ref, len_ref, layer_ref):
         # clamp j into this slot's relevant page range so skipped programs
         # revisit the resident page and the DMA is elided
         length = len_ref[b]
@@ -211,30 +226,15 @@ def paged_decode_attention(
         if sliding_window is not None:
             lo = jnp.maximum((length - sliding_window) // page_size, 0)
             jj = jnp.maximum(jj, lo)
-        if two_d_dots:
-            return (pt_ref[b, jj], 0, 0)
-        return (pt_ref[b, jj], 0, 0, 0)
+        return (layer_ref[0], pt_ref[b, jj], 0, 0)
 
-    if two_d_dots:
-        # the pool arrives at the kernel MINOR-MERGED ([N, page, Hkv*D] —
-        # a free caller-side reshape): in-kernel merges of a loaded block
-        # are an unsupported vector shape_cast under Mosaic, lane slices
-        # of a 2D block are not
-        k_pool = k_pool.reshape(k_pool.shape[0], page_size, Hkv * D)
-        v_pool = v_pool.reshape(v_pool.shape[0], page_size, Hkv * D)
-        kv_spec = pl.BlockSpec((1, page_size, Hkv * D), _page_index)
-    else:
-        kv_spec = pl.BlockSpec((1, page_size, Hkv, D), _page_index)
-
+    kv_spec = pl.BlockSpec((1, 1, page_size, HD), _page_index)
+    q_spec = pl.BlockSpec((1, Hq, D), lambda b, j, pt, ln, ly: (b, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, Pmax),
-        in_specs=[
-            pl.BlockSpec((1, Hq, D), lambda b, j, pt, ln: (b, 0, 0)),
-            kv_spec,
-            kv_spec,
-        ],
-        out_specs=pl.BlockSpec((1, Hq, D), lambda b, j, pt, ln: (b, 0, 0)),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((Hq, D), jnp.float32),
             pltpu.VMEM((Hq, _LANES), jnp.float32),
@@ -252,10 +252,11 @@ def paged_decode_attention(
         ),
         interpret=interpret,
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      q, k_pool, v_pool)
+      jnp.asarray(layer, jnp.int32).reshape(1), q, k_pool, v_pool)
 
 
-def _ragged_kernel(pt_ref, hist_ref, qlen_ref, q_ref, k_ref, v_ref, o_ref,
+def _ragged_kernel(pt_ref, hist_ref, qlen_ref, layer_ref, q_ref, k_ref, v_ref,
+                   o_ref,
                    acc_ref, m_ref, l_ref, *, page_size: int, q_block: int,
                    sliding_window: int | None = None,
                    two_d_dots: bool = False,
@@ -266,7 +267,8 @@ def _ragged_kernel(pt_ref, hist_ref, qlen_ref, q_ref, k_ref, v_ref, o_ref,
       pt_ref:   [B, Pmax] int32 SMEM — page table
       hist_ref: [B] int32 SMEM — kv tokens BEFORE this row's query span
       qlen_ref: [B] int32 SMEM — query-span length (0 = idle row)
-      q_ref:    [1, Qb, Hq, D] VMEM; k_ref/v_ref: [1, page, Hkv, D] VMEM
+      layer_ref: [1] int32 SMEM — read by the index maps only
+      q_ref:    [1, Qb, Hq, D] VMEM; k_ref/v_ref: [1, 1, page, Hkv*D] VMEM
       o_ref:    [1, Qb, Hq, D] VMEM
       acc_ref:  [Hq*Qb, D] f32; m_ref/l_ref: [Hq*Qb, LANES] f32
 
@@ -275,9 +277,9 @@ def _ragged_kernel(pt_ref, hist_ref, qlen_ref, q_ref, k_ref, v_ref, o_ref,
     span's earlier tokens — prefill-chunk self attention). Rows are flat
     r = h*Qb + qi so the GQA dot keeps the decode kernel's head grouping.
 
-    ``two_d_dots`` (the Mosaic-lowerable form): q/k/v/o blocks arrive
-    MINOR-MERGED ([1, Qb, Hq*D] / [1, page, Hkv*D]; ``head_dim`` un-merges
-    them) and the head-major [Qb,Hq,D]↔[Hq,Qb,D] shuffles plus the batched
+    ``two_d_dots`` (the Mosaic-lowerable form): q/o blocks arrive
+    MINOR-MERGED too ([1, Qb, Hq*D]; ``head_dim`` un-merges them) and the
+    head-major [Qb,Hq,D]↔[Hq,Qb,D] shuffles plus the batched
     GQA dots — the constructs Mosaic cannot lower — become unrolled lane
     slices, sublane/lane concats and per-kv-head 2D dots. Bitwise-identical
     to the batched interpret form (golden-pinned).
@@ -318,7 +320,7 @@ def _ragged_kernel(pt_ref, hist_ref, qlen_ref, q_ref, k_ref, v_ref, o_ref,
         # head-major rows: r = h*Qb + qi (h = kv*G + g), so the GQA grouping
         # matches the decode kernel's reshape(Hkv, G, D) exactly
         if two_d_dots:
-            G = Hq // (k_ref.shape[2] // D)
+            G = Hq // (k_ref.shape[3] // D)
             # the [Qb,Hq,D]→head-major shuffle as unrolled per-head
             # REF-level lane slices of the minor-merged [1, Qb, Hq*D]
             # block feeding per-head 2D dots — neither the rank-3
@@ -327,12 +329,12 @@ def _ragged_kernel(pt_ref, hist_ref, qlen_ref, q_ref, k_ref, v_ref, o_ref,
             scores = _banded_scores_2d(
                 [(q_ref[0, :, h * D:(h + 1) * D], h // G)
                  for h in range(Hq)],
-                lambda kv: k_ref[0, :, kv * D:(kv + 1) * D],
+                lambda kv: k_ref[0, 0, :, kv * D:(kv + 1) * D],
             )                                    # [R, page], rows h*Qb+qi
         else:
             q = q_ref[0]      # [Qb, Hq, D]
-            k = k_ref[0]      # [page, Hkv, D]
-            Hkv = k.shape[1]
+            Hkv = k_ref.shape[3] // D
+            k = k_ref[0, 0].reshape(page_size, Hkv, D)
             G = Hq // Hkv
             qt = jnp.transpose(q, (1, 0, 2)).reshape(Hkv, G * Qb, D)
             kt = jnp.transpose(k, (1, 2, 0))    # [Hkv, D, page]
@@ -378,9 +380,9 @@ def _ragged_kernel(pt_ref, hist_ref, qlen_ref, q_ref, k_ref, v_ref, o_ref,
         if two_d_dots:
             pv = _banded_weighted_v_2d(
                 p, [(h * Qb, Qb, h // G) for h in range(Hq)],
-                lambda kv: v_ref[0, :, kv * D:(kv + 1) * D])
+                lambda kv: v_ref[0, 0, :, kv * D:(kv + 1) * D])
         else:
-            v = v_ref[0]
+            v = v_ref[0, 0].reshape(page_size, Hkv, D)
             pg = p.reshape(Hkv, G * Qb, page_size)
             vt = jnp.transpose(v, (1, 0, 2))                # [Hkv, page, D]
             pv = jax.lax.dot_general(
@@ -415,11 +417,12 @@ def _ragged_kernel(pt_ref, hist_ref, qlen_ref, q_ref, k_ref, v_ref, o_ref,
                                              "sliding_window", "two_d_dots"))
 def ragged_paged_attention(
     q: jnp.ndarray,           # [B, Qmax, Hq, D] — per-row query span, padded
-    k_pool: jnp.ndarray,      # [N, page, Hkv, D] — one layer's page pool
+    k_pool: jnp.ndarray,      # [L, N, page, Hkv*D] — the stacked page pool
     v_pool: jnp.ndarray,
     page_table: jnp.ndarray,  # [B, Pmax] int32 physical page ids
     hist: jnp.ndarray,        # [B] int32 kv tokens BEFORE the span
     q_lens: jnp.ndarray,      # [B] int32 span length (0 = idle row)
+    layer: jnp.ndarray | int = 0,  # scalar int32 — which layer's pages
     q_block: int = 8,
     interpret: bool = False,
     sliding_window: int | None = None,
@@ -434,7 +437,9 @@ def ragged_paged_attention(
 
     The span's own KV must already be present in the pool (the caller
     scatters the chunk's k/v before attending — within-span causality then
-    reads the earlier chunk tokens through the page chain).
+    reads the earlier chunk tokens through the page chain). The pool operands
+    reach the ``pallas_call`` as they are passed; ``layer`` and the page are
+    picked by the blocks' index map.
 
     ``two_d_dots`` (default: on exactly when compiling for real) replaces
     the head-major [Qb,Hq,D]↔[Hq,Qb,D] shuffles and the batched GQA dots —
@@ -443,12 +448,12 @@ def ragged_paged_attention(
     if two_d_dots is None:
         two_d_dots = not interpret
     B, Qmax, Hq, D = q.shape
-    _, page_size, Hkv, _ = k_pool.shape
+    _, _, page_size, HD = k_pool.shape
     Pmax = page_table.shape[1]
     if Qmax % q_block:
         raise ValueError(f"Qmax {Qmax} must be a multiple of q_block {q_block}")
 
-    def _page_index(b, qb, j, pt_ref, hist_ref, qlen_ref):
+    def _page_index(b, qb, j, pt_ref, hist_ref, qlen_ref, layer_ref):
         # clamp j into the pages this (row, q-block) can actually see so
         # skipped programs revisit the resident page and the DMA is elided
         hist_b = hist_ref[b]
@@ -460,30 +465,23 @@ def ragged_paged_attention(
             lo = jnp.maximum(
                 (hist_b + qb * q_block - sliding_window) // page_size, 0)
             jj = jnp.maximum(jj, jnp.minimum(lo, last))
-        if two_d_dots:
-            return (pt_ref[b, jj], 0, 0)
-        return (pt_ref[b, jj], 0, 0, 0)
+        return (layer_ref[0], pt_ref[b, jj], 0, 0)
 
+    kv_spec = pl.BlockSpec((1, 1, page_size, HD), _page_index)
     if two_d_dots:
-        # q/k/v/o travel MINOR-MERGED (free caller-side reshapes): in-kernel
-        # merges of loaded blocks are unsupported vector shape_casts under
-        # Mosaic, lane slices of 2D blocks are not
+        # q/o travel MINOR-MERGED like the pool (a request-sized reshape):
+        # in-kernel merges of loaded blocks are unsupported vector
+        # shape_casts under Mosaic, lane slices of 2D blocks are not
         q_in = q.reshape(B, Qmax, Hq * D)
-        k_in = k_pool.reshape(k_pool.shape[0], page_size, Hkv * D)
-        v_in = v_pool.reshape(v_pool.shape[0], page_size, Hkv * D)
         q_spec = pl.BlockSpec((1, q_block, Hq * D),
-                              lambda b, qb, j, pt, hh, ql: (b, qb, 0))
-        kv_spec = pl.BlockSpec((1, page_size, Hkv * D), _page_index)
-        out_shape = jax.ShapeDtypeStruct((B, Qmax, Hq * D), q.dtype)
+                              lambda b, qb, j, pt, hh, ql, ly: (b, qb, 0))
     else:
-        q_in, k_in, v_in = q, k_pool, v_pool
+        q_in = q
         q_spec = pl.BlockSpec((1, q_block, Hq, D),
-                              lambda b, qb, j, pt, hh, ql: (b, qb, 0, 0))
-        kv_spec = pl.BlockSpec((1, page_size, Hkv, D), _page_index)
-        out_shape = jax.ShapeDtypeStruct((B, Qmax, Hq, D), q.dtype)
+                              lambda b, qb, j, pt, hh, ql, ly: (b, qb, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, Qmax // q_block, Pmax),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=q_spec,
@@ -499,21 +497,23 @@ def ragged_paged_attention(
                           two_d_dots=two_d_dots,
                           head_dim=D if two_d_dots else None),
         grid_spec=grid_spec,
-        out_shape=out_shape,
+        out_shape=jax.ShapeDtypeStruct(q_in.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(page_table.astype(jnp.int32), hist.astype(jnp.int32),
-      q_lens.astype(jnp.int32), q_in, k_in, v_in)
-    return out.reshape(B, Qmax, Hq, D) if two_d_dots else out
+      q_lens.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      q_in, k_pool, v_pool)
+    return out.reshape(B, Qmax, Hq, D)
 
 
-def paged_gather_dense(k_pool, v_pool, page_table):
-    """Reference helper: materialize each slot's paged KV as a dense cache
-    [B, Pmax*page, Hkv, D] (tests / CPU fallback only — O(pool) reads)."""
-    k = jnp.take(k_pool, page_table, axis=0)  # [B, Pmax, page, Hkv, D]
-    v = jnp.take(v_pool, page_table, axis=0)
-    B, Pmax, page, Hkv, D = k.shape
-    return (k.reshape(B, Pmax * page, Hkv, D),
-            v.reshape(B, Pmax * page, Hkv, D))
+def paged_gather_dense(k_pool, v_pool, page_table, head_dim, layer=0):
+    """Reference helper: materialize each slot's paged KV in one layer of
+    the merged pool as a dense cache [B, Pmax*page, Hkv, D] (tests and
+    chip_smoke.py only — O(pool) reads)."""
+    k = jnp.take(k_pool[layer], page_table, axis=0)  # [B, Pmax, page, Hkv*D]
+    v = jnp.take(v_pool[layer], page_table, axis=0)
+    B, Pmax, page, HD = k.shape
+    return (k.reshape(B, Pmax * page, HD // head_dim, head_dim),
+            v.reshape(B, Pmax * page, HD // head_dim, head_dim))

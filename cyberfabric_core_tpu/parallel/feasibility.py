@@ -168,7 +168,7 @@ def tp_plan(
             param_bytes_device += per_dev
             param_bytes_total += total
 
-    # KV pool [L, n_pages, page, Hkv, D], kv heads sharded on tp (or page
+    # KV pool [L, n_pages, page, Hkv*D], kv heads sharded on tp (or page
     # replicated when tp > kv heads — q_per_kv grouping still shards queries).
     # ``num_pages`` pins the ENGINE's actual pool size (prefix-cache headroom
     # included) so the gate budgets the bytes serving will really allocate.

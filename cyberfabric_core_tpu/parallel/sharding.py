@@ -92,13 +92,14 @@ def _kv_head_axis(cfg: ModelConfig, mesh: Mesh):
 
 
 def llama_page_pool_sharding(cfg: ModelConfig, mesh: Mesh) -> NamedSharding:
-    """Paged KV pool [L, num_pages, page, Hkv, D] (runtime/paged.py): the
-    kv-head axis shards on ``tp`` — every device holds its heads' slice of
-    EVERY page, so page allocation, the radix prefix tree, page-table rows
-    and save/restore-to-host all stay head-count-agnostic host bookkeeping.
-    Falls back to replication when tp does not divide the kv heads."""
-    return NamedSharding(mesh, P(None, None, None, _kv_head_axis(cfg, mesh),
-                                 None))
+    """Paged KV pool [L, num_pages, page, Hkv*D] (runtime/paged.py; the two
+    minor dimensions stored merged, head-major): the merged axis shards on
+    ``tp``, which hands a device its contiguous Hkv/tp heads — every device
+    holds its heads' slice of EVERY page, so page allocation, the radix
+    prefix tree, page-table rows and save/restore-to-host all stay
+    head-count-agnostic host bookkeeping. Falls back to replication when tp
+    does not divide the kv heads."""
+    return NamedSharding(mesh, P(None, None, None, _kv_head_axis(cfg, mesh)))
 
 
 def dense_cache_sharding(cfg: ModelConfig, mesh: Mesh) -> NamedSharding:

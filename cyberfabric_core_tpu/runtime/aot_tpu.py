@@ -171,8 +171,8 @@ def serving_programs(
     # shape, so it keys the cache too (AK01)
     pmax = -(-max_seq_len // page_size)
     n_pages = max(prefix_cache_pages, max_batch * pmax + 1)
-    pool_shape = (cfg.num_layers, n_pages, page_size, cfg.num_kv_heads,
-                  cfg.head_dim)
+    pool_shape = (cfg.num_layers, n_pages, page_size,
+                  cfg.num_kv_heads * cfg.head_dim)
     pool_sds = _plain_sds(pool_shape, dtype, sharding=pool_sharding) \
         if pool_sharding is not None else _plain_sds(pool_shape, dtype)
 

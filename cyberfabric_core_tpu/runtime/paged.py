@@ -2,8 +2,12 @@
 
 The PAPERS.md direction (ragged paged attention for TPU) applied where it pays
 most on a serving host: **prompt prefix reuse**. Completed prefill KV is stored in
-a paged device pool ([L, num_pages, page_size, Hkv, D]) indexed by the native
-radix prefix cache (runtime/native.py — C++ fabric_host). A new request whose
+a paged device pool ([L, num_pages, page_size, Hkv*D]: the two minor dimensions
+are stored merged, head-major, which is the block the paged kernels read — on a
+tiled TPU layout merging them in front of the kernel is a copy of the pool)
+indexed by the native radix prefix cache (runtime/native.py — C++ fabric_host).
+The movers below hand out and take in request-sized tensors with their
+[..., Hkv, D] tail and reshape them at this boundary. A new request whose
 prompt shares a page-aligned prefix with any earlier one:
 
 1. matches the prefix in the radix tree (pinning its pages),
@@ -57,14 +61,15 @@ class PrefixKVPool:
         self.num_pages = num_pages
         self.dtype = dtype
         #: tensor-parallel serving: a NamedSharding for the pool arrays
-        #: ([L, P, page, Hkv, D], kv heads on tp — parallel/sharding.py
-        #: llama_page_pool_sharding). Every mover program (gather/scatter/
-        #: tail) runs under GSPMD against the sharded pool; the host-side
+        #: ([L, P, page, Hkv*D], the merged head axis on tp — parallel/
+        #: sharding.py llama_page_pool_sharding). Every mover program
+        #: (gather/scatter/tail) runs under GSPMD against the sharded pool;
+        #: the host-side
         #: bookkeeping (allocator, radix tree, refcounts, page ids) is
         #: byte-count-agnostic and identical to the single-device pool.
         self.sharding = sharding
         L, H, D = model_config.num_layers, model_config.num_kv_heads, model_config.head_dim
-        shape = (L, num_pages, page_size, H, D)
+        shape = (L, num_pages, page_size, H * D)
         self.k_pool = jnp.zeros(shape, dtype)
         self.v_pool = jnp.zeros(shape, dtype)
         if sharding is not None:
@@ -107,13 +112,11 @@ class PrefixKVPool:
     def _gather(self, pools, page_ids, n_pages_bucket):
         """pool[:, pids] → [L, 1, Pb*page, H, D] contiguous block."""
         k_pool, v_pool = pools
-        k = jnp.take(k_pool, page_ids, axis=1)  # [L, Pb, page, H, D]
+        k = jnp.take(k_pool, page_ids, axis=1)  # [L, Pb, page, H*D]
         v = jnp.take(v_pool, page_ids, axis=1)
-        L = k.shape[0]
-        Pb = n_pages_bucket
-        k = k.reshape(L, 1, Pb * self.page_size, *k.shape[3:])
-        v = v.reshape(L, 1, Pb * self.page_size, *v.shape[3:])
-        return k, v
+        out = (k.shape[0], 1, n_pages_bucket * self.page_size,
+               self.cfg.num_kv_heads, self.cfg.head_dim)
+        return k.reshape(out), v.reshape(out)
 
     @partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
     def _scatter(self, pools, kv, page_ids, start_token):
@@ -126,8 +129,8 @@ class PrefixKVPool:
         span = Pb * self.page_size
         k_slice = jax.lax.dynamic_slice_in_dim(k_new[:, 0], start_token, span, axis=1)
         v_slice = jax.lax.dynamic_slice_in_dim(v_new[:, 0], start_token, span, axis=1)
-        k_pages = k_slice.reshape(L, Pb, self.page_size, *k_slice.shape[2:])
-        v_pages = v_slice.reshape(L, Pb, self.page_size, *v_slice.shape[2:])
+        k_pages = k_slice.reshape(L, Pb, self.page_size, -1)
+        v_pages = v_slice.reshape(L, Pb, self.page_size, -1)
         return (k_pool.at[:, page_ids].set(k_pages),
                 v_pool.at[:, page_ids].set(v_pages))
 
@@ -285,12 +288,13 @@ class PrefixKVPool:
         prompt are garbage masked by length and overwritten by decode)."""
         k_pool, v_pool = pools
         k_new, v_new = kv
+        L = k_new.shape[0]
         k_page = jax.lax.dynamic_slice_in_dim(
             k_new[:, 0], start_token, self.page_size, axis=1).astype(k_pool.dtype)
         v_page = jax.lax.dynamic_slice_in_dim(
             v_new[:, 0], start_token, self.page_size, axis=1).astype(v_pool.dtype)
-        return (k_pool.at[:, page_id].set(k_page),
-                v_pool.at[:, page_id].set(v_page))
+        return (k_pool.at[:, page_id].set(k_page.reshape(L, self.page_size, -1)),
+                v_pool.at[:, page_id].set(v_page.reshape(L, self.page_size, -1)))
 
     def scatter_tail(self, kv: tuple, start_token: int, page_id: int) -> None:
         """Host wrapper: place a slot's partial tail tokens into its private
@@ -406,9 +410,13 @@ class PrefixKVPool:
         """Copy a slot's chain pages device→host (KV eviction for preempted
         requests — SURVEY §5 checkpoint/resume; the serving analogue of the
         reference's suspend path). One gather per pool; the transfer is the
-        chain's actual bytes, not the window."""
+        chain's actual bytes, not the window. Returns [L, n, page, Hkv, D]
+        each (the PD wire format; a host reshape is a view)."""
         idx = jnp.asarray(chain, jnp.int32)
-        return (np.asarray(self.k_pool[:, idx]), np.asarray(self.v_pool[:, idx]))
+        out = (self.k_pool.shape[0], len(chain), self.page_size,
+               self.cfg.num_kv_heads, self.cfg.head_dim)
+        return (np.asarray(self.k_pool[:, idx]).reshape(out),
+                np.asarray(self.v_pool[:, idx]).reshape(out))
 
     def restore_chain_from_host(self, host_kv: tuple[np.ndarray, np.ndarray]) -> list[int]:
         """Allocate fresh pages and scatter a saved chain back (device resume).
@@ -421,10 +429,11 @@ class PrefixKVPool:
         ids = self._alloc(n)
         self.ref_pages(ids)
         idx = jnp.asarray(ids, jnp.int32)
+        merged = (*host_kv[0].shape[:3], -1)
         self.k_pool = self.k_pool.at[:, idx].set(
-            jnp.asarray(host_kv[0], self.k_pool.dtype))
+            jnp.asarray(host_kv[0].reshape(merged), self.k_pool.dtype))
         self.v_pool = self.v_pool.at[:, idx].set(
-            jnp.asarray(host_kv[1], self.v_pool.dtype))
+            jnp.asarray(host_kv[1].reshape(merged), self.v_pool.dtype))
         return ids
 
     # ------------------------------------------------------------ PD handoff

@@ -60,6 +60,7 @@ def one_chip():
 
 _HQ, _HKV, _D, _PAGE, _B, _PMAX = 32, 8, 128, 64, 8, 32
 _N_PAGES = _B * _PMAX * 5 // 4 + 1   # the worker's default pool
+_POOL = (2, _N_PAGES, _PAGE, _HKV * _D)   # two layers of it, as stored
 _WINDOW = 4096
 
 
@@ -87,13 +88,14 @@ def test_flash_kernel_compiles_at_mistral_7b_shapes(one_chip, batch):
 def test_paged_decode_kernel_compiles_at_mistral_7b_shapes(one_chip):
     from cyberfabric_core_tpu.ops.paged_attention import paged_decode_attention
 
-    pool = one_chip((_N_PAGES, _PAGE, _HKV, _D), jnp.bfloat16)
+    pool = one_chip(_POOL, jnp.bfloat16)
     _compiles_with_mosaic(
-        lambda q, k, v, pt, n: paged_decode_attention(
-            q, k, v, pt, n, interpret=False, sliding_window=_WINDOW,
+        lambda q, k, v, pt, n, layer: paged_decode_attention(
+            q, k, v, pt, n, layer, interpret=False, sliding_window=_WINDOW,
             two_d_dots=True),
         one_chip((_B, _HQ, _D), jnp.bfloat16), pool, pool,
-        one_chip((_B, _PMAX), jnp.int32), one_chip((_B,), jnp.int32))
+        one_chip((_B, _PMAX), jnp.int32), one_chip((_B,), jnp.int32),
+        one_chip((), jnp.int32))
 
 
 @pytest.mark.parametrize("q_width", [8, 64, 512])
@@ -102,14 +104,14 @@ def test_ragged_kernel_compiles_at_mistral_7b_shapes(one_chip, q_width):
     prefill chunk bucket (8 = a speculative span, 512 = the chunk budget)."""
     from cyberfabric_core_tpu.ops.paged_attention import ragged_paged_attention
 
-    pool = one_chip((_N_PAGES, _PAGE, _HKV, _D), jnp.bfloat16)
+    pool = one_chip(_POOL, jnp.bfloat16)
     _compiles_with_mosaic(
-        lambda q, k, v, pt, h, n: ragged_paged_attention(
-            q, k, v, pt, h, n, interpret=False, sliding_window=_WINDOW,
-            two_d_dots=True),
+        lambda q, k, v, pt, h, n, layer: ragged_paged_attention(
+            q, k, v, pt, h, n, layer, interpret=False,
+            sliding_window=_WINDOW, two_d_dots=True),
         one_chip((_B, q_width, _HQ, _D), jnp.bfloat16), pool, pool,
         one_chip((_B, _PMAX), jnp.int32), one_chip((_B,), jnp.int32),
-        one_chip((_B,), jnp.int32))
+        one_chip((_B,), jnp.int32), one_chip((), jnp.int32))
 
 
 # ---- the compile cache and chip_smoke.py without a chip
@@ -323,8 +325,8 @@ def test_scheduler_programs_compile_for_v5e_at_mistral_7b(tp):
         return sds((n,), dtype)
 
     i32, f32 = jnp.int32, jnp.float32
-    pool = sds((cfg.num_layers, _N_PAGES, _PAGE, cfg.num_kv_heads,
-                cfg.head_dim), jnp.bfloat16, pool_sharding)
+    pool = sds((cfg.num_layers, _N_PAGES, _PAGE,
+                cfg.num_kv_heads * cfg.head_dim), jnp.bfloat16, pool_sharding)
     table, keys = sds((n, _PMAX), i32), sds((n, 2), jnp.uint32)
     stops = sds((n, eng.config.device_stop_width), i32)
     sampling = (row(f32), row(f32), row(i32))

@@ -1,4 +1,6 @@
-"""forward_paged_decode vs dense forward: decode parity over a paged pool."""
+"""forward_paged_decode vs dense forward: decode parity over a paged pool;
+the 5-D pool entry of forward_paged_*; and the guard of PR 25's gain: the
+pool reaches the kernels as it is stored, with no pool-sized op in between."""
 
 import jax
 import jax.numpy as jnp
@@ -10,10 +12,11 @@ from cyberfabric_core_tpu.models.configs import get_config
 from cyberfabric_core_tpu.ops.rope import rope_frequencies
 
 
-def _pool_from_dense(cache, page_size, num_pages):
+def _pool_from_dense(cache, page_size, num_pages, merged=True):
     """Copy a dense [L, B, S, Hkv, D] cache into a paged pool + page tables.
     Slot b's pages are laid out at distinct physical ids (reversed order to
-    prove the table indirection is honored)."""
+    prove the table indirection is honored). ``merged``: the engine's
+    [L, N, page, Hkv*D], else the 5-D shape the forwards also accept."""
     k_cache, v_cache = cache
     L, B, S, Hkv, D = k_cache.shape
     assert S % page_size == 0
@@ -30,11 +33,15 @@ def _pool_from_dense(cache, page_size, num_pages):
             v_pool[:, next_id] = np.asarray(
                 v_cache[:, b, p * page_size:(p + 1) * page_size])
             next_id -= 1
+    if merged:
+        k_pool = k_pool.reshape(L, num_pages, page_size, Hkv * D)
+        v_pool = v_pool.reshape(L, num_pages, page_size, Hkv * D)
     return (jnp.asarray(k_pool), jnp.asarray(v_pool)), jnp.asarray(pt)
 
 
+@pytest.mark.parametrize("merged", [True, False], ids=["merged", "5d-entry"])
 @pytest.mark.parametrize("model", ["tiny-llama", "tiny-moe"])
-def test_paged_decode_matches_dense(model):
+def test_paged_decode_matches_dense(model, merged):
     cfg = get_config(model)
     rope = rope_frequencies(cfg.head_dim, cfg.max_position, cfg.rope_theta)
     params = llama.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
@@ -54,7 +61,8 @@ def test_paged_decode_matches_dense(model):
         jnp.zeros((B,), jnp.int32), rope)
     lengths = jnp.asarray(prompt_lens, jnp.int32)
 
-    pools, pt = _pool_from_dense(cache, page, num_pages=B * (S // page) + 1)
+    pools, pt = _pool_from_dense(cache, page, num_pages=B * (S // page) + 1,
+                                 merged=merged)
 
     # 5 decode steps, both paths, same tokens in
     toks = rng.integers(1, cfg.vocab_size, (5, B)).astype(np.int32)
@@ -70,3 +78,123 @@ def test_paged_decode_matches_dense(model):
             np.asarray(hd), np.asarray(hp), rtol=2e-4, atol=2e-4)
         dense_lens = dense_lens + 1
         paged_lens = paged_lens + 1
+        assert pools[0].ndim == (4 if merged else 5)
+
+
+def _tiny_step_inputs(seed=0):
+    cfg = get_config("tiny-llama")
+    rope = rope_frequencies(cfg.head_dim, cfg.max_position, cfg.rope_theta)
+    params = llama.init_params(cfg, jax.random.PRNGKey(seed), jnp.float32)
+    B, page, pmax = 3, 8, 4
+    n_pages = B * pmax + 1
+    rng = np.random.default_rng(seed)
+    shape = (cfg.num_layers, n_pages, page, cfg.num_kv_heads, cfg.head_dim)
+    pools5 = tuple(jnp.asarray(rng.standard_normal(shape, np.float32))
+                   for _ in range(2))
+    table = jnp.asarray(1 + np.arange(B * pmax).reshape(B, pmax), jnp.int32)
+    return cfg, rope, params, rng, pools5, table
+
+
+@pytest.mark.parametrize("step", ["decode", "mixed"])
+def test_5d_pool_entry_is_bitwise_the_merged_path(step):
+    """benchmark/correctness.py hands forward_paged_* a [L, N, page, Hkv, D]
+    pool: it is merged once on entry and comes back 5-D, and hidden states
+    and pool are bit for bit what the merged path gives."""
+    cfg, rope, params, rng, pools5, table = _tiny_step_inputs()
+    pools4 = tuple(p.reshape(*p.shape[:3], -1) for p in pools5)
+    B = table.shape[0]
+    if step == "decode":
+        ids = jnp.asarray(rng.integers(1, cfg.vocab_size, (B, 1)), jnp.int32)
+        lens = jnp.asarray([5, 8, 23], jnp.int32)
+        run = lambda pools: llama.forward_paged_decode(  # noqa: E731
+            params, cfg, ids, pools, table, lens, rope, interpret=True,
+            write_mask=jnp.asarray([True, True, False]))
+    else:
+        ids = jnp.asarray(rng.integers(1, cfg.vocab_size, (B, 16)), jnp.int32)
+        hist = jnp.asarray([5, 0, 16], jnp.int32)
+        q_lens = jnp.asarray([1, 13, 0], jnp.int32)
+        run = lambda pools: llama.forward_paged_mixed(  # noqa: E731
+            params, cfg, ids, pools, table, hist, q_lens, rope,
+            interpret=True)
+    h5, out5 = run(pools5)
+    h4, out4 = run(pools4)
+    np.testing.assert_array_equal(np.asarray(h5), np.asarray(h4))
+    for got, want, sent in zip(out5, out4, pools5):
+        assert got.shape == sent.shape
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(want).reshape(sent.shape))
+        assert not np.array_equal(np.asarray(got), np.asarray(sent))
+
+
+def _producers(jaxpr, known=None):
+    """var -> the equation that makes it, through every nested jaxpr; an
+    argument of a nested jit -> the variable passed for it (a scan body's
+    arguments stay unmapped: there the carry begins)."""
+    known = {} if known is None else known
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            known[v] = eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            if eqn.primitive.name in ("jit", "pjit"):
+                known.update(zip(sub.invars, eqn.invars))
+            _producers(sub, known)
+    return known
+
+
+def _find(jaxpr, name):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _find(sub, name)
+
+
+@pytest.mark.parametrize("step", ["decode", "mixed"])
+def test_pool_reaches_the_kernel_uncopied(step):
+    """The gain of PR 25, guarded where no chip is needed: in the traced
+    compiled-path step (two_d_dots, no lowering) the K and V operands of the
+    pallas_call have the stored pool's shape and nothing but the scatter of
+    the step's own rows made them. A reshape, dynamic_slice, squeeze or
+    gather of pool size in front of the kernel was a copy of a layer's pool,
+    twice a layer a step, a third of a decode step on the chip."""
+    from unittest import mock
+
+    from cyberfabric_core_tpu.ops import paged_attention
+
+    cfg, rope, params, rng, pools5, table = _tiny_step_inputs()
+    pools = tuple(p.reshape(*p.shape[:3], -1) for p in pools5)
+    B = table.shape[0]
+    rows = jnp.asarray([5, 8, 23], jnp.int32)
+    name = "paged_decode_attention" if step == "decode" \
+        else "ragged_paged_attention"
+    kernel = getattr(paged_attention, name)
+    compiled_form = lambda *a, **kw: kernel(  # noqa: E731
+        *a, **{**kw, "two_d_dots": True})
+    with mock.patch.object(paged_attention, name, compiled_form):
+        if step == "decode":
+            jaxpr = jax.make_jaxpr(lambda pools: llama.forward_paged_decode(
+                params, cfg, jnp.ones((B, 1), jnp.int32), pools, table, rows,
+                rope, interpret=True))(pools)
+        else:
+            jaxpr = jax.make_jaxpr(lambda pools: llama.forward_paged_mixed(
+                params, cfg, jnp.ones((B, 8), jnp.int32), pools, table, rows,
+                jnp.asarray([1, 8, 0], jnp.int32), rope,
+                interpret=True))(pools)
+
+    eqn, = _find(jaxpr.jaxpr, "pallas_call")   # one, inside the layer scan
+    made_by = _producers(jaxpr.jaxpr)
+    pool_shape = pools[0].shape
+    # scalar prefetch (table, rows..., layer), then q, K, V
+    k_op, v_op = eqn.invars[-2:]
+    for op in (k_op, v_op):
+        assert op.aval.shape == pool_shape
+        seen = []
+        while op in made_by:                    # walk back to the scan carry
+            src = made_by[op]
+            if not hasattr(src, "primitive"):   # a jit's argument
+                op = src
+                continue
+            seen.append(src.primitive.name)
+            op = next(v for v in src.invars
+                      if getattr(v.aval, "shape", None) == pool_shape)
+        assert seen == ["scatter"], seen

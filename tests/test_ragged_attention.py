@@ -21,9 +21,10 @@ from cyberfabric_core_tpu.ops.paged_attention import (
 
 
 def _build_pool(key, B, page, Pmax, Hkv, D, N):
+    """A one-layer pool as the engine keeps it: [1, N, page, Hkv*D]."""
     kk, kv = jax.random.split(key)
-    k_pool = jax.random.normal(kk, (N, page, Hkv, D), jnp.float32)
-    v_pool = jax.random.normal(kv, (N, page, Hkv, D), jnp.float32)
+    k_pool = jax.random.normal(kk, (1, N, page, Hkv * D), jnp.float32)
+    v_pool = jax.random.normal(kv, (1, N, page, Hkv * D), jnp.float32)
     rng = np.random.default_rng(0)
     ids = rng.permutation(N - 1)[: B * Pmax] + 1
     pt = ids.reshape(B, Pmax).astype(np.int32)
@@ -33,7 +34,7 @@ def _build_pool(key, B, page, Pmax, Hkv, D, N):
 def _ref_rows(q, k_pool, v_pool, pt, hist, q_lens, window=None):
     """Dense reference: per row, gather the chain and attend the span at its
     absolute positions."""
-    k_dense, v_dense = paged_gather_dense(k_pool, v_pool, pt)
+    k_dense, v_dense = paged_gather_dense(k_pool, v_pool, pt, q.shape[-1])
     outs = []
     for b in range(q.shape[0]):
         ql, h = int(q_lens[b]), int(hist[b])
@@ -63,6 +64,14 @@ def _ref_rows(q, k_pool, v_pool, pt, hist, q_lens, window=None):
     (3, 4, 2, 16, 16, 6, [40, 10, 25], [1, 14, 2], 24),
     # span longer than one q_block (exercises multiple q-block programs)
     (1, 2, 2, 16, 8, 8, [11, ], [33, ], None),
+    # the served models' heads (mistral-7b, qwen2-7b) and phi-3-mini's head
+    # size, full attention and a sliding window
+    (3, 32, 8, 128, 16, 6, [37, 12, 0], [1, 23, 0], None),
+    (3, 32, 8, 128, 16, 6, [40, 10, 25], [1, 14, 2], 24),
+    (3, 28, 4, 128, 16, 6, [37, 12, 0], [1, 23, 0], None),
+    (3, 28, 4, 128, 16, 6, [40, 10, 25], [1, 14, 2], 24),
+    (3, 8, 8, 96, 16, 6, [37, 12, 0], [1, 23, 0], None),
+    (3, 8, 8, 96, 16, 6, [40, 10, 25], [1, 14, 2], 24),
 ])
 def test_ragged_matches_dense(B, Hq, Hkv, D, page, Pmax, hist, q_lens, window):
     N = B * Pmax + 2
@@ -119,8 +128,8 @@ def test_ragged_shared_prefix_pages():
     key = jax.random.PRNGKey(1)
     kq, kk, kv = jax.random.split(key, 3)
     q = jax.random.normal(kq, (B, 8, Hq, D), jnp.float32)
-    k_pool = jax.random.normal(kk, (N, page, Hkv, D), jnp.float32)
-    v_pool = jax.random.normal(kv, (N, page, Hkv, D), jnp.float32)
+    k_pool = jax.random.normal(kk, (1, N, page, Hkv * D), jnp.float32)
+    v_pool = jax.random.normal(kv, (1, N, page, Hkv * D), jnp.float32)
     pt = jnp.asarray([[3, 7, 2, 0], [3, 7, 9, 0]], jnp.int32)
     hist = jnp.asarray([19, 16], jnp.int32)
     q_lens = jnp.asarray([1, 7], jnp.int32)

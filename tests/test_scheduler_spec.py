@@ -198,8 +198,8 @@ def test_rejected_suffix_kv_never_commits_kernel_golden():
     rope = rope_frequencies(cfg.head_dim, cfg.max_position, cfg.rope_theta)
     page = 8
     n_pages = 5
-    pool_shape = (cfg.num_layers, n_pages, page, cfg.num_kv_heads,
-                  cfg.head_dim)
+    pool_shape = (cfg.num_layers, n_pages, page,
+                  cfg.num_kv_heads * cfg.head_dim)
     table = jnp.asarray([[1, 2, 0, 0]], jnp.int32)
     rng = np.random.default_rng(3)
     prompt = rng.integers(3, 200, 8).tolist()
@@ -219,8 +219,8 @@ def test_rejected_suffix_kv_never_commits_kernel_golden():
             # a rejected draft span: garbage KV at positions committed..+7
             # (the state a spec round leaves after rejecting its suffix)
             k_pool, v_pool = pools
-            junk = jnp.full((cfg.num_layers, page, cfg.num_kv_heads,
-                             cfg.head_dim), 7.25, jnp.float32)
+            junk = jnp.full((cfg.num_layers, page, pool_shape[-1]), 7.25,
+                            jnp.float32)
             pools = (k_pool.at[:, 2].set(junk), v_pool.at[:, 2].set(junk))
         # next round: the span starts AT the committed length and rewrites
         # the poisoned positions before attending
