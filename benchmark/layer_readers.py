@@ -1,6 +1,8 @@
 """Per-layer metric readers. A per-layer metric is a file
 ``benchmark/layer_metrics/<name>.json``: a reader ``kind`` and its arguments.
 A new metric over these kinds is a new file and an entry in BENCHMARK.json.
+A kind is one of this file's (``READERS``) or, written ``package.module:function``,
+a reader that a later PR brought as a file of its own (``resolve``).
 
 A reader is given the traced run's context and returns a number, or None
 where it finds nothing to read (the harness then leaves the metric out).
@@ -8,9 +10,13 @@ where it finds nothing to read (the harness then leaves the metric out).
 The context (``ctx``): ``requests`` (the client's record of every measured
 request), ``flight`` (request id -> the program's flight record), ``rounds``
 (the scheduler's round records inside the window), ``samples`` (series the
-harness sampled during the window, by name: ``pool_pages``), ``server_log``, ``trace`` (reduce_trace's output), ``config`` (the
-configuration file), ``peaks`` (this device's row of peaks.json), ``values``
-(metrics already read, for readers that build on another).
+harness sampled during the window, by name: ``pool_pages``), ``scrapes``
+(the server's ``/metrics`` as the harness read it: ``start`` and ``end`` at
+the window's two ends, ``all`` every scrape from start to end in order;
+unlabelled series, name -> value), ``server_log``, ``trace`` (reduce_trace's
+output), ``config`` (the configuration file), ``peaks`` (this device's row of
+peaks.json), ``values`` (metrics already read, for readers that build on
+another).
 """
 
 from __future__ import annotations
@@ -18,7 +24,8 @@ from __future__ import annotations
 import re
 from typing import Callable, Optional
 
-from . import metrics, opcounts
+from . import metrics, names, opcounts
+from .server import HarnessFailure
 
 _CLIENT_FIELDS: dict[str, Callable] = {
     "ttft_ms": metrics.ttft_ms, "tpot_ms": metrics.tpot_ms,
@@ -85,6 +92,31 @@ def samples(ctx: dict, series: str, stat: str,
     return out
 
 
+def counter(ctx: dict, series: str, over: Optional[str] = None,
+            gauge: bool = False) -> Optional[float]:
+    """What a ``/metrics`` series did over the window: a counter's
+    difference between the window's two ends, or with ``gauge`` the mean of a
+    gauge's samples. ``over`` names a second such series to divide by. A
+    series missing from a scrape, or a divisor that did not move, is nothing
+    to read."""
+    scrapes = ctx.get("scrapes") or {}
+
+    def moved(name: str) -> Optional[float]:
+        if gauge:
+            return metrics.stat([s[name] for s in scrapes.get("all", ())
+                                 if name in s], "mean")
+        start, end = scrapes.get("start"), scrapes.get("end")
+        if not start or not end or name not in start or name not in end:
+            return None
+        return end[name] - start[name]
+
+    out = moved(series)
+    if out is not None and over is not None:
+        below = moved(over)
+        out = out / below if below else None
+    return out
+
+
 def _matching(trace: dict, where: str, pattern: str) -> list[dict]:
     return [v for k, v in (trace.get(where) or {}).items()
             if re.search(pattern, k)]
@@ -126,13 +158,14 @@ def trace_idle(ctx: dict) -> Optional[float]:
 
 
 def roofline(ctx: dict, count_function: str, time_metric: str) -> Optional[float]:
-    """Least time the chip could take for what ``count_function`` counts,
-    over the measured time of ``time_metric`` (ms, read before this one)."""
+    """Least time the chip could take for what ``count_function`` counts
+    (a role; ``opcounts.count_function`` finds this configuration's), over
+    the measured time of ``time_metric`` (ms, read before this one)."""
     measured_ms = ctx["values"].get(time_metric)
-    if not measured_ms or not ctx.get("peaks"):
+    count = opcounts.count_function(ctx["config"], count_function)
+    if not measured_ms or not ctx.get("peaks") or count is None:
         return None
-    counts = opcounts.COUNT_FUNCTIONS[count_function](
-        ctx["config"], ctx["config"]["serving"])
+    counts = count(ctx["config"], ctx["config"]["serving"])
     least_s, _ = opcounts.least_seconds(counts, ctx["peaks"])
     return 100.0 * least_s * 1e3 / measured_ms
 
@@ -140,6 +173,16 @@ def roofline(ctx: dict, count_function: str, time_metric: str) -> Optional[float
 READERS: dict[str, Callable] = {
     "client_stat": client_stat, "client_minus_flight": client_minus_flight,
     "flight_records": flight_records, "server_log": server_log,
-    "rounds": rounds, "samples": samples, "trace_ops": trace_ops,
-    "trace_idle": trace_idle, "roofline": roofline,
+    "rounds": rounds, "samples": samples, "counter": counter,
+    "trace_ops": trace_ops, "trace_idle": trace_idle, "roofline": roofline,
 }
+
+
+def resolve(kind: str) -> Callable:
+    """The reader of a layer-metric file's ``kind``."""
+    if ":" in kind:
+        return names.load(kind)
+    if kind not in READERS:
+        raise HarnessFailure(f"unknown reader kind {kind!r}; this file's: "
+                             f"{sorted(READERS)}")
+    return READERS[kind]

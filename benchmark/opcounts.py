@@ -4,9 +4,19 @@ Kept with the benchmark so that no PR that claims a gain can change what a
 roofline share is measured against. Each function takes the published
 configuration and the serving block of its file and returns
 ``{"flops", "bytes", "what"}`` for ONE execution of the thing named.
+
+A layer-metric file names a count by its role (``decode_step_weights``). The
+functions of this file count the llama family's block. A configuration of
+another architecture names, under ``counts``, the module whose functions of
+the same names count its own; one that names none gets no reading, never
+this file's count.
 """
 
 from __future__ import annotations
+
+from typing import Callable, Optional
+
+from . import names
 
 
 def _dims(cfg: dict) -> dict:
@@ -43,7 +53,14 @@ def decode_step_weights(cfg: dict, serving: dict) -> dict:
             "what": f"int8 weights + f32 scales read once, {rows} rows"}
 
 
-COUNT_FUNCTIONS = {"decode_step_weights": decode_step_weights}
+def count_function(conf: dict, role: str) -> Optional[Callable]:
+    """The function that counts ``role`` for this configuration's
+    architecture, or None where nothing does."""
+    module = conf.get("counts")
+    if module is None and names.adapter_of(conf) != names.DEFAULT_ADAPTER:
+        return None
+    found = getattr(names.load(module or __name__), role, None)
+    return found if callable(found) and not role.startswith("_") else None
 
 
 def least_seconds(counts: dict, peaks: dict) -> tuple[float, str]:

@@ -17,19 +17,21 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import inspect
 import json
 import shutil
 import sys
 import time
 from pathlib import Path
 
-from . import layer_readers, loadgen, metrics
+from . import layer_readers, loadgen, metrics, names
 from .server import (COMPILE_LINES, CACHE_HITS, REPO, WORK,
                      HarnessFailure, Server, dedupe, http_json, run_child)
 
 T_START = time.monotonic()
-#: the jitted programs of the serving path (runtime/scheduler.py); one of
-#: them compiling inside the window makes the run not correct
+#: the jitted programs of the serving path (runtime/scheduler.py) of a
+#: configuration whose ``serving`` block lists none under ``programs``; one
+#: of them compiling inside the window makes the run not correct
 SERVING_PROGRAMS = ("mixed_step", "paged_decode_chunk")
 #: besides those the scheduler runs small op-by-op programs (a page-table
 #: row patch, a slot's sampling row); warm-up brings up the ones it can
@@ -65,9 +67,43 @@ def load_cell(bench_file: Path, workload: str) -> dict:
     def reported(metric: dict) -> bool:
         return workload in metric.get("workloads", [workload])
 
-    return {"bench": bench, "cell": cell, "conf": conf, "mix": mix,
-            "end_to_end": [m for m in bench["end_to_end"] if reported(m)],
-            "per_layer": [m for m in bench["per_layer"] if reported(m)]}
+    loaded = {"bench": bench, "cell": cell, "conf": conf, "mix": mix,
+              "end_to_end": [m for m in bench["end_to_end"] if reported(m)],
+              "per_layer": [m for m in bench["per_layer"] if reported(m)]}
+    loaded["readers"] = resolve_names(loaded)
+    return loaded
+
+
+def resolve_names(loaded: dict) -> dict:
+    """Every name in the cell's data files that stands for code, resolved
+    before a child holds the chip: a wrong one fails here, in seconds, and not
+    after set-up and the window, when a traced run first needs it. The adapter
+    is only found (the correctness child imports it); the counts module and
+    the readers are imported, as they would be later, by this process, which
+    never imports JAX: so they may not either. Returns each per-layer
+    metric's reader and the arguments its file gives it."""
+    conf = loaded["conf"]
+    names.find(names.adapter_of(conf))
+    if "counts" in conf:
+        names.load(conf["counts"])
+    readers = {}
+    for m in loaded["per_layer"]:
+        spec = json.loads(find_file(
+            loaded["bench"]["paths"],
+            f"layer_metrics/{m['name']}.json").read_text())
+        reader = layer_readers.resolve(spec.pop("kind"))
+        spec.pop("what", None)
+        try:
+            inspect.signature(reader).bind({}, **spec)
+        except TypeError as e:
+            raise HarnessFailure(f"layer_metrics/{m['name']}.json does not "
+                                 f"fit its reader {reader.__name__}: {e}")
+        readers[m["name"]] = (reader, spec)
+    if "jax" in sys.modules:
+        raise HarnessFailure("a counts or reader module of this cell imports "
+                             "JAX: the harness's parent may not (the chip "
+                             "belongs to its children, one at a time)")
+    return readers
 
 
 def warm_widths(conf: dict, mix: dict) -> list[int]:
@@ -213,7 +249,8 @@ async def run_window(srv: Server, loaded: dict, seed: int, seconds: float,
     conf, mix = loaded["conf"], loaded["mix"]
     serving = conf["serving"]
     schedule = loadgen.build_schedule(mix, seed)
-    state: dict = {"hbm": [], "pages": [], "log": {}, "rounds": {},
+    state: dict = {"hbm": [], "pages": [], "scrapes": [], "log": {},
+                   "rounds": {},
                    "trace_dir": None, "trace_on": False, "trace_done": False,
                    "trace_span_s": 0.0}
     trace_s = min(3.0, seconds / 4)
@@ -224,8 +261,11 @@ async def run_window(srv: Server, loaded: dict, seed: int, seconds: float,
             state["rounds"][r["ts"]] = r
 
     def sample() -> None:
-        """Live device memory, and the pool pages that requests hold."""
-        state["hbm"].append(srv.metrics().get("tpu_hbm_bytes_in_use", 0.0))
+        """The server's /metrics (kept whole for the ``counter`` readers; live
+        device memory is one of its series), and the pool pages that requests
+        hold."""
+        state["scrapes"].append(srv.metrics())
+        state["hbm"].append(state["scrapes"][-1].get("tpu_hbm_bytes_in_use", 0.0))
         tenants = http_json("GET", f"{srv.base}/v1/monitoring/tenants")
         state["pages"].append(float(sum(t.get("pages", 0)
                                         for t in tenants["tenants"])))
@@ -374,7 +414,9 @@ def main() -> int:
             "utf-8", "replace")
         in_win = log_programs(in_window)["compiles"]
         compile_s_in_window = sum(sum(v) for v in in_win.values())
-        serving_in_window = sorted(k for k in in_win if k in SERVING_PROGRAMS)
+        serving_programs = tuple(conf["serving"].get("programs",
+                                                     SERVING_PROGRAMS))
+        serving_in_window = sorted(k for k in in_win if k in serving_programs)
         progs = log_programs(log_text)
         idle = srv.wait_idle(30.0)
         rc_srv = srv.stop()
@@ -382,9 +424,9 @@ def main() -> int:
         say(f"run: server idle before SIGTERM: {idle}; exit code {rc_srv} "
             "(reported, not part of correct)")
         n_prog = sum(len(v) for k, v in progs["compiles"].items()
-                     if k in SERVING_PROGRAMS)
+                     if k in serving_programs)
         n_hit = sum(v for k, v in progs["hits"].items()
-                    if k in SERVING_PROGRAMS)
+                    if k in serving_programs)
         say(f"run: serving programs {n_prog}, persistent-cache hits {n_hit}"
             + ("" if n_hit >= n_prog else ": THIS RUN COMPILED (a first run "
                "in this checkout); its setup_s is a cold one"))
@@ -407,7 +449,8 @@ def main() -> int:
         say(f"run: SLO engine went to shedding {len(shed)} times since the "
             f"server started" + (f": {json.dumps(shed[-2:])}" if shed else ""))
         say(f"run: compiled inside the window: serving programs "
-            f"{serving_in_window} (limit: none), small programs "
+            f"{serving_in_window} of {list(serving_programs)} (limit: none), "
+            f"small programs "
             f"{sorted(set(in_win) - set(serving_in_window))} "
             f"{compile_s_in_window:.3f} s in all (limit {COMPILE_S_LIMIT} s)")
 
@@ -422,6 +465,23 @@ def main() -> int:
         for what, ok in checks.items():
             say(f"check: {what}: {'ok' if ok else 'NOT ok'}")
         correct = all(checks.values())
+        judged = res["readings"][0]
+        compared = [
+            f"worst logits row rms(program - reference) / std(reference) "
+            f"{judged['program']['worst_row_rms']} (limit {res['limit']}), at "
+            f"[row, position] {judged['program']['row']}",
+            f"idle rows whose state came back changed "
+            f"{judged.get('idle_rows_touched')} (limit: none)",
+            f"measured requests {len(measured)} (limit: at least 1), of which "
+            f"failed {failed} (limit 0)",
+            f"repeated greedy request identical {warm['greedy_identical']} "
+            f"(limit: True)",
+            f"serving programs compiled inside the window {serving_in_window} "
+            f"(limit: none)",
+            f"small compiles inside the window {compile_s_in_window:.3f} s "
+            f"(limit {COMPILE_S_LIMIT} s)"]
+        for line in compared:
+            say("compared: " + line)
         result: dict = {"correct": bool(correct), "attempted": len(measured),
                         "failed": failed, "metrics": {},
                         "device": {**device, "memory_peak_bytes": int(hbm_peak)}}
@@ -476,15 +536,14 @@ def main() -> int:
             ctx = {"requests": measured, "flight": flight, "rounds": rounds_in,
                    "server_log": log_text,
                    "samples": {"pool_pages": st["pages"]},
+                   # sample() runs at the window's start, its end and between
+                   "scrapes": {"start": st["scrapes"][0],
+                               "end": st["scrapes"][-1], "all": st["scrapes"]},
                    "trace": trace, "config": conf, "peaks": dev_peaks,
                    "values": {}}
-            dirs = loaded["bench"]["paths"]
             for m in loaded["per_layer"]:
-                spec = json.loads(find_file(
-                    dirs, f"layer_metrics/{m['name']}.json").read_text())
-                kind = spec.pop("kind")
-                spec.pop("what", None)
-                value = layer_readers.READERS[kind](ctx, **spec)
+                reader, spec = loaded["readers"][m["name"]]
+                value = reader(ctx, **spec)
                 say(f"layer metric: {m['name']} = {value} {m['unit']} "
                     f"[{m['layer']}]")
                 if value is not None:
@@ -496,6 +555,10 @@ def main() -> int:
             result["breakdown"] = breakdown_of(trace)
             say(f"trace: flight records read {len(flight)} of {len(measured)},"
                 f" round records {len(rounds_in)}")
+        # each number compared, beside its limit, as the last lines of
+        # standard error too: what is kept of a run that is not correct
+        print("\n".join(f"compared: {line}" for line in compared)
+              + f"\ncompared: correct {bool(correct)}", file=sys.stderr, flush=True)
         if args.rehearse:
             say("run: REHEARSAL: no device number above is a chip number")
             print("REHEARSAL " + json.dumps(result), flush=True)

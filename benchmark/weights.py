@@ -81,19 +81,21 @@ def make_weights(cfg: dict, seed: int, layers: int) -> dict:
                  bias=bool(cfg.get("attention_bias", False)))
 
 
+def _is_matmul_leaf(node) -> bool:
+    return isinstance(node, dict) and "q" in node and "s" in node
+
+
 @jax.jit
 def to_int4_grid(weights: dict) -> dict:
-    """The control: every matmul weight rounded to the 15 levels of int4
-    (kept in an int8 container so the same program runs it). One precision
-    below what the configurations state."""
+    """The control: every matmul weight (a ``{"q", "s"}`` node, wherever it
+    sits in the tree) rounded to the 15 levels of int4, kept in an int8
+    container so the same program runs it. One precision below what the
+    configurations state."""
     def leaf(w):
-        if isinstance(w, dict) and "q" in w:
-            q4 = jnp.clip(jnp.round(w["q"].astype(jnp.float32) * (7.0 / 127.0)),
-                          -7, 7)
-            return {"q": q4.astype(jnp.int8), "s": w["s"] * (127.0 / 7.0)}
-        return w
+        if not _is_matmul_leaf(w):
+            return w
+        q4 = jnp.clip(jnp.round(w["q"].astype(jnp.float32) * (7.0 / 127.0)),
+                      -7, 7)
+        return {**w, "q": q4.astype(jnp.int8), "s": w["s"] * (127.0 / 7.0)}
 
-    out = dict(weights)
-    out["layers"] = {k: leaf(v) for k, v in weights["layers"].items()}
-    out["lm_head"] = leaf(weights["lm_head"])
-    return out
+    return jax.tree_util.tree_map(leaf, weights, is_leaf=_is_matmul_leaf)
