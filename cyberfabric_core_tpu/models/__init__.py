@@ -6,6 +6,25 @@ PRD only specifies (managed local models, safetensors format, architectures —
 modules/model-registry/docs/PRD.md:200-224).
 """
 
+import importlib
+from types import ModuleType
+
 from .configs import MODEL_CONFIGS, ModelConfig, get_config
 
-__all__ = ["MODEL_CONFIGS", "ModelConfig", "get_config"]
+__all__ = ["MODEL_CONFIGS", "ModelConfig", "get_config", "decoder_module"]
+
+#: decoder architectures the serving scheduler drives → their model module.
+#: Each exposes init_params, forward_paged_decode, forward_paged_mixed,
+#: lm_head_logits and gather_last_hidden.
+_DECODERS = {"llama": "llama", "falcon_h1": "falcon_h1"}
+
+
+def decoder_module(cfg: ModelConfig) -> ModuleType:
+    """The model module of a decoder ``ModelConfig.architecture``."""
+    try:
+        name = _DECODERS[cfg.architecture]
+    except KeyError:
+        raise ValueError(
+            f"{cfg.name}: architecture {cfg.architecture!r} is not a decoder "
+            f"the scheduler serves (known: {sorted(_DECODERS)})") from None
+    return importlib.import_module(f"{__name__}.{name}")

@@ -9,14 +9,17 @@ forward serves them all.
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+import dataclasses
+from dataclasses import dataclass
 from typing import Optional
 
 
 @dataclass(frozen=True)
 class ModelConfig:
     name: str
-    architecture: str  # "llama" (decoder family) | "bert" (encoder family)
+    #: "llama" (decoder family) | "falcon_h1" (decoder whose every block runs
+    #: a Mamba-2 mixer beside GQA attention) | "bert" (encoder family)
+    architecture: str
     vocab_size: int
     hidden_size: int
     intermediate_size: int
@@ -42,6 +45,28 @@ class ModelConfig:
     #: Tokens overflowing an expert's bucket lose that expert's contribution
     #: (standard capacity semantics); 2.0 makes drops rare at serving loads.
     moe_capacity_factor: float = 2.0
+    # falcon_h1: the state-space mixer beside attention (0 heads = no mixer).
+    # Names follow the published config: mamba_d_ssm, mamba_n_heads,
+    # mamba_d_head, mamba_d_state, mamba_n_groups, mamba_d_conv,
+    # mamba_chunk_size.
+    ssm_inner: int = 0
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    # falcon_h1's fixed multipliers (muP), each applied where the published
+    # forward applies it; ssm_multipliers are for (z, x, B, C, dt) of the
+    # mixer's input projection, mlp_multipliers for (gate, down)
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
+    mlp_multipliers: tuple = (1.0, 1.0)
     # bert-family extras
     layer_norm_eps: float = 1e-12
     type_vocab_size: int = 2
@@ -58,6 +83,27 @@ class ModelConfig:
     def q_per_kv(self) -> int:
         return self.num_heads // self.num_kv_heads
 
+    @property
+    def has_state(self) -> bool:
+        """A recurrent state a row beside its K/V pages."""
+        return self.ssm_heads > 0
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the mixer's depthwise conv runs over: x, B and C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def ssm_proj_dim(self) -> int:
+        """Width of the mixer's input projection: z, then x B C, then dt."""
+        return self.ssm_inner + self.ssm_conv_dim + self.ssm_heads
+
+    def state_bytes_per_row(self) -> int:
+        """f32 recurrent state and conv tail of one row, all layers."""
+        per_layer = (self.ssm_heads * self.ssm_head_dim * self.ssm_state
+                     + (self.ssm_conv - 1) * self.ssm_conv_dim)
+        return 4 * self.num_layers * per_layer if self.has_state else 0
+
     def param_count(self) -> int:
         """Approximate parameter count (for HBM budgeting)."""
         h, i, v, l = self.hidden_size, self.intermediate_size, self.vocab_size, self.num_layers
@@ -65,7 +111,12 @@ class ModelConfig:
             + (self.num_heads * self.head_dim) * h
         mlp = 3 * h * i
         emb = v * h * (1 if self.tie_embeddings else 2)
-        return l * (attn + mlp + 2 * h) + emb + h
+        mixer = 0
+        if self.has_state:  # in/out projections, conv + bias, A, D, dt, norm
+            mixer = (h * self.ssm_proj_dim + self.ssm_inner * h
+                     + (self.ssm_conv + 1) * self.ssm_conv_dim
+                     + 3 * self.ssm_heads + self.ssm_inner)
+        return l * (attn + mlp + mixer + 2 * h) + emb + h
 
 
 MODEL_CONFIGS: dict[str, ModelConfig] = {
@@ -88,7 +139,7 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
     "mistral-7b": ModelConfig(
         name="mistral-7b", architecture="llama", vocab_size=32000, hidden_size=4096,
         intermediate_size=14336, num_layers=32, num_heads=32, num_kv_heads=8,
-        head_dim=128, max_position=8192, rope_theta=10000.0, sliding_window=4096,
+        head_dim=128, max_position=32768, rope_theta=10000.0, sliding_window=4096,
     ),
     "phi-3-mini": ModelConfig(
         name="phi-3-mini", architecture="llama", vocab_size=32064, hidden_size=3072,
@@ -118,7 +169,7 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         name="qwen2-7b", architecture="llama", vocab_size=152064,
         hidden_size=3584, intermediate_size=18944, num_layers=28,
         num_heads=28, num_kv_heads=4, head_dim=128, max_position=32768,
-        rope_theta=1e6, attention_bias=True,
+        rope_theta=1e6, rms_norm_eps=1e-6, attention_bias=True,
     ),
     "tiny-qwen2": ModelConfig(
         name="tiny-qwen2", architecture="llama", vocab_size=512,
@@ -178,6 +229,40 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         num_kv_heads=2, head_dim=16, max_position=256, rope_theta=1e6,
         rms_norm_eps=1e-5, num_experts=4, experts_per_token=2,
     ),
+    # Falcon-H1-34B-Instruct, config.json as published: 72 identical blocks,
+    # each a Mamba-2 mixer (32 heads of 128, state 256, 2 groups of B and C)
+    # beside GQA 20/4 attention, then a SwiGLU MLP; every multiplier as given
+    "falcon-h1-34b": ModelConfig(
+        name="falcon-h1-34b", architecture="falcon_h1", vocab_size=261120,
+        hidden_size=5120, intermediate_size=21504, num_layers=72,
+        num_heads=20, num_kv_heads=4, head_dim=128, max_position=262144,
+        rope_theta=1e11, rms_norm_eps=1e-5,
+        embedding_multiplier=5.656854249492381,
+        ssm_inner=4096, ssm_heads=32, ssm_head_dim=128, ssm_state=256,
+        ssm_groups=2, ssm_conv=4, ssm_chunk=128,
+        attention_in_multiplier=1.0, attention_out_multiplier=0.0375,
+        key_multiplier=0.011048543456039804, lm_head_multiplier=0.0078125,
+        ssm_in_multiplier=0.25, ssm_out_multiplier=0.08838834764831845,
+        ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                         0.3535533905932738),
+        mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
+    ),
+    # CPU-test preset of the same block, every ratio kept: more than one
+    # group, a state size that is not the head size, attention 2 queries a
+    # kv head, a chunk (8) shorter than the test prompts
+    "tiny-falcon-h1": ModelConfig(
+        name="tiny-falcon-h1", architecture="falcon_h1", vocab_size=512,
+        hidden_size=64, intermediate_size=128, num_layers=2, num_heads=4,
+        num_kv_heads=2, head_dim=16, max_position=256, rope_theta=10000.0,
+        embedding_multiplier=2.0,
+        ssm_inner=64, ssm_heads=4, ssm_head_dim=16, ssm_state=32,
+        ssm_groups=2, ssm_conv=4, ssm_chunk=8,
+        attention_in_multiplier=1.0, attention_out_multiplier=0.5,
+        key_multiplier=0.25, lm_head_multiplier=0.5,
+        ssm_in_multiplier=0.5, ssm_out_multiplier=0.35,
+        ssm_multipliers=(0.7, 0.5, 0.35, 1.0, 0.7),
+        mlp_multipliers=(0.7, 0.3),
+    ),
     "bge-base-en": ModelConfig(
         name="bge-base-en", architecture="bert", vocab_size=30522, hidden_size=768,
         intermediate_size=3072, num_layers=12, num_heads=12, num_kv_heads=12,
@@ -189,6 +274,11 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         max_position=128, rope_theta=0.0,
     ),
 }
+
+# one chip's stage of the 72-block deployment: 16 blocks of falcon-h1-34b and
+# nothing else changed (tiny-llama-8l is the precedent for a named depth)
+MODEL_CONFIGS["falcon-h1-34b-16l"] = dataclasses.replace(
+    MODEL_CONFIGS["falcon-h1-34b"], name="falcon-h1-34b-16l", num_layers=16)
 
 
 def get_config(name: str) -> ModelConfig:

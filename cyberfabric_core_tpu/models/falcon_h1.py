@@ -1,0 +1,339 @@
+"""Falcon-H1: a decoder whose every block runs a Mamba-2 mixer beside GQA
+attention on one normalised input, then a SwiGLU MLP (``model_type:
+falcon_h1``; written from the published config and the Hugging Face
+modelling code it names).
+
+    x   = RMSNorm(h)
+    o_a = attention_out_multiplier · W_o · GQA(RoPE(W_q x_a), key_multiplier ·
+          RoPE(W_k x_a), W_v x_a),      x_a = x · attention_in_multiplier
+    [z | xBC | dt] = (W_in (x · ssm_in_multiplier)) ⊙ ssm_multipliers
+    xBC = SiLU(conv1d(xBC) + b);  Δ = softplus(dt + dt_bias);  A = −exp(A_log)
+    S_t = exp(Δ_t A) S_{t-1} + Δ_t x_t B_tᵀ;   y_t = S_t C_t + D x_t
+    o_s = ssm_out_multiplier · W_out · RMSNorm_groups(y ⊙ SiLU(z))
+    h  ← h + o_a + o_s
+    h  ← h + mlp_multipliers[1] · W_down(SiLU(mlp_multipliers[0] · W_gate x′)
+             ⊙ W_up x′),   x′ = RMSNorm(h)
+
+``embedding_multiplier`` after the lookup, ``lm_head_multiplier`` on the
+logits, untied head. The attention half and the MLP are ``models/llama.py``'s
+``_qkv_proj`` / ``_attn_out`` / ``_mlp_residual`` and the paged kernels; the
+mixer is ``ops/ssd.py``. No multiplier is folded into a matrix's scales: each
+is one scalar multiply in the f32 epilogue of its matmul (XLA fuses it), so
+nothing rests on a product of two f32 numbers being exact.
+
+The entry points are the ones ``runtime/scheduler.py`` drives for the llama
+family, with one more operand: the recurrent state beside the K/V pools,
+``{"ssm": [L, rows, H, P, N] f32, "conv": [L, rows, K-1, C] f32}``; row ``b``
+of a batch is row ``b`` of the slab (further rows are the pool's snapshots,
+which these programs never touch). ``write_mask`` governs state as it governs
+pages: a row marked False, or a mixed row with ``q_len`` 0, keeps its state
+bit for bit. A mixed row whose history is 0 starts from the zero state, so
+admission clears nothing.
+"""
+
+from __future__ import annotations
+
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..ops.norms import rms_norm
+from ..ops.platform import default_interpret as _default_interpret
+from ..ops.ssd import (causal_conv, causal_conv_step, ssd_chunked,
+                       ssm_state_update)
+from . import llama
+from .configs import ModelConfig
+from .llama import (PagedPools, Params, _attn_out, _embed_scale, _mlp_residual,
+                    _qkv_proj, _scaled, _wmat, embed_lookup,
+                    gather_last_hidden, lm_head_logits)
+
+__all__ = ["init_params", "init_mixer_small", "init_state",
+           "forward_paged_decode", "forward_paged_mixed", "lm_head_logits",
+           "gather_last_hidden"]
+
+State = dict[str, jnp.ndarray]
+
+
+def init_mixer_small(cfg: ModelConfig, key: jax.Array) -> dict[str, jnp.ndarray]:
+    """The mixer's small f32 leaves, drawn so that a synthetic model's decays
+    are neither 0 nor 1: ``A_log = log U(1, 16)`` and ``dt_bias`` the inverse
+    softplus of a step log-uniform in [1e-3, 1e-1] (the Mamba-2 defaults), so
+    ``exp(Δ A)`` lies between about 0.2 and 0.999; ``D = 1``; conv taps
+    U(±K^-1/2), a small conv bias; the gated norm's weight 1."""
+    L, Hs, K, C = cfg.num_layers, cfg.ssm_heads, cfg.ssm_conv, cfg.ssm_conv_dim
+    k = jax.random.split(key, 4)
+    step = jnp.exp(jax.random.uniform(k[0], (L, Hs), jnp.float32,
+                                      np.log(1e-3), np.log(1e-1)))
+    return {
+        "A_log": jnp.log(jax.random.uniform(k[1], (L, Hs), jnp.float32,
+                                            1.0, 16.0)),
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "D": jnp.ones((L, Hs), jnp.float32),
+        "conv_w": jax.random.uniform(k[2], (L, K, C), jnp.float32,
+                                     -K ** -0.5, K ** -0.5),
+        "conv_b": 0.1 * jax.random.normal(k[3], (L, C), jnp.float32),
+        "ssm_norm": jnp.ones((L, cfg.ssm_inner), jnp.float32),
+    }
+
+
+def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
+    """Random-init parameters: the llama tree plus the mixer's leaves."""
+    k_llama, k_in, k_out, k_small = jax.random.split(key, 4)
+    params = llama.init_params(cfg, k_llama, dtype)
+    H, L = cfg.hidden_size, cfg.num_layers
+
+    def w(rng, *shape):
+        return jax.random.normal(rng, shape, dtype) * jnp.asarray(
+            shape[-2] ** -0.5, dtype)
+
+    params["layers"].update({
+        "ssm_in": w(k_in, L, H, cfg.ssm_proj_dim),
+        "ssm_out": w(k_out, L, cfg.ssm_inner, H),
+        **init_mixer_small(cfg, k_small)})
+    return params
+
+
+def init_state(cfg: ModelConfig, rows: int) -> State:
+    """The zero state slab for ``rows`` rows (slots first, then snapshots)."""
+    L = cfg.num_layers
+    return {"ssm": jnp.zeros((L, rows, cfg.ssm_heads, cfg.ssm_head_dim,
+                              cfg.ssm_state), jnp.float32),
+            "conv": jnp.zeros((L, rows, cfg.ssm_conv - 1, cfg.ssm_conv_dim),
+                              jnp.float32)}
+
+
+def _mup_vector(cfg: ModelConfig) -> np.ndarray:
+    """``ssm_multipliers`` spread over the parts of the input projection."""
+    gn = cfg.ssm_groups * cfg.ssm_state
+    widths = (cfg.ssm_inner, cfg.ssm_inner, gn, gn, cfg.ssm_heads)
+    return np.concatenate([np.full(n, m, np.float32)
+                           for n, m in zip(widths, cfg.ssm_multipliers)])
+
+
+def _mixer_in(lp: dict, x: jnp.ndarray, cfg: ModelConfig):
+    """The input projection and its split: z [B, T, d_ssm], xBC [B, T, C]
+    (conv input), dt [B, T, Hs], all f32."""
+    w_m, w_s = _wmat(lp["ssm_in"], x.dtype)
+    if cfg.ssm_in_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.ssm_in_multiplier, x.dtype)
+    proj = _scaled(jnp.einsum("bth,hd->btd", x, w_m,
+                              preferred_element_type=jnp.float32), w_s)
+    proj = proj * _mup_vector(cfg)
+    d, c = cfg.ssm_inner, cfg.ssm_conv_dim
+    return proj[..., :d], proj[..., d: d + c], proj[..., d + c:]
+
+
+def _split_xbc(xbc: jnp.ndarray, cfg: ModelConfig):
+    """Conv output (after SiLU) → x [.., Hs, P], B and C [.., G, N]."""
+    d, gn = cfg.ssm_inner, cfg.ssm_groups * cfg.ssm_state
+    lead = xbc.shape[:-1]
+    return (xbc[..., :d].reshape(*lead, cfg.ssm_heads, cfg.ssm_head_dim),
+            xbc[..., d: d + gn].reshape(*lead, cfg.ssm_groups, cfg.ssm_state),
+            xbc[..., d + gn:].reshape(*lead, cfg.ssm_groups, cfg.ssm_state))
+
+
+def _mixer_out(lp: dict, y: jnp.ndarray, z: jnp.ndarray, cfg: ModelConfig,
+               dtype) -> jnp.ndarray:
+    """Gate first, then the grouped RMSNorm (``mamba_norm_before_gate``
+    false), the output projection and its multiplier. ``y``, ``z``
+    [B, T, d_ssm] f32."""
+    g = y * jax.nn.silu(z)
+    lead = g.shape[:-1]
+    grouped = g.reshape(*lead, cfg.ssm_groups, -1)
+    var = jnp.mean(grouped * grouped, axis=-1, keepdims=True)
+    normed = (grouped * jax.lax.rsqrt(var + cfg.rms_norm_eps)).reshape(
+        *lead, cfg.ssm_inner) * lp["ssm_norm"]
+    w_m, w_s = _wmat(lp["ssm_out"], dtype)
+    out = _scaled(jnp.einsum("btd,dh->bth", normed.astype(dtype), w_m,
+                             preferred_element_type=jnp.float32), w_s)
+    return (out * cfg.ssm_out_multiplier).astype(dtype)
+
+
+def _layer_rows(slab: jnp.ndarray, layer, rows: int) -> jnp.ndarray:
+    """Rows ``[:rows]`` of one layer of a stacked slab: a slice the size of
+    what the caller computes on, never the slab."""
+    return jax.lax.dynamic_slice(
+        slab, (layer,) + (0,) * (slab.ndim - 1),
+        (1, rows) + slab.shape[2:])[0]
+
+
+def _store_rows(slab: jnp.ndarray, layer, rows: jnp.ndarray) -> jnp.ndarray:
+    return jax.lax.dynamic_update_slice(
+        slab, rows[None], (layer,) + (0,) * (slab.ndim - 1))
+
+
+def _one_device(mesh: Any, interpret: bool | None) -> bool:
+    """The forwards' shared entry checks; returns ``interpret`` resolved."""
+    if mesh is not None:
+        raise ValueError("falcon_h1 serves on one device: the state slab has "
+                         "no tp sharding")
+    return _default_interpret() if interpret is None else interpret
+
+
+def _attn_in(x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    if cfg.attention_in_multiplier != 1.0:
+        return x * jnp.asarray(cfg.attention_in_multiplier, x.dtype)
+    return x
+
+
+def forward_paged_decode(
+    params: Params,
+    cfg: ModelConfig,
+    input_ids: jnp.ndarray,    # [B, 1]
+    pools: PagedPools,
+    page_table: jnp.ndarray,   # [B, Pmax]
+    lengths: jnp.ndarray,      # [B] valid length BEFORE this token
+    rope_tables: tuple[jnp.ndarray, jnp.ndarray],
+    interpret: bool | None = None,
+    write_mask: jnp.ndarray | None = None,
+    mesh: Any = None,
+    *,
+    state: State,
+) -> tuple[jnp.ndarray, PagedPools, State]:
+    """One decode step over the page pool and the state slab. Returns
+    (hidden [B, 1, H], pools, state). K/V as ``llama.forward_paged_decode``;
+    the recurrent state of row ``b`` is read and written in place by the
+    ``ssm_state_update`` kernel, and stays as it was where ``write_mask`` is
+    False."""
+    from ..ops.paged_attention import paged_decode_attention
+
+    interpret = _one_device(mesh, interpret)
+    cos_t, sin_t = rope_tables
+    B = input_ids.shape[0]
+    Hq, D = cfg.num_heads, cfg.head_dim
+    page_size = pools[0].shape[2]
+    positions = lengths[:, None]
+    if write_mask is None:
+        write_mask = jnp.ones((B,), bool)
+    idx_page = lengths // page_size
+    pid = jnp.take_along_axis(page_table, idx_page[:, None], axis=1)[:, 0]
+    pid = jnp.where(write_mask, pid, 0)
+    off = jnp.where(write_mask, lengths % page_size, 0)
+
+    h = _embed_scale(embed_lookup(params["embed"], input_ids,
+                                  params["final_norm"].dtype), cfg)
+
+    def layer_body(carry, xs):
+        h, k_pool, v_pool, ssm, conv = carry
+        lp, layer = xs
+        x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
+        q, kproj, vproj = _qkv_proj(lp, _attn_in(x, cfg), cfg, positions,
+                                    cos_t, sin_t)
+        k_pool = k_pool.at[layer, pid, off].set(
+            kproj.reshape(B, -1).astype(k_pool.dtype))
+        v_pool = v_pool.at[layer, pid, off].set(
+            vproj.reshape(B, -1).astype(v_pool.dtype))
+        attn = paged_decode_attention(q[:, 0], k_pool, v_pool, page_table,
+                                      lengths + 1, layer, interpret=interpret)
+
+        z, u, dt = _mixer_in(lp, x, cfg)
+        tail = _layer_rows(conv, layer, B)
+        xbc, new_tail = causal_conv_step(u[:, 0], tail, lp["conv_w"],
+                                         lp["conv_b"])
+        conv = _store_rows(conv, layer, jnp.where(
+            write_mask[:, None, None], new_tail, tail))
+        xs_, b_mat, c_mat = _split_xbc(jax.nn.silu(xbc).astype(h.dtype), cfg)
+        y, ssm = ssm_state_update(
+            ssm, layer, xs_, jax.nn.softplus(dt[:, 0] + lp["dt_bias"]),
+            -jnp.exp(lp["A_log"]), b_mat, c_mat, write_mask,
+            kernel=not interpret)
+        y = y + lp["D"][:, None] * xs_.astype(jnp.float32)
+        o_s = _mixer_out(lp, y.reshape(B, 1, -1), z, cfg, h.dtype)
+
+        h = _attn_out(lp, h, attn.reshape(B, 1, Hq * D),
+                      cfg.attention_out_multiplier) + o_s
+        h = _mlp_residual(lp, h, cfg)
+        return (h, k_pool, v_pool, ssm, conv), None
+
+    (h, k_pool, v_pool, ssm, conv), _ = jax.lax.scan(
+        layer_body, (h, *pools, state["ssm"], state["conv"]),
+        (params["layers"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))
+    h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
+    return h, (k_pool, v_pool), {"ssm": ssm, "conv": conv}
+
+
+def forward_paged_mixed(
+    params: Params,
+    cfg: ModelConfig,
+    input_ids: jnp.ndarray,    # [B, Qmax]
+    pools: PagedPools,
+    page_table: jnp.ndarray,
+    hist: jnp.ndarray,         # [B] tokens BEFORE each row's span
+    q_lens: jnp.ndarray,       # [B] span length (0 = idle row)
+    rope_tables: tuple[jnp.ndarray, jnp.ndarray],
+    interpret: bool | None = None,
+    write_mask: jnp.ndarray | None = None,
+    mesh: Any = None,
+    *,
+    state: State,
+) -> tuple[jnp.ndarray, PagedPools, State]:
+    """One ragged mixed step. Returns (hidden [B, Qmax, H], pools, state).
+    K/V as ``llama.forward_paged_mixed``. A row's recurrent state advances by
+    its ``q_len`` tokens through the chunked form, from the zero state where
+    its history is 0 and from its slab row otherwise; rows with ``q_len`` 0
+    or ``write_mask`` False keep state and conv tail bit for bit."""
+    from ..ops.paged_attention import ragged_paged_attention
+
+    interpret = _one_device(mesh, interpret)
+    cos_t, sin_t = rope_tables
+    B, Qmax = input_ids.shape
+    Hq, D = cfg.num_heads, cfg.head_dim
+    page_size = pools[0].shape[2]
+    if write_mask is None:
+        write_mask = jnp.ones((B,), bool)
+
+    offs = jnp.arange(Qmax, dtype=jnp.int32)[None, :]
+    valid = (offs < q_lens[:, None]) & write_mask[:, None]
+    positions = jnp.where(valid, hist[:, None] + offs, 0)
+    pid = jnp.where(
+        valid,
+        jnp.take_along_axis(page_table, positions // page_size, axis=1), 0)
+    off = jnp.where(valid, positions % page_size, 0)
+    advance = write_mask & (q_lens > 0)          # rows whose state moves
+    fresh = (hist == 0)[:, None, None]           # rows that start from zero
+    span = jnp.where(advance, q_lens, 0)
+
+    h = _embed_scale(embed_lookup(params["embed"], input_ids,
+                                  params["final_norm"].dtype), cfg)
+
+    def layer_body(carry, xs):
+        h, k_pool, v_pool, ssm, conv = carry
+        lp, layer = xs
+        x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
+        q, kproj, vproj = _qkv_proj(lp, _attn_in(x, cfg), cfg, positions,
+                                    cos_t, sin_t)
+        k_pool = k_pool.at[layer, pid, off].set(
+            kproj.reshape(B, Qmax, -1).astype(k_pool.dtype))
+        v_pool = v_pool.at[layer, pid, off].set(
+            vproj.reshape(B, Qmax, -1).astype(v_pool.dtype))
+        attn = ragged_paged_attention(q, k_pool, v_pool, page_table, hist,
+                                      q_lens, layer, interpret=interpret)
+
+        z, u, dt = _mixer_in(lp, x, cfg)
+        tail = _layer_rows(conv, layer, B)
+        s_old = _layer_rows(ssm, layer, B)
+        xbc, new_tail = causal_conv(u, jnp.where(fresh, 0.0, tail),
+                                    lp["conv_w"], lp["conv_b"], span)
+        xs_, b_mat, c_mat = _split_xbc(jax.nn.silu(xbc).astype(h.dtype), cfg)
+        y, s_new = ssd_chunked(
+            xs_, jax.nn.softplus(dt + lp["dt_bias"]), -jnp.exp(lp["A_log"]),
+            b_mat, c_mat, lp["D"], jnp.where(fresh[..., None], 0.0, s_old),
+            span, cfg.ssm_chunk)
+        conv = _store_rows(conv, layer, jnp.where(
+            advance[:, None, None], new_tail, tail))
+        ssm = _store_rows(ssm, layer, jnp.where(
+            advance[:, None, None, None], s_new, s_old))
+        o_s = _mixer_out(lp, y.reshape(B, Qmax, -1), z, cfg, h.dtype)
+
+        h = _attn_out(lp, h, attn.reshape(B, Qmax, Hq * D),
+                      cfg.attention_out_multiplier) + o_s
+        h = _mlp_residual(lp, h, cfg)
+        return (h, k_pool, v_pool, ssm, conv), None
+
+    (h, k_pool, v_pool, ssm, conv), _ = jax.lax.scan(
+        layer_body, (h, *pools, state["ssm"], state["conv"]),
+        (params["layers"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))
+    h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
+    return h, (k_pool, v_pool), {"ssm": ssm, "conv": conv}

@@ -219,7 +219,9 @@ def _moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig) -> jnp.ndarray:
 
 def _qkv_proj(lp: dict, x: jnp.ndarray, cfg: ModelConfig,
               positions: jnp.ndarray, cos_t, sin_t):
-    """Shared q/k/v projection + reshape + rope for one layer (any T)."""
+    """Shared q/k/v projection + reshape + rope for one layer (any T).
+    ``cfg.key_multiplier`` (falcon_h1) scales k in f32, before the cast; at
+    1.0 nothing is traced for it."""
     B, T = x.shape[0], x.shape[1]
     Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     wq_m, wq_s = _wmat(lp["wq"], x.dtype)
@@ -235,6 +237,8 @@ def _qkv_proj(lp: dict, x: jnp.ndarray, cfg: ModelConfig,
         q = q + lp["bq"]
         kproj = kproj + lp["bk"]
         vproj = vproj + lp["bv"]
+    if cfg.key_multiplier != 1.0:
+        kproj = kproj * cfg.key_multiplier
     q = q.astype(x.dtype)
     kproj = kproj.astype(x.dtype)
     vproj = vproj.astype(x.dtype)
@@ -246,10 +250,16 @@ def _qkv_proj(lp: dict, x: jnp.ndarray, cfg: ModelConfig,
     return q, kproj, vproj
 
 
-def _attn_out(lp: dict, h: jnp.ndarray, attn_flat: jnp.ndarray) -> jnp.ndarray:
+def _attn_out(lp: dict, h: jnp.ndarray, attn_flat: jnp.ndarray,
+              multiplier: float = 1.0) -> jnp.ndarray:
+    """Output projection + residual; ``multiplier`` (falcon_h1's
+    attention_out_multiplier) scales the projection in f32."""
     wo_m, wo_s = _wmat(lp["wo"], h.dtype)
-    return h + _scaled(jnp.einsum("btd,dh->bth", attn_flat, wo_m,
-                       preferred_element_type=jnp.float32), wo_s).astype(h.dtype)
+    out = _scaled(jnp.einsum("btd,dh->bth", attn_flat, wo_m,
+                  preferred_element_type=jnp.float32), wo_s)
+    if multiplier != 1.0:
+        out = out * multiplier
+    return h + out.astype(h.dtype)
 
 
 def _mlp_residual(lp: dict, h: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
@@ -264,9 +274,15 @@ def _mlp_residual(lp: dict, h: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
                    preferred_element_type=jnp.float32), g_s)
     up = _scaled(jnp.einsum("bth,hi->bti", x, u_m,
                  preferred_element_type=jnp.float32), u_s)
+    gate_mult, down_mult = cfg.mlp_multipliers      # falcon_h1; (1, 1) else
+    if gate_mult != 1.0:
+        gate = gate * gate_mult
     act = (_act(gate, cfg) * up).astype(h.dtype)
-    return h + _scaled(jnp.einsum("bti,ih->bth", act, d_m,
-                       preferred_element_type=jnp.float32), d_s).astype(h.dtype)
+    down = _scaled(jnp.einsum("bti,ih->bth", act, d_m,
+                   preferred_element_type=jnp.float32), d_s)
+    if down_mult != 1.0:
+        down = down * down_mult
+    return h + down.astype(h.dtype)
 
 
 def forward(
@@ -345,7 +361,7 @@ PagedPools = tuple[jnp.ndarray, jnp.ndarray]
 
 def _merged_pools(pools: PagedPools) -> tuple[PagedPools, tuple | None]:
     """What ``forward_paged_*`` run on, and the shape to hand back. A 5-D
-    ``[L, N, page, Hkv, D]`` pool (benchmark/correctness.py builds them) is
+    ``[L, N, page, Hkv, D]`` pool (benchmark/adapters/llama.py builds them) is
     merged once here, outside the layer scan, and un-merged on return by
     :func:`_restore_pools`: one copy a call, the same scatter and kernels.
     ROADMAP D12 deletes this entry once that caller builds merged pools."""
@@ -625,6 +641,8 @@ def lm_head_logits(params: Params, cfg: ModelConfig, hidden: jnp.ndarray) -> jnp
             head = head.T
         logits = jnp.einsum("...h,hv->...v", hidden, head,
                             preferred_element_type=jnp.float32)
+    if cfg.lm_head_multiplier != 1.0:   # falcon_h1
+        logits = logits * cfg.lm_head_multiplier
     # single exit: every head variant gets the gemma-2 softcap
     return _softcap(logits, cfg)
 

@@ -403,6 +403,8 @@ class LocalTpuWorker(LlmWorkerApi):
             # problem; hbm_bytes_per_device=0 plans without enforcing.
             tp=int(opts.pop("tp", 1)),
             hbm_bytes_per_device=int(opts.pop("hbm_bytes_per_device", 0)),
+            # a model with recurrent state: snapshot rows for prefix reuse
+            state_snapshots=int(opts.pop("state_snapshots", -1)),
         )
         params = None
         tokenizer: Tokenizer

@@ -484,6 +484,43 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
             "Prefill tokens skipped via prefix-cache hits (cumulative)"
         ).set_function(prefill_tokens_saved)
 
+        # recurrent state beside the pages (a model with a state-space mixer:
+        # runtime/paged.py): snapshots taken at chunk boundaries, prefix hits
+        # that resumed from one, snapshots freed (their page evicted, or the
+        # bounded set full), rows restored after preemption — pushed by the
+        # pool — and how much of the state slab holds something
+        for name, text in (
+                ("llm_state_snapshots_taken_total",
+                 "Recurrent-state snapshots taken where a prefill chunk "
+                 "ended on a snapshot boundary"),
+                ("llm_state_snapshot_hits_total",
+                 "Admissions whose prefix hit resumed from a state snapshot"),
+                ("llm_state_snapshot_evictions_total",
+                 "State snapshots freed: their page evicted, or the oldest "
+                 "dropped from the full set"),
+                ("llm_state_restores_total",
+                 "State rows restored from host on resume after preemption")):
+            self.registry.counter(name, text).inc(0.0)
+
+        def _state_stat(key: str) -> float:
+            return float(sum(st.get(key, 0) for st in _pool_stats()))
+
+        def state_rows_in_use() -> float:
+            return float(sum(getattr(s, "state_rows_in_use", lambda: 0)()
+                             for s in _schedulers()))
+
+        self.registry.gauge(
+            "llm_state_rows_in_use",
+            "Rows of the recurrent-state slab in use: live slots plus "
+            "snapshots held").set_function(state_rows_in_use)
+        self.registry.gauge(
+            "llm_state_rows",
+            "Rows of the recurrent-state slab (slots plus snapshot rows)"
+        ).set_function(lambda: _state_stat("state_rows"))
+        self.registry.gauge(
+            "llm_state_bytes", "Bytes of the recurrent-state slab"
+        ).set_function(lambda: _state_stat("state_bytes"))
+
         def mixed_chunk_tokens() -> float:
             return float(sum(getattr(s, "chunked_prefill_tokens", 0)
                              for s in _schedulers()))

@@ -66,6 +66,7 @@ def gate_engine_plan(
     page_size: int = 64,
     num_pages: Optional[int] = None,
     hbm_bytes: Optional[int] = None,
+    state_rows: Optional[int] = None,
 ) -> dict[str, Any]:
     """Engine-construction gate: derive the per-device byte plan for the
     EXACT serving geometry (the engine passes its real page-pool size via
@@ -77,6 +78,7 @@ def gate_engine_plan(
     plan = tp_plan(cfg, max(1, tp), quantization=quantization, dtype=dtype,
                    max_batch=max_batch, max_seq_len=max_seq_len,
                    page_size=page_size, num_pages=num_pages,
+                   state_rows=state_rows,
                    hbm_bytes=hbm_bytes or V5E_HBM_BYTES,
                    # the engine's pool REPLICATES when tp cannot divide the
                    # kv heads — budget what serving actually allocates
@@ -87,7 +89,8 @@ def gate_engine_plan(
             f"{plan['model']} @ tp={plan['tp']} quant={quantization} needs "
             f"{plan['total_bytes_per_device']} bytes/device "
             f"(params {plan['param_bytes_per_device']} + KV "
-            f"{plan['kv_bytes_per_device']} + activations "
+            f"{plan['kv_bytes_per_device']} + state "
+            f"{plan['state_bytes_per_device']} + activations "
             f"{plan['activation_bytes_estimate']}) > HBM budget {hbm_bytes} "
             f"({plan['hbm_utilization']:.2f}x the budget); "
             "raise tp, quantize, or shrink max_batch/max_seq_len",
@@ -120,6 +123,7 @@ def tp_plan(
     hbm_bytes: int = V5E_HBM_BYTES,
     num_pages: Optional[int] = None,
     kv_replicated: bool = False,
+    state_rows: Optional[int] = None,
 ) -> dict[str, Any]:
     """Per-device byte budget + per-shard read plan for ``model`` at tp=N.
 
@@ -191,7 +195,14 @@ def tp_plan(
     # analysis) is the exact oracle; this keeps the planner device-free.
     act_bytes = int(prefill_bucket * cfg.hidden_size * 2 * 8)
 
-    total_device = param_bytes_device + kv_bytes_device + act_bytes
+    # recurrent state beside the pool (falcon_h1): one f32 row a slot plus the
+    # snapshot rows (``state_rows``: the engine's count; absent: the slots),
+    # replicated — every device pays all of it
+    state_bytes_device = cfg.state_bytes_per_row() * (
+        state_rows if state_rows is not None else max_batch)
+
+    total_device = (param_bytes_device + kv_bytes_device + state_bytes_device
+                    + act_bytes)
     read_plan = _read_plan(cfg, tp, ep, specs, sharded)
     return {
         "model": model, "tp": tp, "ep": ep, "quantization": quantization,
@@ -200,6 +211,7 @@ def tp_plan(
         "param_bytes_total": param_bytes_total,
         "param_bytes_per_device": param_bytes_device,
         "kv_bytes_per_device": kv_bytes_device,
+        "state_bytes_per_device": state_bytes_device,
         "activation_bytes_estimate": act_bytes,
         "total_bytes_per_device": total_device,
         "hbm_bytes": hbm_bytes,

@@ -75,6 +75,15 @@ def llama_param_shardings(cfg: ModelConfig, mesh: Mesh,
         })
         for dense_key in ("gate", "up", "down"):
             tree["layers"].pop(dense_key, None)
+    if cfg.has_state:
+        # falcon_h1's mixer serves on one device (the state slab has no tp
+        # sharding; the scheduler refuses tp > 1): every leaf replicated
+        tree["layers"].update({
+            "ssm_in": ns(None, None, None), "ssm_out": ns(None, None, None),
+            "conv_w": ns(None, None, None), "conv_b": ns(None, None),
+            "A_log": ns(None, None), "D": ns(None, None),
+            "dt_bias": ns(None, None), "ssm_norm": ns(None, None),
+        })
     return tree
 
 
@@ -181,13 +190,14 @@ def abstract_params(cfg: ModelConfig, dtype, quantization: str = "none"):
     eval_shape over the SAME builders serving uses, zero allocation."""
     import jax
 
-    from ..models import llama
+    from ..models import decoder_module
     from ..runtime.quant import quant_bits, quantize_llama_params
 
     bits = quant_bits(quantization)
+    model = decoder_module(cfg)
 
     def build(key):
-        p = llama.init_params(cfg, key, dtype)
+        p = model.init_params(cfg, key, dtype)
         return quantize_llama_params(p, bits) if bits else p
 
     return jax.eval_shape(build, jax.random.PRNGKey(0))

@@ -254,6 +254,12 @@ class EngineConfig:
     #: pool. Set by PDServingPool (runtime/pd.py) via
     #: engine_options.pd_prefill_replicas / pd_decode_replicas.
     pd_role: str = ""
+    #: a model with recurrent state (falcon_h1) keeps snapshots of it for
+    #: prefix reuse in this many further rows of the state slab, each owned
+    #: by the prefix-tree page at whose end it was taken (runtime/paged.py).
+    #: -1 = as many as slots; 0 = none (every prompt prefills from its start).
+    #: Ignored by models without state.
+    state_snapshots: int = -1
 
     def resolve_lookahead_depth(self) -> int:
         """Lookahead ring depth as an int ≥ 0. Legacy bool configs parse as
@@ -409,7 +415,11 @@ class InferenceEngine:
         self.config = config
         self.model_config = model_config or get_config(config.model)
         if self.model_config.architecture != "llama":
-            raise ValueError(f"InferenceEngine drives decoder models, got {self.model_config.architecture}")
+            raise ValueError(
+                f"InferenceEngine drives llama-family decoders through a "
+                f"dense cache, got {self.model_config.architecture!r} "
+                f"({self.model_config.name}): a model with recurrent state "
+                "is served by the continuous scheduler's paged path only")
         self.dtype = jnp.bfloat16 if config.dtype == "bfloat16" else jnp.dtype(config.dtype)
         from .quant import quant_bits as _qb
 

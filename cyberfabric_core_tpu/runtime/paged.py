@@ -6,6 +6,17 @@ a paged device pool ([L, num_pages, page_size, Hkv*D]: the two minor dimensions
 are stored merged, head-major, which is the block the paged kernels read — on a
 tiled TPU layout merging them in front of the kernel is a copy of the pool)
 indexed by the native radix prefix cache (runtime/native.py — C++ fabric_host).
+
+One manager for both kinds of cache. A model with recurrent state
+(``ModelConfig.has_state``: falcon_h1) also gets a state slab here —
+``{"ssm": [L, rows, H, P, N], "conv": [L, rows, K-1, C]}`` f32 — whose first
+``state_slots`` rows are the slots' own (row = slot) and whose further rows
+are **snapshots**, each owned by the prefix-tree page at whose end it was
+taken. State does not grow with a row's length and cannot be shared by
+aliasing a page-table line, so a prefix hit is only worth its pages where a
+snapshot of the state at that boundary exists: ``match_prefix`` trims a hit
+to the deepest page that owns one, admission copies it into the slot's row,
+evicting the page frees it, and the preemption movers carry the slot's row.
 The movers below hand out and take in request-sized tensors with their
 [..., Hkv, D] tail and reshape them at this boundary. A new request whose
 prompt shares a page-aligned prefix with any earlier one:
@@ -25,6 +36,7 @@ from __future__ import annotations
 
 import logging
 import threading
+from collections import OrderedDict
 from functools import partial
 from typing import Any, Optional
 
@@ -32,12 +44,28 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models import llama
 from ..models.configs import ModelConfig
+from ..modkit.metrics import bump_counter
 from ..ops.sampling import sample_token
 from .native import BlockAllocator, PrefixCache
 
 logger = logging.getLogger("paged")
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def state_copy_row(state: dict, src, dst) -> dict:
+    """Row ``src`` of every layer of the state slab copied over row ``dst``,
+    in place (snapshot ← slot at a chunk boundary, slot ← snapshot at
+    admission)."""
+    return {k: v.at[:, dst].set(v[:, src]) for k, v in state.items()}
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def state_set_row(state: dict, row, values: dict) -> dict:
+    """Row ``row`` set from ``values`` (``[L, ...]`` per leaf), in place:
+    the resume half of preemption."""
+    return {k: v.at[:, row].set(values[k].astype(v.dtype))
+            for k, v in state.items()}
 
 
 def _buckets_upto(n: int) -> list[int]:
@@ -55,7 +83,8 @@ class PrefixKVPool:
     def __init__(self, model_config: ModelConfig, *, num_pages: int = 64,
                  page_size: int = 64, dtype=jnp.bfloat16,
                  force_python_native: bool = False,
-                 sharding: Optional[Any] = None) -> None:
+                 sharding: Optional[Any] = None,
+                 state_slots: int = 0, state_snapshots: int = 0) -> None:
         self.cfg = model_config
         self.page_size = page_size
         self.num_pages = num_pages
@@ -99,6 +128,26 @@ class PrefixKVPool:
         self._refs: dict[int, int] = {}
         self._tree_owned: set[int] = set()
         self._orphans: set[int] = set()
+        # recurrent state beside the pages (module docstring): the slab, the
+        # free snapshot rows, and the snapshots the tree's pages own, oldest
+        # use first (page id → row)
+        self.state: Optional[dict[str, jnp.ndarray]] = None
+        self.state_slots = state_slots
+        self._free_snapshot_rows: list[int] = []
+        self._snapshots: "OrderedDict[int, int]" = OrderedDict()
+        self.snapshots_taken = 0
+        self.snapshot_hits = 0
+        self.snapshot_evictions = 0
+        self.state_restores = 0
+        if model_config.has_state and state_slots > 0:
+            from ..models.falcon_h1 import init_state
+
+            rows = state_slots + max(0, state_snapshots)
+            self.state = init_state(model_config, rows)
+            self._free_snapshot_rows = list(range(rows - 1, state_slots - 1, -1))
+            # compile the row copy now, at build: its first use is otherwise
+            # the first chunk boundary of a long prompt, mid-serving
+            self.state = state_copy_row(self.state, 0, 0)
 
     @property
     def capacity_pages(self) -> int:
@@ -168,6 +217,7 @@ class PrefixKVPool:
                 now_free = []
                 for p in freed:
                     self._tree_owned.discard(p)
+                    self._drop_snapshot(p)
                     if self._refs.get(p, 0) > 0:
                         self._orphans.add(p)
                     else:
@@ -188,6 +238,7 @@ class PrefixKVPool:
                 self._refs.pop(p, None)
                 if p not in self._tree_owned:
                     self._orphans.discard(p)
+                    self._drop_snapshot(p)
                     to_free.append(p - self._page_offset)
             else:
                 self._refs[p] = c
@@ -204,12 +255,91 @@ class PrefixKVPool:
             drop = (cached - len(prompt_ids)) // self.page_size + 1
             pages = pages[:-drop] if drop <= len(pages) else []
             cached = len(pages) * self.page_size
+        if self.state is not None:
+            pages = self._upto_snapshot(pages)
+            cached = len(pages) * self.page_size
         self.prefix_lookups += 1
         self.prefill_tokens_total += len(prompt_ids)
         if pages:
             self.prefix_hits += 1
             self.prefill_tokens_saved += cached
         return pages, cached
+
+    # ------------------------------------------------------------ state rows
+    @property
+    def has_state(self) -> bool:
+        return self.state is not None
+
+    def cache_operands(self) -> tuple:
+        """What the serving programs take and give back, donated: the two
+        pools, and the state slab where the model has one."""
+        if self.state is None:
+            return (self.k_pool, self.v_pool)
+        return (self.k_pool, self.v_pool, self.state)
+
+    def adopt(self, outs: tuple) -> tuple:
+        """Take a program's returned cache operands (they lead ``outs``);
+        returns the rest."""
+        n = 2 if self.state is None else 3
+        self.k_pool, self.v_pool = outs[0], outs[1]
+        if self.state is not None:
+            self.state = outs[2]
+        return tuple(outs[n:])
+
+    def _upto_snapshot(self, pages: list[int]) -> list[int]:
+        """A matched chain cut to the deepest page that owns a snapshot of
+        the state at its end: pages beyond it are worth nothing (a prompt
+        that shares less than one snapshot boundary shares nothing)."""
+        keep = max((i + 1 for i, p in enumerate(pages)
+                    if p in self._snapshots), default=0)
+        return pages[:keep]
+
+    def _drop_snapshot(self, page: int) -> None:
+        row = self._snapshots.pop(page, None)
+        if row is not None:
+            self._free_snapshot_rows.append(row)
+            self.snapshot_evictions += 1
+            bump_counter("llm_state_snapshot_evictions_total")
+
+    def take_snapshot(self, slot: int) -> Optional[int]:
+        """Copy slot ``slot``'s state row, as the last program left it, into
+        a snapshot row the caller holds until :meth:`commit_chain` hands it
+        to a page (or :meth:`drop_snapshot_rows` frees it). The set is
+        bounded: with no row free the least recently used committed snapshot
+        goes; with none of those either, no snapshot is taken (None)."""
+        if self.state is None:
+            return None
+        if not self._free_snapshot_rows and self._snapshots:
+            self._drop_snapshot(next(iter(self._snapshots)))
+        if not self._free_snapshot_rows:
+            return None
+        row = self._free_snapshot_rows.pop()
+        self.state = state_copy_row(self.state, slot, row)
+        self.snapshots_taken += 1
+        bump_counter("llm_state_snapshots_taken_total")
+        return row
+
+    def drop_snapshot_rows(self, rows: list[int]) -> None:
+        """Free snapshot rows that never reached the tree (a prompt that was
+        cancelled or preempted before its commit)."""
+        self._free_snapshot_rows.extend(rows)
+
+    def seed_state_row(self, slot: int, pages: list[int]) -> None:
+        """Admission of a prefix hit: the snapshot owned by the last matched
+        page becomes the slot's state (``match_prefix`` trimmed the hit to
+        such a page). A row admitted with no pages needs nothing: a mixed
+        step starts a row whose history is 0 from the zero state."""
+        if self.state is None or not pages:
+            return
+        row = self._snapshots[pages[-1]]
+        self._snapshots.move_to_end(pages[-1])
+        self.state = state_copy_row(self.state, row, slot)
+        self.snapshot_hits += 1
+        bump_counter("llm_state_snapshot_hits_total")
+
+    def state_row(self, row: int) -> dict[str, np.ndarray]:
+        """One row of the slab on the host (tests, preemption)."""
+        return {k: np.asarray(v[:, row]) for k, v in self.state.items()}
 
     def peek_prefix_len(self, prompt_ids: list[int]) -> int:
         """Non-pinning probe: how many head tokens of ``prompt_ids`` this
@@ -220,6 +350,8 @@ class PrefixKVPool:
         with self._tree_lock:
             pages = self.tree.match(prompt_ids)
             try:
+                if self.state is not None:
+                    pages = self._upto_snapshot(pages)
                 return min(len(pages) * self.page_size,
                            max(len(prompt_ids) - 1, 0))
             finally:
@@ -362,15 +494,22 @@ class PrefixKVPool:
             raise
         return chain
 
-    def commit_chain(self, prompt_ids: list[int], chain: list[int]) -> None:
+    def commit_chain(self, prompt_ids: list[int], chain: list[int],
+                     snapshots: Optional[list[tuple[int, int]]] = None
+                     ) -> None:
         """Mixed-batch chunked prefill wrote its KV straight into the chain's
         pages (no scatter pass) — after the final chunk, record the prompt's
         FULL pages in the radix tree so later requests share them zero-copy.
         Pages the tree declines (a racing same-prefix admission already
         cached those positions) simply stay private to the chain, exactly
-        like store_prefill's general contract."""
+        like store_prefill's general contract. ``snapshots`` (a model with
+        recurrent state): ``(tokens, row)`` pairs the caller took with
+        :meth:`take_snapshot` where a mixed call ended on ``tokens``; each
+        goes to the page that ends there if the tree now owns that page and
+        it has none yet, and is freed otherwise."""
         total_pages = len(prompt_ids) // self.page_size
         if total_pages <= 0:
+            self.drop_snapshot_rows([row for _, row in snapshots or ()])
             return
         with self._tree_lock:
             _, unused = self.tree.insert_tracked(
@@ -384,6 +523,14 @@ class PrefixKVPool:
                 # alive as an orphan) is tree-owned again — unmark it, or
                 # the orphan stat leaks and unref would double-account
                 self._orphans.discard(p)
+        for tokens, row in snapshots or ():
+            page = chain[tokens // self.page_size - 1] \
+                if 0 < tokens <= total_pages * self.page_size else None
+            if page is not None and page in self._tree_owned \
+                    and page not in self._snapshots:
+                self._snapshots[page] = row
+            else:
+                self._free_snapshot_rows.append(row)
         self.admissions += 1
 
     def extend_chain(self, chain: list[int], length_needed: int) -> list[int]:
@@ -406,27 +553,49 @@ class PrefixKVPool:
         self.unref_pages(chain)
 
     # ------------------------------------------------------------ preemption
-    def save_chain_to_host(self, chain: list[int]) -> tuple[np.ndarray, np.ndarray]:
+    def save_chain_to_host(self, chain: list[int],
+                           state_row: Optional[int] = None) -> tuple:
         """Copy a slot's chain pages device→host (KV eviction for preempted
         requests — SURVEY §5 checkpoint/resume; the serving analogue of the
         reference's suspend path). One gather per pool; the transfer is the
         chain's actual bytes, not the window. Returns [L, n, page, Hkv, D]
-        each (the PD wire format; a host reshape is a view)."""
+        each (the PD wire format; a host reshape is a view), and where the
+        model has recurrent state a third entry: slot ``state_row``'s row
+        of the slab, so that the request resumes exactly."""
         idx = jnp.asarray(chain, jnp.int32)
         out = (self.k_pool.shape[0], len(chain), self.page_size,
                self.cfg.num_kv_heads, self.cfg.head_dim)
-        return (np.asarray(self.k_pool[:, idx]).reshape(out),
-                np.asarray(self.v_pool[:, idx]).reshape(out))
+        host_kv = (np.asarray(self.k_pool[:, idx]).reshape(out),
+                   np.asarray(self.v_pool[:, idx]).reshape(out))
+        if self.state is not None:
+            if state_row is None:
+                raise ValueError("a model with recurrent state is saved with "
+                                 "its slot's state row")
+            host_kv += (self.state_row(state_row),)
+        return host_kv
 
-    def restore_chain_from_host(self, host_kv: tuple[np.ndarray, np.ndarray]) -> list[int]:
+    def restore_chain_from_host(self, host_kv: tuple,
+                                state_row: Optional[int] = None) -> list[int]:
         """Allocate fresh pages and scatter a saved chain back (device resume).
         Raises MemoryError when the pool still lacks space — caller keeps the
         request suspended. Restored pages are private (shared-prefix structure
-        is not reconstructed; correctness is unaffected)."""
+        is not reconstructed; correctness is unaffected). ``state_row``: the
+        slot whose row of the state slab takes the saved state."""
         n = host_kv[0].shape[1]
         if n == 0:  # a prefill-phase preempt before any chunk landed
             return []
         ids = self._alloc(n)
+        if self.state is not None:
+            if state_row is None or len(host_kv) < 3:
+                self.allocator.free([p - self._page_offset for p in ids])
+                raise ValueError("a model with recurrent state is restored "
+                                 "into a slot's state row, from a save that "
+                                 "carries one")
+            self.state = state_set_row(
+                self.state, state_row,
+                {k: jnp.asarray(v) for k, v in host_kv[2].items()})
+            self.state_restores += 1
+            bump_counter("llm_state_restores_total")
         self.ref_pages(ids)
         idx = jnp.asarray(ids, jnp.int32)
         merged = (*host_kv[0].shape[:3], -1)
@@ -448,7 +617,9 @@ class PrefixKVPool:
         radix pins the caller still holds from match_prefix. Host numpy is
         the transfer format on purpose — it is sharding-agnostic, so pages
         move between same-tp meshes (import re-shards under the destination
-        pool's NamedSharding)."""
+        pool's NamedSharding). Refused for a model with recurrent state:
+        the export carries none."""
+        self._refuse_pd()
         host_kv = self.save_chain_to_host(chain)
         if prompt_ids is not None:
             self.release(prompt_ids)
@@ -462,7 +633,14 @@ class PrefixKVPool:
         the radix structure is not reconstructed — the decode-role pool never
         serves prefix matches, so nothing is lost. Raises MemoryError when
         this pool cannot hold the chain even after eviction."""
+        self._refuse_pd()
         return self.restore_chain_from_host(host_kv)
+
+    def _refuse_pd(self) -> None:
+        if self.state is not None:
+            raise ValueError(
+                f"{self.cfg.name}: the PD page export carries no recurrent "
+                "state, so a decode replica could not continue the row")
 
     def stats(self) -> dict[str, Any]:
         with self._tree_lock:
@@ -483,4 +661,27 @@ class PrefixKVPool:
             "lookups": self.prefix_lookups,
             "hits": self.prefix_hits,
             "native": self.tree.native,
+            **self.state_stats(),
+        }
+
+    def state_stats(self) -> dict[str, Any]:
+        """State rows beside pages: how many rows the slab has, how many are
+        snapshots in use (held by a page or by a prompt still in prefill),
+        and the slab's bytes. Slot rows in use are the scheduler's live
+        slots (``stats()["state_rows_in_use"]`` there adds them)."""
+        if self.state is None:
+            return {}
+        rows = self.state["ssm"].shape[1]
+        return {
+            "state_rows": rows,
+            "state_slot_rows": self.state_slots,
+            "state_snapshot_rows_in_use": (
+                rows - self.state_slots - len(self._free_snapshot_rows)),
+            "state_snapshots_cached": len(self._snapshots),
+            "state_bytes": int(sum(v.size * v.dtype.itemsize
+                                   for v in self.state.values())),
+            "state_snapshots_taken": self.snapshots_taken,
+            "state_snapshot_hits": self.snapshot_hits,
+            "state_snapshot_evictions": self.snapshot_evictions,
+            "state_restores": self.state_restores,
         }

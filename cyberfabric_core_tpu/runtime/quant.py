@@ -25,7 +25,10 @@ import jax.numpy as jnp
 
 #: layers-tree leaves that are matmul weights (contraction on axis -2)
 _MATMUL_LEAVES = {"wq", "wk", "wv", "wo", "gate", "up", "down",
-                  "moe_gate", "moe_up", "moe_down"}
+                  "moe_gate", "moe_up", "moe_down",
+                  # falcon_h1's mixer: W_in and W_out like any matrix; its
+                  # conv, A_log, D, dt_bias and norm weights stay f32
+                  "ssm_in", "ssm_out"}
 
 
 def quant_bits(quantization: str) -> int | None:
@@ -133,11 +136,19 @@ def init_params_quantized(cfg, key: jax.Array, dtype=jnp.bfloat16,
                        "moe_down": w(L, E, I, H)})
     else:
         layers.update({"gate": w(L, H, I), "up": w(L, H, I), "down": w(L, I, H)})
+    if cfg.has_state:  # falcon_h1: the mixer beside attention
+        from ..models.falcon_h1 import init_mixer_small
+
+        layers.update({"ssm_in": w(L, H, cfg.ssm_proj_dim),
+                       "ssm_out": w(L, cfg.ssm_inner, H),
+                       **init_mixer_small(cfg, next(keys))})
 
     embed_full = (jax.random.normal(next(keys), (V, H), dtype)
                   * jnp.asarray(H ** -0.5, dtype))
     params: dict[str, Any] = {
-        "embed": _quantize_embed(embed_full),
+        # jitted: op by op, the f32 copies of a [261120, 5120] table are
+        # 5 GB each beside 7 GB of layers (falcon-h1-34b-16l on a v5e)
+        "embed": jax.jit(_quantize_embed)(embed_full),
         "final_norm": jnp.ones((H,), dtype),
         "layers": layers,
     }

@@ -91,6 +91,7 @@ chunk.
 from __future__ import annotations
 
 import logging
+import math
 import threading
 import time
 import uuid
@@ -101,7 +102,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models import llama
+from ..models import decoder_module, llama
 from ..models.configs import ModelConfig, get_config
 from ..modkit.concurrency import locked_snapshot
 from ..modkit.failpoints import failpoint, record_recovery
@@ -117,6 +118,10 @@ from .engine import (EngineConfig, SamplingParams, SchedulerSaturated,
 from .speculative import NgramProposer, greedy_accept_counts
 
 logger = logging.getLogger("scheduler")
+
+#: most rows of a rope table (the largest published context of the llama
+#: configurations served so far); the served window is always covered
+_ROPE_TABLE_ROWS = 32768
 
 
 def _null_ctx():
@@ -152,6 +157,10 @@ class _SlotState:
     cached_len: int = 0
     prefill_key: Any = None
     prefill_chunks: int = 0
+    #: a model with recurrent state: ``(tokens, snapshot row)`` taken where a
+    #: mixed call ended on a snapshot boundary of this prompt; handed to the
+    #: pool at commit, given back if the prompt leaves its slot before
+    state_snapshots: list = field(default_factory=list)
     prefill_t0: float = 0.0
     prefill_wall: float = 0.0
     #: absolute monotonic deadline (None = unbounded): the per-round expiry
@@ -458,6 +467,15 @@ class ContinuousBatchingEngine:
         # machinery, no decode rows survive past the first token) and push
         # each stream's KV + resume state to _handoff_sink; decode engines
         # admit those records in a handoff phase that skips prefill.
+        #: the decoder's model module (models/llama.py, models/falcon_h1.py):
+        #: the paged programs call its entry points by one set of names
+        self._model = decoder_module(self.model_config)
+        #: recurrent state beside the K/V pages (falcon_h1): the serving
+        #: programs carry the state slab as a third donated operand, and
+        #: the modes that cannot carry it are refused here, at build
+        self._has_state = self.model_config.has_state
+        if self._has_state:
+            self._refuse_without_state_support(config)
         self.pd_role = str(config.pd_role or "")
         if self.pd_role not in ("", "prefill", "decode"):
             raise ValueError(
@@ -503,6 +521,8 @@ class ContinuousBatchingEngine:
                 quantization=config.quantization, dtype=self.dtype,
                 max_batch=config.max_batch, max_seq_len=config.max_seq_len,
                 page_size=page, num_pages=planned_pages,
+                state_rows=(config.max_batch + self._state_snapshot_rows()
+                            if self._has_state else None),
                 hbm_bytes=config.hbm_bytes_per_device or None)
             self.feasibility.pop("leaves", None)
             self.feasibility.pop("read_plan", None)
@@ -550,7 +570,7 @@ class ContinuousBatchingEngine:
                     self.model_config, jax.random.PRNGKey(seed), self.dtype,
                     bits=quant_bits)
             else:
-                params = llama.init_params(
+                params = self._model.init_params(
                     self.model_config, jax.random.PRNGKey(seed), self.dtype)
         else:
             if quant_bits is not None and not isinstance(
@@ -570,9 +590,13 @@ class ContinuousBatchingEngine:
 
             params = shard_llama_params(params, self.model_config, self.mesh)
         self.params = params
+        # the table is a constant of every program: it covers the model's
+        # published positions up to _ROPE_TABLE_ROWS (a 262144-position model
+        # would carry a 134 MB literal in each) and always the served window
         self.rope_tables = rope_frequencies(
             self.model_config.head_dim,
-            max(self.model_config.max_position, config.max_seq_len),
+            max(min(self.model_config.max_position, _ROPE_TABLE_ROWS),
+                config.max_seq_len),
             self.model_config.rope_theta,
         )
         if self.mesh is not None:
@@ -630,7 +654,9 @@ class ContinuousBatchingEngine:
             self.pool = PrefixKVPool(
                 self.model_config, num_pages=num_pages,
                 page_size=page, dtype=self.dtype,
-                sharding=self._pool_sharding)
+                sharding=self._pool_sharding,
+                state_slots=self.n_slots if self._has_state else 0,
+                state_snapshots=self._state_snapshot_rows())
             self.page_table = np.zeros((self.n_slots, self.pmax), np.int32)
             self._page_table_dev = self._dev(jnp.asarray(self.page_table))
             self._pt_dirty_rows: set[int] = set()
@@ -709,6 +735,12 @@ class ContinuousBatchingEngine:
         #: mixed-batch chunked prefill (Sarathi-style piggybacking through the
         #: ragged kernel) — paged mode only; dense mode has no page chains
         self.mixed = self.paged and config.mixed_batch
+        #: a state snapshot can be taken where a mixed call ends on a multiple
+        #: of this many tokens: the prefill budget, in whole pages (0: none)
+        budget = config.prefill_budget_tokens
+        self._state_unit = (math.lcm(budget, config.prefix_page_size)
+                            if self._has_state and budget > 0
+                            and self._state_snapshot_rows() > 0 else 0)
         #: slots currently in "prefill" phase, FIFO by admission — the chunk
         #: planner fills the per-round token budget in this order
         self._prefill_slots: "_deque[int]" = _deque()
@@ -794,8 +826,42 @@ class ContinuousBatchingEngine:
         _init_ctx.close()
 
     # ------------------------------------------------------------------ programs
+    def _refuse_without_state_support(self, config: EngineConfig) -> None:
+        """A model with recurrent state is served by the paged mixed-batch
+        path on one device; each mode below lacks one named thing."""
+        name = self.model_config.name
+        if config.prefix_cache_pages <= 0:
+            raise ValueError(
+                f"{name}: the dense (non-paged) mode has no state slab; "
+                "recurrent state lives beside the page pool "
+                "(prefix_cache_pages > 0)")
+        if not config.mixed_batch:
+            raise ValueError(
+                f"{name}: mixed_batch=False prefills through the dense "
+                "llama forward, which has no mixer; prompts of a model with "
+                "recurrent state run as mixed-step chunks")
+        if config.scheduler_spec_k > 0:
+            raise ValueError(
+                f"{name}: scheduler_spec_k > 0 needs a state rollback: a "
+                "rejected draft has already advanced the recurrent state, "
+                "and nothing restores it")
+        if config.pd_role:
+            raise ValueError(
+                f"{name}: pd_role={config.pd_role!r} hands rows over as "
+                "pages, and the export carries no recurrent state")
+        if max(1, int(config.tp)) > 1:
+            raise ValueError(
+                f"{name}: tp > 1 has no sharding for the state slab")
+
+    def _state_snapshot_rows(self) -> int:
+        if not self._has_state:
+            return 0
+        n = self.config.state_snapshots
+        return self.config.max_batch if n < 0 else n
+
     def _build_programs(self) -> None:
         cfg = self.model_config
+        model = self._model
         k_steps = max(1, self.config.decode_chunk)
 
         # tp meshes take the jnp prefill attention path: the flash Pallas
@@ -854,10 +920,21 @@ class ContinuousBatchingEngine:
 
             max_seq = self.config.max_seq_len
 
-            def paged_decode_chunk(params, k_pool, v_pool, page_table,
-                                   last_tokens, lengths, active, finished,
-                                   stop_ids, limit_lens, keys,
-                                   temp, top_p, top_k):
+            def paged_forward(forward, params, ids, caches, *tail, **kwargs):
+                """One of the model's paged forward passes over the cache
+                operands: the two pools, and the state slab where the model
+                has one. Returns (hidden, caches)."""
+                if not self._has_state:
+                    return forward(params, cfg, ids, caches, *tail, **kwargs)
+                hidden, pools, state = forward(
+                    params, cfg, ids, caches[:2], *tail, state=caches[2],
+                    **kwargs)
+                return hidden, (*pools, state)
+
+            def decode_chunk_body(params, caches, page_table,
+                                  last_tokens, lengths, active, finished,
+                                  stop_ids, limit_lens, keys,
+                                  temp, top_p, top_k):
                 """k fused paged decode steps; per-slot key streams so each
                 request's seed reproduces its tokens (round-1 advisory).
                 Lengths are device-resident: running rows advance by k inside
@@ -875,12 +952,13 @@ class ContinuousBatchingEngine:
                 lookahead ring survive them."""
 
                 def step(carry, j):
-                    pools, toks, lens, fin, keys = carry
+                    caches, toks, lens, fin, keys = carry
                     run = active & jnp.logical_not(fin)
-                    hidden, pools = llama.forward_paged_decode(
-                        params, cfg, toks[:, None], pools, page_table, lens,
-                        rope, write_mask=run, mesh=self._attn_mesh)
-                    logits = llama.lm_head_logits(params, cfg, hidden[:, 0, :])
+                    hidden, caches = paged_forward(
+                        model.forward_paged_decode, params, toks[:, None],
+                        caches, page_table, lens, rope,
+                        write_mask=run, mesh=self._attn_mesh)
+                    logits = model.lm_head_logits(params, cfg, hidden[:, 0, :])
                     keys2, subs = split_keys_per_slot(keys)
                     nxt = sample_token_per_slot(logits, subs, temp, top_p,
                                                 top_k)
@@ -889,25 +967,38 @@ class ContinuousBatchingEngine:
                     hit = (new_lens >= limit_lens) | (
                         (j == k_steps - 1) & (new_lens + k_steps > max_seq))
                     emit = jnp.where(run, nxt, -1)
-                    return (pools, jnp.where(run, nxt, toks),
+                    return (caches, jnp.where(run, nxt, toks),
                             jnp.where(run, new_lens, lens),
                             fin | (run & (is_stop | hit)),
                             jnp.where(run[:, None], keys2, keys)), emit
 
-                (pools, last, lens, fin, keys), toks = jax.lax.scan(
-                    step, ((k_pool, v_pool), last_tokens, lengths, finished,
-                           keys),
+                (caches, last, lens, fin, keys), toks = jax.lax.scan(
+                    step, (caches, last_tokens, lengths, finished, keys),
                     jnp.arange(k_steps, dtype=jnp.int32))
                 lens = jnp.where(active, lens, 0)
-                return toks.T, pools[0], pools[1], last, keys, lens, fin
+                return (toks.T, *caches, last, keys, lens, fin)
 
+            # the program a llama-family model gets takes the two pools, as
+            # it always has; a model with recurrent state gets the same body
+            # with the state slab as a third donated operand. One name for
+            # both: it is what the compile log and the device trace show.
+            if self._has_state:
+                def paged_decode_chunk(params, k_pool, v_pool, state, *rest):
+                    return decode_chunk_body(params, (k_pool, v_pool, state),
+                                             *rest)
+            else:
+                def paged_decode_chunk(params, k_pool, v_pool, *rest):
+                    return decode_chunk_body(params, (k_pool, v_pool), *rest)
+
+            donate = (1, 2, 3) if self._has_state else (1, 2)
             self._paged_decode_fn = jax.jit(paged_decode_chunk,
-                                            donate_argnums=(1, 2))
+                                            donate_argnums=donate)
 
-            def mixed_step(params, k_pool, v_pool, page_table, q_ids, q_lens,
-                           prefill_hist, last_tokens, lengths, active,
-                           finished, sample_mask, final_mask, final_lens,
-                           stop_ids, limit_lens, keys, temp, top_p, top_k):
+            def mixed_step_body(params, caches, page_table, q_ids, q_lens,
+                                prefill_hist, last_tokens, lengths, active,
+                                finished, sample_mask, final_mask, final_lens,
+                                stop_ids, limit_lens, keys, temp, top_p,
+                                top_k):
                 """One ragged mixed-batch round: decode rows (q_len=1) take
                 their next token while prefill rows consume a prompt chunk —
                 one dispatch, no phase separation. ``sample_mask`` rows
@@ -926,13 +1017,13 @@ class ContinuousBatchingEngine:
                 q_ids = q_ids.at[:, 0].set(
                     jnp.where(active, last_tokens, q_ids[:, 0]))
                 hist = jnp.where(active, lengths, prefill_hist)
-                hidden, pools = llama.forward_paged_mixed(
-                    params, cfg, q_ids, (k_pool, v_pool), page_table,
-                    hist, q_lens, rope,
+                hidden, caches = paged_forward(
+                    model.forward_paged_mixed, params, q_ids, caches,
+                    page_table, hist, q_lens, rope,
                     write_mask=run | jnp.logical_not(active),
                     mesh=self._attn_mesh)
-                last_h = llama.gather_last_hidden(hidden, q_lens)
-                logits = llama.lm_head_logits(params, cfg, last_h)
+                last_h = model.gather_last_hidden(hidden, q_lens)
+                logits = model.lm_head_logits(params, cfg, last_h)
                 keys2, subs = split_keys_per_slot(keys)
                 nxt = sample_token_per_slot(logits, subs, temp, top_p, top_k)
                 sample = sample_mask & jnp.logical_not(finished)
@@ -947,10 +1038,18 @@ class ContinuousBatchingEngine:
                 hit = (new_lens >= limit_lens) | (new_lens + k_steps > max_seq)
                 fin_out = finished | (sample & (is_stop | hit))
                 active_out = active | final_mask
-                return (toks, pools[0], pools[1], new_last, keys_out,
+                return (toks, *caches, new_last, keys_out,
                         new_lens, fin_out, active_out)
 
-            self._mixed_step_fn = jax.jit(mixed_step, donate_argnums=(1, 2))
+            if self._has_state:
+                def mixed_step(params, k_pool, v_pool, state, *rest):
+                    return mixed_step_body(params, (k_pool, v_pool, state),
+                                           *rest)
+            else:
+                def mixed_step(params, k_pool, v_pool, *rest):
+                    return mixed_step_body(params, (k_pool, v_pool), *rest)
+
+            self._mixed_step_fn = jax.jit(mixed_step, donate_argnums=donate)
 
             if self.spec_k:
                 spec_w = self._spec_w
@@ -1475,6 +1574,7 @@ class ContinuousBatchingEngine:
         self._deactivate_slot_device(slot)
         if self.paged and state.chain is not None:
             self.pool.release_slot(state.chain)
+            self._drop_pending_snapshots(state)
             self.page_table[slot, :] = 0
             self._mark_pt_row(slot)
         self._cancel_finalize(
@@ -1716,7 +1816,19 @@ class ContinuousBatchingEngine:
                 "soft_yields": yields.get(tenant, 0),
                 "rejections": rejections.get(tenant, {}),
             }
+            if self._has_state:
+                # state rows held beside pages held: one a slot
+                out[tenant]["state_rows"] = slots.get(tenant, 0)
         return out
+
+    def state_rows_in_use(self) -> int:
+        """Rows of the state slab that hold something: one a live slot, plus
+        the snapshot rows a page or a prompt in prefill holds (0 for a model
+        without recurrent state)."""
+        if not self._has_state:
+            return 0
+        live = sum(1 for s in locked_snapshot(self.slots) if s is not None)
+        return live + self.pool.state_stats()["state_snapshot_rows_in_use"]
 
     # -------------------------------------------------------- health surface
     def mesh_info(self) -> dict[str, Any]:
@@ -1886,6 +1998,7 @@ class ContinuousBatchingEngine:
             # claim rests on (BENCH_SPEC.json reads this surface)
             "speculative": speculative,
             "prefix_cache": self.pool.stats() if self.pool is not None else None,
+            "state_rows_in_use": self.state_rows_in_use(),
             "slots": self.n_slots,
             "active": self.active_slots,
             "prefilling": len(self._prefill_slots),
@@ -1947,7 +2060,14 @@ class ContinuousBatchingEngine:
                 # tenant soft-quota sweep: pure bookkeeping (marks a yield
                 # victim; the capacity pass performs the actual preempt)
                 self._service_tenant_caps()
-                admitted = self._admit()
+                # recurrent state: admission, resume and preemption patch
+                # device rows that a still-undrained chunk would overwrite
+                # when it commits, and save a state row that such a chunk has
+                # already advanced; they wait for the ring (_discard_ring)
+                if self._has_state and self._ring and not self.active.any():
+                    self._discard_ring()    # no row runs: nothing to replay
+                admitted = 0 if self._has_state and self._ring \
+                    else self._admit()
                 # prefilling slots are work too: mixed-batch rounds must run
                 # even before any slot reaches decode phase
                 if not self.active.any() and not self._prefill_slots:
@@ -2151,8 +2271,15 @@ class ContinuousBatchingEngine:
                 # PD handoff records land through the import half of the
                 # export/import pair (same restore machinery: fresh private
                 # pages, cast + re-sharded under THIS pool's sharding)
-                chain = (self.pool.import_pages(rec.host_kv) if rec.handoff
-                         else self.pool.restore_chain_from_host(rec.host_kv))
+                if rec.handoff:
+                    chain = self.pool.import_pages(rec.host_kv)
+                elif self._has_state:
+                    # the row comes back with the pages, into the slot the
+                    # record will take (the take below pops this one)
+                    chain = self.pool.restore_chain_from_host(
+                        rec.host_kv, state_row=self._free_slots[0])
+                else:
+                    chain = self.pool.restore_chain_from_host(rec.host_kv)
                 try:
                     self.pool.extend_chain(chain, rec.length + self._k_steps)
                 except MemoryError:
@@ -2484,6 +2611,8 @@ class ContinuousBatchingEngine:
         if chain:
             # refs (not the radix pin) protect the pages from here on
             self.pool.ref_pages(chain)
+            # recurrent state: the snapshot at the hit's end is the row's
+            self.pool.seed_state_row(slot, chain)
         # LOAD-BEARING for chain == [] too: a fully-cached prompt matches
         # (and pins) tree nodes but match_prefix trims its page list to
         # empty — this release is the only unpin for those nodes (same
@@ -2973,7 +3102,9 @@ class ContinuousBatchingEngine:
             reset_log_context(token)
         record_event(state.request_id, "preempted", slot=slot,
                      phase=state.phase, length=length)
-        host_kv = self.pool.save_chain_to_host(chain)
+        host_kv = (self.pool.save_chain_to_host(chain, state_row=slot)
+                   if self._has_state else self.pool.save_chain_to_host(chain))
+        self._drop_pending_snapshots(state)
         with self._submit_lock:
             self._suspended.append(_Suspended(
                 state=state, host_kv=host_kv,
@@ -2995,6 +3126,14 @@ class ContinuousBatchingEngine:
         self.page_table[slot, :] = 0
         self._mark_pt_row(slot)
 
+    def _drop_pending_snapshots(self, state: _SlotState) -> None:
+        """A prompt that leaves its slot before its commit (preempted,
+        cancelled) gives back the snapshot rows it took on the way."""
+        if state.state_snapshots:
+            self.pool.drop_snapshot_rows(
+                [row for _, row in state.state_snapshots])
+            state.state_snapshots = []
+
     def _dispatch_chunk(self, after: Optional[_InflightChunk]) -> _InflightChunk:
         """One fused-chunk dispatch (async — the return holds futures).
         ``after`` chains the dispatch onto a still-unread ring entry's device
@@ -3012,13 +3151,12 @@ class ContinuousBatchingEngine:
             last, keys, lengths, fin, active = (
                 after.last, after.keys, after.lengths_dev,
                 after.finished_dev, after.active_dev)
-        chunk_dev, k_pool, v_pool, last_o, keys_o, lens_o, fin_o = \
-            self._paged_decode_fn(
-                self.params, self.pool.k_pool, self.pool.v_pool,
-                self._page_table_dev, last, lengths, active, fin,
-                self._stops_dev, self._limit_dev, keys,
-                self._temp_dev, self._top_p_dev, self._top_k_dev)
-        self.pool.k_pool, self.pool.v_pool = k_pool, v_pool
+        chunk_dev, *outs = self._paged_decode_fn(
+            self.params, *self.pool.cache_operands(),
+            self._page_table_dev, last, lengths, active, fin,
+            self._stops_dev, self._limit_dev, keys,
+            self._temp_dev, self._top_p_dev, self._top_k_dev)
+        last_o, keys_o, lens_o, fin_o = self.pool.adopt(outs)
         try:
             chunk_dev.copy_to_host_async()  # non-blocking D2H start
         except AttributeError:  # non-jax.Array backends (tests/stubs)
@@ -3076,6 +3214,16 @@ class ContinuousBatchingEngine:
         committed length — rewritten identically by the synchronous fallback
         for surviving slots, masked by attention-length bounds, or fully
         rescattered by the next owner of a freed slot's pages."""
+        if self._has_state and self.active.any():
+            # a chunk in flight has ADVANCED each running row's recurrent
+            # state, and a replay from the committed lengths would advance it
+            # again (K/V past a committed length is rewritten; state is
+            # not). So a stale ring is never dropped while a row runs: the
+            # rounds that follow drain it (it is not extended:
+            # _can_extend_ring checks the epoch), rows the host finished are
+            # masked out of the emit, and _loop_body admits nothing until it
+            # is empty.
+            return
         self._lookahead_stats["discarded"] += len(self._ring)
         self._ring.clear()
 
@@ -3179,6 +3327,11 @@ class ContinuousBatchingEngine:
             remaining = len(state.prompt_ids) - state.prefill_pos
             chunk = int(min(remaining, left)) if left != float("inf") \
                 else remaining
+            if self._state_unit:
+                # end on the next snapshot boundary rather than past it, so
+                # that every row passes through the boundaries it crosses
+                chunk = min(chunk, self._state_unit
+                            - state.prefill_pos % self._state_unit)
             if chunk <= 0:
                 continue
             plan.append((slot, state, chunk))
@@ -3214,10 +3367,12 @@ class ContinuousBatchingEngine:
         chunks chained off it are valid and must not be discarded."""
         T = len(state.prompt_ids)
         try:
-            self.pool.commit_chain(state.prompt_ids, state.chain)
+            self.pool.commit_chain(state.prompt_ids, state.chain,
+                                   snapshots=state.state_snapshots)
         except Exception:  # noqa: BLE001 — the cache insert is best-effort
             logger.exception("prefix-tree commit failed for %s",
                              state.request_id)
+        state.state_snapshots = []
         state.phase = "decode"
         self._arm_spec(state, state.prompt_ids)
         self._prefill_slots.remove(slot)
@@ -3543,9 +3698,8 @@ class ContinuousBatchingEngine:
             spec_lens[slot] = d
         self._flush_pt_patches()
         if spec_plan:
-            (toks_dev, k_pool, v_pool, last_o, keys_o, lens_o, fin_o,
-             active_o) = self._spec_step_fn(
-                self.params, self.pool.k_pool, self.pool.v_pool,
+            toks_dev, *outs = self._spec_step_fn(
+                self.params, *self.pool.cache_operands(),
                 self._page_table_dev, self._dev(q_ids), self._dev(q_lens),
                 self._dev(hist), self._last_tokens, self._lengths_dev,
                 self._active_dev, self._finished_dev, self._dev(sample),
@@ -3554,16 +3708,24 @@ class ContinuousBatchingEngine:
                 self._slot_keys, self._temp_dev, self._top_p_dev,
                 self._top_k_dev)
         else:
-            (toks_dev, k_pool, v_pool, last_o, keys_o, lens_o, fin_o,
-             active_o) = self._mixed_step_fn(
-                self.params, self.pool.k_pool, self.pool.v_pool,
+            toks_dev, *outs = self._mixed_step_fn(
+                self.params, *self.pool.cache_operands(),
                 self._page_table_dev, self._dev(q_ids), self._dev(q_lens),
                 self._dev(hist), self._last_tokens, self._lengths_dev,
                 self._active_dev, self._finished_dev, self._dev(sample),
                 self._dev(final_mask), self._dev(final_lens),
                 self._stops_dev, self._limit_dev, self._slot_keys,
                 self._temp_dev, self._top_p_dev, self._top_k_dev)
-        self.pool.k_pool, self.pool.v_pool = k_pool, v_pool
+        last_o, keys_o, lens_o, fin_o, active_o = self.pool.adopt(outs)
+        if self._state_unit:
+            # a snapshot of each row whose chunk ended on a boundary, as THIS
+            # call left it: before anything chained below advances the row
+            for slot, state, chunk in plan:
+                end = state.prefill_pos + chunk
+                if end % self._state_unit == 0:
+                    row = self.pool.take_snapshot(slot)
+                    if row is not None:
+                        state.state_snapshots.append((end, row))
         try:
             toks_dev.copy_to_host_async()  # non-blocking D2H start
         except AttributeError:

@@ -280,7 +280,7 @@ def test_scheduler_programs_compile_for_v5e_at_mistral_7b(tp):
     from jax.sharding import NamedSharding, PartitionSpec as P, \
         SingleDeviceSharding
 
-    from cyberfabric_core_tpu.models import get_config
+    from cyberfabric_core_tpu.models import decoder_module, get_config
     from cyberfabric_core_tpu.ops.platform import compiled_kernels
     from cyberfabric_core_tpu.ops.rope import rope_frequencies
     from cyberfabric_core_tpu.parallel.feasibility import V5E_HBM_BYTES
@@ -300,6 +300,7 @@ def test_scheduler_programs_compile_for_v5e_at_mistral_7b(tp):
         quantization="int8", prefix_cache_pages=_N_PAGES,
         prefix_page_size=_PAGE, tp=tp)
     eng.model_config, eng.dtype, eng.paged = cfg, jnp.bfloat16, True
+    eng._model, eng._has_state = decoder_module(cfg), False
     eng.spec_k, eng._spec_w = 0, 1
     eng.rope_tables = rope_frequencies(
         cfg.head_dim, max(cfg.max_position, max_seq), cfg.rope_theta)
@@ -353,3 +354,105 @@ def test_scheduler_programs_compile_for_v5e_at_mistral_7b(tp):
         assert mem.alias_size_in_bytes >= 2 * np.prod(
             pool.sharding.shard_shape(pool.shape)) * 2, "pools not donated"
         assert live < V5E_HBM_BYTES, (name, live)
+
+
+@slow
+def test_scheduler_programs_compile_for_v5e_at_falcon_h1():
+    """The scheduler's own ``paged_decode_chunk`` and ``mixed_step`` for
+    falcon-h1-34b-16l int8 at the benchmark cell's shape (16 slots of 2048,
+    641 pages, 16 snapshot rows), on one described chip: each holds the
+    ``ssm_state_update`` Mosaic call or the chunked form, donates the state
+    slab with the pools, fits the 15.75 GiB the compiler budgets, and copies
+    nothing the size of the slab. A compile, not a chip run."""
+    from jax.sharding import SingleDeviceSharding
+
+    from cyberfabric_core_tpu.models import decoder_module, get_config
+    from cyberfabric_core_tpu.ops.platform import compiled_kernels
+    from cyberfabric_core_tpu.ops.rope import rope_frequencies
+    from cyberfabric_core_tpu.parallel.feasibility import V5E_HBM_BYTES
+    from cyberfabric_core_tpu.parallel.sharding import abstract_params
+    from cyberfabric_core_tpu.runtime.engine import EngineConfig
+    from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
+
+    topo = _topo_or_skip()
+    n, max_seq, pages, rows = 16, 2048, 641, 32
+    cfg = get_config("falcon-h1-34b-16l")
+    eng = object.__new__(ContinuousBatchingEngine)
+    eng.config = EngineConfig(
+        model=cfg.name, max_seq_len=max_seq, max_batch=n, decode_chunk=8,
+        quantization="int8", prefix_cache_pages=pages, prefix_page_size=_PAGE)
+    eng.model_config, eng.dtype, eng.paged = cfg, jnp.bfloat16, True
+    eng._model, eng._has_state = decoder_module(cfg), True
+    eng.spec_k, eng._spec_w = 0, 1
+    eng.mesh = eng._attn_mesh = None
+    eng.rope_tables = rope_frequencies(cfg.head_dim, max_seq, cfg.rope_theta)
+    here = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=here)
+
+    params = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                          abstract_params(cfg, jnp.bfloat16, "int8"))
+    with compiled_kernels():
+        eng._build_programs()
+
+    def row(dtype):
+        return sds((n,), dtype)
+
+    i32, f32 = jnp.int32, jnp.float32
+    pool = sds((cfg.num_layers, pages, _PAGE, cfg.num_kv_heads * cfg.head_dim),
+               jnp.bfloat16)
+    state = {"ssm": sds((cfg.num_layers, rows, cfg.ssm_heads,
+                         cfg.ssm_head_dim, cfg.ssm_state), f32),
+             "conv": sds((cfg.num_layers, rows, cfg.ssm_conv - 1,
+                          cfg.ssm_conv_dim), f32)}
+    slab_bytes = int(np.prod(state["ssm"].shape)) * 4
+    table, keys = sds((n, max_seq // _PAGE), i32), sds((n, 2), jnp.uint32)
+    stops = sds((n, eng.config.device_stop_width), i32)
+    sampling = (row(f32), row(f32), row(i32))
+
+    def mixed(width):
+        return (eng._mixed_step_fn, (
+            params, pool, pool, state, table, sds((n, width), i32), row(i32),
+            row(i32), row(i32), row(i32), row(bool), row(bool), row(bool),
+            row(bool), row(i32), stops, row(i32), keys, *sampling))
+
+    programs = {
+        "paged_decode_chunk": (eng._paged_decode_fn, (
+            params, pool, pool, state, table, row(i32), row(i32), row(bool),
+            row(bool), stops, row(i32), keys, *sampling)),
+        "mixed_step@64": mixed(64), "mixed_step@512": mixed(512),
+    }
+    import re
+
+    for name, (fn, args) in programs.items():
+        with compiled_kernels():
+            compiled = fn.lower(*args).compile()
+        mem = compiled.memory_analysis()
+        live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+        print(f"{name}: argument {mem.argument_size_in_bytes / 1e9:.2f} output "
+              f"{mem.output_size_in_bytes / 1e9:.2f} aliased "
+              f"{mem.alias_size_in_bytes / 1e9:.2f} temp "
+              f"{mem.temp_size_in_bytes / 1e9:.2f} GB")
+        text = compiled.as_text()
+        if os.environ.get("AOT_DUMP_DIR"):
+            Path(os.environ["AOT_DUMP_DIR"], f"{name}.hlo.txt").write_text(text)
+        assert "tpu_custom_call" in text, name
+        if name == "paged_decode_chunk":
+            assert "ssm_state_update" in text
+        assert mem.alias_size_in_bytes >= slab_bytes + 2 * int(
+            np.prod(pool.shape)) * 2, "pools and state slab not donated"
+        assert live < V5E_HBM_BYTES, (name, live)
+        # nothing the size of the slab is copied, sliced or reshaped: every
+        # instruction that yields a whole-slab array is a parameter, the
+        # loop's tuple plumbing, the kernel itself or an in-place update
+        slab = "f32[%s]" % ",".join(map(str, state["ssm"].shape))
+        for line in text.splitlines():
+            m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = " + re.escape(slab)
+                         + r"\S* (\w[\w\-]*)\(", line)
+            if m:
+                assert m.group(1) in (
+                    "parameter", "get-tuple-element", "custom-call",
+                    "dynamic-update-slice", "fusion", "while", "bitcast",
+                    "tuple"), (name, line[:200])

@@ -97,9 +97,11 @@ def _tiny_step_inputs(seed=0):
 
 @pytest.mark.parametrize("step", ["decode", "mixed"])
 def test_5d_pool_entry_is_bitwise_the_merged_path(step):
-    """benchmark/correctness.py hands forward_paged_* a [L, N, page, Hkv, D]
-    pool: it is merged once on entry and comes back 5-D, and hidden states
-    and pool are bit for bit what the merged path gives."""
+    """benchmark/adapters/llama.py (the llama family's binding, which the
+    judge benchmark/correctness.py drives) hands forward_paged_* a
+    [L, N, page, Hkv, D] pool: it is merged once on entry and comes back 5-D,
+    and hidden states and pool are bit for bit what the merged path gives.
+    (benchmark/adapters/falcon_h1.py builds merged pools.)"""
     cfg, rope, params, rng, pools5, table = _tiny_step_inputs()
     pools4 = tuple(p.reshape(*p.shape[:3], -1) for p in pools5)
     B = table.shape[0]
