@@ -45,9 +45,11 @@ from ..ops.ssd import (causal_conv, causal_conv_step, ssd_chunked,
                        ssm_state_update)
 from . import llama
 from .configs import ModelConfig
-from .llama import (PagedPools, Params, _attn_out, _embed_scale, _mlp_residual,
-                    _qkv_proj, _scaled, _wmat, embed_lookup,
-                    gather_last_hidden, lm_head_logits)
+from .llama import (DecodeGroup, PagedPools, Params, _attn_out,
+                    _decode_attend, _decode_targets, _embed_scale,
+                    _mlp_residual, _qkv_proj, _ragged_attend, _scaled, _wmat,
+                    embed_lookup, gather_last_hidden, lm_head_logits,
+                    mixed_attention, mixed_hidden_out, mixed_layout)
 
 __all__ = ["init_params", "init_mixer_small", "init_state",
            "forward_paged_decode", "forward_paged_mixed", "lm_head_logits",
@@ -164,6 +166,30 @@ def _store_rows(slab: jnp.ndarray, layer, rows: jnp.ndarray) -> jnp.ndarray:
         slab, rows[None], (layer,) + (0,) * (slab.ndim - 1))
 
 
+def _lane_rows(slab: jnp.ndarray, layer, rows, n: int) -> jnp.ndarray:
+    """The ``n`` lanes' rows of one layer of a slab, ``[n, ...]``: rows
+    ``[:n]`` where ``rows`` is None (lane r = row r), else the named rows,
+    each a row-sized slice. Either way what is read is the lanes' size,
+    never a layer of the slab."""
+    if rows is None:
+        return _layer_rows(slab, layer, n)
+    tail = (0,) * (slab.ndim - 2)
+    return jnp.concatenate([
+        jax.lax.dynamic_slice(slab, (layer, rows[r]) + tail,
+                              (1, 1) + slab.shape[2:])[0] for r in range(n)])
+
+
+def _store_lane_rows(slab: jnp.ndarray, layer, rows, new: jnp.ndarray
+                     ) -> jnp.ndarray:
+    if rows is None:
+        return _store_rows(slab, layer, new)
+    tail = (0,) * (slab.ndim - 2)
+    for r in range(new.shape[0]):
+        slab = jax.lax.dynamic_update_slice(
+            slab, new[r][None, None], (layer, rows[r]) + tail)
+    return slab
+
+
 def _one_device(mesh: Any, interpret: bool | None) -> bool:
     """The forwards' shared entry checks; returns ``interpret`` resolved."""
     if mesh is not None:
@@ -176,6 +202,26 @@ def _attn_in(x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     if cfg.attention_in_multiplier != 1.0:
         return x * jnp.asarray(cfg.attention_in_multiplier, x.dtype)
     return x
+
+
+def _mixer_step(lp: dict, layer, cfg: ModelConfig, u, dt, ssm, conv,
+                run, act, kernel: bool):
+    """One token of rows ``[:B]`` of the slab (a decode step; a mixed step's
+    decode group): ``u`` [B, C] and ``dt`` [B, Hs] f32 from the input
+    projection. The conv tail moves with a row-sized slice, the state in
+    place under the ``ssm_state_update`` kernel; rows with ``run`` False keep
+    both bit for bit. Returns (y [B, d_ssm] f32, ssm, conv)."""
+    B = u.shape[0]
+    tail = _layer_rows(conv, layer, B)
+    xbc, new_tail = causal_conv_step(u, tail, lp["conv_w"], lp["conv_b"])
+    conv = _store_rows(conv, layer, jnp.where(
+        run[:, None, None], new_tail, tail))
+    xs_, b_mat, c_mat = _split_xbc(jax.nn.silu(xbc).astype(act), cfg)
+    y, ssm = ssm_state_update(
+        ssm, layer, xs_, jax.nn.softplus(dt + lp["dt_bias"]),
+        -jnp.exp(lp["A_log"]), b_mat, c_mat, run, kernel=kernel)
+    y = y + lp["D"][:, None] * xs_.astype(jnp.float32)
+    return y.reshape(B, -1), ssm, conv
 
 
 def forward_paged_decode(
@@ -197,20 +243,16 @@ def forward_paged_decode(
     the recurrent state of row ``b`` is read and written in place by the
     ``ssm_state_update`` kernel, and stays as it was where ``write_mask`` is
     False."""
-    from ..ops.paged_attention import paged_decode_attention
-
     interpret = _one_device(mesh, interpret)
     cos_t, sin_t = rope_tables
     B = input_ids.shape[0]
     Hq, D = cfg.num_heads, cfg.head_dim
-    page_size = pools[0].shape[2]
     positions = lengths[:, None]
     if write_mask is None:
         write_mask = jnp.ones((B,), bool)
-    idx_page = lengths // page_size
-    pid = jnp.take_along_axis(page_table, idx_page[:, None], axis=1)[:, 0]
-    pid = jnp.where(write_mask, pid, 0)
-    off = jnp.where(write_mask, lengths % page_size, 0)
+    pid, off = _decode_targets(page_table, lengths, write_mask,
+                               pools[0].shape[2])
+    attend = _decode_attend(cfg, interpret, None)
 
     h = _embed_scale(embed_lookup(params["embed"], input_ids,
                                   params["final_norm"].dtype), cfg)
@@ -225,22 +267,12 @@ def forward_paged_decode(
             kproj.reshape(B, -1).astype(k_pool.dtype))
         v_pool = v_pool.at[layer, pid, off].set(
             vproj.reshape(B, -1).astype(v_pool.dtype))
-        attn = paged_decode_attention(q[:, 0], k_pool, v_pool, page_table,
-                                      lengths + 1, layer, interpret=interpret)
+        attn = attend(q[:, 0], k_pool, v_pool, page_table, lengths + 1, layer)
 
         z, u, dt = _mixer_in(lp, x, cfg)
-        tail = _layer_rows(conv, layer, B)
-        xbc, new_tail = causal_conv_step(u[:, 0], tail, lp["conv_w"],
-                                         lp["conv_b"])
-        conv = _store_rows(conv, layer, jnp.where(
-            write_mask[:, None, None], new_tail, tail))
-        xs_, b_mat, c_mat = _split_xbc(jax.nn.silu(xbc).astype(h.dtype), cfg)
-        y, ssm = ssm_state_update(
-            ssm, layer, xs_, jax.nn.softplus(dt[:, 0] + lp["dt_bias"]),
-            -jnp.exp(lp["A_log"]), b_mat, c_mat, write_mask,
-            kernel=not interpret)
-        y = y + lp["D"][:, None] * xs_.astype(jnp.float32)
-        o_s = _mixer_out(lp, y.reshape(B, 1, -1), z, cfg, h.dtype)
+        y, ssm, conv = _mixer_step(lp, layer, cfg, u[:, 0], dt[:, 0], ssm,
+                                   conv, write_mask, h.dtype, not interpret)
+        o_s = _mixer_out(lp, y[:, None], z, cfg, h.dtype)
 
         h = _attn_out(lp, h, attn.reshape(B, 1, Hq * D),
                       cfg.attention_out_multiplier) + o_s
@@ -257,83 +289,94 @@ def forward_paged_decode(
 def forward_paged_mixed(
     params: Params,
     cfg: ModelConfig,
-    input_ids: jnp.ndarray,    # [B, Qmax]
+    input_ids: jnp.ndarray,    # [R, Qc]
     pools: PagedPools,
-    page_table: jnp.ndarray,
-    hist: jnp.ndarray,         # [B] tokens BEFORE each row's span
-    q_lens: jnp.ndarray,       # [B] span length (0 = idle row)
+    page_table: jnp.ndarray,   # [B, Pmax]
+    hist: jnp.ndarray,         # [R] tokens BEFORE each lane's span
+    q_lens: jnp.ndarray,       # [R] span length (0 = idle lane)
     rope_tables: tuple[jnp.ndarray, jnp.ndarray],
     interpret: bool | None = None,
     write_mask: jnp.ndarray | None = None,
     mesh: Any = None,
     *,
+    rows: jnp.ndarray | None = None,
+    decode: DecodeGroup | None = None,
     state: State,
 ) -> tuple[jnp.ndarray, PagedPools, State]:
-    """One ragged mixed step. Returns (hidden [B, Qmax, H], pools, state).
-    K/V as ``llama.forward_paged_mixed``. A row's recurrent state advances by
-    its ``q_len`` tokens through the chunked form, from the zero state where
-    its history is 0 and from its slab row otherwise; rows with ``q_len`` 0
-    or ``write_mask`` False keep state and conv tail bit for bit."""
-    from ..ops.paged_attention import ragged_paged_attention
-
+    """One ragged mixed step over the tokens it has. Returns (hidden, pools,
+    state); lanes, the decode group, K/V and ``hidden`` as
+    ``llama.forward_paged_mixed``. A lane's slot advances its recurrent state
+    by the lane's ``q_len`` tokens through the chunked form, from the zero
+    state where its history is 0 and from its slab row otherwise, and only
+    the lanes' rows are read and written; a lane with ``q_len`` 0 or
+    ``write_mask`` False keeps state and conv tail bit for bit. A decode
+    group's rows advance by one token as in :func:`forward_paged_decode`.
+    Every other row of the slab is not touched."""
     interpret = _one_device(mesh, interpret)
     cos_t, sin_t = rope_tables
-    B, Qmax = input_ids.shape
-    Hq, D = cfg.num_heads, cfg.head_dim
-    page_size = pools[0].shape[2]
-    if write_mask is None:
-        write_mask = jnp.ones((B,), bool)
-
-    offs = jnp.arange(Qmax, dtype=jnp.int32)[None, :]
-    valid = (offs < q_lens[:, None]) & write_mask[:, None]
-    positions = jnp.where(valid, hist[:, None] + offs, 0)
-    pid = jnp.where(
-        valid,
-        jnp.take_along_axis(page_table, positions // page_size, axis=1), 0)
-    off = jnp.where(valid, positions % page_size, 0)
-    advance = write_mask & (q_lens > 0)          # rows whose state moves
-    fresh = (hist == 0)[:, None, None]           # rows that start from zero
+    R, Qc = input_ids.shape
+    lay = mixed_layout(input_ids, page_table, hist, q_lens, write_mask, rows,
+                       decode, pools[0].shape[2])
+    nd = lay.n_dec
+    lane_attend = _ragged_attend(cfg, interpret, None)
+    decode_attend = _decode_attend(cfg, interpret, None)
+    advance = lay.lane_valid                     # lanes whose state moves
+    fresh = (hist == 0)[:, None, None]           # lanes that start from zero
     span = jnp.where(advance, q_lens, 0)
 
-    h = _embed_scale(embed_lookup(params["embed"], input_ids,
+    h = _embed_scale(embed_lookup(params["embed"], lay.ids,
                                   params["final_norm"].dtype), cfg)
 
     def layer_body(carry, xs):
         h, k_pool, v_pool, ssm, conv = carry
         lp, layer = xs
         x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
-        q, kproj, vproj = _qkv_proj(lp, _attn_in(x, cfg), cfg, positions,
+        q, kproj, vproj = _qkv_proj(lp, _attn_in(x, cfg), cfg, lay.positions,
                                     cos_t, sin_t)
-        k_pool = k_pool.at[layer, pid, off].set(
-            kproj.reshape(B, Qmax, -1).astype(k_pool.dtype))
-        v_pool = v_pool.at[layer, pid, off].set(
-            vproj.reshape(B, Qmax, -1).astype(v_pool.dtype))
-        attn = ragged_paged_attention(q, k_pool, v_pool, page_table, hist,
-                                      q_lens, layer, interpret=interpret)
+        n = lay.pid.shape[0]
+        k_pool = k_pool.at[layer, lay.pid, lay.off].set(
+            kproj.reshape(n, -1).astype(k_pool.dtype))
+        v_pool = v_pool.at[layer, lay.pid, lay.off].set(
+            vproj.reshape(n, -1).astype(v_pool.dtype))
+        attn = mixed_attention(lay, q, k_pool, v_pool, page_table, hist,
+                               q_lens, decode, layer, lane_attend,
+                               decode_attend)
 
+        # the mixer: one input and one output projection over all tokens,
+        # split only around the recurrence — the decode group's step first,
+        # then the lanes' chunk on their own rows of the slab
         z, u, dt = _mixer_in(lp, x, cfg)
-        tail = _layer_rows(conv, layer, B)
-        s_old = _layer_rows(ssm, layer, B)
-        xbc, new_tail = causal_conv(u, jnp.where(fresh, 0.0, tail),
-                                    lp["conv_w"], lp["conv_b"], span)
+        ys = []
+        if nd:
+            y_dec, ssm, conv = _mixer_step(
+                lp, layer, cfg, u[0, :nd], dt[0, :nd], ssm, conv, decode.run,
+                h.dtype, not interpret)
+            ys.append(y_dec)
+        tail = _lane_rows(conv, layer, rows, R)
+        s_old = _lane_rows(ssm, layer, rows, R)
+        xbc, new_tail = causal_conv(
+            u[0, nd:].reshape(R, Qc, -1), jnp.where(fresh, 0.0, tail),
+            lp["conv_w"], lp["conv_b"], span)
         xs_, b_mat, c_mat = _split_xbc(jax.nn.silu(xbc).astype(h.dtype), cfg)
         y, s_new = ssd_chunked(
-            xs_, jax.nn.softplus(dt + lp["dt_bias"]), -jnp.exp(lp["A_log"]),
-            b_mat, c_mat, lp["D"], jnp.where(fresh[..., None], 0.0, s_old),
-            span, cfg.ssm_chunk)
-        conv = _store_rows(conv, layer, jnp.where(
+            xs_, jax.nn.softplus(dt[0, nd:].reshape(R, Qc, -1)
+                                 + lp["dt_bias"]),
+            -jnp.exp(lp["A_log"]), b_mat, c_mat, lp["D"],
+            jnp.where(fresh[..., None], 0.0, s_old), span, cfg.ssm_chunk)
+        conv = _store_lane_rows(conv, layer, rows, jnp.where(
             advance[:, None, None], new_tail, tail))
-        ssm = _store_rows(ssm, layer, jnp.where(
+        ssm = _store_lane_rows(ssm, layer, rows, jnp.where(
             advance[:, None, None, None], s_new, s_old))
-        o_s = _mixer_out(lp, y.reshape(B, Qmax, -1), z, cfg, h.dtype)
+        ys.append(y.reshape(R * Qc, -1))
+        o_s = _mixer_out(lp, jnp.concatenate(ys)[None], z, cfg, h.dtype)
 
-        h = _attn_out(lp, h, attn.reshape(B, Qmax, Hq * D),
-                      cfg.attention_out_multiplier) + o_s
+        h = _attn_out(lp, h, attn, cfg.attention_out_multiplier) + o_s
         h = _mlp_residual(lp, h, cfg)
         return (h, k_pool, v_pool, ssm, conv), None
 
     (h, k_pool, v_pool, ssm, conv), _ = jax.lax.scan(
         layer_body, (h, *pools, state["ssm"], state["conv"]),
         (params["layers"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))
+    h = mixed_hidden_out(lay, h, q_lens, rows)
     h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
     return h, (k_pool, v_pool), {"ssm": ssm, "conv": conv}

@@ -19,7 +19,7 @@ safetensors format).
 
 from __future__ import annotations
 
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -398,6 +398,65 @@ def _shard_mapped_attn(mesh, kernel_fn, q_spec, tail_specs):
         out_specs=q_spec, check_vma=False)
 
 
+class DecodeGroup(NamedTuple):
+    """The decode rows of a mixed step: every slot's one token, beside the
+    lane's chunk (``forward_paged_mixed``). Row ``b`` is slot ``b``."""
+    tokens: jnp.ndarray    # [B] int32 each slot's last token
+    lengths: jnp.ndarray   # [B] int32 valid length BEFORE this token
+    run: jnp.ndarray       # [B] bool; False rows write to scratch, keep state
+
+
+def _decode_attend(cfg: ModelConfig, interpret: bool, mesh):
+    """``attend(q [B, Hq, D], k_pool, v_pool, page_table, lengths, layer)``
+    over the stacked pools. The kernel takes the pools whole and picks the
+    layer in its index map: a ``k_pool[layer]`` in front of it would
+    materialise 1/L of the pool."""
+    from ..ops.paged_attention import paged_decode_attention
+
+    def attend(qq, kk, vv, pt, ln, ly):
+        return paged_decode_attention(
+            qq, kk, vv, pt, ln, ly, interpret=interpret,
+            sliding_window=cfg.sliding_window)
+
+    if mesh is None:
+        return attend
+    from jax.sharding import PartitionSpec as P
+
+    return _shard_mapped_attn(
+        mesh, attend, P(None, "tp", None), (P(None, None), P(None)))
+
+
+def _ragged_attend(cfg: ModelConfig, interpret: bool, mesh):
+    """``attend(q [R, Qc, Hq, D], k_pool, v_pool, page_table [R, Pmax], hist,
+    q_lens, layer)``: the ragged kernel, as :func:`_decode_attend`."""
+    from ..ops.paged_attention import ragged_paged_attention
+
+    def attend(qq, kk, vv, pt, hh, ql, ly):
+        return ragged_paged_attention(
+            qq, kk, vv, pt, hh, ql, ly, interpret=interpret,
+            sliding_window=cfg.sliding_window)
+
+    if mesh is None:
+        return attend
+    from jax.sharding import PartitionSpec as P
+
+    return _shard_mapped_attn(
+        mesh, attend, P(None, None, "tp", None),
+        (P(None, None), P(None), P(None)))
+
+
+def _decode_targets(page_table, lengths, write_mask, page_size: int):
+    """Where each slot's one new token is written: (page id, offset), [B]
+    each; rows with ``write_mask`` False target scratch page 0."""
+    pid = jnp.take_along_axis(
+        page_table, (lengths // page_size)[:, None], axis=1)[:, 0]
+    off = lengths % page_size
+    if write_mask is not None:
+        pid = jnp.where(write_mask, pid, 0)
+        off = jnp.where(write_mask, off, 0)
+    return pid, off
+
+
 def forward_paged_decode(
     params: Params,
     cfg: ModelConfig,
@@ -425,8 +484,6 @@ def forward_paged_decode(
     kernel compiles as a real Mosaic call (GSPMD cannot auto-partition it);
     on interpret backends it is an equivalent, bit-identical partitioning.
     """
-    from ..ops.paged_attention import paged_decode_attention
-
     if interpret is None:
         interpret = _default_interpret()
     cos_t, sin_t = rope_tables
@@ -435,13 +492,8 @@ def forward_paged_decode(
     pools, caller_shape = _merged_pools(pools)
     page_size = pools[0].shape[2]
     positions = lengths[:, None]
-
-    idx_page = lengths // page_size
-    pid = jnp.take_along_axis(page_table, idx_page[:, None], axis=1)[:, 0]
-    off = lengths % page_size
-    if write_mask is not None:
-        pid = jnp.where(write_mask, pid, 0)
-        off = jnp.where(write_mask, off, 0)
+    pid, off = _decode_targets(page_table, lengths, write_mask, page_size)
+    attend = _decode_attend(cfg, interpret, mesh)
 
     h = _embed_scale(embed_lookup(params["embed"], input_ids, params["final_norm"].dtype), cfg)
 
@@ -461,19 +513,6 @@ def forward_paged_decode(
             kproj.reshape(B, -1).astype(k_pool.dtype))
         v_pool = v_pool.at[layer, pid, off].set(
             vproj.reshape(B, -1).astype(v_pool.dtype))
-
-        # the kernel takes the stacked pools whole and picks the layer in its
-        # index map: a ``k_pool[layer]`` here would materialise 1/L of the pool
-        def attend(qq, kk, vv, pt, ln, ly):
-            return paged_decode_attention(
-                qq, kk, vv, pt, ln, ly, interpret=interpret,
-                sliding_window=cfg.sliding_window)
-
-        if mesh is not None:
-            from jax.sharding import PartitionSpec as P
-
-            attend = _shard_mapped_attn(
-                mesh, attend, P(None, "tp", None), (P(None, None), P(None)))
         attn = attend(q[:, 0], k_pool, v_pool, page_table, lengths + 1, layer)
         h = _attn_out(lp, h, attn.reshape(B, 1, Hq * D))
         h = _mlp_residual(lp, h, cfg)
@@ -487,82 +526,158 @@ def forward_paged_decode(
     return h, _restore_pools((k_pool, v_pool), caller_shape)
 
 
-def forward_paged_mixed(
-    params: Params,
-    cfg: ModelConfig,
-    input_ids: jnp.ndarray,    # [B, Qmax] int32 — per-row query span, padded
-    pools: PagedPools,
-    page_table: jnp.ndarray,   # [B, Pmax] int32 physical page ids per slot
-    hist: jnp.ndarray,         # [B] int32 kv tokens BEFORE each row's span
-    q_lens: jnp.ndarray,       # [B] int32 span length (0 = idle row)
-    rope_tables: tuple[jnp.ndarray, jnp.ndarray],
-    interpret: bool | None = None,
-    write_mask: jnp.ndarray | None = None,  # [B] bool; False rows → scratch
-    mesh=None,
-) -> tuple[jnp.ndarray, PagedPools]:
-    """One ragged mixed-batch step over the paged KV pool: decode rows
-    (q_len=1) and chunked-prefill rows (q_len=chunk) in one dispatch.
-    Returns (hidden [B, Qmax, H], pools). ``mesh``: see
-    :func:`forward_paged_decode` — shard_map over the tp head axis.
+class MixedLayout(NamedTuple):
+    """The token layout of one mixed step: the decode group's ``n_dec``
+    tokens (0 where there is no group) ahead of the lane's ``R x Qc``, as one
+    row of ``n_dec + R*Qc`` tokens, so that every matmul of a layer runs once
+    over the tokens the step has and reads its weight once."""
+    n_dec: int
+    lanes: tuple[int, int]     # (R, Qc)
+    ids: jnp.ndarray           # [1, N] int32
+    positions: jnp.ndarray     # [1, N] int32
+    pid: jnp.ndarray           # [N] page each token's k/v is written to
+    off: jnp.ndarray           # [N] its offset in that page
+    lane_table: jnp.ndarray    # [R, Pmax] the lanes' rows of the page table
+    lane_valid: jnp.ndarray    # [R] bool: the lane has tokens and may write
 
-    Row b's span tokens land at absolute positions hist[b] .. hist[b]+q_len-1
-    of its page chain (a chunk may cross page boundaries — per-token page
-    resolution); attention runs the ragged paged kernel, causal relative to
-    each row's own history. Padding positions scatter to scratch page 0 and
-    produce garbage hidden states that nothing downstream reads.
-    ``write_mask`` rows marked False (frozen by device-side termination)
-    scatter to scratch page 0 as padding does.
-    """
-    from ..ops.paged_attention import ragged_paged_attention
 
-    if interpret is None:
-        interpret = _default_interpret()
-    cos_t, sin_t = rope_tables
-    B, Qmax = input_ids.shape
-    Hq, D = cfg.num_heads, cfg.head_dim
-    pools, caller_shape = _merged_pools(pools)
-    page_size = pools[0].shape[2]
-
-    offs = jnp.arange(Qmax, dtype=jnp.int32)[None, :]          # [1, Qmax]
-    valid = offs < q_lens[:, None]                             # [B, Qmax]
+def mixed_layout(input_ids, page_table, hist, q_lens, write_mask, rows,
+                 decode: DecodeGroup | None, page_size: int) -> MixedLayout:
+    """Lay a mixed step's tokens out (see :func:`forward_paged_mixed`)."""
+    R, Qc = input_ids.shape
+    lane_table = page_table if rows is None else page_table[rows]
+    offs = jnp.arange(Qc, dtype=jnp.int32)[None, :]            # [1, Qc]
+    valid = offs < q_lens[:, None]                             # [R, Qc]
     if write_mask is not None:
         valid = valid & write_mask[:, None]
     positions = jnp.where(valid, hist[:, None] + offs, 0)
     # per-token write targets; padding targets scratch page 0 (harmless)
     pid = jnp.where(
         valid,
-        jnp.take_along_axis(page_table, positions // page_size, axis=1), 0)
+        jnp.take_along_axis(lane_table, positions // page_size, axis=1), 0)
     off = jnp.where(valid, positions % page_size, 0)
+    ids, positions = input_ids.reshape(-1), positions.reshape(-1)
+    pid, off = pid.reshape(-1), off.reshape(-1)
+    n_dec = 0
+    if decode is not None:
+        n_dec = decode.tokens.shape[0]
+        d_pid, d_off = _decode_targets(page_table, decode.lengths, decode.run,
+                                       page_size)
+        ids = jnp.concatenate([decode.tokens, ids])
+        positions = jnp.concatenate([decode.lengths, positions])
+        pid = jnp.concatenate([d_pid, pid])
+        off = jnp.concatenate([d_off, off])
+    return MixedLayout(n_dec, (R, Qc), ids[None], positions[None], pid, off,
+                       lane_table, valid[:, 0])
 
-    h = _embed_scale(embed_lookup(params["embed"], input_ids,
+
+def mixed_attention(lay: MixedLayout, q, k_pool, v_pool, page_table, hist,
+                    q_lens, decode: DecodeGroup | None, layer,
+                    lane_attend, decode_attend) -> jnp.ndarray:
+    """The one place a mixed step's token row is split: ``q`` [1, N, Hq, D]
+    → attention output [1, N, Hq*D]. The lane goes through the ragged kernel
+    on its own rows of the page table, the decode group through the decode
+    kernel, each after the step's k/v is in the pool."""
+    R, Qc = lay.lanes
+    nd = lay.n_dec
+    lane = lane_attend(q[0, nd:].reshape(R, Qc, *q.shape[2:]), k_pool, v_pool,
+                       lay.lane_table, hist, q_lens, layer)
+    lane = lane.reshape(1, R * Qc, -1)
+    if not nd:
+        return lane
+    dec = decode_attend(q[0, :nd], k_pool, v_pool, page_table,
+                        decode.lengths + 1, layer)
+    return jnp.concatenate([dec.reshape(1, nd, -1), lane], axis=1)
+
+
+def mixed_hidden_out(lay: MixedLayout, h: jnp.ndarray, q_lens, rows):
+    """What a mixed step hands back of ``h`` [1, N, H]: the lanes' hidden
+    ``[R, Qc, H]`` where there is no decode group; with one, the ``[B, H]``
+    rows the head needs — a slot's decode row, or its lane's last position
+    where a lane with tokens names it."""
+    R, Qc = lay.lanes
+    nd = lay.n_dec
+    lane = h[0, nd:].reshape(R, Qc, -1)
+    if not nd:
+        return lane
+    dec = h[0, :nd]
+    if rows is None:
+        rows = jnp.arange(R, dtype=jnp.int32)
+    last = gather_last_hidden(lane, q_lens)
+    return dec.at[rows].set(
+        jnp.where((q_lens > 0)[:, None], last, dec[rows]))
+
+
+def forward_paged_mixed(
+    params: Params,
+    cfg: ModelConfig,
+    input_ids: jnp.ndarray,    # [R, Qc] int32 — per-lane query span, padded
+    pools: PagedPools,
+    page_table: jnp.ndarray,   # [B, Pmax] int32 physical page ids per slot
+    hist: jnp.ndarray,         # [R] int32 kv tokens BEFORE each lane's span
+    q_lens: jnp.ndarray,       # [R] int32 span length (0 = idle lane)
+    rope_tables: tuple[jnp.ndarray, jnp.ndarray],
+    interpret: bool | None = None,
+    write_mask: jnp.ndarray | None = None,  # [R] bool; False lanes → scratch
+    mesh=None,
+    *,
+    rows: jnp.ndarray | None = None,        # [R] int32 each lane's slot
+    decode: DecodeGroup | None = None,
+) -> tuple[jnp.ndarray, PagedPools]:
+    """One ragged mixed-batch step over the paged KV pool, computed over the
+    tokens it has. ``mesh``: see :func:`forward_paged_decode`.
+
+    **Lanes.** Lane ``r`` carries a span of ``q_lens[r]`` tokens of slot
+    ``rows[r]`` (distinct slots); ``rows=None`` is the all-rows call, lane
+    ``r`` = slot ``r`` (``R = B``): what a caller with ragged spans on many
+    rows wants (speculative spans, a reference comparison). The span's tokens
+    land at absolute positions hist[r] .. hist[r]+q_len-1 of the slot's page
+    chain (a chunk may cross page boundaries — per-token page resolution);
+    attention runs the ragged paged kernel on the lanes' rows of the page
+    table, causal relative to each lane's own history. Padding positions
+    scatter to scratch page 0 and produce garbage hidden states that nothing
+    downstream reads; so does a lane whose ``write_mask`` is False.
+
+    **Decode group.** With ``decode``, every slot's one token
+    (:class:`DecodeGroup`) rides the same pass, attended by the decode kernel
+    exactly as :func:`forward_paged_decode` does. Inside a layer the group's
+    ``B`` tokens and the lanes' ``R*Qc`` are one row of ``B + R*Qc``, so each
+    weight is read once and the work is the tokens', not ``B x Qc``. A slot
+    should not both run in the group and have a lane with tokens.
+
+    Returns (hidden, pools): without a decode group the lanes' hidden
+    ``[R, Qc, H]``; with one, ``[B, H]`` — each slot's decode row, or the
+    last position of the lane that names it (what the head reads).
+    """
+    if interpret is None:
+        interpret = _default_interpret()
+    cos_t, sin_t = rope_tables
+    pools, caller_shape = _merged_pools(pools)
+    lay = mixed_layout(input_ids, page_table, hist, q_lens, write_mask, rows,
+                       decode, pools[0].shape[2])
+    lane_attend = _ragged_attend(cfg, interpret, mesh)
+    decode_attend = _decode_attend(cfg, interpret, mesh)
+
+    h = _embed_scale(embed_lookup(params["embed"], lay.ids,
                                   params["final_norm"].dtype), cfg)
 
     def layer_body(carry, xs):
         h, k_pool, v_pool = carry
         lp, layer = xs
         x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_weight_offset)
-        q, kproj, vproj = _qkv_proj(lp, x, cfg, positions, cos_t, sin_t)
+        q, kproj, vproj = _qkv_proj(lp, x, cfg, lay.positions, cos_t, sin_t)
 
-        # scatter the span's k/v BEFORE attending: within-span causality then
+        # scatter the step's k/v BEFORE attending: within-span causality then
         # reads the chunk's earlier tokens back through the page chain
-        k_pool = k_pool.at[layer, pid, off].set(
-            kproj.reshape(B, Qmax, -1).astype(k_pool.dtype))
-        v_pool = v_pool.at[layer, pid, off].set(
-            vproj.reshape(B, Qmax, -1).astype(v_pool.dtype))
-
-        def attend(qq, kk, vv, pt, hh, ql, ly):
-            return ragged_paged_attention(
-                qq, kk, vv, pt, hh, ql, ly, interpret=interpret,
-                sliding_window=cfg.sliding_window)
-
-        if mesh is not None:
-            from jax.sharding import PartitionSpec as P
-
-            attend = _shard_mapped_attn(
-                mesh, attend, P(None, None, "tp", None),
-                (P(None, None), P(None), P(None)))
-        attn = attend(q, k_pool, v_pool, page_table, hist, q_lens, layer)
-        h = _attn_out(lp, h, attn.reshape(B, Qmax, Hq * D))
+        n = lay.pid.shape[0]
+        k_pool = k_pool.at[layer, lay.pid, lay.off].set(
+            kproj.reshape(n, -1).astype(k_pool.dtype))
+        v_pool = v_pool.at[layer, lay.pid, lay.off].set(
+            vproj.reshape(n, -1).astype(v_pool.dtype))
+        attn = mixed_attention(lay, q, k_pool, v_pool, page_table, hist,
+                               q_lens, decode, layer, lane_attend,
+                               decode_attend)
+        h = _attn_out(lp, h, attn)
         h = _mlp_residual(lp, h, cfg)
         return (h, k_pool, v_pool), None
 
@@ -570,6 +685,7 @@ def forward_paged_mixed(
     (h, k_pool, v_pool), _ = jax.lax.scan(
         layer_body, (h, k_pool, v_pool),
         (params["layers"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))
+    h = mixed_hidden_out(lay, h, q_lens, rows)
     h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps, cfg.norm_weight_offset)
     return h, _restore_pools((k_pool, v_pool), caller_shape)
 

@@ -531,6 +531,17 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
             "into decode rounds (cumulative)"
         ).set_function(mixed_chunk_tokens)
 
+        def mixed_positions() -> float:
+            return float(sum(getattr(s, "mixed_positions", 0)
+                             for s in _schedulers()))
+
+        self.registry.gauge(
+            "llm_mixed_step_positions_total",
+            "Positions the mixed-batch dispatches computed (cumulative): a "
+            "lane step's decode rows plus its chunk's padded width; beside "
+            "llm_prefill_chunk_tokens_total it shows how much was padding"
+        ).set_function(mixed_positions)
+
         def queue_wait_p50_ms() -> float:
             waits: list[float] = []
             for sched in _schedulers():

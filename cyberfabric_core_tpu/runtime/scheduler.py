@@ -122,6 +122,11 @@ logger = logging.getLogger("scheduler")
 #: most rows of a rope table (the largest published context of the llama
 #: configurations served so far); the served window is always covered
 _ROPE_TABLE_ROWS = 32768
+#: lanes of a ``mixed_step``: one prefilling slot's chunk a step, so the
+#: compiled shape is keyed by the chunk's width alone (a warm-up that runs
+#: each width once has run every shape; two arrivals in one round take two
+#: steps, not a program of their own)
+LANE_ROWS = 1
 
 
 def _null_ctx():
@@ -809,6 +814,11 @@ class ContinuousBatchingEngine:
         self.mixed_rounds = 0
         self.prefill_chunks = 0
         self.chunked_prefill_tokens = 0
+        #: over mixed rounds: positions the dispatches computed, and the
+        #: tokens among them (chunk tokens + decode rows) — stats() gives
+        #: their ratio, the useful share of a mixed step
+        self.mixed_positions = 0
+        self.mixed_useful_tokens = 0
         self.occupancy_samples: "deque[int]" = deque(maxlen=1000)
         self.round_timings: "deque[dict]" = deque(maxlen=512)
         self.queue_wait_samples: "deque[float]" = deque(maxlen=2048)
@@ -995,16 +1005,20 @@ class ContinuousBatchingEngine:
                                             donate_argnums=donate)
 
             def mixed_step_body(params, caches, page_table, q_ids, q_lens,
-                                prefill_hist, last_tokens, lengths, active,
-                                finished, sample_mask, final_mask, final_lens,
-                                stop_ids, limit_lens, keys, temp, top_p,
-                                top_k):
-                """One ragged mixed-batch round: decode rows (q_len=1) take
-                their next token while prefill rows consume a prompt chunk —
-                one dispatch, no phase separation. ``sample_mask`` rows
-                (decode + final-chunk prefill) draw from their key stream;
-                everyone else's key is untouched, so a mid-prefill request's
-                seed reproduces exactly the phase-separated stream.
+                                prefill_hist, lane_rows, last_tokens, lengths,
+                                active, finished, sample_mask, final_mask,
+                                final_lens, stop_ids, limit_lens, keys, temp,
+                                top_p, top_k):
+                """One mixed-batch round over the tokens it has: every decode
+                row takes its next token (the decode group: ``last_tokens``,
+                ``lengths``, ``run``) while the lane — ``q_ids [R, Qc]``, the
+                chunk of the prefilling slot ``lane_rows`` names — consumes a
+                prompt chunk, in ONE pass over the weights of
+                ``B + R*Qc`` positions. The compiled shape is keyed by the
+                lane's width alone. ``sample_mask`` rows (decode +
+                final-chunk prefill) draw from their key stream; everyone
+                else's key is untouched, so a mid-prefill request's seed
+                reproduces exactly the phase-separated stream.
 
                 Device-side termination + ring spanning: sampled rows run the
                 same stop/limit/window checks as the decode chunk and fold
@@ -1014,15 +1028,11 @@ class ContinuousBatchingEngine:
                 dispatch when the prefill queue drains — the mixed→pure
                 transition needs no synchronous fallback round."""
                 run = active & jnp.logical_not(finished)
-                q_ids = q_ids.at[:, 0].set(
-                    jnp.where(active, last_tokens, q_ids[:, 0]))
-                hist = jnp.where(active, lengths, prefill_hist)
-                hidden, caches = paged_forward(
+                last_h, caches = paged_forward(
                     model.forward_paged_mixed, params, q_ids, caches,
-                    page_table, hist, q_lens, rope,
-                    write_mask=run | jnp.logical_not(active),
-                    mesh=self._attn_mesh)
-                last_h = model.gather_last_hidden(hidden, q_lens)
+                    page_table, prefill_hist, q_lens, rope,
+                    mesh=self._attn_mesh, rows=lane_rows,
+                    decode=llama.DecodeGroup(last_tokens, lengths, run))
                 logits = model.lm_head_logits(params, cfg, last_h)
                 keys2, subs = split_keys_per_slot(keys)
                 nxt = sample_token_per_slot(logits, subs, temp, top_p, top_k)
@@ -1961,6 +1971,11 @@ class ContinuousBatchingEngine:
             "mixed_rounds": self.mixed_rounds,
             "prefill_chunks": self.prefill_chunks,
             "chunked_prefill_tokens": self.chunked_prefill_tokens,
+            # what the mixed dispatches computed, and the share of it that
+            # was a token (a chunk's, or a decode row's): padding is the rest
+            "mixed_positions": self.mixed_positions,
+            "mixed_useful_share": round(
+                self.mixed_useful_tokens / max(1, self.mixed_positions), 4),
             # per-round-kind dispatch-time breakdown: pure-decode rounds vs
             # mixed (decode + prefill chunks) vs prefill-only — the
             # attribution the PD-disaggregation claim rests on (a unified
@@ -3255,16 +3270,24 @@ class ContinuousBatchingEngine:
                       chunk_tokens: int = 0,
                       depth: int = 0,
                       spec_tokens: int = 0,
-                      kind: str = "decode") -> None:
+                      kind: str = "decode",
+                      positions: Optional[int] = None) -> None:
         """One timing-schema owner for both decode modes — the stats()
         percentile keys cannot drift between paged and dense. ``ts`` is the
         round's wall-clock start; /v1/monitoring/rounds exports these entries
-        as Chrome trace events, which need absolute timestamps."""
+        as Chrome trace events, which need absolute timestamps.
+        ``positions`` is what the dispatch computed: ``B + R*Qc`` for a lane
+        step, ``B x Qmax`` for an all-rows (speculative) one, and ``B`` a
+        step for a decode round (the default)."""
+        if positions is None:
+            positions = self.n_slots * self._k_steps
         self.decode_rounds += 1
         if lookahead:
             self.lookahead_rounds += 1
         if mixed:
             self.mixed_rounds += 1
+            self.mixed_positions += positions
+            self.mixed_useful_tokens += chunk_tokens + self.active_slots
         self.last_round_at = time.monotonic()
         self.round_timings.append({
             "ts": round(ts if ts is not None else time.time(), 6),
@@ -3280,6 +3303,7 @@ class ContinuousBatchingEngine:
             # engine's steady state, and the unified pool's storm rounds)
             "kind": kind,
             "chunk_tokens": chunk_tokens,
+            "positions": positions,
             "depth": depth,
             "spec_tokens": spec_tokens,
             "active": self.active_slots,
@@ -3310,16 +3334,21 @@ class ContinuousBatchingEngine:
                     force_length=last_of_chunk and next_chunk_overflows)
 
     # ------------------------------------------------------------ mixed round
-    def _plan_prefill_chunks(self) -> list[tuple[int, _SlotState, int]]:
+    def _plan_prefill_chunks(self, max_rows: Optional[int] = None
+                             ) -> list[tuple[int, _SlotState, int]]:
         """Assign this round's prompt chunks: fill ``prefill_budget_tokens``
-        across prefilling slots FIFO (admission order). The head slot always
-        gets at least one token, so a tiny budget cannot stall prefill; a
-        budget of 0 means one unbounded chunk (whole remaining prompt)."""
+        across prefilling slots FIFO (admission order), at most ``max_rows``
+        of them — ``mixed_step``'s lane holds one slot's chunk a step, so
+        slots in prefill take consecutive steps, each under the budget; the
+        all-rows speculative step takes as many as the budget covers. The
+        head slot always gets at least one token, so a tiny budget cannot
+        stall prefill; a budget of 0 means one unbounded chunk (whole
+        remaining prompt)."""
         budget = self.config.prefill_budget_tokens
         left = budget if budget > 0 else float("inf")
         plan: list[tuple[int, _SlotState, int]] = []
         for slot in list(self._prefill_slots):
-            if left <= 0:
+            if left <= 0 or (max_rows is not None and len(plan) >= max_rows):
                 break
             state = self.slots[slot]
             if state is None or state.phase != "prefill":
@@ -3602,11 +3631,13 @@ class ContinuousBatchingEngine:
         return chained
 
     def _decode_round_mixed(self, spec_only: bool = False) -> bool:
-        """One ragged mixed-batch round: decode rows advance ONE token while
-        this round's prompt chunks (≤ prefill_budget_tokens, FIFO across
-        prefilling slots) run in the SAME dispatch through the ragged paged
-        kernel — Sarathi-style piggybacking with no phase separation, so an
-        arrival burst never stalls in-flight streams behind a prefill drain.
+        """One mixed-batch round: decode rows advance ONE token while the
+        head prefilling slot's chunk (≤ prefill_budget_tokens; slots take
+        consecutive rounds, FIFO) runs in the SAME dispatch as the lane of
+        ``mixed_step`` — Sarathi-style piggybacking with no phase
+        separation, so an arrival burst never stalls in-flight streams
+        behind a prefill drain, and the dispatch computes the tokens it has
+        (``n_slots + width`` positions).
         A ring in flight here is stale by construction (admission of prefill
         work bumped the epoch) and is discarded — EXCEPT the other way
         around: when this round's plan drains the prefill queue, lookahead
@@ -3635,7 +3666,11 @@ class ContinuousBatchingEngine:
         self._ensure_chunk_capacity(self._k_steps)
         plan: list[tuple[int, _SlotState, int]] = []
         if not spec_only:
-            for slot, state, chunk in self._plan_prefill_chunks():
+            # one slot's chunk a step (the lane), FIFO; an engine that
+            # speculates plans every slot the budget covers, for the all-rows
+            # step a round with draft spans takes
+            for slot, state, chunk in self._plan_prefill_chunks(
+                    max_rows=None if self.spec_k else LANE_ROWS):
                 try:
                     self._grow_chain_prefill(slot, state, chunk)
                     plan.append((slot, state, chunk))
@@ -3657,6 +3692,10 @@ class ContinuousBatchingEngine:
             # resumes from host (spec_only: the caller falls through to the
             # plain round immediately)
             return False
+        if not spec_plan:
+            # no draft survived: the lane step, whose chunk is the head's (a
+            # later slot's grown chain serves its own step)
+            plan = plan[:LANE_ROWS]
         n = self.n_slots
         # static dispatch width: the prefill bucket covering the largest
         # chunk — and the spec span width when rows speculate — rounded to
@@ -3665,20 +3704,25 @@ class ContinuousBatchingEngine:
         if spec_plan:
             q_need = max(q_need, self._spec_w)
         q_max = -(-q_need // 8) * 8
-        q_ids = np.zeros((n, q_max), np.int32)
-        q_lens = np.zeros(n, np.int32)
-        hist = np.zeros(n, np.int32)
+        # the span operands: one row a lane (mixed_step), or one a slot with
+        # the decode rows as spans of 1 among them (spec_mixed_step)
+        n_spans = n if spec_plan else len(plan)
+        q_ids = np.zeros((n_spans, q_max), np.int32)
+        q_lens = np.zeros(n_spans, np.int32)
+        hist = np.zeros(n_spans, np.int32)
         spec_lens = np.zeros(n, np.int32)
-        q_lens[self.active] = 1  # decode rows
+        if spec_plan:
+            q_lens[self.active] = 1  # decode rows
         sample = self.active.copy()
         final_mask = np.zeros(n, bool)
         final_lens = np.zeros(n, np.int32)
         finals: list[tuple[int, _SlotState]] = []
-        for slot, state, chunk in plan:
+        for lane, (slot, state, chunk) in enumerate(plan):
             pos = state.prefill_pos
-            q_ids[slot, :chunk] = state.prompt_ids[pos: pos + chunk]
-            q_lens[slot] = chunk
-            hist[slot] = pos
+            r = slot if spec_plan else lane
+            q_ids[r, :chunk] = state.prompt_ids[pos: pos + chunk]
+            q_lens[r] = chunk
+            hist[r] = pos
             if pos + chunk == len(state.prompt_ids):
                 # final chunk: this dispatch samples the first token — hand
                 # the request's untouched key stream to the device row NOW
@@ -3698,6 +3742,7 @@ class ContinuousBatchingEngine:
             spec_lens[slot] = d
         self._flush_pt_patches()
         if spec_plan:
+            positions = n * q_max
             toks_dev, *outs = self._spec_step_fn(
                 self.params, *self.pool.cache_operands(),
                 self._page_table_dev, self._dev(q_ids), self._dev(q_lens),
@@ -3708,14 +3753,18 @@ class ContinuousBatchingEngine:
                 self._slot_keys, self._temp_dev, self._top_p_dev,
                 self._top_k_dev)
         else:
+            positions = n + q_ids.size
             toks_dev, *outs = self._mixed_step_fn(
                 self.params, *self.pool.cache_operands(),
                 self._page_table_dev, self._dev(q_ids), self._dev(q_lens),
-                self._dev(hist), self._last_tokens, self._lengths_dev,
-                self._active_dev, self._finished_dev, self._dev(sample),
-                self._dev(final_mask), self._dev(final_lens),
-                self._stops_dev, self._limit_dev, self._slot_keys,
-                self._temp_dev, self._top_p_dev, self._top_k_dev)
+                self._dev(hist),
+                self._dev(np.array([slot for slot, _, _ in plan], np.int32)),
+                self._last_tokens,
+                self._lengths_dev, self._active_dev, self._finished_dev,
+                self._dev(sample), self._dev(final_mask),
+                self._dev(final_lens), self._stops_dev, self._limit_dev,
+                self._slot_keys, self._temp_dev, self._top_p_dev,
+                self._top_k_dev)
         last_o, keys_o, lens_o, fin_o, active_o = self.pool.adopt(outs)
         if self._state_unit:
             # a snapshot of each row whose chunk ended on a boundary, as THIS
@@ -3854,7 +3903,7 @@ class ContinuousBatchingEngine:
                            spec_tokens=sum(len(dr)
                                            for _, _, dr in spec_plan),
                            kind=("mixed" if decode_rows else "prefill")
-                           if plan else "decode")
+                           if plan else "decode", positions=positions)
         return True
 
     def _decode_round(self) -> None:

@@ -268,6 +268,35 @@ def test_compiled_kernels_context_forces_mosaic():
     assert default_interpret() is on_cpu
 
 
+def _lane_operands(sds, width: int) -> tuple:
+    """``mixed_step``'s lane: one slot's chunk ``[1, width]``, then its
+    length, its history and the slot it is."""
+    lane = sds((1,), jnp.int32)
+    return sds((1, width), jnp.int32), lane, lane, lane
+
+
+def _assert_whole_array_untouched(text: str, array, name: str) -> None:
+    """Every instruction of the optimised HLO that yields an array of
+    ``array``'s shape (a page pool, the state slab) is a parameter, the
+    loop's tuple plumbing, a kernel or an in-place update: none is a copy, a
+    ``dynamic-slice`` or a ``reshape``, which would move the whole of it."""
+    import re
+
+    shape = "%s[%s]" % ({"bfloat16": "bf16", "float32": "f32"}[
+        str(array.dtype)], ",".join(map(str, array.shape)))
+    seen = 0
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = " + re.escape(shape)
+                     + r"\S* (\w[\w\-]*)\(", line)
+        if m:
+            seen += 1
+            assert m.group(1) in (
+                "parameter", "get-tuple-element", "custom-call",
+                "dynamic-update-slice", "scatter", "fusion", "while",
+                "bitcast", "tuple"), (name, line[:200])
+    assert seen, f"{name}: no instruction of shape {shape} (a wrong pattern?)"
+
+
 @slow
 @pytest.mark.parametrize("tp", [1, 4])
 def test_scheduler_programs_compile_for_v5e_at_mistral_7b(tp):
@@ -335,14 +364,16 @@ def test_scheduler_programs_compile_for_v5e_at_mistral_7b(tp):
         "paged_decode_chunk": (eng._paged_decode_fn, (
             params, pool, pool, table, row(i32), row(i32), row(bool),
             row(bool), stops, row(i32), keys, *sampling)),
-        "mixed_step@64": (eng._mixed_step_fn, (
-            params, pool, pool, table, sds((n, 64), i32), row(i32), row(i32),
-            row(i32), row(i32), row(bool), row(bool), row(bool), row(bool),
-            row(i32), stops, row(i32), keys, *sampling)),
+        **{f"mixed_step@{w}": (eng._mixed_step_fn, (
+            params, pool, pool, table, *_lane_operands(sds, w), row(i32),
+            row(i32), row(bool), row(bool), row(bool), row(bool), row(i32),
+            stops, row(i32), keys, *sampling)) for w in (64, 256)},
     }
     for name, (fn, args) in programs.items():
         with compiled_kernels():
             compiled = fn.lower(*args).compile()
+        if tp == 1:
+            _assert_whole_array_untouched(compiled.as_text(), pool, name)
         mem = compiled.memory_analysis()
         live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                 - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
@@ -413,18 +444,16 @@ def test_scheduler_programs_compile_for_v5e_at_falcon_h1():
 
     def mixed(width):
         return (eng._mixed_step_fn, (
-            params, pool, pool, state, table, sds((n, width), i32), row(i32),
-            row(i32), row(i32), row(i32), row(bool), row(bool), row(bool),
+            params, pool, pool, state, table, *_lane_operands(sds, width),
+            row(i32), row(i32), row(bool), row(bool), row(bool),
             row(bool), row(i32), stops, row(i32), keys, *sampling))
 
     programs = {
         "paged_decode_chunk": (eng._paged_decode_fn, (
             params, pool, pool, state, table, row(i32), row(i32), row(bool),
             row(bool), stops, row(i32), keys, *sampling)),
-        "mixed_step@64": mixed(64), "mixed_step@512": mixed(512),
+        "mixed_step@64": mixed(64), "mixed_step@256": mixed(256),
     }
-    import re
-
     for name, (fn, args) in programs.items():
         with compiled_kernels():
             compiled = fn.lower(*args).compile()
@@ -444,15 +473,7 @@ def test_scheduler_programs_compile_for_v5e_at_falcon_h1():
         assert mem.alias_size_in_bytes >= slab_bytes + 2 * int(
             np.prod(pool.shape)) * 2, "pools and state slab not donated"
         assert live < V5E_HBM_BYTES, (name, live)
-        # nothing the size of the slab is copied, sliced or reshaped: every
-        # instruction that yields a whole-slab array is a parameter, the
-        # loop's tuple plumbing, the kernel itself or an in-place update
-        slab = "f32[%s]" % ",".join(map(str, state["ssm"].shape))
-        for line in text.splitlines():
-            m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = " + re.escape(slab)
-                         + r"\S* (\w[\w\-]*)\(", line)
-            if m:
-                assert m.group(1) in (
-                    "parameter", "get-tuple-element", "custom-call",
-                    "dynamic-update-slice", "fusion", "while", "bitcast",
-                    "tuple"), (name, line[:200])
+        # nothing the size of the slab or of a pool is copied, sliced or
+        # reshaped
+        _assert_whole_array_untouched(text, state["ssm"], name)
+        _assert_whole_array_untouched(text, pool, name)

@@ -124,6 +124,40 @@ def test_a_prompt_sharing_two_whole_chunks_resumes_from_the_snapshot():
     assert warm[1] == cold[0]
 
 
+@pytest.mark.parametrize("n", [2, 3])
+def test_prompts_admitted_in_one_round_take_consecutive_steps(n):
+    """``n`` prompts pending when the loop starts are admitted in one pass
+    and take the lane in turn, a chunk a step: chunks still end on snapshot
+    boundaries, a snapshot is taken wherever one does, and every answer is
+    that of the prompt served alone."""
+    base, other = _prompts(5)
+    prompts = [base, other + other[:25], base[10:55]][:n]    # 70, 45, 45
+    alone = [_run(_cfg(), [p])[0][0] for p in prompts]
+    sched = ContinuousBatchingEngine(_cfg(), seed=0)
+    col = _Collector(n)
+    start, sched.start = sched.start, lambda: None          # hold the loop
+    try:
+        for i, p in enumerate(prompts):
+            sched.submit(p, SamplingParams(max_tokens=12), col.emit_for(i))
+        sched.start = start
+        sched.start()
+        assert col.done.wait(240), (col.finishes, sched.stats())
+        time.sleep(0.2)
+        timings = list(sched.round_timings)
+        pool = sched.stats()["prefix_cache"]
+    finally:
+        sched.shutdown()
+    assert [col.tokens[i] for i in range(n)] == alone
+    mixed = [t for t in timings if t["mixed"]]
+    want = [min(BUDGET, len(p) - at) for p in prompts
+            for at in range(0, len(p), BUDGET)]
+    assert [t["chunk_tokens"] for t in mixed] == want
+    assert all(t["positions"] == 4 + _cfg().bucket_for(t["chunk_tokens"])
+               for t in mixed)
+    # ends on a boundary: 32 and 64 of the first prompt, 32 of each other
+    assert pool["state_snapshots_taken"] == n + 1
+
+
 def test_a_prompt_sharing_less_than_a_chunk_saves_nothing():
     """Pages without a snapshot at their end are worth nothing: 16 shared
     tokens (one page, half a chunk) are prefilled again."""

@@ -146,6 +146,54 @@ def test_mixed_streams_bit_identical_to_phase_separated_greedy():
     assert sep_stats["pipeline"]["mixed_rounds"] == 0
 
 
+def _lane_chunks(lengths, budget):
+    """The chunk sizes FIFO lane steps take: each prompt whole, in turn, in
+    chunks of the budget."""
+    return [min(budget, n - at) for n in lengths for at in range(0, n, budget)]
+
+
+@pytest.mark.parametrize("n", [2, 5])
+def test_prompts_admitted_in_one_round_take_consecutive_steps_fifo(n):
+    """``n`` prompts are all pending when the loop starts, so one admission
+    pass takes them all. The lane holds one slot's chunk a step: the slots
+    take consecutive mixed steps in admission order, each step within the
+    budget and computing ``slots + width`` positions, and every stream is
+    the phase-separated scheduler's, bit for bit."""
+    prompts = [np.random.default_rng(seed).integers(3, 900, 12 + 9 * i).tolist()
+               for i, seed in enumerate(_CLEAR_PROMPT_SEEDS)][:n]
+    samplings = [SamplingParams(max_tokens=24) for _ in range(n)]
+    cfg = _cfg(mixed_batch=True, max_batch=6)
+    sched = ContinuousBatchingEngine(cfg, seed=0)
+    col = _Collector(n)
+    start, sched.start = sched.start, lambda: None      # hold the loop
+    try:
+        for i, (p, s) in enumerate(zip(prompts, samplings)):
+            sched.submit(p, s, col.emit_for(i))
+        sched.start = start
+        sched.start()
+        assert col.done.wait(240), (col.finishes, sched.stats())
+        timings = list(sched.round_timings)
+        stats = sched.stats()["pipeline"]
+    finally:
+        sched.shutdown()
+    sep_col, _ = _run_streams(_cfg(mixed_batch=False, max_batch=6), prompts,
+                              samplings)
+    assert col.tokens == sep_col.tokens, "lane streams diverged"
+    assert col.finishes == sep_col.finishes
+
+    budget = cfg.prefill_budget_tokens
+    mixed = [t for t in timings if t["mixed"]]
+    assert [t["chunk_tokens"] for t in mixed] == _lane_chunks(
+        [len(p) for p in prompts], budget)
+    for t in mixed:
+        assert t["positions"] == 6 + cfg.bucket_for(t["chunk_tokens"])
+    assert stats["mixed_positions"] == sum(t["positions"] for t in mixed)
+    # one slot's chunk a step: the first prompt's first token leaves before
+    # the second prompt's prefill is through
+    assert col.order.index((0, col.tokens[0][0])) < min(
+        col.order.index((i, col.tokens[i][0])) for i in range(1, n))
+
+
 def test_mixed_lookahead_vs_sync_bit_identical_seeded():
     """The PR 2 pipeline invariant carries into mixed batching: lookahead
     on/off never changes a stream, including seeded sampling — rounds with
@@ -201,6 +249,11 @@ def test_prefill_storm_rounds_bounded_by_chunk_budget():
     assert max(t["chunk_tokens"] for t in mixed) <= budget
     assert stats["pipeline"]["prefill_chunks"] >= n_storm * 3, \
         "100+-token prompts at budget 32 must take >= 4 chunks each"
+    # a round computes its decode rows and ONE slot's chunk at its width,
+    # never every slot at that width; most of what it computes is a token
+    assert all(t["positions"] == 6 + cfg.bucket_for(t["chunk_tokens"])
+               for t in mixed)
+    assert stats["pipeline"]["mixed_useful_share"] > 0.5
     # stream 0 interleaves with the storm: its tokens appear between the
     # storm requests' first tokens rather than only after the drain
     first_pos = {}

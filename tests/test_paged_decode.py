@@ -200,3 +200,59 @@ def test_pool_reaches_the_kernel_uncopied(step):
             op = next(v for v in src.invars
                       if getattr(v.aval, "shape", None) == pool_shape)
         assert seen == ["scatter"], seen
+
+
+@pytest.mark.parametrize("model", ["tiny-llama", "tiny-falcon-h1"])
+def test_mixed_step_computes_the_tokens_it_has(model):
+    """The guard S11 lacked: in the scheduler's own ``mixed_step`` at 16
+    slots and a 256-wide chunk, every matmul over a layer weight runs over
+    16 + 256 token rows — the decode rows' one token each and the lane's
+    chunk — and none over 16 x 256; and a served round of that shape records
+    those positions."""
+    import threading
+
+    from cyberfabric_core_tpu.runtime import EngineConfig, SamplingParams
+    from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
+
+    n, width = 16, 256
+    eng = ContinuousBatchingEngine(EngineConfig(
+        model=model, max_seq_len=512, max_batch=n, decode_chunk=4,
+        use_flash=False, prefix_cache_pages=48, prefix_page_size=16,
+        prefill_budget_tokens=width), seed=0)
+    try:
+        lane = jnp.zeros((1,), jnp.int32)
+        row_b = jnp.zeros((n,), bool)
+        jaxpr = jax.make_jaxpr(eng._mixed_step_fn)(
+            eng.params, *eng.pool.cache_operands(), eng._page_table_dev,
+            jnp.zeros((1, width), jnp.int32), lane, lane, lane,
+            eng._last_tokens, eng._lengths_dev, eng._active_dev,
+            eng._finished_dev, row_b, row_b, eng._lengths_dev,
+            eng._stops_dev, eng._limit_dev, eng._slot_keys, eng._temp_dev,
+            eng._top_p_dev, eng._top_k_dev)
+        weights = {w.shape[1:] for w in jax.tree.leaves(eng.params["layers"])
+                   if w.ndim == 3}
+        scan, = [e for e in _find(jaxpr.jaxpr, "scan")
+                 if e.params["length"] == eng.model_config.num_layers]
+        rows = []
+        for eqn in _find(scan.params["jaxpr"].jaxpr, "dot_general"):
+            lhs, rhs = (v.aval.shape for v in eqn.invars)
+            if rhs in weights:
+                (contract, _), _ = eqn.params["dimension_numbers"]
+                rows.append(int(np.prod(lhs)) // int(np.prod(
+                    [lhs[d] for d in contract])))
+        assert len(rows) >= 7, rows       # q k v o gate up down at the least
+        assert set(rows) == {n + width}, rows
+
+        done = threading.Event()
+        eng.submit(list(range(1, 201)), SamplingParams(max_tokens=2),
+                   lambda ev: done.set() if ev.finished else None)
+        assert done.wait(120)
+        mixed = [r for r in eng.round_timings if r["mixed"]]
+        assert [r["chunk_tokens"] for r in mixed] == [200]
+        assert mixed[0]["positions"] == n + width
+        stats = eng.stats()["pipeline"]
+        assert stats["mixed_positions"] == n + width
+        assert stats["mixed_useful_share"] == round(
+            (200 + mixed[0]["active"]) / (n + width), 4)
+    finally:
+        eng.shutdown()
