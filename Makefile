@@ -75,9 +75,8 @@ doctor:  ## fabric-doctor: SLO engine/watchdog/state-machine tests + the burn-ra
 doctor-guard:  ## fabric-doctor armed-vs-stubbed overhead A/B under the aggregate workload (BENCH_DOCTOR.json, <1% bar)
 	$(PY) bench.py --doctor-guard > /dev/null
 
-ragged-bench:  ## ragged mixed-batch kernel/scheduler tests + the mixed-vs-phase-separated A/B (BENCH_RAGGED.json: itl_p99 + ttft must improve)
+ragged-bench:  ## ragged mixed-batch kernel/scheduler tests
 	$(PY) -m pytest tests/test_ragged_attention.py tests/test_mixed_batch.py -q
-	$(PY) bench.py --ragged-bench > /dev/null
 
 overlap-bench:  ## deep-lookahead pipeline tests + the depth 0/1/N sweep (BENCH_OVERLAP.json: overlap_ratio > 0.85 at depth >= 2)
 	$(PY) -m pytest tests/test_scheduler_pipeline.py -q

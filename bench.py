@@ -593,96 +593,6 @@ def fairness_guard() -> int:
         "per arm (contention only slows runs down)")
 
 
-def ragged_bench() -> int:
-    """Mixed-batch A/B (BENCH_RAGGED.json): the --aggregate staggered storm
-    with ragged mixed-batch rounds ON (prefill chunks piggyback into decode
-    rounds through the ragged paged-attention kernel) vs OFF (the
-    phase-separated coalesced cold-prefill baseline, ``BENCH_MIXED_BATCH=0``).
-
-    Both arms run the COLD storm — the same measurement BENCH_PIPELINE.json
-    took and the one the motivating tail numbers came from: a storm hitting
-    a fresh engine pays first-compile latency exactly where production pays
-    it (restart, scale-up, new bucket). Phase separation makes that worst
-    case brutal: every decode stream stalls behind each cold prefill
-    dispatch AND its per-bucket/per-coalesce-width program zoo, all of it
-    landing in the itl tail. Mixed batching admits prompts into
-    chunk-piggybacked rounds with no separate prefill programs at all, so
-    the same storm compiles a handful of ragged-round variants instead.
-    (A warm steady-state A/B is mostly flat on CPU: the interpret-mode
-    ragged kernel costs more per prefill token than XLA dense prefill,
-    which inverts ttft — on TPU the compiled kernel closes that gap;
-    ``BENCH_WARMUP=1``/``BENCH_DECODE_CHUNK`` remain available to measure
-    it.) Interleaved ABBA ordering decorrelates host drift; per arm the run
-    with the LOWEST itl_p99 is reported (contention and co-tenant noise
-    only ever add latency, so the min is the least-contaminated measurement
-    — the latency dual of the overhead guards' best-tok/s rule). Pass bar:
-    itl_p99 AND ttft_p50 both improve under mixed batching, tokens/sec
-    within 5% or better."""
-    reps = int(os.environ.get("BENCH_RAGGED_REPS", "2"))
-    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_COST="0")
-    env.setdefault("BENCH_STAGGER_S", "0.05")
-
-    def one(mixed: str) -> Optional[dict]:
-        proc = subprocess.run(
-            [sys.executable, os.path.abspath(__file__), "--aggregate",
-             "tiny-llama", "none"],
-            capture_output=True, text=True, timeout=900,
-            env=dict(env, BENCH_MIXED_BATCH=mixed))
-        sys.stderr.write(proc.stderr[-2000:])
-        try:
-            row = json.loads(proc.stdout.strip().splitlines()[-1])
-            return row if "itl_p99_ms" in row else None
-        except Exception as e:  # noqa: BLE001
-            log(f"ragged-bench child (mixed={mixed}) failed: {e}")
-            return None
-
-    arms: dict[str, list[dict]] = {"mixed": [], "separated": []}
-    order = (["mixed", "separated", "separated", "mixed"]
-             * ((reps + 1) // 2))[: 2 * reps]
-    for label in order:
-        row = one("1" if label == "mixed" else "0")
-        if row is not None:
-            arms[label].append(row)
-
-    def best(rows: list[dict]) -> Optional[dict]:
-        return min(rows, key=lambda r: r["itl_p99_ms"]) if rows else None
-
-    mixed_best, sep_best = best(arms["mixed"]), best(arms["separated"])
-    report: dict = {
-        "kind": "ragged_mixed_batch_ab_cpu_evidence",
-        "note": "aggregate COLD staggered storm (8 streams, fresh engine — "
-                "the BENCH_PIPELINE.json measurement), mixed-batch ragged "
-                "rounds vs phase-separated cold prefill; interleaved ABBA "
-                "runs, per-arm min-itl_p99 run reported (contention only "
-                "adds latency)",
-        "runs": {k: [{m: r[m] for m in ("tokens_per_sec", "itl_p50_ms",
-                                        "itl_p99_ms", "ttft_p50_ms",
-                                        "mixed_rounds", "prefill_chunks")}
-                     for r in v] for k, v in arms.items()},
-        "mixed": mixed_best, "separated": sep_best,
-    }
-    if mixed_best and sep_best:
-        itl_red = (1.0 - mixed_best["itl_p99_ms"]
-                   / max(sep_best["itl_p99_ms"], 1e-9)) * 100.0
-        ttft_red = (1.0 - mixed_best["ttft_p50_ms"]
-                    / max(sep_best["ttft_p50_ms"], 1e-9)) * 100.0
-        toks_delta = (mixed_best["tokens_per_sec"]
-                      / max(sep_best["tokens_per_sec"], 1e-9) - 1.0) * 100.0
-        report.update({
-            "itl_p99_reduction_pct": round(itl_red, 1),
-            "ttft_p50_reduction_pct": round(ttft_red, 1),
-            "tokens_per_sec_delta_pct": round(toks_delta, 1),
-            "pass": bool(itl_red > 0 and ttft_red > 0 and toks_delta > -5.0),
-        })
-    else:
-        report["pass"] = False
-    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                           "BENCH_RAGGED.json"), "w") as f:
-        json.dump(report, f, indent=1)
-    print(json.dumps(report))
-    return 0 if report["pass"] else 1
-
-
 def overlap_bench() -> int:
     """Deep-lookahead sweep (BENCH_OVERLAP.json): the --aggregate staggered
     storm at ring depth 0 (synchronous baseline), 1 (the legacy single-chunk
@@ -698,7 +608,7 @@ def overlap_bench() -> int:
     CPU with fused chunks: tokens are emitted in decode_chunk-sized bursts,
     so intra-chunk deltas are ~0 ms (the p50) while the p99 IS the ~1 s
     CPU decode-round dispatch itself — the round boundary, not host/device
-    serialization (PR 6 hit the same wall; BENCH_RAGGED.json documents it).
+    serialization (PR 6 hit the same wall).
     On TPU the same round is ~ms-scale and the ratio collapses. The report
     therefore carries both verdicts: ``overlap_pass`` (the A/B claim this
     harness CAN prove) and ``itl_ratio_deep`` with ``itl_note`` explaining
@@ -713,7 +623,7 @@ def overlap_bench() -> int:
     # whole 192-token storm is ~16 rounds — too few for ANY pipeline to fill
     # (the admission/mixed prologue is half the run). Overlap is a per-round
     # structure metric; more, shorter rounds measure it without changing
-    # what is measured (the ragged A/B uses the same knob for ITL studies).
+    # what is measured.
     env.setdefault("BENCH_DECODE_CHUNK", "8")
 
     def one(depth: int) -> Optional[dict]:
@@ -775,8 +685,7 @@ def overlap_bench() -> int:
             "itl_p50 is the ~0 ms intra-chunk delta while itl_p99 is the "
             "CPU decode-round dispatch itself (~1 s here, ~ms on TPU) — "
             "the 2x bound is a TPU target; the round time, not host/device "
-            "serialization, is the tail on CPU (same wall as "
-            "BENCH_RAGGED.json)")
+            "serialization, is the tail on CPU")
         report["itl_p99_reduction_vs_sync_pct"] = round(
             (1.0 - dn["itl_p99_ms"] / max(d0["itl_p99_ms"], 1e-9)) * 100.0, 1)
         report["tokens_per_sec_delta_vs_sync_pct"] = round(
@@ -1588,18 +1497,13 @@ def aggregate(model_name: str, quant: str) -> int:
         # --overlap-bench sweeps it (BENCH_OVERLAP.json).
         _la_raw = os.environ.get("BENCH_LOOKAHEAD", "")
         lookahead = int(_la_raw) if _la_raw else EngineConfig.decode_lookahead
-        # BENCH_MIXED_BATCH=0 pins the phase-separated cold-prefill scheduler
-        # — the pre/post knob for the ragged mixed-batch (Sarathi
-        # piggybacking) win; BENCH_RAGGED.json holds the A/B evidence
-        mixed = os.environ.get("BENCH_MIXED_BATCH", "1") != "0"
         # chunk budget: the Sarathi knob — smaller chunks bound each mixed
-        # round's decode stall (BENCH_RAGGED.json sweeps it); 0 = unbounded
+        # round's decode stall; 0 = unbounded
         budget = int(os.environ.get("BENCH_PREFILL_BUDGET", "512"))
         stagger_s = float(os.environ.get("BENCH_STAGGER_S", "0.1"))
         # decode chunk size: tokens emitted per dispatch. BENCH_DECODE_CHUNK
         # lets steady-state ITL studies drop it (smaller chunks resolve
-        # per-round stalls that a 32-token round boundary would swamp); the
-        # cold-storm ragged A/B keeps the production default
+        # per-round stalls that a 32-token round boundary would swamp)
         decode_chunk = int(os.environ.get("BENCH_DECODE_CHUNK", "32"))
         # fairness-guard A/B arms (BENCH_FAIRNESS.json): "on"/unset keeps
         # tenancy ARMED with every request landing in the one default
@@ -1622,7 +1526,6 @@ def aggregate(model_name: str, quant: str) -> int:
                            prefix_cache_pages=slots * 8 + 33,
                            prefix_page_size=64,
                            decode_lookahead=lookahead,
-                           mixed_batch=mixed,
                            prefill_budget_tokens=budget,
                            tenant_fair=tenant_fair,
                            scheduler_spec_k=spec_k,
@@ -1689,8 +1592,7 @@ def aggregate(model_name: str, quant: str) -> int:
         # BENCH_WARMUP=1 pre-compiles every program variant the storm will
         # hit (one request per prompt bucket, run to completion) so the
         # percentiles measure steady-state scheduling, not first-compile
-        # latency — the mixed-vs-separated A/B (BENCH_RAGGED.json) is about
-        # head-of-line blocking, which compile spikes drown out on CPU
+        # latency, which drowns head-of-line blocking out on CPU
         if os.environ.get("BENCH_WARMUP") == "1":
             warm_done = threading.Event()
             warm_left = [2]
@@ -1794,7 +1696,6 @@ def aggregate(model_name: str, quant: str) -> int:
                           "itl_p99_ms": pct(deltas_ms, 0.99),
                           "ttft_p50_ms": pct(ttfts_ms, 0.5),
                           "decode_lookahead": lookahead,
-                          "mixed_batch": mixed,
                           "spec_k": spec_k,
                           "tp": tp,
                           "pd": pd_stats,
@@ -2173,8 +2074,6 @@ if __name__ == "__main__":
         sys.exit(cancel_guard())
     if len(sys.argv) > 1 and sys.argv[1] == "--trace-guard":
         sys.exit(trace_guard())
-    if len(sys.argv) > 1 and sys.argv[1] == "--ragged-bench":
-        sys.exit(ragged_bench())
     if len(sys.argv) > 1 and sys.argv[1] == "--overlap-bench":
         sys.exit(overlap_bench())
     if len(sys.argv) > 1 and sys.argv[1] == "--spec-bench":

@@ -110,7 +110,6 @@ _DEVICE_PUT = frozenset({"jax.device_put", "device_put"})
 _REPLICATED_HELPERS = frozenset({"replicated"})
 _SHARDED_HELPERS = frozenset({
     "shard_llama_params", "apply_shardings", "llama_page_pool_sharding",
-    "dense_cache_sharding",
 })
 
 _SHARD_MAP_NAMES = frozenset({

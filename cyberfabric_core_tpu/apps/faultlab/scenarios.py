@@ -67,12 +67,9 @@ BUILTIN_SCENARIOS: list[dict[str, Any]] = [
         "name": "prefill-fault",
         "kind": "engine",
         "seed": 102,
-        # phase-separated mode (scheduler.prefill lives on that path — mixed
-        # batching has no prefill dispatch; its faults are covered by
-        # mixed-prefill-preempt) with coalesce off, so the FIFO-first request
-        # deterministically takes the single-prefill path where the fault is
-        # injected
-        "engine": {**_TINY, "prefill_coalesce": 1, "mixed_batch": False},
+        # fires in the FIFO-first request's admission (_admit_prefill_slot):
+        # _place reclaims its slot and error-terminates only that request
+        "engine": _TINY,
         "load": _LOAD,
         "faults": [{"point": "scheduler.prefill", "spec": "1*raise"}],
         "invariants": ["exactly_one_terminal", "expected_errors",

@@ -57,8 +57,8 @@ FAILPOINT_CATALOG: dict[str, tuple[str, str]] = {
         "runtime", "decode-chunk device readback in the scheduler hot loop; "
         "a raise breaks the engine and error-terminates every stream"),
     "scheduler.prefill": (
-        "runtime", "single-request prefill dispatch; exercises the "
-        "failed-admission slot/page reclaim path"),
+        "runtime", "a request's admission into a prefill-phase slot; "
+        "exercises the failed-admission slot/page reclaim path"),
     "scheduler.admit": (
         "runtime", "admission loop entry; delay throttles admission, raise "
         "breaks the engine"),

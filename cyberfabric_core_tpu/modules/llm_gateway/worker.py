@@ -73,6 +73,15 @@ def _parse_lookahead(raw: Any) -> int:
         return default
 
 
+def _warn_ignored_options(opts: dict, model_id: str) -> None:
+    """``opts`` is what no pop of ``_build_entry`` took: a removed or misspelt
+    engine option serves the default, so say which (no refusal: a registry
+    entry written for an older build must still boot)."""
+    if opts:
+        logger.warning("engine_options for %s: ignoring unknown keys %s",
+                       model_id, sorted(opts))
+
+
 @dataclass
 class _Request:
     prompt_ids: list[int]
@@ -332,9 +341,9 @@ class LocalTpuWorker(LlmWorkerApi):
         max_seq_len = int(opts.pop("max_seq_len", 2048))
         max_batch = int(opts.pop("max_batch", 8))
         page_size = int(opts.pop("prefix_page_size", 64))
-        # paged decode is the default serving path: slot KV + prefix cache in
-        # ONE paged pool (the scheduler raises this to the per-slot minimum;
-        # the margin here is prefix-cache retention headroom). 0 disables.
+        # slot KV + prefix cache live in ONE paged pool (the scheduler raises
+        # this to the per-slot minimum; the margin here is prefix-cache
+        # retention headroom)
         default_pages = max_batch * (-(-max_seq_len // page_size)) * 5 // 4 + 1
         eng_cfg = EngineConfig(
             model=arch_config,
@@ -347,20 +356,14 @@ class LocalTpuWorker(LlmWorkerApi):
             prefix_cache_pages=int(opts.pop("prefix_cache_pages", default_pages)),
             prefix_page_size=page_size,
             # scheduler pipeline knobs (docs/ARCHITECTURE.md "Scheduler
-            # pipeline"): lookahead ring depth, Sarathi-style admission
-            # budget, cold-prefill coalescing. Registry options can arrive as
+            # pipeline"): lookahead ring depth, Sarathi-style chunk budget
+            # of the mixed-batch rounds. Registry options can arrive as
             # strings — bool("false") is True, so parse the words, not the
             # truthiness; digits are a ring DEPTH (0=sync, N=N-deep), bool
             # words map to off / the EngineConfig default depth.
             decode_lookahead=_parse_lookahead(
                 opts.pop("decode_lookahead", None)),
             prefill_budget_tokens=int(opts.pop("prefill_budget_tokens", 512)),
-            prefill_coalesce=int(opts.pop("prefill_coalesce", 4)),
-            # ragged mixed-batch rounds: prefill chunks piggyback into decode
-            # rounds (one dispatch) instead of a blocking cold-prefill phase
-            mixed_batch=str(opts.pop("mixed_batch", True)
-                            ).strip().lower() not in ("0", "false", "no",
-                                                      "off"),
             # admission backpressure bound (faultlab satellite): overflow
             # surfaces as 429 + Retry-After instead of unbounded queueing
             max_pending=int(opts.pop("max_pending", 2048)),
@@ -455,6 +458,7 @@ class LocalTpuWorker(LlmWorkerApi):
             # least one replica to serve).
             pd_prefill = int(opts.pop("pd_prefill_replicas", 0))
             pd_decode = int(opts.pop("pd_decode_replicas", 0))
+            _warn_ignored_options(opts, model.canonical_id)
             if (pd_prefill > 0) != (pd_decode > 0):
                 raise ValueError(
                     f"engine_options for {model.canonical_id}: "
@@ -524,6 +528,7 @@ class LocalTpuWorker(LlmWorkerApi):
             return _EngineEntry(config=eng_cfg, tokenizer=tokenizer,
                                 scheduler=scheduler, supervisor=supervisor,
                                 model_family=chat_family)
+        _warn_ignored_options(opts, model.canonical_id)
         engine = InferenceEngine(eng_cfg)
         if params is not None:
             engine.params = params

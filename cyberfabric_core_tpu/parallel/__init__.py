@@ -8,14 +8,13 @@ sequence parallelism for long context.
 """
 
 from .mesh import MeshConfig, build_mesh, local_device_count
-from .sharding import (dense_cache_sharding, input_shardings,
-                       llama_cache_sharding, llama_page_pool_sharding,
-                       llama_param_shardings, replicated, shard_llama_params)
+from .sharding import (input_shardings, llama_cache_sharding,
+                       llama_page_pool_sharding, llama_param_shardings,
+                       replicated, shard_llama_params)
 
 __all__ = [
     "MeshConfig",
     "build_mesh",
-    "dense_cache_sharding",
     "input_shardings",
     "llama_cache_sharding",
     "llama_page_pool_sharding",

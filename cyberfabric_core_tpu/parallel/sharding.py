@@ -111,13 +111,6 @@ def llama_page_pool_sharding(cfg: ModelConfig, mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P(None, None, None, _kv_head_axis(cfg, mesh)))
 
 
-def dense_cache_sharding(cfg: ModelConfig, mesh: Mesh) -> NamedSharding:
-    """Dense slot cache [L, B, S, Hkv, D] for the non-paged scheduler under
-    a pure-tp serving mesh (no dp axis in play: batch stays whole)."""
-    return NamedSharding(mesh, P(None, None, None, _kv_head_axis(cfg, mesh),
-                                 None))
-
-
 def replicated(mesh: Mesh) -> NamedSharding:
     """The explicit destination for host-control rows under a serving mesh
     (tokens / lengths / stops / page table / sampling params): every device

@@ -102,7 +102,9 @@ class EngineConfig:
     decode_chunk: int = 8
     #: Pallas flash kernel for prefill attention. None = auto (on for TPU).
     use_flash: Optional[bool] = None
-    #: prefix-cache pool size in pages (0 = disabled). Continuous scheduler only.
+    #: continuous scheduler: the page pool's size in pages. Anything under
+    #: the slot minimum (every slot a full window, plus the scratch page),
+    #: the default included, is raised to that minimum.
     prefix_cache_pages: int = 0
     prefix_page_size: int = 64
     #: weight-only quantization: "none" | "int8" | "int4" (each rung ~halves
@@ -123,7 +125,7 @@ class EngineConfig:
     #: vocab/tokenizer) + optional checkpoint dir for its weights
     draft_model: str = ""
     draft_checkpoint: str = ""
-    #: batched speculative decoding in the CONTINUOUS scheduler (paged mode):
+    #: batched speculative decoding in the CONTINUOUS scheduler:
     #: up to this many ngram-proposed draft tokens per speculating slot per
     #: round, verified as ONE q_len=k+1 ragged span inside the mixed-batch
     #: dispatch with accept/reject, accepted-length and rollback computed on
@@ -143,7 +145,7 @@ class EngineConfig:
     #: 0.0 = never disable. Deterministic per stream and acceptance-checked,
     #: so the gate can only ever change speed, never token values.
     spec_min_accept: float = 0.0
-    #: continuous scheduler (paged mode only): lookahead DEPTH — up to this
+    #: continuous scheduler: lookahead DEPTH — up to this
     #: many decode chunks are kept in flight beyond the one being drained
     #: (an epoch ring). Each chunk chains off device-resident state, so the
     #: host emit loop overlaps N device chunks instead of alternating.
@@ -162,25 +164,14 @@ class EngineConfig:
     #: pre-device-termination behavior); max-tokens/window bounds are always
     #: device-resident regardless.
     device_stop_width: int = 8
-    #: continuous scheduler: per-round prefill admission budget in prompt
-    #: tokens (Sarathi-style interleave). A burst of arrivals no longer drains
-    #: the whole queue with back-to-back prefills before decode resumes; at
-    #: least one request is always admitted per round so big prompts cannot
-    #: starve. 0 = unbounded drain (pre-pipeline behavior).
+    #: continuous scheduler: per-round prefill budget in prompt tokens
+    #: (Sarathi-style mixed-batch rounds). Pending prompts are split into
+    #: chunks of at most this many tokens that piggyback INTO decode rounds
+    #: through the ragged paged-attention kernel (one dispatch serves decode
+    #: rows at q_len=1 and the prefilling slot's chunk), so an arrival burst
+    #: never stalls the decode streams behind a whole prompt. 0 = a prompt
+    #: is one chunk.
     prefill_budget_tokens: int = 512
-    #: continuous scheduler: coalesce up to this many COLD (no prefix hit)
-    #: same-bucket pending requests into one multi-row prefill dispatch.
-    #: 1 = off (every prefill is its own batch-1 dispatch).
-    prefill_coalesce: int = 4
-    #: continuous scheduler (paged mode): Sarathi-style mixed-batch rounds —
-    #: pending prompts are split into prefill chunks (sized by
-    #: ``prefill_budget_tokens``) that piggyback INTO decode rounds through
-    #: the ragged paged-attention kernel (one dispatch serves decode rows at
-    #: q_len=1 and prefill-chunk rows at q_len=chunk), instead of running a
-    #: blocking phase-separated cold prefill that stalls every decode stream.
-    #: False restores the phase-separated path (the A/B baseline; also what
-    #: dense mode always uses).
-    mixed_batch: bool = True
     #: continuous scheduler: bound on the pending (not-yet-admitted) queue.
     #: ``submit`` raises :class:`SchedulerSaturated` at the bound — the
     #: gateway maps it to 429 + Retry-After — instead of queueing without
@@ -307,24 +298,10 @@ class EngineConfig:
 
 
 def build_decode_chunk_fn(model_config: ModelConfig, k_steps: int,
-                          rope_tables, *, max_seq: Optional[int] = None,
-                          device_term: bool = False) -> Callable:
-    """The shared fused decode body: k (forward T=1 → lm_head → sample) steps
-    under one lax.scan. Both the lockstep engine and the continuous scheduler jit
-    this same function (with their own donation specs) so the decode semantics
-    can never diverge between them.
-
-    ``device_term=True`` adds the device-resident termination machinery the
-    deep-lookahead scheduler needs: extra inputs (active, finished, stop_ids,
-    limit_lens) and extra outputs (lengths, finished). Each step matches the
-    sampled token against the row's padded stop-id set and its length limit
-    (max-tokens bound folded into ``limit_lens``; the window bound
-    ``len + k > max_seq`` is checked at the chunk's last step, mirroring the
-    host's force-length rule), and a finished row FREEZES: its last token,
-    key/rng effect, length and KV writes stop advancing, so a chunk chained
-    off this one stays valid even when a row terminates mid-chunk. Frozen
-    steps emit -1 sentinels (discarded host-side). Running rows compute
-    bit-identically to the plain body."""
+                          rope_tables) -> Callable:
+    """The fused dense decode body: k (forward T=1 → lm_head → sample) steps
+    under one lax.scan. The lockstep engine and the export path jit this same
+    function (with their own donation specs)."""
 
     def decode_chunk(params, k_cache, v_cache, last_tokens, lengths, rng,
                      temperature, top_p, top_k):
@@ -343,35 +320,7 @@ def build_decode_chunk_fn(model_config: ModelConfig, k_steps: int,
             None, length=k_steps)
         return toks.T, cache[0], cache[1], last, rng  # toks: [B, k]
 
-    def decode_chunk_term(params, k_cache, v_cache, last_tokens, lengths, rng,
-                          temperature, top_p, top_k, active, finished,
-                          stop_ids, limit_lens):
-        def step(carry, j):
-            cache, toks, lens, fin, rng = carry
-            run = active & jnp.logical_not(fin)
-            hidden, cache = llama.forward(
-                params, model_config, toks[:, None], lens[:, None], cache, lens,
-                rope_tables)
-            logits = llama.lm_head_logits(params, model_config, hidden[:, 0, :])
-            rng, sub = jax.random.split(rng)
-            nxt = sample_token(logits, sub, temperature, top_p, top_k)
-            new_lens = lens + 1
-            is_stop = jnp.any(nxt[:, None] == stop_ids, axis=1)
-            hit = new_lens >= limit_lens
-            if max_seq is not None:
-                hit = hit | ((j == k_steps - 1) & (new_lens + k_steps > max_seq))
-            emit = jnp.where(run, nxt, -1)
-            return (cache, jnp.where(run, nxt, toks),
-                    jnp.where(run, new_lens, lens),
-                    fin | (run & (is_stop | hit)), rng), emit
-
-        (cache, last, lens, fin, rng), toks = jax.lax.scan(
-            step, ((k_cache, v_cache), last_tokens, lengths, finished, rng),
-            jnp.arange(k_steps, dtype=jnp.int32))
-        lens = jnp.where(active, lens, 0)
-        return toks.T, cache[0], cache[1], last, rng, lens, fin
-
-    return decode_chunk_term if device_term else decode_chunk
+    return decode_chunk
 
 
 @dataclass

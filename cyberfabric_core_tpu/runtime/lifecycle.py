@@ -149,7 +149,7 @@ class LifecycleConfig:
             return cls(enabled=False)
         if isinstance(raw, str):
             # registry options can arrive as strings — bool("false") is
-            # True, so parse the words (the mixed_batch convention)
+            # True, so parse the words
             return cls(enabled=raw.strip().lower()
                        not in ("0", "false", "no", "off"))
         raw = dict(raw)

@@ -1,10 +1,11 @@
-"""Prefix-cached KV pool: paged storage of prefill KV for cross-request reuse.
+"""The page pool: every slot's KV, and the prompt prefixes requests share.
 
-The PAPERS.md direction (ragged paged attention for TPU) applied where it pays
-most on a serving host: **prompt prefix reuse**. Completed prefill KV is stored in
-a paged device pool ([L, num_pages, page_size, Hkv*D]: the two minor dimensions
+The PAPERS.md direction (ragged paged attention for TPU): a slot's KV lives in
+pages of one device pool that the serving programs write in place, and a
+committed prompt's full pages stay behind for **prompt prefix reuse**. The pool
+is [L, num_pages, page_size, Hkv*D] (the two minor dimensions
 are stored merged, head-major, which is the block the paged kernels read — on a
-tiled TPU layout merging them in front of the kernel is a copy of the pool)
+tiled TPU layout merging them in front of the kernel is a copy of the pool),
 indexed by the native radix prefix cache (runtime/native.py — C++ fabric_host).
 
 One manager for both kinds of cache. A model with recurrent state
@@ -18,18 +19,24 @@ snapshot of the state at that boundary exists: ``match_prefix`` trims a hit
 to the deepest page that owns one, admission copies it into the slot's row,
 evicting the page frees it, and the preemption movers carry the slot's row.
 The movers below hand out and take in request-sized tensors with their
-[..., Hkv, D] tail and reshape them at this boundary. A new request whose
-prompt shares a page-aligned prefix with any earlier one:
+[..., Hkv, D] tail and reshape them at this boundary.
 
-1. matches the prefix in the radix tree (pinning its pages),
-2. gathers those pages into its prefill cache in one device op,
-3. runs prefill ONLY over the uncached suffix (with history attention),
-4. scatters its own new full pages back into the pool and records them.
+The admission protocol, one for every request (scheduler.py:
+``_admit_prefill_slot``, ``_grow_chain_prefill``, ``_finish_prefill``):
 
-Decode stays on the dense slot cache (decode state is unshared by nature); the
-pool accelerates TTFT and prefill FLOPs — the llm-gateway's shared system prompts
-are the canonical win. Pool pressure is handled by LRU eviction of unpinned
-entries. Page id 0 is a scratch page: bucket padding scatters land there.
+1. ``match_prefix`` finds the prompt's page-aligned prefix in the radix tree
+   (pinning its nodes); ``ref_pages`` takes the slot's hold on those pages and
+   ``release`` drops the pin,
+2. ``extend_chain`` allocates private pages a chunk at a time while the mixed
+   steps write the uncached suffix's KV straight into them,
+3. ``commit_chain`` records the prompt's full pages in the tree after the last
+   chunk, so later requests alias them in their page tables (zero-copy),
+4. ``release_slot`` drops the chain's holds when the request leaves its slot.
+
+The llm-gateway's shared system prompts are the canonical win (TTFT and
+prefill FLOPs). Pool pressure is handled by LRU eviction of unpinned entries;
+a page evicted while a slot still holds it is an orphan until that slot lets
+go. Page id 0 is a scratch page: masked KV writes land there.
 """
 
 from __future__ import annotations
@@ -68,15 +75,6 @@ def state_set_row(state: dict, row, values: dict) -> dict:
             for k, v in state.items()}
 
 
-def _buckets_upto(n: int) -> list[int]:
-    out, b = [], 1
-    while b < n:
-        out.append(b)
-        b *= 2
-    out.append(n)
-    return out
-
-
 class PrefixKVPool:
     """Device page pool + native allocator/radix tree + jitted move programs."""
 
@@ -91,9 +89,8 @@ class PrefixKVPool:
         self.dtype = dtype
         #: tensor-parallel serving: a NamedSharding for the pool arrays
         #: ([L, P, page, Hkv*D], the merged head axis on tp — parallel/
-        #: sharding.py llama_page_pool_sharding). Every mover program
-        #: (gather/scatter/tail) runs under GSPMD against the sharded pool;
-        #: the host-side
+        #: sharding.py llama_page_pool_sharding). The preemption and PD
+        #: movers run under GSPMD against the sharded pool; the host-side
         #: bookkeeping (allocator, radix tree, refcounts, page ids) is
         #: byte-count-agnostic and identical to the single-device pool.
         self.sharding = sharding
@@ -155,51 +152,6 @@ class PrefixKVPool:
         feasibility bound callers must check before parking a request on
         'the pool will free up eventually'."""
         return self.num_pages - self._page_offset
-
-    # ------------------------------------------------------------ jitted movers
-    @partial(jax.jit, static_argnums=(0, 3))
-    def _gather(self, pools, page_ids, n_pages_bucket):
-        """pool[:, pids] → [L, 1, Pb*page, H, D] contiguous block."""
-        k_pool, v_pool = pools
-        k = jnp.take(k_pool, page_ids, axis=1)  # [L, Pb, page, H*D]
-        v = jnp.take(v_pool, page_ids, axis=1)
-        out = (k.shape[0], 1, n_pages_bucket * self.page_size,
-               self.cfg.num_kv_heads, self.cfg.head_dim)
-        return k.reshape(out), v.reshape(out)
-
-    @partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
-    def _scatter(self, pools, kv, page_ids, start_token):
-        """Write pages [start_token .. start_token + Pb*page) of kv [L,1,S,...]
-        into pool slots page_ids (padding ids point at scratch page 0)."""
-        k_pool, v_pool = pools
-        k_new, v_new = kv
-        L = k_new.shape[0]
-        Pb = page_ids.shape[0]
-        span = Pb * self.page_size
-        k_slice = jax.lax.dynamic_slice_in_dim(k_new[:, 0], start_token, span, axis=1)
-        v_slice = jax.lax.dynamic_slice_in_dim(v_new[:, 0], start_token, span, axis=1)
-        k_pages = k_slice.reshape(L, Pb, self.page_size, -1)
-        v_pages = v_slice.reshape(L, Pb, self.page_size, -1)
-        return (k_pool.at[:, page_ids].set(k_pages),
-                v_pool.at[:, page_ids].set(v_pages))
-
-    def _scatter_full_pages(self, kv: tuple, page_ids: list[int],
-                            start_token: int) -> None:
-        """Scatter len(page_ids) full pages from kv (token dim) into the pool.
-        Pads both the id list (to a pow2 bucket: bounded compile variants;
-        padding targets scratch page 0) and the kv token dim (the pow2 span can
-        exceed the prefill bucket — dynamic_slice rejects, never clamps)."""
-        n = len(page_ids)
-        pb = next(b for b in _buckets_upto(self.num_pages) if b >= n)
-        padded = np.zeros(pb, np.int32)
-        padded[:n] = page_ids
-        span_end = start_token + pb * self.page_size
-        width = kv[0].shape[2]
-        if width < span_end:
-            pad = [(0, 0), (0, 0), (0, span_end - width), (0, 0), (0, 0)]
-            kv = (jnp.pad(kv[0], pad), jnp.pad(kv[1], pad))
-        self.k_pool, self.v_pool = self._scatter(
-            (self.k_pool, self.v_pool), kv, jnp.asarray(padded), start_token)
 
     # ------------------------------------------------------------ admission
     def _alloc(self, n: int) -> list[int]:
@@ -358,89 +310,6 @@ class PrefixKVPool:
                 if pages is not None:
                     self.tree.release(prompt_ids)
 
-    def gather_for_prefill(self, page_ids: list[int], seq_bucket: int,
-                           cache: tuple) -> tuple:
-        """Place cached pages at the head of a fresh [L,1,seq_bucket,...] prefill
-        cache. Returns the updated cache."""
-        if not page_ids:
-            return cache
-        pb = next(b for b in _buckets_upto(self.num_pages) if b >= len(page_ids))
-        padded = np.zeros(pb, np.int32)  # pad → scratch page 0 (harmless reads)
-        padded[: len(page_ids)] = page_ids
-        k_blk, v_blk = self._gather((self.k_pool, self.v_pool),
-                                    jnp.asarray(padded), pb)
-        span = min(pb * self.page_size, seq_bucket)
-        k, v = cache
-        k = jax.lax.dynamic_update_slice(
-            k, k_blk[:, :, :span].astype(k.dtype), (0, 0, 0, 0, 0))
-        v = jax.lax.dynamic_update_slice(
-            v, v_blk[:, :, :span].astype(v.dtype), (0, 0, 0, 0, 0))
-        return (k, v)
-
-    def store_prefill(self, prompt_ids: list[int], cached_pages: list[int],
-                      kv: tuple) -> list[int]:
-        """After prefill: scatter the NEW full pages into the pool and record the
-        whole prompt's page chain in the radix tree. Returns the full-page chain
-        (cached + new) for the admitting slot's page table."""
-        total_pages = len(prompt_ids) // self.page_size
-        n_new = total_pages - len(cached_pages)
-        if n_new <= 0:
-            return list(cached_pages)
-        try:
-            new_ids = self._alloc(n_new)
-        except MemoryError:
-            logger.debug("pool exhausted; skipping prefix store")
-            return list(cached_pages)
-        try:
-            self._scatter_full_pages(kv, new_ids,
-                                     len(cached_pages) * self.page_size)
-        except Exception:
-            self.allocator.free([p - self._page_offset for p in new_ids])
-            raise
-        chain = list(cached_pages) + new_ids
-        with self._tree_lock:
-            _, unused = self.tree.insert_tracked(
-                prompt_ids[: total_pages * self.page_size], chain)
-        # Single-threaded (match pinned the prefix just above) the tree
-        # consumes exactly new_ids and ``unused`` == cached_pages. Handle
-        # the general contract anyway: a new page the tree declined (the
-        # position was already cached) stays PRIVATE to this chain —
-        # refcounted by the slot, never tree-owned — instead of being
-        # mislabeled as shared (insert_tracked exists because a count-only
-        # contract leaked pages in the sanitizer exercise).
-        declined = set(unused)
-        self._tree_owned.update(p for p in new_ids if p not in declined)
-        self.admissions += 1
-        return chain
-
-    @partial(jax.jit, static_argnums=(0,))
-    def _scatter_tail(self, pools, kv, start_token, page_id):
-        """Write one page worth of kv tokens starting at start_token into pool
-        page page_id (the slot's partial tail after prefill; positions past the
-        prompt are garbage masked by length and overwritten by decode)."""
-        k_pool, v_pool = pools
-        k_new, v_new = kv
-        L = k_new.shape[0]
-        k_page = jax.lax.dynamic_slice_in_dim(
-            k_new[:, 0], start_token, self.page_size, axis=1).astype(k_pool.dtype)
-        v_page = jax.lax.dynamic_slice_in_dim(
-            v_new[:, 0], start_token, self.page_size, axis=1).astype(v_pool.dtype)
-        return (k_pool.at[:, page_id].set(k_page.reshape(L, self.page_size, -1)),
-                v_pool.at[:, page_id].set(v_page.reshape(L, self.page_size, -1)))
-
-    def scatter_tail(self, kv: tuple, start_token: int, page_id: int) -> None:
-        """Host wrapper: place a slot's partial tail tokens into its private
-        page. Pads kv when the prefill bucket is shorter than one page past
-        start_token (dynamic_slice would otherwise clamp the start)."""
-        bucket = kv[0].shape[2]
-        if bucket < start_token + self.page_size:
-            pad = [(0, 0), (0, 0), (0, start_token + self.page_size - bucket),
-                   (0, 0), (0, 0)]
-            kv = (jnp.pad(kv[0], pad), jnp.pad(kv[1], pad))
-        self.k_pool, self.v_pool = self._scatter_tail(
-            (self.k_pool, self.v_pool), kv,
-            jnp.asarray(start_token, jnp.int32), jnp.asarray(page_id, jnp.int32))
-
     def release(self, prompt_ids: list[int]) -> None:
         with self._tree_lock:
             self.tree.release(prompt_ids)
@@ -449,51 +318,6 @@ class PrefixKVPool:
     def pages_for(self, length: int) -> int:
         return (length + self.page_size - 1) // self.page_size
 
-    def admit_slot(self, prompt_ids: list[int], cached_pages: list[int],
-                   kv: tuple) -> list[int]:
-        """Place one request's prefilled KV into pool pages for paged decode.
-
-        Full prompt pages go through the shared radix tree (store_prefill) so
-        later requests reuse them; the partial tail lands in a private page.
-        Every chain page is ref'd for the slot's lifetime — call
-        release_slot(chain) on completion. Raises MemoryError when the pool
-        cannot hold the request even after eviction."""
-        T = len(prompt_ids)
-        full = T // self.page_size
-        tail = T - full * self.page_size
-        chain = self.store_prefill(prompt_ids, cached_pages, kv)
-        # Ref IMMEDIATELY, before any further allocation: the tail/private
-        # allocs below can trigger tree eviction, and on a full pool the
-        # evictor may pick THIS request's just-inserted (unpinned) entry —
-        # un-ref'd, its pages would free and re-allocate into the same
-        # chain as the tail page (chain [p, p]: the slot then decodes over
-        # its own prefix KV). Found by the bounded model checker
-        # (tests/test_model_check_pool.py, invariant I5).
-        self.ref_pages(chain)
-        refed = list(chain)
-        try:
-            if len(chain) < full:
-                # tree store skipped (pool pressure): hold the remaining full
-                # pages privately so the slot can still decode
-                missing = full - len(chain)
-                ids = self._alloc(missing)
-                self.ref_pages(ids)
-                refed.extend(ids)
-                self._scatter_full_pages(kv, ids, len(chain) * self.page_size)
-                chain = chain + ids
-            if tail:
-                tid = self._alloc(1)[0]
-                self.ref_pages([tid])
-                refed.append(tid)
-                self.scatter_tail(kv, full * self.page_size, tid)
-                chain = chain + [tid]
-        except Exception:
-            # unref everything this admission holds — tree-owned pages stay
-            # cached, private ones return to the allocator
-            self.unref_pages(refed)
-            raise
-        return chain
-
     def commit_chain(self, prompt_ids: list[int], chain: list[int],
                      snapshots: Optional[list[tuple[int, int]]] = None
                      ) -> None:
@@ -501,8 +325,10 @@ class PrefixKVPool:
         pages (no scatter pass) — after the final chunk, record the prompt's
         FULL pages in the radix tree so later requests share them zero-copy.
         Pages the tree declines (a racing same-prefix admission already
-        cached those positions) simply stay private to the chain, exactly
-        like store_prefill's general contract. ``snapshots`` (a model with
+        cached those positions) simply stay PRIVATE to the chain —
+        refcounted by the slot, never tree-owned (insert_tracked exists
+        because a count-only contract leaked pages in the sanitizer
+        exercise). ``snapshots`` (a model with
         recurrent state): ``(tokens, row)`` pairs the caller took with
         :meth:`take_snapshot` where a mixed call ended on ``tokens``; each
         goes to the page that ends there if the tree now owns that page and

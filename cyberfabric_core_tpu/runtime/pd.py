@@ -1,6 +1,6 @@
 """Prefill/decode disaggregated serving pool (PD split).
 
-BENCH_RAGGED's residual decode-ITL tail is prefill interference: a cold
+The residual decode-ITL tail of mixed rounds is prefill interference: a cold
 prompt storm landing on a unified replica steals the decode round's dispatch
 budget even with Sarathi-style chunking — the storm rounds are "mixed"/
 "prefill" kinds in ``stats()["pipeline"]["dispatch_ms_by_kind"]``, and a

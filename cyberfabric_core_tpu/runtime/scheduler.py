@@ -6,16 +6,16 @@ mid-flight, decode runs lockstep chunks across ALL active slots, finished slots
 free immediately for the next waiting request. Unlike the lockstep batcher
 (worker._DynamicBatcher), a long generation never blocks a short one.
 
-Device programs (all jitted, caches donated):
-- prefill_collect: one request's prompt → last hidden + its kv [L, 1, T, Hkv, D]
-- batched prefill: up to ``prefill_coalesce`` COLD pending requests in one
-  multi-row dispatch (per-row key streams — coalescing never changes tokens)
-- insert_slot_kv:  scatter that kv into the pool at the slot index
-- decode chunk:    k fused steps over all slots (inactive slots compute garbage
-  that is masked host-side — the static shape is the price of zero recompiles)
+Device programs (jitted, the page pools donated):
+- mixed_step:         every decode row's next token and one prefilling slot's
+  prompt chunk in one pass over the weights; a prompt is only ever computed
+  as such chunks, admitted into a slot in PREFILL phase with no device work
+- paged_decode_chunk: k fused steps over all slots (inactive slots compute
+  garbage that is masked host-side — the static shape is the price of zero
+  recompiles)
 
-The decode loop is PIPELINED (paged mode): host work and device work overlap
-instead of alternating.
+The decode loop is PIPELINED: host work and device work overlap instead of
+alternating.
 
 - N-deep lookahead (the epoch ring): up to ``decode_lookahead`` chunks are
   kept in flight beyond the one being drained, each chained off the previous
@@ -52,7 +52,7 @@ instead of alternating.
 - Device-resident sampling state: temp/top_p/top_k/lengths/active/finished/
   stop-ids/limits live on device and only CHANGED rows are patched at
   admission/finish/preempt/resume; the page table patches changed rows
-  instead of re-uploading. This holds for the dense (non-paged) rounds too.
+  instead of re-uploading.
 - Tenant isolation: the pending queue is PER-TENANT FIFO deques drained by
   token-weighted fair scheduling (``TenantFairQueue`` — a VTC-style virtual
   counter per tenant, charged with the prefill + decode tokens actually
@@ -111,10 +111,9 @@ from ..modkit.metrics import bump_counter
 from ..modkit.telemetry import (get_global_tracer, reset_log_context,
                                 set_log_context, traceparent_ids)
 from ..ops.rope import rope_frequencies
-from ..ops.sampling import sample_token, sample_token_per_slot, split_keys_per_slot
+from ..ops.sampling import sample_token_per_slot, split_keys_per_slot
 from .engine import (EngineConfig, SamplingParams, SchedulerSaturated,
-                     StepEvent, TenantQuotaExceeded, TenantSaturated,
-                     build_decode_chunk_fn)
+                     StepEvent, TenantQuotaExceeded, TenantSaturated)
 from .speculative import NgramProposer, greedy_accept_counts
 
 logger = logging.getLogger("scheduler")
@@ -143,14 +142,14 @@ class _SlotState:
     stops: frozenset[int]
     emitted: int = 0
     request_index: int = 0  # external correlation id
-    chain: Optional[list[int]] = None  # paged mode: page ids held by this slot
+    chain: Optional[list[int]] = None  # page ids held by this slot
     #: W3C traceparent the gateway propagated through submit; trace_sampled is
     #: parsed ONCE at submission — the decode hot loop's span guard is a
     #: single bool check (the disarmed-failpoint pattern), so an unsampled
     #: trace costs ~nothing per chunk
     trace: Optional[str] = None
     trace_sampled: bool = False
-    #: mixed-batch chunked prefill (paged mode): a slot is admitted in
+    #: mixed-batch chunked prefill: a slot is admitted in
     #: "prefill" phase with NO device work done yet — its prompt is consumed
     #: chunk-by-chunk inside decode rounds (the ragged dispatch) and the slot
     #: flips to "decode" when the last chunk lands. ``prefill_key`` holds the
@@ -177,7 +176,7 @@ class _SlotState:
     #: gateway/worker): decode tokens are charged to its virtual counter,
     #: per-tenant caps count this slot, and the cap sweep can yield it
     tenant: str = "default"
-    #: batched speculative decoding (paged mode, scheduler_spec_k > 0): the
+    #: batched speculative decoding (scheduler_spec_k > 0): the
     #: per-stream prompt-lookup proposer, fed every emitted token from
     #: _emit_token. Armed at decode activation only for ELIGIBLE requests —
     #: temperature 0 (verification is argmax equality: lossless) whose token
@@ -198,8 +197,7 @@ class _Pending:
     sampling: SamplingParams
     emit: Callable[[StepEvent], None]
     enqueued_at: float = field(default_factory=time.monotonic)
-    #: paged mode: per-request PRNG key, assigned at TAKE time in FIFO order so
-    #: coalescing/partitioning can never reorder the shared-rng split sequence
+    #: per-request PRNG key, assigned at TAKE time in FIFO order
     key: Any = None
     trace: Optional[str] = None  # W3C traceparent from the gateway span
     #: absolute monotonic deadline (None = unbounded); a pending entry whose
@@ -486,14 +484,6 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 f"pd_role must be '', 'prefill' or 'decode', got "
                 f"{config.pd_role!r}")
-        if self.pd_role and config.prefix_cache_pages <= 0:
-            raise ValueError(
-                f"pd_role={self.pd_role!r} requires the paged pool "
-                "(prefix_cache_pages > 0) — KV handoff moves pool pages")
-        if self.pd_role == "prefill" and not config.mixed_batch:
-            raise ValueError(
-                "pd_role='prefill' requires mixed_batch=True (prefill-role "
-                "engines run chunked prefill through the ragged dispatch)")
         #: set by PDServingPool on prefill-role engines: called on the
         #: scheduler thread with the _Suspended handoff record right after
         #: the first token samples. Never set on unified/decode engines.
@@ -508,12 +498,15 @@ class ContinuousBatchingEngine:
                 "pools own one device per engine; shard OR replicate, "
                 "not both)")
         page = config.prefix_page_size
-        paged_planned = config.prefix_cache_pages > 0
-        planned_pages = None
-        if paged_planned:
-            pmax = -(-config.max_seq_len // page)
-            planned_pages = max(config.prefix_cache_pages,
-                                config.max_batch * pmax + 1)
+        self.n_slots = config.max_batch
+        self.pmax = -(-config.max_seq_len // page)
+        # every slot must be able to hold a full-window chain: size the
+        # pool so capacity extension can always succeed via eviction
+        num_pages = max(config.prefix_cache_pages,
+                        self.n_slots * self.pmax + 1)
+        if num_pages > config.prefix_cache_pages:
+            logger.info("prefix_cache_pages %d below slot minimum; using %d",
+                        config.prefix_cache_pages, num_pages)
         if self.tp > 1 or config.hbm_bytes_per_device > 0:
             # feasibility gate BEFORE any allocation: an over-HBM plan dies
             # here as a typed error (parallel/feasibility.py derives the
@@ -525,7 +518,7 @@ class ContinuousBatchingEngine:
                 self.model_config, self.tp,
                 quantization=config.quantization, dtype=self.dtype,
                 max_batch=config.max_batch, max_seq_len=config.max_seq_len,
-                page_size=page, num_pages=planned_pages,
+                page_size=page, num_pages=num_pages,
                 state_rows=(config.max_batch + self._state_snapshot_rows()
                             if self._has_state else None),
                 hbm_bytes=config.hbm_bytes_per_device or None)
@@ -606,7 +599,6 @@ class ContinuousBatchingEngine:
         )
         if self.mesh is not None:
             self.rope_tables = self._dev(self.rope_tables)
-        self.n_slots = config.max_batch
         self._rng = jax.random.PRNGKey(seed)
 
         # host-side slot state (mirrors of the device-resident rows)
@@ -616,8 +608,8 @@ class ContinuousBatchingEngine:
 
         self._last_tokens = self._dev(jnp.zeros((self.n_slots,), jnp.int32))
 
-        # device-resident per-slot sampling/termination state (paged AND
-        # dense rounds): patched row-wise at admission/finish/preempt/resume,
+        # device-resident per-slot sampling/termination state: patched
+        # row-wise at admission/finish/preempt/resume,
         # never re-uploaded per round. The stop-id rows (-1 padded to
         # device_stop_width) + limit lengths let the decode program freeze
         # finished rows on-device; _dev_term marks slots whose FULL stop set
@@ -637,46 +629,23 @@ class ContinuousBatchingEngine:
         self._limit_dev = self._dev(jnp.zeros((self.n_slots,), jnp.int32))
         self._dev_term = np.ones(self.n_slots, bool)
 
-        # paged decode (default): slot KV lives in ONE paged pool shared with
-        # the prefix cache — decode attention reads through per-slot page
-        # tables (ops/paged_attention.py), prefix pages are shared zero-copy,
-        # and idle slots cost one scratch-page read instead of a max_seq scan.
-        # config.prefix_cache_pages <= 0 opts out (dense per-slot cache).
-        self.pool = None
-        self.paged = config.prefix_cache_pages > 0
-        if self.paged:
-            from .paged import PrefixKVPool
+        # slot KV lives in ONE paged pool shared with the prefix cache —
+        # decode attention reads through per-slot page tables
+        # (ops/paged_attention.py), prefix pages are shared zero-copy, and
+        # idle slots cost one scratch-page read instead of a max_seq scan.
+        from .paged import PrefixKVPool
 
-            page = config.prefix_page_size
-            self.pmax = -(-config.max_seq_len // page)
-            # every slot must be able to hold a full-window chain: size the
-            # pool so capacity extension can always succeed via eviction
-            min_pages = self.n_slots * self.pmax + 1
-            num_pages = max(config.prefix_cache_pages, min_pages)
-            if num_pages > config.prefix_cache_pages:
-                logger.info("prefix_cache_pages %d below slot minimum; using %d",
-                            config.prefix_cache_pages, num_pages)
-            self.pool = PrefixKVPool(
-                self.model_config, num_pages=num_pages,
-                page_size=page, dtype=self.dtype,
-                sharding=self._pool_sharding,
-                state_slots=self.n_slots if self._has_state else 0,
-                state_snapshots=self._state_snapshot_rows())
-            self.page_table = np.zeros((self.n_slots, self.pmax), np.int32)
-            self._page_table_dev = self._dev(jnp.asarray(self.page_table))
-            self._pt_dirty_rows: set[int] = set()
-            self.cache = None  # no dense pool — HBM belongs to the paged pool
-            self._slot_keys = self._dev(jax.random.split(
-                jax.random.PRNGKey(seed ^ 0x5EED), self.n_slots))
-        else:
-            self.cache = llama.init_cache(
-                self.model_config, self.n_slots, config.max_seq_len, self.dtype)
-            if self.mesh is not None:
-                from ..parallel.sharding import dense_cache_sharding
-
-                self.cache = jax.device_put(
-                    self.cache, dense_cache_sharding(self.model_config,
-                                                     self.mesh))
+        self.pool = PrefixKVPool(
+            self.model_config, num_pages=num_pages,
+            page_size=page, dtype=self.dtype,
+            sharding=self._pool_sharding,
+            state_slots=self.n_slots if self._has_state else 0,
+            state_snapshots=self._state_snapshot_rows())
+        self.page_table = np.zeros((self.n_slots, self.pmax), np.int32)
+        self._page_table_dev = self._dev(jnp.asarray(self.page_table))
+        self._pt_dirty_rows: set[int] = set()
+        self._slot_keys = self._dev(jax.random.split(
+            jax.random.PRNGKey(seed ^ 0x5EED), self.n_slots))
 
         from collections import deque as _deque
 
@@ -737,9 +706,6 @@ class ContinuousBatchingEngine:
         #: therefore never poison the gate into rejecting all traffic.
         self._prefill_rates: "_rate_deque[float]" = _rate_deque(maxlen=32)
         self._suspended: "_deque[_Suspended]" = _deque()
-        #: mixed-batch chunked prefill (Sarathi-style piggybacking through the
-        #: ragged kernel) — paged mode only; dense mode has no page chains
-        self.mixed = self.paged and config.mixed_batch
         #: a state snapshot can be taken where a mixed call ends on a multiple
         #: of this many tokens: the prefill budget, in whole pages (0: none)
         budget = config.prefill_budget_tokens
@@ -769,20 +735,14 @@ class ContinuousBatchingEngine:
         #: the lookahead ring: dispatched-but-undrained chunks, oldest first.
         #: Ring size beyond the drained chunk is capped at _lookahead_depth.
         self._ring: "_deque[_InflightChunk]" = _deque()
-        self._lookahead_depth = (config.resolve_lookahead_depth()
-                                 if self.paged else 0)
+        self._lookahead_depth = config.resolve_lookahead_depth()
         #: batched speculative decoding: k draft tokens per speculating slot
         #: per round, verified as a q_len=k+1 ragged span in the mixed-batch
-        #: dispatch (paged mode only — the span rides the ragged kernel).
+        #: dispatch.
         #: 0 disables everything: no spec program is built and every round
         #: takes the exact pre-speculation code path (the bit-identity
         #: default the k=0 goldens pin).
-        self.spec_k = (max(0, int(config.scheduler_spec_k))
-                       if self.paged else 0)
-        if config.scheduler_spec_k > 0 and not self.paged:
-            logger.info("scheduler_spec_k=%d needs the paged scheduler "
-                        "(prefix_cache_pages > 0); speculation disabled",
-                        config.scheduler_spec_k)
+        self.spec_k = max(0, int(config.scheduler_spec_k))
         self._spec_w = self.spec_k + 1
         #: acceptance observability (stats()["speculative"]): rounds that
         #: carried at least one draft span, the subset that also carried
@@ -810,7 +770,6 @@ class ContinuousBatchingEngine:
         self.resume_latency_samples: "deque[float]" = deque(maxlen=512)
         self.decode_rounds = 0
         self.lookahead_rounds = 0
-        self.coalesced_prefills = 0
         self.mixed_rounds = 0
         self.prefill_chunks = 0
         self.chunked_prefill_tokens = 0
@@ -837,19 +796,9 @@ class ContinuousBatchingEngine:
 
     # ------------------------------------------------------------------ programs
     def _refuse_without_state_support(self, config: EngineConfig) -> None:
-        """A model with recurrent state is served by the paged mixed-batch
-        path on one device; each mode below lacks one named thing."""
+        """A model with recurrent state is served on one device, unified;
+        each mode below lacks one named thing."""
         name = self.model_config.name
-        if config.prefix_cache_pages <= 0:
-            raise ValueError(
-                f"{name}: the dense (non-paged) mode has no state slab; "
-                "recurrent state lives beside the page pool "
-                "(prefix_cache_pages > 0)")
-        if not config.mixed_batch:
-            raise ValueError(
-                f"{name}: mixed_batch=False prefills through the dense "
-                "llama forward, which has no mixer; prompts of a model with "
-                "recurrent state run as mixed-step chunks")
         if config.scheduler_spec_k > 0:
             raise ValueError(
                 f"{name}: scheduler_spec_k > 0 needs a state rollback: a "
@@ -873,330 +822,260 @@ class ContinuousBatchingEngine:
         cfg = self.model_config
         model = self._model
         k_steps = max(1, self.config.decode_chunk)
+        rope = self.rope_tables
+        max_seq = self.config.max_seq_len
 
-        # tp meshes take the jnp prefill attention path: the flash Pallas
-        # kernel cannot auto-partition under GSPMD (the same constraint the
-        # AOT tp variants honor — aot_tpu.py compiles the tp prefill with
-        # use_flash=False), so a live-TPU tp engine must not jit it either.
-        # The paged decode/ragged kernels stay real: they run under
-        # shard_map over the tp head axis (_attn_mesh).
-        use_flash = self.config.resolve_use_flash() and self.mesh is None
+        def paged_forward(forward, params, ids, caches, *tail, **kwargs):
+            """One of the model's paged forward passes over the cache
+            operands: the two pools, and the state slab where the model
+            has one. Returns (hidden, caches)."""
+            if not self._has_state:
+                return forward(params, cfg, ids, caches, *tail, **kwargs)
+            hidden, pools, state = forward(
+                params, cfg, ids, caches[:2], *tail, state=caches[2],
+                **kwargs)
+            return hidden, (*pools, state)
 
-        def prefill(params, ids, lengths, rng, temp, top_p, top_k, rope):
-            last_h, kv = llama.prefill_collect(params, cfg, ids, lengths, rope,
-                                               use_flash=use_flash)
-            logits = llama.lm_head_logits(params, cfg, last_h)
-            rng, sub = jax.random.split(rng)
-            first = sample_token(logits, sub, temp, top_p, top_k)
-            return first, kv, rng
+        def decode_chunk_body(params, caches, page_table,
+                              last_tokens, lengths, active, finished,
+                              stop_ids, limit_lens, keys,
+                              temp, top_p, top_k):
+            """k fused paged decode steps; per-slot key streams so each
+            request's seed reproduces its tokens (round-1 advisory).
+            Lengths are device-resident: running rows advance by k inside
+            the program; inactive rows pin back to 0 so garbage positions
+            never creep past the rope table / page chain bounds.
 
-        self._prefill_fn = jax.jit(prefill)
+            Device-side termination: each step matches the sampled token
+            against the row's padded stop ids and its length limit
+            (max-tokens bound; the window bound fires at the chunk's last
+            step, mirroring the host force-length rule), and a finished
+            row FREEZES — last token, key stream, length and KV writes
+            all stop advancing (writes park on scratch page 0), emitting
+            -1 sentinels. A chunk chained off this one therefore stays
+            valid across mid-chunk finishes, which is what lets the
+            lookahead ring survive them."""
 
-        def suffix_prefill(params, ids, suffix_len, cached_len, cache,
-                           rng, temp, top_p, top_k):
-            """Prefill only the uncached suffix against gathered prefix history
-            (jnp attention path — queries must see the cached slots)."""
-            B, T = ids.shape
-            positions = cached_len + jnp.broadcast_to(
-                jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
-            start = jnp.full((B,), cached_len, jnp.int32)
-            hidden, kv = llama.forward(params, cfg, ids, positions, cache, start,
-                                       self.rope_tables)
-            last_h = llama.gather_last_hidden(hidden, suffix_len)
-            logits = llama.lm_head_logits(params, cfg, last_h)
-            rng, sub = jax.random.split(rng)
-            first = sample_token(logits, sub, temp, top_p, top_k)
-            return first, kv, rng
-
-        self._suffix_prefill_fn = jax.jit(suffix_prefill)
-
-        if self.paged:
-            rope = self.rope_tables
-
-            def batch_prefill(params, ids, lengths, keys, temp, top_p, top_k,
-                              rope_t):
-                """Coalesced COLD prefill: B pending requests, one dispatch.
-                Per-row key streams advance exactly as the single-request path
-                (split, then sample with the subkey) so coalescing never
-                changes any request's tokens."""
-                last_h, kv = llama.prefill_collect(params, cfg, ids, lengths,
-                                                   rope_t, use_flash=use_flash)
-                logits = llama.lm_head_logits(params, cfg, last_h)
-                keys, subs = split_keys_per_slot(keys)
-                first = sample_token_per_slot(logits, subs, temp, top_p, top_k)
-                return first, kv, keys
-
-            self._batch_prefill_fn = jax.jit(batch_prefill)
-
-            max_seq = self.config.max_seq_len
-
-            def paged_forward(forward, params, ids, caches, *tail, **kwargs):
-                """One of the model's paged forward passes over the cache
-                operands: the two pools, and the state slab where the model
-                has one. Returns (hidden, caches)."""
-                if not self._has_state:
-                    return forward(params, cfg, ids, caches, *tail, **kwargs)
-                hidden, pools, state = forward(
-                    params, cfg, ids, caches[:2], *tail, state=caches[2],
-                    **kwargs)
-                return hidden, (*pools, state)
-
-            def decode_chunk_body(params, caches, page_table,
-                                  last_tokens, lengths, active, finished,
-                                  stop_ids, limit_lens, keys,
-                                  temp, top_p, top_k):
-                """k fused paged decode steps; per-slot key streams so each
-                request's seed reproduces its tokens (round-1 advisory).
-                Lengths are device-resident: running rows advance by k inside
-                the program; inactive rows pin back to 0 so garbage positions
-                never creep past the rope table / page chain bounds.
-
-                Device-side termination: each step matches the sampled token
-                against the row's padded stop ids and its length limit
-                (max-tokens bound; the window bound fires at the chunk's last
-                step, mirroring the host force-length rule), and a finished
-                row FREEZES — last token, key stream, length and KV writes
-                all stop advancing (writes park on scratch page 0), emitting
-                -1 sentinels. A chunk chained off this one therefore stays
-                valid across mid-chunk finishes, which is what lets the
-                lookahead ring survive them."""
-
-                def step(carry, j):
-                    caches, toks, lens, fin, keys = carry
-                    run = active & jnp.logical_not(fin)
-                    hidden, caches = paged_forward(
-                        model.forward_paged_decode, params, toks[:, None],
-                        caches, page_table, lens, rope,
-                        write_mask=run, mesh=self._attn_mesh)
-                    logits = model.lm_head_logits(params, cfg, hidden[:, 0, :])
-                    keys2, subs = split_keys_per_slot(keys)
-                    nxt = sample_token_per_slot(logits, subs, temp, top_p,
-                                                top_k)
-                    new_lens = lens + 1
-                    is_stop = jnp.any(nxt[:, None] == stop_ids, axis=1)
-                    hit = (new_lens >= limit_lens) | (
-                        (j == k_steps - 1) & (new_lens + k_steps > max_seq))
-                    emit = jnp.where(run, nxt, -1)
-                    return (caches, jnp.where(run, nxt, toks),
-                            jnp.where(run, new_lens, lens),
-                            fin | (run & (is_stop | hit)),
-                            jnp.where(run[:, None], keys2, keys)), emit
-
-                (caches, last, lens, fin, keys), toks = jax.lax.scan(
-                    step, (caches, last_tokens, lengths, finished, keys),
-                    jnp.arange(k_steps, dtype=jnp.int32))
-                lens = jnp.where(active, lens, 0)
-                return (toks.T, *caches, last, keys, lens, fin)
-
-            # the program a llama-family model gets takes the two pools, as
-            # it always has; a model with recurrent state gets the same body
-            # with the state slab as a third donated operand. One name for
-            # both: it is what the compile log and the device trace show.
-            if self._has_state:
-                def paged_decode_chunk(params, k_pool, v_pool, state, *rest):
-                    return decode_chunk_body(params, (k_pool, v_pool, state),
-                                             *rest)
-            else:
-                def paged_decode_chunk(params, k_pool, v_pool, *rest):
-                    return decode_chunk_body(params, (k_pool, v_pool), *rest)
-
-            donate = (1, 2, 3) if self._has_state else (1, 2)
-            self._paged_decode_fn = jax.jit(paged_decode_chunk,
-                                            donate_argnums=donate)
-
-            def mixed_step_body(params, caches, page_table, q_ids, q_lens,
-                                prefill_hist, lane_rows, last_tokens, lengths,
-                                active, finished, sample_mask, final_mask,
-                                final_lens, stop_ids, limit_lens, keys, temp,
-                                top_p, top_k):
-                """One mixed-batch round over the tokens it has: every decode
-                row takes its next token (the decode group: ``last_tokens``,
-                ``lengths``, ``run``) while the lane — ``q_ids [R, Qc]``, the
-                chunk of the prefilling slot ``lane_rows`` names — consumes a
-                prompt chunk, in ONE pass over the weights of
-                ``B + R*Qc`` positions. The compiled shape is keyed by the
-                lane's width alone. ``sample_mask`` rows (decode +
-                final-chunk prefill) draw from their key stream; everyone
-                else's key is untouched, so a mid-prefill request's seed
-                reproduces exactly the phase-separated stream.
-
-                Device-side termination + ring spanning: sampled rows run the
-                same stop/limit/window checks as the decode chunk and fold
-                into the finished mask; ``final_mask`` rows flip to decode ON
-                DEVICE (active_out, lengths = final_lens, first token in
-                last_out) so lookahead chunks can chain directly off this
-                dispatch when the prefill queue drains — the mixed→pure
-                transition needs no synchronous fallback round."""
-                run = active & jnp.logical_not(finished)
-                last_h, caches = paged_forward(
-                    model.forward_paged_mixed, params, q_ids, caches,
-                    page_table, prefill_hist, q_lens, rope,
-                    mesh=self._attn_mesh, rows=lane_rows,
-                    decode=llama.DecodeGroup(last_tokens, lengths, run))
-                logits = model.lm_head_logits(params, cfg, last_h)
+            def step(carry, j):
+                caches, toks, lens, fin, keys = carry
+                run = active & jnp.logical_not(fin)
+                hidden, caches = paged_forward(
+                    model.forward_paged_decode, params, toks[:, None],
+                    caches, page_table, lens, rope,
+                    write_mask=run, mesh=self._attn_mesh)
+                logits = model.lm_head_logits(params, cfg, hidden[:, 0, :])
                 keys2, subs = split_keys_per_slot(keys)
-                nxt = sample_token_per_slot(logits, subs, temp, top_p, top_k)
+                nxt = sample_token_per_slot(logits, subs, temp, top_p,
+                                            top_k)
+                new_lens = lens + 1
+                is_stop = jnp.any(nxt[:, None] == stop_ids, axis=1)
+                hit = (new_lens >= limit_lens) | (
+                    (j == k_steps - 1) & (new_lens + k_steps > max_seq))
+                emit = jnp.where(run, nxt, -1)
+                return (caches, jnp.where(run, nxt, toks),
+                        jnp.where(run, new_lens, lens),
+                        fin | (run & (is_stop | hit)),
+                        jnp.where(run[:, None], keys2, keys)), emit
+
+            (caches, last, lens, fin, keys), toks = jax.lax.scan(
+                step, (caches, last_tokens, lengths, finished, keys),
+                jnp.arange(k_steps, dtype=jnp.int32))
+            lens = jnp.where(active, lens, 0)
+            return (toks.T, *caches, last, keys, lens, fin)
+
+        # the program a llama-family model gets takes the two pools, as
+        # it always has; a model with recurrent state gets the same body
+        # with the state slab as a third donated operand. One name for
+        # both: it is what the compile log and the device trace show.
+        if self._has_state:
+            def paged_decode_chunk(params, k_pool, v_pool, state, *rest):
+                return decode_chunk_body(params, (k_pool, v_pool, state),
+                                         *rest)
+        else:
+            def paged_decode_chunk(params, k_pool, v_pool, *rest):
+                return decode_chunk_body(params, (k_pool, v_pool), *rest)
+
+        donate = (1, 2, 3) if self._has_state else (1, 2)
+        self._paged_decode_fn = jax.jit(paged_decode_chunk,
+                                        donate_argnums=donate)
+
+        def mixed_step_body(params, caches, page_table, q_ids, q_lens,
+                            prefill_hist, lane_rows, last_tokens, lengths,
+                            active, finished, sample_mask, final_mask,
+                            final_lens, stop_ids, limit_lens, keys, temp,
+                            top_p, top_k):
+            """One mixed-batch round over the tokens it has: every decode
+            row takes its next token (the decode group: ``last_tokens``,
+            ``lengths``, ``run``) while the lane — ``q_ids [R, Qc]``, the
+            chunk of the prefilling slot ``lane_rows`` names — consumes a
+            prompt chunk, in ONE pass over the weights of
+            ``B + R*Qc`` positions. The compiled shape is keyed by the
+            lane's width alone. ``sample_mask`` rows (decode +
+            final-chunk prefill) draw from their key stream; everyone
+            else's key is untouched, so a mid-prefill request's seed
+            reproduces its stream whatever rode beside it.
+
+            Device-side termination + ring spanning: sampled rows run the
+            same stop/limit/window checks as the decode chunk and fold
+            into the finished mask; ``final_mask`` rows flip to decode ON
+            DEVICE (active_out, lengths = final_lens, first token in
+            last_out) so lookahead chunks can chain directly off this
+            dispatch when the prefill queue drains — the mixed→pure
+            transition needs no synchronous fallback round."""
+            run = active & jnp.logical_not(finished)
+            last_h, caches = paged_forward(
+                model.forward_paged_mixed, params, q_ids, caches,
+                page_table, prefill_hist, q_lens, rope,
+                mesh=self._attn_mesh, rows=lane_rows,
+                decode=llama.DecodeGroup(last_tokens, lengths, run))
+            logits = model.lm_head_logits(params, cfg, last_h)
+            keys2, subs = split_keys_per_slot(keys)
+            nxt = sample_token_per_slot(logits, subs, temp, top_p, top_k)
+            sample = sample_mask & jnp.logical_not(finished)
+            keys_out = jnp.where(sample[:, None], keys2, keys)
+            new_last = jnp.where(sample, nxt, last_tokens)
+            new_lens = jnp.where(
+                run, lengths + 1,
+                jnp.where(final_mask, final_lens,
+                          jnp.where(active, lengths, 0)))
+            toks = jnp.where(sample, nxt, -1)
+            is_stop = jnp.any(nxt[:, None] == stop_ids, axis=1)
+            hit = (new_lens >= limit_lens) | (new_lens + k_steps > max_seq)
+            fin_out = finished | (sample & (is_stop | hit))
+            active_out = active | final_mask
+            return (toks, *caches, new_last, keys_out,
+                    new_lens, fin_out, active_out)
+
+        if self._has_state:
+            def mixed_step(params, k_pool, v_pool, state, *rest):
+                return mixed_step_body(params, (k_pool, v_pool, state),
+                                       *rest)
+        else:
+            def mixed_step(params, k_pool, v_pool, *rest):
+                return mixed_step_body(params, (k_pool, v_pool), *rest)
+
+        self._mixed_step_fn = jax.jit(mixed_step, donate_argnums=donate)
+
+        if self.spec_k:
+            spec_w = self._spec_w
+
+            def spec_mixed_step(params, k_pool, v_pool, page_table,
+                                q_ids, q_lens, prefill_hist, last_tokens,
+                                lengths, active, finished, sample_mask,
+                                final_mask, final_lens, spec_lens,
+                                stop_ids, limit_lens, keys,
+                                temp, top_p, top_k):
+                """mixed_step + k-token speculation: speculating rows run
+                their draft span (q_len = 1 + spec_lens ≤ spec_w, q_ids =
+                [last_token, d_1..d_d]) through the SAME ragged dispatch
+                as decode rows (q_len=1) and prefill-chunk rows. Greedy
+                accept/reject, accepted-length, per-position stop/limit
+                truncation and the length advance all happen HERE, on
+                device — only the [N, spec_w] emit matrix (-1 sentinels
+                past each row's commit) and the accept counts cross to
+                the host.
+
+                Rollback is rewrite-before-read: a rejected suffix's KV
+                sits at positions new_length..L+d of the row's own chain
+                pages — masked out of attention by the per-row length
+                bounds, and every later dispatch's span starts at the
+                committed length and scatters BEFORE it attends, so the
+                stale entries are overwritten before any read (the same
+                discipline the discarded-ring argument rests on). Non-
+                speculating rows compute bit-identically to mixed_step;
+                greedy speculating rows commit exactly the tokens plain
+                decode would have produced (acceptance is argmax
+                equality), so speculation changes speed, never text."""
+                run = active & jnp.logical_not(finished)
+                q_ids = q_ids.at[:, 0].set(
+                    jnp.where(active, last_tokens, q_ids[:, 0]))
+                hist = jnp.where(active, lengths, prefill_hist)
+                hidden, pools = llama.forward_paged_mixed(
+                    params, cfg, q_ids, (k_pool, v_pool), page_table,
+                    hist, q_lens, rope,
+                    write_mask=run | jnp.logical_not(active),
+                    mesh=self._attn_mesh)
+                last_h = llama.gather_last_hidden(hidden, q_lens)
+                logits = llama.lm_head_logits(params, cfg, last_h)
+                keys2, subs = split_keys_per_slot(keys)
+                nxt = sample_token_per_slot(logits, subs, temp, top_p,
+                                            top_k)
+                # verify: per-position argmax over the span's first
+                # spec_w positions (q_lens ≤ spec_w for speculating rows;
+                # prefill rows ignore these logits entirely)
+                N = q_ids.shape[0]
+                H = hidden.shape[-1]
+                span_h = jax.lax.dynamic_slice_in_dim(hidden, 0, spec_w,
+                                                      axis=1)
+                span_logits = llama.lm_head_logits(
+                    params, cfg, span_h.reshape(N * spec_w, H))
+                outs = jnp.argmax(span_logits, axis=-1).astype(
+                    jnp.int32).reshape(N, spec_w)
+                spec = (spec_lens > 0) & run
+                a = greedy_accept_counts(outs, q_ids[:, 1:spec_w],
+                                         spec_lens)
+                # committed[i] = the model's token after the accepted
+                # prefix of length i. Position 0 keeps the sampled path
+                # for non-spec rows (bit-identity with mixed_step);
+                # spec rows are greedy, so outs[:, 0] IS that argmax.
+                committed = outs.at[:, 0].set(
+                    jnp.where(spec, outs[:, 0], nxt))
+                n_commit = jnp.where(spec, a + 1, 1)
+                idx = jnp.arange(spec_w, dtype=jnp.int32)[None, :]
+                in_commit = idx < n_commit[:, None]
+                is_stop = jnp.any(
+                    committed[:, :, None] == stop_ids[:, None, :],
+                    axis=2)
+                # per-position termination, mirroring mixed_step's
+                # single-token rule exactly at idx 0 (final-chunk prefill
+                # rows carry lengths=0 on device — their post-token
+                # length is final_lens, hence eff_len)
+                eff_len = jnp.where(
+                    run, lengths,
+                    jnp.where(final_mask, final_lens - 1, lengths))
+                len_after = eff_len[:, None] + idx + 1
+                hit = (len_after >= limit_lens[:, None]) | (
+                    len_after + k_steps > max_seq)
+                fin_at = (is_stop | hit) & in_commit
+                # token i commits only while no stop/limit fired before
+                # it: the accepted suffix past a terminal is dropped ON
+                # DEVICE, the same truncation the scan chunk's freeze
+                # gives mid-chunk finishes
+                alive = jnp.cumprod(
+                    1 - jnp.pad(fin_at.astype(jnp.int32),
+                                ((0, 0), (1, 0)))[:, :spec_w],
+                    axis=1) > 0
+                emit = in_commit & alive
+                n_emit = jnp.sum(emit.astype(jnp.int32), axis=1)
                 sample = sample_mask & jnp.logical_not(finished)
+                toks = jnp.where(emit & sample[:, None], committed, -1)
+                new_last = jnp.where(
+                    sample,
+                    jnp.take_along_axis(
+                        committed,
+                        jnp.maximum(n_emit - 1, 0)[:, None],
+                        axis=1)[:, 0],
+                    last_tokens)
                 keys_out = jnp.where(sample[:, None], keys2, keys)
-                new_last = jnp.where(sample, nxt, last_tokens)
                 new_lens = jnp.where(
-                    run, lengths + 1,
+                    run, lengths + n_emit,
                     jnp.where(final_mask, final_lens,
                               jnp.where(active, lengths, 0)))
-                toks = jnp.where(sample, nxt, -1)
-                is_stop = jnp.any(nxt[:, None] == stop_ids, axis=1)
-                hit = (new_lens >= limit_lens) | (new_lens + k_steps > max_seq)
-                fin_out = finished | (sample & (is_stop | hit))
+                fin_out = finished | (sample & jnp.any(fin_at & emit,
+                                                       axis=1))
                 active_out = active | final_mask
-                return (toks, *caches, new_last, keys_out,
-                        new_lens, fin_out, active_out)
+                # accept counts ride the emit matrix's last column (-1
+                # for non-spec rows): ONE drain carries tokens AND the
+                # acceptance evidence — the round keeps its single
+                # sanctioned sync point (AS04)
+                a_out = jnp.where(spec, a, -1)
+                toks_out = jnp.concatenate([toks, a_out[:, None]],
+                                           axis=1)
+                return (toks_out, pools[0], pools[1], new_last,
+                        keys_out, new_lens, fin_out, active_out)
 
-            if self._has_state:
-                def mixed_step(params, k_pool, v_pool, state, *rest):
-                    return mixed_step_body(params, (k_pool, v_pool, state),
-                                           *rest)
-            else:
-                def mixed_step(params, k_pool, v_pool, *rest):
-                    return mixed_step_body(params, (k_pool, v_pool), *rest)
-
-            self._mixed_step_fn = jax.jit(mixed_step, donate_argnums=donate)
-
-            if self.spec_k:
-                spec_w = self._spec_w
-
-                def spec_mixed_step(params, k_pool, v_pool, page_table,
-                                    q_ids, q_lens, prefill_hist, last_tokens,
-                                    lengths, active, finished, sample_mask,
-                                    final_mask, final_lens, spec_lens,
-                                    stop_ids, limit_lens, keys,
-                                    temp, top_p, top_k):
-                    """mixed_step + k-token speculation: speculating rows run
-                    their draft span (q_len = 1 + spec_lens ≤ spec_w, q_ids =
-                    [last_token, d_1..d_d]) through the SAME ragged dispatch
-                    as decode rows (q_len=1) and prefill-chunk rows. Greedy
-                    accept/reject, accepted-length, per-position stop/limit
-                    truncation and the length advance all happen HERE, on
-                    device — only the [N, spec_w] emit matrix (-1 sentinels
-                    past each row's commit) and the accept counts cross to
-                    the host.
-
-                    Rollback is rewrite-before-read: a rejected suffix's KV
-                    sits at positions new_length..L+d of the row's own chain
-                    pages — masked out of attention by the per-row length
-                    bounds, and every later dispatch's span starts at the
-                    committed length and scatters BEFORE it attends, so the
-                    stale entries are overwritten before any read (the same
-                    discipline the discarded-ring argument rests on). Non-
-                    speculating rows compute bit-identically to mixed_step;
-                    greedy speculating rows commit exactly the tokens plain
-                    decode would have produced (acceptance is argmax
-                    equality), so speculation changes speed, never text."""
-                    run = active & jnp.logical_not(finished)
-                    q_ids = q_ids.at[:, 0].set(
-                        jnp.where(active, last_tokens, q_ids[:, 0]))
-                    hist = jnp.where(active, lengths, prefill_hist)
-                    hidden, pools = llama.forward_paged_mixed(
-                        params, cfg, q_ids, (k_pool, v_pool), page_table,
-                        hist, q_lens, rope,
-                        write_mask=run | jnp.logical_not(active),
-                        mesh=self._attn_mesh)
-                    last_h = llama.gather_last_hidden(hidden, q_lens)
-                    logits = llama.lm_head_logits(params, cfg, last_h)
-                    keys2, subs = split_keys_per_slot(keys)
-                    nxt = sample_token_per_slot(logits, subs, temp, top_p,
-                                                top_k)
-                    # verify: per-position argmax over the span's first
-                    # spec_w positions (q_lens ≤ spec_w for speculating rows;
-                    # prefill rows ignore these logits entirely)
-                    N = q_ids.shape[0]
-                    H = hidden.shape[-1]
-                    span_h = jax.lax.dynamic_slice_in_dim(hidden, 0, spec_w,
-                                                          axis=1)
-                    span_logits = llama.lm_head_logits(
-                        params, cfg, span_h.reshape(N * spec_w, H))
-                    outs = jnp.argmax(span_logits, axis=-1).astype(
-                        jnp.int32).reshape(N, spec_w)
-                    spec = (spec_lens > 0) & run
-                    a = greedy_accept_counts(outs, q_ids[:, 1:spec_w],
-                                             spec_lens)
-                    # committed[i] = the model's token after the accepted
-                    # prefix of length i. Position 0 keeps the sampled path
-                    # for non-spec rows (bit-identity with mixed_step);
-                    # spec rows are greedy, so outs[:, 0] IS that argmax.
-                    committed = outs.at[:, 0].set(
-                        jnp.where(spec, outs[:, 0], nxt))
-                    n_commit = jnp.where(spec, a + 1, 1)
-                    idx = jnp.arange(spec_w, dtype=jnp.int32)[None, :]
-                    in_commit = idx < n_commit[:, None]
-                    is_stop = jnp.any(
-                        committed[:, :, None] == stop_ids[:, None, :],
-                        axis=2)
-                    # per-position termination, mirroring mixed_step's
-                    # single-token rule exactly at idx 0 (final-chunk prefill
-                    # rows carry lengths=0 on device — their post-token
-                    # length is final_lens, hence eff_len)
-                    eff_len = jnp.where(
-                        run, lengths,
-                        jnp.where(final_mask, final_lens - 1, lengths))
-                    len_after = eff_len[:, None] + idx + 1
-                    hit = (len_after >= limit_lens[:, None]) | (
-                        len_after + k_steps > max_seq)
-                    fin_at = (is_stop | hit) & in_commit
-                    # token i commits only while no stop/limit fired before
-                    # it: the accepted suffix past a terminal is dropped ON
-                    # DEVICE, the same truncation the scan chunk's freeze
-                    # gives mid-chunk finishes
-                    alive = jnp.cumprod(
-                        1 - jnp.pad(fin_at.astype(jnp.int32),
-                                    ((0, 0), (1, 0)))[:, :spec_w],
-                        axis=1) > 0
-                    emit = in_commit & alive
-                    n_emit = jnp.sum(emit.astype(jnp.int32), axis=1)
-                    sample = sample_mask & jnp.logical_not(finished)
-                    toks = jnp.where(emit & sample[:, None], committed, -1)
-                    new_last = jnp.where(
-                        sample,
-                        jnp.take_along_axis(
-                            committed,
-                            jnp.maximum(n_emit - 1, 0)[:, None],
-                            axis=1)[:, 0],
-                        last_tokens)
-                    keys_out = jnp.where(sample[:, None], keys2, keys)
-                    new_lens = jnp.where(
-                        run, lengths + n_emit,
-                        jnp.where(final_mask, final_lens,
-                                  jnp.where(active, lengths, 0)))
-                    fin_out = finished | (sample & jnp.any(fin_at & emit,
-                                                           axis=1))
-                    active_out = active | final_mask
-                    # accept counts ride the emit matrix's last column (-1
-                    # for non-spec rows): ONE drain carries tokens AND the
-                    # acceptance evidence — the round keeps its single
-                    # sanctioned sync point (AS04)
-                    a_out = jnp.where(spec, a, -1)
-                    toks_out = jnp.concatenate([toks, a_out[:, None]],
-                                               axis=1)
-                    return (toks_out, pools[0], pools[1], new_last,
-                            keys_out, new_lens, fin_out, active_out)
-
-                self._spec_step_fn = jax.jit(spec_mixed_step,
-                                             donate_argnums=(1, 2))
-        else:
-            def insert(k_cache, v_cache, k_new, v_new, slot):
-                return llama.insert_slot_kv((k_cache, v_cache), (k_new, v_new), slot)
-
-            self._insert_fn = jax.jit(insert, donate_argnums=(0, 1))
-
-            # the SAME fused decode body as InferenceEngine — semantics cannot
-            # diverge between the lockstep engine and the dense scheduler.
-            # device_term adds the device-resident finished/stop/limit rows so
-            # dense rounds stop re-uploading host state (and finished rows
-            # freeze on-device, mirroring the paged path).
-            self._decode_fn = jax.jit(
-                build_decode_chunk_fn(cfg, k_steps, self.rope_tables,
-                                      max_seq=self.config.max_seq_len,
-                                      device_term=True),
-                donate_argnums=(1, 2))
+            self._spec_step_fn = jax.jit(spec_mixed_step,
+                                         donate_argnums=(1, 2))
         self._k_steps = k_steps
 
     def _bucket_for(self, length: int) -> int:
@@ -1264,8 +1143,7 @@ class ContinuousBatchingEngine:
         rid = request_id or f"req-{uuid.uuid4().hex[:16]}"
         tenant = tenant or "default"
         self._bucket_for(len(prompt_ids))  # validate early, in caller context
-        if self.paged and self._tenant_caps_armed \
-                and self.config.tenant_max_pages > 0:
+        if self._tenant_caps_armed and self.config.tenant_max_pages > 0:
             # hard page quota, checked against the request's WORST-CASE need
             # (full prompt + max_tokens): a request that can never fit the
             # tenant's quota must be rejected now, not admitted into a
@@ -1280,14 +1158,6 @@ class ContinuousBatchingEngine:
                     f"{self.config.tenant_max_pages} (prompt "
                     f"{len(prompt_ids)} + max_tokens {sampling.max_tokens})",
                     tenant=tenant)
-        if not self.paged and sampling.seed is not None:
-            # dense mode shares ONE key stream across the whole batch — a
-            # per-request seed cannot be honored there (the paged default
-            # carries per-slot key streams). Rejecting loudly beats silently
-            # sampling from the shared stream (round-2 verdict weak #5).
-            raise ValueError(
-                "SamplingParams.seed requires the paged scheduler "
-                "(prefix_cache_pages > 0); dense mode shares one RNG stream")
         if not self.active_slots and not self._suspended \
                 and not self._prefill_slots and self._pending.qsize() == 0:
             # idle→busy: restart the round-stall clock. last_round_at is
@@ -1378,9 +1248,6 @@ class ContinuousBatchingEngine:
         if self.pd_role == "prefill":
             raise RuntimeError(
                 "handoff target must be a decode-role or unified engine")
-        if not self.paged:
-            raise RuntimeError("handoff needs the paged pool "
-                               "(prefix_cache_pages > 0)")
         state = rec.state
         # (re-)arm speculation under THIS engine's spec config — the
         # prefill role runs with spec disabled, so the proposer arrives
@@ -1582,7 +1449,7 @@ class ContinuousBatchingEngine:
         self.slots[slot] = None
         self._release_free_slot(slot)
         self._deactivate_slot_device(slot)
-        if self.paged and state.chain is not None:
+        if state.chain is not None:
             self.pool.release_slot(state.chain)
             self._drop_pending_snapshots(state)
             self.page_table[slot, :] = 0
@@ -1735,8 +1602,7 @@ class ContinuousBatchingEngine:
         max_slots = self.config.tenant_max_slots
         max_pages = self.config.tenant_max_pages
         slots = self._tenant_slot_counts() if max_slots else {}
-        pages = self._tenant_page_counts() if (self.paged and max_pages) \
-            else {}
+        pages = self._tenant_page_counts() if max_pages else {}
         for tenant, n in slots.items():
             if n >= max_slots:
                 blocked.add(tenant)
@@ -1755,7 +1621,7 @@ class ContinuousBatchingEngine:
         preemption's device work already lives) preempts it to host through
         the existing `_preempt_slot` path. One victim per sweep, so a
         momentary overshoot never thrashes a tenant's whole fleet."""
-        if not self._tenant_caps_armed or not self.paged:
+        if not self._tenant_caps_armed:
             return
         soft = self.config.tenant_soft_pages
         if soft <= 0 or self._soft_yield:
@@ -1966,7 +1832,6 @@ class ContinuousBatchingEngine:
             "discard_ratio": round(
                 la["discarded"] / max(1, la["dispatched"]), 3),
             "readback_wait_ms_p50": round(self._p50(rb_waits), 3),
-            "coalesced_prefills": self.coalesced_prefills,
             # mixed-batch chunked prefill (ragged kernel piggybacking)
             "mixed_rounds": self.mixed_rounds,
             "prefill_chunks": self.prefill_chunks,
@@ -2176,13 +2041,12 @@ class ContinuousBatchingEngine:
 
     def _reclaim_failed_admission(self, slot: int) -> bool:
         """After an admission exception: return the slot to the free deque
-        ONLY if activation never completed. A client emit callback that raises
-        on the first token surfaces here AFTER _activate_slot marked the slot
-        live — releasing it then would hand the same slot to a second request
-        (stream hijack + leaked page chain). Returns True when the request was
-        NOT admitted (caller should emit its error event)."""
+        ONLY if the slot holds no request. Releasing a slot that was marked
+        live would hand it to a second request (stream hijack + leaked page
+        chain). Returns True when the request was NOT admitted (caller
+        should emit its error event)."""
         if self.active[slot] or self.slots[slot] is not None:
-            return False  # activation completed; the slot is serving
+            return False  # the slot is serving
         if slot not in self._free_slots:  # first-token finish already freed it
             self._release_free_slot(slot)
         return True
@@ -2426,20 +2290,13 @@ class ContinuousBatchingEngine:
             self._tenant_page_counts().get(tenant, 0) > soft
 
     def _admit(self) -> int:
-        """Admit pending requests under the per-round prefill token budget.
-
-        The old unbounded drain ran batch-1 synchronous prefills for the WHOLE
-        queue before any decode resumed — head-of-line blocking for every
-        active stream during an arrival burst. Now at most
-        ``prefill_budget_tokens`` prompt tokens are admitted per round (always
-        at least one request, so big prompts cannot starve), and COLD
-        same-bucket requests coalesce into one multi-row prefill dispatch."""
+        """Resume suspended streams, then admit pending requests into free
+        slots in PREFILL phase. Admission does no device work: the round
+        loop paces the prompts' chunks under ``prefill_budget_tokens``."""
         t0 = time.monotonic()
         failpoint("scheduler.admit")
-        admitted = self._resume_suspended() if self.paged else 0
-        budget = self.config.prefill_budget_tokens
+        admitted = self._resume_suspended()
         taken: list[_Pending] = []
-        spent = 0
         popped = 0
         # tenants at their slot/page caps are skipped by the fair pop —
         # their requests stay queued, everyone else admits around them.
@@ -2449,10 +2306,6 @@ class ContinuousBatchingEngine:
         max_slots = self.config.tenant_max_slots
         tenant_taken = self._tenant_slot_counts() if max_slots else {}
         while len(taken) < len(self._free_slots):
-            # mixed mode admits straight into prefill-phase slots (no device
-            # work here) — the budget paces CHUNKS per round, not admissions
-            if not self.mixed and budget > 0 and spent >= budget and taken:
-                break
             req = self._pending.pop_fair(blocked)
             if req is None:
                 break
@@ -2481,7 +2334,6 @@ class ContinuousBatchingEngine:
                         tenant=req.tenant)
                     continue
             taken.append(req)
-            spent += len(req.prompt_ids)
             if max_slots:
                 tenant_taken[req.tenant] = tenant_taken.get(req.tenant, 0) + 1
                 if tenant_taken[req.tenant] >= max_slots:
@@ -2500,8 +2352,8 @@ class ContinuousBatchingEngine:
         return admitted
 
     def _assign_keys(self, reqs: list[_Pending]) -> None:
-        """Assign per-request key streams in FIFO order BEFORE partitioning,
-        so coalescing can never reorder the shared-rng split sequence."""
+        """Assign per-request key streams in FIFO order: a seeded request
+        gets its own stream, the rest split the engine's in admission order."""
         for req in reqs:
             if req.key is None:
                 if req.sampling.seed is not None:
@@ -2510,80 +2362,11 @@ class ContinuousBatchingEngine:
                     self._rng, req.key = jax.random.split(self._rng)
 
     def _place(self, reqs: list[_Pending]) -> int:
-        """Partition taken requests into prefix-hit singles and coalesced cold
-        groups, then prefill them into slots."""
-        placed = 0
-        if self.mixed:
-            return self._place_mixed(reqs)
-        #: (request, prematched): the ONE radix match per request — its pin is
-        #: held from the probe here until _prefill_into_slot's release, so the
-        #: cold batches admitted below cannot evict a just-classified prefix
-        singles: list[tuple[_Pending, Optional[tuple[list[int], int]]]] = []
-        cold: dict[int, list[_Pending]] = {}
-        coalesce = self.config.prefill_coalesce if self.paged else 1
-        if self.paged:
-            self._assign_keys(reqs)
-        if coalesce > 1 and self.pool is not None:
-            for req in reqs:
-                match = self.pool.match_prefix(req.prompt_ids)
-                if match[0]:
-                    singles.append((req, match))  # hit: suffix-prefill path
-                else:
-                    # LOAD-BEARING release: a fully-cached prompt matches (and
-                    # pins) tree nodes but match_prefix trims its page list to
-                    # empty — this is the only unpin for those nodes (the cold
-                    # prefill path skips release for prematched requests)
-                    self.pool.release(req.prompt_ids)
-                    cold.setdefault(
-                        self._bucket_for(len(req.prompt_ids)), []).append(req)
-        else:
-            singles = [(req, None) for req in reqs]
-        for bucket in sorted(cold):
-            group = cold[bucket]
-            while group:
-                batch, group = group[:coalesce], group[coalesce:]
-                if len(batch) == 1:
-                    singles.extend((req, ([], 0)) for req in batch)
-                    continue
-                placed += self._prefill_batch(batch, bucket)
-        for i, (req, match) in enumerate(singles):
-            slot = self._take_free_slot()
-            if slot is None:  # unreachable: takes are bounded by free slots
-                # reversed: put_front restores each tenant's FIFO order
-                for dropped, d_match in reversed(singles[i:]):
-                    logger.error("no free slot for %s; requeueing",
-                                 dropped.request_id)
-                    if d_match and d_match[0]:
-                        self.pool.release(dropped.prompt_ids)
-                    self._pending.put_front(dropped)
-                break
-            try:
-                self._prefill_into_slot(slot, req, prematched=match)
-                placed += 1
-            except Exception:  # noqa: BLE001
-                log_tok = set_log_context(req.request_id,
-                                          traceparent_ids(req.trace)[0])
-                try:
-                    logger.exception("prefill failed for %s", req.request_id)
-                finally:
-                    reset_log_context(log_tok)
-                if self._reclaim_failed_admission(slot):
-                    record_event(req.request_id, "error",
-                                 detail="prefill failed")
-                    try:
-                        req.emit(StepEvent(0, -1, "error"))
-                    except Exception:  # noqa: BLE001 — emit itself may be the fault
-                        pass
-                else:
-                    placed += 1  # admitted; the emit callback raised post-hoc
-        return placed
-
-    def _place_mixed(self, reqs: list[_Pending]) -> int:
-        """Mixed-batch admission: every request — cold or prefix-hit — claims
-        a slot in PREFILL phase with zero device work; the round loop then
-        piggybacks its prompt chunks into decode rounds. A prefix hit seeds
-        the slot's chain with the cached pages, so only the uncached suffix
-        is ever chunk-prefilled."""
+        """Every taken request — cold or prefix-hit — claims a slot in
+        PREFILL phase with zero device work; the round loop then piggybacks
+        its prompt chunks into decode rounds. A prefix hit seeds the slot's
+        chain with the cached pages, so only the uncached suffix is ever
+        chunk-prefilled."""
         placed = 0
         self._assign_keys(reqs)
         for i, req in enumerate(reqs):
@@ -2621,6 +2404,9 @@ class ContinuousBatchingEngine:
         cache's matched pages (slot-ref'd so tree eviction orphans rather
         than frees them — the existing ref/orphan machinery); private pages
         are allocated chunk-by-chunk as prefill progresses."""
+        # armed raise exercises the failed-admission reclaim path: _place
+        # catches, reclaims the slot, and error-terminates only this request
+        failpoint("scheduler.prefill")
         cached_pages, cached_len = self.pool.match_prefix(req.prompt_ids)
         chain = list(cached_pages)
         if chain:
@@ -2630,8 +2416,7 @@ class ContinuousBatchingEngine:
             self.pool.seed_state_row(slot, chain)
         # LOAD-BEARING for chain == [] too: a fully-cached prompt matches
         # (and pins) tree nodes but match_prefix trims its page list to
-        # empty — this release is the only unpin for those nodes (same
-        # contract as the phase-separated cold path)
+        # empty — this release is the only unpin for those nodes
         self.pool.release(req.prompt_ids)
         s = req.sampling
         try:
@@ -2665,236 +2450,16 @@ class ContinuousBatchingEngine:
                 limit=len(req.prompt_ids) + s.max_tokens - 1)
         except Exception:
             self.pool.release_slot(chain)
+            self.page_table[slot, :] = 0
+            self._mark_pt_row(slot)
             self.slots[slot] = None
             raise
         self._prefill_slots.append(slot)
         self._epoch += 1
 
-    def _prefill_batch(self, reqs: list[_Pending], bucket: int) -> int:
-        """One multi-row prefill dispatch for coalesced COLD requests (paged
-        mode). Rows pad to a power-of-two batch (bounded compile variants);
-        pad rows replay row 0 under a dummy key and are discarded."""
-        B = len(reqs)
-        Bp = 1
-        while Bp < B:
-            Bp *= 2
-        ids = np.zeros((Bp, bucket), np.int32)
-        lengths = np.zeros(Bp, np.int32)
-        temp = np.zeros(Bp, np.float32)
-        top_p = np.ones(Bp, np.float32)
-        top_k = np.zeros(Bp, np.int32)
-        keys = np.zeros((Bp, 2), np.uint32)
-        for i, req in enumerate(reqs):
-            T = len(req.prompt_ids)
-            ids[i, :T] = req.prompt_ids
-            lengths[i] = T
-            s = req.sampling
-            temp[i], top_p[i], top_k[i] = s.temperature, s.top_p, s.top_k
-            keys[i] = np.asarray(req.key, np.uint32)
-        for i in range(B, Bp):
-            ids[i] = ids[0]
-            lengths[i] = lengths[0]
-        t_pf = time.monotonic()
-        wall_pf = time.time()
-        try:
-            first, kv, keys_out = self._batch_prefill_fn(
-                self.params, self._dev(ids), self._dev(lengths),
-                self._dev(keys), self._dev(temp), self._dev(top_p),
-                self._dev(top_k), self.rope_tables)
-            first_host = np.asarray(first, np.int32)
-        except Exception:  # noqa: BLE001 — the whole dispatch failed
-            logger.exception("coalesced prefill failed (%d reqs, bucket %d)",
-                             B, bucket)
-            for req in reqs:
-                req.emit(StepEvent(0, -1, "error"))
-                record_event(req.request_id, "error",
-                             detail="coalesced prefill failed")
-            return 0
-        placed = 0
-        self._note_prefill_rate(sum(len(r.prompt_ids) for r in reqs),
-                                time.monotonic() - t_pf)
-        for req in reqs:  # actual prefill tokens consumed, per tenant
-            self._charge_tenant(req.tenant, len(req.prompt_ids))
-        for i, req in enumerate(reqs):
-            slot = self._take_free_slot()
-            if slot is None:  # unreachable: takes bounded by free slots
-                for dropped in reversed(reqs[i:]):  # requeue EVERY one
-                    logger.error("no free slot for %s; requeueing",
-                                 dropped.request_id)
-                    self._pending.put_front(dropped)
-                break
-            chain: Optional[list[int]] = None
-            try:
-                kv_row = (kv[0][:, i:i + 1], kv[1][:, i:i + 1])
-                chain = self.pool.admit_slot(req.prompt_ids, [], kv_row)
-                dur_ms = (time.monotonic() - t_pf) * 1000.0
-                record_event(req.request_id, "prefill", slot=slot,
-                             coalesced=True, batch=B, cached_len=0,
-                             prompt_tokens=len(req.prompt_ids),
-                             dur_ms=round(dur_ms, 3))
-                if req.trace:
-                    get_global_tracer().emit_span(
-                        "llm.prefill", traceparent=req.trace,
-                        start_unix_ns=int(wall_pf * 1e9), duration_ms=dur_ms,
-                        request_id=req.request_id, slot=slot, coalesced=True,
-                        batch=B, prompt_tokens=len(req.prompt_ids),
-                        tenant=req.tenant)
-                self._activate_slot(slot, req, chain, int(first_host[i]),
-                                    keys_out[i])
-                placed += 1
-            except Exception:  # noqa: BLE001
-                logger.exception("prefill failed for %s", req.request_id)
-                if self._reclaim_failed_admission(slot):
-                    # not admitted: the chain (if any) belongs to no one
-                    if chain is not None:
-                        self.pool.release_slot(chain)
-                        self.page_table[slot, :] = 0
-                        self._mark_pt_row(slot)
-                    record_event(req.request_id, "error",
-                                 detail="coalesced admission failed")
-                    try:
-                        req.emit(StepEvent(0, -1, "error"))
-                    except Exception:  # noqa: BLE001 — emit itself may be the fault
-                        pass
-                else:
-                    placed += 1  # admitted; the emit callback raised post-hoc
-        if placed:
-            self.coalesced_prefills += 1
-        return placed
-
-    def _prefill_into_slot(self, slot: int, req: _Pending,
-                           prematched: Optional[tuple[list[int], int]] = None
-                           ) -> None:
-        """``prematched`` carries _place's probe result (pages, cached_len):
-        the ONE radix match for this request, its pin still held on a hit —
-        no second tree walk, and no probe/admit window where the classified
-        prefix could be evicted."""
-        # armed raise exercises the failed-admission reclaim path: _place
-        # catches, reclaims the slot, and error-terminates only this request
-        failpoint("scheduler.prefill")
-        t_pf = time.monotonic()
-        wall_pf = time.time()
-        T = len(req.prompt_ids)
-        bucket = self._bucket_for(T)
-        s = req.sampling
-        temp = self._dev(np.asarray([s.temperature], np.float32))
-        top_p = self._dev(np.asarray([s.top_p], np.float32))
-        top_k = self._dev(np.asarray([s.top_k], np.int32))
-
-        # paged mode: the request gets its own key stream from admission on —
-        # an explicit seed reproduces the whole generation (first token
-        # included) regardless of batch composition (round-1 advisory)
-        if self.paged:
-            self._assign_keys([req])
-            req_key = req.key
-        else:
-            req_key = None
-
-        cached_pages: list[int] = []
-        cached_len = 0
-        pin_held = False  # exactly ONE release per held pin — a spare release
-        #                   can steal a same-prefix peer's pin (pins floor at 0)
-        if self.pool is not None:
-            if prematched is None:
-                cached_pages, cached_len = self.pool.match_prefix(req.prompt_ids)
-                pin_held = True
-            else:
-                cached_pages, cached_len = prematched
-                pin_held = bool(cached_pages)  # cold probes already released
-            if cached_pages:
-                # the suffix insert at offset cached_len must fit the prefill
-                # cache entirely (dynamic_update_slice clamps, which would
-                # overwrite cached history) — grow the cache bucket to cover it,
-                # or fall back to a cold prefill near the window edge
-                suf_bucket = self.config.bucket_for(T - cached_len)
-                if cached_len + suf_bucket <= self.config.max_seq_len:
-                    bucket = max(bucket, next(
-                        b for b in self.config.buckets()
-                        if b >= cached_len + suf_bucket))
-                else:
-                    self.pool.release(req.prompt_ids)
-                    pin_held = False
-                    cached_pages = []
-        chain: Optional[list[int]] = None
-        if cached_pages:
-            # prefix hit: gather history, prefill the suffix only
-            try:
-                suffix = req.prompt_ids[cached_len:]
-                suf_bucket = self.config.bucket_for(len(suffix))
-                ids = np.zeros((1, suf_bucket), np.int32)
-                ids[0, : len(suffix)] = suffix
-                cache = llama.init_cache(self.model_config, 1, bucket, self.dtype)
-                cache = self.pool.gather_for_prefill(cached_pages, bucket, cache)
-                first, kv, rng_out = self._suffix_prefill_fn(
-                    self.params, self._dev(ids),
-                    self._dev(np.asarray([len(suffix)], np.int32)),
-                    self._dev(np.asarray(cached_len, np.int32)), cache,
-                    req_key if self.paged else self._rng, temp, top_p, top_k)
-                if self.paged:
-                    req_key = rng_out
-                else:
-                    self._rng = rng_out
-                chain = self.pool.admit_slot(req.prompt_ids, cached_pages, kv)
-            finally:
-                self.pool.release(req.prompt_ids)
-                pin_held = False
-        else:
-            ids = np.zeros((1, bucket), np.int32)
-            ids[0, :T] = req.prompt_ids
-            first, kv, rng_out = self._prefill_fn(
-                self.params, self._dev(ids),
-                self._dev(np.asarray([T], np.int32)),
-                req_key if self.paged else self._rng, temp, top_p, top_k,
-                self.rope_tables)
-            if self.paged:
-                req_key = rng_out
-            else:
-                self._rng = rng_out
-            if self.pool is not None:  # pool exists iff paged mode
-                try:
-                    chain = self.pool.admit_slot(req.prompt_ids, [], kv)
-                finally:
-                    if pin_held:
-                        self.pool.release(req.prompt_ids)
-                        pin_held = False
-        try:
-            if not self.paged:
-                # dense mode: scatter the collected kv into the slot's cache rows
-                self.cache = self._insert_fn(
-                    self.cache[0], self.cache[1], kv[0], kv[1],
-                    jnp.asarray(slot, jnp.int32))
-            tok = int(np.asarray(first)[0])
-        except Exception:
-            # the chain's refs are held from admit_slot on — drop them or the
-            # pool shrinks permanently on every failed admission
-            if chain is not None:
-                self.pool.release_slot(chain)
-                self.page_table[slot, :] = 0
-                self._mark_pt_row(slot)
-            raise
-        if self.paged:
-            assert chain is not None
-        dur_ms = (time.monotonic() - t_pf) * 1000.0
-        self._note_prefill_rate(T - cached_len, dur_ms / 1000.0)
-        # only the UNCACHED suffix is charged: a prefix-cache hit consumed
-        # no prefill compute, so fairness must not bill it
-        self._charge_tenant(req.tenant, T - cached_len)
-        # recorded BEFORE activation: the first token emitted there may finish
-        # the request, and a terminal event must be the timeline's last
-        record_event(req.request_id, "prefill", slot=slot, coalesced=False,
-                     cached_len=cached_len, prompt_tokens=T,
-                     dur_ms=round(dur_ms, 3))
-        if req.trace:
-            get_global_tracer().emit_span(
-                "llm.prefill", traceparent=req.trace,
-                start_unix_ns=int(wall_pf * 1e9), duration_ms=dur_ms,
-                request_id=req.request_id, slot=slot, prompt_tokens=T,
-                cached_len=cached_len, tenant=req.tenant)
-        self._activate_slot(slot, req, chain, tok, req_key)
-
     def _arm_spec(self, state: _SlotState, prompt_ids: list[int]) -> None:
-        """Arm per-stream speculation at decode activation (phase-separated
-        AND chunked-prefill flips both land here). Eligibility: greedy only —
+        """Arm per-stream speculation at decode activation (the final
+        chunk's flip, and a PD handoff). Eligibility: greedy only —
         verification is argmax equality, so acceptance is lossless — and the
         request's token limit must fire before the window bound ever could
         (limit + decode_chunk ≤ max_seq): a window-bound stream's "length"
@@ -2914,50 +2479,6 @@ class ContinuousBatchingEngine:
                                  self.config.spec_min_ngram, self.spec_k)
         proposer.extend(list(prompt_ids))
         state.proposer = proposer
-
-    def _activate_slot(self, slot: int, req: _Pending,
-                       chain: Optional[list[int]], tok: int,
-                       slot_key: Any) -> None:
-        """Commit an admitted request into its slot: host mirrors, device-row
-        patches, page-table row, first-token emission."""
-        s = req.sampling
-        stops = (frozenset(s.stop_token_ids)
-                 | frozenset(self.config.eos_token_ids))
-        if self.paged:
-            self.page_table[slot, :] = 0
-            self.page_table[slot, : len(chain)] = chain
-            self._mark_pt_row(slot)
-            # continue this request's key stream (advanced by prefill)
-            i = jnp.asarray(slot, jnp.int32)
-            self._slot_keys = self._slot_keys.at[i].set(slot_key)
-        # device rows are patched in dense mode too (the dense round reads
-        # lengths/termination state off-device instead of re-uploading)
-        self._patch_slot_device(
-            slot, s.temperature, s.top_p, s.top_k, len(req.prompt_ids), True,
-            stops=stops, limit=len(req.prompt_ids) + s.max_tokens - 1)
-        state = _SlotState(
-            request_id=req.request_id,
-            emit=req.emit,
-            sampling=s,
-            stops=frozenset(s.stop_token_ids) | frozenset(self.config.eos_token_ids),
-            chain=chain,
-            trace=req.trace,
-            trace_sampled=traceparent_ids(req.trace)[1],
-            deadline=req.deadline,
-            tenant=req.tenant,
-        )
-        self._arm_spec(state, req.prompt_ids)
-        T = len(req.prompt_ids)
-        self.slots[slot] = state
-        self.lengths[slot] = T
-        self.active[slot] = True
-        self._last_tokens = self._last_tokens.at[
-            jnp.asarray(slot, jnp.int32)].set(jnp.int32(tok))
-        self._epoch += 1
-        # invariant: an active slot can ALWAYS fit a full decode chunk — slots
-        # that can't are finished here/at chunk end, so decode never clamp-writes
-        no_room = T + self._k_steps > self.config.max_seq_len
-        self._emit_token(slot, tok, force_length=no_room)
 
     def _emit_token(self, slot: int, tok: int, force_length: bool = False) -> None:
         state = self.slots[slot]
@@ -2998,15 +2519,14 @@ class ContinuousBatchingEngine:
                 # finish — the whole point of device-side termination.
                 self._epoch += 1
             self._deactivate_slot_device(slot)
-            if self.paged:
-                if state.chain is not None:
-                    self.pool.release_slot(state.chain)
-                    self.page_table[slot, :] = 0
-                    self._mark_pt_row(slot)
+            if state.chain is not None:
+                self.pool.release_slot(state.chain)
+                self.page_table[slot, :] = 0
+                self._mark_pt_row(slot)
 
     # ------------------------------------------------------------ decode round
     def _ensure_chunk_capacity(self, horizon: Optional[int] = None) -> None:
-        """Paged mode: before a chunk, every active slot's chain must cover its
+        """Before a chunk, every active slot's chain must cover its
         length + horizon tokens (a chunk may cross a page boundary mid-flight;
         page allocation is host-side, so it happens here, never inside jit).
         With an N-deep lookahead ring the horizon is (N+1)·k so every
@@ -3247,17 +2767,13 @@ class ContinuousBatchingEngine:
         the host length mirror. Returns the pre-chunk lengths for the emit
         loop. The active mask is NOT committed (it is an input the chunk never
         modifies — committing it would resurrect rows the host finished while
-        the chunk was in flight)."""
+        the chunk was in flight). Active slots advance by k; inactive slots
+        pin to 0 so their garbage positions never run past the rope table /
+        page chain bounds."""
         self._last_tokens = rec.last
         self._slot_keys = rec.keys
         self._lengths_dev = rec.lengths_dev
         self._finished_dev = rec.finished_dev
-        return self._advance_lengths()
-
-    def _advance_lengths(self) -> np.ndarray:
-        """Shared by the paged and dense rounds: active slots advance by k;
-        inactive slots pin to 0 so their garbage positions never run past the
-        rope table / cache bounds. Returns the pre-chunk lengths."""
         old_lengths = self.lengths.copy()
         self.lengths = np.where(self.active, self.lengths + self._k_steps,
                                 0).astype(np.int32)
@@ -3272,8 +2788,7 @@ class ContinuousBatchingEngine:
                       spec_tokens: int = 0,
                       kind: str = "decode",
                       positions: Optional[int] = None) -> None:
-        """One timing-schema owner for both decode modes — the stats()
-        percentile keys cannot drift between paged and dense. ``ts`` is the
+        """One timing-schema owner for every round kind. ``ts`` is the
         round's wall-clock start; /v1/monitoring/rounds exports these entries
         as Chrome trace events, which need absolute timestamps.
         ``positions`` is what the dispatch computed: ``B + R*Qc`` for a lane
@@ -3417,8 +2932,8 @@ class ContinuousBatchingEngine:
         # the chunked path's duration spans the budget-paced rounds — the
         # realistic "time to get through prefill under current load"
         self._note_prefill_rate(T - state.cached_len, dur_ms / 1000.0)
-        # same terminal "prefill" event as the phase-separated path (ttft
-        # anchors here); the per-chunk progress lives in prefill_chunk events
+        # the terminal "prefill" event (ttft anchors here); the per-chunk
+        # progress lives in prefill_chunk events
         record_event(state.request_id, "prefill", slot=slot, mixed=True,
                      cached_len=state.cached_len, prompt_tokens=T,
                      chunks=state.prefill_chunks, dur_ms=round(dur_ms, 3))
@@ -3908,10 +3423,7 @@ class ContinuousBatchingEngine:
 
     def _decode_round(self) -> None:
         self.occupancy_samples.append(self.active_slots)
-        if not self.paged:
-            self._decode_round_dense()
-            return
-        if self.mixed and self._prefill_slots:
+        if self._prefill_slots:
             self._decode_round_mixed()
             return
         if self.spec_k and not self._ring and self._spec_round_safe() \
@@ -4000,36 +3512,3 @@ class ContinuousBatchingEngine:
                 request_id=state.request_id, slot=slot,
                 tokens=row_tokens.get(slot, k) if row_tokens else k,
                 lookahead=lookahead, depth=depth, **extra)
-
-    def _decode_round_dense(self) -> None:
-        """Dense (non-paged) synchronous round. All per-slot state —
-        temp/top_p/top_k/lengths/active/finished/stop-ids/limits — is
-        device-resident and row-patched (mirroring the paged path), so the
-        steady-state round uploads NOTHING; the pre-pipeline code re-uploaded
-        the lengths and the three sampling arrays from host every round."""
-        t0 = time.monotonic()
-        wall0 = time.time()
-        chunk_dev, k_cache, v_cache, last, self._rng, lens_o, fin_o = \
-            self._decode_fn(
-                self.params, self.cache[0], self.cache[1], self._last_tokens,
-                self._lengths_dev, self._rng,
-                self._temp_dev, self._top_p_dev, self._top_k_dev,
-                self._active_dev, self._finished_dev,
-                self._stops_dev, self._limit_dev)
-        self.cache = (k_cache, v_cache)
-        self._last_tokens = last
-        try:
-            chunk_dev.copy_to_host_async()  # non-blocking D2H start
-        except AttributeError:
-            pass
-        t1 = time.monotonic()
-        chunk = np.asarray(chunk_dev, np.int32)  # sync-point: dense-mode chunk drain (AS04)
-        t2 = time.monotonic()
-        self.readback_wait_samples.append((t2 - t1) * 1000.0)
-        self._lengths_dev = lens_o
-        self._finished_dev = fin_o
-        self._emit_decode_spans(wall0, (t2 - t0) * 1000.0, lookahead=False)
-        self._emit_chunk(chunk, self._advance_lengths())
-        t3 = time.monotonic()
-        self._record_round((t1 - t0) * 1000.0, (t2 - t1) * 1000.0,
-                           (t3 - t2) * 1000.0, lookahead=False, ts=wall0)

@@ -48,7 +48,7 @@ class PagedRun:
             1 + np.arange(rows * pmax).reshape(rows, pmax), jnp.int32)
         self.state = falcon_h1.init_state(cfg, rows + 1)
 
-    def mixed(self, ids, hist, q_lens, write_mask=None):
+    def mixed_step(self, ids, hist, q_lens, write_mask=None):
         hidden, self.pools, self.state = falcon_h1.forward_paged_mixed(
             self.params, self.cfg, jnp.asarray(ids), self.pools, self.table,
             jnp.asarray(hist), jnp.asarray(q_lens), self.rope,
@@ -76,7 +76,7 @@ class PagedRun:
             ids = np.zeros((self.rows, self.chunk), np.int32)
             for r in range(self.rows):
                 ids[r, : q[r]] = seqs[r][done[r]: done[r] + q[r]]
-            logits = self.mixed(ids, done, q)
+            logits = self.mixed_step(ids, done, q)
             for r in range(self.rows):
                 done[r] += q[r]
                 if q[r] and done[r] >= lens[r]:

@@ -71,7 +71,7 @@ def _compiles_with_mosaic(fn, *args):
 
 @pytest.mark.parametrize("batch", [1, 4])
 def test_flash_kernel_compiles_at_mistral_7b_shapes(one_chip, batch):
-    """One prompt, and a coalesced cold prefill of several: per-row lengths
+    """One prompt, and a prefill of several rows: per-row lengths
     as a blocked SMEM operand lowered at batch 1 only (first chip run)."""
     from cyberfabric_core_tpu.ops.flash_attention import flash_self_attention
 
@@ -328,7 +328,7 @@ def test_scheduler_programs_compile_for_v5e_at_mistral_7b(tp):
         model="mistral-7b", max_seq_len=max_seq, max_batch=n, decode_chunk=8,
         quantization="int8", prefix_cache_pages=_N_PAGES,
         prefix_page_size=_PAGE, tp=tp)
-    eng.model_config, eng.dtype, eng.paged = cfg, jnp.bfloat16, True
+    eng.model_config, eng.dtype = cfg, jnp.bfloat16
     eng._model, eng._has_state = decoder_module(cfg), False
     eng.spec_k, eng._spec_w = 0, 1
     eng.rope_tables = rope_frequencies(
@@ -412,7 +412,7 @@ def test_scheduler_programs_compile_for_v5e_at_falcon_h1():
     eng.config = EngineConfig(
         model=cfg.name, max_seq_len=max_seq, max_batch=n, decode_chunk=8,
         quantization="int8", prefix_cache_pages=pages, prefix_page_size=_PAGE)
-    eng.model_config, eng.dtype, eng.paged = cfg, jnp.bfloat16, True
+    eng.model_config, eng.dtype = cfg, jnp.bfloat16
     eng._model, eng._has_state = decoder_module(cfg), True
     eng.spec_k, eng._spec_w = 0, 1
     eng.mesh = eng._attn_mesh = None

@@ -204,32 +204,6 @@ def test_cancel_mid_chunked_prefill_releases_chain():
     _assert_clean(sched)
 
 
-def test_cancel_works_in_dense_mode():
-    """Dense (non-paged) scheduling has no page chains but the same
-    cancel contract: slot freed, one terminal."""
-    cfg = EngineConfig(model="tiny-llama", max_seq_len=64, max_batch=2,
-                       decode_chunk=4, use_flash=False, prefix_cache_pages=0)
-    sched = ContinuousBatchingEngine(cfg, seed=0)
-    col = _Collector(1)
-    fired = []
-    try:
-        inner = col.emit_for(0)
-
-        def emit(ev):
-            inner(ev)
-            if len(col.tokens[0]) >= 4 and not fired:
-                fired.append(1)
-                sched.cancel("dense", "test")
-        sched.submit([5, 6, 7], SamplingParams(max_tokens=40), emit,
-                     request_id="dense")
-        assert col.done.wait(240), (col.finishes, sched.stats())
-    finally:
-        sched.shutdown()
-    assert col.finishes[0] == "cancelled"
-    assert len(col.tokens[0]) < 40
-    _assert_clean(sched)
-
-
 # ------------------------------------------------------------ replica pool
 # (bare-instance doubles — the tests/test_replicas.py pattern)
 

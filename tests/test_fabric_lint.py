@@ -243,7 +243,7 @@ def test_AS04_sync_outside_loop_methods_passes():
     # admission-path syncs (first-token readback) are inherent, not hot-loop
     ok = lint(
         _AS04_CLASS +
-        "    def _prefill_into_slot(self, slot, req):\n"
+        "    def _admit_prefill_slot(self, slot, req):\n"
         "        tok = int(np.asarray(self._first)[0])\n",
         tier="runtime", select=("AS04",))
     assert ok == []
@@ -2104,7 +2104,8 @@ def test_shard_graph_cli_json_and_drift():
         "the regenerated SPMD world")
     assert regenerated["aot_key"]["uncovered"] == []
     assert "tp" in regenerated["axes"]
-    assert any(d["attr"] == "_decode_fn" for d in regenerated["dispatches"])
+    assert any(d["attr"] == "_paged_decode_fn"
+               for d in regenerated["dispatches"])
 
 
 def test_max_seconds_budget_exceeded(tmp_path):

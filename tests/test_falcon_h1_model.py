@@ -115,9 +115,9 @@ def test_idle_and_masked_rows_keep_their_state_bit_for_bit():
     run = PagedRun(CFG, weights, rows=3)
     seqs = _seqs(2)
     ids = np.stack([np.resize(s, 16) for s in seqs])
-    run.mixed(ids, [0, 0, 0], [16, 16, 5])          # every row holds something
+    run.mixed_step(ids, [0, 0, 0], [16, 16, 5])          # every row holds something
     before = jax.tree.map(np.asarray, run.state)
-    run.mixed(ids, [16, 16, 5], [16, 0, 16],
+    run.mixed_step(ids, [16, 16, 5], [16, 0, 16],
               write_mask=jnp.asarray([True, True, False]))
     run.decode(ids[:, :1], [32, 16, 5],
                write_mask=jnp.asarray([True, False, False]))
@@ -136,10 +136,10 @@ def test_a_row_with_no_history_starts_from_the_zero_state():
     seqs = _seqs(4)
     ids = np.stack([np.resize(s, 16) for s in seqs])
     clean = PagedRun(CFG, weights, rows=3)
-    first = clean.mixed(ids, [0, 0, 0], [16, 16, 5])
+    first = clean.mixed_step(ids, [0, 0, 0], [16, 16, 5])
     dirty = PagedRun(CFG, weights, rows=3)
     dirty.state = jax.tree.map(lambda x: x + 3.0, dirty.state)
-    again = dirty.mixed(ids, [0, 0, 0], [16, 16, 5])
+    again = dirty.mixed_step(ids, [0, 0, 0], [16, 16, 5])
     assert np.array_equal(first, again)
 
 

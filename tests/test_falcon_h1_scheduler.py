@@ -282,8 +282,6 @@ def test_a_finished_row_freezes_and_an_idle_row_is_untouched():
 @pytest.mark.parametrize("over,what", [
     (dict(scheduler_spec_k=3), "state rollback"),
     (dict(pd_role="prefill"), "export carries no recurrent state"),
-    (dict(prefix_cache_pages=0), "no state slab"),
-    (dict(mixed_batch=False), "no mixer"),
     (dict(tp=2), "no sharding for the state slab"),
 ])
 def test_a_mode_that_cannot_carry_state_is_refused_at_build(over, what):

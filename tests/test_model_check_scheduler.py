@@ -41,12 +41,11 @@ from cyberfabric_core_tpu.runtime import EngineConfig, SamplingParams
 from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
 
 
-def _make_engine(slots: int = 2, max_seq: int = 64, pages: int = 0,
-                 mixed: bool = True):
+def _make_engine(slots: int = 2, max_seq: int = 64,
+                 budget: int = 512):
     cfg = EngineConfig(model="tiny-llama", max_seq_len=max_seq,
                        max_batch=slots, decode_chunk=4, use_flash=False,
-                       prefix_cache_pages=pages or 1,  # >0 → paged
-                       prefix_page_size=16, mixed_batch=mixed)
+                       prefix_page_size=16, prefill_budget_tokens=budget)
     eng = ContinuousBatchingEngine(cfg, seed=0)
     eng.start = lambda: None  # drive synchronously — no scheduler thread
     return eng
@@ -160,13 +159,14 @@ class Harness:
             self.audit(f"{ctx}/post-round")
 
 
-@pytest.mark.parametrize("mixed", [True, False],
-                         ids=["mixed", "phase-separated"])
-def test_churn_schedule_holds_invariants(mixed):
+@pytest.mark.parametrize("budget", [16, 512],
+                         ids=["several-chunks-a-prompt", "one-lane-a-prompt"])
+def test_churn_schedule_holds_invariants(budget):
     """Slot churn: more requests than slots, staggered lengths — admission,
-    completion, and slot reuse audited at every step (both scheduling
-    modes: mixed-batch chunked prefill and the phase-separated baseline)."""
-    eng = _make_engine(slots=2, max_seq=64, mixed=mixed)
+    completion, and slot reuse audited at every step, with a prompt taking
+    several mixed steps (budget 16: the 17-token prompt is two chunks) and
+    with every prompt one lane."""
+    eng = _make_engine(slots=2, max_seq=64, budget=budget)
     h = Harness(eng)
     prompts = [list(range(10, 10 + n)) for n in (5, 9, 17, 7, 12)]
     for i, p in enumerate(prompts):

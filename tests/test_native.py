@@ -99,7 +99,7 @@ def test_native_python_parity():
         pages = list(range(page, page + n_pages))
         page += n_pages
         # insert_tracked parity covers the OWNERSHIP-critical surface: the
-        # unused list tells store_prefill which pages the tree declined —
+        # unused list tells commit_chain which pages the tree declined —
         # a native/fallback divergence here mislabels page ownership
         a1, u1 = nat.insert_tracked(seq, pages)
         a2, u2 = pyt.insert_tracked(seq, pages)

@@ -364,11 +364,6 @@ def test_pd_constructor_validation():
         PDServingPool(EngineConfig(**CFG), n_prefill=0, n_decode=1)
     with pytest.raises(ValueError):
         PDServingPool(EngineConfig(**CFG), n_prefill=1, n_decode=0)
-    # a PD role needs the paged pool (the handoff currency is pages)
-    with pytest.raises(ValueError):
-        ContinuousBatchingEngine(
-            EngineConfig(**{**CFG, "prefix_cache_pages": 0},
-                         pd_role="prefill"), seed=0)
     with pytest.raises(ValueError):
         ContinuousBatchingEngine(EngineConfig(**CFG, pd_role="verify"),
                                  seed=0)

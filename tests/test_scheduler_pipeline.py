@@ -1,7 +1,7 @@
 """Overlapped decode pipeline tests (scheduler lookahead + admission budget).
 
-The golden contract: with one-chunk lookahead, prefill budgeting, and cold
-coalescing all enabled, per-request token streams are BIT-IDENTICAL to the
+The golden contract: with one-chunk lookahead and prefill budgeting
+enabled, per-request token streams are BIT-IDENTICAL to the
 synchronous scheduler for fixed seeds — speculation and admission shaping may
 change *when* device work runs, never *what* any request receives.
 """
@@ -65,7 +65,7 @@ def _run_streams(cfg, prompts, samplings, timeout=240.0,
 
 
 def test_lookahead_streams_bit_identical_to_sync():
-    """The golden test: pipeline on (lookahead + budget + coalesce) vs the
+    """The golden test: pipeline on (lookahead + budget) vs the
     synchronous scheduler — same seeds, identical per-request streams. The
     pipeline run must actually overlap (lookahead rounds used), so the
     equivalence cannot pass vacuously."""
@@ -75,11 +75,11 @@ def test_lookahead_streams_bit_identical_to_sync():
                                 seed=1000 + i) for i in range(6)]
 
     pipe_col, pipe_stats = _run_streams(
-        _cfg(decode_lookahead=True, prefill_budget_tokens=64,
-             prefill_coalesce=4), prompts, samplings)
+        _cfg(decode_lookahead=True, prefill_budget_tokens=64),
+        prompts, samplings)
     sync_col, sync_stats = _run_streams(
-        _cfg(decode_lookahead=False, prefill_budget_tokens=0,
-             prefill_coalesce=1), prompts, samplings)
+        _cfg(decode_lookahead=False, prefill_budget_tokens=0),
+        prompts, samplings)
 
     assert pipe_col.tokens == sync_col.tokens, "pipelined streams diverged"
     assert pipe_col.finishes == sync_col.finishes
@@ -117,7 +117,7 @@ def test_prefill_storm_does_not_starve_decode():
     # storm); a small batch keeps the CPU decode rounds cheap while storm
     # requests recycle slots fast (max_tokens=4)
     cfg = _cfg(max_batch=12, max_seq_len=256,
-               prefill_budget_tokens=48, prefill_coalesce=1,
+               prefill_budget_tokens=48,
                prefix_cache_pages=12 * 16 + 1)
     sched = ContinuousBatchingEngine(cfg, seed=0)
     col = _Collector(n_storm + 1)
@@ -375,47 +375,6 @@ def test_stats_surface_pipeline_breakdown():
     assert set(pipe["lookahead"]) == {"dispatched", "used", "discarded"}
     assert pipe["lookahead"]["dispatched"] >= pipe["lookahead"]["used"]
     assert set(st["queue_wait_ms"]) == {"p50", "max", "count"}
-
-
-def test_coalesced_prefill_matches_single_prefill_streams():
-    """Cold same-bucket arrivals coalesce into one multi-row prefill; per-row
-    key streams must make every request's tokens identical to the
-    one-at-a-time admission path. (Pins the PHASE-SEPARATED prefill path —
-    mixed_batch=False — which stays supported as the mixed-batch A/B
-    baseline; under mixed batching prompts are chunk-piggybacked instead of
-    coalesced, see tests/test_mixed_batch.py.)"""
-    rng = np.random.default_rng(9)
-    # same bucket (16): lengths 10..13, distinct content, seeded sampling
-    prompts = [rng.integers(3, 900, 10 + i).tolist() for i in range(4)]
-    samplings = [SamplingParams(max_tokens=16, temperature=0.7, seed=70 + i)
-                 for i in range(4)]
-    co_col, co_stats = _run_streams(
-        _cfg(prefill_coalesce=4, decode_lookahead=False, mixed_batch=False),
-        prompts, samplings)
-    single_col, _ = _run_streams(
-        _cfg(prefill_coalesce=1, decode_lookahead=False, mixed_batch=False),
-        prompts, samplings)
-    assert co_col.tokens == single_col.tokens
-    assert co_stats["pipeline"]["coalesced_prefills"] >= 1, \
-        "coalescing never triggered — the equivalence is vacuous"
-
-
-def test_dense_mode_still_serves():
-    """The dense (non-paged) scheduler keeps working without the pipeline
-    (lookahead is a paged-mode feature; dense rounds stay synchronous)."""
-    cfg = EngineConfig(model="tiny-llama", max_seq_len=64, max_batch=2,
-                       decode_chunk=4, use_flash=False, prefix_cache_pages=0)
-    sched = ContinuousBatchingEngine(cfg, seed=0)
-    col = _Collector(1)
-    try:
-        sched.submit([5, 6, 7], SamplingParams(max_tokens=8), col.emit_for(0))
-        assert col.done.wait(120)
-        st = sched.stats()
-    finally:
-        sched.shutdown()
-    assert len(col.tokens[0]) == 8
-    assert st["pipeline"]["rounds"] > 0
-    assert st["pipeline"]["lookahead_rounds"] == 0
 
 
 # ------------------------------------------------- cancellation × pipeline

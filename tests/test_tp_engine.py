@@ -4,7 +4,7 @@ The acceptance contract of the tp tentpole: ``EngineConfig.tp`` lifts the
 WHOLE continuous scheduler onto a NamedSharding mesh — Megatron-sharded
 params, the paged KV pool split on the kv-head axis, replicated host-control
 rows — and the streams it emits are BIT-IDENTICAL to the single-device
-engine across every dispatch family: coalesced/chunked mixed-batch prefill,
+engine across every dispatch family: chunked mixed-batch prefill,
 the deep lookahead ring, spec-k ragged verify spans, seeded sampling, and
 mid-stream cancellation. Sharding is an implementation detail, never a
 semantics change (the test_parallel.py invariant, now end-to-end through
@@ -184,23 +184,6 @@ def test_tp_mesh_surface(tp_runs):
     stats8, _, _ = tp_runs[8]
     assert stats8["mesh"]["kv_heads_sharded"] is False  # 2 heads % 8 != 0
     assert stats8["mesh"]["sharded_page_bytes_per_device"] > 0
-
-
-def test_tp_dense_mode_identity():
-    """Dense (non-paged) engines shard too: greedy streams at tp=2 equal
-    tp=1 (the dense cache takes dense_cache_sharding, control rows stay
-    replicated)."""
-    reqs = [([5, 6, 7, 8], SamplingParams(max_tokens=10)),
-            ([9, 10, 11], SamplingParams(max_tokens=8))]
-    runs = {}
-    for tp in (1, 2):
-        eng = ContinuousBatchingEngine(
-            _config(tp, prefix_cache_pages=0, scheduler_spec_k=0,
-                    decode_lookahead=0), seed=0)
-        eng.start()
-        runs[tp] = _drive(eng, reqs)
-        eng.shutdown()
-    assert runs[1] == runs[2]
 
 
 def test_tp_rejects_pinned_device():
