@@ -118,7 +118,7 @@ _DEVICE_SYNC_CALLS = {"np.asarray", "numpy.asarray", "jax.device_get"}
 #: ``_run_loop``): the steady-state path that runs once per decode chunk.
 #: Admission/preemption helpers (rare, inherently synchronizing) are excluded.
 _HOT_LOOP_RE = re.compile(
-    r"^(_loop_body|_decode_round\w*|_emit_\w+|_dispatch_\w+|_commit_\w+"
+    r"^(_loop_body|_loop_pass|_decode_round\w*|_emit_\w+|_dispatch_\w+|_commit_\w+"
     r"|_read_chunk)$")
 
 #: the sanctioned sync carries this marker in a trailing comment — exactly one

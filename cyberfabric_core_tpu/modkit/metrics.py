@@ -176,7 +176,12 @@ class MetricsRegistry:
         self.started_at = time.time()
 
     def counter(self, name: str, help: str = "") -> Counter:
-        return self._get_or_create(name, lambda: Counter(name, help))
+        m = self._get_or_create(name, lambda: Counter(name, help))
+        if help and not m.help:
+            # a fire-and-forget bump ran before the pre-registration: the
+            # first help text that says something is the one kept
+            m.help = help
+        return m
 
     def gauge(self, name: str, help: str = "") -> Gauge:
         return self._get_or_create(name, lambda: Gauge(name, help))

@@ -390,7 +390,22 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
             "Speculative decode chunks discarded as stale / dispatched (0..1)"
         ).set_function(lookahead_discard_ratio)
 
-        # per-round-kind dispatch time (PD disaggregation's measurement):
+        # the ring as counters (pushed by the scheduler): every decode chunk
+        # dispatched, every one dropped undrained, and the loop passes that
+        # held an admission back for the chunks in flight
+        for name, text in (
+                ("llm_decode_chunks_dispatched_total",
+                 "Decode chunks dispatched (the head of the lookahead ring "
+                 "and every chunk chained behind it)"),
+                ("llm_decode_chunks_discarded_total",
+                 "Decode chunks dropped from the lookahead ring undrained: "
+                 "computed, never emitted (a preemption, a host-detected "
+                 "stop, no running row left)"),
+                ("llm_admission_ring_waits_total",
+                 "Scheduler passes in which a request could have been "
+                 "admitted or resumed and waited for the chunks in flight")):
+            self.registry.counter(name, text).inc(0.0)
+
         # pure-decode vs mixed vs prefill-only round dispatch percentiles,
         # read straight off the scheduler round_timings ring (advisory
         # snapshot; same entries stats()["pipeline"]["dispatch_ms_by_kind"]

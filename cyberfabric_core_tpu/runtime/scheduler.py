@@ -21,14 +21,24 @@ alternating.
   kept in flight beyond the one being drained, each chained off the previous
   chunk's device-resident outputs (last_tokens / keys / pools / page table /
   lengths / finished mask) — the host emit loop runs while the device works
-  N chunks ahead. A structural state change (a request admitted/resumed, a
-  preemption, a host-detected stop) bumps ``_epoch`` and the stale SUFFIX of
-  the ring is discarded; the fallback synchronous round recomputes from
-  committed state, so emitted streams are byte-identical at any depth.
-  (Discarded chunks are harmless: their KV writes land past every committed
-  length and are either rewritten identically or masked by attention-length
-  bounds; pages they touched of freed slots are fully rescattered by the
-  next owner.)
+  N chunks ahead. The chunks in flight are tokens the running rows are
+  owed, so what can wait for them does: ADMISSION AND RESUME WAIT until the
+  ring is empty (``_admit``; the ring stops deepening the moment a slot is
+  free and a request is pending or suspended, ``_can_extend_ring``, so that
+  is at most ``decode_lookahead`` further drains, each emitting its tokens
+  to every running row), then the prompt's chunk runs as the lane of a
+  ``mixed_step`` and the ring is rebuilt off that dispatch. What cannot
+  wait and can be replayed still DISCARDS: a preemption or a host-detected
+  stop bumps ``_epoch`` and the stale suffix of the ring is dropped
+  (``_discard_ring``), as is a ring no running row is left to drain; the
+  fallback synchronous round recomputes from committed state, so emitted
+  streams are byte-identical at any depth. (Discarded chunks are harmless:
+  their KV writes land past every committed length and are either
+  rewritten identically or masked by attention-length bounds; pages they
+  touched of freed slots are fully rescattered by the next owner.) A model
+  with recurrent state cannot replay a chunk (it has advanced the state),
+  so there a stale ring only ever drains: the one place the ring's rules
+  ask what kind of model is served.
 - Device-side termination: stop-token matching (per-slot padded stop-id
   rows), the max-tokens bound and the window bound are evaluated INSIDE the
   decode program against a device-resident ``finished`` mask — a finished
@@ -244,11 +254,12 @@ class _InflightChunk:
     """A dispatched-but-unread decode chunk (one entry of the lookahead
     ring).
 
-    ``epoch`` is the scheduler state epoch at dispatch; an admission /
-    preemption / resume (or a host-side stop the device could not see)
-    bumps the engine epoch, invalidating this chunk and every ring entry
-    after it — their tokens are discarded and a synchronous round recomputes
-    from committed state. Device-predicted finishes (stop match inside the
+    ``epoch`` is the scheduler state epoch at dispatch; a preemption (or a
+    host-side stop the device could not see) bumps the engine epoch,
+    invalidating this chunk and every ring entry after it — their tokens
+    are discarded and a synchronous round recomputes from committed state.
+    An admission or a resume never finds this chunk in flight: they wait
+    for the ring to empty. Device-predicted finishes (stop match inside the
     device stop width, max-tokens, window) do NOT bump: the finished row is
     frozen on-device, so the ring stays valid. The device outputs here are
     FUTURES: nothing blocks until the oldest-chunk drain (the D2H transfer
@@ -729,8 +740,10 @@ class ContinuousBatchingEngine:
         #: clean but spent; submit/start reject, and a lifecycle manager
         #: builds a FRESH engine (reusing .params) instead of restarting it
         self._closed = False
-        #: state epoch: bumped on admission/preempt/resume and host-fallback
-        #: stop finishes — ring entries dispatched at an older epoch are stale
+        #: state epoch: bumped by what changes the rows under a ring in
+        #: flight (preemption, a host-fallback stop, a handoff export) —
+        #: ring entries dispatched at an older epoch are stale. Admission
+        #: and resume wait for an empty ring instead (_admit)
         self._epoch = 0
         #: the lookahead ring: dispatched-but-undrained chunks, oldest first.
         #: Ring size beyond the drained chunk is capped at _lookahead_depth.
@@ -782,6 +795,12 @@ class ContinuousBatchingEngine:
         self.round_timings: "deque[dict]" = deque(maxlen=512)
         self.queue_wait_samples: "deque[float]" = deque(maxlen=2048)
         self._lookahead_stats = {"dispatched": 0, "used": 0, "discarded": 0}
+        # the ring's /metrics series exist from engine build, so a window
+        # without a discard reads 0 and not nothing
+        for series in ("llm_decode_chunks_dispatched_total",
+                       "llm_decode_chunks_discarded_total",
+                       "llm_admission_ring_waits_total"):
+            bump_counter(series, n=0.0)
         #: achieved ring depth at each drain (how many chunks stayed in
         #: flight while the host emitted) → stats() depth histogram
         self._depth_hist: dict[int, int] = {}
@@ -1361,12 +1380,6 @@ class ContinuousBatchingEngine:
         # ids that matched nothing raced a terminal (finished/preempt-shed in
         # the same round): the request already got its one terminal — the
         # cancel is consumed without effect, never a second emission
-        if self._ring and not self.active.any() and not self._prefill_slots:
-            # no OCCUPIED slot remains (prefill-phase slots are occupied but
-            # inactive — the PR-6 invariant — and their mixed round would
-            # discard/drain the ring properly itself): nothing will ever
-            # drain these speculative chunks
-            self._discard_ring()
 
     def _cancel_filter_pending(self, cancels: dict[str, str],
                                now: float) -> None:
@@ -1933,34 +1946,38 @@ class ContinuousBatchingEngine:
     def _loop_body(self) -> None:
         while not self._stop.is_set():
             try:
-                # cancels/deadlines apply at the round boundary: BEFORE
-                # admission (a lapsed pending entry must never take the slot
-                # this pass is about to hand out)
-                self._service_cancellations()
-                # tenant soft-quota sweep: pure bookkeeping (marks a yield
-                # victim; the capacity pass performs the actual preempt)
-                self._service_tenant_caps()
-                # recurrent state: admission, resume and preemption patch
-                # device rows that a still-undrained chunk would overwrite
-                # when it commits, and save a state row that such a chunk has
-                # already advanced; they wait for the ring (_discard_ring)
-                if self._has_state and self._ring and not self.active.any():
-                    self._discard_ring()    # no row runs: nothing to replay
-                admitted = 0 if self._has_state and self._ring \
-                    else self._admit()
-                # prefilling slots are work too: mixed-batch rounds must run
-                # even before any slot reaches decode phase
-                if not self.active.any() and not self._prefill_slots:
-                    if admitted == 0:
-                        self._wake.wait(timeout=0.1)
-                        self._wake.clear()
-                    continue
-                self._decode_round()
+                if not self._loop_pass():
+                    self._wake.wait(timeout=0.1)
+                    self._wake.clear()
             except Exception as e:  # noqa: BLE001 — device errors must not hang clients
                 logger.exception("scheduler loop failed; failing in-flight requests")
                 self._broken = str(e)[:500]
                 self._fail_all_inflight("scheduler loop failed")
                 return
+
+    def _loop_pass(self) -> bool:
+        """One pass of the loop: the round boundary's bookkeeping, admission,
+        one round. False when there was nothing to do (the loop then waits
+        to be woken)."""
+        # cancels/deadlines apply at the round boundary: BEFORE admission (a
+        # lapsed pending entry must never take the slot this pass is about
+        # to hand out)
+        self._service_cancellations()
+        # tenant soft-quota sweep: pure bookkeeping (marks a yield victim;
+        # the capacity pass performs the actual preempt)
+        self._service_tenant_caps()
+        if self._ring and not self.active.any():
+            # no row runs (all finished or cancelled): nothing will drain
+            # these chunks, and nothing is left to replay them for
+            self._discard_ring()
+        # holds everything back while the ring has chunks in flight
+        admitted = self._admit()
+        # prefilling slots are work too: mixed-batch rounds must run even
+        # before any slot reaches decode phase
+        if not self.active.any() and not self._prefill_slots:
+            return admitted > 0
+        self._decode_round()
+        return True
 
     def _fail_all_inflight(self, why: str) -> None:
         """Error-terminate every in-flight, prefilling, suspended, and queued
@@ -2229,7 +2246,6 @@ class ContinuousBatchingEngine:
             self.page_table[slot, :] = 0
             self.page_table[slot, : len(chain)] = chain
             self._mark_pt_row(slot)
-            self._epoch += 1
             resumed += 1
             pause_s = time.monotonic() - rec.suspended_at
             if rec.handoff:
@@ -2292,7 +2308,22 @@ class ContinuousBatchingEngine:
     def _admit(self) -> int:
         """Resume suspended streams, then admit pending requests into free
         slots in PREFILL phase. Admission does no device work: the round
-        loop paces the prompts' chunks under ``prefill_budget_tokens``."""
+        loop paces the prompts' chunks under ``prefill_budget_tokens``.
+
+        Both WAIT FOR AN EMPTY RING, whatever the model: the chunks in
+        flight are tokens the running rows are owed, and the device runs
+        them before anything dispatched now, so dropping them gains the
+        arrival nothing and costs every running row a chunk computed twice
+        (with recurrent state it cannot even be replayed). The wait is
+        bounded: ``_can_extend_ring`` stops deepening the ring while
+        ``_admission_waiting``, so it is empty within ``decode_lookahead``
+        further drains. The rows a slot's admission patches are therefore
+        never under a chunk in flight, and nothing here bumps ``_epoch``."""
+        if self._ring:
+            if self._admission_waiting():
+                bump_counter("llm_admission_ring_waits_total")
+            self._last_admit_ms = 0.0
+            return 0
         t0 = time.monotonic()
         failpoint("scheduler.admit")
         admitted = self._resume_suspended()
@@ -2455,7 +2486,6 @@ class ContinuousBatchingEngine:
             self.slots[slot] = None
             raise
         self._prefill_slots.append(slot)
-        self._epoch += 1
 
     def _arm_spec(self, state: _SlotState, prompt_ids: list[int]) -> None:
         """Arm per-stream speculation at decode activation (the final
@@ -2696,13 +2726,21 @@ class ContinuousBatchingEngine:
             chunk_dev.copy_to_host_async()  # non-blocking D2H start
         except AttributeError:  # non-jax.Array backends (tests/stubs)
             pass
+        bump_counter("llm_decode_chunks_dispatched_total")
         return _InflightChunk(chunk_dev, last_o, keys_o, lens_o, fin_o,
                               active, self._epoch)
 
+    def _admission_waiting(self) -> bool:
+        """A slot is free and a request is pending or suspended: ``_admit``
+        takes it as soon as the ring is empty."""
+        return bool(self._free_slots) and (
+            bool(self._suspended) or not self._pending.empty())
+
     def _can_extend_ring(self) -> bool:
         """Chain one more speculative chunk off the ring tail only when the
-        speculation is likely to survive: no admission/resume can occur next
-        round, no prompt chunks are pending (a mixed round would be next),
+        speculation is likely to survive: no admission/resume is waiting for
+        the ring to empty (deepening it would starve the arrival), no prompt
+        chunks are pending (a mixed round would be next),
         and every active chain pre-extends to cover the deeper horizon
         WITHOUT preempting (a failed extension just caps the ring depth; the
         next synchronous round preempts properly). Predictable finishes
@@ -2719,8 +2757,8 @@ class ContinuousBatchingEngine:
             # pending prompt chunks: the next round is a mixed round, not the
             # speculated pure-decode chunk — deterministic fallback to sync
             return False
-        if self._free_slots and (self._suspended or not self._pending.empty()):
-            return False  # an admission next round would invalidate it
+        if self._admission_waiting():
+            return False  # _admit holds it back until this ring is empty
         if self.spec_k and self._spec_round_safe() and self._spec_candidates():
             # live draft proposals: stop deepening the ring so it drains and
             # the next dispatch speculates instead — a k-token verify span
@@ -2743,6 +2781,11 @@ class ContinuousBatchingEngine:
     def _discard_ring(self) -> None:
         """Drop every still-undrained ring entry (the stale suffix of the
         pipeline — chunks already drained were committed and emitted).
+        Callers: what cannot wait for the ring and can be replayed — the
+        epoch checks of a decode round (a preemption or a host-fallback stop
+        bumped ``_epoch``) and of a mixed round's emit, and ``_loop_pass``
+        when no running row is left to drain it. Admission and resume never
+        come here: they wait (``_admit``).
         Committed state (last_tokens / keys / lengths / finished) was never
         advanced past the last drained chunk, so nothing needs restoring; a
         discarded chunk's only lasting effect is KV written past every
@@ -2756,10 +2799,12 @@ class ContinuousBatchingEngine:
             # not). So a stale ring is never dropped while a row runs: the
             # rounds that follow drain it (it is not extended:
             # _can_extend_ring checks the epoch), rows the host finished are
-            # masked out of the emit, and _loop_body admits nothing until it
-            # is empty.
+            # masked out of the emit, and _admit takes nothing until it is
+            # empty. This is the one rule of the ring that asks whether the
+            # model has state.
             return
         self._lookahead_stats["discarded"] += len(self._ring)
+        bump_counter("llm_decode_chunks_discarded_total", n=len(self._ring))
         self._ring.clear()
 
     def _commit_chunk(self, rec: _InflightChunk) -> np.ndarray:
@@ -2900,15 +2945,14 @@ class ContinuousBatchingEngine:
         self.page_table[slot, before: len(chain)] = chain[before:]
         self._mark_pt_row(slot)
 
-    def _finish_prefill(self, slot: int, state: _SlotState, tok: int,
-                        bump_epoch: bool = True) -> None:
+    def _finish_prefill(self, slot: int, state: _SlotState, tok: int) -> None:
         """Flip a fully-prefilled slot to decode: commit the prompt's full
         pages to the radix tree (later requests reuse them zero-copy),
         activate the slot's device rows, and emit the first token (sampled
-        inside the same mixed dispatch that ran the final chunk).
-        ``bump_epoch=False`` is the ring-spanning path: the mixed dispatch
-        already computed the flip on-device (active_out/final_lens), so the
-        chunks chained off it are valid and must not be discarded."""
+        inside the same mixed dispatch that ran the final chunk). The epoch
+        stays: chunks that span the flip were chained off the mixed
+        dispatch, which computed it on-device (active_out/final_lens), and
+        with no span there is no ring to stale."""
         T = len(state.prompt_ids)
         try:
             self.pool.commit_chain(state.prompt_ids, state.chain,
@@ -2926,8 +2970,6 @@ class ContinuousBatchingEngine:
         self._patch_slot_device(
             slot, s.temperature, s.top_p, s.top_k, T, True,
             stops=state.stops, limit=T + s.max_tokens - 1)
-        if bump_epoch:
-            self._epoch += 1
         dur_ms = (time.monotonic() - state.prefill_t0) * 1000.0
         # the chunked path's duration spans the budget-paced rounds — the
         # realistic "time to get through prefill under current load"
@@ -3153,11 +3195,13 @@ class ContinuousBatchingEngine:
         separation, so an arrival burst never stalls in-flight streams
         behind a prefill drain, and the dispatch computes the tokens it has
         (``n_slots + width`` positions).
-        A ring in flight here is stale by construction (admission of prefill
-        work bumped the epoch) and is discarded — EXCEPT the other way
-        around: when this round's plan drains the prefill queue, lookahead
-        chunks chain off THIS dispatch's outputs (_mixed_ring_span), so the
-        mixed→pure-decode transition keeps the pipeline full.
+        No ring is in flight here: prefill work is admitted and resumed
+        only off an empty ring (``_admit``), none is built while a prompt
+        has chunks left, and the ``spec_only`` entry is taken off a drained
+        ring. The other way around it is this round that builds one: when
+        its plan drains the prefill queue, lookahead chunks chain off THIS
+        dispatch's outputs (_mixed_ring_span), so the mixed→pure-decode
+        transition keeps the pipeline full.
 
         Speculative rounds (scheduler_spec_k > 0): eligible greedy rows with
         a live ngram proposal become q_len=1+d draft spans in the SAME
@@ -3173,8 +3217,7 @@ class ContinuousBatchingEngine:
         round."""
         t0 = time.monotonic()
         wall0 = time.time()
-        if self._ring:
-            self._discard_ring()
+        assert not self._ring, "a mixed round met chunks in flight"
         # capacity: decode rows keep a full chunk of headroom (the invariant
         # every round preserves); prefill rows cover their chunk's pages.
         # MemoryError on either path preempts-to-host.
@@ -3385,10 +3428,7 @@ class ContinuousBatchingEngine:
                     duration_ms=(t2 - t0) * 1000.0,
                     request_id=state.request_id, slot=slot, tokens=chunk)
         for slot, state in finals:
-            # spanned flips must not bump the epoch: the chained ring chunks
-            # already carry the flip state (device-computed) and stay valid
-            self._finish_prefill(slot, state, int(toks2d[slot, 0]),
-                                 bump_epoch=spanned == 0)
+            self._finish_prefill(slot, state, int(toks2d[slot, 0]))
         for slot in decode_rows:
             state = self.slots[slot]
             if state is None or not self.active[slot]:
@@ -3440,8 +3480,8 @@ class ContinuousBatchingEngine:
         t0 = time.monotonic()
         wall0 = time.time()
         depth = self._lookahead_depth
-        # an epoch bump since dispatch (admission/resume/preempt/host-fallback
-        # stop) stales every undrained entry — drop the suffix, resync below
+        # an epoch bump since dispatch (preempt/host-fallback stop/handoff
+        # export) stales every undrained entry — drop the suffix, resync below
         if self._ring and self._ring[0].epoch != self._epoch:
             self._discard_ring()
         used_lookahead = bool(self._ring)

@@ -7,6 +7,7 @@ and resumed, a finished row and an idle row each leave exactly what a run
 without them leaves. Greedy tokens are compared, and where the test can stop
 the world, the state rows bit for bit."""
 
+import functools
 import threading
 import time
 
@@ -93,18 +94,34 @@ def test_a_greedy_answer_repeats():
     assert all(len(t) == 12 for t in first.values())
 
 
-@pytest.mark.parametrize("lookahead", [0, 2])
-def test_lookahead_depth_never_changes_a_stream(lookahead):
-    """A chunk in flight has advanced the state; a stale ring is drained, not
-    replayed. Arrivals land while the ring holds chunks (staggered)."""
+def _staggered_prompts():
     base, other = _prompts(1)
-    prompts = [base, other, base[:40], other[:9]]
-    sync, _, _ = _run(_cfg(decode_lookahead=0), prompts, max_tokens=24)
-    got, stats, _ = _run(_cfg(decode_lookahead=lookahead), prompts,
-                         max_tokens=24, stagger_s=0.3)
+    return [base, other, base[:40], other[:9]]
+
+
+@functools.cache
+def _sync_streams(model):
+    """What the synchronous scheduler emits for the staggered prompts."""
+    return _run(_cfg(model=model, decode_lookahead=0), _staggered_prompts(),
+                max_tokens=24)[0]
+
+
+@pytest.mark.parametrize("lookahead", [0, 1, 2])
+@pytest.mark.parametrize("model", ["tiny-falcon-h1", "tiny-llama"])
+def test_lookahead_depth_never_changes_a_stream(model, lookahead):
+    """A chunk in flight has advanced the state; a stale ring is drained, not
+    replayed. Arrivals land while the ring holds chunks (staggered), and wait
+    for them: the one admission rule, so the case holds a model without
+    state to the same."""
+    sync = _sync_streams(model)
+    waits = _counter("llm_admission_ring_waits_total")
+    got, stats, _ = _run(_cfg(model=model, decode_lookahead=lookahead),
+                         _staggered_prompts(), max_tokens=24, stagger_s=0.3)
     assert got == sync
     if lookahead:   # the scenario occurred: chunks were in flight
         assert stats["pipeline"]["lookahead"]["dispatched"] > 0
+    else:           # nothing is ever in flight at a pass's start
+        assert _counter("llm_admission_ring_waits_total") == waits
 
 
 def test_a_prompt_sharing_two_whole_chunks_resumes_from_the_snapshot():

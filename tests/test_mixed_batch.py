@@ -209,6 +209,44 @@ def test_mixed_lookahead_vs_sync_bit_identical_seeded():
         "lookahead never engaged after prefill drained — vacuous"
 
 
+@pytest.mark.parametrize("depth", [1, 2])
+def test_an_arrivals_lane_runs_off_an_empty_ring_and_rebuilds_it(depth):
+    """A mixed round never meets chunks in flight: the arrival is admitted
+    only once the ring has drained (nothing discarded), its one-chunk prompt
+    runs as the lane of the next step, and the ring is rebuilt off that
+    dispatch (``depth`` in the round's record), so the round after it is
+    served by a chunk already in flight."""
+    rng = np.random.default_rng(8)
+    eng = ContinuousBatchingEngine(_cfg(decode_lookahead=depth), seed=0)
+    eng.start = lambda: None    # the test makes the loop's passes
+    col = _Collector(2)
+    try:
+        eng.submit(rng.integers(3, 900, 10).tolist(),
+                   SamplingParams(max_tokens=60), col.emit_for(0))
+        while not (eng.active.any() and len(eng._ring) == depth):
+            eng._loop_pass()
+        eng.submit(rng.integers(3, 900, 20).tolist(),
+                   SamplingParams(max_tokens=8), col.emit_for(1))
+        kinds = []
+        while not col.tokens[1]:
+            rings = len(eng._ring)
+            eng._loop_pass()
+            kinds.append((rings, eng.round_timings[-1]["kind"],
+                          eng.round_timings[-1]["depth"]))
+        # depth drains of the chunks in flight, then the lane off an empty
+        # ring, which chains depth chunks behind itself
+        assert kinds == [(depth - i, "decode", depth - i - 1)
+                         for i in range(depth)] + [(0, "mixed", depth)]
+        assert eng._lookahead_stats["discarded"] == 0
+        eng._loop_pass()
+        assert eng.round_timings[-1]["lookahead"] is True
+        while not col.done.is_set():
+            eng._loop_pass()
+    finally:
+        eng.shutdown()
+    assert len(col.tokens[0]) == 60 and len(col.tokens[1]) == 8
+
+
 def test_prefill_storm_rounds_bounded_by_chunk_budget():
     """A storm of long prompts must be consumed in budget-bounded chunks: no
     round prefills more than prefill_budget_tokens, and the in-flight decode

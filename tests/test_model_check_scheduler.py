@@ -151,12 +151,10 @@ class Harness:
             assert c >= 1, f"A7 refs[{p}]={c} {ctx}"
 
     def step(self, ctx: str) -> None:
-        self.eng._admit()
-        self.audit(f"{ctx}/post-admit")
-        # prefilling slots are work too: mixed-batch rounds run their chunks
-        if self.eng.active.any() or self.eng._prefill_slots:
-            self.eng._decode_round()
-            self.audit(f"{ctx}/post-round")
+        # the loop's own pass: admission (held while chunks are in flight),
+        # then a round if any slot is occupied
+        self.eng._loop_pass()
+        self.audit(f"{ctx}/post-pass")
 
 
 @pytest.mark.parametrize("budget", [16, 512],

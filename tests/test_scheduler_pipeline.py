@@ -6,12 +6,14 @@ synchronous scheduler for fixed seeds — speculation and admission shaping may
 change *when* device work runs, never *what* any request receives.
 """
 
+import functools
 import threading
 import time
 
 import numpy as np
 import pytest
 
+from cyberfabric_core_tpu.modkit.metrics import default_registry
 from cyberfabric_core_tpu.runtime import EngineConfig, SamplingParams
 from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
 
@@ -62,6 +64,20 @@ def _run_streams(cfg, prompts, samplings, timeout=240.0,
     finally:
         sched.shutdown()
     return col, stats
+
+
+def _manual(cfg):
+    eng = ContinuousBatchingEngine(cfg, seed=0)
+    eng.start = lambda: None    # no thread: the test makes the loop's passes
+    return eng
+
+
+def _passes_until(eng, done, limit=600) -> int:
+    for n in range(1, limit + 1):
+        eng._loop_pass()
+        if done():
+            return n
+    raise AssertionError(f"not reached in {limit} passes: {eng.stats()}")
 
 
 def test_lookahead_streams_bit_identical_to_sync():
@@ -204,14 +220,24 @@ def test_device_termination_keeps_ring_alive_through_finish():
     fallback rounds after the flip (every post-prefill round is served by
     a pre-dispatched chunk)."""
     prompt = np.random.default_rng(4).integers(3, 900, 12).tolist()
-    col, stats = _run_streams(
-        _cfg(decode_lookahead=3),
-        [prompt], [SamplingParams(max_tokens=40, temperature=0.7, seed=9)])
+    eng = _manual(_cfg(decode_lookahead=3))
+    col = _Collector(1)
+    try:
+        eng.submit(prompt, SamplingParams(max_tokens=40, temperature=0.7,
+                                          seed=9), col.emit_for(0))
+        _passes_until(eng, col.done.is_set)
+        stats = eng.stats()
+        eng._loop_pass()        # no row runs: the chunks past the end go
+        left = eng._lookahead_stats["discarded"]
+    finally:
+        eng.shutdown()
     assert len(col.tokens[0]) == 40
     pipe = stats["pipeline"]
     assert pipe["lookahead"]["discarded"] == 0, pipe
     assert pipe["discard_ratio"] == 0.0
     assert pipe["lookahead"]["used"] > 0
+    # what was in flight past the stream's end is dropped once nothing runs
+    assert 0 < left <= 3
     # mixed rounds ran (chunked admission), and every later decode round
     # was ring-served: rounds == mixed_rounds + lookahead_rounds exactly
     assert pipe["mixed_rounds"] >= 1
@@ -377,6 +403,207 @@ def test_stats_surface_pipeline_breakdown():
     assert set(st["queue_wait_ms"]) == {"p50", "max", "count"}
 
 
+# ------------------------------------------- admission waits for the ring
+#
+# One rule for every model: an arrival (or a resume) waits until the chunks
+# in flight have been drained — each to every running row — and is admitted
+# off the empty ring. The loop's passes are made by hand here (no thread), so
+# rounds can be counted.
+
+FAMILIES = ["tiny-llama", "tiny-falcon-h1"]
+
+
+def _counter(name) -> float:
+    for _labels, value in default_registry.counter(name).samples():
+        return value
+    return 0.0
+
+
+def _arrival_run(model, depth, arrivals):
+    """Two rows decode with the ring as deep as it gets; then ``arrivals``
+    requests arrive at once into the two free slots. Returns the streams and
+    what the arrival met."""
+    rng = np.random.default_rng(40)
+    prompts = [rng.integers(3, 500, n).tolist() for n in (12, 20, 9, 15)]
+    samplings = [SamplingParams(max_tokens=44,
+                                temperature=0.8 if i % 2 else 0.0,
+                                top_p=0.9, seed=300 + i) for i in range(4)]
+    eng = _manual(_cfg(model=model, decode_lookahead=depth,
+                       prefill_budget_tokens=32))
+    col = _Collector(2 + arrivals)
+    try:
+        for i in range(2):
+            eng.submit(prompts[i], samplings[i], col.emit_for(i))
+        _passes_until(eng, lambda: eng.active.sum() == 2
+                      and len(eng._ring) == depth)
+        seen = {"ring": len(eng._ring),
+                "waits": _counter("llm_admission_ring_waits_total"),
+                "emitted": eng.tokens_emitted}
+        for i in range(2, 2 + arrivals):
+            eng.submit(prompts[i], samplings[i], col.emit_for(i))
+        held = []               # tokens emitted as each pass began
+
+        def admitted():
+            held.append(eng.tokens_emitted)
+            return eng._pending.empty()
+        held.append(eng.tokens_emitted)
+        seen["passes"] = _passes_until(eng, admitted)
+        seen["waits"] = _counter("llm_admission_ring_waits_total") - seen["waits"]
+        # what the running rows received while the arrival was held
+        seen["emitted"] = held[-2] - seen["emitted"]
+        seen["occupied"] = sum(s is not None for s in eng.slots)
+        seen["ring_rebuilt"] = len(eng._ring)
+        seen["discarded"] = eng._lookahead_stats["discarded"]
+        _passes_until(eng, col.done.is_set)
+    finally:
+        eng.shutdown()
+    return col, seen
+
+
+@functools.cache
+def _sync_arrivals(model, arrivals):
+    """The synchronous scheduler's run of the same arrivals: the reference."""
+    return _arrival_run(model, 0, arrivals)
+
+
+@pytest.mark.parametrize("arrivals", [1, 2])
+@pytest.mark.parametrize("depth", [1, 2])
+@pytest.mark.parametrize("model", FAMILIES)
+def test_an_arrival_waits_for_the_ring_and_discards_nothing(model, depth,
+                                                           arrivals):
+    """The tentpole, for both families: an arrival into a full ring is held
+    for exactly the chunks in flight (``depth`` drains, each emitted to both
+    running rows), then admitted off the empty ring in the next pass — two
+    arrivals into two free slots in that ONE pass — with nothing discarded,
+    and every stream is what the synchronous scheduler emits."""
+    sync_col, sync_seen = _sync_arrivals(model, arrivals)
+    assert sync_seen["passes"] == 1 and sync_seen["waits"] == 0
+    col, seen = _arrival_run(model, depth, arrivals)
+    assert col.tokens == sync_col.tokens, "streams differ from depth 0"
+    assert col.finishes == sync_col.finishes
+    assert seen["ring"] == depth
+    # no starvation: the ring stopped extending, so the wait is its depth
+    assert seen["passes"] == depth + 1, seen
+    assert seen["waits"] == depth, seen
+    # each drain went to both running rows: the wait cost them nothing
+    assert seen["emitted"] == depth * 2 * 4, seen
+    # the admitting pass ran the arrival's chunk as a lane; a lone arrival's
+    # step drains the prefill queue, so the ring is rebuilt off that dispatch
+    assert seen["ring_rebuilt"] == (depth if arrivals == 1 else 0), seen
+    assert seen["occupied"] == 2 + arrivals, "not all admitted in one pass"
+    assert seen["discarded"] == 0, seen
+
+
+@pytest.mark.parametrize("model,discards", [("tiny-llama", True),
+                                            ("tiny-falcon-h1", False)])
+def test_a_host_fallback_stop_discards_on_llama_and_drains_with_state(
+        model, discards):
+    """What cannot wait still invalidates the ring: a stop the device could
+    not see (the stop set overflows ``device_stop_width``) while another row
+    runs. Without state the stale chunks are dropped and recomputed; with
+    state they are drained (a replay would advance the state twice) and
+    nothing is admitted meanwhile. Either way the survivor's stream is the
+    synchronous scheduler's."""
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(3, 500, 14).tolist() for _ in range(2)]
+    samplings = [SamplingParams(max_tokens=60, temperature=0.0),
+                 SamplingParams(max_tokens=60, temperature=0.9, seed=51,
+                                stop_token_ids=tuple(range(0, 400)))]
+
+    def run(depth):
+        eng = _manual(_cfg(model=model, decode_lookahead=depth,
+                           prefill_budget_tokens=32))
+        col = _Collector(2)
+        try:
+            for i in range(2):
+                eng.submit(prompts[i], samplings[i], col.emit_for(i))
+            before = _counter("llm_decode_chunks_discarded_total")
+            _passes_until(eng, lambda: 1 in col.finishes)
+            assert col.finishes[1] == "stop" and 0 not in col.finishes
+            seen = {"discarded": eng._lookahead_stats["discarded"],
+                    "counted": _counter("llm_decode_chunks_discarded_total")
+                    - before,
+                    "ring": len(eng._ring)}
+            _passes_until(eng, col.done.is_set)
+        finally:
+            eng.shutdown()
+        return col, seen
+
+    sync_col, _ = run(0)
+    col, seen = run(2)
+    assert col.tokens == sync_col.tokens and col.finishes == sync_col.finishes
+    assert len(col.tokens[1]) < 60, "the stop set never fired"
+    assert seen["counted"] == seen["discarded"]     # one count, two surfaces
+    if discards:
+        assert seen["discarded"] >= 1 and seen["ring"] == 0, seen
+    else:
+        assert seen["discarded"] == 0, seen
+
+
+def test_the_rings_series_are_at_zero_from_engine_build():
+    """The three counters the rule brings exist from engine build, unlabelled,
+    so a window without a discard reads 0 and not nothing; a dispatch and a
+    drop then move them as ``stats()["pipeline"]["lookahead"]`` moves."""
+    names = ("llm_decode_chunks_dispatched_total",
+             "llm_decode_chunks_discarded_total",
+             "llm_admission_ring_waits_total")
+    before = {n: _counter(n) for n in names}
+    eng = _manual(_cfg(decode_lookahead=2))
+    col = _Collector(1)
+    try:
+        text = default_registry.render()
+        for n in names:
+            assert any(line.split(" ")[0] == n
+                       for line in text.splitlines()), f"{n} not rendered"
+            assert _counter(n) == before[n], "engine build moved a counter"
+        eng.submit([5, 6, 7, 8], SamplingParams(max_tokens=24),
+                   col.emit_for(0))
+        _passes_until(eng, col.done.is_set)
+        eng._loop_pass()        # no row runs: what is left in flight is dropped
+        la = dict(eng._lookahead_stats)
+    finally:
+        eng.shutdown()
+    dispatched = _counter(names[0]) - before[names[0]]
+    # every _dispatch_chunk: the chained ones and the heads of sync rounds
+    assert dispatched >= la["dispatched"] > 0
+    assert _counter(names[1]) - before[names[1]] == la["discarded"]
+    assert _counter(names[2]) == before[names[2]]   # nobody waited
+
+
+def test_the_rings_series_are_on_metrics_before_the_first_request():
+    """A freshly booted stack's ``/metrics`` names the three series, with
+    their help text and an unlabelled sample, before any request is served:
+    the benchmark's ``counter`` reader needs both ends of a window."""
+    import asyncio
+
+    import aiohttp
+    from conftest import boot_stack, stop_stack
+
+    async def go():
+        rt, base = await boot_stack({"modules": {
+            "api_gateway": {"config": {"bind_addr": "127.0.0.1:0"}},
+            "monitoring": {}}})
+        try:
+            async with aiohttp.ClientSession() as s:
+                async with s.get(base + "/metrics") as r:
+                    assert r.status == 200
+                    return await r.text()
+        finally:
+            await stop_stack(rt)
+
+    text = asyncio.run(go())
+    for name in ("llm_decode_chunks_dispatched_total",
+                 "llm_decode_chunks_discarded_total",
+                 "llm_admission_ring_waits_total"):
+        assert f"# TYPE {name} counter" in text
+        help_line = next(line for line in text.splitlines()
+                         if line.startswith(f"# HELP {name} "))
+        assert len(help_line) > len(f"# HELP {name} ") + 20, help_line
+        sample = [line for line in text.splitlines()
+                  if line.split(" ")[0] == name]
+        assert len(sample) == 1 and float(sample[0].split(" ")[1]) >= 0.0
+
+
 # ------------------------------------------------- cancellation × pipeline
 
 
@@ -405,14 +632,21 @@ def test_cancel_mid_decode_survivor_bit_identical_no_ring_discard():
     samplings = [SamplingParams(max_tokens=40), SamplingParams(max_tokens=40)]
     cfg = _cfg(decode_lookahead=2)
 
-    ref_col, ref_stats = _run_streams(cfg, prompts, samplings)
+    ref_col, _ = _run_streams(cfg, prompts, samplings)
 
     sched = ContinuousBatchingEngine(cfg, seed=0)
     col = _Collector(2)
     triggered = []
+    discarded_at_finish = []
     try:
-        sched.submit(prompts[0], samplings[0], col.emit_for(0),
-                     request_id="surv")
+        inner_a = col.emit_for(0)
+
+        def emit_a(ev):
+            if ev.finished:     # on the scheduler thread, before any later pass
+                discarded_at_finish.append(
+                    sched._lookahead_stats["discarded"])
+            inner_a(ev)
+        sched.submit(prompts[0], samplings[0], emit_a, request_id="surv")
         inner_b = col.emit_for(1)
 
         def emit_b(ev):
@@ -432,10 +666,9 @@ def test_cancel_mid_decode_survivor_bit_identical_no_ring_discard():
     assert len(col.tokens[1]) < 40, "victim ran to completion anyway"
     assert stats["cancellations"] == {"test": 1}
     assert stats["reclaimed_tokens"] == 40 - len(col.tokens[1])
-    # the ring survived the cancel: no discard beyond what the uncancelled
-    # run itself did (admissions account for both runs identically)
-    assert stats["pipeline"]["lookahead"]["discarded"] \
-        <= ref_stats["pipeline"]["lookahead"]["discarded"]
+    # the ring survived the cancel (and the victim's admission waited for
+    # it): nothing was discarded while the survivor ran
+    assert discarded_at_finish == [0]
     _drain_clean(sched)
 
 
