@@ -16,7 +16,8 @@ __all__ = ["MODEL_CONFIGS", "ModelConfig", "get_config", "decoder_module"]
 #: decoder architectures the serving scheduler drives → their model module.
 #: Each exposes init_params, forward_paged_decode, forward_paged_mixed,
 #: lm_head_logits and gather_last_hidden.
-_DECODERS = {"llama": "llama", "falcon_h1": "falcon_h1"}
+_DECODERS = {"llama": "llama", "falcon_h1": "falcon_h1",
+             "sdar_moe": "sdar_moe"}
 
 
 def decoder_module(cfg: ModelConfig) -> ModuleType:

@@ -18,7 +18,8 @@ from typing import Optional
 class ModelConfig:
     name: str
     #: "llama" (decoder family) | "falcon_h1" (decoder whose every block runs
-    #: a Mamba-2 mixer beside GQA attention) | "bert" (encoder family)
+    #: a Mamba-2 mixer beside GQA attention) | "sdar_moe" (decoder of routed
+    #: experts that generates by diffusion over blocks) | "bert" (encoder)
     architecture: str
     vocab_size: int
     hidden_size: int
@@ -41,10 +42,21 @@ class ModelConfig:
     # mixture-of-experts (0 = dense MLP)
     num_experts: int = 0
     experts_per_token: int = 2
-    #: grouped-dispatch bucket headroom: capacity = ceil(N*K/E) * factor.
-    #: Tokens overflowing an expert's bucket lose that expert's contribution
-    #: (standard capacity semantics); 2.0 makes drops rare at serving loads.
-    moe_capacity_factor: float = 2.0
+    #: RMSNorm over each q and k head before RoPE (the Qwen3 block's
+    #: q_norm/k_norm; one weight vector of head_dim a layer each)
+    qk_norm: bool = False
+    # generation by diffusion over blocks (sdar_moe; 1 = one token a step,
+    # left to right). The sequence is cut into blocks of block_length at
+    # absolute positions; attention is causal between blocks and full inside
+    # one; an open block holds mask_token_id where nothing is decided yet and
+    # is denoised in forwards that unmask, by ``remasking``, at least
+    # block_length / denoising_steps positions each. The model card's
+    # defaults, not config.json keys.
+    block_length: int = 1
+    denoising_steps: int = 1
+    remasking: str = "low_confidence_static"
+    confidence_threshold: float = 0.9
+    mask_token_id: int = -1
     # falcon_h1: the state-space mixer beside attention (0 heads = no mixer).
     # Names follow the published config: mamba_d_ssm, mamba_n_heads,
     # mamba_d_head, mamba_d_state, mamba_n_groups, mamba_d_conv,
@@ -78,10 +90,31 @@ class ModelConfig:
             raise ValueError(
                 f"unknown hidden_act {self.hidden_act!r} "
                 "(supported: silu, gelu, gelu_pytorch_tanh)")
+        if self.remasking not in ("low_confidence_static",
+                                  "low_confidence_dynamic"):
+            raise ValueError(f"unknown remasking {self.remasking!r}")
+        if self.block_length > 1 and (
+                self.block_length % self.denoising_steps
+                or not 0 <= self.mask_token_id < self.vocab_size):
+            raise ValueError(
+                f"{self.name}: block_length {self.block_length} needs "
+                "denoising_steps that divide it and a mask_token_id inside "
+                "the vocabulary")
 
     @property
     def q_per_kv(self) -> int:
         return self.num_heads // self.num_kv_heads
+
+    @property
+    def router_float32(self) -> bool:
+        """The router's weights stay float32 whatever the activations' dtype
+        (a score decides WHICH experts run, not only how much)."""
+        return self.architecture == "sdar_moe"
+
+    @property
+    def is_block(self) -> bool:
+        """A decode step yields a block of tokens, not a token."""
+        return self.block_length > 1
 
     @property
     def has_state(self) -> bool:
@@ -109,7 +142,7 @@ class ModelConfig:
         h, i, v, l = self.hidden_size, self.intermediate_size, self.vocab_size, self.num_layers
         attn = h * (self.num_heads * self.head_dim) + 2 * h * (self.num_kv_heads * self.head_dim) \
             + (self.num_heads * self.head_dim) * h
-        mlp = 3 * h * i
+        mlp = 3 * h * i * max(self.num_experts, 1) + h * self.num_experts
         emb = v * h * (1 if self.tie_embeddings else 2)
         mixer = 0
         if self.has_state:  # in/out projections, conv + bias, A, D, dt, norm
@@ -263,6 +296,28 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         ssm_multipliers=(0.7, 0.5, 0.35, 1.0, 0.7),
         mlp_multipliers=(0.7, 0.3),
     ),
+    # SDAR-30B-A3B-Chat, config.json as published (model_type sdar_moe): 48
+    # blocks of GQA 32/4 attention with q/k head norms and 128 routed experts
+    # top-8 (moe_intermediate_size 768; every layer sparse, so the dense
+    # intermediate_size 6144 of the file names no matrix); generation by
+    # diffusion over blocks of 4 with the model card's defaults
+    "sdar-30b-a3b": ModelConfig(
+        name="sdar-30b-a3b", architecture="sdar_moe", vocab_size=151936,
+        hidden_size=2048, intermediate_size=768, num_layers=48,
+        num_heads=32, num_kv_heads=4, head_dim=128, max_position=32768,
+        rope_theta=1e6, rms_norm_eps=1e-6, num_experts=128,
+        experts_per_token=8, qk_norm=True, block_length=4,
+        denoising_steps=4, mask_token_id=151669,
+    ),
+    # CPU-test preset of the same block: 8 experts top-2, 2 queries a kv
+    # head, blocks of 4 in 4 steps, the mask id inside a vocabulary of 512
+    "tiny-sdar": ModelConfig(
+        name="tiny-sdar", architecture="sdar_moe", vocab_size=512,
+        hidden_size=64, intermediate_size=32, num_layers=2, num_heads=4,
+        num_kv_heads=2, head_dim=16, max_position=256, rope_theta=10000.0,
+        rms_norm_eps=1e-6, num_experts=8, experts_per_token=2, qk_norm=True,
+        block_length=4, denoising_steps=4, mask_token_id=511,
+    ),
     "bge-base-en": ModelConfig(
         name="bge-base-en", architecture="bert", vocab_size=30522, hidden_size=768,
         intermediate_size=3072, num_layers=12, num_heads=12, num_kv_heads=12,
@@ -279,6 +334,11 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
 # nothing else changed (tiny-llama-8l is the precedent for a named depth)
 MODEL_CONFIGS["falcon-h1-34b-16l"] = dataclasses.replace(
     MODEL_CONFIGS["falcon-h1-34b"], name="falcon-h1-34b-16l", num_layers=16)
+
+
+# one chip's stage of the 48-block deployment: 16 blocks, every expert
+MODEL_CONFIGS["sdar-30b-a3b-16l"] = dataclasses.replace(
+    MODEL_CONFIGS["sdar-30b-a3b"], name="sdar-30b-a3b-16l", num_layers=16)
 
 
 def get_config(name: str) -> ModelConfig:

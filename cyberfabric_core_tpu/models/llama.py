@@ -95,10 +95,15 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
             "bq": w(next(k), L, Dq), "bk": w(next(k), L, Dkv),
             "bv": w(next(k), L, Dkv),
         })
+    if cfg.qk_norm:
+        layers.update({"q_norm": jnp.ones((L, cfg.head_dim), dtype),
+                       "k_norm": jnp.ones((L, cfg.head_dim), dtype)})
     if cfg.num_experts > 0:
         E = cfg.num_experts
+        router = w(next(k), L, H, E)
         layers.update({
-            "router": w(next(k), L, H, E),
+            "router": router.astype(jnp.float32) if cfg.router_float32
+            else router,
             "moe_gate": w(next(k), L, E, H, I),
             "moe_up": w(next(k), L, E, H, I),
             "moe_down": w(next(k), L, E, I, H),
@@ -151,70 +156,79 @@ def _moe_mlp_dense(x: jnp.ndarray, lp: dict, cfg: ModelConfig) -> jnp.ndarray:
     return jnp.einsum("bteh,bte->bth", expert_out, weights.astype(jnp.float32))
 
 
-def _moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig) -> jnp.ndarray:
-    """Routed (grouped) MoE MLP — tokens are dispatched to per-expert buckets
-    and only the selected experts compute (VERDICT r1 weak #5: the dense
-    formulation paid E× FLOPs).
+#: the expert matrices of a layers tree: handed to the expert layer as the
+#: tree stacks them, ``[L, E, ...]``, with the layer's index, never a layer of
+#: them (ops/grouped_matmul.py says why)
+MOE_LEAVES = ("moe_gate", "moe_up", "moe_down")
 
-    TPU formulation: static shapes throughout — tokens sort by expert id, land
-    in an [E, C, H] dispatch buffer (C = capacity from cfg.moe_capacity_factor;
-    overflow tokens lose that expert's contribution, standard MoE capacity
-    semantics), one batched einsum per projection runs all experts' buckets on
-    the MXU, and a scatter-add combines weighted expert outputs. FLOPs scale
-    with K·C, not E. With expert weights sharded over the ``ep`` mesh axis the
-    einsums split per-device exactly as the dense form did.
-    """
-    E, K = cfg.num_experts, cfg.experts_per_token
+
+def split_moe(layers: dict) -> tuple[dict, dict | None]:
+    """(what a ``lax.scan`` over the layers slices, the stacked expert
+    matrices it must not slice); the second is None for a dense model."""
+    if "moe_gate" not in layers:
+        return layers, None
+    return ({k: v for k, v in layers.items() if k not in MOE_LEAVES},
+            {k: layers[k] for k in MOE_LEAVES})
+
+
+def moe_route(flat: jnp.ndarray, router: jnp.ndarray, k: int):
+    """Top-k routing of ``flat`` [N, H]: (experts [N, k] int32, gates [N, k]
+    f32). The gate is the softmax over the chosen experts' scores, which is
+    also the softmax over every expert renormalised over the chosen
+    (``norm_topk_prob``): the two are one number. A float32 router is
+    multiplied in float32 at full precision, since a score decides WHICH
+    experts run, not only how much."""
+    if router.dtype == jnp.float32:
+        logits = jnp.einsum("nh,he->ne", flat.astype(jnp.float32), router,
+                            precision=jax.lax.Precision.HIGHEST)
+    else:
+        logits = jnp.einsum("nh,he->ne", flat, router,
+                            preferred_element_type=jnp.float32)
+    top_vals, top_idx = jax.lax.top_k(logits, k)
+    return top_idx.astype(jnp.int32), jax.nn.softmax(top_vals, axis=-1)
+
+
+def moe_experts(flat: jnp.ndarray, top_idx: jnp.ndarray, gates: jnp.ndarray,
+                moe: dict, cfg: ModelConfig, layer) -> jnp.ndarray:
+    """The chosen experts' gated MLPs, summed by gate: [N, H] f32. ``moe``
+    holds the STACKED expert matrices and ``layer`` picks the layer inside
+    the grouped matmul. Dropless: the ``N*K`` assignments are sorted by
+    expert, each expert multiplies the rows that chose it, however many, and
+    the results go back to token order."""
+    from ..ops.grouped_matmul import grouped_matmul
+
+    E = cfg.num_experts
+    N, K = top_idx.shape
+    interpret = _default_interpret()
+    expert_of = top_idx.reshape(N * K)
+    order = jnp.argsort(expert_of)            # stable: token order in a group
+    sizes = jnp.bincount(expert_of, length=E).astype(jnp.int32)
+    rows = flat[order // K]                                    # [NK, H]
+
+    def gmm(x, w):
+        m, s = (w["q"], w["s"]) if isinstance(w, dict) else (w, None)
+        return grouped_matmul(x, m, s, sizes, layer, interpret=interpret)
+
+    gate = gmm(rows, moe["moe_gate"])
+    up = gmm(rows, moe["moe_up"])
+    act = (_act(gate, cfg) * up).astype(flat.dtype)
+    out = gmm(act, moe["moe_down"]) * gates.reshape(N * K)[order][:, None]
+    return jnp.zeros((N, flat.shape[1]), jnp.float32).at[order // K].add(out)
+
+
+def _moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig,
+             moe: dict | None = None, layer=None) -> jnp.ndarray:
+    """Routed MoE MLP over ``x`` [B, T, H] -> f32: :func:`moe_route` then
+    :func:`moe_experts`, for every MoE configuration. ``moe``/``layer``: the
+    stacked expert matrices and the layer (what the forwards pass); without
+    them ``lp`` holds one layer's, which is then a stack of one."""
     B, T, H = x.shape
-    N = B * T
-    flat = x.reshape(N, H)
-
-    router_logits = jnp.einsum("nh,he->ne", flat, lp["router"],
-                               preferred_element_type=jnp.float32)
-    top_vals, top_idx = jax.lax.top_k(router_logits, K)      # [N, K]
-    weights = jax.nn.softmax(top_vals, axis=-1)              # [N, K]
-
-    # dispatch plan: assignments sorted by expert; position within the
-    # expert's bucket via counts/offsets — all static-shape
-    NK = N * K
-    expert_of = top_idx.reshape(NK)                          # [NK]
-    token_of = jnp.repeat(jnp.arange(N, dtype=jnp.int32), K)
-    gate_of = weights.reshape(NK)
-    order = jnp.argsort(expert_of)
-    se, st, sg = expert_of[order], token_of[order], gate_of[order]
-    counts = jnp.bincount(se, length=E)                      # [E]
-    offsets = jnp.cumsum(counts) - counts                    # [E]
-    pos = jnp.arange(NK, dtype=jnp.int32) - offsets[se]      # slot in bucket
-
-    # floor the bucket size at small N (decode: N == batch): the mean-load
-    # formula collapses there while a single expert can legally receive every
-    # token — min(N, 256) restores exactness precisely when it is cheap
-    capacity = max(int(-(-N * K // E) * cfg.moe_capacity_factor),
-                   min(N, 256), 1)
-    keep = pos < capacity
-    # overflow lands in a sacrificial extra bucket row, never corrupting data
-    safe_e = jnp.where(keep, se, E)
-    safe_p = jnp.where(keep, pos, 0)
-    dispatch = jnp.zeros((E + 1, capacity, H), x.dtype)
-    dispatch = dispatch.at[safe_e, safe_p].set(flat[st])
-
-    g_m, g_s = _wmat(lp["moe_gate"], x.dtype)
-    u_m, u_s = _wmat(lp["moe_up"], x.dtype)
-    d_m, d_s = _wmat(lp["moe_down"], x.dtype)
-    xb = dispatch[:E]                                        # [E, C, H]
-    gate = _scaled(jnp.einsum("ech,ehi->eci", xb, g_m,
-                   preferred_element_type=jnp.float32), g_s)
-    up = _scaled(jnp.einsum("ech,ehi->eci", xb, u_m,
-                 preferred_element_type=jnp.float32), u_s)
-    act = (_act(gate, cfg) * up).astype(x.dtype)
-    expert_out = _scaled(jnp.einsum("eci,eih->ech", act, d_m,
-                         preferred_element_type=jnp.float32), d_s)  # [E, C, H]
-
-    # combine: weighted scatter-add back to token order (dropped tokens add 0)
-    contrib = expert_out[safe_e, safe_p] * sg[:, None]       # [NK, H] f32
-    contrib = jnp.where(keep[:, None], contrib, 0.0)
-    out = jnp.zeros((N, H), jnp.float32).at[st].add(contrib)
-    return out.reshape(B, T, H)
+    flat = x.reshape(B * T, H)
+    if moe is None:
+        moe = jax.tree.map(lambda a: a[None], {k: lp[k] for k in MOE_LEAVES})
+        layer = 0
+    top_idx, gates = moe_route(flat, lp["router"], cfg.experts_per_token)
+    return moe_experts(flat, top_idx, gates, moe, cfg, layer).reshape(B, T, H)
 
 
 def _qkv_proj(lp: dict, x: jnp.ndarray, cfg: ModelConfig,
@@ -245,6 +259,9 @@ def _qkv_proj(lp: dict, x: jnp.ndarray, cfg: ModelConfig,
     q = q.reshape(B, T, Hq, D)
     kproj = kproj.reshape(B, T, Hkv, D)
     vproj = vproj.reshape(B, T, Hkv, D)
+    if cfg.qk_norm:     # per head, before the rotation (the Qwen3 block)
+        q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
+        kproj = rms_norm(kproj, lp["k_norm"], cfg.rms_norm_eps)
     q = apply_rope(q, positions, cos_t, sin_t)
     kproj = apply_rope(kproj, positions, cos_t, sin_t)
     return q, kproj, vproj
@@ -262,11 +279,13 @@ def _attn_out(lp: dict, h: jnp.ndarray, attn_flat: jnp.ndarray,
     return h + out.astype(h.dtype)
 
 
-def _mlp_residual(lp: dict, h: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
-    """Post-attention norm + (MoE or dense) MLP + residual."""
+def _mlp_residual(lp: dict, h: jnp.ndarray, cfg: ModelConfig,
+                  moe: dict | None = None, layer=None) -> jnp.ndarray:
+    """Post-attention norm + (MoE or dense) MLP + residual. ``moe`` and
+    ``layer``: see :func:`_moe_mlp`."""
     x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps, cfg.norm_weight_offset)
     if cfg.num_experts > 0:
-        return h + _moe_mlp(x, lp, cfg).astype(h.dtype)
+        return h + _moe_mlp(x, lp, cfg, moe, layer).astype(h.dtype)
     g_m, g_s = _wmat(lp["gate"], h.dtype)
     u_m, u_s = _wmat(lp["up"], h.dtype)
     d_m, d_s = _wmat(lp["down"], h.dtype)
@@ -341,13 +360,14 @@ def forward(
                 sliding_window=cfg.sliding_window,
             )
         h = _attn_out(lp, h, attn.reshape(B, T, Hq * D))
-        h = _mlp_residual(lp, h, cfg)
+        h = _mlp_residual(lp, h, cfg, moe, layer)
         return (h, k_cache, v_cache), None
 
     k_cache, v_cache = cache
+    scanned, moe = split_moe(params["layers"])
     (h, k_cache, v_cache), _ = jax.lax.scan(
         layer_body, (h, k_cache, v_cache),
-        (params["layers"], jnp.arange(cfg.num_layers, dtype=jnp.int32)),
+        (scanned, jnp.arange(cfg.num_layers, dtype=jnp.int32)),
     )
     h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps, cfg.norm_weight_offset)
     return h, (k_cache, v_cache)
@@ -400,7 +420,9 @@ def _shard_mapped_attn(mesh, kernel_fn, q_spec, tail_specs):
 
 class DecodeGroup(NamedTuple):
     """The decode rows of a mixed step: every slot's one token, beside the
-    lane's chunk (``forward_paged_mixed``). Row ``b`` is slot ``b``."""
+    lane's chunk (``forward_paged_mixed``). Row ``b`` is slot ``b``. A model
+    that generates by blocks has ``tokens`` [B, W], each slot's open block,
+    at positions ``lengths .. lengths + W - 1``."""
     tokens: jnp.ndarray    # [B] int32 each slot's last token
     lengths: jnp.ndarray   # [B] int32 valid length BEFORE this token
     run: jnp.ndarray       # [B] bool; False rows write to scratch, keep state
@@ -428,13 +450,14 @@ def _decode_attend(cfg: ModelConfig, interpret: bool, mesh):
 
 def _ragged_attend(cfg: ModelConfig, interpret: bool, mesh):
     """``attend(q [R, Qc, Hq, D], k_pool, v_pool, page_table [R, Pmax], hist,
-    q_lens, layer)``: the ragged kernel, as :func:`_decode_attend`."""
+    q_lens, layer)``: the ragged kernel, as :func:`_decode_attend`; under
+    the block mask where the model generates by blocks."""
     from ..ops.paged_attention import ragged_paged_attention
 
     def attend(qq, kk, vv, pt, hh, ql, ly):
         return ragged_paged_attention(
             qq, kk, vv, pt, hh, ql, ly, interpret=interpret,
-            sliding_window=cfg.sliding_window)
+            sliding_window=cfg.sliding_window, block=cfg.block_length)
 
     if mesh is None:
         return attend
@@ -445,12 +468,21 @@ def _ragged_attend(cfg: ModelConfig, interpret: bool, mesh):
         (P(None, None), P(None), P(None)))
 
 
-def _decode_targets(page_table, lengths, write_mask, page_size: int):
+def _decode_targets(page_table, lengths, write_mask, page_size: int,
+                    width: int | None = None):
     """Where each slot's one new token is written: (page id, offset), [B]
-    each; rows with ``write_mask`` False target scratch page 0."""
-    pid = jnp.take_along_axis(
-        page_table, (lengths // page_size)[:, None], axis=1)[:, 0]
-    off = lengths % page_size
+    each; rows with ``write_mask`` False target scratch page 0. ``width``:
+    a block of that many tokens a slot, at ``lengths ..``; [B, width]."""
+    if width is None:
+        pid = jnp.take_along_axis(
+            page_table, (lengths // page_size)[:, None], axis=1)[:, 0]
+        off = lengths % page_size
+    else:
+        pos = lengths[:, None] + jnp.arange(width, dtype=jnp.int32)[None, :]
+        pid = jnp.take_along_axis(page_table, pos // page_size, axis=1)
+        off = pos % page_size
+        if write_mask is not None:
+            write_mask = write_mask[:, None]
     if write_mask is not None:
         pid = jnp.where(write_mask, pid, 0)
         off = jnp.where(write_mask, off, 0)
@@ -515,13 +547,14 @@ def forward_paged_decode(
             vproj.reshape(B, -1).astype(v_pool.dtype))
         attn = attend(q[:, 0], k_pool, v_pool, page_table, lengths + 1, layer)
         h = _attn_out(lp, h, attn.reshape(B, 1, Hq * D))
-        h = _mlp_residual(lp, h, cfg)
+        h = _mlp_residual(lp, h, cfg, moe, layer)
         return (h, k_pool, v_pool), None
 
     k_pool, v_pool = pools
+    scanned, moe = split_moe(params["layers"])
     (h, k_pool, v_pool), _ = jax.lax.scan(
         layer_body, (h, k_pool, v_pool),
-        (params["layers"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))
+        (scanned, jnp.arange(cfg.num_layers, dtype=jnp.int32)))
     h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps, cfg.norm_weight_offset)
     return h, _restore_pools((k_pool, v_pool), caller_shape)
 
@@ -560,13 +593,16 @@ def mixed_layout(input_ids, page_table, hist, q_lens, write_mask, rows,
     pid, off = pid.reshape(-1), off.reshape(-1)
     n_dec = 0
     if decode is not None:
-        n_dec = decode.tokens.shape[0]
+        n_dec = decode.tokens.size
+        width = decode.tokens.shape[1] if decode.tokens.ndim == 2 else None
         d_pid, d_off = _decode_targets(page_table, decode.lengths, decode.run,
-                                       page_size)
-        ids = jnp.concatenate([decode.tokens, ids])
-        positions = jnp.concatenate([decode.lengths, positions])
-        pid = jnp.concatenate([d_pid, pid])
-        off = jnp.concatenate([d_off, off])
+                                       page_size, width)
+        d_pos = decode.lengths if width is None else (
+            decode.lengths[:, None] + jnp.arange(width, dtype=jnp.int32))
+        ids = jnp.concatenate([decode.tokens.reshape(-1), ids])
+        positions = jnp.concatenate([d_pos.reshape(-1), positions])
+        pid = jnp.concatenate([d_pid.reshape(-1), pid])
+        off = jnp.concatenate([d_off.reshape(-1), off])
     return MixedLayout(n_dec, (R, Qc), ids[None], positions[None], pid, off,
                        lane_table, valid[:, 0])
 
@@ -585,8 +621,9 @@ def mixed_attention(lay: MixedLayout, q, k_pool, v_pool, page_table, hist,
     lane = lane.reshape(1, R * Qc, -1)
     if not nd:
         return lane
+    width = decode.tokens.shape[1] if decode.tokens.ndim == 2 else 1
     dec = decode_attend(q[0, :nd], k_pool, v_pool, page_table,
-                        decode.lengths + 1, layer)
+                        decode.lengths + width, layer)
     return jnp.concatenate([dec.reshape(1, nd, -1), lane], axis=1)
 
 
@@ -678,13 +715,14 @@ def forward_paged_mixed(
                                q_lens, decode, layer, lane_attend,
                                decode_attend)
         h = _attn_out(lp, h, attn)
-        h = _mlp_residual(lp, h, cfg)
+        h = _mlp_residual(lp, h, cfg, moe, layer)
         return (h, k_pool, v_pool), None
 
     k_pool, v_pool = pools
+    scanned, moe = split_moe(params["layers"])
     (h, k_pool, v_pool), _ = jax.lax.scan(
         layer_body, (h, k_pool, v_pool),
-        (params["layers"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))
+        (scanned, jnp.arange(cfg.num_layers, dtype=jnp.int32)))
     h = mixed_hidden_out(lay, h, q_lens, rows)
     h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps, cfg.norm_weight_offset)
     return h, _restore_pools((k_pool, v_pool), caller_shape)

@@ -517,6 +517,28 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                  "State rows restored from host on resume after preemption")):
             self.registry.counter(name, text).inc(0.0)
 
+        # a model that generates by diffusion over blocks, with routed
+        # experts (sdar_moe): pushed by the scheduler from what each drained
+        # program counted on the device
+        for name, text in (
+                ("llm_block_row_forwards_total",
+                 "A running row's part in one forward of its open block "
+                 "(denoise and commit forwards alike)"),
+                ("llm_block_commit_row_forwards_total",
+                 "Those of them that were commit forwards: the block had no "
+                 "mask left and its K/V was kept"),
+                ("llm_blocks_committed_total",
+                 "Blocks the host took from drained programs and emitted"),
+                ("llm_block_tokens_emitted_total",
+                 "Tokens emitted out of committed blocks (a stop id or the "
+                 "token limit cuts inside a block)"),
+                ("llm_moe_experts_touched_total",
+                 "Experts that received at least one token, summed over "
+                 "layers and forwards"),
+                ("llm_moe_experts_offered_total",
+                 "Experts there are, summed over layers and forwards")):
+            self.registry.counter(name, text).inc(0.0)
+
         def _state_stat(key: str) -> float:
             return float(sum(st.get(key, 0) for st in _pool_stats()))
 

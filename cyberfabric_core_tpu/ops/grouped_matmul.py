@@ -1,0 +1,145 @@
+"""Pallas grouped matmul: the expert matmuls of a dropless mixture-of-experts
+layer.
+
+Rows arrive SORTED BY EXPERT (``models/llama.py:_moe_mlp`` sorts a step's
+token-expert assignments), so expert ``e`` owns the contiguous rows
+``offsets[e] .. offsets[e+1]`` and the layer is ``E`` matmuls of ragged
+height. The kernel walks work items — one (row tile, expert) pair for every
+tile an expert's rows touch, in row order — so the work is in the rows there
+are and each touched expert's matrix is read once (an expert that spans two
+tiles keeps its block resident between the two items). No expert has a
+capacity and no row is dropped: an expert that every row chose is simply
+every tile's item.
+
+Like the paged kernels (``ops/paged_attention.py``) it takes the weights AS
+THE TREE STACKS THEM, ``[L, E, K, N]``, and the layer as a scalar-prefetch
+operand: a Mosaic call takes whole buffers, so a layer of the stack sliced in
+front of it (what ``lax.scan`` over the layers hands its body, and what
+``jax.lax.ragged_dot``'s own TPU kernel would be given) is a copy of every
+expert of the layer, three times the bytes of the matmul itself. int8 weights
+are converted block by block inside the kernel and their per-channel scale is
+applied to the f32 result.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+#: rows of a tile at serving sizes; a smaller step is one tile of its rows
+ROW_TILE = 128
+
+
+def _row_tile(m: int) -> int:
+    return ROW_TILE if m >= ROW_TILE else -(-m // 16) * 16
+
+
+def group_items(group_sizes: jnp.ndarray, m_pad: int, tm: int):
+    """The work items of one grouped matmul over ``m_pad`` rows in tiles of
+    ``tm``: per item its row tile, its expert and the expert's row range,
+    ``n_items`` long (static: every tile once, plus one for every further
+    expert that can start inside a tile), and how many of them are real. The
+    rest repeat the last real item, so their blocks are resident and their
+    body is skipped."""
+    E = group_sizes.shape[0]
+    tiles = m_pad // tm
+    n_items = tiles + min(E, m_pad) - 1
+    sizes = group_sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    first = starts // tm
+    per = jnp.where(sizes > 0, (ends - 1) // tm - first + 1, 0)
+    item_ends = jnp.cumsum(per)
+    n_real = item_ends[-1]
+    t = jnp.minimum(jnp.arange(n_items, dtype=jnp.int32),
+                    jnp.maximum(n_real - 1, 0))
+    expert = jnp.minimum(
+        jnp.searchsorted(item_ends, t, side="right").astype(jnp.int32), E - 1)
+    tile = first[expert] + t - (item_ends[expert] - per[expert])
+    return (tile.astype(jnp.int32), expert, starts[expert], ends[expert],
+            n_real.reshape(1))
+
+
+def _kernel(tile_ref, expert_ref, lo_ref, hi_ref, n_ref, layer_ref, x_ref,
+            w_ref, *rest, tm: int, scaled: bool):
+    """One work item: rows ``lo..hi`` of tile ``tile`` times expert
+    ``expert``'s matrix, added into the tile's output block (stored on the
+    tile's first item, so a block is never read before it is written)."""
+    s_ref, o_ref = rest if scaled else (None, rest[0])
+    t = pl.program_id(0)
+
+    @pl.when(t < n_ref[0])
+    def _item():
+        tile = tile_ref[t]
+        rows = tile * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+        mine = (rows >= lo_ref[t]) & (rows < hi_ref[t])
+        w = w_ref[0, 0]
+        if w.dtype != x_ref.dtype:
+            # int8 -> f32 -> the activations' dtype: the two-step convert is
+            # the one Mosaic lowers for every source width
+            w = w.astype(jnp.float32).astype(x_ref.dtype)
+        y = jax.lax.dot_general(x_ref[...], w, (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        if scaled:
+            y = y * s_ref[0, 0]
+        y = jnp.where(mine, y, 0.0)
+        opens = (t == 0) | (tile_ref[jnp.maximum(t - 1, 0)] != tile)
+
+        @pl.when(opens)
+        def _store():
+            o_ref[...] = y
+
+        @pl.when(jnp.logical_not(opens))
+        def _add():
+            o_ref[...] += y
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def grouped_matmul(
+    x: jnp.ndarray,            # [M, K] rows sorted by group
+    w: jnp.ndarray,            # [L, E, K, N] the stacked expert matrices
+    scale: jnp.ndarray | None,  # [L, E, N] f32 per-channel scale, or None
+    group_sizes: jnp.ndarray,  # [E] int32 rows of each group, summing to M
+    layer: jnp.ndarray | int = 0,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """``out[r] = x[r] @ w[layer, g(r)] * scale[layer, g(r)]`` in f32, where
+    ``g(r)`` is the group whose row range holds ``r``."""
+    M, K = x.shape
+    L, E, _, N = w.shape
+    tm = _row_tile(M)
+    m_pad = -(-M // tm) * tm
+    if m_pad != M:
+        x = jnp.pad(x, ((0, m_pad - M), (0, 0)))
+    tile, expert, lo, hi, n_real = group_items(group_sizes, m_pad, tm)
+    scaled = scale is not None
+
+    def rows_at(t, tile_ref, *_):
+        return (tile_ref[t], 0)
+
+    def expert_at(t, tile_ref, expert_ref, lo_ref, hi_ref, n_ref, layer_ref):
+        return (layer_ref[0], expert_ref[t], 0, 0)
+
+    in_specs = [pl.BlockSpec((tm, K), rows_at),
+                pl.BlockSpec((1, 1, K, N), expert_at)]
+    operands = [x, w]
+    if scaled:
+        in_specs.append(pl.BlockSpec((1, 1, 1, N), expert_at))
+        operands.append(scale.reshape(L, E, 1, N).astype(jnp.float32))
+    out = pl.pallas_call(
+        functools.partial(_kernel, tm=tm, scaled=scaled),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6, grid=(tile.shape[0],),
+            in_specs=in_specs, out_specs=pl.BlockSpec((tm, N), rows_at)),
+        out_shape=jax.ShapeDtypeStruct((m_pad, N), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=interpret,
+    )(tile, expert, lo, hi, n_real,
+      jnp.asarray(layer, jnp.int32).reshape(1), *operands)
+    return out[:M]

@@ -260,7 +260,7 @@ def _ragged_kernel(pt_ref, hist_ref, qlen_ref, layer_ref, q_ref, k_ref, v_ref,
                    acc_ref, m_ref, l_ref, *, page_size: int, q_block: int,
                    sliding_window: int | None = None,
                    two_d_dots: bool = False,
-                   head_dim: int | None = None):
+                   head_dim: int | None = None, block: int = 1):
     """One (slot, q-block, page) program of the ragged mixed-batch kernel.
 
     Refs:
@@ -283,6 +283,11 @@ def _ragged_kernel(pt_ref, hist_ref, qlen_ref, layer_ref, q_ref, k_ref, v_ref,
     GQA dots — the constructs Mosaic cannot lower — become unrolled lane
     slices, sublane/lane concats and per-kv-head 2D dots. Bitwise-identical
     to the batched interpret form (golden-pinned).
+
+    ``block`` (a power of two; 1 = causal): the mask of a model that
+    generates by diffusion over blocks — causal between blocks of that many
+    absolute positions and full inside one, so the bound of the query at
+    ``pos`` is the last position of its block, ``pos | (block - 1)``.
     """
     b = pl.program_id(0)
     qb = pl.program_id(1)
@@ -302,6 +307,8 @@ def _ragged_kernel(pt_ref, hist_ref, qlen_ref, layer_ref, q_ref, k_ref, v_ref,
     # last absolute query position this block serves: keys past it are
     # causally invisible to every row of the block, so the page is skipped
     q_hi = hist + jnp.minimum(qlen, q0 + q_block) - 1
+    if block > 1:
+        q_hi = q_hi | (block - 1)
     relevant = jnp.logical_and(q0 < qlen, k_start <= q_hi)
     if sliding_window is not None:
         # earliest window start across the block's queries
@@ -351,7 +358,8 @@ def _ragged_kernel(pt_ref, hist_ref, qlen_ref, layer_ref, q_ref, k_ref, v_ref,
             jnp.int32, (R, page_size), 1)
         # causal within the row's own history: k <= this query's position
         # (subsumes k < hist + qlen); padding query rows mask out entirely
-        mask = (q_idx < qlen) & (k_pos <= q_abs)
+        bound = q_abs | (block - 1) if block > 1 else q_abs
+        mask = (q_idx < qlen) & (k_pos <= bound)
         if sliding_window is not None:
             mask = mask & (k_pos > q_abs - sliding_window)
         scores = jnp.where(mask, scores, _NEG_INF)
@@ -414,7 +422,8 @@ def _ragged_kernel(pt_ref, hist_ref, qlen_ref, layer_ref, q_ref, k_ref, v_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("q_block", "interpret",
-                                             "sliding_window", "two_d_dots"))
+                                             "sliding_window", "two_d_dots",
+                                             "block"))
 def ragged_paged_attention(
     q: jnp.ndarray,           # [B, Qmax, Hq, D] — per-row query span, padded
     k_pool: jnp.ndarray,      # [L, N, page, Hkv*D] — the stacked page pool
@@ -427,6 +436,7 @@ def ragged_paged_attention(
     interpret: bool = False,
     sliding_window: int | None = None,
     two_d_dots: bool | None = None,
+    block: int = 1,
 ) -> jnp.ndarray:
     """Ragged mixed-batch paged attention: one dispatch where each batch row
     attends a variable-length query span over its paged KV chain with causal
@@ -444,9 +454,15 @@ def ragged_paged_attention(
     ``two_d_dots`` (default: on exactly when compiling for real) replaces
     the head-major [Qb,Hq,D]↔[Hq,Qb,D] shuffles and the batched GQA dots —
     the two constructs Mosaic cannot lower — with unrolled 2D slices/dots;
-    bitwise-identical to the batched interpret form (golden-pinned)."""
+    bitwise-identical to the batched interpret form (golden-pinned).
+
+    ``block`` > 1 is the block mask (see the kernel): a query sees the keys
+    up to the end of its own block of ``block`` absolute positions, which
+    must all be in the pool already."""
     if two_d_dots is None:
         two_d_dots = not interpret
+    if block & (block - 1):
+        raise ValueError(f"block {block} must be a power of two")
     B, Qmax, Hq, D = q.shape
     _, _, page_size, HD = k_pool.shape
     Pmax = page_table.shape[1]
@@ -459,6 +475,8 @@ def ragged_paged_attention(
         hist_b = hist_ref[b]
         qlen = qlen_ref[b]
         q_hi = hist_b + jnp.minimum(qlen, (qb + 1) * q_block) - 1
+        if block > 1:
+            q_hi = q_hi | (block - 1)
         last = jnp.maximum(q_hi // page_size, 0)
         jj = jnp.minimum(j, last)
         if sliding_window is not None:
@@ -495,7 +513,7 @@ def ragged_paged_attention(
         functools.partial(_ragged_kernel, page_size=page_size,
                           q_block=q_block, sliding_window=sliding_window,
                           two_d_dots=two_d_dots,
-                          head_dim=D if two_d_dots else None),
+                          head_dim=D if two_d_dots else None, block=block),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q_in.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -506,6 +524,26 @@ def ragged_paged_attention(
       q_lens.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
       q_in, k_pool, v_pool)
     return out.reshape(B, Qmax, Hq, D)
+
+
+def paged_block_attention(q, k_pool, v_pool, page_table, lengths, layer=0,
+                          interpret: bool = False):
+    """The open block of a model that generates by diffusion over blocks:
+    ``q`` [B, W, Hq, D], the block's W queries a row, every one of which sees
+    all ``lengths`` keys (the row's kept history and the block itself, which
+    the caller has written). That is :func:`paged_decode_attention` with the
+    block folded into the GQA group axis, W x G query rows a kv head, so the
+    pages are walked once a row and not once a position. Returns
+    [B, W, Hq, D]."""
+    B, W, Hq, D = q.shape
+    Hkv = k_pool.shape[3] // D
+    G = Hq // Hkv
+    folded = q.reshape(B, W, Hkv, G, D).transpose(0, 2, 1, 3, 4)
+    out = paged_decode_attention(
+        folded.reshape(B, Hkv * W * G, D), k_pool, v_pool, page_table,
+        lengths, layer, interpret=interpret)
+    return out.reshape(B, Hkv, W, G, D).transpose(0, 2, 1, 3, 4).reshape(
+        B, W, Hq, D)
 
 
 def paged_gather_dense(k_pool, v_pool, page_table, head_dim, layer=0):

@@ -127,3 +127,48 @@ def warped_probs(
     inv = jnp.argsort(sorted_idx, axis=-1)
     warped = jnp.take_along_axis(probs_sorted, inv, axis=-1)
     return jnp.where((temperature > 0)[:, None], warped, greedy)
+
+
+def block_unmask(
+    block: jnp.ndarray,        # [B, W] int32 the open block; mask_id = open
+    logits: jnp.ndarray,       # [B, W, V] f32, position i's for the token AT i
+    keys: jnp.ndarray,         # [B, 2] one PRNG key per slot, for this forward
+    temperature: jnp.ndarray,  # [B]
+    top_p: jnp.ndarray,        # [B]
+    top_k: jnp.ndarray,        # [B]
+    *,
+    mask_id: int,
+    per_step: int,
+    dynamic: bool = False,
+    threshold: float = 0.9,
+) -> jnp.ndarray:
+    """One denoising step of generation by diffusion over blocks: at every
+    masked position draw ``x0`` (greedy, or from the slot's key stream as
+    :func:`sample_token_per_slot` draws) with confidence
+    ``softmax(logits)[x0]``, then unmask by confidence. ``low_confidence_
+    static`` (``dynamic`` False) unmasks the ``per_step`` most confident
+    masked positions; ``low_confidence_dynamic`` every masked position whose
+    confidence is over ``threshold`` and at least the ``per_step`` most
+    confident. Ties go to the earlier position. Positions already decided
+    keep their token. Returns the block [B, W]."""
+    B, W, V = logits.shape
+    # the mask token is never drawn (a trained model does not predict it;
+    # random weights would, and the block would never close)
+    flat = logits.reshape(B * W, V).at[:, mask_id].set(-jnp.inf)
+    sub = jax.vmap(lambda k: jax.random.split(k, W))(keys).reshape(B * W, 2)
+    x0 = sample_token_per_slot(
+        flat, sub, jnp.repeat(temperature, W), jnp.repeat(top_p, W),
+        jnp.repeat(top_k, W))
+    picked = jnp.take_along_axis(flat, x0[:, None], axis=1)[:, 0]
+    conf = jnp.exp(picked - jax.nn.logsumexp(flat, axis=-1)).reshape(B, W)
+    x0 = x0.reshape(B, W)
+    masked = block == mask_id
+    conf = jnp.where(masked, conf, -jnp.inf)
+    # rank 0 = the most confident; a stable sort keeps the earlier position
+    # ahead among equals
+    order = jnp.argsort(-conf, axis=1, stable=True)
+    rank = jnp.argsort(order, axis=1)
+    take = rank < per_step
+    if dynamic:
+        take = take | (conf > threshold)
+    return jnp.where(take & masked, x0, block)

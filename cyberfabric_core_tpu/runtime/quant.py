@@ -128,10 +128,16 @@ def init_params_quantized(cfg, key: jax.Array, dtype=jnp.bfloat16,
             "bk": jax.random.normal(next(keys), (L, Dkv), dtype) * 0.02,
             "bv": jax.random.normal(next(keys), (L, Dkv), dtype) * 0.02,
         })
+    if cfg.qk_norm:
+        layers.update({"q_norm": jnp.ones((L, cfg.head_dim), dtype),
+                       "k_norm": jnp.ones((L, cfg.head_dim), dtype)})
     if cfg.num_experts > 0:
         E = cfg.num_experts
-        layers["router"] = (jax.random.normal(next(keys), (L, H, E), dtype)
-                            * jnp.asarray(H ** -0.5, dtype))
+        # never quantized
+        router_dtype = jnp.float32 if cfg.router_float32 else dtype
+        layers["router"] = (jax.random.normal(next(keys), (L, H, E),
+                                              router_dtype)
+                            * jnp.asarray(H ** -0.5, router_dtype))
         layers.update({"moe_gate": w(L, E, H, I), "moe_up": w(L, E, H, I),
                        "moe_down": w(L, E, I, H)})
     else:

@@ -121,7 +121,8 @@ from ..modkit.metrics import bump_counter
 from ..modkit.telemetry import (get_global_tracer, reset_log_context,
                                 set_log_context, traceparent_ids)
 from ..ops.rope import rope_frequencies
-from ..ops.sampling import sample_token_per_slot, split_keys_per_slot
+from ..ops.sampling import (block_unmask, sample_token_per_slot,
+                            split_keys_per_slot)
 from .engine import (EngineConfig, SamplingParams, SchedulerSaturated,
                      StepEvent, TenantQuotaExceeded, TenantSaturated)
 from .speculative import NgramProposer, greedy_accept_counts
@@ -136,6 +137,16 @@ _ROPE_TABLE_ROWS = 32768
 #: each width once has run every shape; two arrivals in one round take two
 #: steps, not a program of their own)
 LANE_ROWS = 1
+#: /metrics of a model that generates by blocks: a running row's part in one
+#: forward, those of them that were commit forwards, the blocks and tokens
+#: the host took from them, and over layers and forwards the experts that
+#: received a token beside the experts there are
+_BLOCK_SERIES = ("llm_block_row_forwards_total",
+                 "llm_block_commit_row_forwards_total",
+                 "llm_blocks_committed_total",
+                 "llm_block_tokens_emitted_total",
+                 "llm_moe_experts_touched_total",
+                 "llm_moe_experts_offered_total")
 
 
 def _null_ctx():
@@ -228,7 +239,9 @@ class _Suspended:
     state: _SlotState
     host_kv: tuple  # (k, v) numpy [L, n_pages, page, Hkv, D]
     length: int  # decode: valid kv length; prefill phase: prefill_pos
-    last_token: int  # meaningless for a prefill-phase suspend (no sample yet)
+    #: meaningless for a prefill-phase suspend (no sample yet); a model that
+    #: generates by blocks parks its open block here, [block_length] tokens
+    last_token: Any
     slot_key: Any  # per-slot RNG key (None for prefill phase: key untouched)
     #: True when the preemption was a tenant soft-quota YIELD (not pool
     #: pressure): resume defers this record while another tenant still has
@@ -490,6 +503,14 @@ class ContinuousBatchingEngine:
         self._has_state = self.model_config.has_state
         if self._has_state:
             self._refuse_without_state_support(config)
+        #: a decode step yields a block of tokens, not a token (sdar_moe):
+        #: the width of the open block every running row carries where the
+        #: llama family carries its last token; 0 = one token a step. Read
+        #: from the ModelConfig, as ``_has_state`` is: no option picks it
+        self._block = (self.model_config.block_length
+                       if self.model_config.is_block else 0)
+        if self._block:
+            self._refuse_without_block_support(config)
         self.pd_role = str(config.pd_role or "")
         if self.pd_role not in ("", "prefill", "decode"):
             raise ValueError(
@@ -617,7 +638,12 @@ class ContinuousBatchingEngine:
         self.lengths = np.zeros(self.n_slots, np.int32)
         self.active = np.zeros(self.n_slots, bool)
 
-        self._last_tokens = self._dev(jnp.zeros((self.n_slots,), jnp.int32))
+        # what a running row feeds its next forward: its last token, or
+        # (a model that generates by blocks) its open block
+        self._last_tokens = self._dev(
+            jnp.full((self.n_slots, self._block),
+                     self.model_config.mask_token_id, jnp.int32)
+            if self._block else jnp.zeros((self.n_slots,), jnp.int32))
 
         # device-resident per-slot sampling/termination state: patched
         # row-wise at admission/finish/preempt/resume,
@@ -638,6 +664,9 @@ class ContinuousBatchingEngine:
         self._stops_dev = self._dev(jnp.full(
             (self.n_slots, self._stop_width), -1, jnp.int32))
         self._limit_dev = self._dev(jnp.zeros((self.n_slots,), jnp.int32))
+        #: block models: the first generated position (the prompt's length);
+        #: a stop id among the prompt's leftover in the first block is none
+        self._gen_start_dev = self._dev(jnp.zeros((self.n_slots,), jnp.int32))
         self._dev_term = np.ones(self.n_slots, bool)
 
         # slot KV lives in ONE paged pool shared with the prefix cache —
@@ -799,7 +828,8 @@ class ContinuousBatchingEngine:
         # without a discard reads 0 and not nothing
         for series in ("llm_decode_chunks_dispatched_total",
                        "llm_decode_chunks_discarded_total",
-                       "llm_admission_ring_waits_total"):
+                       "llm_admission_ring_waits_total") + (
+                           _BLOCK_SERIES if self._block else ()):
             bump_counter(series, n=0.0)
         #: achieved ring depth at each drain (how many chunks stayed in
         #: flight while the host emitted) → stats() depth histogram
@@ -831,13 +861,160 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 f"{name}: tp > 1 has no sharding for the state slab")
 
+    def _refuse_without_block_support(self, config: EngineConfig) -> None:
+        """A model that generates by blocks is served on one device, unified,
+        without speculation; each mode below lacks one named thing."""
+        name = self.model_config.name
+        if config.scheduler_spec_k > 0:
+            raise ValueError(
+                f"{name}: scheduler_spec_k > 0 verifies a draft left to "
+                "right against next-token logits, and a block model's "
+                "logits at a position are for the token AT it")
+        if config.pd_role:
+            raise ValueError(
+                f"{name}: pd_role={config.pd_role!r} hands a row over with "
+                "one last token, and the export carries no open block")
+        if max(1, int(config.tp)) > 1:
+            raise ValueError(
+                f"{name}: tp > 1 has no partitioning of the grouped expert "
+                "matmul (no tp or ep axis)")
+        if config.prefix_page_size % self._block:
+            raise ValueError(
+                f"{name}: a page of {config.prefix_page_size} tokens has to "
+                f"be whole blocks of {self._block}: a shared prefix ends on "
+                "a block boundary")
+
     def _state_snapshot_rows(self) -> int:
         if not self._has_state:
             return 0
         n = self.config.state_snapshots
         return self.config.max_batch if n < 0 else n
 
+    def _build_block_programs(self) -> None:
+        """``paged_decode_chunk`` and ``mixed_step`` of a model that generates
+        by diffusion over blocks: the same two programs by the same names
+        (the ring, the admission rule, the round records and the device
+        trace's readers apply unchanged), whose carry is every row's OPEN
+        BLOCK ``[B, W]`` where the llama family carries a last token.
+
+        Each forward, every running row either DENOISES (its block still
+        holds a mask: unmask by confidence, ``ops/sampling.py:block_unmask``)
+        or COMMITS (no mask left: the forward that just ran has written the
+        final tokens' K/V, so the row's length advances by ``W``, the block
+        is emitted and the next one opens all-mask), each by its own phase:
+        rows arrive mid-block of their neighbours. A denoise forward's K/V
+        does not outlive it, since the row's next forward starts at the same
+        length and scatters before it attends. ``decode_chunk`` counts
+        forwards. Tokens leave as ``[B, forwards * W]`` with -1 where a
+        forward committed nothing; one more column carries the forwards each
+        row ran and one more row the counters (row forwards, commit row
+        forwards, experts touched), so one drain brings all three. Stops and
+        the token limit cut inside a block on the host (``_emit_block``); the
+        device freezes the row at that commit."""
+        cfg = self.model_config
+        model = self._model
+        k_steps = max(1, self.config.decode_chunk)
+        rope = self.rope_tables
+        max_seq = self.config.max_seq_len
+        W = self._block
+        unmask = dict(
+            mask_id=cfg.mask_token_id, per_step=W // cfg.denoising_steps,
+            dynamic=cfg.remasking == "low_confidence_dynamic",
+            threshold=cfg.confidence_threshold)
+        offs = jnp.arange(W, dtype=jnp.int32)[None, :]
+
+        def advance(params, hidden, block, lens, run, fin, keys, gen_start,
+                    stop_ids, limit_lens, temp, top_p, top_k):
+            """What one forward of the open blocks (``hidden`` [B, W, H])
+            does to the rows that ran it. Returns (block, lens, fin, keys,
+            emitted [B, W] or -1, (row forwards, commit row forwards))."""
+            masked = jnp.any(block == cfg.mask_token_id, axis=1)
+            denoise, commit = run & masked, run & jnp.logical_not(masked)
+            logits = model.lm_head_logits(params, cfg, hidden)
+            keys2, subs = split_keys_per_slot(keys)
+            opened = block_unmask(block, logits, subs, temp, top_p, top_k,
+                                  **unmask)
+            new_lens = jnp.where(commit, lens + W, lens)
+            generated = (lens[:, None] + offs) >= gen_start[:, None]
+            is_stop = jnp.any(block[:, :, None] == stop_ids[:, None, :],
+                              axis=2) & generated
+            hit = jnp.any(is_stop, axis=1) | (new_lens >= limit_lens) | (
+                new_lens + W > max_seq)
+            new_block = jnp.where(
+                commit[:, None], cfg.mask_token_id,
+                jnp.where(denoise[:, None], opened, block))
+            return (new_block, new_lens, fin | (commit & hit),
+                    jnp.where(denoise[:, None], keys2, keys),
+                    jnp.where(commit[:, None], block, -1),
+                    jnp.stack([jnp.sum(run), jnp.sum(commit)]))
+
+        def with_counters(toks, ran, counts, touched):
+            toks = jnp.concatenate([toks, ran[:, None]], axis=1)
+            row = jnp.zeros((1, toks.shape[1]), jnp.int32).at[0, :3].set(
+                jnp.concatenate([counts, touched[None]]).astype(jnp.int32))
+            return jnp.concatenate([toks, row], axis=0)
+
+        def paged_decode_chunk(params, k_pool, v_pool, page_table, block,
+                               lengths, active, finished, stop_ids,
+                               limit_lens, gen_start, keys, temp, top_p,
+                               top_k):
+            def step(carry, _):
+                pools, blk, lens, fin, keys, ran, counts, touched = carry
+                run = active & jnp.logical_not(fin)
+                hidden, pools, aux = model.forward_paged_decode(
+                    params, cfg, blk, pools, page_table, lens, rope,
+                    write_mask=run)
+                blk, lens, fin, keys, emit, n = advance(
+                    params, hidden, blk, lens, run, fin, keys, gen_start,
+                    stop_ids, limit_lens, temp, top_p, top_k)
+                return (pools, blk, lens, fin, keys,
+                        ran + run.astype(jnp.int32), counts + n,
+                        touched + aux["touched"]), emit
+
+            zero = jnp.zeros((), jnp.int32)
+            (pools, blk, lens, fin, keys, ran, counts, touched), toks = \
+                jax.lax.scan(step, ((k_pool, v_pool), block, lengths,
+                                    finished, keys, jnp.zeros_like(lengths),
+                                    jnp.zeros((2,), jnp.int32), zero),
+                             None, length=k_steps)
+            toks = toks.transpose(1, 0, 2).reshape(block.shape[0], -1)
+            lens = jnp.where(active, lens, 0)
+            return (with_counters(toks, ran, counts, touched), *pools, blk,
+                    keys, lens, fin)
+
+        self._paged_decode_fn = jax.jit(paged_decode_chunk,
+                                        donate_argnums=(1, 2))
+
+        def mixed_step(params, k_pool, v_pool, page_table, q_ids, q_lens,
+                       prefill_hist, lane_rows, block, lengths, active,
+                       finished, final_mask, final_lens, stop_ids, limit_lens,
+                       gen_start, keys, temp, top_p, top_k):
+            """One forward of every open block beside the lane's chunk of
+            whole prompt blocks. A lane samples no first token: a row whose
+            prompt ends here (``final_mask``) flips to running at
+            ``final_lens`` with the open block the host put in ``block``
+            (the prompt's leftover, then masks), which this forward has not
+            run."""
+            run = active & jnp.logical_not(finished)
+            hidden, pools, aux = model.forward_paged_mixed(
+                params, cfg, q_ids, (k_pool, v_pool), page_table,
+                prefill_hist, q_lens, rope, rows=lane_rows,
+                decode=llama.DecodeGroup(block, lengths, run))
+            blk, lens, fin, keys, emit, n = advance(
+                params, hidden, block, lengths, run, finished, keys,
+                gen_start, stop_ids, limit_lens, temp, top_p, top_k)
+            lens = jnp.where(run, lens, jnp.where(
+                final_mask, final_lens, jnp.where(active, lengths, 0)))
+            return (with_counters(emit, run.astype(jnp.int32), n,
+                                  aux["touched"]),
+                    *pools, blk, keys, lens, fin, active | final_mask)
+
+        self._mixed_step_fn = jax.jit(mixed_step, donate_argnums=(1, 2))
+        self._k_steps = k_steps
+
     def _build_programs(self) -> None:
+        if self._block:
+            return self._build_block_programs()
         cfg = self.model_config
         model = self._model
         k_steps = max(1, self.config.decode_chunk)
@@ -1099,6 +1276,34 @@ class ContinuousBatchingEngine:
 
     def _bucket_for(self, length: int) -> int:
         return self.config.bucket_for(length)
+
+    @property
+    def _step_tokens(self) -> int:
+        """The most a row's length advances in one forward."""
+        return self._block or 1
+
+    @property
+    def _chunk_tokens(self) -> int:
+        """The most a row's length advances in one decode chunk: what the
+        page chains are grown by. A block model commits at most every other
+        forward (a denoise forward that unmasks everything, then the commit
+        forward)."""
+        if self._block:
+            return self._block * -(-self._k_steps // 2)
+        return self._k_steps
+
+    def _prefill_target(self, state: "_SlotState") -> int:
+        """The prompt tokens a row's lane computes: all of them, or (a model
+        that generates by blocks) its whole blocks; the leftover opens the
+        row's first block."""
+        n = len(state.prompt_ids)
+        return n - n % self._block if self._block else n
+
+    def _token_limit(self, prompt_len: int, max_tokens: int) -> int:
+        """The kept length at which a row has its ``max_tokens``: the llama
+        family emits its first token at the prompt's end, before any decode
+        step; a block model emits what it commits."""
+        return prompt_len + max_tokens - (0 if self._block else 1)
 
     # ------------------------------------------------------------------ public api
     def start(self) -> None:
@@ -2084,7 +2289,7 @@ class ContinuousBatchingEngine:
     def _patch_slot_device(self, slot: int, temp: float, top_p: float,
                            top_k: int, length: int, active: bool,
                            stops: frozenset = frozenset(),
-                           limit: int = 0) -> None:
+                           limit: int = 0, gen_start: int = 0) -> None:
         """Patch ONE slot's device-resident rows (admission/resume). A dynamic
         scalar index keeps this a single cached program, not one per slot.
         ``stops``/``limit`` feed the device-side termination rows: the first
@@ -2103,6 +2308,9 @@ class ContinuousBatchingEngine:
         row[: len(ids)] = ids
         self._stops_dev = self._stops_dev.at[i].set(jnp.asarray(row))
         self._limit_dev = self._limit_dev.at[i].set(jnp.int32(max(0, limit)))
+        if self._block:
+            self._gen_start_dev = self._gen_start_dev.at[i].set(
+                jnp.int32(gen_start))
         self._dev_term[slot] = len(stops) <= self._stop_width
 
     def _deactivate_slot_device(self, slot: int) -> None:
@@ -2177,7 +2385,7 @@ class ContinuousBatchingEngine:
                 else:
                     chain = self.pool.restore_chain_from_host(rec.host_kv)
                 try:
-                    self.pool.extend_chain(chain, rec.length + self._k_steps)
+                    self.pool.extend_chain(chain, rec.length + self._chunk_tokens)
                 except MemoryError:
                     # give back the restored pages — a half-resume must not leak
                     self.pool.release_slot(chain)
@@ -2191,7 +2399,7 @@ class ContinuousBatchingEngine:
                 # suspended request would otherwise hang its client stream
                 # and everyone FIFO-behind it while thrashing restore/release
                 # of its host KV pages every cycle (round-2 advisory).
-                pages_needed = self.pool.pages_for(rec.length + self._k_steps)
+                pages_needed = self.pool.pages_for(rec.length + self._chunk_tokens)
                 if (pages_needed > self.pool.capacity_pages
                         or not self.active.any()):
                     with self._submit_lock:
@@ -2228,7 +2436,9 @@ class ContinuousBatchingEngine:
                 self._patch_slot_device(
                     slot, s.temperature, s.top_p, s.top_k, 0, False,
                     stops=state.stops,
-                    limit=len(state.prompt_ids) + s.max_tokens - 1)
+                    limit=self._token_limit(len(state.prompt_ids),
+                                            s.max_tokens),
+                    gen_start=len(state.prompt_ids))
                 self._prefill_slots.append(slot)
             else:
                 self.active[slot] = True
@@ -2238,9 +2448,13 @@ class ContinuousBatchingEngine:
                 self._patch_slot_device(
                     slot, s.temperature, s.top_p, s.top_k, rec.length, True,
                     stops=state.stops,
-                    limit=rec.length - state.emitted + s.max_tokens)
+                    limit=(self._token_limit(len(state.prompt_ids),
+                                             s.max_tokens) if self._block
+                           else rec.length - state.emitted + s.max_tokens),
+                    gen_start=len(state.prompt_ids or ()))
                 i = jnp.asarray(slot, jnp.int32)
-                self._last_tokens = self._last_tokens.at[i].set(rec.last_token)
+                self._last_tokens = self._last_tokens.at[i].set(
+                    jnp.asarray(rec.last_token, jnp.int32))
                 self._slot_keys = self._slot_keys.at[i].set(
                     jnp.asarray(rec.slot_key))
             self.page_table[slot, :] = 0
@@ -2478,7 +2692,8 @@ class ContinuousBatchingEngine:
             self._patch_slot_device(
                 slot, s.temperature, s.top_p, s.top_k, 0, False,
                 stops=state.stops,
-                limit=len(req.prompt_ids) + s.max_tokens - 1)
+                limit=self._token_limit(len(req.prompt_ids), s.max_tokens),
+                gen_start=len(req.prompt_ids))
         except Exception:
             self.pool.release_slot(chain)
             self.page_table[slot, :] = 0
@@ -2564,7 +2779,7 @@ class ContinuousBatchingEngine:
         serve are preempted to host and resumed by _admit when space frees; a
         request even an idle pool can't hold is terminal-shed there (bounded —
         no infinite retry)."""
-        horizon = horizon if horizon is not None else self._k_steps
+        horizon = horizon if horizon is not None else self._chunk_tokens
         for slot in range(self.n_slots):
             state = self.slots[slot]
             if state is None or not self.active[slot]:
@@ -2637,7 +2852,7 @@ class ContinuousBatchingEngine:
             # (preempting on the optimistic ask would livelock: resume only
             # restores length+k, the next round asks the ring horizon again,
             # and the request round-trips its KV forever without a token)
-            mandatory = min(L + self._k_steps, self.config.max_seq_len)
+            mandatory = min(L + self._chunk_tokens, self.config.max_seq_len)
             if self.pool.pages_for(mandatory) <= len(chain):
                 return  # enough for the chunk; lookahead will just skip
         before = len(chain)
@@ -2675,6 +2890,7 @@ class ContinuousBatchingEngine:
                 state=state, host_kv=host_kv,
                 length=length,
                 last_token=0 if is_prefill
+                else np.asarray(self._last_tokens)[slot].copy() if self._block
                 else int(np.asarray(self._last_tokens)[slot]),
                 slot_key=None if is_prefill
                 else np.asarray(self._slot_keys[slot]),
@@ -2719,7 +2935,7 @@ class ContinuousBatchingEngine:
         chunk_dev, *outs = self._paged_decode_fn(
             self.params, *self.pool.cache_operands(),
             self._page_table_dev, last, lengths, active, fin,
-            self._stops_dev, self._limit_dev, keys,
+            self._stops_dev, self._limit_dev, *self._block_operands(), keys,
             self._temp_dev, self._top_p_dev, self._top_k_dev)
         last_o, keys_o, lens_o, fin_o = self.pool.adopt(outs)
         try:
@@ -2729,6 +2945,10 @@ class ContinuousBatchingEngine:
         bump_counter("llm_decode_chunks_dispatched_total")
         return _InflightChunk(chunk_dev, last_o, keys_o, lens_o, fin_o,
                               active, self._epoch)
+
+    def _block_operands(self) -> tuple:
+        """What a block model's programs take after the limits."""
+        return (self._gen_start_dev,) if self._block else ()
 
     def _admission_waiting(self) -> bool:
         """A slot is free and a request is pending or suspended: ``_admit``
@@ -2764,7 +2984,7 @@ class ContinuousBatchingEngine:
             # the next dispatch speculates instead — a k-token verify span
             # beats a chained plain chunk on the same traffic
             return False
-        k = self._k_steps
+        k = self._chunk_tokens
         horizon = (len(self._ring) + 1) * k
         max_seq = self.config.max_seq_len
         for slot in range(self.n_slots):
@@ -2807,20 +3027,23 @@ class ContinuousBatchingEngine:
         bump_counter("llm_decode_chunks_discarded_total", n=len(self._ring))
         self._ring.clear()
 
-    def _commit_chunk(self, rec: _InflightChunk) -> np.ndarray:
+    def _commit_chunk(self, rec: _InflightChunk,
+                      commits: Optional[np.ndarray] = None) -> np.ndarray:
         """Adopt a drained chunk's device outputs as committed state; advance
         the host length mirror. Returns the pre-chunk lengths for the emit
         loop. The active mask is NOT committed (it is an input the chunk never
         modifies — committing it would resurrect rows the host finished while
         the chunk was in flight). Active slots advance by k; inactive slots
         pin to 0 so their garbage positions never run past the rope table /
-        page chain bounds."""
+        page chain bounds. ``commits`` (a block model): the blocks each row
+        committed in the chunk; a row advances by those, not by k."""
         self._last_tokens = rec.last
         self._slot_keys = rec.keys
         self._lengths_dev = rec.lengths_dev
         self._finished_dev = rec.finished_dev
         old_lengths = self.lengths.copy()
-        self.lengths = np.where(self.active, self.lengths + self._k_steps,
+        advance = self._k_steps if commits is None else commits * self._block
+        self.lengths = np.where(self.active, self.lengths + advance,
                                 0).astype(np.int32)
         return old_lengths
 
@@ -2832,7 +3055,8 @@ class ContinuousBatchingEngine:
                       depth: int = 0,
                       spec_tokens: int = 0,
                       kind: str = "decode",
-                      positions: Optional[int] = None) -> None:
+                      positions: Optional[int] = None,
+                      block_out: Optional[tuple[int, int]] = None) -> None:
         """One timing-schema owner for every round kind. ``ts`` is the
         round's wall-clock start; /v1/monitoring/rounds exports these entries
         as Chrome trace events, which need absolute timestamps.
@@ -2840,7 +3064,7 @@ class ContinuousBatchingEngine:
         step, ``B x Qmax`` for an all-rows (speculative) one, and ``B`` a
         step for a decode round (the default)."""
         if positions is None:
-            positions = self.n_slots * self._k_steps
+            positions = self.n_slots * self._k_steps * self._step_tokens
         self.decode_rounds += 1
         if lookahead:
             self.lookahead_rounds += 1
@@ -2867,7 +3091,77 @@ class ContinuousBatchingEngine:
             "depth": depth,
             "spec_tokens": spec_tokens,
             "active": self.active_slots,
+            # a block model: the forwards of the round's dispatch, and the
+            # blocks and tokens the host took from it
+            **({"forwards": 1 if kind != "decode" or mixed
+                else self._k_steps, "blocks_committed": block_out[0],
+                "tokens_emitted": block_out[1]} if block_out else {}),
         })
+
+    def _take_block_counters(self, drained: np.ndarray, forwards: int
+                             ) -> tuple[np.ndarray, np.ndarray]:
+        """A block model's drained matrix carries one more row, the
+        program's counters, and one more column, the forwards each row ran.
+        Bump the counters; hand back (tokens, forwards a row)."""
+        row_forwards, commits, touched = (int(v) for v in drained[-1, :3])
+        bump_counter("llm_block_row_forwards_total", n=row_forwards)
+        bump_counter("llm_block_commit_row_forwards_total", n=commits)
+        bump_counter("llm_moe_experts_touched_total", n=touched)
+        bump_counter("llm_moe_experts_offered_total",
+                     n=forwards * self.model_config.num_layers
+                     * self.model_config.num_experts)
+        return drained[:-1, :-1], drained[:-1, -1]
+
+    def _emit_block(self, slot: int, toks: np.ndarray, start: int) -> int:
+        """Emit the block a row committed at ``start .. start + W - 1``:
+        the positions past the prompt (a first block opens with the
+        prompt's leftover), up to the stop id or token limit that ends the
+        answer INSIDE the block; the last token ends it with ``length``
+        where no further block fits the window. Returns tokens emitted."""
+        state = self.slots[slot]
+        W, emitted = self._block, 0
+        no_room = start + 2 * W > self.config.max_seq_len
+        for j in range(W):
+            if start + j < len(state.prompt_ids):
+                continue
+            if not self.active[slot]:
+                break
+            self._emit_token(slot, int(toks[j]),
+                             force_length=no_room and j == W - 1)
+            emitted += 1
+        return emitted
+
+    def _emit_block_chunk(self, chunk: np.ndarray, ran: np.ndarray,
+                          old_lengths: np.ndarray, depth: int = 0,
+                          rows: Optional[list[int]] = None
+                          ) -> tuple[int, int]:
+        """``_emit_chunk`` of a block model: ``chunk`` [B, forwards*W],
+        forward ``f``'s tokens at ``[f*W, (f+1)*W)`` or -1 where the row
+        committed nothing in it; ``ran`` the forwards each row ran. One
+        flight-recorder event a row a chunk, before its tokens (a finish
+        closes the record). Returns (blocks, tokens) emitted."""
+        W = self._block
+        committed = chunk[:, ::W] >= 0                    # [B, forwards]
+        rows = [s for s in (range(self.n_slots) if rows is None else rows)
+                if self.active[s] and self.slots[s] is not None]
+        for slot in rows:
+            record_event(self.slots[slot].request_id, "decode_chunk",
+                         slot=slot, tokens=int(committed[slot].sum()) * W,
+                         depth=depth, blocks=int(committed[slot].sum()),
+                         row_forwards=int(ran[slot]))
+        at = old_lengths.copy()
+        blocks = tokens = 0
+        for f in range(committed.shape[1]):
+            for slot in rows:
+                if not self.active[slot] or not committed[slot, f]:
+                    continue
+                tokens += self._emit_block(
+                    slot, chunk[slot, f * W:(f + 1) * W], int(at[slot]))
+                at[slot] += W
+                blocks += 1
+        bump_counter("llm_blocks_committed_total", n=blocks)
+        bump_counter("llm_block_tokens_emitted_total", n=tokens)
+        return blocks, tokens
 
     def _emit_chunk(self, chunk: np.ndarray, old_lengths: np.ndarray,
                     depth: int = 0) -> None:
@@ -2913,9 +3207,20 @@ class ContinuousBatchingEngine:
             state = self.slots[slot]
             if state is None or state.phase != "prefill":
                 continue  # defensive: the deque tracks prefill-phase slots
-            remaining = len(state.prompt_ids) - state.prefill_pos
+            remaining = self._prefill_target(state) - state.prefill_pos
             chunk = int(min(remaining, left)) if left != float("inf") \
                 else remaining
+            if self._block:
+                # whole blocks; a prompt with none left to compute (shorter
+                # than a block, or cached up to its leftover) still takes
+                # its step, with an empty lane, to flip
+                chunk -= chunk % self._block
+                if chunk <= 0 and remaining > 0:
+                    chunk = self._block
+                if remaining == 0:
+                    plan.append((slot, state, 0))
+                    left -= 1
+                    continue
             if self._state_unit:
                 # end on the next snapshot boundary rather than past it, so
                 # that every row passes through the boundaries it crosses
@@ -2945,7 +3250,8 @@ class ContinuousBatchingEngine:
         self.page_table[slot, before: len(chain)] = chain[before:]
         self._mark_pt_row(slot)
 
-    def _finish_prefill(self, slot: int, state: _SlotState, tok: int) -> None:
+    def _finish_prefill(self, slot: int, state: _SlotState,
+                        tok: Optional[int]) -> None:
         """Flip a fully-prefilled slot to decode: commit the prompt's full
         pages to the radix tree (later requests reuse them zero-copy),
         activate the slot's device rows, and emit the first token (sampled
@@ -2953,7 +3259,9 @@ class ContinuousBatchingEngine:
         stays: chunks that span the flip were chained off the mixed
         dispatch, which computed it on-device (active_out/final_lens), and
         with no span there is no ring to stale."""
-        T = len(state.prompt_ids)
+        # the kept length at the flip: the whole prompt, or (a block model,
+        # which gets no first token here: ``tok`` None) its whole blocks
+        T = self._prefill_target(state)
         try:
             self.pool.commit_chain(state.prompt_ids, state.chain,
                                    snapshots=state.state_snapshots)
@@ -2969,7 +3277,9 @@ class ContinuousBatchingEngine:
         s = state.sampling
         self._patch_slot_device(
             slot, s.temperature, s.top_p, s.top_k, T, True,
-            stops=state.stops, limit=T + s.max_tokens - 1)
+            stops=state.stops,
+            limit=self._token_limit(len(state.prompt_ids), s.max_tokens),
+            gen_start=len(state.prompt_ids))
         dur_ms = (time.monotonic() - state.prefill_t0) * 1000.0
         # the chunked path's duration spans the budget-paced rounds — the
         # realistic "time to get through prefill under current load"
@@ -2977,15 +3287,19 @@ class ContinuousBatchingEngine:
         # the terminal "prefill" event (ttft anchors here); the per-chunk
         # progress lives in prefill_chunk events
         record_event(state.request_id, "prefill", slot=slot, mixed=True,
-                     cached_len=state.cached_len, prompt_tokens=T,
+                     cached_len=state.cached_len,
+                     prompt_tokens=len(state.prompt_ids),
                      chunks=state.prefill_chunks, dur_ms=round(dur_ms, 3))
         if state.trace:
             get_global_tracer().emit_span(
                 "llm.prefill", traceparent=state.trace,
                 start_unix_ns=int(state.prefill_wall * 1e9),
                 duration_ms=dur_ms, request_id=state.request_id, slot=slot,
-                prompt_tokens=T, cached_len=state.cached_len, mixed=True,
+                prompt_tokens=len(state.prompt_ids),
+                cached_len=state.cached_len, mixed=True,
                 chunks=state.prefill_chunks, tenant=state.tenant)
+        if tok is None:
+            return
         no_room = T + self._k_steps > self.config.max_seq_len
         self._emit_token(slot, tok, force_length=no_room)
         # PD disaggregation: a prefill-role engine's job ends at the first
@@ -3159,13 +3473,14 @@ class ContinuousBatchingEngine:
                 or self._suspended or not self._pending.empty()
                 or self._stop.is_set()):
             return 0
-        k = self._k_steps
+        k = self._chunk_tokens
         max_seq = self.config.max_seq_len
         flipping = {slot for slot, _ in finals}
         chained = 0
         tail = rec
         for h in range(depth):
-            horizon = 1 + (h + 1) * k  # mixed token + h+1 chained chunks
+            # the mixed step's own advance + h+1 chained chunks
+            horizon = self._step_tokens + (h + 1) * k
             for slot in range(self.n_slots):
                 state = self.slots[slot]
                 if state is None:
@@ -3173,7 +3488,7 @@ class ContinuousBatchingEngine:
                 if self.active[slot]:
                     L = int(self.lengths[slot])
                 elif slot in flipping:
-                    L = len(state.prompt_ids)
+                    L = self._prefill_target(state)
                 else:
                     continue
                 try:
@@ -3221,7 +3536,7 @@ class ContinuousBatchingEngine:
         # capacity: decode rows keep a full chunk of headroom (the invariant
         # every round preserves); prefill rows cover their chunk's pages.
         # MemoryError on either path preempts-to-host.
-        self._ensure_chunk_capacity(self._k_steps)
+        self._ensure_chunk_capacity(self._chunk_tokens)
         plan: list[tuple[int, _SlotState, int]] = []
         if not spec_only:
             # one slot's chunk a step (the lane), FIFO; an engine that
@@ -3258,7 +3573,8 @@ class ContinuousBatchingEngine:
         # static dispatch width: the prefill bucket covering the largest
         # chunk — and the spec span width when rows speculate — rounded to
         # the kernel's q_block (bounded compile variants)
-        q_need = self._bucket_for(max(c for _, _, c in plan)) if plan else 1
+        q_need = self._bucket_for(max(max(c for _, _, c in plan), 1)) \
+            if plan else 1
         if spec_plan:
             q_need = max(q_need, self._spec_w)
         q_max = -(-q_need // 8) * 8
@@ -3281,16 +3597,25 @@ class ContinuousBatchingEngine:
             q_ids[r, :chunk] = state.prompt_ids[pos: pos + chunk]
             q_lens[r] = chunk
             hist[r] = pos
-            if pos + chunk == len(state.prompt_ids):
+            if pos + chunk == self._prefill_target(state):
                 # final chunk: this dispatch samples the first token — hand
                 # the request's untouched key stream to the device row NOW
                 finals.append((slot, state))
                 sample[slot] = True
                 final_mask[slot] = True
-                final_lens[slot] = len(state.prompt_ids)
+                final_lens[slot] = pos + chunk
                 i = jnp.asarray(slot, jnp.int32)
                 self._slot_keys = self._slot_keys.at[i].set(
                     jnp.asarray(state.prefill_key))
+                if self._block:
+                    # no first token: the prompt's leftover opens the row's
+                    # first block, which the row's first forward will run
+                    opened = np.full(self._block,
+                                     self.model_config.mask_token_id, np.int32)
+                    left = state.prompt_ids[pos + chunk:]
+                    opened[: len(left)] = left
+                    self._last_tokens = self._last_tokens.at[i].set(
+                        jnp.asarray(opened))
         for slot, state, drafts in spec_plan:
             # draft span: position 0 (the last committed token) is filled on
             # device from last_tokens; the drafts follow
@@ -3311,7 +3636,7 @@ class ContinuousBatchingEngine:
                 self._slot_keys, self._temp_dev, self._top_p_dev,
                 self._top_k_dev)
         else:
-            positions = n + q_ids.size
+            positions = n * self._step_tokens + q_ids.size
             toks_dev, *outs = self._mixed_step_fn(
                 self.params, *self.pool.cache_operands(),
                 self._page_table_dev, self._dev(q_ids), self._dev(q_lens),
@@ -3319,8 +3644,10 @@ class ContinuousBatchingEngine:
                 self._dev(np.array([slot for slot, _, _ in plan], np.int32)),
                 self._last_tokens,
                 self._lengths_dev, self._active_dev, self._finished_dev,
-                self._dev(sample), self._dev(final_mask),
+                *(() if self._block else (self._dev(sample),)),
+                self._dev(final_mask),
                 self._dev(final_lens), self._stops_dev, self._limit_dev,
+                *self._block_operands(),
                 self._slot_keys, self._temp_dev, self._top_p_dev,
                 self._top_k_dev)
         last_o, keys_o, lens_o, fin_o, active_o = self.pool.adopt(outs)
@@ -3359,13 +3686,21 @@ class ContinuousBatchingEngine:
         # spec dispatches return [n, spec_w + 1]: -1-sentinel emit columns
         # plus the accept-count column (one drain carries both); plain mixed
         # returns [n] — normalize to 2-D so one emit loop serves both
-        if toks.ndim == 2:
+        ran = None
+        if self._block:
+            toks2d, ran = self._take_block_counters(toks, forwards=1)
+            accepts = None
+        elif toks.ndim == 2:
             toks2d, accepts = toks[:, :-1], toks[:, -1]
         else:
             toks2d, accepts = toks[:, None], None
         decode_rows = [s for s in range(n) if self.active[s]]
         old_lengths = self.lengths.copy()
-        if spec_plan:
+        if self._block:
+            self.lengths = np.where(
+                self.active & (toks2d[:, 0] >= 0),
+                self.lengths + self._block, self.lengths).astype(np.int32)
+        elif spec_plan:
             # variable per-slot advance: the host mirror adopts each row's
             # actual emit count (1..k+1), matching the device's new_lens
             adv = (toks2d >= 0).sum(axis=1).astype(np.int32)
@@ -3382,6 +3717,12 @@ class ContinuousBatchingEngine:
                             "spec_accepted": int(accepts[slot])}
                      for slot, (state, drafts) in spec_slots.items()} \
             if spec_plan else None
+        if self._block:
+            row_tokens = {slot: int(toks2d[slot, 0] >= 0) * self._block
+                          for slot in decode_rows}
+            row_attrs = {slot: {"blocks": int(toks2d[slot, 0] >= 0),
+                                "row_forwards": int(ran[slot])}
+                         for slot in decode_rows}
         self._emit_decode_spans(wall0, (t2 - t0) * 1000.0, lookahead=False,
                                 rows=decode_rows, tokens=1, depth=spanned,
                                 row_tokens=row_tokens, row_attrs=row_attrs)
@@ -3428,8 +3769,14 @@ class ContinuousBatchingEngine:
                     duration_ms=(t2 - t0) * 1000.0,
                     request_id=state.request_id, slot=slot, tokens=chunk)
         for slot, state in finals:
-            self._finish_prefill(slot, state, int(toks2d[slot, 0]))
-        for slot in decode_rows:
+            self._finish_prefill(
+                slot, state, None if self._block else int(toks2d[slot, 0]))
+        block_out = None
+        if self._block:
+            block_out = self._emit_block_chunk(toks2d, ran, old_lengths,
+                                               depth=spanned,
+                                               rows=decode_rows)
+        for slot in () if self._block else decode_rows:
             state = self.slots[slot]
             if state is None or not self.active[slot]:
                 continue
@@ -3458,7 +3805,8 @@ class ContinuousBatchingEngine:
                            spec_tokens=sum(len(dr)
                                            for _, _, dr in spec_plan),
                            kind=("mixed" if decode_rows else "prefill")
-                           if plan else "decode", positions=positions)
+                           if plan else "decode", positions=positions,
+                           block_out=block_out)
         return True
 
     def _decode_round(self) -> None:
@@ -3488,7 +3836,7 @@ class ContinuousBatchingEngine:
         if used_lookahead:
             self._lookahead_stats["used"] += 1
         else:
-            self._ensure_chunk_capacity(self._k_steps * (depth + 1))
+            self._ensure_chunk_capacity(self._chunk_tokens * (depth + 1))
             if not self.active.any():
                 return  # everyone got preempted
             self._ring.append(self._dispatch_chunk(after=None))
@@ -3509,10 +3857,24 @@ class ContinuousBatchingEngine:
         t3 = time.monotonic()
         self.readback_wait_samples.append((t3 - t2) * 1000.0)
         self._depth_hist[ring_depth] = self._depth_hist.get(ring_depth, 0) + 1
-        old_lengths = self._commit_chunk(inflight)
-        self._emit_decode_spans(wall0, (t3 - t0) * 1000.0, used_lookahead,
-                                depth=ring_depth)
-        self._emit_chunk(chunk, old_lengths, depth=ring_depth)
+        block_out = None
+        if self._block:
+            chunk, ran = self._take_block_counters(chunk, self._k_steps)
+            commits = (chunk[:, ::self._block] >= 0).sum(axis=1)
+            old_lengths = self._commit_chunk(inflight, commits)
+            self._emit_decode_spans(
+                wall0, (t3 - t0) * 1000.0, used_lookahead, depth=ring_depth,
+                row_tokens={s: int(c) * self._block
+                            for s, c in enumerate(commits)},
+                row_attrs={s: {"blocks": int(c), "row_forwards": int(ran[s])}
+                           for s, c in enumerate(commits)})
+            block_out = self._emit_block_chunk(chunk, ran, old_lengths,
+                                               depth=ring_depth)
+        else:
+            old_lengths = self._commit_chunk(inflight)
+            self._emit_decode_spans(wall0, (t3 - t0) * 1000.0,
+                                    used_lookahead, depth=ring_depth)
+            self._emit_chunk(chunk, old_lengths, depth=ring_depth)
         t4 = time.monotonic()
         # a host-fallback stop just changed the world — the ring suffix is
         # stale (device-predicted finishes leave the epoch alone, so the
@@ -3521,7 +3883,7 @@ class ContinuousBatchingEngine:
             self._discard_ring()
         self._record_round((t2 - t0) * 1000.0, (t3 - t2) * 1000.0,
                            (t4 - t3) * 1000.0, used_lookahead, ts=wall0,
-                           depth=ring_depth)
+                           depth=ring_depth, block_out=block_out)
 
     def _emit_decode_spans(self, wall0: float, dur_ms: float,
                            lookahead: bool, rows: Optional[list[int]] = None,

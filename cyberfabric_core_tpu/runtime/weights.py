@@ -51,6 +51,26 @@ _LLAMA_MAP: dict[str, tuple[str, bool]] = {
     "layers.moe_down": ("model.layers.{i}.block_sparse_moe.experts.{e}.w2.weight", True),
 }
 
+# sdar_moe (the Qwen3-MoE block's names): q/k head norms, the router as
+# ``mlp.gate``, and the experts by their projection names
+_SDAR_MOE_NAMES: dict[str, tuple[str, bool]] = {
+    "layers.q_norm": ("model.layers.{i}.self_attn.q_norm.weight", False),
+    "layers.k_norm": ("model.layers.{i}.self_attn.k_norm.weight", False),
+    "layers.router": ("model.layers.{i}.mlp.gate.weight", True),
+    "layers.moe_gate": ("model.layers.{i}.mlp.experts.{e}.gate_proj.weight", True),
+    "layers.moe_up": ("model.layers.{i}.mlp.experts.{e}.up_proj.weight", True),
+    "layers.moe_down": ("model.layers.{i}.mlp.experts.{e}.down_proj.weight", True),
+}
+
+
+def checkpoint_names(cfg: ModelConfig) -> dict[str, tuple[str, bool]]:
+    """Our tree leaf -> (published tensor name, transpose?) for ``cfg``'s
+    architecture: ``_LLAMA_MAP``, with ``sdar_moe``'s names over it."""
+    if cfg.architecture == "sdar_moe":
+        return {**_LLAMA_MAP, **_SDAR_MOE_NAMES}
+    return _LLAMA_MAP
+
+
 #: leaves that exist only in one MLP variant — the loader picks per config
 _DENSE_MLP_LEAVES = ("layers.gate", "layers.up", "layers.down")
 _MOE_LEAVES = ("layers.router", "layers.moe_gate", "layers.moe_up",
@@ -113,6 +133,8 @@ def load_llama_params(
         if progress:
             progress(path)
         target = arr.astype(np.float32).astype(dtype) if arr.dtype != np.dtype("bfloat16") else arr
+        if path == "layers.router" and cfg.router_float32:
+            target = arr.astype(np.float32)
         leaf_name = path.split(".")[-1]
         if quantize and (leaf_name in _MATMUL_LEAVES or path in ("lm_head", "embed")):
             dev = jnp.asarray(target)
@@ -126,10 +148,12 @@ def load_llama_params(
         return jnp.asarray(target)
 
     params: dict[str, Any] = {"layers": {}}
-    for leaf, (tmpl, transpose) in _LLAMA_MAP.items():
+    for leaf, (tmpl, transpose) in checkpoint_names(cfg).items():
         if leaf == "lm_head":
             if cfg.tie_embeddings or not idx.has(tmpl):
                 continue
+        if leaf in ("layers.q_norm", "layers.k_norm") and not cfg.qk_norm:
+            continue
         if leaf in ("layers.bq", "layers.bk", "layers.bv") \
                 and not cfg.attention_bias:
             continue
@@ -269,7 +293,7 @@ def save_llama_params(params: dict, cfg: ModelConfig, out_dir: str | Path) -> Pa
     out_dir = Path(out_dir)
     out_dir.mkdir(parents=True, exist_ok=True)
     tensors: dict[str, np.ndarray] = {}
-    for leaf, (tmpl, transpose) in _LLAMA_MAP.items():
+    for leaf, (tmpl, transpose) in checkpoint_names(cfg).items():
         node: Any = params
         try:
             for p in leaf.split("."):

@@ -477,3 +477,93 @@ def test_scheduler_programs_compile_for_v5e_at_falcon_h1():
         # reshaped
         _assert_whole_array_untouched(text, state["ssm"], name)
         _assert_whole_array_untouched(text, pool, name)
+
+
+@pytest.mark.slow
+def test_scheduler_programs_compile_for_v5e_at_sdar_moe():
+    """The scheduler's own ``paged_decode_chunk`` and ``mixed_step`` for
+    sdar-30b-a3b-16l int8 at the benchmark cell's shape (16 slots of 2048,
+    641 pages, 10 forwards a chunk, blocks of 4), on one described chip: each
+    holds the ``grouped_matmul`` Mosaic call and both paged kernels, fits the
+    15.75 GiB the compiler budgets, and copies no layer of the expert stacks
+    (an ``s8[128, 2048, 768]`` array is what a sliced stack in front of a
+    kernel would be). A compile, not a chip run."""
+    from jax.sharding import SingleDeviceSharding
+
+    from cyberfabric_core_tpu.models import decoder_module, get_config
+    from cyberfabric_core_tpu.ops.platform import compiled_kernels
+    from cyberfabric_core_tpu.ops.rope import rope_frequencies
+    from cyberfabric_core_tpu.parallel.feasibility import V5E_HBM_BYTES
+    from cyberfabric_core_tpu.parallel.sharding import abstract_params
+    from cyberfabric_core_tpu.runtime.engine import EngineConfig
+    from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
+
+    topo = _topo_or_skip()
+    n, max_seq, pages = 16, 2048, 641
+    cfg = get_config("sdar-30b-a3b-16l")
+    eng = object.__new__(ContinuousBatchingEngine)
+    eng.config = EngineConfig(
+        model=cfg.name, max_seq_len=max_seq, max_batch=n, decode_chunk=10,
+        quantization="int8", prefix_cache_pages=pages, prefix_page_size=_PAGE)
+    eng.model_config, eng.dtype = cfg, jnp.bfloat16
+    eng._model, eng._has_state = decoder_module(cfg), False
+    eng._block = cfg.block_length
+    eng.spec_k, eng._spec_w = 0, 1
+    eng.mesh = eng._attn_mesh = None
+    eng.rope_tables = rope_frequencies(cfg.head_dim, max_seq, cfg.rope_theta)
+    here = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=here)
+
+    params = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                          abstract_params(cfg, jnp.bfloat16, "int8"))
+    assert params["layers"]["router"].dtype == jnp.float32
+    with compiled_kernels():
+        eng._build_programs()
+
+    def row(dtype):
+        return sds((n,), dtype)
+
+    i32, f32 = jnp.int32, jnp.float32
+    pool = sds((cfg.num_layers, pages, _PAGE, cfg.num_kv_heads * cfg.head_dim),
+               jnp.bfloat16)
+    table, keys = sds((n, max_seq // _PAGE), i32), sds((n, 2), jnp.uint32)
+    stops = sds((n, eng.config.device_stop_width), i32)
+    block = sds((n, cfg.block_length), i32)
+    sampling = (row(f32), row(f32), row(i32))
+
+    def mixed(width):
+        return (eng._mixed_step_fn, (
+            params, pool, pool, table, *_lane_operands(sds, width),
+            block, row(i32), row(bool), row(bool), row(bool), row(i32),
+            stops, row(i32), row(i32), keys, *sampling))
+
+    programs = {
+        "paged_decode_chunk": (eng._paged_decode_fn, (
+            params, pool, pool, table, block, row(i32), row(bool), row(bool),
+            stops, row(i32), row(i32), keys, *sampling)),
+        "mixed_step@64": mixed(64), "mixed_step@512": mixed(512),
+    }
+    import re
+
+    for name, (fn, args) in programs.items():
+        with compiled_kernels():
+            compiled = fn.lower(*args).compile()
+        mem = compiled.memory_analysis()
+        live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+        print(f"{name}: argument {mem.argument_size_in_bytes / 1e9:.2f} output "
+              f"{mem.output_size_in_bytes / 1e9:.2f} aliased "
+              f"{mem.alias_size_in_bytes / 1e9:.2f} temp "
+              f"{mem.temp_size_in_bytes / 1e9:.2f} GB")
+        text = compiled.as_text()
+        if os.environ.get("AOT_DUMP_DIR"):
+            Path(os.environ["AOT_DUMP_DIR"], f"{name}.hlo.txt").write_text(text)
+        for kernel in ("grouped_matmul", "paged_decode_attention"):
+            assert kernel in text, (name, kernel)
+        assert "tpu_custom_call" in text, name
+        assert mem.alias_size_in_bytes >= 2 * int(np.prod(pool.shape)) * 2, name
+        sliced = re.search(r"s8\[(1,)?128,(2048,768|768,2048)\]", text)
+        assert not sliced, f"{name}: a layer of an expert stack, {sliced[0]}"
+        assert live <= V5E_HBM_BYTES, (name, live)
