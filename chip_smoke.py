@@ -572,7 +572,8 @@ def kernel_checks(jax, spec: dict, cfg) -> None:
     from cyberfabric_core_tpu.ops.attention import attention_with_cache
     from cyberfabric_core_tpu.ops.flash_attention import flash_self_attention
     from cyberfabric_core_tpu.ops.paged_attention import (
-        paged_decode_attention, paged_gather_dense, ragged_paged_attention)
+        decode_work_list, paged_decode_attention, paged_gather_dense,
+        ragged_paged_attention)
     from cyberfabric_core_tpu.ops.platform import default_interpret
 
     interpret = default_interpret()
@@ -617,9 +618,10 @@ def kernel_checks(jax, spec: dict, cfg) -> None:
     lens = jnp.asarray(([1, page - 1, page, page + 1, cap // 3, cap // 2,
                          cap - 1, cap] * B)[:B], jnp.int32)
     q = rnd(B, Hq, D)
-    out = paged_decode_attention(q, k_pool, v_pool, table, lens, layer,
-                                 interpret=interpret, sliding_window=window,
-                                 two_d_dots=True)
+    out = paged_decode_attention(q, k_pool, v_pool,
+                                 decode_work_list(table, lens, page, window),
+                                 layer, interpret=interpret,
+                                 sliding_window=window, two_d_dots=True)
     ref = reference(q[:, None], k_dense, v_dense, (lens - 1)[:, None], lens)
     check_close(f"paged decode (two_d_dots) B={B} lens {lens.tolist()}", out,
                 ref[:, 0])

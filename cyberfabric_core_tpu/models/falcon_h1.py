@@ -48,8 +48,9 @@ from .configs import ModelConfig
 from .llama import (DecodeGroup, PagedPools, Params, _attn_out,
                     _decode_attend, _decode_targets, _embed_scale,
                     _mlp_residual, _qkv_proj, _ragged_attend, _scaled, _wmat,
-                    embed_lookup, gather_last_hidden, lm_head_logits,
-                    mixed_attention, mixed_hidden_out, mixed_layout)
+                    decode_work, embed_lookup, gather_last_hidden,
+                    lm_head_logits, mixed_attention, mixed_hidden_out,
+                    mixed_layout)
 
 __all__ = ["init_params", "init_mixer_small", "init_state",
            "forward_paged_decode", "forward_paged_mixed", "lm_head_logits",
@@ -253,6 +254,7 @@ def forward_paged_decode(
     pid, off = _decode_targets(page_table, lengths, write_mask,
                                pools[0].shape[2])
     attend = _decode_attend(cfg, interpret, None)
+    work = decode_work(cfg, page_table, lengths + 1, pools[0].shape[2])
 
     h = _embed_scale(embed_lookup(params["embed"], input_ids,
                                   params["final_norm"].dtype), cfg)
@@ -267,7 +269,7 @@ def forward_paged_decode(
             kproj.reshape(B, -1).astype(k_pool.dtype))
         v_pool = v_pool.at[layer, pid, off].set(
             vproj.reshape(B, -1).astype(v_pool.dtype))
-        attn = attend(q[:, 0], k_pool, v_pool, page_table, lengths + 1, layer)
+        attn = attend(q[:, 0], k_pool, v_pool, work, layer)
 
         z, u, dt = _mixer_in(lp, x, cfg)
         y, ssm, conv = _mixer_step(lp, layer, cfg, u[:, 0], dt[:, 0], ssm,
@@ -315,8 +317,8 @@ def forward_paged_mixed(
     interpret = _one_device(mesh, interpret)
     cos_t, sin_t = rope_tables
     R, Qc = input_ids.shape
-    lay = mixed_layout(input_ids, page_table, hist, q_lens, write_mask, rows,
-                       decode, pools[0].shape[2])
+    lay = mixed_layout(cfg, input_ids, page_table, hist, q_lens, write_mask,
+                       rows, decode, pools[0].shape[2])
     nd = lay.n_dec
     lane_attend = _ragged_attend(cfg, interpret, None)
     decode_attend = _decode_attend(cfg, interpret, None)
@@ -338,9 +340,8 @@ def forward_paged_mixed(
             kproj.reshape(n, -1).astype(k_pool.dtype))
         v_pool = v_pool.at[layer, lay.pid, lay.off].set(
             vproj.reshape(n, -1).astype(v_pool.dtype))
-        attn = mixed_attention(lay, q, k_pool, v_pool, page_table, hist,
-                               q_lens, decode, layer, lane_attend,
-                               decode_attend)
+        attn = mixed_attention(lay, q, k_pool, v_pool, hist, q_lens, layer,
+                               lane_attend, decode_attend)
 
         # the mixer: one input and one output projection over all tokens,
         # split only around the recurrence — the decode group's step first,

@@ -19,7 +19,7 @@ safetensors format).
 
 from __future__ import annotations
 
-from typing import Any, NamedTuple, Optional
+from typing import TYPE_CHECKING, Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +29,9 @@ from ..ops.norms import rms_norm
 from ..ops.platform import default_interpret as _default_interpret
 from ..ops.rope import apply_rope, rope_frequencies
 from .configs import ModelConfig
+
+if TYPE_CHECKING:
+    from ..ops.paged_attention import DecodeWork
 
 Params = dict[str, Any]
 KVCache = tuple[jnp.ndarray, jnp.ndarray]  # (k, v): [L, B, S, Hkv, D]
@@ -429,15 +432,15 @@ class DecodeGroup(NamedTuple):
 
 
 def _decode_attend(cfg: ModelConfig, interpret: bool, mesh):
-    """``attend(q [B, Hq, D], k_pool, v_pool, page_table, lengths, layer)``
-    over the stacked pools. The kernel takes the pools whole and picks the
-    layer in its index map: a ``k_pool[layer]`` in front of it would
-    materialise 1/L of the pool."""
-    from ..ops.paged_attention import paged_decode_attention
+    """``attend(q [B, Hq, D], k_pool, v_pool, work, layer)`` over the stacked
+    pools, ``work`` the step's :func:`decode_work`. The kernel takes the
+    pools whole and picks the layer in its index map: a ``k_pool[layer]`` in
+    front of it would materialise 1/L of the pool."""
+    from ..ops.paged_attention import DecodeWork, paged_decode_attention
 
-    def attend(qq, kk, vv, pt, ln, ly):
+    def attend(qq, kk, vv, work, ly):
         return paged_decode_attention(
-            qq, kk, vv, pt, ln, ly, interpret=interpret,
+            qq, kk, vv, work, ly, interpret=interpret,
             sliding_window=cfg.sliding_window)
 
     if mesh is None:
@@ -445,7 +448,17 @@ def _decode_attend(cfg: ModelConfig, interpret: bool, mesh):
     from jax.sharding import PartitionSpec as P
 
     return _shard_mapped_attn(
-        mesh, attend, P(None, "tp", None), (P(None, None), P(None)))
+        mesh, attend, P(None, "tp", None), (DecodeWork(*[P()] * 5),))
+
+
+def decode_work(cfg: ModelConfig, page_table, lengths, page_size: int):
+    """The decode kernel's work list for one step: ``lengths`` [B] counts
+    the tokens the step itself writes. The same for every layer, so it is
+    built here, outside the scan over layers."""
+    from ..ops.paged_attention import decode_work_list
+
+    return decode_work_list(page_table, lengths, page_size,
+                            cfg.sliding_window)
 
 
 def _ragged_attend(cfg: ModelConfig, interpret: bool, mesh):
@@ -526,6 +539,7 @@ def forward_paged_decode(
     positions = lengths[:, None]
     pid, off = _decode_targets(page_table, lengths, write_mask, page_size)
     attend = _decode_attend(cfg, interpret, mesh)
+    work = decode_work(cfg, page_table, lengths + 1, page_size)
 
     h = _embed_scale(embed_lookup(params["embed"], input_ids, params["final_norm"].dtype), cfg)
 
@@ -545,7 +559,7 @@ def forward_paged_decode(
             kproj.reshape(B, -1).astype(k_pool.dtype))
         v_pool = v_pool.at[layer, pid, off].set(
             vproj.reshape(B, -1).astype(v_pool.dtype))
-        attn = attend(q[:, 0], k_pool, v_pool, page_table, lengths + 1, layer)
+        attn = attend(q[:, 0], k_pool, v_pool, work, layer)
         h = _attn_out(lp, h, attn.reshape(B, 1, Hq * D))
         h = _mlp_residual(lp, h, cfg, moe, layer)
         return (h, k_pool, v_pool), None
@@ -572,10 +586,12 @@ class MixedLayout(NamedTuple):
     off: jnp.ndarray           # [N] its offset in that page
     lane_table: jnp.ndarray    # [R, Pmax] the lanes' rows of the page table
     lane_valid: jnp.ndarray    # [R] bool: the lane has tokens and may write
+    work: DecodeWork | None    # the decode group's (None: no group)
 
 
-def mixed_layout(input_ids, page_table, hist, q_lens, write_mask, rows,
-                 decode: DecodeGroup | None, page_size: int) -> MixedLayout:
+def mixed_layout(cfg: ModelConfig, input_ids, page_table, hist, q_lens,
+                 write_mask, rows, decode: DecodeGroup | None,
+                 page_size: int) -> MixedLayout:
     """Lay a mixed step's tokens out (see :func:`forward_paged_mixed`)."""
     R, Qc = input_ids.shape
     lane_table = page_table if rows is None else page_table[rows]
@@ -591,10 +607,12 @@ def mixed_layout(input_ids, page_table, hist, q_lens, write_mask, rows,
     off = jnp.where(valid, positions % page_size, 0)
     ids, positions = input_ids.reshape(-1), positions.reshape(-1)
     pid, off = pid.reshape(-1), off.reshape(-1)
-    n_dec = 0
+    n_dec, work = 0, None
     if decode is not None:
         n_dec = decode.tokens.size
         width = decode.tokens.shape[1] if decode.tokens.ndim == 2 else None
+        work = decode_work(cfg, page_table, decode.lengths + (width or 1),
+                           page_size)
         d_pid, d_off = _decode_targets(page_table, decode.lengths, decode.run,
                                        page_size, width)
         d_pos = decode.lengths if width is None else (
@@ -604,16 +622,16 @@ def mixed_layout(input_ids, page_table, hist, q_lens, write_mask, rows,
         pid = jnp.concatenate([d_pid.reshape(-1), pid])
         off = jnp.concatenate([d_off.reshape(-1), off])
     return MixedLayout(n_dec, (R, Qc), ids[None], positions[None], pid, off,
-                       lane_table, valid[:, 0])
+                       lane_table, valid[:, 0], work)
 
 
-def mixed_attention(lay: MixedLayout, q, k_pool, v_pool, page_table, hist,
-                    q_lens, decode: DecodeGroup | None, layer,
+def mixed_attention(lay: MixedLayout, q, k_pool, v_pool, hist, q_lens, layer,
                     lane_attend, decode_attend) -> jnp.ndarray:
     """The one place a mixed step's token row is split: ``q`` [1, N, Hq, D]
     → attention output [1, N, Hq*D]. The lane goes through the ragged kernel
     on its own rows of the page table, the decode group through the decode
-    kernel, each after the step's k/v is in the pool."""
+    kernel over the layout's work list, each after the step's k/v is in the
+    pool."""
     R, Qc = lay.lanes
     nd = lay.n_dec
     lane = lane_attend(q[0, nd:].reshape(R, Qc, *q.shape[2:]), k_pool, v_pool,
@@ -621,9 +639,7 @@ def mixed_attention(lay: MixedLayout, q, k_pool, v_pool, page_table, hist,
     lane = lane.reshape(1, R * Qc, -1)
     if not nd:
         return lane
-    width = decode.tokens.shape[1] if decode.tokens.ndim == 2 else 1
-    dec = decode_attend(q[0, :nd], k_pool, v_pool, page_table,
-                        decode.lengths + width, layer)
+    dec = decode_attend(q[0, :nd], k_pool, v_pool, lay.work, layer)
     return jnp.concatenate([dec.reshape(1, nd, -1), lane], axis=1)
 
 
@@ -690,8 +706,8 @@ def forward_paged_mixed(
         interpret = _default_interpret()
     cos_t, sin_t = rope_tables
     pools, caller_shape = _merged_pools(pools)
-    lay = mixed_layout(input_ids, page_table, hist, q_lens, write_mask, rows,
-                       decode, pools[0].shape[2])
+    lay = mixed_layout(cfg, input_ids, page_table, hist, q_lens, write_mask,
+                       rows, decode, pools[0].shape[2])
     lane_attend = _ragged_attend(cfg, interpret, mesh)
     decode_attend = _decode_attend(cfg, interpret, mesh)
 
@@ -711,9 +727,8 @@ def forward_paged_mixed(
             kproj.reshape(n, -1).astype(k_pool.dtype))
         v_pool = v_pool.at[layer, lay.pid, lay.off].set(
             vproj.reshape(n, -1).astype(v_pool.dtype))
-        attn = mixed_attention(lay, q, k_pool, v_pool, page_table, hist,
-                               q_lens, decode, layer, lane_attend,
-                               decode_attend)
+        attn = mixed_attention(lay, q, k_pool, v_pool, hist, q_lens, layer,
+                               lane_attend, decode_attend)
         h = _attn_out(lp, h, attn)
         h = _mlp_residual(lp, h, cfg, moe, layer)
         return (h, k_pool, v_pool), None

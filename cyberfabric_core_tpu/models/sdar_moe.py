@@ -44,10 +44,10 @@ from ..ops.norms import rms_norm
 from ..ops.platform import default_interpret as _default_interpret
 from .configs import ModelConfig
 from .llama import (DecodeGroup, PagedPools, Params, _attn_out,
-                    _decode_targets, _qkv_proj, _ragged_attend, embed_lookup,
-                    gather_last_hidden, init_params, lm_head_logits,
-                    mixed_attention, mixed_layout, moe_experts, moe_route,
-                    split_moe)
+                    _decode_targets, _qkv_proj, _ragged_attend, decode_work,
+                    embed_lookup, gather_last_hidden, init_params,
+                    lm_head_logits, mixed_attention, mixed_layout,
+                    moe_experts, moe_route, split_moe)
 
 __all__ = ["init_params", "forward_paged_decode", "forward_paged_mixed",
            "lm_head_logits", "gather_last_hidden"]
@@ -63,13 +63,13 @@ def _one_device(mesh: Any, interpret: bool | None) -> bool:
 
 
 def _block_attend(interpret: bool, width: int):
-    """``attend(q [B*W, Hq, D], k_pool, v_pool, page_table, lengths, layer)``
-    for ``mixed_attention``'s decode group: the open blocks."""
+    """``attend(q [B*W, Hq, D], k_pool, v_pool, work, layer)`` for
+    ``mixed_attention``'s decode group: the open blocks."""
     from ..ops.paged_attention import paged_block_attention
 
-    def attend(qq, kk, vv, pt, ln, ly):
+    def attend(qq, kk, vv, work, ly):
         out = paged_block_attention(
-            qq.reshape(-1, width, *qq.shape[1:]), kk, vv, pt, ln, ly,
+            qq.reshape(-1, width, *qq.shape[1:]), kk, vv, work, ly,
             interpret=interpret)
         return out.reshape(qq.shape)
 
@@ -132,6 +132,7 @@ def forward_paged_decode(
                                pools[0].shape[2], W)
     pid, off = pid.reshape(-1), off.reshape(-1)
     attend = _block_attend(interpret, W)
+    work = decode_work(cfg, page_table, lengths + W, pools[0].shape[2])
     h = embed_lookup(params["embed"], input_ids.reshape(1, B * W),
                      params["final_norm"].dtype)
 
@@ -143,7 +144,7 @@ def forward_paged_decode(
             kproj.reshape(B * W, -1).astype(k_pool.dtype))
         v_pool = v_pool.at[layer, pid, off].set(
             vproj.reshape(B * W, -1).astype(v_pool.dtype))
-        attn = attend(q[0], k_pool, v_pool, page_table, lengths + W, layer)
+        attn = attend(q[0], k_pool, v_pool, work, layer)
         return (_attn_out(lp, h, attn.reshape(1, B * W, Hq * D)),
                 k_pool, v_pool)
 
@@ -179,8 +180,8 @@ def forward_paged_mixed(
     interpret = _one_device(mesh, interpret)
     cos_t, sin_t = rope_tables
     R, Qc = input_ids.shape
-    lay = mixed_layout(input_ids, page_table, hist, q_lens, write_mask, rows,
-                       decode, pools[0].shape[2])
+    lay = mixed_layout(cfg, input_ids, page_table, hist, q_lens, write_mask,
+                       rows, decode, pools[0].shape[2])
     nd = lay.n_dec
     lane_attend = _ragged_attend(cfg, interpret, None)
     block_attend = _block_attend(interpret, cfg.block_length)
@@ -194,9 +195,8 @@ def forward_paged_mixed(
             kproj.reshape(n, -1).astype(k_pool.dtype))
         v_pool = v_pool.at[layer, lay.pid, lay.off].set(
             vproj.reshape(n, -1).astype(v_pool.dtype))
-        attn = mixed_attention(lay, q, k_pool, v_pool, page_table, hist,
-                               q_lens, decode, layer, lane_attend,
-                               block_attend)
+        attn = mixed_attention(lay, q, k_pool, v_pool, hist, q_lens, layer,
+                               lane_attend, block_attend)
         return _attn_out(lp, h, attn), k_pool, v_pool
 
     h, pools, aux = _run_layers(params, cfg, h, pools, body)

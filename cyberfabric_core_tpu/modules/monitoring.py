@@ -392,7 +392,8 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
 
         # the ring as counters (pushed by the scheduler): every decode chunk
         # dispatched, every one dropped undrained, and the loop passes that
-        # held an admission back for the chunks in flight
+        # held an admission back for the chunks in flight; and what the
+        # decode kernel's grid walked in the dispatches that were drained
         for name, text in (
                 ("llm_decode_chunks_dispatched_total",
                  "Decode chunks dispatched (the head of the lookahead ring "
@@ -403,7 +404,14 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                  "stop, no running row left)"),
                 ("llm_admission_ring_waits_total",
                  "Scheduler passes in which a request could have been "
-                 "admitted or resumed and waited for the chunks in flight")):
+                 "admitted or resumed and waited for the chunks in flight"),
+                ("llm_attn_pages_walked_total",
+                 "Grid programs the paged decode kernel launched: one for "
+                 "every page that holds tokens a row's query reads, summed "
+                 "over steps and layers"),
+                ("llm_attn_pages_offered_total",
+                 "Slots of the page table (rows x pages a row) beside "
+                 "them, for the same calls")):
             self.registry.counter(name, text).inc(0.0)
 
         # pure-decode vs mixed vs prefill-only round dispatch percentiles,

@@ -1,16 +1,26 @@
 """Pallas ragged paged-attention kernel for decode (T=1) over a paged KV pool.
 
 The decode hot loop reads each sequence's KV history through a page table
-instead of a dense per-slot cache. Per (slot, page) program, the kernel:
+instead of a dense per-slot cache. The grid is one program for every page
+that holds tokens a slot's query reads, and no other:
 
-1. resolves the physical page via scalar-prefetched page_table (SMEM) — the
-   BlockSpec index_map does the lookup, so the pipeline DMAs exactly the pages
-   the sequence owns;
-2. skips pages past the sequence's valid length entirely — the index map
-   clamps to the last relevant page so the DMA is elided (same-block revisit)
-   and @pl.when skips the compute;
-3. accumulates flash-style online softmax (f32 m/l/acc scratch) across the
-   page axis, finalizing at the last page program.
+1. once a step, outside the scan over layers, ``decode_work_list`` flattens
+   the slots' page spans into one list of (slot, logical page, physical
+   page) items, slots in order and a slot's pages ascending (a sliding
+   window leaves a slot's first pages out; an empty slot keeps one item,
+   which computes nothing and finalises to zeros). The list rides in scalar
+   prefetch (SMEM) and its length is the grid's bound, known only when the
+   step runs: a table of ``B x Pmax`` slots of which a sixth holds tokens
+   launches a sixth of the programs;
+2. the BlockSpec index maps read an item's physical page and slot from the
+   list, so the pipeline DMAs exactly the pages the sequences own, each
+   once, and prefetches across the boundary between two slots;
+3. a slot's items accumulate flash-style online softmax (f32 m/l/acc
+   scratch), initialised at its first page and finalised at its last.
+
+(The ragged kernel below still launches a program for every slot of the
+table and skips the ones past a row's span, their DMA elided by an index map
+clamped to the last page in use: ROADMAP S18.)
 
 Why this beats the dense path (VERDICT r1 weak #3/#6): attention reads scale
 with the *tokens actually present* (sum of per-slot lengths), not
@@ -35,6 +45,7 @@ llm-gateway local worker (BASELINE config #2: 64 concurrent streams).
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -76,14 +87,32 @@ def _banded_weighted_v_2d(p, row_bands, v_of):
     return jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
 
 
-def _paged_kernel(pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, page_size: int,
+def page_span(length, page_size: int, n_pages: int,
+              sliding_window: int | None):
+    """(first, last) logical page a decode query at position ``length - 1``
+    reads, of a table of ``n_pages`` slots: up to the page of its own token,
+    from the page that holds the window's first key. An empty slot has the
+    span (0, 0). Scalars in the kernel, ``[B]`` arrays in the work list,
+    NumPy arrays where the host counts what the grid walked."""
+    last = ((length - 1) // page_size).clip(0, n_pages - 1)
+    if sliding_window is None:
+        return last * 0, last
+    # keys <= length - 1 - window are out, so page j is in while
+    # (j + 1) * page_size > length - window
+    return ((length - sliding_window) // page_size).clip(0, last), last
+
+
+def _paged_kernel(row_ref, page_ref, phys_ref, len_ref, layer_ref, q_ref,
+                  k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
+                  page_size: int, n_pages: int,
                   sliding_window: int | None = None,
                   two_d_dots: bool = False):
-    """One (slot, page) program.
+    """One work item: one page that holds tokens of one slot.
 
     Refs:
-      pt_ref:  [B, Pmax] int32 SMEM (scalar prefetch) — page table
+      row_ref, page_ref: [B*Pmax] int32 SMEM (scalar prefetch) — the item's
+        slot and its logical page (:class:`DecodeWork`)
+      phys_ref: [B*Pmax] int32 SMEM — read by the index maps only
       len_ref: [B] int32 SMEM — valid kv length per slot (incl. current token)
       layer_ref: [1] int32 SMEM — read by the index maps only
       q_ref:   [1, Hq, D] VMEM; k_ref/v_ref: [1, 1, page, Hkv*D] VMEM
@@ -95,25 +124,20 @@ def _paged_kernel(pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
     can lower (its dot supports only 2D tensors); bitwise-identical to the
     batched form, which interpret mode keeps for tier-1 wall-clock.
     """
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
-    length = len_ref[b]
+    i = pl.program_id(0)
+    j = page_ref[i]
+    length = len_ref[row_ref[i]]
+    first, last = page_span(length, page_size, n_pages, sliding_window)
 
-    @pl.when(j == 0)
+    @pl.when(j == first)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
     k_start = j * page_size
-    relevant = k_start < length
-    if sliding_window is not None:
-        # decode query position is length-1; keys <= q_pos - window are out
-        relevant = jnp.logical_and(
-            relevant, k_start + page_size - 1 > length - 1 - sliding_window)
 
-    @pl.when(relevant)
+    @pl.when(k_start < length)      # every item but an empty slot's
     def _compute():
         q = q_ref[0]          # [Hq, D]
         Hq, D = q.shape
@@ -181,10 +205,44 @@ def _paged_kernel(pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
                 preferred_element_type=jnp.float32).reshape(Hq, D)
         acc_ref[...] = acc_ref[...] * correction[:, :1] + pv
 
-    @pl.when(j == nj - 1)
+    @pl.when(j == last)
     def _finalize():
         denom = jnp.maximum(l_ref[...][:, :1], 1e-30)
         o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+
+
+class DecodeWork(NamedTuple):
+    """The decode kernel's grid, flattened: one item for every page that
+    holds tokens a slot's query reads. Slots in order, a slot's pages
+    ascending, every slot at least one item (an empty slot's computes
+    nothing and finalises to zeros). The arrays are ``B * Pmax`` long, as
+    many as a full table needs; the grid runs the first ``n_items``."""
+    row: jnp.ndarray       # [B*Pmax] int32 the item's slot
+    page: jnp.ndarray      # [B*Pmax] int32 its logical page
+    phys: jnp.ndarray      # [B*Pmax] int32 page_table[row, page]
+    lengths: jnp.ndarray   # [B] int32 valid kv length (incl. current token)
+    n_items: jnp.ndarray   # [] int32 items in use: the grid's bound
+
+
+def decode_work_list(page_table: jnp.ndarray, lengths: jnp.ndarray,
+                     page_size: int,
+                     sliding_window: int | None = None) -> DecodeWork:
+    """The work list of one decode step (:class:`DecodeWork`) from
+    ``page_table`` [B, Pmax] and ``lengths`` [B] (incl. the current token).
+    It is the same for every layer: build it once a step, outside the scan
+    over layers."""
+    B, Pmax = page_table.shape
+    lengths = jnp.asarray(lengths, jnp.int32)
+    first, last = page_span(lengths, page_size, Pmax, sliding_window)
+    ends = jnp.cumsum(last - first + 1)                     # [B]
+    item = jnp.arange(B * Pmax, dtype=jnp.int32)
+    # items past n_items are never run; they name the last slot's last page
+    row = jnp.minimum(
+        jnp.sum(item[:, None] >= ends[None, :], axis=1, dtype=jnp.int32),
+        B - 1)
+    page = jnp.minimum(item - (ends - 1 - last)[row], last[row])
+    return DecodeWork(row, page, jnp.asarray(page_table, jnp.int32)[row, page],
+                      lengths, ends[-1])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "sliding_window",
@@ -193,8 +251,7 @@ def paged_decode_attention(
     q: jnp.ndarray,           # [B, Hq, D] — one query token per slot
     k_pool: jnp.ndarray,      # [L, N, page, Hkv*D] — the stacked page pool
     v_pool: jnp.ndarray,
-    page_table: jnp.ndarray,  # [B, Pmax] int32 physical page ids
-    lengths: jnp.ndarray,     # [B] int32 valid kv length (incl. current token)
+    work: DecodeWork,         # decode_work_list(page_table, lengths, ...)
     layer: jnp.ndarray | int = 0,  # scalar int32 — which layer's pages
     interpret: bool = False,
     sliding_window: int | None = None,
@@ -203,7 +260,7 @@ def paged_decode_attention(
     """Returns [B, Hq, D] attention over each slot's paged history in layer
     ``layer`` of the pool. The pool operands reach the ``pallas_call`` as
     they are passed: the layer and the page are both picked by the blocks'
-    index map.
+    index map. ``sliding_window`` is the one ``work`` was built with.
 
     ``two_d_dots`` (default: on exactly when compiling for real — Mosaic's
     dot supports only 2D tensors) selects the unrolled per-kv-head 2D-dot
@@ -215,24 +272,15 @@ def paged_decode_attention(
         two_d_dots = not interpret
     B, Hq, D = q.shape
     _, _, page_size, HD = k_pool.shape
-    Pmax = page_table.shape[1]
 
-    def _page_index(b, j, pt_ref, len_ref, layer_ref):
-        # clamp j into this slot's relevant page range so skipped programs
-        # revisit the resident page and the DMA is elided
-        length = len_ref[b]
-        last = jnp.maximum((length - 1) // page_size, 0)
-        jj = jnp.minimum(j, last)
-        if sliding_window is not None:
-            lo = jnp.maximum((length - sliding_window) // page_size, 0)
-            jj = jnp.maximum(jj, lo)
-        return (layer_ref[0], pt_ref[b, jj], 0, 0)
-
-    kv_spec = pl.BlockSpec((1, 1, page_size, HD), _page_index)
-    q_spec = pl.BlockSpec((1, Hq, D), lambda b, j, pt, ln, ly: (b, 0, 0))
+    kv_spec = pl.BlockSpec(
+        (1, 1, page_size, HD),
+        lambda i, row, page, phys, ln, ly: (ly[0], phys[i], 0, 0))
+    q_spec = pl.BlockSpec(
+        (1, Hq, D), lambda i, row, page, phys, ln, ly: (row[i], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, Pmax),
+        num_scalar_prefetch=5,
+        grid=(work.n_items,),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=q_spec,
         scratch_shapes=[
@@ -243,15 +291,16 @@ def paged_decode_attention(
     )
     return pl.pallas_call(
         functools.partial(_paged_kernel, page_size=page_size,
+                          n_pages=work.row.shape[0] // B,
                           sliding_window=sliding_window,
                           two_d_dots=two_d_dots),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
+    )(work.row, work.page, work.phys, work.lengths,
       jnp.asarray(layer, jnp.int32).reshape(1), q, k_pool, v_pool)
 
 
@@ -526,22 +575,23 @@ def ragged_paged_attention(
     return out.reshape(B, Qmax, Hq, D)
 
 
-def paged_block_attention(q, k_pool, v_pool, page_table, lengths, layer=0,
+def paged_block_attention(q, k_pool, v_pool, work: DecodeWork, layer=0,
                           interpret: bool = False):
     """The open block of a model that generates by diffusion over blocks:
     ``q`` [B, W, Hq, D], the block's W queries a row, every one of which sees
-    all ``lengths`` keys (the row's kept history and the block itself, which
-    the caller has written). That is :func:`paged_decode_attention` with the
-    block folded into the GQA group axis, W x G query rows a kv head, so the
-    pages are walked once a row and not once a position. Returns
-    [B, W, Hq, D]."""
+    all of ``work.lengths`` keys (the row's kept history and the block
+    itself, which the caller has written). That is
+    :func:`paged_decode_attention` with the block folded into the GQA group
+    axis, W x G query rows a kv head, so the pages are walked once a row and
+    not once a position. No sliding window: ``work`` is built without one.
+    Returns [B, W, Hq, D]."""
     B, W, Hq, D = q.shape
     Hkv = k_pool.shape[3] // D
     G = Hq // Hkv
     folded = q.reshape(B, W, Hkv, G, D).transpose(0, 2, 1, 3, 4)
     out = paged_decode_attention(
-        folded.reshape(B, Hkv * W * G, D), k_pool, v_pool, page_table,
-        lengths, layer, interpret=interpret)
+        folded.reshape(B, Hkv * W * G, D), k_pool, v_pool, work, layer,
+        interpret=interpret)
     return out.reshape(B, Hkv, W, G, D).transpose(0, 2, 1, 3, 4).reshape(
         B, W, Hq, D)
 

@@ -828,7 +828,9 @@ class ContinuousBatchingEngine:
         # without a discard reads 0 and not nothing
         for series in ("llm_decode_chunks_dispatched_total",
                        "llm_decode_chunks_discarded_total",
-                       "llm_admission_ring_waits_total") + (
+                       "llm_admission_ring_waits_total",
+                       "llm_attn_pages_walked_total",
+                       "llm_attn_pages_offered_total") + (
                            _BLOCK_SERIES if self._block else ()):
             bump_counter(series, n=0.0)
         #: achieved ring depth at each drain (how many chunks stayed in
@@ -3098,6 +3100,30 @@ class ContinuousBatchingEngine:
                 "tokens_emitted": block_out[1]} if block_out else {}),
         })
 
+    def _count_attn_pages(self, kept: np.ndarray, grew: np.ndarray) -> None:
+        """/metrics of the decode kernel's grid over a drained dispatch: the
+        programs it launched, one for every page that holds tokens a row's
+        query reads, beside the page table's slots, summed over forwards and
+        layers. ``kept`` [B]: each row's length going in; ``grew``
+        [B, forwards]: whether that forward added a step's tokens to it (a
+        frozen row stops growing). A row that does not run sits at length 0
+        on the device and costs the one program every row has. Counted from
+        the host's mirror by the kernel's own :func:`page_span`, so a step
+        pays nothing for it."""
+        from ..ops.paged_attention import page_span
+
+        step = self._step_tokens
+        lengths = (np.where(self.active, kept, 0)[:, None]
+                   + step * (np.cumsum(grew, axis=1) - grew + 1))
+        slots = self.page_table.shape[1]
+        first, last = page_span(lengths, self.config.prefix_page_size, slots,
+                                self.model_config.sliding_window)
+        layers = self.model_config.num_layers
+        bump_counter("llm_attn_pages_walked_total",
+                     n=int((last - first + 1).sum()) * layers)
+        bump_counter("llm_attn_pages_offered_total",
+                     n=lengths.size * slots * layers)
+
     def _take_block_counters(self, drained: np.ndarray, forwards: int
                              ) -> tuple[np.ndarray, np.ndarray]:
         """A block model's drained matrix carries one more row, the
@@ -3696,6 +3722,8 @@ class ContinuousBatchingEngine:
             toks2d, accepts = toks[:, None], None
         decode_rows = [s for s in range(n) if self.active[s]]
         old_lengths = self.lengths.copy()
+        if not spec_plan:    # a draft span rides the ragged kernel
+            self._count_attn_pages(old_lengths, np.zeros((n, 1), bool))
         if self._block:
             self.lengths = np.where(
                 self.active & (toks2d[:, 0] >= 0),
@@ -3860,8 +3888,10 @@ class ContinuousBatchingEngine:
         block_out = None
         if self._block:
             chunk, ran = self._take_block_counters(chunk, self._k_steps)
-            commits = (chunk[:, ::self._block] >= 0).sum(axis=1)
+            committed = chunk[:, ::self._block] >= 0
+            commits = committed.sum(axis=1)
             old_lengths = self._commit_chunk(inflight, commits)
+            self._count_attn_pages(old_lengths, committed)
             self._emit_decode_spans(
                 wall0, (t3 - t0) * 1000.0, used_lookahead, depth=ring_depth,
                 row_tokens={s: int(c) * self._block
@@ -3872,6 +3902,7 @@ class ContinuousBatchingEngine:
                                                depth=ring_depth)
         else:
             old_lengths = self._commit_chunk(inflight)
+            self._count_attn_pages(old_lengths, chunk >= 0)
             self._emit_decode_spans(wall0, (t3 - t0) * 1000.0,
                                     used_lookahead, depth=ring_depth)
             self._emit_chunk(chunk, old_lengths, depth=ring_depth)

@@ -85,16 +85,26 @@ def test_flash_kernel_compiles_at_mistral_7b_shapes(one_chip, batch):
         one_chip((batch,), jnp.int32))
 
 
-def test_paged_decode_kernel_compiles_at_mistral_7b_shapes(one_chip):
-    from cyberfabric_core_tpu.ops.paged_attention import paged_decode_attention
+@pytest.mark.parametrize("heads,kv_heads,batch,pmax,window", [
+    (_HQ, _HKV, _B, _PMAX, _WINDOW),
+    (28, 4, 16, 64, None),      # qwen2-7b: 7 queries a kv head, 4k context
+    (128, 4, 16, 32, None),     # sdar: 4 x 8 query rows folded on a kv head
+], ids=["mistral-7b", "qwen2-7b", "sdar-folded"])
+def test_paged_decode_kernel_compiles_at_served_shapes(
+        one_chip, heads, kv_heads, batch, pmax, window):
+    """The grid over the pages in use: a work list in scalar prefetch and
+    a grid bound that is known only when the step runs."""
+    from cyberfabric_core_tpu.ops.paged_attention import (
+        decode_work_list, paged_decode_attention)
 
-    pool = one_chip(_POOL, jnp.bfloat16)
+    pool = one_chip((2, batch * pmax * 5 // 4 + 1, _PAGE, kv_heads * _D),
+                    jnp.bfloat16)
     _compiles_with_mosaic(
         lambda q, k, v, pt, n, layer: paged_decode_attention(
-            q, k, v, pt, n, layer, interpret=False, sliding_window=_WINDOW,
-            two_d_dots=True),
-        one_chip((_B, _HQ, _D), jnp.bfloat16), pool, pool,
-        one_chip((_B, _PMAX), jnp.int32), one_chip((_B,), jnp.int32),
+            q, k, v, decode_work_list(pt, n, _PAGE, window), layer,
+            interpret=False, sliding_window=window, two_d_dots=True),
+        one_chip((batch, heads, _D), jnp.bfloat16), pool, pool,
+        one_chip((batch, pmax), jnp.int32), one_chip((batch,), jnp.int32),
         one_chip((), jnp.int32))
 
 

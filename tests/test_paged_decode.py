@@ -202,6 +202,58 @@ def test_pool_reaches_the_kernel_uncopied(step):
         assert seen == ["scatter"], seen
 
 
+@pytest.mark.parametrize("program", ["paged_decode_chunk", "mixed_step"])
+@pytest.mark.parametrize("model", ["tiny-llama", "tiny-falcon-h1",
+                                   "tiny-sdar"])
+def test_work_list_is_built_once_a_step(model, program):
+    """The decode kernel's grid is laid out from the rows' lengths, which
+    are the same for every layer: in the scheduler's own programs the one
+    cumsum over the rows sits outside the scan over layers, and the kernel
+    inside it takes the list (a bound, three arrays of ``B x Pmax`` items,
+    the lengths) as it was built."""
+    from cyberfabric_core_tpu.runtime import EngineConfig
+    from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
+
+    n, width = 4, 32
+    eng = ContinuousBatchingEngine(EngineConfig(
+        model=model, max_seq_len=128, max_batch=n, decode_chunk=3,
+        use_flash=False, prefix_cache_pages=16, prefix_page_size=16,
+        prefill_budget_tokens=width), seed=0)
+    try:
+        row_b = jnp.zeros((n,), bool)
+        tail = (eng._stops_dev, eng._limit_dev, *eng._block_operands(),
+                eng._slot_keys, eng._temp_dev, eng._top_p_dev, eng._top_k_dev)
+        if program == "paged_decode_chunk":
+            jaxpr = jax.make_jaxpr(eng._paged_decode_fn)(
+                eng.params, *eng.pool.cache_operands(), eng._page_table_dev,
+                eng._last_tokens, eng._lengths_dev, eng._active_dev,
+                eng._finished_dev, *tail)
+        else:
+            lane = jnp.zeros((1,), jnp.int32)
+            jaxpr = jax.make_jaxpr(eng._mixed_step_fn)(
+                eng.params, *eng.pool.cache_operands(), eng._page_table_dev,
+                jnp.zeros((1, width), jnp.int32), lane, lane, lane,
+                eng._last_tokens, eng._lengths_dev, eng._active_dev,
+                eng._finished_dev, *(() if eng._block else (row_b,)), row_b,
+                eng._lengths_dev, *tail)
+        layers, = [e for e in _find(jaxpr.jaxpr, "scan")
+                   if e.params["length"] == eng.model_config.num_layers]
+        def over_rows(jaxpr):     # an expert layer and a mixer have others
+            return [e for e in _find(jaxpr, "cumsum")
+                    if e.outvars[0].aval.shape == (n,)
+                    and e.outvars[0].aval.dtype == jnp.int32]
+
+        body = layers.params["jaxpr"].jaxpr
+        assert len(over_rows(jaxpr.jaxpr)) == 1 and not over_rows(body)
+        items = n * eng.page_table.shape[1]
+        decode, = [e for e in _find(body, "pallas_call")
+                   if e.params["grid_mapping"].num_dynamic_grid_bounds]
+        assert [v.aval.shape for v in decode.invars[:5]] == [
+            (), (items,), (items,), (items,), (n,)]
+    finally:
+        eng.shutdown()
+
+
 @pytest.mark.parametrize("model", ["tiny-llama", "tiny-falcon-h1"])
 def test_mixed_step_computes_the_tokens_it_has(model):
     """The guard S11 lacked: in the scheduler's own ``mixed_step`` at 16

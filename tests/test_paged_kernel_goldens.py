@@ -108,12 +108,18 @@ def _stacked(case: dict, which: str) -> jnp.ndarray:
 @pytest.mark.parametrize("name", CASES)
 def test_merged_stacked_pool_is_bitwise_the_parent(name, body, goldens):
     from cyberfabric_core_tpu.ops.paged_attention import (
-        paged_decode_attention, ragged_paged_attention)
+        decode_work_list, paged_decode_attention, ragged_paged_attention)
 
     case = case_inputs(name)
-    fn = paged_decode_attention if case["kernel"] == "decode" \
-        else ragged_paged_attention
+    if case["kernel"] == "decode":
+        # the grid over the pages in use (PR 32) walks a row's pages in the
+        # parent's order, so the digests of the (B, Pmax) grid still hold
+        fn = paged_decode_attention
+        rows = (decode_work_list(case["table"], *case["rows"], PAGE,
+                                 case["window"]),)
+    else:
+        fn, rows = ragged_paged_attention, (case["table"], *case["rows"])
     out = fn(case["q"], _stacked(case, "k_pool"), _stacked(case, "v_pool"),
-             case["table"], *case["rows"], LAYER, interpret=True,
+             *rows, LAYER, interpret=True,
              sliding_window=case["window"], two_d_dots=body == "two_d_dots")
     assert digest(out) == goldens[name][body]

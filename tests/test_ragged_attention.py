@@ -17,7 +17,8 @@ import pytest
 
 from cyberfabric_core_tpu.ops.attention import attention_with_cache
 from cyberfabric_core_tpu.ops.paged_attention import (
-    paged_decode_attention, paged_gather_dense, ragged_paged_attention)
+    decode_work_list, paged_decode_attention, paged_gather_dense,
+    ragged_paged_attention)
 
 
 def _build_pool(key, B, page, Pmax, Hkv, D, N):
@@ -111,7 +112,8 @@ def test_ragged_decode_rows_bit_identical_to_decode_kernel():
     k_pool, v_pool, pt = _build_pool(kp, B, page, Pmax, Hkv, D, N)
     hist = jnp.asarray([0, 9, 33, 80], jnp.int32)
 
-    dec = paged_decode_attention(q1, k_pool, v_pool, pt, hist + 1,
+    dec = paged_decode_attention(q1, k_pool, v_pool,
+                                 decode_work_list(pt, hist + 1, page),
                                  interpret=True)
     q = jnp.zeros((B, 8, Hq, D), jnp.float32).at[:, 0].set(q1)
     rag = ragged_paged_attention(q, k_pool, v_pool, pt, hist,
@@ -218,8 +220,9 @@ def test_two_d_dot_rewrite_bitwise_decode_kernel():
     k_pool, v_pool, pt = _build_pool(kp, B, page, Pmax, Hkv, D, N)
     lengths = jnp.asarray([1, 10, 34, 81], jnp.int32)
 
-    batched = paged_decode_attention(q, k_pool, v_pool, pt, lengths,
+    work = decode_work_list(pt, lengths, page)
+    batched = paged_decode_attention(q, k_pool, v_pool, work,
                                      interpret=True, two_d_dots=False)
-    two_d = paged_decode_attention(q, k_pool, v_pool, pt, lengths,
+    two_d = paged_decode_attention(q, k_pool, v_pool, work,
                                    interpret=True, two_d_dots=True)
     np.testing.assert_array_equal(np.asarray(two_d), np.asarray(batched))

@@ -546,7 +546,9 @@ def test_the_rings_series_are_at_zero_from_engine_build():
     drop then move them as ``stats()["pipeline"]["lookahead"]`` moves."""
     names = ("llm_decode_chunks_dispatched_total",
              "llm_decode_chunks_discarded_total",
-             "llm_admission_ring_waits_total")
+             "llm_admission_ring_waits_total",
+             "llm_attn_pages_walked_total",
+             "llm_attn_pages_offered_total")
     before = {n: _counter(n) for n in names}
     eng = _manual(_cfg(decode_lookahead=2))
     col = _Collector(1)
@@ -568,6 +570,71 @@ def test_the_rings_series_are_at_zero_from_engine_build():
     assert dispatched >= la["dispatched"] > 0
     assert _counter(names[1]) - before[names[1]] == la["discarded"]
     assert _counter(names[2]) == before[names[2]]   # nobody waited
+
+
+@pytest.mark.parametrize("model", ["tiny-llama", "tiny-falcon-h1"])
+def test_attn_pages_walked_is_what_the_rows_lengths_say(model):
+    """``llm_attn_pages_walked_total`` over ``llm_attn_pages_offered_total``
+    for one request served alone, against a count made here from its length
+    at every step: the arrival's mixed step finds every row of the decode
+    group idle (one program each), then each step of each chunk walks the
+    pages the running row's tokens lie on, its own included, and one program
+    for each of the three idle rows; a row the device froze inside a chunk
+    stays at the length it reached."""
+    names = ("llm_attn_pages_walked_total", "llm_attn_pages_offered_total")
+    prompt, answer, page, k = 40, 11, 16, 4
+    eng = _manual(_cfg(model=model, decode_lookahead=0))
+    col = _Collector(1)
+    try:
+        before = [_counter(n) for n in names]
+        eng.submit(list(range(5, 5 + prompt)),
+                   SamplingParams(max_tokens=answer), col.emit_for(0))
+        _passes_until(eng, col.done.is_set)
+        rows, slots = eng.n_slots, eng.page_table.shape[1]
+        layers, mixed = eng.model_config.num_layers, eng.mixed_rounds
+        chunks = eng.decode_rounds - mixed
+    finally:
+        eng.shutdown()
+    assert col.finishes[0] == "length" and len(col.tokens[0]) == answer
+    walked, length, left = mixed * rows, prompt, answer - 1
+    assert chunks == -(-left // k)
+    for _ in range(chunks * k):
+        walked += -(-(length + 1) // page) + rows - 1
+        if left:
+            length, left = length + 1, left - 1
+    got = [_counter(n) - b for n, b in zip(names, before)]
+    assert got == [walked * layers,
+                   (mixed + chunks * k) * rows * slots * layers]
+    assert 0.0 < got[0] / got[1] < 0.5
+
+
+@pytest.mark.parametrize("model", ["tiny-llama", "tiny-sdar"])
+def test_attn_pages_counted_by_step_from_kept_lengths(model):
+    """The count itself, for a token step and a block step: a forward reads
+    the row's kept length and the step's own tokens; a forward that added to
+    the length moves the ones after it; a row that does not run is at 0 on
+    the device."""
+    names = ("llm_attn_pages_walked_total", "llm_attn_pages_offered_total")
+    eng = _manual(_cfg(model=model))
+    try:
+        step, slots = eng._step_tokens, eng.page_table.shape[1]
+        layers = eng.model_config.num_layers
+        eng.active[:] = [True, True, False, True]
+        kept = np.asarray([15, 100, 77, 255], np.int32)
+        grew = np.asarray([[1, 1, 0], [0, 1, 0], [1, 1, 1], [0, 0, 0]], bool)
+        before = [_counter(n) for n in names]
+        eng._count_attn_pages(kept, grew)
+        got = [_counter(n) - b for n, b in zip(names, before)]
+    finally:
+        eng.active[:] = False
+        eng.shutdown()
+    walked = 0
+    for row in range(4):
+        length = int(kept[row]) if row != 2 else 0
+        for f in range(3):
+            walked += min(-(-(length + step) // 16), slots)
+            length += step * int(grew[row, f])
+    assert got == [walked * layers, 4 * 3 * slots * layers]
 
 
 def test_the_rings_series_are_on_metrics_before_the_first_request():
@@ -594,7 +661,9 @@ def test_the_rings_series_are_on_metrics_before_the_first_request():
     text = asyncio.run(go())
     for name in ("llm_decode_chunks_dispatched_total",
                  "llm_decode_chunks_discarded_total",
-                 "llm_admission_ring_waits_total"):
+                 "llm_admission_ring_waits_total",
+                 "llm_attn_pages_walked_total",
+                 "llm_attn_pages_offered_total"):
         assert f"# TYPE {name} counter" in text
         help_line = next(line for line in text.splitlines()
                          if line.startswith(f"# HELP {name} "))

@@ -17,7 +17,8 @@ from benchmark import sdar_reference, sdar_weights
 from benchmark.adapters import sdar as adapter
 from cyberfabric_core_tpu.models import get_config, sdar_moe
 from cyberfabric_core_tpu.ops.paged_attention import (
-    paged_block_attention, paged_gather_dense, ragged_paged_attention)
+    decode_work_list, paged_block_attention, paged_gather_dense,
+    ragged_paged_attention)
 from cyberfabric_core_tpu.ops.sampling import block_unmask
 
 CONF = json.loads((Path(__file__).resolve().parents[1] / "benchmark/tests"
@@ -134,8 +135,9 @@ def test_decode_kernel_block_fold_against_a_dense_mask(block):
     kept = np.array([8, 16])
     q = rng.standard_normal((2, block, 4, 16)).astype(np.float32)
     out = np.asarray(paged_block_attention(
-        jnp.asarray(q), k_pool, v_pool, table, jnp.asarray(kept + block), 0,
-        interpret=True))
+        jnp.asarray(q), k_pool, v_pool,
+        decode_work_list(table, jnp.asarray(kept + block), k_pool.shape[2]),
+        0, interpret=True))
     kd, vd = (np.asarray(a) for a in paged_gather_dense(k_pool, v_pool, table,
                                                         16))
     for r in range(2):
