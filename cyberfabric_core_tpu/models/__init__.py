@@ -17,7 +17,7 @@ __all__ = ["MODEL_CONFIGS", "ModelConfig", "get_config", "decoder_module"]
 #: Each exposes init_params, forward_paged_decode, forward_paged_mixed,
 #: lm_head_logits and gather_last_hidden.
 _DECODERS = {"llama": "llama", "falcon_h1": "falcon_h1",
-             "sdar_moe": "sdar_moe"}
+             "sdar_moe": "sdar_moe", "kimi_k2": "kimi_k2"}
 
 
 def decoder_module(cfg: ModelConfig) -> ModuleType:

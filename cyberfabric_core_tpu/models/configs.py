@@ -19,7 +19,9 @@ class ModelConfig:
     name: str
     #: "llama" (decoder family) | "falcon_h1" (decoder whose every block runs
     #: a Mamba-2 mixer beside GQA attention) | "sdar_moe" (decoder of routed
-    #: experts that generates by diffusion over blocks) | "bert" (encoder)
+    #: experts that generates by diffusion over blocks) | "kimi_k2" (decoder
+    #: of latent attention over a latent page, a leading dense layer, then
+    #: sigmoid-routed experts beside a shared expert) | "bert" (encoder)
     architecture: str
     vocab_size: int
     hidden_size: int
@@ -57,6 +59,44 @@ class ModelConfig:
     remasking: str = "low_confidence_static"
     confidence_threshold: float = 0.9
     mask_token_id: int = -1
+    # kimi_k2 (the DeepSeek-V3 block). Latent attention (kv_lora_rank 0 =
+    # GQA attention): q is projected down to q_lora_rank, normed, and up to
+    # num_heads x (qk_nope_head_dim + qk_rope_head_dim); k and v come from
+    # ONE compressed row of kv_lora_rank a token plus one rotary key of
+    # qk_rope_head_dim that all heads share, and the cache holds only those
+    # (``latent_width`` numbers a token a layer). Names as published.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    #: leading layers with a dense MLP of intermediate_size; the layers
+    #: after them hold the experts, of moe_intermediate_size each
+    first_k_dense: int = 0
+    moe_intermediate_size: int = 0
+    #: experts every token runs, beside the routed ones (one MLP of
+    #: shared_experts x moe_intermediate_size)
+    shared_experts: int = 0
+    #: the sigmoid router's gate is score over the chosen scores' sum, times
+    #: this (the scoring itself is the architecture's constant, as
+    #: router_float32 is: kimi_k2 scores by sigmoid, the others by softmax)
+    routed_scaling_factor: float = 1.0
+    # one chip's share of a layer that a deployment divides over chips: the
+    # router scores all num_experts, this chip computes experts
+    # expert_offset .. expert_offset + experts_held - 1 (0 = all of them)
+    # and holds vocab_held rows of the embedding and the head (0 = all)
+    experts_held: int = 0
+    expert_offset: int = 0
+    vocab_held: int = 0
+    # YaRN rotary scaling (rope_factor 1 = none): the published rope_scaling
+    # keys factor, original_max_position_embeddings, beta_fast, beta_slow,
+    # mscale, mscale_all_dim
+    rope_factor: float = 1.0
+    rope_original_max: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
     # falcon_h1: the state-space mixer beside attention (0 heads = no mixer).
     # Names follow the published config: mamba_d_ssm, mamba_n_heads,
     # mamba_d_head, mamba_d_state, mamba_n_groups, mamba_d_conv,
@@ -93,6 +133,11 @@ class ModelConfig:
         if self.remasking not in ("low_confidence_static",
                                   "low_confidence_dynamic"):
             raise ValueError(f"unknown remasking {self.remasking!r}")
+        if self.expert_offset + self.experts_held > max(self.num_experts, 0):
+            raise ValueError(
+                f"{self.name}: experts {self.expert_offset}.."
+                f"{self.expert_offset + self.experts_held - 1} are not among "
+                f"the router's {self.num_experts}")
         if self.block_length > 1 and (
                 self.block_length % self.denoising_steps
                 or not 0 <= self.mask_token_id < self.vocab_size):
@@ -109,7 +154,51 @@ class ModelConfig:
     def router_float32(self) -> bool:
         """The router's weights stay float32 whatever the activations' dtype
         (a score decides WHICH experts run, not only how much)."""
-        return self.architecture == "sdar_moe"
+        return self.architecture in ("sdar_moe", "kimi_k2")
+
+    @property
+    def is_latent(self) -> bool:
+        """The cache is one latent row a token a layer, not K and V."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        """Numbers a token a layer in a latent page: the compressed row and
+        the shared rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_lanes(self) -> int:
+        """Lanes a token takes in a latent page: ``latent_width`` rounded up
+        to whole lane tiles of 128, the rest zero. A TPU array's minor
+        dimension is tiled by 128 whatever its shape says, so 576 numbers
+        take 640 lanes of HBM either way; stated in the shape, the pool keeps
+        the row-major layout its kernels and its scatter read (left to the
+        device, a minor dimension with 11% padding makes it choose the PAGE
+        axis as the minor one for a pool of 3073 pages, and every step then
+        copies the pool twice)."""
+        return -(-self.latent_width // 128) * 128
+
+    @property
+    def experts_local(self) -> int:
+        """Routed experts this chip computes, of ``num_experts`` routed over."""
+        return self.experts_held or self.num_experts
+
+    @property
+    def vocab_rows(self) -> int:
+        """Rows of the embedding and the head this chip holds: what ids and
+        logits range over."""
+        return self.vocab_held or self.vocab_size
+
+    @property
+    def num_moe_layers(self) -> int:
+        return self.num_layers - self.first_k_dense if self.num_experts else 0
+
+    def cache_bytes_per_token(self, itemsize: int = 2) -> int:
+        """Bytes a token holds in the page pool over all layers."""
+        per_layer = (self.latent_lanes if self.is_latent
+                     else 2 * self.num_kv_heads * self.head_dim)
+        return self.num_layers * per_layer * itemsize
 
     @property
     def is_block(self) -> bool:
@@ -318,6 +407,38 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         rms_norm_eps=1e-6, num_experts=8, experts_per_token=2, qk_norm=True,
         block_length=4, denoising_steps=4, mask_token_id=511,
     ),
+    # Kimi-K2.5's language model, config.json as published (model_type
+    # kimi_k2, the DeepSeek-V3 block): 61 layers of 64-head latent attention
+    # (head_dim here is the query/key head, 128 + 64), one leading dense
+    # layer of 18432, then 384 routed experts of 2048 top-8 under a sigmoid
+    # router with a selection bias beside one shared expert; YaRN x64 over
+    # 4096. Text in, text out: the vision tower is not part of it
+    "kimi-k2.5": ModelConfig(
+        name="kimi-k2.5", architecture="kimi_k2", vocab_size=163840,
+        hidden_size=7168, intermediate_size=18432, num_layers=61,
+        num_heads=64, num_kv_heads=64, head_dim=192, max_position=262144,
+        rope_theta=50000.0, rms_norm_eps=1e-5, num_experts=384,
+        experts_per_token=8, q_lora_rank=1536, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        first_k_dense=1, moe_intermediate_size=2048, shared_experts=1,
+        routed_scaling_factor=2.827,
+        rope_factor=64.0, rope_original_max=4096, rope_beta_fast=32.0,
+        rope_beta_slow=1.0, rope_mscale=1.0, rope_mscale_all_dim=1.0,
+    ),
+    # CPU-test preset of the same block: a dense layer then 2 expert layers,
+    # 16 experts top-4 of which a chip may hold 4, latent 32 + 16, YaRN x4
+    "tiny-kimi": ModelConfig(
+        name="tiny-kimi", architecture="kimi_k2", vocab_size=512,
+        hidden_size=64, intermediate_size=128, num_layers=3, num_heads=4,
+        num_kv_heads=4, head_dim=48, max_position=1024, rope_theta=10000.0,
+        rms_norm_eps=1e-5, num_experts=16, experts_per_token=4,
+        q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=32,
+        qk_rope_head_dim=16, v_head_dim=32, first_k_dense=1,
+        moe_intermediate_size=32, shared_experts=1,
+        routed_scaling_factor=2.5,
+        rope_factor=4.0, rope_original_max=64, rope_beta_fast=32.0,
+        rope_beta_slow=1.0, rope_mscale=1.0, rope_mscale_all_dim=1.0,
+    ),
     "bge-base-en": ModelConfig(
         name="bge-base-en", architecture="bert", vocab_size=30522, hidden_size=768,
         intermediate_size=3072, num_layers=12, num_heads=12, num_kv_heads=12,
@@ -339,6 +460,20 @@ MODEL_CONFIGS["falcon-h1-34b-16l"] = dataclasses.replace(
 # one chip's stage of the 48-block deployment: 16 blocks, every expert
 MODEL_CONFIGS["sdar-30b-a3b-16l"] = dataclasses.replace(
     MODEL_CONFIGS["sdar-30b-a3b"], name="sdar-30b-a3b-16l", num_layers=16)
+
+
+# share 0 of the first pipeline stage of a 128-chip deployment of kimi-k2.5
+# (4 stages; each layer divided over 32 chips): the dense layer and 14 expert
+# layers, experts 0-11 of each layer's 384, rows 0-20479 of the vocabulary
+# (an 8-way split); attention is data-parallel, so every head is here
+MODEL_CONFIGS["kimi-k2.5-share32-15l"] = dataclasses.replace(
+    MODEL_CONFIGS["kimi-k2.5"], name="kimi-k2.5-share32-15l", num_layers=15,
+    experts_held=12, expert_offset=0, vocab_held=20480)
+
+# a share of the tiny preset: experts 4-7 of 16, half the vocabulary
+MODEL_CONFIGS["tiny-kimi-share4"] = dataclasses.replace(
+    MODEL_CONFIGS["tiny-kimi"], name="tiny-kimi-share4", experts_held=4,
+    expert_offset=4, vocab_held=256)
 
 
 def get_config(name: str) -> ModelConfig:

@@ -174,21 +174,35 @@ def split_moe(layers: dict) -> tuple[dict, dict | None]:
             {k: layers[k] for k in MOE_LEAVES})
 
 
-def moe_route(flat: jnp.ndarray, router: jnp.ndarray, k: int):
+def moe_route(flat: jnp.ndarray, router: jnp.ndarray, k: int, *,
+              sigmoid: bool = False, bias: jnp.ndarray | None = None,
+              scale: float = 1.0):
     """Top-k routing of ``flat`` [N, H]: (experts [N, k] int32, gates [N, k]
     f32). The gate is the softmax over the chosen experts' scores, which is
     also the softmax over every expert renormalised over the chosen
     (``norm_topk_prob``): the two are one number. A float32 router is
     multiplied in float32 at full precision, since a score decides WHICH
-    experts run, not only how much."""
+    experts run, not only how much.
+
+    ``sigmoid`` (the DeepSeek-V3 router): the score is ``sigmoid(x W_g)``,
+    the experts chosen are the ``k`` largest of score + ``bias`` [E] (the
+    selection bias only chooses), and the gate is the score over the chosen
+    scores' sum, times ``scale`` (``routed_scaling_factor``)."""
     if router.dtype == jnp.float32:
         logits = jnp.einsum("nh,he->ne", flat.astype(jnp.float32), router,
                             precision=jax.lax.Precision.HIGHEST)
     else:
         logits = jnp.einsum("nh,he->ne", flat, router,
                             preferred_element_type=jnp.float32)
-    top_vals, top_idx = jax.lax.top_k(logits, k)
-    return top_idx.astype(jnp.int32), jax.nn.softmax(top_vals, axis=-1)
+    if not sigmoid:
+        top_vals, top_idx = jax.lax.top_k(logits, k)
+        return top_idx.astype(jnp.int32), jax.nn.softmax(top_vals, axis=-1)
+    score = jax.nn.sigmoid(logits)
+    _, top_idx = jax.lax.top_k(
+        score if bias is None else score + bias.astype(jnp.float32), k)
+    chosen = jnp.take_along_axis(score, top_idx, axis=1)
+    gates = chosen / jnp.sum(chosen, axis=1, keepdims=True) * scale
+    return top_idx.astype(jnp.int32), gates
 
 
 def moe_experts(flat: jnp.ndarray, top_idx: jnp.ndarray, gates: jnp.ndarray,
@@ -197,13 +211,22 @@ def moe_experts(flat: jnp.ndarray, top_idx: jnp.ndarray, gates: jnp.ndarray,
     holds the STACKED expert matrices and ``layer`` picks the layer inside
     the grouped matmul. Dropless: the ``N*K`` assignments are sorted by
     expert, each expert multiplies the rows that chose it, however many, and
-    the results go back to token order."""
+    the results go back to token order.
+
+    **A chip's share** (``cfg.experts_held``): ``top_idx`` ranges over all
+    ``cfg.num_experts`` but ``moe`` holds experts ``cfg.expert_offset ..``
+    only. The assignments to experts held elsewhere sort to the end, past
+    every group, where no work item reaches them, and add nothing: the
+    result is the part of the layer that the held experts give."""
     from ..ops.grouped_matmul import grouped_matmul
 
-    E = cfg.num_experts
+    E = cfg.experts_local
     N, K = top_idx.shape
     interpret = _default_interpret()
     expert_of = top_idx.reshape(N * K)
+    if cfg.experts_held:
+        expert_of = expert_of - cfg.expert_offset
+        expert_of = jnp.where((expert_of >= 0) & (expert_of < E), expert_of, E)
     order = jnp.argsort(expert_of)            # stable: token order in a group
     sizes = jnp.bincount(expert_of, length=E).astype(jnp.int32)
     rows = flat[order // K]                                    # [NK, H]
@@ -216,6 +239,9 @@ def moe_experts(flat: jnp.ndarray, top_idx: jnp.ndarray, gates: jnp.ndarray,
     up = gmm(rows, moe["moe_up"])
     act = (_act(gate, cfg) * up).astype(flat.dtype)
     out = gmm(act, moe["moe_down"]) * gates.reshape(N * K)[order][:, None]
+    if cfg.experts_held:   # rows past the groups were never written
+        held = jnp.arange(N * K, dtype=jnp.int32) < jnp.sum(sizes)
+        out = jnp.where(held[:, None], out, 0.0)
     return jnp.zeros((N, flat.shape[1]), jnp.float32).at[order // K].add(out)
 
 
@@ -455,6 +481,10 @@ def decode_work(cfg: ModelConfig, page_table, lengths, page_size: int):
     """The decode kernel's work list for one step: ``lengths`` [B] counts
     the tokens the step itself writes. The same for every layer, so it is
     built here, outside the scan over layers."""
+    if cfg.is_latent:       # the same pages, a slot's several at a time
+        from ..ops.mla_attention import latent_work_list
+
+        return latent_work_list(page_table, lengths, page_size)
     from ..ops.paged_attention import decode_work_list
 
     return decode_work_list(page_table, lengths, page_size,

@@ -30,7 +30,8 @@ bookkeeping, not the model's.
 Every entry point also returns ``aux``: the experts each token chose
 (``[L, N, K]``, what the benchmark's judge holds against the reference's own
 scores) and ``touched``, the experts with at least one token summed over the
-layers (the ``/metrics`` counter behind ``moe_experts_touched_share``).
+layers (``MOE_COUNTERS``: the ``/metrics`` counter behind
+``moe_experts_touched_share``).
 """
 
 from __future__ import annotations
@@ -50,9 +51,12 @@ from .llama import (DecodeGroup, PagedPools, Params, _attn_out,
                     moe_experts, moe_route, split_moe)
 
 __all__ = ["init_params", "forward_paged_decode", "forward_paged_mixed",
-           "lm_head_logits", "gather_last_hidden"]
+           "lm_head_logits", "gather_last_hidden", "MOE_COUNTERS"]
 
 Aux = dict[str, jnp.ndarray]
+#: what ``aux`` counts over a forward's expert layers, for the serving
+#: programs to hand to the host
+MOE_COUNTERS = ("touched",)
 
 
 def _one_device(mesh: Any, interpret: bool | None) -> bool:

@@ -544,7 +544,22 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                  "Experts that received at least one token, summed over "
                  "layers and forwards"),
                 ("llm_moe_experts_offered_total",
-                 "Experts there are, summed over layers and forwards")):
+                 "Experts held here (all of them where a chip holds them "
+                 "all), summed over expert layers and forwards"),
+                # a chip's share of the experts (kimi_k2)
+                ("llm_moe_assignments_total",
+                 "Token-expert assignments the forwards routed (tokens x "
+                 "experts a token), summed over expert layers"),
+                ("llm_moe_assignments_local_total",
+                 "Those of them that fell on experts held here: experts "
+                 "held / experts routed of them under uniform routing"),
+                ("llm_moe_decode_experts_touched_total",
+                 "Experts that received at least one token, summed over "
+                 "layers and the forwards of decode chunks alone (a mixed "
+                 "step's prompt chunk is left out)"),
+                ("llm_moe_decode_experts_offered_total",
+                 "Experts held here, summed over expert layers and the "
+                 "forwards of decode chunks alone")):
             self.registry.counter(name, text).inc(0.0)
 
         def _state_stat(key: str) -> float:

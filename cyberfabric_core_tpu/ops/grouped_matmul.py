@@ -32,10 +32,25 @@ from jax.experimental.pallas import tpu as pltpu
 
 #: rows of a tile at serving sizes; a smaller step is one tile of its rows
 ROW_TILE = 128
+#: the most bytes of one expert's matrix a program takes as a block: a wider
+#: matrix is walked in column tiles (the block is double-buffered and an
+#: int8 block converted in VMEM, so 7168 x 2048 whole would not fit)
+BLOCK_BYTES = 4 * 1024 * 1024
 
 
 def _row_tile(m: int) -> int:
     return ROW_TILE if m >= ROW_TILE else -(-m // 16) * 16
+
+
+def _col_tile(k: int, n: int, itemsize: int) -> int:
+    """Columns of a weight block: all ``n`` where ``k x n`` fits
+    ``BLOCK_BYTES``, else the most whole lane tiles that divide ``n`` and
+    fit."""
+    if k * n * itemsize <= BLOCK_BYTES or n % 128:
+        return n
+    fits = [t for t in range(128, n, 128)
+            if n % t == 0 and k * t * itemsize <= BLOCK_BYTES]
+    return max(fits, default=128)
 
 
 def group_items(group_sizes: jnp.ndarray, m_pad: int, tm: int):
@@ -66,11 +81,12 @@ def group_items(group_sizes: jnp.ndarray, m_pad: int, tm: int):
 
 def _kernel(tile_ref, expert_ref, lo_ref, hi_ref, n_ref, layer_ref, x_ref,
             w_ref, *rest, tm: int, scaled: bool):
-    """One work item: rows ``lo..hi`` of tile ``tile`` times expert
-    ``expert``'s matrix, added into the tile's output block (stored on the
-    tile's first item, so a block is never read before it is written)."""
+    """One work item of one column tile (grid: column tiles x items): rows
+    ``lo..hi`` of tile ``tile`` times that column tile of expert ``expert``'s
+    matrix, added into the tile's output block (stored on the tile's first
+    item, so a block is never read before it is written)."""
     s_ref, o_ref = rest if scaled else (None, rest[0])
-    t = pl.program_id(0)
+    t = pl.program_id(1)
 
     @pl.when(t < n_ref[0])
     def _item():
@@ -117,27 +133,34 @@ def grouped_matmul(
         x = jnp.pad(x, ((0, m_pad - M), (0, 0)))
     tile, expert, lo, hi, n_real = group_items(group_sizes, m_pad, tm)
     scaled = scale is not None
+    # the column tiles (one, where the matrix fits a block) are the OUTER
+    # grid axis, so that a tile's items stay consecutive and an output block
+    # is finished before the next
+    tn = _col_tile(K, N, w.dtype.itemsize)
 
-    def rows_at(t, tile_ref, *_):
-        return (tile_ref[t], 0)
+    def rows_at(j, t, tile, *_):
+        return (tile[t], 0)
 
-    def expert_at(t, tile_ref, expert_ref, lo_ref, hi_ref, n_ref, layer_ref):
-        return (layer_ref[0], expert_ref[t], 0, 0)
+    def out_at(j, t, tile, *_):
+        return (tile[t], j)
+
+    def expert_at(j, t, tile, expert, lo, hi, n, layer):
+        return (layer[0], expert[t], 0, j)
 
     in_specs = [pl.BlockSpec((tm, K), rows_at),
-                pl.BlockSpec((1, 1, K, N), expert_at)]
+                pl.BlockSpec((1, 1, K, tn), expert_at)]
     operands = [x, w]
     if scaled:
-        in_specs.append(pl.BlockSpec((1, 1, 1, N), expert_at))
+        in_specs.append(pl.BlockSpec((1, 1, 1, tn), expert_at))
         operands.append(scale.reshape(L, E, 1, N).astype(jnp.float32))
     out = pl.pallas_call(
         functools.partial(_kernel, tm=tm, scaled=scaled),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=6, grid=(tile.shape[0],),
-            in_specs=in_specs, out_specs=pl.BlockSpec((tm, N), rows_at)),
+            num_scalar_prefetch=6, grid=(N // tn, tile.shape[0]),
+            in_specs=in_specs, out_specs=pl.BlockSpec((tm, tn), out_at)),
         out_shape=jax.ShapeDtypeStruct((m_pad, N), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
+            dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
     )(tile, expert, lo, hi, n_real,

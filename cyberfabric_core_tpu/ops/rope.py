@@ -6,16 +6,79 @@ no per-step trig on the hot path, and gather-by-position keeps decode shapes sta
 
 from __future__ import annotations
 
+import math
+
 import jax.numpy as jnp
 import numpy as np
 
 
-def rope_frequencies(head_dim: int, max_position: int, theta: float) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Returns (cos, sin) tables of shape [max_position, head_dim//2] in f32."""
-    inv_freq = 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature for a context stretched ``factor``
+    times: ``0.1 mscale ln(factor) + 1`` (1 where nothing is stretched)."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's inverse frequencies [dim/2]: a pair that turns more than
+    ``beta_fast`` times over the original context keeps ``theta^(-2i/dim)``,
+    one that turns fewer than ``beta_slow`` times gets that over ``factor``
+    (positions interpolated), and between the two correction dimensions the
+    blend is a linear ramp."""
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def correction_dim(rotations: float) -> float:
+        return dim * math.log(original_max / (rotations * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def rope_frequencies(head_dim: int, max_position: int, theta: float,
+                     inv_freq: np.ndarray | None = None,
+                     scale: float = 1.0) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Returns (cos, sin) tables of shape [max_position, head_dim//2] in f32.
+    ``inv_freq`` replaces ``theta^(-2i/head_dim)`` (YaRN's blend) and
+    ``scale`` multiplies both tables (YaRN's ``mscale`` ratio)."""
+    if inv_freq is None:
+        inv_freq = 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim))
     pos = np.arange(max_position, dtype=np.float64)
     angles = np.outer(pos, inv_freq)  # [P, D/2]
-    return jnp.asarray(np.cos(angles), jnp.float32), jnp.asarray(np.sin(angles), jnp.float32)
+    return (jnp.asarray(np.cos(angles) * scale, jnp.float32),
+            jnp.asarray(np.sin(angles) * scale, jnp.float32))
+
+
+def rope_tables(cfg, max_position: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The (cos, sin) tables of a ``ModelConfig``: over the rotary part of a
+    head (``qk_rope_head_dim`` where the head has a part that is not
+    rotated), YaRN's frequencies where ``rope_factor`` > 1, the tables
+    scaled by ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)``."""
+    dim = cfg.qk_rope_head_dim or cfg.head_dim
+    if cfg.rope_factor <= 1.0:
+        return rope_frequencies(dim, max_position, cfg.rope_theta)
+    return rope_frequencies(
+        dim, max_position, cfg.rope_theta,
+        yarn_inv_freq(dim, cfg.rope_theta, cfg.rope_factor,
+                      cfg.rope_original_max, cfg.rope_beta_fast,
+                      cfg.rope_beta_slow),
+        yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
+        / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
+
+
+def attention_scale(cfg) -> float:
+    """The softmax scale of a ``ModelConfig``: ``head_dim^-1/2``, times
+    YaRN's ``mscale(factor, mscale_all_dim)^2`` where the context is
+    stretched and ``mscale_all_dim`` is set."""
+    scale = cfg.head_dim ** -0.5
+    if cfg.rope_factor > 1.0 and cfg.rope_mscale_all_dim:
+        scale *= yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim) ** 2
+    return scale
 
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,

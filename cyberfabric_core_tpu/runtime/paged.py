@@ -8,6 +8,15 @@ are stored merged, head-major, which is the block the paged kernels read — on 
 tiled TPU layout merging them in front of the kernel is a copy of the pool),
 indexed by the native radix prefix cache (runtime/native.py — C++ fabric_host).
 
+**The layout is the configuration's.** A model of latent attention
+(``ModelConfig.is_latent``: kimi_k2) caches one compressed row and one shared
+rotary key a token, so its pool is ONE array ``latent_pool`` [L, num_pages,
+page_size, latent_lanes] (``latent_width`` numbers in whole lane tiles) with
+no kv-head axis and no V pool; ``pools``,
+``cache_operands``/``adopt``, the preemption movers and ``stats()`` follow
+``_pool_names``. The allocator, the radix tree, the refcounts and the
+admission protocol count pages, whatever a page holds.
+
 One manager for both kinds of cache. A model with recurrent state
 (``ModelConfig.has_state``: falcon_h1) also gets a state slab here —
 ``{"ssm": [L, rows, H, P, N], "conv": [L, rows, K-1, C]}`` f32 — whose first
@@ -94,13 +103,25 @@ class PrefixKVPool:
         #: bookkeeping (allocator, radix tree, refcounts, page ids) is
         #: byte-count-agnostic and identical to the single-device pool.
         self.sharding = sharding
-        L, H, D = model_config.num_layers, model_config.num_kv_heads, model_config.head_dim
-        shape = (L, num_pages, page_size, H * D)
-        self.k_pool = jnp.zeros(shape, dtype)
-        self.v_pool = jnp.zeros(shape, dtype)
-        if sharding is not None:
-            self.k_pool = jax.device_put(self.k_pool, sharding)
-            self.v_pool = jax.device_put(self.v_pool, sharding)
+        L = model_config.num_layers
+        if model_config.is_latent:
+            if sharding is not None:
+                raise ValueError(
+                    f"{model_config.name}: a latent page has no kv-head axis "
+                    "to shard over tp")
+            #: the pool arrays by attribute name, and a page's minor
+            #: dimensions as the movers hand them out
+            self._pool_names: tuple[str, ...] = ("latent_pool",)
+            self._page_tail: tuple[int, ...] = (model_config.latent_lanes,)
+        else:
+            self._pool_names = ("k_pool", "v_pool")
+            self._page_tail = (model_config.num_kv_heads,
+                               model_config.head_dim)
+        shape = (L, num_pages, page_size, int(np.prod(self._page_tail)))
+        for name in self._pool_names:
+            pool = jnp.zeros(shape, dtype)
+            setattr(self, name, pool if sharding is None
+                    else jax.device_put(pool, sharding))
         # page 0 is scratch (padding target); allocator hands out 1..num_pages-1
         self.allocator = BlockAllocator(num_pages - 1, force_python=force_python_native)
         self._page_offset = 1
@@ -222,20 +243,28 @@ class PrefixKVPool:
     def has_state(self) -> bool:
         return self.state is not None
 
+    @property
+    def pools(self) -> tuple:
+        """The pool arrays: K and V, or the one latent pool."""
+        return tuple(getattr(self, name) for name in self._pool_names)
+
+    def pool_bytes(self) -> int:
+        return sum(int(p.size) * p.dtype.itemsize for p in self.pools)
+
     def cache_operands(self) -> tuple:
-        """What the serving programs take and give back, donated: the two
+        """What the serving programs take and give back, donated: the
         pools, and the state slab where the model has one."""
-        if self.state is None:
-            return (self.k_pool, self.v_pool)
-        return (self.k_pool, self.v_pool, self.state)
+        return self.pools if self.state is None else (*self.pools, self.state)
 
     def adopt(self, outs: tuple) -> tuple:
         """Take a program's returned cache operands (they lead ``outs``);
         returns the rest."""
-        n = 2 if self.state is None else 3
-        self.k_pool, self.v_pool = outs[0], outs[1]
+        n = len(self._pool_names)
+        for name, pool in zip(self._pool_names, outs):
+            setattr(self, name, pool)
         if self.state is not None:
-            self.state = outs[2]
+            self.state = outs[n]
+            n += 1
         return tuple(outs[n:])
 
     def _upto_snapshot(self, pages: list[int]) -> list[int]:
@@ -385,14 +414,15 @@ class PrefixKVPool:
         requests — SURVEY §5 checkpoint/resume; the serving analogue of the
         reference's suspend path). One gather per pool; the transfer is the
         chain's actual bytes, not the window. Returns [L, n, page, Hkv, D]
-        each (the PD wire format; a host reshape is a view), and where the
-        model has recurrent state a third entry: slot ``state_row``'s row
+        each (the PD wire format; a host reshape is a view; a latent pool:
+        its one array as [L, n, page, latent_lanes]), and where the
+        model has recurrent state one more entry: slot ``state_row``'s row
         of the slab, so that the request resumes exactly."""
         idx = jnp.asarray(chain, jnp.int32)
-        out = (self.k_pool.shape[0], len(chain), self.page_size,
-               self.cfg.num_kv_heads, self.cfg.head_dim)
-        host_kv = (np.asarray(self.k_pool[:, idx]).reshape(out),
-                   np.asarray(self.v_pool[:, idx]).reshape(out))
+        out = (self.cfg.num_layers, len(chain), self.page_size,
+               *self._page_tail)
+        host_kv = tuple(np.asarray(pool[:, idx]).reshape(out)
+                        for pool in self.pools)
         if self.state is not None:
             if state_row is None:
                 raise ValueError("a model with recurrent state is saved with "
@@ -411,24 +441,25 @@ class PrefixKVPool:
         if n == 0:  # a prefill-phase preempt before any chunk landed
             return []
         ids = self._alloc(n)
+        n_pools = len(self._pool_names)
         if self.state is not None:
-            if state_row is None or len(host_kv) < 3:
+            if state_row is None or len(host_kv) <= n_pools:
                 self.allocator.free([p - self._page_offset for p in ids])
                 raise ValueError("a model with recurrent state is restored "
                                  "into a slot's state row, from a save that "
                                  "carries one")
             self.state = state_set_row(
                 self.state, state_row,
-                {k: jnp.asarray(v) for k, v in host_kv[2].items()})
+                {k: jnp.asarray(v) for k, v in host_kv[n_pools].items()})
             self.state_restores += 1
             bump_counter("llm_state_restores_total")
         self.ref_pages(ids)
         idx = jnp.asarray(ids, jnp.int32)
         merged = (*host_kv[0].shape[:3], -1)
-        self.k_pool = self.k_pool.at[:, idx].set(
-            jnp.asarray(host_kv[0].reshape(merged), self.k_pool.dtype))
-        self.v_pool = self.v_pool.at[:, idx].set(
-            jnp.asarray(host_kv[1].reshape(merged), self.v_pool.dtype))
+        for name, saved in zip(self._pool_names, host_kv):
+            pool = getattr(self, name)
+            setattr(self, name, pool.at[:, idx].set(
+                jnp.asarray(saved.reshape(merged), pool.dtype)))
         return ids
 
     # ------------------------------------------------------------ PD handoff
@@ -463,6 +494,10 @@ class PrefixKVPool:
         return self.restore_chain_from_host(host_kv)
 
     def _refuse_pd(self) -> None:
+        if self.cfg.is_latent:
+            raise ValueError(
+                f"{self.cfg.name}: the PD page export is K and V pages with a "
+                "kv-head axis; a latent page has neither")
         if self.state is not None:
             raise ValueError(
                 f"{self.cfg.name}: the PD page export carries no recurrent "
@@ -487,6 +522,12 @@ class PrefixKVPool:
             "lookups": self.prefix_lookups,
             "hits": self.prefix_hits,
             "native": self.tree.native,
+            # what a page holds: the layout is the configuration's
+            "page_layout": ("latent" if self.cfg.is_latent else "kv"),
+            "page_shape": [self.page_size, *self._page_tail],
+            "cache_bytes_per_token": self.cfg.cache_bytes_per_token(
+                jnp.dtype(self.dtype).itemsize),
+            "pool_bytes": self.pool_bytes(),
             **self.state_stats(),
         }
 

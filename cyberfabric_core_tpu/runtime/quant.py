@@ -26,6 +26,11 @@ import jax.numpy as jnp
 #: layers-tree leaves that are matmul weights (contraction on axis -2)
 _MATMUL_LEAVES = {"wq", "wk", "wv", "wo", "gate", "up", "down",
                   "moe_gate", "moe_up", "moe_down",
+                  # kimi_k2: latent attention's projections and the shared
+                  # expert; its norms stay as they are, its router and the
+                  # router's selection bias float32
+                  "wq_a", "wq_b", "wkv_a", "wkv_b",
+                  "shared_gate", "shared_up", "shared_down",
                   # falcon_h1's mixer: W_in and W_out like any matrix; its
                   # conv, A_log, D, dt_bias and norm weights stay f32
                   "ssm_in", "ssm_out"}
@@ -65,13 +70,14 @@ def quantize_llama_params(params: dict[str, Any], bits: int = 8) -> dict[str, An
     out["embed"] = _quantize_embed(params["embed"])
     if "lm_head" in params:
         out["lm_head"] = quantize_weight(params["lm_head"], bits)
-    layers = {}
-    for name, w in params["layers"].items():
-        if name in _MATMUL_LEAVES:
-            layers[name] = quantize_weight(w, bits)
-        else:
-            layers[name] = w  # norms, router (tiny + precision-sensitive)
-    out["layers"] = layers
+    # "layers", and where the stack is not one repeated layer (kimi_k2) the
+    # leading "dense" stack beside it
+    for stack in ("dense", "layers"):
+        if stack in params:
+            out[stack] = {
+                # norms, router (tiny + precision-sensitive) stay as they are
+                name: quantize_weight(w, bits) if name in _MATMUL_LEAVES else w
+                for name, w in params[stack].items()}
     return out
 
 
@@ -109,6 +115,22 @@ def init_params_quantized(cfg, key: jax.Array, dtype=jnp.bfloat16,
     """Synthetic-weight init directly into W8/W4: peak HBM is the intN tree
     plus one layer's slice of one leaf, so a 7-8B model inits inside one
     v5e chip."""
+    from ..models import decoder_module
+
+    lay_out = getattr(decoder_module(cfg), "init_params_with", None)
+    if lay_out is not None:
+        # a tree that is not the llama family's: the model module lays it
+        # out, every matrix made here, a layer's slice at a time
+        def embed(k, shape):
+            return jax.jit(_quantize_embed)(
+                jax.random.normal(k, shape, dtype)
+                * jnp.asarray(shape[-1] ** -0.5, dtype))
+
+        return lay_out(
+            cfg, key, dtype,
+            lambda k, shape: _init_quantized_leaf(k, tuple(shape),
+                                                  jnp.dtype(dtype), bits),
+            embed)
     H, I, V, L = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size, cfg.num_layers
     Dq, Dkv = cfg.num_heads * cfg.head_dim, cfg.num_kv_heads * cfg.head_dim
     keys = iter(jax.random.split(key, 16))

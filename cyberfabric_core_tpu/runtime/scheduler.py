@@ -120,7 +120,7 @@ from ..modkit.flight_recorder import record_event
 from ..modkit.metrics import bump_counter
 from ..modkit.telemetry import (get_global_tracer, reset_log_context,
                                 set_log_context, traceparent_ids)
-from ..ops.rope import rope_frequencies
+from ..ops.rope import rope_tables
 from ..ops.sampling import (block_unmask, sample_token_per_slot,
                             split_keys_per_slot)
 from .engine import (EngineConfig, SamplingParams, SchedulerSaturated,
@@ -139,14 +139,47 @@ _ROPE_TABLE_ROWS = 32768
 LANE_ROWS = 1
 #: /metrics of a model that generates by blocks: a running row's part in one
 #: forward, those of them that were commit forwards, the blocks and tokens
-#: the host took from them, and over layers and forwards the experts that
-#: received a token beside the experts there are
+#: the host took from them
 _BLOCK_SERIES = ("llm_block_row_forwards_total",
                  "llm_block_commit_row_forwards_total",
                  "llm_blocks_committed_total",
-                 "llm_block_tokens_emitted_total",
-                 "llm_moe_experts_touched_total",
-                 "llm_moe_experts_offered_total")
+                 "llm_block_tokens_emitted_total")
+
+
+#: /metrics of a model whose forwards count their expert layers, by the names
+#: of its module's ``MOE_COUNTERS``: the assignments routed (tokens x experts a
+#: token, over layers and forwards), those that fell on experts held here
+#: (experts held over experts routed of them where routing is uniform), the
+#: held experts that received a token. Beside them the held experts offered,
+#: and both once more over the forwards of decode chunks alone: a mixed step's
+#: prompt chunk touches nearly every expert, a decode step's rows do not
+_MOE_SERIES_OF = {"assignments": "llm_moe_assignments_total",
+                  "local": "llm_moe_assignments_local_total",
+                  "touched": "llm_moe_experts_touched_total"}
+_MOE_DRAIN_SERIES = ("llm_moe_experts_offered_total",
+                     "llm_moe_decode_experts_touched_total",
+                     "llm_moe_decode_experts_offered_total")
+
+
+def _moe_series(counters: tuple) -> tuple:
+    """The /metrics series of a model whose module has these
+    ``MOE_COUNTERS`` (none for none)."""
+    return (tuple(_MOE_SERIES_OF[n] for n in counters) + _MOE_DRAIN_SERIES
+            if counters else ())
+
+
+def _append_counts(toks: jnp.ndarray, counts: jnp.ndarray) -> jnp.ndarray:
+    """A program's drained matrix (``[rows, columns]``, or ``[rows]`` of a
+    mixed step) with the model's counters as rows past its own, padded to
+    whole rows (``_take_moe_counters`` takes them off); unchanged where the
+    model counts nothing."""
+    if not counts.shape[0]:
+        return toks
+    if toks.ndim == 1:
+        return jnp.concatenate([toks, counts])
+    pad = -counts.shape[0] % toks.shape[1]
+    return jnp.concatenate(
+        [toks, jnp.pad(counts, (0, pad)).reshape(-1, toks.shape[1])])
 
 
 def _null_ctx():
@@ -511,6 +544,13 @@ class ContinuousBatchingEngine:
                        if self.model_config.is_block else 0)
         if self._block:
             self._refuse_without_block_support(config)
+        if self.model_config.is_latent:
+            self._refuse_without_latent_support(config)
+        #: counters a model's forwards hand over beside the hidden state
+        #: (``MOE_COUNTERS`` of its module: routed assignments, those on
+        #: experts held here, held experts touched); they ride the drained
+        #: token matrix as its last rows
+        self._moe_counters: tuple = getattr(self._model, "MOE_COUNTERS", ())
         self.pd_role = str(config.pd_role or "")
         if self.pd_role not in ("", "prefill", "decode"):
             raise ValueError(
@@ -623,12 +663,10 @@ class ContinuousBatchingEngine:
         # the table is a constant of every program: it covers the model's
         # published positions up to _ROPE_TABLE_ROWS (a 262144-position model
         # would carry a 134 MB literal in each) and always the served window
-        self.rope_tables = rope_frequencies(
-            self.model_config.head_dim,
+        self.rope_tables = rope_tables(
+            self.model_config,
             max(min(self.model_config.max_position, _ROPE_TABLE_ROWS),
-                config.max_seq_len),
-            self.model_config.rope_theta,
-        )
+                config.max_seq_len))
         if self.mesh is not None:
             self.rope_tables = self._dev(self.rope_tables)
         self._rng = jax.random.PRNGKey(seed)
@@ -831,7 +869,8 @@ class ContinuousBatchingEngine:
                        "llm_admission_ring_waits_total",
                        "llm_attn_pages_walked_total",
                        "llm_attn_pages_offered_total") + (
-                           _BLOCK_SERIES if self._block else ()):
+                           _BLOCK_SERIES if self._block else ()
+                       ) + _moe_series(self._moe_counters):
             bump_counter(series, n=0.0)
         #: achieved ring depth at each drain (how many chunks stayed in
         #: flight while the host emitted) → stats() depth histogram
@@ -886,6 +925,25 @@ class ContinuousBatchingEngine:
                 f"be whole blocks of {self._block}: a shared prefix ends on "
                 "a block boundary")
 
+    def _refuse_without_latent_support(self, config: EngineConfig) -> None:
+        """A model whose cache is a latent page is served on one device,
+        unified, without speculation; each mode below lacks one named
+        thing."""
+        name = self.model_config.name
+        if config.scheduler_spec_k > 0:
+            raise ValueError(
+                f"{name}: scheduler_spec_k > 0 verifies a draft span through "
+                "the K/V ragged kernel's all-rows call, and the latent "
+                "kernels have no such program")
+        if config.pd_role:
+            raise ValueError(
+                f"{name}: pd_role={config.pd_role!r} hands rows over as K "
+                "and V pages with a kv-head axis; a latent page has neither")
+        if max(1, int(config.tp)) > 1:
+            raise ValueError(
+                f"{name}: tp > 1 has no sharding for a latent page (no "
+                "kv-head axis) nor an ep axis for the experts")
+
     def _state_snapshot_rows(self) -> int:
         if not self._has_state:
             return 0
@@ -909,8 +967,9 @@ class ContinuousBatchingEngine:
         length and scatters before it attends. ``decode_chunk`` counts
         forwards. Tokens leave as ``[B, forwards * W]`` with -1 where a
         forward committed nothing; one more column carries the forwards each
-        row ran and one more row the counters (row forwards, commit row
-        forwards, experts touched), so one drain brings all three. Stops and
+        row ran, one more row the counters (row forwards, commit row
+        forwards) and the last the model's own (``_append_counts``), so one
+        drain brings them all. Stops and
         the token limit cut inside a block on the host (``_emit_block``); the
         device freezes the row at that commit."""
         cfg = self.model_config
@@ -950,18 +1009,18 @@ class ContinuousBatchingEngine:
                     jnp.where(commit[:, None], block, -1),
                     jnp.stack([jnp.sum(run), jnp.sum(commit)]))
 
-        def with_counters(toks, ran, counts, touched):
+        def with_counters(toks, ran, counts, moe):
             toks = jnp.concatenate([toks, ran[:, None]], axis=1)
-            row = jnp.zeros((1, toks.shape[1]), jnp.int32).at[0, :3].set(
-                jnp.concatenate([counts, touched[None]]).astype(jnp.int32))
-            return jnp.concatenate([toks, row], axis=0)
+            row = jnp.zeros((1, toks.shape[1]), jnp.int32).at[0, :2].set(
+                counts.astype(jnp.int32))
+            return _append_counts(jnp.concatenate([toks, row], axis=0), moe)
 
         def paged_decode_chunk(params, k_pool, v_pool, page_table, block,
                                lengths, active, finished, stop_ids,
                                limit_lens, gen_start, keys, temp, top_p,
                                top_k):
             def step(carry, _):
-                pools, blk, lens, fin, keys, ran, counts, touched = carry
+                pools, blk, lens, fin, keys, ran, counts, moe = carry
                 run = active & jnp.logical_not(fin)
                 hidden, pools, aux = model.forward_paged_decode(
                     params, cfg, blk, pools, page_table, lens, rope,
@@ -971,17 +1030,17 @@ class ContinuousBatchingEngine:
                     stop_ids, limit_lens, temp, top_p, top_k)
                 return (pools, blk, lens, fin, keys,
                         ran + run.astype(jnp.int32), counts + n,
-                        touched + aux["touched"]), emit
+                        moe + self._moe_counts(aux)), emit
 
-            zero = jnp.zeros((), jnp.int32)
-            (pools, blk, lens, fin, keys, ran, counts, touched), toks = \
+            (pools, blk, lens, fin, keys, ran, counts, moe), toks = \
                 jax.lax.scan(step, ((k_pool, v_pool), block, lengths,
                                     finished, keys, jnp.zeros_like(lengths),
-                                    jnp.zeros((2,), jnp.int32), zero),
+                                    jnp.zeros((2,), jnp.int32),
+                                    self._moe_counts(None)),
                              None, length=k_steps)
             toks = toks.transpose(1, 0, 2).reshape(block.shape[0], -1)
             lens = jnp.where(active, lens, 0)
-            return (with_counters(toks, ran, counts, touched), *pools, blk,
+            return (with_counters(toks, ran, counts, moe), *pools, blk,
                     keys, lens, fin)
 
         self._paged_decode_fn = jax.jit(paged_decode_chunk,
@@ -1008,7 +1067,7 @@ class ContinuousBatchingEngine:
             lens = jnp.where(run, lens, jnp.where(
                 final_mask, final_lens, jnp.where(active, lengths, 0)))
             return (with_counters(emit, run.astype(jnp.int32), n,
-                                  aux["touched"]),
+                                  self._moe_counts(aux)),
                     *pools, blk, keys, lens, fin, active | final_mask)
 
         self._mixed_step_fn = jax.jit(mixed_step, donate_argnums=(1, 2))
@@ -1023,16 +1082,25 @@ class ContinuousBatchingEngine:
         rope = self.rope_tables
         max_seq = self.config.max_seq_len
 
+        no_counts = self._moe_counts(None)
+
         def paged_forward(forward, params, ids, caches, *tail, **kwargs):
             """One of the model's paged forward passes over the cache
-            operands: the two pools, and the state slab where the model
-            has one. Returns (hidden, caches)."""
-            if not self._has_state:
-                return forward(params, cfg, ids, caches, *tail, **kwargs)
-            hidden, pools, state = forward(
-                params, cfg, ids, caches[:2], *tail, state=caches[2],
-                **kwargs)
-            return hidden, (*pools, state)
+            operands: the pools (K and V, or the one latent pool), and the
+            state slab where the model has one. Returns (hidden, caches,
+            the forward's ``counters``)."""
+            if self._has_state:
+                hidden, pools, state = forward(
+                    params, cfg, ids, caches[:-1], *tail, state=caches[-1],
+                    **kwargs)
+                return hidden, (*pools, state), no_counts
+            if not self._moe_counters:
+                hidden, pools = forward(params, cfg, ids, caches, *tail,
+                                        **kwargs)
+                return hidden, tuple(pools), no_counts
+            hidden, pools, aux = forward(params, cfg, ids, caches, *tail,
+                                         **kwargs)
+            return hidden, tuple(pools), self._moe_counts(aux)
 
         def decode_chunk_body(params, caches, page_table,
                               last_tokens, lengths, active, finished,
@@ -1055,9 +1123,9 @@ class ContinuousBatchingEngine:
             lookahead ring survive them."""
 
             def step(carry, j):
-                caches, toks, lens, fin, keys = carry
+                caches, toks, lens, fin, keys, counts = carry
                 run = active & jnp.logical_not(fin)
-                hidden, caches = paged_forward(
+                hidden, caches, n = paged_forward(
                     model.forward_paged_decode, params, toks[:, None],
                     caches, page_table, lens, rope,
                     write_mask=run, mesh=self._attn_mesh)
@@ -1073,27 +1141,28 @@ class ContinuousBatchingEngine:
                 return (caches, jnp.where(run, nxt, toks),
                         jnp.where(run, new_lens, lens),
                         fin | (run & (is_stop | hit)),
-                        jnp.where(run[:, None], keys2, keys)), emit
+                        jnp.where(run[:, None], keys2, keys),
+                        counts + n), emit
 
-            (caches, last, lens, fin, keys), toks = jax.lax.scan(
-                step, (caches, last_tokens, lengths, finished, keys),
+            (caches, last, lens, fin, keys, counts), toks = jax.lax.scan(
+                step, (caches, last_tokens, lengths, finished, keys,
+                       no_counts),
                 jnp.arange(k_steps, dtype=jnp.int32))
             lens = jnp.where(active, lens, 0)
-            return (toks.T, *caches, last, keys, lens, fin)
+            return (_append_counts(toks.T, counts), *caches, last, keys,
+                    lens, fin)
 
-        # the program a llama-family model gets takes the two pools, as
-        # it always has; a model with recurrent state gets the same body
-        # with the state slab as a third donated operand. One name for
-        # both: it is what the compile log and the device trace show.
-        if self._has_state:
-            def paged_decode_chunk(params, k_pool, v_pool, state, *rest):
-                return decode_chunk_body(params, (k_pool, v_pool, state),
-                                         *rest)
-        else:
-            def paged_decode_chunk(params, k_pool, v_pool, *rest):
-                return decode_chunk_body(params, (k_pool, v_pool), *rest)
+        # the cache operands lead the programs' arguments, donated: K and V
+        # for the llama family, as always; the state slab as a third where
+        # the model has recurrent state; the one latent pool where the cache
+        # is latent. One name for all: it is what the compile log and the
+        # device trace show.
+        n_cache = len(self.pool.cache_operands())
 
-        donate = (1, 2, 3) if self._has_state else (1, 2)
+        def paged_decode_chunk(params, *rest):
+            return decode_chunk_body(params, rest[:n_cache], *rest[n_cache:])
+
+        donate = tuple(range(1, 1 + n_cache))
         self._paged_decode_fn = jax.jit(paged_decode_chunk,
                                         donate_argnums=donate)
 
@@ -1121,7 +1190,7 @@ class ContinuousBatchingEngine:
             dispatch when the prefill queue drains — the mixed→pure
             transition needs no synchronous fallback round."""
             run = active & jnp.logical_not(finished)
-            last_h, caches = paged_forward(
+            last_h, caches, counts = paged_forward(
                 model.forward_paged_mixed, params, q_ids, caches,
                 page_table, prefill_hist, q_lens, rope,
                 mesh=self._attn_mesh, rows=lane_rows,
@@ -1141,16 +1210,11 @@ class ContinuousBatchingEngine:
             hit = (new_lens >= limit_lens) | (new_lens + k_steps > max_seq)
             fin_out = finished | (sample & (is_stop | hit))
             active_out = active | final_mask
-            return (toks, *caches, new_last, keys_out,
-                    new_lens, fin_out, active_out)
+            return (_append_counts(toks, counts), *caches, new_last,
+                    keys_out, new_lens, fin_out, active_out)
 
-        if self._has_state:
-            def mixed_step(params, k_pool, v_pool, state, *rest):
-                return mixed_step_body(params, (k_pool, v_pool, state),
-                                       *rest)
-        else:
-            def mixed_step(params, k_pool, v_pool, *rest):
-                return mixed_step_body(params, (k_pool, v_pool), *rest)
+        def mixed_step(params, *rest):
+            return mixed_step_body(params, rest[:n_cache], *rest[n_cache:])
 
         self._mixed_step_fn = jax.jit(mixed_step, donate_argnums=donate)
 
@@ -1915,6 +1979,12 @@ class ContinuousBatchingEngine:
             if self._has_state:
                 # state rows held beside pages held: one a slot
                 out[tenant]["state_rows"] = slots.get(tenant, 0)
+            if self.model_config.is_latent:
+                # what a page holds is the configuration's: a latent row
+                out[tenant]["page_layout"] = "latent"
+                out[tenant]["cache_bytes_per_token"] = \
+                    self.model_config.cache_bytes_per_token(
+                        jnp.dtype(self.dtype).itemsize)
         return out
 
     def state_rows_in_use(self) -> int:
@@ -1946,8 +2016,7 @@ class ContinuousBatchingEngine:
             "kv_heads_sharded": kv_sharded,
         }
         if self.pool is not None:
-            pool_bytes = 2 * int(np.prod(self.pool.k_pool.shape)) \
-                * self.pool.k_pool.dtype.itemsize
+            pool_bytes = self.pool.pool_bytes()
             info["sharded_page_bytes_per_device"] = (
                 pool_bytes // self.tp if kv_sharded else pool_bytes)
         if self.feasibility is not None:
@@ -3058,7 +3127,8 @@ class ContinuousBatchingEngine:
                       spec_tokens: int = 0,
                       kind: str = "decode",
                       positions: Optional[int] = None,
-                      block_out: Optional[tuple[int, int]] = None) -> None:
+                      block_out: Optional[tuple[int, int]] = None,
+                      local_assignments: Optional[int] = None) -> None:
         """One timing-schema owner for every round kind. ``ts`` is the
         round's wall-clock start; /v1/monitoring/rounds exports these entries
         as Chrome trace events, which need absolute timestamps.
@@ -3098,6 +3168,10 @@ class ContinuousBatchingEngine:
             **({"forwards": 1 if kind != "decode" or mixed
                 else self._k_steps, "blocks_committed": block_out[0],
                 "tokens_emitted": block_out[1]} if block_out else {}),
+            # a chip's share of the experts: the routed assignments of the
+            # round's dispatch that fell on experts held here
+            **({"local_assignments": local_assignments}
+               if local_assignments is not None else {}),
         })
 
     def _count_attn_pages(self, kept: np.ndarray, grew: np.ndarray) -> None:
@@ -3124,19 +3198,51 @@ class ContinuousBatchingEngine:
         bump_counter("llm_attn_pages_offered_total",
                      n=lengths.size * slots * layers)
 
-    def _take_block_counters(self, drained: np.ndarray, forwards: int
+    def _take_block_counters(self, drained: np.ndarray
                              ) -> tuple[np.ndarray, np.ndarray]:
         """A block model's drained matrix carries one more row, the
         program's counters, and one more column, the forwards each row ran.
         Bump the counters; hand back (tokens, forwards a row)."""
-        row_forwards, commits, touched = (int(v) for v in drained[-1, :3])
+        row_forwards, commits = (int(v) for v in drained[-1, :2])
         bump_counter("llm_block_row_forwards_total", n=row_forwards)
         bump_counter("llm_block_commit_row_forwards_total", n=commits)
-        bump_counter("llm_moe_experts_touched_total", n=touched)
-        bump_counter("llm_moe_experts_offered_total",
-                     n=forwards * self.model_config.num_layers
-                     * self.model_config.num_experts)
         return drained[:-1, :-1], drained[:-1, -1]
+
+    def _moe_counts(self, aux: Optional[dict]) -> jnp.ndarray:
+        """Inside a program: the counters of one forward's ``aux`` in
+        ``_moe_counters``' order (zeros for None, where a sum starts)."""
+        if aux is None:
+            return jnp.zeros((len(self._moe_counters),), jnp.int32)
+        return jnp.stack([aux[n] for n in self._moe_counters]
+                         ).astype(jnp.int32)
+
+    def _take_moe_counters(self, drained: np.ndarray, forwards: int,
+                           decode: bool = False
+                           ) -> tuple[np.ndarray, Optional[int]]:
+        """Where the model's forwards count their expert layers
+        (``_moe_counters``), the drained matrix carries the counters as its
+        last rows (``_append_counts``). Bump them, with the experts HELD that
+        the ``forwards`` offered, and for a ``decode`` chunk's drain the
+        decode-only pair too; hand back (the matrix without them, the
+        assignments that fell on held experts or None where the model does
+        not count them)."""
+        names = self._moe_counters
+        if not names:
+            return drained, None
+        rows = len(names) if drained.ndim == 1 else \
+            -(-len(names) // drained.shape[1])
+        counts = dict(zip(names,
+                          (int(v) for v in drained[-rows:].reshape(-1))))
+        for name, n_counted in counts.items():
+            bump_counter(_MOE_SERIES_OF[name], n=n_counted)
+        offered = (forwards * self.model_config.num_moe_layers
+                   * self.model_config.experts_local)
+        bump_counter("llm_moe_experts_offered_total", n=offered)
+        if decode:
+            bump_counter("llm_moe_decode_experts_touched_total",
+                         n=counts["touched"])
+            bump_counter("llm_moe_decode_experts_offered_total", n=offered)
+        return drained[:-rows], counts.get("local")
 
     def _emit_block(self, slot: int, toks: np.ndarray, start: int) -> int:
         """Emit the block a row committed at ``start .. start + W - 1``:
@@ -3713,8 +3819,9 @@ class ContinuousBatchingEngine:
         # plus the accept-count column (one drain carries both); plain mixed
         # returns [n] — normalize to 2-D so one emit loop serves both
         ran = None
+        toks, local = self._take_moe_counters(toks, forwards=1)
         if self._block:
-            toks2d, ran = self._take_block_counters(toks, forwards=1)
+            toks2d, ran = self._take_block_counters(toks)
             accepts = None
         elif toks.ndim == 2:
             toks2d, accepts = toks[:, :-1], toks[:, -1]
@@ -3834,7 +3941,7 @@ class ContinuousBatchingEngine:
                                            for _, _, dr in spec_plan),
                            kind=("mixed" if decode_rows else "prefill")
                            if plan else "decode", positions=positions,
-                           block_out=block_out)
+                           block_out=block_out, local_assignments=local)
         return True
 
     def _decode_round(self) -> None:
@@ -3886,8 +3993,11 @@ class ContinuousBatchingEngine:
         self.readback_wait_samples.append((t3 - t2) * 1000.0)
         self._depth_hist[ring_depth] = self._depth_hist.get(ring_depth, 0) + 1
         block_out = None
+        chunk, local = self._take_moe_counters(chunk, self._k_steps,
+                                               decode=True)
+        round_attrs = None if local is None else {"local_assignments": local}
         if self._block:
-            chunk, ran = self._take_block_counters(chunk, self._k_steps)
+            chunk, ran = self._take_block_counters(chunk)
             committed = chunk[:, ::self._block] >= 0
             commits = committed.sum(axis=1)
             old_lengths = self._commit_chunk(inflight, commits)
@@ -3897,14 +4007,16 @@ class ContinuousBatchingEngine:
                 row_tokens={s: int(c) * self._block
                             for s, c in enumerate(commits)},
                 row_attrs={s: {"blocks": int(c), "row_forwards": int(ran[s])}
-                           for s, c in enumerate(commits)})
+                           for s, c in enumerate(commits)},
+                round_attrs=round_attrs)
             block_out = self._emit_block_chunk(chunk, ran, old_lengths,
                                                depth=ring_depth)
         else:
             old_lengths = self._commit_chunk(inflight)
             self._count_attn_pages(old_lengths, chunk >= 0)
-            self._emit_decode_spans(wall0, (t3 - t0) * 1000.0,
-                                    used_lookahead, depth=ring_depth)
+            self._emit_decode_spans(
+                wall0, (t3 - t0) * 1000.0, used_lookahead, depth=ring_depth,
+                round_attrs=round_attrs)
             self._emit_chunk(chunk, old_lengths, depth=ring_depth)
         t4 = time.monotonic()
         # a host-fallback stop just changed the world — the ring suffix is
@@ -3914,14 +4026,16 @@ class ContinuousBatchingEngine:
             self._discard_ring()
         self._record_round((t2 - t0) * 1000.0, (t3 - t2) * 1000.0,
                            (t4 - t3) * 1000.0, used_lookahead, ts=wall0,
-                           depth=ring_depth, block_out=block_out)
+                           depth=ring_depth, block_out=block_out,
+                           local_assignments=local)
 
     def _emit_decode_spans(self, wall0: float, dur_ms: float,
                            lookahead: bool, rows: Optional[list[int]] = None,
                            tokens: Optional[int] = None,
                            depth: int = 0,
                            row_tokens: Optional[dict] = None,
-                           row_attrs: Optional[dict] = None) -> None:
+                           row_attrs: Optional[dict] = None,
+                           round_attrs: Optional[dict] = None) -> None:
         """llm.decode_chunk spans for SAMPLED in-flight requests — called
         before the emit loop (a mid-chunk finish clears the slot state). The
         guard is one bool attribute per slot: an unsampled or traceless
@@ -3931,14 +4045,16 @@ class ContinuousBatchingEngine:
         the ring depth still in flight at this round's drain. Speculative
         rounds pass ``row_tokens`` (per-slot variable advance) and
         ``row_attrs`` (spec_proposed/spec_accepted stamps — the depth-style
-        acceptance evidence on each span)."""
+        acceptance evidence on each span); ``round_attrs`` stamps what the
+        round counted as a whole (``local_assignments``) on every span."""
         k = tokens if tokens is not None else self._k_steps
         start_ns = int(wall0 * 1e9)
         for slot in (rows if rows is not None else range(self.n_slots)):
             state = self.slots[slot]
             if state is None or not state.trace_sampled or not self.active[slot]:
                 continue
-            extra = row_attrs.get(slot, {}) if row_attrs else {}
+            extra = {**(round_attrs or {}),
+                     **(row_attrs.get(slot, {}) if row_attrs else {})}
             get_global_tracer().emit_span(
                 "llm.decode_chunk", traceparent=state.trace,
                 start_unix_ns=start_ns, duration_ms=dur_ms,

@@ -518,6 +518,7 @@ def test_scheduler_programs_compile_for_v5e_at_sdar_moe():
     eng.model_config, eng.dtype = cfg, jnp.bfloat16
     eng._model, eng._has_state = decoder_module(cfg), False
     eng._block = cfg.block_length
+    eng._moe_counters = eng._model.MOE_COUNTERS
     eng.spec_k, eng._spec_w = 0, 1
     eng.mesh = eng._attn_mesh = None
     eng.rope_tables = rope_frequencies(cfg.head_dim, max_seq, cfg.rope_theta)
@@ -575,5 +576,130 @@ def test_scheduler_programs_compile_for_v5e_at_sdar_moe():
         assert "tpu_custom_call" in text, name
         assert mem.alias_size_in_bytes >= 2 * int(np.prod(pool.shape)) * 2, name
         sliced = re.search(r"s8\[(1,)?128,(2048,768|768,2048)\]", text)
+        assert not sliced, f"{name}: a layer of an expert stack, {sliced[0]}"
+        assert live <= V5E_HBM_BYTES, (name, live)
+
+
+def test_latent_kernels_compile_at_kimi_k2_shapes(one_chip):
+    """``mla_decode_attention`` over the work list and ``mla_ragged_attention``
+    at a chunk of 512, at kimi-k2.5's 64 heads on a latent page of 512 + 64
+    lanes in 640, 64 slots of 48
+    pages."""
+    from cyberfabric_core_tpu.ops.mla_attention import (latent_work_list,
+                                                        mla_decode_attention,
+                                                        mla_ragged_attention)
+
+    batch, pmax, heads, width, rank = 64, 48, 64, 640, 512
+    pool = one_chip((2, batch * pmax + 1, _PAGE, width), jnp.bfloat16)
+    _compiles_with_mosaic(
+        lambda q, p, pt, n, layer: mla_decode_attention(
+            q, p, latent_work_list(pt, n, _PAGE), layer, rank=rank,
+            scale=0.1447, interpret=False),
+        one_chip((batch, heads, width), jnp.bfloat16), pool,
+        one_chip((batch, pmax), jnp.int32), one_chip((batch,), jnp.int32),
+        one_chip((), jnp.int32))
+    lane = one_chip((1,), jnp.int32)
+    _compiles_with_mosaic(
+        lambda q, p, pt, h, n, layer: mla_ragged_attention(
+            q, p, pt, h, n, layer, rank=rank, scale=0.1447, interpret=False),
+        one_chip((1, heads, 512, width), jnp.bfloat16), pool,
+        one_chip((1, pmax), jnp.int32), lane, lane, one_chip((), jnp.int32))
+
+
+@pytest.mark.slow
+def test_scheduler_programs_compile_for_v5e_at_kimi_k2():
+    """The scheduler's own ``paged_decode_chunk`` and ``mixed_step`` for
+    kimi-k2.5-share32-15l int8 at the benchmark cell's shape (64 slots of
+    3072, 3073 latent pages, 8 steps a chunk), on one described chip: each
+    holds both latent kernels' and the ``grouped_matmul`` Mosaic calls, takes
+    ONE pool operand and aliases it, fits the 15.75 GiB the compiler budgets,
+    and copies no layer of the expert stacks. A compile, not a chip run."""
+    from jax.sharding import SingleDeviceSharding
+
+    from cyberfabric_core_tpu.models import decoder_module, get_config
+    from cyberfabric_core_tpu.ops.platform import compiled_kernels
+    from cyberfabric_core_tpu.ops.rope import rope_tables
+    from cyberfabric_core_tpu.parallel.feasibility import V5E_HBM_BYTES
+    from cyberfabric_core_tpu.parallel.sharding import abstract_params
+    from cyberfabric_core_tpu.runtime.engine import EngineConfig
+    from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
+
+    topo = _topo_or_skip()
+    n, max_seq, pages = 64, 3072, 3073
+    cfg = get_config("kimi-k2.5-share32-15l")
+    eng = object.__new__(ContinuousBatchingEngine)
+    eng.config = EngineConfig(
+        model=cfg.name, max_seq_len=max_seq, max_batch=n, decode_chunk=8,
+        quantization="int8", prefix_cache_pages=pages, prefix_page_size=_PAGE)
+    eng.model_config, eng.dtype = cfg, jnp.bfloat16
+    eng._model, eng._has_state, eng._block = decoder_module(cfg), False, 0
+    eng._moe_counters = eng._model.MOE_COUNTERS
+    eng.spec_k, eng._spec_w = 0, 1
+    eng.mesh = eng._attn_mesh = None
+    eng.rope_tables = rope_tables(cfg, max_seq)
+    here = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=here)
+
+    pool = sds((cfg.num_layers, pages, _PAGE, cfg.latent_lanes), jnp.bfloat16)
+
+    class _Pool:        # what _build_programs asks the page pool
+        @staticmethod
+        def cache_operands():
+            return (pool,)
+
+    eng.pool = _Pool()
+    params = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                          abstract_params(cfg, jnp.bfloat16, "int8"))
+    assert params["layers"]["router"].dtype == jnp.float32
+    assert params["layers"]["moe_gate"]["q"].shape == (14, 12, 7168, 2048)
+    with compiled_kernels():
+        eng._build_programs()
+
+    def row(dtype):
+        return sds((n,), dtype)
+
+    i32, f32 = jnp.int32, jnp.float32
+    table, keys = sds((n, max_seq // _PAGE), i32), sds((n, 2), jnp.uint32)
+    stops = sds((n, eng.config.device_stop_width), i32)
+    sampling = (row(f32), row(f32), row(i32))
+
+    def mixed(width):
+        return (eng._mixed_step_fn, (
+            params, pool, table, *_lane_operands(sds, width),
+            row(i32), row(i32), row(bool), row(bool), row(bool), row(bool),
+            row(i32), stops, row(i32), keys, *sampling))
+
+    programs = {
+        "paged_decode_chunk": (eng._paged_decode_fn, (
+            params, pool, table, row(i32), row(i32), row(bool), row(bool),
+            stops, row(i32), keys, *sampling)),
+        "mixed_step@64": mixed(64), "mixed_step@512": mixed(512),
+    }
+    import re
+
+    for name, (fn, args) in programs.items():
+        with compiled_kernels():
+            compiled = fn.lower(*args).compile()
+        mem = compiled.memory_analysis()
+        live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+        print(f"{name}: argument {mem.argument_size_in_bytes / 1e9:.2f} output "
+              f"{mem.output_size_in_bytes / 1e9:.2f} aliased "
+              f"{mem.alias_size_in_bytes / 1e9:.2f} temp "
+              f"{mem.temp_size_in_bytes / 1e9:.2f} GB")
+        text = compiled.as_text()
+        if os.environ.get("AOT_DUMP_DIR"):
+            Path(os.environ["AOT_DUMP_DIR"], f"{name}.hlo.txt").write_text(text)
+        kernels = ["grouped_matmul", "mla_decode_attention"]
+        if name != "paged_decode_chunk":
+            kernels.append("mla_ragged_attention")
+        for kernel in kernels:
+            assert kernel in text, (name, kernel)
+        assert "paged_decode_attention" not in text.replace(
+            "mla_decode_attention", ""), name
+        assert mem.alias_size_in_bytes >= int(np.prod(pool.shape)) * 2, name
+        sliced = re.search(r"s8\[(1,)?12,(7168,2048|2048,7168)\]", text)
         assert not sliced, f"{name}: a layer of an expert stack, {sliced[0]}"
         assert live <= V5E_HBM_BYTES, (name, live)

@@ -21,8 +21,8 @@ from benchmark import sdar_reference, sdar_weights
 from cyberfabric_core_tpu.models import get_config
 from cyberfabric_core_tpu.modkit.metrics import default_registry
 from cyberfabric_core_tpu.runtime import EngineConfig, SamplingParams
-from cyberfabric_core_tpu.runtime.scheduler import (_BLOCK_SERIES,
-                                                    ContinuousBatchingEngine)
+from cyberfabric_core_tpu.runtime.scheduler import (
+    _BLOCK_SERIES, ContinuousBatchingEngine, _moe_series)
 
 CFG = get_config("tiny-sdar")
 W = CFG.block_length
@@ -232,17 +232,21 @@ def test_the_counters_of_a_block_model():
     the round records and a request's flight record carry the same."""
     from cyberfabric_core_tpu.modkit.flight_recorder import default_recorder
 
-    before = {s: _counter(s) for s in _BLOCK_SERIES}
+    series = _BLOCK_SERIES + _moe_series(("touched",))
+    before = {s: _counter(s) for s in series}
     col, sched = _run(_cfg(decode_lookahead=0), [_prompt(9, 18)],
                       max_tokens=16)
     d = {s.removeprefix("llm_").removesuffix("_total"):
-         _counter(s) - before[s] for s in _BLOCK_SERIES}
+         _counter(s) - before[s] for s in series}
     assert d["block_tokens_emitted"] == 16
     assert d["blocks_committed"] == d["block_commit_row_forwards"] == 5
     # 2 leftover tokens: 2 denoise forwards, then 4 a block; one commit each
     assert d["block_row_forwards"] == 2 + 4 * 4 + 5
     assert 0 < d["moe_experts_touched"] <= d["moe_experts_offered"]
     assert d["moe_experts_offered"] % (CFG.num_layers * CFG.num_experts) == 0
+    # the one mechanism: the decode chunks' share of both, counted apart
+    assert 0 < d["moe_decode_experts_touched"] <= d["moe_experts_touched"]
+    assert 0 < d["moe_decode_experts_offered"] < d["moe_experts_offered"]
     rounds = [r for r in sched.round_timings if "forwards" in r]
     assert sum(r["blocks_committed"] for r in rounds) == 5
     assert sum(r["tokens_emitted"] for r in rounds) == 16
