@@ -405,6 +405,14 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                 ("llm_admission_ring_waits_total",
                  "Scheduler passes in which a request could have been "
                  "admitted or resumed and waited for the chunks in flight"),
+                ("llm_control_rows_uploads_total",
+                 "Dispatches that found the host-owned rows (a slot's "
+                 "sampling and termination rows, the active mask) changed "
+                 "and uploaded them whole"),
+                ("llm_loose_row_programs_total",
+                 "Programs dispatched beside the step programs to write a "
+                 "slot's device rows (restore_row at a resume or a handoff "
+                 "import; none in steady serving)"),
                 ("llm_attn_pages_walked_total",
                  "Grid programs the paged decode kernel launched: one for "
                  "every page that holds tokens a row's query reads, summed "

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def _warp_sorted(
@@ -106,6 +107,41 @@ def split_keys_per_slot(keys: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     """[B, 2] keys → (advanced keys [B, 2], subkeys [B, 2]), vmapped split."""
     both = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
     return both[:, 0], both[:, 1]
+
+
+_M32 = 0xFFFFFFFF
+_THREEFRY_ROTATIONS = ((13, 15, 26, 6), (17, 29, 16, 24))
+
+
+def _threefry2x32(k0: int, k1: int, x0: int, x1: int) -> tuple[int, int]:
+    """Threefry-2x32 (20 rounds) on Python ints: what ``jax.random`` runs."""
+    ks = (k0, k1, k0 ^ k1 ^ 0x1BD11BDA)
+    x0, x1 = (x0 + ks[0]) & _M32, (x1 + ks[1]) & _M32
+    for i in range(5):
+        for r in _THREEFRY_ROTATIONS[i % 2]:
+            x0 = (x0 + x1) & _M32
+            x1 = ((x1 << r) | (x1 >> (32 - r))) & _M32
+            x1 ^= x0
+        x0 = (x0 + ks[(i + 1) % 3]) & _M32
+        x1 = (x1 + ks[(i + 2) % 3] + i + 1) & _M32
+    return x0, x1
+
+
+def host_key(seed: int) -> np.ndarray:
+    """``jax.random.PRNGKey(seed)``'s key data made on the host, with no
+    program: the seed's low 32 bits under a zero word (32-bit mode folds
+    the seed to int32 first; 64-bit mode keeps the high word)."""
+    high = (seed >> 32) & _M32 if jax.config.jax_enable_x64 else 0
+    return np.array([high, seed & _M32], np.uint32)
+
+
+def host_split(key: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """``jax.random.split(key)`` on the host (the partitionable threefry
+    split: output ``i`` is the block cipher of the counter ``(0, i)``), so a
+    scheduler hands out key streams without dispatching a program."""
+    k0, k1 = int(key[0]), int(key[1])
+    return tuple(np.array(_threefry2x32(k0, k1, 0, i), np.uint32)
+                 for i in range(2))
 
 
 def warped_probs(

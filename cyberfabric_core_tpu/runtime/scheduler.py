@@ -59,10 +59,12 @@ alternating.
   decode chunks chain directly off the mixed dispatch's outputs (the flip
   state — active mask, first tokens, lengths — is computed on device), so
   mixed→pure-decode needs no synchronous fallback round.
-- Device-resident sampling state: temp/top_p/top_k/lengths/active/finished/
-  stop-ids/limits live on device and only CHANGED rows are patched at
-  admission/finish/preempt/resume; the page table patches changed rows
-  instead of re-uploading.
+- A slot's rows, by who writes them: what the programs only read (the page
+  table, temp/top_p/top_k, stop ids, limits, the active mask) the HOST owns
+  as numpy arrays and uploads whole, when changed, with the next dispatch;
+  what the device advances (last token, key, length, finished) only the
+  step programs write (a flipping row inside ``mixed_step``). No program
+  is dispatched for a row at an arrival, a flip or a finish.
 - Tenant isolation: the pending queue is PER-TENANT FIFO deques drained by
   token-weighted fair scheduling (``TenantFairQueue`` — a VTC-style virtual
   counter per tenant, charged with the prefill + decode tokens actually
@@ -82,8 +84,8 @@ alternating.
   row deactivation, suspended drop), and a per-round expiry sweep lapses
   requests whose ``deadline`` passed (``deadline_exceeded``; a queued
   request whose remaining budget cannot cover its estimated prefill is
-  never admitted). A mid-decode cancel freezes the row (device rows
-  deactivated, page-table row zeroed so later dispatches park its KV writes
+  never admitted). A mid-decode cancel freezes the row (off the active
+  mask, page-table row zeroed so later dispatches park its KV writes
   on scratch) WITHOUT bumping the epoch — the lookahead ring drains through
   the cancel instead of discarding, so surviving streams lose nothing.
 
@@ -121,8 +123,8 @@ from ..modkit.metrics import bump_counter
 from ..modkit.telemetry import (get_global_tracer, reset_log_context,
                                 set_log_context, traceparent_ids)
 from ..ops.rope import rope_tables
-from ..ops.sampling import (block_unmask, sample_token_per_slot,
-                            split_keys_per_slot)
+from ..ops.sampling import (block_unmask, host_key, host_split,
+                            sample_token_per_slot, split_keys_per_slot)
 from .engine import (EngineConfig, SamplingParams, SchedulerSaturated,
                      StepEvent, TenantQuotaExceeded, TenantSaturated)
 from .speculative import NgramProposer, greedy_accept_counts
@@ -180,6 +182,46 @@ def _append_counts(toks: jnp.ndarray, counts: jnp.ndarray) -> jnp.ndarray:
     pad = -counts.shape[0] % toks.shape[1]
     return jnp.concatenate(
         [toks, jnp.pad(counts, (0, pad)).reshape(-1, toks.shape[1])])
+
+
+#: the rows the host owns and the programs only read, one int32 block
+#: ``[B, pmax + _CTL + stop_width]`` uploaded whole when it changed: the page
+#: table, then top_k, the limit length, the first generated position (a
+#: block model), temperature and top_p (float32 bits), then the stop ids
+_CTL = 5
+#: what a mixed step's dispatch carries by slot, ahead of the lane's spans
+#: in ONE flat int32 upload: active, sample, final_mask, final_lens, the
+#: final chunk's key (2 words) and, for a block model, its opened block
+_LANE_COLS = 6
+
+
+def _unpack_rows(rows: jnp.ndarray, pmax: int) -> tuple:
+    """Inside a program: (page_table, top_k, limit_lens, gen_start, temp,
+    top_p, stop_ids) of the host-owned block."""
+    ctl = rows[:, pmax:]
+    warp = jax.lax.bitcast_convert_type(ctl[:, 3:_CTL], jnp.float32)
+    return (rows[:, :pmax], ctl[:, 0], ctl[:, 1], ctl[:, 2], warp[:, 0],
+            warp[:, 1], ctl[:, _CTL:])
+
+
+def _unpack_lane(lane: jnp.ndarray, n_slots: int, block: int,
+                 n_spans: int) -> tuple:
+    """Inside a mixed step, of its flat upload: by slot (active,
+    sample_mask, final_mask, final_lens, final keys [B, 2], opened blocks
+    [B, W]), then by span (q_ids [R, Qc], q_lens, hist, and the lane's slot
+    or, in the all-rows step, the slot's draft length)."""
+    cols = _LANE_COLS + block
+    slot = lane[: n_slots * cols].reshape(n_slots, cols)
+    span = lane[n_slots * cols:].reshape(n_spans, -1)
+    return (slot[:, 0] > 0, slot[:, 1] > 0, slot[:, 2] > 0, slot[:, 3],
+            jax.lax.bitcast_convert_type(slot[:, 4:_LANE_COLS], jnp.uint32),
+            slot[:, _LANE_COLS:], span[:, :-3], span[:, -3], span[:, -2],
+            span[:, -1])
+
+
+def lane_words(n_slots: int, block: int, n_spans: int, width: int) -> int:
+    """The int32 words of a mixed step's flat upload at a lane width."""
+    return n_slots * (_LANE_COLS + block) + n_spans * (width + 3)
 
 
 def _null_ctx():
@@ -669,42 +711,46 @@ class ContinuousBatchingEngine:
                 config.max_seq_len))
         if self.mesh is not None:
             self.rope_tables = self._dev(self.rope_tables)
-        self._rng = jax.random.PRNGKey(seed)
+        self._rng = host_key(seed)
 
         # host-side slot state (mirrors of the device-resident rows)
         self.slots: list[Optional[_SlotState]] = [None] * self.n_slots
         self.lengths = np.zeros(self.n_slots, np.int32)
         self.active = np.zeros(self.n_slots, bool)
 
-        # what a running row feeds its next forward: its last token, or
-        # (a model that generates by blocks) its open block
+        # what the device ADVANCES, a row a slot: its last token (a model
+        # that generates by blocks: its open block), key stream, length and
+        # finished mark. Only the step programs write them (a flipping row
+        # inside mixed_step), and restore_row at a resume
         self._last_tokens = self._dev(
             jnp.full((self.n_slots, self._block),
                      self.model_config.mask_token_id, jnp.int32)
             if self._block else jnp.zeros((self.n_slots,), jnp.int32))
-
-        # device-resident per-slot sampling/termination state: patched
-        # row-wise at admission/finish/preempt/resume,
-        # never re-uploaded per round. The stop-id rows (-1 padded to
-        # device_stop_width) + limit lengths let the decode program freeze
-        # finished rows on-device; _dev_term marks slots whose FULL stop set
-        # fits the device rows (others fall back to host stop detection).
-        # Mesh mode commits every row EXPLICITLY replicated (_dev): control
-        # state is host bookkeeping every device must agree on, and row
-        # patches (.at[].set) propagate the replication forward.
-        self._stop_width = max(1, config.device_stop_width)
-        self._temp_dev = self._dev(jnp.zeros((self.n_slots,), jnp.float32))
-        self._top_p_dev = self._dev(jnp.ones((self.n_slots,), jnp.float32))
-        self._top_k_dev = self._dev(jnp.zeros((self.n_slots,), jnp.int32))
         self._lengths_dev = self._dev(jnp.zeros((self.n_slots,), jnp.int32))
-        self._active_dev = self._dev(jnp.zeros((self.n_slots,), bool))
         self._finished_dev = self._dev(jnp.zeros((self.n_slots,), bool))
-        self._stops_dev = self._dev(jnp.full(
-            (self.n_slots, self._stop_width), -1, jnp.int32))
-        self._limit_dev = self._dev(jnp.zeros((self.n_slots,), jnp.int32))
-        #: block models: the first generated position (the prompt's length);
-        #: a stop id among the prompt's leftover in the first block is none
-        self._gen_start_dev = self._dev(jnp.zeros((self.n_slots,), jnp.int32))
+        self._slot_keys = self._dev(jax.random.split(
+            jax.random.PRNGKey(seed ^ 0x5EED), self.n_slots))
+
+        # what the host OWNS and the programs only read: the page table and
+        # every slot's sampling and termination rows in one block (_CTL),
+        # and the active mask. Host arrays written in place at admission,
+        # chain growth, finish, cancel, preemption and resume; _sync_rows
+        # uploads what changed since its last upload, whole, ahead of a
+        # dispatch (replicated over a mesh: _dev). The stop ids (-1 padded
+        # to device_stop_width) and the limit let the programs freeze a
+        # finished row on the device; _dev_term marks slots whose FULL stop
+        # set fits the row (others fall back to host stop detection)
+        self._stop_width = max(1, config.device_stop_width)
+        self._rows = np.zeros(
+            (self.n_slots, self.pmax + _CTL + self._stop_width), np.int32)
+        self._rows[:, self.pmax + _CTL:] = -1
+        self.page_table = self._rows[:, : self.pmax]  # a view, as is _warp
+        self._warp = self._rows[:, self.pmax + 3: self.pmax + _CTL].view(
+            np.float32)  # temperature, top_p
+        self._warp[:, 1] = 1.0
+        self._rows_up, self._active_up = self._rows.copy(), self.active.copy()
+        self._rows_dev = self._dev(self._rows_up)
+        self._active_dev = self._dev(self._active_up)
         self._dev_term = np.ones(self.n_slots, bool)
 
         # slot KV lives in ONE paged pool shared with the prefix cache —
@@ -719,11 +765,6 @@ class ContinuousBatchingEngine:
             sharding=self._pool_sharding,
             state_slots=self.n_slots if self._has_state else 0,
             state_snapshots=self._state_snapshot_rows())
-        self.page_table = np.zeros((self.n_slots, self.pmax), np.int32)
-        self._page_table_dev = self._dev(jnp.asarray(self.page_table))
-        self._pt_dirty_rows: set[int] = set()
-        self._slot_keys = self._dev(jax.random.split(
-            jax.random.PRNGKey(seed ^ 0x5EED), self.n_slots))
 
         from collections import deque as _deque
 
@@ -867,6 +908,8 @@ class ContinuousBatchingEngine:
         for series in ("llm_decode_chunks_dispatched_total",
                        "llm_decode_chunks_discarded_total",
                        "llm_admission_ring_waits_total",
+                       "llm_control_rows_uploads_total",
+                       "llm_loose_row_programs_total",
                        "llm_attn_pages_walked_total",
                        "llm_attn_pages_offered_total") + (
                            _BLOCK_SERIES if self._block else ()
@@ -983,6 +1026,7 @@ class ContinuousBatchingEngine:
             dynamic=cfg.remasking == "low_confidence_dynamic",
             threshold=cfg.confidence_threshold)
         offs = jnp.arange(W, dtype=jnp.int32)[None, :]
+        n_slots, pmax = self.n_slots, self.pmax
 
         def advance(params, hidden, block, lens, run, fin, keys, gen_start,
                     stop_ids, limit_lens, temp, top_p, top_k):
@@ -1015,10 +1059,12 @@ class ContinuousBatchingEngine:
                 counts.astype(jnp.int32))
             return _append_counts(jnp.concatenate([toks, row], axis=0), moe)
 
-        def paged_decode_chunk(params, k_pool, v_pool, page_table, block,
-                               lengths, active, finished, stop_ids,
-                               limit_lens, gen_start, keys, temp, top_p,
-                               top_k):
+        def paged_decode_chunk(params, k_pool, v_pool, rows, block,
+                               lengths, active, finished, keys):
+            (page_table, top_k, limit_lens, gen_start, temp, top_p,
+             stop_ids) = _unpack_rows(rows, pmax)
+            lengths = jnp.where(active, lengths, 0)
+
             def step(carry, _):
                 pools, blk, lens, fin, keys, ran, counts, moe = carry
                 run = active & jnp.logical_not(fin)
@@ -1046,16 +1092,24 @@ class ContinuousBatchingEngine:
         self._paged_decode_fn = jax.jit(paged_decode_chunk,
                                         donate_argnums=(1, 2))
 
-        def mixed_step(params, k_pool, v_pool, page_table, q_ids, q_lens,
-                       prefill_hist, lane_rows, block, lengths, active,
-                       finished, final_mask, final_lens, stop_ids, limit_lens,
-                       gen_start, keys, temp, top_p, top_k):
+        def mixed_step(params, k_pool, v_pool, rows, lane, block, lengths,
+                       finished, keys):
             """One forward of every open block beside the lane's chunk of
             whole prompt blocks. A lane samples no first token: a row whose
             prompt ends here (``final_mask``) flips to running at
-            ``final_lens`` with the open block the host put in ``block``
+            ``final_lens`` with its key and the open block the lane brought
             (the prompt's leftover, then masks), which this forward has not
-            run."""
+            run. A row that is not active is not finished: the lane's slot
+            starts its owner clean."""
+            (page_table, top_k, limit_lens, gen_start, temp, top_p,
+             stop_ids) = _unpack_rows(rows, pmax)
+            (active, _, final_mask, final_lens, new_keys, opened, q_ids,
+             q_lens, prefill_hist, lane_rows) = _unpack_lane(
+                 lane, n_slots, W, LANE_ROWS)
+            finished = finished & active
+            lengths = jnp.where(active, lengths, 0)
+            keys = jnp.where(final_mask[:, None], new_keys, keys)
+            block = jnp.where(final_mask[:, None], opened, block)
             run = active & jnp.logical_not(finished)
             hidden, pools, aux = model.forward_paged_mixed(
                 params, cfg, q_ids, (k_pool, v_pool), page_table,
@@ -1074,6 +1128,20 @@ class ContinuousBatchingEngine:
         self._k_steps = k_steps
 
     def _build_programs(self) -> None:
+        def restore_row(last_tokens, keys, lengths, finished, row):
+            """A row comes back mid-stream (resume, handoff import): the one
+            program beside the step programs that writes a slot's rows.
+            ``row``: slot, length, the key's two words, then the last token
+            or the open block."""
+            slot = row[0]
+            return (last_tokens.at[slot].set(
+                        row[4:] if last_tokens.ndim == 2 else row[4]),
+                    keys.at[slot].set(jax.lax.bitcast_convert_type(
+                        row[2:4], jnp.uint32)),
+                    lengths.at[slot].set(row[1]),
+                    finished.at[slot].set(False))
+
+        self._restore_row_fn = jax.jit(restore_row)
         if self._block:
             return self._build_block_programs()
         cfg = self.model_config
@@ -1083,6 +1151,7 @@ class ContinuousBatchingEngine:
         max_seq = self.config.max_seq_len
 
         no_counts = self._moe_counts(None)
+        n_slots, pmax = self.n_slots, self.pmax
 
         def paged_forward(forward, params, ids, caches, *tail, **kwargs):
             """One of the model's paged forward passes over the cache
@@ -1102,10 +1171,8 @@ class ContinuousBatchingEngine:
                                          **kwargs)
             return hidden, tuple(pools), self._moe_counts(aux)
 
-        def decode_chunk_body(params, caches, page_table,
-                              last_tokens, lengths, active, finished,
-                              stop_ids, limit_lens, keys,
-                              temp, top_p, top_k):
+        def decode_chunk_body(params, caches, rows, last_tokens, lengths,
+                              active, finished, keys):
             """k fused paged decode steps; per-slot key streams so each
             request's seed reproduces its tokens (round-1 advisory).
             Lengths are device-resident: running rows advance by k inside
@@ -1121,6 +1188,9 @@ class ContinuousBatchingEngine:
             -1 sentinels. A chunk chained off this one therefore stays
             valid across mid-chunk finishes, which is what lets the
             lookahead ring survive them."""
+            (page_table, top_k, limit_lens, _, temp, top_p,
+             stop_ids) = _unpack_rows(rows, pmax)
+            lengths = jnp.where(active, lengths, 0)
 
             def step(carry, j):
                 caches, toks, lens, fin, keys, counts = carry
@@ -1166,11 +1236,8 @@ class ContinuousBatchingEngine:
         self._paged_decode_fn = jax.jit(paged_decode_chunk,
                                         donate_argnums=donate)
 
-        def mixed_step_body(params, caches, page_table, q_ids, q_lens,
-                            prefill_hist, lane_rows, last_tokens, lengths,
-                            active, finished, sample_mask, final_mask,
-                            final_lens, stop_ids, limit_lens, keys, temp,
-                            top_p, top_k):
+        def mixed_step_body(params, caches, rows, lane, last_tokens,
+                            lengths, finished, keys):
             """One mixed-batch round over the tokens it has: every decode
             row takes its next token (the decode group: ``last_tokens``,
             ``lengths``, ``run``) while the lane — ``q_ids [R, Qc]``, the
@@ -1188,7 +1255,18 @@ class ContinuousBatchingEngine:
             DEVICE (active_out, lengths = final_lens, first token in
             last_out) so lookahead chunks can chain directly off this
             dispatch when the prefill queue drains — the mixed→pure
-            transition needs no synchronous fallback round."""
+            transition needs no synchronous fallback round. The lane's slot
+            starts its owner clean HERE: a row that is not active is not
+            finished, and a ``final_mask`` row takes the key the lane
+            brought before it samples."""
+            (page_table, top_k, limit_lens, _, temp, top_p,
+             stop_ids) = _unpack_rows(rows, pmax)
+            (active, sample_mask, final_mask, final_lens, new_keys, _,
+             q_ids, q_lens, prefill_hist, lane_rows) = _unpack_lane(
+                 lane, n_slots, 0, LANE_ROWS)
+            finished = finished & active
+            lengths = jnp.where(active, lengths, 0)
+            keys = jnp.where(final_mask[:, None], new_keys, keys)
             run = active & jnp.logical_not(finished)
             last_h, caches, counts = paged_forward(
                 model.forward_paged_mixed, params, q_ids, caches,
@@ -1221,12 +1299,8 @@ class ContinuousBatchingEngine:
         if self.spec_k:
             spec_w = self._spec_w
 
-            def spec_mixed_step(params, k_pool, v_pool, page_table,
-                                q_ids, q_lens, prefill_hist, last_tokens,
-                                lengths, active, finished, sample_mask,
-                                final_mask, final_lens, spec_lens,
-                                stop_ids, limit_lens, keys,
-                                temp, top_p, top_k):
+            def spec_mixed_step(params, k_pool, v_pool, rows, lane,
+                                last_tokens, lengths, finished, keys):
                 """mixed_step + k-token speculation: speculating rows run
                 their draft span (q_len = 1 + spec_lens ≤ spec_w, q_ids =
                 [last_token, d_1..d_d]) through the SAME ragged dispatch
@@ -1248,6 +1322,14 @@ class ContinuousBatchingEngine:
                 greedy speculating rows commit exactly the tokens plain
                 decode would have produced (acceptance is argmax
                 equality), so speculation changes speed, never text."""
+                (page_table, top_k, limit_lens, _, temp, top_p,
+                 stop_ids) = _unpack_rows(rows, pmax)
+                (active, sample_mask, final_mask, final_lens, new_keys, _,
+                 q_ids, q_lens, prefill_hist, spec_lens) = _unpack_lane(
+                     lane, n_slots, 0, n_slots)
+                finished = finished & active
+                lengths = jnp.where(active, lengths, 0)
+                keys = jnp.where(final_mask[:, None], new_keys, keys)
                 run = active & jnp.logical_not(finished)
                 q_ids = q_ids.at[:, 0].set(
                     jnp.where(active, last_tokens, q_ids[:, 0]))
@@ -1527,7 +1609,7 @@ class ContinuousBatchingEngine:
         done elsewhere, KV on host, first token emitted) for decode-side
         admission. The record enters the suspended deque — the handoff
         phase IS the resume path: _resume_suspended restores the pages,
-        patches the slot rows from the record's length/last-token/key, and
+        restores the slot rows from the record's length/last-token/key, and
         decode continues with zero prefill work on this engine. Suspended
         outranks admission, so a handoff is never stuck behind this
         engine's own queue. Runs on the SOURCE engine's scheduler thread
@@ -1721,8 +1803,8 @@ class ContinuousBatchingEngine:
         """Deactivate one occupied slot (prefill OR decode phase) and
         release everything it holds: the slot itself, its page chain (the
         chain's refs are the only pins a mid-flight request holds — the
-        radix probe pin was released at admission), and its device rows
-        (frozen via the finished mask + zeroed page-table row, so chunks
+        radix probe pin was released at admission), and its rows on the
+        host (off the active mask, the page-table row zeroed, so chunks
         dispatched after this park the row's KV writes on scratch).
         Deliberately NO epoch bump — see _service_cancellations: the
         lookahead ring drains through a cancel instead of discarding."""
@@ -1732,12 +1814,10 @@ class ContinuousBatchingEngine:
         self.active[slot] = False
         self.slots[slot] = None
         self._release_free_slot(slot)
-        self._deactivate_slot_device(slot)
         if state.chain is not None:
             self.pool.release_slot(state.chain)
             self._drop_pending_snapshots(state)
             self.page_table[slot, :] = 0
-            self._mark_pt_row(slot)
         self._cancel_finalize(
             state.request_id, state.emit, reason, kind, phase=phase,
             emitted=state.emitted, slot=slot,
@@ -2344,12 +2424,12 @@ class ContinuousBatchingEngine:
             self._release_free_slot(slot)
         return True
 
-    # ------------------------------------------------------------ device patches
+    # ------------------------------------------------------------ device rows
     def _dev(self, x: Any) -> Any:
         """Host→device upload with an EXPLICIT destination: replicated over
         the serving mesh (tp > 1) or the plain default device. Every
-        host-control upload in this engine routes through here — tokens,
-        lengths, stop rows, page-table patches, per-round ragged plans — so
+        host-control upload in this engine routes through here — the
+        host-owned rows (_sync_rows), a mixed step's lane, a resumed row — so
         a sharded-intent array can never be silently full-replicated by an
         implicit transfer, and control rows are guaranteed identical on
         every mesh device (the fabric-lint SH01 discipline)."""
@@ -2357,61 +2437,42 @@ class ContinuousBatchingEngine:
             return jax.device_put(x, self._replicated)
         return jnp.asarray(x)
 
-    def _patch_slot_device(self, slot: int, temp: float, top_p: float,
-                           top_k: int, length: int, active: bool,
-                           stops: frozenset = frozenset(),
-                           limit: int = 0, gen_start: int = 0) -> None:
-        """Patch ONE slot's device-resident rows (admission/resume). A dynamic
-        scalar index keeps this a single cached program, not one per slot.
-        ``stops``/``limit`` feed the device-side termination rows: the first
-        ``device_stop_width`` stop ids (-1 padded; sets that overflow fall
-        back to host stop detection via _dev_term) and the length at which
-        the row hits its max-tokens bound."""
-        i = jnp.asarray(slot, jnp.int32)
-        self._temp_dev = self._temp_dev.at[i].set(jnp.float32(temp))
-        self._top_p_dev = self._top_p_dev.at[i].set(jnp.float32(top_p))
-        self._top_k_dev = self._top_k_dev.at[i].set(jnp.int32(top_k))
-        self._lengths_dev = self._lengths_dev.at[i].set(jnp.int32(length))
-        self._active_dev = self._active_dev.at[i].set(jnp.bool_(active))
-        self._finished_dev = self._finished_dev.at[i].set(jnp.bool_(False))
-        row = np.full((self._stop_width,), -1, np.int32)
+    def _set_slot_rows(self, slot: int, s: SamplingParams, stops: frozenset,
+                       limit: int, gen_start: int = 0) -> None:
+        """A slot's sampling and termination rows, written on the HOST
+        (admission, resume); ``_sync_rows`` uploads them with the next
+        dispatch. The first ``device_stop_width`` stop ids (-1 padded; sets
+        that overflow fall back to host stop detection via _dev_term) and
+        the length at which the row hits its max-tokens bound."""
+        ctl = self._rows[slot, self.pmax:]
+        ctl[:3] = s.top_k, max(0, limit), gen_start
+        self._warp[slot] = s.temperature, s.top_p
         ids = sorted(stops)[: self._stop_width]
-        row[: len(ids)] = ids
-        self._stops_dev = self._stops_dev.at[i].set(jnp.asarray(row))
-        self._limit_dev = self._limit_dev.at[i].set(jnp.int32(max(0, limit)))
-        if self._block:
-            self._gen_start_dev = self._gen_start_dev.at[i].set(
-                jnp.int32(gen_start))
+        ctl[_CTL:] = -1
+        ctl[_CTL: _CTL + len(ids)] = ids
         self._dev_term[slot] = len(stops) <= self._stop_width
 
-    def _deactivate_slot_device(self, slot: int) -> None:
-        i = jnp.asarray(slot, jnp.int32)
-        self._lengths_dev = self._lengths_dev.at[i].set(jnp.int32(0))
-        self._active_dev = self._active_dev.at[i].set(jnp.bool_(False))
-        # a later ring commit may clobber the length row with the frozen
-        # terminal value — harmless: inactive rows pin to 0 at the next
-        # chunk's output and their page-table row is zeroed (scratch writes)
-        self._finished_dev = self._finished_dev.at[i].set(jnp.bool_(True))
-
-    def _mark_pt_row(self, slot: int) -> None:
-        self._pt_dirty_rows.add(slot)
-
-    def _flush_pt_patches(self) -> None:
-        """Patch only the CHANGED page-table rows to device — the full
-        [n_slots, pmax] table is never re-uploaded in steady state. The row
-        count pads to a power of two (bounded scatter variants); pad rows
-        rewrite a real row with its own current value, which is harmless."""
-        if not self._pt_dirty_rows:
-            return
-        rows = sorted(self._pt_dirty_rows)
-        self._pt_dirty_rows.clear()
-        np2 = 1
-        while np2 < len(rows):
-            np2 *= 2
-        rows = rows + [rows[0]] * (np2 - len(rows))
-        idx = self._dev(np.asarray(rows, np.int32))
-        self._page_table_dev = self._page_table_dev.at[idx].set(
-            self._dev(self.page_table[rows]))
+    def _sync_rows(self, active: bool = True) -> None:
+        """Ahead of a dispatch: upload, whole, the host-owned rows that
+        changed since their last upload (the page table and the control
+        rows are one block of a few KB; a copy goes up, so the host array
+        stays free to change). A chained chunk and a mixed step take
+        ``active`` elsewhere (their predecessor's, the lane's) and pass
+        False. A finish, a cancel or a preemption writes nothing to the
+        device: it clears ``self.active``, and a row that is not active
+        neither runs nor stays finished nor keeps a length."""
+        changed = False
+        if not np.array_equal(self._rows, self._rows_up):
+            changed = not np.array_equal(self._rows[:, self.pmax:],
+                                         self._rows_up[:, self.pmax:])
+            self._rows_up = self._rows.copy()
+            self._rows_dev = self._dev(self._rows_up)
+        if active and not np.array_equal(self.active, self._active_up):
+            changed = True
+            self._active_up = self.active.copy()
+            self._active_dev = self._dev(self._active_up)
+        if changed:
+            bump_counter("llm_control_rows_uploads_total")
 
     # ------------------------------------------------------------ admission
     def _resume_suspended(self) -> int:
@@ -2504,33 +2565,36 @@ class ContinuousBatchingEngine:
                 # stream is still untouched (no sample happened yet)
                 self.active[slot] = False
                 self.lengths[slot] = 0
-                self._patch_slot_device(
-                    slot, s.temperature, s.top_p, s.top_k, 0, False,
-                    stops=state.stops,
-                    limit=self._token_limit(len(state.prompt_ids),
-                                            s.max_tokens),
-                    gen_start=len(state.prompt_ids))
+                self._set_slot_rows(
+                    slot, s, state.stops,
+                    self._token_limit(len(state.prompt_ids), s.max_tokens),
+                    len(state.prompt_ids))
                 self._prefill_slots.append(slot)
             else:
                 self.active[slot] = True
                 self.lengths[slot] = rec.length
                 # limit re-derived from the resume point: L - emitted + max
                 # equals the original prompt_len + max_tokens - 1 bound
-                self._patch_slot_device(
-                    slot, s.temperature, s.top_p, s.top_k, rec.length, True,
-                    stops=state.stops,
-                    limit=(self._token_limit(len(state.prompt_ids),
-                                             s.max_tokens) if self._block
-                           else rec.length - state.emitted + s.max_tokens),
-                    gen_start=len(state.prompt_ids or ()))
-                i = jnp.asarray(slot, jnp.int32)
-                self._last_tokens = self._last_tokens.at[i].set(
-                    jnp.asarray(rec.last_token, jnp.int32))
-                self._slot_keys = self._slot_keys.at[i].set(
-                    jnp.asarray(rec.slot_key))
+                self._set_slot_rows(
+                    slot, s, state.stops,
+                    self._token_limit(len(state.prompt_ids), s.max_tokens)
+                    if self._block
+                    else rec.length - state.emitted + s.max_tokens,
+                    len(state.prompt_ids or ()))
+                # the row's device-advanced state comes back mid-stream:
+                # ONE program, its values one upload (slot, length, key,
+                # last token or open block)
+                row = np.concatenate([
+                    [slot, rec.length],
+                    np.asarray(rec.slot_key, np.uint32).view(np.int32),
+                    np.atleast_1d(rec.last_token)]).astype(np.int32)
+                (self._last_tokens, self._slot_keys, self._lengths_dev,
+                 self._finished_dev) = self._restore_row_fn(
+                     self._last_tokens, self._slot_keys, self._lengths_dev,
+                     self._finished_dev, self._dev(row))
+                bump_counter("llm_loose_row_programs_total")
             self.page_table[slot, :] = 0
             self.page_table[slot, : len(chain)] = chain
-            self._mark_pt_row(slot)
             resumed += 1
             pause_s = time.monotonic() - rec.suspended_at
             if rec.handoff:
@@ -2602,8 +2666,10 @@ class ContinuousBatchingEngine:
         (with recurrent state it cannot even be replayed). The wait is
         bounded: ``_can_extend_ring`` stops deepening the ring while
         ``_admission_waiting``, so it is empty within ``decode_lookahead``
-        further drains. The rows a slot's admission patches are therefore
-        never under a chunk in flight, and nothing here bumps ``_epoch``."""
+        further drains. The rows a slot's admission writes (on the host:
+        ``_set_slot_rows``; its ``mixed_step`` uploads them and starts the
+        device rows itself) are therefore never under a chunk in flight, and
+        nothing here bumps ``_epoch``."""
         if self._ring:
             if self._admission_waiting():
                 bump_counter("llm_admission_ring_waits_total")
@@ -2669,13 +2735,15 @@ class ContinuousBatchingEngine:
 
     def _assign_keys(self, reqs: list[_Pending]) -> None:
         """Assign per-request key streams in FIFO order: a seeded request
-        gets its own stream, the rest split the engine's in admission order."""
+        gets its own stream, the rest split the engine's in admission order.
+        Key data made on the host (``ops/sampling.py``), the same words
+        ``jax.random`` would give: no program is dispatched."""
         for req in reqs:
             if req.key is None:
                 if req.sampling.seed is not None:
-                    req.key = jax.random.PRNGKey(req.sampling.seed)
+                    req.key = host_key(req.sampling.seed)
                 else:
-                    self._rng, req.key = jax.random.split(self._rng)
+                    self._rng, req.key = host_split(self._rng)
 
     def _place(self, reqs: list[_Pending]) -> int:
         """Every taken request — cold or prefix-hit — claims a slot in
@@ -2759,16 +2827,13 @@ class ContinuousBatchingEngine:
             self.lengths[slot] = 0
             self.page_table[slot, :] = 0
             self.page_table[slot, : len(chain)] = chain
-            self._mark_pt_row(slot)
-            self._patch_slot_device(
-                slot, s.temperature, s.top_p, s.top_k, 0, False,
-                stops=state.stops,
-                limit=self._token_limit(len(req.prompt_ids), s.max_tokens),
-                gen_start=len(req.prompt_ids))
+            self._set_slot_rows(
+                slot, s, state.stops,
+                self._token_limit(len(req.prompt_ids), s.max_tokens),
+                len(req.prompt_ids))
         except Exception:
             self.pool.release_slot(chain)
             self.page_table[slot, :] = 0
-            self._mark_pt_row(slot)
             self.slots[slot] = None
             raise
         self._prefill_slots.append(slot)
@@ -2834,11 +2899,9 @@ class ContinuousBatchingEngine:
                 # the row, so the ring stays valid and overlap survives the
                 # finish — the whole point of device-side termination.
                 self._epoch += 1
-            self._deactivate_slot_device(slot)
             if state.chain is not None:
                 self.pool.release_slot(state.chain)
                 self.page_table[slot, :] = 0
-                self._mark_pt_row(slot)
 
     # ------------------------------------------------------------ decode round
     def _ensure_chunk_capacity(self, horizon: Optional[int] = None) -> None:
@@ -2899,7 +2962,6 @@ class ContinuousBatchingEngine:
         before = len(chain)
         self.pool.extend_chain(chain, target)
         self.page_table[slot, before: len(chain)] = chain[before:]
-        self._mark_pt_row(slot)
 
     def _grow_chain(self, slot: int, state: _SlotState, horizon: int) -> None:
         """Extend one slot's chain to cover length + horizon. Raises
@@ -2915,7 +2977,6 @@ class ContinuousBatchingEngine:
             before = len(chain)
             self.pool.extend_chain(chain, needed)
             self.page_table[slot, before: len(chain)] = chain[before:]
-            self._mark_pt_row(slot)
             return
         except MemoryError:
             # the deep-lookahead horizon is OPPORTUNISTIC — a slot that can
@@ -2929,7 +2990,6 @@ class ContinuousBatchingEngine:
         before = len(chain)
         self.pool.extend_chain(chain, mandatory)  # MemoryError → preempt
         self.page_table[slot, before: len(chain)] = chain[before:]
-        self._mark_pt_row(slot)
 
     def _preempt_slot(self, slot: int, state: _SlotState,
                       soft_yielded: bool = False) -> None:
@@ -2964,7 +3024,7 @@ class ContinuousBatchingEngine:
                 else np.asarray(self._last_tokens)[slot].copy() if self._block
                 else int(np.asarray(self._last_tokens)[slot]),
                 slot_key=None if is_prefill
-                else np.asarray(self._slot_keys[slot]),
+                else np.asarray(self._slot_keys)[slot],
                 soft_yielded=soft_yielded))
         self.preemptions += 1
         if is_prefill:
@@ -2972,11 +3032,9 @@ class ContinuousBatchingEngine:
         self.active[slot] = False
         self.slots[slot] = None
         self._release_free_slot(slot)
-        self._deactivate_slot_device(slot)
         self._epoch += 1
         self.pool.release_slot(chain)
         self.page_table[slot, :] = 0
-        self._mark_pt_row(slot)
 
     def _drop_pending_snapshots(self, state: _SlotState) -> None:
         """A prompt that leaves its slot before its commit (preempted,
@@ -2994,7 +3052,7 @@ class ContinuousBatchingEngine:
         transfer enqueue, not a sync — AS04-clean by design): by the time the
         drain's sanctioned sync point reads the oldest chunk, its bytes have
         usually already landed host-side."""
-        self._flush_pt_patches()
+        self._sync_rows(active=after is None)
         if after is None:
             last, keys, lengths, fin, active = (
                 self._last_tokens, self._slot_keys, self._lengths_dev,
@@ -3004,10 +3062,8 @@ class ContinuousBatchingEngine:
                 after.last, after.keys, after.lengths_dev,
                 after.finished_dev, after.active_dev)
         chunk_dev, *outs = self._paged_decode_fn(
-            self.params, *self.pool.cache_operands(),
-            self._page_table_dev, last, lengths, active, fin,
-            self._stops_dev, self._limit_dev, *self._block_operands(), keys,
-            self._temp_dev, self._top_p_dev, self._top_k_dev)
+            self.params, *self.pool.cache_operands(), self._rows_dev, last,
+            lengths, active, fin, keys)
         last_o, keys_o, lens_o, fin_o = self.pool.adopt(outs)
         try:
             chunk_dev.copy_to_host_async()  # non-blocking D2H start
@@ -3016,10 +3072,6 @@ class ContinuousBatchingEngine:
         bump_counter("llm_decode_chunks_dispatched_total")
         return _InflightChunk(chunk_dev, last_o, keys_o, lens_o, fin_o,
                               active, self._epoch)
-
-    def _block_operands(self) -> tuple:
-        """What a block model's programs take after the limits."""
-        return (self._gen_start_dev,) if self._block else ()
 
     def _admission_waiting(self) -> bool:
         """A slot is free and a request is pending or suspended: ``_admit``
@@ -3380,14 +3432,14 @@ class ContinuousBatchingEngine:
         before = len(chain)
         self.pool.extend_chain(chain, needed)
         self.page_table[slot, before: len(chain)] = chain[before:]
-        self._mark_pt_row(slot)
 
     def _finish_prefill(self, slot: int, state: _SlotState,
                         tok: Optional[int]) -> None:
         """Flip a fully-prefilled slot to decode: commit the prompt's full
         pages to the radix tree (later requests reuse them zero-copy),
-        activate the slot's device rows, and emit the first token (sampled
-        inside the same mixed dispatch that ran the final chunk). The epoch
+        adopt the flip the mixed dispatch computed on the device (its
+        outputs are already the committed rows: nothing is patched), and
+        emit the first token (sampled inside that dispatch). The epoch
         stays: chunks that span the flip were chained off the mixed
         dispatch, which computed it on-device (active_out/final_lens), and
         with no span there is no ring to stale."""
@@ -3406,12 +3458,6 @@ class ContinuousBatchingEngine:
         self._prefill_slots.remove(slot)
         self.lengths[slot] = T
         self.active[slot] = True
-        s = state.sampling
-        self._patch_slot_device(
-            slot, s.temperature, s.top_p, s.top_k, T, True,
-            stops=state.stops,
-            limit=self._token_limit(len(state.prompt_ids), s.max_tokens),
-            gen_start=len(state.prompt_ids))
         dur_ms = (time.monotonic() - state.prefill_t0) * 1000.0
         # the chunked path's duration spans the budget-paced rounds — the
         # realistic "time to get through prefill under current load"
@@ -3470,7 +3516,7 @@ class ContinuousBatchingEngine:
         # the post-first-sample key stream (committed at the mixed-round
         # drain) — the decode engine continues sampling from exactly here,
         # which is what makes seeded streams bit-identical across the split
-        slot_key = np.asarray(self._slot_keys[slot])
+        slot_key = np.asarray(self._slot_keys)[slot]
         rec = _Suspended(state=state, host_kv=host_kv, length=T,
                          last_token=tok, slot_key=slot_key, handoff=True)
         # free the slot with the preempt teardown idiom — the chain is
@@ -3478,10 +3524,8 @@ class ContinuousBatchingEngine:
         self.active[slot] = False
         self.slots[slot] = None
         self._release_free_slot(slot)
-        self._deactivate_slot_device(slot)
         self._epoch += 1
         self.page_table[slot, :] = 0
-        self._mark_pt_row(slot)
         record_event(state.request_id, "handoff_export", slot=slot,
                      length=T, pages=n_pages, tokens_emitted=state.emitted)
         self._handoff_sink(rec)
@@ -3722,6 +3766,9 @@ class ContinuousBatchingEngine:
         sample = self.active.copy()
         final_mask = np.zeros(n, bool)
         final_lens = np.zeros(n, np.int32)
+        #: the dispatch's by-slot columns (_unpack_lane); a flipping row's
+        #: key and (a block model) opened block go in as its chunk is planned
+        by_slot = np.zeros((n, _LANE_COLS + self._block), np.int32)
         finals: list[tuple[int, _SlotState]] = []
         for lane, (slot, state, chunk) in enumerate(plan):
             pos = state.prefill_pos
@@ -3730,24 +3777,20 @@ class ContinuousBatchingEngine:
             q_lens[r] = chunk
             hist[r] = pos
             if pos + chunk == self._prefill_target(state):
-                # final chunk: this dispatch samples the first token — hand
-                # the request's untouched key stream to the device row NOW
+                # final chunk: this dispatch samples the first token — the
+                # request's untouched key stream rides the lane to its row
                 finals.append((slot, state))
                 sample[slot] = True
                 final_mask[slot] = True
                 final_lens[slot] = pos + chunk
-                i = jnp.asarray(slot, jnp.int32)
-                self._slot_keys = self._slot_keys.at[i].set(
-                    jnp.asarray(state.prefill_key))
+                by_slot[slot, 4:_LANE_COLS] = state.prefill_key.view(np.int32)
                 if self._block:
                     # no first token: the prompt's leftover opens the row's
                     # first block, which the row's first forward will run
-                    opened = np.full(self._block,
-                                     self.model_config.mask_token_id, np.int32)
                     left = state.prompt_ids[pos + chunk:]
+                    opened = by_slot[slot, _LANE_COLS:]
+                    opened[:] = self.model_config.mask_token_id
                     opened[: len(left)] = left
-                    self._last_tokens = self._last_tokens.at[i].set(
-                        jnp.asarray(opened))
         for slot, state, drafts in spec_plan:
             # draft span: position 0 (the last committed token) is filled on
             # device from last_tokens; the drafts follow
@@ -3755,34 +3798,28 @@ class ContinuousBatchingEngine:
             q_ids[slot, 1:1 + d] = drafts
             q_lens[slot] = 1 + d
             spec_lens[slot] = d
-        self._flush_pt_patches()
-        if spec_plan:
-            positions = n * q_max
-            toks_dev, *outs = self._spec_step_fn(
-                self.params, *self.pool.cache_operands(),
-                self._page_table_dev, self._dev(q_ids), self._dev(q_lens),
-                self._dev(hist), self._last_tokens, self._lengths_dev,
-                self._active_dev, self._finished_dev, self._dev(sample),
-                self._dev(final_mask), self._dev(final_lens),
-                self._dev(spec_lens), self._stops_dev, self._limit_dev,
-                self._slot_keys, self._temp_dev, self._top_p_dev,
-                self._top_k_dev)
-        else:
-            positions = n * self._step_tokens + q_ids.size
-            toks_dev, *outs = self._mixed_step_fn(
-                self.params, *self.pool.cache_operands(),
-                self._page_table_dev, self._dev(q_ids), self._dev(q_lens),
-                self._dev(hist),
-                self._dev(np.array([slot for slot, _, _ in plan], np.int32)),
-                self._last_tokens,
-                self._lengths_dev, self._active_dev, self._finished_dev,
-                *(() if self._block else (self._dev(sample),)),
-                self._dev(final_mask),
-                self._dev(final_lens), self._stops_dev, self._limit_dev,
-                *self._block_operands(),
-                self._slot_keys, self._temp_dev, self._top_p_dev,
-                self._top_k_dev)
+        # TWO uploads a mixed step: the host-owned rows where they changed,
+        # and everything this dispatch carries as one flat block
+        # (_unpack_lane; the spans' last column: the lane's slot, or a
+        # slot's draft length in the all-rows step)
+        self._sync_rows(active=False)
+        by_slot[:, :4] = np.column_stack(
+            [self.active, sample, final_mask, final_lens])
+        lane = self._dev(np.concatenate([
+            by_slot.ravel(),
+            np.column_stack([q_ids, q_lens, hist, spec_lens if spec_plan
+                             else [slot for slot, _, _ in plan]]).ravel()
+        ]).astype(np.int32))
+        positions = n * q_max if spec_plan \
+            else n * self._step_tokens + q_ids.size
+        toks_dev, *outs = (self._spec_step_fn if spec_plan
+                           else self._mixed_step_fn)(
+            self.params, *self.pool.cache_operands(), self._rows_dev, lane,
+            self._last_tokens, self._lengths_dev, self._finished_dev,
+            self._slot_keys)
         last_o, keys_o, lens_o, fin_o, active_o = self.pool.adopt(outs)
+        # the flip's active mask is the device's own from here on
+        self._active_dev, self._active_up = active_o, self.active | final_mask
         if self._state_unit:
             # a snapshot of each row whose chunk ended on a boundary, as THIS
             # call left it: before anything chained below advances the row

@@ -13,6 +13,7 @@ CI instead of waiting for a chip run. SURVEY §7 stage 3.
 import os
 import subprocess
 import sys
+import types
 from pathlib import Path
 
 import numpy as np
@@ -278,11 +279,28 @@ def test_compiled_kernels_context_forces_mosaic():
     assert default_interpret() is on_cpu
 
 
-def _lane_operands(sds, width: int) -> tuple:
-    """``mixed_step``'s lane: one slot's chunk ``[1, width]``, then its
-    length, its history and the slot it is."""
-    lane = sds((1,), jnp.int32)
-    return sds((1, width), jnp.int32), lane, lane, lane
+def _decode_operands(sds, eng, block: int = 0) -> tuple:
+    """``paged_decode_chunk`` after the cache operands: the host-owned rows
+    (page table and control rows, one block), the last tokens (or the open
+    blocks), lengths, active, finished, keys."""
+    from cyberfabric_core_tpu.runtime.scheduler import _CTL
+
+    n = eng.n_slots
+    return (sds((n, eng.pmax + _CTL + eng.config.device_stop_width),
+                jnp.int32),
+            sds((n, block) if block else (n,), jnp.int32),
+            sds((n,), jnp.int32), sds((n,), bool), sds((n,), bool),
+            sds((n, 2), jnp.uint32))
+
+
+def _mixed_operands(sds, eng, width: int, block: int = 0) -> tuple:
+    """``mixed_step`` after the cache operands: the rows, the dispatch's
+    flat lane block at ``width``, then what the device advances."""
+    from cyberfabric_core_tpu.runtime.scheduler import LANE_ROWS, lane_words
+
+    rows, last, lens, _, fin, keys = _decode_operands(sds, eng, block)
+    return (rows, sds((lane_words(eng.n_slots, block, LANE_ROWS, width),),
+                      jnp.int32), last, lens, fin, keys)
 
 
 def _assert_whole_array_untouched(text: str, array, name: str) -> None:
@@ -339,7 +357,8 @@ def test_scheduler_programs_compile_for_v5e_at_mistral_7b(tp):
         quantization="int8", prefix_cache_pages=_N_PAGES,
         prefix_page_size=_PAGE, tp=tp)
     eng.model_config, eng.dtype = cfg, jnp.bfloat16
-    eng._model, eng._has_state = decoder_module(cfg), False
+    eng._model, eng._has_state, eng._block = decoder_module(cfg), False, 0
+    eng._moe_counters, eng.n_slots, eng.pmax = (), n, _PMAX
     eng.spec_k, eng._spec_w = 0, 1
     eng.rope_tables = rope_frequencies(
         cfg.head_dim, max(cfg.max_position, max_seq), cfg.rope_theta)
@@ -355,29 +374,21 @@ def test_scheduler_programs_compile_for_v5e_at_mistral_7b(tp):
         params = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=repl),
             abstract_params(cfg, jnp.bfloat16, "int8"))
+    eng.pool = types.SimpleNamespace(cache_operands=lambda: (None, None))
     with compiled_kernels():
         eng._build_programs()
 
     def sds(shape, dtype, sharding=repl):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-    def row(dtype):
-        return sds((n,), dtype)
-
-    i32, f32 = jnp.int32, jnp.float32
     pool = sds((cfg.num_layers, _N_PAGES, _PAGE,
                 cfg.num_kv_heads * cfg.head_dim), jnp.bfloat16, pool_sharding)
-    table, keys = sds((n, _PMAX), i32), sds((n, 2), jnp.uint32)
-    stops = sds((n, eng.config.device_stop_width), i32)
-    sampling = (row(f32), row(f32), row(i32))
     programs = {
         "paged_decode_chunk": (eng._paged_decode_fn, (
-            params, pool, pool, table, row(i32), row(i32), row(bool),
-            row(bool), stops, row(i32), keys, *sampling)),
+            params, pool, pool, *_decode_operands(sds, eng))),
         **{f"mixed_step@{w}": (eng._mixed_step_fn, (
-            params, pool, pool, table, *_lane_operands(sds, w), row(i32),
-            row(i32), row(bool), row(bool), row(bool), row(bool), row(i32),
-            stops, row(i32), keys, *sampling)) for w in (64, 256)},
+            params, pool, pool, *_mixed_operands(sds, eng, w)))
+           for w in (64, 256)},
     }
     for name, (fn, args) in programs.items():
         with compiled_kernels():
@@ -423,7 +434,8 @@ def test_scheduler_programs_compile_for_v5e_at_falcon_h1():
         model=cfg.name, max_seq_len=max_seq, max_batch=n, decode_chunk=8,
         quantization="int8", prefix_cache_pages=pages, prefix_page_size=_PAGE)
     eng.model_config, eng.dtype = cfg, jnp.bfloat16
-    eng._model, eng._has_state = decoder_module(cfg), True
+    eng._model, eng._has_state, eng._block = decoder_module(cfg), True, 0
+    eng._moe_counters, eng.n_slots, eng.pmax = (), n, max_seq // _PAGE
     eng.spec_k, eng._spec_w = 0, 1
     eng.mesh = eng._attn_mesh = None
     eng.rope_tables = rope_frequencies(cfg.head_dim, max_seq, cfg.rope_theta)
@@ -434,13 +446,11 @@ def test_scheduler_programs_compile_for_v5e_at_falcon_h1():
 
     params = jax.tree.map(lambda x: sds(x.shape, x.dtype),
                           abstract_params(cfg, jnp.bfloat16, "int8"))
+    eng.pool = types.SimpleNamespace(cache_operands=lambda: (None,) * 3)
     with compiled_kernels():
         eng._build_programs()
 
-    def row(dtype):
-        return sds((n,), dtype)
-
-    i32, f32 = jnp.int32, jnp.float32
+    f32 = jnp.float32
     pool = sds((cfg.num_layers, pages, _PAGE, cfg.num_kv_heads * cfg.head_dim),
                jnp.bfloat16)
     state = {"ssm": sds((cfg.num_layers, rows, cfg.ssm_heads,
@@ -448,20 +458,14 @@ def test_scheduler_programs_compile_for_v5e_at_falcon_h1():
              "conv": sds((cfg.num_layers, rows, cfg.ssm_conv - 1,
                           cfg.ssm_conv_dim), f32)}
     slab_bytes = int(np.prod(state["ssm"].shape)) * 4
-    table, keys = sds((n, max_seq // _PAGE), i32), sds((n, 2), jnp.uint32)
-    stops = sds((n, eng.config.device_stop_width), i32)
-    sampling = (row(f32), row(f32), row(i32))
 
     def mixed(width):
         return (eng._mixed_step_fn, (
-            params, pool, pool, state, table, *_lane_operands(sds, width),
-            row(i32), row(i32), row(bool), row(bool), row(bool),
-            row(bool), row(i32), stops, row(i32), keys, *sampling))
+            params, pool, pool, state, *_mixed_operands(sds, eng, width)))
 
     programs = {
         "paged_decode_chunk": (eng._paged_decode_fn, (
-            params, pool, pool, state, table, row(i32), row(i32), row(bool),
-            row(bool), stops, row(i32), keys, *sampling)),
+            params, pool, pool, state, *_decode_operands(sds, eng))),
         "mixed_step@64": mixed(64), "mixed_step@256": mixed(256),
     }
     for name, (fn, args) in programs.items():
@@ -519,6 +523,7 @@ def test_scheduler_programs_compile_for_v5e_at_sdar_moe():
     eng._model, eng._has_state = decoder_module(cfg), False
     eng._block = cfg.block_length
     eng._moe_counters = eng._model.MOE_COUNTERS
+    eng.n_slots, eng.pmax = n, max_seq // _PAGE
     eng.spec_k, eng._spec_w = 0, 1
     eng.mesh = eng._attn_mesh = None
     eng.rope_tables = rope_frequencies(cfg.head_dim, max_seq, cfg.rope_theta)
@@ -533,27 +538,17 @@ def test_scheduler_programs_compile_for_v5e_at_sdar_moe():
     with compiled_kernels():
         eng._build_programs()
 
-    def row(dtype):
-        return sds((n,), dtype)
-
-    i32, f32 = jnp.int32, jnp.float32
     pool = sds((cfg.num_layers, pages, _PAGE, cfg.num_kv_heads * cfg.head_dim),
                jnp.bfloat16)
-    table, keys = sds((n, max_seq // _PAGE), i32), sds((n, 2), jnp.uint32)
-    stops = sds((n, eng.config.device_stop_width), i32)
-    block = sds((n, cfg.block_length), i32)
-    sampling = (row(f32), row(f32), row(i32))
+    W = cfg.block_length
 
     def mixed(width):
         return (eng._mixed_step_fn, (
-            params, pool, pool, table, *_lane_operands(sds, width),
-            block, row(i32), row(bool), row(bool), row(bool), row(i32),
-            stops, row(i32), row(i32), keys, *sampling))
+            params, pool, pool, *_mixed_operands(sds, eng, width, W)))
 
     programs = {
         "paged_decode_chunk": (eng._paged_decode_fn, (
-            params, pool, pool, table, block, row(i32), row(bool), row(bool),
-            stops, row(i32), row(i32), keys, *sampling)),
+            params, pool, pool, *_decode_operands(sds, eng, W))),
         "mixed_step@64": mixed(64), "mixed_step@512": mixed(512),
     }
     import re
@@ -634,6 +629,7 @@ def test_scheduler_programs_compile_for_v5e_at_kimi_k2():
     eng.model_config, eng.dtype = cfg, jnp.bfloat16
     eng._model, eng._has_state, eng._block = decoder_module(cfg), False, 0
     eng._moe_counters = eng._model.MOE_COUNTERS
+    eng.n_slots, eng.pmax = n, max_seq // _PAGE
     eng.spec_k, eng._spec_w = 0, 1
     eng.mesh = eng._attn_mesh = None
     eng.rope_tables = rope_tables(cfg, max_seq)
@@ -657,24 +653,13 @@ def test_scheduler_programs_compile_for_v5e_at_kimi_k2():
     with compiled_kernels():
         eng._build_programs()
 
-    def row(dtype):
-        return sds((n,), dtype)
-
-    i32, f32 = jnp.int32, jnp.float32
-    table, keys = sds((n, max_seq // _PAGE), i32), sds((n, 2), jnp.uint32)
-    stops = sds((n, eng.config.device_stop_width), i32)
-    sampling = (row(f32), row(f32), row(i32))
-
     def mixed(width):
         return (eng._mixed_step_fn, (
-            params, pool, table, *_lane_operands(sds, width),
-            row(i32), row(i32), row(bool), row(bool), row(bool), row(bool),
-            row(i32), stops, row(i32), keys, *sampling))
+            params, pool, *_mixed_operands(sds, eng, width)))
 
     programs = {
         "paged_decode_chunk": (eng._paged_decode_fn, (
-            params, pool, table, row(i32), row(i32), row(bool), row(bool),
-            stops, row(i32), keys, *sampling)),
+            params, pool, *_decode_operands(sds, eng))),
         "mixed_step@64": mixed(64), "mixed_step@512": mixed(512),
     }
     import re

@@ -329,19 +329,12 @@ def test_llama_programs_take_the_operands_they_took():
     """For a llama-family model the two serving programs take params, the two
     pools (donated) and the control rows, as before this architecture came:
     no third cache operand, nothing of the mixer in the lowered text."""
-    import jax.numpy as jnp
-
     sched = ContinuousBatchingEngine(_cfg(model="tiny-llama"), seed=0)
     try:
         assert sched.pool.state is None
         assert len(sched.pool.cache_operands()) == 2
-        n = sched.n_slots
-        row = lambda dt: jnp.zeros((n,), dt)         # noqa: E731
-        stops = jnp.full((n, sched._stop_width), -1, jnp.int32)
-        control = (sched._page_table_dev, row(jnp.int32), row(jnp.int32),
-                   row(bool), row(bool), stops, row(jnp.int32),
-                   sched._slot_keys, row(jnp.float32), row(jnp.float32),
-                   row(jnp.int32))
+        control = (sched._rows_dev, sched._last_tokens, sched._lengths_dev,
+                   sched._active_dev, sched._finished_dev, sched._slot_keys)
         lowered = sched._paged_decode_fn.lower(
             sched.params, sched.pool.k_pool, sched.pool.v_pool, *control)
         n_params = len(jax.tree.leaves(sched.params))

@@ -375,7 +375,7 @@ def test_prefix_hit_chunks_only_the_suffix():
 def test_an_admission_that_fails_after_its_match_gives_everything_back(
         shares_head):
     """An admission that raises AFTER the chain took its hold on the matched
-    pages (here: the slot's device rows cannot be patched) ends that request
+    pages (here: the slot's rows cannot be written) ends that request
     alone with ``error``: the slot is free again, its page-table row is
     zero, no page stays referenced, and the next request is served — from
     the same cached head where there is one."""
@@ -400,13 +400,13 @@ def test_an_admission_that_fails_after_its_match_gives_everything_back(
     try:
         sched.submit(first, SamplingParams(max_tokens=6), col.emit_for(0))
         wait_idle_after(0)
-        patch = sched._patch_slot_device
+        patch = sched._set_slot_rows
 
         def fails_once(*a, **k):
-            sched._patch_slot_device = patch
-            raise RuntimeError("injected: device rows not patched")
+            sched._set_slot_rows = patch
+            raise RuntimeError("injected: the slot's rows not written")
 
-        sched._patch_slot_device = fails_once
+        sched._set_slot_rows = fails_once
         sched.submit(prompt, SamplingParams(max_tokens=6), col.emit_for(1))
         wait_idle_after(1)
         table_after = sched.page_table.copy()

@@ -202,6 +202,17 @@ def test_pool_reaches_the_kernel_uncopied(step):
         assert seen == ["scatter"], seen
 
 
+def _mixed_step_operands(eng, width):
+    """``mixed_step``'s operands with an empty lane of ``width``."""
+    from cyberfabric_core_tpu.runtime.scheduler import LANE_ROWS, lane_words
+
+    lane = jnp.zeros((lane_words(eng.n_slots, eng._block, LANE_ROWS,
+                                 width),), jnp.int32)
+    return (eng.params, *eng.pool.cache_operands(), eng._rows_dev, lane,
+            eng._last_tokens, eng._lengths_dev, eng._finished_dev,
+            eng._slot_keys)
+
+
 @pytest.mark.parametrize("program", ["paged_decode_chunk", "mixed_step"])
 @pytest.mark.parametrize("model", ["tiny-llama", "tiny-falcon-h1",
                                    "tiny-sdar"])
@@ -220,22 +231,14 @@ def test_work_list_is_built_once_a_step(model, program):
         use_flash=False, prefix_cache_pages=16, prefix_page_size=16,
         prefill_budget_tokens=width), seed=0)
     try:
-        row_b = jnp.zeros((n,), bool)
-        tail = (eng._stops_dev, eng._limit_dev, *eng._block_operands(),
-                eng._slot_keys, eng._temp_dev, eng._top_p_dev, eng._top_k_dev)
         if program == "paged_decode_chunk":
             jaxpr = jax.make_jaxpr(eng._paged_decode_fn)(
-                eng.params, *eng.pool.cache_operands(), eng._page_table_dev,
+                eng.params, *eng.pool.cache_operands(), eng._rows_dev,
                 eng._last_tokens, eng._lengths_dev, eng._active_dev,
-                eng._finished_dev, *tail)
+                eng._finished_dev, eng._slot_keys)
         else:
-            lane = jnp.zeros((1,), jnp.int32)
             jaxpr = jax.make_jaxpr(eng._mixed_step_fn)(
-                eng.params, *eng.pool.cache_operands(), eng._page_table_dev,
-                jnp.zeros((1, width), jnp.int32), lane, lane, lane,
-                eng._last_tokens, eng._lengths_dev, eng._active_dev,
-                eng._finished_dev, *(() if eng._block else (row_b,)), row_b,
-                eng._lengths_dev, *tail)
+                *_mixed_step_operands(eng, width))
         layers, = [e for e in _find(jaxpr.jaxpr, "scan")
                    if e.params["length"] == eng.model_config.num_layers]
         def over_rows(jaxpr):     # an expert layer and a mixer have others
@@ -272,15 +275,8 @@ def test_mixed_step_computes_the_tokens_it_has(model):
         use_flash=False, prefix_cache_pages=48, prefix_page_size=16,
         prefill_budget_tokens=width), seed=0)
     try:
-        lane = jnp.zeros((1,), jnp.int32)
-        row_b = jnp.zeros((n,), bool)
         jaxpr = jax.make_jaxpr(eng._mixed_step_fn)(
-            eng.params, *eng.pool.cache_operands(), eng._page_table_dev,
-            jnp.zeros((1, width), jnp.int32), lane, lane, lane,
-            eng._last_tokens, eng._lengths_dev, eng._active_dev,
-            eng._finished_dev, row_b, row_b, eng._lengths_dev,
-            eng._stops_dev, eng._limit_dev, eng._slot_keys, eng._temp_dev,
-            eng._top_p_dev, eng._top_k_dev)
+            *_mixed_step_operands(eng, width))
         weights = {w.shape[1:] for w in jax.tree.leaves(eng.params["layers"])
                    if w.ndim == 3}
         scan, = [e for e in _find(jaxpr.jaxpr, "scan")

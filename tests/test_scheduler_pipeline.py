@@ -346,7 +346,7 @@ def test_free_slot_deque_and_device_mirrors_stay_consistent():
         assert col.done.wait(240), (col.finishes, sched.stats())
         # quiesce: let in-flight rounds drain, then JOIN the scheduler thread
         # (emit fires before the finish bookkeeping — polling host state alone
-        # races the device-row patches by a few statements)
+        # races the finish bookkeeping by a few statements)
         deadline = time.monotonic() + 30
         while (sched.active.any() or sched._pending.qsize()) \
                 and time.monotonic() < deadline:
@@ -355,27 +355,24 @@ def test_free_slot_deque_and_device_mirrors_stay_consistent():
         free = list(sched._free_slots)
         assert sorted(free) == list(range(cfg.max_batch)), free
         assert len(set(free)) == len(free), f"duplicate free slots: {free}"
-        # device rows mirror host rows (the patch-only-changed-rows contract)
+        # what the NEXT dispatch would read is the host's rows: a finish
+        # writes nothing to the device, the upload ahead of a dispatch does
+        sched._sync_rows()
         np.testing.assert_array_equal(
             np.asarray(sched._active_dev), sched.active)
+        np.testing.assert_array_equal(
+            np.asarray(sched._rows_dev), sched._rows)
         # ACTIVE rows' device lengths mirror host lengths exactly. Inactive
-        # rows are DON'T-CARE under the epoch ring: the finish patch zeroes
-        # them, but a later ring-chunk commit may re-land the frozen terminal
-        # value — which the next dispatch masks (write target = zeroed page
-        # table row = scratch; chunk output pins them back to 0). What must
-        # hold for safety: no inactive device length exceeds the window, and
-        # their page-table rows are zeroed.
+        # rows are DON'T-CARE: they keep the frozen terminal value until a
+        # program pins them to 0 at its entry (their page-table rows are
+        # zeroed: writes park on scratch). What must hold for safety: no
+        # inactive device length exceeds the window, and their page-table
+        # rows are zeroed.
         lengths_dev = np.asarray(sched._lengths_dev)
         np.testing.assert_array_equal(
             lengths_dev[sched.active], sched.lengths[sched.active])
         assert (lengths_dev <= cfg.max_seq_len).all()
-        if not sched._pt_dirty_rows:
-            inactive = ~sched.active
-            assert (sched.page_table[inactive] == 0).all()
-        np.testing.assert_array_equal(
-            np.asarray(sched._page_table_dev),
-            sched.page_table if not sched._pt_dirty_rows else
-            np.asarray(sched._page_table_dev))
+        assert (sched.page_table[~sched.active] == 0).all()
     finally:
         sched.shutdown()
 
@@ -547,6 +544,8 @@ def test_the_rings_series_are_at_zero_from_engine_build():
     names = ("llm_decode_chunks_dispatched_total",
              "llm_decode_chunks_discarded_total",
              "llm_admission_ring_waits_total",
+             "llm_control_rows_uploads_total",
+             "llm_loose_row_programs_total",
              "llm_attn_pages_walked_total",
              "llm_attn_pages_offered_total")
     before = {n: _counter(n) for n in names}
@@ -662,6 +661,8 @@ def test_the_rings_series_are_on_metrics_before_the_first_request():
     for name in ("llm_decode_chunks_dispatched_total",
                  "llm_decode_chunks_discarded_total",
                  "llm_admission_ring_waits_total",
+                 "llm_control_rows_uploads_total",
+                 "llm_loose_row_programs_total",
                  "llm_attn_pages_walked_total",
                  "llm_attn_pages_offered_total"):
         assert f"# TYPE {name} counter" in text
