@@ -18,7 +18,6 @@ import os
 import random
 import re
 import time
-from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
@@ -389,19 +388,6 @@ class ThrottledLog:
             self._last[key] = now
             return True
         return False
-
-
-@contextmanager
-def device_profile(name: str, enabled: bool = False, logdir: str = "/tmp/jax-trace"):
-    """Wrap a device-side region in a jax.profiler trace when enabled."""
-    if not enabled:
-        yield
-        return
-    import jax
-
-    with jax.profiler.trace(logdir):
-        with jax.profiler.TraceAnnotation(name):
-            yield
 
 
 def xla_cost_summary(compiled) -> dict[str, float]:

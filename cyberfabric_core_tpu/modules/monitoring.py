@@ -20,47 +20,58 @@ from ..gateway.validation import read_json
 from .sdk import LlmWorkerApi
 
 
-#: the four stages of one scheduler round, rendered as one Perfetto track
-#: each — the admit → dispatch → sync-wait → host-emit pipeline from the
-#: overlapped-decode stats becomes visually inspectable
+#: the four stages of a round record that predates ``phases``
 _ROUND_STAGES = ("admit", "dispatch", "sync_wait", "host_emit")
 
 
 def _chrome_trace(per_model: dict[str, list[dict]]) -> dict:
-    """Scheduler round timings → Chrome trace-event JSON (the format Perfetto
+    """Scheduler round records → Chrome trace-event JSON (the format Perfetto
     and chrome://tracing load directly). One process per engine, one thread
-    track per pipeline stage, "X" complete events in µs."""
+    track per phase of the scheduler's loop, "X" complete events in µs. A
+    record draws its ``phases`` (the scheduler thread's time since the
+    previous record, ``runtime/scheduler.py: _PhaseClock``) in their
+    recorded order and lengths, back to back over its ``pass_ms``, which
+    ends where the round's last stage does; a record without them draws its
+    four stages from the round's start."""
     events: list[dict] = []
     for pid, name in enumerate(sorted(per_model), start=1):
         events.append({"ph": "M", "pid": pid, "name": "process_name",
                        "args": {"name": f"scheduler {name}"}})
-        for tid, stage in enumerate(_ROUND_STAGES, start=1):
-            events.append({"ph": "M", "pid": pid, "tid": tid,
-                           "name": "thread_name", "args": {"name": stage}})
+        tids: dict[str, int] = {}
+
+        def draw(track: str, start_us: float, dur_ms: float, r: dict,
+                 **args) -> None:
+            if track not in tids:
+                tids[track] = len(tids) + 1
+                events.append({"ph": "M", "pid": pid, "tid": tids[track],
+                               "name": "thread_name",
+                               "args": {"name": track}})
+            events.append({
+                "name": track, "ph": "X", "pid": pid, "tid": tids[track],
+                "ts": round(start_us, 1),
+                "dur": round(max(0.0, dur_ms) * 1000.0, 1),
+                "args": {"lookahead": bool(r.get("lookahead")),
+                         "active_slots": r.get("active"), **args}})
+
         for r in per_model[name]:
             ts = r.get("ts")
             if ts is None:  # entry predating the wall-clock column
                 continue
-            round_us = ts * 1e6
-            # admission ran just BEFORE the round's dispatch; the remaining
-            # stages are sequential from the round start
-            starts_us = (
-                round_us - r["admit_ms"] * 1000.0,
-                round_us,
-                round_us + r["dispatch_ms"] * 1000.0,
-                round_us + (r["dispatch_ms"] + r["sync_wait_ms"]) * 1000.0,
-            )
-            durs_ms = (r["admit_ms"], r["dispatch_ms"], r["sync_wait_ms"],
-                       r["host_emit_ms"])
-            for tid, (stage, start_us, dur_ms) in enumerate(
-                    zip(_ROUND_STAGES, starts_us, durs_ms), start=1):
-                events.append({
-                    "name": stage, "ph": "X", "pid": pid, "tid": tid,
-                    "ts": round(start_us, 1),
-                    "dur": round(max(0.0, dur_ms) * 1000.0, 1),
-                    "args": {"lookahead": bool(r.get("lookahead")),
-                             "active_slots": r.get("active")},
-                })
+            at_us = ts * 1e6
+            if "phases" in r:
+                at_us += (r["dispatch_ms"] + r["sync_wait_ms"]
+                          + r["host_emit_ms"] - r["pass_ms"]) * 1000.0
+                for phase, (wall, cpu, starved) in r["phases"].items():
+                    draw(phase, at_us, wall, r, cpu_ms=cpu,
+                         starved_ms=starved)
+                    at_us += wall * 1000.0
+                continue
+            # admission ran just before the round's start; the other three
+            # stages follow it in order
+            at_us -= r["admit_ms"] * 1000.0
+            for stage in _ROUND_STAGES:
+                draw(stage, at_us, r[f"{stage}_ms"], r)
+                at_us += r[f"{stage}_ms"] * 1000.0
     return {"traceEvents": events, "displayTimeUnit": "ms"}
 
 
@@ -419,7 +430,12 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                  "over steps and layers"),
                 ("llm_attn_pages_offered_total",
                  "Slots of the page table (rows x pages a row) beside "
-                 "them, for the same calls")):
+                 "them, for the same calls"),
+                ("llm_device_starved_seconds_total",
+                 "Seconds in which nothing the scheduler had launched was "
+                 "undrained (the device waited for the host: a lower bound "
+                 "of its idle time), by model and by the phase the "
+                 "scheduler thread was in; wait = no work to give")):
             self.registry.counter(name, text).inc(0.0)
 
         # pure-decode vs mixed vs prefill-only round dispatch percentiles,
@@ -685,7 +701,14 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                     jax.profiler.stop_trace()
                 except Exception:
                     pass
-            jax.profiler.start_trace(str(out))
+            # the Python tracer stays off: hooking every Python call slows
+            # the host this trace is taken to judge, and with it on the
+            # /host:CPU plane (where the scheduler's sched.* spans lie) runs
+            # 1.5 ms ahead of the device planes' clock (PERF.md section 6,
+            # PR 37); TraceMe events (the spans, the runtime's own) stay
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(str(out), profiler_options=options)
             # only a successful start proves the global tracer is ours again;
             # clearing the flag before this point would make a persistently
             # failing stop wedge every future /start

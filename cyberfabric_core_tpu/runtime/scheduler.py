@@ -51,7 +51,8 @@ alternating.
   non-blocking device→host transfer immediately
   (``copy_to_host_async``), and the round's single sanctioned sync point
   drains the OLDEST chunk — by then its transfer has typically landed, so
-  the blocking wait collapses (``readback_wait_ms`` in stats()).
+  the blocking wait collapses (``readback_wait_ms_p50`` in stats(): the
+  records' ``sync_wait_ms``).
 - Prefill admission budget: ``prefill_budget_tokens`` caps prompt tokens
   admitted per round (Sarathi-style interleave) so an arrival burst no longer
   stalls every in-flight decode behind an unbounded prefill drain. When the
@@ -362,6 +363,106 @@ class _InflightChunk:
     #                       (chained dispatches reuse it; NEVER committed —
     #                       host finish deactivations must not be undone)
     epoch: int
+
+
+#: what the scheduler thread can be doing: the loop is TILED by these, every
+#: instant from one pass's start to the next in exactly one of them
+#: (docs/ARCHITECTURE.md, "The scheduler's phases", says what each holds)
+PHASES = ("wait", "service", "admit", "capacity", "plan", "upload", "launch",
+          "drain", "commit", "emit")
+_SPAN_NAMES = {(p, s): f"sched.{p}.starved" if s else f"sched.{p}"
+               for p in PHASES for s in (False, True)}
+
+
+class _PhaseClock:
+    """The one place the scheduler thread's time is taken. ``to(phase)`` is a
+    SWITCH, not a nest: it closes the phase the thread was in and opens the
+    next, so the phases tile the thread's time by construction, in three
+    forms at once:
+
+    - sums for the next round record: per phase ``[wall, cpu, starved]``
+      seconds since the previous record (``take``), wall from
+      ``time.monotonic()``, cpu from ``time.thread_time()`` (wall less cpu is
+      time in which the thread did not run: a wait for the GIL, or the host
+      descheduling it);
+    - a ``jax.profiler.TraceAnnotation`` named ``sched.<phase>``, so that a
+      trace taken through ``/v1/monitoring/profiler/start`` has the same
+      phases on the clock of its device planes (no profiler running: an
+      annotation is a branch);
+    - the device's wait for the host. ``starved`` is up while nothing this
+      scheduler launched is undrained: raised at the return of a drain that
+      leaves the ring empty (and at a discard that empties it, the one place
+      it can read high: by the device time of the chunks dropped), dropped at
+      the return of the next launch. A switch adds the time elapsed under
+      the flag to the leaving phase's ``starved`` and to
+      ``llm_device_starved_seconds_total{model,phase}``, and names the span
+      ``sched.<phase>.starved``. A lower bound of the device's idle time: a
+      chunk that ended before its drain was called is seen late, and what
+      the device takes to start a launched program is not seen.
+
+    Used by one thread at a time (the scheduler's; a test that steps
+    ``_loop_pass`` by hand is that thread)."""
+
+    def __init__(self, model: str) -> None:
+        self._model = model
+        self.phase = "wait"
+        self.starved = True         # nothing launched yet
+        self._acc: dict[str, list[float]] = {}
+        self._span: Any = None
+        self._thread = threading.get_ident()
+        self._t = self._pass_t0 = time.monotonic()
+        self._cpu = time.thread_time()
+
+    def _stamp(self) -> float:
+        """Book the time since the last stamp to the current phase."""
+        now, cpu, thread = (time.monotonic(), time.thread_time(),
+                            threading.get_ident())
+        wall = now - self._t
+        acc = self._acc.get(self.phase)
+        if acc is None:
+            acc = self._acc[self.phase] = [0.0, 0.0, 0.0]
+        acc[0] += wall
+        if thread == self._thread:  # thread_time is per thread
+            acc[1] += cpu - self._cpu
+        if self.starved:
+            acc[2] += wall
+            bump_counter("llm_device_starved_seconds_total", n=wall,
+                         model=self._model, phase=self.phase)
+        self._t, self._cpu, self._thread = now, cpu, thread
+        return now
+
+    def to(self, phase: str, starved: Optional[bool] = None) -> float:
+        """Switch to ``phase`` (re-entering the current one is a switch too:
+        it is how ``starved`` changes inside a phase). Returns the instant,
+        ``time.monotonic()``."""
+        now = self._stamp()
+        self.phase = phase
+        if starved is not None:
+            self.starved = starved
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+        self._span = jax.profiler.TraceAnnotation(
+            _SPAN_NAMES[phase, self.starved])
+        self._span.__enter__()
+        return now
+
+    def take(self) -> tuple[dict[str, list[float]], float, float]:
+        """At a round record: ``phases`` (phase -> [wall_ms, cpu_ms,
+        starved_ms], in the order they first ran) and ``pass_ms`` since the
+        previous record, whose sum over the phases it is; and the instant."""
+        now = self._stamp()
+        phases = {p: [round(1e3 * wall, 4), round(1e3 * min(cpu, wall), 4),
+                      round(1e3 * starved, 4)]
+                  for p, (wall, cpu, starved) in self._acc.items()}
+        pass_ms = round(1e3 * (now - self._pass_t0), 4)
+        self._acc, self._pass_t0 = {}, now
+        return phases, pass_ms, now
+
+    def close(self) -> None:
+        """The loop's thread ends: close its open span."""
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
 
 
 class TenantFairQueue:
@@ -918,10 +1019,10 @@ class ContinuousBatchingEngine:
         #: achieved ring depth at each drain (how many chunks stayed in
         #: flight while the host emitted) → stats() depth histogram
         self._depth_hist: dict[int, int] = {}
-        #: blocking time of the sanctioned oldest-chunk drain — with the
-        #: dispatch-time async transfer this should collapse toward zero
-        self.readback_wait_samples: "deque[float]" = deque(maxlen=512)
-        self._last_admit_ms = 0.0
+        #: the scheduler thread's time by phase (the round records' timing
+        #: fields, the ``sched.*`` spans of a profiler trace, the device's
+        #: wait for the host): the only clock the loop reads
+        self._clock = _PhaseClock(config.model)
         #: round heartbeat (monotonic): the doctor's scheduler-round
         #: watchdog reads this to notice a wedged decode loop
         self.last_round_at = time.monotonic()
@@ -2173,10 +2274,11 @@ class ContinuousBatchingEngine:
         timings = locked_snapshot(self.round_timings)
         waits = locked_snapshot(self.queue_wait_samples)
         resumes = locked_snapshot(self.resume_latency_samples)
-        rb_waits = locked_snapshot(self.readback_wait_samples)
         la = dict(self._lookahead_stats)  # fixed key set: updates, no resize
         depth_hist = locked_snapshot(self._depth_hist)
         per_kind = self._dispatch_by_kind(timings)
+        sync_wait_p50 = round(self._p50(
+            [t["sync_wait_ms"] for t in timings]), 3)
         pipeline = {
             "rounds": self.decode_rounds,
             "lookahead_rounds": self.lookahead_rounds,
@@ -2186,21 +2288,21 @@ class ContinuousBatchingEngine:
                 [t["admit_ms"] for t in timings]), 3),
             "dispatch_ms_p50": round(self._p50(
                 [t["dispatch_ms"] for t in timings]), 3),
-            "sync_wait_ms_p50": round(self._p50(
-                [t["sync_wait_ms"] for t in timings]), 3),
+            "sync_wait_ms_p50": sync_wait_p50,
             "host_emit_ms_p50": round(self._p50(
                 [t["host_emit_ms"] for t in timings]), 3),
             "lookahead": la,
             # deep lookahead (the epoch ring): configured depth, achieved
             # depth histogram at drain time, what fraction of speculative
             # dispatches were thrown away, and how long the sanctioned drain
-            # actually blocked (≈0 when the async D2H transfer won the race)
+            # actually blocked (≈0 when the async D2H transfer won the race;
+            # the records' sync_wait_ms under its older name)
             "depth": self._lookahead_depth,
             "depth_hist": {str(d): n
                            for d, n in sorted(depth_hist.items())},
             "discard_ratio": round(
                 la["discarded"] / max(1, la["dispatched"]), 3),
-            "readback_wait_ms_p50": round(self._p50(rb_waits), 3),
+            "readback_wait_ms_p50": sync_wait_p50,
             # mixed-batch chunked prefill (ragged kernel piggybacking)
             "mixed_rounds": self.mixed_rounds,
             "prefill_chunks": self.prefill_chunks,
@@ -2300,16 +2402,20 @@ class ContinuousBatchingEngine:
             self._loop_body()
 
     def _loop_body(self) -> None:
-        while not self._stop.is_set():
-            try:
-                if not self._loop_pass():
-                    self._wake.wait(timeout=0.1)
-                    self._wake.clear()
-            except Exception as e:  # noqa: BLE001 — device errors must not hang clients
-                logger.exception("scheduler loop failed; failing in-flight requests")
-                self._broken = str(e)[:500]
-                self._fail_all_inflight("scheduler loop failed")
-                return
+        try:
+            while not self._stop.is_set():
+                try:
+                    if not self._loop_pass():
+                        self._clock.to("wait")
+                        self._wake.wait(timeout=0.1)
+                        self._wake.clear()
+                except Exception as e:  # noqa: BLE001 — device errors must not hang clients
+                    logger.exception("scheduler loop failed; failing in-flight requests")
+                    self._broken = str(e)[:500]
+                    self._fail_all_inflight("scheduler loop failed")
+                    return
+        finally:
+            self._clock.close()
 
     def _loop_pass(self) -> bool:
         """One pass of the loop: the round boundary's bookkeeping, admission,
@@ -2318,6 +2424,7 @@ class ContinuousBatchingEngine:
         # cancels/deadlines apply at the round boundary: BEFORE admission (a
         # lapsed pending entry must never take the slot this pass is about
         # to hand out)
+        self._clock.to("service")
         self._service_cancellations()
         # tenant soft-quota sweep: pure bookkeeping (marks a yield victim;
         # the capacity pass performs the actual preempt)
@@ -2327,6 +2434,7 @@ class ContinuousBatchingEngine:
             # these chunks, and nothing is left to replay them for
             self._discard_ring()
         # holds everything back while the ring has chunks in flight
+        self._clock.to("admit")
         admitted = self._admit()
         # prefilling slots are work too: mixed-batch rounds must run even
         # before any slot reaches decode phase
@@ -2673,9 +2781,7 @@ class ContinuousBatchingEngine:
         if self._ring:
             if self._admission_waiting():
                 bump_counter("llm_admission_ring_waits_total")
-            self._last_admit_ms = 0.0
             return 0
-        t0 = time.monotonic()
         failpoint("scheduler.admit")
         admitted = self._resume_suspended()
         taken: list[_Pending] = []
@@ -2730,7 +2836,6 @@ class ContinuousBatchingEngine:
             self._admit_events.append((time.monotonic(), popped))
         if taken:
             admitted += self._place(taken)
-        self._last_admit_ms = round((time.monotonic() - t0) * 1000.0, 3)
         return admitted
 
     def _assign_keys(self, reqs: list[_Pending]) -> None:
@@ -3052,7 +3157,9 @@ class ContinuousBatchingEngine:
         transfer enqueue, not a sync — AS04-clean by design): by the time the
         drain's sanctioned sync point reads the oldest chunk, its bytes have
         usually already landed host-side."""
+        self._clock.to("upload")
         self._sync_rows(active=after is None)
+        self._clock.to("launch")
         if after is None:
             last, keys, lengths, fin, active = (
                 self._last_tokens, self._slot_keys, self._lengths_dev,
@@ -3064,6 +3171,7 @@ class ContinuousBatchingEngine:
         chunk_dev, *outs = self._paged_decode_fn(
             self.params, *self.pool.cache_operands(), self._rows_dev, last,
             lengths, active, fin, keys)
+        self._clock.to("launch", starved=False)  # the device has work
         last_o, keys_o, lens_o, fin_o = self.pool.adopt(outs)
         try:
             chunk_dev.copy_to_host_async()  # non-blocking D2H start
@@ -3149,6 +3257,9 @@ class ContinuousBatchingEngine:
         self._lookahead_stats["discarded"] += len(self._ring)
         bump_counter("llm_decode_chunks_discarded_total", n=len(self._ring))
         self._ring.clear()
+        # nothing launched is undrained any more (the device may still be
+        # running what was dropped: _PhaseClock says what that costs)
+        self._clock.to(self._clock.phase, starved=True)
 
     def _commit_chunk(self, rec: _InflightChunk,
                       commits: Optional[np.ndarray] = None) -> np.ndarray:
@@ -3170,8 +3281,7 @@ class ContinuousBatchingEngine:
                                 0).astype(np.int32)
         return old_lengths
 
-    def _record_round(self, dispatch_ms: float, sync_wait_ms: float,
-                      host_emit_ms: float, lookahead: bool,
+    def _record_round(self, lookahead: bool,
                       ts: Optional[float] = None,
                       mixed: bool = False,
                       chunk_tokens: int = 0,
@@ -3186,7 +3296,20 @@ class ContinuousBatchingEngine:
         as Chrome trace events, which need absolute timestamps.
         ``positions`` is what the dispatch computed: ``B + R*Qc`` for a lane
         step, ``B x Qmax`` for an all-rows (speculative) one, and ``B`` a
-        step for a decode round (the default)."""
+        step for a decode round (the default).
+
+        The record closes the PASS: ``phases`` is the scheduler thread's
+        time since the previous record by phase, ``[wall_ms, cpu_ms,
+        starved_ms]`` each (``_PhaseClock``), and ``pass_ms`` their walls'
+        sum, so consecutive records add up to the thread's time; passes that
+        ran no round are in the next record's ``wait`` / ``service`` /
+        ``admit``. The four stage fields are sums over the phases they
+        cover."""
+        phases, pass_ms, self.last_round_at = self._clock.take()
+
+        def wall_ms(*of: str) -> float:
+            return round(sum(phases[p][0] for p in of if p in phases), 3)
+
         if positions is None:
             positions = self.n_slots * self._k_steps * self._step_tokens
         self.decode_rounds += 1
@@ -3196,13 +3319,14 @@ class ContinuousBatchingEngine:
             self.mixed_rounds += 1
             self.mixed_positions += positions
             self.mixed_useful_tokens += chunk_tokens + self.active_slots
-        self.last_round_at = time.monotonic()
         self.round_timings.append({
             "ts": round(ts if ts is not None else time.time(), 6),
-            "admit_ms": self._last_admit_ms,
-            "dispatch_ms": round(dispatch_ms, 3),
-            "sync_wait_ms": round(sync_wait_ms, 3),
-            "host_emit_ms": round(host_emit_ms, 3),
+            "admit_ms": wall_ms("admit"),
+            "dispatch_ms": wall_ms("capacity", "plan", "upload", "launch"),
+            "sync_wait_ms": wall_ms("drain"),
+            "host_emit_ms": wall_ms("commit", "emit"),
+            "phases": phases,
+            "pass_ms": pass_ms,
             "lookahead": lookahead,
             "mixed": mixed,
             # round kind for the dispatch-time attribution: "decode" (pure
@@ -3655,6 +3779,7 @@ class ContinuousBatchingEngine:
         chained = 0
         tail = rec
         for h in range(depth):
+            self._clock.to("capacity")
             # the mixed step's own advance + h+1 chained chunks
             horizon = self._step_tokens + (h + 1) * k
             for slot in range(self.n_slots):
@@ -3706,7 +3831,7 @@ class ContinuousBatchingEngine:
         (no prefill slots): returns False without dispatching when no draft
         survives planning, and the caller falls back to the plain chunk
         round."""
-        t0 = time.monotonic()
+        t0 = self._clock.to("capacity")
         wall0 = time.time()
         assert not self._ring, "a mixed round met chunks in flight"
         # capacity: decode rows keep a full chunk of headroom (the invariant
@@ -3718,13 +3843,17 @@ class ContinuousBatchingEngine:
             # one slot's chunk a step (the lane), FIFO; an engine that
             # speculates plans every slot the budget covers, for the all-rows
             # step a round with draft spans takes
-            for slot, state, chunk in self._plan_prefill_chunks(
-                    max_rows=None if self.spec_k else LANE_ROWS):
+            self._clock.to("plan")
+            planned = self._plan_prefill_chunks(
+                max_rows=None if self.spec_k else LANE_ROWS)
+            self._clock.to("capacity")
+            for slot, state, chunk in planned:
                 try:
                     self._grow_chain_prefill(slot, state, chunk)
                     plan.append((slot, state, chunk))
                 except MemoryError:
                     self._preempt_slot(slot, state)
+        self._clock.to("plan")
         # speculation shares the ragged token budget: prefill chunks draw
         # first (a cold prompt's TTFT beats an optimistic draft, and chunk
         # pacing stays bit-identical to k=0), drafts take what is left —
@@ -3802,21 +3931,25 @@ class ContinuousBatchingEngine:
         # and everything this dispatch carries as one flat block
         # (_unpack_lane; the spans' last column: the lane's slot, or a
         # slot's draft length in the all-rows step)
-        self._sync_rows(active=False)
         by_slot[:, :4] = np.column_stack(
             [self.active, sample, final_mask, final_lens])
-        lane = self._dev(np.concatenate([
+        lane_host = np.concatenate([
             by_slot.ravel(),
             np.column_stack([q_ids, q_lens, hist, spec_lens if spec_plan
                              else [slot for slot, _, _ in plan]]).ravel()
-        ]).astype(np.int32))
+        ]).astype(np.int32)
         positions = n * q_max if spec_plan \
             else n * self._step_tokens + q_ids.size
+        self._clock.to("upload")
+        self._sync_rows(active=False)
+        lane = self._dev(lane_host)
+        self._clock.to("launch")
         toks_dev, *outs = (self._spec_step_fn if spec_plan
                            else self._mixed_step_fn)(
             self.params, *self.pool.cache_operands(), self._rows_dev, lane,
             self._last_tokens, self._lengths_dev, self._finished_dev,
             self._slot_keys)
+        self._clock.to("launch", starved=False)  # the device has work
         last_o, keys_o, lens_o, fin_o, active_o = self.pool.adopt(outs)
         # the flip's active mask is the device's own from here on
         self._active_dev, self._active_up = active_o, self.active | final_mask
@@ -3844,10 +3977,12 @@ class ContinuousBatchingEngine:
                                    active_o, self._epoch)
         spanned = 0 if spec_plan else self._mixed_ring_span(mixed_rec,
                                                             finals)
-        t1 = time.monotonic()
+        self._clock.to("drain")
         toks = np.asarray(toks_dev, np.int32)  # sync-point: mixed-round drain (AS04)
-        t2 = time.monotonic()
-        self.readback_wait_samples.append((t2 - t1) * 1000.0)
+        # where nothing was chained off this dispatch the device waits from
+        # here to the next launch
+        round_ms = (self._clock.to("commit", starved=not self._ring)
+                    - t0) * 1000.0
         self._last_tokens = last_o
         self._slot_keys = keys_o
         self._lengths_dev = lens_o
@@ -3895,7 +4030,7 @@ class ContinuousBatchingEngine:
             row_attrs = {slot: {"blocks": int(toks2d[slot, 0] >= 0),
                                 "row_forwards": int(ran[slot])}
                          for slot in decode_rows}
-        self._emit_decode_spans(wall0, (t2 - t0) * 1000.0, lookahead=False,
+        self._emit_decode_spans(wall0, round_ms, lookahead=False,
                                 rows=decode_rows, tokens=1, depth=spanned,
                                 row_tokens=row_tokens, row_attrs=row_attrs)
         # acceptance accounting BEFORE the emit loop (a mid-row finish
@@ -3921,6 +4056,7 @@ class ContinuousBatchingEngine:
                 state.spec_accepted += a
             bump_counter("llm_spec_tokens_proposed_total", n=round_proposed)
             bump_counter("llm_spec_tokens_accepted_total", n=round_accepted)
+        self._clock.to("emit")
         for slot, state, chunk in plan:
             state.prefill_pos += chunk
             state.prefill_chunks += 1
@@ -3938,7 +4074,7 @@ class ContinuousBatchingEngine:
                 get_global_tracer().emit_span(
                     "llm.prefill_chunk", traceparent=state.trace,
                     start_unix_ns=int(wall0 * 1e9),
-                    duration_ms=(t2 - t0) * 1000.0,
+                    duration_ms=round_ms,
                     request_id=state.request_id, slot=slot, tokens=chunk)
         for slot, state in finals:
             self._finish_prefill(
@@ -3968,9 +4104,7 @@ class ContinuousBatchingEngine:
         # a host-fallback stop during the emit stales the spanned suffix
         if self._ring and self._ring[0].epoch != self._epoch:
             self._discard_ring()
-        t3 = time.monotonic()
-        self._record_round((t1 - t0) * 1000.0, (t2 - t1) * 1000.0,
-                           (t3 - t2) * 1000.0, lookahead=False, ts=wall0,
+        self._record_round(lookahead=False, ts=wall0,
                            mixed=bool(plan),
                            chunk_tokens=sum(c for _, _, c in plan),
                            depth=spanned,
@@ -3997,7 +4131,7 @@ class ContinuousBatchingEngine:
             # draft survives planning (budget/pages/limits).
             if self._decode_round_mixed(spec_only=True):
                 return
-        t0 = time.monotonic()
+        t0 = self._clock.to("capacity")
         wall0 = time.time()
         depth = self._lookahead_depth
         # an epoch bump since dispatch (preempt/host-fallback stop/handoff
@@ -4012,13 +4146,16 @@ class ContinuousBatchingEngine:
             if not self.active.any():
                 return  # everyone got preempted
             self._ring.append(self._dispatch_chunk(after=None))
-        t1 = time.monotonic()
         # top up the ring: chain chunks off the tail until depth is reached
-        # (each extension re-validates epoch + page-chain coverage)
-        while len(self._ring) <= depth and self._can_extend_ring():
+        # (each extension re-validates epoch + page-chain coverage, which
+        # is capacity work; the dispatch switches to upload and launch)
+        while len(self._ring) <= depth:
+            self._clock.to("capacity")
+            if not self._can_extend_ring():
+                break
             self._ring.append(self._dispatch_chunk(after=self._ring[-1]))
             self._lookahead_stats["dispatched"] += 1
-        t2 = time.monotonic()
+        self._clock.to("drain")
         inflight = self._ring.popleft()
         ring_depth = len(self._ring)  # chunks still in flight while we emit
         # armed raise here models a device fault at the chunk readback: the
@@ -4026,8 +4163,9 @@ class ContinuousBatchingEngine:
         # stream (the replica pool's failover trigger)
         failpoint("scheduler.readback")
         chunk = np.asarray(inflight.chunk_dev, np.int32)  # sync-point: the ONE sanctioned decode-loop drain (AS04)
-        t3 = time.monotonic()
-        self.readback_wait_samples.append((t3 - t2) * 1000.0)
+        # the ring's last chunk: the device waits from here to the next launch
+        round_ms = (self._clock.to("commit", starved=not self._ring)
+                    - t0) * 1000.0
         self._depth_hist[ring_depth] = self._depth_hist.get(ring_depth, 0) + 1
         block_out = None
         chunk, local = self._take_moe_counters(chunk, self._k_steps,
@@ -4040,29 +4178,29 @@ class ContinuousBatchingEngine:
             old_lengths = self._commit_chunk(inflight, commits)
             self._count_attn_pages(old_lengths, committed)
             self._emit_decode_spans(
-                wall0, (t3 - t0) * 1000.0, used_lookahead, depth=ring_depth,
+                wall0, round_ms, used_lookahead, depth=ring_depth,
                 row_tokens={s: int(c) * self._block
                             for s, c in enumerate(commits)},
                 row_attrs={s: {"blocks": int(c), "row_forwards": int(ran[s])}
                            for s, c in enumerate(commits)},
                 round_attrs=round_attrs)
+            self._clock.to("emit")
             block_out = self._emit_block_chunk(chunk, ran, old_lengths,
                                                depth=ring_depth)
         else:
             old_lengths = self._commit_chunk(inflight)
             self._count_attn_pages(old_lengths, chunk >= 0)
             self._emit_decode_spans(
-                wall0, (t3 - t0) * 1000.0, used_lookahead, depth=ring_depth,
+                wall0, round_ms, used_lookahead, depth=ring_depth,
                 round_attrs=round_attrs)
+            self._clock.to("emit")
             self._emit_chunk(chunk, old_lengths, depth=ring_depth)
-        t4 = time.monotonic()
         # a host-fallback stop just changed the world — the ring suffix is
         # stale (device-predicted finishes leave the epoch alone, so the
         # ring survives them; that is the deep-lookahead win)
         if self._ring and self._ring[0].epoch != self._epoch:
             self._discard_ring()
-        self._record_round((t2 - t0) * 1000.0, (t3 - t2) * 1000.0,
-                           (t4 - t3) * 1000.0, used_lookahead, ts=wall0,
+        self._record_round(used_lookahead, ts=wall0,
                            depth=ring_depth, block_out=block_out,
                            local_assignments=local)
 

@@ -441,6 +441,39 @@ def test_chrome_trace_export_shape():
     assert all(isinstance(e["dur"], float) and e["dur"] >= 0 for e in slices)
 
 
+def test_chrome_trace_draws_a_records_phases():
+    """A record with ``phases`` draws them, in their recorded order and
+    lengths, back to back over its pass, which ends where the round does."""
+    from cyberfabric_core_tpu.modules.monitoring import _chrome_trace
+
+    phases = {"emit": [0.25, 0.2, 0.0], "service": [0.5, 0.5, 0.5],
+              "admit": [1.0, 0.4, 1.0], "capacity": [0.5, 0.5, 0.5],
+              "launch": [1.5, 1.0, 1.25], "drain": [7.0, 0.1, 0.0],
+              "commit": [0.75, 0.75, 0.0]}
+    record = {"ts": 2000.0, "admit_ms": 1.0, "dispatch_ms": 2.0,
+              "sync_wait_ms": 7.0, "host_emit_ms": 1.0, "lookahead": False,
+              "active": 2, "phases": phases, "pass_ms": 11.5}
+    doc = _chrome_trace({"local::tiny-llama": [record]})
+    slices = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+    assert [e["name"] for e in slices] == list(phases)
+    assert [e["dur"] for e in slices] == [v[0] * 1000.0
+                                          for v in phases.values()]
+    # back to back, and the last ends with the round: ts + 2 + 7 + 1 ms
+    for a, b in zip(slices, slices[1:]):
+        assert b["ts"] == pytest.approx(a["ts"] + a["dur"])
+    assert slices[-1]["ts"] + slices[-1]["dur"] == pytest.approx(
+        2000.0 * 1e6 + 10000.0)
+    assert slices[0]["ts"] == pytest.approx(2000.0 * 1e6 + 10000.0 - 11500.0)
+    launch = next(e for e in slices if e["name"] == "launch")
+    assert launch["args"]["cpu_ms"] == 1.0
+    assert launch["args"]["starved_ms"] == 1.25
+    # one track a phase, named
+    tracks = {e["args"]["name"]: e["tid"] for e in doc["traceEvents"]
+              if e["ph"] == "M" and e["name"] == "thread_name"}
+    assert set(tracks) == set(phases)
+    assert all(e["tid"] == tracks[e["name"]] for e in slices)
+
+
 def test_traceparent_ids_parser():
     tid, sampled = traceparent_ids(f"00-{'ab' * 16}-{'cd' * 8}-01")
     assert tid == "ab" * 16 and sampled is True

@@ -231,7 +231,7 @@ def test_engine_supervisor_rebuild_backoff_bench_and_reset():
         return eng
 
     sup = EngineSupervisor(build, LifecycleConfig(
-        rebuild_backoff_s=0.01, rebuild_backoff_max_s=0.02, max_strikes=2,
+        rebuild_backoff_s=0.2, rebuild_backoff_max_s=0.4, max_strikes=2,
         backoff_jitter=0.0), name="t")
     healthy = _FakeEngine()
     assert sup.ensure(healthy) is healthy  # no-op on a servable engine
@@ -244,7 +244,7 @@ def test_engine_supervisor_rebuild_backoff_bench_and_reset():
     with pytest.raises(ReplicaUnavailable) as ei:
         sup.ensure(broken)  # inside the backoff window
     assert ei.value.retry_after_s is not None
-    time.sleep(0.025)
+    time.sleep(0.45)    # past the window (10 ms windows lost a race under load)
     fresh = sup.ensure(broken)
     assert fresh is built[-1] and fresh.started
     sup.note_ok()

@@ -201,7 +201,7 @@ def test_profiler_stop_failure_recoverable(tmp_path, monkeypatch):
 
     calls = []
     monkeypatch.setattr(jax.profiler, "start_trace",
-                        lambda d: calls.append(("start", d)))
+                        lambda d, **options: calls.append(("start", d)))
 
     def failing_stop():
         calls.append(("stop",))

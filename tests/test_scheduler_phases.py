@@ -1,0 +1,268 @@
+"""The scheduler's phase clock (runtime/scheduler.py: _PhaseClock).
+
+The loop is TILED by phases: every record's ``phases`` sum to its
+``pass_ms``, consecutive records sum to the thread's time, the four stage
+fields are sums over the phases they cover, and the ``starved`` flag (nothing
+launched is undrained: the device waits for the host) rises at a drain that
+empties the ring and falls at the next launch. With a profiler running the
+same phases are ``sched.*`` spans on a line of the trace's ``/host:CPU``
+plane."""
+
+import time
+
+import numpy as np
+import pytest
+
+from cyberfabric_core_tpu.modkit.metrics import default_registry
+from cyberfabric_core_tpu.runtime import EngineConfig, SamplingParams
+from cyberfabric_core_tpu.runtime.scheduler import (PHASES,
+                                                    ContinuousBatchingEngine,
+                                                    _PhaseClock)
+
+MODELS = {
+    "tiny-llama": dict(decode_chunk=4),
+    # a block model: a decode step yields a block
+    "tiny-sdar": dict(decode_chunk=10, prefill_budget_tokens=32),
+    # a latent page, one chip's share of the experts
+    "tiny-kimi-share4": dict(decode_chunk=4, prefill_budget_tokens=32,
+                             quantization="int8"),
+}
+STAGES = {"admit_ms": ("admit",),
+          "dispatch_ms": ("capacity", "plan", "upload", "launch"),
+          "sync_wait_ms": ("drain",),
+          "host_emit_ms": ("commit", "emit")}
+
+
+def _manual(model="tiny-llama", **over):
+    base = dict(model=model, max_seq_len=128, max_batch=4, use_flash=False,
+                prefix_cache_pages=80, prefix_page_size=16,
+                **MODELS[model])
+    base.update(over)
+    eng = ContinuousBatchingEngine(EngineConfig(**base), seed=0)
+    eng.start = lambda: None    # no thread: the test makes the loop's passes
+    return eng
+
+
+def _submit(eng, n, max_tokens=12, prompt=20, done=None):
+    done = [] if done is None else done
+    rng = np.random.default_rng(5)
+    for i in range(n):
+        eng.submit(rng.integers(3, 200, prompt + 3 * i).tolist(),
+                   SamplingParams(max_tokens=max_tokens),
+                   lambda ev: done.append(1) if ev.finished else None)
+    return done
+
+
+def _run(eng, done, n, limit=400):
+    for _ in range(limit):
+        eng._loop_pass()
+        if len(done) >= n:
+            return
+    raise AssertionError(f"not finished in {limit} passes: {eng.stats()}")
+
+
+@pytest.fixture(scope="module", params=sorted(MODELS))
+def served(request):
+    """Three requests served by hand-made passes; the records, and the
+    thread's time from the clock's reset to the last record."""
+    eng = _manual(request.param)
+    done = _submit(eng, 3)
+    _, _, t0 = eng._clock.take()          # the pass starts here
+    _run(eng, done, 3)
+    records = list(eng.round_timings)
+    elapsed_ms = (eng.last_round_at - t0) * 1000.0
+    eng.shutdown()
+    assert len(records) >= 4
+    return records, elapsed_ms
+
+
+def test_phases_tile_every_pass(served):
+    records, _ = served
+    for r in records:
+        assert set(r["phases"]) <= set(PHASES)
+        walls = sum(v[0] for v in r["phases"].values())
+        assert walls == pytest.approx(r["pass_ms"], rel=0.01, abs=0.002)
+
+
+def test_passes_sum_to_the_threads_time(served):
+    records, elapsed_ms = served
+    assert sum(r["pass_ms"] for r in records) == pytest.approx(elapsed_ms,
+                                                                rel=0.02)
+
+
+def test_stage_fields_are_their_phases_sums(served):
+    records, _ = served
+    for r in records:
+        for field, phases in STAGES.items():
+            want = sum(r["phases"][p][0] for p in phases if p in r["phases"])
+            assert r[field] == pytest.approx(want, abs=0.002), (field, r)
+
+
+def test_cpu_and_starved_lie_inside_wall(served):
+    records, _ = served
+    for r in records:
+        for phase, (wall, cpu, starved) in r["phases"].items():
+            assert 0.0 <= cpu <= wall and 0.0 <= starved <= wall, (phase, r)
+    # a round ran in every record: it launched, drained, committed, emitted
+    assert all({"launch", "drain", "commit", "emit"} <= set(r["phases"])
+               for r in records)
+
+
+def test_clock_switches_tile_and_starve():
+    """The clock alone, under a scripted sequence of switches."""
+    clock = _PhaseClock("scripted")
+    assert clock.starved and clock.phase == "wait"    # nothing launched yet
+    _, _, t0 = clock.take()
+    clock.to("service")
+    clock.to("launch")
+    time.sleep(0.002)
+    clock.to("launch", starved=False)     # the launch returned
+    time.sleep(0.002)
+    clock.to("drain")
+    time.sleep(0.002)
+    clock.to("commit", starved=False)     # a chunk is still in flight
+    time.sleep(0.002)
+    clock.to("drain")
+    clock.to("commit", starved=True)      # that drain emptied the ring
+    time.sleep(0.002)
+    clock.to("emit")
+    phases, pass_ms, t1 = clock.take()
+    clock.close()
+    assert list(phases) == ["wait", "service", "launch", "drain", "commit",
+                            "emit"]
+    assert sum(v[0] for v in phases.values()) == pytest.approx(pass_ms,
+                                                               rel=0.01)
+    assert pass_ms == pytest.approx((t1 - t0) * 1000.0, rel=0.01)
+    assert phases["service"][2] == phases["service"][0]   # still starved
+    assert 2.0 <= phases["launch"][2] <= phases["launch"][0] - 2.0
+    assert phases["drain"][2] == 0.0
+    assert 2.0 <= phases["commit"][2] <= phases["commit"][0] - 2.0
+    assert phases["emit"][2] == phases["emit"][0]
+    assert all(cpu <= wall for wall, cpu, _ in phases.values())
+
+
+def _starved_of(record, *phases):
+    return sum(record["phases"][p][2] for p in phases
+               if p in record["phases"])
+
+
+def test_starved_follows_the_ring():
+    """With a ring two deep a steady decode round drains with chunks still
+    in flight and is never starved; the drain that empties the ring (an
+    arrival waits for it) raises the flag, and the arrival's launch drops
+    it."""
+    eng = _manual(decode_lookahead=2)
+    try:
+        done = _submit(eng, 1, max_tokens=60)
+        assert eng._clock.starved             # nothing launched yet
+        eng._loop_pass()                      # admit + the mixed step
+        first = eng.round_timings[-1]
+        assert first["kind"] == "prefill" and first["depth"] == 2
+        # chunks were chained off the mixed step: its drain left them in
+        # flight, so nothing after the launch's return is starved
+        assert not eng._clock.starved
+        assert _starved_of(first, "service", "admit", "capacity", "plan",
+                           "upload") > 0.0
+        assert 0.0 < first["phases"]["launch"][2] <= first["phases"]["launch"][0]
+        assert _starved_of(first, "drain", "commit") == 0.0
+        for _ in range(3):                    # steady state: ring topped up
+            eng._loop_pass()
+            steady = eng.round_timings[-1]
+            assert steady["kind"] == "decode" and steady["depth"] >= 1
+            assert _starved_of(steady, *PHASES) == 0.0
+            assert not eng._clock.starved
+        # an arrival: the ring is no longer topped up and drains down
+        _submit(eng, 1, max_tokens=4, done=done)
+        while eng._ring:
+            eng._loop_pass()
+        last = eng.round_timings[-1]
+        assert last["depth"] == 0 and eng._clock.starved
+        assert last["phases"]["drain"][2] == 0.0
+        assert last["phases"]["commit"][2] == last["phases"]["commit"][0] > 0
+        assert _starved_of(last, "emit") > 0.0
+        eng._loop_pass()                      # the arrival's mixed step
+        arrival = eng.round_timings[-1]
+        assert arrival["kind"] == "mixed"
+        assert arrival["phases"]["admit"][2] == arrival["phases"]["admit"][0]
+        assert arrival["phases"]["upload"][2] > 0.0
+        assert arrival["phases"]["drain"][2] == 0.0
+        _run(eng, done, 2)
+    finally:
+        eng.shutdown()
+
+
+def test_no_lookahead_starves_between_every_two_rounds():
+    """Without a ring every drain empties it: the host's commit and emit,
+    and the next round up to its launch, are all the device's wait."""
+    eng = _manual(decode_lookahead=0)
+    try:
+        done = _submit(eng, 2, max_tokens=16)
+        before = _starved_seconds("tiny-llama")
+        _run(eng, done, 2)
+        records = [r for r in eng.round_timings if r["kind"] == "decode"]
+        assert len(records) >= 3
+        for r in records[1:]:
+            for phase in ("commit", "service", "admit", "capacity"):
+                assert r["phases"][phase][2] == r["phases"][phase][0]
+            assert r["phases"]["drain"][2] == 0.0
+        # /metrics carries the same seconds, by model and phase
+        after = _starved_seconds("tiny-llama")
+        grew = {p: after.get(p, 0.0) - before.get(p, 0.0) for p in after}
+        assert grew["commit"] > 0.0 and grew["emit"] > 0.0
+        assert grew.get("drain", 0.0) == 0.0
+        recorded = sum(v[2] for r in eng.round_timings
+                       for v in r["phases"].values()) / 1000.0
+        assert sum(grew.values()) >= 0.95 * recorded
+    finally:
+        eng.shutdown()
+
+
+def _starved_seconds(model):
+    counter = default_registry.counter("llm_device_starved_seconds_total")
+    return {labels["phase"]: value for labels, value in counter.samples()
+            if labels.get("model") == model}
+
+
+def test_phases_are_spans_on_the_profilers_host_plane(tmp_path):
+    """With jax.profiler running, the phases of a few rounds are ``sched.*``
+    events on ONE line of the trace's /host:CPU plane, and they tile that
+    thread's time: each starts where the one before ended."""
+    import jax
+    from jax.profiler import ProfileData
+
+    eng = _manual(decode_lookahead=0)
+    try:
+        done = _submit(eng, 2, max_tokens=24)
+        for _ in range(3):                 # compile outside the trace
+            eng._loop_pass()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            for _ in range(5):
+                eng._loop_pass()
+        finally:
+            jax.profiler.stop_trace()
+        _run(eng, done, 2)
+    finally:
+        eng.shutdown()
+    path = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    host = next(p for p in ProfileData.from_file(str(path)).planes
+                if p.name == "/host:CPU")
+    lines = []
+    for line in host.lines:
+        spans = sorted((int(e.start_ns), int(e.start_ns + e.duration_ns),
+                        e.name) for e in line.events
+                       if e.name.startswith("sched."))
+        # (an idle engine another test of this process left running has a
+        # line too: service, admit, wait, ten times a second)
+        if any(name.startswith("sched.drain") for _, _, name in spans):
+            lines.append(spans)
+    assert len(lines) == 1, "the rounds' spans lie on one thread's line"
+    spans = lines[0]
+    names = {name for _, _, name in spans}
+    assert {n.split(".")[1] for n in names} <= set(PHASES)
+    assert "sched.drain" in names and "sched.emit.starved" in names
+    assert "sched.drain.starved" not in names
+    covered = sum(e - s for s, e, _ in spans)
+    assert covered >= 0.98 * (spans[-1][1] - spans[0][0])
+    # a switch, not a nest: no span starts inside another
+    assert all(b[0] >= a[1] - 1 for a, b in zip(spans, spans[1:]))
