@@ -254,7 +254,7 @@ def forward_paged_decode(
     pid, off = _decode_targets(page_table, lengths, write_mask,
                                pools[0].shape[2])
     attend = _decode_attend(cfg, interpret, None)
-    work = decode_work(cfg, page_table, lengths + 1, pools[0].shape[2])
+    work = decode_work(cfg, page_table, lengths + 1, pools[0])
 
     h = _embed_scale(embed_lookup(params["embed"], input_ids,
                                   params["final_norm"].dtype), cfg)
@@ -318,7 +318,7 @@ def forward_paged_mixed(
     cos_t, sin_t = rope_tables
     R, Qc = input_ids.shape
     lay = mixed_layout(cfg, input_ids, page_table, hist, q_lens, write_mask,
-                       rows, decode, pools[0].shape[2])
+                       rows, decode, pools[0])
     nd = lay.n_dec
     lane_attend = _ragged_attend(cfg, interpret, None)
     decode_attend = _decode_attend(cfg, interpret, None)

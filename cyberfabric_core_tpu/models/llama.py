@@ -474,21 +474,42 @@ def _decode_attend(cfg: ModelConfig, interpret: bool, mesh):
     from jax.sharding import PartitionSpec as P
 
     return _shard_mapped_attn(
-        mesh, attend, P(None, "tp", None), (DecodeWork(*[P()] * 5),))
+        mesh, attend, P(None, "tp", None),
+        (DecodeWork(*[P()] * len(DecodeWork._fields)),))
 
 
-def decode_work(cfg: ModelConfig, page_table, lengths, page_size: int):
+def decode_page_group(cfg: ModelConfig, page_size: int, n_pages: int,
+                      itemsize: int) -> int:
+    """Pages one program of ``cfg``'s decode kernel takes, over a table of
+    ``n_pages`` slots a row whose pool holds ``itemsize`` bytes a number:
+    what :func:`decode_work` builds its list with, and what the scheduler
+    counts the kernel's programs by."""
+    if cfg.is_latent:
+        from ..ops.mla_attention import PAGE_GROUP
+
+        return PAGE_GROUP
+    from ..ops.paged_attention import decode_page_group as by_shapes
+
+    return by_shapes(page_size, cfg.num_kv_heads * cfg.head_dim, itemsize,
+                     cfg.num_heads * cfg.block_length, n_pages)
+
+
+def decode_work(cfg: ModelConfig, page_table, lengths, pool):
     """The decode kernel's work list for one step: ``lengths`` [B] counts
-    the tokens the step itself writes. The same for every layer, so it is
-    built here, outside the scan over layers."""
-    if cfg.is_latent:       # the same pages, a slot's several at a time
+    the tokens the step itself writes, ``pool`` is the cache the kernel
+    reads (its page size and its bytes a number pick the group). The same
+    for every layer, so it is built here, outside the scan over layers."""
+    page_size = pool.shape[2]
+    group = decode_page_group(cfg, page_size, page_table.shape[1],
+                              pool.dtype.itemsize)
+    if cfg.is_latent:       # the same pages, no window
         from ..ops.mla_attention import latent_work_list
 
-        return latent_work_list(page_table, lengths, page_size)
+        return latent_work_list(page_table, lengths, page_size, group)
     from ..ops.paged_attention import decode_work_list
 
     return decode_work_list(page_table, lengths, page_size,
-                            cfg.sliding_window)
+                            cfg.sliding_window, group)
 
 
 def _ragged_attend(cfg: ModelConfig, interpret: bool, mesh):
@@ -569,7 +590,7 @@ def forward_paged_decode(
     positions = lengths[:, None]
     pid, off = _decode_targets(page_table, lengths, write_mask, page_size)
     attend = _decode_attend(cfg, interpret, mesh)
-    work = decode_work(cfg, page_table, lengths + 1, page_size)
+    work = decode_work(cfg, page_table, lengths + 1, pools[0])
 
     h = _embed_scale(embed_lookup(params["embed"], input_ids, params["final_norm"].dtype), cfg)
 
@@ -621,8 +642,10 @@ class MixedLayout(NamedTuple):
 
 def mixed_layout(cfg: ModelConfig, input_ids, page_table, hist, q_lens,
                  write_mask, rows, decode: DecodeGroup | None,
-                 page_size: int) -> MixedLayout:
-    """Lay a mixed step's tokens out (see :func:`forward_paged_mixed`)."""
+                 pool) -> MixedLayout:
+    """Lay a mixed step's tokens out (see :func:`forward_paged_mixed`);
+    ``pool`` is the cache the attention kernels read."""
+    page_size = pool.shape[2]
     R, Qc = input_ids.shape
     lane_table = page_table if rows is None else page_table[rows]
     offs = jnp.arange(Qc, dtype=jnp.int32)[None, :]            # [1, Qc]
@@ -642,7 +665,7 @@ def mixed_layout(cfg: ModelConfig, input_ids, page_table, hist, q_lens,
         n_dec = decode.tokens.size
         width = decode.tokens.shape[1] if decode.tokens.ndim == 2 else None
         work = decode_work(cfg, page_table, decode.lengths + (width or 1),
-                           page_size)
+                           pool)
         d_pid, d_off = _decode_targets(page_table, decode.lengths, decode.run,
                                        page_size, width)
         d_pos = decode.lengths if width is None else (
@@ -737,7 +760,7 @@ def forward_paged_mixed(
     cos_t, sin_t = rope_tables
     pools, caller_shape = _merged_pools(pools)
     lay = mixed_layout(cfg, input_ids, page_table, hist, q_lens, write_mask,
-                       rows, decode, pools[0].shape[2])
+                       rows, decode, pools[0])
     lane_attend = _ragged_attend(cfg, interpret, mesh)
     decode_attend = _decode_attend(cfg, interpret, mesh)
 

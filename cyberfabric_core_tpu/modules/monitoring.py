@@ -425,9 +425,13 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                  "slot's device rows (restore_row at a resume or a handoff "
                  "import; none in steady serving)"),
                 ("llm_attn_pages_walked_total",
-                 "Grid programs the paged decode kernel launched: one for "
-                 "every page that holds tokens a row's query reads, summed "
-                 "over steps and layers"),
+                 "Pages the paged decode kernel's grid walked: every page "
+                 "that holds tokens a row's query reads, summed over steps "
+                 "and layers"),
+                ("llm_attn_page_groups_total",
+                 "Grid programs the paged decode kernel launched for them: "
+                 "one for every group of a row's pages, every row at least "
+                 "one"),
                 ("llm_attn_pages_offered_total",
                  "Slots of the page table (rows x pages a row) beside "
                  "them, for the same calls"),

@@ -1,22 +1,30 @@
 """Pallas ragged paged-attention kernel for decode (T=1) over a paged KV pool.
 
 The decode hot loop reads each sequence's KV history through a page table
-instead of a dense per-slot cache. The grid is one program for every page
-that holds tokens a slot's query reads, and no other:
+instead of a dense per-slot cache. The grid is one program for every GROUP
+of consecutive pages that hold tokens a slot's query reads, and no other:
 
 1. once a step, outside the scan over layers, ``decode_work_list`` flattens
-   the slots' page spans into one list of (slot, logical page, physical
-   page) items, slots in order and a slot's pages ascending (a sliding
-   window leaves a slot's first pages out; an empty slot keeps one item,
-   which computes nothing and finalises to zeros). The list rides in scalar
-   prefetch (SMEM) and its length is the grid's bound, known only when the
-   step runs: a table of ``B x Pmax`` slots of which a sixth holds tokens
-   launches a sixth of the programs;
-2. the BlockSpec index maps read an item's physical page and slot from the
-   list, so the pipeline DMAs exactly the pages the sequences own, each
-   once, and prefetches across the boundary between two slots;
-3. a slot's items accumulate flash-style online softmax (f32 m/l/acc
-   scratch), initialised at its first page and finalised at its last.
+   the slots' page spans into one list of (slot, first logical page,
+   ``group`` physical pages) items, slots in order and a slot's groups
+   ascending from its span's first page (a sliding window leaves a slot's
+   first pages out; an empty slot keeps one item, which computes nothing and
+   finalises to zeros). The list rides in scalar prefetch (SMEM) and its
+   length is the grid's bound, known only when the step runs: a table of
+   ``B x Pmax`` slots of which a sixth holds tokens launches a sixth of the
+   programs. ``decode_page_group`` picks the group from the page's bytes
+   and the query rows: 4 pages at 8 kv heads of 128, 8 at 4;
+2. the pools are passed once for every page of a group, each with a
+   one-page BlockSpec whose index map reads that page from the list, so the
+   pipeline DMAs exactly the pages the sequences own, each once, and
+   prefetches across the boundary between two slots. Where a slot's last
+   group runs past its span, the spare operand names the page the same
+   operand held in the item before and moves no bytes;
+3. a program joins its pages' rows into ONE block of keys: one score dot,
+   one mask (by position, so a spare operand's rows never count), one
+   online-softmax update (f32 m/l/acc scratch) and one value dot a kv head,
+   with MXU tiles that 128 and more keys fill; the accumulators are
+   initialised at a slot's first group and finalised at its last.
 
 (The ragged kernel below still launches a program for every slot of the
 table and skips the ones past a row's span, their DMA elided by an index map
@@ -95,41 +103,55 @@ def page_span(length, page_size: int, n_pages: int,
     span (0, 0). Scalars in the kernel, ``[B]`` arrays in the work list,
     NumPy arrays where the host counts what the grid walked."""
     last = ((length - 1) // page_size).clip(0, n_pages - 1)
+    return _span_first(length, page_size, last, sliding_window), last
+
+
+def _span_first(length, page_size: int, last, sliding_window: int | None):
+    """The first page of the span that ends at page ``last``."""
     if sliding_window is None:
-        return last * 0, last
+        return last * 0
     # keys <= length - 1 - window are out, so page j is in while
     # (j + 1) * page_size > length - window
-    return ((length - sliding_window) // page_size).clip(0, last), last
+    return ((length - sliding_window) // page_size).clip(0, last)
 
 
-def _paged_kernel(row_ref, page_ref, phys_ref, len_ref, layer_ref, q_ref,
-                  k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                  page_size: int, n_pages: int,
+def _paged_kernel(row_ref, page_ref, phys_ref, len_ref, last_ref, layer_ref,
+                  q_ref, *rest, page_size: int, group: int,
                   sliding_window: int | None = None,
                   two_d_dots: bool = False):
-    """One work item: one page that holds tokens of one slot.
+    """One work item: a GROUP of consecutive pages of one slot.
 
     Refs:
-      row_ref, page_ref: [B*Pmax] int32 SMEM (scalar prefetch) — the item's
-        slot and its logical page (:class:`DecodeWork`)
-      phys_ref: [B*Pmax] int32 SMEM — read by the index maps only
+      row_ref, page_ref: [N] int32 SMEM (scalar prefetch) — the item's slot
+        and the first logical page of its group (:class:`DecodeWork`)
+      phys_ref: [N*group] int32 SMEM — read by the index maps only
       len_ref: [B] int32 SMEM — valid kv length per slot (incl. current token)
+      last_ref: [B] int32 SMEM — the last page of each slot's span
       layer_ref: [1] int32 SMEM — read by the index maps only
-      q_ref:   [1, Hq, D] VMEM; k_ref/v_ref: [1, 1, page, Hkv*D] VMEM
+      q_ref:   [1, Hq, D] VMEM; then ``group`` key refs and ``group`` value
+        refs, [1, 1, page, Hkv*D] VMEM each: the group's pages, in order
       o_ref:   [1, Hq, D] VMEM
       acc_ref: [Hq, D] f32; m_ref/l_ref: [Hq, LANES] f32
+
+    The group's keys are ONE block of ``group * page`` rows: one score dot,
+    one mask, one online-softmax update and one value dot a kv head. A
+    slot's last group may hold fewer pages than ``group``; what its other
+    operands hold lies past the slot's length and is masked by position.
 
     ``two_d_dots`` replaces the batched GQA dot_generals (and their rank-3
     operand transposes) with unrolled per-kv-head 2D dots — the form Mosaic
     can lower (its dot supports only 2D tensors); bitwise-identical to the
     batched form, which interpret mode keeps for tier-1 wall-clock.
     """
+    k_refs, v_refs = rest[:group], rest[group:2 * group]
+    o_ref, acc_ref, m_ref, l_ref = rest[2 * group:]
     i = pl.program_id(0)
     j = page_ref[i]
     length = len_ref[row_ref[i]]
-    first, last = page_span(length, page_size, n_pages, sliding_window)
+    last = last_ref[row_ref[i]]
+    keys = group * page_size
 
-    @pl.when(j == first)
+    @pl.when(j == _span_first(length, page_size, last, sliding_window))
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
@@ -137,10 +159,21 @@ def _paged_kernel(row_ref, page_ref, phys_ref, len_ref, layer_ref, q_ref,
 
     k_start = j * page_size
 
+    def rows_of(refs, lanes):
+        """The group's pages as one block of ``keys`` rows (a lane slice of
+        each, taken at the ref: see :func:`_banded_scores_2d`; a page of 16
+        rows or a multiple is whole 16-bit sublane tiles, so the join is no
+        multi-row shift)."""
+        parts = [r[0, 0, :, lanes] for r in refs]
+        return parts[0] if group == 1 else jnp.concatenate(parts, axis=0)
+
     @pl.when(k_start < length)      # every item but an empty slot's
     def _compute():
         q = q_ref[0]          # [Hq, D]
         Hq, D = q.shape
+        Hkv = k_refs[0].shape[3] // D
+        G = Hq // Hkv
+        head = lambda kv: slice(kv * D, (kv + 1) * D)  # noqa: E731
 
         if two_d_dots:
             # merged kv blocks ([1, 1, page, Hkv*D]): each head is a REF-level
@@ -151,32 +184,26 @@ def _paged_kernel(row_ref, page_ref, phys_ref, len_ref, layer_ref, q_ref,
             # lower fine). The retained elements are the same contractions
             # the batched dot computes: bitwise identical, a little
             # redundant MXU work on a tiny [Hq, D] operand.
-            Hkv = k_ref.shape[3] // D
-            G = Hq // Hkv
-            k_of = lambda kv: k_ref[0, 0, :, kv * D:(kv + 1) * D]  # noqa: E731
-            scores = jnp.concatenate([
-                jax.lax.dot_general(
-                    q, k_of(kv), (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)[kv * G:(kv + 1) * G]
-                for kv in range(Hkv)], axis=0) if Hkv > 1 \
-                else jax.lax.dot_general(
-                    q, k_of(0), (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)  # [Hq, page]
+            per_head = [jax.lax.dot_general(
+                q, rows_of(k_refs, head(kv)), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)[kv * G:(kv + 1) * G]
+                for kv in range(Hkv)]
+            scores = jnp.concatenate(per_head, axis=0) if Hkv > 1 \
+                else per_head[0]                     # [Hq, keys]
         else:
-            Hkv = k_ref.shape[3] // D
-            k = k_ref[0, 0].reshape(page_size, Hkv, D)
-            G = Hq // Hkv
+            k = rows_of(k_refs, slice(None)).reshape(keys, Hkv, D)
             qg = q.reshape(Hkv, G, D)
-            kt = jnp.transpose(k, (1, 2, 0))        # [Hkv, D, page]
+            kt = jnp.transpose(k, (1, 2, 0))        # [Hkv, D, keys]
             scores = jax.lax.dot_general(
                 qg, kt, (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)  # [Hkv, G, page]
-            scores = scores.reshape(Hq, page_size)
+                preferred_element_type=jnp.float32)  # [Hkv, G, keys]
+            scores = scores.reshape(Hq, keys)
         scores = scores * (1.0 / (D ** 0.5))
 
-        k_pos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (Hq, page_size), 1)
-        mask = k_pos < length
+        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (Hq, keys), 1)
+        # by position: the slot's own tokens, and nothing of a page past its
+        # span (a last group's spare operands; a length past the table)
+        mask = k_pos < jnp.minimum(length, (last + 1) * page_size)
         if sliding_window is not None:
             mask = mask & (k_pos > length - 1 - sliding_window)
         scores = jnp.where(mask, scores, _NEG_INF)
@@ -187,7 +214,7 @@ def _paged_kernel(row_ref, page_ref, phys_ref, len_ref, layer_ref, q_ref,
             m_blk, m_prev.shape, (0, 1)))
         m_ref[...] = m_new
         correction = jnp.exp(m_prev - m_new)                # [Hq, LANES]
-        p = jnp.exp(scores - m_new[:, :1])                  # [Hq, page]
+        p = jnp.exp(scores - m_new[:, :1])                  # [Hq, keys]
         p = jnp.where(mask, p, 0.0)
         l_blk = jnp.sum(p, axis=1, keepdims=True)
         l_ref[...] = l_ref[...] * correction + jax.lax.broadcast_in_dim(
@@ -195,54 +222,108 @@ def _paged_kernel(row_ref, page_ref, phys_ref, len_ref, layer_ref, q_ref,
         if two_d_dots:
             pv = _banded_weighted_v_2d(
                 p, [(kv * G, G, kv) for kv in range(Hkv)],
-                lambda kv: v_ref[0, 0, :, kv * D:(kv + 1) * D])
+                lambda kv: rows_of(v_refs, head(kv)))
         else:
-            v = v_ref[0, 0].reshape(page_size, Hkv, D)
-            pg = p.reshape(Hkv, G, page_size)
-            vt = jnp.transpose(v, (1, 0, 2))                # [Hkv, page, D]
+            v = rows_of(v_refs, slice(None)).reshape(keys, Hkv, D)
+            pg = p.reshape(Hkv, G, keys)
+            vt = jnp.transpose(v, (1, 0, 2))                # [Hkv, keys, D]
             pv = jax.lax.dot_general(
                 pg, vt.astype(pg.dtype), (((2,), (1,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32).reshape(Hq, D)
         acc_ref[...] = acc_ref[...] * correction[:, :1] + pv
 
-    @pl.when(j == last)
+    @pl.when(j + group > last)
     def _finalize():
         denom = jnp.maximum(l_ref[...][:, :1], 1e-30)
         o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 class DecodeWork(NamedTuple):
-    """The decode kernel's grid, flattened: one item for every page that
-    holds tokens a slot's query reads. Slots in order, a slot's pages
-    ascending, every slot at least one item (an empty slot's computes
-    nothing and finalises to zeros). The arrays are ``B * Pmax`` long, as
-    many as a full table needs; the grid runs the first ``n_items``."""
-    row: jnp.ndarray       # [B*Pmax] int32 the item's slot
-    page: jnp.ndarray      # [B*Pmax] int32 its logical page
-    phys: jnp.ndarray      # [B*Pmax] int32 page_table[row, page]
+    """The decode kernel's grid, flattened: one item for every GROUP of
+    consecutive pages that hold tokens a slot's query reads. Slots in order,
+    a slot's groups ascending from its span's first page, every slot at
+    least one item (an empty slot's computes nothing and finalises to
+    zeros). With ``N = B * ceil(Pmax / group)`` the arrays are as long as a
+    full table needs; the grid runs the first ``n_items``."""
+    row: jnp.ndarray       # [N] int32 the item's slot
+    page: jnp.ndarray      # [N] int32 the first logical page of its group
+    phys: jnp.ndarray      # [N*group] int32 the group's physical pages
     lengths: jnp.ndarray   # [B] int32 valid kv length (incl. current token)
+    last: jnp.ndarray      # [B] int32 the last page of the slot's span
     n_items: jnp.ndarray   # [] int32 items in use: the grid's bound
+
+    @property
+    def group(self) -> int:
+        """Pages an item takes (static: read off the arrays' shapes)."""
+        return self.phys.shape[0] // self.row.shape[0]
+
+
+#: K and V bytes one program should move. A program costs 0.30-0.35 us
+#: before it has moved a byte (the grid step, the m/l/acc round trip, tiles
+#: the MXU latches half full), what 1 MB takes to arrive four times over; a
+#: larger group buys under a tenth at a full table, and where rows hold a few
+#: pages it computes more keys that the mask then drops (PERF.md, PR 38)
+_GROUP_BYTES = 1 << 20
+#: VMEM a group may take, of the 16 MB a v5e kernel gets by default
+_GROUP_VMEM = 8 << 20
+
+
+def decode_page_group(page_size: int, kv_lanes: int, itemsize: int,
+                      q_rows: int, n_pages: int) -> int:
+    """Pages one program of the decode kernel takes: the largest power of
+    two (at most 8: 16 bought under 1% at a full table in three shapes of
+    four; and no more than a row of the table has) whose K and V bytes stay
+    within ``_GROUP_BYTES`` and whose blocks fit ``_GROUP_VMEM``.
+    ``kv_lanes`` is ``Hkv * D``, a pool row's numbers; ``q_rows`` the query
+    rows of a slot (``Hq``; an open block folds its width in). At 8 kv heads
+    of 128 in bfloat16 that is 4 pages, at 4 kv heads 8."""
+    page_bytes = page_size * kv_lanes * itemsize
+    # two pools, double-buffered, and as much again where a kv head's rows
+    # are joined and V is cast up; the f32 scores, their mask and ``p``
+    vmem = 4 * page_bytes + 3 * 4 * q_rows * page_size
+    group = 1
+    while (group < 8 and 2 * group <= n_pages
+           and 4 * group * page_bytes <= _GROUP_BYTES
+           and 2 * group * vmem <= _GROUP_VMEM):
+        group *= 2
+    return group
 
 
 def decode_work_list(page_table: jnp.ndarray, lengths: jnp.ndarray,
-                     page_size: int,
-                     sliding_window: int | None = None) -> DecodeWork:
+                     page_size: int, sliding_window: int | None = None,
+                     group: int = 1) -> DecodeWork:
     """The work list of one decode step (:class:`DecodeWork`) from
-    ``page_table`` [B, Pmax] and ``lengths`` [B] (incl. the current token).
-    It is the same for every layer: build it once a step, outside the scan
-    over layers."""
+    ``page_table`` [B, Pmax] and ``lengths`` [B] (incl. the current token),
+    a slot's span (:func:`page_span`) taken ``group`` pages at a time. It is
+    the same for every layer: build it once a step, outside the scan over
+    layers.
+
+    A slot's last group may run past its span. Such an operand names the
+    page the SAME operand held in the item before (the pipeline fetches a
+    block only where its index changed, so it moves no bytes), and the
+    kernel masks what it holds by position."""
     B, Pmax = page_table.shape
     lengths = jnp.asarray(lengths, jnp.int32)
     first, last = page_span(lengths, page_size, Pmax, sliding_window)
-    ends = jnp.cumsum(last - first + 1)                     # [B]
-    item = jnp.arange(B * Pmax, dtype=jnp.int32)
-    # items past n_items are never run; they name the last slot's last page
+    groups = (last - first) // group + 1                    # [B], >= 1
+    ends = jnp.cumsum(groups)
+    item = jnp.arange(B * -(-Pmax // group), dtype=jnp.int32)
+    # items past n_items are never run; they name the last slot's last group
     row = jnp.minimum(
         jnp.sum(item[:, None] >= ends[None, :], axis=1, dtype=jnp.int32),
         B - 1)
-    page = jnp.minimum(item - (ends - 1 - last)[row], last[row])
-    return DecodeWork(row, page, jnp.asarray(page_table, jnp.int32)[row, page],
-                      lengths, ends[-1])
+    page = first[row] + group * jnp.minimum(
+        item - (ends - groups)[row], groups[row] - 1)
+    lane = jnp.arange(group, dtype=jnp.int32)[None, :]
+    pages = page[:, None] + lane                            # [N, group]
+    in_span = pages <= last[row][:, None]
+    phys = jnp.asarray(page_table, jnp.int32)[
+        row[:, None], jnp.minimum(pages, last[row][:, None])]
+    # the newest item at or before this one whose operand held a page (the
+    # first item, where none has yet)
+    held_at = jax.lax.cummax(jnp.where(in_span, item[:, None], 0), axis=0)
+    phys = phys[held_at, lane]
+    return DecodeWork(row, page, phys.reshape(-1), lengths, last, ends[-1])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "sliding_window",
@@ -259,8 +340,10 @@ def paged_decode_attention(
 ) -> jnp.ndarray:
     """Returns [B, Hq, D] attention over each slot's paged history in layer
     ``layer`` of the pool. The pool operands reach the ``pallas_call`` as
-    they are passed: the layer and the page are both picked by the blocks'
-    index map. ``sliding_window`` is the one ``work`` was built with.
+    they are passed, once for every page of a group (``work.group``): the
+    layer and the page are both picked by the blocks' index maps, so the
+    pipeline DMAs whole pages and nothing pool-sized is sliced or copied.
+    ``sliding_window`` is the one ``work`` was built with.
 
     ``two_d_dots`` (default: on exactly when compiling for real — Mosaic's
     dot supports only 2D tensors) selects the unrolled per-kv-head 2D-dot
@@ -272,16 +355,21 @@ def paged_decode_attention(
         two_d_dots = not interpret
     B, Hq, D = q.shape
     _, _, page_size, HD = k_pool.shape
+    group = work.group
 
-    kv_spec = pl.BlockSpec(
-        (1, 1, page_size, HD),
-        lambda i, row, page, phys, ln, ly: (ly[0], phys[i], 0, 0))
+    def page_spec(g: int) -> pl.BlockSpec:
+        return pl.BlockSpec(
+            (1, 1, page_size, HD),
+            lambda i, row, page, phys, ln, last, ly: (
+                ly[0], phys[i * group + g], 0, 0))
+
     q_spec = pl.BlockSpec(
-        (1, Hq, D), lambda i, row, page, phys, ln, ly: (row[i], 0, 0))
+        (1, Hq, D), lambda i, row, page, phys, ln, last, ly: (row[i], 0, 0))
+    pages = [page_spec(g) for g in range(group)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=6,
         grid=(work.n_items,),
-        in_specs=[q_spec, kv_spec, kv_spec],
+        in_specs=[q_spec, *pages, *pages],
         out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((Hq, D), jnp.float32),
@@ -290,8 +378,7 @@ def paged_decode_attention(
         ],
     )
     return pl.pallas_call(
-        functools.partial(_paged_kernel, page_size=page_size,
-                          n_pages=work.row.shape[0] // B,
+        functools.partial(_paged_kernel, page_size=page_size, group=group,
                           sliding_window=sliding_window,
                           two_d_dots=two_d_dots),
         grid_spec=grid_spec,
@@ -300,8 +387,9 @@ def paged_decode_attention(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(work.row, work.page, work.phys, work.lengths,
-      jnp.asarray(layer, jnp.int32).reshape(1), q, k_pool, v_pool)
+    )(work.row, work.page, work.phys, work.lengths, work.last,
+      jnp.asarray(layer, jnp.int32).reshape(1), q,
+      *([k_pool] * group), *([v_pool] * group))
 
 
 def _ragged_kernel(pt_ref, hist_ref, qlen_ref, layer_ref, q_ref, k_ref, v_ref,

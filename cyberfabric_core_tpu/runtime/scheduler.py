@@ -1012,6 +1012,7 @@ class ContinuousBatchingEngine:
                        "llm_control_rows_uploads_total",
                        "llm_loose_row_programs_total",
                        "llm_attn_pages_walked_total",
+                       "llm_attn_page_groups_total",
                        "llm_attn_pages_offered_total") + (
                            _BLOCK_SERIES if self._block else ()
                        ) + _moe_series(self._moe_counters):
@@ -3352,14 +3353,16 @@ class ContinuousBatchingEngine:
 
     def _count_attn_pages(self, kept: np.ndarray, grew: np.ndarray) -> None:
         """/metrics of the decode kernel's grid over a drained dispatch: the
-        programs it launched, one for every page that holds tokens a row's
-        query reads, beside the page table's slots, summed over forwards and
-        layers. ``kept`` [B]: each row's length going in; ``grew``
-        [B, forwards]: whether that forward added a step's tokens to it (a
-        frozen row stops growing). A row that does not run sits at length 0
-        on the device and costs the one program every row has. Counted from
-        the host's mirror by the kernel's own :func:`page_span`, so a step
-        pays nothing for it."""
+        pages it walked (those that hold tokens a row's query reads) and the
+        programs it launched (one for every group of a row's pages, the
+        group the work list was built with), beside the page table's slots,
+        summed over forwards and layers. ``kept`` [B]: each row's length
+        going in; ``grew`` [B, forwards]: whether that forward added a
+        step's tokens to it (a frozen row stops growing). A row that does
+        not run sits at length 0 on the device and costs the one program
+        every row has. Counted from the host's mirror by the kernel's own
+        :func:`page_span`, so a step pays nothing for it."""
+        from ..models.llama import decode_page_group
         from ..ops.paged_attention import page_span
 
         step = self._step_tokens
@@ -3369,8 +3372,13 @@ class ContinuousBatchingEngine:
         first, last = page_span(lengths, self.config.prefix_page_size, slots,
                                 self.model_config.sliding_window)
         layers = self.model_config.num_layers
+        group = decode_page_group(
+            self.model_config, self.config.prefix_page_size, slots,
+            jnp.dtype(self.dtype).itemsize)
         bump_counter("llm_attn_pages_walked_total",
                      n=int((last - first + 1).sum()) * layers)
+        bump_counter("llm_attn_page_groups_total",
+                     n=int(((last - first) // group + 1).sum()) * layers)
         bump_counter("llm_attn_pages_offered_total",
                      n=lengths.size * slots * layers)
 

@@ -93,16 +93,18 @@ def test_flash_kernel_compiles_at_mistral_7b_shapes(one_chip, batch):
 ], ids=["mistral-7b", "qwen2-7b", "sdar-folded"])
 def test_paged_decode_kernel_compiles_at_served_shapes(
         one_chip, heads, kv_heads, batch, pmax, window):
-    """The grid over the pages in use: a work list in scalar prefetch and
+    """The grid over the pages in use, a slot's several to a program (the
+    group these shapes are served with): a work list in scalar prefetch and
     a grid bound that is known only when the step runs."""
     from cyberfabric_core_tpu.ops.paged_attention import (
-        decode_work_list, paged_decode_attention)
+        decode_page_group, decode_work_list, paged_decode_attention)
 
     pool = one_chip((2, batch * pmax * 5 // 4 + 1, _PAGE, kv_heads * _D),
                     jnp.bfloat16)
+    group = decode_page_group(_PAGE, kv_heads * _D, 2, heads, pmax)
     _compiles_with_mosaic(
         lambda q, k, v, pt, n, layer: paged_decode_attention(
-            q, k, v, decode_work_list(pt, n, _PAGE, window), layer,
+            q, k, v, decode_work_list(pt, n, _PAGE, window, group), layer,
             interpret=False, sliding_window=window, two_d_dots=True),
         one_chip((batch, heads, _D), jnp.bfloat16), pool, pool,
         one_chip((batch, pmax), jnp.int32), one_chip((batch,), jnp.int32),

@@ -220,8 +220,10 @@ def test_work_list_is_built_once_a_step(model, program):
     """The decode kernel's grid is laid out from the rows' lengths, which
     are the same for every layer: in the scheduler's own programs the one
     cumsum over the rows sits outside the scan over layers, and the kernel
-    inside it takes the list (a bound, three arrays of ``B x Pmax`` items,
-    the lengths) as it was built."""
+    inside it takes the list (a bound, a slot and a first page for every
+    group of pages a full table holds, the groups' pages, the rows' lengths
+    and last pages) as it was built."""
+    from cyberfabric_core_tpu.models.llama import decode_page_group
     from cyberfabric_core_tpu.runtime import EngineConfig
     from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
 
@@ -248,11 +250,14 @@ def test_work_list_is_built_once_a_step(model, program):
 
         body = layers.params["jaxpr"].jaxpr
         assert len(over_rows(jaxpr.jaxpr)) == 1 and not over_rows(body)
-        items = n * eng.page_table.shape[1]
+        slots = eng.page_table.shape[1]
+        group = decode_page_group(eng.model_config, 16, slots,
+                                  jnp.dtype(eng.dtype).itemsize)
+        items = n * -(-slots // group)
         decode, = [e for e in _find(body, "pallas_call")
                    if e.params["grid_mapping"].num_dynamic_grid_bounds]
-        assert [v.aval.shape for v in decode.invars[:5]] == [
-            (), (items,), (items,), (items,), (n,)]
+        assert group > 1 and [v.aval.shape for v in decode.invars[:6]] == [
+            (), (items,), (items,), (items * group,), (n,), (n,)]
     finally:
         eng.shutdown()
 

@@ -547,6 +547,7 @@ def test_the_rings_series_are_at_zero_from_engine_build():
              "llm_control_rows_uploads_total",
              "llm_loose_row_programs_total",
              "llm_attn_pages_walked_total",
+             "llm_attn_page_groups_total",
              "llm_attn_pages_offered_total")
     before = {n: _counter(n) for n in names}
     eng = _manual(_cfg(decode_lookahead=2))
@@ -579,8 +580,12 @@ def test_attn_pages_walked_is_what_the_rows_lengths_say(model):
     group idle (one program each), then each step of each chunk walks the
     pages the running row's tokens lie on, its own included, and one program
     for each of the three idle rows; a row the device froze inside a chunk
-    stays at the length it reached."""
-    names = ("llm_attn_pages_walked_total", "llm_attn_pages_offered_total")
+    stays at the length it reached. ``llm_attn_page_groups_total`` counts the
+    same steps' programs: a row's pages a group at a time."""
+    from cyberfabric_core_tpu.models.llama import decode_page_group
+
+    names = ("llm_attn_pages_walked_total", "llm_attn_pages_offered_total",
+             "llm_attn_page_groups_total")
     prompt, answer, page, k = 40, 11, 16, 4
     eng = _manual(_cfg(model=model, decode_lookahead=0))
     col = _Collector(1)
@@ -592,48 +597,71 @@ def test_attn_pages_walked_is_what_the_rows_lengths_say(model):
         rows, slots = eng.n_slots, eng.page_table.shape[1]
         layers, mixed = eng.model_config.num_layers, eng.mixed_rounds
         chunks = eng.decode_rounds - mixed
+        group = decode_page_group(eng.model_config, page, slots,
+                                  np.dtype(eng.dtype).itemsize)
     finally:
         eng.shutdown()
     assert col.finishes[0] == "length" and len(col.tokens[0]) == answer
     walked, length, left = mixed * rows, prompt, answer - 1
-    assert chunks == -(-left // k)
+    programs = walked
+    assert chunks == -(-left // k) and group > 1
     for _ in range(chunks * k):
-        walked += -(-(length + 1) // page) + rows - 1
+        pages = -(-(length + 1) // page)
+        walked += pages + rows - 1
+        programs += -(-pages // group) + rows - 1
         if left:
             length, left = length + 1, left - 1
     got = [_counter(n) - b for n, b in zip(names, before)]
     assert got == [walked * layers,
-                   (mixed + chunks * k) * rows * slots * layers]
+                   (mixed + chunks * k) * rows * slots * layers,
+                   programs * layers]
     assert 0.0 < got[0] / got[1] < 0.5
 
 
-@pytest.mark.parametrize("model", ["tiny-llama", "tiny-sdar"])
-def test_attn_pages_counted_by_step_from_kept_lengths(model):
-    """The count itself, for a token step and a block step: a forward reads
-    the row's kept length and the step's own tokens; a forward that added to
-    the length moves the ones after it; a row that does not run is at 0 on
-    the device."""
-    names = ("llm_attn_pages_walked_total", "llm_attn_pages_offered_total")
-    eng = _manual(_cfg(model=model))
+@pytest.mark.parametrize("model,over", [
+    ("tiny-llama", {}), ("tiny-sdar", {}),
+    ("tiny-kimi-share4", {"quantization": "int8"}),
+    # pages of 4 tokens: a table of 64 slots a row
+    ("tiny-llama", {"prefix_page_size": 4, "prefix_cache_pages": 320}),
+], ids=["tiny-llama", "tiny-sdar", "tiny-kimi-share4", "tiny-llama-page4"])
+def test_attn_pages_counted_by_step_from_kept_lengths(model, over):
+    """The count itself, for a token step, a block step and a latent page:
+    a forward reads the row's kept length and the step's own tokens; a
+    forward that added to the length moves the ones after it; a row that
+    does not run is at 0 on the device. The programs counted are the work
+    lists' own ``n_items`` (the list each forward's program builds, from the
+    same table and lengths), and the pages walked do not depend on how they
+    are grouped."""
+    from cyberfabric_core_tpu.models.llama import decode_work
+
+    names = ("llm_attn_pages_walked_total", "llm_attn_page_groups_total",
+             "llm_attn_pages_offered_total")
+    eng = _manual(_cfg(model=model, **over))
     try:
         step, slots = eng._step_tokens, eng.page_table.shape[1]
-        layers = eng.model_config.num_layers
+        page = eng.config.prefix_page_size
+        layers, cfg = eng.model_config.num_layers, eng.model_config
         eng.active[:] = [True, True, False, True]
         kept = np.asarray([15, 100, 77, 255], np.int32)
         grew = np.asarray([[1, 1, 0], [0, 1, 0], [1, 1, 1], [0, 0, 0]], bool)
         before = [_counter(n) for n in names]
         eng._count_attn_pages(kept, grew)
         got = [_counter(n) - b for n, b in zip(names, before)]
+        pool = eng.pool.cache_operands()[0]
     finally:
         eng.active[:] = False
         eng.shutdown()
-    walked = 0
-    for row in range(4):
-        length = int(kept[row]) if row != 2 else 0
-        for f in range(3):
-            walked += min(-(-(length + step) // 16), slots)
-            length += step * int(grew[row, f])
-    assert got == [walked * layers, 4 * 3 * slots * layers]
+    walked, programs = 0, 0
+    lengths = np.where([True, True, False, True], kept, 0)
+    for f in range(3):
+        walked += int(np.minimum(-(-(lengths + step) // page), slots).sum())
+        work = decode_work(cfg, eng.page_table, lengths + step, pool)
+        programs += int(work.n_items)
+        lengths = lengths + step * grew[:, f]
+    assert got == [walked * layers, programs * layers,
+                   4 * 3 * slots * layers]
+    group = work.phys.shape[0] // work.row.shape[0]
+    assert group > 1 and walked / group <= programs < walked
 
 
 def test_the_rings_series_are_on_metrics_before_the_first_request():
@@ -664,6 +692,7 @@ def test_the_rings_series_are_on_metrics_before_the_first_request():
                  "llm_control_rows_uploads_total",
                  "llm_loose_row_programs_total",
                  "llm_attn_pages_walked_total",
+                 "llm_attn_page_groups_total",
                  "llm_attn_pages_offered_total"):
         assert f"# TYPE {name} counter" in text
         help_line = next(line for line in text.splitlines()

@@ -125,10 +125,12 @@ def test_ragged_kernel_block_mask_against_a_dense_mask(block):
                                    atol=2e-5)
 
 
-@pytest.mark.parametrize("block", [1, 4, 8])
-def test_decode_kernel_block_fold_against_a_dense_mask(block):
+@pytest.mark.parametrize("block,group", [(1, 1), (4, 1), (8, 1), (4, 2),
+                                         (4, 4), (8, 4)])
+def test_decode_kernel_block_fold_against_a_dense_mask(block, group):
     """The open block's kernel: ``block`` queries a row, each seeing the
-    kept keys and the whole block; 1 is the decode kernel as it was."""
+    kept keys and the whole block; 1 is the decode kernel as it was. The
+    folded rows take a row's pages ``group`` at a time like any others."""
     rng = np.random.default_rng(10 + block)
     k_pool, v_pool = _pool(rng)
     table = jnp.asarray([[3, 5, 7, 9], [2, 4, 6, 8]], jnp.int32)
@@ -136,7 +138,8 @@ def test_decode_kernel_block_fold_against_a_dense_mask(block):
     q = rng.standard_normal((2, block, 4, 16)).astype(np.float32)
     out = np.asarray(paged_block_attention(
         jnp.asarray(q), k_pool, v_pool,
-        decode_work_list(table, jnp.asarray(kept + block), k_pool.shape[2]),
+        decode_work_list(table, jnp.asarray(kept + block), k_pool.shape[2],
+                         group=group),
         0, interpret=True))
     kd, vd = (np.asarray(a) for a in paged_gather_dense(k_pool, v_pool, table,
                                                         16))
