@@ -15,9 +15,11 @@ __all__ = ["MODEL_CONFIGS", "ModelConfig", "get_config", "decoder_module"]
 
 #: decoder architectures the serving scheduler drives → their model module.
 #: Each exposes init_params, forward_paged_decode, forward_paged_mixed,
-#: lm_head_logits and gather_last_hidden.
+#: lm_head_logits and gather_last_hidden; one with recurrent state also
+#: init_state.
 _DECODERS = {"llama": "llama", "falcon_h1": "falcon_h1",
-             "sdar_moe": "sdar_moe", "kimi_k2": "kimi_k2"}
+             "sdar_moe": "sdar_moe", "kimi_k2": "kimi_k2",
+             "granite_hybrid": "granite_hybrid"}
 
 
 def decoder_module(cfg: ModelConfig) -> ModuleType:

@@ -21,7 +21,10 @@ class ModelConfig:
     #: a Mamba-2 mixer beside GQA attention) | "sdar_moe" (decoder of routed
     #: experts that generates by diffusion over blocks) | "kimi_k2" (decoder
     #: of latent attention over a latent page, a leading dense layer, then
-    #: sigmoid-routed experts beside a shared expert) | "bert" (encoder)
+    #: sigmoid-routed experts beside a shared expert) | "granite_hybrid" (a
+    #: stack whose layers differ in kind, ``layer_types``: Mamba-2 mixer
+    #: layers and attention layers without rotary, routed experts beside a
+    #: shared MLP after each) | "bert" (encoder)
     architecture: str
     vocab_size: int
     hidden_size: int
@@ -119,6 +122,24 @@ class ModelConfig:
     ssm_out_multiplier: float = 1.0
     ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
     mlp_multipliers: tuple = (1.0, 1.0)
+    # granite_hybrid (model_type granitemoehybrid), names as published. The
+    # kind of every layer, "mamba" or "attention" (empty: every layer is what
+    # the architecture's one block is): a mamba layer holds recurrent state
+    # and writes no page, an attention layer holds pages and no state, so
+    # the page pool has ``kv_layers`` layers and the state slab
+    # ``state_layers``
+    layer_types: tuple = ()
+    #: every branch (mixer or attention, then the expert layer) is added to
+    #: the residual stream times this
+    residual_multiplier: float = 1.0
+    #: the softmax scale as a given number (0: ``head_dim^-1/2``)
+    attention_multiplier: float = 0.0
+    #: the logits are divided by this
+    logits_scaling: float = 1.0
+    #: False: no rotary embedding ("position_embedding_type": "nope")
+    rotary: bool = True
+    #: width of the shared MLP every token runs beside the routed experts
+    shared_intermediate_size: int = 0
     # bert-family extras
     layer_norm_eps: float = 1e-12
     type_vocab_size: int = 2
@@ -138,6 +159,13 @@ class ModelConfig:
                 f"{self.name}: experts {self.expert_offset}.."
                 f"{self.expert_offset + self.experts_held - 1} are not among "
                 f"the router's {self.num_experts}")
+        if self.layer_types and (
+                len(self.layer_types) != self.num_layers
+                or set(self.layer_types) - {"mamba", "attention"}):
+            raise ValueError(
+                f"{self.name}: layer_types names {len(self.layer_types)} "
+                f"layers of kinds {sorted(set(self.layer_types))} for "
+                f"num_layers {self.num_layers} (kinds: mamba, attention)")
         if self.block_length > 1 and (
                 self.block_length % self.denoising_steps
                 or not 0 <= self.mask_token_id < self.vocab_size):
@@ -154,7 +182,7 @@ class ModelConfig:
     def router_float32(self) -> bool:
         """The router's weights stay float32 whatever the activations' dtype
         (a score decides WHICH experts run, not only how much)."""
-        return self.architecture in ("sdar_moe", "kimi_k2")
+        return self.architecture in ("sdar_moe", "kimi_k2", "granite_hybrid")
 
     @property
     def is_latent(self) -> bool:
@@ -194,11 +222,33 @@ class ModelConfig:
     def num_moe_layers(self) -> int:
         return self.num_layers - self.first_k_dense if self.num_experts else 0
 
+    @property
+    def kv_layers(self) -> int:
+        """Layers that cache pages: the page pool's leading dimension."""
+        if not self.layer_types:
+            return self.num_layers
+        return self.layer_types.count("attention")
+
+    @property
+    def state_layers(self) -> int:
+        """Layers that hold recurrent state: the state slab's leading
+        dimension."""
+        if not self.layer_types:
+            return self.num_layers if self.has_state else 0
+        return self.layer_types.count("mamba")
+
+    def cut_to(self, layers: int, name: str | None = None) -> "ModelConfig":
+        """The first ``layers`` layers of this configuration (a pipeline
+        stage; a judged depth), ``layer_types`` cut with them."""
+        return dataclasses.replace(
+            self, name=name or self.name, num_layers=layers,
+            layer_types=self.layer_types[:layers])
+
     def cache_bytes_per_token(self, itemsize: int = 2) -> int:
-        """Bytes a token holds in the page pool over all layers."""
+        """Bytes a token holds in the page pool over the layers that cache."""
         per_layer = (self.latent_lanes if self.is_latent
                      else 2 * self.num_kv_heads * self.head_dim)
-        return self.num_layers * per_layer * itemsize
+        return self.kv_layers * per_layer * itemsize
 
     @property
     def is_block(self) -> bool:
@@ -221,24 +271,27 @@ class ModelConfig:
         return self.ssm_inner + self.ssm_conv_dim + self.ssm_heads
 
     def state_bytes_per_row(self) -> int:
-        """f32 recurrent state and conv tail of one row, all layers."""
+        """f32 recurrent state and conv tail of one row, over the layers
+        that hold state."""
         per_layer = (self.ssm_heads * self.ssm_head_dim * self.ssm_state
                      + (self.ssm_conv - 1) * self.ssm_conv_dim)
-        return 4 * self.num_layers * per_layer if self.has_state else 0
+        return 4 * self.state_layers * per_layer
 
     def param_count(self) -> int:
         """Approximate parameter count (for HBM budgeting)."""
         h, i, v, l = self.hidden_size, self.intermediate_size, self.vocab_size, self.num_layers
         attn = h * (self.num_heads * self.head_dim) + 2 * h * (self.num_kv_heads * self.head_dim) \
             + (self.num_heads * self.head_dim) * h
-        mlp = 3 * h * i * max(self.num_experts, 1) + h * self.num_experts
+        mlp = 3 * h * i * max(self.num_experts, 1) + h * self.num_experts \
+            + 3 * h * self.shared_intermediate_size
         emb = v * h * (1 if self.tie_embeddings else 2)
         mixer = 0
         if self.has_state:  # in/out projections, conv + bias, A, D, dt, norm
             mixer = (h * self.ssm_proj_dim + self.ssm_inner * h
                      + (self.ssm_conv + 1) * self.ssm_conv_dim
                      + 3 * self.ssm_heads + self.ssm_inner)
-        return l * (attn + mlp + mixer + 2 * h) + emb + h
+        return (self.kv_layers * attn + self.state_layers * mixer
+                + l * (mlp + 2 * h) + emb + h)
 
 
 MODEL_CONFIGS: dict[str, ModelConfig] = {
@@ -439,6 +492,42 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         rope_factor=4.0, rope_original_max=64, rope_beta_fast=32.0,
         rope_beta_slow=1.0, rope_mscale=1.0, rope_mscale_all_dim=1.0,
     ),
+    # granite-4.0-h-small, config.json as published (model_type
+    # granitemoehybrid, 32B-A9B): 40 layers of which those at 5, 15, 25 and
+    # 35 are GQA 32/8 attention WITHOUT rotary and the rest Mamba-2 mixers
+    # (128 heads of 64, state 128, one group, conv 4, chunk 256); after each,
+    # 72 routed experts of 768 top-10 (softmax over the chosen logits) beside
+    # a shared MLP of 1536; embedding x 12, every branch x 0.22 into the
+    # residual stream, the softmax scale 1/128 as given, tied head, logits / 16
+    "granite-4.0-h-small": ModelConfig(
+        name="granite-4.0-h-small", architecture="granite_hybrid",
+        vocab_size=100352, hidden_size=4096, intermediate_size=768,
+        num_layers=40, num_heads=32, num_kv_heads=8, head_dim=128,
+        max_position=131072, rope_theta=10000.0, rms_norm_eps=1e-5,
+        tie_embeddings=True, num_experts=72, experts_per_token=10,
+        shared_intermediate_size=1536, embedding_multiplier=12.0,
+        residual_multiplier=0.22, attention_multiplier=0.0078125,
+        logits_scaling=16.0, rotary=False,
+        layer_types=tuple("attention" if i % 10 == 5 else "mamba"
+                          for i in range(40)),
+        ssm_inner=8192, ssm_heads=128, ssm_head_dim=64, ssm_state=128,
+        ssm_groups=1, ssm_conv=4, ssm_chunk=256,
+    ),
+    # CPU-test preset of the same stack: two periods of ``m m a m``, 8
+    # experts top-3 beside a shared MLP, heads >> 8 in one group, every
+    # multiplier other than 1, a chunk (8) shorter than the test prompts
+    "tiny-granite-hybrid": ModelConfig(
+        name="tiny-granite-hybrid", architecture="granite_hybrid",
+        vocab_size=512, hidden_size=64, intermediate_size=32, num_layers=8,
+        num_heads=4, num_kv_heads=2, head_dim=16, max_position=256,
+        rope_theta=10000.0, rms_norm_eps=1e-5, tie_embeddings=True,
+        num_experts=8, experts_per_token=3, shared_intermediate_size=48,
+        embedding_multiplier=3.0, residual_multiplier=0.5,
+        attention_multiplier=0.125, logits_scaling=2.0, rotary=False,
+        layer_types=("mamba", "mamba", "attention", "mamba") * 2,
+        ssm_inner=128, ssm_heads=16, ssm_head_dim=8, ssm_state=16,
+        ssm_groups=1, ssm_conv=4, ssm_chunk=8,
+    ),
     "bge-base-en": ModelConfig(
         name="bge-base-en", architecture="bert", vocab_size=30522, hidden_size=768,
         intermediate_size=3072, num_layers=12, num_heads=12, num_kv_heads=12,
@@ -474,6 +563,19 @@ MODEL_CONFIGS["kimi-k2.5-share32-15l"] = dataclasses.replace(
 MODEL_CONFIGS["tiny-kimi-share4"] = dataclasses.replace(
     MODEL_CONFIGS["tiny-kimi"], name="tiny-kimi-share4", experts_held=4,
     expert_offset=4, vocab_held=256)
+
+
+# the first of 4 pipeline stages of granite-4.0-h-small, one period of its
+# layer pattern each: layers 0-9, nine mamba layers and the attention layer
+# at 5, every expert, every head and the whole vocabulary (the tied head
+# rides on the first stage as it does in falcon's and sdar's cuts)
+MODEL_CONFIGS["granite-4.0-h-small-10l"] = MODEL_CONFIGS[
+    "granite-4.0-h-small"].cut_to(10, "granite-4.0-h-small-10l")
+
+# one period of the tiny preset (``m m a m``: three runs where the preset has
+# five), for the CPU tests that build an engine: compile time is the runs'
+MODEL_CONFIGS["tiny-granite-hybrid-4l"] = MODEL_CONFIGS[
+    "tiny-granite-hybrid"].cut_to(4, "tiny-granite-hybrid-4l")
 
 
 def get_config(name: str) -> ModelConfig:

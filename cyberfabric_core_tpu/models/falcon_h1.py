@@ -65,7 +65,7 @@ def init_mixer_small(cfg: ModelConfig, key: jax.Array) -> dict[str, jnp.ndarray]
     softplus of a step log-uniform in [1e-3, 1e-1] (the Mamba-2 defaults), so
     ``exp(Δ A)`` lies between about 0.2 and 0.999; ``D = 1``; conv taps
     U(±K^-1/2), a small conv bias; the gated norm's weight 1."""
-    L, Hs, K, C = cfg.num_layers, cfg.ssm_heads, cfg.ssm_conv, cfg.ssm_conv_dim
+    L, Hs, K, C = cfg.state_layers, cfg.ssm_heads, cfg.ssm_conv, cfg.ssm_conv_dim
     k = jax.random.split(key, 4)
     step = jnp.exp(jax.random.uniform(k[0], (L, Hs), jnp.float32,
                                       np.log(1e-3), np.log(1e-1)))
@@ -99,8 +99,9 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
 
 
 def init_state(cfg: ModelConfig, rows: int) -> State:
-    """The zero state slab for ``rows`` rows (slots first, then snapshots)."""
-    L = cfg.num_layers
+    """The zero state slab for ``rows`` rows (slots first, then snapshots),
+    one layer for every layer that holds state."""
+    L = cfg.state_layers
     return {"ssm": jnp.zeros((L, rows, cfg.ssm_heads, cfg.ssm_head_dim,
                               cfg.ssm_state), jnp.float32),
             "conv": jnp.zeros((L, rows, cfg.ssm_conv - 1, cfg.ssm_conv_dim),
@@ -123,7 +124,8 @@ def _mixer_in(lp: dict, x: jnp.ndarray, cfg: ModelConfig):
         x = x * jnp.asarray(cfg.ssm_in_multiplier, x.dtype)
     proj = _scaled(jnp.einsum("bth,hd->btd", x, w_m,
                               preferred_element_type=jnp.float32), w_s)
-    proj = proj * _mup_vector(cfg)
+    if any(m != 1.0 for m in cfg.ssm_multipliers):
+        proj = proj * _mup_vector(cfg)
     d, c = cfg.ssm_inner, cfg.ssm_conv_dim
     return proj[..., :d], proj[..., d: d + c], proj[..., d + c:]
 
@@ -151,7 +153,9 @@ def _mixer_out(lp: dict, y: jnp.ndarray, z: jnp.ndarray, cfg: ModelConfig,
     w_m, w_s = _wmat(lp["ssm_out"], dtype)
     out = _scaled(jnp.einsum("btd,dh->bth", normed.astype(dtype), w_m,
                              preferred_element_type=jnp.float32), w_s)
-    return (out * cfg.ssm_out_multiplier).astype(dtype)
+    if cfg.ssm_out_multiplier != 1.0:
+        out = out * cfg.ssm_out_multiplier
+    return out.astype(dtype)
 
 
 def _layer_rows(slab: jnp.ndarray, layer, rows: int) -> jnp.ndarray:
@@ -223,6 +227,33 @@ def _mixer_step(lp: dict, layer, cfg: ModelConfig, u, dt, ssm, conv,
         -jnp.exp(lp["A_log"]), b_mat, c_mat, run, kernel=kernel)
     y = y + lp["D"][:, None] * xs_.astype(jnp.float32)
     return y.reshape(B, -1), ssm, conv
+
+
+def _mixer_chunk(lp: dict, layer, cfg: ModelConfig, u, dt, ssm, conv, rows,
+                 fresh, advance, span, act):
+    """The lanes' chunk of a mixed step through the chunked form: ``u``
+    [R, Qc, C] and ``dt`` [R, Qc, Hs] f32 from the input projection, each
+    lane on its own row of the slab (``rows``; None: lane r = row r), from
+    the zero state where ``fresh`` [R]. Only the lanes' rows are read and
+    written; a lane with ``advance`` False keeps state and conv tail bit for
+    bit. Returns (y [R, Qc, d_ssm] f32, ssm, conv)."""
+    R, Qc = u.shape[:2]
+    tail = _lane_rows(conv, layer, rows, R)
+    s_old = _lane_rows(ssm, layer, rows, R)
+    xbc, new_tail = causal_conv(
+        u, jnp.where(fresh[:, None, None], 0.0, tail), lp["conv_w"],
+        lp["conv_b"], span)
+    xs_, b_mat, c_mat = _split_xbc(jax.nn.silu(xbc).astype(act), cfg)
+    y, s_new = ssd_chunked(
+        xs_, jax.nn.softplus(dt + lp["dt_bias"]), -jnp.exp(lp["A_log"]),
+        b_mat, c_mat, lp["D"],
+        jnp.where(fresh[:, None, None, None], 0.0, s_old), span,
+        cfg.ssm_chunk)
+    conv = _store_lane_rows(conv, layer, rows, jnp.where(
+        advance[:, None, None], new_tail, tail))
+    ssm = _store_lane_rows(ssm, layer, rows, jnp.where(
+        advance[:, None, None, None], s_new, s_old))
+    return y.reshape(R, Qc, -1), ssm, conv
 
 
 def forward_paged_decode(
@@ -323,7 +354,6 @@ def forward_paged_mixed(
     lane_attend = _ragged_attend(cfg, interpret, None)
     decode_attend = _decode_attend(cfg, interpret, None)
     advance = lay.lane_valid                     # lanes whose state moves
-    fresh = (hist == 0)[:, None, None]           # lanes that start from zero
     span = jnp.where(advance, q_lens, 0)
 
     h = _embed_scale(embed_lookup(params["embed"], lay.ids,
@@ -353,21 +383,10 @@ def forward_paged_mixed(
                 lp, layer, cfg, u[0, :nd], dt[0, :nd], ssm, conv, decode.run,
                 h.dtype, not interpret)
             ys.append(y_dec)
-        tail = _lane_rows(conv, layer, rows, R)
-        s_old = _lane_rows(ssm, layer, rows, R)
-        xbc, new_tail = causal_conv(
-            u[0, nd:].reshape(R, Qc, -1), jnp.where(fresh, 0.0, tail),
-            lp["conv_w"], lp["conv_b"], span)
-        xs_, b_mat, c_mat = _split_xbc(jax.nn.silu(xbc).astype(h.dtype), cfg)
-        y, s_new = ssd_chunked(
-            xs_, jax.nn.softplus(dt[0, nd:].reshape(R, Qc, -1)
-                                 + lp["dt_bias"]),
-            -jnp.exp(lp["A_log"]), b_mat, c_mat, lp["D"],
-            jnp.where(fresh[..., None], 0.0, s_old), span, cfg.ssm_chunk)
-        conv = _store_lane_rows(conv, layer, rows, jnp.where(
-            advance[:, None, None], new_tail, tail))
-        ssm = _store_lane_rows(ssm, layer, rows, jnp.where(
-            advance[:, None, None, None], s_new, s_old))
+        y, ssm, conv = _mixer_chunk(
+            lp, layer, cfg, u[0, nd:].reshape(R, Qc, -1),
+            dt[0, nd:].reshape(R, Qc, -1), ssm, conv, rows, hist == 0,
+            advance, span, h.dtype)
         ys.append(y.reshape(R * Qc, -1))
         o_s = _mixer_out(lp, jnp.concatenate(ys)[None], z, cfg, h.dtype)
 

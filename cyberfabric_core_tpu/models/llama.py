@@ -264,7 +264,8 @@ def _qkv_proj(lp: dict, x: jnp.ndarray, cfg: ModelConfig,
               positions: jnp.ndarray, cos_t, sin_t):
     """Shared q/k/v projection + reshape + rope for one layer (any T).
     ``cfg.key_multiplier`` (falcon_h1) scales k in f32, before the cast; at
-    1.0 nothing is traced for it."""
+    1.0 nothing is traced for it. ``cfg.rotary`` False (granite_hybrid): no
+    rotation, q and k go on as projected."""
     B, T = x.shape[0], x.shape[1]
     Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     wq_m, wq_s = _wmat(lp["wq"], x.dtype)
@@ -291,8 +292,9 @@ def _qkv_proj(lp: dict, x: jnp.ndarray, cfg: ModelConfig,
     if cfg.qk_norm:     # per head, before the rotation (the Qwen3 block)
         q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
         kproj = rms_norm(kproj, lp["k_norm"], cfg.rms_norm_eps)
-    q = apply_rope(q, positions, cos_t, sin_t)
-    kproj = apply_rope(kproj, positions, cos_t, sin_t)
+    if cfg.rotary:
+        q = apply_rope(q, positions, cos_t, sin_t)
+        kproj = apply_rope(kproj, positions, cos_t, sin_t)
     return q, kproj, vproj
 
 
@@ -467,7 +469,8 @@ def _decode_attend(cfg: ModelConfig, interpret: bool, mesh):
     def attend(qq, kk, vv, work, ly):
         return paged_decode_attention(
             qq, kk, vv, work, ly, interpret=interpret,
-            sliding_window=cfg.sliding_window)
+            sliding_window=cfg.sliding_window,
+            scale=cfg.attention_multiplier or None)
 
     if mesh is None:
         return attend
@@ -521,7 +524,8 @@ def _ragged_attend(cfg: ModelConfig, interpret: bool, mesh):
     def attend(qq, kk, vv, pt, hh, ql, ly):
         return ragged_paged_attention(
             qq, kk, vv, pt, hh, ql, ly, interpret=interpret,
-            sliding_window=cfg.sliding_window, block=cfg.block_length)
+            sliding_window=cfg.sliding_window, block=cfg.block_length,
+            scale=cfg.attention_multiplier or None)
 
     if mesh is None:
         return attend
@@ -865,6 +869,8 @@ def lm_head_logits(params: Params, cfg: ModelConfig, hidden: jnp.ndarray) -> jnp
                             preferred_element_type=jnp.float32)
     if cfg.lm_head_multiplier != 1.0:   # falcon_h1
         logits = logits * cfg.lm_head_multiplier
+    if cfg.logits_scaling != 1.0:       # granite_hybrid: a divisor
+        logits = logits / cfg.logits_scaling
     # single exit: every head variant gets the gemma-2 softcap
     return _softcap(logits, cfg)
 

@@ -268,7 +268,7 @@ class LocalTpuWorker(LlmWorkerApi):
         weights = cfg.param_count() * 2  # bf16
         max_seq = int(opts.get("max_seq", opts.get("max_seq_len", 2048)))
         slots = int(opts.get("max_batch", 8))
-        cache = (cfg.num_layers * slots * max_seq * cfg.num_kv_heads
+        cache = (cfg.kv_layers * slots * max_seq * cfg.num_kv_heads
                  * cfg.head_dim * 2 * 2)
         return weights + cache
 

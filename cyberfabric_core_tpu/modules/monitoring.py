@@ -609,6 +609,21 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
             "llm_state_bytes", "Bytes of the recurrent-state slab"
         ).set_function(lambda: _state_stat("state_bytes"))
 
+        # what the caches were built with (a pool sized by the layers that
+        # attend, a slab by the layers that hold state), and their bytes
+        for name, key, text in (
+                ("llm_kv_layers", "kv_layers",
+                 "Layers of the page pool: the model's layers that attend"),
+                ("llm_state_layers", "state_layers",
+                 "Layers of the recurrent-state slab: the model's layers "
+                 "that hold state"),
+                ("llm_model_layers", "model_layers",
+                 "Layers of the model the caches were built for"),
+                ("llm_cache_bytes", "cache_bytes",
+                 "Bytes of the page pool plus the recurrent-state slab")):
+            self.registry.gauge(name, text).set_function(
+                lambda key=key: _state_stat(key))
+
         def mixed_chunk_tokens() -> float:
             return float(sum(getattr(s, "chunked_prefill_tokens", 0)
                              for s in _schedulers()))

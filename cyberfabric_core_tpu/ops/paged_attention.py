@@ -118,7 +118,7 @@ def _span_first(length, page_size: int, last, sliding_window: int | None):
 def _paged_kernel(row_ref, page_ref, phys_ref, len_ref, last_ref, layer_ref,
                   q_ref, *rest, page_size: int, group: int,
                   sliding_window: int | None = None,
-                  two_d_dots: bool = False):
+                  two_d_dots: bool = False, scale: float | None = None):
     """One work item: a GROUP of consecutive pages of one slot.
 
     Refs:
@@ -198,7 +198,7 @@ def _paged_kernel(row_ref, page_ref, phys_ref, len_ref, last_ref, layer_ref,
                 qg, kt, (((2,), (1,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32)  # [Hkv, G, keys]
             scores = scores.reshape(Hq, keys)
-        scores = scores * (1.0 / (D ** 0.5))
+        scores = scores * (1.0 / (D ** 0.5) if scale is None else scale)
 
         k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (Hq, keys), 1)
         # by position: the slot's own tokens, and nothing of a page past its
@@ -327,7 +327,7 @@ def decode_work_list(page_table: jnp.ndarray, lengths: jnp.ndarray,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "sliding_window",
-                                             "two_d_dots"))
+                                             "two_d_dots", "scale"))
 def paged_decode_attention(
     q: jnp.ndarray,           # [B, Hq, D] — one query token per slot
     k_pool: jnp.ndarray,      # [L, N, page, Hkv*D] — the stacked page pool
@@ -337,13 +337,15 @@ def paged_decode_attention(
     interpret: bool = False,
     sliding_window: int | None = None,
     two_d_dots: bool | None = None,
+    scale: float | None = None,
 ) -> jnp.ndarray:
     """Returns [B, Hq, D] attention over each slot's paged history in layer
     ``layer`` of the pool. The pool operands reach the ``pallas_call`` as
     they are passed, once for every page of a group (``work.group``): the
     layer and the page are both picked by the blocks' index maps, so the
     pipeline DMAs whole pages and nothing pool-sized is sliced or copied.
-    ``sliding_window`` is the one ``work`` was built with.
+    ``sliding_window`` is the one ``work`` was built with; ``scale`` is the
+    softmax scale where the model gives one (absent: ``D^-1/2``).
 
     ``two_d_dots`` (default: on exactly when compiling for real — Mosaic's
     dot supports only 2D tensors) selects the unrolled per-kv-head 2D-dot
@@ -380,7 +382,7 @@ def paged_decode_attention(
     return pl.pallas_call(
         functools.partial(_paged_kernel, page_size=page_size, group=group,
                           sliding_window=sliding_window,
-                          two_d_dots=two_d_dots),
+                          two_d_dots=two_d_dots, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -397,7 +399,8 @@ def _ragged_kernel(pt_ref, hist_ref, qlen_ref, layer_ref, q_ref, k_ref, v_ref,
                    acc_ref, m_ref, l_ref, *, page_size: int, q_block: int,
                    sliding_window: int | None = None,
                    two_d_dots: bool = False,
-                   head_dim: int | None = None, block: int = 1):
+                   head_dim: int | None = None, block: int = 1,
+                   scale: float | None = None):
     """One (slot, q-block, page) program of the ragged mixed-batch kernel.
 
     Refs:
@@ -486,7 +489,7 @@ def _ragged_kernel(pt_ref, hist_ref, qlen_ref, layer_ref, q_ref, k_ref, v_ref,
                 qt, kt, (((2,), (1,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32)  # [Hkv, G*Qb, page]
             scores = scores.reshape(R, page_size)
-        scores = scores * (1.0 / (D ** 0.5))
+        scores = scores * (1.0 / (D ** 0.5) if scale is None else scale)
 
         qi = jax.lax.broadcasted_iota(jnp.int32, (R, page_size), 0) % Qb
         q_idx = q0 + qi                          # index within the span
@@ -560,7 +563,7 @@ def _ragged_kernel(pt_ref, hist_ref, qlen_ref, layer_ref, q_ref, k_ref, v_ref,
 
 @functools.partial(jax.jit, static_argnames=("q_block", "interpret",
                                              "sliding_window", "two_d_dots",
-                                             "block"))
+                                             "block", "scale"))
 def ragged_paged_attention(
     q: jnp.ndarray,           # [B, Qmax, Hq, D] — per-row query span, padded
     k_pool: jnp.ndarray,      # [L, N, page, Hkv*D] — the stacked page pool
@@ -574,6 +577,7 @@ def ragged_paged_attention(
     sliding_window: int | None = None,
     two_d_dots: bool | None = None,
     block: int = 1,
+    scale: float | None = None,
 ) -> jnp.ndarray:
     """Ragged mixed-batch paged attention: one dispatch where each batch row
     attends a variable-length query span over its paged KV chain with causal
@@ -595,7 +599,8 @@ def ragged_paged_attention(
 
     ``block`` > 1 is the block mask (see the kernel): a query sees the keys
     up to the end of its own block of ``block`` absolute positions, which
-    must all be in the pool already."""
+    must all be in the pool already. ``scale``: the softmax scale where the
+    model gives one (absent: ``D^-1/2``)."""
     if two_d_dots is None:
         two_d_dots = not interpret
     if block & (block - 1):
@@ -650,7 +655,8 @@ def ragged_paged_attention(
         functools.partial(_ragged_kernel, page_size=page_size,
                           q_block=q_block, sliding_window=sliding_window,
                           two_d_dots=two_d_dots,
-                          head_dim=D if two_d_dots else None, block=block),
+                          head_dim=D if two_d_dots else None, block=block,
+                          scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q_in.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(

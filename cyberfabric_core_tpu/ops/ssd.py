@@ -137,10 +137,19 @@ def ssd_chunked(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
 
 
 # ------------------------------------------------------------ the decode step
-def _head_block(heads: int, groups: int) -> int:
-    """Heads a kernel program handles: at most 8, all of one group."""
+#: f32 state bytes one kernel program should move each way: what falcon-h1's
+#: 8 heads of [128, 256] are. A program costs about a third of a microsecond
+#: before it has moved a byte, so a row of many small heads (granite: 128
+#: heads of [64, 128], 32 KB each) takes several to a program
+_STATE_BLOCK_BYTES = 1024 * 1024
+
+
+def _head_block(heads: int, groups: int, head_bytes: int) -> int:
+    """Heads a kernel program handles: all of one group, and the most whose
+    f32 state (``head_bytes`` each) stays within ``_STATE_BLOCK_BYTES``: 8
+    at falcon-h1's [128, 256], 32 at granite's [64, 128]."""
     per_group = heads // groups
-    hb = min(8, per_group)
+    hb = max(1, min(per_group, _STATE_BLOCK_BYTES // head_bytes))
     while per_group % hb:
         hb -= 1
     return hb
@@ -171,7 +180,7 @@ def _state_update_pallas(ssm, layer, xdt, da, b_mat, c_mat, write_mask, *,
                          interpret: bool):
     _, _, H, P, N = ssm.shape
     B, G = xdt.shape[0], b_mat.shape[1]
-    hb = _head_block(H, G)
+    hb = _head_block(H, G, 4 * P * N)
     nhb = H // hb
     blocks_per_group = (H // G) // hb
 

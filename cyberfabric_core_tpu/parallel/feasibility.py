@@ -186,7 +186,7 @@ def tp_plan(
     kv_heads_dev = cfg.num_kv_heads if kv_replicated \
         else max(1, cfg.num_kv_heads // tp)
     kv_dtype = jnp.dtype(dtype)
-    kv_bytes_device = (2 * cfg.num_layers * pages * page_size * kv_heads_dev
+    kv_bytes_device = (2 * cfg.kv_layers * pages * page_size * kv_heads_dev
                        * cfg.head_dim * kv_dtype.itemsize)
 
     # activation high-water estimate for the prefill bucket (B=1): hidden

@@ -18,8 +18,12 @@ no kv-head axis and no V pool; ``pools``,
 admission protocol count pages, whatever a page holds.
 
 One manager for both kinds of cache. A model with recurrent state
-(``ModelConfig.has_state``: falcon_h1) also gets a state slab here —
-``{"ssm": [L, rows, H, P, N], "conv": [L, rows, K-1, C]}`` f32 — whose first
+(``ModelConfig.has_state``: falcon_h1, granite_hybrid) also gets a state slab
+here — ``{"ssm": [Ls, rows, H, P, N], "conv": [Ls, rows, K-1, C]}`` f32, made
+by the model's own module (``init_state``) — **each cache as deep as the
+layers of its kind**: the pool arrays have ``ModelConfig.kv_layers`` layers
+and the slab ``state_layers`` (both ``num_layers`` where every layer is one
+block; 1 and 9 for one period of granite's stack). The slab's first
 ``state_slots`` rows are the slots' own (row = slot) and whose further rows
 are **snapshots**, each owned by the prefix-tree page at whose end it was
 taken. State does not grow with a row's length and cannot be shared by
@@ -60,6 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..models import decoder_module
 from ..models.configs import ModelConfig
 from ..modkit.metrics import bump_counter
 from ..ops.sampling import sample_token
@@ -103,7 +108,7 @@ class PrefixKVPool:
         #: bookkeeping (allocator, radix tree, refcounts, page ids) is
         #: byte-count-agnostic and identical to the single-device pool.
         self.sharding = sharding
-        L = model_config.num_layers
+        L = model_config.kv_layers
         if model_config.is_latent:
             if sharding is not None:
                 raise ValueError(
@@ -158,10 +163,9 @@ class PrefixKVPool:
         self.snapshot_evictions = 0
         self.state_restores = 0
         if model_config.has_state and state_slots > 0:
-            from ..models.falcon_h1 import init_state
-
             rows = state_slots + max(0, state_snapshots)
-            self.state = init_state(model_config, rows)
+            self.state = decoder_module(model_config).init_state(
+                model_config, rows)
             self._free_snapshot_rows = list(range(rows - 1, state_slots - 1, -1))
             # compile the row copy now, at build: its first use is otherwise
             # the first chunk boundary of a long prompt, mid-serving
@@ -250,6 +254,10 @@ class PrefixKVPool:
 
     def pool_bytes(self) -> int:
         return sum(int(p.size) * p.dtype.itemsize for p in self.pools)
+
+    def state_bytes(self) -> int:
+        return sum(int(v.size) * v.dtype.itemsize
+                   for v in (self.state or {}).values())
 
     def cache_operands(self) -> tuple:
         """What the serving programs take and give back, donated: the
@@ -413,13 +421,13 @@ class PrefixKVPool:
         """Copy a slot's chain pages device→host (KV eviction for preempted
         requests — SURVEY §5 checkpoint/resume; the serving analogue of the
         reference's suspend path). One gather per pool; the transfer is the
-        chain's actual bytes, not the window. Returns [L, n, page, Hkv, D]
-        each (the PD wire format; a host reshape is a view; a latent pool:
+        chain's actual bytes, not the window. Returns [kv_layers, n, page,
+        Hkv, D] each (the PD wire format; a host reshape is a view; a latent pool:
         its one array as [L, n, page, latent_lanes]), and where the
         model has recurrent state one more entry: slot ``state_row``'s row
         of the slab, so that the request resumes exactly."""
         idx = jnp.asarray(chain, jnp.int32)
-        out = (self.cfg.num_layers, len(chain), self.page_size,
+        out = (self.cfg.kv_layers, len(chain), self.page_size,
                *self._page_tail)
         host_kv = tuple(np.asarray(pool[:, idx]).reshape(out)
                         for pool in self.pools)
@@ -528,6 +536,13 @@ class PrefixKVPool:
             "cache_bytes_per_token": self.cfg.cache_bytes_per_token(
                 jnp.dtype(self.dtype).itemsize),
             "pool_bytes": self.pool_bytes(),
+            # what the caches were BUILT with: layers of the page pool, of
+            # the state slab and of the model, and both caches' bytes
+            "kv_layers": int(self.pools[0].shape[0]),
+            "state_layers": (int(self.state["ssm"].shape[0])
+                             if self.state is not None else 0),
+            "model_layers": self.cfg.num_layers,
+            "cache_bytes": self.pool_bytes() + self.state_bytes(),
             **self.state_stats(),
         }
 
@@ -545,8 +560,7 @@ class PrefixKVPool:
             "state_snapshot_rows_in_use": (
                 rows - self.state_slots - len(self._free_snapshot_rows)),
             "state_snapshots_cached": len(self._snapshots),
-            "state_bytes": int(sum(v.size * v.dtype.itemsize
-                                   for v in self.state.values())),
+            "state_bytes": self.state_bytes(),
             "state_snapshots_taken": self.snapshots_taken,
             "state_snapshot_hits": self.snapshot_hits,
             "state_snapshot_evictions": self.snapshot_evictions,

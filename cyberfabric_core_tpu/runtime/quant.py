@@ -70,9 +70,11 @@ def quantize_llama_params(params: dict[str, Any], bits: int = 8) -> dict[str, An
     out["embed"] = _quantize_embed(params["embed"])
     if "lm_head" in params:
         out["lm_head"] = quantize_weight(params["lm_head"], bits)
-    # "layers", and where the stack is not one repeated layer (kimi_k2) the
-    # leading "dense" stack beside it
-    for stack in ("dense", "layers"):
+    # "layers", and where the stack is not one repeated layer the stacks
+    # beside it: kimi_k2's leading "dense" layers, granite_hybrid's "mamba"
+    # and "attention" layers (the mixer's conv, A_log, D, dt_bias and norm
+    # stay float32; the router float32)
+    for stack in ("dense", "mamba", "attention", "layers"):
         if stack in params:
             out[stack] = {
                 # norms, router (tiny + precision-sensitive) stay as they are

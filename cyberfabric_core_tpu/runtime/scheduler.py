@@ -673,9 +673,10 @@ class ContinuousBatchingEngine:
         #: the decoder's model module (models/llama.py, models/falcon_h1.py):
         #: the paged programs call its entry points by one set of names
         self._model = decoder_module(self.model_config)
-        #: recurrent state beside the K/V pages (falcon_h1): the serving
-        #: programs carry the state slab as a third donated operand, and
-        #: the modes that cannot carry it are refused here, at build
+        #: recurrent state beside the K/V pages (falcon_h1; granite_hybrid,
+        #: in the layers of that kind): the serving programs carry the state
+        #: slab as a third donated operand, and the modes that cannot carry
+        #: it are refused here, at build
         self._has_state = self.model_config.has_state
         if self._has_state:
             self._refuse_without_state_support(config)
@@ -1258,20 +1259,20 @@ class ContinuousBatchingEngine:
         def paged_forward(forward, params, ids, caches, *tail, **kwargs):
             """One of the model's paged forward passes over the cache
             operands: the pools (K and V, or the one latent pool), and the
-            state slab where the model has one. Returns (hidden, caches,
-            the forward's ``counters``)."""
+            state slab where the model has one. A forward hands back
+            (hidden, pools), then its state where it has one, then its
+            ``aux`` where its module counts its expert layers: the parts
+            compose, a model may have both. Returns (hidden, caches, the
+            forward's ``counters``)."""
+            n_pools = len(caches) - self._has_state
             if self._has_state:
-                hidden, pools, state = forward(
-                    params, cfg, ids, caches[:-1], *tail, state=caches[-1],
-                    **kwargs)
-                return hidden, (*pools, state), no_counts
-            if not self._moe_counters:
-                hidden, pools = forward(params, cfg, ids, caches, *tail,
-                                        **kwargs)
-                return hidden, tuple(pools), no_counts
-            hidden, pools, aux = forward(params, cfg, ids, caches, *tail,
-                                         **kwargs)
-            return hidden, tuple(pools), self._moe_counts(aux)
+                kwargs["state"] = caches[-1]
+            hidden, pools, *rest = forward(
+                params, cfg, ids, caches[:n_pools], *tail, **kwargs)
+            state = (rest.pop(0),) if self._has_state else ()
+            counts = (self._moe_counts(rest[0]) if self._moe_counters
+                      else no_counts)
+            return hidden, (*pools, *state), counts
 
         def decode_chunk_body(params, caches, rows, last_tokens, lengths,
                               active, finished, keys):
@@ -3371,7 +3372,7 @@ class ContinuousBatchingEngine:
         slots = self.page_table.shape[1]
         first, last = page_span(lengths, self.config.prefix_page_size, slots,
                                 self.model_config.sliding_window)
-        layers = self.model_config.num_layers
+        layers = self.model_config.kv_layers     # the layers that attend
         group = decode_page_group(
             self.model_config, self.config.prefix_page_size, slots,
             jnp.dtype(self.dtype).itemsize)
