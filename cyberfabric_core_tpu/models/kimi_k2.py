@@ -34,7 +34,14 @@ kernel reads and the pool stays the only cache.
 scores all ``cfg.num_experts``, the gates are normalised over all the chosen,
 and the sum runs over the chosen experts this chip holds; the shared expert
 is whole; the embedding and the head are the held rows of the vocabulary.
-Nothing here stands in for the chips that hold the rest.
+Nothing here stands in for the chips that hold the rest, and the expert
+layer does not pay for their assignments either: as a deployment's exchange
+hands a chip only the tokens routed to it, ``llama.moe_experts`` gathers,
+multiplies and scatter-adds the compacted list of the assignments held here
+(``llama.moe_capacity`` rows from shapes, four times the uniform expectation:
+640 of a 512-token mixed step's 4 608, 128 of a decode step's 512), chosen
+on the device by the held count; a step that holds more takes every row, so
+none is dropped (``MOE_COUNTERS``' ``compact`` over ``forwards``).
 
 The stack is not one repeated layer, so the parameters are two stacks:
 ``params["dense"]`` (the leading ``cfg.first_k_dense`` layers) and
@@ -58,7 +65,7 @@ from .configs import ModelConfig
 from .llama import (MOE_LEAVES, DecodeGroup, Params, _act, _decode_targets,
                     _scaled, _wmat, decode_work, embed_lookup,
                     gather_last_hidden, lm_head_logits, mixed_hidden_out,
-                    mixed_layout, moe_experts, moe_route)
+                    mixed_layout, moe_capacity, moe_experts, moe_route)
 
 __all__ = ["init_params", "init_params_with", "forward_paged_decode",
            "forward_paged_mixed", "lm_head_logits", "gather_last_hidden",
@@ -66,8 +73,10 @@ __all__ = ["init_params", "init_params_with", "forward_paged_decode",
 
 #: what ``aux`` counts over a forward's expert layers, in the order the
 #: serving programs hand them to the host: assignments routed (tokens x K),
-#: those that fell on experts held here, and held experts with at least one
-MOE_COUNTERS = ("assignments", "local", "touched")
+#: those that fell on experts held here, held experts with at least one, and
+#: the expert layers whose held assignments fitted ``moe_capacity`` (so
+#: ``moe_experts`` ran over the compacted list) beside the expert layers run
+MOE_COUNTERS = ("assignments", "local", "touched", "compact", "forwards")
 
 LatentPool = tuple[jnp.ndarray]     # (latent,): [L, N, page, rank + rope]
 Aux = dict[str, jnp.ndarray]
@@ -230,9 +239,12 @@ def _moe_residual(lp: dict, moe: dict, layer, h: jnp.ndarray,
     mine = (held >= 0) & (held < cfg.experts_local)
     per_expert = jnp.bincount(jnp.where(mine, held, cfg.experts_local),
                               length=cfg.experts_local + 1)[:-1]
-    counts = jnp.stack([jnp.asarray(top_idx.size, jnp.int32),
-                        jnp.sum(mine).astype(jnp.int32),
-                        jnp.sum(per_expert > 0).astype(jnp.int32)])
+    local = jnp.sum(mine).astype(jnp.int32)
+    counts = jnp.stack([jnp.asarray(top_idx.size, jnp.int32), local,
+                        jnp.sum(per_expert > 0).astype(jnp.int32),
+                        (local <= moe_capacity(top_idx.size, cfg)
+                         ).astype(jnp.int32),
+                        jnp.asarray(1, jnp.int32)])
     return h + y.reshape(h.shape).astype(h.dtype), top_idx, counts
 
 

@@ -205,6 +205,20 @@ def moe_route(flat: jnp.ndarray, router: jnp.ndarray, k: int, *,
     return top_idx.astype(jnp.int32), gates
 
 
+def moe_capacity(n_assign: int, cfg: ModelConfig) -> int:
+    """Rows of the sorted assignments that :func:`moe_experts` computes where
+    a chip holds a SHARE of the routed experts: whole ``ROW_TILE``s, four
+    times what uniform routing sends the held experts (``n_assign x
+    experts_local / num_experts``), at most all ``n_assign``. A function of
+    shapes alone; a chip that holds every expert computes every row."""
+    from ..ops.grouped_matmul import ROW_TILE
+
+    if not cfg.experts_held:
+        return n_assign
+    tiles = -(-4 * n_assign * cfg.experts_local // (cfg.num_experts * ROW_TILE))
+    return min(tiles * ROW_TILE, n_assign)
+
+
 def moe_experts(flat: jnp.ndarray, top_idx: jnp.ndarray, gates: jnp.ndarray,
                 moe: dict, cfg: ModelConfig, layer) -> jnp.ndarray:
     """The chosen experts' gated MLPs, summed by gate: [N, H] f32. ``moe``
@@ -216,8 +230,13 @@ def moe_experts(flat: jnp.ndarray, top_idx: jnp.ndarray, gates: jnp.ndarray,
     **A chip's share** (``cfg.experts_held``): ``top_idx`` ranges over all
     ``cfg.num_experts`` but ``moe`` holds experts ``cfg.expert_offset ..``
     only. The assignments to experts held elsewhere sort to the end, past
-    every group, where no work item reaches them, and add nothing: the
-    result is the part of the layer that the held experts give."""
+    every group, and add nothing: the result is the part of the layer that
+    the held experts give. The gather, the three matmuls' buffers, the gate
+    multiply and the scatter-add run over the first ``moe_capacity`` rows of
+    the sorted list, the COMPACTED list of the assignments held here, where
+    those are all of them (``lax.cond`` on the held count, on the device);
+    a step that holds more takes every row. No assignment is dropped either
+    way, and the held rows are the same rows in the same tiles."""
     from ..ops.grouped_matmul import grouped_matmul
 
     E = cfg.experts_local
@@ -229,20 +248,30 @@ def moe_experts(flat: jnp.ndarray, top_idx: jnp.ndarray, gates: jnp.ndarray,
         expert_of = jnp.where((expert_of >= 0) & (expert_of < E), expert_of, E)
     order = jnp.argsort(expert_of)            # stable: token order in a group
     sizes = jnp.bincount(expert_of, length=E).astype(jnp.int32)
-    rows = flat[order // K]                                    # [NK, H]
 
     def gmm(x, w):
         m, s = (w["q"], w["s"]) if isinstance(w, dict) else (w, None)
         return grouped_matmul(x, m, s, sizes, layer, interpret=interpret)
 
-    gate = gmm(rows, moe["moe_gate"])
-    up = gmm(rows, moe["moe_up"])
-    act = (_act(gate, cfg) * up).astype(flat.dtype)
-    out = gmm(act, moe["moe_down"]) * gates.reshape(N * K)[order][:, None]
-    if cfg.experts_held:   # rows past the groups were never written
-        held = jnp.arange(N * K, dtype=jnp.int32) < jnp.sum(sizes)
-        out = jnp.where(held[:, None], out, 0.0)
-    return jnp.zeros((N, flat.shape[1]), jnp.float32).at[order // K].add(out)
+    def over(order):
+        """The layer over these rows of the sorted list (all that are held
+        lie inside them)."""
+        rows = flat[order // K]                                # [rows, H]
+        gate = gmm(rows, moe["moe_gate"])
+        up = gmm(rows, moe["moe_up"])
+        act = (_act(gate, cfg) * up).astype(flat.dtype)
+        out = gmm(act, moe["moe_down"]) * gates.reshape(N * K)[order][:, None]
+        if cfg.experts_held:   # rows past the groups were never written
+            held = jnp.arange(order.shape[0], dtype=jnp.int32) < jnp.sum(sizes)
+            out = jnp.where(held[:, None], out, 0.0)
+        return jnp.zeros((N, flat.shape[1]), jnp.float32
+                         ).at[order // K].add(out)
+
+    capacity = moe_capacity(N * K, cfg)
+    if capacity == N * K:
+        return over(order)
+    return jax.lax.cond(jnp.sum(sizes) <= capacity,
+                        lambda: over(order[:capacity]), lambda: over(order))
 
 
 def _moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig,

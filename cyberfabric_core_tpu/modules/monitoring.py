@@ -581,6 +581,13 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                 ("llm_moe_assignments_local_total",
                  "Those of them that fell on experts held here: experts "
                  "held / experts routed of them under uniform routing"),
+                ("llm_moe_layer_forwards_total",
+                 "Expert layers the forwards ran (layers x forwards) where "
+                 "a chip holds a share of the routed experts"),
+                ("llm_moe_layer_forwards_compact_total",
+                 "Those of them whose held assignments fitted the capacity "
+                 "taken from shapes, so the layer ran over the compacted "
+                 "list; the rest ran over every assignment, none dropped"),
                 ("llm_moe_decode_experts_touched_total",
                  "Experts that received at least one token, summed over "
                  "layers and the forwards of decode chunks alone (a mixed "

@@ -153,12 +153,17 @@ _BLOCK_SERIES = ("llm_block_row_forwards_total",
 #: of its module's ``MOE_COUNTERS``: the assignments routed (tokens x experts a
 #: token, over layers and forwards), those that fell on experts held here
 #: (experts held over experts routed of them where routing is uniform), the
-#: held experts that received a token. Beside them the held experts offered,
-#: and both once more over the forwards of decode chunks alone: a mixed step's
-#: prompt chunk touches nearly every expert, a decode step's rows do not
+#: held experts that received a token, the expert layers that ran over the
+#: compacted list of a share's assignments (``models/llama.py: moe_experts``)
+#: and the expert layers run. Beside them the held experts offered, and
+#: touched and offered once more over the forwards of decode chunks alone: a
+#: mixed step's prompt chunk touches nearly every expert, a decode step's
+#: rows do not
 _MOE_SERIES_OF = {"assignments": "llm_moe_assignments_total",
                   "local": "llm_moe_assignments_local_total",
-                  "touched": "llm_moe_experts_touched_total"}
+                  "touched": "llm_moe_experts_touched_total",
+                  "compact": "llm_moe_layer_forwards_compact_total",
+                  "forwards": "llm_moe_layer_forwards_total"}
 _MOE_DRAIN_SERIES = ("llm_moe_experts_offered_total",
                      "llm_moe_decode_experts_touched_total",
                      "llm_moe_decode_experts_offered_total")
@@ -692,7 +697,8 @@ class ContinuousBatchingEngine:
             self._refuse_without_latent_support(config)
         #: counters a model's forwards hand over beside the hidden state
         #: (``MOE_COUNTERS`` of its module: routed assignments, those on
-        #: experts held here, held experts touched); they ride the drained
+        #: experts held here, held experts touched, a share's expert layers
+        #: run over the compacted list and all of them); they ride the drained
         #: token matrix as its last rows
         self._moe_counters: tuple = getattr(self._model, "MOE_COUNTERS", ())
         self.pd_role = str(config.pd_role or "")

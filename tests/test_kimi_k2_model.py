@@ -295,3 +295,181 @@ def test_yarn_frequencies_and_softmax_scale_by_hand():
     want = rope.rope_frequencies(16, 8, 10000.0)
     got = rope.rope_tables(plain_cfg, 8)
     assert all(np.array_equal(a, b) for a, b in zip(got, want))
+
+
+# ------------------------------------------ a share's compacted assignments
+def _moe_experts_before(flat, top_idx, gates, moe, cfg, layer):
+    """``moe_experts`` as it was before a share's assignments were
+    compacted (PR 39), word for word: what the compact path, the fallback
+    and a whole model's trace are held to."""
+    from cyberfabric_core_tpu.models.llama import _act, _default_interpret
+    from cyberfabric_core_tpu.ops.grouped_matmul import grouped_matmul
+
+    E = cfg.experts_local
+    N, K = top_idx.shape
+    interpret = _default_interpret()
+    expert_of = top_idx.reshape(N * K)
+    if cfg.experts_held:
+        expert_of = expert_of - cfg.expert_offset
+        expert_of = jnp.where((expert_of >= 0) & (expert_of < E), expert_of, E)
+    order = jnp.argsort(expert_of)
+    sizes = jnp.bincount(expert_of, length=E).astype(jnp.int32)
+    rows = flat[order // K]
+
+    def gmm(x, w):
+        m, s = (w["q"], w["s"]) if isinstance(w, dict) else (w, None)
+        return grouped_matmul(x, m, s, sizes, layer, interpret=interpret)
+
+    gate = gmm(rows, moe["moe_gate"])
+    up = gmm(rows, moe["moe_up"])
+    act = (_act(gate, cfg) * up).astype(flat.dtype)
+    out = gmm(act, moe["moe_down"]) * gates.reshape(N * K)[order][:, None]
+    if cfg.experts_held:
+        held = jnp.arange(N * K, dtype=jnp.int32) < jnp.sum(sizes)
+        out = jnp.where(held[:, None], out, 0.0)
+    return jnp.zeros((N, flat.shape[1]), jnp.float32).at[order // K].add(out)
+
+
+#: a thin share of the tiny preset: 3 of 64 experts, so that four times the
+#: uniform expectation is one ROW_TILE (128) of a decode-shaped step's 256
+#: assignments and of a mixed-shaped step's 640
+THIN = dataclasses.replace(get_config("tiny-kimi-share4"), num_experts=64,
+                           experts_held=3, expert_offset=4)
+
+
+def _layer_inputs(cfg, n, seed=0):
+    rng = np.random.default_rng(seed)
+    H, El = cfg.hidden_size, cfg.experts_local
+    I = cfg.moe_intermediate_size or cfg.intermediate_size
+    moe = {"moe_gate": rng.normal(size=(1, El, H, I)) * H ** -0.5,
+           "moe_up": rng.normal(size=(1, El, H, I)) * H ** -0.5,
+           "moe_down": rng.normal(size=(1, El, I, H)) * I ** -0.5}
+    moe = {k: jnp.asarray(v, jnp.float32) for k, v in moe.items()}
+    flat = jnp.asarray(rng.normal(size=(n, H)), jnp.float32)
+    gates = jnp.asarray(rng.random((n, cfg.experts_per_token)), jnp.float32)
+    return rng, moe, flat, gates
+
+
+def _top_idx_with(rng, cfg, n, held):
+    """[n, K] choices of which exactly ``held`` fall on the held experts
+    (a token's choices distinct), the rest on experts held elsewhere."""
+    K, El, lo = cfg.experts_per_token, cfg.experts_local, cfg.expert_offset
+    away = np.setdiff1d(np.arange(cfg.num_experts), np.arange(lo, lo + El))
+    top = np.stack([rng.choice(away, K, replace=False) for _ in range(n)])
+    for at in rng.permutation(n * El)[:held]:
+        token, e = divmod(int(at), El)
+        top[token, e] = lo + e
+    return jnp.asarray(top, jnp.int32)
+
+
+@pytest.mark.parametrize("n", [64, 160], ids=["decode-shaped", "mixed-shaped"])
+@pytest.mark.parametrize("held", ["none", "one", "capacity", "capacity+1",
+                                  "all"])
+def test_a_shares_compacted_assignments_give_the_same_sum(n, held):
+    """The expert layer over the first ``moe_capacity`` rows of the sorted
+    assignments against the layer over every row (the function as it was):
+    nothing held, one, exactly the capacity (the last step that compacts),
+    one more (the fallback runs and nothing is dropped), every choice of
+    every token that can be held."""
+    from cyberfabric_core_tpu.models.llama import moe_capacity
+
+    K, El = THIN.experts_per_token, THIN.experts_local
+    capacity = moe_capacity(n * K, THIN)
+    assert capacity == 128 < n * El <= n * K      # it can overflow
+    count = {"none": 0, "one": 1, "capacity": capacity,
+             "capacity+1": capacity + 1, "all": n * El}[held]
+    rng, moe, flat, gates = _layer_inputs(THIN, n, seed=n + count)
+    top_idx = _top_idx_with(rng, THIN, n, count)
+    assert int(((top_idx >= 4) & (top_idx < 7)).sum()) == count
+    got = moe_experts(flat, top_idx, gates, moe, THIN, 0)
+    want = _moe_experts_before(flat, top_idx, gates, moe, THIN, 0)
+    assert (np.abs(np.asarray(want)).max() > 0) == (count > 0)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
+    jaxpr = jax.make_jaxpr(
+        lambda f, t, g: moe_experts(f, t, g, moe, THIN, 0))(
+            flat, top_idx, gates)
+    assert "cond" in [e.primitive.name for e in jaxpr.jaxpr.eqns]
+
+
+def test_the_capacity_is_a_function_of_shapes_alone():
+    """Whole ROW_TILEs, four times the uniform expectation, at most every
+    assignment: the benchmark cell's two shapes, and no row cut where a chip
+    holds every expert or its share is a quarter or more."""
+    from cyberfabric_core_tpu.models.llama import moe_capacity
+
+    share = get_config("kimi-k2.5-share32-15l")
+    assert moe_capacity((64 + 512) * 8, share) == 640     # mean 144 held
+    assert moe_capacity(64 * 8, share) == 128             # mean 16
+    assert moe_capacity(16, share) == 16
+    for name in ("tiny-kimi", "tiny-kimi-share4", "tiny-sdar",
+                 "tiny-granite-hybrid", "kimi-k2.5"):
+        assert moe_capacity(4608, get_config(name)) == 4608, name
+
+
+@pytest.mark.parametrize("name", ["tiny-sdar", "tiny-granite-hybrid",
+                                  "tiny-kimi", "tiny-kimi-share4"])
+def test_a_chip_that_computes_every_row_traces_as_it_did(name):
+    """Where ``moe_capacity`` is every assignment (a chip that holds every
+    expert: sdar, granite, the uncut kimi; and a share of a quarter) the
+    function traces to the jaxpr it had: no ``cond``, the same equations,
+    so the same compiled programs."""
+    cfg = get_config(name)
+    _, moe, flat, gates = _layer_inputs(cfg, 36)
+    top_idx = jnp.asarray(np.random.default_rng(1).integers(
+        0, cfg.num_experts, (36, cfg.experts_per_token)), jnp.int32)
+
+    def traced(fn):
+        return jax.make_jaxpr(
+            lambda f, t, g: fn(f, t, g, moe, cfg, 0))(flat, top_idx, gates)
+
+    now = traced(moe_experts)
+    # (a kernel's own ``pl.when`` is a cond inside its pallas_call)
+    assert "cond" not in [e.primitive.name for e in now.jaxpr.eqns]
+    assert str(now) == str(traced(_moe_experts_before))
+
+
+@pytest.mark.parametrize("shape", ["mixed", "decode"])
+def test_a_thin_shares_forwards_equal_the_uncompacted_ones(shape,
+                                                           monkeypatch):
+    """``forward_paged_mixed`` (8 lanes of 32: 1 024 assignments a layer, of
+    which the capacity takes 256) and ``forward_paged_decode`` (64 rows: 128
+    of 256) of a thin share on a fixed seed: hidden states, the pool and
+    the counters equal to what the layers gave over every row."""
+    from cyberfabric_core_tpu.models import llama
+
+    cfg = dataclasses.replace(THIN, num_experts=16, experts_held=1)
+    B, P = 64, 4
+    params = kimi_k2.init_params(cfg, jax.random.PRNGKey(3), jnp.float32)
+    tables = rope.rope_tables(cfg, P * PAGE)
+    table = jnp.arange(1, 1 + B * P, dtype=jnp.int32).reshape(B, P)
+
+    def run():
+        rng = np.random.default_rng(4)
+        pool = jnp.zeros((cfg.num_layers, 1 + B * P, PAGE, cfg.latent_lanes),
+                         jnp.float32)
+        if shape == "decode":
+            ids = jnp.asarray(rng.integers(3, 250, (B, 1)), jnp.int32)
+            return kimi_k2.forward_paged_decode(
+                params, cfg, ids, (pool,), table,
+                jnp.zeros((B,), jnp.int32), tables)
+        ids = jnp.asarray(rng.integers(3, 250, (8, 32)), jnp.int32)
+        return kimi_k2.forward_paged_mixed(
+            params, cfg, ids, (pool,), table[:8], jnp.zeros((8,), jnp.int32),
+            jnp.full((8,), 32, jnp.int32), tables)
+
+    h, (pool,), aux = run()
+    tokens = B if shape == "decode" else 8 * 32
+    assert llama.moe_capacity(tokens * cfg.experts_per_token, cfg) \
+        < tokens * cfg.experts_per_token
+    assert int(aux["forwards"]) == cfg.num_moe_layers
+    assert int(aux["compact"]) == cfg.num_moe_layers       # none overflowed
+    monkeypatch.setattr(llama, "moe_capacity", lambda n, cfg: n)
+    h0, (pool0,), aux0 = run()
+    np.testing.assert_allclose(np.asarray(h), np.asarray(h0), rtol=1e-6,
+                               atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(pool), np.asarray(pool0))
+    for name in ("experts", "assignments", "local", "touched"):
+        np.testing.assert_array_equal(np.asarray(aux[name]),
+                                      np.asarray(aux0[name]))
+    assert int(aux["local"]) > 0

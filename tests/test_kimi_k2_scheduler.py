@@ -5,8 +5,11 @@ refused), the programs ``mixed_step`` and ``paged_decode_chunk`` over one
 donated cache operand, the expert counters of a chip's share and the
 walked/offered counters of the latent kernel's work list."""
 
+import dataclasses
+import json
 import threading
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -257,3 +260,83 @@ def test_the_counters_of_a_chips_share():
     assert st["page_layout"] == "latent"
     assert st["cache_bytes_per_token"] == CFG.cache_bytes_per_token() == 768
     assert len(col.tokens[0]) == 13
+
+
+COMPACT = ("llm_moe_layer_forwards_compact_total",
+           "llm_moe_layer_forwards_total")
+
+
+def test_a_served_share_counts_its_compact_expert_layers():
+    """/metrics: every expert layer of every forward is counted, and all of
+    them as compact where the held assignments fit the capacity (here every
+    row is inside it: a share of a quarter). The benchmark's
+    ``moe_compact_share`` reads the pair from a window's two scrapes through
+    the ``counter`` kind, by its data file's own arguments."""
+    from benchmark import layer_readers
+    from cyberfabric_core_tpu.models.kimi_k2 import MOE_COUNTERS
+
+    assert MOE_COUNTERS[-2:] == ("compact", "forwards")
+    assert _moe_series(MOE_COUNTERS)[3:5] == COMPACT
+    start = {s: _counter(s) for s in COMPACT}
+    _run(_cfg(decode_lookahead=0), [_prompt(9, 18)], max_tokens=13)
+    end = {s: _counter(s) for s in COMPACT}
+    forwards = 1 + 3 * 4            # one mixed step, then 3 chunks of 4
+    assert end[COMPACT[1]] - start[COMPACT[1]] == \
+        forwards * CFG.num_moe_layers
+    assert end[COMPACT[0]] - start[COMPACT[0]] == \
+        forwards * CFG.num_moe_layers
+    metric = json.loads((Path(__file__).resolve().parents[1] / "benchmark"
+                         / "layer_metrics/moe_compact_share.json").read_text())
+    assert metric["kind"] == "counter"
+    args = {k: v for k, v in metric.items() if k not in ("kind", "what")}
+    assert (args["series"], args["over"]) == COMPACT
+    ctx = {"scrapes": {"start": start, "end": end}}
+    assert layer_readers.counter(ctx, **args) == 1.0
+    # a program without the series (this PR's parent): nothing to read
+    assert layer_readers.counter({"scrapes": {"start": {}, "end": {}}},
+                                 **args) is None
+
+
+def test_an_overflowing_step_takes_every_row_and_is_counted(monkeypatch):
+    """A thin share (3 of 64 experts) whose router is biased onto the held
+    experts: a mixed step of 4 + 64 tokens holds 3 x 68 = 204 assignments a
+    layer where the capacity is 128, so its expert layers take every row (the
+    counters say so) and the answer is the one the same engine gives with no
+    capacity at all; a decode step's 16 assignments are all inside theirs."""
+    from cyberfabric_core_tpu.models import MODEL_CONFIGS, llama
+
+    thin = dataclasses.replace(CFG, name="tiny-kimi-share3of64",
+                               num_experts=64, experts_held=3)
+    monkeypatch.setitem(MODEL_CONFIGS, thin.name, thin)
+    assert llama.moe_capacity((4 + 64) * thin.experts_per_token, thin) == 128
+
+    def served():
+        sched = ContinuousBatchingEngine(
+            _cfg(model=thin.name, decode_lookahead=0,
+                 prefill_budget_tokens=64), seed=0)
+        layers = dict(sched.params["layers"])
+        layers["router_bias"] = layers["router_bias"].at[
+            :, thin.expert_offset: thin.expert_offset + 3].set(100.0)
+        sched.params = {**sched.params, "layers": layers}
+        col = _Collector(1)
+        before = {s: _counter(s) for s in COMPACT + (
+            "llm_moe_assignments_local_total", "llm_moe_assignments_total")}
+        try:
+            sched.submit(_prompt(11, 70), SamplingParams(max_tokens=9),
+                         col.emit_for(0))
+            assert col.done.wait(240), sched.stats()
+            time.sleep(0.2)
+        finally:
+            sched.shutdown()
+        return col.tokens[0], {s: _counter(s) - v for s, v in before.items()}
+
+    tokens, d = served()
+    layers = thin.num_moe_layers
+    # two mixed steps (64 + 6 prompt tokens), then 2 chunks of 4 steps
+    assert d[COMPACT[1]] == (2 + 2 * 4) * layers
+    assert d[COMPACT[1]] - d[COMPACT[0]] == layers      # the 68-token step
+    # every token chose all three held experts: nothing was dropped
+    assert d["llm_moe_assignments_local_total"] * 4 == \
+        d["llm_moe_assignments_total"] * 3
+    monkeypatch.setattr(llama, "moe_capacity", lambda n, cfg: n)
+    assert served()[0] == tokens and len(tokens) == 9
