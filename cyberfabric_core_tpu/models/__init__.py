@@ -19,7 +19,7 @@ __all__ = ["MODEL_CONFIGS", "ModelConfig", "get_config", "decoder_module"]
 #: init_state.
 _DECODERS = {"llama": "llama", "falcon_h1": "falcon_h1",
              "sdar_moe": "sdar_moe", "kimi_k2": "kimi_k2",
-             "granite_hybrid": "granite_hybrid"}
+             "granite_hybrid": "granite_hybrid", "nemotron_h": "nemotron_h"}
 
 
 def decoder_module(cfg: ModelConfig) -> ModuleType:

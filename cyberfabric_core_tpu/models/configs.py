@@ -24,7 +24,10 @@ class ModelConfig:
     #: sigmoid-routed experts beside a shared expert) | "granite_hybrid" (a
     #: stack whose layers differ in kind, ``layer_types``: Mamba-2 mixer
     #: layers and attention layers without rotary, routed experts beside a
-    #: shared MLP after each) | "bert" (encoder)
+    #: shared MLP after each) | "nemotron_h" (a stack whose every layer is ONE
+    #: sub-layer, ``layer_types``: a Mamba-2 mixer, attention without rotary,
+    #: or routed experts that work in a latent beside a shared expert) |
+    #: "bert" (encoder)
     architecture: str
     vocab_size: int
     hidden_size: int
@@ -40,7 +43,10 @@ class ModelConfig:
     sliding_window: Optional[int] = None  # Mistral-style SWA
     attention_bias: bool = False
     # gemma-family knobs
-    hidden_act: str = "silu"          # "silu" (llama) | "gelu" (gemma GeGLU)
+    #: "silu" (llama) | "gelu" (gemma GeGLU): the gate's activation in a gated
+    #: MLP of three matrices; "relu2" (nemotron_h): ``relu(x W1)² W2``, an
+    #: MLP of two matrices with no gate
+    hidden_act: str = "silu"
     norm_weight_offset: float = 0.0   # gemma RMSNorm computes (offset + w) * x̂
     embedding_multiplier: float = 1.0  # gemma scales embeddings by sqrt(H)
     final_logit_softcap: float = 0.0  # gemma-2: logits = cap * tanh(logits/cap)
@@ -122,12 +128,16 @@ class ModelConfig:
     ssm_out_multiplier: float = 1.0
     ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
     mlp_multipliers: tuple = (1.0, 1.0)
-    # granite_hybrid (model_type granitemoehybrid), names as published. The
-    # kind of every layer, "mamba" or "attention" (empty: every layer is what
-    # the architecture's one block is): a mamba layer holds recurrent state
-    # and writes no page, an attention layer holds pages and no state, so
-    # the page pool has ``kv_layers`` layers and the state slab
-    # ``state_layers``
+    # granite_hybrid (model_type granitemoehybrid) and nemotron_h, names as
+    # published. The kind of every layer, "mamba", "attention" or "moe"
+    # (empty: every layer is what the architecture's one block is): a mamba
+    # layer holds recurrent state and writes no page, an attention layer
+    # holds pages and no state, a moe layer (experts ALONE, no mixer in front
+    # of them: nemotron_h) neither, so the page pool has ``kv_layers``
+    # layers, the state slab ``state_layers`` and the expert stack
+    # ``moe_layers``. Whether a mamba or attention layer ALSO holds an expert
+    # layer is what the architecture says (granite_hybrid: every one does),
+    # not what ``layer_types`` implies
     layer_types: tuple = ()
     #: every branch (mixer or attention, then the expert layer) is added to
     #: the residual stream times this
@@ -140,17 +150,22 @@ class ModelConfig:
     rotary: bool = True
     #: width of the shared MLP every token runs beside the routed experts
     shared_intermediate_size: int = 0
+    #: nemotron_h: the routed experts work on rows of this width, between ONE
+    #: dense down-projection of the hidden and ONE up-projection (0: on the
+    #: hidden itself); the router and the shared expert see the full hidden
+    moe_latent_size: int = 0
     # bert-family extras
     layer_norm_eps: float = 1e-12
     type_vocab_size: int = 2
     pooling: str = "cls"  # bge uses CLS pooling + L2 norm
 
     def __post_init__(self) -> None:
-        if self.hidden_act not in ("silu", "gelu", "gelu_pytorch_tanh"):
+        if self.hidden_act not in ("silu", "gelu", "gelu_pytorch_tanh",
+                                   "relu2"):
             # fail at config time, not as silently-wrong activations at runtime
             raise ValueError(
                 f"unknown hidden_act {self.hidden_act!r} "
-                "(supported: silu, gelu, gelu_pytorch_tanh)")
+                "(supported: silu, gelu, gelu_pytorch_tanh, relu2)")
         if self.remasking not in ("low_confidence_static",
                                   "low_confidence_dynamic"):
             raise ValueError(f"unknown remasking {self.remasking!r}")
@@ -161,11 +176,12 @@ class ModelConfig:
                 f"the router's {self.num_experts}")
         if self.layer_types and (
                 len(self.layer_types) != self.num_layers
-                or set(self.layer_types) - {"mamba", "attention"}):
+                or set(self.layer_types) - {"mamba", "attention", "moe"}):
             raise ValueError(
                 f"{self.name}: layer_types names {len(self.layer_types)} "
                 f"layers of kinds {sorted(set(self.layer_types))} for "
-                f"num_layers {self.num_layers} (kinds: mamba, attention)")
+                f"num_layers {self.num_layers} (kinds: mamba, attention, "
+                "moe)")
         if self.block_length > 1 and (
                 self.block_length % self.denoising_steps
                 or not 0 <= self.mask_token_id < self.vocab_size):
@@ -182,7 +198,8 @@ class ModelConfig:
     def router_float32(self) -> bool:
         """The router's weights stay float32 whatever the activations' dtype
         (a score decides WHICH experts run, not only how much)."""
-        return self.architecture in ("sdar_moe", "kimi_k2", "granite_hybrid")
+        return self.architecture in ("sdar_moe", "kimi_k2", "granite_hybrid",
+                                     "nemotron_h")
 
     @property
     def is_latent(self) -> bool:
@@ -219,8 +236,21 @@ class ModelConfig:
         return self.vocab_held or self.vocab_size
 
     @property
-    def num_moe_layers(self) -> int:
+    def moe_layers(self) -> int:
+        """Layers that hold routed experts: the expert stack's leading
+        dimension. Where ``layer_types`` names ``moe`` layers, those; else
+        every layer past the leading dense ones."""
+        if "moe" in self.layer_types:
+            return self.layer_types.count("moe")
         return self.num_layers - self.first_k_dense if self.num_experts else 0
+
+    #: the name kimi_k2's code reads it by
+    num_moe_layers = moe_layers
+
+    @property
+    def expert_row_width(self) -> int:
+        """Width of the rows the routed experts multiply."""
+        return self.moe_latent_size or self.hidden_size
 
     @property
     def kv_layers(self) -> int:
@@ -282,16 +312,63 @@ class ModelConfig:
         h, i, v, l = self.hidden_size, self.intermediate_size, self.vocab_size, self.num_layers
         attn = h * (self.num_heads * self.head_dim) + 2 * h * (self.num_kv_heads * self.head_dim) \
             + (self.num_heads * self.head_dim) * h
-        mlp = 3 * h * i * max(self.num_experts, 1) + h * self.num_experts \
-            + 3 * h * self.shared_intermediate_size
         emb = v * h * (1 if self.tie_embeddings else 2)
         mixer = 0
         if self.has_state:  # in/out projections, conv + bias, A, D, dt, norm
             mixer = (h * self.ssm_proj_dim + self.ssm_inner * h
                      + (self.ssm_conv + 1) * self.ssm_conv_dim
                      + 3 * self.ssm_heads + self.ssm_inner)
+        if "moe" in self.layer_types:
+            # one sub-layer and one norm a layer; an expert of two matrices
+            # on the latent, the router and its selection bias, the latent's
+            # two projections, the shared expert of two matrices
+            w = self.expert_row_width
+            experts = (self.num_experts * (2 * w * i + h + 1)
+                       + (2 * h * w if self.moe_latent_size else 0)
+                       + 2 * h * self.shared_intermediate_size)
+            return (self.kv_layers * attn + self.state_layers * mixer
+                    + self.moe_layers * experts + l * h + emb + h)
+        mlp = 3 * h * i * max(self.num_experts, 1) + h * self.num_experts \
+            + 3 * h * self.shared_intermediate_size
         return (self.kv_layers * attn + self.state_layers * mixer
                 + l * (mlp + 2 * h) + emb + h)
+
+    def weight_bytes(self, itemsize: int = 1) -> dict[str, int]:
+        """Bytes of the matrices THIS CHIP holds, by the kind of layer that
+        holds them (``experts``: the held routed experts alone; ``vocab``:
+        the held rows of embedding and head), at ``itemsize`` a weight with
+        one f32 scale an output channel where ``itemsize`` is 1. For a
+        ``layer_types`` stack of one sub-layer a layer (nemotron_h)."""
+        h, i, w = self.hidden_size, self.intermediate_size, \
+            self.expert_row_width
+        scale = 4 if itemsize == 1 else 0
+
+        def mat(k: int, n: int, count: int = 1) -> int:
+            return count * (k * n * itemsize + n * scale)
+
+        dq, dkv = self.num_heads * self.head_dim, \
+            self.num_kv_heads * self.head_dim
+        return {
+            "mamba": self.state_layers * (
+                mat(h, self.ssm_proj_dim) + mat(self.ssm_inner, h)),
+            "attention": self.kv_layers * (
+                mat(h, dq) + 2 * mat(h, dkv) + mat(dq, h)),
+            "moe_dense": self.moe_layers * (
+                mat(h, self.shared_intermediate_size)
+                + mat(self.shared_intermediate_size, h)
+                + (mat(h, w) + mat(w, h) if self.moe_latent_size else 0)
+                + 4 * (h + 1) * self.num_experts),
+            "experts": self.moe_layers * self.experts_local * (
+                mat(w, i) + mat(i, w)),
+            "vocab": (self.vocab_rows * (h * itemsize + scale)
+                      * (1 if self.tie_embeddings else 2)),
+        }
+
+
+def _nemotron_kinds(pattern: str) -> tuple:
+    """``hybrid_override_pattern`` as ``layer_types``."""
+    kind = {"M": "mamba", "*": "attention", "E": "moe"}
+    return tuple(kind[c] for c in pattern)
 
 
 MODEL_CONFIGS: dict[str, ModelConfig] = {
@@ -528,6 +605,43 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         ssm_inner=128, ssm_heads=16, ssm_head_dim=8, ssm_state=16,
         ssm_groups=1, ssm_conv=4, ssm_chunk=8,
     ),
+    # NVIDIA-Nemotron-3-Super-120B-A12B, config.json as published (model_type
+    # nemotron_h): 88 layers, each ONE sub-layer by hybrid_override_pattern
+    # (M a Mamba-2 mixer of 128 heads of 64, state 128, 8 groups; * GQA 32/2
+    # attention without rotary; E 512 sigmoid-routed experts top-22, scale 5,
+    # of 2688 in a latent of 1024, relu2 and no gate, beside a shared expert
+    # of 5376 on the full hidden), untied head. The multi-token-prediction
+    # module (num_nextn_predict_layers 1) is a draft module and not part of
+    # the served model
+    "nemotron-3-super-120b-a12b": ModelConfig(
+        name="nemotron-3-super-120b-a12b", architecture="nemotron_h",
+        vocab_size=131072, hidden_size=4096, intermediate_size=2688,
+        num_layers=88, num_heads=32, num_kv_heads=2, head_dim=128,
+        max_position=262144, rope_theta=10000.0, rms_norm_eps=1e-5,
+        hidden_act="relu2", num_experts=512, experts_per_token=22,
+        routed_scaling_factor=5.0, moe_latent_size=1024,
+        shared_intermediate_size=5376, rotary=False,
+        layer_types=_nemotron_kinds(
+            "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+            "EMEMEMEMEM*EMEMEMEM*EMEMEMEME"),
+        ssm_inner=8192, ssm_heads=128, ssm_head_dim=64, ssm_state=128,
+        ssm_groups=8, ssm_conv=4, ssm_chunk=128,
+    ),
+    # CPU-test preset of the same stack: all three kinds and the M E pair
+    # repeated, 16 experts top-3 (scale 2.5) of 24 in a latent of 32 < 64,
+    # 2 groups of 8 heads, 4 queries a kv head, an untied head, a chunk (8)
+    # shorter than the test prompts
+    "tiny-nemotron-h": ModelConfig(
+        name="tiny-nemotron-h", architecture="nemotron_h", vocab_size=512,
+        hidden_size=64, intermediate_size=24, num_layers=16, num_heads=4,
+        num_kv_heads=1, head_dim=16, max_position=256, rope_theta=10000.0,
+        rms_norm_eps=1e-5, hidden_act="relu2", num_experts=16,
+        experts_per_token=3, routed_scaling_factor=2.5, moe_latent_size=32,
+        shared_intermediate_size=48, rotary=False,
+        layer_types=_nemotron_kinds("MEME*EME" * 2),
+        ssm_inner=128, ssm_heads=16, ssm_head_dim=8, ssm_state=16,
+        ssm_groups=2, ssm_conv=4, ssm_chunk=8,
+    ),
     "bge-base-en": ModelConfig(
         name="bge-base-en", architecture="bert", vocab_size=30522, hidden_size=768,
         intermediate_size=3072, num_layers=12, num_heads=12, num_kv_heads=12,
@@ -576,6 +690,25 @@ MODEL_CONFIGS["granite-4.0-h-small-10l"] = MODEL_CONFIGS[
 # five), for the CPU tests that build an engine: compile time is the runs'
 MODEL_CONFIGS["tiny-granite-hybrid-4l"] = MODEL_CONFIGS[
     "tiny-granite-hybrid"].cut_to(4, "tiny-granite-hybrid-4l")
+
+
+# chip 0 of the first of 4 pipeline stages of nemotron-3-super (16 chips, a
+# four-chip host a stage): layers 0-21, two periods of the pattern (10 M, 10
+# E, 2 *); of each E layer's 512 experts the 128 this chip holds, rows
+# 0-32767 of the vocabulary (the first stage carries embedding and head);
+# mixers, attention, shared expert, router and latent projections whole
+MODEL_CONFIGS["nemotron-3-super-share4-22l"] = dataclasses.replace(
+    MODEL_CONFIGS["nemotron-3-super-120b-a12b"].cut_to(
+        22, "nemotron-3-super-share4-22l"),
+    experts_held=128, expert_offset=0, vocab_held=32768, max_position=4096)
+
+# a share of the tiny preset: experts 4-7 of 16, half the vocabulary; and its
+# first period alone (``MEME*EME``), for the CPU tests that build an engine
+MODEL_CONFIGS["tiny-nemotron-h-share4"] = dataclasses.replace(
+    MODEL_CONFIGS["tiny-nemotron-h"], name="tiny-nemotron-h-share4",
+    experts_held=4, expert_offset=4, vocab_held=256)
+MODEL_CONFIGS["tiny-nemotron-h-share4-8l"] = MODEL_CONFIGS[
+    "tiny-nemotron-h-share4"].cut_to(8, "tiny-nemotron-h-share4-8l")
 
 
 def get_config(name: str) -> ModelConfig:

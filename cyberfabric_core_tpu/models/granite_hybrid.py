@@ -74,11 +74,11 @@ MOE_COUNTERS = ("assignments", "local", "touched")
 Aux = dict[str, jnp.ndarray]
 
 
-def _one_device(mesh: Any, interpret: bool | None) -> bool:
+def _one_device(mesh: Any, interpret: bool | None, cfg: ModelConfig) -> bool:
     if mesh is not None:
-        raise ValueError("granite_hybrid serves on one device: the state "
-                         "slab has no tp sharding and the expert layer no ep "
-                         "axis")
+        raise ValueError(f"{cfg.architecture} serves on one device: the "
+                         "state slab has no tp sharding and the expert layer "
+                         "no ep axis")
     return _default_interpret() if interpret is None else interpret
 
 
@@ -235,11 +235,14 @@ def forward_paged_decode(
     mesh: Any = None,
     *,
     state: State,              # {"ssm", "conv"}: [state_layers, rows, ...]
+    run_layers: Callable | None = None,
 ) -> tuple[jnp.ndarray, PagedPools, State, Aux]:
     """One decode step over the page pool and the state slab. Returns
     (hidden [B, 1, H], pools, state, aux); pages and state move as in
-    ``falcon_h1.forward_paged_decode``, each in the layers of its kind."""
-    interpret = _one_device(mesh, interpret)
+    ``falcon_h1.forward_paged_decode``, each in the layers of its kind.
+    ``run_layers``: another stack over the same two sub-layers (``_run_layers``
+    says what it is handed; ``models/nemotron_h.py``)."""
+    interpret = _one_device(mesh, interpret, cfg)
     cos_t, sin_t = rope_tables     # read only where cfg.rotary
     B = input_ids.shape[0]
     positions = lengths[None, :]
@@ -269,8 +272,8 @@ def forward_paged_decode(
         return (_attn_out(lp, h, attn.reshape(1, B, -1),
                           cfg.residual_multiplier), k_pool, v_pool)
 
-    h, pools, state, aux = _run_layers(params, cfg, h, pools, state, mix,
-                                       attend)
+    h, pools, state, aux = (run_layers or _run_layers)(
+        params, cfg, h, pools, state, mix, attend)
     h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
     return h.reshape(B, 1, -1), pools, state, aux
 
@@ -291,12 +294,13 @@ def forward_paged_mixed(
     rows: jnp.ndarray | None = None,
     decode: DecodeGroup | None = None,
     state: State,
+    run_layers: Callable | None = None,
 ) -> tuple[jnp.ndarray, PagedPools, State, Aux]:
     """One ragged mixed step over the tokens it has. Returns (hidden, pools,
     state, aux); lanes, the decode group, pages, state and ``hidden`` as
     ``falcon_h1.forward_paged_mixed``, each cache in the layers of its
-    kind."""
-    interpret = _one_device(mesh, interpret)
+    kind. ``run_layers`` as in ``forward_paged_decode``."""
+    interpret = _one_device(mesh, interpret, cfg)
     cos_t, sin_t = rope_tables     # read only where cfg.rotary
     R, Qc = input_ids.shape
     lay = mixed_layout(cfg, input_ids, page_table, hist, q_lens, write_mask,
@@ -339,8 +343,8 @@ def forward_paged_mixed(
         return (_attn_out(lp, h, attn, cfg.residual_multiplier), k_pool,
                 v_pool)
 
-    h, pools, state, aux = _run_layers(params, cfg, h, pools, state, mix,
-                                       attend)
+    h, pools, state, aux = (run_layers or _run_layers)(
+        params, cfg, h, pools, state, mix, attend)
     h = mixed_hidden_out(lay, h, q_lens, rows)
     h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
     return h, pools, state, aux

@@ -65,7 +65,8 @@ from .configs import ModelConfig
 from .llama import (MOE_LEAVES, DecodeGroup, Params, _act, _decode_targets,
                     _scaled, _wmat, decode_work, embed_lookup,
                     gather_last_hidden, lm_head_logits, mixed_hidden_out,
-                    mixed_layout, moe_capacity, moe_experts, moe_route)
+                    mixed_layout, moe_capacity, moe_experts, moe_route,
+                    moe_share_counts)
 
 __all__ = ["init_params", "init_params_with", "forward_paged_decode",
            "forward_paged_mixed", "lm_head_logits", "gather_last_hidden",
@@ -235,13 +236,8 @@ def _moe_residual(lp: dict, moe: dict, layer, h: jnp.ndarray,
     y = moe_experts(flat, top_idx, gates, moe, cfg, layer)
     y = y + _swiglu(flat, lp["shared_gate"], lp["shared_up"],
                     lp["shared_down"], cfg)
-    held = top_idx.reshape(-1) - cfg.expert_offset
-    mine = (held >= 0) & (held < cfg.experts_local)
-    per_expert = jnp.bincount(jnp.where(mine, held, cfg.experts_local),
-                              length=cfg.experts_local + 1)[:-1]
-    local = jnp.sum(mine).astype(jnp.int32)
-    counts = jnp.stack([jnp.asarray(top_idx.size, jnp.int32), local,
-                        jnp.sum(per_expert > 0).astype(jnp.int32),
+    routed, local, touched = moe_share_counts(top_idx, cfg)
+    counts = jnp.stack([routed, local, touched,
                         (local <= moe_capacity(top_idx.size, cfg)
                          ).astype(jnp.int32),
                         jnp.asarray(1, jnp.int32)])

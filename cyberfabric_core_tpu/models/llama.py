@@ -51,10 +51,13 @@ def _scaled(y: jnp.ndarray, scale) -> jnp.ndarray:
 
 
 def _act(x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
-    """Gated-MLP activation: SiLU (llama family) or tanh-approx GeLU (gemma).
-    Unknown values are rejected at config time (ModelConfig.__post_init__)."""
+    """MLP activation: SiLU (llama family), tanh-approx GeLU (gemma) or the
+    squared ReLU (nemotron_h's two-matrix MLP). Unknown values are rejected
+    at config time (ModelConfig.__post_init__)."""
     if cfg.hidden_act in ("gelu", "gelu_pytorch_tanh"):
         return jax.nn.gelu(x, approximate=True)
+    if cfg.hidden_act == "relu2":
+        return jnp.square(jax.nn.relu(x))
     return jax.nn.silu(x)
 
 
@@ -161,17 +164,22 @@ def _moe_mlp_dense(x: jnp.ndarray, lp: dict, cfg: ModelConfig) -> jnp.ndarray:
 
 #: the expert matrices of a layers tree: handed to the expert layer as the
 #: tree stacks them, ``[L, E, ...]``, with the layer's index, never a layer of
-#: them (ops/grouped_matmul.py says why)
+#: them (ops/grouped_matmul.py says why). **An expert's form is which of
+#: them the tree holds**: all three is the gated MLP ``(act(x W_gate) ⊙ x
+#: W_up) W_down``; without ``moe_gate`` it is the two-matrix MLP ``act(x
+#: W_up) W_down`` (nemotron_h, ``act`` the squared ReLU). ``moe_up`` is
+#: ``[L, E, rows' width, I]`` and ``moe_down`` ``[L, E, I, rows' width]``,
+#: the rows' width the hidden size or a latent narrower than it
 MOE_LEAVES = ("moe_gate", "moe_up", "moe_down")
 
 
 def split_moe(layers: dict) -> tuple[dict, dict | None]:
     """(what a ``lax.scan`` over the layers slices, the stacked expert
     matrices it must not slice); the second is None for a dense model."""
-    if "moe_gate" not in layers:
+    if "moe_up" not in layers:
         return layers, None
     return ({k: v for k, v in layers.items() if k not in MOE_LEAVES},
-            {k: layers[k] for k in MOE_LEAVES})
+            {k: layers[k] for k in MOE_LEAVES if k in layers})
 
 
 def moe_route(flat: jnp.ndarray, router: jnp.ndarray, k: int, *,
@@ -221,17 +229,20 @@ def moe_capacity(n_assign: int, cfg: ModelConfig) -> int:
 
 def moe_experts(flat: jnp.ndarray, top_idx: jnp.ndarray, gates: jnp.ndarray,
                 moe: dict, cfg: ModelConfig, layer) -> jnp.ndarray:
-    """The chosen experts' gated MLPs, summed by gate: [N, H] f32. ``moe``
-    holds the STACKED expert matrices and ``layer`` picks the layer inside
-    the grouped matmul. Dropless: the ``N*K`` assignments are sorted by
-    expert, each expert multiplies the rows that chose it, however many, and
-    the results go back to token order.
+    """The chosen experts' MLPs over the rows ``flat`` [N, W], summed by
+    gate: [N, W] f32. ``moe`` holds the STACKED expert matrices, in the form
+    ``MOE_LEAVES`` describes (gated three-matrix, or two-matrix where the
+    tree has no ``moe_gate``), and ``layer`` picks the layer inside the
+    grouped matmul; ``W`` is whatever width the matrices take (the hidden
+    size, or nemotron_h's latent). Dropless: the ``N*K`` assignments are
+    sorted by expert, each expert multiplies the rows that chose it, however
+    many, and the results go back to token order.
 
     **A chip's share** (``cfg.experts_held``): ``top_idx`` ranges over all
     ``cfg.num_experts`` but ``moe`` holds experts ``cfg.expert_offset ..``
     only. The assignments to experts held elsewhere sort to the end, past
     every group, and add nothing: the result is the part of the layer that
-    the held experts give. The gather, the three matmuls' buffers, the gate
+    the held experts give. The gather, the matmuls' buffers, the gate
     multiply and the scatter-add run over the first ``moe_capacity`` rows of
     the sorted list, the COMPACTED list of the assignments held here, where
     those are all of them (``lax.cond`` on the held count, on the device);
@@ -256,10 +267,14 @@ def moe_experts(flat: jnp.ndarray, top_idx: jnp.ndarray, gates: jnp.ndarray,
     def over(order):
         """The layer over these rows of the sorted list (all that are held
         lie inside them)."""
-        rows = flat[order // K]                                # [rows, H]
-        gate = gmm(rows, moe["moe_gate"])
-        up = gmm(rows, moe["moe_up"])
-        act = (_act(gate, cfg) * up).astype(flat.dtype)
+        rows = flat[order // K]                                # [rows, W]
+        if "moe_gate" in moe:
+            gate = gmm(rows, moe["moe_gate"])
+            up = gmm(rows, moe["moe_up"])
+            act = _act(gate, cfg) * up
+        else:
+            act = _act(gmm(rows, moe["moe_up"]), cfg)
+        act = act.astype(flat.dtype)
         out = gmm(act, moe["moe_down"]) * gates.reshape(N * K)[order][:, None]
         if cfg.experts_held:   # rows past the groups were never written
             held = jnp.arange(order.shape[0], dtype=jnp.int32) < jnp.sum(sizes)
@@ -272,6 +287,19 @@ def moe_experts(flat: jnp.ndarray, top_idx: jnp.ndarray, gates: jnp.ndarray,
         return over(order)
     return jax.lax.cond(jnp.sum(sizes) <= capacity,
                         lambda: over(order[:capacity]), lambda: over(order))
+
+
+def moe_share_counts(top_idx: jnp.ndarray, cfg: ModelConfig):
+    """What one expert layer counts of the experts chosen ``[N, K]``: the
+    assignments routed, those that fell on experts this chip holds, and the
+    held experts with at least one (three int32 scalars)."""
+    held = top_idx.reshape(-1) - cfg.expert_offset
+    mine = (held >= 0) & (held < cfg.experts_local)
+    per_expert = jnp.bincount(jnp.where(mine, held, cfg.experts_local),
+                              length=cfg.experts_local + 1)[:-1]
+    return (jnp.asarray(top_idx.size, jnp.int32),
+            jnp.sum(mine).astype(jnp.int32),
+            jnp.sum(per_expert > 0).astype(jnp.int32))
 
 
 def _moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig,

@@ -594,7 +594,12 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                  "step's prompt chunk is left out)"),
                 ("llm_moe_decode_experts_offered_total",
                  "Experts held here, summed over expert layers and the "
-                 "forwards of decode chunks alone")):
+                 "forwards of decode chunks alone"),
+                ("llm_moe_decode_assignments_local_total",
+                 "Assignments that fell on experts held here, summed over "
+                 "layers and the forwards of decode chunks alone: over the "
+                 "decode-only touched, the rows a touched expert multiplies "
+                 "(a model whose module counts them)")):
             self.registry.counter(name, text).inc(0.0)
 
         def _state_stat(key: str) -> float:
@@ -630,6 +635,14 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                  "Bytes of the page pool plus the recurrent-state slab")):
             self.registry.gauge(name, text).set_function(
                 lambda key=key: _state_stat(key))
+
+        self.registry.gauge(
+            "llm_moe_layers",
+            "Layers of the stacked expert matrices: the model's layers that "
+            "hold routed experts"
+        ).set_function(lambda: float(sum(
+            getattr(s, "moe_layers_built", lambda: 0)()
+            for s in _schedulers())))
 
         def mixed_chunk_tokens() -> float:
             return float(sum(getattr(s, "chunked_prefill_tokens", 0)

@@ -18,12 +18,13 @@ no kv-head axis and no V pool; ``pools``,
 admission protocol count pages, whatever a page holds.
 
 One manager for both kinds of cache. A model with recurrent state
-(``ModelConfig.has_state``: falcon_h1, granite_hybrid) also gets a state slab
-here — ``{"ssm": [Ls, rows, H, P, N], "conv": [Ls, rows, K-1, C]}`` f32, made
+(``ModelConfig.has_state``: falcon_h1, granite_hybrid, nemotron_h) also gets a
+state slab here — ``{"ssm": [Ls, rows, H, P, N], "conv": [Ls, rows, K-1, C]}`` f32, made
 by the model's own module (``init_state``) — **each cache as deep as the
 layers of its kind**: the pool arrays have ``ModelConfig.kv_layers`` layers
 and the slab ``state_layers`` (both ``num_layers`` where every layer is one
-block; 1 and 9 for one period of granite's stack). The slab's first
+block; 1 and 9 for one period of granite's stack; 2 and 10 for nemotron_h's
+22 layers, whose other 10 hold experts alone and neither cache). The slab's first
 ``state_slots`` rows are the slots' own (row = slot) and whose further rows
 are **snapshots**, each owned by the prefix-tree page at whose end it was
 taken. State does not grow with a row's length and cannot be shared by

@@ -31,6 +31,8 @@ _MATMUL_LEAVES = {"wq", "wk", "wv", "wo", "gate", "up", "down",
                   # router's selection bias float32
                   "wq_a", "wq_b", "wkv_a", "wkv_b",
                   "shared_gate", "shared_up", "shared_down",
+                  # nemotron_h: the two projections around the experts' latent
+                  "latent_down", "latent_up",
                   # falcon_h1's mixer: W_in and W_out like any matrix; its
                   # conv, A_log, D, dt_bias and norm weights stay f32
                   "ssm_in", "ssm_out"}
@@ -73,8 +75,8 @@ def quantize_llama_params(params: dict[str, Any], bits: int = 8) -> dict[str, An
     # "layers", and where the stack is not one repeated layer the stacks
     # beside it: kimi_k2's leading "dense" layers, granite_hybrid's "mamba"
     # and "attention" layers (the mixer's conv, A_log, D, dt_bias and norm
-    # stay float32; the router float32)
-    for stack in ("dense", "mamba", "attention", "layers"):
+    # stay float32; the router float32), nemotron_h's "moe" layers
+    for stack in ("dense", "mamba", "attention", "moe", "layers"):
         if stack in params:
             out[stack] = {
                 # norms, router (tiny + precision-sensitive) stay as they are

@@ -155,7 +155,9 @@ _BLOCK_SERIES = ("llm_block_row_forwards_total",
 #: (experts held over experts routed of them where routing is uniform), the
 #: held experts that received a token, the expert layers that ran over the
 #: compacted list of a share's assignments (``models/llama.py: moe_experts``)
-#: and the expert layers run. Beside them the held experts offered, and
+#: and the expert layers run, and the held assignments of decode steps alone
+#: (nemotron_h: over the decode-only touched, the rows a touched expert
+#: multiplies). Beside them the held experts offered, and
 #: touched and offered once more over the forwards of decode chunks alone: a
 #: mixed step's prompt chunk touches nearly every expert, a decode step's
 #: rows do not
@@ -163,7 +165,8 @@ _MOE_SERIES_OF = {"assignments": "llm_moe_assignments_total",
                   "local": "llm_moe_assignments_local_total",
                   "touched": "llm_moe_experts_touched_total",
                   "compact": "llm_moe_layer_forwards_compact_total",
-                  "forwards": "llm_moe_layer_forwards_total"}
+                  "forwards": "llm_moe_layer_forwards_total",
+                  "decode_local": "llm_moe_decode_assignments_local_total"}
 _MOE_DRAIN_SERIES = ("llm_moe_experts_offered_total",
                      "llm_moe_decode_experts_touched_total",
                      "llm_moe_decode_experts_offered_total")
@@ -2184,6 +2187,17 @@ class ContinuousBatchingEngine:
             return 0
         live = sum(1 for s in locked_snapshot(self.slots) if s is not None)
         return live + self.pool.state_stats()["state_snapshot_rows_in_use"]
+
+    def moe_layers_built(self) -> int:
+        """Layers of the expert stack the parameters were BUILT with (its
+        leading dimension; 0 for a dense model): ``cfg.moe_layers`` unless a
+        tree stacks its experts over layers that hold none."""
+        for stack in self.params.values():
+            if isinstance(stack, dict) and "moe_up" in stack:
+                leaf = stack["moe_up"]
+                return int((leaf["q"] if isinstance(leaf, dict)
+                            else leaf).shape[0])
+        return 0
 
     # -------------------------------------------------------- health surface
     def mesh_info(self) -> dict[str, Any]:

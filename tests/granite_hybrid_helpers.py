@@ -33,17 +33,18 @@ def published(c) -> dict:
         logits_scaling=c.logits_scaling)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
+@functools.partial(jax.jit, static_argnames=("cfg", "module"))
 def _mixed(params, cfg, ids, pools, table, hist, q_lens, rope, write_mask,
-           rows, decode, state):
-    return granite_hybrid.forward_paged_mixed(
+           rows, decode, state, module=granite_hybrid):
+    return module.forward_paged_mixed(
         params, cfg, ids, pools, table, hist, q_lens, rope,
         write_mask=write_mask, rows=rows, decode=decode, state=state)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def _decode(params, cfg, ids, pools, table, lens, rope, write_mask, state):
-    return granite_hybrid.forward_paged_decode(
+@functools.partial(jax.jit, static_argnames=("cfg", "module"))
+def _decode(params, cfg, ids, pools, table, lens, rope, write_mask, state,
+            module=granite_hybrid):
+    return module.forward_paged_decode(
         params, cfg, ids, pools, table, lens, rope, write_mask=write_mask,
         state=state)
 
@@ -52,10 +53,14 @@ class PagedRun:
     """Prefill in chunks through ``forward_paged_mixed``, then decode through
     ``forward_paged_decode``, each row on its own pages; collects the logits
     at every position from the last prompt token on, and the experts every
-    token of a row chose (``self.experts[r]``: [layers, tokens, K])."""
+    token of a row chose (``self.experts[r]``: [expert layers, tokens, K]).
+    ``module``: the model module whose forwards run (the granite_hybrid
+    signatures; ``models/nemotron_h.py`` has them too)."""
 
-    def __init__(self, cfg, params, rows, page=16, pmax=8, chunk=16):
+    def __init__(self, cfg, params, rows, page=16, pmax=8, chunk=16,
+                 module=granite_hybrid):
         self.cfg, self.params, self.rows, self.chunk = cfg, params, rows, chunk
+        self.module = module
         self.rope = rope_tables(cfg, page * pmax)
         shape = (cfg.kv_layers, rows * pmax + 1, page,
                  cfg.num_kv_heads * cfg.head_dim)
@@ -63,13 +68,13 @@ class PagedRun:
                       jnp.zeros(shape, jnp.bfloat16))
         self.table = jnp.asarray(
             1 + np.arange(rows * pmax).reshape(rows, pmax), jnp.int32)
-        self.state = granite_hybrid.init_state(cfg, rows + 1)
-        self.experts = [np.zeros((cfg.num_layers, 0, cfg.experts_per_token),
+        self.state = module.init_state(cfg, rows + 1)
+        self.experts = [np.zeros((cfg.moe_layers, 0, cfg.experts_per_token),
                                  np.int32) for _ in range(rows)]
         self.aux = None
 
     def _logits(self, hidden):
-        return np.asarray(granite_hybrid.lm_head_logits(
+        return np.asarray(self.module.lm_head_logits(
             self.params, self.cfg, hidden), np.float32)
 
     def mixed_step(self, ids, hist, q_lens, write_mask=None, rows=None,
@@ -79,7 +84,7 @@ class PagedRun:
         hidden, self.pools, self.state, self.aux = _mixed(
             self.params, self.cfg, jnp.asarray(ids), self.pools, self.table,
             jnp.asarray(hist), jnp.asarray(q_lens), self.rope, write_mask,
-            rows, decode, self.state)
+            rows, decode, self.state, module=self.module)
         if decode is not None:
             return self._logits(hidden)
         chosen = np.asarray(self.aux["experts"])
@@ -87,13 +92,14 @@ class PagedRun:
         for r, q in enumerate(np.asarray(q_lens)):
             self.experts[r] = np.concatenate(
                 [self.experts[r], chosen[:, r * width: r * width + q]], 1)
-        return self._logits(granite_hybrid.gather_last_hidden(
+        return self._logits(self.module.gather_last_hidden(
             hidden, jnp.asarray(q_lens)))
 
     def decode(self, ids, lens, write_mask=None):
         hidden, self.pools, self.state, self.aux = _decode(
             self.params, self.cfg, jnp.asarray(ids), self.pools, self.table,
-            jnp.asarray(lens), self.rope, write_mask, self.state)
+            jnp.asarray(lens), self.rope, write_mask, self.state,
+            module=self.module)
         chosen = np.asarray(self.aux["experts"])
         for r in range(self.rows):
             self.experts[r] = np.concatenate(

@@ -797,3 +797,122 @@ def test_scheduler_programs_compile_for_v5e_at_granite_hybrid():
             r"s8\[(1,)?(72,(4096,768|768,4096)|4096,16768|8192,4096)\]\S* "
             r"copy\(", text)
         assert not copied, f"{name}: a layer of a weight stack, {copied[0]}"
+
+
+@pytest.mark.slow
+def test_scheduler_programs_compile_for_v5e_at_nemotron_h():
+    """The scheduler's own ``paged_decode_chunk`` and ``mixed_step`` for
+    nemotron-3-super-share4-22l int8 at the served shapes of
+    ``benchmark/configs/nemotron-3-super-int8.json`` (64 slots of 4096, 5121
+    pages in TWO pool layers, 96 state rows in TEN slab layers, 128 held
+    experts in TEN expert-stack layers, 8 steps a chunk), on one described
+    chip: each holds the ``ssm_state_update``, the ``grouped_matmul`` and the
+    paged attention Mosaic calls, donates the pools and the slab, fits the
+    15.75 GiB the compiler budgets, copies nothing the size of the slab or
+    of a pool and no layer of a weight stack. The compiled size is printed
+    beside granite's (``-k 'granite or nemotron' -s``). A compile, not a
+    chip run."""
+    import json
+    import re
+    import time
+
+    from jax.sharding import SingleDeviceSharding
+
+    from cyberfabric_core_tpu.models import decoder_module, get_config
+    from cyberfabric_core_tpu.ops.platform import compiled_kernels
+    from cyberfabric_core_tpu.ops.rope import rope_tables
+    from cyberfabric_core_tpu.parallel.feasibility import V5E_HBM_BYTES
+    from cyberfabric_core_tpu.parallel.sharding import abstract_params
+    from cyberfabric_core_tpu.runtime.engine import EngineConfig
+    from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
+
+    topo = _topo_or_skip()
+    serving = json.loads(Path(
+        __file__).resolve().parents[1].joinpath(
+            "benchmark/configs/nemotron-3-super-int8.json").read_text()
+    )["serving"]
+    n, max_seq = serving["max_batch"], serving["max_seq_len"]
+    pages = serving["pool_pages"] + 1      # the worker's default margin in
+    rows = n + serving["state_snapshots"]
+    cfg = get_config(serving["model_config"])
+    assert (cfg.kv_layers, cfg.state_layers, cfg.moe_layers, pages) == (
+        2, 10, 10, 5121)
+    eng = object.__new__(ContinuousBatchingEngine)
+    eng.config = EngineConfig(
+        model=cfg.name, max_seq_len=max_seq, max_batch=n,
+        decode_chunk=serving["decode_chunk"], quantization="int8",
+        prefix_cache_pages=pages, prefix_page_size=_PAGE)
+    eng.model_config, eng.dtype = cfg, jnp.bfloat16
+    eng._model, eng._has_state, eng._block = decoder_module(cfg), True, 0
+    eng._moe_counters = eng._model.MOE_COUNTERS
+    eng.n_slots, eng.pmax = n, max_seq // _PAGE
+    eng.spec_k, eng._spec_w = 0, 1
+    eng.mesh = eng._attn_mesh = None
+    eng.rope_tables = rope_tables(cfg, max_seq)
+    here = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=here)
+
+    params = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                          abstract_params(cfg, jnp.bfloat16, "int8"))
+    assert params["moe"]["router"].dtype == jnp.float32
+    assert params["moe"]["moe_up"]["q"].shape == (10, 128, 1024, 2688)
+    assert params["moe"]["moe_down"]["q"].shape == (10, 128, 2688, 1024)
+    assert "moe_gate" not in params["moe"]
+    assert params["mamba"]["ssm_in"]["q"].shape == (10, 4096, 18560)
+    assert params["lm_head"]["q"].shape == (4096, 32768)
+    eng.pool = types.SimpleNamespace(cache_operands=lambda: (None,) * 3)
+    with compiled_kernels():
+        eng._build_programs()
+
+    f32 = jnp.float32
+    pool = sds((cfg.kv_layers, pages, _PAGE, cfg.num_kv_heads * cfg.head_dim),
+               jnp.bfloat16)
+    state = {"ssm": sds((cfg.state_layers, rows, cfg.ssm_heads,
+                         cfg.ssm_head_dim, cfg.ssm_state), f32),
+             "conv": sds((cfg.state_layers, rows, cfg.ssm_conv - 1,
+                          cfg.ssm_conv_dim), f32)}
+    slab_bytes = int(np.prod(state["ssm"].shape)) * 4
+
+    def mixed(width):
+        return (eng._mixed_step_fn, (
+            params, pool, pool, state, *_mixed_operands(sds, eng, width)))
+
+    programs = {
+        "paged_decode_chunk": (eng._paged_decode_fn, (
+            params, pool, pool, state, *_decode_operands(sds, eng))),
+        "mixed_step@64": mixed(64), "mixed_step@512": mixed(512),
+    }
+    for name, (fn, args) in programs.items():
+        started = time.monotonic()
+        with compiled_kernels():
+            compiled = fn.lower(*args).compile()
+        took = time.monotonic() - started
+        mem = compiled.memory_analysis()
+        live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+        text = compiled.as_text()
+        print(f"{name}: argument {mem.argument_size_in_bytes / 1e9:.2f} output "
+              f"{mem.output_size_in_bytes / 1e9:.2f} aliased "
+              f"{mem.alias_size_in_bytes / 1e9:.2f} temp "
+              f"{mem.temp_size_in_bytes / 1e9:.2f} GB; compiled in "
+              f"{took:.0f} s, code "
+              f"{mem.generated_code_size_in_bytes / 1e6:.1f} MB, optimised "
+              f"HLO {len(text) / 1e6:.1f} MB")
+        if os.environ.get("AOT_DUMP_DIR"):
+            Path(os.environ["AOT_DUMP_DIR"], f"{name}.hlo.txt").write_text(text)
+        for kernel in ("ssm_state_update", "grouped_matmul",
+                       "paged_decode_attention"):
+            assert kernel in text, (name, kernel)
+        assert mem.alias_size_in_bytes >= slab_bytes + 2 * int(
+            np.prod(pool.shape)) * 2, "pools and state slab not donated"
+        assert live < V5E_HBM_BYTES, (name, live)
+        _assert_whole_array_untouched(text, state["ssm"], name)
+        _assert_whole_array_untouched(text, pool, name)
+        # a unit picks its layers out of the whole stacks as a scan picks its
+        # ``xs``: no layer of a stack is copied
+        copied = re.search(
+            r"s8\[(1,)?(128,(1024,2688|2688,1024)|4096,18560|8192,4096|"
+            r"4096,5376|5376,4096)\]\S* copy\(", text)
+        assert not copied, f"{name}: a layer of a weight stack, {copied[0]}"
