@@ -416,6 +416,15 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                 ("llm_admission_ring_waits_total",
                  "Scheduler passes in which a request could have been "
                  "admitted or resumed and waited for the chunks in flight"),
+                ("llm_drains_ring_empty_total",
+                 "Drains that left nothing the scheduler launched undrained "
+                 "(the ring's last chunk ahead of an arrival, a prompt's "
+                 "chunk that is not its last): their emit is held for the "
+                 "next launch"),
+                ("llm_emits_deferred_total",
+                 "Held emits that ran behind the next launch, under the "
+                 "device's work; the rest were flushed first (a cancel, a "
+                 "preemption, nothing to launch)"),
                 ("llm_control_rows_uploads_total",
                  "Dispatches that found the host-owned rows (a slot's "
                  "sampling and termination rows, the active mask) changed "

@@ -39,6 +39,15 @@ alternating.
   with recurrent state cannot replay a chunk (it has advanced the state),
   so there a stale ring only ever drains: the one place the ring's rules
   ask what kind of model is served.
+- The emit behind the next launch: a drain that leaves NOTHING undrained
+  (the ring's last chunk ahead of an arrival, a prompt's chunk that is not
+  its last, every round without lookahead) commits as any other and HOLDS
+  its emit; the loop admits, plans, uploads and launches what comes next,
+  and flushes the held token events right behind that launch, under the
+  device's work (``_close_round``, ``_flush_held_emit``). The same
+  speculation as the ring's, for the one launch that used to wait. A
+  ``mixed_step`` launched ahead of a held emit is never discarded, and
+  whatever reads or ends a stream on the host flushes first.
 - Device-side termination: stop-token matching (per-slot padded stop-id
   rows), the max-tokens bound and the window bound are evaluated INSIDE the
   decode program against a device-resident ``finished`` mask — a finished
@@ -371,6 +380,29 @@ class _InflightChunk:
     #                       (chained dispatches reuse it; NEVER committed —
     #                       host finish deactivations must not be undone)
     epoch: int
+
+
+@dataclass
+class _HeldEmit:
+    """The emit of a round whose drain left NOTHING UNDRAINED, held back so
+    that it runs behind the next launch instead of in front of it (the device
+    would wait out every token event of it). The round is committed: the
+    device handles, the host's length mirror, the counters and what the next
+    plan reads (``prefill_pos``, a flip) are as after any drain. What waits
+    is what a stream sees: token events, flight-recorder events, tenant
+    charges, finishes (a slot freed by one becomes free at the flush) and
+    the round's record. At most one is held, and never past the next drain:
+    ``_flush_held_emit``."""
+
+    #: the round's emit over what it drained (its tokens, ``old_lengths``,
+    #: depth and plan are the closure's); returns a block model's
+    #: (blocks, tokens) for the record
+    emit: Callable[[], Optional[tuple[int, int]]]
+    #: ``_record_round``'s arguments
+    record: dict[str, Any]
+    #: the pass's phases, taken where it ended (``_PhaseClock.take``): the
+    #: flushed emit's time belongs to the pass it runs in
+    clock: tuple[dict[str, list[float]], float, float]
 
 
 #: what the scheduler thread can be doing: the loop is TILED by these, every
@@ -967,6 +999,9 @@ class ContinuousBatchingEngine:
         #: the lookahead ring: dispatched-but-undrained chunks, oldest first.
         #: Ring size beyond the drained chunk is capped at _lookahead_depth.
         self._ring: "_deque[_InflightChunk]" = _deque()
+        #: the emit of the round whose drain emptied the ring, until the
+        #: next launch is queued in front of it (_close_round)
+        self._held: Optional[_HeldEmit] = None
         self._lookahead_depth = config.resolve_lookahead_depth()
         #: batched speculative decoding: k draft tokens per speculating slot
         #: per round, verified as a q_len=k+1 ragged span in the mixed-batch
@@ -1019,6 +1054,8 @@ class ContinuousBatchingEngine:
         for series in ("llm_decode_chunks_dispatched_total",
                        "llm_decode_chunks_discarded_total",
                        "llm_admission_ring_waits_total",
+                       "llm_drains_ring_empty_total",
+                       "llm_emits_deferred_total",
                        "llm_control_rows_uploads_total",
                        "llm_loose_row_programs_total",
                        "llm_attn_pages_walked_total",
@@ -1841,6 +1878,11 @@ class ContinuousBatchingEngine:
                 reason, kind = "deadline", "deadline_exceeded"
             if reason is None:
                 continue
+            # what ends a stream on the host flushes first: tokens drained
+            # before the cancel precede its terminal, and if they ended the
+            # stream the cancel raced that terminal
+            if self._flush_held_emit() and self.slots[slot] is not state:
+                continue
             self._cancel_slot(slot, state, reason, kind)
         # ids that matched nothing raced a terminal (finished/preempt-shed in
         # the same round): the request already got its one terminal — the
@@ -2425,8 +2467,14 @@ class ContinuousBatchingEngine:
 
     def _loop_body(self) -> None:
         try:
-            while not self._stop.is_set():
+            while True:
                 try:
+                    if self._stop.is_set():
+                        # stopped between a drain and the launch its emit
+                        # would have followed: the tokens are the streams',
+                        # whoever ends them next
+                        self._flush_held_emit()
+                        return
                     if not self._loop_pass():
                         self._clock.to("wait")
                         self._wake.wait(timeout=0.1)
@@ -2442,7 +2490,15 @@ class ContinuousBatchingEngine:
     def _loop_pass(self) -> bool:
         """One pass of the loop: the round boundary's bookkeeping, admission,
         one round. False when there was nothing to do (the loop then waits
-        to be woken)."""
+        to be woken).
+
+        A pass may begin with the previous round's emit HELD (its drain left
+        the device with nothing queued: ``_close_round``). The round this
+        pass launches flushes it right behind its launch; a pass that
+        launches nothing (no arrival after all, every row preempted, the
+        loop about to wait) flushes it before it returns, so no token waits
+        longer than one launch."""
+        held = self._held
         # cancels/deadlines apply at the round boundary: BEFORE admission (a
         # lapsed pending entry must never take the slot this pass is about
         # to hand out)
@@ -2460,10 +2516,13 @@ class ContinuousBatchingEngine:
         admitted = self._admit()
         # prefilling slots are work too: mixed-batch rounds must run even
         # before any slot reaches decode phase
-        if not self.active.any() and not self._prefill_slots:
-            return admitted > 0
-        self._decode_round()
-        return True
+        ran = bool(self.active.any() or self._prefill_slots)
+        if ran:
+            self._decode_round()
+        if held is not None and self._held is held:
+            self._flush_held_emit()      # nothing was launched in front of it
+            return True
+        return ran or admitted > 0
 
     def _fail_all_inflight(self, why: str) -> None:
         """Error-terminate every in-flight, prefilling, suspended, and queued
@@ -2471,7 +2530,13 @@ class ContinuousBatchingEngine:
         by the caller) and :meth:`close` (``_broken`` stays None: a closed
         engine is SPENT, not poisoned — lifecycle managers rebuild a fresh
         engine off its ``.params``). Single-threaded by construction: runs on
-        the scheduler thread (crash) or after the thread joined (close)."""
+        the scheduler thread (crash) or after the thread joined (close).
+        Tokens of a held emit were drained before the fault: they go out
+        first, then the terminals."""
+        try:
+            self._flush_held_emit()
+        except Exception:  # noqa: BLE001 — the teardown must reach every stream
+            logger.exception("the held emit failed during teardown")
         self._ring.clear()
         with self._cancel_lock:
             # every in-flight/queued request gets its error terminal below;
@@ -2799,7 +2864,15 @@ class ContinuousBatchingEngine:
         further drains. The rows a slot's admission writes (on the host:
         ``_set_slot_rows``; its ``mixed_step`` uploads them and starts the
         device rows itself) are therefore never under a chunk in flight, and
-        nothing here bumps ``_epoch``."""
+        nothing here bumps ``_epoch``.
+
+        The rule is unchanged by a HELD emit (``_close_round``): the round
+        whose drain emptied the ring is drained and committed, only what
+        its streams see of it waits, so the ring IS empty and the arrival
+        is admitted, planned and launched ahead of those token events. What
+        the held emit will free is not there yet: a slot (and the pages) of
+        a row it finishes becomes free at the flush, one launch later, and
+        is never handed out early."""
         if self._ring:
             if self._admission_waiting():
                 bump_counter("llm_admission_ring_waits_total")
@@ -3126,7 +3199,11 @@ class ContinuousBatchingEngine:
         prefill too: the saved pages cover prefill_pos tokens and chunking
         continues from there on resume. ``soft_yielded`` marks a tenant
         soft-quota yield: resume defers it while other tenants have pending
-        work (see _resume_suspended)."""
+        work (see _resume_suspended). A held emit is flushed first: the
+        record parks the stream as its client has seen it (``emitted``, the
+        last token), and a row those tokens finished has nothing to park."""
+        if self._flush_held_emit() and self.slots[slot] is not state:
+            return
         chain = state.chain
         is_prefill = state.phase == "prefill"
         length = state.prefill_pos if is_prefill else int(self.lengths[slot])
@@ -3256,9 +3333,13 @@ class ContinuousBatchingEngine:
         pipeline — chunks already drained were committed and emitted).
         Callers: what cannot wait for the ring and can be replayed — the
         epoch checks of a decode round (a preemption or a host-fallback stop
-        bumped ``_epoch``) and of a mixed round's emit, and ``_loop_pass``
-        when no running row is left to drain it. Admission and resume never
-        come here: they wait (``_admit``).
+        bumped ``_epoch``) and of every emit (``_emit_round``: at once or
+        flushed behind a launch, a decode round's or a mixed one's), and
+        ``_loop_pass`` when no running row is left to drain it. Admission
+        and resume never come here: they wait (``_admit``). Only DECODE
+        chunks are ever in the ring: a ``mixed_step`` launched ahead of a
+        held emit is not, so nothing here can drop a prompt's chunk
+        (``_close_round``, rule 1).
         Committed state (last_tokens / keys / lengths / finished) was never
         advanced past the last drained chunk, so nothing needs restoring; a
         discarded chunk's only lasting effect is KV written past every
@@ -3303,6 +3384,85 @@ class ContinuousBatchingEngine:
                                 0).astype(np.int32)
         return old_lengths
 
+    def _close_round(self, emit: Callable[[], Optional[tuple[int, int]]],
+                     hold: bool = True, **record: Any) -> None:
+        """The end of a round, from its commit on: ``emit`` hands the
+        drained tokens to their streams, then ``_record_round(**record)``
+        closes the pass. ONE emit routine, run at one of two points, by what
+        the drain left behind:
+
+        - chunks still in flight (a steady decode round, a mixed step with
+          chunks chained off it): at once. The device works under it.
+        - NOTHING UNDRAINED (the ring's last chunk ahead of an arrival, a
+          prompt's chunk that is not its last, an engine with no lookahead):
+          the device would wait out every token event, so the emit is HELD
+          and the loop goes on to what it does next anyway: ``_admit`` off
+          the empty ring, plan, upload and launch of the arrival's or the
+          next chunk's ``mixed_step`` (``_mixed_ring_span`` behind it) or of
+          the resync decode chunk, which flushes it right behind that launch
+          and before its own drain (``_flush_held_emit``). This is the
+          speculation the ring already makes, a chunk launched before the
+          host has emitted the one in front of it, applied to the one launch
+          that used to wait. What carries it is the same: the device's
+          finished mask freezes a row that ends by length, window or a
+          device-matched stop, and a host-fallback stop found in the flushed
+          emit bumps ``_epoch``.
+
+        Two rules, because the step launched ahead may carry a prompt's
+        chunk. (1) A ``mixed_step`` launched ahead of a held emit is NEVER
+        DISCARDED (a chunk's K/V and, with recurrent state, its state
+        advance are not replayable): it is not in the ring, its drain
+        commits it whatever the flush found, and rows the flushed emit
+        finished are masked out of its own emit (its decode rows are read
+        after the flush), as ``_discard_ring`` already does for every model
+        with state. Decode chunks launched ahead follow the ring's rule.
+        (2) Whatever reads or ends a stream on the host FLUSHES FIRST:
+        ``_service_cancellations`` for a slot it ends, ``_preempt_slot``,
+        ``_fail_all_inflight``, the loop's stop, a pass that launches
+        nothing, a speculating engine before ``_spec_candidates`` is asked
+        (``_decode_round``), and an emit that itself hands a row off to
+        another engine (``hold`` False: it is not held at all). A slot
+        freed by a finish in a held emit is free at the flush, one launch
+        later: ``_admit`` never sees it early, and its rule (wait for an
+        empty ring) is unchanged, since the held round IS drained and
+        committed."""
+        if not self._ring:
+            bump_counter("llm_drains_ring_empty_total")
+        if self._ring or not hold:
+            self._emit_round(emit, record)
+            return
+        # the record's pass ends here; the emit's time is the next pass's
+        self._held = _HeldEmit(emit, record, self._clock.take())
+        self.last_round_at = self._held.clock[2]
+
+    def _flush_held_emit(self, behind_launch: bool = False) -> bool:
+        """Run the held emit, if there is one, and close its round's record.
+        ``behind_launch``: the caller has just queued the next program, which
+        is what the emit was held for (counted); every other caller flushes
+        FIRST, before it reads or ends a stream. Returns whether anything
+        was held. The clock comes back to the caller's phase."""
+        held, self._held = self._held, None
+        if held is None:
+            return False
+        if behind_launch:
+            bump_counter("llm_emits_deferred_total")
+        back = self._clock.phase
+        self._emit_round(held.emit, held.record, held.clock)
+        self._clock.to(back)
+        return True
+
+    def _emit_round(self, emit: Callable[[], Optional[tuple[int, int]]],
+                    record: dict[str, Any],
+                    clock: Optional[tuple] = None) -> None:
+        self._clock.to("emit")
+        block_out = emit()
+        # a host-fallback stop in there changed the world: the chunks in
+        # flight are stale (device-predicted finishes leave the epoch alone,
+        # so the ring survives them; that is the deep-lookahead win)
+        if self._ring and self._ring[0].epoch != self._epoch:
+            self._discard_ring()
+        self._record_round(block_out=block_out, clock=clock, **record)
+
     def _record_round(self, lookahead: bool,
                       ts: Optional[float] = None,
                       mixed: bool = False,
@@ -3312,7 +3472,8 @@ class ContinuousBatchingEngine:
                       kind: str = "decode",
                       positions: Optional[int] = None,
                       block_out: Optional[tuple[int, int]] = None,
-                      local_assignments: Optional[int] = None) -> None:
+                      local_assignments: Optional[int] = None,
+                      clock: Optional[tuple] = None) -> None:
         """One timing-schema owner for every round kind. ``ts`` is the
         round's wall-clock start; /v1/monitoring/rounds exports these entries
         as Chrome trace events, which need absolute timestamps.
@@ -3326,8 +3487,13 @@ class ContinuousBatchingEngine:
         sum, so consecutive records add up to the thread's time; passes that
         ran no round are in the next record's ``wait`` / ``service`` /
         ``admit``. The four stage fields are sums over the phases they
-        cover."""
-        phases, pass_ms, self.last_round_at = self._clock.take()
+        cover. ``clock``: the phases of a round whose emit was held, taken
+        where its pass ended (the emit's time is in the record of the pass
+        that flushed it, behind that pass's ``launch``)."""
+        if clock is None:
+            clock = self._clock.take()
+            self.last_round_at = clock[2]
+        phases, pass_ms, _ = clock
 
         def wall_ms(*of: str) -> float:
             return round(sum(phases[p][0] for p in of if p in phases), 3)
@@ -3501,20 +3667,23 @@ class ContinuousBatchingEngine:
         return blocks, tokens
 
     def _emit_chunk(self, chunk: np.ndarray, old_lengths: np.ndarray,
-                    depth: int = 0) -> None:
+                    rows: list[int], depth: int = 0) -> None:
+        """``rows``: the slots that ran in the chunk (active at its commit).
+        A held emit runs after the next pass's ``_admit``, and a row resumed
+        there is active but has no token in THIS chunk."""
         k = self._k_steps
         # one flight-recorder event per active slot per CHUNK (k fused
         # tokens), never per token — the per-round cost is a handful of
         # lock-once appends against a whole device dispatch. ``depth`` stamps
         # how many lookahead chunks were still in flight at this drain.
-        for slot in range(self.n_slots):
+        for slot in rows:
             state = self.slots[slot]
             if state is not None and self.active[slot]:
                 record_event(state.request_id, "decode_chunk", slot=slot,
                              tokens=k, depth=depth)
         for j in range(k):
             last_of_chunk = j == k - 1
-            for slot in range(self.n_slots):
+            for slot in rows:
                 if not self.active[slot]:
                     continue
                 # finish-with-length at chunk end when the NEXT chunk can't fit
@@ -3586,18 +3755,19 @@ class ContinuousBatchingEngine:
         self.pool.extend_chain(chain, needed)
         self.page_table[slot, before: len(chain)] = chain[before:]
 
-    def _finish_prefill(self, slot: int, state: _SlotState,
-                        tok: Optional[int]) -> None:
-        """Flip a fully-prefilled slot to decode: commit the prompt's full
-        pages to the radix tree (later requests reuse them zero-copy),
-        adopt the flip the mixed dispatch computed on the device (its
-        outputs are already the committed rows: nothing is patched), and
-        emit the first token (sampled inside that dispatch). The epoch
-        stays: chunks that span the flip were chained off the mixed
-        dispatch, which computed it on-device (active_out/final_lens), and
-        with no span there is no ring to stale."""
-        # the kept length at the flip: the whole prompt, or (a block model,
-        # which gets no first token here: ``tok`` None) its whole blocks
+    def _finish_prefill(self, slot: int, state: _SlotState) -> float:
+        """Flip a fully-prefilled slot to decode, at its mixed step's
+        COMMIT (the next plan, capacity pass and admission read all of it,
+        so it never waits for a held emit): commit the prompt's full pages
+        to the radix tree (later requests reuse them zero-copy) and adopt
+        the flip the mixed dispatch computed on the device (its outputs are
+        already the committed rows: nothing is patched). The epoch stays:
+        chunks that span the flip were chained off the mixed dispatch,
+        which computed it on-device (active_out/final_lens), and with no
+        span there is no ring to stale. What the stream sees of it is
+        ``_emit_first_token``'s. Returns the prefill's duration, ms."""
+        # the kept length at the flip: the whole prompt, or (a block model)
+        # its whole blocks
         T = self._prefill_target(state)
         try:
             self.pool.commit_chain(state.prompt_ids, state.chain,
@@ -3615,6 +3785,13 @@ class ContinuousBatchingEngine:
         # the chunked path's duration spans the budget-paced rounds — the
         # realistic "time to get through prefill under current load"
         self._note_prefill_rate(T - state.cached_len, dur_ms / 1000.0)
+        return dur_ms
+
+    def _emit_first_token(self, slot: int, state: _SlotState,
+                          tok: Optional[int], dur_ms: float) -> None:
+        """The emit half of a flip: the terminal ``prefill`` event and span,
+        then the first token, sampled inside the flip's dispatch (a block
+        model gets none here: ``tok`` None)."""
         # the terminal "prefill" event (ttft anchors here); the per-chunk
         # progress lives in prefill_chunk events
         record_event(state.request_id, "prefill", slot=slot, mixed=True,
@@ -3631,7 +3808,8 @@ class ContinuousBatchingEngine:
                 chunks=state.prefill_chunks, tenant=state.tenant)
         if tok is None:
             return
-        no_room = T + self._k_steps > self.config.max_seq_len
+        no_room = (self._prefill_target(state) + self._k_steps
+                   > self.config.max_seq_len)
         self._emit_token(slot, tok, force_length=no_room)
         # PD disaggregation: a prefill-role engine's job ends at the first
         # token. If the emit above finished the stream (stop/length on
@@ -3848,6 +4026,18 @@ class ContinuousBatchingEngine:
         dispatch's outputs (_mixed_ring_span), so the mixed→pure-decode
         transition keeps the pipeline full.
 
+        Where the emit runs (``_close_round``): with chunks chained off the
+        step, at once, after its commit; with nothing chained (a prompt has
+        chunks left, another arrival is queued) it is HELD, and the next
+        pass's launch goes out first. In turn this round flushes the emit a
+        previous drain held, right behind its own launch and span and
+        before its own drain. A step launched ahead of a held emit is never
+        discarded (its chunk's K/V and state advance cannot be replayed):
+        rows the flushed emit finished are masked out of this round's emit,
+        whose ``decode_rows`` are read after the flush. The commit carries
+        what the next plan reads: ``prefill_pos`` and a final chunk's flip
+        (``_finish_prefill``).
+
         Speculative rounds (scheduler_spec_k > 0): eligible greedy rows with
         a live ngram proposal become q_len=1+d draft spans in the SAME
         dispatch (the _spec_step_fn variant), sharing the round's ragged
@@ -4006,7 +4196,13 @@ class ContinuousBatchingEngine:
                                    active_o, self._epoch)
         spanned = 0 if spec_plan else self._mixed_ring_span(mixed_rec,
                                                             finals)
-        self._clock.to("drain")
+        # the step and what was chained off it are queued: the emit held
+        # back at the last drain runs under them (``_close_round``, rule 1:
+        # whatever it finds, THIS step is drained and committed below). A
+        # host-fallback stop in it drops only the chunks chained behind,
+        # and the step itself is still undrained
+        self._flush_held_emit(behind_launch=True)
+        self._clock.to("drain", starved=False)
         toks = np.asarray(toks_dev, np.int32)  # sync-point: mixed-round drain (AS04)
         # where nothing was chained off this dispatch the device waits from
         # here to the next launch
@@ -4085,67 +4281,88 @@ class ContinuousBatchingEngine:
                 state.spec_accepted += a
             bump_counter("llm_spec_tokens_proposed_total", n=round_proposed)
             bump_counter("llm_spec_tokens_accepted_total", n=round_accepted)
-        self._clock.to("emit")
+        # what the next plan, capacity pass and admission read is the
+        # commit's: each chunk's progress, and a final chunk's flip (after
+        # the decode rows' lengths above: the flipped row starts at T)
+        done = []
         for slot, state, chunk in plan:
             state.prefill_pos += chunk
             state.prefill_chunks += 1
             self.prefill_chunks += 1
             self.chunked_prefill_tokens += chunk
-            # chunked prefill charges as it lands — a tenant mid-prompt is
-            # already paying its fair-queue bill, not only at completion
-            self._charge_tenant(state.tenant, chunk)
-            # one event per piggybacked chunk (mirrors decode_chunk): the
-            # request timeline shows interleaved prefill progress
-            record_event(state.request_id, "prefill_chunk", slot=slot,
-                         tokens=chunk, pos=state.prefill_pos,
-                         of=len(state.prompt_ids))
-            if state.trace_sampled:
-                get_global_tracer().emit_span(
-                    "llm.prefill_chunk", traceparent=state.trace,
-                    start_unix_ns=int(wall0 * 1e9),
-                    duration_ms=round_ms,
-                    request_id=state.request_id, slot=slot, tokens=chunk)
-        for slot, state in finals:
-            self._finish_prefill(
-                slot, state, None if self._block else int(toks2d[slot, 0]))
-        block_out = None
-        if self._block:
-            block_out = self._emit_block_chunk(toks2d, ran, old_lengths,
-                                               depth=spanned,
-                                               rows=decode_rows)
-        for slot in () if self._block else decode_rows:
-            state = self.slots[slot]
-            if state is None or not self.active[slot]:
-                continue
-            n_row = int((toks2d[slot] >= 0).sum())
-            extra = row_attrs.get(slot, {}) if row_attrs else {}
-            record_event(state.request_id, "decode_chunk", slot=slot,
-                         tokens=n_row, depth=spanned, **extra)
-            for j in range(n_row):
-                if not self.active[slot]:
-                    break  # a host-authoritative finish truncates the row
-                # keep the invariant: after each token the slot must still
-                # fit a full decode chunk, else finish with 'length' now
-                no_room = (int(old_lengths[slot]) + j + 1 + self._k_steps
-                           > self.config.max_seq_len)
-                self._emit_token(slot, int(toks2d[slot, j]),
-                                 force_length=no_room)
-        # a host-fallback stop during the emit stales the spanned suffix
-        if self._ring and self._ring[0].epoch != self._epoch:
-            self._discard_ring()
-        self._record_round(lookahead=False, ts=wall0,
-                           mixed=bool(plan),
-                           chunk_tokens=sum(c for _, _, c in plan),
-                           depth=spanned,
-                           spec_tokens=sum(len(dr)
-                                           for _, _, dr in spec_plan),
-                           kind=("mixed" if decode_rows else "prefill")
-                           if plan else "decode", positions=positions,
-                           block_out=block_out, local_assignments=local)
+            done.append((slot, state, chunk, state.prefill_pos))
+        first = [(slot, state, None if self._block else int(toks2d[slot, 0]),
+                  self._finish_prefill(slot, state))
+                 for slot, state in finals]
+
+        def emit() -> Optional[tuple[int, int]]:
+            for slot, state, chunk, pos in done:
+                # chunked prefill charges as it lands — a tenant mid-prompt
+                # is already paying its fair-queue bill, not only at
+                # completion
+                self._charge_tenant(state.tenant, chunk)
+                # one event per piggybacked chunk (mirrors decode_chunk):
+                # the request timeline shows interleaved prefill progress
+                record_event(state.request_id, "prefill_chunk", slot=slot,
+                             tokens=chunk, pos=pos,
+                             of=len(state.prompt_ids))
+                if state.trace_sampled:
+                    get_global_tracer().emit_span(
+                        "llm.prefill_chunk", traceparent=state.trace,
+                        start_unix_ns=int(wall0 * 1e9),
+                        duration_ms=round_ms,
+                        request_id=state.request_id, slot=slot,
+                        tokens=chunk)
+            for slot, state, tok, dur_ms in first:
+                self._emit_first_token(slot, state, tok, dur_ms)
+            if self._block:
+                return self._emit_block_chunk(toks2d, ran, old_lengths,
+                                              depth=spanned,
+                                              rows=decode_rows)
+            for slot in decode_rows:
+                state = self.slots[slot]
+                if state is None or not self.active[slot]:
+                    continue
+                n_row = int((toks2d[slot] >= 0).sum())
+                extra = row_attrs.get(slot, {}) if row_attrs else {}
+                record_event(state.request_id, "decode_chunk", slot=slot,
+                             tokens=n_row, depth=spanned, **extra)
+                for j in range(n_row):
+                    if not self.active[slot]:
+                        break  # a host-authoritative finish truncates the row
+                    # keep the invariant: after each token the slot must
+                    # still fit a full decode chunk, else finish with
+                    # 'length' now
+                    no_room = (int(old_lengths[slot]) + j + 1 + self._k_steps
+                               > self.config.max_seq_len)
+                    self._emit_token(slot, int(toks2d[slot, j]),
+                                     force_length=no_room)
+            return None
+
+        # at once under the chunks chained off this step, or held for the
+        # next launch (a prompt's next chunk, another arrival's, a resync).
+        # A prefill-role engine hands a flipped row off inside this emit
+        # (``_export_handoff``), and what ends a stream here is not held
+        exports = bool(first) and self.pd_role == "prefill" \
+            and self._handoff_sink is not None
+        self._close_round(emit, hold=not exports, lookahead=False, ts=wall0,
+                          mixed=bool(plan),
+                          chunk_tokens=sum(c for _, _, c in plan),
+                          depth=spanned,
+                          spec_tokens=sum(len(dr) for _, _, dr in spec_plan),
+                          kind=("mixed" if decode_rows else "prefill")
+                          if plan else "decode", positions=positions,
+                          local_assignments=local)
         return True
 
     def _decode_round(self) -> None:
         self.occupancy_samples.append(self.active_slots)
+        if self.spec_k:
+            # a speculative dispatch is taken off a drained ring and its
+            # proposals come from EMITTED text (``_emit_token`` feeds the
+            # proposer): a held emit goes out before ``_spec_candidates``
+            # or ``_plan_spec`` is asked
+            self._flush_held_emit()
         if self._prefill_slots:
             self._decode_round_mixed()
             return
@@ -4175,6 +4392,15 @@ class ContinuousBatchingEngine:
             if not self.active.any():
                 return  # everyone got preempted
             self._ring.append(self._dispatch_chunk(after=None))
+            # the device has a chunk queued: the emit held back at the last
+            # drain runs under it. A host-fallback stop in it stales that
+            # chunk like any other in flight (a K/V model drops it and the
+            # next pass replays; with state it stays and drains), and if it
+            # finished the last running row the chunk is nobody's: the next
+            # pass drops it undrained, as it does a ring no row is left for
+            if self._flush_held_emit(behind_launch=True) and not (
+                    self._ring and self.active.any()):
+                return
         # top up the ring: chain chunks off the tail until depth is reached
         # (each extension re-validates epoch + page-chain coverage, which
         # is capacity work; the dispatch switches to upload and launch)
@@ -4196,10 +4422,12 @@ class ContinuousBatchingEngine:
         round_ms = (self._clock.to("commit", starved=not self._ring)
                     - t0) * 1000.0
         self._depth_hist[ring_depth] = self._depth_hist.get(ring_depth, 0) + 1
-        block_out = None
         chunk, local = self._take_moe_counters(chunk, self._k_steps,
                                                decode=True)
         round_attrs = None if local is None else {"local_assignments": local}
+        # the rows that ran in this chunk: a held emit is flushed after the
+        # next pass's ``_admit``, whose resumed rows have no token in it
+        rows = np.flatnonzero(self.active).tolist()
         if self._block:
             chunk, ran = self._take_block_counters(chunk)
             committed = chunk[:, ::self._block] >= 0
@@ -4213,25 +4441,22 @@ class ContinuousBatchingEngine:
                 row_attrs={s: {"blocks": int(c), "row_forwards": int(ran[s])}
                            for s, c in enumerate(commits)},
                 round_attrs=round_attrs)
-            self._clock.to("emit")
-            block_out = self._emit_block_chunk(chunk, ran, old_lengths,
-                                               depth=ring_depth)
+
+            def emit() -> tuple[int, int]:
+                return self._emit_block_chunk(chunk, ran, old_lengths,
+                                              depth=ring_depth, rows=rows)
         else:
             old_lengths = self._commit_chunk(inflight)
             self._count_attn_pages(old_lengths, chunk >= 0)
             self._emit_decode_spans(
                 wall0, round_ms, used_lookahead, depth=ring_depth,
                 round_attrs=round_attrs)
-            self._clock.to("emit")
-            self._emit_chunk(chunk, old_lengths, depth=ring_depth)
-        # a host-fallback stop just changed the world — the ring suffix is
-        # stale (device-predicted finishes leave the epoch alone, so the
-        # ring survives them; that is the deep-lookahead win)
-        if self._ring and self._ring[0].epoch != self._epoch:
-            self._discard_ring()
-        self._record_round(used_lookahead, ts=wall0,
-                           depth=ring_depth, block_out=block_out,
-                           local_assignments=local)
+
+            def emit() -> None:
+                self._emit_chunk(chunk, old_lengths, rows, depth=ring_depth)
+        # at once under the chunks in flight, or held for the next launch
+        self._close_round(emit, lookahead=used_lookahead, ts=wall0,
+                          depth=ring_depth, local_assignments=local)
 
     def _emit_decode_spans(self, wall0: float, dur_ms: float,
                            lookahead: bool, rows: Optional[list[int]] = None,

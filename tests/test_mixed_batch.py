@@ -227,16 +227,19 @@ def test_an_arrivals_lane_runs_off_an_empty_ring_and_rebuilds_it(depth):
             eng._loop_pass()
         eng.submit(rng.integers(3, 900, 20).tolist(),
                    SamplingParams(max_tokens=8), col.emit_for(1))
-        kinds = []
+        rings, records = [], len(eng.round_timings)
         while not col.tokens[1]:
-            rings = len(eng._ring)
+            rings.append(len(eng._ring))
             eng._loop_pass()
-            kinds.append((rings, eng.round_timings[-1]["kind"],
-                          eng.round_timings[-1]["depth"]))
         # depth drains of the chunks in flight, then the lane off an empty
-        # ring, which chains depth chunks behind itself
-        assert kinds == [(depth - i, "decode", depth - i - 1)
-                         for i in range(depth)] + [(0, "mixed", depth)]
+        # ring, which chains depth chunks behind itself (the record of the
+        # drain that emptied the ring closes in the lane's pass: its emit
+        # was held for the lane's launch)
+        assert rings == list(range(depth, -1, -1))
+        kinds = [(r["kind"], r["depth"])
+                 for r in list(eng.round_timings)[records:]]
+        assert kinds == [("decode", depth - i - 1)
+                         for i in range(depth)] + [("mixed", depth)]
         assert eng._lookahead_stats["discarded"] == 0
         eng._loop_pass()
         assert eng.round_timings[-1]["lookahead"] is True

@@ -4,9 +4,10 @@ The loop is TILED by phases: every record's ``phases`` sum to its
 ``pass_ms``, consecutive records sum to the thread's time, the four stage
 fields are sums over the phases they cover, and the ``starved`` flag (nothing
 launched is undrained: the device waits for the host) rises at a drain that
-empties the ring and falls at the next launch. With a profiler running the
-same phases are ``sched.*`` spans on a line of the trace's ``/host:CPU``
-plane."""
+empties the ring and falls at the next launch. The emit of such a drain is
+HELD and runs behind that launch (``_close_round``), so ``emit`` is never
+starved where something follows. With a profiler running the same phases are
+``sched.*`` spans on a line of the trace's ``/host:CPU`` plane."""
 
 import time
 
@@ -36,7 +37,7 @@ STAGES = {"admit_ms": ("admit",),
 def _manual(model="tiny-llama", **over):
     base = dict(model=model, max_seq_len=128, max_batch=4, use_flash=False,
                 prefix_cache_pages=80, prefix_page_size=16,
-                **MODELS[model])
+                **MODELS.get(model, {}))
     base.update(over)
     eng = ContinuousBatchingEngine(EngineConfig(**base), seed=0)
     eng.start = lambda: None    # no thread: the test makes the loop's passes
@@ -103,9 +104,19 @@ def test_cpu_and_starved_lie_inside_wall(served):
     for r in records:
         for phase, (wall, cpu, starved) in r["phases"].items():
             assert 0.0 <= cpu <= wall and 0.0 <= starved <= wall, (phase, r)
-    # a round ran in every record: it launched, drained, committed, emitted
-    assert all({"launch", "drain", "commit", "emit"} <= set(r["phases"])
-               for r in records)
+    # a round ran in every record: it drained and committed; where its
+    # drain left nothing in flight its emit is in the NEXT record, behind
+    # that pass's launch and in front of its drain
+    assert all({"drain", "commit"} <= set(r["phases"]) for r in records)
+    held = [(r, after) for r, after in zip(records, records[1:])
+            if r["depth"] == 0]
+    assert held
+    for r, after in held:
+        assert _starved_of(r, "emit") == 0.0
+        order = list(after["phases"])
+        assert (order.index("launch") < order.index("emit")
+                < order.index("drain")), order
+        assert after["phases"]["emit"][2] == 0.0 < after["phases"]["emit"][0]
 
 
 def test_clock_switches_tile_and_starve():
@@ -150,7 +161,7 @@ def test_starved_follows_the_ring():
     """With a ring two deep a steady decode round drains with chunks still
     in flight and is never starved; the drain that empties the ring (an
     arrival waits for it) raises the flag, and the arrival's launch drops
-    it."""
+    it. That drain's emit waits for the launch: no emit is starved."""
     eng = _manual(decode_lookahead=2)
     try:
         done = _submit(eng, 1, max_tokens=60)
@@ -175,25 +186,30 @@ def test_starved_follows_the_ring():
         _submit(eng, 1, max_tokens=4, done=done)
         while eng._ring:
             eng._loop_pass()
-        last = eng.round_timings[-1]
-        assert last["depth"] == 0 and eng._clock.starved
+        assert eng._held is not None and eng._clock.starved
+        n_records = len(eng.round_timings)    # the held round's comes later
+        eng._loop_pass()                      # the arrival's mixed step
+        assert eng._held is None and len(eng.round_timings) == n_records + 2
+        last, arrival = list(eng.round_timings)[-2:]
+        assert last["kind"] == "decode" and last["depth"] == 0
         assert last["phases"]["drain"][2] == 0.0
         assert last["phases"]["commit"][2] == last["phases"]["commit"][0] > 0
-        assert _starved_of(last, "emit") > 0.0
-        eng._loop_pass()                      # the arrival's mixed step
-        arrival = eng.round_timings[-1]
+        assert _starved_of(last, "emit") == 0.0   # held: none ran starved
         assert arrival["kind"] == "mixed"
         assert arrival["phases"]["admit"][2] == arrival["phases"]["admit"][0]
         assert arrival["phases"]["upload"][2] > 0.0
         assert arrival["phases"]["drain"][2] == 0.0
+        assert arrival["phases"]["emit"][0] > 0.0 == _starved_of(arrival,
+                                                                 "emit")
         _run(eng, done, 2)
     finally:
         eng.shutdown()
 
 
 def test_no_lookahead_starves_between_every_two_rounds():
-    """Without a ring every drain empties it: the host's commit and emit,
-    and the next round up to its launch, are all the device's wait."""
+    """Without a ring every drain empties it: the host's commit, and the
+    next round up to its launch, are the device's wait. The emit is not: it
+    runs behind that launch."""
     eng = _manual(decode_lookahead=0)
     try:
         done = _submit(eng, 2, max_tokens=16)
@@ -208,8 +224,10 @@ def test_no_lookahead_starves_between_every_two_rounds():
         # /metrics carries the same seconds, by model and phase
         after = _starved_seconds("tiny-llama")
         grew = {p: after.get(p, 0.0) - before.get(p, 0.0) for p in after}
-        assert grew["commit"] > 0.0 and grew["emit"] > 0.0
-        assert grew.get("drain", 0.0) == 0.0
+        assert grew["commit"] > 0.0 and grew["admit"] > 0.0
+        assert grew.get("drain", 0.0) == 0.0 == grew.get("emit", 0.0)
+        assert all(r["phases"]["emit"][0] > 0.0 == r["phases"]["emit"][2]
+                   for r in records[1:])
         recorded = sum(v[2] for r in eng.round_timings
                        for v in r["phases"].values()) / 1000.0
         assert sum(grew.values()) >= 0.95 * recorded
@@ -260,9 +278,133 @@ def test_phases_are_spans_on_the_profilers_host_plane(tmp_path):
     spans = lines[0]
     names = {name for _, _, name in spans}
     assert {n.split(".")[1] for n in names} <= set(PHASES)
-    assert "sched.drain" in names and "sched.emit.starved" in names
-    assert "sched.drain.starved" not in names
+    assert "sched.drain" in names and "sched.commit.starved" in names
+    assert "sched.emit" in names      # behind a launch, every one of them
+    assert not {"sched.drain.starved", "sched.emit.starved"} & names
     covered = sum(e - s for s, e, _ in spans)
     assert covered >= 0.98 * (spans[-1][1] - spans[0][0])
     # a switch, not a nest: no span starts inside another
     assert all(b[0] >= a[1] - 1 for a, b in zip(spans, spans[1:]))
+
+
+# ------------------------------------------------ the emit behind the launch
+#
+# A drain that leaves nothing in flight holds its emit; the pass that follows
+# launches what comes next and flushes it right behind that launch, before
+# its own drain. One rule for every model: K/V pages, recurrent state, blocks.
+
+HELD = {"tiny-llama": dict(decode_chunk=4),
+        "tiny-falcon-h1": dict(decode_chunk=4),
+        "tiny-sdar": dict(decode_chunk=10)}
+SERIES = ("llm_drains_ring_empty_total", "llm_emits_deferred_total")
+
+
+def _held_engine(model, **over):
+    return _manual(model, prefill_budget_tokens=32, **HELD[model], **over)
+
+
+def _count(name) -> float:
+    return sum(v for _, v in default_registry.counter(name).samples())
+
+
+def _switches(eng) -> list:
+    """Every switch of the engine's clock from here on, in order."""
+    seen, to = [], eng._clock.to
+
+    def spy(phase, starved=None):
+        seen.append(phase)
+        return to(phase, starved)
+    eng._clock.to = spy
+    return seen
+
+
+def _ask(eng, prompt, max_tokens, got):
+    rng = np.random.default_rng(prompt)
+    eng.submit(rng.integers(3, 200, prompt).tolist(),
+               SamplingParams(max_tokens=max_tokens),
+               lambda ev: got.append(ev.token_id))
+
+
+@pytest.mark.parametrize("model", sorted(HELD))
+def test_an_arrivals_step_is_launched_before_the_held_emit(model):
+    """An arrival waits while the ring drains. The drain of its last chunk
+    commits and HOLDS the emit; the next pass admits, launches the arrival's
+    mixed step and only then emits that chunk's tokens, under the step."""
+    eng = _held_engine(model, decode_lookahead=2)
+    try:
+        got, new = [], []
+        _ask(eng, 32, 60, got)
+        while len(eng._ring) < 2:
+            eng._loop_pass()
+        _ask(eng, 32, 8, new)
+        while len(eng._ring) > 1:
+            eng._loop_pass()
+        before = [_count(n) for n in SERIES]
+        tokens, records = len(got), len(eng.round_timings)
+        eng._loop_pass()                      # drains the ring's last chunk
+        assert not eng._ring and eng._held is not None
+        assert len(got) == tokens, "the held tokens went out early"
+        assert len(eng.round_timings) == records     # nor is its record
+        assert [_count(n) - b for n, b in zip(SERIES, before)] == [1, 0]
+        seen = _switches(eng)
+        eng._loop_pass()                      # the arrival's mixed step
+        assert eng._held is None and len(got) > tokens
+        # the flip's first token too (a block model samples none there)
+        assert bool(new) == (model != "tiny-sdar")
+        assert (seen.index("admit") < seen.index("launch")
+                < seen.index("emit") < seen.index("drain")), seen
+        assert [_count(n) - b for n, b in zip(SERIES, before)] == [1, 1]
+        # the step drained the prefill queue: chunks were chained off it,
+        # so its own emit ran at once (two records closed in this pass)
+        last, arrival = list(eng.round_timings)[records:]
+        assert last["kind"] == "decode" and last["depth"] == 0
+        assert last["phases"]["commit"][2] == last["phases"]["commit"][0]
+        assert _starved_of(last, "emit", "drain") == 0.0
+        assert arrival["kind"] == "mixed" and arrival["depth"] == 2
+        order = list(arrival["phases"])
+        assert order.index("launch") < order.index("emit"), order
+        assert arrival["phases"]["emit"][2] == 0.0
+        assert arrival["phases"]["emit"][0] > 0.0
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("model", sorted(HELD))
+def test_a_prompts_next_chunk_is_launched_before_the_held_emit(model):
+    """Between two chunks of one prompt nothing is chained (the plan did not
+    drain the prefill queue), so the step's drain leaves the device with
+    nothing: the next chunk's step is launched first, and the running row's
+    token, the ``prefill_chunk`` event and the record follow it."""
+    eng = _held_engine(model, decode_lookahead=2)
+    try:
+        got, new = [], []
+        _ask(eng, 32, 60, got)
+        while len(eng._ring) < 2:
+            eng._loop_pass()
+        _ask(eng, 96, 8, new)                 # three chunks of 32
+        while not eng._prefill_slots:
+            eng._loop_pass()
+        state = eng.slots[eng._prefill_slots[0]]
+        assert state.prefill_pos == 32 and state.prefill_chunks == 1
+        assert eng._held is not None and not eng._ring    # chunk 1, held
+        before = [_count(n) for n in SERIES]
+        tokens, records = len(got), len(eng.round_timings)
+        seen = _switches(eng)
+        eng._loop_pass()                      # chunk 2
+        assert state.prefill_pos == 64        # the commit's, never held
+        assert (seen.index("launch") < seen.index("emit")
+                < seen.index("drain")), seen
+        assert len(got) == tokens + (0 if model == "tiny-sdar" else 1)
+        first, = list(eng.round_timings)[records:]      # chunk 1's record
+        assert first["kind"] == "mixed" and first["chunk_tokens"] == 32
+        assert first["depth"] == 0 and _starved_of(first, "emit") == 0.0
+        assert eng._held is not None          # chunk 2's, held in its turn
+        assert [_count(n) - b for n, b in zip(SERIES, before)] == [1, 1]
+        eng._loop_pass()                      # the last chunk: chains
+        second, third = list(eng.round_timings)[records + 1:]
+        assert second["phases"]["emit"][2] == 0.0 < second["phases"]["emit"][0]
+        assert third["depth"] == 2
+        assert bool(new) == (model != "tiny-sdar"), "the flip's first token"
+        _run(eng, new, 8)
+    finally:
+        eng.shutdown()

@@ -446,8 +446,10 @@ def _arrival_run(model, depth, arrivals):
         held.append(eng.tokens_emitted)
         seen["passes"] = _passes_until(eng, admitted)
         seen["waits"] = _counter("llm_admission_ring_waits_total") - seen["waits"]
-        # what the running rows received while the arrival was held
-        seen["emitted"] = held[-2] - seen["emitted"]
+        # what the running rows received while the arrival was held, and by
+        # the end of the pass that admitted it
+        seen["emitted"], seen["emitted_after"] = (
+            held[-2] - seen["emitted"], held[-1] - seen["emitted"])
         seen["occupied"] = sum(s is not None for s in eng.slots)
         seen["ring_rebuilt"] = len(eng._ring)
         seen["discarded"] = eng._lookahead_stats["discarded"]
@@ -470,7 +472,8 @@ def test_an_arrival_waits_for_the_ring_and_discards_nothing(model, depth,
                                                            arrivals):
     """The tentpole, for both families: an arrival into a full ring is held
     for exactly the chunks in flight (``depth`` drains, each emitted to both
-    running rows), then admitted off the empty ring in the next pass — two
+    running rows: the last one's emit behind the arrival's launch, in the
+    admitting pass), then admitted off the empty ring in the next pass — two
     arrivals into two free slots in that ONE pass — with nothing discarded,
     and every stream is what the synchronous scheduler emits."""
     sync_col, sync_seen = _sync_arrivals(model, arrivals)
@@ -482,8 +485,10 @@ def test_an_arrival_waits_for_the_ring_and_discards_nothing(model, depth,
     # no starvation: the ring stopped extending, so the wait is its depth
     assert seen["passes"] == depth + 1, seen
     assert seen["waits"] == depth, seen
-    # each drain went to both running rows: the wait cost them nothing
-    assert seen["emitted"] == depth * 2 * 4, seen
+    # each drain went to both running rows: the wait cost them nothing.
+    # The drain that emptied the ring held its emit for the arrival's launch
+    assert seen["emitted"] == (depth - 1) * 2 * 4, seen
+    assert seen["emitted_after"] >= depth * 2 * 4, seen
     # the admitting pass ran the arrival's chunk as a lane; a lone arrival's
     # step drains the prefill queue, so the ring is rebuilt off that dispatch
     assert seen["ring_rebuilt"] == (depth if arrivals == 1 else 0), seen
@@ -544,6 +549,8 @@ def test_the_rings_series_are_at_zero_from_engine_build():
     names = ("llm_decode_chunks_dispatched_total",
              "llm_decode_chunks_discarded_total",
              "llm_admission_ring_waits_total",
+             "llm_drains_ring_empty_total",
+             "llm_emits_deferred_total",
              "llm_control_rows_uploads_total",
              "llm_loose_row_programs_total",
              "llm_attn_pages_walked_total",
@@ -689,6 +696,8 @@ def test_the_rings_series_are_on_metrics_before_the_first_request():
     for name in ("llm_decode_chunks_dispatched_total",
                  "llm_decode_chunks_discarded_total",
                  "llm_admission_ring_waits_total",
+                 "llm_drains_ring_empty_total",
+                 "llm_emits_deferred_total",
                  "llm_control_rows_uploads_total",
                  "llm_loose_row_programs_total",
                  "llm_attn_pages_walked_total",
@@ -884,3 +893,187 @@ def test_cancel_while_suspended_never_resurrects():
             assert stats["cancellations"] == {"mid_suspend": 1}
     assert len(col.finishes) == 2
     _drain_clean(sched)
+
+
+# --------------------------------------------- the emit behind the next launch
+#
+# A drain that leaves nothing in flight holds its emit until the next program
+# is queued (`_close_round`). Held or not, a stream is what it was: the
+# reference below makes the same passes and emits at the end of each, which
+# is the order the scheduler had before (drain, commit, emit, then the next
+# pass's admit and launch).
+
+HELD_FAMILIES = {"tiny-llama": {}, "tiny-falcon-h1": {},
+                 "tiny-sdar": {"decode_chunk": 10}}
+_TIMELINE_KEYS = ("event", "tokens", "pos", "of", "reason", "chunks",
+                  "prompt_tokens", "blocks")
+
+
+def _held_run(model, depth, parents_order):
+    """Four seeded requests, passes by hand: A (greedy, ends by max-tokens)
+    and B (sampled, a stop set wider than ``device_stop_width``: the host
+    alone sees it stop) start together; C (four chunks) and D (two) arrive
+    as B's chunk runs, so six mixed steps follow one another with nothing
+    chained between them and A's and B's tokens ride held emits. Returns
+    the streams, each request's flight record and what the flushes met."""
+    from cyberfabric_core_tpu.modkit.flight_recorder import default_recorder
+
+    rng = np.random.default_rng(11)
+    # every chunk 32 wide: one mixed_step shape to compile an engine
+    prompts = [rng.integers(3, 500, n).tolist() for n in (32, 32, 128, 64)]
+    samplings = [
+        SamplingParams(max_tokens=4, temperature=0.0),
+        SamplingParams(max_tokens=60, temperature=0.9, seed=51,
+                       stop_token_ids=tuple(range(0, 400))),
+        SamplingParams(max_tokens=10, temperature=0.0),
+        SamplingParams(max_tokens=6, temperature=0.8, top_p=0.9, seed=7)]
+    arrive = {0: (0, 1), 1: (2, 3)}         # pass -> submitted ahead of it
+    eng = _manual(_cfg(model=model, decode_lookahead=depth,
+                       prefill_budget_tokens=32, **HELD_FAMILIES[model]))
+    ids = [f"held-{model}-{depth}-{parents_order}-{i}" for i in range(4)]
+    col = _Collector(4)
+    seen = {"flushed_behind_a_chunk": 0, "finished_there": 0,
+            "stopped_there": 0}
+    flush = eng._flush_held_emit
+
+    def spy(behind_launch=False):
+        carrying = behind_launch and bool(eng._prefill_slots)
+        done, epoch = eng.requests_completed, eng._epoch
+        held = flush(behind_launch)
+        if held and carrying:
+            seen["flushed_behind_a_chunk"] += 1
+            seen["finished_there"] += eng.requests_completed - done
+            seen["stopped_there"] += eng._epoch - epoch
+        return held
+    eng._flush_held_emit = spy
+    series = ("llm_drains_ring_empty_total", "llm_emits_deferred_total")
+    before = [_counter(n) for n in series]
+    try:
+        for n in range(600):
+            for i in arrive.get(n, ()):
+                eng.submit(prompts[i], samplings[i], col.emit_for(i),
+                           request_id=ids[i])
+            eng._loop_pass()
+            if parents_order:
+                flush()
+            if col.done.is_set():
+                break
+        assert col.done.is_set(), eng.stats()
+        seen["prefill"] = (eng.prefill_chunks, eng.chunked_prefill_tokens)
+        seen["discarded"] = eng._lookahead_stats["discarded"]
+        seen["ring_empty"], seen["deferred"] = (
+            _counter(n) - b for n, b in zip(series, before))
+        seen["depth_0"] = sum(r["depth"] == 0 for r in eng.round_timings)
+        seen["records"] = len(eng.round_timings)
+    finally:
+        eng.shutdown()
+    records = [[{k: e[k] for k in _TIMELINE_KEYS if k in e}
+                for e in default_recorder.lookup(rid)["timeline"]]
+               for rid in ids]
+    return col, records, seen
+
+
+@functools.cache
+def _parents_order(model, depth):
+    return _held_run(model, depth, True)
+
+
+@pytest.mark.parametrize("model,depth", [(m, 0) for m in sorted(HELD_FAMILIES)]
+                         + [("tiny-llama", 2)])
+def test_a_held_emit_never_changes_a_stream(model, depth):
+    """Every stream's tokens, finish reason and event order are those of the
+    order the scheduler had, for K/V pages, recurrent state and blocks, with
+    and without a ring: through arrivals, prompts of several chunks, a
+    max-tokens finish inside a held emit and a host-fallback stop inside one.
+    The step launched ahead of that stop holds a token for a row that is
+    gone: it is not emitted, and the prompt chunk the step carried is kept
+    (every chunk is computed once)."""
+    want, want_records, want_seen = _parents_order(model, depth)
+    col, records, seen = _held_run(model, depth, False)
+    assert col.tokens == want.tokens and col.finishes == want.finishes
+    assert col.finishes[0] == "length" and col.finishes[1] == "stop"
+    assert len(col.tokens[0]) == 4 and len(col.tokens[1]) < 60
+    for i, (got, ref) in enumerate(zip(records, want_records)):
+        assert got == ref, f"request {i}: event order"
+    # the cases did occur: emits ran behind steps that carried a chunk, A's
+    # last token and B's stop among them; the reference held nothing there
+    assert seen["flushed_behind_a_chunk"] >= 5, seen
+    assert seen["finished_there"] >= 2 and seen["stopped_there"] == 1, seen
+    assert want_seen["flushed_behind_a_chunk"] == 0
+    # the counters count as stated: every drain that left nothing undrained
+    # (the records of depth 0; without a ring, every round), and of those
+    # the emits that ran behind the next launch: all, nothing flushed first
+    assert seen["ring_empty"] == seen["depth_0"] == seen["deferred"] > 0
+    assert depth or seen["depth_0"] == seen["records"]
+    assert want_seen["ring_empty"] == seen["ring_empty"]
+    assert want_seen["deferred"] < seen["deferred"]
+    # 32 + 32 + 128 + 64 tokens in 1 + 1 + 4 + 2 chunks, none twice
+    assert seen["prefill"] == want_seen["prefill"] == (8, 256)
+
+
+def _end_cancel(eng, rid):
+    eng.cancel(rid)
+    eng._loop_pass()
+    return "cancelled"
+
+
+def _end_close(eng, rid):
+    eng.close()
+    return "error"
+
+
+def _end_stop(eng, rid):
+    eng._stop.set()             # the loop's thread, stopped with one held
+    eng._loop_body()
+    return None
+
+
+def _end_idle(eng, rid):
+    eng._decode_round = lambda: None    # a pass that launches nothing
+    eng._loop_pass()
+    return None
+
+
+def _end_preempt(eng, rid):
+    slot = next(s for s, st in enumerate(eng.slots) if st is not None)
+    eng._preempt_slot(slot, eng.slots[slot])
+    assert eng._suspended[0].state.emitted == eng.tokens_emitted
+    return None
+
+
+@pytest.mark.parametrize("model,end", [
+    (m, end) for m in sorted(HELD_FAMILIES)
+    for end in (_end_cancel, _end_close, _end_idle)
+] + [("tiny-llama", _end_stop), ("tiny-llama", _end_preempt)],
+    ids=lambda v: v if isinstance(v, str) else v.__name__[5:])
+def test_what_ends_or_reads_a_stream_flushes_the_held_emit_first(model, end):
+    """A cancel, ``close()``, the loop's stop, a pass with nothing to launch
+    and a preemption each meet an emit that is held: its tokens go out
+    first, in order, then whatever terminal the act brings; none of it
+    counts as an emit deferred behind a launch."""
+    eng = _manual(_cfg(model=model, decode_lookahead=0,
+                       prefill_budget_tokens=32, **HELD_FAMILIES[model]))
+    events = []
+    try:
+        eng.submit(list(range(5, 37)), SamplingParams(max_tokens=200),
+                   lambda ev: events.append((ev.token_id, ev.finished)),
+                   request_id=f"flush-first-{model}-{end.__name__}")
+        _passes_until(eng, lambda: eng.decode_rounds >= 3
+                      and eng._held is not None)
+        got, emitted = len(events), eng.tokens_emitted
+        deferred = _counter("llm_emits_deferred_total")
+        terminal = end(eng, f"flush-first-{model}-{end.__name__}")
+        assert eng._held is None
+        assert _counter("llm_emits_deferred_total") == deferred
+        new = events[got:]
+        tokens = [e for e in new if e[0] >= 0]
+        # one chunk's tokens (a block model's: the blocks it committed)
+        assert len(tokens) == eng.tokens_emitted - emitted > 0
+        assert len(tokens) == 4 or model == "tiny-sdar"
+        assert all(fin is None for _, fin in tokens)
+        if terminal is None:
+            assert new == tokens
+        else:
+            assert new == tokens + [(-1, terminal)]
+    finally:
+        eng.shutdown()
