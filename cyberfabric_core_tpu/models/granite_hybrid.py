@@ -60,7 +60,8 @@ from .llama import (MOE_LEAVES, DecodeGroup, PagedPools, Params, _attn_out,
                     _decode_attend, _decode_targets, _embed_scale, _qkv_proj,
                     _ragged_attend, decode_work, embed_lookup,
                     gather_last_hidden, lm_head_logits, mixed_attention,
-                    mixed_hidden_out, mixed_layout, moe_experts, moe_route)
+                    mixed_hidden_out, mixed_layout, moe_experts,
+                    moe_item_rows, moe_route)
 
 __all__ = ["init_params", "init_params_with", "init_state", "layer_runs",
            "forward_paged_decode", "forward_paged_mixed", "lm_head_logits",
@@ -68,8 +69,9 @@ __all__ = ["init_params", "init_params_with", "init_state", "layer_runs",
 
 #: what ``aux`` counts over a forward's expert layers, in the order the
 #: serving programs hand them to the host (kimi_k2's names: every expert is
-#: held here, so ``local`` equals ``assignments``)
-MOE_COUNTERS = ("assignments", "local", "touched")
+#: held here, so ``local`` equals ``assignments``), then the rows one grouped
+#: matmul of the layer multiplied (``llama.moe_item_rows``)
+MOE_COUNTERS = ("assignments", "local", "touched", "item_rows")
 
 Aux = dict[str, jnp.ndarray]
 
@@ -175,7 +177,8 @@ def _moe_residual(lp: dict, moe: dict, layer, h: jnp.ndarray,
     routed = jnp.asarray(top_idx.size, jnp.int32)
     touched = jnp.sum(jnp.bincount(top_idx.reshape(-1),
                                    length=cfg.num_experts) > 0)
-    counts = jnp.stack([routed, routed, touched.astype(jnp.int32)])
+    counts = jnp.stack([routed, routed, touched.astype(jnp.int32),
+                        moe_item_rows(top_idx, cfg)])
     return _branch(h, y.reshape(h.shape), cfg), top_idx, counts
 
 

@@ -65,7 +65,8 @@ from .configs import ModelConfig
 from .llama import (MOE_LEAVES, DecodeGroup, Params, _act, _decode_targets,
                     _scaled, _wmat, decode_work, embed_lookup,
                     gather_last_hidden, lm_head_logits, mixed_hidden_out,
-                    mixed_layout, moe_capacity, moe_experts, moe_route,
+                    mixed_layout, moe_capacity, moe_experts, moe_item_rows,
+                    moe_route,
                     moe_share_counts)
 
 __all__ = ["init_params", "init_params_with", "forward_paged_decode",
@@ -76,8 +77,11 @@ __all__ = ["init_params", "init_params_with", "forward_paged_decode",
 #: serving programs hand them to the host: assignments routed (tokens x K),
 #: those that fell on experts held here, held experts with at least one, and
 #: the expert layers whose held assignments fitted ``moe_capacity`` (so
-#: ``moe_experts`` ran over the compacted list) beside the expert layers run
-MOE_COUNTERS = ("assignments", "local", "touched", "compact", "forwards")
+#: ``moe_experts`` ran over the compacted list) beside the expert layers run,
+#: then the rows one grouped matmul of the layer multiplied
+#: (``llama.moe_item_rows``)
+MOE_COUNTERS = ("assignments", "local", "touched", "compact", "forwards",
+                "item_rows")
 
 LatentPool = tuple[jnp.ndarray]     # (latent,): [L, N, page, rank + rope]
 Aux = dict[str, jnp.ndarray]
@@ -240,7 +244,8 @@ def _moe_residual(lp: dict, moe: dict, layer, h: jnp.ndarray,
     counts = jnp.stack([routed, local, touched,
                         (local <= moe_capacity(top_idx.size, cfg)
                          ).astype(jnp.int32),
-                        jnp.asarray(1, jnp.int32)])
+                        jnp.asarray(1, jnp.int32),
+                        moe_item_rows(top_idx, cfg)])
     return h + y.reshape(h.shape).astype(h.dtype), top_idx, counts
 
 

@@ -289,17 +289,40 @@ def moe_experts(flat: jnp.ndarray, top_idx: jnp.ndarray, gates: jnp.ndarray,
                         lambda: over(order[:capacity]), lambda: over(order))
 
 
+def _held_sizes(top_idx: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """The assignments ``[N, K]`` that fell on each expert this chip holds
+    ``[experts_local]``."""
+    E = cfg.experts_local
+    held = top_idx.reshape(-1) - cfg.expert_offset
+    return jnp.bincount(jnp.where((held >= 0) & (held < E), held, E),
+                        length=E + 1)[:-1]
+
+
+def moe_item_rows(top_idx: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """The rows ONE grouped matmul of :func:`moe_experts` multiplies for the
+    experts chosen ``[N, K]``: its real work items times the row tile it
+    picked for the rows it ran over (``ops/grouped_matmul.py: item_rows``;
+    the compacted list where a share's assignments fitted it). Over the
+    experts touched it says how many rows the MXU is fed for an expert,
+    beside the rows that are real (int32 scalar)."""
+    from ..ops.grouped_matmul import item_rows
+
+    sizes, n = _held_sizes(top_idx, cfg), top_idx.size
+    capacity = moe_capacity(n, cfg)
+    if capacity == n:
+        return item_rows(sizes, n)
+    return jnp.where(jnp.sum(sizes) <= capacity, item_rows(sizes, capacity),
+                     item_rows(sizes, n))
+
+
 def moe_share_counts(top_idx: jnp.ndarray, cfg: ModelConfig):
     """What one expert layer counts of the experts chosen ``[N, K]``: the
     assignments routed, those that fell on experts this chip holds, and the
     held experts with at least one (three int32 scalars)."""
-    held = top_idx.reshape(-1) - cfg.expert_offset
-    mine = (held >= 0) & (held < cfg.experts_local)
-    per_expert = jnp.bincount(jnp.where(mine, held, cfg.experts_local),
-                              length=cfg.experts_local + 1)[:-1]
+    sizes = _held_sizes(top_idx, cfg)
     return (jnp.asarray(top_idx.size, jnp.int32),
-            jnp.sum(mine).astype(jnp.int32),
-            jnp.sum(per_expert > 0).astype(jnp.int32))
+            jnp.sum(sizes).astype(jnp.int32),
+            jnp.sum(sizes > 0).astype(jnp.int32))
 
 
 def _moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig,

@@ -67,7 +67,8 @@ from .falcon_h1 import init_mixer_small, init_state
 from .granite_hybrid import _at
 from .kimi_k2 import _proj
 from .llama import (Params, _act, gather_last_hidden, lm_head_logits,
-                    moe_experts, moe_route, moe_share_counts, split_moe)
+                    moe_experts, moe_item_rows, moe_route, moe_share_counts,
+                    split_moe)
 
 __all__ = ["init_params", "init_params_with", "init_state", "layer_runs",
            "forward_paged_decode", "forward_paged_mixed", "lm_head_logits",
@@ -75,11 +76,15 @@ __all__ = ["init_params", "init_params_with", "init_state", "layer_runs",
 
 #: what ``aux`` counts over a forward's expert layers, in the order the
 #: serving programs hand them to the host: assignments routed (tokens x K),
-#: those that fell on experts held here, held experts with at least one, and
-#: the held assignments once more where the forward was a decode step (0 in
-#: a mixed step: with ``touched`` over decode chunks it says how many rows a
-#: touched expert multiplies, of its 128-row tile)
-MOE_COUNTERS = ("assignments", "local", "touched", "decode_local")
+#: those that fell on experts held here, held experts with at least one, the
+#: rows one grouped matmul of the layer multiplied (``llama.moe_item_rows``:
+#: its work items x its row tile), and the held assignments once more where
+#: the forward was a decode step (0 in a mixed step: with ``touched`` over
+#: decode chunks it says how many rows a touched expert has)
+MOE_COUNTERS = ("assignments", "local", "touched", "item_rows",
+                "decode_local")
+#: those of them a layer counts (the forwards add the last)
+_LAYER_COUNTERS = MOE_COUNTERS[:-1]
 
 _KINDS = ("mamba", "attention", "moe")
 
@@ -170,7 +175,7 @@ def _experts(lp: dict, moe: dict, i, h: jnp.ndarray, x: jnp.ndarray,
              cfg: ModelConfig):
     """An expert layer on expert-stack layer ``i`` over the normed ``x``
     [1, N, H], added to ``h``; also the experts chosen [N, K] and the layer's
-    first three ``MOE_COUNTERS``."""
+    ``_LAYER_COUNTERS``."""
     flat = x.reshape(-1, x.shape[-1])
     top_idx, gates = moe_route(
         flat, lp["router"], cfg.experts_per_token, sigmoid=True,
@@ -180,7 +185,8 @@ def _experts(lp: dict, moe: dict, i, h: jnp.ndarray, x: jnp.ndarray,
     shared = _act(_proj(flat, lp["shared_up"]), cfg).astype(flat.dtype)
     y = (_proj(routed.astype(flat.dtype), lp["latent_up"])
          + _proj(shared, lp["shared_down"]))
-    counts = jnp.stack(moe_share_counts(top_idx, cfg))
+    counts = jnp.stack([*moe_share_counts(top_idx, cfg),
+                        moe_item_rows(top_idx, cfg)])
     return h + y.reshape(h.shape).astype(h.dtype), top_idx, counts
 
 
@@ -192,12 +198,12 @@ def _run_layers(params: Params, cfg: ModelConfig, h, pools, state,
     norms = params["layers"]["norm"]
     small, moe = split_moe(params["moe"])
     carry = (h, *pools, state["ssm"], state["conv"])
-    experts, counts = [], jnp.zeros((3,), jnp.int32)
+    experts, counts = [], jnp.zeros((len(_LAYER_COUNTERS),), jnp.int32)
 
     for unit, first, first_of, reps in layer_runs(cfg):
         def body(carry, step, unit=unit, first=first, first_of=first_of):
             h, k_pool, v_pool, ssm, conv = carry
-            chosen, n = [], jnp.zeros((3,), jnp.int32)
+            chosen, n = [], jnp.zeros_like(counts)
             for j, kind in enumerate(unit):
                 layer = first + step * len(unit) + j
                 i = first_of[kind] + step
@@ -222,7 +228,7 @@ def _run_layers(params: Params, cfg: ModelConfig, h, pools, state,
 
     h, k_pool, v_pool, ssm, conv = carry
     aux = {"experts": jnp.concatenate(experts),
-           **{name: counts[i] for i, name in enumerate(MOE_COUNTERS[:3])}}
+           **{name: counts[i] for i, name in enumerate(_LAYER_COUNTERS)}}
     return h, (k_pool, v_pool), {"ssm": ssm, "conv": conv}, aux
 
 

@@ -48,15 +48,16 @@ from .llama import (DecodeGroup, PagedPools, Params, _attn_out,
                     _decode_targets, _qkv_proj, _ragged_attend, decode_work,
                     embed_lookup, gather_last_hidden, init_params,
                     lm_head_logits, mixed_attention, mixed_layout,
-                    moe_experts, moe_route, split_moe)
+                    moe_experts, moe_item_rows, moe_route, split_moe)
 
 __all__ = ["init_params", "forward_paged_decode", "forward_paged_mixed",
            "lm_head_logits", "gather_last_hidden", "MOE_COUNTERS"]
 
 Aux = dict[str, jnp.ndarray]
 #: what ``aux`` counts over a forward's expert layers, for the serving
-#: programs to hand to the host
-MOE_COUNTERS = ("touched",)
+#: programs to hand to the host: the experts with at least one token, and the
+#: rows one grouped matmul of the layer multiplied (``llama.moe_item_rows``)
+MOE_COUNTERS = ("touched", "item_rows")
 
 
 def _one_device(mesh: Any, interpret: bool | None) -> bool:
@@ -83,14 +84,17 @@ def _block_attend(interpret: bool, width: int):
 def _moe_residual(lp: dict, moe: dict, layer, h: jnp.ndarray,
                   cfg: ModelConfig):
     """Post-attention norm + the expert layer + residual over ``h``
-    [1, N, H]; also the experts chosen [N, K] and how many were touched."""
+    [1, N, H]; also the experts chosen [N, K] and the layer's
+    ``MOE_COUNTERS``."""
     x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
     flat = x.reshape(-1, x.shape[-1])
     top_idx, gates = moe_route(flat, lp["router"], cfg.experts_per_token)
     y = moe_experts(flat, top_idx, gates, moe, cfg, layer)
     touched = jnp.sum(jnp.bincount(top_idx.reshape(-1),
                                    length=cfg.num_experts) > 0)
-    return h + y.reshape(h.shape).astype(h.dtype), top_idx, touched
+    counts = jnp.stack([touched.astype(jnp.int32),
+                        moe_item_rows(top_idx, cfg)])
+    return h + y.reshape(h.shape).astype(h.dtype), top_idx, counts
 
 
 def _run_layers(params: Params, cfg: ModelConfig, h, pools, body):
@@ -102,14 +106,16 @@ def _run_layers(params: Params, cfg: ModelConfig, h, pools, body):
         h, k_pool, v_pool = carry
         lp, layer = xs
         h, k_pool, v_pool = body(lp, layer, h, k_pool, v_pool)
-        h, top_idx, touched = _moe_residual(lp, moe, layer, h, cfg)
-        return (h, k_pool, v_pool), (top_idx, touched)
+        h, top_idx, counts = _moe_residual(lp, moe, layer, h, cfg)
+        return (h, k_pool, v_pool), (top_idx, counts)
 
-    (h, k_pool, v_pool), (experts, touched) = jax.lax.scan(
+    (h, k_pool, v_pool), (experts, counts) = jax.lax.scan(
         layer_body, (h, *pools),
         (scanned, jnp.arange(cfg.num_layers, dtype=jnp.int32)))
-    return h, (k_pool, v_pool), {"experts": experts,
-                                 "touched": jnp.sum(touched).astype(jnp.int32)}
+    counts = jnp.sum(counts, axis=0)
+    return h, (k_pool, v_pool), {
+        "experts": experts,
+        **{n: counts[i] for i, n in enumerate(MOE_COUNTERS)}}
 
 
 def forward_paged_decode(

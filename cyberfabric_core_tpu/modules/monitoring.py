@@ -608,7 +608,12 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                  "Assignments that fell on experts held here, summed over "
                  "layers and the forwards of decode chunks alone: over the "
                  "decode-only touched, the rows a touched expert multiplies "
-                 "(a model whose module counts them)")):
+                 "(a model whose module counts them)"),
+                ("llm_moe_item_rows_total",
+                 "Rows ONE grouped matmul of an expert layer multiplied (its "
+                 "work items x the row tile picked from the call's shapes), "
+                 "summed over expert layers and forwards: over the experts "
+                 "touched, the rows the MXU is fed for an expert")):
             self.registry.counter(name, text).inc(0.0)
 
         def _state_stat(key: str) -> float:

@@ -19,6 +19,36 @@ front of it (what ``lax.scan`` over the layers hands its body, and what
 expert of the layer, three times the bytes of the matmul itself. int8 weights
 are converted block by block inside the kernel and their per-channel scale is
 applied to the f32 result.
+
+**The row tile follows from the call's shapes** (``row_tile(M, E)``, evaluated
+when the call is traced, like ``ops/paged_attention.py: decode_page_group``):
+64 rows where the mean group has at most 64 (``M <= 64 E``), ``ROW_TILE`` =
+128 above. A work item multiplies a whole tile by its expert's block, whatever
+rows of it are real, and converts an int8 block first: over sdar's 1.57 MB
+blocks (1.92 us of bytes) an item takes 2.63 us at 128 rows and 2.26 / 2.17 /
+2.05 at 64 / 32 / 16, but every tile boundary an expert's rows cross is one
+more item that converts the block again, and under 64 rows those cost more
+than the rows save (at 9 rows an expert one expert in eight crosses a
+boundary of 64, one in two a boundary of 16). A converted block kept in a
+VMEM scratch for an expert's further items costs its own store and load, 5-25%
+of a call at every tile, so the kernel has none. Above 64 rows a group the
+items that 128 saves win. The calls the benchmark's cells run (the kernel
+alone on a v5e: ``PERF.md`` section 5):
+
+===========================================  ======  ===  =========  ====
+call (rows of the sorted assignments)        M       E    mean rows  tile
+===========================================  ======  ===  =========  ====
+sdar decode forward, 64 tokens top-8            512  128        4.0    64
+granite decode step, 64 rows top-10             640   72        8.9    64
+kimi decode step, the compacted rows            128   12       10.7    64
+nemotron decode step, 64 rows top-22           1408  128       11.0    64
+kimi 512-token mixed step, compacted            640   12       53.3    64
+granite 512-token mixed step                   5760   72       80.0   128
+nemotron 512-token mixed step                 12672  128       99.0   128
+===========================================  ======  ===  =========  ====
+
+A row's result does not depend on the tile: the same int8 block, the same
+bfloat16 operands, one f32 accumulation over all of ``K``.
 """
 
 from __future__ import annotations
@@ -30,7 +60,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: rows of a tile at serving sizes; a smaller step is one tile of its rows
+#: the most rows of a tile (``row_tile`` picks the tile of a call), and the
+#: unit of ``models/llama.py: moe_capacity``
 ROW_TILE = 128
 #: the most bytes of one expert's matrix a program takes as a block: a wider
 #: matrix is walked in column tiles (the block is double-buffered and an
@@ -38,8 +69,19 @@ ROW_TILE = 128
 BLOCK_BYTES = 4 * 1024 * 1024
 
 
-def _row_tile(m: int) -> int:
-    return ROW_TILE if m >= ROW_TILE else -(-m // 16) * 16
+#: the rows of a tile where the mean group has at most this many rows
+SMALL_ROW_TILE = 64
+
+
+def row_tile(m: int, groups: int) -> int:
+    """Rows of a tile of a call over ``m`` rows in ``groups`` groups, from
+    those two shapes alone, evaluated when the call is traced:
+    ``SMALL_ROW_TILE`` where the mean group has at most that many rows (``m
+    <= 64 x groups``), ``ROW_TILE`` above, and one tile of its rows (whole
+    sublane tiles of 16) for a call with fewer. The module docstring has the
+    reasons and the cells' calls."""
+    tm = SMALL_ROW_TILE if m <= SMALL_ROW_TILE * groups else ROW_TILE
+    return min(tm, -(-m // 16) * 16)
 
 
 def _col_tile(k: int, n: int, itemsize: int) -> int:
@@ -53,6 +95,24 @@ def _col_tile(k: int, n: int, itemsize: int) -> int:
     return max(fits, default=128)
 
 
+def _items_of(sizes: jnp.ndarray, tm: int):
+    """Per group (``sizes`` int32) its first row, the row past its last, its
+    first tile and the tiles its rows touch (0 for an empty group)."""
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    first = starts // tm
+    return starts, ends, first, jnp.where(sizes > 0,
+                                          (ends - 1) // tm - first + 1, 0)
+
+
+def item_rows(group_sizes: jnp.ndarray, m: int) -> jnp.ndarray:
+    """The rows ONE grouped matmul over ``m`` rows multiplies for these
+    groups: its real work items times its tile (int32 scalar)."""
+    tm = row_tile(m, group_sizes.shape[0])
+    per = _items_of(group_sizes.astype(jnp.int32), tm)[3]
+    return jnp.sum(per).astype(jnp.int32) * tm
+
+
 def group_items(group_sizes: jnp.ndarray, m_pad: int, tm: int):
     """The work items of one grouped matmul over ``m_pad`` rows in tiles of
     ``tm``: per item its row tile, its expert and the expert's row range,
@@ -63,11 +123,7 @@ def group_items(group_sizes: jnp.ndarray, m_pad: int, tm: int):
     E = group_sizes.shape[0]
     tiles = m_pad // tm
     n_items = tiles + min(E, m_pad) - 1
-    sizes = group_sizes.astype(jnp.int32)
-    ends = jnp.cumsum(sizes)
-    starts = ends - sizes
-    first = starts // tm
-    per = jnp.where(sizes > 0, (ends - 1) // tm - first + 1, 0)
+    starts, ends, first, per = _items_of(group_sizes.astype(jnp.int32), tm)
     item_ends = jnp.cumsum(per)
     n_real = item_ends[-1]
     t = jnp.minimum(jnp.arange(n_items, dtype=jnp.int32),
@@ -96,7 +152,8 @@ def _kernel(tile_ref, expert_ref, lo_ref, hi_ref, n_ref, layer_ref, x_ref,
         w = w_ref[0, 0]
         if w.dtype != x_ref.dtype:
             # int8 -> f32 -> the activations' dtype: the two-step convert is
-            # the one Mosaic lowers for every source width
+            # the one Mosaic lowers for every source width (every item
+            # converts its block again: the module docstring says why)
             w = w.astype(jnp.float32).astype(x_ref.dtype)
         y = jax.lax.dot_general(x_ref[...], w, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
@@ -114,7 +171,7 @@ def _kernel(tile_ref, expert_ref, lo_ref, hi_ref, n_ref, layer_ref, x_ref,
             o_ref[...] += y
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "tile_rows"))
 def grouped_matmul(
     x: jnp.ndarray,            # [M, K] rows sorted by group
     w: jnp.ndarray,            # [L, E, K, N] the stacked expert matrices
@@ -122,12 +179,15 @@ def grouped_matmul(
     group_sizes: jnp.ndarray,  # [E] int32 rows of each group, summing to M
     layer: jnp.ndarray | int = 0,
     interpret: bool = False,
+    tile_rows: int | None = None,
 ) -> jnp.ndarray:
     """``out[r] = x[r] @ w[layer, g(r)] * scale[layer, g(r)]`` in f32, where
-    ``g(r)`` is the group whose row range holds ``r``."""
+    ``g(r)`` is the group whose row range holds ``r``. ``tile_rows`` (a
+    multiple of 16) takes the place of :func:`row_tile`'s pick, for the tests
+    and the probe: a row's result does not depend on it."""
     M, K = x.shape
     L, E, _, N = w.shape
-    tm = _row_tile(M)
+    tm = tile_rows or row_tile(M, E)
     m_pad = -(-M // tm) * tm
     if m_pad != M:
         x = jnp.pad(x, ((0, m_pad - M), (0, 0)))

@@ -166,7 +166,10 @@ _BLOCK_SERIES = ("llm_block_row_forwards_total",
 #: compacted list of a share's assignments (``models/llama.py: moe_experts``)
 #: and the expert layers run, and the held assignments of decode steps alone
 #: (nemotron_h: over the decode-only touched, the rows a touched expert
-#: multiplies). Beside them the held experts offered, and
+#: has), and the rows ONE grouped matmul of an expert layer multiplied (its
+#: work items x the row tile ``ops/grouped_matmul.py: row_tile`` picked: over
+#: the experts touched, the rows the MXU is fed for an expert). Beside them
+#: the held experts offered, and
 #: touched and offered once more over the forwards of decode chunks alone: a
 #: mixed step's prompt chunk touches nearly every expert, a decode step's
 #: rows do not
@@ -175,7 +178,8 @@ _MOE_SERIES_OF = {"assignments": "llm_moe_assignments_total",
                   "touched": "llm_moe_experts_touched_total",
                   "compact": "llm_moe_layer_forwards_compact_total",
                   "forwards": "llm_moe_layer_forwards_total",
-                  "decode_local": "llm_moe_decode_assignments_local_total"}
+                  "decode_local": "llm_moe_decode_assignments_local_total",
+                  "item_rows": "llm_moe_item_rows_total"}
 _MOE_DRAIN_SERIES = ("llm_moe_experts_offered_total",
                      "llm_moe_decode_experts_touched_total",
                      "llm_moe_decode_experts_offered_total")

@@ -127,6 +127,32 @@ def test_ragged_kernel_compiles_at_mistral_7b_shapes(one_chip, q_width):
         one_chip((_B,), jnp.int32), one_chip((), jnp.int32))
 
 
+@pytest.mark.parametrize("rows,experts,width,inner", [
+    (512, 128, 2048, 768),      # sdar decode forward: tile 64
+    (128, 12, 7168, 2048),      # kimi decode step: tile 64, 4 column tiles
+    (1408, 128, 1024, 2688),    # nemotron decode step: tile 64
+    (5760, 72, 4096, 768),      # granite 512-token mixed step: tile 128
+], ids=["sdar-decode", "kimi-decode", "nemotron-decode", "granite-mixed"])
+def test_grouped_matmul_compiles_at_the_cells_calls(one_chip, rows, experts,
+                                                    width, inner):
+    """The expert kernel at the row tile its rule picks for the cells' calls
+    (``row_tile``: 64 where the mean expert has at most 64 rows), int8 blocks
+    out of a stack of two layers, into the rows' width and back; the Mosaic
+    call keeps the name the benchmark's trace reader looks for."""
+    from cyberfabric_core_tpu.ops.grouped_matmul import grouped_matmul
+
+    for k, n in ((width, inner), (inner, width)):
+        text = jax.jit(
+            lambda x, w, s, sizes, layer: grouped_matmul(
+                x, w, s, sizes, layer, interpret=False)).lower(
+            one_chip((rows, k), jnp.bfloat16),
+            one_chip((2, experts, k, n), jnp.int8),
+            one_chip((2, experts, n), jnp.float32),
+            one_chip((experts,), jnp.int32),
+            one_chip((), jnp.int32)).compile().as_text()
+        assert "tpu_custom_call" in text and "grouped_matmul" in text
+
+
 # ---- the compile cache and chip_smoke.py without a chip
 
 @pytest.mark.parametrize("case", ["from_env", "fixed_path", "cpu"])

@@ -331,8 +331,9 @@ def _moe_experts_before(flat, top_idx, gates, moe, cfg, layer):
 
 
 #: a thin share of the tiny preset: 3 of 64 experts, so that four times the
-#: uniform expectation is one ROW_TILE (128) of a decode-shaped step's 256
-#: assignments and of a mixed-shaped step's 640
+#: uniform expectation is one ROW_TILE (128, the capacity's unit; the kernel
+#: walks it in two tiles of 64) of a decode-shaped step's 256 assignments and
+#: of a mixed-shaped step's 640
 THIN = dataclasses.replace(get_config("tiny-kimi-share4"), num_experts=64,
                            experts_held=3, expert_offset=4)
 
@@ -405,6 +406,33 @@ def test_the_capacity_is_a_function_of_shapes_alone():
     for name in ("tiny-kimi", "tiny-kimi-share4", "tiny-sdar",
                  "tiny-granite-hybrid", "kimi-k2.5"):
         assert moe_capacity(4608, get_config(name)) == 4608, name
+
+
+@pytest.mark.parametrize("held", ["none", "one", "capacity", "capacity+1"])
+def test_a_shares_item_rows_are_those_of_the_branch_that_ran(held):
+    """``moe_item_rows`` (the counter behind the benchmark's
+    ``moe_item_rows_per_touched_expert``): the work items of ONE grouped
+    matmul times its row tile, for the rows the layer ran over: the
+    compacted 128 at a tile of 64 (43 rows a held expert), or, past the
+    capacity, all 256 at 128 (85). The capacity itself stays whole
+    ``ROW_TILE``s whatever tile the kernel takes."""
+    from cyberfabric_core_tpu.models.llama import moe_capacity, moe_item_rows
+    from cyberfabric_core_tpu.ops.grouped_matmul import group_items, row_tile
+
+    n, K, El = 64, THIN.experts_per_token, THIN.experts_local
+    capacity = moe_capacity(n * K, THIN)
+    assert (row_tile(capacity, El), row_tile(n * K, El)) == (64, 128)
+    count = {"none": 0, "one": 1, "capacity": capacity,
+             "capacity+1": capacity + 1}[held]
+    top_idx = _top_idx_with(np.random.default_rng(count), THIN, n, count)
+    local = np.asarray(top_idx).reshape(-1) - THIN.expert_offset
+    sizes = np.bincount(local[(local >= 0) & (local < El)], minlength=El)
+    assert sizes.sum() == count
+    rows = capacity if count <= capacity else n * K
+    tile = row_tile(rows, El)
+    *_, real = group_items(jnp.asarray(sizes, jnp.int32), rows, tile)
+    assert int(moe_item_rows(top_idx, THIN)) == int(real[0]) * tile
+    assert (int(real[0]) > 0) == (count > 0)
 
 
 @pytest.mark.parametrize("name", ["tiny-sdar", "tiny-granite-hybrid",

@@ -10,11 +10,13 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from cyberfabric_core_tpu.models import get_config, llama
 from cyberfabric_core_tpu.ops import ssd
-from cyberfabric_core_tpu.ops.grouped_matmul import (BLOCK_BYTES, _col_tile,
-                                                     group_items)
+from cyberfabric_core_tpu.ops.grouped_matmul import (BLOCK_BYTES, ROW_TILE,
+                                                     _col_tile, group_items,
+                                                     row_tile)
 from cyberfabric_core_tpu.ops.paged_attention import (
     decode_page_group, decode_work_list, paged_decode_attention,
     ragged_paged_attention)
@@ -39,7 +41,8 @@ def test_the_tiles_follow_from_the_shapes_and_the_others_keep_theirs():
     falcon's 8); an expert's 1024 x 2688 and 2688 x 1024 int8 matrices are one
     block each, no column tiles (kimi's 7168 x 2048 keeps its 512, sdar's and
     granite's stay whole); a decode step's 1408 sorted assignments over 128
-    held experts are 11 row tiles + 127 = 138 work items; a page row of 2 kv
+    held experts are 22 row tiles of 64 (11 rows an expert: ``row_tile``, PR
+    43) + 127 = 149 work items; a page row of 2 kv
     heads takes 8 pages a program (mistral's 8 kv heads 4)."""
     assert ssd._head_block(128, 8, 4 * 64 * 128) == 16          # nemotron
     assert ssd._head_block(128, 1, 4 * 64 * 128) == 32          # granite
@@ -50,10 +53,49 @@ def test_the_tiles_follow_from_the_shapes_and_the_others_keep_theirs():
     assert _col_tile(4096, 768, 1) == 768                       # granite
     assert _col_tile(2048, 768, 1) == 768                       # sdar
     sizes = jnp.zeros((128,), jnp.int32).at[:100].set(3)
-    tile, *_ = group_items(sizes, 1408, 128)
-    assert tile.shape == (11 + 127,)
+    assert row_tile(1408, 128) == 64
+    tile, *_ = group_items(sizes, 1408, row_tile(1408, 128))
+    assert tile.shape == (22 + 127,)
     assert decode_page_group(64, 2 * 128, 2, 32, 64) == 8       # nemotron
     assert decode_page_group(64, 8 * 128, 2, 32, 64) == 4       # mistral
+
+
+#: the grouped matmul calls the benchmark's four MoE cells run (rows of the
+#: sorted assignments, experts held) and the row tile each gets
+CELL_CALLS = {
+    "sdar decode forward: 64 tokens top-8 over 128": (512, 128, 64),
+    "granite decode step: 64 rows top-10 over 72": (640, 72, 64),
+    "kimi decode step: the compacted 128 rows over 12 held": (128, 12, 64),
+    "nemotron decode step: 64 rows top-22 over 128 held": (1408, 128, 64),
+    "granite mixed step: 576 tokens top-10 over 72": (5760, 72, 128),
+    "nemotron mixed step: 576 tokens top-22 over 128 held": (12672, 128, 128),
+    "kimi mixed step: the compacted 640 rows over 12 held": (640, 12, 64),
+}
+
+
+@pytest.mark.parametrize("call", list(CELL_CALLS))
+def test_the_row_tile_follows_from_the_calls_shapes_alone(call):
+    """``row_tile(M, E)``: 64 rows where the mean group has at most 64
+    (every decode step of the four cells: 4-11 rows an expert; kimi's
+    compacted mixed step: 53), 128 above (a 512-token mixed step of granite
+    and nemotron: 80 and 99), as the kernel-alone probe read them (PERF.md
+    section 5); the work items' count follows from the tile."""
+    m, groups, tile = CELL_CALLS[call]
+    assert row_tile(m, groups) == tile
+    items, *_ = group_items(jnp.zeros((groups,), jnp.int32), m, tile)
+    assert items.shape == (m // tile + groups - 1,)
+
+
+def test_the_row_tile_of_calls_no_cell_runs():
+    """A call of fewer rows than the tile is one tile of its rows (whole
+    sublane tiles of 16); the boundary is the mean group's 64 rows; the
+    tile never passes ``ROW_TILE``, which stays the unit of
+    ``moe_capacity``."""
+    assert [row_tile(m, 8) for m in (1, 16, 17, 40, 64, 65)] == \
+        [16, 16, 32, 48, 64, 64]
+    assert row_tile(64 * 72, 72) == 64 and row_tile(64 * 72 + 1, 72) == 128
+    assert row_tile(1 << 20, 2) == ROW_TILE == 128
+    assert row_tile(100, 1) == 112            # one group: one tile
 
 
 def test_state_kernel_at_several_groups_of_sixteen_heads():

@@ -10,16 +10,19 @@ greedy answer is the same whatever rides beside it, arrives mid-block of it,
 or preempts it with a block open."""
 
 import dataclasses
+import json
 import threading
 import time
+from pathlib import Path
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import sdar_reference, sdar_weights
-from cyberfabric_core_tpu.models import get_config
+from benchmark import layer_readers, sdar_reference, sdar_weights
+from cyberfabric_core_tpu.models import get_config, sdar_moe
 from cyberfabric_core_tpu.modkit.metrics import default_registry
+from cyberfabric_core_tpu.ops.grouped_matmul import row_tile
 from cyberfabric_core_tpu.runtime import EngineConfig, SamplingParams
 from cyberfabric_core_tpu.runtime.scheduler import (
     _BLOCK_SERIES, ContinuousBatchingEngine, _moe_series)
@@ -232,12 +235,13 @@ def test_the_counters_of_a_block_model():
     the round records and a request's flight record carry the same."""
     from cyberfabric_core_tpu.modkit.flight_recorder import default_recorder
 
-    series = _BLOCK_SERIES + _moe_series(("touched",))
+    series = _BLOCK_SERIES + _moe_series(sdar_moe.MOE_COUNTERS)
     before = {s: _counter(s) for s in series}
     col, sched = _run(_cfg(decode_lookahead=0), [_prompt(9, 18)],
                       max_tokens=16)
+    after = {s: _counter(s) for s in series}
     d = {s.removeprefix("llm_").removesuffix("_total"):
-         _counter(s) - before[s] for s in series}
+         after[s] - before[s] for s in series}
     assert d["block_tokens_emitted"] == 16
     assert d["blocks_committed"] == d["block_commit_row_forwards"] == 5
     # 2 leftover tokens: 2 denoise forwards, then 4 a block; one commit each
@@ -247,6 +251,25 @@ def test_the_counters_of_a_block_model():
     # the one mechanism: the decode chunks' share of both, counted apart
     assert 0 < d["moe_decode_experts_touched"] <= d["moe_experts_touched"]
     assert 0 < d["moe_decode_experts_offered"] < d["moe_experts_offered"]
+    # the rows one grouped matmul of a layer multiplied: whole tiles, at
+    # least one for every expert touched; the benchmark's
+    # ``moe_item_rows_per_touched_expert`` reads the pair through the
+    # ``counter`` kind, and nothing from a program without the series
+    tile = row_tile(16 * CFG.experts_per_token, CFG.num_experts)
+    assert d["moe_item_rows"] % 16 == 0
+    assert d["moe_item_rows"] >= tile * d["moe_experts_touched"] // 2
+    metric = json.loads((Path(__file__).resolve().parents[1] / "benchmark" /
+                         "layer_metrics/moe_item_rows_per_touched_expert.json"
+                         ).read_text())
+    args = {k: v for k, v in metric.items() if k not in ("kind", "what")}
+    names = (args["series"], args["over"])
+    assert metric["kind"] == "counter" and set(names) <= set(series)
+    ends = {"start": before, "end": after}
+    assert layer_readers.counter({"scrapes": ends}, **args) == \
+        d["moe_item_rows"] / d["moe_experts_touched"]
+    assert layer_readers.counter(
+        {"scrapes": {k: {names[1]: v[names[1]]} for k, v in ends.items()}},
+        **args) is None
     rounds = [r for r in sched.round_timings if "forwards" in r]
     assert sum(r["blocks_committed"] for r in rounds) == 5
     assert sum(r["tokens_emitted"] for r in rounds) == 16
