@@ -752,6 +752,7 @@ def engine_logits(jax, engine, ids, lengths, forced):
     import numpy as np
 
     from cyberfabric_core_tpu.models import llama
+    from cyberfabric_core_tpu.runtime.programs import serving_rope_tables
 
     cfg = engine.model_config
     page = engine.config.prefix_page_size
@@ -783,7 +784,8 @@ def engine_logits(jax, engine, ids, lengths, forced):
         return jnp.concatenate([first[None], rest])
 
     with engine._device_ctx():
-        out = jax.jit(run)(engine.params, engine.rope_tables,
+        out = jax.jit(run)(engine.params, engine._dev(serving_rope_tables(
+                               cfg, engine.config.max_seq_len)),
                            *(engine._dev(np.asarray(x, np.int32))
                              for x in (ids, lengths, forced)))
         return np.asarray(out, np.float32)

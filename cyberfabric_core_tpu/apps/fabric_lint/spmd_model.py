@@ -23,8 +23,8 @@ global picture the SH02–SH04/AK01 rules (``rules/spmd.py``) run over:
   ``NamedSharding(mesh, P(axes))`` destination ⇒ sharded(axes)). SH02
   forward-propagates the same lattice through locals to every jitted
   dispatch call;
-- a **jitted-dispatch map** per class: the ``self._X_fn = jax.jit(...)``
-  attributes whose call sites are the device boundary SH02 guards;
+- a **jitted-dispatch map** per class: the ``self._X_fn = jax.jit(...)`` or
+  ``... = step_programs(key)`` attributes whose call sites SH02 guards;
 - a **bare-upload summary** over pass 1's call graph: for every method, a
   witness chain when some call path from it reaches a destination-less
   ``jax.device_put`` — how SH02 generalizes SH01 from syntax to dataflow
@@ -142,6 +142,7 @@ _CONFIG_RECEIVERS = frozenset({
     "config", "cfg", "self.config", "self.cfg", "self._config",
 })
 _PROGRAM_BUILDER = "_build_programs"
+_PROGRAM_SOURCE = "step_programs"   # its result's parts are dispatches too
 
 #: affix match needs this much signal before "prefix_page_size" may cover
 #: key "page_size" (equality is always enough)
@@ -480,13 +481,15 @@ def _collect_dispatches_and_prov(model: SpmdModel,
             dispatches: dict[str, int] = {}
             for sub in ast.walk(node):
                 if isinstance(sub, ast.Assign) and \
-                        isinstance(sub.value, ast.Call) and \
-                        _is_jit_expr(sub.value.func):
+                        isinstance(sub.value, ast.Call) and (
+                            _is_jit_expr(sub.value.func)
+                            or dotted_name(sub.value.func) == _PROGRAM_SOURCE):
                     for t in sub.targets:
-                        if isinstance(t, ast.Attribute) and \
-                                isinstance(t.value, ast.Name) and \
-                                t.value.id == "self":
-                            dispatches.setdefault(t.attr, sub.lineno)
+                        for leaf in getattr(t, "elts", [t]):
+                            if isinstance(leaf, ast.Attribute) and \
+                                    isinstance(leaf.value, ast.Name) and \
+                                    leaf.value.id == "self":
+                                dispatches.setdefault(leaf.attr, sub.lineno)
             if dispatches:
                 model.dispatch_attrs[key] = dispatches
             if key in model.mesh_classes:

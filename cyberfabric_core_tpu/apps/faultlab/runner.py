@@ -145,12 +145,18 @@ def _drive_engine(cfg, load, faults: list[dict],
 
     for f in faults:
         fp.arm(f["point"], f["spec"])
+    if not stagger_s:
+        # the whole load is queued before the loop's first pass: what an armed
+        # fault meets first must not depend on how long the first round takes
+        engine.start = lambda: None
     try:
         for i, (prompt, max_tokens) in enumerate(load):
             engine.submit(prompt, SamplingParams(max_tokens=max_tokens),
                           mk_emit(i))
             if stagger_s:
                 time.sleep(stagger_s)  # fabric-lint: waive AS01 reason=scenario driver thread staggering arrivals; no event loop in this process path
+        engine.__dict__.pop("start", None)
+        engine.start()
         done.wait(_DRAIN_TIMEOUT_S)
     finally:
         for f in faults:
@@ -1004,12 +1010,19 @@ def _run_replica_crash_loop_scenario(spec: dict) -> ScenarioResult:
     pool = _lifecycle_pool(spec, cfg, n_replicas)
     lc = pool.lifecycle
     problems: dict[str, list[str]] = {}
-    streams, pool, _errs = _drive_pool(
-        cfg, load, list(spec.get("faults", [])), n_replicas, pool=pool)
-    # the armed replicas.rebuild rejected every attempt: max_strikes
-    # failures → benched (the crash-loop backstop). Faults are already
-    # disarmed by _drive_pool's finally.
-    benched = _wait_for(lambda: lc.counts()["benched"] >= 1, 20.0)
+    # replicas.rebuild stays armed past the load's end (which may come before
+    # the second attempt): max_strikes failures → benched, the backstop
+    faults = list(spec.get("faults", []))
+    rebuild = [f for f in faults if f["point"] == "replicas.rebuild"]
+    for f in rebuild:
+        fp.arm(f["point"], f["spec"])
+    try:
+        streams, pool, _errs = _drive_pool(
+            cfg, load, [f for f in faults if f not in rebuild], n_replicas,
+            pool=pool)
+        benched = _wait_for(lambda: lc.counts()["benched"] >= 1, 20.0)
+    finally:
+        fp.disarm("replicas.rebuild")
     problems["crash_loop_benched"] = [] if benched else [
         f"replica never benched: {lc.status()}"]
     problems["rebuild_retries_backed_off"] = (

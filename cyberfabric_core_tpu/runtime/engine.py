@@ -145,17 +145,14 @@ class EngineConfig:
     #: 0.0 = never disable. Deterministic per stream and acceptance-checked,
     #: so the gate can only ever change speed, never token values.
     spec_min_accept: float = 0.0
-    #: continuous scheduler: lookahead DEPTH — up to this
-    #: many decode chunks are kept in flight beyond the one being drained
-    #: (an epoch ring). Each chunk chains off device-resident state, so the
-    #: host emit loop overlaps N device chunks instead of alternating.
-    #: Termination (stop tokens / max-tokens / window) is detected INSIDE
-    #: the decode program via a device-resident finished mask, so a finish
-    #: freezes its row on-device and the ring survives it; admissions,
-    #: resumes and preemptions still discard the stale ring suffix and fall
-    #: back to a synchronous round, so emitted streams are byte-identical
-    #: across any depth (0 = fully synchronous; legacy bools still parse:
-    #: True ≡ the default depth, False ≡ 0).
+    #: continuous scheduler: lookahead DEPTH — up to this many decode chunks
+    #: are kept in flight beyond the one being drained (an epoch ring), each
+    #: chained off device-resident state, so the host's emit overlaps N device
+    #: chunks. The HOST's number: no program reads it (programs.ProgramKey).
+    #: A finish freezes its row inside the decode program and the ring
+    #: survives it; admissions and resumes WAIT for an empty ring (PR 30); a
+    #: preemption or a host-detected stop discards the stale suffix: streams
+    #: are byte-identical at any depth (0 = synchronous; legacy bools parse).
     decode_lookahead: int = 2
     #: device-side stop-token matching width: per-slot stop ids live in a
     #: [n_slots, device_stop_width] device array (-1 padded). A request whose

@@ -164,6 +164,19 @@ def client_hub():
 
 
 @pytest.fixture()
+def retrace():
+    """``runtime/programs.py`` keeps a key's step programs for the process.
+    A test that asserts a compile is seen, or patches what a program reads
+    while it is traced, starts with none kept; called after a patch, the
+    next engine of the key traces anew; what the test traced goes with it."""
+    from cyberfabric_core_tpu.runtime.programs import step_programs
+
+    step_programs.cache_clear()
+    yield step_programs.cache_clear
+    step_programs.cache_clear()
+
+
+@pytest.fixture()
 def fresh_registry():
     """Isolate module registrations per test."""
     # ensure the full decorator inventory exists BEFORE saving — otherwise a

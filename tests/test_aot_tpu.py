@@ -311,7 +311,7 @@ def _decode_operands(sds, eng, block: int = 0) -> tuple:
     """``paged_decode_chunk`` after the cache operands: the host-owned rows
     (page table and control rows, one block), the last tokens (or the open
     blocks), lengths, active, finished, keys."""
-    from cyberfabric_core_tpu.runtime.scheduler import _CTL
+    from cyberfabric_core_tpu.runtime.programs import _CTL
 
     n = eng.n_slots
     return (sds((n, eng.pmax + _CTL + eng.config.device_stop_width),
@@ -324,7 +324,7 @@ def _decode_operands(sds, eng, block: int = 0) -> tuple:
 def _mixed_operands(sds, eng, width: int, block: int = 0) -> tuple:
     """``mixed_step`` after the cache operands: the rows, the dispatch's
     flat lane block at ``width``, then what the device advances."""
-    from cyberfabric_core_tpu.runtime.scheduler import LANE_ROWS, lane_words
+    from cyberfabric_core_tpu.runtime.programs import LANE_ROWS, lane_words
 
     rows, last, lens, _, fin, keys = _decode_operands(sds, eng, block)
     return (rows, sds((lane_words(eng.n_slots, block, LANE_ROWS, width),),
@@ -367,7 +367,6 @@ def test_scheduler_programs_compile_for_v5e_at_mistral_7b(tp):
 
     from cyberfabric_core_tpu.models import decoder_module, get_config
     from cyberfabric_core_tpu.ops.platform import compiled_kernels
-    from cyberfabric_core_tpu.ops.rope import rope_frequencies
     from cyberfabric_core_tpu.parallel.feasibility import V5E_HBM_BYTES
     from cyberfabric_core_tpu.parallel.mesh import MeshConfig, build_mesh
     from cyberfabric_core_tpu.parallel.sharding import (
@@ -388,8 +387,6 @@ def test_scheduler_programs_compile_for_v5e_at_mistral_7b(tp):
     eng._model, eng._has_state, eng._block = decoder_module(cfg), False, 0
     eng._moe_counters, eng.n_slots, eng.pmax = (), n, _PMAX
     eng.spec_k, eng._spec_w = 0, 1
-    eng.rope_tables = rope_frequencies(
-        cfg.head_dim, max(cfg.max_position, max_seq), cfg.rope_theta)
     if tp > 1:
         eng.mesh = eng._attn_mesh = build_mesh(MeshConfig(dp=1, tp=tp),
                                                topo.devices[:tp])
@@ -448,7 +445,6 @@ def test_scheduler_programs_compile_for_v5e_at_falcon_h1():
 
     from cyberfabric_core_tpu.models import decoder_module, get_config
     from cyberfabric_core_tpu.ops.platform import compiled_kernels
-    from cyberfabric_core_tpu.ops.rope import rope_frequencies
     from cyberfabric_core_tpu.parallel.feasibility import V5E_HBM_BYTES
     from cyberfabric_core_tpu.parallel.sharding import abstract_params
     from cyberfabric_core_tpu.runtime.engine import EngineConfig
@@ -466,7 +462,6 @@ def test_scheduler_programs_compile_for_v5e_at_falcon_h1():
     eng._moe_counters, eng.n_slots, eng.pmax = (), n, max_seq // _PAGE
     eng.spec_k, eng._spec_w = 0, 1
     eng.mesh = eng._attn_mesh = None
-    eng.rope_tables = rope_frequencies(cfg.head_dim, max_seq, cfg.rope_theta)
     here = SingleDeviceSharding(topo.devices[0])
 
     def sds(shape, dtype):
@@ -534,7 +529,6 @@ def test_scheduler_programs_compile_for_v5e_at_sdar_moe():
 
     from cyberfabric_core_tpu.models import decoder_module, get_config
     from cyberfabric_core_tpu.ops.platform import compiled_kernels
-    from cyberfabric_core_tpu.ops.rope import rope_frequencies
     from cyberfabric_core_tpu.parallel.feasibility import V5E_HBM_BYTES
     from cyberfabric_core_tpu.parallel.sharding import abstract_params
     from cyberfabric_core_tpu.runtime.engine import EngineConfig
@@ -554,7 +548,7 @@ def test_scheduler_programs_compile_for_v5e_at_sdar_moe():
     eng.n_slots, eng.pmax = n, max_seq // _PAGE
     eng.spec_k, eng._spec_w = 0, 1
     eng.mesh = eng._attn_mesh = None
-    eng.rope_tables = rope_frequencies(cfg.head_dim, max_seq, cfg.rope_theta)
+    eng.pool = types.SimpleNamespace(cache_operands=lambda: (None, None))
     here = SingleDeviceSharding(topo.devices[0])
 
     def sds(shape, dtype):
@@ -641,7 +635,6 @@ def test_scheduler_programs_compile_for_v5e_at_kimi_k2():
 
     from cyberfabric_core_tpu.models import decoder_module, get_config
     from cyberfabric_core_tpu.ops.platform import compiled_kernels
-    from cyberfabric_core_tpu.ops.rope import rope_tables
     from cyberfabric_core_tpu.parallel.feasibility import V5E_HBM_BYTES
     from cyberfabric_core_tpu.parallel.sharding import abstract_params
     from cyberfabric_core_tpu.runtime.engine import EngineConfig
@@ -660,7 +653,6 @@ def test_scheduler_programs_compile_for_v5e_at_kimi_k2():
     eng.n_slots, eng.pmax = n, max_seq // _PAGE
     eng.spec_k, eng._spec_w = 0, 1
     eng.mesh = eng._attn_mesh = None
-    eng.rope_tables = rope_tables(cfg, max_seq)
     here = SingleDeviceSharding(topo.devices[0])
 
     def sds(shape, dtype):
@@ -736,7 +728,6 @@ def test_scheduler_programs_compile_for_v5e_at_granite_hybrid():
 
     from cyberfabric_core_tpu.models import decoder_module, get_config
     from cyberfabric_core_tpu.ops.platform import compiled_kernels
-    from cyberfabric_core_tpu.ops.rope import rope_tables
     from cyberfabric_core_tpu.parallel.feasibility import V5E_HBM_BYTES
     from cyberfabric_core_tpu.parallel.sharding import abstract_params
     from cyberfabric_core_tpu.runtime.engine import EngineConfig
@@ -763,7 +754,6 @@ def test_scheduler_programs_compile_for_v5e_at_granite_hybrid():
     eng.n_slots, eng.pmax = n, max_seq // _PAGE
     eng.spec_k, eng._spec_w = 0, 1
     eng.mesh = eng._attn_mesh = None
-    eng.rope_tables = rope_tables(cfg, max_seq)
     here = SingleDeviceSharding(topo.devices[0])
 
     def sds(shape, dtype):
@@ -846,7 +836,6 @@ def test_scheduler_programs_compile_for_v5e_at_nemotron_h():
 
     from cyberfabric_core_tpu.models import decoder_module, get_config
     from cyberfabric_core_tpu.ops.platform import compiled_kernels
-    from cyberfabric_core_tpu.ops.rope import rope_tables
     from cyberfabric_core_tpu.parallel.feasibility import V5E_HBM_BYTES
     from cyberfabric_core_tpu.parallel.sharding import abstract_params
     from cyberfabric_core_tpu.runtime.engine import EngineConfig
@@ -874,7 +863,6 @@ def test_scheduler_programs_compile_for_v5e_at_nemotron_h():
     eng.n_slots, eng.pmax = n, max_seq // _PAGE
     eng.spec_k, eng._spec_w = 0, 1
     eng.mesh = eng._attn_mesh = None
-    eng.rope_tables = rope_tables(cfg, max_seq)
     here = SingleDeviceSharding(topo.devices[0])
 
     def sds(shape, dtype):

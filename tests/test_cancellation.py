@@ -47,6 +47,16 @@ def _cfg(**over):
     return EngineConfig(**base)
 
 
+def _slow(point="scheduler.readback"):
+    """Every decode round (or, ``scheduler.prefill_chunk``, every chunk of a
+    prompt) takes at least 50 ms. A deadline test states how slow the server
+    is; it must not count on a first compile to be that (a process that
+    holds the step programs has none)."""
+    from cyberfabric_core_tpu.modkit import failpoints as fp
+
+    return fp.scoped(point, "delay(0.05)")
+
+
 class _Collector:
     def __init__(self, n):
         self.tokens = {i: [] for i in range(n)}
@@ -124,6 +134,7 @@ def test_cancel_unknown_id_is_noop():
     _assert_clean(sched)
 
 
+@_slow()       # 200 tokens: 50 rounds, 2.5 s at the least
 def test_deadline_lapses_mid_decode():
     """An admitted stream whose deadline passes mid-generation gets a
     'deadline' terminal within a round — partial output, slot freed."""
@@ -143,6 +154,7 @@ def test_deadline_lapses_mid_decode():
     _assert_clean(sched)
 
 
+@_slow()       # the runner is busy for 2.5 s at the least
 def test_deadline_admission_estimate_rejects_unfillable_budget():
     """White-box: while the engine is BUSY and the best observed prefill
     rate says this request cannot possibly prefill inside its remaining
@@ -155,11 +167,11 @@ def test_deadline_admission_estimate_rejects_unfillable_budget():
         sched.submit([5] * 8, SamplingParams(max_tokens=200),
                      col.emit_for(0), request_id="runner")
         deadline = time.monotonic() + 60
-        while not (sched.active_slots or sched._prefill_slots) \
-                and time.monotonic() < deadline:
+        while not col.tokens[0] and time.monotonic() < deadline:
             time.sleep(0.005)
         # pin the estimate: 1 tok/s → a 40-token prompt ≈ 40 s ≫ 2 s budget
-        # (the runner's own fast prefill sample must not win the max)
+        # (the runner's own fast prefill sample must not win the max: it is
+        # in by the runner's first token)
         sched._prefill_rates.clear()
         sched._prefill_rates.append(1.0)
         sched.submit([9] * 40, SamplingParams(max_tokens=10),
@@ -387,6 +399,7 @@ def test_worker_deadline_maps_to_408_when_never_started():
     assert (status, code) == (408, "request_timeout")
 
 
+@_slow("scheduler.prefill_chunk")     # 20 chunks: 1 s at the least
 def test_worker_deadline_maps_to_504_when_admitted_but_no_output():
     """A deadline lapsing AFTER admission (mid-chunked-prefill — the slot
     was claimed, the server just ran out of time) but before any output
@@ -421,6 +434,7 @@ def test_worker_deadline_maps_to_504_when_admitted_but_no_output():
     assert (status, code) == (504, "deadline_exceeded")
 
 
+@_slow()       # the window holds 30 rounds: 1.5 s at the least
 def test_worker_mid_stream_deadline_finishes_with_reason():
     """A deadline lapsing after output started closes the stream with
     finish_reason=deadline_exceeded and honest usage (no re-status on an

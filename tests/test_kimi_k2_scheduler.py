@@ -297,7 +297,8 @@ def test_a_served_share_counts_its_compact_expert_layers():
                                  **args) is None
 
 
-def test_an_overflowing_step_takes_every_row_and_is_counted(monkeypatch):
+def test_an_overflowing_step_takes_every_row_and_is_counted(monkeypatch,
+                                                            retrace):
     """A thin share (3 of 64 experts) whose router is biased onto the held
     experts: a mixed step of 4 + 64 tokens holds 3 x 68 = 204 assignments a
     layer where the capacity is 128, so its expert layers take every row (the
@@ -339,4 +340,5 @@ def test_an_overflowing_step_takes_every_row_and_is_counted(monkeypatch):
     assert d["llm_moe_assignments_local_total"] * 4 == \
         d["llm_moe_assignments_total"] * 3
     monkeypatch.setattr(llama, "moe_capacity", lambda n, cfg: n)
+    retrace()
     assert served()[0] == tokens and len(tokens) == 9

@@ -204,7 +204,7 @@ def test_pool_reaches_the_kernel_uncopied(step):
 
 def _mixed_step_operands(eng, width):
     """``mixed_step``'s operands with an empty lane of ``width``."""
-    from cyberfabric_core_tpu.runtime.scheduler import LANE_ROWS, lane_words
+    from cyberfabric_core_tpu.runtime.programs import LANE_ROWS, lane_words
 
     lane = jnp.zeros((lane_words(eng.n_slots, eng._block, LANE_ROWS,
                                  width),), jnp.int32)

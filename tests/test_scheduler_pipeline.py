@@ -853,15 +853,18 @@ def test_cancel_while_suspended_never_resurrects():
     sched = ContinuousBatchingEngine(cfg, seed=0)
     col = _Collector(2)
     try:
-        # one forced MemoryError on a page-chain growth → preempt-to-host
-        fp.arm("scheduler.page_alloc", "1*raise(MemoryError)")
+        # forced MemoryErrors on page-chain growth until one lands on the
+        # capacity sweep → preempt-to-host (a ring extension that meets one
+        # only caps the ring: which comes first is a matter of timing)
+        fp.arm("scheduler.page_alloc", "raise(MemoryError)")
         sched.submit(prompts[0], samplings[0], col.emit_for(0),
                      request_id="keeper")
         sched.submit(prompts[1], samplings[1], col.emit_for(1),
                      request_id="parked")
         deadline = time.monotonic() + 60.0
         while sched.preemptions == 0 and time.monotonic() < deadline:
-            time.sleep(0.005)
+            time.sleep(0.001)
+        fp.disarm("scheduler.page_alloc")
         assert sched.preemptions >= 1, "injected pressure never preempted"
         # cancel whichever request is currently suspended
         victim = None

@@ -108,7 +108,7 @@ def _prompts(seed=5):
 
 
 @pytest.mark.parametrize("model", MODELS)
-def test_serving_compiles_only_the_step_programs(model):
+def test_serving_compiles_only_the_step_programs(model, retrace):
     """Admission, chunks, the flip, decode, a finish and the slot's next
     owner: from the engine's build on, nothing is compiled but
     ``mixed_step`` (a width each) and ``paged_decode_chunk``; no loose
@@ -134,7 +134,7 @@ def test_serving_compiles_only_the_step_programs(model):
     assert _counter("llm_control_rows_uploads_total") >= uploads + 3
 
 
-def test_a_resume_takes_one_program():
+def test_a_resume_takes_one_program(retrace):
     """A preempted row comes back through ``restore_row``, one program, with
     its stream bit for bit (a greedy row and a seeded sampled one): beside
     the step programs the scheduler compiles that and nothing else."""
