@@ -14,6 +14,11 @@ from dataclasses import dataclass
 from typing import Optional
 
 
+#: the kinds of ``ModelConfig.layer_types`` whose layers hold recurrent state
+#: (a row of the state slab and a conv tail) and write no page
+STATE_KINDS = ("mamba", "kda")
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     name: str
@@ -27,6 +32,9 @@ class ModelConfig:
     #: shared MLP after each) | "nemotron_h" (a stack whose every layer is ONE
     #: sub-layer, ``layer_types``: a Mamba-2 mixer, attention without rotary,
     #: or routed experts that work in a latent beside a shared expert) |
+    #: "solar_open2" (a stack of linear-attention layers under a gated delta
+    #: rule, ``kda`` of ``layer_types``, and gated attention layers without
+    #: rotary, sigmoid-routed experts beside a shared expert after each) |
     #: "bert" (encoder)
     architecture: str
     vocab_size: int
@@ -129,13 +137,18 @@ class ModelConfig:
     ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
     mlp_multipliers: tuple = (1.0, 1.0)
     # granite_hybrid (model_type granitemoehybrid) and nemotron_h, names as
-    # published. The kind of every layer, "mamba", "attention" or "moe"
-    # (empty: every layer is what the architecture's one block is): a mamba
-    # layer holds recurrent state and writes no page, an attention layer
-    # holds pages and no state, a moe layer (experts ALONE, no mixer in front
-    # of them: nemotron_h) neither, so the page pool has ``kv_layers``
-    # layers, the state slab ``state_layers`` and the expert stack
-    # ``moe_layers``. Whether a mamba or attention layer ALSO holds an expert
+    # published. The kind of every layer, "mamba", "attention", "moe" or
+    # "kda" (empty: every layer is what the architecture's one block is): a
+    # mamba layer holds recurrent state and writes no page, an attention
+    # layer holds pages and no state, a moe layer (experts ALONE, no mixer in
+    # front of them: nemotron_h) neither, and a kda layer (solar_open2:
+    # linear attention under a gated delta rule) holds state and no page as
+    # a mamba layer does: ``ssm_heads`` heads whose state is ``[ssm_head_dim
+    # keys, ssm_state values]`` (``linear_attn_config``'s num_heads and
+    # head_dim, twice), a conv tail of ``ssm_conv - 1`` inputs over q, k AND
+    # v, the WY form in chunks of ``ssm_chunk``. So the page pool has
+    # ``kv_layers`` layers, the state slab ``state_layers`` and the expert
+    # stack ``moe_layers``. Whether a mamba or attention layer ALSO holds an expert
     # layer is what the architecture says (granite_hybrid: every one does),
     # not what ``layer_types`` implies
     layer_types: tuple = ()
@@ -154,6 +167,12 @@ class ModelConfig:
     #: dense down-projection of the hidden and ONE up-projection (0: on the
     #: hidden itself); the router and the shared expert see the full hidden
     moe_latent_size: int = 0
+    # solar_open2, names as published. use_gqa_gate: an attention layer's
+    # output is multiplied by ``sigmoid(x W_gate)`` before ``W_o``
+    use_gqa_gate: bool = False
+    #: ``β = 2 sigmoid(.)`` (True) or ``sigmoid(.)``: with the factor 2 the
+    #: eigenvalue of ``I − β k kᵀ`` lies in (−1, 1)
+    kda_allow_neg_eigval: bool = False
     # bert-family extras
     layer_norm_eps: float = 1e-12
     type_vocab_size: int = 2
@@ -176,12 +195,13 @@ class ModelConfig:
                 f"the router's {self.num_experts}")
         if self.layer_types and (
                 len(self.layer_types) != self.num_layers
-                or set(self.layer_types) - {"mamba", "attention", "moe"}):
+                or set(self.layer_types) - {"mamba", "attention", "moe",
+                                            "kda"}):
             raise ValueError(
                 f"{self.name}: layer_types names {len(self.layer_types)} "
                 f"layers of kinds {sorted(set(self.layer_types))} for "
                 f"num_layers {self.num_layers} (kinds: mamba, attention, "
-                "moe)")
+                "moe, kda)")
         if self.block_length > 1 and (
                 self.block_length % self.denoising_steps
                 or not 0 <= self.mask_token_id < self.vocab_size):
@@ -199,7 +219,7 @@ class ModelConfig:
         """The router's weights stay float32 whatever the activations' dtype
         (a score decides WHICH experts run, not only how much)."""
         return self.architecture in ("sdar_moe", "kimi_k2", "granite_hybrid",
-                                     "nemotron_h")
+                                     "nemotron_h", "solar_open2")
 
     @property
     def is_latent(self) -> bool:
@@ -261,11 +281,11 @@ class ModelConfig:
 
     @property
     def state_layers(self) -> int:
-        """Layers that hold recurrent state: the state slab's leading
-        dimension."""
+        """Layers that hold recurrent state, of either kind that does: the
+        state slab's leading dimension."""
         if not self.layer_types:
             return self.num_layers if self.has_state else 0
-        return self.layer_types.count("mamba")
+        return sum(self.layer_types.count(k) for k in STATE_KINDS)
 
     def cut_to(self, layers: int, name: str | None = None) -> "ModelConfig":
         """The first ``layers`` layers of this configuration (a pipeline
@@ -292,7 +312,10 @@ class ModelConfig:
 
     @property
     def ssm_conv_dim(self) -> int:
-        """Channels the mixer's depthwise conv runs over: x, B and C."""
+        """Channels the mixer's depthwise conv runs over: x, B and C of a
+        Mamba-2 mixer; q, k and v of a kda layer."""
+        if "kda" in self.layer_types:
+            return self.ssm_heads * (2 * self.ssm_head_dim + self.ssm_state)
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
@@ -307,14 +330,44 @@ class ModelConfig:
                      + (self.ssm_conv - 1) * self.ssm_conv_dim)
         return 4 * self.state_layers * per_layer
 
+    @property
+    def expert_width(self) -> int:
+        """Width of one routed expert's MLP."""
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def shared_width(self) -> int:
+        """Width of the MLP every token runs beside the routed experts."""
+        return (self.shared_intermediate_size
+                or self.shared_experts * self.moe_intermediate_size)
+
+    def kda_matrices(self) -> dict[str, tuple[int, int]]:
+        """(rows, columns) of a kda layer's matrices by the names the
+        parameter tree gives them: q, k, v, o, the decay's and the output
+        gate's low-rank pairs (rank = the head size: ``kda_use_full_proj``
+        false), β."""
+        h, r = self.hidden_size, self.ssm_head_dim
+        dk, dv = self.ssm_heads * self.ssm_head_dim, \
+            self.ssm_heads * self.ssm_state
+        return {"wq": (h, dk), "wk": (h, dk), "wv": (h, dv), "wo": (dv, h),
+                "f_a": (h, r), "f_b": (r, dk), "g_a": (h, r), "g_b": (r, dv),
+                "w_beta": (h, self.ssm_heads)}
+
     def param_count(self) -> int:
         """Approximate parameter count (for HBM budgeting)."""
         h, i, v, l = self.hidden_size, self.intermediate_size, self.vocab_size, self.num_layers
         attn = h * (self.num_heads * self.head_dim) + 2 * h * (self.num_kv_heads * self.head_dim) \
             + (self.num_heads * self.head_dim) * h
+        if self.use_gqa_gate:
+            attn += h * self.num_heads * self.head_dim
         emb = v * h * (1 if self.tie_embeddings else 2)
         mixer = 0
-        if self.has_state:  # in/out projections, conv + bias, A, D, dt, norm
+        if "kda" in self.layer_types:
+            # the matrices; conv taps, A_log, dt_bias, the head norm's weight
+            mixer = (sum(k * n for k, n in self.kda_matrices().values())
+                     + self.ssm_conv * self.ssm_conv_dim + self.ssm_heads
+                     + self.ssm_heads * self.ssm_head_dim + self.ssm_state)
+        elif self.has_state:  # in/out projections, conv + bias, A, D, dt, norm
             mixer = (h * self.ssm_proj_dim + self.ssm_inner * h
                      + (self.ssm_conv + 1) * self.ssm_conv_dim
                      + 3 * self.ssm_heads + self.ssm_inner)
@@ -328,8 +381,14 @@ class ModelConfig:
                        + 2 * h * self.shared_intermediate_size)
             return (self.kv_layers * attn + self.state_layers * mixer
                     + self.moe_layers * experts + l * h + emb + h)
-        mlp = 3 * h * i * max(self.num_experts, 1) + h * self.num_experts \
-            + 3 * h * self.shared_intermediate_size
+        if "kda" in self.layer_types:
+            # a gated expert of three matrices, the router and its selection
+            # bias, the shared expert, after every mixer
+            mlp = (self.num_experts * (3 * h * self.expert_width + h + 1)
+                   + 3 * h * self.shared_width)
+        else:
+            mlp = 3 * h * i * max(self.num_experts, 1) + h * self.num_experts \
+                + 3 * h * self.shared_intermediate_size
         return (self.kv_layers * attn + self.state_layers * mixer
                 + l * (mlp + 2 * h) + emb + h)
 
@@ -338,10 +397,13 @@ class ModelConfig:
         holds them (``experts``: the held routed experts alone; ``vocab``:
         the held rows of embedding and head), at ``itemsize`` a weight with
         one f32 scale an output channel where ``itemsize`` is 1. For a
-        ``layer_types`` stack of one sub-layer a layer (nemotron_h)."""
-        h, i, w = self.hidden_size, self.intermediate_size, \
-            self.expert_row_width
+        ``layer_types`` stack (nemotron_h: one sub-layer a layer, an expert
+        of two matrices; solar_open2: an expert layer of gated three-matrix
+        experts after every mixer)."""
+        h, w = self.hidden_size, self.expert_row_width
+        i, shared = self.expert_width, self.shared_width
         scale = 4 if itemsize == 1 else 0
+        gated = self.hidden_act != "relu2"      # a third matrix, the gate
 
         def mat(k: int, n: int, count: int = 1) -> int:
             return count * (k * n * itemsize + n * scale)
@@ -349,17 +411,19 @@ class ModelConfig:
         dq, dkv = self.num_heads * self.head_dim, \
             self.num_kv_heads * self.head_dim
         return {
-            "mamba": self.state_layers * (
+            "mamba": self.layer_types.count("mamba") * (
                 mat(h, self.ssm_proj_dim) + mat(self.ssm_inner, h)),
+            "kda": self.layer_types.count("kda") * sum(
+                mat(k, n) for k, n in self.kda_matrices().values()),
             "attention": self.kv_layers * (
-                mat(h, dq) + 2 * mat(h, dkv) + mat(dq, h)),
+                mat(h, dq, 2 if self.use_gqa_gate else 1) + 2 * mat(h, dkv)
+                + mat(dq, h)),
             "moe_dense": self.moe_layers * (
-                mat(h, self.shared_intermediate_size)
-                + mat(self.shared_intermediate_size, h)
+                mat(h, shared, 2 if gated else 1) + mat(shared, h)
                 + (mat(h, w) + mat(w, h) if self.moe_latent_size else 0)
                 + 4 * (h + 1) * self.num_experts),
             "experts": self.moe_layers * self.experts_local * (
-                mat(w, i) + mat(i, w)),
+                mat(w, i, 2 if gated else 1) + mat(i, w)),
             "vocab": (self.vocab_rows * (h * itemsize + scale)
                       * (1 if self.tie_embeddings else 2)),
         }
@@ -642,6 +706,45 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         ssm_inner=128, ssm_heads=16, ssm_head_dim=8, ssm_state=16,
         ssm_groups=2, ssm_conv=4, ssm_chunk=8,
     ),
+    # Solar-Open2-250B, config.json as published (model_type solar_open2,
+    # 250B-A15B): 48 layers of which gqa_layers 0, 4, ..., 44 are GQA 64/8
+    # attention WITHOUT rotary (use_rope false) with a sigmoid output gate
+    # (use_gqa_gate) and the rest KDA, linear attention under a gated delta
+    # rule with a decay for every key channel (linear_attn_config: 64 heads
+    # of 128 keys and 128 values, a short conv of 4 over q, k and v;
+    # kda_allow_neg_eigval; kda_use_full_proj false: the decay's and the
+    # gate's projections are low rank). After EVERY mixer
+    # (first_k_dense_replace 0) 320 sigmoid-routed experts of 1280 top-8,
+    # gates normalised over the chosen, scale 1, beside one shared expert;
+    # untied head. intermediate_size 10240 is a dense layer's width and no
+    # layer is dense; ssm_chunk is the WY form's (not a published key)
+    "solar-open2-250b": ModelConfig(
+        name="solar-open2-250b", architecture="solar_open2",
+        vocab_size=196608, hidden_size=4096, intermediate_size=10240,
+        num_layers=48, num_heads=64, num_kv_heads=8, head_dim=128,
+        max_position=1048576, rope_theta=10000.0, rms_norm_eps=1e-5,
+        rotary=False, use_gqa_gate=True, kda_allow_neg_eigval=True,
+        num_experts=320, experts_per_token=8, moe_intermediate_size=1280,
+        shared_experts=1, routed_scaling_factor=1.0,
+        layer_types=("attention", "kda", "kda", "kda") * 12,
+        ssm_heads=64, ssm_head_dim=128, ssm_state=128, ssm_conv=4,
+        ssm_chunk=64,
+    ),
+    # CPU-test preset of the same stack: two periods of ``a k k k``, 16
+    # experts top-4 of which a chip may hold 4, 2 queries a kv head, 4 linear
+    # heads of 16 keys and 16 values, a chunk (8) shorter than the test
+    # prompts
+    "tiny-solar-open2": ModelConfig(
+        name="tiny-solar-open2", architecture="solar_open2", vocab_size=512,
+        hidden_size=64, intermediate_size=128, num_layers=8, num_heads=4,
+        num_kv_heads=2, head_dim=16, max_position=256, rope_theta=10000.0,
+        rms_norm_eps=1e-5, rotary=False, use_gqa_gate=True,
+        kda_allow_neg_eigval=True, num_experts=16, experts_per_token=4,
+        moe_intermediate_size=32, shared_experts=1,
+        routed_scaling_factor=1.0,
+        layer_types=("attention", "kda", "kda", "kda") * 2,
+        ssm_heads=4, ssm_head_dim=16, ssm_state=16, ssm_conv=4, ssm_chunk=8,
+    ),
     "bge-base-en": ModelConfig(
         name="bge-base-en", architecture="bert", vocab_size=30522, hidden_size=768,
         intermediate_size=3072, num_layers=12, num_heads=12, num_kv_heads=12,
@@ -709,6 +812,24 @@ MODEL_CONFIGS["tiny-nemotron-h-share4"] = dataclasses.replace(
     experts_held=4, expert_offset=4, vocab_held=256)
 MODEL_CONFIGS["tiny-nemotron-h-share4-8l"] = MODEL_CONFIGS[
     "tiny-nemotron-h-share4"].cut_to(8, "tiny-nemotron-h-share4-8l")
+
+
+# chip 0 of the first of 4 pipeline stages of solar-open2-250b (32 chips, 8
+# sharing each layer): layers 0-11, three periods of ``a k k k`` (3 attention
+# layers, 9 kda layers, 12 expert layers); of each layer's 320 experts the 40
+# this chip holds, rows 0-24575 of the vocabulary (the first stage carries
+# embedding and head); mixers, attention, shared expert and router whole
+MODEL_CONFIGS["solar-open2-share8-12l"] = dataclasses.replace(
+    MODEL_CONFIGS["solar-open2-250b"].cut_to(12, "solar-open2-share8-12l"),
+    experts_held=40, expert_offset=0, vocab_held=24576, max_position=3072)
+
+# a share of the tiny preset: experts 4-7 of 16, half the vocabulary; and its
+# first period alone (``a k k k``), for the CPU tests that build an engine
+MODEL_CONFIGS["tiny-solar-open2-share4"] = dataclasses.replace(
+    MODEL_CONFIGS["tiny-solar-open2"], name="tiny-solar-open2-share4",
+    experts_held=4, expert_offset=4, vocab_held=256)
+MODEL_CONFIGS["tiny-solar-open2-share4-4l"] = MODEL_CONFIGS[
+    "tiny-solar-open2-share4"].cut_to(4, "tiny-solar-open2-share4-4l")
 
 
 def get_config(name: str) -> ModelConfig:

@@ -34,7 +34,8 @@ dynamic slice ``lax.scan`` itself would make of its ``xs``); a static slice
 of a stack in front of the scan would be a copy of those weights every step.
 
 The mixer is ``models/falcon_h1.py``'s (``_mixer_in`` / ``_mixer_step`` /
-``_mixer_chunk`` / ``_mixer_out`` over ``ops/ssd.py``), attention
+``_mixer_chunk`` / ``_mixer_out`` over ``ops/ssd.py``: ``MAMBA2``, which a
+stack of another recurrence replaces by ``mixer=``), attention
 ``models/llama.py``'s projections and both paged kernels with the scale
 handed to them, the expert layer ``llama.moe_route`` / ``moe_experts`` and
 kimi's SwiGLU. The entry points are the ones ``runtime/scheduler.py`` drives
@@ -45,7 +46,7 @@ the experts each token chose (``[L, N, K]``) and ``MOE_COUNTERS``.
 
 from __future__ import annotations
 
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -55,7 +56,7 @@ from ..ops.platform import default_interpret as _default_interpret
 from .configs import ModelConfig
 from .falcon_h1 import (State, _mixer_chunk, _mixer_in, _mixer_out,
                         _mixer_step, init_mixer_small, init_state)
-from .kimi_k2 import _swiglu
+from .kimi_k2 import _proj, _swiglu
 from .llama import (MOE_LEAVES, DecodeGroup, PagedPools, Params, _attn_out,
                     _decode_attend, _decode_targets, _embed_scale, _qkv_proj,
                     _ragged_attend, decode_work, embed_lookup,
@@ -74,6 +75,22 @@ __all__ = ["init_params", "init_params_with", "init_state", "layer_runs",
 MOE_COUNTERS = ("assignments", "local", "touched", "item_rows")
 
 Aux = dict[str, jnp.ndarray]
+
+
+class Mixer(NamedTuple):
+    """The four functions of a layer that holds state, as the forwards call
+    them (``falcon_h1._mixer_in`` / ``_mixer_step`` / ``_mixer_chunk`` /
+    ``_mixer_out`` say what each takes and returns): the projections of the
+    normed input, one token of rows ``[:B]`` of the slab, a lane's chunk on
+    its own rows, and the way out. ``models/solar_open2.py`` hands the
+    forwards another (``mixer=``)."""
+    into: Callable
+    step: Callable
+    chunk: Callable
+    out: Callable
+
+
+MAMBA2 = Mixer(_mixer_in, _mixer_step, _mixer_chunk, _mixer_out)
 
 
 def _one_device(mesh: Any, interpret: bool | None, cfg: ModelConfig) -> bool:
@@ -163,6 +180,15 @@ def _branch(h: jnp.ndarray, m: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
         h.dtype)
 
 
+def _gated(lp: dict, x: jnp.ndarray, attn: jnp.ndarray) -> jnp.ndarray:
+    """``attn ⊙ sigmoid(x W_gate)`` where the attention layer holds a
+    ``w_gate`` (solar_open2's ``use_gqa_gate``); ``attn`` as it is where it
+    holds none."""
+    if "w_gate" not in lp:
+        return attn
+    return (attn * jax.nn.sigmoid(_proj(x, lp["w_gate"]))).astype(attn.dtype)
+
+
 def _moe_residual(lp: dict, moe: dict, layer, h: jnp.ndarray,
                   cfg: ModelConfig):
     """The expert layer's norm, the routed experts and the shared MLP, added
@@ -239,12 +265,14 @@ def forward_paged_decode(
     *,
     state: State,              # {"ssm", "conv"}: [state_layers, rows, ...]
     run_layers: Callable | None = None,
+    mixer: Mixer = MAMBA2,
 ) -> tuple[jnp.ndarray, PagedPools, State, Aux]:
     """One decode step over the page pool and the state slab. Returns
     (hidden [B, 1, H], pools, state, aux); pages and state move as in
     ``falcon_h1.forward_paged_decode``, each in the layers of its kind.
     ``run_layers``: another stack over the same two sub-layers (``_run_layers``
-    says what it is handed; ``models/nemotron_h.py``)."""
+    says what it is handed; ``models/nemotron_h.py``). ``mixer``: what a
+    layer that holds state computes (``Mixer``)."""
     interpret = _one_device(mesh, interpret, cfg)
     cos_t, sin_t = rope_tables     # read only where cfg.rotary
     B = input_ids.shape[0]
@@ -259,10 +287,10 @@ def forward_paged_decode(
                                   params["final_norm"].dtype), cfg)
 
     def mix(lp, i, h, x, ssm, conv):
-        z, u, dt = _mixer_in(lp, x, cfg)
-        y, ssm, conv = _mixer_step(lp, i, cfg, u[0], dt[0], ssm, conv,
-                                   write_mask, h.dtype, not interpret)
-        return (_branch(h, _mixer_out(lp, y[None], z, cfg, h.dtype), cfg),
+        z, u, dt = mixer.into(lp, x, cfg)
+        y, ssm, conv = mixer.step(lp, i, cfg, u[0], dt[0], ssm, conv,
+                                  write_mask, h.dtype, not interpret)
+        return (_branch(h, mixer.out(lp, y[None], z, cfg, h.dtype), cfg),
                 ssm, conv)
 
     def attend(lp, i, h, x, k_pool, v_pool):
@@ -272,7 +300,7 @@ def forward_paged_decode(
         v_pool = v_pool.at[i, pid, off].set(
             vproj.reshape(B, -1).astype(v_pool.dtype))
         attn = decode_attend(q[0], k_pool, v_pool, work, i)
-        return (_attn_out(lp, h, attn.reshape(1, B, -1),
+        return (_attn_out(lp, h, _gated(lp, x, attn.reshape(1, B, -1)),
                           cfg.residual_multiplier), k_pool, v_pool)
 
     h, pools, state, aux = (run_layers or _run_layers)(
@@ -298,11 +326,12 @@ def forward_paged_mixed(
     decode: DecodeGroup | None = None,
     state: State,
     run_layers: Callable | None = None,
+    mixer: Mixer = MAMBA2,
 ) -> tuple[jnp.ndarray, PagedPools, State, Aux]:
     """One ragged mixed step over the tokens it has. Returns (hidden, pools,
     state, aux); lanes, the decode group, pages, state and ``hidden`` as
     ``falcon_h1.forward_paged_mixed``, each cache in the layers of its
-    kind. ``run_layers`` as in ``forward_paged_decode``."""
+    kind. ``run_layers`` and ``mixer`` as in ``forward_paged_decode``."""
     interpret = _one_device(mesh, interpret, cfg)
     cos_t, sin_t = rope_tables     # read only where cfg.rotary
     R, Qc = input_ids.shape
@@ -319,19 +348,19 @@ def forward_paged_mixed(
     def mix(lp, i, h, x, ssm, conv):
         # one input and one output projection over all tokens, split only
         # around the recurrence: the decode group's step, the lanes' chunk
-        z, u, dt = _mixer_in(lp, x, cfg)
+        z, u, dt = mixer.into(lp, x, cfg)
         ys = []
         if nd:
-            y_dec, ssm, conv = _mixer_step(
+            y_dec, ssm, conv = mixer.step(
                 lp, i, cfg, u[0, :nd], dt[0, :nd], ssm, conv, decode.run,
                 h.dtype, not interpret)
             ys.append(y_dec)
-        y, ssm, conv = _mixer_chunk(
+        y, ssm, conv = mixer.chunk(
             lp, i, cfg, u[0, nd:].reshape(R, Qc, -1),
             dt[0, nd:].reshape(R, Qc, -1), ssm, conv, rows, hist == 0,
             advance, span, h.dtype)
         ys.append(y.reshape(R * Qc, -1))
-        m = _mixer_out(lp, jnp.concatenate(ys)[None], z, cfg, h.dtype)
+        m = mixer.out(lp, jnp.concatenate(ys)[None], z, cfg, h.dtype)
         return _branch(h, m, cfg), ssm, conv
 
     def attend(lp, i, h, x, k_pool, v_pool):
@@ -343,8 +372,8 @@ def forward_paged_mixed(
             vproj.reshape(n, -1).astype(v_pool.dtype))
         attn = mixed_attention(lay, q, k_pool, v_pool, hist, q_lens, i,
                                lane_attend, decode_attend)
-        return (_attn_out(lp, h, attn, cfg.residual_multiplier), k_pool,
-                v_pool)
+        return (_attn_out(lp, h, _gated(lp, x, attn),
+                          cfg.residual_multiplier), k_pool, v_pool)
 
     h, pools, state, aux = (run_layers or _run_layers)(
         params, cfg, h, pools, state, mix, attend)

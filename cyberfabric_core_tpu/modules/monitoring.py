@@ -643,6 +643,10 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                 ("llm_state_layers", "state_layers",
                  "Layers of the recurrent-state slab: the model's layers "
                  "that hold state"),
+                ("llm_kda_layers", "kda_layers",
+                 "Layers of the recurrent-state slab that hold a matrix of "
+                 "state a head under a gated delta rule (0 for a Mamba-2 "
+                 "slab)"),
                 ("llm_model_layers", "model_layers",
                  "Layers of the model the caches were built for"),
                 ("llm_cache_bytes", "cache_bytes",

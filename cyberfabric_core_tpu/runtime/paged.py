@@ -18,9 +18,11 @@ no kv-head axis and no V pool; ``pools``,
 admission protocol count pages, whatever a page holds.
 
 One manager for both kinds of cache. A model with recurrent state
-(``ModelConfig.has_state``: falcon_h1, granite_hybrid, nemotron_h) also gets a
-state slab here — ``{"ssm": [Ls, rows, H, P, N], "conv": [Ls, rows, K-1, C]}`` f32, made
-by the model's own module (``init_state``) — **each cache as deep as the
+(``ModelConfig.has_state``: falcon_h1, granite_hybrid, nemotron_h,
+solar_open2) also gets a state slab here — ``{"ssm": [Ls, rows, H, P, N],
+"conv": [Ls, rows, K-1, C]}`` f32 (a Mamba-2 layer's ``[P, N]`` a head, or a
+kda layer's ``[keys, values]``; the conv tail over ``x B C``, or over ``q k
+v`` and then one flat row), made by the model's own module (``init_state``) — **each cache as deep as the
 layers of its kind**: the pool arrays have ``ModelConfig.kv_layers`` layers
 and the slab ``state_layers`` (both ``num_layers`` where every layer is one
 block; 1 and 9 for one period of granite's stack; 2 and 10 for nemotron_h's
@@ -515,6 +517,8 @@ class PrefixKVPool:
     def stats(self) -> dict[str, Any]:
         with self._tree_lock:
             tree_stats = self.tree.stats()
+        state_layers = (int(self.state["ssm"].shape[0])
+                        if self.state is not None else 0)
         return {
             **tree_stats,
             "pages_free": self.allocator.num_free,
@@ -540,8 +544,10 @@ class PrefixKVPool:
             # what the caches were BUILT with: layers of the page pool, of
             # the state slab and of the model, and both caches' bytes
             "kv_layers": int(self.pools[0].shape[0]),
-            "state_layers": (int(self.state["ssm"].shape[0])
-                             if self.state is not None else 0),
+            "state_layers": state_layers,
+            # those of them that hold a matrix of state under a delta rule
+            "kda_layers": (state_layers if "kda" in self.cfg.layer_types
+                           else 0),
             "model_layers": self.cfg.num_layers,
             "cache_bytes": self.pool_bytes() + self.state_bytes(),
             **self.state_stats(),

@@ -33,6 +33,11 @@ _MATMUL_LEAVES = {"wq", "wk", "wv", "wo", "gate", "up", "down",
                   "shared_gate", "shared_up", "shared_down",
                   # nemotron_h: the two projections around the experts' latent
                   "latent_down", "latent_up",
+                  # solar_open2: the attention layers' output gate; a kda
+                  # layer's q, k, v, o under the names above, the decay's and
+                  # the gate's low-rank pairs and β. Its conv taps, A_log,
+                  # dt_bias and the head norm's weight stay f32
+                  "w_gate", "f_a", "f_b", "g_a", "g_b", "w_beta",
                   # falcon_h1's mixer: W_in and W_out like any matrix; its
                   # conv, A_log, D, dt_bias and norm weights stay f32
                   "ssm_in", "ssm_out"}
@@ -75,8 +80,9 @@ def quantize_llama_params(params: dict[str, Any], bits: int = 8) -> dict[str, An
     # "layers", and where the stack is not one repeated layer the stacks
     # beside it: kimi_k2's leading "dense" layers, granite_hybrid's "mamba"
     # and "attention" layers (the mixer's conv, A_log, D, dt_bias and norm
-    # stay float32; the router float32), nemotron_h's "moe" layers
-    for stack in ("dense", "mamba", "attention", "moe", "layers"):
+    # stay float32; the router float32), nemotron_h's "moe" layers,
+    # solar_open2's "kda" layers
+    for stack in ("dense", "mamba", "kda", "attention", "moe", "layers"):
         if stack in params:
             out[stack] = {
                 # norms, router (tiny + precision-sensitive) stay as they are

@@ -930,3 +930,135 @@ def test_scheduler_programs_compile_for_v5e_at_nemotron_h():
             r"s8\[(1,)?(128,(1024,2688|2688,1024)|4096,18560|8192,4096|"
             r"4096,5376|5376,4096)\]\S* copy\(", text)
         assert not copied, f"{name}: a layer of a weight stack, {copied[0]}"
+
+
+def test_kda_state_kernel_compiles_at_served_shapes(one_chip):
+    """``ops/kda.kda_state_update`` at solar-open2's shapes (64 rows of 64
+    heads of [128, 128] f32 in layer 5 of a 9-layer slab of 80 rows) lowers
+    through Mosaic for a described v5e, 16 heads a program, the slab donated
+    and aliased: nothing slab-sized besides the argument."""
+    from cyberfabric_core_tpu.ops.kda import kda_state_update
+    from cyberfabric_core_tpu.ops.ssd import _head_block
+
+    assert _head_block(64, 1, 4 * 128 * 128) == 16
+    f32, B, H, D = jnp.float32, 64, 64, 128
+    slab = one_chip((9, 80, H, D, D), f32)
+    compiled = jax.jit(
+        lambda s, layer, q, k, v, g, beta, mask: kda_state_update(
+            s, layer, q, k, v, g, beta, mask, kernel=True),
+        donate_argnums=(0,)).lower(
+            slab, one_chip((), jnp.int32), *(one_chip((B, H, D), f32),) * 4,
+            one_chip((B, H), f32), one_chip((B,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "kda_state_update" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 9 * 80 * H * D * D * 4
+    assert mem.temp_size_in_bytes < 64 * 1024 * 1024
+
+
+@pytest.mark.slow
+def test_scheduler_programs_compile_for_v5e_at_solar_open2():
+    """The scheduler's own ``paged_decode_chunk`` and ``mixed_step`` for
+    solar-open2-share8-12l int8 at the served shapes of
+    ``benchmark/configs/solar-open2-int8.json`` (64 slots of 3072, 3073 pages
+    in THREE pool layers, 80 state rows in NINE slab layers, 40 held experts
+    in TWELVE expert-stack layers, 8 steps a chunk), on one described chip:
+    each holds the ``kda_state_update``, the ``grouped_matmul`` and the paged
+    attention Mosaic calls, donates the pools and the slab, fits the 15.75
+    GiB the compiler budgets and copies nothing the size of the slab or of a
+    pool. A compile, not a chip run (``-s`` prints the sizes)."""
+    import json
+    import time
+
+    from jax.sharding import SingleDeviceSharding
+
+    from cyberfabric_core_tpu.models import decoder_module, get_config
+    from cyberfabric_core_tpu.ops.platform import compiled_kernels
+    from cyberfabric_core_tpu.parallel.feasibility import V5E_HBM_BYTES
+    from cyberfabric_core_tpu.parallel.sharding import abstract_params
+    from cyberfabric_core_tpu.runtime.engine import EngineConfig
+    from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
+
+    topo = _topo_or_skip()
+    serving = json.loads(REPO.joinpath(
+        "benchmark/configs/solar-open2-int8.json").read_text())["serving"]
+    n, max_seq = serving["max_batch"], serving["max_seq_len"]
+    pages = serving["pool_pages"] + 1      # the scratch page
+    rows = n + serving["state_snapshots"]
+    cfg = get_config(serving["model_config"])
+    assert (cfg.kv_layers, cfg.state_layers, cfg.moe_layers, pages) == (
+        3, 9, 12, 3073)
+    eng = object.__new__(ContinuousBatchingEngine)
+    eng.config = EngineConfig(
+        model=cfg.name, max_seq_len=max_seq, max_batch=n,
+        decode_chunk=serving["decode_chunk"], quantization="int8",
+        prefix_cache_pages=pages, prefix_page_size=_PAGE)
+    eng.model_config, eng.dtype = cfg, jnp.bfloat16
+    eng._model, eng._has_state, eng._block = decoder_module(cfg), True, 0
+    eng._moe_counters = eng._model.MOE_COUNTERS
+    eng.n_slots, eng.pmax = n, max_seq // _PAGE
+    eng.spec_k, eng._spec_w = 0, 1
+    eng.mesh = eng._attn_mesh = None
+    here = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=here)
+
+    params = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                          abstract_params(cfg, jnp.bfloat16, "int8"))
+    assert params["layers"]["router"].dtype == jnp.float32
+    assert params["layers"]["moe_gate"]["q"].shape == (12, 40, 4096, 1280)
+    assert params["kda"]["wq"]["q"].shape == (9, 4096, 8192)
+    assert params["kda"]["conv_w"].shape == (9, 4, 24576)
+    assert params["attention"]["w_gate"]["q"].shape == (3, 4096, 8192)
+    assert params["lm_head"]["q"].shape == (4096, 24576)
+    eng.pool = types.SimpleNamespace(cache_operands=lambda: (None,) * 3)
+    with compiled_kernels():
+        eng._build_programs()
+
+    f32 = jnp.float32
+    pool = sds((cfg.kv_layers, pages, _PAGE, cfg.num_kv_heads * cfg.head_dim),
+               jnp.bfloat16)
+    state = {"ssm": sds((cfg.state_layers, rows, cfg.ssm_heads,
+                         cfg.ssm_head_dim, cfg.ssm_state), f32),
+             "conv": sds((cfg.state_layers, rows,
+                          (cfg.ssm_conv - 1) * cfg.ssm_conv_dim), f32)}
+    slab_bytes = int(np.prod(state["ssm"].shape)) * 4
+
+    def mixed(width):
+        return (eng._mixed_step_fn, (
+            params, pool, pool, state, *_mixed_operands(sds, eng, width)))
+
+    programs = {
+        "paged_decode_chunk": (eng._paged_decode_fn, (
+            params, pool, pool, state, *_decode_operands(sds, eng))),
+        "mixed_step@64": mixed(64), "mixed_step@512": mixed(512),
+    }
+    for name, (fn, args) in programs.items():
+        started = time.monotonic()
+        with compiled_kernels():
+            compiled = fn.lower(*args).compile()
+        took = time.monotonic() - started
+        mem = compiled.memory_analysis()
+        live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+        text = compiled.as_text()
+        print(f"{name}: argument {mem.argument_size_in_bytes / 1e9:.2f} output "
+              f"{mem.output_size_in_bytes / 1e9:.2f} aliased "
+              f"{mem.alias_size_in_bytes / 1e9:.2f} temp "
+              f"{mem.temp_size_in_bytes / 1e9:.2f} GB; compiled in "
+              f"{took:.0f} s, code "
+              f"{mem.generated_code_size_in_bytes / 1e6:.1f} MB, optimised "
+              f"HLO {len(text) / 1e6:.1f} MB")
+        if os.environ.get("AOT_DUMP_DIR"):
+            Path(os.environ["AOT_DUMP_DIR"], f"{name}.hlo.txt").write_text(text)
+        for kernel in ("kda_state_update", "grouped_matmul",
+                       "paged_decode_attention"):
+            assert kernel in text, (name, kernel)
+        assert "ssm_state_update" not in text, name
+        assert mem.alias_size_in_bytes >= slab_bytes + 2 * int(
+            np.prod(pool.shape)) * 2, "pools and state slab not donated"
+        assert live < V5E_HBM_BYTES, (name, live)
+        _assert_whole_array_untouched(text, state["ssm"], name)
+        _assert_whole_array_untouched(text, state["conv"], name)
+        _assert_whole_array_untouched(text, pool, name)

@@ -1,0 +1,160 @@
+"""``tiny-solar-open2-share4`` served by the continuous scheduler (no gateway):
+KDA layers under a gated delta rule and gated attention layers in one stack,
+as one chip's share of the experts and the vocabulary, on the one path. The
+pool is as deep as the attention layers, the slab as the KDA layers; the
+state (a matrix a head, and a conv tail over q, k and v) rides the programs
+as a third donated operand through the seam Mamba-2's rides, and the expert
+counters ride their drain, from one forward. Scheduler, pool and programs
+name no kind of state: nothing there was edited for this model.
+
+The contract is falcon_h1's and granite_hybrid's: a request that resumes from
+a snapshot, or is preempted and resumed, leaves what the uninterrupted run
+leaves. Greedy tokens are compared. Most cases build the one-period cut
+``tiny-solar-open2-share4-4l`` (``a k k k``)."""
+
+import threading
+
+import numpy as np
+import pytest
+
+from cyberfabric_core_tpu.models import get_config, solar_open2
+from cyberfabric_core_tpu.runtime import EngineConfig, SamplingParams
+from cyberfabric_core_tpu.runtime.scheduler import (ContinuousBatchingEngine,
+                                                    _moe_series)
+from test_nemotron_h_scheduler import _Collector, _counter, _prompts, _run
+
+BUDGET = 32          # the prefill budget: a snapshot boundary every 32 tokens
+ONE_PERIOD = "tiny-solar-open2-share4-4l"
+SERIES = _moe_series(solar_open2.MOE_COUNTERS) + (
+    "llm_attn_pages_walked_total", "llm_attn_pages_offered_total",
+    "llm_state_snapshots_taken_total", "llm_state_snapshot_hits_total",
+    "llm_state_restores_total")
+
+
+def _cfg(**over):
+    base = dict(model=ONE_PERIOD, max_seq_len=256, max_batch=4,
+                decode_chunk=4, use_flash=False, prefix_cache_pages=80,
+                prefix_page_size=16, prefill_budget_tokens=BUDGET)
+    base.update(over)
+    return EngineConfig(**base)
+
+
+def test_one_model_counts_experts_and_state():
+    """Two periods of the stack, int8: ``/metrics``' expert counters AND
+    state counters move from one model's forwards; the expert counters are
+    over EVERY layer (8 of 8) and the experts HELD (4 of 16), the compact
+    branch's over the layers run; the pool and the engine report the layers
+    each stack was built with, ``kda_layers`` among them."""
+    base, other = _prompts()
+    cfg = _cfg(model="tiny-solar-open2-share4", quantization="int8",
+               decode_lookahead=0)
+    model = get_config("tiny-solar-open2-share4")
+    before = {s: _counter(s) for s in SERIES}
+    first, stats, sched = _run(cfg, [base, other])
+    d = {s.removeprefix("llm_").removesuffix("_total"):
+         _counter(s) - before[s] for s in SERIES}
+    assert all(len(t) == 12 for t in first.values())
+    assert all(0 <= tok < model.vocab_rows for t in first.values() for tok in t)
+    mixed = [r for r in sched.round_timings if r["mixed"]]
+    decode_chunks = [r for r in sched.round_timings if not r["mixed"]]
+    forwards = len(mixed) + 4 * len(decode_chunks)
+    Le, held, K = model.moe_layers, model.experts_local, \
+        model.experts_per_token
+    assert (Le, held, model.num_experts) == (8, 4, 16)
+    assert d["moe_experts_offered"] == forwards * Le * held
+    assert d["moe_decode_experts_offered"] == 4 * len(decode_chunks) * Le * held
+    positions = sum(r["positions"] for r in mixed) + 16 * len(decode_chunks)
+    assert d["moe_assignments"] == positions * K * Le
+    # a quarter of the experts is held: about a quarter of the assignments
+    assert 0.1 < d["moe_assignments_local"] / d["moe_assignments"] < 0.45
+    assert 0 < d["moe_decode_experts_touched"] < d["moe_experts_touched"] \
+        <= d["moe_experts_offered"]
+    assert d["moe_layer_forwards"] == forwards * Le
+    assert 0 <= d["moe_layer_forwards_compact"] <= d["moe_layer_forwards"]
+    assert d["moe_item_rows"] >= d["moe_experts_touched"]
+    # pages are offered by the layers that attend: 2 of 8
+    assert d["attn_pages_offered"] == forwards * model.kv_layers * 4 * 16
+    assert 0 < d["attn_pages_walked"] < d["attn_pages_offered"]
+    assert d["state_snapshots_taken"] == 2          # at 32 and at 64
+    pool = stats["prefix_cache"]
+    assert (pool["kv_layers"], pool["state_layers"], pool["kda_layers"],
+            pool["model_layers"]) == (2, 6, 6, 8)
+    assert sched.moe_layers_built() == 8
+    assert pool["cache_bytes"] == pool["pool_bytes"] + pool["state_bytes"]
+    assert pool["state_bytes"] == pool["state_rows"] * \
+        model.state_bytes_per_row()
+    assert sched.pool.k_pool.shape[0] == 2
+    assert sched.pool.state["ssm"].shape == (6, pool["state_rows"], 4, 16, 16)
+    assert sched.pool.state["conv"].shape == (6, pool["state_rows"], 3 * 192)
+    assert sched.params["layers"]["moe_up"]["q"].shape[:2] == (8, 4)
+    assert sched.params["kda"]["conv_w"].dtype == np.float32
+    assert len(sched.pool.cache_operands()) == 3
+    # a Mamba-2 slab is no kda slab
+    from cyberfabric_core_tpu.runtime.paged import PrefixKVPool
+    granite = PrefixKVPool(get_config("tiny-granite-hybrid-4l"), num_pages=9,
+                           page_size=16, state_slots=2, state_snapshots=1)
+    assert (granite.stats()["state_layers"],
+            granite.stats()["kda_layers"]) == (3, 0)
+
+
+def test_a_prompt_sharing_two_whole_chunks_resumes_from_the_snapshot():
+    """The second request shares 64 tokens = two chunks of the budget with
+    the first: it takes the attention layer's pages AND the mamba layers'
+    snapshot at token 64, prefills only its suffix, and answers as a cold
+    run of the same prompt does."""
+    base, _ = _prompts(2)
+    shared = base[:2 * BUDGET] + [7, 8, 9, 10, 11, 12, 13, 14, 15]
+    cold, _, _ = _run(_cfg(), [shared])
+    warm, stats, _ = _run(_cfg(), [base, shared], in_turn=True)
+    pool = stats["prefix_cache"]
+    assert pool["state_snapshot_hits"] == 1
+    assert pool["prefill_tokens_saved"] == 2 * BUDGET
+    assert pool["state_snapshots_taken"] >= 2       # at 32 and at 64
+    assert warm[1] == cold[0]
+
+
+def test_preempt_mid_decode_and_resume_equals_the_uninterrupted_run():
+    """Pool pressure while the stream decodes, with chunks in flight: the
+    row's state (the KDA layers') goes to the host with its pages (the
+    attention layer's) and comes back exactly."""
+    prompt = np.random.default_rng(6).integers(3, 250, 20).tolist()
+    cfg = _cfg(max_batch=2, max_seq_len=128, prefix_cache_pages=64,
+               prefix_page_size=8, decode_lookahead=2)
+    want, _, _ = _run(cfg, [prompt], max_tokens=40)
+
+    sched = ContinuousBatchingEngine(cfg, seed=0)
+    col = _Collector(1)
+    try:
+        orig_extend = sched.pool.extend_chain
+        armed = threading.Event()
+
+        def flaky_extend(chain, needed):
+            if armed.is_set() and sched.preemptions == 0:
+                raise MemoryError("injected pool pressure")
+            return orig_extend(chain, needed)
+
+        sched.pool.extend_chain = flaky_extend
+
+        def arm(ev):
+            if len(col.tokens[0]) == 12:
+                armed.set()
+        sched.submit(prompt, SamplingParams(max_tokens=40),
+                     col.emit_for(0, then=arm))
+        assert col.done.wait(240), (col.tokens, sched.stats())
+    finally:
+        sched.shutdown()
+    assert sched.preemptions >= 1, "injected pressure never preempted"
+    assert col.tokens == want
+    assert sched.pool.stats()["state_restores"] >= 1
+
+
+@pytest.mark.parametrize("over,what", [
+    (dict(scheduler_spec_k=3), "state rollback"),
+    (dict(pd_role="prefill"), "export carries no recurrent state"),
+    (dict(tp=2), "no sharding for the state slab"),
+])
+def test_a_mode_that_cannot_carry_state_is_refused_at_build(over, what):
+    """The lines falcon_h1 and granite_hybrid are refused with, for the same
+    reasons."""
+    with pytest.raises(ValueError, match=what):
+        ContinuousBatchingEngine(_cfg(**over), seed=0)
