@@ -18,7 +18,14 @@ group), with ``Δ_t = softplus(dt_t + dt_bias)`` and ``A < 0``::
   a scalar-prefetch operand of the index map and the slab is aliased to the
   output, so nothing slab-sized is sliced or copied in front of the call
   (PERF.md section 6, PR 25: a Mosaic call handed ``slab[layer]`` makes XLA
-  materialise that layer first). A plain ``jax.numpy`` step with the same
+  materialise that layer first). A program takes 1 MB of heads
+  (``_head_block``: whole groups where several fit), updates them on the VPU
+  and sums the read-out ``S' C`` over the state axis on the MXU
+  (``_row_sums``: three bfloat16 pieces of each f32 product against ones, an
+  f32 sum in another order): summed over the lanes on the XLU a call took
+  980 us at granite's ``[64, 128]`` heads where a program that only copies
+  its block takes 835, and a fold of rotates and selects took 1 788
+  (PERF.md section 5, PR 46). A plain ``jax.numpy`` step with the same
   arithmetic serves the CPU (tests, rehearsals).
 - :func:`causal_conv` / :func:`causal_conv_step` — the depthwise conv in
   front of the recurrence, carrying each row's tail of ``K - 1`` inputs.
@@ -138,40 +145,72 @@ def ssd_chunked(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
 
 # ------------------------------------------------------------ the decode step
 #: f32 state bytes one kernel program should move each way: what falcon-h1's
-#: 8 heads of [128, 256] are. A program costs about a third of a microsecond
-#: before it has moved a byte, so a row of many small heads (granite: 128
-#: heads of [64, 128], 32 KB each) takes several to a program
+#: 8 heads of [128, 256] are. A program that only copies its block moves a
+#: call's 537 MB in 847 / 835 / 841 us at 512 KB / 1 MB / 2 MB (my chip runs,
+#: PR 46: 78.6% of 819 GB/s at 1 MB, the floor of this tiling whatever the
+#: body computes), so a row of many small heads (granite: 128 heads of
+#: [64, 128], 32 KB each) takes several to a program
 _STATE_BLOCK_BYTES = 1024 * 1024
 
 
 def _head_block(heads: int, groups: int, head_bytes: int) -> int:
-    """Heads a kernel program handles: all of one group, and the most whose
-    f32 state (``head_bytes`` each) stays within ``_STATE_BLOCK_BYTES``: 8
-    at falcon-h1's [128, 256], 32 at granite's [64, 128]."""
+    """Heads a kernel program handles: the most whose f32 state
+    (``head_bytes`` each) stays within ``_STATE_BLOCK_BYTES``, as a divisor
+    of one group's heads where a group is more than that (8 at falcon-h1's
+    [128, 256], 32 at granite's [64, 128], 16 at solar-open2's [128, 128])
+    and as WHOLE groups where several fit (nemotron: two groups of 16)."""
     per_group = heads // groups
-    hb = max(1, min(per_group, _STATE_BLOCK_BYTES // head_bytes))
-    while per_group % hb:
-        hb -= 1
-    return hb
+    fit = max(1, _STATE_BLOCK_BYTES // head_bytes)
+    if fit >= per_group:
+        whole = min(groups, fit // per_group)
+        while groups % whole:
+            whole -= 1
+        return whole * per_group
+    while per_group % fit:
+        fit -= 1
+    return fit
+
+
+def _row_sums(x: jnp.ndarray, width: int) -> jnp.ndarray:
+    """Each row's sum over the lanes of ``x`` [P, N] f32, as [P, width] with
+    the sum in every lane, on the MXU. ``x`` is split into three bfloat16
+    pieces that add up to it (8 + 8 + 8 bits of its 24) and each is
+    multiplied against a block of ones with f32 accumulation: the products
+    are exact, so this is the f32 sum in another order (on the chip 1.5 ulp
+    from the VPU's; two pieces read 1e-4 of a sum of 32). The lane reduction
+    it replaces cost the XLU 15 ns a vreg of state where the vreg's DMA
+    takes 9.8 (PERF.md section 5, PR 46)."""
+    ones = jnp.ones((x.shape[1], width), jnp.bfloat16)
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+
+    def dot(piece):
+        return jnp.dot(piece, ones, preferred_element_type=jnp.float32)
+
+    return dot(hi) + dot(mid) + dot(lo)
 
 
 def _state_update_kernel(layer_ref, mask_ref, s_ref, xdt_ref, da_ref, b_ref,
-                         c_ref, s_out_ref, y_ref, *, hb: int):
+                         c_ref, s_out_ref, y_ref, *, hb: int, per_group: int):
     """One (row, head block): S' = dA S + (Δx) Bᵀ, y = S' C. The state block
     is [hb, P, N] with N on the lanes; Δx arrives as columns [P, hb] so that a
-    head's x broadcasts along the lanes and B, C along the sublanes: no
-    transpose in the kernel, all of it on the VPU in f32."""
+    head's x broadcasts along the lanes and B, C (a row for each group the
+    block holds) along the sublanes: no transpose in the kernel. The update
+    is the VPU's, in f32; the read-out's sums over N are the MXU's
+    (``_row_sums``), which the update leaves idle."""
     del layer_ref                                   # used by the index maps
     keep = mask_ref[pl.program_id(0)] != 0
-    b_row, c_row = b_ref[0, 0], c_ref[0, 0]         # [1, N]
     xdt, da = xdt_ref[0, 0], da_ref[0, 0]           # [P, hb], [1, hb]
     lane = jax.lax.broadcasted_iota(jnp.int32, xdt.shape, 1)
+    width = -(-hb // 128) * 128                     # hb in whole lane tiles
     y = jnp.zeros(xdt.shape, jnp.float32)
     for j in range(hb):
+        b_row, c_row = b_ref[0, j // per_group], c_ref[0, j // per_group]
         s = s_ref[0, 0, j]                          # [P, N]
         new = s * da[:, j: j + 1] + xdt[:, j: j + 1] * b_row
-        y = jnp.where(lane == j,
-                      jnp.sum(new * c_row, axis=1, keepdims=True), y)
+        y = jnp.where(lane == j, _row_sums(new * c_row, width)[:, :hb], y)
         s_out_ref[0, 0, j] = jnp.where(keep, new, s)
     y_ref[0, 0] = y
 
@@ -180,12 +219,16 @@ def _state_update_pallas(ssm, layer, xdt, da, b_mat, c_mat, write_mask, *,
                          interpret: bool):
     _, _, H, P, N = ssm.shape
     B, G = xdt.shape[0], b_mat.shape[1]
+    per_group = H // G
     hb = _head_block(H, G, 4 * P * N)
     nhb = H // hb
-    blocks_per_group = (H // G) // hb
+    span = max(hb, per_group)           # heads whose B and C one block holds
 
     def cols(t):        # [B, H, P] -> [B, nhb, P, hb]: a head's values a column
         return t.reshape(B, nhb, hb, P).transpose(0, 1, 3, 2)
+
+    def group_block(b, h, layer, mask):
+        return (b, h * hb // span, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -195,10 +238,8 @@ def _state_update_pallas(ssm, layer, xdt, da, b_mat, c_mat, write_mask, *,
                          lambda b, h, layer, mask: (layer[0], b, h, 0, 0)),
             pl.BlockSpec((1, 1, P, hb), lambda b, h, layer, mask: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, 1, hb), lambda b, h, layer, mask: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, 1, N), lambda b, h, layer, mask:
-                         (b, h // blocks_per_group, 0, 0)),
-            pl.BlockSpec((1, 1, 1, N), lambda b, h, layer, mask:
-                         (b, h // blocks_per_group, 0, 0)),
+            pl.BlockSpec((1, span // per_group, 1, N), group_block),
+            pl.BlockSpec((1, span // per_group, 1, N), group_block),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, hb, P, N),
@@ -206,7 +247,7 @@ def _state_update_pallas(ssm, layer, xdt, da, b_mat, c_mat, write_mask, *,
             pl.BlockSpec((1, 1, P, hb), lambda b, h, layer, mask: (b, h, 0, 0)),
         ])
     ssm, y = pl.pallas_call(
-        functools.partial(_state_update_kernel, hb=hb),
+        functools.partial(_state_update_kernel, hb=hb, per_group=per_group),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(ssm.shape, ssm.dtype),
                    jax.ShapeDtypeStruct((B, nhb, P, hb), jnp.float32)],

@@ -176,6 +176,44 @@ def retrace():
     step_programs.cache_clear()
 
 
+#: A worker keeps every program it compiled (``runtime/programs.py``'s memo,
+#: jax's own caches), and a compiled program is hundreds of memory mappings of
+#: its code: three scheduler files leave 31 390 of the 65 530 the kernel
+#: allows a process (``vm.max_map_count``; the same at PR 46's parent), and a
+#: worker that xdist hands a few more dies of SIGABRT inside XLA's CPU compile
+#: when a mapping is refused (PR 46: two workers of six at 92% of a whole
+#: run, which was then cut by its time limit). Above this share of the limit
+#: the end of a test module forgets the programs (21 281 -> 855 measured)
+_MAPPINGS_SHARE_KEPT = 0.4
+
+
+def _mappings(path="/proc/self/maps"):
+    try:
+        with open(path) as f:
+            return sum(1 for _ in f)
+    except OSError:
+        return 0
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _bounded_code_mappings():
+    yield
+    try:
+        with open("/proc/sys/vm/max_map_count") as f:
+            allowed = int(f.read())
+    except (OSError, ValueError):
+        return
+    if _mappings() > _MAPPINGS_SHARE_KEPT * allowed:
+        import gc
+
+        import jax
+        from cyberfabric_core_tpu.runtime.programs import step_programs
+
+        step_programs.cache_clear()
+        jax.clear_caches()
+        gc.collect()
+
+
 @pytest.fixture()
 def fresh_registry():
     """Isolate module registrations per test."""

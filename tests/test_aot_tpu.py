@@ -956,6 +956,39 @@ def test_kda_state_kernel_compiles_at_served_shapes(one_chip):
     assert mem.temp_size_in_bytes < 64 * 1024 * 1024
 
 
+@pytest.mark.parametrize("layers,rows,batch,heads,p,n,groups,block", [
+    pytest.param(9, 96, 64, 128, 64, 128, 1, 32, id="granite"),
+    pytest.param(10, 96, 64, 128, 64, 128, 8, 32, id="nemotron"),
+    pytest.param(16, 32, 16, 32, 128, 256, 2, 8, id="falcon-h1"),
+])
+def test_ssm_state_kernel_compiles_at_served_shapes(
+        one_chip, layers, rows, batch, heads, p, n, groups, block):
+    """``ops/ssd.ssm_state_update`` at the three cells' shapes (the batch's
+    rows of the whole slab, a middle layer) lowers through Mosaic for a
+    described v5e with its read-out on the MXU (PR 46: bfloat16 pieces
+    against a block of ones; interpret mode cannot say whether Mosaic takes
+    the casts, the dots and a block of several groups' B and C), the slab
+    donated and aliased: nothing slab-sized besides the argument."""
+    from cyberfabric_core_tpu.ops.ssd import _head_block, ssm_state_update
+
+    assert _head_block(heads, groups, 4 * p * n) == block
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    compiled = jax.jit(
+        lambda s, layer, x, dt, a, b, c, mask: ssm_state_update(
+            s, layer, x, dt, a, b, c, mask, kernel=True),
+        donate_argnums=(0,)).lower(
+            one_chip((layers, rows, heads, p, n), f32),
+            one_chip((), jnp.int32), one_chip((batch, heads, p), bf16),
+            one_chip((batch, heads), f32), one_chip((heads,), f32),
+            *(one_chip((batch, groups, n), bf16),) * 2,
+            one_chip((batch,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ssm_state_update" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= layers * rows * heads * p * n * 4
+    assert mem.temp_size_in_bytes < 64 * 1024 * 1024
+
+
 @pytest.mark.slow
 def test_scheduler_programs_compile_for_v5e_at_solar_open2():
     """The scheduler's own ``paged_decode_chunk`` and ``mixed_step`` for

@@ -30,15 +30,38 @@ def _inputs(T, seed):
             "state": jax.random.normal(k[5], (B, H, P, N), jnp.float32)}
 
 
-def test_the_head_block_follows_from_the_shapes():
+#: ``_head_block(heads, groups, f32 bytes of a head's state)`` by shape: 1 MB
+#: of state a program, a divisor of one group's heads, or WHOLE groups where
+#: several fit (PR 46)
+HEAD_BLOCKS = {
+    "falcon-h1: 8 of a group's 16 heads of [128, 256]":
+        ((32, 2, 4 * 128 * 256), 8),
+    "granite: 32 of one group's 128 heads of [64, 128]":
+        ((128, 1, 4 * 64 * 128), 32),
+    "nemotron: two whole groups of 16 heads of [64, 128]":
+        ((128, 8, 4 * 64 * 128), 32),
+    "solar-open2 (ops/kda.py): 16 of 64 heads of [128, 128]":
+        ((64, 1, 4 * 128 * 128), 16),
+    "tiny heads: every group, the whole row": ((4, 2, 4 * 16 * 32), 4),
+    "a divisor of the group's 12": ((12, 1, 4 * 64 * 1024), 4),
+    "a head over the budget: one": ((6, 1, 4 * 1024 * 1024), 1),
+    "three groups fit, of 8: a divisor of the groups, two":
+        ((64, 8, 4 * 64 * 170), 16),
+    "a group and a half fit: one group": ((32, 2, 4 * 64 * 170), 16),
+}
+
+
+@pytest.mark.parametrize("case", list(HEAD_BLOCKS))
+def test_the_head_block_follows_from_the_shapes(case):
     """1 MB of f32 state a program: falcon-h1 keeps its 8 heads of [128,
     256] (4 programs a row), granite's [64, 128] take 32 (4 programs a row,
-    not 16), and a block never spans groups or leaves a remainder."""
-    assert ssd._head_block(32, 2, 4 * 128 * 256) == 8          # falcon-h1
-    assert ssd._head_block(128, 1, 4 * 64 * 128) == 32         # granite
-    assert ssd._head_block(4, 2, 4 * 16 * 32) == 2             # all of a group
-    assert ssd._head_block(12, 1, 4 * 64 * 1024) == 4          # divides 12
-    assert ssd._head_block(6, 1, 4 * 1024 * 1024) == 1
+    not 16), nemotron's groups of 16 go two to a program (granite's block);
+    a block is whole groups or divides one, and never leaves a remainder."""
+    (heads, groups, head_bytes), want = HEAD_BLOCKS[case]
+    got = ssd._head_block(heads, groups, head_bytes)
+    assert got == want
+    per_group = heads // groups
+    assert heads % got == 0 and (per_group % got == 0 or got % per_group == 0)
 
 
 def test_state_kernel_at_one_group_and_two_head_blocks_a_row(monkeypatch):

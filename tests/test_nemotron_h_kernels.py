@@ -1,6 +1,7 @@
 """The Pallas kernels nemotron_h runs, in interpret mode at its shapes' RATIOS
 (tiny sizes): the state kernel and the chunked form at SEVERAL groups of 16
-heads (a program takes a whole group; a row is as many programs as groups);
+heads (a program takes whole groups, two at the served size: PR 46; one
+where the budget holds one);
 the grouped matmul in its two-matrix form on rows of a latent width, with
 many more held experts than rows; both paged attention kernels at 16 queries
 a kv head on a page row of 2 kv heads."""
@@ -36,17 +37,19 @@ def _inputs(T, seed):
 
 
 def test_the_tiles_follow_from_the_shapes_and_the_others_keep_theirs():
-    """No tile rule changed for this model: 8 groups of 16 heads of [64, 128]
-    give a whole group a program (16 heads, 512 KB; granite's one group 32,
-    falcon's 8); an expert's 1024 x 2688 and 2688 x 1024 int8 matrices are one
+    """8 groups of 16 heads of [64, 128] give TWO whole groups a program (32
+    heads, 1 MB, granite's block: PR 46; a group alone was 512 KB and 512
+    programs a call; falcon's 8 of a group stay); an expert's 1024 x 2688
+    and 2688 x 1024 int8 matrices are one
     block each, no column tiles (kimi's 7168 x 2048 keeps its 512, sdar's and
     granite's stay whole); a decode step's 1408 sorted assignments over 128
     held experts are 22 row tiles of 64 (11 rows an expert: ``row_tile``, PR
     43) + 127 = 149 work items; a page row of 2 kv
     heads takes 8 pages a program (mistral's 8 kv heads 4)."""
-    assert ssd._head_block(128, 8, 4 * 64 * 128) == 16          # nemotron
+    assert ssd._head_block(128, 8, 4 * 64 * 128) == 32          # nemotron
     assert ssd._head_block(128, 1, 4 * 64 * 128) == 32          # granite
     assert ssd._head_block(32, 2, 4 * 128 * 256) == 8           # falcon-h1
+    assert ssd._head_block(64, 1, 4 * 128 * 128) == 16          # solar-open2
     assert _col_tile(1024, 2688, 1) == 2688 and 1024 * 2688 <= BLOCK_BYTES
     assert _col_tile(2688, 1024, 1) == 1024
     assert _col_tile(7168, 2048, 1) == 512                      # kimi
@@ -98,8 +101,14 @@ def test_the_row_tile_of_calls_no_cell_runs():
     assert row_tile(100, 1) == 112            # one group: one tile
 
 
-def test_state_kernel_at_several_groups_of_sixteen_heads():
-    assert ssd._head_block(H, G, 4 * P * N) == 16       # a group a program
+@pytest.mark.parametrize("groups_a_program", [1, 2])
+def test_state_kernel_at_several_groups_of_sixteen_heads(monkeypatch,
+                                                         groups_a_program):
+    """A program of ONE whole group (the budget holds 16 heads) and of TWO
+    (the served ratio: a head reads row ``j // 16`` of its block's B and C)."""
+    monkeypatch.setattr(ssd, "_STATE_BLOCK_BYTES",
+                        groups_a_program * 16 * 4 * P * N)
+    assert ssd._head_block(H, G, 4 * P * N) == 16 * groups_a_program
     inp = _inputs(1, seed=5)
     slab = jnp.stack([inp["state"] * 0.5, inp["state"]])
     slab = jnp.concatenate([slab, slab[:, :1] + 1.0], axis=1)   # a 4th row

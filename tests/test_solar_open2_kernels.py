@@ -45,8 +45,8 @@ def _scan(inp, upto=None):
 
 def test_the_tiles_follow_from_the_shapes_and_the_others_keep_theirs():
     """No tile rule changed for this model. 64 heads of [128, 128] f32 are 16
-    a program (1 MB, ``_head_block``'s budget; granite 32, nemotron 16,
-    falcon 8). An expert's 4096 x 1280 int8 matrix is 5.2 MB, over the 4 MB
+    a program (1 MB, ``_head_block``'s budget; granite 32, nemotron two
+    whole groups of 16 since PR 46, falcon 8). An expert's 4096 x 1280 int8 matrix is 5.2 MB, over the 4 MB
     block: the rule there is gives it TWO column tiles of 640, and 1280 x
     4096 two of 2048 (ISSUE 45 expected none; kimi's 7168 x 2048 keeps its
     512, granite's and sdar's stay whole). A decode step's 512 assignments
@@ -54,7 +54,7 @@ def test_the_tiles_follow_from_the_shapes_and_the_others_keep_theirs():
     mixed step of 512 tokens beside 64 decode rows 2 304 of 4 608, in 64s
     too. A page row of 8 kv heads takes 4 pages a program, as mistral's."""
     assert ssd._head_block(64, 1, 4 * 128 * 128) == 16          # solar-open2
-    assert ssd._head_block(128, 8, 4 * 64 * 128) == 16          # nemotron
+    assert ssd._head_block(128, 8, 4 * 64 * 128) == 32          # nemotron
     assert ssd._head_block(128, 1, 4 * 64 * 128) == 32          # granite
     assert ssd._head_block(32, 2, 4 * 128 * 256) == 8           # falcon-h1
     assert 4096 * 1280 > BLOCK_BYTES

@@ -123,6 +123,107 @@ def test_kernel_in_interpret_mode_equals_the_jnp_step():
     np.testing.assert_allclose(np.asarray(s_k), np.asarray(s_j), atol=1e-6)
 
 
+def _step_args(rows, heads, p, n, groups, seed):
+    """A decode step's operands at any shape: layer 1 of a slab of two layers
+    and one row more than the batch, activations in bfloat16 as served."""
+    k = jax.random.split(jax.random.PRNGKey(seed), 6)
+    slab = jax.random.normal(k[0], (2, rows + 1, heads, p, n))
+    return (slab, jnp.int32(1),
+            jax.random.normal(k[1], (rows, heads, p), jnp.bfloat16),
+            jax.nn.softplus(jax.random.normal(k[2], (rows, heads)) - 1.0),
+            -jnp.exp(jax.random.normal(k[3], (heads,))),
+            jax.random.normal(k[4], (rows, groups, n), jnp.bfloat16),
+            jax.random.normal(k[5], (rows, groups, n), jnp.bfloat16))
+
+
+def _assert_kernel_equals_the_jnp_step(args, mask):
+    """y and state to this file's tolerances; the masked rows, the other
+    layer and the rows past the batch bit for bit."""
+    slab, layer, rows = args[0], int(args[1]), len(mask)
+    mask = jnp.asarray(mask)
+    y_j, s_j = ssd.ssm_state_update(*args, mask, kernel=False)
+    y_k, s_k = ssd.ssm_state_update(*args, mask, kernel=True, interpret=True)
+    scale = float(jnp.max(jnp.abs(y_j)))
+    # the same f32 products, summed in another order (on the MXU since PR 46)
+    np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_j),
+                               atol=2e-5 * max(1.0, scale / 8))
+    np.testing.assert_allclose(np.asarray(s_k), np.asarray(s_j), atol=1e-6)
+    for row in range(slab.shape[1]):
+        if row >= rows or not bool(mask[row]):
+            assert np.array_equal(np.asarray(s_k[layer, row]),
+                                  np.asarray(slab[layer, row]))
+        else:
+            assert not np.array_equal(np.asarray(s_k[layer, row]),
+                                      np.asarray(slab[layer, row]))
+    assert np.array_equal(np.asarray(s_k[:layer]), np.asarray(slab[:layer]))
+    return y_k
+
+
+#: P, N, H, G of the three configurations the kernel serves, and the heads a
+#: program takes there; the rows alone are scaled down
+SERVED = {
+    "granite: 128 heads of [64, 128], one group, 32 a program":
+        ((64, 128, 128, 1), 32),
+    "nemotron: 8 groups of 16 heads of [64, 128], two groups a program":
+        ((64, 128, 128, 8), 32),
+    "falcon-h1: 2 groups of 16 heads of [128, 256], 8 a program":
+        ((128, 256, 32, 2), 8),
+}
+
+
+@pytest.mark.parametrize("shape", list(SERVED))
+def test_kernel_at_the_served_shapes_equals_the_jnp_step(shape):
+    """The three shapes differ in every number the read-out depends on: vregs
+    a head 8 / 8 / 32, heads a program 32 / 32 / 8, groups 1 / 8 / 2."""
+    (p, n, heads, groups), block = SERVED[shape]
+    assert ssd._head_block(heads, groups, 4 * p * n) == block
+    _assert_kernel_equals_the_jnp_step(
+        _step_args(3, heads, p, n, groups, seed=11), [True, False, True])
+
+
+def test_kernel_at_a_block_of_one_head(monkeypatch):
+    p, n, heads, groups = 64, 128, 4, 2
+    monkeypatch.setattr(ssd, "_STATE_BLOCK_BYTES", 4 * p * n + 4)
+    assert ssd._STATE_BLOCK_BYTES // (4 * p * n) == 1
+    assert ssd._head_block(heads, groups, 4 * p * n) == 1
+    _assert_kernel_equals_the_jnp_step(
+        _step_args(2, heads, p, n, groups, seed=12), [False, True])
+
+
+def test_adjacent_groups_of_a_block_read_their_own_b_and_c(monkeypatch):
+    """A program of several whole groups (nemotron's two): head ``j`` reads
+    row ``j // heads_a_group`` of the block's B and C. With group 1's B and C
+    moved and group 0's kept, exactly the heads of group 1 move, in both
+    programs of a row of four groups."""
+    p, n, heads, groups = 8, 128, 16, 4
+    monkeypatch.setattr(ssd, "_STATE_BLOCK_BYTES", 8 * 4 * p * n)
+    assert ssd._head_block(heads, groups, 4 * p * n) == 8       # two groups
+    args = _step_args(2, heads, p, n, groups, seed=13)
+    y = _assert_kernel_equals_the_jnp_step(args, [True, True])
+    b, c = args[5], args[6]
+    moved = (*args[:5], b.at[:, 1].set(b[:, 2]), c.at[:, 1].set(-c[:, 1]))
+    y_moved = _assert_kernel_equals_the_jnp_step(moved, [True, True])
+    differs = np.any(np.asarray(y_moved != y), axis=(0, 2))     # by head
+    assert differs.tolist() == [False] * 4 + [True] * 4 + [False] * 8
+
+
+def test_the_read_out_on_the_mxu_is_an_f32_sum():
+    """``_row_sums``: three bfloat16 pieces of each product against a block
+    of ones, f32 accumulation: every lane the row's sum, to a rounding of
+    f32 where one bfloat16 pass reads a hundredth; a sum that cancels keeps
+    its small terms."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (16, 256)) * 7.0
+    x = x.at[0].set(jnp.where(jnp.arange(256) % 2 == 0, 1e4, -1e4))
+    x = x.at[0, 5].add(0.375)
+    got = np.asarray(ssd._row_sums(x, 128))
+    want = np.sum(np.asarray(x, np.float64), axis=1)
+    assert got.shape == (16, 128) and np.all(got == got[:, :1])
+    np.testing.assert_allclose(got[:, 0], want, atol=3e-5, rtol=0)
+    one_pass = np.asarray(jnp.sum(x.astype(jnp.bfloat16).astype(jnp.float32),
+                                  axis=1))
+    assert np.max(np.abs(one_pass - want)) > 1e-2
+
+
 def test_conv_over_a_chunk_equals_the_conv_token_by_token():
     K, C, T = 4, 12, 10
     k = jax.random.split(jax.random.PRNGKey(9), 4)
