@@ -114,10 +114,12 @@ BUILTIN_SCENARIOS: list[dict[str, Any]] = [
         # injected MemoryError on a prefill-chunk page growth preempts the
         # request mid-chunked-prefill; resume must continue chunking from the
         # saved position and reproduce the unfaulted stream bit-for-bit,
-        # with no page refs or orphans leaked
+        # with no page refs or orphans leaked. Two asks in a row fail: the
+        # one made for a chunk's step planned ahead of the drain before it
+        # launches nothing, and the next round's own ask preempts
         "faults": [{"point": "scheduler.prefill_chunk",
                     "spec": {"kind": "raise", "exc": "MemoryError",
-                             "mode": "once", "after": 1}}],
+                             "mode": "once", "after": 1, "n": 2}}],
         "invariants": ["exactly_one_terminal", "expected_errors",
                        "streams_match_baseline", "engine_accounting"],
         "expect_stats": {"preemptions": [1, None]},

@@ -425,6 +425,13 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                  "Held emits that ran behind the next launch, under the "
                  "device's work; the rest were flushed first (a cancel, a "
                  "preemption, nothing to launch)"),
+                ("llm_mixed_steps_total",
+                 "mixed_step dispatches (a step that carries a prompt's "
+                 "chunk, or a speculating engine's draft spans)"),
+                ("llm_mixed_steps_chained_total",
+                 "Of them, those launched off the device outputs of a "
+                 "mixed_step that was still undrained (a prompt's next "
+                 "chunk, known before the drain)"),
                 ("llm_control_rows_uploads_total",
                  "Dispatches that found the host-owned rows (a slot's "
                  "sampling and termination rows, the active mask) changed "

@@ -48,6 +48,15 @@ alternating.
   speculation as the ring's, for the one launch that used to wait. A
   ``mixed_step`` launched ahead of a held emit is never discarded, and
   whatever reads or ends a stream on the host flushes first.
+- A prompt's next chunk behind the one in flight: a mixed round is a launch
+  half and a drain half (``_dispatch_mixed``, ``_commit_mixed``), and where
+  the host knows what the next ``mixed_step`` computes before this one's
+  tokens are read (a plain step advances every running row by one; the
+  lane is the prompt's ids) the next step is launched off this one's
+  device outputs BEFORE this one is drained, as a decode chunk chains off
+  the ring's tail (``_chains_mixed``). Steps that carry a prompt's chunks
+  follow one another on the device with no host work between them; the
+  next round starts at the chained step's drain half.
 - Device-side termination: stop-token matching (per-slot padded stop-id
   rows), the max-tokens bound and the window bound are evaluated INSIDE the
   decode program against a device-resident ``finished`` mask — a finished
@@ -345,6 +354,33 @@ class _HeldEmit:
     #: the pass's phases, taken where it ended (``_PhaseClock.take``): the
     #: flushed emit's time belongs to the pass it runs in
     clock: tuple[dict[str, list[float]], float, float]
+
+
+@dataclass
+class _MixedStep:
+    """A launched-but-undrained ``mixed_step``: the LAUNCH half of a mixed
+    round (``_dispatch_mixed``), kept until its drain half
+    (``_commit_mixed``) has read its tokens. Between the two the host may
+    queue what comes next behind it, off its device outputs (``rec``): the
+    decode chunks of ``_mixed_ring_span``, or the next prompt chunk's step
+    (``chained`` on THAT step). It is never in the ring and never discarded:
+    its drain commits it (``_close_round``, rule 1)."""
+
+    toks_dev: Any         # [N] (or [N, spec_w + 1]) tokens, counters appended
+    rec: _InflightChunk   # the step's device outputs: what is chained off it
+    plan: list            # (slot, state, chunk): the prompt chunks it carries
+    finals: list          # (slot, state): the prompts whose last chunk it is
+    spec_plan: list       # (slot, state, drafts): an all-rows step's spans
+    positions: int        # what the dispatch computes (the round's record)
+    t0: float             # the launch half's start, monotonic and wall
+    wall0: float
+    chained: bool         # launched off a step that was still undrained
+    spanned: int = 0      # decode chunks chained off it
+
+    def carried(self) -> dict[int, int]:
+        """slot -> the tokens of its prompt this step carries: what its
+        commit will add to ``prefill_pos``."""
+        return {slot: chunk for slot, _, chunk in self.plan}
 
 
 #: what the scheduler thread can be doing: the loop is TILED by these, every
@@ -935,6 +971,10 @@ class ContinuousBatchingEngine:
         #: the emit of the round whose drain emptied the ring, until the
         #: next launch is queued in front of it (_close_round)
         self._held: Optional[_HeldEmit] = None
+        #: the ``mixed_step`` a round launched behind the one it drained (a
+        #: prompt's next chunk, known before the drain), until the next
+        #: round drains it (_decode_round_mixed, _chains_mixed)
+        self._mixed: Optional[_MixedStep] = None
         self._lookahead_depth = config.resolve_lookahead_depth()
         #: batched speculative decoding: k draft tokens per speculating slot
         #: per round, verified as a q_len=k+1 ragged span in the mixed-batch
@@ -989,6 +1029,8 @@ class ContinuousBatchingEngine:
                        "llm_admission_ring_waits_total",
                        "llm_drains_ring_empty_total",
                        "llm_emits_deferred_total",
+                       "llm_mixed_steps_total",
+                       "llm_mixed_steps_chained_total",
                        "llm_control_rows_uploads_total",
                        "llm_loose_row_programs_total",
                        "llm_attn_pages_walked_total",
@@ -1398,7 +1440,7 @@ class ContinuousBatchingEngine:
             # what ends a stream on the host flushes first: tokens drained
             # before the cancel precede its terminal, and if they ended the
             # stream the cancel raced that terminal
-            if self._flush_held_emit() and self.slots[slot] is not state:
+            if self._settle() and self.slots[slot] is not state:
                 continue
             self._cancel_slot(slot, state, reason, kind)
         # ids that matched nothing raced a terminal (finished/preempt-shed in
@@ -1988,9 +2030,10 @@ class ContinuousBatchingEngine:
                 try:
                     if self._stop.is_set():
                         # stopped between a drain and the launch its emit
-                        # would have followed: the tokens are the streams',
-                        # whoever ends them next
-                        self._flush_held_emit()
+                        # would have followed, or with a step launched ahead
+                        # of its drain: the tokens are the streams', whoever
+                        # ends them next
+                        self._settle()
                         return
                     if not self._loop_pass():
                         self._clock.to("wait")
@@ -2009,12 +2052,25 @@ class ContinuousBatchingEngine:
         one round. False when there was nothing to do (the loop then waits
         to be woken).
 
-        A pass may begin with the previous round's emit HELD (its drain left
-        the device with nothing queued: ``_close_round``). The round this
-        pass launches flushes it right behind its launch; a pass that
-        launches nothing (no arrival after all, every row preempted, the
-        loop about to wait) flushes it before it returns, so no token waits
-        longer than one launch."""
+        A round is a launch half and a drain half, and a pass need not hold
+        both halves of the same one. What may be in flight, or held, as a
+        pass begins, one of:
+
+        - the RING of decode chunks: admission waits for it (``_admit``),
+          the round drains its oldest chunk and tops it up;
+        - an UNDRAINED ``mixed_step`` (``self._mixed``: a prompt's next
+          chunk, launched by the previous round behind the step it
+          drained): the round starts at its drain half, having first
+          queued what comes next behind it (``_decode_round_mixed``);
+          a cancel or a preemption drains and commits it first
+          (``_settle``), a resume waits a round for it;
+        - the previous round's emit HELD (its drain left the device with
+          nothing queued: ``_close_round``): the round this pass launches
+          flushes it right behind its launch; a pass that launches nothing
+          (no arrival after all, every row preempted, the loop about to
+          wait) flushes it before it returns, so no token waits longer than
+          one launch;
+        - or nothing."""
         held = self._held
         # cancels/deadlines apply at the round boundary: BEFORE admission (a
         # lapsed pending entry must never take the slot this pass is about
@@ -2033,7 +2089,8 @@ class ContinuousBatchingEngine:
         admitted = self._admit()
         # prefilling slots are work too: mixed-batch rounds must run even
         # before any slot reaches decode phase
-        ran = bool(self.active.any() or self._prefill_slots)
+        ran = bool(self.active.any() or self._prefill_slots
+                   or self._mixed is not None)
         if ran:
             self._decode_round()
         if held is not None and self._held is held:
@@ -2049,12 +2106,18 @@ class ContinuousBatchingEngine:
         engine off its ``.params``). Single-threaded by construction: runs on
         the scheduler thread (crash) or after the thread joined (close).
         Tokens of a held emit were drained before the fault: they go out
-        first, then the terminals."""
+        first, then the terminals. What is undrained is dropped, the ring's
+        chunks and a ``mixed_step`` launched ahead of its drain alike: no
+        stream is left to owe its tokens to, and after a fault inside an
+        emit a later step's tokens would leave a gap in a stream that a
+        pool's failover replays (the loop's clean stop has drained the step
+        before it returns: ``_loop_body``)."""
         try:
             self._flush_held_emit()
         except Exception:  # noqa: BLE001 — the teardown must reach every stream
             logger.exception("the held emit failed during teardown")
         self._ring.clear()
+        self._mixed = None
         with self._cancel_lock:
             # every in-flight/queued request gets its error terminal below;
             # a pending cancel for one of them must not re-fire later
@@ -2389,10 +2452,25 @@ class ContinuousBatchingEngine:
         is admitted, planned and launched ahead of those token events. What
         the held emit will free is not there yet: a slot (and the pages) of
         a row it finishes becomes free at the flush, one launch later, and
-        is never handed out early."""
+        is never handed out early.
+
+        A ``mixed_step`` launched ahead of its drain (``self._mixed``: a
+        prompt's next chunk, queued behind the step the last round drained)
+        is not in the ring and holds an arrival back no more than a held
+        emit does: the arrival is admitted now, as it was when that chunk's
+        step was launched a pass later, and joins the lane behind the
+        prompts already there (what admission writes is the host's, and a
+        free slot is no row of the step in flight; a state row seeded from a
+        snapshot is queued behind it). What that step's commit will bring
+        is not there yet: the pages of a prompt whose final chunk it carries
+        reach the prefix tree at its drain. A RESUME waits for that drain,
+        one round: it writes the committed device carry, which the step in
+        flight is ahead of (``_chains_mixed`` chains nothing meanwhile)."""
         if self._ring:
             if self._admission_waiting():
                 bump_counter("llm_admission_ring_waits_total")
+            return 0
+        if self._mixed is not None and self._suspended:
             return 0
         failpoint("scheduler.admit")
         admitted = self._resume_suspended()
@@ -2716,10 +2794,13 @@ class ContinuousBatchingEngine:
         prefill too: the saved pages cover prefill_pos tokens and chunking
         continues from there on resume. ``soft_yielded`` marks a tenant
         soft-quota yield: resume defers it while other tenants have pending
-        work (see _resume_suspended). A held emit is flushed first: the
-        record parks the stream as its client has seen it (``emitted``, the
-        last token), and a row those tokens finished has nothing to park."""
-        if self._flush_held_emit() and self.slots[slot] is not state:
+        work (see _resume_suspended). What is in flight or held is settled
+        first (``_settle``: a step launched ahead of its drain is drained
+        and committed, a held emit flushed): the record parks the stream as
+        its client has seen it (``emitted``, the last token) at the lengths
+        the device has reached, and a row those tokens finished has nothing
+        to park."""
+        if self._settle() and self.slots[slot] is not state:
             return
         chain = state.chain
         is_prefill = state.phase == "prefill"
@@ -2908,10 +2989,13 @@ class ContinuousBatchingEngine:
         closes the pass. ONE emit routine, run at one of two points, by what
         the drain left behind:
 
-        - chunks still in flight (a steady decode round, a mixed step with
-          chunks chained off it): at once. The device works under it.
+        - something still in flight (a steady decode round, a mixed step
+          with chunks chained off it, or with the next prompt chunk's step
+          launched off it before this drain: ``self._mixed``): at once. The
+          device works under it.
         - NOTHING UNDRAINED (the ring's last chunk ahead of an arrival, a
-          prompt's chunk that is not its last, an engine with no lookahead):
+          prompt's last known chunk ahead of one, a step whose successor
+          could not be known before its drain, an engine with no lookahead):
           the device would wait out every token event, so the emit is HELD
           and the loop goes on to what it does next anyway: ``_admit`` off
           the empty ring, plan, upload and launch of the arrival's or the
@@ -2926,31 +3010,54 @@ class ContinuousBatchingEngine:
           emit bumps ``_epoch``.
 
         Two rules, because the step launched ahead may carry a prompt's
-        chunk. (1) A ``mixed_step`` launched ahead of a held emit is NEVER
-        DISCARDED (a chunk's K/V and, with recurrent state, its state
+        chunk. (1) A ``mixed_step`` launched ahead, of a held emit or (one
+        case more: ``_chains_mixed``) of the DRAIN of the step before it, is
+        NEVER DISCARDED (a chunk's K/V and, with recurrent state, its state
         advance are not replayable): it is not in the ring, its drain
-        commits it whatever the flush found, and rows the flushed emit
-        finished are masked out of its own emit (its decode rows are read
-        after the flush), as ``_discard_ring`` already does for every model
-        with state. Decode chunks launched ahead follow the ring's rule.
-        (2) Whatever reads or ends a stream on the host FLUSHES FIRST:
-        ``_service_cancellations`` for a slot it ends, ``_preempt_slot``,
-        ``_fail_all_inflight``, the loop's stop, a pass that launches
-        nothing, a speculating engine before ``_spec_candidates`` is asked
-        (``_decode_round``), and an emit that itself hands a row off to
-        another engine (``hold`` False: it is not held at all). A slot
-        freed by a finish in a held emit is free at the flush, one launch
-        later: ``_admit`` never sees it early, and its rule (wait for an
-        empty ring) is unchanged, since the held round IS drained and
-        committed."""
-        if not self._ring:
+        commits it whatever an emit found in the meantime, and rows such an
+        emit finished are masked out of its own emit (its decode rows are
+        read at its commit), as ``_discard_ring`` already does for every
+        model with state. A host-fallback stop found in a step's emit cannot
+        un-run the step chained behind it: the row is masked out there.
+        Decode chunks launched ahead follow the ring's rule.
+        (2) Whatever reads or ends a stream on the host SETTLES FIRST
+        (``_settle``: drain and commit the step in flight, flush the held
+        emit): ``_service_cancellations`` for a slot it ends,
+        ``_preempt_slot``, the loop's stop; a pass that launches nothing
+        flushes, as does a speculating engine before ``_spec_candidates``
+        is asked (``_decode_round``; it chains no step); an emit that
+        itself hands a row off to another engine is not held at all
+        (``hold`` False) and no step is chained off its step; and
+        ``_fail_all_inflight`` flushes what was drained and drops what was
+        not. A slot freed by a finish in a held emit is free at the flush,
+        one launch later: ``_admit`` never sees it early, and its rule
+        (wait for an empty ring) is unchanged, since the held round IS
+        drained and committed."""
+        in_flight = bool(self._ring) or self._mixed is not None
+        if not in_flight:
             bump_counter("llm_drains_ring_empty_total")
-        if self._ring or not hold:
+        if in_flight or not hold:
             self._emit_round(emit, record)
             return
         # the record's pass ends here; the emit's time is the next pass's
         self._held = _HeldEmit(emit, record, self._clock.take())
         self.last_round_at = self._held.clock[2]
+
+    def _settle(self) -> bool:
+        """Rule 2 of ``_close_round``, for whatever is about to read or end
+        a stream on the host: DRAIN AND COMMIT the ``mixed_step`` in flight
+        (launched behind the one the last round drained), then flush the
+        emit that is held (that step's own, now). Decode chunks in the ring
+        are not drained here: they follow the ring's rules. Returns whether
+        anything was emitted. The clock comes back to the caller's phase."""
+        step, self._mixed = self._mixed, None
+        if step is None:
+            return self._flush_held_emit()
+        back = self._clock.phase
+        self._commit_mixed(step)        # nothing is in flight: its emit is held
+        self._flush_held_emit()
+        self._clock.to(back)
+        return True
 
     def _flush_held_emit(self, behind_launch: bool = False) -> bool:
         """Run the held emit, if there is one, and close its round's record.
@@ -2990,13 +3097,18 @@ class ContinuousBatchingEngine:
                       positions: Optional[int] = None,
                       block_out: Optional[tuple[int, int]] = None,
                       local_assignments: Optional[int] = None,
+                      chained: bool = False,
                       clock: Optional[tuple] = None) -> None:
         """One timing-schema owner for every round kind. ``ts`` is the
         round's wall-clock start; /v1/monitoring/rounds exports these entries
         as Chrome trace events, which need absolute timestamps.
         ``positions`` is what the dispatch computed: ``B + R*Qc`` for a lane
         step, ``B x Qmax`` for an all-rows (speculative) one, and ``B`` a
-        step for a decode round (the default).
+        step for a decode round (the default). ``depth``: what the device
+        had queued behind the drained dispatch while the host emitted it
+        (decode chunks in the ring, a mixed step's chained successor: 0 is
+        a drain that left nothing undrained); ``chained``: a mixed step
+        that was itself launched off a still-undrained step.
 
         The record closes the PASS: ``phases`` is the scheduler thread's
         time since the previous record by phase, ``[wall_ms, cpu_ms,
@@ -3042,6 +3154,7 @@ class ContinuousBatchingEngine:
             "chunk_tokens": chunk_tokens,
             "positions": positions,
             "depth": depth,
+            "chained": chained,
             "spec_tokens": spec_tokens,
             "active": self.active_slots,
             # a block model: the forwards of the round's dispatch, and the
@@ -3203,7 +3316,8 @@ class ContinuousBatchingEngine:
                     force_length=last_of_chunk and next_chunk_overflows)
 
     # ------------------------------------------------------------ mixed round
-    def _plan_prefill_chunks(self, max_rows: Optional[int] = None
+    def _plan_prefill_chunks(self, max_rows: Optional[int] = None,
+                             ahead: Optional[dict[int, int]] = None
                              ) -> list[tuple[int, _SlotState, int]]:
         """Assign this round's prompt chunks: fill ``prefill_budget_tokens``
         across prefilling slots FIFO (admission order), at most ``max_rows``
@@ -3212,8 +3326,11 @@ class ContinuousBatchingEngine:
         all-rows speculative step takes as many as the budget covers. The
         head slot always gets at least one token, so a tiny budget cannot
         stall prefill; a budget of 0 means one unbounded chunk (whole
-        remaining prompt)."""
+        remaining prompt). ``ahead``: the tokens of a slot's prompt that an
+        undrained step already carries (slot -> chunk): the plan starts
+        where that step's commit will leave ``prefill_pos``."""
         budget = self.config.prefill_budget_tokens
+        ahead = ahead or {}
         left = budget if budget > 0 else float("inf")
         plan: list[tuple[int, _SlotState, int]] = []
         for slot in list(self._prefill_slots):
@@ -3222,7 +3339,8 @@ class ContinuousBatchingEngine:
             state = self.slots[slot]
             if state is None or state.phase != "prefill":
                 continue  # defensive: the deque tracks prefill-phase slots
-            remaining = self._prefill_target(state) - state.prefill_pos
+            pos = state.prefill_pos + ahead.get(slot, 0)
+            remaining = self._prefill_target(state) - pos
             chunk = int(min(remaining, left)) if left != float("inf") \
                 else remaining
             if self._block:
@@ -3240,7 +3358,7 @@ class ContinuousBatchingEngine:
                 # end on the next snapshot boundary rather than past it, so
                 # that every row passes through the boundaries it crosses
                 chunk = min(chunk, self._state_unit
-                            - state.prefill_pos % self._state_unit)
+                            - pos % self._state_unit)
             if chunk <= 0:
                 continue
             plan.append((slot, state, chunk))
@@ -3248,20 +3366,22 @@ class ContinuousBatchingEngine:
         return plan
 
     def _grow_chain_prefill(self, slot: int, state: _SlotState,
-                            chunk: int) -> None:
-        """Extend a prefilling slot's chain to cover its next chunk's pages
-        (a chunk may cross page boundaries). Raises MemoryError when the pool
-        cannot serve it even after eviction — the caller preempts-to-host and
-        chunking resumes where it left off."""
+                            end: int) -> None:
+        """Extend a prefilling slot's chain to cover its next chunk's pages,
+        up to the prompt position ``end`` where the chunk stops (a chunk may
+        cross page boundaries). Raises MemoryError when the pool cannot
+        serve it even after eviction — the caller preempts-to-host and
+        chunking resumes where it left off; for a step planned ahead of a
+        drain the caller launches nothing, and the next round asks again on
+        committed state (``_dispatch_mixed``)."""
         # armed MemoryError forces the preempt-mid-chunked-prefill path with
         # no real pool pressure (faultlab mixed-prefill-preempt scenario)
         failpoint("scheduler.prefill_chunk")
         chain = state.chain
-        needed = state.prefill_pos + chunk
-        if self.pool.pages_for(needed) <= len(chain):
+        if self.pool.pages_for(end) <= len(chain):
             return
         before = len(chain)
-        self.pool.extend_chain(chain, needed)
+        self.pool.extend_chain(chain, end)
         self.page_table[slot, before: len(chain)] = chain[before:]
 
     def _finish_prefill(self, slot: int, state: _SlotState) -> float:
@@ -3483,36 +3603,25 @@ class ContinuousBatchingEngine:
         (the next synchronous round preempts properly). Returns the number of
         chunks chained. Speculative dispatches never span (see the call
         site), so the record's device lengths always match the host mirror
-        +1 here and the horizons below stay exact."""
+        +1 here and the horizons below stay exact. A step launched ahead of
+        its predecessor's drain comes here a round later, that predecessor
+        committed; if a host-fallback stop in the predecessor's emit bumped
+        the epoch since, the step's active mask still runs the stopped row
+        and nothing is chained off it."""
         depth = self._lookahead_depth
         if (depth <= 0 or len(finals) != len(self._prefill_slots)
                 or self._suspended or not self._pending.empty()
-                or self._stop.is_set()):
+                or self._stop.is_set() or rec.epoch != self._epoch):
             return 0
         k = self._chunk_tokens
-        max_seq = self.config.max_seq_len
-        flipping = {slot for slot, _ in finals}
         chained = 0
         tail = rec
         for h in range(depth):
             self._clock.to("capacity")
             # the mixed step's own advance + h+1 chained chunks
-            horizon = self._step_tokens + (h + 1) * k
-            for slot in range(self.n_slots):
-                state = self.slots[slot]
-                if state is None:
-                    continue
-                if self.active[slot]:
-                    L = int(self.lengths[slot])
-                elif slot in flipping:
-                    L = self._prefill_target(state)
-                else:
-                    continue
-                try:
-                    self._extend_chain_to(slot, state,
-                                          min(L + horizon, max_seq))
-                except MemoryError:
-                    return chained  # cap the span; sync rounds preempt
+            if not self._extend_chains_behind(
+                    finals, self._step_tokens + (h + 1) * k):
+                return chained  # cap the span; sync rounds preempt
             self._ring.append(self._dispatch_chunk(after=tail))
             tail = self._ring[-1]
             self._lookahead_stats["dispatched"] += 1
@@ -3527,25 +3636,42 @@ class ContinuousBatchingEngine:
         separation, so an arrival burst never stalls in-flight streams
         behind a prefill drain, and the dispatch computes the tokens it has
         (``n_slots + width`` positions).
-        No ring is in flight here: prefill work is admitted and resumed
-        only off an empty ring (``_admit``), none is built while a prompt
-        has chunks left, and the ``spec_only`` entry is taken off a drained
-        ring. The other way around it is this round that builds one: when
-        its plan drains the prefill queue, lookahead chunks chain off THIS
-        dispatch's outputs (_mixed_ring_span), so the mixed→pure-decode
-        transition keeps the pipeline full.
 
-        Where the emit runs (``_close_round``): with chunks chained off the
-        step, at once, after its commit; with nothing chained (a prompt has
-        chunks left, another arrival is queued) it is HELD, and the next
-        pass's launch goes out first. In turn this round flushes the emit a
-        previous drain held, right behind its own launch and span and
-        before its own drain. A step launched ahead of a held emit is never
-        discarded (its chunk's K/V and state advance cannot be replayed):
-        rows the flushed emit finished are masked out of this round's emit,
-        whose ``decode_rows`` are read after the flush. The commit carries
-        what the next plan reads: ``prefill_pos`` and a final chunk's flip
-        (``_finish_prefill``).
+        A round is a LAUNCH HALF (``_dispatch_mixed``: capacity, plan,
+        upload, launch, the state snapshot, the transfer's start) and a
+        DRAIN HALF (``_commit_mixed``: the one sync, the commit, the emit),
+        and between the two it queues what comes next behind its step:
+
+        - the step drains the prefill queue: decode chunks chain off its
+          outputs (``_mixed_ring_span``), so the mixed→pure-decode
+          transition keeps the pipeline full;
+        - a prompt chunk is left (this prompt's next, another admitted
+          slot's) and the host knows NOW what its step computes
+          (``_chains_mixed``): that step is launched off this one's device
+          carry before this one is drained, as a decode chunk chains off the
+          ring's tail, and stays in ``self._mixed`` for the next round,
+          which begins at this point (its launch half is done), so steps
+          follow one another on the device with no host work between them;
+        - neither: the drain leaves nothing in flight and the emit is held
+          for the next pass's launch (``_close_round``).
+
+        No ring is in flight at the start: prefill work is admitted and
+        resumed only off an empty ring (``_admit``), none is built while a
+        prompt has chunks left, and the ``spec_only`` entry is taken off a
+        drained ring.
+
+        Where the emit runs (``_close_round``): with chunks or a step
+        chained off this step, at once, after its commit; with nothing
+        chained it is HELD, and the next pass's launch goes out first. In
+        turn this round flushes the emit a previous drain held, right
+        behind its own launches and before its own drain. A step launched
+        ahead of an emit or of a drain is never discarded (its chunk's K/V
+        and state advance cannot be replayed): rows an emit finished in the
+        meantime are masked out of its own emit, whose ``decode_rows`` are
+        read at its commit. The commit carries what the next plan reads:
+        ``prefill_pos`` and a final chunk's flip (``_finish_prefill``); a
+        step chained ahead of it is planned against what that commit WILL
+        leave (``_dispatch_mixed``).
 
         Speculative rounds (scheduler_spec_k > 0): eligible greedy rows with
         a live ngram proposal become q_len=1+d draft spans in the SAME
@@ -3553,33 +3679,124 @@ class ContinuousBatchingEngine:
         token budget with prefill chunks — chunks first (a cold prompt beats
         an optimistic draft), leftovers to drafts. Accept/reject, per-row
         advance (1..k+1 tokens) and rollback all run on device; the emit
-        loop below just walks each row's -1-terminated token list through
+        loop just walks each row's -1-terminated token list through
         the ordinary _emit_token path, so stop/limit/charging/cancel
         semantics are untouched. ``spec_only=True`` is the pure-decode entry
         (no prefill slots): returns False without dispatching when no draft
         survives planning, and the caller falls back to the plain chunk
         round."""
+        assert not self._ring, "a mixed round met chunks in flight"
+        step, self._mixed = self._mixed, None
+        if step is None:
+            step = self._dispatch_mixed(spec_only=spec_only)
+            if step is None:
+                return False
+        # ring spanning: chain lookahead chunks off the step BEFORE its
+        # drain, so the device keeps working while the host emits + flips.
+        # Speculative dispatches deliberately do NOT span: their proposals
+        # almost always recur next round (repetitive text is why they fired),
+        # and a chained plain chunk would spend k weight passes on k tokens
+        # where the next verify span spends ONE on up to k+1 — the ring
+        # instead rebuilds the moment proposals dry up (_can_extend_ring).
+        if not step.spec_plan:
+            step.spanned = self._mixed_ring_span(step.rec, step.finals)
+        # or the next prompt chunk's step, where the host knows it already
+        if not step.spanned and self._chains_mixed(step):
+            self._mixed = self._dispatch_mixed(after=step)
+        # the step and what was chained off it are queued: the emit held
+        # back at the last drain runs under them (``_close_round``, rule 1:
+        # whatever it finds, THIS step is drained and committed below). A
+        # host-fallback stop in it drops only the chunks chained behind,
+        # and the step itself is still undrained
+        self._flush_held_emit(behind_launch=True)
+        self._commit_mixed(step)
+        return True
+
+    def _chains_mixed(self, step: _MixedStep) -> bool:
+        """Whether the next ``mixed_step`` may be launched off ``step``
+        while ``step`` is undrained: everything the next step takes from the
+        host has to be known before ``step``'s tokens are. From what the
+        round can see, no option: a plain step advances every running row by
+        exactly one and its lane (a prompt's ids, where the chunk starts,
+        whether it is final) is host data, so it chains; a speculating
+        engine does not (a draft span's advance is 1..k+1, and the next
+        proposals come from text not yet emitted), nor a block model (a row
+        advances by what the forward committed), nor a step whose emit hands
+        its flipped rows to another engine (they leave this one). And
+        nothing is queued ahead of what has to act on committed state first:
+        a resume (it writes the committed carry), a preemption marked for
+        the next capacity pass, the loop's stop. Whether a next chunk
+        exists, and whether its pages can be had, is the plan's to find
+        (``_dispatch_mixed``)."""
+        return not (self.spec_k or self._block
+                    or (step.finals and self._exports_flips())
+                    or self._suspended or self._soft_yield
+                    or self._stop.is_set())
+
+    def _exports_flips(self) -> bool:
+        """A prefill-role engine hands a flipped row off inside the emit of
+        the step that flipped it (``_export_handoff``)."""
+        return self.pd_role == "prefill" and self._handoff_sink is not None
+
+    def _dispatch_mixed(self, after: Optional[_MixedStep] = None,
+                        spec_only: bool = False) -> Optional[_MixedStep]:
+        """The launch half of a mixed round: capacity, plan, the lane's
+        upload and the launch of one ``mixed_step`` (async: the return holds
+        futures), then the snapshot of a row whose chunk ended on a boundary
+        and the start of the tokens' transfer. None when there is nothing to
+        launch (every planned slot preempted or flipped, no draft).
+
+        ``after`` chains the step onto a still-undrained step's device
+        outputs, as ``_dispatch_chunk(after=)`` chains a decode chunk: the
+        carry (last tokens, keys, lengths, finished) is ``after``'s, and the
+        plan is made against the state ``after``'s commit WILL leave: each
+        of its chunks landed (``prefill_pos + chunk``), its final chunks'
+        rows flipped (active, at the prompt's length), every running row one
+        token longer. A row that ends inside ``after`` by length, window or
+        a device-matched stop is frozen by its finished mask, as for a
+        chained decode chunk. Chained, nothing is preempted here (that reads
+        committed state): a chain the pool cannot grow means no step, None,
+        and the round goes on as one with nothing to chain."""
         t0 = self._clock.to("capacity")
         wall0 = time.time()
-        assert not self._ring, "a mixed round met chunks in flight"
-        # capacity: decode rows keep a full chunk of headroom (the invariant
-        # every round preserves); prefill rows cover their chunk's pages.
-        # MemoryError on either path preempts-to-host.
-        self._ensure_chunk_capacity(self._chunk_tokens)
+        n = self.n_slots
+        ahead = after.carried() if after else {}
         plan: list[tuple[int, _SlotState, int]] = []
+        if after is None:
+            # capacity: decode rows keep a full chunk of headroom (the
+            # invariant every round preserves); prefill rows cover their
+            # chunk's pages. MemoryError on either path preempts-to-host.
+            self._ensure_chunk_capacity(self._chunk_tokens)
+            active = self.active
+            carry = (self._last_tokens, self._lengths_dev,
+                     self._finished_dev, self._slot_keys)
+        else:
+            active = self.active.copy()
+            active[[slot for slot, _ in after.finals]] = True
+            carry = (after.rec.last, after.rec.lengths_dev,
+                     after.rec.finished_dev, after.rec.keys)
         if not spec_only:
             # one slot's chunk a step (the lane), FIFO; an engine that
             # speculates plans every slot the budget covers, for the all-rows
             # step a round with draft spans takes
             self._clock.to("plan")
             planned = self._plan_prefill_chunks(
-                max_rows=None if self.spec_k else LANE_ROWS)
+                max_rows=None if self.spec_k else LANE_ROWS, ahead=ahead)
             self._clock.to("capacity")
+            if after is not None and not (
+                    planned and self._extend_chains_behind(
+                        after.finals,
+                        self._step_tokens + self._chunk_tokens)):
+                return None
             for slot, state, chunk in planned:
                 try:
-                    self._grow_chain_prefill(slot, state, chunk)
+                    self._grow_chain_prefill(
+                        slot, state,
+                        state.prefill_pos + ahead.get(slot, 0) + chunk)
                     plan.append((slot, state, chunk))
                 except MemoryError:
+                    if after is not None:
+                        return None
                     self._preempt_slot(slot, state)
         self._clock.to("plan")
         # speculation shares the ragged token budget: prefill chunks draw
@@ -3597,12 +3814,11 @@ class ContinuousBatchingEngine:
             # speculates: the next loop pass runs a plain decode round /
             # resumes from host (spec_only: the caller falls through to the
             # plain round immediately)
-            return False
+            return None
         if not spec_plan:
             # no draft survived: the lane step, whose chunk is the head's (a
             # later slot's grown chain serves its own step)
             plan = plan[:LANE_ROWS]
-        n = self.n_slots
         # static dispatch width: the prefill bucket covering the largest
         # chunk — and the spec span width when rows speculate — rounded to
         # the kernel's q_block (bounded compile variants)
@@ -3619,8 +3835,8 @@ class ContinuousBatchingEngine:
         hist = np.zeros(n_spans, np.int32)
         spec_lens = np.zeros(n, np.int32)
         if spec_plan:
-            q_lens[self.active] = 1  # decode rows
-        sample = self.active.copy()
+            q_lens[active] = 1  # decode rows
+        sample = active.copy()
         final_mask = np.zeros(n, bool)
         final_lens = np.zeros(n, np.int32)
         #: the dispatch's by-slot columns (_unpack_lane); a flipping row's
@@ -3628,7 +3844,7 @@ class ContinuousBatchingEngine:
         by_slot = np.zeros((n, _LANE_COLS + self._block), np.int32)
         finals: list[tuple[int, _SlotState]] = []
         for lane, (slot, state, chunk) in enumerate(plan):
-            pos = state.prefill_pos
+            pos = state.prefill_pos + ahead.get(slot, 0)
             r = slot if spec_plan else lane
             q_ids[r, :chunk] = state.prompt_ids[pos: pos + chunk]
             q_lens[r] = chunk
@@ -3660,7 +3876,7 @@ class ContinuousBatchingEngine:
         # (_unpack_lane; the spans' last column: the lane's slot, or a
         # slot's draft length in the all-rows step)
         by_slot[:, :4] = np.column_stack(
-            [self.active, sample, final_mask, final_lens])
+            [active, sample, final_mask, final_lens])
         lane_host = np.concatenate([
             by_slot.ravel(),
             np.column_stack([q_ids, q_lens, hist, spec_lens if spec_plan
@@ -3672,20 +3888,20 @@ class ContinuousBatchingEngine:
         self._sync_rows(active=False)
         lane = self._dev(lane_host)
         self._clock.to("launch")
+        last, lengths, fin, keys = carry
         toks_dev, *outs = (self._spec_step_fn if spec_plan
                            else self._mixed_step_fn)(
             self.params, *self.pool.cache_operands(), self._rows_dev, lane,
-            self._last_tokens, self._lengths_dev, self._finished_dev,
-            self._slot_keys)
+            last, lengths, fin, keys)
         self._clock.to("launch", starved=False)  # the device has work
         last_o, keys_o, lens_o, fin_o, active_o = self.pool.adopt(outs)
         # the flip's active mask is the device's own from here on
-        self._active_dev, self._active_up = active_o, self.active | final_mask
+        self._active_dev, self._active_up = active_o, active | final_mask
         if self._state_unit:
             # a snapshot of each row whose chunk ended on a boundary, as THIS
-            # call left it: before anything chained below advances the row
+            # call left it: before anything chained behind advances the row
             for slot, state, chunk in plan:
-                end = state.prefill_pos + chunk
+                end = state.prefill_pos + ahead.get(slot, 0) + chunk
                 if end % self._state_unit == 0:
                     row = self.pool.take_snapshot(slot)
                     if row is not None:
@@ -3694,33 +3910,65 @@ class ContinuousBatchingEngine:
             toks_dev.copy_to_host_async()  # non-blocking D2H start
         except AttributeError:
             pass
-        # ring spanning: chain lookahead chunks off this dispatch BEFORE the
-        # drain, so the device keeps working while the host emits + flips.
-        # Speculative dispatches deliberately do NOT span: their proposals
-        # almost always recur next round (repetitive text is why they fired),
-        # and a chained plain chunk would spend k weight passes on k tokens
-        # where the next verify span spends ONE on up to k+1 — the ring
-        # instead rebuilds the moment proposals dry up (_can_extend_ring).
-        mixed_rec = _InflightChunk(toks_dev, last_o, keys_o, lens_o, fin_o,
-                                   active_o, self._epoch)
-        spanned = 0 if spec_plan else self._mixed_ring_span(mixed_rec,
-                                                            finals)
-        # the step and what was chained off it are queued: the emit held
-        # back at the last drain runs under them (``_close_round``, rule 1:
-        # whatever it finds, THIS step is drained and committed below). A
-        # host-fallback stop in it drops only the chunks chained behind,
-        # and the step itself is still undrained
-        self._flush_held_emit(behind_launch=True)
+        bump_counter("llm_mixed_steps_total")
+        if after is not None:
+            bump_counter("llm_mixed_steps_chained_total")
+        return _MixedStep(
+            toks_dev, _InflightChunk(toks_dev, last_o, keys_o, lens_o, fin_o,
+                                     active_o, self._epoch),
+            plan, finals, spec_plan, positions, t0, wall0,
+            chained=after is not None)
+
+    def _extend_chains_behind(self, finals: list[tuple[int, _SlotState]],
+                              horizon: int) -> bool:
+        """The capacity pass of a dispatch chained off an undrained mixed
+        step, whose ``finals`` it flips: every running row (from its
+        committed length) and every flipped row (from its prompt's length)
+        covers ``horizon`` more tokens, that step's own advance included.
+        Grown without preempting, as the ring's extensions are
+        (``_extend_chain_to``): False where the pool cannot serve one, and
+        the capacity pass of the next round made on committed state
+        preempts as ever."""
+        max_seq = self.config.max_seq_len
+        flipping = {slot for slot, _ in finals}
+        for slot in range(self.n_slots):
+            state = self.slots[slot]
+            if state is None:
+                continue
+            if self.active[slot]:
+                length = int(self.lengths[slot])
+            elif slot in flipping:
+                length = self._prefill_target(state)
+            else:
+                continue
+            try:
+                self._extend_chain_to(slot, state,
+                                      min(length + horizon, max_seq))
+            except MemoryError:
+                return False
+        return True
+
+    def _commit_mixed(self, step: _MixedStep) -> None:
+        """The drain half of a mixed round: the one sync (the step's
+        tokens), the commit (the device carry, the host's length mirror,
+        each chunk's progress and a final chunk's flip), and the emit
+        through ``_close_round``: at once under what was chained off the
+        step (decode chunks in the ring, the next chunk's step in
+        ``self._mixed``), held where nothing was."""
+        plan, finals, spec_plan = step.plan, step.finals, step.spec_plan
+        wall0, n = step.wall0, self.n_slots
+        # what the device has behind this step while the host emits it
+        depth = step.spanned + (self._mixed is not None)
         self._clock.to("drain", starved=False)
-        toks = np.asarray(toks_dev, np.int32)  # sync-point: mixed-round drain (AS04)
+        toks = np.asarray(step.toks_dev, np.int32)  # sync-point: mixed-round drain (AS04)
         # where nothing was chained off this dispatch the device waits from
         # here to the next launch
-        round_ms = (self._clock.to("commit", starved=not self._ring)
-                    - t0) * 1000.0
-        self._last_tokens = last_o
-        self._slot_keys = keys_o
-        self._lengths_dev = lens_o
-        self._finished_dev = fin_o
+        round_ms = (self._clock.to("commit", starved=not depth)
+                    - step.t0) * 1000.0
+        self._last_tokens = step.rec.last
+        self._slot_keys = step.rec.keys
+        self._lengths_dev = step.rec.lengths_dev
+        self._finished_dev = step.rec.finished_dev
         # spec dispatches return [n, spec_w + 1]: -1-sentinel emit columns
         # plus the accept-count column (one drain carries both); plain mixed
         # returns [n] — normalize to 2-D so one emit loop serves both
@@ -3733,6 +3981,9 @@ class ContinuousBatchingEngine:
             toks2d, accepts = toks[:, :-1], toks[:, -1]
         else:
             toks2d, accepts = toks[:, None], None
+        # the rows that ran AND still run: one an emit finished since the
+        # launch (a held emit flushed behind it, the emit of the step this
+        # one was chained off) has a token here that nobody is owed
         decode_rows = [s for s in range(n) if self.active[s]]
         old_lengths = self.lengths.copy()
         if not spec_plan:    # a draft span rides the ragged kernel
@@ -3765,7 +4016,7 @@ class ContinuousBatchingEngine:
                                 "row_forwards": int(ran[slot])}
                          for slot in decode_rows}
         self._emit_decode_spans(wall0, round_ms, lookahead=False,
-                                rows=decode_rows, tokens=1, depth=spanned,
+                                rows=decode_rows, tokens=1, depth=depth,
                                 row_tokens=row_tokens, row_attrs=row_attrs)
         # acceptance accounting BEFORE the emit loop (a mid-row finish
         # clears the slot state): totals, the accept-length histogram, the
@@ -3826,7 +4077,7 @@ class ContinuousBatchingEngine:
                 self._emit_first_token(slot, state, tok, dur_ms)
             if self._block:
                 return self._emit_block_chunk(toks2d, ran, old_lengths,
-                                              depth=spanned,
+                                              depth=depth,
                                               rows=decode_rows)
             for slot in decode_rows:
                 state = self.slots[slot]
@@ -3835,7 +4086,7 @@ class ContinuousBatchingEngine:
                 n_row = int((toks2d[slot] >= 0).sum())
                 extra = row_attrs.get(slot, {}) if row_attrs else {}
                 record_event(state.request_id, "decode_chunk", slot=slot,
-                             tokens=n_row, depth=spanned, **extra)
+                             tokens=n_row, depth=depth, **extra)
                 for j in range(n_row):
                     if not self.active[slot]:
                         break  # a host-authoritative finish truncates the row
@@ -3848,21 +4099,19 @@ class ContinuousBatchingEngine:
                                      force_length=no_room)
             return None
 
-        # at once under the chunks chained off this step, or held for the
-        # next launch (a prompt's next chunk, another arrival's, a resync).
-        # A prefill-role engine hands a flipped row off inside this emit
+        # at once under what was chained off this step, or held for the
+        # next launch (another arrival's chunk, a resync). A prefill-role
+        # engine hands a flipped row off inside this emit
         # (``_export_handoff``), and what ends a stream here is not held
-        exports = bool(first) and self.pd_role == "prefill" \
-            and self._handoff_sink is not None
+        exports = bool(first) and self._exports_flips()
         self._close_round(emit, hold=not exports, lookahead=False, ts=wall0,
                           mixed=bool(plan),
                           chunk_tokens=sum(c for _, _, c in plan),
-                          depth=spanned,
+                          depth=depth,
                           spec_tokens=sum(len(dr) for _, _, dr in spec_plan),
                           kind=("mixed" if decode_rows else "prefill")
-                          if plan else "decode", positions=positions,
-                          local_assignments=local)
-        return True
+                          if plan else "decode", positions=step.positions,
+                          local_assignments=local, chained=step.chained)
 
     def _decode_round(self) -> None:
         self.occupancy_samples.append(self.active_slots)
@@ -3872,7 +4121,7 @@ class ContinuousBatchingEngine:
             # proposer): a held emit goes out before ``_spec_candidates``
             # or ``_plan_spec`` is asked
             self._flush_held_emit()
-        if self._prefill_slots:
+        if self._prefill_slots or self._mixed is not None:
             self._decode_round_mixed()
             return
         if self.spec_k and not self._ring and self._spec_round_safe() \

@@ -216,7 +216,8 @@ def test_preempt_mid_prefill_and_resume_equals_the_uninterrupted_run():
     restores = _counter("llm_state_restores_total")
     fp.configure(0)
     fp.arm("scheduler.prefill_chunk",
-           {"kind": "raise", "exc": "MemoryError", "mode": "once", "after": 2})
+           {"kind": "raise", "exc": "MemoryError", "mode": "once", "after": 2,
+            "n": 2})
     try:
         got, stats, sched = _run(_cfg(prefill_budget_tokens=16), prompts,
                                  max_tokens=16)

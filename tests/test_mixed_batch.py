@@ -308,7 +308,10 @@ def test_prefill_storm_rounds_bounded_by_chunk_budget():
 def test_preempt_mid_chunked_prefill_stream_identical():
     """An injected MemoryError on a prefill-chunk page growth preempts the
     request mid-prefill (pages saved to host); after resume the stream must
-    be bit-identical to the unfaulted run, and the pool must not leak refs."""
+    be bit-identical to the unfaulted run, and the pool must not leak refs.
+    The pool is out of pages for two asks in a row: one made for a step
+    planned ahead of a drain launches nothing, and it is the next round's
+    own ask, on committed state, that preempts."""
     rng = np.random.default_rng(11)
     prompts = [rng.integers(3, 900, 40 + 5 * i).tolist() for i in range(3)]
     samplings = [SamplingParams(max_tokens=16) for _ in range(3)]
@@ -319,7 +322,7 @@ def test_preempt_mid_chunked_prefill_stream_identical():
     fp.configure(0)
     fp.arm("scheduler.prefill_chunk",
            {"kind": "raise", "exc": "MemoryError", "mode": "once",
-            "after": 2})
+            "after": 2, "n": 2})
     try:
         sched = ContinuousBatchingEngine(cfg, seed=0)
         col = _Collector(3)

@@ -64,11 +64,16 @@ def _run(eng, done, n, limit=400):
 
 @pytest.fixture(scope="module", params=sorted(MODELS))
 def served(request):
-    """Three requests served by hand-made passes; the records, and the
-    thread's time from the clock's reset to the last record."""
+    """Three requests served by hand-made passes, the third arriving while
+    the first two decode (it waits for the ring, whose last chunk's drain
+    leaves nothing in flight); the records, and the thread's time from the
+    clock's reset to the last record."""
     eng = _manual(request.param)
-    done = _submit(eng, 3)
+    done = _submit(eng, 2)
     _, _, t0 = eng._clock.take()          # the pass starts here
+    while not eng._ring:
+        eng._loop_pass()
+    _submit(eng, 1, prompt=26, done=done)
     _run(eng, done, 3)
     records = list(eng.round_timings)
     elapsed_ms = (eng.last_round_at - t0) * 1000.0
@@ -371,11 +376,15 @@ def test_an_arrivals_step_is_launched_before_the_held_emit(model):
 
 @pytest.mark.parametrize("model", sorted(HELD))
 def test_a_prompts_next_chunk_is_launched_before_the_held_emit(model):
-    """Between two chunks of one prompt nothing is chained (the plan did not
-    drain the prefill queue), so the step's drain leaves the device with
-    nothing: the next chunk's step is launched first, and the running row's
-    token, the ``prefill_chunk`` event and the record follow it."""
+    """Where the next chunk's step cannot be launched ahead of this one's
+    drain (a block model's rows advance by what the forward committed; for
+    the others the test says so in the engine's place) nothing is chained
+    between two chunks of one prompt, so the step's drain leaves the device
+    with nothing: the next chunk's step is launched first, and the running
+    row's token, the ``prefill_chunk`` event and the record follow it."""
     eng = _held_engine(model, decode_lookahead=2)
+    if model != "tiny-sdar":
+        eng._chains_mixed = lambda step: False
     try:
         got, new = [], []
         _ask(eng, 32, 60, got)
@@ -405,6 +414,63 @@ def test_a_prompts_next_chunk_is_launched_before_the_held_emit(model):
         assert second["phases"]["emit"][2] == 0.0 < second["phases"]["emit"][0]
         assert third["depth"] == 2
         assert bool(new) == (model != "tiny-sdar"), "the flip's first token"
+        _run(eng, new, 8)
+    finally:
+        eng.shutdown()
+
+
+STEPS = ("llm_mixed_steps_total", "llm_mixed_steps_chained_total")
+
+
+@pytest.mark.parametrize("model", ["tiny-llama", "tiny-falcon-h1"])
+def test_a_prompts_next_chunk_is_launched_before_the_drain(model):
+    """Between two chunks of one prompt the host knows the next step before
+    this one's tokens are read: it is launched off this step's device
+    outputs ahead of the drain, the drain leaves it in flight, and the emit
+    runs at once under it. Nothing is held and the device is never
+    starved from the prompt's first chunk to the chunks chained off its
+    last."""
+    eng = _held_engine(model, decode_lookahead=2)
+    try:
+        got, new = [], []
+        _ask(eng, 32, 60, got)
+        while len(eng._ring) < 2:
+            eng._loop_pass()
+        _ask(eng, 96, 8, new)                 # three chunks of 32
+        before = [_count(n) for n in SERIES + STEPS]
+        records = len(eng.round_timings)
+        while not eng._prefill_slots:
+            eng._loop_pass()
+        # the pass that admitted it launched chunk 1, chunk 2 behind it,
+        # and drained chunk 1
+        state = eng.slots[eng._prefill_slots[0]]
+        assert state.prefill_pos == 32 and state.prefill_chunks == 1
+        assert eng._mixed is not None and eng._mixed.chained
+        assert eng._held is None and not eng._ring
+        first = eng.round_timings[-1]
+        assert first["kind"] == "mixed" and first["chunk_tokens"] == 32
+        assert first["depth"] == 1 and not first["chained"]
+        assert _starved_of(first, "emit") == 0.0
+        tokens = len(got)
+        seen = _switches(eng)
+        eng._loop_pass()                      # launches chunk 3, drains 2
+        assert state.prefill_pos == 64 and eng._mixed.finals
+        assert (seen.index("launch") < seen.index("drain")
+                < seen.index("emit")), seen
+        assert len(got) == tokens + 1
+        second = eng.round_timings[-1]
+        assert second["chained"] and second["depth"] == 1
+        assert sum(v[2] for v in second["phases"].values()) == 0.0
+        eng._loop_pass()                      # chunk 3: decode chunks chain
+        third = eng.round_timings[-1]
+        assert third["chained"] and third["depth"] == 2
+        assert eng._mixed is None and len(eng._ring) == 2
+        assert new, "the flip's first token"
+        # three steps, two of them chained; the one drain that left nothing
+        # in flight was the ring's last chunk ahead of the arrival
+        assert [_count(n) - b for n, b in zip(SERIES + STEPS, before)] \
+            == [1, 1, 3, 2]
+        assert len(eng.round_timings) - records >= 4
         _run(eng, new, 8)
     finally:
         eng.shutdown()

@@ -9,6 +9,7 @@ change *when* device work runs, never *what* any request receives.
 import functools
 import threading
 import time
+import uuid
 
 import numpy as np
 import pytest
@@ -551,6 +552,8 @@ def test_the_rings_series_are_at_zero_from_engine_build():
              "llm_admission_ring_waits_total",
              "llm_drains_ring_empty_total",
              "llm_emits_deferred_total",
+             "llm_mixed_steps_total",
+             "llm_mixed_steps_chained_total",
              "llm_control_rows_uploads_total",
              "llm_loose_row_programs_total",
              "llm_attn_pages_walked_total",
@@ -698,6 +701,8 @@ def test_the_rings_series_are_on_metrics_before_the_first_request():
                  "llm_admission_ring_waits_total",
                  "llm_drains_ring_empty_total",
                  "llm_emits_deferred_total",
+                 "llm_mixed_steps_total",
+                 "llm_mixed_steps_chained_total",
                  "llm_control_rows_uploads_total",
                  "llm_loose_row_programs_total",
                  "llm_attn_pages_walked_total",
@@ -912,13 +917,17 @@ _TIMELINE_KEYS = ("event", "tokens", "pos", "of", "reason", "chunks",
                   "prompt_tokens", "blocks")
 
 
-def _held_run(model, depth, parents_order):
+def _held_run(model, depth, parents_order, chain=False):
     """Four seeded requests, passes by hand: A (greedy, ends by max-tokens)
     and B (sampled, a stop set wider than ``device_stop_width``: the host
     alone sees it stop) start together; C (four chunks) and D (two) arrive
     as B's chunk runs, so six mixed steps follow one another with nothing
-    chained between them and A's and B's tokens ride held emits. Returns
-    the streams, each request's flight record and what the flushes met."""
+    chained between them and A's and B's tokens ride held emits (``chain``
+    False: the test says in the engine's place that no step may be launched
+    ahead of a drain, which is what a model that cannot know its next step
+    says; True: the engine decides, and every one of those steps but the
+    first is launched off the undrained one before it). Returns the
+    streams, each request's flight record and what the flushes met."""
     from cyberfabric_core_tpu.modkit.flight_recorder import default_recorder
 
     rng = np.random.default_rng(11)
@@ -933,7 +942,10 @@ def _held_run(model, depth, parents_order):
     arrive = {0: (0, 1), 1: (2, 3)}         # pass -> submitted ahead of it
     eng = _manual(_cfg(model=model, decode_lookahead=depth,
                        prefill_budget_tokens=32, **HELD_FAMILIES[model]))
-    ids = [f"held-{model}-{depth}-{parents_order}-{i}" for i in range(4)]
+    if not chain:
+        eng._chains_mixed = lambda step: False
+    ids = [f"held-{model}-{depth}-{parents_order}-{chain}-{i}"
+           for i in range(4)]
     col = _Collector(4)
     seen = {"flushed_behind_a_chunk": 0, "finished_there": 0,
             "stopped_there": 0}
@@ -949,7 +961,8 @@ def _held_run(model, depth, parents_order):
             seen["stopped_there"] += eng._epoch - epoch
         return held
     eng._flush_held_emit = spy
-    series = ("llm_drains_ring_empty_total", "llm_emits_deferred_total")
+    series = ("llm_drains_ring_empty_total", "llm_emits_deferred_total",
+              "llm_mixed_steps_total", "llm_mixed_steps_chained_total")
     before = [_counter(n) for n in series]
     try:
         for n in range(600):
@@ -964,8 +977,9 @@ def _held_run(model, depth, parents_order):
         assert col.done.is_set(), eng.stats()
         seen["prefill"] = (eng.prefill_chunks, eng.chunked_prefill_tokens)
         seen["discarded"] = eng._lookahead_stats["discarded"]
-        seen["ring_empty"], seen["deferred"] = (
-            _counter(n) - b for n, b in zip(series, before))
+        (seen["ring_empty"], seen["deferred"], seen["steps"],
+         seen["chained"]) = (_counter(n) - b for n, b in zip(series, before))
+        seen["chained_records"] = sum(r["chained"] for r in eng.round_timings)
         seen["depth_0"] = sum(r["depth"] == 0 for r in eng.round_timings)
         seen["records"] = len(eng.round_timings)
     finally:
@@ -1012,6 +1026,31 @@ def test_a_held_emit_never_changes_a_stream(model, depth):
     assert want_seen["deferred"] < seen["deferred"]
     # 32 + 32 + 128 + 64 tokens in 1 + 1 + 4 + 2 chunks, none twice
     assert seen["prefill"] == want_seen["prefill"] == (8, 256)
+    assert seen["chained"] == want_seen["chained"] == 0
+
+
+@pytest.mark.parametrize("model,depth", [("tiny-llama", 0), ("tiny-llama", 2),
+                                         ("tiny-falcon-h1", 0)])
+def test_a_step_chained_off_an_undrained_one_never_changes_a_stream(model,
+                                                                    depth):
+    """The same four requests with the engine left to decide: six mixed
+    steps follow one another and all but the first are launched off the
+    undrained step before them, so A's max-tokens finish and B's
+    host-fallback stop are found in emits that run UNDER a step already
+    launched with those rows in it. Every stream's tokens, finish reason
+    and event order are the parents'; each chunk is computed once; the
+    drains that leave nothing in flight are the few around the steps."""
+    want, want_records, want_seen = _parents_order(model, depth)
+    col, records, seen = _held_run(model, depth, False, chain=True)
+    assert col.tokens == want.tokens and col.finishes == want.finishes
+    assert col.finishes[0] == "length" and col.finishes[1] == "stop"
+    for i, (got, ref) in enumerate(zip(records, want_records)):
+        assert got == ref, f"request {i}: event order"
+    assert seen["prefill"] == want_seen["prefill"] == (8, 256)
+    assert seen["steps"] == want_seen["steps"]
+    assert seen["chained"] == seen["chained_records"] >= 5, seen
+    assert seen["ring_empty"] < want_seen["ring_empty"], seen
+    assert seen["deferred"] == seen["ring_empty"]      # none flushed first
 
 
 def _end_cancel(eng, rid):
@@ -1080,3 +1119,336 @@ def test_what_ends_or_reads_a_stream_flushes_the_held_emit_first(model, end):
             assert new == tokens + [(-1, terminal)]
     finally:
         eng.shutdown()
+
+
+# ------------------------------------- a prompt's next chunk behind the one in flight
+#
+# A mixed round is a launch half and a drain half. Where the host knows what
+# the next `mixed_step` computes before this one's tokens are read (a plain
+# step: every running row one token on, the lane a prompt's ids) the next
+# step is launched off this one's device outputs ahead of the drain
+# (`_chains_mixed`, `_dispatch_mixed(after=)`). Chained or not, a stream is
+# what it was: the reference below is the same engine with the predicate
+# answered False from the test (no setting does that).
+
+CHAIN_FAMILIES = {                      # K/V pages; a latent page; state
+    "tiny-llama": {},
+    "tiny-kimi-share4": {"quantization": "int8"},
+    "tiny-nemotron-h-share4-8l": {}}
+_STEPS = ("llm_mixed_steps_total", "llm_mixed_steps_chained_total")
+
+
+class _ChainRun:
+    """A (``a``: its sampling) and B (seeded sampling) decode with the ring
+    as deep as it gets; then C arrives, a prompt of three chunks of the
+    budget. Passes by hand. ``at_chunk_1`` runs right after the pass that
+    admitted C: chunk 1's step is drained and committed, and where the
+    engine chains, chunk 2's step is in flight behind it, undrained."""
+
+    def __init__(self, model, chain=True, a=None, b=None, c=None, **over):
+        self.eng = eng = _manual(_cfg(
+            model=model, decode_lookahead=2, prefill_budget_tokens=32,
+            **CHAIN_FAMILIES.get(model, {}), **over))
+        if not chain:
+            eng._chains_mixed = lambda step: False
+        rng = np.random.default_rng(23)
+        self.prompts = {n: rng.integers(3, 200, size).tolist()
+                        for n, size in (("A", 32), ("B", 32), ("C", 96))}
+        self.sampling = {
+            "A": a or SamplingParams(max_tokens=40, temperature=0.0),
+            "B": b or SamplingParams(max_tokens=40, temperature=0.9,
+                                     top_p=0.95, seed=77),
+            "C": c or SamplingParams(max_tokens=6, temperature=0.0)}
+        self.events = {n: [] for n in "ABC"}
+        self.ids = {n: f"chain-{uuid.uuid4().hex[:8]}-{n}" for n in "ABC"}
+        self.before = [_counter(n) for n in _STEPS]
+
+    def ask(self, name):
+        self.eng.submit(self.prompts[name], self.sampling[name],
+                        lambda ev: self.events[name].append(
+                            (ev.token_id, ev.finished)),
+                        request_id=self.ids[name])
+
+    def tokens(self, name):
+        return [t for t, _ in self.events[name] if t >= 0]
+
+    def finishes(self, name):
+        return [f for _, f in self.events[name] if f]
+
+    def steps(self):
+        """(mixed steps launched, those chained) since the run began."""
+        return tuple(_counter(n) - b for n, b in zip(_STEPS, self.before))
+
+    def run(self, at_chunk_1=None, asked="ABC"):
+        eng = self.eng
+        try:
+            for name in asked[:2]:
+                self.ask(name)
+            _passes_until(eng, lambda: eng.active.sum() == 2
+                          and len(eng._ring) == 2)
+            self.rows_before_c = eng.state_rows_in_use()
+            self.ask("C")
+            _passes_until(eng, lambda: bool(eng._prefill_slots))
+            self.state_c = eng.slots[eng._prefill_slots[0]]
+            assert self.state_c.prefill_pos == 32
+            if at_chunk_1 is not None:
+                at_chunk_1(self)
+            _passes_until(eng, lambda: all(
+                self.finishes(n) for n in asked))
+            eng._loop_pass()
+            eng._flush_held_emit()
+            self.records = list(eng.round_timings)
+            self.counted = self.steps()
+            self.snapshots = eng.pool.stats().get("state_snapshots_taken")
+            # the state rows left: the snapshots that pages of the tree own
+            self.rows_left = eng.state_rows_in_use()
+            _drain_clean(eng)
+        finally:
+            eng.shutdown()
+        return self
+
+
+def _sampled(on: bool, max_tokens: int, seed: int) -> SamplingParams:
+    return SamplingParams(max_tokens=max_tokens, temperature=0.8, top_p=0.9,
+                          seed=seed) if on else \
+        SamplingParams(max_tokens=max_tokens, temperature=0.0)
+
+
+@functools.cache
+def _unchained(model, sampled):
+    return _ChainRun(model, chain=False, a=_sampled(sampled, 40, 5),
+                     c=_sampled(sampled, 6, 9)).run()
+
+
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "seeded"])
+@pytest.mark.parametrize("model", sorted(CHAIN_FAMILIES))
+def test_a_three_chunk_prompt_chains_and_every_stream_is_the_unchained_one(
+        model, sampled):
+    """(a) Chunk 2's step is launched off chunk 1's undrained step and
+    chunk 3's off chunk 2's; the decode rows beside them and the prompt's
+    own answer are token for token those of the engine that chains nothing,
+    greedy and under seeded sampling."""
+    want = _unchained(model, sampled)
+    seen = {}
+
+    def in_flight(run):
+        step = run.eng._mixed
+        seen["chunk 2"] = (step is not None and step.chained
+                           and not step.finals, run.eng._held)
+    got = _ChainRun(model, a=_sampled(sampled, 40, 5),
+                    c=_sampled(sampled, 6, 9)).run(in_flight)
+    assert seen["chunk 2"] == (True, None)
+    for name in "ABC":
+        assert got.tokens(name) == want.tokens(name), name
+        assert got.finishes(name) == want.finishes(name) == ["length"]
+    assert (len(got.tokens("A")), len(got.tokens("C"))) == (40, 6)
+    # A's and B's own steps (a chunk each, B's known when A's is launched),
+    # then C's three: chunk 2 and chunk 3 chained
+    assert got.counted == (5, 3) and want.counted == (5, 0)
+    assert [r["chained"] for r in got.records if r["mixed"]] == [
+        False, True, False, True, True]
+    assert not any(r["chained"] for r in want.records)
+    assert got.snapshots == want.snapshots
+
+
+@pytest.mark.parametrize("model", sorted(CHAIN_FAMILIES))
+def test_a_row_that_ends_by_max_tokens_under_a_chained_step_ends_once(model):
+    """(b) A's last token is the one chunk 1's step samples. When the host
+    reads it, chunk 2's step is already launched with A's row running in
+    its lane: the device froze the row by its limit, the host ends the
+    stream once, with ``length`` and its ``max_tokens``, and the chained
+    step's emit has nothing for it."""
+    want = _unchained(model, False)
+    at_chunk_1 = {}
+    _ChainRun(model).run(lambda run: at_chunk_1.update(
+        n=len(run.tokens("A"))))
+    n = at_chunk_1["n"]
+    assert 4 < n < 40
+    seen = {}
+
+    def ended(run):
+        seen["finishes"] = run.finishes("A")
+        seen["in flight"] = run.eng._mixed is not None
+        seen["completed"] = run.eng.requests_completed
+    got = _ChainRun(model, a=SamplingParams(max_tokens=n, temperature=0.0)
+                    ).run(ended)
+    assert seen == {"finishes": ["length"], "in flight": True,
+                    "completed": 1}
+    assert got.events["A"][-1] == (want.tokens("A")[n - 1], "length")
+    assert got.tokens("A") == want.tokens("A")[:n]
+    assert got.finishes("A") == ["length"]
+    assert got.tokens("B") == want.tokens("B")
+    assert got.tokens("C") == want.tokens("C")
+    assert got.counted == (5, 3) and got.eng.requests_completed == 3
+
+
+def _cancel(victim):
+    def act(run):
+        eng = run.eng
+        step, before = eng._mixed, len(run.tokens("A"))
+        assert step is not None and step.chained and run.state_c.prefill_pos == 32
+        assert not run.finishes(victim)
+        deferred = _counter("llm_emits_deferred_total")
+        real, order = eng._cancel_slot, []
+        eng._cancel_slot = lambda *a: (order.append(
+            (run.state_c.prefill_pos, eng._mixed, len(run.tokens("A")))),
+            real(*a))[1]
+        eng.cancel(run.ids[victim], "test")
+        eng._loop_pass()
+        # settled first: the chained step was drained and COMMITTED (chunk
+        # 2 landed) and its emit went out (A's token of that step) before
+        # the row was ended; none of it counts as an emit behind a launch
+        assert order == [(64, None, before + 1)]
+        assert run.events[victim][-1] == (-1, "cancelled")
+        assert _counter("llm_emits_deferred_total") == deferred
+    return act
+
+
+def _host_stop(run):
+    """B's stop set is wider than ``device_stop_width`` (the host alone sees
+    it stop) and holds the token chunk 1's step sampled for B."""
+    assert run.finishes("B") == ["stop"] and run.eng._mixed is not None
+    run.b_tokens = len(run.tokens("B"))
+    run.epoch = run.eng._epoch
+
+
+@pytest.mark.parametrize("end", ["cancel-a-row", "cancel-the-prompt",
+                                 "host-stop"])
+@pytest.mark.parametrize("model", sorted(CHAIN_FAMILIES))
+def test_a_stream_ended_while_a_chained_step_is_in_flight(model, end):
+    """(c) A cancel (of a decode row; of the prompt whose chunk is in the
+    step) lands while chunk 2's step is in flight: the step is drained and
+    committed and its tokens emitted FIRST, then the row ends, once. A
+    host-fallback stop found in chunk 1's emit cannot un-run chunk 2's
+    step, which had the row in it: the row is absent from that step's
+    emit. Either way the pages (and the state row) are freed once and the
+    other streams are the unchained ones."""
+    want = _unchained(model, False)
+    if end == "host-stop":
+        at_chunk_1 = {}
+        _ChainRun(model).run(lambda run: at_chunk_1.update(
+            b=run.tokens("B")))
+        *earlier, tok = at_chunk_1["b"]
+        assert tok not in earlier
+        stops = (tok, *range(10_000, 10_020))
+        got = _ChainRun(model, b=SamplingParams(
+            max_tokens=40, temperature=0.9, top_p=0.95, seed=77,
+            stop_token_ids=stops)).run(_host_stop)
+        assert got.tokens("B") == want.tokens("B")[:len(earlier) + 1]
+        assert got.b_tokens == len(earlier) + 1     # nothing after the stop
+        assert got.finishes("B") == ["stop"] and got.eng._epoch >= got.epoch
+        others = "AC"
+    else:
+        victim = "A" if end == "cancel-a-row" else "C"
+        got = _ChainRun(model).run(_cancel(victim))
+        assert got.finishes(victim) == ["cancelled"]
+        assert got.tokens(victim) == want.tokens(victim)[
+            :len(got.tokens(victim))]
+        assert got.eng.cancellations == {"test": 1}
+        others = "ABC".replace(victim, "")
+    for name in others:
+        assert got.tokens(name) == want.tokens(name), name
+        assert got.finishes(name) == ["length"]
+    # every chunk computed once, whoever ended meanwhile (a cancelled
+    # prompt's third chunk never ran)
+    assert got.eng.prefill_chunks == (4 if end == "cancel-the-prompt" else 5)
+    if end == "cancel-the-prompt":
+        # with state: A's and B's rows went with their slots, and the
+        # snapshots C took on the way (at 32, at 64) went back with it, so
+        # what is left is what A's and B's pages own
+        assert got.rows_left == max(got.rows_before_c - 2, 0)
+    else:
+        assert got.rows_left == want.rows_left
+
+
+@pytest.mark.parametrize("model", sorted(CHAIN_FAMILIES))
+def test_no_pages_for_the_next_chunk_means_no_chained_step(model):
+    """(d) The pool has no page for the next chunk when the step that
+    would carry it is to be launched ahead of the drain: nothing is chained
+    and nothing is preempted there; the round ends as one with nothing to
+    chain (its emit held), and the next pass's own capacity pass takes the
+    pages as ever."""
+    want = _unchained(model, False)
+    run = _ChainRun(model)
+    grow = run.eng._grow_chain_prefill
+
+    def no_pages_ahead(slot, state, end):
+        if end > state.prefill_pos + 32:    # past the chunk not yet drained
+            raise MemoryError("no page for a chunk planned ahead")
+        return grow(slot, state, end)
+    run.eng._grow_chain_prefill = no_pages_ahead
+    seen = {}
+    got = run.run(lambda r: seen.update(in_flight=r.eng._mixed,
+                                        held=r.eng._held is not None))
+    assert seen == {"in_flight": None, "held": True}
+    # B's own chunk, planned behind A's undrained step, had its pages; no
+    # later chunk of C's had: one step chained of five
+    assert got.counted == (5, 1) and got.eng.preemptions == 0
+    for name in "ABC":
+        assert got.tokens(name) == want.tokens(name), name
+
+
+def test_a_snapshot_is_the_rows_state_after_its_own_step():
+    """(e) With recurrent state a chunk that ends on a boundary leaves a
+    snapshot of the row, taken right behind its own step and AHEAD of the
+    step chained behind it, which advances the row. The snapshots at 32 and
+    at 64 are taken with the next chunk's step about to be queued off an
+    undrained one: they equal, bit for bit, those of the engine that chains
+    nothing, and a second prompt that shares the first 64 tokens resumes
+    from the one at 64 and answers as it does there."""
+    model = "tiny-nemotron-h-share4-8l"
+    rng = np.random.default_rng(23)
+    first = rng.integers(3, 200, 96).tolist()
+    shared = first[:64] + list(range(7, 20))
+
+    def serve(chain):
+        eng = _manual(_cfg(model=model, decode_lookahead=2,
+                           prefill_budget_tokens=32))
+        if not chain:
+            eng._chains_mixed = lambda step: False
+        taken, take = [], eng.pool.take_snapshot
+        eng.pool.take_snapshot = lambda slot: taken.append(take(slot)) \
+            or taken[-1]
+        out, chained = {0: [], 1: []}, _counter(_STEPS[1])
+        try:
+            for i, prompt in enumerate((first, shared)):
+                eng.submit(prompt, SamplingParams(max_tokens=8),
+                           lambda ev, i=i: out[i].append(
+                               (ev.token_id, ev.finished)))
+                _passes_until(eng, lambda: out[i] and out[i][-1][1])
+                if i == 0:
+                    rows = [eng.pool.state_row(row) for row in taken]
+            eng._loop_pass()
+            hits = eng.pool.stats()["state_snapshot_hits"]
+            _drain_clean(eng)
+        finally:
+            eng.shutdown()
+        return out, rows, hits, _counter(_STEPS[1]) - chained
+    out, rows, hits, chained = serve(True)
+    ref_out, ref_rows, ref_hits, ref_chained = serve(False)
+    assert chained >= 2 and ref_chained == 0
+    assert len(rows) == len(ref_rows) == 3          # at 32, 64 and 96
+    for got, ref in zip(rows, ref_rows):
+        assert got.keys() == ref.keys()
+        for leaf in got:
+            np.testing.assert_array_equal(got[leaf], ref[leaf])
+    assert any((rows[0][leaf] != rows[1][leaf]).any() for leaf in rows[0])
+    assert (hits, ref_hits) == (1, 1)
+    assert out == ref_out and len(out[1]) == 8
+
+
+@pytest.mark.parametrize("model,over", [
+    ("tiny-llama", {"scheduler_spec_k": 2}),
+    ("tiny-sdar", {"decode_chunk": 10})], ids=["speculating", "blocks"])
+def test_an_engine_that_cannot_know_its_next_step_never_chains(model, over):
+    """(f) A speculating engine (a draft span advances a row by 1..k+1, and
+    the next proposals come from text not yet emitted) and a block model (a
+    row advances by what the forward committed) take a prompt of three
+    chunks as three steps with a drain between them."""
+    run = _ChainRun(model, **over)
+    seen = {}
+    run.run(lambda r: seen.update(in_flight=r.eng._mixed))
+    assert seen == {"in_flight": None}
+    steps, chained = run.counted
+    assert steps >= 5 and chained == 0
+    assert not any(r["chained"] for r in run.records)
