@@ -35,6 +35,10 @@ class ModelConfig:
     #: "solar_open2" (a stack of linear-attention layers under a gated delta
     #: rule, ``kda`` of ``layer_types``, and gated attention layers without
     #: rotary, sigmoid-routed experts beside a shared expert after each) |
+    #: "motif" (a decoder of grouped differential attention over a latent
+    #: page, three layers in four behind a window whose pages live in a page
+    #: group of their own, four residual streams mixed by hyper-connections,
+    #: PolyNorm MLPs, kimi_k2's sigmoid router over a share of the experts) |
     #: "bert" (encoder)
     architecture: str
     vocab_size: int
@@ -53,7 +57,9 @@ class ModelConfig:
     # gemma-family knobs
     #: "silu" (llama) | "gelu" (gemma GeGLU): the gate's activation in a gated
     #: MLP of three matrices; "relu2" (nemotron_h): ``relu(x W1)² W2``, an
-    #: MLP of two matrices with no gate
+    #: MLP of two matrices with no gate; "poly_norm" (motif): a gated MLP whose
+    #: gate goes through ``s (a1 z/rms(z) + a2 z²/rms(z²) + a3 z³/rms(z³)) +
+    #: clamp(b)``, rms over the MLP's whole row, coefficients a layer's own
     hidden_act: str = "silu"
     norm_weight_offset: float = 0.0   # gemma RMSNorm computes (offset + w) * x̂
     embedding_multiplier: float = 1.0  # gemma scales embeddings by sqrt(H)
@@ -173,6 +179,26 @@ class ModelConfig:
     #: ``β = 2 sigmoid(.)`` (True) or ``sigmoid(.)``: with the factor 2 the
     #: eigenvalue of ``I − β k kᵀ`` lies in (−1, 1)
     kda_allow_neg_eigval: bool = False
+    # motif (model_type Motif), each beside its published name.
+    #: sliding_window_period (with sliding_window_pattern interleave): layer
+    #: ``i`` attends over everything where ``(i + 1) % period == 0`` and
+    #: over its last ``sliding_window`` tokens otherwise (0: one window, or
+    #: none, for every layer). The two kinds cache in two PAGE GROUPS
+    #: (``runtime/paged.py``): a pool and a page table each
+    sliding_window_period: int = 0
+    #: num_noise_heads: the LAST this many of ``num_heads`` are the noise
+    #: heads of differential attention; noise head ``g`` and signal heads
+    #: ``4g..`` read latent kv group ``g`` of ``num_kv_heads``
+    num_noise_heads: int = 0
+    #: mhc_expansion_rate: residual streams a token carries (1: one residual)
+    mhc_expansion_rate: int = 1
+    #: mhc_sinkhorn_iters: alternations of row and column normalisation
+    mhc_sinkhorn_iters: int = 20
+    #: polynorm_output_scale, polynorm_bias_clamp
+    polynorm_output_scale: float = 1.0
+    polynorm_bias_clamp: float = 0.0
+    #: hidden_clamp: every sub-layer's output is clamped to +- this (0: not)
+    hidden_clamp: float = 0.0
     # bert-family extras
     layer_norm_eps: float = 1e-12
     type_vocab_size: int = 2
@@ -180,11 +206,18 @@ class ModelConfig:
 
     def __post_init__(self) -> None:
         if self.hidden_act not in ("silu", "gelu", "gelu_pytorch_tanh",
-                                   "relu2"):
+                                   "relu2", "poly_norm"):
             # fail at config time, not as silently-wrong activations at runtime
             raise ValueError(
-                f"unknown hidden_act {self.hidden_act!r} "
-                "(supported: silu, gelu, gelu_pytorch_tanh, relu2)")
+                f"unknown hidden_act {self.hidden_act!r} (supported: silu, "
+                "gelu, gelu_pytorch_tanh, relu2, poly_norm)")
+        if self.sliding_window_period and not (
+                self.is_latent and self.sliding_window):
+            raise ValueError(
+                f"{self.name}: a per-layer window (sliding_window_period "
+                f"{self.sliding_window_period}) needs a sliding_window and "
+                "kernels that take one a layer; only the latent kernels do "
+                "(K/V pages come in one page group)")
         if self.remasking not in ("low_confidence_static",
                                   "low_confidence_dynamic"):
             raise ValueError(f"unknown remasking {self.remasking!r}")
@@ -219,7 +252,7 @@ class ModelConfig:
         """The router's weights stay float32 whatever the activations' dtype
         (a score decides WHICH experts run, not only how much)."""
         return self.architecture in ("sdar_moe", "kimi_k2", "granite_hybrid",
-                                     "nemotron_h", "solar_open2")
+                                     "nemotron_h", "solar_open2", "motif")
 
     @property
     def is_latent(self) -> bool:
@@ -274,10 +307,31 @@ class ModelConfig:
 
     @property
     def kv_layers(self) -> int:
-        """Layers that cache pages: the page pool's leading dimension."""
+        """Layers that cache pages a row keeps for its whole length: the
+        page pool's leading dimension."""
+        if self.sliding_window_period:
+            return self.num_layers // self.sliding_window_period
         if not self.layer_types:
             return self.num_layers
         return self.layer_types.count("attention")
+
+    @property
+    def window_layers(self) -> int:
+        """Layers whose pages a row gives back once they lie left of its
+        window: the WINDOW page group's leading dimension (0: no such
+        group; one window for every layer keeps its pages)."""
+        return self.num_layers - self.kv_layers \
+            if self.sliding_window_period else 0
+
+    def layer_is_full(self, layer: int) -> bool:
+        """Layer ``layer`` attends over a row's whole length."""
+        return (not self.sliding_window_period
+                or (layer + 1) % self.sliding_window_period == 0)
+
+    def window_pages(self, page_size: int, queries: int = 1) -> int:
+        """The most pages the window of ``queries`` consecutive positions
+        spans."""
+        return (self.sliding_window + queries - 3) // page_size + 2
 
     @property
     def state_layers(self) -> int:
@@ -745,6 +799,50 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         layer_types=("attention", "kda", "kda", "kda") * 2,
         ssm_heads=4, ssm_head_dim=16, ssm_state=16, ssm_conv=4, ssm_chunk=8,
     ),
+    # Motif-3-Beta, config.json as published (model_type Motif, 314B-A13.2B):
+    # 53 layers of grouped differential attention over a latent page (GDLA:
+    # 80 query heads of 128 + 64, the last 16 of them noise heads, over 16
+    # latent kv groups; q_lora 1024, kv_lora 512; a sigmoid output gate), the
+    # layers with (i + 1) % 4 == 0 full and the rest behind a window of 128;
+    # rotary 10000 with no YaRN scaling applied (apply_yarn_scaling false);
+    # four residual streams under Sinkhorn-normalised hyper-connections; two
+    # leading dense layers of 12288, then 384 sigmoid-routed experts of 1280
+    # top-8 (route_norm, route_scale 2, no selection bias) beside one shared
+    # expert, every MLP gated through PolyNorm; untied head. The
+    # multi-token-prediction module (num_nextn_predict_layers 1) is a draft
+    # module and not part of the served model
+    "motif-3-beta": ModelConfig(
+        name="motif-3-beta", architecture="motif", vocab_size=220160,
+        hidden_size=4096, intermediate_size=12288, num_layers=53,
+        num_heads=80, num_kv_heads=16, head_dim=192, max_position=262144,
+        rope_theta=10000.0, rms_norm_eps=1e-5, hidden_act="poly_norm",
+        sliding_window=128, sliding_window_period=4, num_noise_heads=16,
+        num_experts=384, experts_per_token=8, q_lora_rank=1024,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, first_k_dense=2, moe_intermediate_size=1280,
+        shared_experts=1, routed_scaling_factor=2.0,
+        mhc_expansion_rate=4, mhc_sinkhorn_iters=20,
+        polynorm_output_scale=0.5, polynorm_bias_clamp=0.5,
+        hidden_clamp=1e6,
+    ),
+    # CPU-test preset of the same stack: two dense layers, then a window
+    # layer and a full one, one whole period and three window layers (every
+    # part of ``motif.layer_plan``); 10 heads = 8 + 2 over 2 groups, latent
+    # 32 + 16, a window of 24 (a page and a half of 16), 4 streams, 16
+    # experts top-4
+    "tiny-motif": ModelConfig(
+        name="tiny-motif", architecture="motif", vocab_size=512,
+        hidden_size=64, intermediate_size=128, num_layers=11, num_heads=10,
+        num_kv_heads=2, head_dim=48, max_position=1024, rope_theta=10000.0,
+        rms_norm_eps=1e-5, hidden_act="poly_norm", sliding_window=24,
+        sliding_window_period=4, num_noise_heads=2, num_experts=16,
+        experts_per_token=4, q_lora_rank=48, kv_lora_rank=32,
+        qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
+        first_k_dense=2, moe_intermediate_size=32, shared_experts=1,
+        routed_scaling_factor=2.0, mhc_expansion_rate=4,
+        mhc_sinkhorn_iters=20, polynorm_output_scale=0.5,
+        polynorm_bias_clamp=0.5, hidden_clamp=1e6,
+    ),
     "bge-base-en": ModelConfig(
         name="bge-base-en", architecture="bert", vocab_size=30522, hidden_size=768,
         intermediate_size=3072, num_layers=12, num_heads=12, num_kv_heads=12,
@@ -830,6 +928,26 @@ MODEL_CONFIGS["tiny-solar-open2-share4"] = dataclasses.replace(
     experts_held=4, expert_offset=4, vocab_held=256)
 MODEL_CONFIGS["tiny-solar-open2-share4-4l"] = MODEL_CONFIGS[
     "tiny-solar-open2-share4"].cut_to(4, "tiny-solar-open2-share4-4l")
+
+
+# share 0 of the first of 2 pipeline stages of a 64-chip deployment of
+# motif-3-beta (32 chips share each layer): layers 0-26 (the 2 dense layers,
+# 25 expert layers; 6 full layers and 21 window layers), experts 0-11 of each
+# layer's 384, rows 0-27519 of the vocabulary (an 8-way split); attention is
+# data-parallel, so every head is here
+MODEL_CONFIGS["motif-3-beta-share32-27l"] = dataclasses.replace(
+    MODEL_CONFIGS["motif-3-beta"], name="motif-3-beta-share32-27l",
+    num_layers=27, experts_held=12, expert_offset=0, vocab_held=27520,
+    max_position=8192)
+
+# a share of the tiny preset: experts 4-7 of 16, half the vocabulary; and its
+# first four layers (dense, dense, a window layer, a full one), for the CPU
+# tests that build an engine
+MODEL_CONFIGS["tiny-motif-share4"] = dataclasses.replace(
+    MODEL_CONFIGS["tiny-motif"], name="tiny-motif-share4", experts_held=4,
+    expert_offset=4, vocab_held=256)
+MODEL_CONFIGS["tiny-motif-share4-4l"] = MODEL_CONFIGS[
+    "tiny-motif-share4"].cut_to(4, "tiny-motif-share4-4l")
 
 
 def get_config(name: str) -> ModelConfig:

@@ -308,7 +308,8 @@ def forward_paged_decode(
     page_size = pool.shape[2]
     positions = lengths[None, :]
     pid, off = _decode_targets(page_table, lengths, write_mask, page_size)
-    work = decode_work(cfg, page_table, lengths + 1, pool)
+    work = decode_work(cfg, page_table, lengths + 1, pool,
+                       cfg.sliding_window)
     scale = attention_scale(cfg)
     h = embed_lookup(params["embed"], input_ids.reshape(1, B),
                      params["final_norm"].dtype)
@@ -318,7 +319,8 @@ def forward_paged_decode(
         latent, q = latent_and_query(lp, x, cfg, positions, cos_t, sin_t)
         pool = pool.at[layer, pid, off].set(latent.astype(pool.dtype))
         o = mla_decode_attention(q, pool, work, layer, rank=cfg.kv_lora_rank,
-                                 scale=scale, interpret=interpret)
+                                 scale=scale, interpret=interpret,
+                                 sliding_window=cfg.sliding_window)
         return attention_out(lp, h, o, cfg), pool
 
     h, pool, aux = _run_layers(params, cfg, h, pool, attend)
@@ -353,7 +355,7 @@ def forward_paged_mixed(
     R, Qc = input_ids.shape
     (pool,) = pools
     lay = mixed_layout(cfg, input_ids, page_table, hist, q_lens, write_mask,
-                       rows, decode, pool)
+                       rows, decode, pool, cfg.sliding_window)
     nd = lay.n_dec
     rank, scale = cfg.kv_lora_rank, attention_scale(cfg)
     h = embed_lookup(params["embed"], lay.ids, params["final_norm"].dtype)
@@ -367,12 +369,14 @@ def forward_paged_mixed(
         lane_q = q[nd:].reshape(R, Qc, *q.shape[1:]).transpose(0, 2, 1, 3)
         lane = mla_ragged_attention(
             lane_q, pool, lay.lane_table, hist, q_lens, layer, rank=rank,
-            scale=scale, interpret=interpret)
+            scale=scale, interpret=interpret,
+            sliding_window=cfg.sliding_window)
         o = lane.transpose(0, 2, 1, 3).reshape(R * Qc, -1, rank)
         if nd:
             dec = mla_decode_attention(q[:nd], pool, lay.work, layer,
                                        rank=rank, scale=scale,
-                                       interpret=interpret)
+                                       interpret=interpret,
+                                       sliding_window=cfg.sliding_window)
             o = jnp.concatenate([dec, o], axis=0)
         return attention_out(lp, h, o, cfg), pool
 

@@ -50,10 +50,32 @@ def _scaled(y: jnp.ndarray, scale) -> jnp.ndarray:
     return y if scale is None else y * scale
 
 
-def _act(x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
-    """MLP activation: SiLU (llama family), tanh-approx GeLU (gemma) or the
-    squared ReLU (nemotron_h's two-matrix MLP). Unknown values are rejected
-    at config time (ModelConfig.__post_init__)."""
+def poly_norm(z: jnp.ndarray, coef: jnp.ndarray, bias: jnp.ndarray,
+              cfg: ModelConfig) -> jnp.ndarray:
+    """PolyNorm (PolyCom, arXiv:2411.03884) over the rows of ``z`` [..., I],
+    f32: ``s (a1 z/rms(z) + a2 z²/rms(z²) + a3 z³/rms(z³)) + clamp(b)``, each
+    rms over the WHOLE row (an MLP's, or one expert's), ``coef`` [3] and
+    ``bias`` [] the unit's own, ``s`` and the clamp the configuration's."""
+    def normed(p):
+        return p * jax.lax.rsqrt(
+            jnp.mean(jnp.square(p), axis=-1, keepdims=True) + cfg.rms_norm_eps)
+
+    with jax.named_scope("poly_norm"):
+        z = z.astype(jnp.float32)
+        z2 = z * z
+        y = (coef[0] * normed(z) + coef[1] * normed(z2)
+             + coef[2] * normed(z2 * z))
+        clamp = cfg.polynorm_bias_clamp
+        return cfg.polynorm_output_scale * y + jnp.clip(bias, -clamp, clamp)
+
+
+def _act(x: jnp.ndarray, cfg: ModelConfig, poly=None) -> jnp.ndarray:
+    """MLP activation: SiLU (llama family), tanh-approx GeLU (gemma), the
+    squared ReLU (nemotron_h's two-matrix MLP) or PolyNorm (motif; ``poly``:
+    the unit's ``(coef, bias)``), the one that reduces over a row. Unknown
+    values are rejected at config time (ModelConfig.__post_init__)."""
+    if cfg.hidden_act == "poly_norm":
+        return poly_norm(x, *poly, cfg)
     if cfg.hidden_act in ("gelu", "gelu_pytorch_tanh"):
         return jax.nn.gelu(x, approximate=True)
     if cfg.hidden_act == "relu2":
@@ -228,7 +250,7 @@ def moe_capacity(n_assign: int, cfg: ModelConfig) -> int:
 
 
 def moe_experts(flat: jnp.ndarray, top_idx: jnp.ndarray, gates: jnp.ndarray,
-                moe: dict, cfg: ModelConfig, layer) -> jnp.ndarray:
+                moe: dict, cfg: ModelConfig, layer, poly=None) -> jnp.ndarray:
     """The chosen experts' MLPs over the rows ``flat`` [N, W], summed by
     gate: [N, W] f32. ``moe`` holds the STACKED expert matrices, in the form
     ``MOE_LEAVES`` describes (gated three-matrix, or two-matrix where the
@@ -236,7 +258,10 @@ def moe_experts(flat: jnp.ndarray, top_idx: jnp.ndarray, gates: jnp.ndarray,
     grouped matmul; ``W`` is whatever width the matrices take (the hidden
     size, or nemotron_h's latent). Dropless: the ``N*K`` assignments are
     sorted by expert, each expert multiplies the rows that chose it, however
-    many, and the results go back to token order.
+    many, and the results go back to token order. ``poly``: the layer's
+    PolyNorm ``(coef, bias)`` where that is the activation; a row of the
+    sorted list is one token at one expert, so the activation's reduction
+    runs over that expert's whole width.
 
     **A chip's share** (``cfg.experts_held``): ``top_idx`` ranges over all
     ``cfg.num_experts`` but ``moe`` holds experts ``cfg.expert_offset ..``
@@ -271,9 +296,9 @@ def moe_experts(flat: jnp.ndarray, top_idx: jnp.ndarray, gates: jnp.ndarray,
         if "moe_gate" in moe:
             gate = gmm(rows, moe["moe_gate"])
             up = gmm(rows, moe["moe_up"])
-            act = _act(gate, cfg) * up
+            act = _act(gate, cfg, poly) * up
         else:
-            act = _act(gmm(rows, moe["moe_up"]), cfg)
+            act = _act(gmm(rows, moe["moe_up"]), cfg, poly)
         act = act.astype(flat.dtype)
         out = gmm(act, moe["moe_down"]) * gates.reshape(N * K)[order][:, None]
         if cfg.experts_held:   # rows past the groups were never written
@@ -577,22 +602,26 @@ def decode_page_group(cfg: ModelConfig, page_size: int, n_pages: int,
                      cfg.num_heads * cfg.block_length, n_pages)
 
 
-def decode_work(cfg: ModelConfig, page_table, lengths, pool):
+def decode_work(cfg: ModelConfig, page_table, lengths, pool,
+                window: int | None):
     """The decode kernel's work list for one step: ``lengths`` [B] counts
     the tokens the step itself writes, ``pool`` is the cache the kernel
     reads (its page size and its bytes a number pick the group). The same
-    for every layer, so it is built here, outside the scan over layers."""
+    for every layer of one ``window`` (``cfg.sliding_window``; None for the
+    layers that attend over everything, where that is some layers' alone),
+    so it is built here, outside the scan over layers."""
     page_size = pool.shape[2]
     group = decode_page_group(cfg, page_size, page_table.shape[1],
                               pool.dtype.itemsize)
-    if cfg.is_latent:       # the same pages, no window
+    if cfg.is_latent:
         from ..ops.mla_attention import latent_work_list
 
-        return latent_work_list(page_table, lengths, page_size, group)
+        if window:      # a program takes no more pages than a window spans
+            group = min(group, cfg.window_pages(page_size))
+        return latent_work_list(page_table, lengths, page_size, group, window)
     from ..ops.paged_attention import decode_work_list
 
-    return decode_work_list(page_table, lengths, page_size,
-                            cfg.sliding_window, group)
+    return decode_work_list(page_table, lengths, page_size, window, group)
 
 
 def _ragged_attend(cfg: ModelConfig, interpret: bool, mesh):
@@ -674,7 +703,8 @@ def forward_paged_decode(
     positions = lengths[:, None]
     pid, off = _decode_targets(page_table, lengths, write_mask, page_size)
     attend = _decode_attend(cfg, interpret, mesh)
-    work = decode_work(cfg, page_table, lengths + 1, pools[0])
+    work = decode_work(cfg, page_table, lengths + 1, pools[0],
+                       cfg.sliding_window)
 
     h = _embed_scale(embed_lookup(params["embed"], input_ids, params["final_norm"].dtype), cfg)
 
@@ -726,9 +756,10 @@ class MixedLayout(NamedTuple):
 
 def mixed_layout(cfg: ModelConfig, input_ids, page_table, hist, q_lens,
                  write_mask, rows, decode: DecodeGroup | None,
-                 pool) -> MixedLayout:
+                 pool, window: int | None) -> MixedLayout:
     """Lay a mixed step's tokens out (see :func:`forward_paged_mixed`);
-    ``pool`` is the cache the attention kernels read."""
+    ``pool`` is the cache the attention kernels read, ``page_table`` and
+    ``window`` its page group's (:func:`decode_work`)."""
     page_size = pool.shape[2]
     R, Qc = input_ids.shape
     lane_table = page_table if rows is None else page_table[rows]
@@ -749,7 +780,7 @@ def mixed_layout(cfg: ModelConfig, input_ids, page_table, hist, q_lens,
         n_dec = decode.tokens.size
         width = decode.tokens.shape[1] if decode.tokens.ndim == 2 else None
         work = decode_work(cfg, page_table, decode.lengths + (width or 1),
-                           pool)
+                           pool, window)
         d_pid, d_off = _decode_targets(page_table, decode.lengths, decode.run,
                                        page_size, width)
         d_pos = decode.lengths if width is None else (
@@ -844,7 +875,7 @@ def forward_paged_mixed(
     cos_t, sin_t = rope_tables
     pools, caller_shape = _merged_pools(pools)
     lay = mixed_layout(cfg, input_ids, page_table, hist, q_lens, write_mask,
-                       rows, decode, pools[0])
+                       rows, decode, pools[0], cfg.sliding_window)
     lane_attend = _ragged_attend(cfg, interpret, mesh)
     decode_attend = _decode_attend(cfg, interpret, mesh)
 

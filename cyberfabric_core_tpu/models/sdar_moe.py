@@ -142,7 +142,8 @@ def forward_paged_decode(
                                pools[0].shape[2], W)
     pid, off = pid.reshape(-1), off.reshape(-1)
     attend = _block_attend(interpret, W)
-    work = decode_work(cfg, page_table, lengths + W, pools[0])
+    work = decode_work(cfg, page_table, lengths + W, pools[0],
+                       cfg.sliding_window)
     h = embed_lookup(params["embed"], input_ids.reshape(1, B * W),
                      params["final_norm"].dtype)
 
@@ -191,7 +192,7 @@ def forward_paged_mixed(
     cos_t, sin_t = rope_tables
     R, Qc = input_ids.shape
     lay = mixed_layout(cfg, input_ids, page_table, hist, q_lens, write_mask,
-                       rows, decode, pools[0])
+                       rows, decode, pools[0], cfg.sliding_window)
     nd = lay.n_dec
     lane_attend = _ragged_attend(cfg, interpret, None)
     block_attend = _block_attend(interpret, cfg.block_length)

@@ -448,6 +448,18 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                  "Grid programs the paged decode kernel launched for them: "
                  "one for every group of a row's pages, every row at least "
                  "one"),
+                ("llm_attn_window_pages_walked_total",
+                 "Pages the decode kernel's grid walked in the WINDOW layers "
+                 "of a model with a window page group (a row's last "
+                 "sliding_window tokens, rounded out to pages), summed over "
+                 "steps and window layers; llm_attn_pages_walked_total then "
+                 "counts the layers that attend over everything"),
+                ("llm_attn_window_pages_offered_total",
+                 "Slots of the window group's page table beside them (rows "
+                 "x pages a row x window layers x steps)"),
+                ("llm_window_pages_freed_total",
+                 "Window-group pages rows gave back while running: each "
+                 "lay left of the window of its row's committed length"),
                 ("llm_attn_pages_offered_total",
                  "Slots of the page table (rows x pages a row) beside "
                  "them, for the same calls"),
@@ -656,6 +668,14 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                  "slab)"),
                 ("llm_model_layers", "model_layers",
                  "Layers of the model the caches were built for"),
+                ("llm_window_layers", "window_layers",
+                 "Layers of the window page group: the model's layers whose "
+                 "pages a row gives back once they lie left of its window "
+                 "(0: one page group)"),
+                ("llm_window_pages_in_use", "window_pages_in_use",
+                 "Pages of the window page group rows hold (over "
+                 "llm_batch_active_slots: about sliding_window / page + 1 a "
+                 "row at rest where the pool frees)"),
                 ("llm_cache_bytes", "cache_bytes",
                  "Bytes of the page pool plus the recurrent-state slab")):
             self.registry.gauge(name, text).set_function(

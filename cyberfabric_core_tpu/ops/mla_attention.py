@@ -16,6 +16,13 @@ of the pages in use (``decode_work_list``, PR 32: the pages ``page_span``
 gives, slots in order, no program for a slot of the table that holds
 nothing), a slot's pages ``PAGE_GROUP`` at a time. The queries arrive HEAD-MAJOR and already absorbed, and the softmax
 scale is the caller's (it carries YaRN's ``mscale^2``).
+
+**A window** (``sliding_window``, static; ``models/motif.py``'s window
+layers): a query at ``t`` sees the keys ``t - window < s <= t``. Both kernels
+start a row at the first page of that span (``paged_attention._span_first``)
+and mask the rest, so the programs a row costs do not grow with its length,
+and the pages left of the span are never read: the pool may have given them
+to another row. Without a window both are what they were, bit for bit.
 """
 
 from __future__ import annotations
@@ -28,7 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .paged_attention import _LANES, _NEG_INF, page_span
+from .paged_attention import _LANES, _NEG_INF, _span_first, page_span
 
 _VMEM_LIMIT = 64 * 1024 * 1024
 
@@ -79,22 +86,26 @@ class LatentWork(NamedTuple):
 
 
 def latent_work_list(page_table: jnp.ndarray, lengths: jnp.ndarray,
-                     page_size: int, group: int = PAGE_GROUP) -> LatentWork:
+                     page_size: int, group: int = PAGE_GROUP,
+                     sliding_window: int | None = None) -> LatentWork:
     """The work list of one decode step over latent pages, from
     ``page_table`` [B, Pmax] and ``lengths`` [B] (incl. the current token);
-    the same for every layer, so it is built once a step, outside the scan
-    over layers. The pages it names are those ``page_span`` gives: what the
-    scheduler's walked/offered counters count."""
+    the same for every layer of one window, so it is built once a step,
+    outside the scan over layers. The pages it names are those ``page_span``
+    gives: what the scheduler's walked/offered counters count."""
     B, Pmax = page_table.shape
     lengths = jnp.asarray(lengths, jnp.int32)
-    _, last = page_span(lengths, page_size, Pmax, None)
-    groups = last // group + 1                              # [B], >= 1
+    start, last = page_span(lengths, page_size, Pmax, sliding_window)
+    span = last if sliding_window is None else last - start
+    groups = span // group + 1                              # [B], >= 1
     ends = jnp.cumsum(groups)
     item = jnp.arange(B * -(-Pmax // group), dtype=jnp.int32)
     row = jnp.minimum(
         jnp.sum(item[:, None] >= ends[None, :], axis=1, dtype=jnp.int32),
         B - 1)
     first = jnp.minimum(item - (ends - groups)[row], groups[row] - 1) * group
+    if sliding_window is not None:
+        first = first + start[row]
     pages = jnp.minimum(
         first[:, None] + jnp.arange(group, dtype=jnp.int32)[None, :],
         last[row][:, None])
@@ -104,7 +115,7 @@ def latent_work_list(page_table: jnp.ndarray, lengths: jnp.ndarray,
 
 def _decode_kernel(row_ref, first_ref, phys_ref, len_ref, layer_ref, q_ref,
                    *rest, page_size: int, n_pages: int, rank: int,
-                   scale: float, group: int):
+                   scale: float, group: int, sliding_window: int | None):
     """One work item: ``group`` latent pages of one slot. q_ref [1, Hq,
     lanes]; the ``group`` page refs [1, 1, page, lanes] each; o_ref [1, Hq,
     rank]; acc [Hq, rank] f32; m/l [Hq, LANES] f32."""
@@ -112,9 +123,9 @@ def _decode_kernel(row_ref, first_ref, phys_ref, len_ref, layer_ref, q_ref,
     i = pl.program_id(0)
     first = first_ref[i]
     length = len_ref[row_ref[i]]
-    _, last = page_span(length, page_size, n_pages, None)
+    start, last = page_span(length, page_size, n_pages, sliding_window)
 
-    @pl.when(first == 0)
+    @pl.when(first == start)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
@@ -133,7 +144,10 @@ def _decode_kernel(row_ref, first_ref, phys_ref, len_ref, layer_ref, q_ref,
             preferred_element_type=jnp.float32) * scale     # [Hq, G*page]
         k_pos = k_start + jax.lax.broadcasted_iota(
             jnp.int32, scores.shape, 1)
-        _online_softmax_step(scores, k_pos < length, rows[:, :rank],
+        mask = k_pos < length
+        if sliding_window is not None:      # the query sits at length - 1
+            mask &= k_pos >= length - sliding_window
+        _online_softmax_step(scores, mask, rows[:, :rank],
                              acc_ref, m_ref, l_ref)
 
     @pl.when(first + group > last)
@@ -142,7 +156,8 @@ def _decode_kernel(row_ref, first_ref, phys_ref, len_ref, layer_ref, q_ref,
         o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("rank", "scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("rank", "scale", "interpret",
+                                             "sliding_window", "name"))
 def mla_decode_attention(
     q: jnp.ndarray,           # [B, Hq, lanes] absorbed query, one a slot
     pool: jnp.ndarray,        # [L, N, page, lanes] the stacked latent pool
@@ -152,6 +167,8 @@ def mla_decode_attention(
     rank: int,
     scale: float,
     interpret: bool = False,
+    sliding_window: int | None = None,   # the one ``work`` was built with
+    name: str | None = None,             # the call site's, in a device trace
 ) -> jnp.ndarray:
     """Returns ``[B, Hq, rank]``: each head's softmax-weighted sum of the
     compressed rows of its slot's pages in layer ``layer`` (the caller
@@ -172,7 +189,8 @@ def mla_decode_attention(
     return pl.pallas_call(
         functools.partial(_decode_kernel, page_size=page_size,
                           n_pages=-(-work.row.shape[0] // B) * group,
-                          rank=rank, scale=scale, group=group),
+                          rank=rank, scale=scale, group=group,
+                          sliding_window=sliding_window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5, grid=(work.n_items,),
             in_specs=[pl.BlockSpec((1, Hq, width), at_row),
@@ -184,18 +202,19 @@ def mla_decode_attention(
         out_shape=jax.ShapeDtypeStruct((B, Hq, rank), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        interpret=interpret,
+        interpret=interpret, name=name,
     )(work.row, work.first, work.phys, work.lengths,
       jnp.asarray(layer, jnp.int32).reshape(1), q, *([pool] * group))
 
 
 def _ragged_kernel(pt_ref, hist_ref, qlen_ref, layer_ref, q_ref, c_ref, o_ref,
                    acc_ref, m_ref, l_ref, *, page_size: int, q_block: int,
-                   rank: int, scale: float):
+                   rank: int, scale: float, sliding_window: int | None):
     """One (lane, q-block, page) program. q_ref [1, Hq, Qb, rank+rope],
     head-major, so its rows flatten to ``r = h*Qb + qi`` for nothing; the
     query at ``qi`` sits at ``hist + q0 + qi`` and sees the keys up to
-    itself."""
+    itself (the last ``sliding_window`` of them, the page axis then starting
+    at the page of the block's first query's first key)."""
     b = pl.program_id(0)
     qb = pl.program_id(1)
     j = pl.program_id(2)
@@ -210,7 +229,7 @@ def _ragged_kernel(pt_ref, hist_ref, qlen_ref, layer_ref, q_ref, c_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    k_start = j * page_size
+    k_start = _ragged_page(j, hist, q0, page_size, sliding_window) * page_size
     q_hi = hist + jnp.minimum(qlen, q0 + q_block) - 1
 
     @pl.when(jnp.logical_and(q0 < qlen, k_start <= q_hi))
@@ -224,6 +243,8 @@ def _ragged_kernel(pt_ref, hist_ref, qlen_ref, layer_ref, q_ref, c_ref, o_ref,
         k_pos = k_start + jax.lax.broadcasted_iota(
             jnp.int32, scores.shape, 1)
         mask = (q_idx < qlen) & (k_pos <= hist + q_idx)
+        if sliding_window is not None:
+            mask &= k_pos > hist + q_idx - sliding_window
         _online_softmax_step(scores, mask, c_ref[0, 0, :, :rank], acc_ref,
                              m_ref, l_ref)
 
@@ -234,13 +255,25 @@ def _ragged_kernel(pt_ref, hist_ref, qlen_ref, layer_ref, q_ref, c_ref, o_ref,
             Hq, q_block, rank).astype(o_ref.dtype)
 
 
+def _ragged_page(j, hist, q0, page_size: int, sliding_window: int | None):
+    """The logical page program ``j`` of a q-block whose first query sits at
+    ``hist + q0`` reads: ``j`` itself, or under a window ``j`` pages past the
+    first page that query sees (:func:`_span_first`: its length is its
+    position + 1)."""
+    if sliding_window is None:
+        return j
+    return j + _span_first(hist + q0 + 1, page_size, hist + q0 + 1,
+                           sliding_window)
+
+
 def ragged_q_block(width: int) -> int:
     """Queries a program of the ragged kernel takes: whole sublane tiles of
     a 16-bit block, and as many as keep the accumulator a few MB."""
     return min(32, width)
 
 
-@functools.partial(jax.jit, static_argnames=("rank", "scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("rank", "scale", "interpret",
+                                             "sliding_window", "name"))
 def mla_ragged_attention(
     q: jnp.ndarray,           # [R, Hq, Qc, rank+rope] absorbed, head-major
     pool: jnp.ndarray,        # [L, N, page, rank+rope]
@@ -252,12 +285,15 @@ def mla_ragged_attention(
     rank: int,
     scale: float,
     interpret: bool = False,
+    sliding_window: int | None = None,
+    name: str | None = None,
 ) -> jnp.ndarray:
     """A prompt's chunk over latent pages, absorbed like the decode kernel:
     each lane's span of ``q_lens`` queries attends causally over its own
     history and the span itself, which the caller has already written to
     the pool. Returns ``[R, Hq, Qc, rank]``; positions past a lane's span
-    are zeros."""
+    are zeros. Under a window the page axis of the grid is the pages a
+    q-block's windows can span, not the table's."""
     R, Hq, Qc, width = q.shape
     _, _, page_size, _ = pool.shape
     Pmax = page_table.shape[1]
@@ -265,12 +301,17 @@ def mla_ragged_attention(
     if Qc % q_block or q_block % 16:
         raise ValueError(f"a chunk of {Qc} queries is not whole blocks of "
                          f"{q_block} (multiples of 16)")
+    n_pages = Pmax if sliding_window is None else min(
+        Pmax, (sliding_window + q_block - 3) // page_size + 2)
 
     def page_index(b, qb, j, pt_ref, hist_ref, qlen_ref, layer_ref):
         # clamp j into the pages this (lane, q-block) sees, so that skipped
         # programs revisit the resident page and their DMA is elided
         q_hi = hist_ref[b] + jnp.minimum(qlen_ref[b], (qb + 1) * q_block) - 1
-        jj = jnp.minimum(j, jnp.maximum(q_hi // page_size, 0))
+        jj = jnp.minimum(
+            _ragged_page(j, hist_ref[b], qb * q_block, page_size,
+                         sliding_window),
+            jnp.maximum(q_hi // page_size, 0))
         return (layer_ref[0], pt_ref[b, jj], 0, 0)
 
     def q_index(b, qb, j, *_):
@@ -279,9 +320,10 @@ def mla_ragged_attention(
     rows = Hq * q_block
     return pl.pallas_call(
         functools.partial(_ragged_kernel, page_size=page_size,
-                          q_block=q_block, rank=rank, scale=scale),
+                          q_block=q_block, rank=rank, scale=scale,
+                          sliding_window=sliding_window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4, grid=(R, Qc // q_block, Pmax),
+            num_scalar_prefetch=4, grid=(R, Qc // q_block, n_pages),
             in_specs=[pl.BlockSpec((1, Hq, q_block, width), q_index),
                       pl.BlockSpec((1, 1, page_size, width), page_index)],
             out_specs=pl.BlockSpec((1, Hq, q_block, rank), q_index),
@@ -292,7 +334,7 @@ def mla_ragged_attention(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=interpret,
+        interpret=interpret, name=name,
     )(page_table.astype(jnp.int32), hist.astype(jnp.int32),
       q_lens.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
       q, pool)

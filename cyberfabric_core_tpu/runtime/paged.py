@@ -17,6 +17,24 @@ no kv-head axis and no V pool; ``pools``,
 ``_pool_names``. The allocator, the radix tree, the refcounts and the
 admission protocol count pages, whatever a page holds.
 
+**Two page groups** (``ModelConfig.window_layers``: motif, whose layers
+attend over everything in one layer of four and over the last 128 tokens in
+the others). The layers that keep a row's whole length are the pool above,
+``kv_layers`` deep, with its tree, refcounts and prefix reuse. The window
+layers get ``window_pool`` [window_layers, window_pages, page, lanes], an
+allocator of its own and NO tree: a row's window pages are private, named by
+the SAME logical page index as its full chain (``extend_window``; 0 where a
+page was given back), and ``trim_window`` returns every page whose last token
+lies left of the window of the row's next query. The kernels start a window
+layer's row at its span's first page (``ops/mla_attention.py``), so a page
+given back is never read again and may be another row's at once: steps run
+on the device in the order they were launched, every launched step's queries
+sit at or past the length the host has committed, and a step launched later
+that writes the page runs after every step that still read it. ``match_prefix`` **treats every
+prefix as no match** for such a model: a prefix is reusable only where the
+window pages before its boundary were kept, and this pool keeps none past
+their row (PERF.md section 7).
+
 One manager for both kinds of cache. A model with recurrent state
 (``ModelConfig.has_state``: falcon_h1, granite_hybrid, nemotron_h,
 solar_open2) also gets a state slab here — ``{"ssm": [Ls, rows, H, P, N],
@@ -99,7 +117,8 @@ class PrefixKVPool:
                  page_size: int = 64, dtype=jnp.bfloat16,
                  force_python_native: bool = False,
                  sharding: Optional[Any] = None,
-                 state_slots: int = 0, state_snapshots: int = 0) -> None:
+                 state_slots: int = 0, state_snapshots: int = 0,
+                 window_pages: int = 0) -> None:
         self.cfg = model_config
         self.page_size = page_size
         self.num_pages = num_pages
@@ -130,6 +149,22 @@ class PrefixKVPool:
             pool = jnp.zeros(shape, dtype)
             setattr(self, name, pool if sharding is None
                     else jax.device_put(pool, sharding))
+        #: the window page group (module docstring): its pool follows the
+        #: full group's in ``pools``; page 0 of it is scratch too
+        self.window = model_config.sliding_window \
+            if model_config.window_layers else 0
+        self.window_pages = window_pages if self.window else 0
+        self.window_pages_freed = 0
+        if self.window:
+            if window_pages < 2:
+                raise ValueError(
+                    f"{model_config.name}: {model_config.window_layers} "
+                    "window layers need a window page group (window_pages)")
+            self._pool_names += ("window_pool",)
+            self.window_pool = jnp.zeros(
+                (model_config.window_layers, window_pages, *shape[2:]), dtype)
+            self.window_allocator = BlockAllocator(
+                window_pages - 1, force_python=force_python_native)
         # page 0 is scratch (padding target); allocator hands out 1..num_pages-1
         self.allocator = BlockAllocator(num_pages - 1, force_python=force_python_native)
         self._page_offset = 1
@@ -229,7 +264,7 @@ class PrefixKVPool:
         prompt as cached — at least one token must go through prefill so the
         model produces the first-token logits."""
         with self._tree_lock:
-            pages = self.tree.match(prompt_ids)
+            pages = [] if self.window else self.tree.match(prompt_ids)
         cached = len(pages) * self.page_size
         if cached >= len(prompt_ids):
             drop = (cached - len(prompt_ids)) // self.page_size + 1
@@ -252,8 +287,14 @@ class PrefixKVPool:
 
     @property
     def pools(self) -> tuple:
-        """The pool arrays: K and V, or the one latent pool."""
+        """The pool arrays: K and V, or the one latent pool; then the window
+        group's where the model has one."""
         return tuple(getattr(self, name) for name in self._pool_names)
+
+    @property
+    def _n_chain_pools(self) -> int:
+        """The pools a slot's (full) chain indexes."""
+        return len(self._pool_names) - bool(self.window)
 
     def pool_bytes(self) -> int:
         return sum(int(p.size) * p.dtype.itemsize for p in self.pools)
@@ -339,6 +380,8 @@ class PrefixKVPool:
         (cache-aware routing in runtime/replicas.py) — it must not pin pages
         or skew the hit-rate stats, so it walks the tree and releases
         immediately."""
+        if self.window:
+            return 0
         with self._tree_lock:
             pages = self.tree.match(prompt_ids)
             try:
@@ -373,7 +416,7 @@ class PrefixKVPool:
         :meth:`take_snapshot` where a mixed call ended on ``tokens``; each
         goes to the page that ends there if the tree now owns that page and
         it has none yet, and is freed otherwise."""
-        total_pages = len(prompt_ids) // self.page_size
+        total_pages = 0 if self.window else len(prompt_ids) // self.page_size
         if total_pages <= 0:
             self.drop_snapshot_rows([row for _, row in snapshots or ()])
             return
@@ -418,6 +461,66 @@ class PrefixKVPool:
         special pool handling to be leak-free."""
         self.unref_pages(chain)
 
+    # ------------------------------------------------------------ window group
+    def extend_window(self, wchain: list[int], length_needed: int) -> list[int]:
+        """Grow a slot's WINDOW chain to cover ``length_needed`` tokens
+        (:meth:`extend_chain` for the window group: private pages, no tree
+        to evict from, MemoryError where the group is spent). The chain is
+        indexed as the full chain is; pages given back stay 0."""
+        add = self.pages_for(length_needed) - len(wchain)
+        if add > 0:
+            wchain.extend(p + 1 for p in self.window_allocator.alloc(add))
+        return wchain
+
+    def trim_window(self, wchain: list[int], length: int) -> int:
+        """Give back the window pages of a row of ``length`` committed
+        tokens that its next query (at ``length``) and every later one
+        cannot see. Returns how many."""
+        dead = min(max(length - self.window + 1, 0) // self.page_size,
+                   len(wchain))
+        first = dead        # the pages given back are a prefix: stop at it
+        while first and wchain[first - 1]:
+            first -= 1
+        gone = wchain[first:dead]
+        if gone:
+            wchain[first:dead] = [0] * len(gone)
+            self.window_allocator.free([p - 1 for p in gone])
+            self.window_pages_freed += len(gone)
+            bump_counter("llm_window_pages_freed_total", n=len(gone))
+        return len(gone)
+
+    def release_window(self, wchain: list[int], keep: int = 0) -> None:
+        """A slot leaves: its window pages return (finish, cancel,
+        preemption). ``keep``: only those past the chain's first ``keep``
+        slots (a growth the full group could not match, taken back)."""
+        self.window_allocator.free([p - 1 for p in wchain[keep:] if p])
+        del wchain[keep:]
+
+    def window_pages_in_use(self) -> int:
+        return (self.window_pages - 1 - self.window_allocator.num_free
+                if self.window else 0)
+
+    def save_window_to_host(self, wchain: list[int]) -> dict:
+        """The live pages of a window chain, device→host, for preemption:
+        their logical indices and ``[window_layers, n, page, lanes]``."""
+        at = [j for j, p in enumerate(wchain) if p]
+        idx = jnp.asarray([wchain[j] for j in at], jnp.int32)
+        return {"at": at, "pages": len(wchain),
+                "rows": np.asarray(self.window_pool[:, idx])}
+
+    def restore_window_from_host(self, saved: dict) -> list[int]:
+        """Fresh window pages for a saved window chain (MemoryError where
+        the group lacks them; nothing is held then)."""
+        ids = [p + 1 for p in self.window_allocator.alloc(len(saved["at"]))]
+        wchain = [0] * saved["pages"]
+        for j, p in zip(saved["at"], ids):
+            wchain[j] = p
+        if ids:
+            self.window_pool = self.window_pool.at[
+                :, jnp.asarray(ids, jnp.int32)].set(
+                    jnp.asarray(saved["rows"], self.window_pool.dtype))
+        return wchain
+
     # ------------------------------------------------------------ preemption
     def save_chain_to_host(self, chain: list[int],
                            state_row: Optional[int] = None) -> tuple:
@@ -433,7 +536,7 @@ class PrefixKVPool:
         out = (self.cfg.kv_layers, len(chain), self.page_size,
                *self._page_tail)
         host_kv = tuple(np.asarray(pool[:, idx]).reshape(out)
-                        for pool in self.pools)
+                        for pool in self.pools[: self._n_chain_pools])
         if self.state is not None:
             if state_row is None:
                 raise ValueError("a model with recurrent state is saved with "
@@ -452,7 +555,7 @@ class PrefixKVPool:
         if n == 0:  # a prefill-phase preempt before any chunk landed
             return []
         ids = self._alloc(n)
-        n_pools = len(self._pool_names)
+        n_pools = self._n_chain_pools
         if self.state is not None:
             if state_row is None or len(host_kv) <= n_pools:
                 self.allocator.free([p - self._page_offset for p in ids])
@@ -467,7 +570,7 @@ class PrefixKVPool:
         self.ref_pages(ids)
         idx = jnp.asarray(ids, jnp.int32)
         merged = (*host_kv[0].shape[:3], -1)
-        for name, saved in zip(self._pool_names, host_kv):
+        for name, saved in zip(self._pool_names[:n_pools], host_kv):
             pool = getattr(self, name)
             setattr(self, name, pool.at[:, idx].set(
                 jnp.asarray(saved.reshape(merged), pool.dtype)))
@@ -508,7 +611,8 @@ class PrefixKVPool:
         if self.cfg.is_latent:
             raise ValueError(
                 f"{self.cfg.name}: the PD page export is K and V pages with a "
-                "kv-head axis; a latent page has neither")
+                "kv-head axis; a latent page has neither (and the export "
+                "carries one page group)")
         if self.state is not None:
             raise ValueError(
                 f"{self.cfg.name}: the PD page export carries no recurrent "
@@ -550,6 +654,12 @@ class PrefixKVPool:
                            else 0),
             "model_layers": self.cfg.num_layers,
             "cache_bytes": self.pool_bytes() + self.state_bytes(),
+            # the window page group: its layers, pages, those in use, and
+            # those rows have given back so far (0, 0, 0, 0: one group)
+            "window_layers": self.cfg.window_layers,
+            "window_pages_total": max(self.window_pages - 1, 0),
+            "window_pages_in_use": self.window_pages_in_use(),
+            "window_pages_freed": self.window_pages_freed,
             **self.state_stats(),
         }
 

@@ -65,7 +65,8 @@ class ProgramKey:
     decode_chunk: int       # forwards a decode chunk fuses, at least 1
     max_seq_len: int
     n_slots: int
-    pmax: int               # pages of a full-window chain
+    pmax: int               # slots of a row's page table: the pages of a
+    #                         full-window chain, for every page group
     n_cache: int            # donated cache operands, the state slab included
     has_state: bool
     moe_counters: tuple     # the model module's ``MOE_COUNTERS``

@@ -38,6 +38,10 @@ _MATMUL_LEAVES = {"wq", "wk", "wv", "wo", "gate", "up", "down",
                   # the gate's low-rank pairs and β. Its conv taps, A_log,
                   # dt_bias and the head norm's weight stay f32
                   "w_gate", "f_a", "f_b", "g_a", "g_b", "w_beta",
+                  # motif: the differential gate a signal head; its
+                  # hyper-connections' maps, PolyNorm's coefficients and the
+                  # router stay float32
+                  "w_lam",
                   # falcon_h1's mixer: W_in and W_out like any matrix; its
                   # conv, A_log, D, dt_bias and norm weights stay f32
                   "ssm_in", "ssm_out"}

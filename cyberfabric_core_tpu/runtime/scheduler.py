@@ -208,6 +208,10 @@ class _SlotState:
     emitted: int = 0
     request_index: int = 0  # external correlation id
     chain: Optional[list[int]] = None  # page ids held by this slot
+    #: the slot's pages of the WINDOW page group (a model with window
+    #: layers; runtime/paged.py), by the chain's logical index, 0 where the
+    #: row has given a page back; None where the model has one group
+    wchain: Optional[list[int]] = None
     #: W3C traceparent the gateway propagated through submit; trace_sampled is
     #: parsed ONCE at submission — the decode hot loop's span guard is a
     #: single bool check (the disarmed-failpoint pattern), so an unsampled
@@ -854,10 +858,12 @@ class ContinuousBatchingEngine:
         # set fits the row (others fall back to host stop detection)
         self._stop_width = max(1, config.device_stop_width)
         self._rows = np.zeros(
-            (self.n_slots, self.pmax + _CTL + self._stop_width), np.int32)
-        self._rows[:, self.pmax + _CTL:] = -1
-        self.page_table = self._rows[:, : self.pmax]  # a view, as is _warp
-        self._warp = self._rows[:, self.pmax + 3: self.pmax + _CTL].view(
+            (self.n_slots, self._tw + _CTL + self._stop_width), np.int32)
+        self._rows[:, self._tw + _CTL:] = -1
+        self._tables = self._rows[:, : self._tw]      # views, as is _warp
+        self.page_table = self._rows[:, : self.pmax]
+        self.window_table = self._rows[:, self.pmax: self._tw]
+        self._warp = self._rows[:, self._tw + 3: self._tw + _CTL].view(
             np.float32)  # temperature, top_p
         self._warp[:, 1] = 1.0
         self._rows_up, self._active_up = self._rows.copy(), self.active.copy()
@@ -876,7 +882,8 @@ class ContinuousBatchingEngine:
             page_size=page, dtype=self.dtype,
             sharding=self._pool_sharding,
             state_slots=self.n_slots if self._has_state else 0,
-            state_snapshots=self._state_snapshot_rows())
+            state_snapshots=self._state_snapshot_rows(),
+            window_pages=self._window_pages())
 
         from collections import deque as _deque
 
@@ -1035,7 +1042,10 @@ class ContinuousBatchingEngine:
                        "llm_loose_row_programs_total",
                        "llm_attn_pages_walked_total",
                        "llm_attn_page_groups_total",
-                       "llm_attn_pages_offered_total") + (
+                       "llm_attn_pages_offered_total",
+                       "llm_attn_window_pages_walked_total",
+                       "llm_attn_window_pages_offered_total",
+                       "llm_window_pages_freed_total") + (
                            _BLOCK_SERIES if self._block else ()
                        ) + _moe_series(self._moe_counters):
             bump_counter(series, n=0.0)
@@ -1111,6 +1121,34 @@ class ContinuousBatchingEngine:
                 f"{name}: tp > 1 has no sharding for a latent page (no "
                 "kv-head axis) nor an ep axis for the experts")
 
+    @property
+    def _two_groups(self) -> bool:
+        """The model caches in two page groups (runtime/paged.py)."""
+        return self.model_config.window_layers > 0
+
+    @property
+    def _tw(self) -> int:
+        """Slots of a row's page table: a run of ``pmax`` a page group, the
+        full group's, then the window group's where the model has one."""
+        return self.pmax * (2 if self._two_groups else 1)
+
+    def _window_pages(self) -> int:
+        """Pages of the window page group (0: the model has one group), from
+        shapes alone: what every slot holds at rest and through a decode
+        ring, and two prompt chunks in flight (a chained ``mixed_step``)
+        beside them, and scratch. The group has no tree and keeps no prefix,
+        so more pages would buy nothing; a row it cannot serve is preempted
+        like one the full group cannot (``_grow_tables``)."""
+        cfg = self.model_config
+        if not cfg.window_layers:
+            return 0
+        page = self.config.prefix_page_size
+        ring = max(1, self.config.decode_chunk) * max(
+            1, self.config.decode_lookahead + 1)
+        at_rest = cfg.window_pages(page, ring)
+        chunk = cfg.window_pages(page, self.config.prefill_budget_tokens)
+        return self.n_slots * (at_rest + 1) + 2 * chunk + 1
+
     def _state_snapshot_rows(self) -> int:
         if not self._has_state:
             return 0
@@ -1122,7 +1160,7 @@ class ContinuousBatchingEngine:
         ``self.config`` here, directly or derived, is what shapes a program."""
         key = ProgramKey(
             self.model_config, max(1, self.config.decode_chunk),
-            self.config.max_seq_len, self.n_slots, self.pmax,
+            self.config.max_seq_len, self.n_slots, self._tw,
             n_cache=len(self.pool.cache_operands()),
             has_state=self._has_state, moe_counters=self._moe_counters,
             block=self._block, attn_mesh=self._attn_mesh, spec_k=self.spec_k)
@@ -1528,9 +1566,9 @@ class ContinuousBatchingEngine:
         self.slots[slot] = None
         self._release_free_slot(slot)
         if state.chain is not None:
-            self.pool.release_slot(state.chain)
+            self._release_chains(state)
             self._drop_pending_snapshots(state)
-            self.page_table[slot, :] = 0
+            self._tables[slot, :] = 0
         self._cancel_finalize(
             state.request_id, state.emit, reason, kind, phase=phase,
             emitted=state.emitted, slot=slot,
@@ -1772,6 +1810,13 @@ class ContinuousBatchingEngine:
             if self._has_state:
                 # state rows held beside pages held: one a slot
                 out[tenant]["state_rows"] = slots.get(tenant, 0)
+            if self._two_groups:
+                # "pages" counts the full group; these the window group
+                out[tenant]["window_pages"] = sum(
+                    sum(1 for p in st.wchain if p)
+                    for st in locked_snapshot(self.slots)
+                    if st is not None and st.tenant == tenant
+                    and st.wchain is not None)
             if self.model_config.is_latent:
                 # what a page holds is the configuration's: a latent row
                 out[tenant]["page_layout"] = "latent"
@@ -2219,7 +2264,7 @@ class ContinuousBatchingEngine:
         dispatch. The first ``device_stop_width`` stop ids (-1 padded; sets
         that overflow fall back to host stop detection via _dev_term) and
         the length at which the row hits its max-tokens bound."""
-        ctl = self._rows[slot, self.pmax:]
+        ctl = self._rows[slot, self._tw:]
         ctl[:3] = s.top_k, max(0, limit), gen_start
         self._warp[slot] = s.temperature, s.top_p
         ids = sorted(stops)[: self._stop_width]
@@ -2238,8 +2283,8 @@ class ContinuousBatchingEngine:
         neither runs nor stays finished nor keeps a length."""
         changed = False
         if not np.array_equal(self._rows, self._rows_up):
-            changed = not np.array_equal(self._rows[:, self.pmax:],
-                                         self._rows_up[:, self.pmax:])
+            changed = not np.array_equal(self._rows[:, self._tw:],
+                                         self._rows_up[:, self._tw:])
             self._rows_up = self._rows.copy()
             self._rows_dev = self._dev(self._rows_up)
         if active and not np.array_equal(self.active, self._active_up):
@@ -2291,11 +2336,19 @@ class ContinuousBatchingEngine:
                         rec.host_kv, state_row=self._free_slots[0])
                 else:
                     chain = self.pool.restore_chain_from_host(rec.host_kv)
+                wchain = None
                 try:
+                    if self._two_groups:    # the window pages it held
+                        wchain = self.pool.restore_window_from_host(
+                            rec.host_kv[-1])
+                        self.pool.extend_window(
+                            wchain, rec.length + self._chunk_tokens)
                     self.pool.extend_chain(chain, rec.length + self._chunk_tokens)
                 except MemoryError:
                     # give back the restored pages — a half-resume must not leak
                     self.pool.release_slot(chain)
+                    if wchain:
+                        self.pool.release_window(wchain)
                     raise
             except MemoryError:
                 # Terminal-shed when the request can NEVER fit: either its
@@ -2331,7 +2384,7 @@ class ContinuousBatchingEngine:
             slot = self._take_free_slot()
             assert slot is not None  # guarded by the _free_slots check above
             state = rec.state
-            state.chain = chain
+            state.chain, state.wchain = chain, wchain
             self.slots[slot] = state
             s = state.sampling
             if state.phase == "prefill":
@@ -2368,8 +2421,10 @@ class ContinuousBatchingEngine:
                      self._last_tokens, self._slot_keys, self._lengths_dev,
                      self._finished_dev, self._dev(row))
                 bump_counter("llm_loose_row_programs_total")
-            self.page_table[slot, :] = 0
+            self._tables[slot, :] = 0
             self.page_table[slot, : len(chain)] = chain
+            if wchain is not None:
+                self.window_table[slot, : len(wchain)] = wchain
             resumed += 1
             pause_s = time.monotonic() - rec.suspended_at
             if rec.handoff:
@@ -2606,6 +2661,7 @@ class ContinuousBatchingEngine:
                 stops=frozenset(s.stop_token_ids)
                 | frozenset(self.config.eos_token_ids),
                 chain=chain,
+                wchain=[] if self._two_groups else None,
                 trace=req.trace,
                 trace_sampled=traceparent_ids(req.trace)[1],
                 phase="prefill",
@@ -2620,7 +2676,7 @@ class ContinuousBatchingEngine:
             )
             self.slots[slot] = state
             self.lengths[slot] = 0
-            self.page_table[slot, :] = 0
+            self._tables[slot, :] = 0
             self.page_table[slot, : len(chain)] = chain
             self._set_slot_rows(
                 slot, s, state.stops,
@@ -2628,7 +2684,7 @@ class ContinuousBatchingEngine:
                 len(req.prompt_ids))
         except Exception:
             self.pool.release_slot(chain)
-            self.page_table[slot, :] = 0
+            self._tables[slot, :] = 0
             self.slots[slot] = None
             raise
         self._prefill_slots.append(slot)
@@ -2695,8 +2751,8 @@ class ContinuousBatchingEngine:
                 # finish — the whole point of device-side termination.
                 self._epoch += 1
             if state.chain is not None:
-                self.pool.release_slot(state.chain)
-                self.page_table[slot, :] = 0
+                self._release_chains(state)
+                self._tables[slot, :] = 0
 
     # ------------------------------------------------------------ decode round
     def _ensure_chunk_capacity(self, horizon: Optional[int] = None) -> None:
@@ -2750,28 +2806,76 @@ class ContinuousBatchingEngine:
         real pool pressure or an armed scheduler.page_alloc — callers cap
         the ring/span instead of preempting (the next synchronous round's
         capacity sweep preempts properly)."""
-        chain = state.chain
-        if self.pool.pages_for(target) <= len(chain):
+        if self.pool.pages_for(target) <= self._pages_held(state):
             return
         self._chain_pressure_check()
+        self._grow_tables(slot, state, target)
+
+    @staticmethod
+    def _pages_held(state: _SlotState) -> int:
+        """Pages of a slot's table that BOTH its page groups cover: what the
+        capacity guards compare a need with (``_grow_tables`` keeps the two
+        chains as long as each other)."""
+        held = len(state.chain)
+        return held if state.wchain is None else min(held, len(state.wchain))
+
+    def _grow_tables(self, slot: int, state: _SlotState, tokens: int) -> None:
+        """Extend a slot's chain, and its window chain where the model has
+        a window page group, to cover ``tokens`` and patch its page-table
+        rows. MemoryError is either group's, and leaves BOTH chains as they
+        were: the window group's pages are taken first (its allocator gives
+        all or nothing and has no tree to evict from) and handed back where
+        the full group then has none, so a row never runs with one group's
+        table short of its tokens."""
+        chain, wchain = state.chain, state.wchain
         before = len(chain)
-        self.pool.extend_chain(chain, target)
+        if wchain is not None:
+            wbefore = len(wchain)
+            self.pool.extend_window(wchain, tokens)
+        try:
+            self.pool.extend_chain(chain, tokens)
+        except MemoryError:
+            if wchain is not None:
+                self.pool.release_window(wchain, keep=wbefore)
+            raise
         self.page_table[slot, before: len(chain)] = chain[before:]
+        if wchain is not None:
+            self.window_table[slot, wbefore: len(wchain)] = wchain[wbefore:]
+
+    def _release_chains(self, state: _SlotState) -> None:
+        """A slot leaves: both its chains' pages go back."""
+        self.pool.release_slot(state.chain)
+        if state.wchain is not None:
+            self.pool.release_window(state.wchain)
+
+    def _trim_windows(self) -> None:
+        """After a commit (a decode chunk's, a mixed step's): every row of a
+        model with a window page group gives back the window pages that lie
+        left of the window of its COMMITTED length (``prefill_pos`` of a
+        prompt still in chunks). Every step in flight or launched later has
+        its queries at or past that length, and runs before any later step
+        that writes the page for its next owner (runtime/paged.py)."""
+        if not self._two_groups:
+            return
+        for slot, state in enumerate(self.slots):
+            if state is None or state.wchain is None:
+                continue
+            length = (state.prefill_pos if state.phase == "prefill"
+                      else int(self.lengths[slot]))
+            if self.pool.trim_window(state.wchain, length):
+                self.window_table[slot, : len(state.wchain)] = state.wchain
 
     def _grow_chain(self, slot: int, state: _SlotState, horizon: int) -> None:
         """Extend one slot's chain to cover length + horizon. Raises
         MemoryError only when even the MANDATORY chunk (length + k) cannot be
         covered — the caller preempts then."""
-        chain = state.chain
-        assert chain is not None
+        assert state.chain is not None
         L = int(self.lengths[slot])
         needed = min(L + horizon, self.config.max_seq_len)
-        if self.pool.pages_for(needed) <= len(chain):
+        if self.pool.pages_for(needed) <= self._pages_held(state):
             return
         try:
-            before = len(chain)
-            self.pool.extend_chain(chain, needed)
-            self.page_table[slot, before: len(chain)] = chain[before:]
+            self._grow_tables(slot, state, needed)
             return
         except MemoryError:
             # the deep-lookahead horizon is OPPORTUNISTIC — a slot that can
@@ -2780,11 +2884,9 @@ class ContinuousBatchingEngine:
             # restores length+k, the next round asks the ring horizon again,
             # and the request round-trips its KV forever without a token)
             mandatory = min(L + self._chunk_tokens, self.config.max_seq_len)
-            if self.pool.pages_for(mandatory) <= len(chain):
+            if self.pool.pages_for(mandatory) <= self._pages_held(state):
                 return  # enough for the chunk; lookahead will just skip
-        before = len(chain)
-        self.pool.extend_chain(chain, mandatory)  # MemoryError → preempt
-        self.page_table[slot, before: len(chain)] = chain[before:]
+        self._grow_tables(slot, state, mandatory)  # MemoryError → preempt
 
     def _preempt_slot(self, slot: int, state: _SlotState,
                       soft_yielded: bool = False) -> None:
@@ -2817,6 +2919,8 @@ class ContinuousBatchingEngine:
                      phase=state.phase, length=length)
         host_kv = (self.pool.save_chain_to_host(chain, state_row=slot)
                    if self._has_state else self.pool.save_chain_to_host(chain))
+        if state.wchain is not None:    # the window pages it still holds
+            host_kv += (self.pool.save_window_to_host(state.wchain),)
         self._drop_pending_snapshots(state)
         with self._submit_lock:
             self._suspended.append(_Suspended(
@@ -2835,8 +2939,8 @@ class ContinuousBatchingEngine:
         self.slots[slot] = None
         self._release_free_slot(slot)
         self._epoch += 1
-        self.pool.release_slot(chain)
-        self.page_table[slot, :] = 0
+        self._release_chains(state)
+        self._tables[slot, :] = 0
 
     def _drop_pending_snapshots(self, state: _SlotState) -> None:
         """A prompt that leaves its slot before its commit (preempted,
@@ -2980,6 +3084,7 @@ class ContinuousBatchingEngine:
         advance = self._k_steps if commits is None else commits * self._block
         self.lengths = np.where(self.active, self.lengths + advance,
                                 0).astype(np.int32)
+        self._trim_windows()
         return old_lengths
 
     def _close_round(self, emit: Callable[[], Optional[tuple[int, int]]],
@@ -3186,18 +3291,27 @@ class ContinuousBatchingEngine:
         lengths = (np.where(self.active, kept, 0)[:, None]
                    + step * (np.cumsum(grew, axis=1) - grew + 1))
         slots = self.page_table.shape[1]
-        first, last = page_span(lengths, self.config.prefix_page_size, slots,
-                                self.model_config.sliding_window)
-        layers = self.model_config.kv_layers     # the layers that attend
-        group = decode_page_group(
-            self.model_config, self.config.prefix_page_size, slots,
-            jnp.dtype(self.dtype).itemsize)
+        cfg, page = self.model_config, self.config.prefix_page_size
+        # a model with a window page group: the layers that attend over
+        # everything here, its window layers under a pair of their own
+        first, last = page_span(lengths, page, slots,
+                                None if self._two_groups
+                                else cfg.sliding_window)
+        layers = cfg.kv_layers                   # the layers that attend
+        group = decode_page_group(cfg, page, slots,
+                                  jnp.dtype(self.dtype).itemsize)
         bump_counter("llm_attn_pages_walked_total",
                      n=int((last - first + 1).sum()) * layers)
         bump_counter("llm_attn_page_groups_total",
                      n=int(((last - first) // group + 1).sum()) * layers)
         bump_counter("llm_attn_pages_offered_total",
                      n=lengths.size * slots * layers)
+        if self._two_groups:
+            first, last = page_span(lengths, page, slots, cfg.sliding_window)
+            bump_counter("llm_attn_window_pages_walked_total",
+                         n=int((last - first + 1).sum()) * cfg.window_layers)
+            bump_counter("llm_attn_window_pages_offered_total",
+                         n=lengths.size * slots * cfg.window_layers)
 
     def _take_block_counters(self, drained: np.ndarray
                              ) -> tuple[np.ndarray, np.ndarray]:
@@ -3377,12 +3491,9 @@ class ContinuousBatchingEngine:
         # armed MemoryError forces the preempt-mid-chunked-prefill path with
         # no real pool pressure (faultlab mixed-prefill-preempt scenario)
         failpoint("scheduler.prefill_chunk")
-        chain = state.chain
-        if self.pool.pages_for(end) <= len(chain):
+        if self.pool.pages_for(end) <= self._pages_held(state):
             return
-        before = len(chain)
-        self.pool.extend_chain(chain, end)
-        self.page_table[slot, before: len(chain)] = chain[before:]
+        self._grow_tables(slot, state, end)
 
     def _finish_prefill(self, slot: int, state: _SlotState) -> float:
         """Flip a fully-prefilled slot to decode, at its mixed step's
@@ -3485,7 +3596,7 @@ class ContinuousBatchingEngine:
         self.slots[slot] = None
         self._release_free_slot(slot)
         self._epoch += 1
-        self.page_table[slot, :] = 0
+        self._tables[slot, :] = 0
         record_event(state.request_id, "handoff_export", slot=slot,
                      length=T, pages=n_pages, tokens_emitted=state.emitted)
         self._handoff_sink(rec)
@@ -4054,6 +4165,7 @@ class ContinuousBatchingEngine:
         first = [(slot, state, None if self._block else int(toks2d[slot, 0]),
                   self._finish_prefill(slot, state))
                  for slot, state in finals]
+        self._trim_windows()
 
         def emit() -> Optional[tuple[int, int]]:
             for slot, state, chunk, pos in done:

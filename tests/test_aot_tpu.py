@@ -314,7 +314,7 @@ def _decode_operands(sds, eng, block: int = 0) -> tuple:
     from cyberfabric_core_tpu.runtime.programs import _CTL
 
     n = eng.n_slots
-    return (sds((n, eng.pmax + _CTL + eng.config.device_stop_width),
+    return (sds((n, eng._tw + _CTL + eng.config.device_stop_width),
                 jnp.int32),
             sds((n, block) if block else (n,), jnp.int32),
             sds((n,), jnp.int32), sds((n,), bool), sds((n,), bool),
@@ -1095,3 +1095,111 @@ def test_scheduler_programs_compile_for_v5e_at_solar_open2():
         _assert_whole_array_untouched(text, state["ssm"], name)
         _assert_whole_array_untouched(text, state["conv"], name)
         _assert_whole_array_untouched(text, pool, name)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("name", ["paged_decode_chunk", "mixed_step@64",
+                                  "mixed_step@512"])
+def test_scheduler_programs_compile_for_v5e_at_motif(name):
+    """The scheduler's own ``paged_decode_chunk`` and ``mixed_step`` (an item
+    a program: each compiles for minutes) for
+    motif-3-beta-share32-27l int8 at the served shapes of
+    ``benchmark/configs/motif-3-beta-int8.json`` (64 slots of 8192, 8193
+    pages in the SIX layers of the full page group, 343 in the TWENTY-ONE of
+    the window group, a page table of 2 x 128 slots a row, 12 held experts in
+    25 expert-stack layers, 8 steps a chunk), on one described chip: each
+    holds the latent kernels of both call sites by their own names and the
+    ``grouped_matmul`` Mosaic call, donates both pools and copies neither,
+    fits the 15.75 GiB the compiler budgets. A compile, not a chip run
+    (``-s`` prints the sizes)."""
+    import json
+    import time
+
+    from jax.sharding import SingleDeviceSharding
+
+    from cyberfabric_core_tpu.models import decoder_module, get_config
+    from cyberfabric_core_tpu.ops.platform import compiled_kernels
+    from cyberfabric_core_tpu.parallel.feasibility import V5E_HBM_BYTES
+    from cyberfabric_core_tpu.parallel.sharding import abstract_params
+    from cyberfabric_core_tpu.runtime.engine import EngineConfig
+    from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
+
+    topo = _topo_or_skip()
+    serving = json.loads(REPO.joinpath(
+        "benchmark/configs/motif-3-beta-int8.json").read_text())["serving"]
+    n, max_seq = serving["max_batch"], serving["max_seq_len"]
+    pages = serving["pool_pages"] + 1
+    cfg = get_config(serving["model_config"])
+    eng = object.__new__(ContinuousBatchingEngine)
+    eng.config = EngineConfig(
+        model=cfg.name, max_seq_len=max_seq, max_batch=n,
+        decode_chunk=serving["decode_chunk"], quantization="int8",
+        prefix_cache_pages=pages, prefix_page_size=_PAGE,
+        prefill_budget_tokens=serving["prefill_budget_tokens"])
+    eng.model_config, eng.dtype = cfg, jnp.bfloat16
+    eng._model, eng._has_state, eng._block = decoder_module(cfg), False, 0
+    eng._moe_counters = eng._model.MOE_COUNTERS
+    eng.n_slots, eng.pmax = n, max_seq // _PAGE
+    window_pages = eng._window_pages()      # from shapes: no option
+    assert (cfg.kv_layers, cfg.window_layers, cfg.moe_layers, pages,
+            window_pages) == (6, 21, 25, 8193,
+                              serving["window_pool_pages"] + 1)
+    eng.spec_k, eng._spec_w = 0, 1
+    eng.mesh = eng._attn_mesh = None
+    assert eng._tw == 2 * eng.pmax == 256
+    here = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=here)
+
+    params = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                          abstract_params(cfg, jnp.bfloat16, "int8"))
+    assert params["layers"]["router"].dtype == jnp.float32
+    assert params["layers"]["moe_gate"]["q"].shape == (25, 12, 4096, 1280)
+    assert params["layers"]["wkv_b"]["q"].shape == (25, 512, 16 * 256)
+    assert params["layers"]["mhc_phi"].shape == (25, 2, 16384, 24)
+    assert params["dense"]["gate"]["q"].shape == (2, 4096, 12288)
+    assert params["lm_head"]["q"].shape == (4096, 27520)
+    full = sds((cfg.kv_layers, pages, _PAGE, cfg.latent_lanes), jnp.bfloat16)
+    window = sds((cfg.window_layers, window_pages, _PAGE, cfg.latent_lanes),
+                 jnp.bfloat16)
+    eng.pool = types.SimpleNamespace(cache_operands=lambda: (full, window))
+    with compiled_kernels():
+        eng._build_programs()
+
+    def mixed(width):
+        return (eng._mixed_step_fn, (
+            params, full, window, *_mixed_operands(sds, eng, width)))
+
+    fn, args = {
+        "paged_decode_chunk": lambda: (eng._paged_decode_fn, (
+            params, full, window, *_decode_operands(sds, eng))),
+        "mixed_step@64": lambda: mixed(64),
+        "mixed_step@512": lambda: mixed(512),
+    }[name]()
+    started = time.monotonic()
+    with compiled_kernels():
+        compiled = fn.lower(*args).compile()
+    took = time.monotonic() - started
+    mem = compiled.memory_analysis()
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    print(f"{name}: argument {mem.argument_size_in_bytes / 1e9:.2f} output "
+          f"{mem.output_size_in_bytes / 1e9:.2f} aliased "
+          f"{mem.alias_size_in_bytes / 1e9:.2f} temp "
+          f"{mem.temp_size_in_bytes / 1e9:.2f} GB, compiled in {took:.0f} s")
+    text = compiled.as_text()
+    if os.environ.get("AOT_DUMP_DIR"):
+        Path(os.environ["AOT_DUMP_DIR"], f"{name}.hlo.txt").write_text(text)
+    kernels = ["grouped_matmul", "gdla_full_decode_attention",
+               "gdla_window_decode_attention"]
+    if name != "paged_decode_chunk":
+        kernels += ["gdla_full_ragged_attention",
+                    "gdla_window_ragged_attention"]
+    for kernel in kernels:
+        assert kernel in text, (name, kernel)
+    for pool in (full, window):
+        _assert_whole_array_untouched(text, pool, name)
+    assert mem.alias_size_in_bytes >= sum(
+        int(np.prod(p.shape)) * 2 for p in (full, window)), name
+    assert live <= V5E_HBM_BYTES, (name, live)

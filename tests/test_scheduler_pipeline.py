@@ -665,7 +665,8 @@ def test_attn_pages_counted_by_step_from_kept_lengths(model, over):
     lengths = np.where([True, True, False, True], kept, 0)
     for f in range(3):
         walked += int(np.minimum(-(-(lengths + step) // page), slots).sum())
-        work = decode_work(cfg, eng.page_table, lengths + step, pool)
+        work = decode_work(cfg, eng.page_table, lengths + step, pool,
+                           cfg.sliding_window)
         programs += int(work.n_items)
         lengths = lengths + step * grew[:, f]
     assert got == [walked * layers, programs * layers,
