@@ -318,7 +318,7 @@ def forward_paged_decode(
         x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
         latent, q = latent_and_query(lp, x, cfg, positions, cos_t, sin_t)
         pool = pool.at[layer, pid, off].set(latent.astype(pool.dtype))
-        o = mla_decode_attention(q, pool, work, layer, rank=cfg.kv_lora_rank,
+        o = mla_decode_attention(q, pool, *work, layer, rank=cfg.kv_lora_rank,
                                  scale=scale, interpret=interpret,
                                  sliding_window=cfg.sliding_window)
         return attention_out(lp, h, o, cfg), pool
@@ -373,7 +373,7 @@ def forward_paged_mixed(
             sliding_window=cfg.sliding_window)
         o = lane.transpose(0, 2, 1, 3).reshape(R * Qc, -1, rank)
         if nd:
-            dec = mla_decode_attention(q[:nd], pool, lay.work, layer,
+            dec = mla_decode_attention(q[:nd], pool, *lay.work, layer,
                                        rank=rank, scale=scale,
                                        interpret=interpret,
                                        sliding_window=cfg.sliding_window)

@@ -588,14 +588,16 @@ def _decode_attend(cfg: ModelConfig, interpret: bool, mesh):
 
 def decode_page_group(cfg: ModelConfig, page_size: int, n_pages: int,
                       itemsize: int) -> int:
-    """Pages one program of ``cfg``'s decode kernel takes, over a table of
-    ``n_pages`` slots a row whose pool holds ``itemsize`` bytes a number:
-    what :func:`decode_work` builds its list with, and what the scheduler
-    counts the kernel's programs by."""
+    """Pages ``cfg``'s decode kernel takes at a time, over a table of
+    ``n_pages`` slots a row whose pool holds ``itemsize`` bytes a number: a
+    program's of the K/V kernel (what :func:`decode_work` builds its list
+    with), a trip's of the latent kernel's walk over the layers that attend
+    over everything. What the scheduler counts a row's groups of pages by."""
     if cfg.is_latent:
-        from ..ops.mla_attention import PAGE_GROUP
+        from ..ops.mla_attention import trip_pages
 
-        return PAGE_GROUP
+        return trip_pages(page_size,
+                          None if cfg.window_layers else cfg.sliding_window)
     from ..ops.paged_attention import decode_page_group as by_shapes
 
     return by_shapes(page_size, cfg.num_kv_heads * cfg.head_dim, itemsize,
@@ -604,23 +606,21 @@ def decode_page_group(cfg: ModelConfig, page_size: int, n_pages: int,
 
 def decode_work(cfg: ModelConfig, page_table, lengths, pool,
                 window: int | None):
-    """The decode kernel's work list for one step: ``lengths`` [B] counts
-    the tokens the step itself writes, ``pool`` is the cache the kernel
-    reads (its page size and its bytes a number pick the group). The same
-    for every layer of one ``window`` (``cfg.sliding_window``; None for the
-    layers that attend over everything, where that is some layers' alone),
-    so it is built here, outside the scan over layers."""
+    """What the decode kernel walks in one step: ``lengths`` [B] counts the
+    tokens the step itself writes, ``pool`` is the cache the kernel reads.
+    The K/V kernel's work list (the pool's page size and its bytes a number
+    pick the group); the latent kernel walks a row's span itself and takes
+    the table and the lengths. The same for every layer of one ``window``
+    (``cfg.sliding_window``; None for the layers that attend over
+    everything, where that is some layers' alone), so it is built here,
+    outside the scan over layers."""
+    if cfg.is_latent:
+        return page_table, lengths
+    from ..ops.paged_attention import decode_work_list
+
     page_size = pool.shape[2]
     group = decode_page_group(cfg, page_size, page_table.shape[1],
                               pool.dtype.itemsize)
-    if cfg.is_latent:
-        from ..ops.mla_attention import latent_work_list
-
-        if window:      # a program takes no more pages than a window spans
-            group = min(group, cfg.window_pages(page_size))
-        return latent_work_list(page_table, lengths, page_size, group, window)
-    from ..ops.paged_attention import decode_work_list
-
     return decode_work_list(page_table, lengths, page_size, window, group)
 
 
@@ -751,7 +751,8 @@ class MixedLayout(NamedTuple):
     off: jnp.ndarray           # [N] its offset in that page
     lane_table: jnp.ndarray    # [R, Pmax] the lanes' rows of the page table
     lane_valid: jnp.ndarray    # [R] bool: the lane has tokens and may write
-    work: DecodeWork | None    # the decode group's (None: no group)
+    work: Any                  # the decode group's :func:`decode_work`
+    #                            (None: no group)
 
 
 def mixed_layout(cfg: ModelConfig, input_ids, page_table, hist, q_lens,

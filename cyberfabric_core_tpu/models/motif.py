@@ -477,7 +477,8 @@ def forward_paged_decode(
     page_size = pools[0].shape[2]
     positions = lengths[None, :]
     scale = attention_scale(cfg)
-    # (write targets, work list) of the full group and of the window group
+    # (write targets, what the kernel walks) of the full group and of the
+    # window group
     group = {}
     for full, table, pool in zip((True, False), _tables(page_table), pools):
         group[full] = (
@@ -491,7 +492,7 @@ def forward_paged_decode(
         pool = pools[not full]
         pool = pool.at[i, pid, off].set(latent.astype(pool.dtype))
         o = mla_decode_attention(
-            q, pool, work, i, rank=cfg.kv_lora_rank, scale=scale,
+            q, pool, *work, i, rank=cfg.kv_lora_rank, scale=scale,
             interpret=interpret,
             sliding_window=None if full else cfg.sliding_window,
             name=_NAMES[full] + "_decode_attention")
@@ -551,7 +552,7 @@ def forward_paged_mixed(
         o = lane.transpose(0, 2, 1, 3).reshape(R * Qc, -1, rank)
         if nd:
             dec = mla_decode_attention(
-                q[:nd], pool, mine.work, i, rank=rank, scale=scale,
+                q[:nd], pool, *mine.work, i, rank=rank, scale=scale,
                 interpret=interpret, sliding_window=window,
                 name=_NAMES[full] + "_decode_attention")
             o = jnp.concatenate([dec, o], axis=0)

@@ -445,9 +445,10 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                  "that holds tokens a row's query reads, summed over steps "
                  "and layers"),
                 ("llm_attn_page_groups_total",
-                 "Grid programs the paged decode kernel launched for them: "
-                 "one for every group of a row's pages, every row at least "
-                 "one"),
+                 "Groups of pages the decode kernel took them in, every row at "
+                 "least one: a grid program each of the K/V kernel, a trip "
+                 "each of the latent kernel's one program a row (a key block "
+                 "of ops/mla_attention.py: trip_pages pages, one score dot)"),
                 ("llm_attn_window_pages_walked_total",
                  "Pages the decode kernel's grid walked in the WINDOW layers "
                  "of a model with a window page group (a row's last "

@@ -11,24 +11,37 @@ its lanes) and as the value (its first ``rank`` lanes), by every head.
 Both kernels take the STACKED pool as the pool keeps it, ``[L, N, page,
 lanes]`` (``rank + rope`` numbers in whole lane tiles, the rest zero, in the
 queries too), and the layer as a scalar-prefetch operand, like
-``ops/paged_attention.py``'s. The decode kernel walks that file's work list
-of the pages in use (``decode_work_list``, PR 32: the pages ``page_span``
-gives, slots in order, no program for a slot of the table that holds
-nothing), a slot's pages ``PAGE_GROUP`` at a time. The queries arrive HEAD-MAJOR and already absorbed, and the softmax
-scale is the caller's (it carries YaRN's ``mscale^2``).
+``ops/paged_attention.py``'s. The queries arrive HEAD-MAJOR and already
+absorbed, and the softmax scale is the caller's (it carries YaRN's
+``mscale^2``).
+
+**The decode kernel walks a row's pages inside ONE program** (PR 49; a grid
+step a group of pages before). ``grid=(B,)``, in order; the pool is left
+where it lives and the page table and the lengths are scalar-prefetch
+operands. A **trip** takes up to :func:`trip_pages` consecutive pages of the
+row's span (``page_span``: what the scheduler's walked/offered counters
+count), one DMA a page and only for the pages inside the span, into one key
+block of a ring of ``RING_BLOCKS``, where they land as the rows of one
+``[trip * page, lanes]`` block: one score dot, one mask by position, one
+online-softmax update, one value dot. The trips of a call are one sequence
+(slots in order, a slot that holds nothing has none) and the ring runs
+through it across the programs: the copies of the trips after the one being
+attended over are in flight, so a row's last trips start the next row's
+first. A group of pages is a trip: ``llm_attn_page_groups_total`` counts
+those.
 
 **A window** (``sliding_window``, static; ``models/motif.py``'s window
 layers): a query at ``t`` sees the keys ``t - window < s <= t``. Both kernels
 start a row at the first page of that span (``paged_attention._span_first``)
-and mask the rest, so the programs a row costs do not grow with its length,
-and the pages left of the span are never read: the pool may have given them
-to another row. Without a window both are what they were, bit for bit.
+and mask the rest, so what a row costs does not grow with its length (the
+decode kernel: one trip of the pages a window spans), and the pages left of
+the span are never read: the pool may have given them to another row.
+Without a window both give what they gave before PR 48, bit for bit.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -40,171 +53,273 @@ from .paged_attention import _LANES, _NEG_INF, _span_first, page_span
 _VMEM_LIMIT = 64 * 1024 * 1024
 
 
-def _online_softmax_step(scores, mask, value, acc_ref, m_ref, l_ref):
+def _online_softmax_step(scores, mask, value, acc_ref, m_ref, l_ref,
+                         first: bool = False):
     """One block of keys of the flash recurrence: ``scores`` [R, keys] f32
     (already scaled), ``mask`` its visible keys, ``value`` [keys, rank] the
-    first ``rank`` lanes of the same latent rows."""
+    first ``rank`` lanes of the same latent rows. ``first`` (static): the
+    accumulators hold nothing yet and are not read; what is written is what
+    the recurrence gives from its start (m at the floor, l and acc zero),
+    bit for bit."""
     scores = jnp.where(mask, scores, _NEG_INF)
-    m_prev = m_ref[...]
     m_blk = jnp.max(scores, axis=1, keepdims=True)
+    m_prev = jnp.full(m_ref.shape, _NEG_INF, m_ref.dtype) if first \
+        else m_ref[...]
     m_new = jnp.maximum(m_prev, jax.lax.broadcast_in_dim(
         m_blk, m_prev.shape, (0, 1)))
     m_ref[...] = m_new
-    # a row with no visible key yet sits at the floor: it carries no mass
-    correction = jnp.where(m_new > _NEG_INF * 0.5,
-                           jnp.exp(m_prev - m_new), 0.0)
     p = jnp.where(mask, jnp.exp(scores - m_new[:, :1]), 0.0)
-    l_blk = jnp.sum(p, axis=1, keepdims=True)
-    l_ref[...] = l_ref[...] * correction + jax.lax.broadcast_in_dim(
-        l_blk, m_prev.shape, (0, 1))
+    l_blk = jax.lax.broadcast_in_dim(
+        jnp.sum(p, axis=1, keepdims=True), m_prev.shape, (0, 1))
     pv = jax.lax.dot_general(p.astype(value.dtype), value,
                              (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
+    if first:
+        l_ref[...] = l_blk
+        acc_ref[...] = pv
+        return
+    # a row with no visible key yet sits at the floor: it carries no mass
+    correction = jnp.where(m_new > _NEG_INF * 0.5,
+                           jnp.exp(m_prev - m_new), 0.0)
+    l_ref[...] = l_ref[...] * correction + l_blk
     acc_ref[...] = acc_ref[...] * correction[:, :1] + pv
 
 
-#: latent pages one program of the decode kernel takes. A page's body is two
-#: small dots and a softmax update, and a grid step costs about what that
-#: body does (PERF.md, PR 33: 0.66 us a page at one page a program, 0.10 us of
-#: it DMA), so a program takes a GROUP of a row's pages: as many DMAs, one
-#: dot over all their keys, one update of the accumulator
-PAGE_GROUP = 8
+#: latent pages a trip of the decode kernel takes at most: the key block of
+#: one score dot and one update of the accumulator. A trip's fixed cost (the
+#: accumulator's rescale, the waits) is about what 4 pages' dots cost, and
+#: what a row's last trip holds under 16 is attended over as a smaller block
+#: (:func:`_block_sizes`): PERF.md, PR 49, has the probe that read 8 / 16 /
+#: 32 at five shapes (16 is 11-15% under 8 and 2-5% under 32)
+TRIP_PAGES = 16
 
 
-class LatentWork(NamedTuple):
-    """The latent decode kernel's grid, flattened: ``decode_work_list``'s
-    list of the pages in use (slots in order, a slot's pages ascending, every
-    slot at least one item, an empty slot's computing nothing) with a slot's
-    pages taken ``group`` at a time. The arrays are as long as a full table
-    needs; the grid runs the first ``n_items``."""
-    row: jnp.ndarray       # [N] int32 the item's slot
-    first: jnp.ndarray     # [N] int32 the first logical page of its group
-    phys: jnp.ndarray      # [N*group] int32 its pages (past the slot's last
-    #                        page: that page again, masked by the length)
-    lengths: jnp.ndarray   # [B] int32 valid length (incl. current token)
-    n_items: jnp.ndarray   # [] int32 items in use: the grid's bound
+def _window_pages(sliding_window: int, page_size: int,
+                  queries: int = 1) -> int:
+    """The most pages the window of ``queries`` consecutive positions spans
+    (``ModelConfig.window_pages``, for a kernel that has no configuration)."""
+    return (sliding_window + queries - 3) // page_size + 2
 
 
-def latent_work_list(page_table: jnp.ndarray, lengths: jnp.ndarray,
-                     page_size: int, group: int = PAGE_GROUP,
-                     sliding_window: int | None = None) -> LatentWork:
-    """The work list of one decode step over latent pages, from
-    ``page_table`` [B, Pmax] and ``lengths`` [B] (incl. the current token);
-    the same for every layer of one window, so it is built once a step,
-    outside the scan over layers. The pages it names are those ``page_span``
-    gives: what the scheduler's walked/offered counters count."""
-    B, Pmax = page_table.shape
-    lengths = jnp.asarray(lengths, jnp.int32)
-    start, last = page_span(lengths, page_size, Pmax, sliding_window)
-    span = last if sliding_window is None else last - start
-    groups = span // group + 1                              # [B], >= 1
-    ends = jnp.cumsum(groups)
-    item = jnp.arange(B * -(-Pmax // group), dtype=jnp.int32)
-    row = jnp.minimum(
-        jnp.sum(item[:, None] >= ends[None, :], axis=1, dtype=jnp.int32),
-        B - 1)
-    first = jnp.minimum(item - (ends - groups)[row], groups[row] - 1) * group
-    if sliding_window is not None:
-        first = first + start[row]
-    pages = jnp.minimum(
-        first[:, None] + jnp.arange(group, dtype=jnp.int32)[None, :],
-        last[row][:, None])
-    phys = jnp.asarray(page_table, jnp.int32)[row[:, None], pages]
-    return LatentWork(row, first, phys.reshape(-1), lengths, ends[-1])
+def trip_pages(page_size: int, sliding_window: int | None) -> int:
+    """Pages a trip of the decode kernel takes, from shapes: ``TRIP_PAGES``,
+    and no more than a window spans."""
+    if sliding_window is None:
+        return TRIP_PAGES
+    return min(TRIP_PAGES, _window_pages(sliding_window, page_size))
 
 
-def _decode_kernel(row_ref, first_ref, phys_ref, len_ref, layer_ref, q_ref,
-                   *rest, page_size: int, n_pages: int, rank: int,
-                   scale: float, group: int, sliding_window: int | None):
-    """One work item: ``group`` latent pages of one slot. q_ref [1, Hq,
-    lanes]; the ``group`` page refs [1, 1, page, lanes] each; o_ref [1, Hq,
-    rank]; acc [Hq, rank] f32; m/l [Hq, LANES] f32."""
-    pages, (o_ref, acc_ref, m_ref, l_ref) = rest[:group], rest[group:]
-    i = pl.program_id(0)
-    first = first_ref[i]
-    length = len_ref[row_ref[i]]
-    start, last = page_span(length, page_size, n_pages, sliding_window)
+#: key blocks of the decode kernel's ring: the one a trip attends over and
+#: the trips whose copies are in flight behind it. With one in flight a copy
+#: has a trip's body to arrive in, and takes about that long itself, so every
+#: trip waited out the DMA's latency (PERF.md, PR 49: the probe at 2 and 3)
+RING_BLOCKS = 3
 
-    @pl.when(first == start)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
 
-    k_start = first * page_size
+def _block_sizes(trip: int) -> tuple[int, ...]:
+    """The key blocks a trip is attended over as, in pages: the powers of
+    two from 2 (128 keys: a lane tile of scores) below ``trip``, and
+    ``trip``. A trip takes the smallest that holds its pages, so a row's last
+    trip, a window's two pages and a row that holds one token do not pay for
+    the dots of a whole block."""
+    sizes = []
+    size = 2
+    while size < trip:
+        sizes.append(size)
+        size *= 2
+    return (*sizes, trip)
 
-    @pl.when(k_start < length)      # every item but an empty slot's
-    def _compute():
-        # the group's rows as ONE key/value block: a page is read once and
-        # is the key (all its lanes) and the value (its first ``rank``)
-        rows = jnp.concatenate([c[0, 0] for c in pages], axis=0) \
-            if group > 1 else pages[0][0, 0]
-        scores = jax.lax.dot_general(
-            q_ref[0], rows, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale     # [Hq, G*page]
-        k_pos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, scores.shape, 1)
-        mask = k_pos < length
-        if sliding_window is not None:      # the query sits at length - 1
-            mask &= k_pos >= length - sliding_window
-        _online_softmax_step(scores, mask, rows[:, :rank],
-                             acc_ref, m_ref, l_ref)
 
-    @pl.when(first + group > last)
-    def _finalize():
+def _attend_trip(q_ref, ring_ref, slot, k_start, length, acc_ref, m_ref,
+                 l_ref, *, pages: int, page_size: int, rank: int,
+                 scale: float, sliding_window: int | None, first: bool):
+    """One trip of the decode kernel's walk: the first ``pages`` pages of
+    key block ``slot`` are the rows of ONE key/value block whose first key
+    sits at ``k_start``. A page is read once and is the key (all its lanes)
+    and the value (its first ``rank``). ``first``: the row's first trip."""
+    keys = pl.ds(0, pages * page_size)
+    scores = jax.lax.dot_general(
+        q_ref[0], ring_ref[slot, keys], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale         # [Hq, keys]
+    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    mask = k_pos < length
+    if sliding_window is not None:          # the query sits at length - 1
+        mask &= k_pos >= length - sliding_window
+    _online_softmax_step(scores, mask, ring_ref[slot, keys, pl.ds(0, rank)],
+                         acc_ref, m_ref, l_ref, first)
+
+
+def _decode_kernel(pt_ref, len_ref, layer_ref, q_ref, pool_ref, o_ref,
+                   ring_ref, sem, walk_ref, acc_ref, m_ref, l_ref, *,
+                   page_size: int, trip: int, rank: int, scale: float,
+                   sliding_window: int | None):
+    """One slot: the program walks its row's span itself, ``trip`` pages a
+    trip. pt_ref [B, Pmax] / len_ref [B] / layer_ref [1] SMEM; q_ref [1, Hq,
+    lanes]; pool_ref the whole stacked pool, where it lives; o_ref [1, Hq,
+    rank]; ring_ref [blocks, trip * page, lanes] the key blocks, ``sem`` a
+    DMA semaphore each; acc [Hq, rank] f32; m/l [Hq, LANES] f32.
+
+    The call's trips are ONE sequence (rows in order, a row's trips
+    ascending, a row that holds nothing has none) and the ring runs through
+    it across the programs, which run in order: while a trip is attended
+    over, the ``blocks - 1`` after it are in flight. walk_ref [3] SMEM
+    carries (the key block of the next trip to attend over, the row and the
+    number of the next trip to START) from a program to the next.
+
+    Every copy started has exactly one wait: the trip ``(row, j)`` is waited
+    for by program ``row`` at its ``j``-th trip, under the condition it was
+    started under (the page lies in the span)."""
+    b = pl.program_id(0)
+    n_rows, n_pages = pt_ref.shape
+    blocks = ring_ref.shape[0]
+
+    def span(row):
+        start, last = page_span(len_ref[row], page_size, n_pages,
+                                sliding_window)
+        return start, last, (last - start) // trip + 1
+
+    def copies(row, start, last, j, slot, do):
+        """``do`` (start or wait) the copy of every page of trip ``j`` of
+        ``row`` that lies in its span, into key block ``slot``: a spare page
+        moves no bytes."""
+        for t in range(trip):
+            page = start + j * trip + t
+
+            @pl.when(page <= last)
+            def _():
+                do(pltpu.make_async_copy(
+                    pool_ref.at[layer_ref[0], pt_ref[row, page]],
+                    ring_ref.at[slot, pl.ds(t * page_size, page_size)],
+                    sem.at[slot]))
+
+    def next_busy(row):
+        """The first row at or after ``row`` that holds tokens; ``n_rows``
+        where none does."""
+        return jax.lax.while_loop(
+            lambda r: (r < n_rows) & (len_ref[jnp.minimum(r, n_rows - 1)]
+                                      == 0),
+            lambda r: r + 1, jnp.minimum(row, n_rows))
+
+    def start_next(slot, row, j):
+        """Start the trip ``(row, j)`` (none: ``row`` is ``n_rows``) into
+        key block ``slot``; returns the trip after it."""
+        at = jnp.minimum(row, n_rows - 1)
+        start, last, trips = span(at)
+
+        @pl.when(row < n_rows)
+        def _():
+            copies(at, start, last, j, slot, lambda c: c.start())
+
+        return jax.lax.cond(j + 1 < trips, lambda: (row, j + 1),
+                            lambda: (next_busy(row + 1), jnp.zeros_like(j)))
+
+    def wrap(slot):
+        return jnp.where(slot >= blocks, slot - blocks, slot)
+
+    @pl.when(b == 0)
+    def _open():
+        # a row of a key block no trip has written yet must hold numbers: a
+        # zero probability times a NaN is a NaN in the value dot. After this
+        # a block holds zeros or pages of some span, which the mask drops
+        ring_ref[...] = jnp.zeros_like(ring_ref)
+        nxt = (next_busy(0), jnp.int32(0))
+        for slot in range(blocks - 1):
+            nxt = start_next(slot, *nxt)
+        walk_ref[0] = 0
+        walk_ref[1], walk_ref[2] = nxt
+
+    length = len_ref[b]
+
+    @pl.when(length == 0)
+    def _idle():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(length > 0)
+    def _walk():
+        start, last, trips = span(b)
+
+        def one_trip(j, walk, first=False):
+            slot, *nxt = walk
+            # the copies of the trip ``blocks - 1`` on go out before this
+            # one's are waited for
+            nxt = start_next(wrap(slot + blocks - 1), *nxt)
+            copies(b, start, last, j, slot, lambda c: c.wait())
+            held = jnp.minimum(last - (start + j * trip) + 1, trip)
+            sizes = _block_sizes(trip)
+            for under, pages in zip((0, *sizes), sizes):
+
+                @pl.when((held > under) & (held <= pages))
+                def _():
+                    _attend_trip(q_ref, ring_ref, slot,
+                                 (start + j * trip) * page_size, length,
+                                 acc_ref, m_ref, l_ref, pages=pages,
+                                 page_size=page_size, rank=rank, scale=scale,
+                                 sliding_window=sliding_window, first=first)
+            return (wrap(slot + 1), *nxt)
+
+        # a row's first trip finds nothing in the accumulators and reads
+        # nothing from them: where a row is one trip (a window's) that is
+        # the whole of it
+        walk = one_trip(0, (walk_ref[0], walk_ref[1], walk_ref[2]),
+                        first=True)
+        walk = jax.lax.fori_loop(1, trips, one_trip, walk)
+        walk_ref[0], walk_ref[1], walk_ref[2] = walk
         denom = jnp.maximum(l_ref[...][:, :1], 1e-30)
         o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("rank", "scale", "interpret",
-                                             "sliding_window", "name"))
+                                             "sliding_window", "name",
+                                             "trip"))
 def mla_decode_attention(
     q: jnp.ndarray,           # [B, Hq, lanes] absorbed query, one a slot
     pool: jnp.ndarray,        # [L, N, page, lanes] the stacked latent pool
-    work: LatentWork,         # latent_work_list(page_table, lengths, page)
+    page_table: jnp.ndarray,  # [B, Pmax] each slot's pages
+    lengths: jnp.ndarray,     # [B] valid length (incl. current token)
     layer: jnp.ndarray | int = 0,
     *,
     rank: int,
     scale: float,
-    interpret: bool = False,
-    sliding_window: int | None = None,   # the one ``work`` was built with
+    interpret: bool | pltpu.InterpretParams = False,
+    sliding_window: int | None = None,
     name: str | None = None,             # the call site's, in a device trace
+    trip: int | None = None,             # a test's or a probe's pages a trip
 ) -> jnp.ndarray:
     """Returns ``[B, Hq, rank]``: each head's softmax-weighted sum of the
     compressed rows of its slot's pages in layer ``layer`` (the caller
-    applies ``W_uv``)."""
+    applies ``W_uv``). One program a slot, in order; the pool stays where it
+    lives and the programs copy the pages of a row's span (``page_span``)
+    themselves, :func:`trip_pages` at a time."""
     B, Hq, width = q.shape
     _, _, page_size, _ = pool.shape
-    group = work.phys.shape[0] // work.row.shape[0]
+    trip = trip or trip_pages(page_size, sliding_window)
 
-    def page_spec(g: int) -> pl.BlockSpec:
-        return pl.BlockSpec(
-            (1, 1, page_size, width),
-            lambda i, row, first, phys, ln, ly: (ly[0], phys[i * group + g],
-                                                 0, 0))
-
-    def at_row(i, row, first, phys, ln, ly):
-        return (row[i], 0, 0)
+    def at_row(i, pt, ln, ly):
+        return (i, 0, 0)
 
     return pl.pallas_call(
-        functools.partial(_decode_kernel, page_size=page_size,
-                          n_pages=-(-work.row.shape[0] // B) * group,
-                          rank=rank, scale=scale, group=group,
+        functools.partial(_decode_kernel, page_size=page_size, trip=trip,
+                          rank=rank, scale=scale,
                           sliding_window=sliding_window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5, grid=(work.n_items,),
+            num_scalar_prefetch=3, grid=(B,),
             in_specs=[pl.BlockSpec((1, Hq, width), at_row),
-                      *(page_spec(g) for g in range(group))],
+                      pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((1, Hq, rank), at_row),
-            scratch_shapes=[pltpu.VMEM((Hq, rank), jnp.float32),
-                            pltpu.VMEM((Hq, _LANES), jnp.float32),
-                            pltpu.VMEM((Hq, _LANES), jnp.float32)]),
+            scratch_shapes=[
+                pltpu.VMEM((RING_BLOCKS, trip * page_size, width),
+                           pool.dtype),
+                pltpu.SemaphoreType.DMA((RING_BLOCKS,)),
+                pltpu.SMEM((3,), jnp.int32),
+                pltpu.VMEM((Hq, rank), jnp.float32),
+                pltpu.VMEM((Hq, _LANES), jnp.float32),
+                pltpu.VMEM((Hq, _LANES), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((B, Hq, rank), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret, name=name,
-    )(work.row, work.first, work.phys, work.lengths,
-      jnp.asarray(layer, jnp.int32).reshape(1), q, *([pool] * group))
+    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q, pool)
 
 
 def _ragged_kernel(pt_ref, hist_ref, qlen_ref, layer_ref, q_ref, c_ref, o_ref,
@@ -302,7 +417,7 @@ def mla_ragged_attention(
         raise ValueError(f"a chunk of {Qc} queries is not whole blocks of "
                          f"{q_block} (multiples of 16)")
     n_pages = Pmax if sliding_window is None else min(
-        Pmax, (sliding_window + q_block - 3) // page_size + 2)
+        Pmax, _window_pages(sliding_window, page_size, q_block))
 
     def page_index(b, qb, j, pt_ref, hist_ref, qlen_ref, layer_ref):
         # clamp j into the pages this (lane, q-block) sees, so that skipped

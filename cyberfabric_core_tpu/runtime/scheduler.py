@@ -3274,16 +3274,18 @@ class ContinuousBatchingEngine:
         })
 
     def _count_attn_pages(self, kept: np.ndarray, grew: np.ndarray) -> None:
-        """/metrics of the decode kernel's grid over a drained dispatch: the
+        """/metrics of the decode kernel's walk over a drained dispatch: the
         pages it walked (those that hold tokens a row's query reads) and the
-        programs it launched (one for every group of a row's pages, the
-        group the work list was built with), beside the page table's slots,
-        summed over forwards and layers. ``kept`` [B]: each row's length
-        going in; ``grew`` [B, forwards]: whether that forward added a
-        step's tokens to it (a frozen row stops growing). A row that does
-        not run sits at length 0 on the device and costs the one program
-        every row has. Counted from the host's mirror by the kernel's own
-        :func:`page_span`, so a step pays nothing for it."""
+        groups it took them in (``decode_page_group``: a grid program each
+        of the K/V kernel, the group the work list was built with; a trip
+        each of the latent kernel, which runs one program a row), beside the
+        page table's slots, summed over forwards and layers. ``kept`` [B]:
+        each row's length going in; ``grew`` [B, forwards]: whether that
+        forward added a step's tokens to it (a frozen row stops growing). A
+        row that does not run sits at length 0 on the device and is counted
+        as the one page and the one group a program costs. Counted from the
+        host's mirror by the kernel's own :func:`page_span`, so a step pays
+        nothing for it."""
         from ..models.llama import decode_page_group
         from ..ops.paged_attention import page_span
 

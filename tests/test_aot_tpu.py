@@ -597,28 +597,35 @@ def test_scheduler_programs_compile_for_v5e_at_sdar_moe():
         assert live <= V5E_HBM_BYTES, (name, live)
 
 
-def test_latent_kernels_compile_at_kimi_k2_shapes(one_chip):
-    """``mla_decode_attention`` over the work list and ``mla_ragged_attention``
-    at a chunk of 512, at kimi-k2.5's 64 heads on a latent page of 512 + 64
-    lanes in 640, 64 slots of 48
-    pages."""
-    from cyberfabric_core_tpu.ops.mla_attention import (latent_work_list,
-                                                        mla_decode_attention,
+@pytest.mark.parametrize("heads,pmax,window", [
+    (64, 48, None),         # kimi-k2.5: 64 slots of 3072
+    (80, 128, None),        # motif-3-beta's full layers: 64 slots of 8192
+    (80, 128, 128),         # ... and its window layers: a trip of 3 pages
+], ids=["kimi-k2.5", "motif-full", "motif-window"])
+def test_latent_kernels_compile_at_served_shapes(one_chip, heads, pmax,
+                                                 window):
+    """``mla_decode_attention`` (one program a slot, the pool left where it
+    lives, a ring of key blocks the kernel copies a row's pages into itself:
+    DMAs and semaphores the interpreter only imitates) and
+    ``mla_ragged_attention`` at a chunk of 512, on a latent page of 512 + 64
+    lanes in 640, 64 slots."""
+    from cyberfabric_core_tpu.ops.mla_attention import (mla_decode_attention,
                                                         mla_ragged_attention)
 
-    batch, pmax, heads, width, rank = 64, 48, 64, 640, 512
+    batch, width, rank = 64, 640, 512
     pool = one_chip((2, batch * pmax + 1, _PAGE, width), jnp.bfloat16)
     _compiles_with_mosaic(
         lambda q, p, pt, n, layer: mla_decode_attention(
-            q, p, latent_work_list(pt, n, _PAGE), layer, rank=rank,
-            scale=0.1447, interpret=False),
+            q, p, pt, n, layer, rank=rank, scale=0.1447, interpret=False,
+            sliding_window=window),
         one_chip((batch, heads, width), jnp.bfloat16), pool,
         one_chip((batch, pmax), jnp.int32), one_chip((batch,), jnp.int32),
         one_chip((), jnp.int32))
     lane = one_chip((1,), jnp.int32)
     _compiles_with_mosaic(
         lambda q, p, pt, h, n, layer: mla_ragged_attention(
-            q, p, pt, h, n, layer, rank=rank, scale=0.1447, interpret=False),
+            q, p, pt, h, n, layer, rank=rank, scale=0.1447, interpret=False,
+            sliding_window=window),
         one_chip((1, heads, 512, width), jnp.bfloat16), pool,
         one_chip((1, pmax), jnp.int32), lane, lane, one_chip((), jnp.int32))
 

@@ -1,20 +1,24 @@
 """The latent attention kernels (ops/mla_attention.py) in interpret mode
 against plain ``jnp``: ragged lengths, idle rows, a page read once as key
-(all its lanes) and as value (its first ``rank``); and the work list they
-share with the K/V decode kernel feeding the walked/offered counters."""
+(all its lanes) and as value (its first ``rank``); and the decode kernel's
+walk: the copies it starts are the pages the walked/offered counters count,
+its key blocks may hold anything before a call, every start has its wait."""
+
+import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
-from cyberfabric_core_tpu.ops.mla_attention import (PAGE_GROUP,
-                                                    latent_work_list,
+from cyberfabric_core_tpu.ops import mla_attention as mla
+from cyberfabric_core_tpu.ops.mla_attention import (RING_BLOCKS, TRIP_PAGES,
                                                     mla_decode_attention,
                                                     mla_ragged_attention,
-                                                    ragged_q_block)
-from cyberfabric_core_tpu.ops.paged_attention import (decode_work_list,
-                                                      page_span)
+                                                    ragged_q_block,
+                                                    trip_pages)
+from cyberfabric_core_tpu.ops.paged_attention import page_span
 
 RANK, ROPE, LANES, HQ, PAGE = 32, 16, 128, 4, 8
 SCALE = 0.21
@@ -35,30 +39,184 @@ def _dense(q, rows, n):
     return (p / p.sum(-1, keepdims=True)) @ rows[:n, :RANK]
 
 
-@pytest.mark.parametrize("lengths", [[13, 0, 48, 9], [1, 8, 0, 0],
-                                     [48, 48, 48, 48]],
-                         ids=["ragged", "short-and-idle", "full-table"])
-@pytest.mark.parametrize("layer,group", [(0, PAGE_GROUP), (1, 4), (1, 1)])
-def test_decode_kernel_against_jnp(lengths, layer, group):
-    """A slot's pages ``group`` at a time: one that covers a whole row of
-    the table, one that does not divide it, and one page a program."""
-    rng = np.random.default_rng(sum(lengths) + layer)
-    pool = _pool(rng)
-    B, pmax = 4, 6
-    table = jnp.asarray(rng.permutation(np.arange(1, 40))[: B * pmax]
-                        .reshape(B, pmax), jnp.int32)
-    q = rng.standard_normal((B, HQ, LANES)).astype(np.float32)
-    lens = jnp.asarray(lengths, jnp.int32)
-    out = np.asarray(mla_decode_attention(
-        jnp.asarray(q), pool, latent_work_list(table, lens, PAGE, group),
-        layer, rank=RANK, scale=SCALE, interpret=True))
-    assert out.shape == (B, HQ, RANK)
+def _table(rng, B, pmax, pages=40):
+    return jnp.asarray(rng.permutation(np.arange(1, pages))[: B * pmax]
+                       .reshape(B, pmax), jnp.int32)
+
+
+def _check(out, q, pool, layer, table, lengths):
     for b, n in enumerate(lengths):
         if n == 0:
             assert not out[b].any()          # an idle row finalises to zeros
             continue
         rows = np.asarray(pool[layer][table[b]]).reshape(-1, LANES)
         np.testing.assert_allclose(out[b], _dense(q[b], rows, n), atol=2e-5)
+
+
+@pytest.mark.parametrize("lengths", [[13, 0, 48, 9], [1, 8, 0, 0],
+                                     [48, 48, 48, 48]],
+                         ids=["ragged", "short-and-idle", "full-table"])
+@pytest.mark.parametrize("layer,trip", [(0, TRIP_PAGES), (1, 4), (1, 1)])
+def test_decode_kernel_against_jnp(lengths, layer, trip):
+    """A slot's pages ``trip`` at a time: a trip that covers a whole row of
+    the table, one that does not divide it, and one page a trip."""
+    rng = np.random.default_rng(sum(lengths) + layer)
+    pool = _pool(rng)
+    B, pmax = 4, 6
+    table = _table(rng, B, pmax)
+    q = rng.standard_normal((B, HQ, LANES)).astype(np.float32)
+    out = np.asarray(mla_decode_attention(
+        jnp.asarray(q), pool, table, jnp.asarray(lengths, jnp.int32),
+        layer, rank=RANK, scale=SCALE, interpret=True, trip=trip))
+    assert out.shape == (B, HQ, RANK)
+    _check(out, q, pool, layer, table, lengths)
+
+
+#: TPU interpret mode: memory no one wrote reads NaN (so do the key blocks
+#: and the accumulators before a call), a DMA lands when it is waited for,
+#: and a read or write that races one is reported
+POISONED = pltpu.InterpretParams(detect_races=True)
+
+
+def _races_found() -> bool:
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+
+    return interpret_pallas_call.races.races_found
+
+
+@pytest.mark.parametrize("lengths,trip", [
+    ([16, 24, 9], 2),           # spans of exactly T and T + 1 pages
+    ([0, 0, 13, 21], 2),        # idle rows first
+    ([13, 0, 0, 21], 2),        # ... between
+    ([13, 21, 0, 0], 2),        # ... last
+    ([0, 0, 0], 2),             # nothing to walk: no copy, no wait
+    ([37], 2),                  # B = 1
+    ([1], TRIP_PAGES),          # B = 1, a span of one page
+    ([48, 3, 48, 0, 48], 1),    # more trips in flight than a row has
+    ([9, 48, 0, 17, 1, 33], 4),
+], ids=["T-and-T+1", "idle-first", "idle-between", "idle-last", "all-idle",
+        "one-row", "one-page", "trip-1", "mixed"])
+def test_decode_kernel_with_the_ring_poisoned(lengths, trip):
+    """The rows of a key block past a span's end hold whatever was there: a
+    NaN there, under a probability of zero, would be a NaN in the output.
+    Pages outside every span are NaN too: the kernel copies none of them."""
+    rng = np.random.default_rng(sum(lengths) + trip)
+    B, pmax = len(lengths), 6
+    pool = np.asarray(_pool(rng, pages=B * pmax + 1)).copy()
+    table = np.arange(1, B * pmax + 1, dtype=np.int32).reshape(B, pmax)
+    for b, n in enumerate(lengths):
+        pool[:, table[b, -(-n // PAGE):]] = np.nan
+    pool[:, 0] = np.nan
+    q = rng.standard_normal((B, HQ, LANES)).astype(np.float32)
+    out = np.asarray(mla_decode_attention(
+        jnp.asarray(q), jnp.asarray(pool), jnp.asarray(table),
+        jnp.asarray(lengths, jnp.int32), 1, rank=RANK, scale=SCALE,
+        interpret=POISONED, trip=trip))
+    assert not _races_found()
+    assert np.isfinite(out).all()
+    _check(out, q, pool, 1, table, lengths)
+
+
+class _CountedCopy:
+    """A DMA whose start and wait say so to the host as they RUN (under the
+    kernel's conditions, not as they are traced); ``seen`` also counts the
+    trips the kernel attended over, by the pages of the block it took."""
+    seen: dict = {}
+
+    def __init__(self, *args):
+        self._copy = pltpu.make_async_copy(*args)
+
+    def _count(self, what):
+        def bump():
+            _CountedCopy.seen[what] = _CountedCopy.seen.get(what, 0) + 1
+        jax.debug.callback(bump)
+
+    def start(self):
+        self._count("start")
+        self._copy.start()
+
+    def wait(self):
+        self._count("wait")
+        self._copy.wait()
+
+
+@pytest.fixture()
+def counted_copies(monkeypatch):
+    proxy = types.SimpleNamespace(**{n: getattr(pltpu, n) for n in dir(pltpu)
+                                     if not n.startswith("__")})
+    proxy.make_async_copy = _CountedCopy
+    monkeypatch.setattr(mla, "pltpu", proxy)
+    _CountedCopy.seen = {}
+    attend = mla._attend_trip
+
+    def counted_trip(*args, pages, **kwargs):
+        def bump():
+            for what in ("trips", f"trips_of_{pages}"):
+                _CountedCopy.seen[what] = _CountedCopy.seen.get(what, 0) + 1
+        jax.debug.callback(bump)
+        attend(*args, pages=pages, **kwargs)
+    monkeypatch.setattr(mla, "_attend_trip", counted_trip)
+
+    def run(name, *args, **kwargs):
+        _CountedCopy.seen.clear()
+        # a name of its own: a trace of its own
+        jax.block_until_ready(mla_decode_attention(
+            *args, interpret=True, name=name, **kwargs))
+        jax.effects_barrier()
+        return dict(_CountedCopy.seen)
+    return run
+
+
+@pytest.mark.parametrize("trip", [1, 4, 8])
+def test_the_copies_a_call_starts_are_what_the_walked_counter_counts(
+        counted_copies, trip):
+    """The scheduler counts the pages the kernel walked from the host's
+    length mirror by the kernel's own ``page_span``: sum over rows of (last -
+    first + 1), of ``B x Pmax`` offered, a row that holds nothing counted as
+    the one page its program costs. The kernel starts one copy for each of
+    those pages (none for the row that holds nothing), and waits for each."""
+    lengths = np.array([13, 0, 48, 9, 1, 17])
+    table = jnp.asarray(np.arange(1, 37).reshape(6, 6), jnp.int32)
+    first, last = page_span(lengths, PAGE, 6, None)
+    walked = int((last - first + 1).sum())
+    assert walked == 2 + 1 + 6 + 2 + 1 + 3
+    assert walked / table.size == pytest.approx(15 / 36)
+    pool = _pool(np.random.default_rng(0))
+    seen = counted_copies(
+        f"counted_{trip}", jnp.ones((6, HQ, LANES)), pool, table,
+        jnp.asarray(lengths, jnp.int32), 0, rank=RANK, scale=SCALE,
+        trip=trip)
+    assert (seen["start"], seen["wait"]) == (walked - 1, walked - 1)
+    # and a row's trips are the groups ``_count_attn_pages`` counts for
+    # ``llm_attn_page_groups_total``, but for the row that holds nothing
+    assert seen["trips"] == int(((last - first) // trip + 1).sum()) - 1
+    blocks = {k: v for k, v in seen.items() if k.startswith("trips_of_")}
+    assert blocks == {1: {"trips_of_1": 14},
+                      4: {"trips_of_2": 4, "trips_of_4": 2},
+                      8: {"trips_of_2": 3, "trips_of_4": 1,
+                          "trips_of_8": 1}}[trip]
+
+
+def test_trip_pages_is_what_the_scheduler_counts_groups_by():
+    """``decode_page_group`` (what ``_count_attn_pages`` divides a row's
+    span by for ``llm_attn_page_groups_total``) is the kernel's pages a
+    trip: 16, and under one window for every layer no more than it spans."""
+    from cyberfabric_core_tpu.models import get_config
+    from cyberfabric_core_tpu.models.llama import decode_page_group
+
+    assert (TRIP_PAGES, RING_BLOCKS) == (16, 3)
+    assert trip_pages(64, None) == 16
+    assert trip_pages(64, 128) == 3 and trip_pages(64, 4096) == 16
+    assert trip_pages(16, 128) == 9 and trip_pages(16, 50) == 5
+    # a trip is attended over as the smallest of these that holds its pages
+    assert mla._block_sizes(16) == (2, 4, 8, 16)
+    assert mla._block_sizes(3) == (2, 3) and mla._block_sizes(1) == (1,)
+    kimi = get_config("kimi-k2.5-share32-15l")
+    assert decode_page_group(kimi, 64, 48, 2) == trip_pages(64, None)
+    motif = get_config("motif-3-beta-share32-27l")
+    assert motif.window_pages(64) == trip_pages(64, motif.sliding_window)
+    # the pair the group counter goes with counts the FULL layers' spans
+    assert decode_page_group(motif, 64, 128, 2) == 16
 
 
 @pytest.mark.parametrize("width", [16, 64])
@@ -97,35 +255,3 @@ def test_ragged_kernel_refuses_a_width_that_is_not_whole_blocks():
             jnp.zeros((1, HQ, 8, LANES)), pool, jnp.ones((1, 4), jnp.int32),
             jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32), 0,
             rank=RANK, scale=SCALE, interpret=True)
-
-
-def test_work_list_is_what_the_walked_counter_counts():
-    """The latent kernel's grid is ``decode_work_list``'s pages in use, a
-    slot's pages a group at a time, and the scheduler counts those pages
-    from the host's length mirror by the same ``page_span``: pages walked ==
-    sum over rows of (last - first + 1), of ``B x Pmax`` offered."""
-    lengths = np.array([13, 0, 48, 9, 1, 17])
-    table = jnp.asarray(np.arange(1, 37).reshape(6, 6), jnp.int32)
-    lens = jnp.asarray(lengths, jnp.int32)
-    pages = decode_work_list(table, lens, PAGE)
-    first, last = page_span(lengths, PAGE, 6, None)
-    walked = int((last - first + 1).sum())
-    assert int(pages.n_items) == walked == 2 + 1 + 6 + 2 + 1 + 3
-    assert walked / table.size == pytest.approx(15 / 36)
-    for group in (1, 4, 8):
-        work = latent_work_list(table, lens, PAGE, group)
-        n = int(work.n_items)
-        assert n == sum(int(l) // group + 1 for l in last)
-        phys = np.asarray(work.phys).reshape(-1, group)[:n]
-        rows, firsts = np.asarray(work.row)[:n], np.asarray(work.first)[:n]
-        seen = []
-        for r, f, ph in zip(rows, firsts, phys):
-            for g in range(group):
-                logical = min(f + g, int(last[r]))   # past the last: it again
-                assert ph[g] == int(table[r, logical])
-                if f + g <= last[r]:
-                    seen.append((int(r), int(f + g)))
-        # every page that holds tokens once, rows in order, pages ascending
-        assert seen == [(r, p) for r in range(6)
-                        for p in range(int(last[r]) + 1)]
-        assert len(seen) == walked

@@ -17,6 +17,8 @@ import pytest
 from cyberfabric_core_tpu.models import get_config
 from cyberfabric_core_tpu.models.llama import decode_work
 from cyberfabric_core_tpu.ops import mla_attention as mla
+from cyberfabric_core_tpu.ops.paged_attention import page_span
+from test_mla_attention import counted_copies  # noqa: F401  (a fixture)
 
 HQ, WINDOW, PAGE, RANK, LANES = 80, 128, 64, 128, 256
 LENGTHS = (127, 128, 129, 191, 192, 193, 700, 4097)
@@ -63,13 +65,12 @@ def test_decode_kernel_at_80_heads(length, window):
         pool[0, 0] = 0.0
     q = jax.random.normal(jax.random.PRNGKey(1), (2, HQ, LANES), jnp.bfloat16)
     lengths = jnp.asarray([length, 0], jnp.int32)
-    work = mla.latent_work_list(jnp.asarray(table), lengths, PAGE,
-                                3 if window else mla.PAGE_GROUP, window)
-    if window:      # a row costs one program whatever its length
-        assert int(work.n_items) == 2
+    # a window's trip is the pages it spans: one trip a row at any length
+    assert mla.trip_pages(PAGE, window) == (3 if window else mla.TRIP_PAGES)
     out = mla.mla_decode_attention(
-        q, jnp.asarray(pool, jnp.bfloat16), work, 0, rank=RANK, scale=SCALE,
-        interpret=True, sliding_window=window, name="gdla_test")
+        q, jnp.asarray(pool, jnp.bfloat16), jnp.asarray(table), lengths, 0,
+        rank=RANK, scale=SCALE, interpret=True, sliding_window=window,
+        name="gdla_test")
     want = _dense(np.asarray(q[0]), rows[:length], np.arange(length),
                   length - 1, window)
     got = np.asarray(out[0].astype(jnp.float32))
@@ -105,22 +106,38 @@ def test_ragged_kernel_at_80_heads(length, window):
         np.testing.assert_allclose(got[:, i], want, atol=0.02, rtol=0.02)
 
 
-def test_a_window_layers_programs_do_not_grow_with_context():
-    """``decode_work`` for a window group takes a group of the pages a window
-    spans (3), one program a row at any length; the ragged grid's page axis
-    is the pages a q-block's windows span (4), not the table's."""
+def test_a_window_layers_copies_do_not_grow_with_context(counted_copies):
+    """A window layer's row is ONE trip of the pages a window spans (3) at
+    any length, and the kernel starts a copy for the pages of the span alone:
+    a row of 8 191 tokens starts 3 (of 8 192, whose window starts on a page:
+    2), where a full layer's starts 128 in 8 trips; the ragged grid's page
+    axis is the pages a q-block's windows span (4), not the table's.
+    ``decode_work`` hands the latent kernel the table and the lengths: it
+    walks them itself."""
     cfg = get_config("motif-3-beta-share32-27l")
     assert cfg.window_pages(PAGE) == 3 and cfg.window_pages(PAGE, 32) == 4
+    assert mla.trip_pages(PAGE, cfg.sliding_window) == 3
     pool = jnp.zeros((1, 4, PAGE, 128), jnp.bfloat16)
-    table = jnp.ones((4, 128), jnp.int32)
-    lengths = jnp.asarray([1, 129, 4097, 8192], jnp.int32)
-    windowed = decode_work(cfg, table, lengths, pool, cfg.sliding_window)
-    full = decode_work(cfg, table, lengths, pool, None)
-    assert int(windowed.n_items) == 4
-    assert windowed.phys.shape[0] == 3 * windowed.row.shape[0]
-    assert int(full.n_items) == 1 + 1 + 9 + 16       # groups of 8 pages
-    np.testing.assert_array_equal(
-        np.asarray(windowed.first[:4]), [0, 0, 62, 126])
+    table = jnp.ones((5, 128), jnp.int32)
+    lengths = jnp.asarray([1, 129, 4097, 8191, 8192], jnp.int32)
+    for window in (cfg.sliding_window, None):
+        work = decode_work(cfg, table, lengths, pool, window)
+        assert work[0] is table and work[1] is lengths
+    q = jnp.zeros((5, 8, 128), jnp.bfloat16)
+    first, last = page_span(np.asarray(lengths), PAGE, 128,
+                            cfg.sliding_window)
+    np.testing.assert_array_equal(first, [0, 0, 62, 125, 126])
+    np.testing.assert_array_equal(last - first + 1, [1, 3, 3, 3, 2])
+    seen = counted_copies("counted_window", q, pool, table, lengths, 0,
+                          rank=64, scale=SCALE,
+                          sliding_window=cfg.sliding_window)
+    assert (seen["start"], seen["wait"], seen["trips"]) == (12, 12, 5)
+    # a window of two pages is attended over as two, not three
+    assert (seen["trips_of_2"], seen["trips_of_3"]) == (2, 3)
+    seen = counted_copies("counted_full", q, pool, table, lengths, 0, rank=64,
+               scale=SCALE)
+    assert (seen["start"], seen["wait"]) == (325, 325)
+    assert seen["trips"] == 1 + 1 + 5 + 8 + 8
 
 
 def _digest(x):
@@ -149,10 +166,9 @@ PARENT = {
 @pytest.mark.parametrize("group", [1, 8])
 def test_the_unwindowed_decode_kernel_is_the_parents_bit_for_bit(group):
     pool, q, table, _ = _parent_inputs()
-    work = mla.latent_work_list(table, jnp.asarray([40, 17, 0], jnp.int32),
-                                16, group)
-    out = mla.mla_decode_attention(q, pool, work, 1, rank=96, scale=0.11,
-                                   interpret=True)
+    out = mla.mla_decode_attention(
+        q, pool, table, jnp.asarray([40, 17, 0], jnp.int32), 1, rank=96,
+        scale=0.11, interpret=True, trip=group)
     assert _digest(out[:2]) == PARENT[f"decode{group}"]
 
 

@@ -643,6 +643,7 @@ def test_attn_pages_counted_by_step_from_kept_lengths(model, over):
     same table and lengths), and the pages walked do not depend on how they
     are grouped."""
     from cyberfabric_core_tpu.models.llama import decode_work
+    from cyberfabric_core_tpu.ops.mla_attention import trip_pages
 
     names = ("llm_attn_pages_walked_total", "llm_attn_page_groups_total",
              "llm_attn_pages_offered_total")
@@ -664,14 +665,21 @@ def test_attn_pages_counted_by_step_from_kept_lengths(model, over):
     walked, programs = 0, 0
     lengths = np.where([True, True, False, True], kept, 0)
     for f in range(3):
-        walked += int(np.minimum(-(-(lengths + step) // page), slots).sum())
-        work = decode_work(cfg, eng.page_table, lengths + step, pool,
-                           cfg.sliding_window)
-        programs += int(work.n_items)
+        pages = np.minimum(-(-(lengths + step) // page), slots)
+        walked += int(pages.sum())
+        if cfg.is_latent:
+            # the latent kernel walks the table itself, a trip of pages at
+            # a time (tests/test_mla_attention.py counts the trips it runs)
+            group = trip_pages(page, cfg.sliding_window)
+            programs += int((-(-pages // group)).sum())
+        else:
+            work = decode_work(cfg, eng.page_table, lengths + step, pool,
+                               cfg.sliding_window)
+            programs += int(work.n_items)
+            group = work.phys.shape[0] // work.row.shape[0]
         lengths = lengths + step * grew[:, f]
     assert got == [walked * layers, programs * layers,
                    4 * 3 * slots * layers]
-    group = work.phys.shape[0] // work.row.shape[0]
     assert group > 1 and walked / group <= programs < walked
 
 
