@@ -184,10 +184,10 @@ def collect_accelerators() -> list[dict[str, Any]]:
     """Accelerator inventory via JAX (gpu_collector analogue for the TPU
     fleet): platform/kind per device + HBM totals where exposed."""
     try:
-        import jax
+        from .telemetry import jax_devices
 
         out = []
-        for d in jax.devices():
+        for d in jax_devices():
             dev: dict[str, Any] = {
                 "id": d.id, "platform": d.platform,
                 "model": getattr(d, "device_kind", "?"),

@@ -35,6 +35,7 @@ from .contracts import (
 from .context import ModuleCtx
 from .lifecycle import ReadySignal
 from .registry import ModuleEntry, ModuleRegistry
+from .telemetry import startup
 
 logger = logging.getLogger(__name__)
 
@@ -100,7 +101,8 @@ class HostRuntime:
 
     async def run_init_phase(self) -> None:
         for entry in self.registry.entries:  # already topo-sorted
-            await entry.instance.init(self.ctx_for(entry))
+            with startup.stage("boot.init." + entry.name):
+                await entry.instance.init(self.ctx_for(entry))
 
     async def run_post_init_phase(self) -> None:
         for entry in self.registry.with_capability("system"):
@@ -157,15 +159,17 @@ class HostRuntime:
             assert isinstance(entry.instance, RunnableCapability)
             ready = ReadySignal()
             ctx = self.ctx_for(entry)
-            await entry.instance.start(ctx, ready)
-            try:
-                await ready.wait(timeout=30.0)
-            except asyncio.TimeoutError:
-                await self._abort_failed_start(entry)
-                raise RuntimeError(f"module {entry.name} did not become ready in 30s")
-            except Exception:
-                await self._abort_failed_start(entry)
-                raise
+            with startup.stage("boot.start." + entry.name):
+                await entry.instance.start(ctx, ready)
+                try:
+                    await ready.wait(timeout=30.0)
+                except asyncio.TimeoutError:
+                    await self._abort_failed_start(entry)
+                    raise RuntimeError(
+                        f"module {entry.name} did not become ready in 30s")
+                except Exception:
+                    await self._abort_failed_start(entry)
+                    raise
             self._started.append(entry)
             logger.info("module %s running", entry.name)
 
@@ -220,15 +224,20 @@ class HostRuntime:
 
     # ------------------------------------------------------------------ drivers
     async def run_setup_phases(self) -> None:
-        """Everything up to (and including) start — then the host is serving."""
-        await self.run_pre_init_phase()
-        await self.run_db_phase()
-        await self.run_init_phase()
-        await self.run_post_init_phase()
-        await self.run_rest_phase()
-        await self.run_grpc_phase()
-        await self.run_start_phase()
-        await self.run_oop_spawn_phase()
+        """Everything up to (and including) start — then the host is serving.
+        Each phase is a stage of the start-up timeline (a child of ``boot``
+        where ``server.py`` opened it), and its end is ``ready``."""
+        for name, phase in (("pre_init", self.run_pre_init_phase),
+                            ("db", self.run_db_phase),
+                            ("init", self.run_init_phase),
+                            ("post_init", self.run_post_init_phase),
+                            ("rest", self.run_rest_phase),
+                            ("grpc", self.run_grpc_phase),
+                            ("start", self.run_start_phase),
+                            ("oop_spawn", self.run_oop_spawn_phase)):
+            with startup.stage("boot." + name):
+                await phase()
+        startup.ready()
 
     async def run_module_phases(self) -> None:
         """Full lifecycle: setup → wait for cancellation → stop

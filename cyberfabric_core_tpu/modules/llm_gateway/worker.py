@@ -28,6 +28,7 @@ from ...modkit.errcat import ERR
 from ...modkit.errors import ProblemError
 from ...modkit.failpoints import failpoint_async
 from ...modkit.logging_host import observe_task
+from ...modkit.telemetry import FIRST_TOKEN, Stage, startup
 from ...parallel.feasibility import InfeasiblePlanError
 from ...runtime.engine import (EngineConfig, InferenceEngine, SamplingParams,
                                SchedulerSaturated, StepEvent,
@@ -36,7 +37,7 @@ from ...runtime.federation import digest_chain, prompt_text
 from ...runtime.lifecycle import (EngineSupervisor, LifecycleConfig,
                                   LifecycleStateError, ReplicaUnavailable)
 from ...runtime.replicas import DataParallelServingPool
-from ...runtime.scheduler import ContinuousBatchingEngine
+from ...runtime.scheduler import ContinuousBatchingEngine, tree_bytes
 from ...runtime.tokenizer import (CHAT_FAMILIES, ByteTokenizer, Tokenizer,
                                   chat_family_for, load_tokenizer, render_chat)
 from ..sdk import ChatStreamChunk, LlmWorkerApi, ModelInfo
@@ -107,6 +108,10 @@ class _EngineEntry:
     model_family: str = "llama"
     last_used: float = 0.0
     est_bytes: int = 0
+    #: the start-up timeline's ``first_token`` stage of the request that
+    #: caused this build, beside that request's ``params`` (its identity),
+    #: until the request's first chunk or its end
+    cold_start: Optional[tuple[Stage, dict]] = None
 
     @property
     def idle(self) -> bool:
@@ -221,7 +226,13 @@ class LocalTpuWorker(LlmWorkerApi):
         self._census_lock = threading.Lock()
 
     # ------------------------------------------------------------------ engines
-    async def _entry_for(self, model: ModelInfo) -> _EngineEntry:
+    async def _entry_for(self, model: ModelInfo,
+                         cause: Optional[dict] = None,
+                         arrived_ns: Optional[int] = None) -> _EngineEntry:
+        """The model's engine, built on the first use. ``cause``: the
+        ``params`` of the request that asks, which arrived at ``arrived_ns``:
+        if this call builds, that request is the first user after a restart,
+        and what it waits for is the timeline's ``first_token`` stage."""
         key = model.canonical_id
         entry = self._entries.get(key)
         if entry is not None:
@@ -236,10 +247,25 @@ class LocalTpuWorker(LlmWorkerApi):
             loop = asyncio.get_running_loop()
             self._maybe_evict_for(model)
             t0 = time.monotonic()
+            request_id = (cause or {}).get("_request_id")
+            first = None if cause is None else startup.begin(
+                FIRST_TOKEN, parent=None, start_unix_ns=arrived_ns,
+                sums_programs=True, model=key, request_id=request_id)
+
+            def build() -> _EngineEntry:
+                # opened on the thread that builds: its children are the
+                # stages that thread opens (runtime/scheduler.py)
+                with startup.stage("engine.build", parent=first, model=key,
+                                   request_id=request_id):
+                    return self._build_entry(model)
+
             try:
-                entry = await loop.run_in_executor(
-                    self._executor, self._build_entry, model)
-            except InfeasiblePlanError as e:
+                entry = await loop.run_in_executor(self._executor, build)
+            except BaseException as e:
+                if first is not None:
+                    startup.end(first, status="error")
+                if not isinstance(e, InfeasiblePlanError):
+                    raise
                 # the feasibility gate fired at engine construction: the
                 # model's (tp, quant, batch, seq) plan cannot fit the
                 # per-device HBM budget. A clean, typed 507 problem — the
@@ -252,8 +278,21 @@ class LocalTpuWorker(LlmWorkerApi):
                         time.monotonic() - t0)
             entry.last_used = time.monotonic()
             entry.est_bytes = self._estimate_bytes(model)
+            if first is not None:
+                entry.cold_start = (first, cause)
             self._entries[key] = entry
             return entry
+
+    @staticmethod
+    def _first_token(entry: _EngineEntry, params: dict,
+                     status: str = "ok") -> None:
+        """Close the ``first_token`` stage if the request of ``params`` is
+        the one that caused the build: at its first chunk, or (``error``)
+        where it ends without one."""
+        cold = entry.cold_start
+        if cold is not None and cold[1] is params:
+            entry.cold_start = None
+            startup.end(cold[0], status=status)
 
     # -------------------------------------------------------- model hot-swap
     def _estimate_bytes(self, model: ModelInfo) -> int:
@@ -327,7 +366,10 @@ class LocalTpuWorker(LlmWorkerApi):
 
             gc.collect()
 
-    def _build_entry(self, model: ModelInfo) -> _EngineEntry:
+    @staticmethod
+    def _engine_config(model: ModelInfo) -> tuple[EngineConfig, str, dict]:
+        """The engine's configuration and the chat family from the registry's
+        ``engine_options``, and the options no pop here took."""
         opts = dict(model.engine_options or {})
         arch_config = opts.pop("model_config", None) or model.provider_model_id
         # registry can pin the chat template family; otherwise inferred from
@@ -409,6 +451,12 @@ class LocalTpuWorker(LlmWorkerApi):
             # a model with recurrent state: snapshot rows for prefix reuse
             state_snapshots=int(opts.pop("state_snapshots", -1)),
         )
+        return eng_cfg, chat_family, opts
+
+    def _build_entry(self, model: ModelInfo) -> _EngineEntry:
+        with startup.stage("engine.config"):
+            eng_cfg, chat_family, opts = self._engine_config(model)
+        arch_config = eng_cfg.model
         params = None
         tokenizer: Tokenizer
         if model.checkpoint_path and Path(model.checkpoint_path).exists():
@@ -419,9 +467,12 @@ class LocalTpuWorker(LlmWorkerApi):
             from ...runtime.quant import quant_bits
 
             bits = quant_bits(eng_cfg.quantization)
-            params = load_llama_params(
-                model.checkpoint_path, cfg,
-                quantize=bits is not None, quant_bits=bits or 8)
+            with startup.stage("engine.weights", source="checkpoint",
+                               quantization=eng_cfg.quantization) as loaded:
+                params = load_llama_params(
+                    model.checkpoint_path, cfg,
+                    quantize=bits is not None, quant_bits=bits or 8)
+                loaded.attrs["bytes"] = tree_bytes(params)
             tokenizer = load_tokenizer(model.checkpoint_path)
         else:
             # synthetic weights (airgapped/dev): byte tokenizer over model vocab
@@ -512,6 +563,8 @@ class LocalTpuWorker(LlmWorkerApi):
                 return _EngineEntry(config=eng_cfg, tokenizer=tokenizer,
                                     pool=pool, model_family=chat_family)
             scheduler = ContinuousBatchingEngine(eng_cfg, params=params)
+            with startup.stage("engine.thread"):
+                scheduler.start()
             supervisor = None
             if lc_cfg.enabled:
                 def _rebuild(old: Any, _cfg=eng_cfg) -> Any:
@@ -549,7 +602,7 @@ class LocalTpuWorker(LlmWorkerApi):
     async def chat_stream(
         self, model: ModelInfo, messages: list[dict], params: dict
     ) -> AsyncIterator[ChatStreamChunk]:
-        entry = await self._entry_for(model)
+        entry = await self._entry_for(model, params, time.time_ns())
         # digest BEFORE any preamble/template work: the federated router
         # hashes the same raw message text on its side of the wire — the two
         # chains must agree byte-for-byte for prefix placement to hit
@@ -574,25 +627,31 @@ class LocalTpuWorker(LlmWorkerApi):
             census_text=census_text)
         try:
             async for chunk in agen:
+                if entry.cold_start is not None:
+                    self._first_token(entry, params)
                 yield chunk
         finally:
             await agen.aclose()
+            self._first_token(entry, params, status="error")
 
     async def completion_stream(
         self, model: ModelInfo, prompt: str, params: dict
     ) -> AsyncIterator[ChatStreamChunk]:
         """Raw text completion (POST /v1/completions, the BASELINE metric
         surface): the prompt is tokenized verbatim — no chat template."""
-        entry = await self._entry_for(model)
+        entry = await self._entry_for(model, params, time.time_ns())
         agen = self._generate_from_ids(
             entry, model, entry.tokenizer.encode(prompt), params,
             census_text=prompt_text(prompt=prompt))
         try:
             async for chunk in agen:
+                if entry.cold_start is not None:
+                    self._first_token(entry, params)
                 yield chunk
         finally:
             # deterministic teardown: see chat_stream
             await agen.aclose()
+            self._first_token(entry, params, status="error")
 
     async def _generate_from_ids(
         self, entry: _EngineEntry, model: ModelInfo, prompt_ids: list[int],

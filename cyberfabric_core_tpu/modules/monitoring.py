@@ -8,6 +8,8 @@ occupancy), and device metrics (TPU count, HBM when the PJRT plugin reports it).
 
 from __future__ import annotations
 
+import time
+
 from aiohttp import web
 
 from ..modkit import Module, module
@@ -16,6 +18,7 @@ from ..modkit.contracts import RestApiCapability, RunnableCapability
 from ..modkit.context import ModuleCtx
 from ..modkit.lifecycle import ReadySignal
 from ..modkit.metrics import MetricsRegistry, default_registry
+from ..modkit.telemetry import startup
 from ..gateway.validation import read_json
 from .sdk import LlmWorkerApi
 
@@ -725,6 +728,33 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
             "p50 pending-queue wait of admitted requests (ms)"
         ).set_function(queue_wait_p50_ms)
 
+        # the start-up timeline's three instants (the compile ledger's
+        # counters are the listeners' own: StartupTimeline)
+        self.registry.gauge(
+            "process_start_time_seconds",
+            "Unix time the OS started this process"
+        ).set(startup.process_start_unix_ns / 1e9)
+        self.registry.gauge(
+            "process_uptime_seconds", "Seconds since the process started"
+        ).set_function(
+            lambda: time.time() - startup.process_start_unix_ns / 1e9)
+        self.registry.gauge(
+            "startup_ready_seconds",
+            "Seconds from the process's start until /healthz could answer "
+            "(the boot stage; 0 until then)"
+        ).set_function(
+            lambda: (startup.ready_unix_ns - startup.process_start_unix_ns)
+            / 1e9 if startup.ready_unix_ns else 0.0)
+        # what the ledger's listeners cost: JAX's calls into them and the
+        # seconds inside (read at a scrape; nothing is counted a call)
+        self.registry.gauge(
+            "startup_listener_calls", "Calls of the compile ledger's listeners"
+        ).set_function(lambda: startup.listener_calls)
+        self.registry.gauge(
+            "startup_listener_seconds",
+            "Seconds inside the compile ledger's listeners"
+        ).set_function(lambda: startup.listener_seconds)
+
     async def start(self, ctx: ModuleCtx, ready: ReadySignal) -> None:
         # the evaluation thread spins up in start (not init) so its lifetime
         # matches the stack's: stop() below is the teardown
@@ -1039,6 +1069,20 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
             .summary("Recent scheduler rounds; ?format=chrome-trace exports "
                      "Perfetto-loadable trace events") \
             .handler(export_rounds).register()
+
+        # ---- the start-up timeline (modkit/telemetry.py StartupTimeline):
+        # boot and each engine's build as a tree of stages with self time,
+        # what the first user after a restart waited for, and the compile
+        # ledger summed by program
+        async def get_startup(request: web.Request):
+            return startup.snapshot()
+
+        router.operation("GET", "/v1/monitoring/startup",
+                         module="monitoring").auth_required() \
+            .summary("Start-up timeline: boot and engine-build stages with "
+                     "self time, first_token a model, the compile ledger by "
+                     "program (trace, lower, compile or cache load)") \
+            .handler(get_startup).register()
 
         # ---- fabric-doctor: the full SLO/state document behind the public
         # /readyz verdict — objective table with fast/slow burn rates,

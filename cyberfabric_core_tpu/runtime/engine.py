@@ -404,7 +404,6 @@ class InferenceEngine:
         self.spec_stats = {"verify_calls": 0, "drafted": 0, "accepted": 0,
                            "spec_tokens": 0, "fallback_steps": 0,
                            "accept_hist": {}}
-        self.last_prefill_compile_s: float = 0.0
 
     def _record_spec_round(self, a: int, spec_k: int, committed: int) -> None:
         """One verify round's evidence — shared by the ngram and draft paths
@@ -605,13 +604,11 @@ class InferenceEngine:
         top_k = jnp.asarray([s.top_k for s in per_req], jnp.int32)
 
         prefill = self._prefill_for(B, bucket)
-        c0 = time.monotonic()
         first_dev, cache, self._rng = prefill(
             self.params, jnp.asarray(ids), lengths, self._rng,
             temperature, top_p, top_k, self.rope_tables,
         )
         first = np.asarray(first_dev, np.int32)
-        self.last_prefill_compile_s = time.monotonic() - c0
         ttft_ms = (time.monotonic() - t_start) * 1000.0
 
         stops = [set(s.stop_token_ids) | set(self.config.eos_token_ids) for s in per_req]

@@ -140,7 +140,7 @@ from ..modkit.failpoints import failpoint, record_recovery
 from ..modkit.flight_recorder import record_event
 from ..modkit.metrics import bump_counter
 from ..modkit.telemetry import (get_global_tracer, reset_log_context,
-                                set_log_context, traceparent_ids)
+                                set_log_context, startup, traceparent_ids)
 from ..ops.sampling import host_key, host_split
 from .engine import (EngineConfig, SamplingParams, SchedulerSaturated,
                      StepEvent, TenantQuotaExceeded, TenantSaturated)
@@ -396,6 +396,11 @@ _SPAN_NAMES = {(p, s): f"sched.{p}.starved" if s else f"sched.{p}"
                for p in PHASES for s in (False, True)}
 
 
+def tree_bytes(tree: Any) -> int:
+    """Bytes of a tree's arrays (the weights as they are held)."""
+    return sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(tree))
+
+
 class _PhaseClock:
     """The one place the scheduler thread's time is taken. ``to(phase)`` is a
     SWITCH, not a nest: it closes the phase the thread was in and opens the
@@ -468,17 +473,21 @@ class _PhaseClock:
         self._span.__enter__()
         return now
 
-    def take(self) -> tuple[dict[str, list[float]], float, float]:
+    def take(self) -> tuple[dict[str, list[float]], float, float,
+                            Optional[dict[str, float]]]:
         """At a round record: ``phases`` (phase -> [wall_ms, cpu_ms,
         starved_ms], in the order they first ran) and ``pass_ms`` since the
-        previous record, whose sum over the phases it is; and the instant."""
+        previous record, whose sum over the phases it is; the instant; and
+        what this thread traced, lowered and compiled since the previous
+        record (program -> seconds, from the compile ledger's listeners:
+        None unless the pass compiled)."""
         now = self._stamp()
         phases = {p: [round(1e3 * wall, 4), round(1e3 * min(cpu, wall), 4),
                       round(1e3 * starved, 4)]
                   for p, (wall, cpu, starved) in self._acc.items()}
         pass_ms = round(1e3 * (now - self._pass_t0), 4)
         self._acc, self._pass_t0 = {}, now
-        return phases, pass_ms, now
+        return phases, pass_ms, now, startup.take_compiled()
 
     def close(self) -> None:
         """The loop's thread ends: close its open span."""
@@ -796,94 +805,106 @@ class ContinuousBatchingEngine:
         _init_ctx = contextlib.ExitStack()  # rest of __init__ allocates on-device
         if device is not None:
             _init_ctx.enter_context(jax.default_device(device))
-        from .quant import quant_bits as _qb
+        # the start-up timeline's stages of an engine's build (children of the
+        # worker's ``engine.build`` where it opened one): weights, then the
+        # slot rows and the pool, then the programs
+        with startup.stage(
+                "engine.weights",
+                source="synthetic" if params is None else "checkpoint",
+                quantization=config.quantization) as made:
+            from .quant import quant_bits as _qb
 
-        quant_bits = _qb(config.quantization)
-        if params is None:
-            if quant_bits is not None:
-                from .quant import init_params_quantized
+            quant_bits = _qb(config.quantization)
+            if params is None:
+                if quant_bits is not None:
+                    from .quant import init_params_quantized
 
-                params = init_params_quantized(
-                    self.model_config, jax.random.PRNGKey(seed), self.dtype,
-                    bits=quant_bits)
+                    params = init_params_quantized(
+                        self.model_config, jax.random.PRNGKey(seed), self.dtype,
+                        bits=quant_bits)
+                else:
+                    params = self._model.init_params(
+                        self.model_config, jax.random.PRNGKey(seed), self.dtype)
             else:
-                params = self._model.init_params(
-                    self.model_config, jax.random.PRNGKey(seed), self.dtype)
-        else:
-            if quant_bits is not None and not isinstance(
-                    params.get("embed"), dict):
-                # same pass-in semantics as InferenceEngine: a provided
-                # unquantized tree gets quantized, never silently served bf16
-                from .quant import quantize_llama_params
+                if quant_bits is not None and not isinstance(
+                        params.get("embed"), dict):
+                    # same pass-in semantics as InferenceEngine: a provided
+                    # unquantized tree gets quantized, never silently served bf16
+                    from .quant import quantize_llama_params
 
-                params = quantize_llama_params(params, bits=quant_bits)
-            if device is not None:
-                params = jax.device_put(params, device)
-        if self.mesh is not None:
-            # Megatron-style tp shardings (wq/wk/wv/gate/up column-parallel,
-            # wo/down row-parallel, lm_head vocab-sharded) — the SAME spec
-            # tree the feasibility gate budgeted and the AOT compiler lowers
-            from ..parallel.sharding import shard_llama_params
+                    params = quantize_llama_params(params, bits=quant_bits)
+                if device is not None:
+                    params = jax.device_put(params, device)
+            if self.mesh is not None:
+                # Megatron-style tp shardings (wq/wk/wv/gate/up column-parallel,
+                # wo/down row-parallel, lm_head vocab-sharded) — the SAME spec
+                # tree the feasibility gate budgeted and the AOT compiler lowers
+                from ..parallel.sharding import shard_llama_params
 
-            params = shard_llama_params(params, self.model_config, self.mesh)
+                params = shard_llama_params(params, self.model_config, self.mesh)
+            made.attrs["bytes"] = tree_bytes(params)
         self.params = params
         self._rng = host_key(seed)
 
-        # host-side slot state (mirrors of the device-resident rows)
-        self.slots: list[Optional[_SlotState]] = [None] * self.n_slots
-        self.lengths = np.zeros(self.n_slots, np.int32)
-        self.active = np.zeros(self.n_slots, bool)
+        with startup.stage("engine.pool") as pooled:
+            # host-side slot state (mirrors of the device-resident rows)
+            self.slots: list[Optional[_SlotState]] = [None] * self.n_slots
+            self.lengths = np.zeros(self.n_slots, np.int32)
+            self.active = np.zeros(self.n_slots, bool)
 
-        # what the device ADVANCES, a row a slot: its last token (a model
-        # that generates by blocks: its open block), key stream, length and
-        # finished mark. Only the step programs write them (a flipping row
-        # inside mixed_step), and restore_row at a resume
-        self._last_tokens = self._dev(
-            jnp.full((self.n_slots, self._block),
-                     self.model_config.mask_token_id, jnp.int32)
-            if self._block else jnp.zeros((self.n_slots,), jnp.int32))
-        self._lengths_dev = self._dev(jnp.zeros((self.n_slots,), jnp.int32))
-        self._finished_dev = self._dev(jnp.zeros((self.n_slots,), bool))
-        self._slot_keys = self._dev(jax.random.split(
-            jax.random.PRNGKey(seed ^ 0x5EED), self.n_slots))
+            # what the device ADVANCES, a row a slot: its last token (a model
+            # that generates by blocks: its open block), key stream, length and
+            # finished mark. Only the step programs write them (a flipping row
+            # inside mixed_step), and restore_row at a resume
+            self._last_tokens = self._dev(
+                jnp.full((self.n_slots, self._block),
+                         self.model_config.mask_token_id, jnp.int32)
+                if self._block else jnp.zeros((self.n_slots,), jnp.int32))
+            self._lengths_dev = self._dev(jnp.zeros((self.n_slots,), jnp.int32))
+            self._finished_dev = self._dev(jnp.zeros((self.n_slots,), bool))
+            self._slot_keys = self._dev(jax.random.split(
+                jax.random.PRNGKey(seed ^ 0x5EED), self.n_slots))
 
-        # what the host OWNS and the programs only read: the page table and
-        # every slot's sampling and termination rows in one block (_CTL),
-        # and the active mask. Host arrays written in place at admission,
-        # chain growth, finish, cancel, preemption and resume; _sync_rows
-        # uploads what changed since its last upload, whole, ahead of a
-        # dispatch (replicated over a mesh: _dev). The stop ids (-1 padded
-        # to device_stop_width) and the limit let the programs freeze a
-        # finished row on the device; _dev_term marks slots whose FULL stop
-        # set fits the row (others fall back to host stop detection)
-        self._stop_width = max(1, config.device_stop_width)
-        self._rows = np.zeros(
-            (self.n_slots, self._tw + _CTL + self._stop_width), np.int32)
-        self._rows[:, self._tw + _CTL:] = -1
-        self._tables = self._rows[:, : self._tw]      # views, as is _warp
-        self.page_table = self._rows[:, : self.pmax]
-        self.window_table = self._rows[:, self.pmax: self._tw]
-        self._warp = self._rows[:, self._tw + 3: self._tw + _CTL].view(
-            np.float32)  # temperature, top_p
-        self._warp[:, 1] = 1.0
-        self._rows_up, self._active_up = self._rows.copy(), self.active.copy()
-        self._rows_dev = self._dev(self._rows_up)
-        self._active_dev = self._dev(self._active_up)
-        self._dev_term = np.ones(self.n_slots, bool)
+            # what the host OWNS and the programs only read: the page table and
+            # every slot's sampling and termination rows in one block (_CTL),
+            # and the active mask. Host arrays written in place at admission,
+            # chain growth, finish, cancel, preemption and resume; _sync_rows
+            # uploads what changed since its last upload, whole, ahead of a
+            # dispatch (replicated over a mesh: _dev). The stop ids (-1 padded
+            # to device_stop_width) and the limit let the programs freeze a
+            # finished row on the device; _dev_term marks slots whose FULL stop
+            # set fits the row (others fall back to host stop detection)
+            self._stop_width = max(1, config.device_stop_width)
+            self._rows = np.zeros(
+                (self.n_slots, self._tw + _CTL + self._stop_width), np.int32)
+            self._rows[:, self._tw + _CTL:] = -1
+            self._tables = self._rows[:, : self._tw]      # views, as is _warp
+            self.page_table = self._rows[:, : self.pmax]
+            self.window_table = self._rows[:, self.pmax: self._tw]
+            self._warp = self._rows[:, self._tw + 3: self._tw + _CTL].view(
+                np.float32)  # temperature, top_p
+            self._warp[:, 1] = 1.0
+            self._rows_up, self._active_up = self._rows.copy(), self.active.copy()
+            self._rows_dev = self._dev(self._rows_up)
+            self._active_dev = self._dev(self._active_up)
+            self._dev_term = np.ones(self.n_slots, bool)
 
-        # slot KV lives in ONE paged pool shared with the prefix cache —
-        # decode attention reads through per-slot page tables
-        # (ops/paged_attention.py), prefix pages are shared zero-copy, and
-        # idle slots cost one scratch-page read instead of a max_seq scan.
-        from .paged import PrefixKVPool
+            # slot KV lives in ONE paged pool shared with the prefix cache —
+            # decode attention reads through per-slot page tables
+            # (ops/paged_attention.py), prefix pages are shared zero-copy, and
+            # idle slots cost one scratch-page read instead of a max_seq scan.
+            from .paged import PrefixKVPool
 
-        self.pool = PrefixKVPool(
-            self.model_config, num_pages=num_pages,
-            page_size=page, dtype=self.dtype,
-            sharding=self._pool_sharding,
-            state_slots=self.n_slots if self._has_state else 0,
-            state_snapshots=self._state_snapshot_rows(),
-            window_pages=self._window_pages())
+            self.pool = PrefixKVPool(
+                self.model_config, num_pages=num_pages,
+                page_size=page, dtype=self.dtype,
+                sharding=self._pool_sharding,
+                state_slots=self.n_slots if self._has_state else 0,
+                state_snapshots=self._state_snapshot_rows(),
+                window_pages=self._window_pages())
+            pooled.attrs.update(
+                pages=num_pages,
+                bytes=self.pool.pool_bytes() + self.pool.state_bytes())
 
         from collections import deque as _deque
 
@@ -1000,7 +1021,8 @@ class ContinuousBatchingEngine:
         self.spec_stats = {"rounds": 0, "mixed_rounds": 0, "proposed": 0,
                            "accepted": 0, "emitted": 0, "slots_disabled": 0}
         self._spec_accept_hist: dict[int, int] = {}
-        self._build_programs()
+        with startup.stage("engine.programs"):
+            self._build_programs()
 
         # metrics (BASELINE observability: batch occupancy, tokens/sec, and
         # the per-round pipeline breakdown the overlap claim rests on)
@@ -1208,8 +1230,11 @@ class ContinuousBatchingEngine:
                 raise RuntimeError("scheduler is closed; build a fresh engine")
             if self._thread is None or not self._thread.is_alive():
                 self._stop.clear()
+                # the thread works for the stage that starts it (the
+                # worker's ``engine.thread``, inside its model's build)
                 self._thread = threading.Thread(
-                    target=self._run_loop, name="cb-scheduler", daemon=True)
+                    target=self._run_loop, args=(startup.current(),),
+                    name="cb-scheduler", daemon=True)
                 self._thread.start()
 
     def shutdown(self, timeout: float = 10.0) -> None:
@@ -2062,7 +2087,8 @@ class ContinuousBatchingEngine:
         }
 
     # ------------------------------------------------------------------ loop
-    def _run_loop(self) -> None:
+    def _run_loop(self, stage: Any = None) -> None:
+        startup.adopt(stage)
         logger.info("continuous scheduler up: %d slots, chunk %d, "
                     "lookahead depth %d",
                     self.n_slots, self._k_steps, self._lookahead_depth)
@@ -3227,7 +3253,7 @@ class ContinuousBatchingEngine:
         if clock is None:
             clock = self._clock.take()
             self.last_round_at = clock[2]
-        phases, pass_ms, _ = clock
+        phases, pass_ms, _, compiled = clock
 
         def wall_ms(*of: str) -> float:
             return round(sum(phases[p][0] for p in of if p in phases), 3)
@@ -3271,6 +3297,10 @@ class ContinuousBatchingEngine:
             # round's dispatch that fell on experts held here
             **({"local_assignments": local_assignments}
                if local_assignments is not None else {}),
+            # the pass compiled (a first use of a program, or a recompile on
+            # the request path): the seconds and the programs' names
+            **({"compile_ms": round(1e3 * sum(compiled.values()), 3),
+                "compiled": sorted(compiled)} if compiled else {}),
         })
 
     def _count_attn_pages(self, kept: np.ndarray, grew: np.ndarray) -> None:

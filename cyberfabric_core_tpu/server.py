@@ -16,12 +16,14 @@ import argparse
 import asyncio
 import json
 import logging
+import os
 import sys
 from typing import Optional, Sequence
 
 from .modkit import AppConfig, ClientHub, ModuleRegistry, RunOptions
 from .modkit.db import DbManager
 from .modkit.runtime import HostRuntime, Runner
+from .modkit.telemetry import jax_devices, startup
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -55,15 +57,21 @@ def _setup_logging(config: AppConfig, override: Optional[str]) -> None:
 
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
-    args = build_parser().parse_args(argv)
-    _load_modules()
+    # the start-up timeline's root, from the process's start as the OS has
+    # it: the interpreter and every import up to here are its first child
+    startup.begin_boot()
+    with startup.stage("boot.imports",
+                       start_unix_ns=startup.process_start_unix_ns):
+        args = build_parser().parse_args(argv)
+        _load_modules()
 
-    try:
-        config = AppConfig.load_or_default(args.config)
-    except Exception as e:  # noqa: BLE001
-        print(f"config error: {e}", file=sys.stderr)
-        return 2
-    _setup_logging(config, args.log_level)
+    with startup.stage("boot.config"):
+        try:
+            config = AppConfig.load_or_default(args.config)
+        except Exception as e:  # noqa: BLE001
+            print(f"config error: {e}", file=sys.stderr)
+            return 2
+        _setup_logging(config, args.log_level)
 
     if args.print_config:
         print(json.dumps(config.dump_effective(), indent=2))
@@ -77,12 +85,15 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             print(f"{mark} {reg.name:<22} deps={list(reg.deps)} caps={list(reg.capabilities)}")
         return 0
 
-    enabled = config.module_names() or None
-    registry = ModuleRegistry.discover_and_build(enabled=enabled)
-    db_manager = DbManager(home_dir=None if args.mock else config.home_dir(),
-                           in_memory=args.mock)
-    opts = RunOptions(config=config, registry=registry, client_hub=ClientHub(),
-                      db_manager=db_manager, install_signal_handlers=True)
+    with startup.stage("boot.registry"):
+        enabled = config.module_names() or None
+        registry = ModuleRegistry.discover_and_build(enabled=enabled)
+        db_manager = DbManager(
+            home_dir=None if args.mock else config.home_dir(),
+            in_memory=args.mock)
+        opts = RunOptions(config=config, registry=registry,
+                          client_hub=ClientHub(), db_manager=db_manager,
+                          install_signal_handlers=True)
 
     if args.command == "check":
         # validate: config parsed, modules resolvable, routes registrable
@@ -99,7 +110,15 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 
     from .ops.platform import enable_compile_cache
 
-    enable_compile_cache()
+    with startup.stage("boot.jax"):
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            # enable_compile_cache() is about to ask on_tpu(): the backend
+            # comes up here, under its own name (boot.device_init)
+            jax_devices()
+        enable_compile_cache()
+        # the compile ledger: every trace, lowering and backend compile (or
+        # cache load) from here on, from JAX's own events
+        startup.install_jax_listeners()
 
     async def serve() -> None:
         await Runner.run(opts)

@@ -211,7 +211,9 @@ def test_worker_maps_saturation_to_429_problem():
                             "max_batch": 1, "decode_chunk": 4,
                             "max_pending": 1})
         entry = await worker._entry_for(model)
-        entry.scheduler.start = lambda: None  # freeze admission
+        # freeze admission (the worker starts the thread at the build)
+        entry.scheduler.shutdown()
+        entry.scheduler.start = lambda: None
         # first request fills the one pending slot ...
         agen = worker.completion_stream(model, "a", {"max_tokens": 2})
         first = asyncio.ensure_future(agen.__anext__())

@@ -22,7 +22,7 @@ from cyberfabric_core_tpu.modkit.flight_recorder import (FlightRecorder,
 from cyberfabric_core_tpu.modkit.telemetry import (Span, SpanExporter, Tracer,
                                                    get_global_tracer,
                                                    set_global_tracer,
-                                                   traceparent_ids)
+                                                   startup, traceparent_ids)
 from cyberfabric_core_tpu.runtime import EngineConfig, SamplingParams
 from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
 
@@ -40,6 +40,8 @@ class _CollectExporter(SpanExporter):
         self._lock = threading.Lock()
 
     def export(self, span: Span, duration_ms: float) -> None:
+        if span.trace_id == startup.trace_id:
+            return      # an engine's build: the start-up timeline's own trace
         with self._lock:
             self.spans.append((span, duration_ms))
 

@@ -70,7 +70,7 @@ def served(request):
     clock's reset to the last record."""
     eng = _manual(request.param)
     done = _submit(eng, 2)
-    _, _, t0 = eng._clock.take()          # the pass starts here
+    t0 = eng._clock.take()[2]             # the pass starts here
     while not eng._ring:
         eng._loop_pass()
     _submit(eng, 1, prompt=26, done=done)
@@ -128,7 +128,7 @@ def test_clock_switches_tile_and_starve():
     """The clock alone, under a scripted sequence of switches."""
     clock = _PhaseClock("scripted")
     assert clock.starved and clock.phase == "wait"    # nothing launched yet
-    _, _, t0 = clock.take()
+    t0 = clock.take()[2]
     clock.to("service")
     clock.to("launch")
     time.sleep(0.002)
@@ -142,7 +142,7 @@ def test_clock_switches_tile_and_starve():
     clock.to("commit", starved=True)      # that drain emptied the ring
     time.sleep(0.002)
     clock.to("emit")
-    phases, pass_ms, t1 = clock.take()
+    phases, pass_ms, t1, _ = clock.take()
     clock.close()
     assert list(phases) == ["wait", "service", "launch", "drain", "commit",
                             "emit"]
