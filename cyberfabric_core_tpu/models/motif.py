@@ -5,8 +5,13 @@ beside a shared expert. Written from the published config and the papers its
 keys name; RMSNorm, no bias anywhere but PolyNorm's.
 
 A token's residual state is ``X [S, C]``, ``S = cfg.mhc_expansion_rate``
-streams of the hidden size. **Around every sub-layer F** (attention, then
-feed-forward), the maps float32, the streams in the activations' dtype
+streams of the hidden size, carried through the stack LANE-DENSE as ``[N, S
+C]``: a token's streams side by side (``_streams``). ``[N, S, C]`` would put
+the four streams on the sublanes, where the chip stores tiles of four rows
+and every pass moves vector registers a quarter (bfloat16) or a half
+(float32) full: twice the time a sub-layer at 64 tokens, three times at 576
+(PERF.md section 5 has the probe). **Around every sub-layer F** (attention,
+then feed-forward), the maps float32, the streams in the activations' dtype
 (mHC, arXiv:2512.24880; ``_mhc_maps``):
 
     x~ = RMSNorm(vec(X));  z = x~ phi
@@ -184,15 +189,26 @@ def sinkhorn(m: jnp.ndarray, iters: int) -> jnp.ndarray:
     return r[:, :, None] * m * c[:, None, :]
 
 
+def _streams(X: jnp.ndarray, S: int) -> list[jnp.ndarray]:
+    """The ``S`` streams of ``X`` [N, S C], each [N, C] float32: runs of
+    whole lane tiles, no reshape (a view ``[N, S, C]`` is the layout this
+    file does not carry)."""
+    return [x.astype(jnp.float32) for x in jnp.split(X, S, axis=-1)]
+
+
 def _mhc_maps(lp: dict, sub: int, X: jnp.ndarray, cfg: ModelConfig):
     """The three maps of sub-layer ``sub`` (0 attention, 1 feed-forward)
-    from the streams ``X`` [N, S, C]: (H_pre [N, S], H_post [N, S], H_res
+    from the streams ``X`` [N, S C]: (H_pre [N, S], H_post [N, S], H_res
     [N, S, S]), float32."""
-    N, S, _ = X.shape
-    x = X.reshape(N, -1).astype(jnp.float32)
-    x = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-                          + cfg.rms_norm_eps) * lp["mhc_norm"][sub]
-    z = jnp.dot(x, lp["mhc_phi"][sub], precision=jax.lax.Precision.HIGHEST)
+    N, S = X.shape[0], cfg.mhc_expansion_rate
+    x = X.astype(jnp.float32)
+    # the norm's weight goes onto phi (S C x M products for N x S C) and the
+    # row's rsqrt onto the product (a scalar a token commutes with it): the
+    # product reads the streams themselves, no normed float32 copy of them
+    z = jnp.dot(x, lp["mhc_norm"][sub][:, None] * lp["mhc_phi"][sub],
+                precision=jax.lax.Precision.HIGHEST)
+    z = z * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+                          + cfg.rms_norm_eps)
     a, b = lp["mhc_alpha"][sub], lp["mhc_bias"][sub]
     pre = jax.nn.sigmoid(a[0] * z[:, :S] + b[:S])
     post = 2.0 * jax.nn.sigmoid(a[1] * z[:, S:2 * S] + b[S:2 * S])
@@ -204,16 +220,22 @@ def _mhc_maps(lp: dict, sub: int, X: jnp.ndarray, cfg: ModelConfig):
 def hyper_connected(lp: dict, sub: int, X: jnp.ndarray, cfg: ModelConfig,
                     norm: jnp.ndarray, f: Callable):
     """One sub-layer ``f(x [1, N, C]) -> (y [N, C] f32, extra)`` around the
-    streams ``X`` [N, S, C]. Returns (X, extra)."""
+    streams ``X`` [N, S C]. Returns (X, extra). Both mixes are a token's
+    scalar times a stream's slab, summed: no product over a 4 x 4 matrix a
+    token for XLA to tile."""
+    S = cfg.mhc_expansion_rate
     with jax.named_scope("mhc"):
         pre, post, res = _mhc_maps(lp, sub, X, cfg)
-        u = jnp.einsum("ns,nsc->nc", pre, X.astype(jnp.float32))
+        xs = _streams(X, S)
+        u = sum(pre[:, s:s + 1] * xs[s] for s in range(S))
     y, extra = f(rms_norm(u.astype(X.dtype)[None], norm, cfg.rms_norm_eps))
     if cfg.hidden_clamp:
         y = jnp.clip(y, -cfg.hidden_clamp, cfg.hidden_clamp)
     with jax.named_scope("mhc"):
-        mixed = jnp.einsum("nst,ntc->nsc", res, X.astype(jnp.float32))
-        X = (mixed + post[:, :, None] * y[:, None, :]).astype(X.dtype)
+        X = jnp.concatenate(
+            [post[:, s:s + 1] * y
+             + sum(res[:, s, t, None] * xs[t] for t in range(S))
+             for s in range(S)], axis=-1).astype(X.dtype)
     return X, extra
 
 
@@ -360,7 +382,7 @@ def layer_plan(cfg: ModelConfig) -> tuple[list, tuple[int, int], list]:
 
 
 def _run_layers(params: Params, cfg: ModelConfig, X, pools, attend):
-    """The stack over the streams ``X`` [N, S, C]. ``attend(full: bool, lp,
+    """The stack over the streams ``X`` [N, S C]. ``attend(full: bool, lp,
     i, x, pools) -> (o~ [N, Hq, rank], pools)`` with ``i`` the layer's index
     in its page group. Returns (X, pools, aux)."""
     P, Ld = cfg.sliding_window_period, cfg.first_k_dense
@@ -433,15 +455,15 @@ def _run_layers(params: Params, cfg: ModelConfig, X, pools, attend):
 
 
 def _streams_in(params: Params, cfg: ModelConfig, ids: jnp.ndarray):
-    """``ids`` [1, N] as the streams [N, S, C]: S copies of the embedding."""
+    """``ids`` [1, N] as the streams [N, S C]: S copies of the embedding,
+    side by side."""
     h = embed_lookup(params["embed"], ids, params["final_norm"].dtype)[0]
-    return jnp.broadcast_to(h[:, None, :],
-                            (h.shape[0], cfg.mhc_expansion_rate, h.shape[1]))
+    return jnp.concatenate([h] * cfg.mhc_expansion_rate, axis=-1)
 
 
-def _streams_out(X: jnp.ndarray) -> jnp.ndarray:
+def _streams_out(X: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     """The streams' sum [1, N, C], what the final norm reads."""
-    return jnp.sum(X.astype(jnp.float32), axis=1).astype(X.dtype)[None]
+    return sum(_streams(X, cfg.mhc_expansion_rate)).astype(X.dtype)[None]
 
 
 def _tables(page_table: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -500,7 +522,7 @@ def forward_paged_decode(
 
     X = _streams_in(params, cfg, input_ids.reshape(1, B))
     X, pools, aux = _run_layers(params, cfg, X, tuple(pools), attend)
-    h = rms_norm(_streams_out(X), params["final_norm"], cfg.rms_norm_eps)
+    h = rms_norm(_streams_out(X, cfg), params["final_norm"], cfg.rms_norm_eps)
     return h.reshape(B, 1, -1), pools, aux
 
 
@@ -560,6 +582,6 @@ def forward_paged_mixed(
 
     X = _streams_in(params, cfg, lay.ids)
     X, pools, aux = _run_layers(params, cfg, X, tuple(pools), attend)
-    h = mixed_hidden_out(lay, _streams_out(X), q_lens, rows)
+    h = mixed_hidden_out(lay, _streams_out(X, cfg), q_lens, rows)
     h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
     return h, pools, aux

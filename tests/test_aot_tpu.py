@@ -11,6 +11,7 @@ CI instead of waiting for a chip run. SURVEY §7 stage 3.
 """
 
 import os
+import re
 import subprocess
 import sys
 import types
@@ -1210,3 +1211,9 @@ def test_scheduler_programs_compile_for_v5e_at_motif(name):
     assert mem.alias_size_in_bytes >= sum(
         int(np.prod(p.shape)) * 2 for p in (full, window)), name
     assert live <= V5E_HBM_BYTES, (name, live)
+    # the residual streams are carried lane-dense, a token's four streams
+    # side by side in whole (16, 128) tiles; no array puts them on the
+    # sublanes, where the chip stores tiles of four rows
+    S, H = cfg.mhc_expansion_rate, cfg.hidden_size
+    carry = re.search(rf"bf16\[(\d+),{S * H}\]\{{1,0:T\(8,128\)\(2,1\)", text)
+    assert carry and f"[{carry.group(1)},{S},{H}]" not in text, name

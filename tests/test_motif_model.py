@@ -214,10 +214,10 @@ def test_sinkhorns_rows_and_columns_sum_to_one(iters):
 
 def test_hyper_connection_maps_by_hand():
     """``H_pre`` in (0, 1), ``H_post`` in (0, 2), ``H_res`` doubly
-    stochastic, from float32 maps over bfloat16 streams; a sub-layer that
-    returns zero leaves ``H_res X``."""
+    stochastic, from float32 maps over bfloat16 streams ``[N, S C]``; a
+    sub-layer that returns zero leaves ``H_res X``."""
     cfg, lp = _one_layer(4)
-    X = jax.random.normal(jax.random.PRNGKey(2), (9, 4, cfg.hidden_size),
+    X = jax.random.normal(jax.random.PRNGKey(2), (9, 4 * cfg.hidden_size),
                           jnp.bfloat16)
     pre, post, res = motif._mhc_maps(lp, 1, X, cfg)
     assert pre.dtype == post.dtype == res.dtype == jnp.float32
@@ -228,9 +228,162 @@ def test_hyper_connection_maps_by_hand():
     out, _ = motif.hyper_connected(
         lp, 1, X, cfg, lp["mlp_norm"],
         lambda x: (jnp.zeros((9, cfg.hidden_size), jnp.float32), None))
-    want = jnp.einsum("nst,ntc->nsc", res, X.astype(jnp.float32))
+    assert out.shape == X.shape and out.dtype == X.dtype
+    want = jnp.einsum("nst,ntc->nsc", res,
+                      X.astype(jnp.float32).reshape(9, 4, -1))
     np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)),
-                               np.asarray(want), rtol=0.02, atol=0.02)
+                               np.asarray(want).reshape(9, -1),
+                               rtol=0.02, atol=0.02)
+
+
+#: (tokens, streams, channels): the tiny model's; the served decode step's;
+#: a mixed step's 576 tokens at a width the CPU can afford
+STREAM_SHAPES = [(9, 4, 64), (64, 4, 4096), (576, 4, 256)]
+
+
+def _hyper_case(N, S, C, dtype, clamp=4.0):
+    """A sub-layer's maps at ``(S, C)`` drawn as the benchmark's
+    (``motif_weights._stack``), the streams ``[N, S C]`` in ``dtype``, the
+    configuration that names the shape."""
+    cfg = dataclasses.replace(get_config("tiny-motif"), hidden_size=C,
+                              mhc_expansion_rate=S, hidden_clamp=clamp)
+    k = jax.random.split(jax.random.PRNGKey(N + C), 6)
+    M, wide = 2 * S + S * S, S * C
+    lp = {"mhc_norm": 1 + 0.1 * jax.random.normal(k[0], (2, wide)),
+          "mhc_phi": jax.random.normal(k[1], (2, wide, M)) * wide ** -0.5,
+          "mhc_alpha": 1 + 0.1 * jax.random.normal(k[2], (2, 3)),
+          "mhc_bias": 0.5 * jax.random.normal(k[3], (2, M)),
+          "norm": 1 + 0.1 * jax.random.normal(k[4], (C,))}
+    X = jax.random.normal(k[5], (N, wide), jnp.float32).astype(dtype)
+    return cfg, lp, X
+
+
+def _reference_lines(cfg, lp, sub, X, f):
+    """``benchmark/motif_reference.py: connected`` line for line, in float32
+    over the streams ``[N, S, C]``: (H_pre, H_post, H_res, u, the streams
+    after the sub-layer)."""
+    N, n, eps = X.shape[0], cfg.mhc_expansion_rate, cfg.rms_norm_eps
+    X = X.astype(jnp.float32).reshape(N, n, -1)
+    x = X.reshape(N, -1)
+    x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                          + eps) * lp["mhc_norm"][sub]
+    z = x @ lp["mhc_phi"][sub]
+    a, b = lp["mhc_alpha"][sub], lp["mhc_bias"][sub]
+    pre = jax.nn.sigmoid(a[0] * z[:, :n] + b[:n])
+    post = 2.0 * jax.nn.sigmoid(a[1] * z[:, n:2 * n] + b[n:2 * n])
+    res = reference.sinkhorn(jnp.exp(a[2] * z[:, 2 * n:] + b[2 * n:]
+                                     ).reshape(N, n, n),
+                             cfg.mhc_sinkhorn_iters)
+    u = jnp.einsum("ts,tsc->tc", pre, X)
+    y = jnp.clip(f(u), -cfg.hidden_clamp, cfg.hidden_clamp)
+    return pre, post, res, u, (jnp.einsum("tsu,tuc->tsc", res, X)
+                               + post[:, :, None] * y[:, None, :])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", STREAM_SHAPES, ids=str)
+def test_hyper_connection_over_lane_dense_streams(shape, dtype):
+    """The maps, ``u = H_pre X`` (as the sub-layer is handed it) and ``H_res
+    X + H_post y`` over ``[N, S C]`` against the reference's lines over ``[N,
+    S, C]``; the maps float32 whatever the streams are."""
+    N, S, C = shape
+    cfg, lp, X = _hyper_case(N, S, C, dtype)
+    with jax.default_matmul_precision("highest"):
+        pre, post, res = motif._mhc_maps(lp, 1, X, cfg)
+        seen = {}
+
+        def f(x):       # what a sub-layer reads, and a y of the streams' size
+            seen["x"] = x
+            return 3.0 * x[0].astype(jnp.float32), None
+
+        out, _ = motif.hyper_connected(lp, 1, X, cfg, lp["norm"], f)
+        want_pre, want_post, want_res, u, want = _reference_lines(
+            cfg, lp, 1, X, lambda u: 3.0 * np.asarray(seen["x"][0],
+                                                      np.float32))
+    assert pre.dtype == post.dtype == res.dtype == jnp.float32
+    for got, ref in ((pre, want_pre), (post, want_post), (res, want_res)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-6)
+    tol = 1e-5 if dtype == jnp.float32 else 0.02
+    normed = u * jax.lax.rsqrt(jnp.mean(u * u, -1, keepdims=True)
+                               + cfg.rms_norm_eps) * lp["norm"]
+    assert seen["x"].shape == (1, N, C) and seen["x"].dtype == dtype
+    np.testing.assert_allclose(np.asarray(seen["x"][0], np.float32),
+                               np.asarray(normed), rtol=tol, atol=tol)
+    assert out.shape == (N, S * C) and out.dtype == dtype
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want).reshape(N, -1),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", STREAM_SHAPES, ids=str)
+def test_a_sub_layer_that_returns_zero_leaves_h_res_x(shape, dtype):
+    N, S, C = shape
+    cfg, lp, X = _hyper_case(N, S, C, dtype)
+    out, _ = motif.hyper_connected(
+        lp, 0, X, cfg, lp["norm"],
+        lambda x: (jnp.zeros((N, C), jnp.float32), None))
+    *_, res, _, want = _reference_lines(cfg, lp, 0, X,
+                                        lambda u: jnp.zeros_like(u))
+    np.testing.assert_allclose(
+        np.asarray(want), np.asarray(jnp.einsum(
+            "nst,ntc->nsc", res, X.astype(jnp.float32).reshape(N, S, C))),
+        rtol=1e-6)
+    tol = 1e-5 if dtype == jnp.float32 else 0.02
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want).reshape(N, -1),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("clamp", [0.5, 0.0], ids=["clamped", "no-clamp"])
+@pytest.mark.parametrize("shape", STREAM_SHAPES, ids=str)
+def test_a_sub_layers_output_is_clamped_before_the_mix(shape, clamp):
+    """``y`` of +-3 beyond a ``hidden_clamp`` of 0.5 enters the streams as
+    +-0.5 times ``H_post``; a configuration without a clamp (0) mixes it as
+    it is."""
+    N, S, C = shape
+    cfg, lp, X = _hyper_case(N, S, C, jnp.float32, clamp)
+    y = 3.0 * jnp.sign(jax.random.normal(jax.random.PRNGKey(9), (N, C)))
+    out, _ = motif.hyper_connected(lp, 1, X, cfg, lp["norm"],
+                                   lambda x: (y, None))
+    zero, _ = motif.hyper_connected(
+        lp, 1, X, cfg, lp["norm"], lambda x: (jnp.zeros_like(y), None))
+    _, post, _ = motif._mhc_maps(lp, 1, X, cfg)
+    entered = (clamp or 3.0) * jnp.sign(y)
+    np.testing.assert_allclose(
+        np.asarray(out - zero).reshape(N, S, C),
+        np.asarray(post[:, :, None] * entered[:, None, :]),
+        rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_the_streams_enter_as_copies_and_leave_as_their_sum(dtype):
+    """``_streams_in`` lays a token's S copies of its embedding side by side
+    (``[N, S C]``), ``_streams`` reads stream ``s`` as columns ``s C .. (s +
+    1) C`` and ``_streams_out`` sums them in float32."""
+    cfg = get_config("tiny-motif")
+    S, C = cfg.mhc_expansion_rate, cfg.hidden_size
+    params = {"embed": jax.random.normal(jax.random.PRNGKey(0), (32, C),
+                                         dtype),
+              "final_norm": jnp.ones((C,), dtype)}
+    ids = jnp.asarray([[3, 1, 4, 1, 5]], jnp.int32)
+    X = motif._streams_in(params, cfg, ids)
+    assert X.shape == (5, S * C) and X.dtype == dtype
+    for s, xs in enumerate(motif._streams(X, S)):
+        assert xs.dtype == jnp.float32
+        np.testing.assert_array_equal(
+            np.asarray(xs), np.asarray(params["embed"][ids[0]], np.float32))
+    X = jax.random.normal(jax.random.PRNGKey(1), (5, S * C), dtype)
+    got = motif._streams_out(X, cfg)
+    assert got.shape == (1, 5, C) and got.dtype == dtype
+    np.testing.assert_allclose(
+        np.asarray(got[0], np.float32),
+        np.asarray(X, np.float32).reshape(5, S, C).sum(axis=1),
+        rtol=1e-2 if dtype == jnp.bfloat16 else 1e-6)
 
 
 def test_poly_norm_by_hand():
