@@ -20,7 +20,8 @@ __all__ = ["MODEL_CONFIGS", "ModelConfig", "get_config", "decoder_module"]
 _DECODERS = {"llama": "llama", "falcon_h1": "falcon_h1",
              "sdar_moe": "sdar_moe", "kimi_k2": "kimi_k2",
              "granite_hybrid": "granite_hybrid", "nemotron_h": "nemotron_h",
-             "solar_open2": "solar_open2", "motif": "motif"}
+             "solar_open2": "solar_open2", "motif": "motif",
+             "ouro": "ouro"}
 
 
 def decoder_module(cfg: ModelConfig) -> ModuleType:

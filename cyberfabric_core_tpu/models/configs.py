@@ -39,6 +39,9 @@ class ModelConfig:
     #: page, three layers in four behind a window whose pages live in a page
     #: group of their own, four residual streams mixed by hyper-connections,
     #: PolyNorm MLPs, kimi_k2's sigmoid router over a share of the experts) |
+    #: "ouro" (a llama stack run ``loop_steps`` times a token with the SAME
+    #: weights, every branch normed on both sides, the final norm after every
+    #: pass and an exit gate that reads it; a pass caches its own K and V) |
     #: "bert" (encoder)
     architecture: str
     vocab_size: int
@@ -199,6 +202,18 @@ class ModelConfig:
     polynorm_bias_clamp: float = 0.0
     #: hidden_clamp: every sub-layer's output is clamped to +- this (0: not)
     hidden_clamp: float = 0.0
+    # ouro (model_type ouro), each beside its published name.
+    #: total_ut_steps: passes of the WHOLE stack a token runs, one set of
+    #: weights; pass ``t`` of layer ``l`` attends over what pass ``t`` of
+    #: layer ``l`` cached, so it is cache layer ``t * num_layers + l``
+    loop_steps: int = 1
+    #: early_exit_threshold: a token leaves at the first pass whose cumulated
+    #: exit probability reaches this; at the published 1 that is the last
+    #: pass, always (the one value the engines serve)
+    early_exit_threshold: float = 1.0
+    #: every branch (attention, MLP) is normed again BEFORE it is added to
+    #: the residual: four norms a layer
+    sandwich_norm: bool = False
     # bert-family extras
     layer_norm_eps: float = 1e-12
     type_vocab_size: int = 2
@@ -235,6 +250,12 @@ class ModelConfig:
                 f"layers of kinds {sorted(set(self.layer_types))} for "
                 f"num_layers {self.num_layers} (kinds: mamba, attention, "
                 "moe, kda)")
+        if self.loop_steps < 1 or (self.loop_steps > 1 and (
+                self.layer_types or self.sliding_window_period)):
+            raise ValueError(
+                f"{self.name}: loop_steps {self.loop_steps} runs ONE stack "
+                "of layers of one kind several times; it is at least 1 and "
+                "goes with neither layer_types nor a per-layer window")
         if self.block_length > 1 and (
                 self.block_length % self.denoising_steps
                 or not 0 <= self.mask_token_id < self.vocab_size):
@@ -307,8 +328,16 @@ class ModelConfig:
 
     @property
     def kv_layers(self) -> int:
-        """Layers that cache pages a row keeps for its whole length: the
-        page pool's leading dimension."""
+        """CACHE layers a row keeps for its whole length: the page pool's
+        leading dimension. A layer that attends caches once for every pass
+        of the stack (``loop_steps``; 1 but for a looped model), so this
+        passes ``num_layers`` where a stack runs several times."""
+        return self.attention_layers * self.loop_steps
+
+    @property
+    def attention_layers(self) -> int:
+        """Layers of the model whose pages a row keeps for its whole length,
+        each counted ONCE: what holds attention weights of that kind."""
         if self.sliding_window_period:
             return self.num_layers // self.sliding_window_period
         if not self.layer_types:
@@ -349,7 +378,8 @@ class ModelConfig:
             layer_types=self.layer_types[:layers])
 
     def cache_bytes_per_token(self, itemsize: int = 2) -> int:
-        """Bytes a token holds in the page pool over the layers that cache."""
+        """Bytes a token holds in the page pool over its ``kv_layers`` cache
+        layers: a layer that caches, times the passes that run it."""
         per_layer = (self.latent_lanes if self.is_latent
                      else 2 * self.num_kv_heads * self.head_dim)
         return self.kv_layers * per_layer * itemsize
@@ -408,7 +438,8 @@ class ModelConfig:
                 "w_beta": (h, self.ssm_heads)}
 
     def param_count(self) -> int:
-        """Approximate parameter count (for HBM budgeting)."""
+        """Approximate parameter count (for HBM budgeting). A layer counts
+        ONCE however many passes run it (``loop_steps``)."""
         h, i, v, l = self.hidden_size, self.intermediate_size, self.vocab_size, self.num_layers
         attn = h * (self.num_heads * self.head_dim) + 2 * h * (self.num_kv_heads * self.head_dim) \
             + (self.num_heads * self.head_dim) * h
@@ -433,7 +464,7 @@ class ModelConfig:
             experts = (self.num_experts * (2 * w * i + h + 1)
                        + (2 * h * w if self.moe_latent_size else 0)
                        + 2 * h * self.shared_intermediate_size)
-            return (self.kv_layers * attn + self.state_layers * mixer
+            return (self.attention_layers * attn + self.state_layers * mixer
                     + self.moe_layers * experts + l * h + emb + h)
         if "kda" in self.layer_types:
             # a gated expert of three matrices, the router and its selection
@@ -443,8 +474,11 @@ class ModelConfig:
         else:
             mlp = 3 * h * i * max(self.num_experts, 1) + h * self.num_experts \
                 + 3 * h * self.shared_intermediate_size
-        return (self.kv_layers * attn + self.state_layers * mixer
-                + l * (mlp + 2 * h) + emb + h)
+        # the norms: two a layer, four under sandwich norms; the exit gate
+        norms = (4 if self.sandwich_norm else 2) * h
+        gate = h + 1 if self.loop_steps > 1 else 0
+        return (self.attention_layers * attn + self.state_layers * mixer
+                + l * (mlp + norms) + emb + h + gate)
 
     def weight_bytes(self, itemsize: int = 1) -> dict[str, int]:
         """Bytes of the matrices THIS CHIP holds, by the kind of layer that
@@ -469,7 +503,7 @@ class ModelConfig:
                 mat(h, self.ssm_proj_dim) + mat(self.ssm_inner, h)),
             "kda": self.layer_types.count("kda") * sum(
                 mat(k, n) for k, n in self.kda_matrices().values()),
-            "attention": self.kv_layers * (
+            "attention": self.attention_layers * (
                 mat(h, dq, 2 if self.use_gqa_gate else 1) + 2 * mat(h, dkv)
                 + mat(dq, h)),
             "moe_dense": self.moe_layers * (
@@ -842,6 +876,23 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         routed_scaling_factor=2.0, mhc_expansion_rate=4,
         mhc_sinkhorn_iters=20, polynorm_output_scale=0.5,
         polynorm_bias_clamp=0.5, hidden_clamp=1e6,
+    ),
+    "ouro-2.6b": ModelConfig(
+        # https://huggingface.co/ByteDance/Ouro-2.6B/blob/main/config.json
+        # (model_type ouro): total_ut_steps 4, early_exit_threshold 1
+        name="ouro-2.6b", architecture="ouro", vocab_size=49152,
+        hidden_size=2048, intermediate_size=5632, num_layers=48,
+        num_heads=16, num_kv_heads=16, head_dim=128, max_position=65536,
+        rope_theta=1e6, rms_norm_eps=1e-6, loop_steps=4,
+        early_exit_threshold=1.0, sandwich_norm=True,
+    ),
+    "tiny-ouro": ModelConfig(
+        # passes != layers, so a swapped index shows
+        name="tiny-ouro", architecture="ouro", vocab_size=512,
+        hidden_size=128, intermediate_size=256, num_layers=3, num_heads=4,
+        num_kv_heads=4, head_dim=32, max_position=512, rope_theta=10000.0,
+        rms_norm_eps=1e-6, loop_steps=3, early_exit_threshold=1.0,
+        sandwich_norm=True,
     ),
     "bge-base-en": ModelConfig(
         name="bge-base-en", architecture="bert", vocab_size=30522, hidden_size=768,

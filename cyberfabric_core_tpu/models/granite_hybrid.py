@@ -41,7 +41,7 @@ handed to them, the expert layer ``llama.moe_route`` / ``moe_experts`` and
 kimi's SwiGLU. The entry points are the ones ``runtime/scheduler.py`` drives
 for falcon_h1 (``state=``, ``write_mask``, a ``q_len`` of 0, a fresh lane and
 an idle row behave as there), and like kimi_k2's they also return ``aux``:
-the experts each token chose (``[L, N, K]``) and ``MOE_COUNTERS``.
+the experts each token chose (``[L, N, K]``) and ``STEP_COUNTERS``.
 """
 
 from __future__ import annotations
@@ -66,13 +66,13 @@ from .llama import (MOE_LEAVES, DecodeGroup, PagedPools, Params, _attn_out,
 
 __all__ = ["init_params", "init_params_with", "init_state", "layer_runs",
            "forward_paged_decode", "forward_paged_mixed", "lm_head_logits",
-           "gather_last_hidden", "MOE_COUNTERS"]
+           "gather_last_hidden", "STEP_COUNTERS"]
 
 #: what ``aux`` counts over a forward's expert layers, in the order the
 #: serving programs hand them to the host (kimi_k2's names: every expert is
 #: held here, so ``local`` equals ``assignments``), then the rows one grouped
 #: matmul of the layer multiplied (``llama.moe_item_rows``)
-MOE_COUNTERS = ("assignments", "local", "touched", "item_rows")
+STEP_COUNTERS = ("assignments", "local", "touched", "item_rows")
 
 Aux = dict[str, jnp.ndarray]
 
@@ -193,7 +193,7 @@ def _moe_residual(lp: dict, moe: dict, layer, h: jnp.ndarray,
                   cfg: ModelConfig):
     """The expert layer's norm, the routed experts and the shared MLP, added
     to ``h`` [1, N, H]; also the experts chosen [N, K] and the layer's
-    ``MOE_COUNTERS``."""
+    ``STEP_COUNTERS``."""
     x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
     flat = x.reshape(-1, x.shape[-1])
     top_idx, gates = moe_route(flat, lp["router"], cfg.experts_per_token)
@@ -246,7 +246,7 @@ def _run_layers(params: Params, cfg: ModelConfig, h, pools, state,
 
     h, k_pool, v_pool, ssm, conv = carry
     aux = {"experts": jnp.concatenate(experts),
-           **{name: counts[i] for i, name in enumerate(MOE_COUNTERS)}}
+           **{name: counts[i] for i, name in enumerate(STEP_COUNTERS)}}
     return h, (k_pool, v_pool), {"ssm": ssm, "conv": conv}, aux
 
 

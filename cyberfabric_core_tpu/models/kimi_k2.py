@@ -41,14 +41,14 @@ multiplies and scatter-adds the compacted list of the assignments held here
 (``llama.moe_capacity`` rows from shapes, four times the uniform expectation:
 640 of a 512-token mixed step's 4 608, 128 of a decode step's 512), chosen
 on the device by the held count; a step that holds more takes every row, so
-none is dropped (``MOE_COUNTERS``' ``compact`` over ``forwards``).
+none is dropped (``STEP_COUNTERS``' ``compact`` over ``forwards``).
 
 The stack is not one repeated layer, so the parameters are two stacks:
 ``params["dense"]`` (the leading ``cfg.first_k_dense`` layers) and
 ``params["layers"]`` (the expert layers), each scanned; pool layer ``l`` is
 model layer ``l``. Every entry point also returns ``aux``: the experts each
 token chose (``[Lm, N, K]``, what the benchmark's judge holds against the
-reference's own scores) and the counters of ``MOE_COUNTERS``.
+reference's own scores) and the counters of ``STEP_COUNTERS``.
 """
 
 from __future__ import annotations
@@ -71,7 +71,7 @@ from .llama import (MOE_LEAVES, DecodeGroup, Params, _act, _decode_targets,
 
 __all__ = ["init_params", "init_params_with", "forward_paged_decode",
            "forward_paged_mixed", "lm_head_logits", "gather_last_hidden",
-           "MOE_COUNTERS"]
+           "STEP_COUNTERS"]
 
 #: what ``aux`` counts over a forward's expert layers, in the order the
 #: serving programs hand them to the host: assignments routed (tokens x K),
@@ -80,7 +80,7 @@ __all__ = ["init_params", "init_params_with", "forward_paged_decode",
 #: ``moe_experts`` ran over the compacted list) beside the expert layers run,
 #: then the rows one grouped matmul of the layer multiplied
 #: (``llama.moe_item_rows``)
-MOE_COUNTERS = ("assignments", "local", "touched", "compact", "forwards",
+STEP_COUNTERS = ("assignments", "local", "touched", "compact", "forwards",
                 "item_rows")
 
 LatentPool = tuple[jnp.ndarray]     # (latent,): [L, N, page, rank + rope]
@@ -231,7 +231,7 @@ def _moe_residual(lp: dict, moe: dict, layer, h: jnp.ndarray,
                   cfg: ModelConfig):
     """Post-attention norm + shared expert + the routed experts held here +
     residual over ``h`` [1, N, H]; also the experts chosen [N, K] and the
-    layer's ``MOE_COUNTERS``."""
+    layer's ``STEP_COUNTERS``."""
     x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
     flat = x.reshape(-1, x.shape[-1])
     top_idx, gates = moe_route(
@@ -281,7 +281,7 @@ def _run_layers(params: Params, cfg: ModelConfig, h, pool, attend):
         (scanned, jnp.arange(cfg.num_moe_layers, dtype=jnp.int32)))
     counts = jnp.sum(counts, axis=0)
     return h, pool, {"experts": experts,
-                     **{n: counts[i] for i, n in enumerate(MOE_COUNTERS)}}
+                     **{n: counts[i] for i, n in enumerate(STEP_COUNTERS)}}
 
 
 # ------------------------------------------------------------------ forwards

@@ -153,7 +153,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype=jnp.bfloat16) -> KVCache:
-    shape = (cfg.num_layers, batch, max_seq, cfg.num_kv_heads, cfg.head_dim)
+    shape = (cfg.kv_layers, batch, max_seq, cfg.num_kv_heads, cfg.head_dim)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 
@@ -403,16 +403,24 @@ def _qkv_proj(lp: dict, x: jnp.ndarray, cfg: ModelConfig,
     return q, kproj, vproj
 
 
-def _attn_out(lp: dict, h: jnp.ndarray, attn_flat: jnp.ndarray,
-              multiplier: float = 1.0) -> jnp.ndarray:
-    """Output projection + residual; ``multiplier`` (falcon_h1's
-    attention_out_multiplier) scales the projection in f32."""
-    wo_m, wo_s = _wmat(lp["wo"], h.dtype)
+def _attn_proj(lp: dict, attn_flat: jnp.ndarray, dtype,
+               multiplier: float = 1.0) -> jnp.ndarray:
+    """The attention branch: the output projection (weights read as
+    ``dtype``), f32, before anything is added to it (a block that norms the
+    branch first reads it here)."""
+    wo_m, wo_s = _wmat(lp["wo"], dtype)
     out = _scaled(jnp.einsum("btd,dh->bth", attn_flat, wo_m,
                   preferred_element_type=jnp.float32), wo_s)
     if multiplier != 1.0:
         out = out * multiplier
-    return h + out.astype(h.dtype)
+    return out
+
+
+def _attn_out(lp: dict, h: jnp.ndarray, attn_flat: jnp.ndarray,
+              multiplier: float = 1.0) -> jnp.ndarray:
+    """Output projection + residual; ``multiplier`` (falcon_h1's
+    attention_out_multiplier) scales the projection in f32."""
+    return h + _attn_proj(lp, attn_flat, h.dtype, multiplier).astype(h.dtype)
 
 
 def _mlp_residual(lp: dict, h: jnp.ndarray, cfg: ModelConfig,
@@ -422,9 +430,15 @@ def _mlp_residual(lp: dict, h: jnp.ndarray, cfg: ModelConfig,
     x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps, cfg.norm_weight_offset)
     if cfg.num_experts > 0:
         return h + _moe_mlp(x, lp, cfg, moe, layer).astype(h.dtype)
-    g_m, g_s = _wmat(lp["gate"], h.dtype)
-    u_m, u_s = _wmat(lp["up"], h.dtype)
-    d_m, d_s = _wmat(lp["down"], h.dtype)
+    return h + _dense_mlp(lp, x, cfg).astype(h.dtype)
+
+
+def _dense_mlp(lp: dict, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """The MLP branch over normed rows ``x`` [B, T, H]: the gated MLP of
+    three matrices, f32, before anything is added to it."""
+    g_m, g_s = _wmat(lp["gate"], x.dtype)
+    u_m, u_s = _wmat(lp["up"], x.dtype)
+    d_m, d_s = _wmat(lp["down"], x.dtype)
     gate = _scaled(jnp.einsum("bth,hi->bti", x, g_m,
                    preferred_element_type=jnp.float32), g_s)
     up = _scaled(jnp.einsum("bth,hi->bti", x, u_m,
@@ -432,12 +446,12 @@ def _mlp_residual(lp: dict, h: jnp.ndarray, cfg: ModelConfig,
     gate_mult, down_mult = cfg.mlp_multipliers      # falcon_h1; (1, 1) else
     if gate_mult != 1.0:
         gate = gate * gate_mult
-    act = (_act(gate, cfg) * up).astype(h.dtype)
+    act = (_act(gate, cfg) * up).astype(x.dtype)
     down = _scaled(jnp.einsum("bti,ih->bth", act, d_m,
                    preferred_element_type=jnp.float32), d_s)
     if down_mult != 1.0:
         down = down * down_mult
-    return h + down.astype(h.dtype)
+    return down
 
 
 def forward(

@@ -58,7 +58,7 @@ layers); the main scan's body is ONE PERIOD of the window pattern, taken from
 a full layer on (full, then a scan over the window layers behind it), the
 layers ahead of the first full expert layer and those past the last whole
 period run as scans over consecutive layers of one kind (``layer_plan``). Every entry point also returns ``aux``
-(kimi_k2's: the experts chosen and ``MOE_COUNTERS``).
+(kimi_k2's: the experts chosen and ``STEP_COUNTERS``).
 """
 
 from __future__ import annotations
@@ -72,7 +72,7 @@ from ..ops.norms import rms_norm
 from ..ops.rope import apply_rope, attention_scale
 from .configs import ModelConfig
 from .granite_hybrid import _at
-from .kimi_k2 import MOE_COUNTERS, _one_device, _proj
+from .kimi_k2 import STEP_COUNTERS, _one_device, _proj
 from .llama import (MOE_LEAVES, DecodeGroup, Params, _act, _decode_targets,
                     _wmat, decode_work, embed_lookup, gather_last_hidden,
                     lm_head_logits, mixed_hidden_out, mixed_layout,
@@ -81,7 +81,7 @@ from .llama import (MOE_LEAVES, DecodeGroup, Params, _act, _decode_targets,
 
 __all__ = ["init_params", "init_params_with", "forward_paged_decode",
            "forward_paged_mixed", "lm_head_logits", "gather_last_hidden",
-           "MOE_COUNTERS", "sinkhorn"]
+           "STEP_COUNTERS", "sinkhorn"]
 
 Pools = tuple[jnp.ndarray, jnp.ndarray]     # (full, window) latent pools
 Aux = dict[str, jnp.ndarray]
@@ -329,7 +329,7 @@ def _dense_ffn(lp: dict, x: jnp.ndarray, cfg: ModelConfig):
 def _moe_ffn(lp: dict, moe: dict, layer, x: jnp.ndarray, cfg: ModelConfig):
     """Shared expert + the routed experts held here over ``x`` [1, N, H]:
     (y [N, H] f32, (the experts chosen [N, K], the layer's
-    ``MOE_COUNTERS``))."""
+    ``STEP_COUNTERS``))."""
     flat = x.reshape(-1, x.shape[-1])
     top_idx, gates = moe_route(
         flat, lp["router"], cfg.experts_per_token, sigmoid=True,
@@ -422,7 +422,7 @@ def _run_layers(params: Params, cfg: ModelConfig, X, pools, attend):
             body, (X, pools), first + jnp.arange(count, dtype=jnp.int32))
         return X, pools, chosen
 
-    experts, counts = [], jnp.zeros((len(MOE_COUNTERS),), jnp.int32)
+    experts, counts = [], jnp.zeros((len(STEP_COUNTERS),), jnp.int32)
 
     def took(chosen):
         nonlocal counts
@@ -450,7 +450,7 @@ def _run_layers(params: Params, cfg: ModelConfig, X, pools, attend):
         X, pools, chosen = run(X, pools, at, count, at)
         took(chosen)
     aux = {"experts": jnp.concatenate(experts),
-           **{name: counts[i] for i, name in enumerate(MOE_COUNTERS)}}
+           **{name: counts[i] for i, name in enumerate(STEP_COUNTERS)}}
     return X, pools, aux
 
 

@@ -50,7 +50,7 @@ The two sub-layers that hold a cache ARE granite_hybrid's (its forwards are
 called with this module's ``_run_layers``); the expert layer is
 ``llama.moe_route``'s sigmoid branch, ``llama.moe_experts`` and kimi_k2's
 share. ``aux`` as kimi_k2's: the experts chosen ``[moe_layers, N, K]`` and
-``MOE_COUNTERS``.
+``STEP_COUNTERS``.
 """
 
 from __future__ import annotations
@@ -72,7 +72,7 @@ from .llama import (Params, _act, gather_last_hidden, lm_head_logits,
 
 __all__ = ["init_params", "init_params_with", "init_state", "layer_runs",
            "forward_paged_decode", "forward_paged_mixed", "lm_head_logits",
-           "gather_last_hidden", "MOE_COUNTERS"]
+           "gather_last_hidden", "STEP_COUNTERS"]
 
 #: what ``aux`` counts over a forward's expert layers, in the order the
 #: serving programs hand them to the host: assignments routed (tokens x K),
@@ -81,10 +81,10 @@ __all__ = ["init_params", "init_params_with", "init_state", "layer_runs",
 #: its work items x its row tile), and the held assignments once more where
 #: the forward was a decode step (0 in a mixed step: with ``touched`` over
 #: decode chunks it says how many rows a touched expert has)
-MOE_COUNTERS = ("assignments", "local", "touched", "item_rows",
+STEP_COUNTERS = ("assignments", "local", "touched", "item_rows",
                 "decode_local")
 #: those of them a layer counts (the forwards add the last)
-_LAYER_COUNTERS = MOE_COUNTERS[:-1]
+_LAYER_COUNTERS = STEP_COUNTERS[:-1]
 
 _KINDS = ("mamba", "attention", "moe")
 

@@ -30,7 +30,7 @@ bookkeeping, not the model's.
 Every entry point also returns ``aux``: the experts each token chose
 (``[L, N, K]``, what the benchmark's judge holds against the reference's own
 scores) and ``touched``, the experts with at least one token summed over the
-layers (``MOE_COUNTERS``: the ``/metrics`` counter behind
+layers (``STEP_COUNTERS``: the ``/metrics`` counter behind
 ``moe_experts_touched_share``).
 """
 
@@ -51,13 +51,13 @@ from .llama import (DecodeGroup, PagedPools, Params, _attn_out,
                     moe_experts, moe_item_rows, moe_route, split_moe)
 
 __all__ = ["init_params", "forward_paged_decode", "forward_paged_mixed",
-           "lm_head_logits", "gather_last_hidden", "MOE_COUNTERS"]
+           "lm_head_logits", "gather_last_hidden", "STEP_COUNTERS"]
 
 Aux = dict[str, jnp.ndarray]
 #: what ``aux`` counts over a forward's expert layers, for the serving
 #: programs to hand to the host: the experts with at least one token, and the
 #: rows one grouped matmul of the layer multiplied (``llama.moe_item_rows``)
-MOE_COUNTERS = ("touched", "item_rows")
+STEP_COUNTERS = ("touched", "item_rows")
 
 
 def _one_device(mesh: Any, interpret: bool | None) -> bool:
@@ -85,7 +85,7 @@ def _moe_residual(lp: dict, moe: dict, layer, h: jnp.ndarray,
                   cfg: ModelConfig):
     """Post-attention norm + the expert layer + residual over ``h``
     [1, N, H]; also the experts chosen [N, K] and the layer's
-    ``MOE_COUNTERS``."""
+    ``STEP_COUNTERS``."""
     x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
     flat = x.reshape(-1, x.shape[-1])
     top_idx, gates = moe_route(flat, lp["router"], cfg.experts_per_token)
@@ -115,7 +115,7 @@ def _run_layers(params: Params, cfg: ModelConfig, h, pools, body):
     counts = jnp.sum(counts, axis=0)
     return h, (k_pool, v_pool), {
         "experts": experts,
-        **{n: counts[i] for i, n in enumerate(MOE_COUNTERS)}}
+        **{n: counts[i] for i, n in enumerate(STEP_COUNTERS)}}
 
 
 def forward_paged_decode(

@@ -29,7 +29,7 @@ a prompt's chunk through ``ops/kda.kda_chunked`` on the lane's own row. An
 attention layer is granite's with one leaf more (``w_gate``). The expert
 layer is kimi_k2's (``_moe_residual``: ``llama.moe_route``'s sigmoid branch,
 ``llama.moe_experts`` told which experts it holds, the compact branch), with
-kimi_k2's ``MOE_COUNTERS``.
+kimi_k2's ``STEP_COUNTERS``.
 
 **The caches follow the kinds**, as granite_hybrid's do: the page pool has
 ``cfg.kv_layers`` layers and the slab ``cfg.state_layers``, each indexed by
@@ -60,12 +60,12 @@ from .configs import ModelConfig
 from .falcon_h1 import (State, _lane_rows, _layer_rows, _store_lane_rows,
                         _store_rows)
 from .granite_hybrid import Mixer, _at
-from .kimi_k2 import MOE_COUNTERS, _moe_residual, _proj
+from .kimi_k2 import STEP_COUNTERS, _moe_residual, _proj
 from .llama import Params, gather_last_hidden, lm_head_logits, split_moe
 
 __all__ = ["init_params", "init_params_with", "init_state", "layer_runs",
            "forward_paged_decode", "forward_paged_mixed", "lm_head_logits",
-           "gather_last_hidden", "MOE_COUNTERS"]
+           "gather_last_hidden", "STEP_COUNTERS"]
 
 
 def layer_runs(cfg: ModelConfig) -> list[tuple[tuple, int, dict, int]]:
@@ -302,7 +302,7 @@ def _run_layers(params: Params, cfg: ModelConfig, h, pools, state,
     state, aux)."""
     every, moe = split_moe(params["layers"])
     carry = (h, *pools, state["ssm"], state["conv"])
-    experts, counts = [], jnp.zeros((len(MOE_COUNTERS),), jnp.int32)
+    experts, counts = [], jnp.zeros((len(STEP_COUNTERS),), jnp.int32)
 
     for unit, first, first_of, reps in layer_runs(cfg):
         def body(carry, step, unit=unit, first=first, first_of=first_of):
@@ -330,7 +330,7 @@ def _run_layers(params: Params, cfg: ModelConfig, h, pools, state,
 
     h, k_pool, v_pool, ssm, conv = carry
     aux = {"experts": jnp.concatenate(experts),
-           **{name: counts[i] for i, name in enumerate(MOE_COUNTERS)}}
+           **{name: counts[i] for i, name in enumerate(STEP_COUNTERS)}}
     return h, (k_pool, v_pool), {"ssm": ssm, "conv": conv}, aux
 
 

@@ -636,7 +636,22 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                  "Rows ONE grouped matmul of an expert layer multiplied (its "
                  "work items x the row tile picked from the call's shapes), "
                  "summed over expert layers and forwards: over the experts "
-                 "touched, the rows the MXU is fed for an expert")):
+                 "touched, the rows the MXU is fed for an expert"),
+                # a model whose stack runs several times a token (ouro)
+                ("llm_loop_forwards_total",
+                 "Forwards drained of a model whose stack runs several "
+                 "times a token (decode chunks' steps and mixed steps)"),
+                ("llm_loop_passes_total",
+                 "Passes of the whole stack those forwards ran: over "
+                 "llm_loop_forwards_total the depth a token paid for "
+                 "(loop_steps while every pass runs)"),
+                ("llm_loop_exit_pass_sum_total",
+                 "The pass at which the exit gate WOULD have let a decode "
+                 "row out (sum over passes of t x p_t from the gate's own "
+                 "values), summed over the decode rows that ran"),
+                ("llm_loop_exit_rows_total",
+                 "Those decode rows: llm_loop_exit_pass_sum_total over this "
+                 "is the mean exit pass the gate asks for")):
             self.registry.counter(name, text).inc(0.0)
 
         def _state_stat(key: str) -> float:
@@ -672,6 +687,10 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                  "slab)"),
                 ("llm_model_layers", "model_layers",
                  "Layers of the model the caches were built for"),
+                ("llm_loop_steps", "loop_steps",
+                 "Passes of the whole stack a token runs with one set of "
+                 "weights (1: a stack runs once); llm_kv_layers is this "
+                 "many times the layers that attend"),
                 ("llm_window_layers", "window_layers",
                  "Layers of the window page group: the model's layers whose "
                  "pages a row gives back once they lie left of its window "
@@ -843,6 +862,12 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
             # the tracer may still be live so the next /start can clear it
             out, self._profile_dir = self._profile_dir, None
             try:
+                # ON the event loop, and it has to stay there: a trace of
+                # 0.9 M device ops (a looped model's 3 s) takes half a minute
+                # to write while no stream and no arrival moves; from a
+                # thread, beside a server that keeps the device busy, the
+                # same stop did not end in two minutes (PERF.md section 7,
+                # PR 52)
                 jax.profiler.stop_trace()
                 self._tracer_maybe_live = False
             except Exception as e:

@@ -473,7 +473,7 @@ class InferenceEngine:
             # abstract avals only — lowering must not allocate a second KV
             # cache on a device already holding the live one
             sds = jax.ShapeDtypeStruct
-            cache_aval = sds((cfg.num_layers, B, self.config.max_seq_len,
+            cache_aval = sds((cfg.kv_layers, B, self.config.max_seq_len,
                               cfg.num_kv_heads, cfg.head_dim), self.dtype)
             params_avals = jax.tree.map(
                 lambda a: sds(jnp.shape(a), jnp.asarray(a).dtype), self.params)

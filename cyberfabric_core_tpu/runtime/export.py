@@ -157,7 +157,7 @@ def export_llama_programs(
         sds((2,), jnp.uint32), sds((B,), jnp.float32), sds((B,), jnp.float32),
         sds((B,), jnp.int32))
     decode_fn = build_decode_chunk_fn(cfg, decode_chunk, rope)
-    cache_aval = sds((cfg.num_layers, B, max_seq_len, cfg.num_kv_heads,
+    cache_aval = sds((cfg.kv_layers, B, max_seq_len, cfg.num_kv_heads,
                       cfg.head_dim), dtype)
     decode_avals = (
         params, cache_aval, cache_aval, sds((B,), jnp.int32),

@@ -653,6 +653,9 @@ class PrefixKVPool:
             "kda_layers": (state_layers if "kda" in self.cfg.layer_types
                            else 0),
             "model_layers": self.cfg.num_layers,
+            # passes of the stack a token runs: kv_layers is this many
+            # times the layers that attend
+            "loop_steps": self.cfg.loop_steps,
             "cache_bytes": self.pool_bytes() + self.state_bytes(),
             # the window page group: its layers, pages, those in use, and
             # those rows have given back so far (0, 0, 0, 0: one group)

@@ -69,7 +69,7 @@ class ProgramKey:
     #                         full-window chain, for every page group
     n_cache: int            # donated cache operands, the state slab included
     has_state: bool
-    moe_counters: tuple     # the model module's ``MOE_COUNTERS``
+    step_counters: tuple    # the model module's ``STEP_COUNTERS``
     block: int              # width of a row's open block, or 0
     attn_mesh: Any          # mesh the paged kernels ``shard_map`` over, or None
     spec_k: int             # draft tokens verified a round; 0: no such program
@@ -90,7 +90,7 @@ def serving_rope_tables(cfg: ModelConfig, max_seq_len: int) -> tuple:
 def _append_counts(toks: jnp.ndarray, counts: jnp.ndarray) -> jnp.ndarray:
     """A program's drained matrix (``[rows, columns]``, or ``[rows]`` of a
     mixed step) with the model's counters as rows past its own, padded to
-    whole rows (``_take_moe_counters`` takes them off); unchanged where the
+    whole rows (``_take_step_counters`` takes them off); unchanged where the
     model counts nothing."""
     if not counts.shape[0]:
         return toks
@@ -125,11 +125,11 @@ def _unpack_lane(lane: jnp.ndarray, n_slots: int, block: int,
             span[:, -1])
 
 
-def _moe_counts(key: ProgramKey, aux: Optional[dict]) -> jnp.ndarray:
-    """A forward's ``aux`` in ``moe_counters``' order (zeros for None)."""
+def _step_counts(key: ProgramKey, aux: Optional[dict]) -> jnp.ndarray:
+    """A forward's ``aux`` in ``step_counters``' order (zeros for None)."""
     if aux is None:
-        return jnp.zeros((len(key.moe_counters),), jnp.int32)
-    return jnp.stack([aux[n] for n in key.moe_counters]).astype(jnp.int32)
+        return jnp.zeros((len(key.step_counters),), jnp.int32)
+    return jnp.stack([aux[n] for n in key.step_counters]).astype(jnp.int32)
 
 
 @functools.lru_cache(maxsize=PROGRAM_SETS_KEPT)
@@ -238,13 +238,13 @@ def _block_programs(key: ProgramKey, rope: tuple) -> tuple:
                 stop_ids, limit_lens, temp, top_p, top_k)
             return (pools, blk, lens, fin, keys,
                     ran + run.astype(jnp.int32), counts + n,
-                    moe + _moe_counts(key, aux)), emit
+                    moe + _step_counts(key, aux)), emit
 
         (pools, blk, lens, fin, keys, ran, counts, moe), toks = \
             jax.lax.scan(step, ((k_pool, v_pool), block, lengths,
                                 finished, keys, jnp.zeros_like(lengths),
                                 jnp.zeros((2,), jnp.int32),
-                                _moe_counts(key, None)),
+                                _step_counts(key, None)),
                          None, length=k_steps)
         toks = toks.transpose(1, 0, 2).reshape(block.shape[0], -1)
         lens = jnp.where(active, lens, 0)
@@ -280,7 +280,7 @@ def _block_programs(key: ProgramKey, rope: tuple) -> tuple:
         lens = jnp.where(run, lens, jnp.where(
             final_mask, final_lens, jnp.where(active, lengths, 0)))
         return (with_counters(emit, run.astype(jnp.int32), n,
-                              _moe_counts(key, aux)),
+                              _step_counts(key, aux)),
                 *pools, blk, keys, lens, fin, active | final_mask)
 
     return (jax.jit(paged_decode_chunk, donate_argnums=(1, 2)),
@@ -294,7 +294,7 @@ def _token_programs(key: ProgramKey, rope: tuple) -> tuple:
     k_steps = key.decode_chunk
     max_seq = key.max_seq_len
 
-    no_counts = _moe_counts(key, None)
+    no_counts = _step_counts(key, None)
     n_slots, pmax = key.n_slots, key.pmax
 
     def paged_forward(forward, params, ids, caches, *tail, **kwargs):
@@ -311,7 +311,7 @@ def _token_programs(key: ProgramKey, rope: tuple) -> tuple:
         hidden, pools, *rest = forward(
             params, cfg, ids, caches[:n_pools], *tail, **kwargs)
         state = (rest.pop(0),) if key.has_state else ()
-        counts = (_moe_counts(key, rest[0]) if key.moe_counters
+        counts = (_step_counts(key, rest[0]) if key.step_counters
                   else no_counts)
         return hidden, (*pools, *state), counts
 

@@ -77,7 +77,9 @@ def quantize_llama_params(params: dict[str, Any], bits: int = 8) -> dict[str, An
     """Quantize every matmul weight + lm_head + embed; norms stay as-is.
     The embed table stays int8 even at bits=4 (gather from s4 is not a
     bandwidth-critical path and per-row int8 is accuracy-safe)."""
-    out: dict[str, Any] = {"final_norm": params["final_norm"]}
+    # what is not named below stays as it is (the final norm; ouro's float32
+    # exit gate)
+    out: dict[str, Any] = dict(params)
     out["embed"] = _quantize_embed(params["embed"])
     if "lm_head" in params:
         out["lm_head"] = quantize_weight(params["lm_head"], bits)

@@ -160,7 +160,7 @@ _BLOCK_SERIES = ("llm_block_row_forwards_total",
 
 
 #: /metrics of a model whose forwards count their expert layers, by the names
-#: of its module's ``MOE_COUNTERS``: the assignments routed (tokens x experts a
+#: of its module's ``STEP_COUNTERS``: the assignments routed (tokens x experts a
 #: token, over layers and forwards), those that fell on experts held here
 #: (experts held over experts routed of them where routing is uniform), the
 #: held experts that received a token, the expert layers that ran over the
@@ -188,9 +188,20 @@ _MOE_DRAIN_SERIES = ("llm_moe_experts_offered_total",
 
 def _moe_series(counters: tuple) -> tuple:
     """The /metrics series of a model whose module has these
-    ``MOE_COUNTERS`` (none for none)."""
+    ``STEP_COUNTERS`` (none for none)."""
     return (tuple(_MOE_SERIES_OF[n] for n in counters) + _MOE_DRAIN_SERIES
             if counters else ())
+
+
+#: /metrics of a model whose stack runs several times a token
+#: (``ModelConfig.loop_steps``; ``models/ouro.py``): the forwards drained and
+#: the passes of the stack they ran (their ratio is the depth a token paid
+#: for: ``loop_steps`` while every pass runs), and what the exit gate says of
+#: the decode rows that ran, the pass at which it WOULD have let each out
+#: summed (``STEP_COUNTERS``: thousandths of a pass on the drained matrix)
+#: over those rows
+_LOOP_SERIES = ("llm_loop_forwards_total", "llm_loop_passes_total",
+                "llm_loop_exit_pass_sum_total", "llm_loop_exit_rows_total")
 
 
 def _null_ctx():
@@ -721,12 +732,19 @@ class ContinuousBatchingEngine:
             self._refuse_without_block_support(config)
         if self.model_config.is_latent:
             self._refuse_without_latent_support(config)
+        #: the stack runs several times a token (ouro): the pool is
+        #: ``loop_steps`` times the model's depth, and the forwards hand
+        #: over what the exit gate says
+        self._looped = self.model_config.loop_steps > 1
+        if self._looped:
+            self._refuse_without_loop_support(config)
         #: counters a model's forwards hand over beside the hidden state
-        #: (``MOE_COUNTERS`` of its module: routed assignments, those on
-        #: experts held here, held experts touched, a share's expert layers
-        #: run over the compacted list and all of them); they ride the drained
-        #: token matrix as its last rows
-        self._moe_counters: tuple = getattr(self._model, "MOE_COUNTERS", ())
+        #: (``STEP_COUNTERS`` of its module: an expert model's routed
+        #: assignments, those on experts held here, held experts touched, a
+        #: share's expert layers run over the compacted list and all of
+        #: them; a looped model's exit gate); they ride the drained token
+        #: matrix as its last rows
+        self._step_counters: tuple = getattr(self._model, "STEP_COUNTERS", ())
         self.pd_role = str(config.pd_role or "")
         if self.pd_role not in ("", "prefill", "decode"):
             raise ValueError(
@@ -1069,7 +1087,8 @@ class ContinuousBatchingEngine:
                        "llm_attn_window_pages_offered_total",
                        "llm_window_pages_freed_total") + (
                            _BLOCK_SERIES if self._block else ()
-                       ) + _moe_series(self._moe_counters):
+                       ) + (_LOOP_SERIES if self._looped
+                            else _moe_series(self._step_counters)):
             bump_counter(series, n=0.0)
         #: achieved ring depth at each drain (how many chunks stayed in
         #: flight while the host emitted) → stats() depth histogram
@@ -1143,6 +1162,32 @@ class ContinuousBatchingEngine:
                 f"{name}: tp > 1 has no sharding for a latent page (no "
                 "kv-head axis) nor an ep axis for the experts")
 
+    def _refuse_without_loop_support(self, config: EngineConfig) -> None:
+        """A model whose stack runs several times a token is served on one
+        device, unified, without speculation, every pass run; each mode
+        below lacks one named thing."""
+        cfg = self.model_config
+        if cfg.early_exit_threshold < 1.0:
+            raise ValueError(
+                f"{cfg.name}: early_exit_threshold "
+                f"{cfg.early_exit_threshold} lets rows of one batch leave at "
+                "different passes, and a step costs every row the same "
+                "here: only the published 1 (every pass runs) is served")
+        if config.scheduler_spec_k > 0:
+            raise ValueError(
+                f"{cfg.name}: scheduler_spec_k > 0 verifies a draft span "
+                "through llama's all-rows forward, which runs the stack "
+                "once and norms no branch")
+        if config.pd_role:
+            raise ValueError(
+                f"{cfg.name}: pd_role={config.pd_role!r} has no decode-role "
+                "admission tested for a pool of loop_steps x num_layers "
+                "cache layers")
+        if max(1, int(config.tp)) > 1:
+            raise ValueError(
+                f"{cfg.name}: tp > 1 has no sharding for the sandwich "
+                "norms' and the exit gate's leaves")
+
     @property
     def _two_groups(self) -> bool:
         """The model caches in two page groups (runtime/paged.py)."""
@@ -1184,7 +1229,7 @@ class ContinuousBatchingEngine:
             self.model_config, max(1, self.config.decode_chunk),
             self.config.max_seq_len, self.n_slots, self._tw,
             n_cache=len(self.pool.cache_operands()),
-            has_state=self._has_state, moe_counters=self._moe_counters,
+            has_state=self._has_state, step_counters=self._step_counters,
             block=self._block, attn_mesh=self._attn_mesh, spec_k=self.spec_k)
         (self._restore_row_fn, self._paged_decode_fn, self._mixed_step_fn,
          self._spec_step_fn) = step_programs(key)
@@ -3227,7 +3272,7 @@ class ContinuousBatchingEngine:
                       kind: str = "decode",
                       positions: Optional[int] = None,
                       block_out: Optional[tuple[int, int]] = None,
-                      local_assignments: Optional[int] = None,
+                      counted: Optional[dict] = None,
                       chained: bool = False,
                       clock: Optional[tuple] = None) -> None:
         """One timing-schema owner for every round kind. ``ts`` is the
@@ -3293,10 +3338,11 @@ class ContinuousBatchingEngine:
             **({"forwards": 1 if kind != "decode" or mixed
                 else self._k_steps, "blocks_committed": block_out[0],
                 "tokens_emitted": block_out[1]} if block_out else {}),
-            # a chip's share of the experts: the routed assignments of the
-            # round's dispatch that fell on experts held here
-            **({"local_assignments": local_assignments}
-               if local_assignments is not None else {}),
+            # what the dispatch's forwards counted (``_take_step_counters``).
+            # A chip's share of the experts: ``local_assignments``, the
+            # routed assignments that fell on experts held here; a looped
+            # model: ``loop_steps``, ``passes`` run and ``exit_pass_mean``
+            **(counted or {}),
             # the pass compiled (a first use of a program, or a recompile on
             # the request path): the seconds and the programs' names
             **({"compile_ms": round(1e3 * sum(compiled.values()), 3),
@@ -3355,23 +3401,28 @@ class ContinuousBatchingEngine:
         bump_counter("llm_block_commit_row_forwards_total", n=commits)
         return drained[:-1, :-1], drained[:-1, -1]
 
-    def _take_moe_counters(self, drained: np.ndarray, forwards: int,
-                           decode: bool = False
-                           ) -> tuple[np.ndarray, Optional[int]]:
-        """Where the model's forwards count their expert layers
-        (``_moe_counters``), the drained matrix carries the counters as its
-        last rows (``_append_counts``). Bump them, with the experts HELD that
-        the ``forwards`` offered, and for a ``decode`` chunk's drain the
-        decode-only pair too; hand back (the matrix without them, the
-        assignments that fell on held experts or None where the model does
-        not count them)."""
-        names = self._moe_counters
+    def _take_step_counters(self, drained: np.ndarray, forwards: int,
+                            decode: bool = False
+                            ) -> tuple[np.ndarray, dict[str, Any]]:
+        """Where the model's forwards hand counters over
+        (``_step_counters``), the drained matrix carries them as its last
+        rows (``_append_counts``): the one way out of the programs beside
+        the tokens. Bump their series and hand back (the matrix without
+        them, what the round's record says of them). An expert model: with
+        the experts HELD that the ``forwards`` offered, and for a ``decode``
+        chunk's drain the decode-only pair too; the record gets the
+        assignments that fell on held experts where the model counts them.
+        A looped model: ``_count_loop``."""
+        names = self._step_counters
         if not names:
-            return drained, None
+            return drained, {}
         rows = len(names) if drained.ndim == 1 else \
             -(-len(names) // drained.shape[1])
         counts = dict(zip(names,
                           (int(v) for v in drained[-rows:].reshape(-1))))
+        drained = drained[:-rows]
+        if self._looped:
+            return drained, self._count_loop(counts, forwards)
         for name, n_counted in counts.items():
             bump_counter(_MOE_SERIES_OF[name], n=n_counted)
         offered = (forwards * self.model_config.num_moe_layers
@@ -3381,7 +3432,25 @@ class ContinuousBatchingEngine:
             bump_counter("llm_moe_decode_experts_touched_total",
                          n=counts["touched"])
             bump_counter("llm_moe_decode_experts_offered_total", n=offered)
-        return drained[:-rows], counts.get("local")
+        return drained, ({"local_assignments": counts["local"]}
+                         if "local" in counts else {})
+
+    def _count_loop(self, counts: dict[str, int],
+                    forwards: int) -> dict[str, Any]:
+        """/metrics of a looped model's drained dispatch (``_LOOP_SERIES``)
+        and what its round's record and ``llm.decode_chunk`` spans say of
+        it: ``loop_steps``, the ``passes`` the forwards ran, and
+        ``exit_pass_mean``, the pass at which the gate would have let the
+        decode rows out (absent where no decode row ran)."""
+        steps = self.model_config.loop_steps
+        exit_sum, rows = counts["exit_pass_milli"] / 1000.0, counts["exit_rows"]
+        bump_counter("llm_loop_forwards_total", n=forwards)
+        bump_counter("llm_loop_passes_total", n=forwards * steps)
+        bump_counter("llm_loop_exit_pass_sum_total", n=exit_sum)
+        bump_counter("llm_loop_exit_rows_total", n=rows)
+        return {"loop_steps": steps, "passes": forwards * steps,
+                **({"exit_pass_mean": round(exit_sum / rows, 3)}
+                   if rows else {})}
 
     def _emit_block(self, slot: int, toks: np.ndarray, start: int) -> int:
         """Emit the block a row committed at ``start .. start + W - 1``:
@@ -4116,7 +4185,7 @@ class ContinuousBatchingEngine:
         # plus the accept-count column (one drain carries both); plain mixed
         # returns [n] — normalize to 2-D so one emit loop serves both
         ran = None
-        toks, local = self._take_moe_counters(toks, forwards=1)
+        toks, counted = self._take_step_counters(toks, forwards=1)
         if self._block:
             toks2d, ran = self._take_block_counters(toks)
             accepts = None
@@ -4160,7 +4229,8 @@ class ContinuousBatchingEngine:
                          for slot in decode_rows}
         self._emit_decode_spans(wall0, round_ms, lookahead=False,
                                 rows=decode_rows, tokens=1, depth=depth,
-                                row_tokens=row_tokens, row_attrs=row_attrs)
+                                row_tokens=row_tokens, row_attrs=row_attrs,
+                                round_attrs=counted if self._looped else None)
         # acceptance accounting BEFORE the emit loop (a mid-row finish
         # clears the slot state): totals, the accept-length histogram, the
         # per-stream evidence the spec_min_accept gate reads, and the
@@ -4255,7 +4325,7 @@ class ContinuousBatchingEngine:
                           spec_tokens=sum(len(dr) for _, _, dr in spec_plan),
                           kind=("mixed" if decode_rows else "prefill")
                           if plan else "decode", positions=step.positions,
-                          local_assignments=local, chained=step.chained)
+                          counted=counted, chained=step.chained)
 
     def _decode_round(self) -> None:
         self.occupancy_samples.append(self.active_slots)
@@ -4324,9 +4394,9 @@ class ContinuousBatchingEngine:
         round_ms = (self._clock.to("commit", starved=not self._ring)
                     - t0) * 1000.0
         self._depth_hist[ring_depth] = self._depth_hist.get(ring_depth, 0) + 1
-        chunk, local = self._take_moe_counters(chunk, self._k_steps,
-                                               decode=True)
-        round_attrs = None if local is None else {"local_assignments": local}
+        chunk, counted = self._take_step_counters(chunk, self._k_steps,
+                                                  decode=True)
+        round_attrs = counted or None   # on every span of the round
         # the rows that ran in this chunk: a held emit is flushed after the
         # next pass's ``_admit``, whose resumed rows have no token in it
         rows = np.flatnonzero(self.active).tolist()
@@ -4358,7 +4428,7 @@ class ContinuousBatchingEngine:
                 self._emit_chunk(chunk, old_lengths, rows, depth=ring_depth)
         # at once under the chunks in flight, or held for the next launch
         self._close_round(emit, lookahead=used_lookahead, ts=wall0,
-                          depth=ring_depth, local_assignments=local)
+                          depth=ring_depth, counted=counted)
 
     def _emit_decode_spans(self, wall0: float, dur_ms: float,
                            lookahead: bool, rows: Optional[list[int]] = None,

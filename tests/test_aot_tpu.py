@@ -386,7 +386,7 @@ def test_scheduler_programs_compile_for_v5e_at_mistral_7b(tp):
         prefix_page_size=_PAGE, tp=tp)
     eng.model_config, eng.dtype = cfg, jnp.bfloat16
     eng._model, eng._has_state, eng._block = decoder_module(cfg), False, 0
-    eng._moe_counters, eng.n_slots, eng.pmax = (), n, _PMAX
+    eng._step_counters, eng.n_slots, eng.pmax = (), n, _PMAX
     eng.spec_k, eng._spec_w = 0, 1
     if tp > 1:
         eng.mesh = eng._attn_mesh = build_mesh(MeshConfig(dp=1, tp=tp),
@@ -460,7 +460,7 @@ def test_scheduler_programs_compile_for_v5e_at_falcon_h1():
         quantization="int8", prefix_cache_pages=pages, prefix_page_size=_PAGE)
     eng.model_config, eng.dtype = cfg, jnp.bfloat16
     eng._model, eng._has_state, eng._block = decoder_module(cfg), True, 0
-    eng._moe_counters, eng.n_slots, eng.pmax = (), n, max_seq // _PAGE
+    eng._step_counters, eng.n_slots, eng.pmax = (), n, max_seq // _PAGE
     eng.spec_k, eng._spec_w = 0, 1
     eng.mesh = eng._attn_mesh = None
     here = SingleDeviceSharding(topo.devices[0])
@@ -545,7 +545,7 @@ def test_scheduler_programs_compile_for_v5e_at_sdar_moe():
     eng.model_config, eng.dtype = cfg, jnp.bfloat16
     eng._model, eng._has_state = decoder_module(cfg), False
     eng._block = cfg.block_length
-    eng._moe_counters = eng._model.MOE_COUNTERS
+    eng._step_counters = eng._model.STEP_COUNTERS
     eng.n_slots, eng.pmax = n, max_seq // _PAGE
     eng.spec_k, eng._spec_w = 0, 1
     eng.mesh = eng._attn_mesh = None
@@ -657,7 +657,7 @@ def test_scheduler_programs_compile_for_v5e_at_kimi_k2():
         quantization="int8", prefix_cache_pages=pages, prefix_page_size=_PAGE)
     eng.model_config, eng.dtype = cfg, jnp.bfloat16
     eng._model, eng._has_state, eng._block = decoder_module(cfg), False, 0
-    eng._moe_counters = eng._model.MOE_COUNTERS
+    eng._step_counters = eng._model.STEP_COUNTERS
     eng.n_slots, eng.pmax = n, max_seq // _PAGE
     eng.spec_k, eng._spec_w = 0, 1
     eng.mesh = eng._attn_mesh = None
@@ -758,7 +758,7 @@ def test_scheduler_programs_compile_for_v5e_at_granite_hybrid():
         prefix_cache_pages=pages, prefix_page_size=_PAGE)
     eng.model_config, eng.dtype = cfg, jnp.bfloat16
     eng._model, eng._has_state, eng._block = decoder_module(cfg), True, 0
-    eng._moe_counters = eng._model.MOE_COUNTERS
+    eng._step_counters = eng._model.STEP_COUNTERS
     eng.n_slots, eng.pmax = n, max_seq // _PAGE
     eng.spec_k, eng._spec_w = 0, 1
     eng.mesh = eng._attn_mesh = None
@@ -867,7 +867,7 @@ def test_scheduler_programs_compile_for_v5e_at_nemotron_h():
         prefix_cache_pages=pages, prefix_page_size=_PAGE)
     eng.model_config, eng.dtype = cfg, jnp.bfloat16
     eng._model, eng._has_state, eng._block = decoder_module(cfg), True, 0
-    eng._moe_counters = eng._model.MOE_COUNTERS
+    eng._step_counters = eng._model.STEP_COUNTERS
     eng.n_slots, eng.pmax = n, max_seq // _PAGE
     eng.spec_k, eng._spec_w = 0, 1
     eng.mesh = eng._attn_mesh = None
@@ -1036,7 +1036,7 @@ def test_scheduler_programs_compile_for_v5e_at_solar_open2():
         prefix_cache_pages=pages, prefix_page_size=_PAGE)
     eng.model_config, eng.dtype = cfg, jnp.bfloat16
     eng._model, eng._has_state, eng._block = decoder_module(cfg), True, 0
-    eng._moe_counters = eng._model.MOE_COUNTERS
+    eng._step_counters = eng._model.STEP_COUNTERS
     eng.n_slots, eng.pmax = n, max_seq // _PAGE
     eng.spec_k, eng._spec_w = 0, 1
     eng.mesh = eng._attn_mesh = None
@@ -1146,7 +1146,7 @@ def test_scheduler_programs_compile_for_v5e_at_motif(name):
         prefill_budget_tokens=serving["prefill_budget_tokens"])
     eng.model_config, eng.dtype = cfg, jnp.bfloat16
     eng._model, eng._has_state, eng._block = decoder_module(cfg), False, 0
-    eng._moe_counters = eng._model.MOE_COUNTERS
+    eng._step_counters = eng._model.STEP_COUNTERS
     eng.n_slots, eng.pmax = n, max_seq // _PAGE
     window_pages = eng._window_pages()      # from shapes: no option
     assert (cfg.kv_layers, cfg.window_layers, cfg.moe_layers, pages,
@@ -1217,3 +1217,121 @@ def test_scheduler_programs_compile_for_v5e_at_motif(name):
     S, H = cfg.mhc_expansion_rate, cfg.hidden_size
     carry = re.search(rf"bf16\[(\d+),{S * H}\]\{{1,0:T\(8,128\)\(2,1\)", text)
     assert carry and f"[{carry.group(1)},{S},{H}]" not in text, name
+
+
+def _ouro_programs(model: str, conf_file: str, sharding):
+    """The scheduler's own two programs for an ouro configuration file's
+    served shapes, and their abstract operands: (cfg, pool, {name: (fn,
+    args)})."""
+    import json
+
+    from cyberfabric_core_tpu.models import decoder_module, get_config
+    from cyberfabric_core_tpu.ops.platform import compiled_kernels
+    from cyberfabric_core_tpu.parallel.sharding import abstract_params
+    from cyberfabric_core_tpu.runtime.engine import EngineConfig
+    from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
+
+    serving = json.loads(REPO.joinpath(conf_file).read_text())["serving"]
+    assert serving["model_config"] == model
+    n, max_seq, page = (serving["max_batch"], serving["max_seq_len"],
+                        serving["page"])
+    pages = serving["pool_pages"] + 1
+    cfg = get_config(model)
+    eng = object.__new__(ContinuousBatchingEngine)
+    eng.config = EngineConfig(
+        model=model, max_seq_len=max_seq, max_batch=n,
+        decode_chunk=serving["decode_chunk"], quantization="int8",
+        prefix_cache_pages=pages, prefix_page_size=page,
+        prefill_budget_tokens=serving["prefill_budget_tokens"])
+    eng.model_config, eng.dtype = cfg, jnp.bfloat16
+    eng._model, eng._has_state, eng._block = decoder_module(cfg), False, 0
+    eng._step_counters = eng._model.STEP_COUNTERS
+    eng.n_slots, eng.pmax = n, -(-max_seq // page)
+    eng.spec_k, eng._spec_w = 0, 1
+    eng.mesh = eng._attn_mesh = None
+    assert pages == n * eng.pmax + 1        # the slot minimum, no margin
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    params = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                          abstract_params(cfg, jnp.bfloat16, "int8"))
+    assert params["layers"]["wq"]["q"].shape[0] == cfg.num_layers
+    assert params["exit_gate"]["w"].dtype == jnp.float32
+    pool = sds((cfg.kv_layers, pages, page, cfg.num_kv_heads * cfg.head_dim),
+               jnp.bfloat16)
+    eng.pool = types.SimpleNamespace(cache_operands=lambda: (pool, pool))
+    with compiled_kernels():
+        eng._build_programs()
+    return cfg, pool, {
+        "paged_decode_chunk": (eng._paged_decode_fn, (
+            params, pool, pool, *_decode_operands(sds, eng))),
+        **{f"mixed_step@{w}": (eng._mixed_step_fn, (
+            params, pool, pool, *_mixed_operands(sds, eng, w)))
+           for w in serving["mixed_widths"][-2:]}}
+
+
+def test_the_loop_is_a_loop_in_the_lowered_programs():
+    """The tiny twin of the slow compile below, on this backend: in
+    ``tiny-ouro``'s two step programs as lowered from shapes the passes are
+    a loop whose body is the loop over layers (a ``while`` inside a
+    ``while``, inside the chunk's), not ``loop_steps`` unrolled copies: each
+    layer matrix is multiplied at ONE site, and the pool is donated."""
+    cfg, pool, programs = _ouro_programs(
+        "tiny-ouro", "benchmark/tests/rehearsal/configs/tiny-ouro.json", None)
+    assert (cfg.loop_steps, cfg.num_layers, pool.shape[0]) == (3, 3, 9)
+    for name, (fn, args) in programs.items():
+        text = fn.lower(*args).as_text()
+        whiles = text.count("stablehlo.while")
+        # the passes, the layers inside them, the chunk's steps around both
+        assert whiles >= (3 if name == "paged_decode_chunk" else 2), name
+        # a layer's seven matrices, the head, the gate and the interpreted
+        # kernels' own products, once each whatever R is: three unrolled
+        # passes would hold 21 layer products alone
+        dots = len(re.findall(r"stablehlo\.dot_general", text))
+        assert dots < 3 * 7, (name, dots)
+        assert text.count("tf.aliasing_output") >= 2, name
+
+
+@pytest.mark.slow
+def test_scheduler_programs_compile_for_v5e_at_ouro():
+    """The scheduler's own ``paged_decode_chunk`` and ``mixed_step`` for
+    ouro-2.6b int8 at the served shapes of
+    ``benchmark/configs/ouro-2.6b-int8.json`` (8 slots of 832, 105 pages in
+    192 cache layers = 10.57 GB, 48 layers of weights run 4 times), on one
+    described chip: each holds the paged kernels, donates both pools and
+    copies neither through the two nested loops, and fits the 15.75 GiB the
+    compiler budgets. A compile, not a chip run (``-s`` prints the sizes)."""
+    import time
+
+    from jax.sharding import SingleDeviceSharding
+
+    from cyberfabric_core_tpu.ops.platform import compiled_kernels
+    from cyberfabric_core_tpu.parallel.feasibility import V5E_HBM_BYTES
+
+    topo = _topo_or_skip()
+    cfg, pool, programs = _ouro_programs(
+        "ouro-2.6b", "benchmark/configs/ouro-2.6b-int8.json",
+        SingleDeviceSharding(topo.devices[0]))
+    assert pool.shape == (192, 105, 64, 2048)
+    for name, (fn, args) in programs.items():
+        started = time.monotonic()
+        with compiled_kernels():
+            compiled = fn.lower(*args).compile()
+        took = time.monotonic() - started
+        mem = compiled.memory_analysis()
+        live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+        print(f"{name}: argument {mem.argument_size_in_bytes / 1e9:.2f} "
+              f"output {mem.output_size_in_bytes / 1e9:.2f} aliased "
+              f"{mem.alias_size_in_bytes / 1e9:.2f} temp "
+              f"{mem.temp_size_in_bytes / 1e9:.2f} GB, compiled in "
+              f"{took:.0f} s")
+        text = compiled.as_text()
+        if os.environ.get("AOT_DUMP_DIR"):
+            Path(os.environ["AOT_DUMP_DIR"],
+                 f"ouro-{name}.hlo.txt").write_text(text)
+        assert "paged_decode_attention" in text, name
+        _assert_whole_array_untouched(text, pool, name)
+        assert mem.alias_size_in_bytes >= 2 * int(np.prod(pool.shape)) * 2
+        assert live <= V5E_HBM_BYTES, (name, live)

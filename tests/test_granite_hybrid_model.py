@@ -176,7 +176,7 @@ def test_a_mixed_step_with_a_decode_group_a_resumed_lane_and_idle_rows():
         jnp.asarray([True, True, False, False]))
     logits = run.mixed_step(lane, [16], [9], rows=jnp.asarray([3]),
                             decode=decode)
-    counts = {k: int(run.aux[k]) for k in granite_hybrid.MOE_COUNTERS}
+    counts = {k: int(run.aux[k]) for k in granite_hybrid.STEP_COUNTERS}
     assert counts["assignments"] == counts["local"] == (4 + 16) * 3 * 8
     after = jax.tree.map(np.asarray, run.state)
     for leaf in ("ssm", "conv"):

@@ -273,10 +273,10 @@ def test_a_served_share_counts_its_compact_expert_layers():
     ``moe_compact_share`` reads the pair from a window's two scrapes through
     the ``counter`` kind, by its data file's own arguments."""
     from benchmark import layer_readers
-    from cyberfabric_core_tpu.models.kimi_k2 import MOE_COUNTERS
+    from cyberfabric_core_tpu.models.kimi_k2 import STEP_COUNTERS
 
-    assert MOE_COUNTERS[3:5] == ("compact", "forwards")
-    assert _moe_series(MOE_COUNTERS)[3:5] == COMPACT
+    assert STEP_COUNTERS[3:5] == ("compact", "forwards")
+    assert _moe_series(STEP_COUNTERS)[3:5] == COMPACT
     start = {s: _counter(s) for s in COMPACT}
     _run(_cfg(decode_lookahead=0), [_prompt(9, 18)], max_tokens=13)
     end = {s: _counter(s) for s in COMPACT}

@@ -24,7 +24,7 @@ from test_nemotron_h_scheduler import _Collector, _counter, _run
 
 MODEL = "tiny-motif-share4-4l"      # dense, dense, a window layer, a full one
 WINDOW, PAGE, BUDGET = 24, 16, 32
-SERIES = _moe_series(motif.MOE_COUNTERS) + (
+SERIES = _moe_series(motif.STEP_COUNTERS) + (
     "llm_attn_pages_walked_total", "llm_attn_pages_offered_total",
     "llm_attn_window_pages_walked_total",
     "llm_attn_window_pages_offered_total", "llm_window_pages_freed_total")

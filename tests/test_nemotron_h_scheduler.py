@@ -24,7 +24,7 @@ from cyberfabric_core_tpu.runtime.scheduler import (ContinuousBatchingEngine,
 
 BUDGET = 32          # the prefill budget: a snapshot boundary every 32 tokens
 ONE_PERIOD = "tiny-nemotron-h-share4-8l"
-SERIES = _moe_series(nemotron_h.MOE_COUNTERS) + (
+SERIES = _moe_series(nemotron_h.STEP_COUNTERS) + (
     "llm_attn_pages_walked_total", "llm_attn_pages_offered_total",
     "llm_state_snapshots_taken_total", "llm_state_snapshot_hits_total",
     "llm_state_restores_total")

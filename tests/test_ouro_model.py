@@ -1,0 +1,444 @@
+"""``tiny-ouro`` (models/ouro.py: 3 layers run 3 times a token, so that a
+swapped pass and layer index shows) against the benchmark's plain reference
+(benchmark/ouro_reference.py: imports nothing from the program, has no cache
+and recomputes every pass over the whole sequence): the full forward, chunked
+prefill then decode through a pool of 9 cache layers, the exit gate's values,
+where a pass's K and V land, the controls that must be caught, and the ties to
+``models/llama.py``."""
+
+import dataclasses
+import json
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import ouro_reference as reference
+from benchmark.adapters import ouro as adapter
+from cyberfabric_core_tpu.models import decoder_module, get_config, llama, ouro
+from cyberfabric_core_tpu.ops.rope import rope_tables
+from cyberfabric_core_tpu.runtime.quant import (init_params_quantized,
+                                                quantize_llama_params)
+
+CONF = json.loads((Path(__file__).resolve().parents[1] / "benchmark/tests"
+                   / "rehearsal/configs/tiny-ouro.json").read_text())
+CFG = get_config("tiny-ouro")
+PAGE, CHUNK, DEPTH = 16, 32, 3
+L, R = CFG.num_layers, CFG.loop_steps
+#: bfloat16 activations and pages through 9 layer applications against
+#: float32: sound rows read 0.010-0.016 here; a wrong page, pass or norm
+#: moves a row to 0.3 and more
+LIMIT = 0.03
+#: the same program with float32 activations and pages differs from the
+#: reference by the order of float32 sums alone
+TIGHT = 2e-4
+KW = reference.reference_kwargs(CONF, DEPTH)
+
+
+def _rms(got, want):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return float(np.sqrt(((got - want) ** 2).mean()) / want.std())
+
+
+def _f32(tree):
+    """The tree with its bfloat16 leaves (norm gains) as float32: the program
+    then keeps float32 activations and pages."""
+    return jax.tree.map(
+        lambda a: a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a,
+        tree)
+
+
+@pytest.fixture(scope="module")
+def weights():
+    return adapter.make_weights(CONF, 11, DEPTH)
+
+
+def _full_forward(w, ids, cfg=CFG):
+    T = len(ids)
+    dtype = w["final_norm"].dtype
+    hidden, cache, aux = ouro.forward(
+        w, cfg, jnp.asarray(ids)[None], jnp.arange(T, dtype=jnp.int32)[None],
+        ouro.init_cache(cfg, 1, T, dtype), jnp.zeros((1,), jnp.int32),
+        rope_tables(cfg, T))
+    return ouro.lm_head_logits(w, cfg, hidden[0]), cache, aux
+
+
+def test_the_module_is_the_decoder_of_its_architecture():
+    assert decoder_module(CFG) is ouro
+    assert (CFG.kv_layers, CFG.attention_layers) == (R * L, L) == (9, 3)
+    assert ouro.STEP_COUNTERS == ("exit_pass_milli", "exit_rows")
+
+
+def test_full_forward_equals_the_reference(weights):
+    """Logits and the gate's values of a whole sequence: float32 activations
+    against the float32 reference (TIGHT), then the served bfloat16 (LIMIT)."""
+    ids = np.random.default_rng(0).integers(3, 512, 75).astype(np.int32)
+    at = jnp.arange(75, dtype=jnp.int32)
+    want, want_lam = reference.forward(weights, jnp.asarray(ids), at, **KW)
+    got, _, aux = _full_forward(_f32(weights), ids)
+    assert _rms(got, want) < TIGHT
+    # the reference has R - 1 gate values a position: the last pass has no say
+    assert want_lam.shape == (R - 1, 75) and aux["lam"].shape == (R, 75)
+    np.testing.assert_allclose(aux["lam"][: R - 1], want_lam, atol=1e-4)
+    assert 0.05 < float(want_lam.min()) and float(want_lam.max()) < 0.95
+    served, _, aux16 = _full_forward(weights, ids)
+    assert max(_rms(served[i], want[i]) for i in range(75)) < LIMIT
+    np.testing.assert_allclose(aux16["lam"][: R - 1], want_lam, atol=0.02)
+
+
+def test_exit_pass_is_the_gates_expectation(weights):
+    """``exit_pass`` (summed as the passes reached) equals ``sum_t t p_t``
+    from the reference's own probabilities; at the published threshold of 1
+    the pass TAKEN is the last whatever the gate says, and under it the
+    first whose cumulated probability reaches it."""
+    ids = np.random.default_rng(1).integers(3, 512, 40).astype(np.int32)
+    at = jnp.arange(40, dtype=jnp.int32)
+    _, lam = reference.forward(weights, jnp.asarray(ids), at, **KW)
+    p = np.asarray(reference.exit_probabilities(lam))
+    np.testing.assert_allclose(p.sum(axis=0), 1.0, atol=1e-6)
+    want = (p * np.arange(1, R + 1)[:, None]).sum(axis=0)
+    _, _, aux = _full_forward(_f32(weights), ids)
+    np.testing.assert_allclose(ouro.exit_pass(aux["lam"]), want, atol=1e-3)
+    assert 1.0 < want.mean() < R
+    assert (np.asarray(reference.exit_pass_taken(lam, 1.0)) == R).all()
+    early = np.asarray(reference.exit_pass_taken(lam, 0.5))
+    assert early.min() >= 1 and early.max() <= R and (early < R).any()
+    np.testing.assert_array_equal(
+        early, 1 + (np.cumsum(p, axis=0)[:-1] < 0.5).sum(axis=0))
+
+
+@pytest.fixture(scope="module")
+def binding():
+    return adapter.bind(CONF, DEPTH, 4)      # its two programs compile once
+
+
+def _scenario(binding, w, seed=0, steps=6):
+    """The judge's scenario in small, through the adapter's binding (its
+    pages the program's own ``PrefixKVPool``'s): row 0 fresh, 75 tokens in
+    three chunks; row 1 resumed from row 0's first two pages; row 2 a short
+    prompt, then a decode rider; row 3 idle in every mixed call. Then decode
+    steps. Returns {(row, position): logits}, {(row, position): lam [R]},
+    the sequences, the binding's state."""
+    rng = np.random.default_rng(seed)
+    lens = [2 * CHUNK + 11, CHUNK + 9, 6, 0]
+    shared = 2 * PAGE
+    seqs = [rng.integers(3, 512, n + steps + 6).astype(np.int32)
+            for n in lens]
+    seqs[1][:shared] = seqs[0][:shared]
+    state = binding.share_prefix(binding.new_state(), 1, 0, shared)
+    done = np.array([0, shared, 0, 0], np.int32)
+    got, lams = {}, {}
+    for call in range(4):
+        q = np.zeros(4, np.int32)
+        for r in range(3):
+            if r == 1 and call == 0:
+                continue
+            left = lens[r] - done[r]
+            q[r] = min(left, CHUNK) if left > 0 else (r == 2)
+        ids = np.zeros((4, CHUNK), np.int32)
+        for r in range(4):
+            ids[r, : q[r]] = seqs[r][done[r]: done[r] + q[r]]
+        last, state = binding.mixed(w, ids, state, done, q)
+        logits = binding.logits(w, last)
+        lam = np.asarray(binding.last_lam).reshape(R, 4, CHUNK)
+        for r in range(3):
+            done[r] += q[r]
+            if q[r] and done[r] >= lens[r]:
+                got[(r, int(done[r]) - 1)] = logits[r]
+                lams[(r, int(done[r]) - 1)] = lam[:, r, q[r] - 1]
+    for _ in range(steps):
+        ids = np.asarray([[seqs[r][done[r]]] for r in range(4)], np.int32)
+        last, state = binding.decode(w, ids, state, done)
+        logits = binding.logits(w, last)
+        lam = np.asarray(binding.last_lam)
+        for r in range(4):
+            got[(r, int(done[r]))] = logits[r]
+            lams[(r, int(done[r]))] = lam[:, r]
+            done[r] += 1
+    return got, lams, [s[: done[r]] for r, s in enumerate(seqs)], state
+
+
+@pytest.fixture(scope="module")
+def judged(binding, weights):
+    return _scenario(binding, weights)
+
+
+def _reference_rows(w, seqs, keys, lower=None):
+    """{(row, position): (logits, lam)} from whole forwards of the
+    reference. Every row goes in at ONE length and ONE number of positions
+    (zeros behind its end, which a causal forward never sees from before it;
+    its last position repeated), so the reference compiles once a control."""
+    T = max(len(s) for s in seqs)
+    n = max(sum(1 for r, _ in keys if r == row) for row in range(len(seqs)))
+    out = {}
+    for r, seq in enumerate(seqs):
+        at = sorted(p for rr, p in keys if rr == r)
+        padded = np.zeros(T, np.int32)
+        padded[: len(seq)] = seq
+        logits, lam = reference.forward(
+            w, jnp.asarray(padded),
+            jnp.asarray(at + [at[-1]] * (n - len(at)), jnp.int32),
+            lower=lower, **KW)
+        out.update({(r, p): (logits[i], np.asarray(lam)[:, i])
+                    for i, p in enumerate(at)})
+    return out
+
+
+def _worst(got, seqs, w, lower=None):
+    want = _reference_rows(w, seqs, got, lower)
+    return max(_rms(row, want[key][0]) for key, row in got.items())
+
+
+def test_chunked_prefill_and_decode_through_the_paged_cache(judged, weights):
+    """Every logits row of the scenario (a fresh row, later chunks, a row
+    resumed from another row's pages, a decode rider, an idle row, then
+    decode steps) against a whole forward of the reference, and the gate's
+    values at the same positions."""
+    got, lams, seqs, state = judged
+    assert len(got) >= 3 + 4 * 6
+    # the resumed row's first pages ARE the source row's
+    assert state["chains"][1][:2] == state["chains"][0][:2]
+    assert state["pool"].pools[0].shape[0] == R * L
+    want = _reference_rows(weights, seqs, got)
+    assert max(_rms(row, want[key][0]) for key, row in got.items()) < LIMIT
+    for key, lam in lams.items():
+        np.testing.assert_allclose(lam[: R - 1], want[key][1], atol=0.03)
+
+
+@pytest.mark.parametrize("lower", ["loop_3", "no_pass_norm"])
+def test_a_control_that_is_another_model_fails_the_limit(judged, weights,
+                                                         lower):
+    """The reference one pass short (loop_3) or without the norm between
+    passes (no_pass_norm) is another model, and the comparison says so by a
+    wide margin. (The precision controls, int4, fp8 and the read-only
+    kv_int8, are held by the judge's own rehearsal at this size:
+    benchmark/tests/test_ouro.py.)"""
+    got, _, seqs, _ = judged
+    assert _worst(got, seqs, weights, lower=lower) > 10 * LIMIT
+
+
+def _pass_kv(w, ids, t, layer):
+    """K (after the rotary embedding) of pass ``t`` of layer ``layer`` for
+    the whole sequence, by the reference's arithmetic walked by hand up to
+    that point: the hidden that enters the pass and layer, normed and
+    projected."""
+    from benchmark.reference import _dequant, _rms_norm, _rope
+
+    T = len(ids)
+    pos = jnp.arange(T, dtype=jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        kw = KW
+        emb = w["embed"]
+        h = emb["qe"][ids].astype(jnp.float32) * emb["se"][ids][:, None]
+        lw = w["layers"]
+        mask = pos[None, :] <= pos[:, None]
+
+        def leaf(name, l):
+            return _dequant({"q": lw[name]["q"][l], "s": lw[name]["s"][l]})
+
+        for tt in range(t + 1):
+            for l in range(L):
+                x = _rms_norm(h, lw["attn_norm"][l], kw["eps"])
+                k = _rope((x @ leaf("wk", l)).reshape(T, kw["kv_heads"], -1),
+                          pos, kw["theta"])
+                if (tt, l) == (t, layer):
+                    return np.asarray(k)
+                q = _rope((x @ leaf("wq", l)).reshape(T, kw["heads"], -1),
+                          pos, kw["theta"])
+                v = (x @ leaf("wv", l)).reshape(T, kw["kv_heads"], -1)
+                s = jnp.einsum("ihd,jhd->hij", q, k) / kw["head_dim"] ** 0.5
+                pr = jax.nn.softmax(jnp.where(mask[None], s, -jnp.inf), -1)
+                a = jnp.einsum("hij,jhd->ihd", pr, v).reshape(T, -1)
+                h = h + _rms_norm(a @ leaf("wo", l),
+                                  lw["attn_post_norm"][l], kw["eps"])
+                x = _rms_norm(h, lw["mlp_norm"][l], kw["eps"])
+                m = (jax.nn.silu(x @ leaf("gate", l)) * (x @ leaf("up", l))
+                     ) @ leaf("down", l)
+                h = h + _rms_norm(m, lw["mlp_post_norm"][l], kw["eps"])
+            h = _rms_norm(h, w["final_norm"], kw["eps"])
+    raise AssertionError("unreachable")
+
+
+def _prefill_pool(w, ids):
+    """One row's prompt through ``forward_paged_mixed``; returns the K pool
+    ``[R L, pages, page, Hkv D]`` and the row's pages."""
+    cfg = CFG
+    T = len(ids)
+    pages = -(-T // PAGE)
+    pool = jnp.zeros((cfg.kv_layers, pages + 1, PAGE,
+                      cfg.num_kv_heads * cfg.head_dim), jnp.float32)
+    table = jnp.arange(1, pages + 1, dtype=jnp.int32)[None]
+    padded = np.zeros((1, 128), np.int32)
+    padded[0, :T] = ids
+    _, (k_pool, _), _ = ouro.forward_paged_mixed(
+        w, cfg, jnp.asarray(padded), (pool, pool), table,
+        jnp.zeros((1,), jnp.int32), jnp.asarray([T], jnp.int32),
+        rope_tables(cfg, 128))
+    return np.asarray(k_pool), pages
+
+
+def test_cache_layer_t_L_plus_l_holds_what_pass_t_of_layer_l_wrote(weights):
+    """A direct read of the pool: for passes and layers chosen so that a
+    swapped index would read another's (t != l), cache layer ``t L + l``
+    holds the K that pass ``t`` of layer ``l`` computes, and NOT what the
+    swapped ``l L + t`` computes."""
+    w = _f32(weights)
+    ids = np.random.default_rng(2).integers(3, 512, 37).astype(np.int32)
+    k_pool, pages = _prefill_pool(w, ids)
+    held = k_pool[:, 1: pages + 1].reshape(R * L, pages * PAGE, -1)[:, :37]
+    for t, layer in ((0, 2), (2, 0), (1, 2), (2, 1)):
+        want = _pass_kv(w, jnp.asarray(ids), t, layer).reshape(37, -1)
+        assert _rms(held[t * L + layer], want) < TIGHT
+        assert _rms(held[layer * L + t], want) > 0.3
+    # every cache layer was written, each with something of its own
+    norms = np.abs(held).mean(axis=(1, 2))
+    assert (norms > 0.1).all() and len({round(float(n), 5) for n in norms}) == R * L
+
+
+def test_a_program_that_wrote_every_pass_into_pass_0s_layers_is_caught(
+        weights, monkeypatch):
+    """The fault the pool's depth exists to prevent: every pass writing (and
+    reading) cache layers ``0 .. L - 1``. Prefill alone cannot show it (a
+    pass reads its own writes back within the call), so the check is the
+    pool read above plus decode: the next token attends over pass R's K/V
+    where it should see pass t's."""
+    w = _f32(weights)
+    ids = np.random.default_rng(3).integers(3, 512, 40).astype(np.int32)
+
+    real = ouro._run_passes
+
+    def pass0_only(params, cfg, h, caches, attend):
+        return real(params, cfg, h, caches,
+                    lambda lp, x, layer, c: attend(lp, x, layer % L, c))
+
+    def decode_logits():
+        cfg = CFG
+        pool = jnp.zeros((cfg.kv_layers, 4, PAGE,
+                          cfg.num_kv_heads * cfg.head_dim), jnp.float32)
+        table = jnp.asarray([[1, 2, 3]], jnp.int32)
+        padded = np.zeros((1, 64), np.int32)
+        padded[0, :39] = ids[:39]
+        rope = rope_tables(cfg, 64)
+        _, pools, _ = ouro.forward_paged_mixed(
+            w, cfg, jnp.asarray(padded), (pool, pool), table,
+            jnp.zeros((1,), jnp.int32), jnp.asarray([39], jnp.int32), rope)
+        hidden, pools, _ = ouro.forward_paged_decode(
+            w, cfg, jnp.asarray(ids[39:40])[None], pools, table,
+            jnp.asarray([39], jnp.int32), rope)
+        return ouro.lm_head_logits(w, cfg, hidden[0]), pools[0]
+
+    want = reference.forward_logits(w, jnp.asarray(ids),
+                                    jnp.asarray([39], jnp.int32), **KW)
+    sound, _ = decode_logits()
+    assert _rms(sound, want) < TIGHT
+    monkeypatch.setattr(ouro, "_run_passes", pass0_only)
+    broken, k_pool = decode_logits()
+    assert _rms(broken, want) > 0.2
+    assert not np.asarray(k_pool[L:]).any()      # and the pool read says why
+
+
+def test_one_pass_without_post_norms_is_llamas_forward():
+    """Ties the module to the code it shares: ``loop_steps`` 1 and no
+    sandwich norms on the same tree equals ``llama.forward`` to the bit, and
+    ``forward_paged_mixed`` / ``forward_paged_decode`` equal llama's."""
+    cfg = dataclasses.replace(CFG, loop_steps=1, sandwich_norm=False)
+    as_llama = dataclasses.replace(cfg, architecture="llama")
+    w = ouro.init_params(cfg, jax.random.PRNGKey(5), jnp.float32)
+    assert "attn_post_norm" not in w["layers"]
+    ids = jnp.asarray(np.random.default_rng(4).integers(3, 512, (1, 33)))
+    pos = jnp.arange(33, dtype=jnp.int32)[None]
+    rope = rope_tables(cfg, 64)
+    start = jnp.zeros((1,), jnp.int32)
+    mine, _, _ = ouro.forward(w, cfg, ids, pos,
+                              ouro.init_cache(cfg, 1, 33, jnp.float32),
+                              start, rope)
+    theirs, _ = llama.forward(w, as_llama, ids, pos,
+                              llama.init_cache(as_llama, 1, 33, jnp.float32),
+                              start, rope)
+    np.testing.assert_array_equal(mine, theirs)
+
+    pool = jnp.zeros((cfg.kv_layers, 4, PAGE,
+                      cfg.num_kv_heads * cfg.head_dim), jnp.float32)
+    table = jnp.asarray([[1, 2, 3]], jnp.int32)
+    padded = jnp.zeros((1, 64), jnp.int32).at[0, :33].set(ids[0])
+    q = jnp.asarray([33], jnp.int32)
+    h1, p1, _ = ouro.forward_paged_mixed(w, cfg, padded, (pool, pool), table,
+                                         start, q, rope)
+    h2, p2 = llama.forward_paged_mixed(w, as_llama, padded, (pool, pool),
+                                       table, start, q, rope)
+    np.testing.assert_array_equal(h1[0, :33], h2[0, :33])
+    d1, _, _ = ouro.forward_paged_decode(w, cfg, ids[:, :1], p1, table, q,
+                                         rope)
+    d2, _ = llama.forward_paged_decode(w, as_llama, ids[:, :1], p2, table, q,
+                                       rope)
+    np.testing.assert_array_equal(d1, d2)
+
+
+def test_int8_tree_against_the_float_tree():
+    """The int8 tree the server makes (norms, the gate and scales kept as
+    they are) against the float tree it was quantised from: the tolerance of
+    tests/test_quant.py's llama case (relative error of the logits under
+    0.08: per-channel int8 rounding through 9 layer applications)."""
+    w = ouro.init_params(CFG, jax.random.PRNGKey(7), jnp.float32)
+    q = quantize_llama_params(w, bits=8)
+    assert q["layers"]["wq"]["q"].dtype == jnp.int8
+    assert q["exit_gate"]["w"].dtype == jnp.float32
+    for name in ("attn_norm", "attn_post_norm", "mlp_norm", "mlp_post_norm"):
+        assert q["layers"][name].dtype == jnp.float32
+    ids = np.random.default_rng(6).integers(3, 512, 30).astype(np.int32)
+    full, _, _ = _full_forward(w, ids)
+    quant, _, _ = _full_forward(q, ids)
+    rel = float(jnp.linalg.norm(quant - full) / jnp.linalg.norm(full))
+    assert rel < 0.08, rel
+    made = init_params_quantized(CFG, jax.random.PRNGKey(0), jnp.bfloat16)
+    assert made["layers"]["down"]["q"].shape == (L, 256, 128)
+    assert made["exit_gate"]["w"].dtype == jnp.float32
+    assert set(made["layers"]) == set(w["layers"])
+
+
+def test_a_stack_counts_its_weights_once_and_its_cache_every_pass():
+    """``param_count`` and ``weight_bytes`` count a layer ONCE (2.67 B, not
+    10 B); the cache counts it ``loop_steps`` times: 1 572 864 B a token in
+    bfloat16, twelve times mistral's 131 072."""
+    big = get_config("ouro-2.6b")
+    assert 2.66e9 < big.param_count() < 2.68e9
+    assert (big.kv_layers, big.attention_layers, big.loop_steps) == (192, 48, 4)
+    assert big.cache_bytes_per_token() == 1_572_864 == \
+        12 * get_config("mistral-7b").cache_bytes_per_token()
+    assert big.weight_bytes()["attention"] == 48 * 4 * (2048 * 2048 + 4 * 2048)
+    once = dataclasses.replace(big, loop_steps=1)
+    assert once.param_count() == big.param_count() - 2049     # the gate
+    assert once.kv_layers == 48
+    # every other model: one pass, the pool as deep as the layers that attend
+    for name in ("mistral-7b", "qwen2-7b", "tiny-granite-hybrid",
+                 "tiny-motif"):
+        cfg = get_config(name)
+        assert cfg.loop_steps == 1 and cfg.kv_layers == cfg.attention_layers
+
+
+@pytest.mark.parametrize("over,what", [
+    (dict(loop_steps=0), "at least 1"),
+    (dict(loop_steps=2, layer_types=("attention",) * 3), "neither layer_types"),
+])
+def test_a_loop_the_configuration_cannot_mean_is_refused(over, what):
+    with pytest.raises(ValueError, match=what):
+        dataclasses.replace(CFG, **over)
+
+
+def test_the_dense_cache_paths_refuse_a_looped_model(tmp_path):
+    """``runtime/engine.py``'s engine and ``runtime/export.py`` drive
+    ``llama.forward`` over a dense cache: they size it by ``kv_layers`` and
+    refuse any architecture but llama with a typed error, a looped one
+    included; ``llama.init_cache`` itself is as deep as ``kv_layers``."""
+    from cyberfabric_core_tpu.runtime import EngineConfig, InferenceEngine
+    from cyberfabric_core_tpu.runtime.export import export_llama_programs
+
+    with pytest.raises(ValueError, match="ouro"):
+        InferenceEngine(EngineConfig(model="tiny-ouro", max_seq_len=64,
+                                     max_batch=2))
+    with pytest.raises(ValueError, match="ouro"):
+        export_llama_programs("tiny-ouro", tmp_path)
+    assert llama.init_cache(CFG, 1, 8)[0].shape[0] == R * L
+    assert ouro.init_cache(CFG, 1, 8)[0].shape[0] == R * L

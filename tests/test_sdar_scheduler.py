@@ -235,7 +235,7 @@ def test_the_counters_of_a_block_model():
     the round records and a request's flight record carry the same."""
     from cyberfabric_core_tpu.modkit.flight_recorder import default_recorder
 
-    series = _BLOCK_SERIES + _moe_series(sdar_moe.MOE_COUNTERS)
+    series = _BLOCK_SERIES + _moe_series(sdar_moe.STEP_COUNTERS)
     before = {s: _counter(s) for s in series}
     col, sched = _run(_cfg(decode_lookahead=0), [_prompt(9, 18)],
                       max_tokens=16)

@@ -175,7 +175,7 @@ def test_a_mixed_step_with_a_decode_group_a_resumed_lane_and_idle_rows():
                             decode=decode)
     chosen = np.asarray(run.aux["experts"])
     assert chosen.shape == (CFG.num_layers, 4 + 16, CFG.experts_per_token)
-    counts = {k: int(run.aux[k]) for k in solar_open2.MOE_COUNTERS}
+    counts = {k: int(run.aux[k]) for k in solar_open2.STEP_COUNTERS}
     assert counts["assignments"] == chosen.size == (4 + 16) * 4 * 4
     assert counts["local"] == int(((chosen >= 4) & (chosen < 8)).sum())
     assert counts["touched"] == sum(

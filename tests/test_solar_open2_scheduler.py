@@ -25,7 +25,7 @@ from test_nemotron_h_scheduler import _Collector, _counter, _prompts, _run
 
 BUDGET = 32          # the prefill budget: a snapshot boundary every 32 tokens
 ONE_PERIOD = "tiny-solar-open2-share4-4l"
-SERIES = _moe_series(solar_open2.MOE_COUNTERS) + (
+SERIES = _moe_series(solar_open2.STEP_COUNTERS) + (
     "llm_attn_pages_walked_total", "llm_attn_pages_offered_total",
     "llm_state_snapshots_taken_total", "llm_state_snapshot_hits_total",
     "llm_state_restores_total")

@@ -36,7 +36,7 @@ def _programs(**over):
 def _key() -> ProgramKey:
     return ProgramKey(
         model_config=get_config("tiny-llama"), decode_chunk=4, max_seq_len=64,
-        n_slots=1, pmax=4, n_cache=2, has_state=False, moe_counters=(),
+        n_slots=1, pmax=4, n_cache=2, has_state=False, step_counters=(),
         block=0, attn_mesh=None, spec_k=0)
 
 
@@ -76,7 +76,7 @@ def test_engines_that_differ_in_a_key_field_do_not_share(base, over):
 OTHER = dict(
     model_config=get_config("tiny-qwen2"), decode_chunk=8, max_seq_len=60,
     n_slots=2, pmax=5, n_cache=3, has_state=True,
-    moe_counters=("assignments",), block=4, attn_mesh="a mesh", spec_k=2,
+    step_counters=("assignments",), block=4, attn_mesh="a mesh", spec_k=2,
     interpret=None)
 
 
