@@ -452,6 +452,16 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                  "least one: a grid program each of the K/V kernel, a trip "
                  "each of the latent kernel's one program a row (a key block "
                  "of ops/mla_attention.py: trip_pages pages, one score dot)"),
+                ("llm_ragged_pages_walked_total",
+                 "Latent pages the ragged kernel's programs copied for the "
+                 "prompt chunks of mixed steps: the pages every block of "
+                 "queries sees (ops/mla_attention.py: ragged_span), summed "
+                 "over q-blocks, steps and layers, a window layer by its "
+                 "window's span"),
+                ("llm_ragged_trips_total",
+                 "Key blocks they attended over those pages in (a trip: up "
+                 "to ragged_trip_pages pages, one score dot): pages a trip "
+                 "near that number say the blocks run full"),
                 ("llm_attn_window_pages_walked_total",
                  "Pages the decode kernel's grid walked in the WINDOW layers "
                  "of a model with a window page group (a row's last "

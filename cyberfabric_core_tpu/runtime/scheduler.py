@@ -391,6 +391,9 @@ class _MixedStep:
     wall0: float
     chained: bool         # launched off a step that was still undrained
     spanned: int = 0      # decode chunks chained off it
+    #: a latent model: what the ragged kernel walks for the lanes' spans
+    #: (``_count_ragged_walk``), for the round's record and the chunk's span
+    ragged: dict = field(default_factory=dict)
 
     def carried(self) -> dict[int, int]:
         """slot -> the tokens of its prompt this step carries: what its
@@ -1085,7 +1088,9 @@ class ContinuousBatchingEngine:
                        "llm_attn_pages_offered_total",
                        "llm_attn_window_pages_walked_total",
                        "llm_attn_window_pages_offered_total",
-                       "llm_window_pages_freed_total") + (
+                       "llm_window_pages_freed_total",
+                       "llm_ragged_pages_walked_total",
+                       "llm_ragged_trips_total") + (
                            _BLOCK_SERIES if self._block else ()
                        ) + (_LOOP_SERIES if self._looped
                             else _moe_series(self._step_counters)):
@@ -3349,6 +3354,39 @@ class ContinuousBatchingEngine:
                 "compiled": sorted(compiled)} if compiled else {}),
         })
 
+    def _count_ragged_walk(self, hist: np.ndarray, q_lens: np.ndarray,
+                           width: int) -> dict:
+        """/metrics of the ragged latent kernel's walk over a mixed step's
+        lanes (spans of ``q_lens`` queries behind ``hist`` tokens, ``width``
+        wide): the pages its programs copy and the trips (key blocks: one
+        score dot each) they attend over them in, summed over the layers,
+        each kind of layer by its own span. Pages a trip near
+        ``ragged_trip_pages`` say the key blocks run full. Counted at the
+        dispatch from the lane's operands by the kernel's own
+        :func:`ragged_span`; nothing for a model with K/V pages, whose
+        ragged kernel has a grid."""
+        cfg = self.model_config
+        if not cfg.is_latent:
+            return {}
+        from ..ops.mla_attention import ragged_walk
+
+        # a model with a window page group: its full layers, then its window
+        # layers; one window for every layer otherwise
+        kinds = [(cfg.kv_layers, cfg.sliding_window)]
+        if self._two_groups:
+            kinds = [(cfg.kv_layers, None),
+                     (cfg.window_layers, cfg.sliding_window)]
+        pages = trips = 0
+        for layers, window in kinds:
+            walked = ragged_walk(hist, q_lens, width,
+                                 self.config.prefix_page_size, self.pmax,
+                                 window)
+            pages += layers * walked[0]
+            trips += layers * walked[1]
+        bump_counter("llm_ragged_pages_walked_total", n=pages)
+        bump_counter("llm_ragged_trips_total", n=trips)
+        return {"ragged_pages": pages, "ragged_trips": trips}
+
     def _count_attn_pages(self, kept: np.ndarray, grew: np.ndarray) -> None:
         """/metrics of the decode kernel's walk over a drained dispatch: the
         pages it walked (those that hold tokens a row's query reads) and the
@@ -4129,7 +4167,8 @@ class ContinuousBatchingEngine:
             toks_dev, _InflightChunk(toks_dev, last_o, keys_o, lens_o, fin_o,
                                      active_o, self._epoch),
             plan, finals, spec_plan, positions, t0, wall0,
-            chained=after is not None)
+            chained=after is not None,
+            ragged=self._count_ragged_walk(hist, q_lens, q_max))
 
     def _extend_chains_behind(self, finals: list[tuple[int, _SlotState]],
                               horizon: int) -> bool:
@@ -4286,7 +4325,7 @@ class ContinuousBatchingEngine:
                         start_unix_ns=int(wall0 * 1e9),
                         duration_ms=round_ms,
                         request_id=state.request_id, slot=slot,
-                        tokens=chunk)
+                        tokens=chunk, **step.ragged)
             for slot, state, tok, dur_ms in first:
                 self._emit_first_token(slot, state, tok, dur_ms)
             if self._block:
@@ -4325,7 +4364,8 @@ class ContinuousBatchingEngine:
                           spec_tokens=sum(len(dr) for _, _, dr in spec_plan),
                           kind=("mixed" if decode_rows else "prefill")
                           if plan else "decode", positions=step.positions,
-                          counted=counted, chained=step.chained)
+                          counted={**(counted or {}), **step.ragged},
+                          chained=step.chained)
 
     def _decode_round(self) -> None:
         self.occupancy_samples.append(self.active_slots)

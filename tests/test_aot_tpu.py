@@ -598,6 +598,18 @@ def test_scheduler_programs_compile_for_v5e_at_sdar_moe():
         assert live <= V5E_HBM_BYTES, (name, live)
 
 
+#: the compiled step programs' temporaries on PR 52's tree, the ragged latent
+#: kernel a grid step a page (``memory_analysis().temp_size_in_bytes`` as the
+#: slow tests print it, rounded up to the next 10 MB), and what PR 53's walk
+#: may add at most: a ring of 3 key blocks of 256 keys x 640 lanes bf16 and
+#: a score block of 80 x 32 rows x 256 keys f32
+_LATENT_TEMP = {("kimi", "paged_decode_chunk"): 0.50e9,
+                ("kimi", "mixed_step@64"): 0.03e9,
+                ("kimi", "mixed_step@512"): 0.30e9,
+                ("motif", "mixed_step@512"): 0.78e9}
+_RAGGED_SCRATCH = 3 * 256 * 640 * 2 + 80 * 32 * 256 * 4
+
+
 @pytest.mark.parametrize("heads,pmax,window", [
     (64, 48, None),         # kimi-k2.5: 64 slots of 3072
     (80, 128, None),        # motif-3-beta's full layers: 64 slots of 8192
@@ -608,8 +620,10 @@ def test_latent_kernels_compile_at_served_shapes(one_chip, heads, pmax,
     """``mla_decode_attention`` (one program a slot, the pool left where it
     lives, a ring of key blocks the kernel copies a row's pages into itself:
     DMAs and semaphores the interpreter only imitates) and
-    ``mla_ragged_attention`` at a chunk of 512, on a latent page of 512 + 64
-    lanes in 640, 64 slots."""
+    ``mla_ragged_attention`` (since PR 53 the same walk, one program a block
+    of 32 queries, a trip of 4 pages) at a chunk of 512, on a latent page of
+    512 + 64 lanes in 640, 64 slots, at 64 and at 80 heads, with a window
+    and without."""
     from cyberfabric_core_tpu.ops.mla_attention import (mla_decode_attention,
                                                         mla_ragged_attention)
 
@@ -716,6 +730,12 @@ def test_scheduler_programs_compile_for_v5e_at_kimi_k2():
         sliced = re.search(r"s8\[(1,)?12,(7168,2048|2048,7168)\]", text)
         assert not sliced, f"{name}: a layer of an expert stack, {sliced[0]}"
         assert live <= V5E_HBM_BYTES, (name, live)
+        # PR 53: the ragged kernel's ring (3 x 256 x 640 bf16) and its score
+        # block (2048 x 256 f32) are VMEM scratch, so the temporaries are
+        # what they were with a grid step a page (0.49 / 0.02 / 0.29 GB as
+        # printed, PR 52's tree): no more than those two on top
+        assert mem.temp_size_in_bytes <= _LATENT_TEMP["kimi", name] \
+            + _RAGGED_SCRATCH, (name, mem.temp_size_in_bytes)
 
 
 @pytest.mark.slow
@@ -1211,6 +1231,11 @@ def test_scheduler_programs_compile_for_v5e_at_motif(name):
     assert mem.alias_size_in_bytes >= sum(
         int(np.prod(p.shape)) * 2 for p in (full, window)), name
     assert live <= V5E_HBM_BYTES, (name, live)
+    # as kimi's: the ring and the score block of both call sites' ragged
+    # kernels are VMEM scratch
+    if ("motif", name) in _LATENT_TEMP:
+        assert mem.temp_size_in_bytes <= _LATENT_TEMP["motif", name] \
+            + 2 * _RAGGED_SCRATCH, (name, mem.temp_size_in_bytes)
     # the residual streams are carried lane-dense, a token's four streams
     # side by side in whole (16, 128) tiles; no array puts them on the
     # sublanes, where the chip stores tiles of four rows

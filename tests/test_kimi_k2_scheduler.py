@@ -262,6 +262,55 @@ def test_the_counters_of_a_chips_share():
     assert len(col.tokens[0]) == 13
 
 
+def test_the_ragged_walk_is_counted_at_a_mixed_steps_dispatch():
+    """/metrics ``llm_ragged_pages_walked_total`` and
+    ``llm_ragged_trips_total``, the round records' ``ragged_pages`` /
+    ``ragged_trips`` and the ``llm.prefill_chunk`` span's attributes: what
+    the ragged kernel copies and attends over for a prompt's chunks, counted
+    from the lane's operands by the kernel's own span (``ragged_walk``: the
+    kernel's side of the equality is tests/test_mla_attention.py's)."""
+    from cyberfabric_core_tpu.modkit.telemetry import (
+        Span, SpanExporter, Tracer, get_global_tracer, set_global_tracer)
+    from cyberfabric_core_tpu.ops.mla_attention import ragged_trip_pages
+
+    class Collect(SpanExporter):
+        spans: list = []
+
+        def export(self, span: Span, duration_ms: float) -> None:
+            self.spans.append(span)
+
+    names = ("llm_ragged_pages_walked_total", "llm_ragged_trips_total")
+    before = {s: _counter(s) for s in names}
+    prev = get_global_tracer()
+    set_global_tracer(Tracer(exporter=Collect()))
+    sched = ContinuousBatchingEngine(_cfg(decode_lookahead=0), seed=0)
+    col = _Collector(1)
+    try:
+        sched.submit(_prompt(9, 50), SamplingParams(max_tokens=5),
+                     col.emit_for(0),
+                     trace="00-" + "ab" * 16 + "-" + "cd" * 8 + "-01")
+        assert col.done.wait(240), sched.stats()
+        time.sleep(0.2)
+    finally:
+        sched.shutdown()
+        set_global_tracer(prev)
+    d = {s: _counter(s) - before[s] for s in names}
+    # a prompt of 50 in chunks of 32 + 18, pages of 16, one q-block a chunk:
+    # keys 0..31 are 2 pages, keys 0..49 are 4, in each of the 3 layers, and
+    # a trip of 16 pages takes either whole
+    assert ragged_trip_pages(16, None, 32) == 16 and CFG.num_layers == 3
+    assert d == {names[0]: 3 * (2 + 4), names[1]: 3 * (1 + 1)}
+    mixed = [r for r in sched.round_timings if r["chunk_tokens"]]
+    assert [(r["ragged_pages"], r["ragged_trips"]) for r in mixed] == \
+        [(6, 3), (12, 3)]
+    assert not any("ragged_pages" in r for r in sched.round_timings
+                   if not r["chunk_tokens"])
+    chunks = [s.attributes for s in Collect.spans
+              if s.name == "llm.prefill_chunk"]
+    assert [(a["tokens"], a["ragged_pages"], a["ragged_trips"])
+            for a in chunks] == [(32, 6, 3), (18, 12, 3)]
+
+
 COMPACT = ("llm_moe_layer_forwards_compact_total",
            "llm_moe_layer_forwards_total")
 

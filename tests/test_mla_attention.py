@@ -1,8 +1,8 @@
 """The latent attention kernels (ops/mla_attention.py) in interpret mode
 against plain ``jnp``: ragged lengths, idle rows, a page read once as key
-(all its lanes) and as value (its first ``rank``); and the decode kernel's
-walk: the copies it starts are the pages the walked/offered counters count,
-its key blocks may hold anything before a call, every start has its wait."""
+(all its lanes) and as value (its first ``rank``); and both kernels' walk:
+the copies a call starts are the pages the host's counters count, its key
+blocks may hold anything before a call, every start has its wait."""
 
 import types
 
@@ -17,6 +17,8 @@ from cyberfabric_core_tpu.ops.mla_attention import (RING_BLOCKS, TRIP_PAGES,
                                                     mla_decode_attention,
                                                     mla_ragged_attention,
                                                     ragged_q_block,
+                                                    ragged_trip_pages,
+                                                    ragged_walk,
                                                     trip_pages)
 from cyberfabric_core_tpu.ops.paged_attention import page_span
 
@@ -157,10 +159,10 @@ def counted_copies(monkeypatch):
         attend(*args, pages=pages, **kwargs)
     monkeypatch.setattr(mla, "_attend_trip", counted_trip)
 
-    def run(name, *args, **kwargs):
+    def run(name, *args, kernel=mla_decode_attention, **kwargs):
         _CountedCopy.seen.clear()
         # a name of its own: a trace of its own
-        jax.block_until_ready(mla_decode_attention(
+        jax.block_until_ready(kernel(
             *args, interpret=True, name=name, **kwargs))
         jax.effects_barrier()
         return dict(_CountedCopy.seen)
@@ -219,33 +221,167 @@ def test_trip_pages_is_what_the_scheduler_counts_groups_by():
     assert decode_page_group(motif, 64, 128, 2) == 16
 
 
-@pytest.mark.parametrize("width", [16, 64])
-def test_ragged_kernel_against_jnp(width):
+def _ragged_dense(q, rows, hist, qlen, window=None):
+    """q [Hq, W, LANES], rows [S, LANES]: each query ``t < qlen`` at position
+    ``hist + t`` over the keys it sees, in float64; zeros past the span."""
+    W, S = q.shape[1], rows.shape[0]
+    pos = hist + np.arange(W)[:, None]
+    keys = np.arange(S)[None, :]
+    seen = keys <= pos
+    if window:
+        seen &= keys > pos - window
+    s = np.where(seen[None], (q.astype(np.float64) @ rows.T) * SCALE, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    out = (p / p.sum(-1, keepdims=True)) @ rows[:, :RANK]
+    out[:, qlen:] = 0.0
+    return out
+
+
+def _ragged_case(name, trip):
+    """(width, hist [3], q_lens [3]): three lanes, the middle one idle. The
+    two cases named after a trip's edge place a lane's LAST key by the trip
+    under test (``trip`` pages of PAGE keys)."""
+    keys = trip * PAGE
+
+    def ends(at, qlen):
+        """The history behind ``qlen`` queries whose last key is key ``at``
+        of a trip (0: its first), a trip or more in."""
+        return (-(-qlen // keys) + 1) * keys + at + 1 - qlen
+
+    return {
+        "history-0": (16, [0, 0, 0], [16, 0, 9]),
+        "history-off-page": (64, [5, 0, 11], [13, 0, 64]),
+        # q-blocks past a span's end write zeros: one query, and 33 of 64
+        "spans-shorter-than-the-chunk": (64, [21, 0, 40], [1, 0, 33]),
+        # the last key is the first of a new trip / the last of a trip, for
+        # the lane's last q-block and (second lane of each) for its first
+        "one-key-into-a-trip": (64, [ends(0, 64), 0, ends(0, 20)],
+                                [64, 0, 20]),
+        "on-a-trips-last-key": (64, [ends(-1, 64), 0, ends(-1, 20)],
+                                [64, 0, 20]),
+        "width-512": (512, [3, 0, 70], [500, 0, 512]),
+    }[name]
+
+
+@pytest.mark.parametrize("trip", [1, 2, None], ids=["trip-1", "trip-2",
+                                                    "shipped"])
+@pytest.mark.parametrize("case", [
+    "history-0", "history-off-page", "spans-shorter-than-the-chunk",
+    "one-key-into-a-trip", "on-a-trips-last-key", "width-512"])
+def test_ragged_kernel_against_jnp(case, trip):
     """A chunk's queries, head-major, each causal over its lane's history
-    and the chunk itself; an idle lane and the padding past a span are
+    and the chunk itself, the pages of a q-block's span ``trip`` at a time;
+    an idle lane between two busy ones and the padding past a span are
     zeros."""
-    rng = np.random.default_rng(width)
-    pool = _pool(rng)
-    table = jnp.asarray([[3, 5, 7, 9, 11, 13, 15, 17, 19, 21],
-                         [2, 4, 6, 8, 10, 12, 14, 16, 18, 20],
-                         [1, 22, 23, 24, 25, 26, 27, 28, 29, 30]], jnp.int32)
-    hist = np.array([5, 0, 11])
-    qlens = np.array([min(width, 13), 0, width])
-    assert ragged_q_block(width) in (16, 32)
+    pages = trip or ragged_trip_pages(PAGE, None, 32)
+    assert pages == (trip or 16)
+    width, hist, qlens = _ragged_case(case, pages)
+    assert min(hist) >= 0 and ragged_q_block(width) in (16, 32)
+    pmax = -(-(max(hist) + width) // PAGE) + 1
+    rng = np.random.default_rng(width + pages)
+    pool = _pool(rng, pages=3 * pmax + 1)
+    table = _table(rng, 3, pmax, pages=3 * pmax + 1)
     q = rng.standard_normal((3, HQ, width, LANES)).astype(np.float32)
     out = np.asarray(mla_ragged_attention(
         jnp.asarray(q), pool, table, jnp.asarray(hist), jnp.asarray(qlens),
-        1, rank=RANK, scale=SCALE, interpret=True))
+        1, rank=RANK, scale=SCALE, interpret=True, trip=trip))
     assert out.shape == (3, HQ, width, RANK)
-    for r in range(3):
+    assert not out[1].any()                               # the idle lane
+    for r in (0, 2):
         rows = np.asarray(pool[1][table[r]]).reshape(-1, LANES)
-        for t in range(width):
-            if t >= qlens[r]:
-                assert not out[r, :, t].any()
-                continue
-            np.testing.assert_allclose(
-                out[r, :, t], _dense(q[r, :, t], rows, hist[r] + t + 1),
-                atol=2e-5)
+        assert not out[r, :, qlens[r]:].any()
+        np.testing.assert_allclose(
+            out[r], _ragged_dense(q[r], rows.astype(np.float64), hist[r],
+                                  qlens[r]), atol=2e-5)
+
+
+@pytest.mark.parametrize("window,trip", [(None, None), (None, 2), (16, None),
+                                         (16, 1), (40, 2)])
+def test_ragged_kernel_with_the_ring_and_the_pool_poisoned(window, trip):
+    """Every pool page outside the lanes' spans is NaN (the pages past a
+    lane's last key, the pages left of its first query's window, a page no
+    table names), and so are the ring's key blocks and the accumulators
+    before the call: nothing outside a span is copied, nothing stale in a
+    key block is attended over, and no access races a copy."""
+    width, hist, qlens = 64, [37, 0, 150, 9], [64, 0, 40, 23]
+    R, pmax = len(hist), 28
+    rng = np.random.default_rng(7 + (window or 0))
+    pool = np.asarray(_pool(rng, pages=R * pmax + 1)).copy()
+    table = np.arange(1, R * pmax + 1, dtype=np.int32).reshape(R, pmax)
+    live = pool.copy()
+    pool[:, 0] = np.nan
+    for r, (h, n) in enumerate(zip(hist, qlens)):
+        first = max(h + 1 - window, 0) // PAGE if window and n else 0
+        last = (h + n - 1) // PAGE if n else -1
+        pool[:, table[r, :first]] = np.nan
+        pool[:, table[r, last + 1:]] = np.nan
+    q = rng.standard_normal((R, HQ, width, LANES)).astype(np.float32)
+    out = np.asarray(mla_ragged_attention(
+        jnp.asarray(q), jnp.asarray(pool), jnp.asarray(table),
+        jnp.asarray(hist), jnp.asarray(qlens), 1, rank=RANK, scale=SCALE,
+        interpret=POISONED, sliding_window=window, trip=trip))
+    assert not _races_found()
+    assert np.isfinite(out).all()
+    for r in range(R):
+        rows = live[1][table[r]].reshape(-1, LANES).astype(np.float64)
+        np.testing.assert_allclose(
+            out[r], _ragged_dense(q[r], rows, hist[r], qlens[r], window),
+            atol=2e-5)
+
+
+@pytest.mark.parametrize("window,trip", [(None, 1), (None, 2), (None, None),
+                                         (16, None)])
+def test_the_copies_a_ragged_call_starts_are_what_the_host_counts(
+        counted_copies, window, trip):
+    """The scheduler counts ``llm_ragged_pages_walked_total`` and
+    ``llm_ragged_trips_total`` at a mixed step's dispatch from the lane's
+    ``hist`` and ``q_lens`` by the kernel's own ``ragged_span``
+    (``ragged_walk``): the kernel starts one copy for each of those pages,
+    waits for each, and attends over that many key blocks; an idle lane and
+    the q-blocks past a span's end cost neither."""
+    width, hist, qlens, pmax = 64, [5, 0, 150, 64], [13, 0, 64, 33], 28
+    pages, trips = ragged_walk(hist, qlens, width, PAGE, pmax, window,
+                               trip=trip)
+    if window is None:
+        # lane 0: one q-block, keys 0..17, 3 pages; lane 2: q-blocks ending
+        # at keys 181 and 213, 23 + 27 pages; lane 3: 96 and 97 keys, 12 + 13
+        assert pages == 3 + (23 + 27) + (12 + 13)
+        assert trips == {1: pages, 2: 2 + (12 + 14) + (6 + 7),
+                         None: 1 + (2 + 2) + (1 + 1)}[trip]
+    else:
+        # a window of 16 keys over 32 queries spans 6-7 pages of 8 (lane 3's
+        # second q-block is one query: 3), each one trip of 7
+        assert (pages, trips) == (3 + (7 + 7) + (6 + 3), 5)
+    pool = _pool(np.random.default_rng(0), pages=4 * pmax + 1)
+    table = jnp.asarray(np.arange(1, 4 * pmax + 1).reshape(4, pmax),
+                        jnp.int32)
+    seen = counted_copies(
+        f"counted_ragged_{window}_{trip}", jnp.ones((4, HQ, width, LANES)),
+        pool, table, jnp.asarray(hist, jnp.int32),
+        jnp.asarray(qlens, jnp.int32), 0, rank=RANK, scale=SCALE,
+        sliding_window=window, trip=trip, kernel=mla_ragged_attention)
+    assert (seen["start"], seen["wait"], seen["trips"]) == (pages, pages,
+                                                            trips)
+
+
+def test_ragged_trip_pages_is_a_key_block_of_full_tiles():
+    """The pages a trip of the ragged kernel takes, from shapes: 256 keys
+    (both dots at full 128-wide MXU tiles), no more than the decode kernel's
+    16 pages, and under a window no more than a q-block's windows span, so a
+    window layer's program is ONE trip."""
+    assert ragged_trip_pages(64, None, 32) == 4
+    assert ragged_trip_pages(64, 128, 32) == 4 == mla._window_pages(128, 64,
+                                                                    32)
+    assert ragged_trip_pages(64, 4096, 32) == 4
+    assert ragged_trip_pages(16, None, 32) == 16
+    assert ragged_trip_pages(8, None, 16) == TRIP_PAGES
+    assert ragged_trip_pages(16, 128, 32) == 11
+    assert mla._block_sizes(4) == (2, 4)
+    from cyberfabric_core_tpu.models import get_config
+
+    motif = get_config("motif-3-beta-share32-27l")
+    assert ragged_trip_pages(64, motif.sliding_window, 32) \
+        == motif.window_pages(64, 32)
 
 
 def test_ragged_kernel_refuses_a_width_that_is_not_whole_blocks():

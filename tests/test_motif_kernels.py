@@ -4,8 +4,9 @@ two), window 128, page 64, at the lengths where a window's span changes its
 page count (127, 128, 129, 191, 192, 193), in the middle (700) and past a
 table of 64 pages (4097), against a dense softmax over the visible keys. The
 pages left of a window's span hold NaN and the table names the (NaN) scratch
-page there, so a kernel that read one would say so. Without a window both
-kernels are the parent commit's, bit for bit."""
+page there, so a kernel that read one would say so. Without a window the
+decode kernel is PR 47's, bit for bit, and so is the ragged kernel at a page
+a trip (its own trip of 4 pages sums in another order)."""
 
 import hashlib
 
@@ -78,11 +79,15 @@ def test_decode_kernel_at_80_heads(length, window):
     np.testing.assert_allclose(got, want, atol=0.02, rtol=0.02)
 
 
+@pytest.mark.parametrize("trip", [1, None], ids=["trip-1", "shipped"])
 @pytest.mark.parametrize("window", [WINDOW, None], ids=["window", "full"])
 @pytest.mark.parametrize("length", LENGTHS)
-def test_ragged_kernel_at_80_heads(length, window):
+def test_ragged_kernel_at_80_heads(length, window, trip):
     """A chunk of 40 queries that ends at ``length`` (two q-blocks of a
-    64-wide lane, the second ragged)."""
+    64-wide lane, the second ragged), a page a trip as the parent's grid
+    took them and at the shipped trip: 4 pages, a window's q-block ONE
+    trip."""
+    assert mla.ragged_trip_pages(PAGE, window, 32) == 4
     qlen = 40
     hist = length - qlen
     rows, pool, table = _pool(length, window, 100 + length)
@@ -97,7 +102,8 @@ def test_ragged_kernel_at_80_heads(length, window):
     out = mla.mla_ragged_attention(
         q, jnp.asarray(pool, jnp.bfloat16), jnp.asarray(table[:1]),
         jnp.asarray([hist], jnp.int32), jnp.asarray([qlen], jnp.int32), 0,
-        rank=RANK, scale=SCALE, interpret=True, sliding_window=window)
+        rank=RANK, scale=SCALE, interpret=True, sliding_window=window,
+        trip=trip)
     got = np.asarray(out[0].astype(jnp.float32))          # [Hq, 64, RANK]
     assert np.isfinite(got[:, :qlen]).all()
     for i in (0, 23, 31, 32, qlen - 1):
@@ -110,8 +116,8 @@ def test_a_window_layers_copies_do_not_grow_with_context(counted_copies):
     """A window layer's row is ONE trip of the pages a window spans (3) at
     any length, and the kernel starts a copy for the pages of the span alone:
     a row of 8 191 tokens starts 3 (of 8 192, whose window starts on a page:
-    2), where a full layer's starts 128 in 8 trips; the ragged grid's page
-    axis is the pages a q-block's windows span (4), not the table's.
+    2), where a full layer's starts 128 in 8 trips; a ragged program's ONE
+    trip is the pages a q-block's windows span (4), whatever the table's.
     ``decode_work`` hands the latent kernel the table and the lengths: it
     walks them itself."""
     cfg = get_config("motif-3-beta-share32-27l")
@@ -177,5 +183,5 @@ def test_the_unwindowed_ragged_kernel_is_the_parents_bit_for_bit():
     out = mla.mla_ragged_attention(
         rq, pool, table[:2], jnp.asarray([9, 0], jnp.int32),
         jnp.asarray([32, 20], jnp.int32), 1, rank=96, scale=0.11,
-        interpret=True)
+        interpret=True, trip=1)
     assert _digest(out) == PARENT["ragged"]

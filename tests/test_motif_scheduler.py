@@ -84,6 +84,32 @@ def test_both_groups_are_built_counted_and_returned():
     assert pool["hits"] == 0 and pool["prefill_tokens_saved"] == 0
 
 
+def test_the_ragged_walk_counts_each_kind_of_layer_by_its_own_span():
+    """``llm_ragged_pages_walked_total`` / ``llm_ragged_trips_total`` sum the
+    full layers' walk (a q-block's whole history) and the window layers' (its
+    windows' span) over a prompt's chunks: here 1 full layer and 3 window
+    layers over one prompt of 70 in chunks of 32, 32 and 6."""
+    from cyberfabric_core_tpu.ops.mla_attention import ragged_walk
+
+    names = ("llm_ragged_pages_walked_total", "llm_ragged_trips_total")
+    before = {s: _counter(s) for s in names}
+    _, _, sched = _run(_cfg(quantization="int8", decode_lookahead=0),
+                       _prompts(sizes=(70,)), max_tokens=4)
+    d = [_counter(s) - before[s] for s in names]
+    want = [0, 0]
+    for hist, qlen in ((0, 32), (32, 32), (64, 6)):
+        full = ragged_walk([hist], [qlen], 32, PAGE, sched.pmax, None)
+        window = ragged_walk([hist], [qlen], 32, PAGE, sched.pmax, WINDOW)
+        # the full layer reads every page so far, a window layer 3-4 of them
+        assert full[0] == -(-(hist + qlen) // PAGE) and 2 <= window[0] <= 4
+        for i in (0, 1):
+            want[i] += full[i] + 3 * window[i]
+    assert d == want
+    mixed = [r for r in sched.round_timings if r["chunk_tokens"]]
+    assert [sum(r[k] for r in mixed)
+            for k in ("ragged_pages", "ragged_trips")] == want
+
+
 def test_a_window_page_is_freed_only_when_no_queued_step_reads_it():
     """Pass by pass: before every launch and after every commit, each row's
     live window pages cover the window of its COMMITTED length (what a step

@@ -451,14 +451,18 @@ def _primitives(jaxpr) -> list[str]:
 #: (kimi's: PR 49, whose latent decode kernel walks a row's pages inside one
 #: program, so the work list in front of the scan went and the kernel's body
 #: holds the trips' copies, waits and key blocks; they were (1379,
-#: "57088846ee5f55da") and (1703, "a079161fd346b428"))
+#: "57088846ee5f55da") and (1703, "a079161fd346b428"); and PR 53, whose
+#: ragged latent kernel walks a q-block's pages the same way, through one
+#: ``_Walk`` with the decode kernel's and with the bookkeeping in ``lax``
+#: primitives; they were (4215, "761c459152d47736") and (4539,
+#: "0c4f9bc2b1332b41"))
 TRACED_AT_THE_PARENT = {
     "tiny-granite-hybrid-4l": [(2786, "2e0c6431a4b078a3"),
                                (3465, "f7ce34d2268058cb")],
     "tiny-nemotron-h-share4-8l": [(2546, "c1f6a78f45ff9309"),
                                   (3225, "c9671f2367cb4e41")],
-    "tiny-kimi-share4": [(4215, "761c459152d47736"),
-                         (4539, "0c4f9bc2b1332b41")],
+    "tiny-kimi-share4": [(3881, "4800cad6e270bfdb"),
+                         (5451, "d4074a4d09f51c13")],
 }
 
 
