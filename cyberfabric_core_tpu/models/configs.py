@@ -18,6 +18,12 @@ from typing import Optional
 #: (a row of the state slab and a conv tail) and write no page
 STATE_KINDS = ("mamba", "kda")
 
+#: the architectures whose model module calls its attention kernels by the
+#: layer's kind over TWO page groups (``sliding_window_period``): motif on
+#: latent pages, laguna on K/V pages. The other modules read one window a
+#: model, so a per-layer window is refused for them at config time
+TWO_GROUP_ARCHITECTURES = ("motif", "laguna")
+
 
 @dataclass(frozen=True)
 class ModelConfig:
@@ -189,6 +195,28 @@ class ModelConfig:
     #: none, for every layer). The two kinds cache in two PAGE GROUPS
     #: (``runtime/paged.py``): a pool and a page table each
     sliding_window_period: int = 0
+    #: which layer of a period is the full one: layer ``i`` attends over
+    #: everything where ``i % period == full_layer_phase % period`` (-1, the
+    #: LAST of each period: motif's reading of its own key; 0, the first:
+    #: laguna's published ``layer_types``)
+    full_layer_phase: int = -1
+    # laguna (model_type laguna), each beside its published name.
+    #: num_attention_heads_per_layer: query heads of a WINDOW layer (0: as
+    #: ``num_heads``, which the full layers have); both kinds read the same
+    #: ``num_kv_heads`` of ``head_dim``
+    window_num_heads: int = 0
+    #: rope_parameters by layer type. ``rope_theta`` and the YaRN keys above
+    #: are the FULL layers'; a window layer's tables are plain
+    #: ``window_rope_theta`` over the whole head (0: every layer shares the
+    #: one pair of tables). ``partial_rotary_factor``: the full layers rotate
+    #: the leading this share of a head and pass the rest through. The
+    #: published ``attention_factor`` is what ``ops/rope.py`` derives from
+    #: ``rope_factor`` (0.1 ln(factor) + 1) and is not stated again
+    window_rope_theta: float = 0.0
+    partial_rotary_factor: float = 1.0
+    #: gating "per-head": an attention layer's output is multiplied, a head
+    #: a token, by ``sigmoid(x W_g)`` [heads] before ``W_o``
+    head_gate: bool = False
     #: num_noise_heads: the LAST this many of ``num_heads`` are the noise
     #: heads of differential attention; noise head ``g`` and signal heads
     #: ``4g..`` read latent kv group ``g`` of ``num_kv_heads``
@@ -227,12 +255,24 @@ class ModelConfig:
                 f"unknown hidden_act {self.hidden_act!r} (supported: silu, "
                 "gelu, gelu_pytorch_tanh, relu2, poly_norm)")
         if self.sliding_window_period and not (
-                self.is_latent and self.sliding_window):
+                self.sliding_window
+                and self.architecture in TWO_GROUP_ARCHITECTURES):
             raise ValueError(
                 f"{self.name}: a per-layer window (sliding_window_period "
-                f"{self.sliding_window_period}) needs a sliding_window and "
-                "kernels that take one a layer; only the latent kernels do "
-                "(K/V pages come in one page group)")
+                f"{self.sliding_window_period}) needs a sliding_window and a "
+                "model module that calls its kernels by the layer's kind "
+                f"over two page groups ({', '.join(TWO_GROUP_ARCHITECTURES)}"
+                "); the other modules read one window a model")
+        if (self.window_num_heads or self.window_rope_theta) \
+                and not self.sliding_window_period:
+            raise ValueError(
+                f"{self.name}: window_num_heads and window_rope_theta are "
+                "the window LAYERS' and need a sliding_window_period")
+        if self.window_heads % max(self.num_kv_heads, 1):
+            raise ValueError(
+                f"{self.name}: {self.window_heads} query heads of a window "
+                f"layer are not whole groups over {self.num_kv_heads} kv "
+                "heads")
         if self.remasking not in ("low_confidence_static",
                                   "low_confidence_dynamic"):
             raise ValueError(f"unknown remasking {self.remasking!r}")
@@ -269,11 +309,17 @@ class ModelConfig:
         return self.num_heads // self.num_kv_heads
 
     @property
+    def window_heads(self) -> int:
+        """Query heads of a window layer (``num_heads``: a full layer's)."""
+        return self.window_num_heads or self.num_heads
+
+    @property
     def router_float32(self) -> bool:
         """The router's weights stay float32 whatever the activations' dtype
         (a score decides WHICH experts run, not only how much)."""
         return self.architecture in ("sdar_moe", "kimi_k2", "granite_hybrid",
-                                     "nemotron_h", "solar_open2", "motif")
+                                     "nemotron_h", "solar_open2", "motif",
+                                     "laguna")
 
     @property
     def is_latent(self) -> bool:
@@ -339,7 +385,7 @@ class ModelConfig:
         """Layers of the model whose pages a row keeps for its whole length,
         each counted ONCE: what holds attention weights of that kind."""
         if self.sliding_window_period:
-            return self.num_layers // self.sliding_window_period
+            return self.full_layers_before(self.num_layers)
         if not self.layer_types:
             return self.num_layers
         return self.layer_types.count("attention")
@@ -354,8 +400,18 @@ class ModelConfig:
 
     def layer_is_full(self, layer: int) -> bool:
         """Layer ``layer`` attends over a row's whole length."""
-        return (not self.sliding_window_period
-                or (layer + 1) % self.sliding_window_period == 0)
+        period = self.sliding_window_period
+        return (not period
+                or layer % period == self.full_layer_phase % period)
+
+    def full_layers_before(self, layer):
+        """The full layers among layers ``0 .. layer - 1`` of a stack with a
+        per-layer window: a full layer's index in the full page group, and
+        ``layer`` less this a window layer's in the window group. Whole
+        numbers in, a whole number out; a traced ``layer`` (inside a scan
+        over layers) gives a traced count."""
+        period = self.sliding_window_period
+        return (layer + period - 1 - self.full_layer_phase % period) // period
 
     def window_pages(self, page_size: int, queries: int = 1) -> int:
         """The most pages the window of ``queries`` consecutive positions
@@ -380,9 +436,19 @@ class ModelConfig:
     def cache_bytes_per_token(self, itemsize: int = 2) -> int:
         """Bytes a token holds in the page pool over its ``kv_layers`` cache
         layers: a layer that caches, times the passes that run it."""
-        per_layer = (self.latent_lanes if self.is_latent
-                     else 2 * self.num_kv_heads * self.head_dim)
-        return self.kv_layers * per_layer * itemsize
+        return self.kv_layers * self._page_row_numbers * itemsize
+
+    def window_bytes_per_token(self, itemsize: int = 2) -> int:
+        """Bytes a token holds in the WINDOW page group while it lies inside
+        its row's window (0: the model has one page group)."""
+        return self.window_layers * self._page_row_numbers * itemsize
+
+    @property
+    def _page_row_numbers(self) -> int:
+        """Numbers a token stores in one cache layer: a latent row in whole
+        lane tiles, or K and V of every kv head."""
+        return (self.latent_lanes if self.is_latent
+                else 2 * self.num_kv_heads * self.head_dim)
 
     @property
     def is_block(self) -> bool:
@@ -441,11 +507,28 @@ class ModelConfig:
         """Approximate parameter count (for HBM budgeting). A layer counts
         ONCE however many passes run it (``loop_steps``)."""
         h, i, v, l = self.hidden_size, self.intermediate_size, self.vocab_size, self.num_layers
-        attn = h * (self.num_heads * self.head_dim) + 2 * h * (self.num_kv_heads * self.head_dim) \
-            + (self.num_heads * self.head_dim) * h
-        if self.use_gqa_gate:
-            attn += h * self.num_heads * self.head_dim
+
+        def attention(heads: int) -> int:
+            """One attention layer of ``heads`` query heads: q, k, v, o, the
+            elementwise output gate or the gate a head."""
+            dq, dkv = heads * self.head_dim, self.num_kv_heads * self.head_dim
+            return (2 * h * dq + 2 * h * dkv
+                    + (h * dq if self.use_gqa_gate else 0)
+                    + (h * heads if self.head_gate else 0))
+
+        attn = attention(self.num_heads)
         emb = v * h * (1 if self.tie_embeddings else 2)
+        if self.window_layers and not self.is_latent:
+            # two kinds of attention layer (laguna); the leading dense MLPs,
+            # then a router, the routed experts and a shared one a layer
+            expert = 3 * h * self.expert_width
+            return (self.attention_layers * attn
+                    + self.window_layers * attention(self.window_heads)
+                    + self.first_k_dense * 3 * h * i
+                    + self.moe_layers * (self.num_experts * expert + h
+                                         * self.num_experts
+                                         + 3 * h * self.shared_width)
+                    + l * 2 * h + emb + h)
         mixer = 0
         if "kda" in self.layer_types:
             # the matrices; conv taps, A_log, dt_bias, the head norm's weight
@@ -487,7 +570,9 @@ class ModelConfig:
         one f32 scale an output channel where ``itemsize`` is 1. For a
         ``layer_types`` stack (nemotron_h: one sub-layer a layer, an expert
         of two matrices; solar_open2: an expert layer of gated three-matrix
-        experts after every mixer)."""
+        experts after every mixer) and for a stack of two kinds of attention
+        layer on K/V pages (laguna: ``window_attention`` beside
+        ``attention``, ``dense_mlp`` the leading dense layers')."""
         h, w = self.hidden_size, self.expert_row_width
         i, shared = self.expert_width, self.shared_width
         scale = 4 if itemsize == 1 else 0
@@ -498,6 +583,11 @@ class ModelConfig:
 
         dq, dkv = self.num_heads * self.head_dim, \
             self.num_kv_heads * self.head_dim
+        dw = self.window_heads * self.head_dim
+
+        def gate(heads: int) -> int:
+            return mat(h, heads) if self.head_gate else 0
+
         return {
             "mamba": self.layer_types.count("mamba") * (
                 mat(h, self.ssm_proj_dim) + mat(self.ssm_inner, h)),
@@ -505,7 +595,15 @@ class ModelConfig:
                 mat(k, n) for k, n in self.kda_matrices().values()),
             "attention": self.attention_layers * (
                 mat(h, dq, 2 if self.use_gqa_gate else 1) + 2 * mat(h, dkv)
-                + mat(dq, h)),
+                + mat(dq, h) + gate(self.num_heads)),
+            # the layers behind a window, where they hold K and V pages of
+            # their own kind of attention (laguna: other query heads)
+            "window_attention": 0 if self.is_latent else self.window_layers * (
+                2 * mat(h, dw) + 2 * mat(h, dkv) + gate(self.window_heads)),
+            "dense_mlp": (self.first_k_dense if self.num_experts
+                          and not self.layer_types else 0) * (
+                2 * mat(h, self.intermediate_size)
+                + mat(self.intermediate_size, h)),
             "moe_dense": self.moe_layers * (
                 mat(h, shared, 2 if gated else 1) + mat(shared, h)
                 + (mat(h, w) + mat(w, h) if self.moe_latent_size else 0)
@@ -894,6 +992,48 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         rms_norm_eps=1e-6, loop_steps=3, early_exit_threshold=1.0,
         sandwich_norm=True,
     ),
+    # Laguna-S-2.1, config.json as published (model_type laguna, poolside,
+    # about 118 B): 48 layers of GQA attention over 8 kv heads of 128, layer i
+    # full where i % 4 == 0 (layer_types) with 48 query heads, YaRN x128 over
+    # 8192 at theta 500000 on the FIRST 64 numbers of a head
+    # (partial_rotary_factor 0.5), tables times the published
+    # attention_factor, which is YaRN's 0.1 ln 128 + 1; the others behind a
+    # window of 512 with 72 query heads and plain theta 10000 over the whole
+    # head; a sigmoid gate a head (gating per-head); layer 0 a
+    # dense SwiGLU of 12288 (mlp_only_layers [0]), then 256 softmax-routed
+    # experts of 1024 top-10, gates normalised over the chosen
+    # (norm_topk_prob) and scaled by 2.5, beside a shared expert of 1024;
+    # untied head
+    "laguna-s-2.1": ModelConfig(
+        name="laguna-s-2.1", architecture="laguna", vocab_size=100352,
+        hidden_size=3072, intermediate_size=12288, num_layers=48,
+        num_heads=48, window_num_heads=72, num_kv_heads=8, head_dim=128,
+        max_position=1048576, rms_norm_eps=1e-6, sliding_window=512,
+        sliding_window_period=4, full_layer_phase=0, head_gate=True,
+        rope_theta=500000.0, rope_factor=128.0, rope_original_max=8192,
+        rope_beta_fast=32.0, rope_beta_slow=1.0,
+        partial_rotary_factor=0.5,
+        window_rope_theta=10000.0, num_experts=256, experts_per_token=10,
+        first_k_dense=1, moe_intermediate_size=1024,
+        shared_intermediate_size=1024, routed_scaling_factor=2.5,
+    ),
+    # CPU-test preset of the same stack: a dense full layer, three window
+    # layers, a full expert layer and a window layer behind it (every body
+    # ``motif.layer_plan`` cuts the served stack into); 6 and 9 query heads
+    # on 3 kv heads of 32, a window of 8 over pages of 4 (shorter than the
+    # test rows), half a head rotated under YaRN x4 in the full layers, 8
+    # experts top-3
+    "tiny-laguna": ModelConfig(
+        name="tiny-laguna", architecture="laguna", vocab_size=512,
+        hidden_size=64, intermediate_size=128, num_layers=6, num_heads=6,
+        window_num_heads=9, num_kv_heads=3, head_dim=32, max_position=1024,
+        rms_norm_eps=1e-6, sliding_window=8, sliding_window_period=4,
+        full_layer_phase=0, head_gate=True, rope_theta=10000.0,
+        rope_factor=4.0, rope_original_max=64, rope_beta_fast=32.0,
+        rope_beta_slow=1.0, partial_rotary_factor=0.5, window_rope_theta=100.0, num_experts=8,
+        experts_per_token=3, first_k_dense=1, moe_intermediate_size=32,
+        shared_intermediate_size=32, routed_scaling_factor=2.5,
+    ),
     "bge-base-en": ModelConfig(
         name="bge-base-en", architecture="bert", vocab_size=30522, hidden_size=768,
         intermediate_size=3072, num_layers=12, num_heads=12, num_kv_heads=12,
@@ -999,6 +1139,22 @@ MODEL_CONFIGS["tiny-motif-share4"] = dataclasses.replace(
     expert_offset=4, vocab_held=256)
 MODEL_CONFIGS["tiny-motif-share4-4l"] = MODEL_CONFIGS[
     "tiny-motif-share4"].cut_to(4, "tiny-motif-share4-4l")
+
+
+# share 0 of the first of 4 pipeline stages of a 32-chip deployment of
+# laguna-s-2.1 (8 chips share each layer): layers 0-11, three whole periods
+# (layer 0 dense and full, full expert layers 4 and 8, nine window expert
+# layers), experts 0-31 of each layer's 256, rows 0-12543 of the vocabulary
+# (an 8-way split); attention is data-parallel, so both head counts are here
+MODEL_CONFIGS["laguna-s-2.1-share8-12l"] = dataclasses.replace(
+    MODEL_CONFIGS["laguna-s-2.1"], name="laguna-s-2.1-share8-12l",
+    num_layers=12, experts_held=32, expert_offset=0, vocab_held=12544,
+    max_position=8192)
+
+# a share of the tiny preset: experts 4-7 of 8, half the vocabulary
+MODEL_CONFIGS["tiny-laguna-share4"] = dataclasses.replace(
+    MODEL_CONFIGS["tiny-laguna"], name="tiny-laguna-share4", experts_held=4,
+    expert_offset=4, vocab_held=256)
 
 
 def get_config(name: str) -> ModelConfig:

@@ -227,16 +227,22 @@ def _dense_residual(lp: dict, h: jnp.ndarray, cfg: ModelConfig):
     return h + y.astype(h.dtype)
 
 
-def _moe_residual(lp: dict, moe: dict, layer, h: jnp.ndarray,
-                  cfg: ModelConfig):
-    """Post-attention norm + shared expert + the routed experts held here +
-    residual over ``h`` [1, N, H]; also the experts chosen [N, K] and the
-    layer's ``STEP_COUNTERS``."""
-    x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
-    flat = x.reshape(-1, x.shape[-1])
-    top_idx, gates = moe_route(
+def _sigmoid_route(flat: jnp.ndarray, lp: dict, cfg: ModelConfig):
+    """This architecture's router: sigmoid scores, a selection bias."""
+    return moe_route(
         flat, lp["router"], cfg.experts_per_token, sigmoid=True,
         bias=lp["router_bias"], scale=cfg.routed_scaling_factor)
+
+
+def _moe_residual(lp: dict, moe: dict, layer, h: jnp.ndarray,
+                  cfg: ModelConfig, route: Callable = _sigmoid_route):
+    """Post-attention norm + shared expert + the routed experts held here +
+    residual over ``h`` [1, N, H]; also the experts chosen [N, K] and the
+    layer's ``STEP_COUNTERS``. ``route(flat, lp, cfg) -> (experts, gates)``:
+    another architecture's router over the same expert layer."""
+    x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
+    flat = x.reshape(-1, x.shape[-1])
+    top_idx, gates = route(flat, lp, cfg)
     y = moe_experts(flat, top_idx, gates, moe, cfg, layer)
     y = y + _swiglu(flat, lp["shared_gate"], lp["shared_up"],
                     lp["shared_down"], cfg)

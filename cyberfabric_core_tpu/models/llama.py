@@ -210,7 +210,8 @@ def moe_route(flat: jnp.ndarray, router: jnp.ndarray, k: int, *,
     """Top-k routing of ``flat`` [N, H]: (experts [N, k] int32, gates [N, k]
     f32). The gate is the softmax over the chosen experts' scores, which is
     also the softmax over every expert renormalised over the chosen
-    (``norm_topk_prob``): the two are one number. A float32 router is
+    (``norm_topk_prob``): the two are one number, times ``scale`` where the
+    model gives one (laguna's ``moe_routed_scaling_factor``). A float32 router is
     multiplied in float32 at full precision, since a score decides WHICH
     experts run, not only how much.
 
@@ -226,7 +227,9 @@ def moe_route(flat: jnp.ndarray, router: jnp.ndarray, k: int, *,
                             preferred_element_type=jnp.float32)
     if not sigmoid:
         top_vals, top_idx = jax.lax.top_k(logits, k)
-        return top_idx.astype(jnp.int32), jax.nn.softmax(top_vals, axis=-1)
+        gates = jax.nn.softmax(top_vals, axis=-1)
+        return top_idx.astype(jnp.int32), (
+            gates if scale == 1.0 else gates * scale)
     score = jax.nn.sigmoid(logits)
     _, top_idx = jax.lax.top_k(
         score if bias is None else score + bias.astype(jnp.float32), k)
@@ -366,13 +369,15 @@ def _moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig,
 
 
 def _qkv_proj(lp: dict, x: jnp.ndarray, cfg: ModelConfig,
-              positions: jnp.ndarray, cos_t, sin_t):
+              positions: jnp.ndarray, cos_t, sin_t, heads: int | None = None):
     """Shared q/k/v projection + reshape + rope for one layer (any T).
     ``cfg.key_multiplier`` (falcon_h1) scales k in f32, before the cast; at
     1.0 nothing is traced for it. ``cfg.rotary`` False (granite_hybrid): no
-    rotation, q and k go on as projected."""
+    rotation, q and k go on as projected. ``heads``: the query heads of
+    this layer's kind where a model has two (laguna); ``cfg.num_heads``
+    otherwise."""
     B, T = x.shape[0], x.shape[1]
-    Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    Hq, Hkv, D = heads or cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     wq_m, wq_s = _wmat(lp["wq"], x.dtype)
     wk_m, wk_s = _wmat(lp["wk"], x.dtype)
     wv_m, wv_s = _wmat(lp["wv"], x.dtype)
@@ -601,12 +606,14 @@ def _decode_attend(cfg: ModelConfig, interpret: bool, mesh):
 
 
 def decode_page_group(cfg: ModelConfig, page_size: int, n_pages: int,
-                      itemsize: int) -> int:
+                      itemsize: int, heads: int | None = None) -> int:
     """Pages ``cfg``'s decode kernel takes at a time, over a table of
     ``n_pages`` slots a row whose pool holds ``itemsize`` bytes a number: a
     program's of the K/V kernel (what :func:`decode_work` builds its list
     with), a trip's of the latent kernel's walk over the layers that attend
-    over everything. What the scheduler counts a row's groups of pages by."""
+    over everything. What the scheduler counts a row's groups of pages by.
+    ``heads``: the query heads of the kind of layer asked about, where the
+    two kinds of a model differ (``cfg.num_heads`` otherwise)."""
     if cfg.is_latent:
         from ..ops.mla_attention import trip_pages
 
@@ -615,26 +622,27 @@ def decode_page_group(cfg: ModelConfig, page_size: int, n_pages: int,
     from ..ops.paged_attention import decode_page_group as by_shapes
 
     return by_shapes(page_size, cfg.num_kv_heads * cfg.head_dim, itemsize,
-                     cfg.num_heads * cfg.block_length, n_pages)
+                     (heads or cfg.num_heads) * cfg.block_length, n_pages)
 
 
 def decode_work(cfg: ModelConfig, page_table, lengths, pool,
-                window: int | None):
+                window: int | None, heads: int | None = None):
     """What the decode kernel walks in one step: ``lengths`` [B] counts the
     tokens the step itself writes, ``pool`` is the cache the kernel reads.
     The K/V kernel's work list (the pool's page size and its bytes a number
     pick the group); the latent kernel walks a row's span itself and takes
     the table and the lengths. The same for every layer of one ``window``
     (``cfg.sliding_window``; None for the layers that attend over
-    everything, where that is some layers' alone), so it is built here,
-    outside the scan over layers."""
+    everything, where that is some layers' alone) and one count of query
+    ``heads`` (:func:`decode_page_group`), so it is built here, outside the
+    scan over layers."""
     if cfg.is_latent:
         return page_table, lengths
     from ..ops.paged_attention import decode_work_list
 
     page_size = pool.shape[2]
     group = decode_page_group(cfg, page_size, page_table.shape[1],
-                              pool.dtype.itemsize)
+                              pool.dtype.itemsize, heads)
     return decode_work_list(page_table, lengths, page_size, window, group)
 
 
@@ -771,10 +779,11 @@ class MixedLayout(NamedTuple):
 
 def mixed_layout(cfg: ModelConfig, input_ids, page_table, hist, q_lens,
                  write_mask, rows, decode: DecodeGroup | None,
-                 pool, window: int | None) -> MixedLayout:
+                 pool, window: int | None,
+                 heads: int | None = None) -> MixedLayout:
     """Lay a mixed step's tokens out (see :func:`forward_paged_mixed`);
-    ``pool`` is the cache the attention kernels read, ``page_table`` and
-    ``window`` its page group's (:func:`decode_work`)."""
+    ``pool`` is the cache the attention kernels read, ``page_table``,
+    ``window`` and ``heads`` its page group's (:func:`decode_work`)."""
     page_size = pool.shape[2]
     R, Qc = input_ids.shape
     lane_table = page_table if rows is None else page_table[rows]
@@ -795,7 +804,7 @@ def mixed_layout(cfg: ModelConfig, input_ids, page_table, hist, q_lens,
         n_dec = decode.tokens.size
         width = decode.tokens.shape[1] if decode.tokens.ndim == 2 else None
         work = decode_work(cfg, page_table, decode.lengths + (width or 1),
-                           pool, window)
+                           pool, window, heads)
         d_pid, d_off = _decode_targets(page_table, decode.lengths, decode.run,
                                        page_size, width)
         d_pos = decode.lengths if width is None else (

@@ -81,7 +81,7 @@ from .llama import (MOE_LEAVES, DecodeGroup, Params, _act, _decode_targets,
 
 __all__ = ["init_params", "init_params_with", "forward_paged_decode",
            "forward_paged_mixed", "lm_head_logits", "gather_last_hidden",
-           "STEP_COUNTERS", "sinkhorn"]
+           "STEP_COUNTERS", "sinkhorn", "layer_plan", "run_plan"]
 
 Pools = tuple[jnp.ndarray, jnp.ndarray]     # (full, window) latent pools
 Aux = dict[str, jnp.ndarray]
@@ -351,7 +351,8 @@ def _moe_ffn(lp: dict, moe: dict, layer, x: jnp.ndarray, cfg: ModelConfig):
 # ------------------------------------------------------------------ the stack
 def _kind(cfg: ModelConfig, layer: int) -> tuple[bool, bool]:
     """(dense, full) of layer ``layer``: what its body is compiled from."""
-    return layer < cfg.first_k_dense, cfg.layer_is_full(layer)
+    return (layer < cfg.first_k_dense or not cfg.num_experts,
+            cfg.layer_is_full(layer))
 
 
 def _runs(cfg: ModelConfig, first: int, end: int) -> list[tuple[int, int]]:
@@ -374,11 +375,62 @@ def layer_plan(cfg: ModelConfig) -> tuple[list, tuple[int, int], list]:
     the served share: the 2 dense layers, layer 2, then 6 units from layer 3
     on, FOUR bodies to compile where a layer a body would be 27."""
     P, L = cfg.sliding_window_period, cfg.num_layers
+    if not P:                       # one kind of attention layer: no units
+        return _runs(cfg, 0, L), (L, 0), []
     first = next((i for i in range(cfg.first_k_dense, L)
                   if cfg.layer_is_full(i)), L)
     units = (L - first) // P
     return (_runs(cfg, 0, first), (first, units),
             _runs(cfg, first + units * P, L))
+
+
+def run_plan(cfg: ModelConfig, carry, one: Callable):
+    """The stack cut as :func:`layer_plan` says, for any model whose layers
+    are (dense or expert) x (full or window): ``one(carry, at, like) ->
+    (carry, chosen)`` runs layer ``at`` (traced inside a scan), whose kind is
+    layer ``like``'s, ``chosen`` (the experts chosen [N, K], the layer's
+    ``STEP_COUNTERS``) or None of a dense layer. Returns (carry, aux)."""
+    P = cfg.sliding_window_period
+
+    def run(carry, first, count: int, like: int):
+        """``count`` consecutive layers of layer ``like``'s kind from
+        ``first`` on: (carry, (experts [count, N, K], counters [count, .])
+        or None)."""
+        def body(carry, at):
+            return one(carry, at, like)
+
+        return jax.lax.scan(
+            body, carry, first + jnp.arange(count, dtype=jnp.int32))
+
+    experts, counts = [], jnp.zeros((len(STEP_COUNTERS),), jnp.int32)
+
+    def took(chosen):
+        nonlocal counts
+        if chosen is not None:
+            experts.append(chosen[0].reshape(-1, *chosen[0].shape[-2:]))
+            counts = counts + jnp.sum(chosen[1].reshape(-1, counts.shape[0]),
+                                      axis=0)
+
+    head, (first, units), tail = layer_plan(cfg)
+    for at, count in head:
+        carry, chosen = run(carry, at, count, at)
+        took(chosen)
+    if units:
+        def unit(carry, at):
+            carry, full = run(carry, at, 1, first)
+            carry, window = run(carry, at + 1, P - 1, first + 1)
+            return carry, jax.tree.map(
+                lambda a, b: jnp.concatenate([a, b]), full, window)
+
+        carry, chosen = jax.lax.scan(
+            unit, carry, first + P * jnp.arange(units, dtype=jnp.int32))
+        took(chosen)                                        # layer order
+    for at, count in tail:
+        carry, chosen = run(carry, at, count, at)
+        took(chosen)
+    aux = {**({"experts": jnp.concatenate(experts)} if experts else {}),
+           **{name: counts[i] for i, name in enumerate(STEP_COUNTERS)}}
+    return carry, aux
 
 
 def _run_layers(params: Params, cfg: ModelConfig, X, pools, attend):
@@ -390,10 +442,8 @@ def _run_layers(params: Params, cfg: ModelConfig, X, pools, attend):
     every = {k: v for k, v in layers.items() if k not in MOE_LEAVES}
     moe = {k: layers[k] for k in MOE_LEAVES}
 
-    def one(X, pools, at, like: int):
-        """Layer ``at`` (traced inside a scan), whose kind is layer
-        ``like``'s. Returns (X, pools, (the experts chosen [N, K], the
-        layer's counters) or None of a dense layer)."""
+    def one(carry, at, like: int):
+        X, pools = carry
         dense, full = _kind(cfg, like)
         lp = _at(params["dense"], at) if dense else _at(every, at - Ld)
         # the layer's index in its page group: the full layers before it,
@@ -408,49 +458,9 @@ def _run_layers(params: Params, cfg: ModelConfig, X, pools, attend):
         ffn = (lambda x: _dense_ffn(lp, x, cfg)) if dense else (
             lambda x: _moe_ffn(lp, moe, at - Ld, x, cfg))
         X, chosen = hyper_connected(lp, 1, X, cfg, lp["mlp_norm"], ffn)
-        return X, pools, chosen
+        return (X, pools), chosen
 
-    def run(X, pools, first, count: int, like: int):
-        """``count`` consecutive layers of layer ``like``'s kind from
-        ``first`` on: (X, pools, (experts [count, N, K], counters [count,
-        .]) or None)."""
-        def body(carry, at):
-            X, pools, chosen = one(*carry, at, like)
-            return (X, pools), chosen
-
-        (X, pools), chosen = jax.lax.scan(
-            body, (X, pools), first + jnp.arange(count, dtype=jnp.int32))
-        return X, pools, chosen
-
-    experts, counts = [], jnp.zeros((len(STEP_COUNTERS),), jnp.int32)
-
-    def took(chosen):
-        nonlocal counts
-        if chosen is not None:
-            experts.append(chosen[0].reshape(-1, *chosen[0].shape[-2:]))
-            counts = counts + jnp.sum(chosen[1].reshape(-1, counts.shape[0]),
-                                      axis=0)
-
-    head, (first, units), tail = layer_plan(cfg)
-    for at, count in head:
-        X, pools, chosen = run(X, pools, at, count, at)
-        took(chosen)
-    if units:
-        def unit(carry, at):
-            X, pools, full = run(*carry, at, 1, first)
-            X, pools, window = run(X, pools, at + 1, P - 1, first + 1)
-            return (X, pools), jax.tree.map(
-                lambda a, b: jnp.concatenate([a, b]), full, window)
-
-        (X, pools), chosen = jax.lax.scan(
-            unit, (X, pools),
-            first + P * jnp.arange(units, dtype=jnp.int32))
-        took(chosen)                                        # layer order
-    for at, count in tail:
-        X, pools, chosen = run(X, pools, at, count, at)
-        took(chosen)
-    aux = {"experts": jnp.concatenate(experts),
-           **{name: counts[i] for i, name in enumerate(STEP_COUNTERS)}}
+    (X, pools), aux = run_plan(cfg, (X, pools), one)
     return X, pools, aux
 
 

@@ -471,6 +471,12 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                 ("llm_attn_window_pages_offered_total",
                  "Slots of the window group's page table beside them (rows "
                  "x pages a row x window layers x steps)"),
+                ("llm_attn_window_page_groups_total",
+                 "Groups the decode kernel took the WINDOW layers' pages in "
+                 "(llm_attn_page_groups_total then counts the layers that "
+                 "attend over everything): a grid program each of the K/V "
+                 "kernel's second work list, its group picked by the window "
+                 "layers' query heads; one trip a row of the latent kernel"),
                 ("llm_window_pages_freed_total",
                  "Window-group pages rows gave back while running: each "
                  "lay left of the window of its row's committed length"),
@@ -710,7 +716,8 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                  "llm_batch_active_slots: about sliding_window / page + 1 a "
                  "row at rest where the pool frees)"),
                 ("llm_cache_bytes", "cache_bytes",
-                 "Bytes of the page pool plus the recurrent-state slab")):
+                 "Bytes of the page pool (both page groups where the model "
+                 "has a window group) plus the recurrent-state slab")):
             self.registry.gauge(name, text).set_function(
                 lambda key=key: _state_stat(key))
 
@@ -721,6 +728,28 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
         ).set_function(lambda: float(sum(
             getattr(s, "moe_layers_built", lambda: 0)()
             for s in _schedulers())))
+
+        # query heads by the kind of attention layer, as the weights were
+        # built (one number twice where a model has one kind of layer). What
+        # an operator reads them for: heads over kv heads is the query rows
+        # a kv head, which picks the decode kernel's page group
+        # (``decode_page_group``), so the two say what
+        # llm_attn_pages_walked_total / llm_attn_page_groups_total and the
+        # window pair can reach when every group is full
+        for name, kind, text in (
+                ("llm_attn_full_heads", 0,
+                 "Query heads of a layer that attends over a row's whole "
+                 "length; over the kv heads, the query rows that pick the "
+                 "decode kernel's page group: the most pages a program of "
+                 "llm_attn_page_groups_total can walk"),
+                ("llm_attn_window_heads", 1,
+                 "Query heads of a layer behind a sliding window (as "
+                 "llm_attn_full_heads where the model has one kind); the "
+                 "same for llm_attn_window_page_groups_total")):
+            self.registry.gauge(name, text).set_function(
+                lambda kind=kind: float(sum(
+                    getattr(s, "attn_heads_built", lambda: (0, 0))()[kind]
+                    for s in _schedulers())))
 
         def mixed_chunk_tokens() -> float:
             return float(sum(getattr(s, "chunked_prefill_tokens", 0)

@@ -327,7 +327,7 @@ def decode_work_list(page_table: jnp.ndarray, lengths: jnp.ndarray,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "sliding_window",
-                                             "two_d_dots", "scale"))
+                                             "two_d_dots", "scale", "name"))
 def paged_decode_attention(
     q: jnp.ndarray,           # [B, Hq, D] — one query token per slot
     k_pool: jnp.ndarray,      # [L, N, page, Hkv*D] — the stacked page pool
@@ -338,6 +338,7 @@ def paged_decode_attention(
     sliding_window: int | None = None,
     two_d_dots: bool | None = None,
     scale: float | None = None,
+    name: str | None = None,      # the call site's, in a device trace
 ) -> jnp.ndarray:
     """Returns [B, Hq, D] attention over each slot's paged history in layer
     ``layer`` of the pool. The pool operands reach the ``pallas_call`` as
@@ -345,7 +346,9 @@ def paged_decode_attention(
     layer and the page are both picked by the blocks' index maps, so the
     pipeline DMAs whole pages and nothing pool-sized is sliced or copied.
     ``sliding_window`` is the one ``work`` was built with; ``scale`` is the
-    softmax scale where the model gives one (absent: ``D^-1/2``).
+    softmax scale where the model gives one (absent: ``D^-1/2``); ``name`` is
+    what a device trace calls this call site's kernel (absent: the kernel's
+    own name), for a model that calls it for two kinds of layer.
 
     ``two_d_dots`` (default: on exactly when compiling for real — Mosaic's
     dot supports only 2D tensors) selects the unrolled per-kv-head 2D-dot
@@ -388,7 +391,7 @@ def paged_decode_attention(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
-        interpret=interpret,
+        interpret=interpret, name=name,
     )(work.row, work.page, work.phys, work.lengths, work.last,
       jnp.asarray(layer, jnp.int32).reshape(1), q,
       *([k_pool] * group), *([v_pool] * group))
@@ -563,7 +566,7 @@ def _ragged_kernel(pt_ref, hist_ref, qlen_ref, layer_ref, q_ref, k_ref, v_ref,
 
 @functools.partial(jax.jit, static_argnames=("q_block", "interpret",
                                              "sliding_window", "two_d_dots",
-                                             "block", "scale"))
+                                             "block", "scale", "name"))
 def ragged_paged_attention(
     q: jnp.ndarray,           # [B, Qmax, Hq, D] — per-row query span, padded
     k_pool: jnp.ndarray,      # [L, N, page, Hkv*D] — the stacked page pool
@@ -578,6 +581,7 @@ def ragged_paged_attention(
     two_d_dots: bool | None = None,
     block: int = 1,
     scale: float | None = None,
+    name: str | None = None,      # the call site's, in a device trace
 ) -> jnp.ndarray:
     """Ragged mixed-batch paged attention: one dispatch where each batch row
     attends a variable-length query span over its paged KV chain with causal
@@ -600,7 +604,9 @@ def ragged_paged_attention(
     ``block`` > 1 is the block mask (see the kernel): a query sees the keys
     up to the end of its own block of ``block`` absolute positions, which
     must all be in the pool already. ``scale``: the softmax scale where the
-    model gives one (absent: ``D^-1/2``)."""
+    model gives one (absent: ``D^-1/2``). ``name``: what a device trace calls
+    this call site's kernel (absent: the kernel's own name), for a model that
+    calls it for two kinds of layer."""
     if two_d_dots is None:
         two_d_dots = not interpret
     if block & (block - 1):
@@ -662,7 +668,7 @@ def ragged_paged_attention(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=interpret, name=name,
     )(page_table.astype(jnp.int32), hist.astype(jnp.int32),
       q_lens.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
       q_in, k_pool, v_pool)

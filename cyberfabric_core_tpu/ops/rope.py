@@ -59,16 +59,22 @@ def rope_tables(cfg, max_position: int) -> tuple[jnp.ndarray, jnp.ndarray]:
     head (``qk_rope_head_dim`` where the head has a part that is not
     rotated), YaRN's frequencies where ``rope_factor`` > 1, the tables
     scaled by ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)``."""
-    dim = cfg.qk_rope_head_dim or cfg.head_dim
+    dim = cfg.qk_rope_head_dim or int(
+        cfg.head_dim * cfg.partial_rotary_factor)
     if cfg.rope_factor <= 1.0:
-        return rope_frequencies(dim, max_position, cfg.rope_theta)
-    return rope_frequencies(
-        dim, max_position, cfg.rope_theta,
-        yarn_inv_freq(dim, cfg.rope_theta, cfg.rope_factor,
-                      cfg.rope_original_max, cfg.rope_beta_fast,
-                      cfg.rope_beta_slow),
-        yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
-        / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
+        full = rope_frequencies(dim, max_position, cfg.rope_theta)
+    else:
+        full = rope_frequencies(
+            dim, max_position, cfg.rope_theta,
+            yarn_inv_freq(dim, cfg.rope_theta, cfg.rope_factor,
+                          cfg.rope_original_max, cfg.rope_beta_fast,
+                          cfg.rope_beta_slow),
+            yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
+            / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
+    if not cfg.window_rope_theta:
+        return full
+    return full, rope_frequencies(cfg.head_dim, max_position,
+                                  cfg.window_rope_theta)
 
 
 def attention_scale(cfg) -> float:
@@ -86,11 +92,18 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
     """Rotate q or k. x: [B, T, H, D]; positions: [B, T] int32.
 
     Uses the HF-llama "rotate_half" convention (first/second half pairing) so
-    safetensors weights load without permutation.
+    safetensors weights load without permutation. Tables of fewer than D/2
+    columns rotate the head's leading part alone (``partial_rotary_factor``).
     """
     cos = cos_table[positions][:, :, None, :]  # [B, T, 1, D/2]
     sin = sin_table[positions][:, :, None, :]
-    half = x.shape[-1] // 2
+    half = cos_table.shape[-1]
+    if 2 * half < x.shape[-1]:
+        # tables narrower than the head: the leading ``2 half`` numbers are
+        # rotated (pairs inside them), the rest go on as they are
+        return jnp.concatenate(
+            [apply_rope(x[..., :2 * half], positions, cos_table, sin_table),
+             x[..., 2 * half:]], axis=-1)
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

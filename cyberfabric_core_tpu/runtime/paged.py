@@ -17,17 +17,21 @@ no kv-head axis and no V pool; ``pools``,
 ``_pool_names``. The allocator, the radix tree, the refcounts and the
 admission protocol count pages, whatever a page holds.
 
-**Two page groups** (``ModelConfig.window_layers``: motif, whose layers
-attend over everything in one layer of four and over the last 128 tokens in
-the others). The layers that keep a row's whole length are the pool above,
-``kv_layers`` deep, with its tree, refcounts and prefix reuse. The window
-layers get ``window_pool`` [window_layers, window_pages, page, lanes], an
-allocator of its own and NO tree: a row's window pages are private, named by
-the SAME logical page index as its full chain (``extend_window``; 0 where a
-page was given back), and ``trim_window`` returns every page whose last token
-lies left of the window of the row's next query. The kernels start a window
-layer's row at its span's first page (``ops/mla_attention.py``), so a page
-given back is never read again and may be another row's at once: steps run
+**Two page groups** (``ModelConfig.window_layers``: a stack whose layers
+attend over everything in one layer of four and over the last
+``sliding_window`` tokens in the others, on latent pages or on K/V pages).
+The layers that keep a row's whole length are the pool above, ``kv_layers``
+deep, with its tree, refcounts and prefix reuse. The window layers get
+arrays of the same layout ``[window_layers, window_pages, page, .]``
+(``window_pool`` beside ``latent_pool``; ``window_k_pool`` and
+``window_v_pool`` beside ``k_pool`` and ``v_pool``: ``_window_names``), ONE
+allocator of their own and NO tree: a row's window pages are private, named
+by the SAME logical page index as its full chain (``extend_window``; 0 where
+a page was given back), and ``trim_window`` returns every page whose last
+token lies left of the window of the row's next query. The kernels start a
+window layer's row at its span's first page (``ops/mla_attention.py``;
+``ops/paged_attention.py: _span_first``), so a page given back is never read
+again and may be another row's at once: steps run
 on the device in the order they were launched, every launched step's queries
 sit at or past the length the host has committed, and a step launched later
 that writes the page runs after every step that still read it. ``match_prefix`` **treats every
@@ -155,14 +159,25 @@ class PrefixKVPool:
             if model_config.window_layers else 0
         self.window_pages = window_pages if self.window else 0
         self.window_pages_freed = 0
+        self._window_names: tuple[str, ...] = ()
         if self.window:
             if window_pages < 2:
                 raise ValueError(
                     f"{model_config.name}: {model_config.window_layers} "
                     "window layers need a window page group (window_pages)")
-            self._pool_names += ("window_pool",)
-            self.window_pool = jnp.zeros(
-                (model_config.window_layers, window_pages, *shape[2:]), dtype)
+            if sharding is not None:
+                raise ValueError(
+                    f"{model_config.name}: the window page group has no "
+                    "sharding over tp")
+            #: the window group's arrays, one for each of the chain's
+            self._window_names = tuple(
+                "window_" + name.removeprefix("latent_")
+                for name in self._pool_names)
+            self._pool_names += self._window_names
+            for name in self._window_names:
+                setattr(self, name, jnp.zeros(
+                    (model_config.window_layers, window_pages, *shape[2:]),
+                    dtype))
             self.window_allocator = BlockAllocator(
                 window_pages - 1, force_python=force_python_native)
         # page 0 is scratch (padding target); allocator hands out 1..num_pages-1
@@ -288,16 +303,25 @@ class PrefixKVPool:
     @property
     def pools(self) -> tuple:
         """The pool arrays: K and V, or the one latent pool; then the window
-        group's where the model has one."""
+        group's, as many again, where the model has one."""
         return tuple(getattr(self, name) for name in self._pool_names)
 
     @property
     def _n_chain_pools(self) -> int:
         """The pools a slot's (full) chain indexes."""
-        return len(self._pool_names) - bool(self.window)
+        return len(self._pool_names) - len(self._window_names)
+
+    @property
+    def window_pools(self) -> tuple:
+        """The window group's arrays (none: one page group)."""
+        return tuple(getattr(self, name) for name in self._window_names)
 
     def pool_bytes(self) -> int:
         return sum(int(p.size) * p.dtype.itemsize for p in self.pools)
+
+    def window_pool_bytes(self) -> int:
+        """The window group's part of :meth:`pool_bytes`."""
+        return sum(int(p.size) * p.dtype.itemsize for p in self.window_pools)
 
     def state_bytes(self) -> int:
         return sum(int(v.size) * v.dtype.itemsize
@@ -502,11 +526,13 @@ class PrefixKVPool:
 
     def save_window_to_host(self, wchain: list[int]) -> dict:
         """The live pages of a window chain, device→host, for preemption:
-        their logical indices and ``[window_layers, n, page, lanes]``."""
+        their logical indices and ``[window_layers, n, page, .]`` of every
+        array of the group."""
         at = [j for j, p in enumerate(wchain) if p]
         idx = jnp.asarray([wchain[j] for j in at], jnp.int32)
         return {"at": at, "pages": len(wchain),
-                "rows": np.asarray(self.window_pool[:, idx])}
+                "rows": tuple(np.asarray(pool[:, idx])
+                              for pool in self.window_pools)}
 
     def restore_window_from_host(self, saved: dict) -> list[int]:
         """Fresh window pages for a saved window chain (MemoryError where
@@ -516,9 +542,11 @@ class PrefixKVPool:
         for j, p in zip(saved["at"], ids):
             wchain[j] = p
         if ids:
-            self.window_pool = self.window_pool.at[
-                :, jnp.asarray(ids, jnp.int32)].set(
-                    jnp.asarray(saved["rows"], self.window_pool.dtype))
+            idx = jnp.asarray(ids, jnp.int32)
+            for name, rows in zip(self._window_names, saved["rows"]):
+                pool = getattr(self, name)
+                setattr(self, name, pool.at[:, idx].set(
+                    jnp.asarray(rows, pool.dtype)))
         return wchain
 
     # ------------------------------------------------------------ preemption
@@ -613,6 +641,11 @@ class PrefixKVPool:
                 f"{self.cfg.name}: the PD page export is K and V pages with a "
                 "kv-head axis; a latent page has neither (and the export "
                 "carries one page group)")
+        if self.window:
+            raise ValueError(
+                f"{self.cfg.name}: the PD page export carries one page "
+                "group, and a decode replica could not continue a row "
+                "without its window pages")
         if self.state is not None:
             raise ValueError(
                 f"{self.cfg.name}: the PD page export carries no recurrent "
@@ -644,6 +677,9 @@ class PrefixKVPool:
             "page_shape": [self.page_size, *self._page_tail],
             "cache_bytes_per_token": self.cfg.cache_bytes_per_token(
                 jnp.dtype(self.dtype).itemsize),
+            # and in the window group, while it lies inside its row's window
+            "window_bytes_per_token": self.cfg.window_bytes_per_token(
+                jnp.dtype(self.dtype).itemsize),
             "pool_bytes": self.pool_bytes(),
             # what the caches were BUILT with: layers of the page pool, of
             # the state slab and of the model, and both caches' bytes
@@ -663,6 +699,7 @@ class PrefixKVPool:
             "window_pages_total": max(self.window_pages - 1, 0),
             "window_pages_in_use": self.window_pages_in_use(),
             "window_pages_freed": self.window_pages_freed,
+            "window_pool_bytes": self.window_pool_bytes(),
             **self.state_stats(),
         }
 

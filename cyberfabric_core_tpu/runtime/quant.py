@@ -87,8 +87,10 @@ def quantize_llama_params(params: dict[str, Any], bits: int = 8) -> dict[str, An
     # beside it: kimi_k2's leading "dense" layers, granite_hybrid's "mamba"
     # and "attention" layers (the mixer's conv, A_log, D, dt_bias and norm
     # stay float32; the router float32), nemotron_h's "moe" layers,
-    # solar_open2's "kda" layers
-    for stack in ("dense", "mamba", "kda", "attention", "moe", "layers"):
+    # solar_open2's "kda" layers, laguna's "full" and "window" attention
+    # layers (its gate a head is ``w_gate``)
+    for stack in ("dense", "mamba", "kda", "attention", "full", "window",
+                  "moe", "layers"):
         if stack in params:
             out[stack] = {
                 # norms, router (tiny + precision-sensitive) stay as they are

@@ -735,6 +735,8 @@ class ContinuousBatchingEngine:
             self._refuse_without_block_support(config)
         if self.model_config.is_latent:
             self._refuse_without_latent_support(config)
+        elif self.model_config.window_layers:
+            self._refuse_without_window_group_support(config)
         #: the stack runs several times a token (ouro): the pool is
         #: ``loop_steps`` times the model's depth, and the forwards hand
         #: over what the exit gate says
@@ -926,6 +928,10 @@ class ContinuousBatchingEngine:
             pooled.attrs.update(
                 pages=num_pages,
                 bytes=self.pool.pool_bytes() + self.pool.state_bytes())
+            if self._two_groups:    # ``bytes`` is both groups'; these the
+                pooled.attrs.update(                        # window group's
+                    window_pages=self.pool.window_pages,
+                    window_bytes=self.pool.window_pool_bytes())
 
         from collections import deque as _deque
 
@@ -1088,6 +1094,7 @@ class ContinuousBatchingEngine:
                        "llm_attn_pages_offered_total",
                        "llm_attn_window_pages_walked_total",
                        "llm_attn_window_pages_offered_total",
+                       "llm_attn_window_page_groups_total",
                        "llm_window_pages_freed_total",
                        "llm_ragged_pages_walked_total",
                        "llm_ragged_trips_total") + (
@@ -1095,6 +1102,8 @@ class ContinuousBatchingEngine:
                        ) + (_LOOP_SERIES if self._looped
                             else _moe_series(self._step_counters)):
             bump_counter(series, n=0.0)
+        #: window pages rows had given back at the last round record
+        self._window_freed_recorded = 0
         #: achieved ring depth at each drain (how many chunks stayed in
         #: flight while the host emitted) → stats() depth histogram
         self._depth_hist: dict[int, int] = {}
@@ -1166,6 +1175,28 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 f"{name}: tp > 1 has no sharding for a latent page (no "
                 "kv-head axis) nor an ep axis for the experts")
+
+    def _refuse_without_window_group_support(self,
+                                             config: EngineConfig) -> None:
+        """A model whose K/V pages come in two page groups is served on one
+        device, unified, without speculation; each mode below lacks one
+        named thing (a latent model's two groups are refused above, by what
+        a latent page lacks)."""
+        name = self.model_config.name
+        if config.scheduler_spec_k > 0:
+            raise ValueError(
+                f"{name}: scheduler_spec_k > 0 verifies a draft span through "
+                "llama's all-rows forward, which reads one page group, one "
+                "window and one count of query heads")
+        if config.pd_role:
+            raise ValueError(
+                f"{name}: pd_role={config.pd_role!r} hands rows over as the "
+                "pages of ONE page group; a decode replica could not "
+                "continue a row without its window pages")
+        if max(1, int(config.tp)) > 1:
+            raise ValueError(
+                f"{name}: tp > 1 has no sharding for the window page group "
+                "nor an ep axis for the experts")
 
     def _refuse_without_loop_support(self, config: EngineConfig) -> None:
         """A model whose stack runs several times a token is served on one
@@ -1919,6 +1950,23 @@ class ContinuousBatchingEngine:
                 return int((leaf["q"] if isinstance(leaf, dict)
                             else leaf).shape[0])
         return 0
+
+    def attn_heads_built(self) -> tuple[int, int]:
+        """Query heads of a layer that attends over everything and of one
+        behind a window, as the parameters were BUILT: the rows of each
+        kind's output projection over the head size where the tree stacks
+        the two kinds apart (``full`` and ``window``), the model's one count
+        twice where it does not."""
+        cfg = self.model_config
+
+        def heads(stack: str) -> int:
+            leaf = self.params[stack]["wo"]
+            rows = (leaf["q"] if isinstance(leaf, dict) else leaf).shape[-2]
+            return int(rows) // cfg.head_dim
+
+        if "window" in self.params and "full" in self.params:
+            return heads("full"), heads("window")
+        return cfg.num_heads, cfg.num_heads
 
     # -------------------------------------------------------- health surface
     def mesh_info(self) -> dict[str, Any]:
@@ -3387,7 +3435,7 @@ class ContinuousBatchingEngine:
         bump_counter("llm_ragged_trips_total", n=trips)
         return {"ragged_pages": pages, "ragged_trips": trips}
 
-    def _count_attn_pages(self, kept: np.ndarray, grew: np.ndarray) -> None:
+    def _count_attn_pages(self, kept: np.ndarray, grew: np.ndarray) -> dict:
         """/metrics of the decode kernel's walk over a drained dispatch: the
         pages it walked (those that hold tokens a row's query reads) and the
         groups it took them in (``decode_page_group``: a grid program each
@@ -3399,7 +3447,10 @@ class ContinuousBatchingEngine:
         row that does not run sits at length 0 on the device and is counted
         as the one page and the one group a program costs. Counted from the
         host's mirror by the kernel's own :func:`page_span`, so a step pays
-        nothing for it."""
+        nothing for it. Returns what the round's record and its
+        ``llm.decode_chunk`` spans say of a model with a window page group
+        (nothing of another): the ``full_pages`` and ``window_pages`` walked,
+        and the ``window_pages_freed`` since the last record."""
         from ..models.llama import decode_page_group
         from ..ops.paged_attention import page_span
 
@@ -3416,18 +3467,32 @@ class ContinuousBatchingEngine:
         layers = cfg.kv_layers                   # the layers that attend
         group = decode_page_group(cfg, page, slots,
                                   jnp.dtype(self.dtype).itemsize)
-        bump_counter("llm_attn_pages_walked_total",
-                     n=int((last - first + 1).sum()) * layers)
+        walked = int((last - first + 1).sum()) * layers
+        bump_counter("llm_attn_pages_walked_total", n=walked)
         bump_counter("llm_attn_page_groups_total",
                      n=int(((last - first) // group + 1).sum()) * layers)
         bump_counter("llm_attn_pages_offered_total",
                      n=lengths.size * slots * layers)
-        if self._two_groups:
-            first, last = page_span(lengths, page, slots, cfg.sliding_window)
-            bump_counter("llm_attn_window_pages_walked_total",
-                         n=int((last - first + 1).sum()) * cfg.window_layers)
-            bump_counter("llm_attn_window_pages_offered_total",
-                         n=lengths.size * slots * cfg.window_layers)
+        if not self._two_groups:
+            return {}
+        first, last = page_span(lengths, page, slots, cfg.sliding_window)
+        window_walked = int((last - first + 1).sum()) * cfg.window_layers
+        # the window layers' groups: the K/V kernel's second work list is
+        # grid programs too, its group picked by those layers' query heads;
+        # a latent window layer takes its span in one trip a program
+        window_groups = lengths.size if cfg.is_latent else int(
+            ((last - first) // decode_page_group(
+                cfg, page, slots, jnp.dtype(self.dtype).itemsize,
+                cfg.window_heads) + 1).sum())
+        bump_counter("llm_attn_window_pages_walked_total", n=window_walked)
+        bump_counter("llm_attn_window_page_groups_total",
+                     n=window_groups * cfg.window_layers)
+        bump_counter("llm_attn_window_pages_offered_total",
+                     n=lengths.size * slots * cfg.window_layers)
+        freed = self.pool.window_pages_freed - self._window_freed_recorded
+        self._window_freed_recorded += freed
+        return {"full_pages": walked, "window_pages": window_walked,
+                "window_pages_freed": freed}
 
     def _take_block_counters(self, drained: np.ndarray
                              ) -> tuple[np.ndarray, np.ndarray]:
@@ -4238,7 +4303,8 @@ class ContinuousBatchingEngine:
         decode_rows = [s for s in range(n) if self.active[s]]
         old_lengths = self.lengths.copy()
         if not spec_plan:    # a draft span rides the ragged kernel
-            self._count_attn_pages(old_lengths, np.zeros((n, 1), bool))
+            counted = {**counted, **self._count_attn_pages(
+                old_lengths, np.zeros((n, 1), bool))}
         if self._block:
             self.lengths = np.where(
                 self.active & (toks2d[:, 0] >= 0),
@@ -4436,7 +4502,6 @@ class ContinuousBatchingEngine:
         self._depth_hist[ring_depth] = self._depth_hist.get(ring_depth, 0) + 1
         chunk, counted = self._take_step_counters(chunk, self._k_steps,
                                                   decode=True)
-        round_attrs = counted or None   # on every span of the round
         # the rows that ran in this chunk: a held emit is flushed after the
         # next pass's ``_admit``, whose resumed rows have no token in it
         rows = np.flatnonzero(self.active).tolist()
@@ -4445,7 +4510,9 @@ class ContinuousBatchingEngine:
             committed = chunk[:, ::self._block] >= 0
             commits = committed.sum(axis=1)
             old_lengths = self._commit_chunk(inflight, commits)
-            self._count_attn_pages(old_lengths, committed)
+            counted = {**counted,
+                       **self._count_attn_pages(old_lengths, committed)}
+            round_attrs = counted or None   # on every span of the round
             self._emit_decode_spans(
                 wall0, round_ms, used_lookahead, depth=ring_depth,
                 row_tokens={s: int(c) * self._block
@@ -4459,10 +4526,11 @@ class ContinuousBatchingEngine:
                                               depth=ring_depth, rows=rows)
         else:
             old_lengths = self._commit_chunk(inflight)
-            self._count_attn_pages(old_lengths, chunk >= 0)
+            counted = {**counted,
+                       **self._count_attn_pages(old_lengths, chunk >= 0)}
             self._emit_decode_spans(
                 wall0, round_ms, used_lookahead, depth=ring_depth,
-                round_attrs=round_attrs)
+                round_attrs=counted or None)    # on every span of the round
 
             def emit() -> None:
                 self._emit_chunk(chunk, old_lengths, rows, depth=ring_depth)

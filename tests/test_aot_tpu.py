@@ -91,7 +91,10 @@ def test_flash_kernel_compiles_at_mistral_7b_shapes(one_chip, batch):
     (_HQ, _HKV, _B, _PMAX, _WINDOW),
     (28, 4, 16, 64, None),      # qwen2-7b: 7 queries a kv head, 4k context
     (128, 4, 16, 32, None),     # sdar: 4 x 8 query rows folded on a kv head
-], ids=["mistral-7b", "qwen2-7b", "sdar-folded"])
+    (48, 8, 64, 128, None),     # laguna's full layers: 6 queries a kv head
+    (72, 8, 64, 128, 512),      # its window layers: 9, a window that binds
+], ids=["mistral-7b", "qwen2-7b", "sdar-folded", "laguna-full",
+        "laguna-window"])
 def test_paged_decode_kernel_compiles_at_served_shapes(
         one_chip, heads, kv_heads, batch, pmax, window):
     """The grid over the pages in use, a slot's several to a program (the
@@ -112,20 +115,32 @@ def test_paged_decode_kernel_compiles_at_served_shapes(
         one_chip((), jnp.int32))
 
 
-@pytest.mark.parametrize("q_width", [8, 64, 512])
-def test_ragged_kernel_compiles_at_mistral_7b_shapes(one_chip, q_width):
+@pytest.mark.parametrize("q_width,heads,window,name", [
+    (8, _HQ, _WINDOW, None), (64, _HQ, _WINDOW, None),
+    (512, _HQ, _WINDOW, None),
+    # laguna's two call sites in one program: a lane of one chunk, 48 query
+    # heads over everything and 72 behind a window that binds, each under
+    # its own name in a device trace
+    (512, 48, None, "gqa_full_ragged_attention"),
+    (512, 72, 512, "gqa_window_ragged_attention"),
+], ids=["8", "64", "512", "laguna-full", "laguna-window"])
+def test_ragged_kernel_compiles_at_mistral_7b_shapes(one_chip, q_width, heads,
+                                                     window, name):
     """Mixed q_len rows share one call; its width is the round's largest
     prefill chunk bucket (8 = a speculative span, 512 = the chunk budget)."""
     from cyberfabric_core_tpu.ops.paged_attention import ragged_paged_attention
 
     pool = one_chip(_POOL, jnp.bfloat16)
-    _compiles_with_mosaic(
+    rows = 1 if name else _B
+    text = jax.jit(
         lambda q, k, v, pt, h, n, layer: ragged_paged_attention(
             q, k, v, pt, h, n, layer, interpret=False,
-            sliding_window=_WINDOW, two_d_dots=True),
-        one_chip((_B, q_width, _HQ, _D), jnp.bfloat16), pool, pool,
-        one_chip((_B, _PMAX), jnp.int32), one_chip((_B,), jnp.int32),
-        one_chip((_B,), jnp.int32), one_chip((), jnp.int32))
+            sliding_window=window, two_d_dots=True, name=name)).lower(
+        one_chip((rows, q_width, heads, _D), jnp.bfloat16), pool, pool,
+        one_chip((rows, _PMAX), jnp.int32), one_chip((rows,), jnp.int32),
+        one_chip((rows,), jnp.int32), one_chip((), jnp.int32)
+    ).compile().as_text()
+    assert "tpu_custom_call" in text and (name or "") in text
 
 
 @pytest.mark.parametrize("rows,experts,width,inner", [
@@ -1359,4 +1374,152 @@ def test_scheduler_programs_compile_for_v5e_at_ouro():
         assert "paged_decode_attention" in text, name
         _assert_whole_array_untouched(text, pool, name)
         assert mem.alias_size_in_bytes >= 2 * int(np.prod(pool.shape)) * 2
+        assert live <= V5E_HBM_BYTES, (name, live)
+
+
+def _laguna_programs(conf_file: str, sharding):
+    """The scheduler's own two programs for a laguna configuration file's
+    served shapes, and their abstract operands: (cfg, the four pools,
+    {name: (fn, args)})."""
+    import json
+
+    from cyberfabric_core_tpu.models import decoder_module, get_config
+    from cyberfabric_core_tpu.ops.platform import compiled_kernels
+    from cyberfabric_core_tpu.parallel.sharding import abstract_params
+    from cyberfabric_core_tpu.runtime.engine import EngineConfig
+    from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
+
+    serving = json.loads(REPO.joinpath(conf_file).read_text())["serving"]
+    n, max_seq, page = (serving["max_batch"], serving["max_seq_len"],
+                        serving["page"])
+    pages = serving["pool_pages"] + 1
+    cfg = get_config(serving["model_config"])
+    eng = object.__new__(ContinuousBatchingEngine)
+    eng.config = EngineConfig(
+        model=cfg.name, max_seq_len=max_seq, max_batch=n,
+        decode_chunk=serving["decode_chunk"], quantization="int8",
+        prefix_cache_pages=pages, prefix_page_size=page,
+        prefill_budget_tokens=serving["prefill_budget_tokens"])
+    eng.model_config, eng.dtype = cfg, jnp.bfloat16
+    eng._model, eng._has_state, eng._block = decoder_module(cfg), False, 0
+    eng._step_counters = eng._model.STEP_COUNTERS
+    eng.n_slots, eng.pmax = n, max_seq // page
+    eng.spec_k, eng._spec_w = 0, 1
+    eng.mesh = eng._attn_mesh = None
+    assert eng._tw == 2 * eng.pmax
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    params = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                          abstract_params(cfg, jnp.bfloat16, "int8"))
+    lanes = cfg.num_kv_heads * cfg.head_dim
+    full = sds((cfg.kv_layers, pages, page, lanes), jnp.bfloat16)
+    window = sds((cfg.window_layers, eng._window_pages(), page, lanes),
+                 jnp.bfloat16)
+    pools = (full, full, window, window)
+    eng.pool = types.SimpleNamespace(cache_operands=lambda: pools)
+    if sharding is None:            # the CPU's: interpreted kernels
+        eng._build_programs()
+    else:
+        with compiled_kernels():
+            eng._build_programs()
+    programs = {"paged_decode_chunk": (eng._paged_decode_fn, (
+        params, *pools, *_decode_operands(sds, eng)))}
+    for width in serving["mixed_widths"][-1:]:
+        programs[f"mixed_step@{width}"] = (eng._mixed_step_fn, (
+            params, *pools, *_mixed_operands(sds, eng, width)))
+    return cfg, params, pools, programs
+
+
+def test_laguna_programs_hold_three_bodies_and_two_kernel_instances():
+    """The tiny twin of the served compile below, lowered and not compiled
+    (seconds): ``tiny-laguna-share4``'s two programs take FOUR donated pools,
+    hold each K/V kernel under both call sites' names, and cut the six
+    layers into a dense full layer, a run of three window layers and the
+    tail (a full expert layer, a window layer): scans, not six bodies."""
+    cfg, params, pools, programs = _laguna_programs(
+        "benchmark/tests/rehearsal/configs/tiny-laguna.json", None)
+    assert (cfg.kv_layers, cfg.window_layers) == (2, 4)
+    assert params["full"]["wq"]["q"].shape == (2, 64, 6 * 32)
+    assert params["window"]["wq"]["q"].shape == (4, 64, 9 * 32)
+    assert params["window"]["w_gate"]["q"].shape == (4, 64, 9)
+    assert params["layers"]["router"].dtype == jnp.float32
+    for name, (fn, args) in programs.items():
+        lowered = fn.lower(*args)
+        text = lowered.as_text(debug_info=True)     # the scopes' names
+        scopes = ["gqa_full_decode_attention", "gqa_window_decode_attention",
+                  "laguna_full_layer", "laguna_window_layer"]
+        if name != "paged_decode_chunk":
+            scopes += ["gqa_full_ragged_attention",
+                       "gqa_window_ragged_attention"]
+        for scope in scopes:
+            assert scope in text, (name, scope)
+        text = lowered.as_text()
+        assert text.count("tf.aliasing_output") == 4, name
+        # the five expert layers' grouped matmuls sit in the three expert
+        # bodies (gate, up, down each), not once a layer (15)
+        assert text.count("call @grouped_matmul") == 9, name
+
+
+@pytest.mark.slow
+def test_scheduler_programs_compile_for_v5e_at_laguna():
+    """The scheduler's own ``paged_decode_chunk`` and ``mixed_step`` at 512
+    for laguna-s-2.1-share8-12l int8 at the served shapes of
+    ``benchmark/configs/laguna-s-2.1-int8.json`` (64 slots of 8192, 8193
+    pages in the THREE layers of the full K/V page group, 739 in the NINE of
+    the window group, a page table of 2 x 128 slots a row, 32 held experts
+    in 11 expert layers, 8 steps a chunk), on one described chip: each holds
+    the K/V kernels of both call sites by their own names and the
+    ``grouped_matmul`` Mosaic call, donates all four pools and copies none,
+    fits the 15.75 GiB the compiler budgets. A compile, not a chip run
+    (``-s`` prints the sizes)."""
+    import json
+    import time
+
+    from jax.sharding import SingleDeviceSharding
+
+    from cyberfabric_core_tpu.ops.platform import compiled_kernels
+    from cyberfabric_core_tpu.parallel.feasibility import V5E_HBM_BYTES
+
+    topo = _topo_or_skip()
+    conf_file = "benchmark/configs/laguna-s-2.1-int8.json"
+    serving = json.loads(REPO.joinpath(conf_file).read_text())["serving"]
+    cfg, params, pools, programs = _laguna_programs(
+        conf_file, SingleDeviceSharding(topo.devices[0]))
+    assert (cfg.kv_layers, cfg.window_layers, cfg.moe_layers) == (3, 9, 11)
+    assert pools[0].shape == (3, 8193, 64, 1024)
+    assert pools[2].shape == (9, serving["window_pool_pages"] + 1, 64, 1024)
+    assert params["full"]["wq"]["q"].shape == (3, 3072, 48 * 128)
+    assert params["window"]["wo"]["q"].shape == (9, 72 * 128, 3072)
+    assert params["layers"]["moe_gate"]["q"].shape == (11, 32, 3072, 1024)
+    assert params["lm_head"]["q"].shape == (3072, 12544)
+    for name, (fn, args) in programs.items():
+        started = time.monotonic()
+        with compiled_kernels():
+            compiled = fn.lower(*args).compile()
+        took = time.monotonic() - started
+        mem = compiled.memory_analysis()
+        live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+        print(f"{name}: argument {mem.argument_size_in_bytes / 1e9:.2f} "
+              f"output {mem.output_size_in_bytes / 1e9:.2f} aliased "
+              f"{mem.alias_size_in_bytes / 1e9:.2f} temp "
+              f"{mem.temp_size_in_bytes / 1e9:.2f} GB, compiled in "
+              f"{took:.0f} s")
+        text = compiled.as_text()
+        if os.environ.get("AOT_DUMP_DIR"):
+            Path(os.environ["AOT_DUMP_DIR"],
+                 f"laguna-{name}.hlo.txt").write_text(text)
+        kernels = ["grouped_matmul", "gqa_full_decode_attention",
+                   "gqa_window_decode_attention"]
+        if name != "paged_decode_chunk":
+            kernels += ["gqa_full_ragged_attention",
+                        "gqa_window_ragged_attention"]
+        for kernel in kernels:
+            assert kernel in text, (name, kernel)
+        for pool in (pools[0], pools[2]):
+            _assert_whole_array_untouched(text, pool, name)
+        assert mem.alias_size_in_bytes >= sum(
+            int(np.prod(p.shape)) * 2 for p in pools), name
         assert live <= V5E_HBM_BYTES, (name, live)

@@ -326,7 +326,7 @@ def test_the_pool_refuses_what_it_cannot_do_with_two_groups():
 
 def test_a_per_layer_window_on_kv_pages_is_refused_by_the_configuration():
     import dataclasses
-    with pytest.raises(ValueError, match="only the latent kernels do"):
+    with pytest.raises(ValueError, match="over two page groups"):
         dataclasses.replace(get_config("mistral-7b"), sliding_window_period=4)
     with pytest.raises(ValueError, match="needs a sliding_window"):
         dataclasses.replace(get_config("tiny-kimi"), sliding_window_period=4)
