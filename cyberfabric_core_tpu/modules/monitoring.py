@@ -453,14 +453,15 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                  "each of the latent kernel's one program a row (a key block "
                  "of ops/mla_attention.py: trip_pages pages, one score dot)"),
                 ("llm_ragged_pages_walked_total",
-                 "Latent pages the ragged kernel's programs copied for the "
-                 "prompt chunks of mixed steps: the pages every block of "
-                 "queries sees (ops/mla_attention.py: ragged_span), summed "
-                 "over q-blocks, steps and layers, a window layer by its "
+                 "Pages the ragged kernel's programs copied for the prompt "
+                 "chunks of mixed steps (latent pages; K/V pages counted "
+                 "once for the pair): the pages every block of queries "
+                 "sees (ops/page_walk.py: ragged_span), summed over "
+                 "q-blocks, steps and layers, a window layer by its "
                  "window's span"),
                 ("llm_ragged_trips_total",
                  "Key blocks they attended over those pages in (a trip: up "
-                 "to ragged_trip_pages pages, one score dot): pages a trip "
+                 "to ragged_trip_pages pages, one score dot a kv head): pages a trip "
                  "near that number say the blocks run full"),
                 ("llm_attn_window_pages_walked_total",
                  "Pages the decode kernel's grid walked in the WINDOW layers "

@@ -1,4 +1,5 @@
-"""Pallas ragged paged-attention kernel for decode (T=1) over a paged KV pool.
+"""Pallas paged-attention kernels over a paged KV pool: decode (T=1), and
+the ragged mixed-batch kernel of a prompt's chunks.
 
 The decode hot loop reads each sequence's KV history through a page table
 instead of a dense per-slot cache. The grid is one program for every GROUP
@@ -26,9 +27,15 @@ of consecutive pages that hold tokens a slot's query reads, and no other:
    with MXU tiles that 128 and more keys fill; the accumulators are
    initialised at a slot's first group and finalised at its last.
 
-(The ragged kernel below still launches a program for every slot of the
-table and skips the ones past a row's span, their DMA elided by an index map
-clamped to the last page in use: ROADMAP S18.)
+The ragged kernel (``ragged_paged_attention``: the lanes of a mixed step)
+has no grid over the table at all since PR 55 (ROADMAP S18, closed): a
+program is a (lane, block of 64 queries) and walks the pages its queries see
+itself, through ``ops/page_walk.py``'s ``_Walk`` (the latent kernels' walk,
+here over K and V pages that share a page id, a ring each): the spans are
+worked out once a call and ride in as scalar prefetch, a trip of up to 16
+pages is ONE key block with one score dot, one online-softmax update and one
+value dot a kv head over its ``G x 64`` query rows, and no program and no
+copy exists for a slot of the table outside a span.
 
 Why this beats the dense path (VERDICT r1 weak #3/#6): attention reads scale
 with the *tokens actually present* (sum of per-slot lengths), not
@@ -39,9 +46,10 @@ table, exactly the PAPERS.md "ragged paged attention for TPU" direction.
 
 Both kernels take the STACKED pool as the pool keeps it — ``[L, N, page,
 Hkv*D]``, head-major on the merged minor axis (runtime/paged.py) — and the
-layer as one more scalar-prefetch operand: a block is ``(1, 1, page, Hkv*D)``
-at ``(layer, page_table[b, jj], 0, 0)``, so nothing pool-sized is sliced,
-reshaped or copied in front of the call. (On a tiled TPU layout a merge of
+layer as one more scalar-prefetch operand: a block (the decode kernel's) or
+a DMA (the ragged kernel's) is a page ``(page, Hkv*D)`` at ``(layer,
+page_table[b, jj])``, so nothing pool-sized is sliced, reshaped or copied
+in front of the call. (On a tiled TPU layout a merge of
 the two minor dimensions is a physical copy, and a Mosaic call takes whole
 buffers, so ``pool[layer]`` materialises: PERF.md section 6, PR 25.)
 
@@ -57,62 +65,33 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_NEG_INF = -1e30
-_LANES = 128
-
-
-def _banded_scores_2d(bands, k_of):
-    """GQA scores via unrolled 2D dots — no rank-3 transpose, no batched
-    dot_general (Mosaic's dot supports only 2D operands). ``bands`` is a
-    list of (q_band [rows, D], kv_head) in head-major row order; ``k_of``
-    maps a kv head to its [page, D] key slice — a REF-level lane slice of
-    the minor-merged [1, 1, page, Hkv*D] block (the pool is stored merged):
-    value-level bf16 lane slices at non-zero tile
-    offsets are an unlowerable relayout, ref-level sliced LOADS are not.
-    The per-band results concatenate in f32 (bf16 sublane concats are an
-    unsupported multi-row shift); each output element is the same
-    contraction the batched dot computes, so the results are bitwise
-    identical (pinned by
-    tests/test_ragged_attention.py::test_two_d_dot_rewrite_bitwise)."""
-    outs = [jax.lax.dot_general(
-        qb, k_of(kv), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) for qb, kv in bands]
-    return jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
+from . import page_walk
+from .page_walk import (_LANES, _NEG_INF, _VMEM_LIMIT, _Walk,
+                        _online_softmax_step, _span_first, _walk_scratch,
+                        page_span, ragged_span)
 
 
 def _banded_weighted_v_2d(p, row_bands, v_of):
-    """The p@v half of the 2D rewrite: per-band [rows, page] x [page, D]
-    2D dots against ref-level lane slices of the minor-merged value block,
-    concatenated (f32) back to head-major rows. ``row_bands`` lists
-    (row_start, rows, kv_head); ``p`` is f32, so its sublane band slices
-    lower (32-bit shifts are implemented, 16-bit are not)."""
+    """The decode kernel's p@v as unrolled 2D dots — no rank-3 transpose, no
+    batched dot_general (Mosaic's dot supports only 2D operands): per-band
+    [rows, page] x [page, D] dots against ``v_of(kv)``, a kv head's [page,
+    D] value slice — a REF-level lane slice of the minor-merged [1, 1, page,
+    Hkv*D] block (the pool is stored merged): value-level bf16 lane slices
+    at non-zero tile offsets are an unlowerable relayout, ref-level sliced
+    LOADS are not. The per-band results concatenate in f32 (bf16 sublane
+    concats are an unsupported multi-row shift) back to head-major rows;
+    each output element is the same contraction the batched dot computes.
+    ``row_bands`` lists (row_start, rows, kv_head); ``p`` is f32, so its
+    sublane band slices lower (32-bit shifts are implemented, 16-bit are
+    not)."""
     outs = [jax.lax.dot_general(
         p[s:s + n], v_of(kv).astype(p.dtype), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32) for s, n, kv in row_bands]
     return jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
-
-
-def page_span(length, page_size: int, n_pages: int,
-              sliding_window: int | None):
-    """(first, last) logical page a decode query at position ``length - 1``
-    reads, of a table of ``n_pages`` slots: up to the page of its own token,
-    from the page that holds the window's first key. An empty slot has the
-    span (0, 0). Scalars in the kernel, ``[B]`` arrays in the work list,
-    NumPy arrays where the host counts what the grid walked."""
-    last = ((length - 1) // page_size).clip(0, n_pages - 1)
-    return _span_first(length, page_size, last, sliding_window), last
-
-
-def _span_first(length, page_size: int, last, sliding_window: int | None):
-    """The first page of the span that ends at page ``last``."""
-    if sliding_window is None:
-        return last * 0
-    # keys <= length - 1 - window are out, so page j is in while
-    # (j + 1) * page_size > length - window
-    return ((length - sliding_window) // page_size).clip(0, last)
 
 
 def _paged_kernel(row_ref, page_ref, phys_ref, len_ref, last_ref, layer_ref,
@@ -161,7 +140,7 @@ def _paged_kernel(row_ref, page_ref, phys_ref, len_ref, last_ref, layer_ref,
 
     def rows_of(refs, lanes):
         """The group's pages as one block of ``keys`` rows (a lane slice of
-        each, taken at the ref: see :func:`_banded_scores_2d`; a page of 16
+        each, taken at the ref: see :func:`_banded_weighted_v_2d`; a page of 16
         rows or a multiple is whole 16-bit sublane tiles, so the join is no
         multi-row shift)."""
         parts = [r[0, 0, :, lanes] for r in refs]
@@ -397,209 +376,267 @@ def paged_decode_attention(
       *([k_pool] * group), *([v_pool] * group))
 
 
-def _ragged_kernel(pt_ref, hist_ref, qlen_ref, layer_ref, q_ref, k_ref, v_ref,
-                   o_ref,
-                   acc_ref, m_ref, l_ref, *, page_size: int, q_block: int,
-                   sliding_window: int | None = None,
-                   two_d_dots: bool = False,
-                   head_dim: int | None = None, block: int = 1,
-                   scale: float | None = None):
-    """One (slot, q-block, page) program of the ragged mixed-batch kernel.
+#: accumulator bytes a q-block may hold (acc, m and l: three rows of 128
+#: float32 a query row): 64 queries of 85 heads. The rings, the q and o
+#: blocks and one trip's scores of a kv head's rows take up to twice as much
+#: again, of ``_VMEM_LIMIT``
+_Q_BLOCK_BYTES = 8 << 20
+
+
+def ragged_q_block(width: int, heads: int) -> int:
+    """Queries a program of the ragged kernel takes, from shapes: 64 (the
+    rows one kv head's score dot has are this times its group: 64 is 10-18%
+    under 32 alone at a chunk of 512, 128 another 3-7% for twice the
+    accumulators: PERF.md, PR 55), the lane's ``width`` where that is less,
+    and half as many for as long as ``heads`` rows a query of float32
+    accumulator, running max and running sum pass ``_Q_BLOCK_BYTES``. A
+    width that is not whole q-blocks is padded by the wrapper."""
+    q_block = min(64, width)
+    while q_block > 8 and heads * q_block * 3 * _LANES * 4 > _Q_BLOCK_BYTES:
+        q_block //= 2
+    return q_block
+
+
+#: keys a trip of the ragged kernel takes at most. A trip's fixed cost is
+#: the flash state's arithmetic, 15 vector operations a row of the
+#: accumulator, the running max and the running sum a kv head: about what
+#: 256 keys of scores cost, and 5 us a trip at laguna's 72 heads. 1 024 keys
+#: (16 pages of 64) is 2.0-2.6 times under 256 alone behind 2-4 k of history
+#: and even with 2 048; a kv head's G x Qb rows are 64-1 024 a score dot
+#: where the latent kernel's are 2 048 (PERF.md, PR 55, has the probe)
+RAGGED_TRIP_KEYS = 1024
+
+
+def ragged_trip_pages(page_size: int, sliding_window: int | None,
+                      q_block: int) -> int:
+    """Pages a trip of the ragged kernel takes (``page_walk``'s rule at
+    ``RAGGED_TRIP_KEYS``): 16 of 64 tokens, and no more than a q-block's
+    windows span (laguna's window of 512: 10), so that a window layer's
+    program is one trip."""
+    return page_walk.ragged_trip_pages(page_size, sliding_window, q_block,
+                                       RAGGED_TRIP_KEYS)
+
+
+def ragged_block_sizes(trip: int) -> tuple[int, ...]:
+    """The key blocks a trip of the ragged kernel is attended over as, in
+    pages: the trip, and 4 pages (256 keys) for what a short lane, a
+    q-block with little behind it or a span's last trip holds. Two and not
+    the latent kernels' four (2 / 4 / 8 / 16): each size is two traced bodies
+    of the attend in every program the kernel sits in, and with four the
+    16-row cells paid for them at every start (qwen2 ``jax_trace_lower_s``
+    11.7 -> 13.4 s on a warm traced pair; PERF.md, PR 55)."""
+    return tuple(sorted({min(4, trip), trip}))
+
+
+def ragged_walk(hist, q_lens, width: int, page_size: int, n_pages: int,
+                sliding_window: int | None, heads: int, block: int = 1,
+                trip: int | None = None) -> tuple[int, int]:
+    """(pages, trips) a call of :func:`ragged_paged_attention` over lanes of
+    ``width`` queries of ``heads`` query heads walks: the copies it starts
+    in EACH pool and the key blocks it attends over, by the kernel's own
+    spans, on the host (NumPy)."""
+    q_block = ragged_q_block(width, heads)
+    return page_walk.ragged_walk(
+        hist, q_lens, width, page_size, n_pages, sliding_window, q_block,
+        trip or ragged_trip_pages(page_size, sliding_window, q_block), block)
+
+
+def _ragged_kernel(pt_ref, first_ref, last_ref, hist_ref, qlen_ref, layer_ref,
+                   q_ref, k_pool_ref, v_pool_ref, o_ref, k_ring, v_ring, sem,
+                   walk_ref, acc_ref, m_ref, l_ref, *, page_size: int,
+                   q_block: int, q_blocks: int, trip: int,
+                   sliding_window: int | None, two_d_dots: bool, block: int,
+                   scale: float | None):
+    """One (lane, q-block) of the ragged mixed-batch kernel: the program
+    walks the pages its queries see itself (``ops/page_walk.py``:
+    :class:`_Walk`, an item a (lane, q-block), lanes in order; K and V of a
+    page are copied together, each into its own ring).
 
     Refs:
-      pt_ref:   [B, Pmax] int32 SMEM — page table
-      hist_ref: [B] int32 SMEM — kv tokens BEFORE this row's query span
-      qlen_ref: [B] int32 SMEM — query-span length (0 = idle row)
-      layer_ref: [1] int32 SMEM — read by the index maps only
-      q_ref:    [1, Qb, Hq, D] VMEM; k_ref/v_ref: [1, 1, page, Hkv*D] VMEM
-      o_ref:    [1, Qb, Hq, D] VMEM
-      acc_ref:  [Hq*Qb, D] f32; m_ref/l_ref: [Hq*Qb, LANES] f32
+      pt_ref:   [R, Pmax] int32 SMEM — the lanes' rows of the page table
+      first_ref, last_ref: [R * q_blocks] int32 SMEM — an item's span
+        (:func:`ragged_span`; one that reads nothing has ``last < first``)
+      hist_ref: [R] int32 SMEM — kv tokens BEFORE this lane's query span
+      qlen_ref: [R] int32 SMEM — query-span length (0 = idle lane)
+      layer_ref: [1] int32 SMEM
+      q_ref:    [1, 1, Hkv, G*Qb, D] VMEM — a kv head's ``G x Qb`` query rows
+        one slab, row ``g * Qb + qi`` (the wrapper's layout)
+      k_pool_ref, v_pool_ref: the whole stacked pools, where they live
+      o_ref:    [1, 1, Hkv, G*Qb, D] VMEM
+      k_ring, v_ring, sem, walk_ref, acc_ref, m_ref, l_ref:
+        :func:`_walk_scratch` of two pools at ``Hq * Qb`` rows
 
-    Each query row qi of the block sits at absolute position hist + q0 + qi
-    and attends causally over its row's paged KV chain (history AND the
-    span's earlier tokens — prefill-chunk self attention). Rows are flat
-    r = h*Qb + qi so the GQA dot keeps the decode kernel's head grouping.
+    Query ``qi`` of the block sits at absolute position hist + q0 + qi and
+    attends causally over its lane's paged KV chain (history AND the span's
+    earlier tokens: prefill-chunk self attention), the last
+    ``sliding_window`` keys of it. Accumulator rows are flat ``r = h*Qb +
+    qi`` (h = kv*G + g), the decode kernel's head grouping.
 
-    ``two_d_dots`` (the Mosaic-lowerable form): q/o blocks arrive
-    MINOR-MERGED too ([1, Qb, Hq*D]; ``head_dim`` un-merges them) and the
-    head-major [Qb,Hq,D]↔[Hq,Qb,D] shuffles plus the batched
-    GQA dots — the constructs Mosaic cannot lower — become unrolled lane
-    slices, sublane/lane concats and per-kv-head 2D dots. Bitwise-identical
-    to the batched interpret form (golden-pinned).
+    A trip's pages are ONE key block: one score dot, one online-softmax
+    update and one value dot a kv head, over its slab of the accumulators,
+    under one mask by query position. ``two_d_dots`` (the Mosaic-lowerable
+    form) takes a head's keys and values as REF-level lane slices of the
+    minor-merged ring block (:func:`_banded_weighted_v_2d` says why) and runs
+    the heads one after another; the batched form is one dot over every kv
+    head, which Mosaic cannot lower and interpret mode keeps for tier-1
+    wall-clock. Bitwise identical.
 
     ``block`` (a power of two; 1 = causal): the mask of a model that
     generates by diffusion over blocks — causal between blocks of that many
     absolute positions and full inside one, so the bound of the query at
     ``pos`` is the last position of its block, ``pos | (block - 1)``.
     """
-    b = pl.program_id(0)
-    qb = pl.program_id(1)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
-    hist = hist_ref[b]
-    qlen = qlen_ref[b]
+    b, qb = pl.program_id(0), pl.program_id(1)
+    Hkv, GQ, D = q_ref.shape[2:]
+    item = b * q_blocks + qb
+    walk = _Walk(pt_ref, layer_ref, (k_pool_ref, v_pool_ref),
+                 (k_ring, v_ring), sem, walk_ref,
+                 n_items=first_ref.shape[0], trip=trip,
+                 sizes=ragged_block_sizes(trip), page_size=page_size,
+                 idle=lambda item: last_ref[item] < first_ref[item],
+                 span=lambda item: (first_ref[item], last_ref[item]),
+                 row=lambda item: lax.div(item, q_blocks), unroll=False)
+
+    @pl.when(item == 0)
+    def _open():
+        walk.open()
+
+    hist, qlen = hist_ref[b], qlen_ref[b]
     q0 = qb * q_block
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    @pl.when(q0 >= qlen)
+    def _idle():
+        o_ref[...] = jnp.zeros_like(o_ref)
 
-    k_start = j * page_size
-    # last absolute query position this block serves: keys past it are
-    # causally invisible to every row of the block, so the page is skipped
-    q_hi = hist + jnp.minimum(qlen, q0 + q_block) - 1
-    if block > 1:
-        q_hi = q_hi | (block - 1)
-    relevant = jnp.logical_and(q0 < qlen, k_start <= q_hi)
-    if sliding_window is not None:
-        # earliest window start across the block's queries
-        relevant = jnp.logical_and(
-            relevant, k_start + page_size - 1 > hist + q0 - sliding_window)
-
-    @pl.when(relevant)
-    def _compute():
-        if two_d_dots:
-            D = head_dim
-            Qb, Hq = q_ref.shape[1], q_ref.shape[2] // D
-        else:
-            Qb, Hq, D = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
-        R = Hq * Qb
-
-        # head-major rows: r = h*Qb + qi (h = kv*G + g), so the GQA grouping
-        # matches the decode kernel's reshape(Hkv, G, D) exactly
-        if two_d_dots:
-            G = Hq // (k_ref.shape[3] // D)
-            # the [Qb,Hq,D]→head-major shuffle as unrolled per-head
-            # REF-level lane slices of the minor-merged [1, Qb, Hq*D]
-            # block feeding per-head 2D dots — neither the rank-3
-            # transpose nor a bf16 relayout (both Mosaic-unlowerable) ever
-            # appears; only the f32 score tiles concatenate
-            scores = _banded_scores_2d(
-                [(q_ref[0, :, h * D:(h + 1) * D], h // G)
-                 for h in range(Hq)],
-                lambda kv: k_ref[0, 0, :, kv * D:(kv + 1) * D],
-            )                                    # [R, page], rows h*Qb+qi
-        else:
-            q = q_ref[0]      # [Qb, Hq, D]
-            Hkv = k_ref.shape[3] // D
-            k = k_ref[0, 0].reshape(page_size, Hkv, D)
-            G = Hq // Hkv
-            qt = jnp.transpose(q, (1, 0, 2)).reshape(Hkv, G * Qb, D)
-            kt = jnp.transpose(k, (1, 2, 0))    # [Hkv, D, page]
-            scores = jax.lax.dot_general(
-                qt, kt, (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)  # [Hkv, G*Qb, page]
-            scores = scores.reshape(R, page_size)
-        scores = scores * (1.0 / (D ** 0.5) if scale is None else scale)
-
-        qi = jax.lax.broadcasted_iota(jnp.int32, (R, page_size), 0) % Qb
-        q_idx = q0 + qi                          # index within the span
-        q_abs = hist + q_idx                     # absolute position
-        k_pos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (R, page_size), 1)
-        # causal within the row's own history: k <= this query's position
-        # (subsumes k < hist + qlen); padding query rows mask out entirely
+    def visible(k_pos):
+        """The keys each row of a block of key positions [rows, keys] sees
+        (rows ``g * Qb + qi``, of one kv head or of all): causal within the
+        lane's own history (which subsumes k < hist + qlen), inside the
+        window; a padding query row sees nothing."""
+        q_idx = q0 + jax.lax.broadcasted_iota(
+            jnp.int32, k_pos.shape, 0) % q_block
+        q_abs = hist + q_idx
         bound = q_abs | (block - 1) if block > 1 else q_abs
         mask = (q_idx < qlen) & (k_pos <= bound)
         if sliding_window is not None:
             mask = mask & (k_pos > q_abs - sliding_window)
-        scores = jnp.where(mask, scores, _NEG_INF)
+        return mask
 
-        m_prev = m_ref[...]
-        m_blk = jnp.max(scores, axis=1, keepdims=True)      # [R, 1]
-        m_new = jnp.maximum(m_prev, jax.lax.broadcast_in_dim(
-            m_blk, m_prev.shape, (0, 1)))
-        m_ref[...] = m_new
-        # a row with no visible key yet still sits at the _NEG_INF floor;
-        # the raw exp could poison acc/l for the rest of the walk — such
-        # rows carry no mass, so their correction is 0 (this keeps padding
-        # query rows inside a partially-valid block at exactly 0.0 in the
-        # output, the documented contract, instead of NaN). The floor
-        # compare replaces jnp.isfinite: same verdict on every reachable
-        # value (masked scores are exactly _NEG_INF, never -inf), and
-        # is_finite has no Pallas TPU lowering — the compare is what lets
-        # the spec-verify program compile under Mosaic.
-        correction = jnp.where(m_new > _NEG_INF * 0.5,
-                               jnp.exp(m_prev - m_new), 0.0)  # [R, LANES]
-        p = jnp.exp(scores - m_new[:, :1])                  # [R, page]
-        p = jnp.where(mask, p, 0.0)
-        l_blk = jnp.sum(p, axis=1, keepdims=True)
-        l_ref[...] = l_ref[...] * correction + jax.lax.broadcast_in_dim(
-            l_blk, m_prev.shape, (0, 1))
-        if two_d_dots:
-            pv = _banded_weighted_v_2d(
-                p, [(h * Qb, Qb, h // G) for h in range(Hq)],
-                lambda kv: v_ref[0, 0, :, kv * D:(kv + 1) * D])
+    def attend(slot, k_start, *, pages, first):
+        keys = pl.ds(0, pages * page_size)
+        n_keys = pages * page_size
+        sm_scale = 1.0 / (D ** 0.5) if scale is None else scale
+        rows = GQ if two_d_dots else Hkv * GQ
+        mask = visible(k_start + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, n_keys), 1))
+        if not two_d_dots:
+            k = k_ring[slot, keys].reshape(n_keys, Hkv, D)
+            scores = jax.lax.dot_general(
+                q_ref[0, 0], jnp.transpose(k, (1, 2, 0)),
+                (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)     # [Hkv, GQ, keys]
+
+            def weigh(p):
+                v = v_ring[slot, keys].reshape(n_keys, Hkv, D)
+                pg = p.reshape(Hkv, GQ, n_keys)
+                return jax.lax.dot_general(
+                    pg, jnp.transpose(v, (1, 0, 2)).astype(pg.dtype),
+                    (((2,), (1,)), ((0,), (0,))),
+                    preferred_element_type=jnp.float32).reshape(rows, D)
+
+            _online_softmax_step(scores.reshape(rows, n_keys) * sm_scale,
+                                 mask, weigh, acc_ref, m_ref, l_ref, first)
+            return
+
+        def one_head(kv, _):
+            if isinstance(kv, int):
+                head, slab = pl.ds(kv * D, D), pl.ds(kv * GQ, GQ)
+            else:
+                head = pl.ds(pl.multiple_of(lax.mul(kv, D), _LANES), D)
+                slab = pl.ds(pl.multiple_of(lax.mul(kv, GQ), 8), GQ)
+            scores = jax.lax.dot_general(
+                q_ref[0, 0, kv], k_ring[slot, keys, head],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)     # [GQ, keys]
+            _online_softmax_step(
+                scores * sm_scale, mask,
+                lambda p: jax.lax.dot_general(
+                    p, v_ring[slot, keys, head].astype(p.dtype),
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32),
+                acc_ref.at[slab], m_ref.at[slab], l_ref.at[slab], first)
+
+        if D % _LANES:
+            # a head's lanes at an offset that is no whole lane tile: only a
+            # static slice lowers (phi-3's head of 96)
+            for kv in range(Hkv):
+                one_head(kv, None)
         else:
-            v = v_ref[0, 0].reshape(page_size, Hkv, D)
-            pg = p.reshape(Hkv, G * Qb, page_size)
-            vt = jnp.transpose(v, (1, 0, 2))                # [Hkv, page, D]
-            pv = jax.lax.dot_general(
-                pg, vt.astype(pg.dtype), (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32).reshape(R, D)
-        acc_ref[...] = acc_ref[...] * correction[:, :1] + pv
+            # a loop: the body is a kv head's G x Qb rows of dots, long
+            # enough not to feel it, and traced and lowered once a kernel
+            # where unrolled heads cost 8-16 bodies in every program the
+            # kernel sits in (set-up: PERF.md, PRs 53 and 55)
+            lax.fori_loop(0, Hkv, one_head, None)
 
-    @pl.when(j == nj - 1)
-    def _finalize():
-        Qb = q_ref.shape[1]
+    @pl.when(q0 < qlen)
+    def _busy():
+        walk.run(item, attend)
         denom = jnp.maximum(l_ref[...][:, :1], 1e-30)
-        out = (acc_ref[...] / denom)                        # [Hq*Qb, D]
-        if two_d_dots:
-            # head-major rows → the minor-merged [Qb, Hq*D] output block
-            # via the inverse shuffle: each head's [Qb, D] band
-            # concatenates along LANES — a single full-block store, no
-            # rank-3 transpose, no strided per-head writes (the wrapper
-            # un-merges outside the kernel)
-            D = head_dim
-            Hq = q_ref.shape[2] // D
-            flat = jnp.concatenate(
-                [out[h * Qb:(h + 1) * Qb] for h in range(Hq)], axis=1) \
-                if Hq > 1 else out                          # [Qb, Hq*D]
-            o_ref[0] = flat.astype(o_ref.dtype)
-        else:
-            Hq, D = q_ref.shape[2], q_ref.shape[3]
-            out = out.reshape(Hq, Qb, D)
-            o_ref[0] = jnp.transpose(out, (1, 0, 2)).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / denom).reshape(
+            Hkv, GQ, D).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("q_block", "interpret",
-                                             "sliding_window", "two_d_dots",
-                                             "block", "scale", "name"))
+@functools.partial(jax.jit, static_argnames=(
+    "interpret", "sliding_window", "two_d_dots", "block", "scale", "name",
+    "trip", "q_block"))
 def ragged_paged_attention(
-    q: jnp.ndarray,           # [B, Qmax, Hq, D] — per-row query span, padded
+    q: jnp.ndarray,           # [R, Qc, Hq, D] — per-lane query span, padded
     k_pool: jnp.ndarray,      # [L, N, page, Hkv*D] — the stacked page pool
     v_pool: jnp.ndarray,
-    page_table: jnp.ndarray,  # [B, Pmax] int32 physical page ids
-    hist: jnp.ndarray,        # [B] int32 kv tokens BEFORE the span
-    q_lens: jnp.ndarray,      # [B] int32 span length (0 = idle row)
+    page_table: jnp.ndarray,  # [R, Pmax] int32 physical page ids
+    hist: jnp.ndarray,        # [R] int32 kv tokens BEFORE the span
+    q_lens: jnp.ndarray,      # [R] int32 span length (0 = idle lane)
     layer: jnp.ndarray | int = 0,  # scalar int32 — which layer's pages
-    q_block: int = 8,
-    interpret: bool = False,
+    *,
+    interpret: bool | pltpu.InterpretParams = False,
     sliding_window: int | None = None,
     two_d_dots: bool | None = None,
     block: int = 1,
     scale: float | None = None,
     name: str | None = None,      # the call site's, in a device trace
+    trip: int | None = None,      # a test's or a probe's pages a trip
+    q_block: int | None = None,   # ... and its queries a program
 ) -> jnp.ndarray:
-    """Ragged mixed-batch paged attention: one dispatch where each batch row
+    """Ragged mixed-batch paged attention: one dispatch where each lane
     attends a variable-length query span over its paged KV chain with causal
     masking relative to its own history. Decode rows (q_len=1) and
-    chunked-prefill rows (q_len=chunk) share the batch; idle rows (q_len=0)
-    cost one scratch-page read. Returns [B, Qmax, Hq, D]; positions past a
-    row's q_len are zeros (their softmax mass is empty).
+    chunked-prefill lanes (q_len=chunk) share the batch; an idle lane
+    (q_len=0) costs nothing. Returns [R, Qc, Hq, D]; positions past a lane's
+    q_len are zeros (their softmax mass is empty).
 
     The span's own KV must already be present in the pool (the caller
     scatters the chunk's k/v before attending — within-span causality then
-    reads the earlier chunk tokens through the page chain). The pool operands
-    reach the ``pallas_call`` as they are passed; ``layer`` and the page are
-    picked by the blocks' index map.
+    reads the earlier chunk tokens through the page chain).
 
-    ``two_d_dots`` (default: on exactly when compiling for real) replaces
-    the head-major [Qb,Hq,D]↔[Hq,Qb,D] shuffles and the batched GQA dots —
-    the two constructs Mosaic cannot lower — with unrolled 2D slices/dots;
-    bitwise-identical to the batched interpret form (golden-pinned).
+    One program a (lane, block of :func:`ragged_q_block` queries), in
+    order; the pools stay where they live and the programs copy the pages of
+    a q-block's span themselves, K and V of a page together,
+    :func:`ragged_trip_pages` at a time (16 of 64 tokens: a key block of
+    1 024 keys a score dot). The spans (:func:`ragged_span`: under the window
+    and the block mask) are worked out here, once a call, and ride in as
+    scalar-prefetch operands; no program and no copy exists for a slot of
+    the table outside a span. ``q`` goes in with a kv head's ``G x q_block``
+    query rows one slab a q-block and the output comes back the same way
+    (request-sized transposes, here), a width that is not whole q-blocks
+    padded. At ``trip=1`` and ``q_block=8`` the sums run in the order of the
+    grid this kernel had up to PR 54 (a program a slot of the table, a page
+    a program) and give its bytes; at the shipped trip it is another order
+    of the same sums.
+
+    ``two_d_dots`` (default: on exactly when compiling for real) runs the
+    kv heads' 2D dots one after another over ref-level lane slices, where
+    the batched form is one dot over every kv head, which Mosaic cannot
+    lower; bitwise-identical (golden-pinned).
 
     ``block`` > 1 is the block mask (see the kernel): a query sees the keys
     up to the end of its own block of ``block`` absolute positions, which
@@ -611,68 +648,55 @@ def ragged_paged_attention(
         two_d_dots = not interpret
     if block & (block - 1):
         raise ValueError(f"block {block} must be a power of two")
-    B, Qmax, Hq, D = q.shape
+    R, Qc, Hq, D = q.shape
     _, _, page_size, HD = k_pool.shape
-    Pmax = page_table.shape[1]
-    if Qmax % q_block:
-        raise ValueError(f"Qmax {Qmax} must be a multiple of q_block {q_block}")
+    Hkv = HD // D
+    G = Hq // Hkv
+    if Qc % 8:
+        raise ValueError(f"a lane of {Qc} queries is not a multiple of 8 "
+                         "(whole sublane tiles)")
+    q_block = q_block or ragged_q_block(Qc, Hq)
+    q_blocks = -(-Qc // q_block)
+    width = q_blocks * q_block
+    trip = trip or ragged_trip_pages(page_size, sliding_window, q_block)
+    hist, q_lens = hist.astype(jnp.int32), q_lens.astype(jnp.int32)
+    first, last = ragged_span(hist, q_lens, width, page_size,
+                              page_table.shape[1], sliding_window, q_block,
+                              block)
+    if width != Qc:
+        q = jnp.pad(q, ((0, 0), (0, width - Qc), (0, 0), (0, 0)))
+    # [R, Qc, (Hkv, G), D] -> [R, q-block, Hkv, (G, Qb), D]
+    slabs = (R, q_blocks, Hkv, G * q_block, D)
+    q = q.reshape(R, q_blocks, q_block, Hkv, G, D).transpose(
+        0, 1, 3, 4, 2, 5).reshape(slabs)
 
-    def _page_index(b, qb, j, pt_ref, hist_ref, qlen_ref, layer_ref):
-        # clamp j into the pages this (row, q-block) can actually see so
-        # skipped programs revisit the resident page and the DMA is elided
-        hist_b = hist_ref[b]
-        qlen = qlen_ref[b]
-        q_hi = hist_b + jnp.minimum(qlen, (qb + 1) * q_block) - 1
-        if block > 1:
-            q_hi = q_hi | (block - 1)
-        last = jnp.maximum(q_hi // page_size, 0)
-        jj = jnp.minimum(j, last)
-        if sliding_window is not None:
-            lo = jnp.maximum(
-                (hist_b + qb * q_block - sliding_window) // page_size, 0)
-            jj = jnp.maximum(jj, jnp.minimum(lo, last))
-        return (layer_ref[0], pt_ref[b, jj], 0, 0)
+    def at_block(b, qb, *_):
+        return (b, qb, 0, 0, 0)
 
-    kv_spec = pl.BlockSpec((1, 1, page_size, HD), _page_index)
-    if two_d_dots:
-        # q/o travel MINOR-MERGED like the pool (a request-sized reshape):
-        # in-kernel merges of loaded blocks are unsupported vector
-        # shape_casts under Mosaic, lane slices of 2D blocks are not
-        q_in = q.reshape(B, Qmax, Hq * D)
-        q_spec = pl.BlockSpec((1, q_block, Hq * D),
-                              lambda b, qb, j, pt, hh, ql, ly: (b, qb, 0))
-    else:
-        q_in = q
-        q_spec = pl.BlockSpec((1, q_block, Hq, D),
-                              lambda b, qb, j, pt, hh, ql, ly: (b, qb, 0, 0))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(B, Qmax // q_block, Pmax),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=q_spec,
-        scratch_shapes=[
-            pltpu.VMEM((Hq * q_block, D), jnp.float32),
-            pltpu.VMEM((Hq * q_block, _LANES), jnp.float32),
-            pltpu.VMEM((Hq * q_block, _LANES), jnp.float32),
-        ],
-    )
+    block_spec = pl.BlockSpec((1, 1, *slabs[2:]), at_block)
     out = pl.pallas_call(
         functools.partial(_ragged_kernel, page_size=page_size,
-                          q_block=q_block, sliding_window=sliding_window,
-                          two_d_dots=two_d_dots,
-                          head_dim=D if two_d_dots else None, block=block,
-                          scale=scale),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q_in.shape, q.dtype),
+                          q_block=q_block, q_blocks=q_blocks, trip=trip,
+                          sliding_window=sliding_window,
+                          two_d_dots=two_d_dots, block=block, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6, grid=(R, q_blocks),
+            in_specs=[block_spec, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=block_spec,
+            scratch_shapes=_walk_scratch(trip, page_size, HD, k_pool.dtype,
+                                         Hq * q_block, D, pools=2)),
+        out_shape=jax.ShapeDtypeStruct(slabs, q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret, name=name,
-    )(page_table.astype(jnp.int32), hist.astype(jnp.int32),
-      q_lens.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
-      q_in, k_pool, v_pool)
-    return out.reshape(B, Qmax, Hq, D)
+    )(page_table.astype(jnp.int32), first.reshape(-1), last.reshape(-1),
+      hist, q_lens, jnp.asarray(layer, jnp.int32).reshape(1), q, k_pool,
+      v_pool)
+    out = out.reshape(R, q_blocks, Hkv, G, q_block, D).transpose(
+        0, 1, 4, 2, 3, 5).reshape(R, width, Hq, D)
+    return out[:, :Qc] if width != Qc else out
 
 
 def paged_block_attention(q, k_pool, v_pool, work: DecodeWork, layer=0,

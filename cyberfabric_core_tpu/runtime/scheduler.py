@@ -391,7 +391,7 @@ class _MixedStep:
     wall0: float
     chained: bool         # launched off a step that was still undrained
     spanned: int = 0      # decode chunks chained off it
-    #: a latent model: what the ragged kernel walks for the lanes' spans
+    #: what the ragged kernel walks for the lanes' spans
     #: (``_count_ragged_walk``), for the round's record and the chunk's span
     ragged: dict = field(default_factory=dict)
 
@@ -3404,33 +3404,43 @@ class ContinuousBatchingEngine:
 
     def _count_ragged_walk(self, hist: np.ndarray, q_lens: np.ndarray,
                            width: int) -> dict:
-        """/metrics of the ragged latent kernel's walk over a mixed step's
-        lanes (spans of ``q_lens`` queries behind ``hist`` tokens, ``width``
-        wide): the pages its programs copy and the trips (key blocks: one
-        score dot each) they attend over them in, summed over the layers,
-        each kind of layer by its own span. Pages a trip near
+        """/metrics of the ragged kernel's walk over a mixed step's lanes
+        (spans of ``q_lens`` queries behind ``hist`` tokens, ``width`` wide):
+        the pages its programs copy (of one pool: a K/V kernel copies a
+        page's K and V together) and the trips (key blocks: one score dot a
+        kv head each) they attend over them in, summed over the layers, each
+        kind of layer by its own window and query heads. Pages a trip near
         ``ragged_trip_pages`` say the key blocks run full. Counted at the
-        dispatch from the lane's operands by the kernel's own
-        :func:`ragged_span`; nothing for a model with K/V pages, whose
-        ragged kernel has a grid."""
+        dispatch from the lane's operands by the kernels' own
+        ``ragged_walk`` (``ops/page_walk.py``: the latent kernel's and the
+        K/V kernel's walk is one; the q-block and the trip are each
+        kernel's own rule)."""
         cfg = self.model_config
-        if not cfg.is_latent:
-            return {}
-        from ..ops.mla_attention import ragged_walk
-
         # a model with a window page group: its full layers, then its window
-        # layers; one window for every layer otherwise
-        kinds = [(cfg.kv_layers, cfg.sliding_window)]
+        # layers; one window for every layer otherwise. The query heads pick
+        # the K/V kernel's q-block (a shard's, under a mesh)
+        kinds = [(cfg.kv_layers, cfg.sliding_window, cfg.num_heads)]
         if self._two_groups:
-            kinds = [(cfg.kv_layers, None),
-                     (cfg.window_layers, cfg.sliding_window)]
+            kinds = [(cfg.kv_layers, None, cfg.num_heads),
+                     (cfg.window_layers, cfg.sliding_window,
+                      cfg.window_heads)]
+        lanes = (hist, q_lens, width, self.config.prefix_page_size, self.pmax)
+        if cfg.is_latent:
+            from ..ops.mla_attention import ragged_walk
+
+            def walked(window, heads):
+                return ragged_walk(*lanes, window)
+        else:
+            from ..ops.paged_attention import ragged_walk
+
+            def walked(window, heads):
+                return ragged_walk(*lanes, window, heads // self.tp,
+                                   cfg.block_length)
         pages = trips = 0
-        for layers, window in kinds:
-            walked = ragged_walk(hist, q_lens, width,
-                                 self.config.prefix_page_size, self.pmax,
-                                 window)
-            pages += layers * walked[0]
-            trips += layers * walked[1]
+        for layers, window, heads in kinds:
+            n_pages, n_trips = walked(window, heads)
+            pages += layers * n_pages
+            trips += layers * n_trips
         bump_counter("llm_ragged_pages_walked_total", n=pages)
         bump_counter("llm_ragged_trips_total", n=trips)
         return {"ragged_pages": pages, "ragged_trips": trips}

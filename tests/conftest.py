@@ -227,3 +227,70 @@ def fresh_registry():
     reg._REGISTRATIONS.clear()
     yield reg
     reg._REGISTRATIONS[:] = saved
+
+
+class _CountedCopy:
+    """A DMA whose start and wait say so to the host as they RUN (under the
+    kernel's conditions, not as they are traced); ``seen`` also counts the
+    trips the kernel attended over, by the pages of the block it took."""
+    seen: dict = {}
+
+    def __init__(self, *args):
+        from jax.experimental.pallas import tpu as pltpu
+
+        self._copy = pltpu.make_async_copy(*args)
+
+    @staticmethod
+    def count(*whats):
+        import jax
+
+        def bump():
+            for what in whats:
+                _CountedCopy.seen[what] = _CountedCopy.seen.get(what, 0) + 1
+        jax.debug.callback(bump)
+
+    def start(self):
+        self.count("start")
+        self._copy.start()
+
+    def wait(self):
+        self.count("wait")
+        self._copy.wait()
+
+
+@pytest.fixture()
+def counted_copies(monkeypatch):
+    """``run(name, *args, kernel=, **kwargs)``: one interpret-mode call of a
+    kernel that walks its pages itself (``ops/page_walk.py``), and what it
+    did as it ran: ``start`` / ``wait`` the DMAs (one a page a pool), ``trips``
+    the key blocks it attended over, ``trips_of_<pages>`` by block size."""
+    import types
+
+    import jax
+    from jax.experimental.pallas import tpu as pltpu
+
+    from cyberfabric_core_tpu.ops import page_walk
+    from cyberfabric_core_tpu.ops.mla_attention import mla_decode_attention
+
+    proxy = types.SimpleNamespace(**{n: getattr(pltpu, n) for n in dir(pltpu)
+                                     if not n.startswith("__")})
+    proxy.make_async_copy = _CountedCopy
+    monkeypatch.setattr(page_walk, "pltpu", proxy)
+    _CountedCopy.seen = {}
+    walk_run = page_walk._Walk.run
+
+    def counted_run(self, item, attend):
+        def counted_trip(*args, pages, **kwargs):
+            _CountedCopy.count("trips", f"trips_of_{pages}")
+            attend(*args, pages=pages, **kwargs)
+        walk_run(self, item, counted_trip)
+    monkeypatch.setattr(page_walk._Walk, "run", counted_run)
+
+    def run(name, *args, kernel=mla_decode_attention, **kwargs):
+        _CountedCopy.seen.clear()
+        # a name of its own: a trace of its own
+        jax.block_until_ready(kernel(
+            *args, interpret=True, name=name, **kwargs))
+        jax.effects_barrier()
+        return dict(_CountedCopy.seen)
+    return run

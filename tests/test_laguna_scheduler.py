@@ -249,3 +249,57 @@ def test_the_pool_saves_and_restores_both_arrays_of_the_window_group():
     assert float(pool.window_k_pool[:, again].min()) == 1.5
     assert float(pool.window_v_pool[:, again].max()) == -2.5
     assert pool.stats()["window_pages_in_use"] == 3
+
+
+def test_the_ragged_walk_counts_each_kind_of_layer_by_its_window_and_heads():
+    """``llm_ragged_pages_walked_total`` / ``llm_ragged_trips_total`` for a
+    model with K/V pages (PR 55: its ragged kernel walks a q-block's pages
+    inside the program, as the latent kernel does): the full layers' walk (6
+    query heads, a q-block's whole history) and the window layers' (9 heads,
+    its windows' span) over a prompt's chunks, by the kernel's own span on
+    the host; the round records and the ``llm.prefill_chunk`` spans carry
+    each step's share."""
+    from cyberfabric_core_tpu.modkit.telemetry import (
+        Span, SpanExporter, Tracer, get_global_tracer, set_global_tracer)
+    from cyberfabric_core_tpu.ops.paged_attention import ragged_walk
+
+    class Collect(SpanExporter):
+        spans: list = []
+
+        def export(self, span: Span, duration_ms: float) -> None:
+            self.spans.append(span)
+
+    names = ("llm_ragged_pages_walked_total", "llm_ragged_trips_total")
+    before = {s: _counter(s) for s in names}
+    prev = get_global_tracer()
+    set_global_tracer(Tracer(exporter=Collect()))
+    sched = ContinuousBatchingEngine(_cfg(decode_lookahead=0), seed=0)
+    col = _Collector(1)
+    try:
+        sched.submit(_prompts(sizes=(40,))[0], SamplingParams(max_tokens=4),
+                     col.emit_for(0),
+                     trace="00-" + "ab" * 16 + "-" + "cd" * 8 + "-01")
+        assert col.done.wait(240), sched.stats()
+    finally:
+        sched.shutdown()
+        set_global_tracer(prev)
+    model = get_config(MODEL)
+    want = []
+    for hist, qlen in ((0, 16), (16, 16), (32, 8)):
+        full = ragged_walk([hist], [qlen], 16, PAGE, sched.pmax, None,
+                           model.num_heads)
+        window = ragged_walk([hist], [qlen], 16, PAGE, sched.pmax, WINDOW,
+                             model.window_heads)
+        # the full layers read every page so far, a window layer the 5-6
+        # that the windows of 16 queries span (the 3 of 8)
+        assert full[0] == (hist + qlen) // PAGE and 3 <= window[0] <= 6
+        want.append(tuple(2 * full[i] + 4 * window[i] for i in (0, 1)))
+    assert [_counter(s) - before[s] for s in names] == \
+        [sum(w[i] for w in want) for i in (0, 1)]
+    mixed = [r for r in sched.round_timings if r["chunk_tokens"]]
+    assert [(r["ragged_pages"], r["ragged_trips"]) for r in mixed] == want
+    assert not any("ragged_pages" in r for r in sched.round_timings
+                   if not r["chunk_tokens"])
+    chunks = [s.attributes for s in Collect.spans
+              if s.name == "llm.prefill_chunk"]
+    assert [(a["ragged_pages"], a["ragged_trips"]) for a in chunks] == want

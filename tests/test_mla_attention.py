@@ -4,23 +4,21 @@ against plain ``jnp``: ragged lengths, idle rows, a page read once as key
 the copies a call starts are the pages the host's counters count, its key
 blocks may hold anything before a call, every start has its wait."""
 
-import types
-
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.experimental.pallas import tpu as pltpu
 
 from cyberfabric_core_tpu.ops import mla_attention as mla
-from cyberfabric_core_tpu.ops.mla_attention import (RING_BLOCKS, TRIP_PAGES,
+from cyberfabric_core_tpu.ops.mla_attention import (TRIP_PAGES,
                                                     mla_decode_attention,
                                                     mla_ragged_attention,
                                                     ragged_q_block,
                                                     ragged_trip_pages,
                                                     ragged_walk,
                                                     trip_pages)
-from cyberfabric_core_tpu.ops.paged_attention import page_span
+from cyberfabric_core_tpu.ops.page_walk import (RING_BLOCKS, _block_sizes,
+                                                 page_span)
 
 RANK, ROPE, LANES, HQ, PAGE = 32, 16, 128, 4, 8
 SCALE = 0.21
@@ -119,56 +117,6 @@ def test_decode_kernel_with_the_ring_poisoned(lengths, trip):
     _check(out, q, pool, 1, table, lengths)
 
 
-class _CountedCopy:
-    """A DMA whose start and wait say so to the host as they RUN (under the
-    kernel's conditions, not as they are traced); ``seen`` also counts the
-    trips the kernel attended over, by the pages of the block it took."""
-    seen: dict = {}
-
-    def __init__(self, *args):
-        self._copy = pltpu.make_async_copy(*args)
-
-    def _count(self, what):
-        def bump():
-            _CountedCopy.seen[what] = _CountedCopy.seen.get(what, 0) + 1
-        jax.debug.callback(bump)
-
-    def start(self):
-        self._count("start")
-        self._copy.start()
-
-    def wait(self):
-        self._count("wait")
-        self._copy.wait()
-
-
-@pytest.fixture()
-def counted_copies(monkeypatch):
-    proxy = types.SimpleNamespace(**{n: getattr(pltpu, n) for n in dir(pltpu)
-                                     if not n.startswith("__")})
-    proxy.make_async_copy = _CountedCopy
-    monkeypatch.setattr(mla, "pltpu", proxy)
-    _CountedCopy.seen = {}
-    attend = mla._attend_trip
-
-    def counted_trip(*args, pages, **kwargs):
-        def bump():
-            for what in ("trips", f"trips_of_{pages}"):
-                _CountedCopy.seen[what] = _CountedCopy.seen.get(what, 0) + 1
-        jax.debug.callback(bump)
-        attend(*args, pages=pages, **kwargs)
-    monkeypatch.setattr(mla, "_attend_trip", counted_trip)
-
-    def run(name, *args, kernel=mla_decode_attention, **kwargs):
-        _CountedCopy.seen.clear()
-        # a name of its own: a trace of its own
-        jax.block_until_ready(kernel(
-            *args, interpret=True, name=name, **kwargs))
-        jax.effects_barrier()
-        return dict(_CountedCopy.seen)
-    return run
-
-
 @pytest.mark.parametrize("trip", [1, 4, 8])
 def test_the_copies_a_call_starts_are_what_the_walked_counter_counts(
         counted_copies, trip):
@@ -211,8 +159,8 @@ def test_trip_pages_is_what_the_scheduler_counts_groups_by():
     assert trip_pages(64, 128) == 3 and trip_pages(64, 4096) == 16
     assert trip_pages(16, 128) == 9 and trip_pages(16, 50) == 5
     # a trip is attended over as the smallest of these that holds its pages
-    assert mla._block_sizes(16) == (2, 4, 8, 16)
-    assert mla._block_sizes(3) == (2, 3) and mla._block_sizes(1) == (1,)
+    assert _block_sizes(16) == (2, 4, 8, 16)
+    assert _block_sizes(3) == (2, 3) and _block_sizes(1) == (1,)
     kimi = get_config("kimi-k2.5-share32-15l")
     assert decode_page_group(kimi, 64, 48, 2) == trip_pages(64, None)
     motif = get_config("motif-3-beta-share32-27l")
@@ -376,7 +324,7 @@ def test_ragged_trip_pages_is_a_key_block_of_full_tiles():
     assert ragged_trip_pages(16, None, 32) == 16
     assert ragged_trip_pages(8, None, 16) == TRIP_PAGES
     assert ragged_trip_pages(16, 128, 32) == 11
-    assert mla._block_sizes(4) == (2, 4)
+    assert _block_sizes(4) == (2, 4)
     from cyberfabric_core_tpu.models import get_config
 
     motif = get_config("motif-3-beta-share32-27l")

@@ -19,7 +19,6 @@ from cyberfabric_core_tpu.models import get_config
 from cyberfabric_core_tpu.models.llama import decode_work
 from cyberfabric_core_tpu.ops import mla_attention as mla
 from cyberfabric_core_tpu.ops.paged_attention import page_span
-from test_mla_attention import counted_copies  # noqa: F401  (a fixture)
 
 HQ, WINDOW, PAGE, RANK, LANES = 80, 128, 64, 128, 256
 LENGTHS = (127, 128, 129, 191, 192, 193, 700, 4097)

@@ -48,7 +48,8 @@ def test_the_real_files_names_resolve_and_only_add(monkeypatch):
                      "gqa_full_ragged_attention_us",
                      "gqa_window_ragged_attention_us",
                      "gqa_window_moe_step_roofline",
-                     "attn_window_pages_per_program"]
+                     "attn_window_pages_per_program",
+                     "ragged_paged_attention_us"]      # PR 55: ouro's cell too
     later = {}
 
     def as_ouro_left(metric: dict) -> dict:

@@ -202,6 +202,15 @@ def test_pool_reaches_the_kernel_uncopied(step):
         assert seen == ["scatter"], seen
 
 
+def _layer_scans(jaxpr, num_layers):
+    """The scans over the model's layers: of that length and around the
+    kernels (a kernel that walks its pages itself has loops of its own, over
+    its kv heads and its ring, which may be as long)."""
+    return [e for e in _find(jaxpr, "scan")
+            if e.params["length"] == num_layers
+            and any(_find(e.params["jaxpr"].jaxpr, "pallas_call"))]
+
+
 def _mixed_step_operands(eng, width):
     """``mixed_step``'s operands with an empty lane of ``width``."""
     from cyberfabric_core_tpu.runtime.programs import LANE_ROWS, lane_words
@@ -241,8 +250,7 @@ def test_work_list_is_built_once_a_step(model, program):
         else:
             jaxpr = jax.make_jaxpr(eng._mixed_step_fn)(
                 *_mixed_step_operands(eng, width))
-        layers, = [e for e in _find(jaxpr.jaxpr, "scan")
-                   if e.params["length"] == eng.model_config.num_layers]
+        layers, = _layer_scans(jaxpr.jaxpr, eng.model_config.num_layers)
         def over_rows(jaxpr):     # an expert layer and a mixer have others
             return [e for e in _find(jaxpr, "cumsum")
                     if e.outvars[0].aval.shape == (n,)
@@ -284,8 +292,7 @@ def test_mixed_step_computes_the_tokens_it_has(model):
             *_mixed_step_operands(eng, width))
         weights = {w.shape[1:] for w in jax.tree.leaves(eng.params["layers"])
                    if w.ndim == 3}
-        scan, = [e for e in _find(jaxpr.jaxpr, "scan")
-                 if e.params["length"] == eng.model_config.num_layers]
+        scan, = _layer_scans(jaxpr.jaxpr, eng.model_config.num_layers)
         rows = []
         for eqn in _find(scan.params["jaxpr"].jaxpr, "dot_general"):
             lhs, rhs = (v.aval.shape for v in eqn.invars)

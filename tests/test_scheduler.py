@@ -175,3 +175,55 @@ def test_quantized_continuous_scheduler_decodes(quant):
         assert len(toks) == 5
     finally:
         sched.shutdown()
+
+
+def test_the_ragged_walk_of_a_kv_model_is_counted_at_a_mixed_steps_dispatch():
+    """/metrics ``llm_ragged_pages_walked_total`` over
+    ``llm_ragged_trips_total``, the round records' ``ragged_pages`` /
+    ``ragged_trips`` and the ``llm.prefill_chunk`` span's attributes for the
+    llama path (mistral's, qwen2's): what the ragged K/V kernel copies and
+    attends over for a prompt's chunks, by its own span on the host (the
+    kernel's side of the equality is tests/test_ragged_attention.py's)."""
+    from cyberfabric_core_tpu.modkit.metrics import default_registry
+    from cyberfabric_core_tpu.modkit.telemetry import (
+        Span, SpanExporter, Tracer, get_global_tracer, set_global_tracer)
+
+    class Collect(SpanExporter):
+        spans: list = []
+
+        def export(self, span: Span, duration_ms: float) -> None:
+            self.spans.append(span)
+
+    def counter(name):
+        for _, value in default_registry.counter(name).samples():
+            return value
+        return 0.0
+
+    names = ("llm_ragged_pages_walked_total", "llm_ragged_trips_total")
+    before = [counter(s) for s in names]
+    prev = get_global_tracer()
+    set_global_tracer(Tracer(exporter=Collect()))
+    sched = ContinuousBatchingEngine(EngineConfig(
+        model="tiny-llama", max_seq_len=96, max_batch=3, decode_chunk=4,
+        prefix_page_size=8, prefill_budget_tokens=32, decode_lookahead=0),
+        seed=0)
+    done = threading.Event()
+    try:
+        sched.submit(list(range(5, 55)), SamplingParams(max_tokens=5),
+                     lambda ev: ev.finished and done.set(),
+                     trace="00-" + "ab" * 16 + "-" + "cd" * 8 + "-01")
+        assert done.wait(240), sched.stats()
+    finally:
+        sched.shutdown()
+        set_global_tracer(prev)
+    # a prompt of 50 in chunks of 32 + 18, pages of 8, one q-block a chunk:
+    # keys 0..31 are 4 pages, keys 0..49 are 7, in each of the 2 layers, and
+    # a trip of 16 pages takes either whole
+    want = [(2 * 4, 2 * 1), (2 * 7, 2 * 1)]
+    assert [counter(s) - b for s, b in zip(names, before)] == [22, 4]
+    mixed = [r for r in sched.round_timings if r["chunk_tokens"]]
+    assert [(r["ragged_pages"], r["ragged_trips"]) for r in mixed] == want
+    chunks = [s.attributes for s in Collect.spans
+              if s.name == "llm.prefill_chunk"]
+    assert [(a["tokens"], a["ragged_pages"], a["ragged_trips"])
+            for a in chunks] == [(32, *want[0]), (18, *want[1])]

@@ -455,12 +455,18 @@ def _primitives(jaxpr) -> list[str]:
 #: ragged latent kernel walks a q-block's pages the same way, through one
 #: ``_Walk`` with the decode kernel's and with the bookkeeping in ``lax``
 #: primitives; they were (4215, "761c459152d47736") and (4539,
-#: "0c4f9bc2b1332b41"))
+#: "0c4f9bc2b1332b41"); granite's and nemotron's MIXED steps: PR 55, whose
+#: ragged K/V kernel walks a q-block's pages inside one program through
+#: that same ``_Walk`` (now ``ops/page_walk.py``), so its body holds the
+#: trips' copies, waits and key blocks and the spans are worked out in front
+#: of the call; they were (3465, "f7ce34d2268058cb") and (3225,
+#: "c9671f2367cb4e41"). Their decode steps, and both of kimi's, held there:
+#: the shared walk moved modules and traces to what it traced to)
 TRACED_AT_THE_PARENT = {
     "tiny-granite-hybrid-4l": [(2786, "2e0c6431a4b078a3"),
-                               (3465, "f7ce34d2268058cb")],
+                               (3865, "191fd682605baa0e")],
     "tiny-nemotron-h-share4-8l": [(2546, "c1f6a78f45ff9309"),
-                                  (3225, "c9671f2367cb4e41")],
+                                  (3625, "91b1392f16fa319b")],
     "tiny-kimi-share4": [(3881, "4800cad6e270bfdb"),
                          (5451, "d4074a4d09f51c13")],
 }
