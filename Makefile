@@ -5,9 +5,9 @@
 PY ?= python
 export JAX_PLATFORMS ?= cpu
 
-.PHONY: safety lint lock-graph lock-graph-check shard-graph shard-graph-check modelcheck fuzz sanitizers contracts test native aot-tpu chaos trace-guard doctor doctor-guard ragged-bench overlap-bench spec-bench tp-bench pd-bench fed-bench fleetobs-guard lifecycle-guard cancel-guard fairness-guard
+.PHONY: safety lint lock-graph lock-graph-check shard-graph shard-graph-check modelcheck fuzz sanitizers contracts test native aot-tpu chaos trace-tests doctor ragged-tests pipeline-tests spec-tests tp-tests pd-tests federation-tests fleetobs-tests lifecycle-tests cancellation-tests tenancy-tests
 
-safety: lint lock-graph-check shard-graph-check modelcheck fuzz sanitizers contracts aot-tpu chaos trace-guard doctor doctor-guard ragged-bench overlap-bench spec-bench tp-bench pd-bench fed-bench fleetobs-guard lifecycle-guard cancel-guard fairness-guard  ## the full local gate
+safety: lint lock-graph-check shard-graph-check modelcheck fuzz sanitizers contracts aot-tpu chaos trace-tests doctor ragged-tests pipeline-tests spec-tests tp-tests pd-tests federation-tests fleetobs-tests lifecycle-tests cancellation-tests tenancy-tests  ## the full local gate
 
 LINT_SARIF ?= build/fabric_lint.sarif
 #: wall-clock budget for the whole-repo analyzer run (all three passes) —
@@ -64,55 +64,42 @@ chaos:  ## faultlab: deterministic seeded chaos-scenario suite (every failpoint 
 	$(PY) -m pytest tests/test_faultlab.py -q
 	$(PY) -m cyberfabric_core_tpu.apps.faultlab --repeat 2 > /dev/null
 
-trace-guard:  ## request observability: flight-recorder/telemetry tests + the tracing disabled-mode overhead A/B (BENCH_TRACE.json, <1% bar)
+trace-tests:  ## request observability: flight-recorder + telemetry tests
 	$(PY) -m pytest tests/test_flight_recorder.py tests/test_telemetry_export.py -q
-	$(PY) bench.py --trace-guard > /dev/null
 
 doctor:  ## fabric-doctor: SLO engine/watchdog/state-machine tests + the burn-rate and stall chaos scenarios
 	$(PY) -m pytest tests/test_doctor.py -q
 	$(PY) -m cyberfabric_core_tpu.apps.doctor --scenarios > /dev/null
 
-doctor-guard:  ## fabric-doctor armed-vs-stubbed overhead A/B under the aggregate workload (BENCH_DOCTOR.json, <1% bar)
-	$(PY) bench.py --doctor-guard > /dev/null
-
-ragged-bench:  ## ragged mixed-batch kernel/scheduler tests
+ragged-tests:  ## ragged mixed-batch kernel/scheduler tests
 	$(PY) -m pytest tests/test_ragged_attention.py tests/test_mixed_batch.py -q
 
-overlap-bench:  ## deep-lookahead pipeline tests + the depth 0/1/N sweep (BENCH_OVERLAP.json: overlap_ratio > 0.85 at depth >= 2)
+pipeline-tests:  ## deep-lookahead pipeline tests (streams byte-identical at any ring depth)
 	$(PY) -m pytest tests/test_scheduler_pipeline.py -q
-	$(PY) bench.py --overlap-bench > /dev/null
 
-spec-bench:  ## batched speculative decoding tests + the greedy repetitive-storm k=0-vs-k A/B (BENCH_SPEC.json: tok/s must improve, acceptance histogram reported)
+spec-tests:  ## batched speculative decoding tests (greedy streams byte-identical to k=0)
 	$(PY) -m pytest tests/test_scheduler_spec.py -q
-	$(PY) bench.py --spec-bench > /dev/null
 
-tp-bench:  ## tensor-parallel engine tests (tp=8 streams bit-identical to tp=1) + the tp=1-vs-N A/B on forced host devices (BENCH_TP.json: per-dispatch collective overhead)
+tp-tests:  ## tensor-parallel engine tests (tp=8 streams bit-identical to tp=1)
 	$(PY) -m pytest tests/test_tp_engine.py tests/test_parallel.py -q
-	$(PY) bench.py --tp-bench > /dev/null
 
-pd-bench:  ## prefill/decode disaggregation tests (PD-split streams bit-identical to unified) + the unified-vs-split cold-storm A/B on forced host devices (BENCH_PD.json: per-arm decode itl_p99 + ttft, role purity)
+pd-tests:  ## prefill/decode disaggregation tests (PD-split streams bit-identical to unified)
 	$(PY) -m pytest tests/test_pd_disaggregation.py -q
-	$(PY) bench.py --pd-bench > /dev/null
 
-fed-bench:  ## federation tests (registry/routing/failover + multi-process e2e) + the in-process-vs-2-loopback-workers cold-storm A/B (BENCH_FED.json: tokens/sec + honest gRPC overhead notes)
+federation-tests:  ## federation tests (registry/routing/failover + multi-process e2e)
 	$(PY) -m pytest tests/test_federation.py tests/test_federation_e2e.py -q
-	$(PY) bench.py --fed-bench > /dev/null
 
-fleetobs-guard:  ## fleet observability tests + the payload-bearing-vs-bare-heartbeat federated storm A/B (BENCH_FLEETOBS.json, <1% tok/s bar)
+fleetobs-tests:  ## fleet observability tests
 	$(PY) -m pytest tests/test_fleetscope.py -q
-	$(PY) bench.py --fleetobs-guard > /dev/null
 
-lifecycle-guard:  ## replica lifecycle tests + the disarmed-supervisor overhead A/B (BENCH_LIFECYCLE.json, <1% bar)
+lifecycle-tests:  ## replica lifecycle tests
 	$(PY) -m pytest tests/test_lifecycle.py tests/test_replicas.py -q
-	$(PY) bench.py --lifecycle-guard > /dev/null
 
-cancel-guard:  ## end-to-end cancellation/deadline tests + the armed-but-unused deadline-sweep overhead A/B (BENCH_CANCEL.json, <1% bar)
+cancellation-tests:  ## end-to-end cancellation/deadline tests
 	$(PY) -m pytest tests/test_cancellation.py -q
-	$(PY) bench.py --cancel-guard > /dev/null
 
-fairness-guard:  ## tenant isolation tests + the armed-with-one-tenant overhead A/B (BENCH_FAIRNESS.json, <1% bar)
+tenancy-tests:  ## tenant isolation tests
 	$(PY) -m pytest tests/test_tenancy.py -q
-	$(PY) bench.py --fairness-guard > /dev/null
 
 test:  ## full suite
 	$(PY) -m pytest tests/ -q

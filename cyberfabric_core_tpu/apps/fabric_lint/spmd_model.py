@@ -34,7 +34,7 @@ global picture the SH02–SH04/AK01 rules (``rules/spmd.py``) run over:
   **program-shape field set** — every config field that reaches
   ``_build_programs`` (directly, through derived attributes like
   ``self._stop_width = max(1, config.device_stop_width)``, through locals,
-  or through config methods like ``resolve_use_flash()``) or that flows
+  or through config methods like ``resolve_lookahead_depth()``) or that flows
   into a device-array shape constructor (``jnp.zeros/full/...``,
   ``jax.random.split``) anywhere in the engine class. AK01 is the set
   difference: shape-affecting but not name-matched by any key parameter.

@@ -42,8 +42,7 @@ __all__ = ["BUILTIN_SCENARIOS", "load_scenario_file", "scenario_by_name"]
 #: paged pool, greedy decode — a handful of compiled programs serve every
 #: engine/pool scenario, and the baseline cache is shared across them
 _TINY = {"model": "tiny-llama", "max_seq_len": 64, "max_batch": 2,
-         "decode_chunk": 4, "prefix_cache_pages": 64, "prefix_page_size": 16,
-         "use_flash": False}
+         "decode_chunk": 4, "prefix_cache_pages": 64, "prefix_page_size": 16}
 _LOAD = {"requests": 4, "prompt_len": [4, 10], "max_tokens": 10}
 
 BUILTIN_SCENARIOS: list[dict[str, Any]] = [

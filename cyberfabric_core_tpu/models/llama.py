@@ -962,23 +962,6 @@ def prefill_collect(
     return last_h, kv
 
 
-def insert_slot_kv(
-    cache: KVCache,
-    new_kv: KVCache,          # [L, 1, T, Hkv, D]
-    slot: jnp.ndarray,        # scalar int32
-) -> KVCache:
-    """Scatter one request's prefilled kv into its pool slot (donate the pool —
-    XLA performs the update in place)."""
-    k_cache, v_cache = cache
-    k_new, v_new = new_kv
-    zero = jnp.zeros((), jnp.int32)
-    idx = (zero, slot.astype(jnp.int32), zero, zero, zero)
-    return (
-        jax.lax.dynamic_update_slice(k_cache, k_new.astype(k_cache.dtype), idx),
-        jax.lax.dynamic_update_slice(v_cache, v_new.astype(v_cache.dtype), idx),
-    )
-
-
 def _softcap(logits: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     """gemma-2 final-logit soft capping: cap * tanh(logits / cap)."""
     if cfg.final_logit_softcap > 0.0:

@@ -1,23 +1,16 @@
 """Single-device toy LM training: Markov corpus + AdamW loop.
 
-Two consumers need actually-TRAINED tiny checkpoints rather than random init:
-
-- the cross-model speculation benchmark (``bench.py --spec-cross``, round-4
-  verdict item 3): a draft/target pair whose distributions OVERLAP but differ
-  — random-independent weights give ~zero acceptance, self-draft gives 100%;
-  neither measures real speculative decoding. Training an 8-layer target and
-  a 2-layer draft on the same synthetic language yields acceptance strictly
-  between, which is the regime the Leviathan sampler exists for.
-- weight-realism tests: trained weights develop the non-Gaussian structure
-  (outlier channels) that random init lacks.
+What needs actually-TRAINED tiny checkpoints rather than random init:
+weight-realism tests, since trained weights develop the non-Gaussian
+structure (outlier channels) that random init lacks. Nothing imports this
+module at present (ROADMAP D22).
 
 The corpus is a first-order Markov chain over the tiny vocab: enough
 structure to learn in seconds on CPU, stochastic enough that sampling at
 temperature > 0 exercises rejection paths.
 
 Reference analogue: none — the reference (an inference platform) trains
-nothing in-repo; this is bench/test scaffolding, kept in-package because the
-benchmark must be runnable from a bare checkout on the TPU host.
+nothing in-repo; this is test scaffolding.
 """
 
 from __future__ import annotations

@@ -48,9 +48,8 @@ Design constraints (the failpoints/flight-recorder discipline):
   never-raises helpers (``record_event`` / ``bump_counter`` /
   :func:`_gauge_set`). fabric-lint WD01 enforces this shape.
 - **Idle is cheap.** With no listener attached and no thread started (the
-  default for a bare ``import``), the doctor costs nothing; armed, the
-  bench A/B (``python bench.py --doctor-guard`` → BENCH_DOCTOR.json) holds
-  the aggregate-workload delta under 1%.
+  default for a bare ``import``), the doctor costs nothing; armed, a tick
+  reads in-memory state only (the rule above).
 """
 
 from __future__ import annotations

@@ -10,8 +10,7 @@ chaos: same seed → same injection schedule).
 Design constraints this module owes the rest of the stack:
 
 - **Disabled is free.** ``failpoint()``'s fast path is one empty-dict
-  truthiness check; no locks, no allocation, no logging. bench.py's
-  failpoints A/B guard (BENCH_FAULTLAB.json) holds the delta under 1%.
+  truthiness check; no locks, no allocation, no logging.
 - **Deterministic.** Probability decisions come from one ``random.Random``
   seeded via :func:`configure`; count-based modes are pure arithmetic on the
   per-point hit counter. The faultlab scenario runner re-seeds per scenario.

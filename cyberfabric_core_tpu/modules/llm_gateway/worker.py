@@ -2,13 +2,13 @@
 
 This is the piece the reference delegates to external HTTP providers
 (DESIGN.md:317-346 "Provider Adapter → OAGW call"); here it is a native local
-worker: prefill/decode as XLA computations, with request-level **dynamic batching**
-— concurrent chat requests landing within a small window are fused into one
-lockstep device batch (BASELINE config #2's mechanism).
+worker: prefill/decode as XLA computations under the continuous-batching
+scheduler (runtime/scheduler.py), which admits each chat request into a free
+slot of the running batch.
 
-Asyncio↔device bridging: jitted steps block, so each engine's batch runs on a
-dedicated thread; tokens cross back via call_soon_threadsafe into per-request
-asyncio queues.
+Asyncio↔device bridging: jitted steps block, so each engine's rounds run on
+the scheduler's own thread; tokens cross back via call_soon_threadsafe into
+per-request asyncio queues.
 """
 
 from __future__ import annotations
@@ -19,18 +19,18 @@ import threading
 import time
 import uuid
 from concurrent.futures import ThreadPoolExecutor
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from pathlib import Path
 from typing import Any, AsyncIterator, Optional
 
 from ...modkit.concurrency import locked_snapshot
+from ...modkit.config import ConfigError
 from ...modkit.errcat import ERR
 from ...modkit.errors import ProblemError
 from ...modkit.failpoints import failpoint_async
-from ...modkit.logging_host import observe_task
 from ...modkit.telemetry import FIRST_TOKEN, Stage, startup
 from ...parallel.feasibility import InfeasiblePlanError
-from ...runtime.engine import (EngineConfig, InferenceEngine, SamplingParams,
+from ...runtime.engine import (EngineConfig, SamplingParams,
                                SchedulerSaturated, StepEvent,
                                TenantQuotaExceeded, TenantSaturated)
 from ...runtime.federation import digest_chain, prompt_text
@@ -43,8 +43,6 @@ from ...runtime.tokenizer import (CHAT_FAMILIES, ByteTokenizer, Tokenizer,
 from ..sdk import ChatStreamChunk, LlmWorkerApi, ModelInfo
 
 logger = logging.getLogger("llm_worker")
-
-_STREAM_END = object()
 
 
 def _parse_lookahead(raw: Any) -> int:
@@ -84,25 +82,16 @@ def _warn_ignored_options(opts: dict, model_id: str) -> None:
 
 
 @dataclass
-class _Request:
-    prompt_ids: list[int]
-    sampling: SamplingParams
-    queue: asyncio.Queue
-    stop_strings: tuple[str, ...] = ()
-
-
-@dataclass
 class _EngineEntry:
     config: EngineConfig
     tokenizer: Tokenizer
-    engine: Optional[InferenceEngine] = None          # lockstep mode
-    batcher: Optional["_DynamicBatcher"] = None       # lockstep mode
-    scheduler: Optional[ContinuousBatchingEngine] = None  # continuous mode
-    #: continuous mode with engine_options.dp_replicas > 1: the request
+    #: the single engine; None where ``pool`` serves the model
+    scheduler: Optional[ContinuousBatchingEngine] = None
+    #: engine_options.dp_replicas > 1 (or a PD split): the request
     #: router IS a data-parallel serving pool (replicas pinned to distinct
     #: devices, mid-stream failover, lifecycle-supervised rebuild)
     pool: Optional[DataParallelServingPool] = None
-    #: continuous single-engine mode: rebuild-in-place supervisor — a broken
+    #: single-engine entries: rebuild-in-place supervisor — a broken
     #: scheduler is replaced (reusing its params) instead of 500ing forever
     supervisor: Optional[EngineSupervisor] = None
     model_family: str = "llama"
@@ -118,10 +107,8 @@ class _EngineEntry:
         if self.pool is not None:
             st = self.pool.stats()
             return st["active"] == 0 and st["pending"] == 0
-        if self.scheduler is not None:
-            return self.scheduler.active_slots == 0 and \
-                self.scheduler._pending.qsize() == 0
-        return True
+        return self.scheduler.active_slots == 0 and \
+            self.scheduler._pending.qsize() == 0
 
 
 @dataclass
@@ -130,79 +117,18 @@ class _EmbedEntry:
     embed_fn: Any = None  # (jitted fwd, params tree, model config)
 
 
-class _DynamicBatcher:
-    """Collect requests for up to ``window_ms``, run them as one device batch."""
-
-    def __init__(self, engine: InferenceEngine, executor: ThreadPoolExecutor,
-                 window_ms: float = 4.0) -> None:
-        self._engine = engine
-        self._executor = executor
-        self._window = window_ms / 1000.0
-        self._pending: list[_Request] = []
-        self._wakeup = asyncio.Event()
-        self._task: Optional[asyncio.Task] = None
-        self._closed = False
-
-    def ensure_running(self) -> None:
-        if self._task is None or self._task.done():
-            # a crash in the batching loop between requests would otherwise
-            # be swallowed until close() awaits the task
-            self._task = observe_task(asyncio.ensure_future(self._run()),
-                                      "llm_gateway.batch_worker",
-                                      logger="llm_gateway")
-
-    async def submit(self, req: _Request) -> None:
-        self._pending.append(req)
-        self._wakeup.set()
-        self.ensure_running()
-
-    async def close(self) -> None:
-        self._closed = True
-        self._wakeup.set()
-        if self._task is not None:
-            await self._task
-
-    async def _run(self) -> None:
-        loop = asyncio.get_running_loop()
-        while not self._closed:
-            if not self._pending:
-                self._wakeup.clear()
-                try:
-                    await asyncio.wait_for(self._wakeup.wait(), timeout=5.0)
-                except asyncio.TimeoutError:
-                    if not self._pending:
-                        return  # idle exit; resurrected on next submit
-                continue
-            await asyncio.sleep(self._window)  # batching window
-            batch = self._pending[: self._engine.config.max_batch]
-            del self._pending[: len(batch)]
-            await loop.run_in_executor(self._executor, self._drive, loop, batch)
-
-    def _drive(self, loop: asyncio.AbstractEventLoop, batch: list[_Request]) -> None:
-        """Thread context: run the blocking lockstep generation. Errors must be
-        enqueued BEFORE the end sentinel or consumers would break on the sentinel
-        and report an empty 200 instead of the failure."""
-        prompts = [r.prompt_ids for r in batch]
-        samplings = [r.sampling for r in batch]
-        try:
-            for ev in self._engine.generate_stream(prompts, samplings):
-                req = batch[ev.request_index]
-                loop.call_soon_threadsafe(req.queue.put_nowait, ev)
-        except Exception as e:  # noqa: BLE001
-            logger.exception("batch generation failed")
-            for req in batch:
-                loop.call_soon_threadsafe(req.queue.put_nowait, e)
-        finally:
-            for req in batch:
-                loop.call_soon_threadsafe(req.queue.put_nowait, _STREAM_END)
-
-
 class LocalTpuWorker(LlmWorkerApi):
     """Engine pool keyed by canonical model id; engines build lazily from
     ModelInfo.engine_options (+ checkpoint when managed)."""
 
     def __init__(self, worker_config: Optional[dict[str, Any]] = None) -> None:
         self._config = worker_config or {}
+        scheduler = self._config.get("scheduler", "continuous")
+        if scheduler != "continuous":
+            raise ConfigError(
+                f"llm_gateway worker config: scheduler={scheduler!r} is not "
+                "served; the continuous scheduler is the one engine (drop "
+                "the 'scheduler' key or set it to 'continuous')")
         self._entries: dict[str, _EngineEntry] = {}
         self._embed_entries: dict[str, _EmbedEntry] = {}
         self._embed_build_lock = threading.Lock()
@@ -425,10 +351,6 @@ class LocalTpuWorker(LlmWorkerApi):
             tenant_soft_pages=int(opts.pop("tenant_soft_pages", 0)),
             tenant_max_pages=int(opts.pop("tenant_max_pages", 0)),
             tenant_max_pending=int(opts.pop("tenant_max_pending", 0)),
-            speculative=opts.pop("speculative", "off"),
-            spec_k=int(opts.pop("spec_k", 8)),
-            draft_model=opts.pop("draft_model", ""),
-            draft_checkpoint=opts.pop("draft_checkpoint", ""),
             # batched speculative decoding in the continuous scheduler
             # (docs/ARCHITECTURE.md "Speculative decoding"): k ngram-drafted
             # tokens per greedy slot per round, verified as a ragged span
@@ -482,121 +404,96 @@ class LocalTpuWorker(LlmWorkerApi):
             if not eng_cfg.eos_token_ids:
                 eng_cfg = EngineConfig(**{**eng_cfg.__dict__,
                                           "eos_token_ids": (tokenizer.eos_id,)})
-        mode = self._config.get("scheduler", "continuous")
-        if eng_cfg.speculative != "off" and mode == "continuous":
-            logger.warning(
-                "engine_options.speculative=%r is inert under the continuous "
-                "scheduler (that field drives the lockstep bs=1 path); set "
-                "engine_options.scheduler_spec_k for batched speculative "
-                "decoding in the continuous scheduler, or scheduler: "
-                "lockstep for this model", eng_cfg.speculative)
-        if mode == "continuous":
-            # replica lifecycle knobs (docs/ARCHITECTURE.md "Replica
-            # lifecycle"): dp_replicas > 1 serves this model through a
-            # data-parallel pool (one engine per device, mid-stream
-            # failover, supervised rebuild + probation + drain control
-            # plane); 1 keeps the single engine but still gains a
-            # rebuild-in-place supervisor. `lifecycle` takes a bool or a
-            # LifecycleConfig-shaped dict; default supervised.
-            dp_replicas = int(opts.pop("dp_replicas", 1))
-            lc_cfg = LifecycleConfig.from_config(opts.pop("lifecycle", True))
-            # prefill/decode disaggregation (docs/ARCHITECTURE.md
-            # "Prefill/decode disaggregation"): role-split replica groups
-            # with page-granularity KV handoff — prefill-role engines run
-            # only chunked prefill and hand each stream's KV to the
-            # decode-role group, so prefill storms never land in decode
-            # rounds. Both knobs must be set together (each role needs at
-            # least one replica to serve).
-            pd_prefill = int(opts.pop("pd_prefill_replicas", 0))
-            pd_decode = int(opts.pop("pd_decode_replicas", 0))
-            _warn_ignored_options(opts, model.canonical_id)
-            if (pd_prefill > 0) != (pd_decode > 0):
-                raise ValueError(
-                    f"engine_options for {model.canonical_id}: "
-                    f"pd_prefill_replicas={pd_prefill} and "
-                    f"pd_decode_replicas={pd_decode} must be set together "
-                    "(each PD role needs at least one replica)")
-            if pd_prefill > 0:
-                if dp_replicas > 1:
-                    raise ValueError(
-                        f"engine_options for {model.canonical_id}: the PD "
-                        f"split cannot combine with dp_replicas="
-                        f"{dp_replicas} (the PD pool IS the replica pool; "
-                        "size it with the pd_*_replicas knobs)")
-                if eng_cfg.tp > 1:
-                    raise ValueError(
-                        f"engine_options for {model.canonical_id}: the PD "
-                        f"split cannot combine with tp={eng_cfg.tp} (PD "
-                        "replicas pin one device each; tp'd PD groups are "
-                        "a future rung)")
-                from ...runtime.pd import PDServingPool
-
-                pool = PDServingPool(
-                    eng_cfg, n_prefill=pd_prefill, n_decode=pd_decode,
-                    params=params, lifecycle=lc_cfg)
-                logger.info(
-                    "PD pool ready for %s (%s, %d prefill + %d decode, "
-                    "slots=%d each, max_seq=%d)", model.canonical_id,
-                    arch_config, pd_prefill, pd_decode, eng_cfg.max_batch,
-                    eng_cfg.max_seq_len)
-                return _EngineEntry(config=eng_cfg, tokenizer=tokenizer,
-                                    pool=pool, model_family=chat_family)
-            if dp_replicas > 1 and eng_cfg.tp > 1:
-                # one engine, one parallelism axis: a dp pool pins each
-                # replica to ONE device, which a tp mesh cannot share.
-                # Fail at build (clear, typed) instead of letting the
-                # engine's own pinned-device check surface as a 500.
-                raise ValueError(
-                    f"engine_options for {model.canonical_id}: dp_replicas="
-                    f"{dp_replicas} cannot combine with tp={eng_cfg.tp} "
-                    "(a dp pool pins one device per replica; tensor-"
-                    "parallel pools are a future rung)")
-            if dp_replicas > 1:
-                pool = DataParallelServingPool(
-                    eng_cfg, n_replicas=dp_replicas, params=params,
-                    lifecycle=lc_cfg)
-                logger.info(
-                    "continuous pool ready for %s (%s, %d replicas, "
-                    "slots=%d each, max_seq=%d)", model.canonical_id,
-                    arch_config, dp_replicas, eng_cfg.max_batch,
-                    eng_cfg.max_seq_len)
-                return _EngineEntry(config=eng_cfg, tokenizer=tokenizer,
-                                    pool=pool, model_family=chat_family)
-            scheduler = ContinuousBatchingEngine(eng_cfg, params=params)
-            with startup.stage("engine.thread"):
-                scheduler.start()
-            supervisor = None
-            if lc_cfg.enabled:
-                def _rebuild(old: Any, _cfg=eng_cfg) -> Any:
-                    # fresh engine off the spent one's committed params —
-                    # O(scheduler start), not O(checkpoint load)
-                    return ContinuousBatchingEngine(
-                        _cfg, params=getattr(old, "params", None))
-
-                supervisor = EngineSupervisor(_rebuild, lc_cfg,
-                                              name=model.canonical_id)
-            logger.info("continuous engine ready for %s (%s, slots=%d, max_seq=%d)",
-                        model.canonical_id, arch_config, eng_cfg.max_batch,
-                        eng_cfg.max_seq_len)
-            return _EngineEntry(config=eng_cfg, tokenizer=tokenizer,
-                                scheduler=scheduler, supervisor=supervisor,
-                                model_family=chat_family)
+        # replica lifecycle knobs (docs/ARCHITECTURE.md "Replica
+        # lifecycle"): dp_replicas > 1 serves this model through a
+        # data-parallel pool (one engine per device, mid-stream
+        # failover, supervised rebuild + probation + drain control
+        # plane); 1 keeps the single engine but still gains a
+        # rebuild-in-place supervisor. `lifecycle` takes a bool or a
+        # LifecycleConfig-shaped dict; default supervised.
+        dp_replicas = int(opts.pop("dp_replicas", 1))
+        lc_cfg = LifecycleConfig.from_config(opts.pop("lifecycle", True))
+        # prefill/decode disaggregation (docs/ARCHITECTURE.md
+        # "Prefill/decode disaggregation"): role-split replica groups
+        # with page-granularity KV handoff — prefill-role engines run
+        # only chunked prefill and hand each stream's KV to the
+        # decode-role group, so prefill storms never land in decode
+        # rounds. Both knobs must be set together (each role needs at
+        # least one replica to serve).
+        pd_prefill = int(opts.pop("pd_prefill_replicas", 0))
+        pd_decode = int(opts.pop("pd_decode_replicas", 0))
         _warn_ignored_options(opts, model.canonical_id)
-        engine = InferenceEngine(eng_cfg)
-        if params is not None:
-            engine.params = params
-        logger.info("lockstep engine ready for %s (%s, max_seq=%d)",
-                    model.canonical_id, arch_config, eng_cfg.max_seq_len)
-        return _EngineEntry(
-            config=eng_cfg,
-            engine=engine,
-            tokenizer=tokenizer,
-            model_family=chat_family,
-            batcher=_DynamicBatcher(
-                engine, self._executor,
-                window_ms=float(self._config.get("batch_window_ms", 4.0)),
-            ),
-        )
+        if (pd_prefill > 0) != (pd_decode > 0):
+            raise ValueError(
+                f"engine_options for {model.canonical_id}: "
+                f"pd_prefill_replicas={pd_prefill} and "
+                f"pd_decode_replicas={pd_decode} must be set together "
+                "(each PD role needs at least one replica)")
+        if pd_prefill > 0:
+            if dp_replicas > 1:
+                raise ValueError(
+                    f"engine_options for {model.canonical_id}: the PD "
+                    f"split cannot combine with dp_replicas="
+                    f"{dp_replicas} (the PD pool IS the replica pool; "
+                    "size it with the pd_*_replicas knobs)")
+            if eng_cfg.tp > 1:
+                raise ValueError(
+                    f"engine_options for {model.canonical_id}: the PD "
+                    f"split cannot combine with tp={eng_cfg.tp} (PD "
+                    "replicas pin one device each; tp'd PD groups are "
+                    "a future rung)")
+            from ...runtime.pd import PDServingPool
+
+            pool = PDServingPool(
+                eng_cfg, n_prefill=pd_prefill, n_decode=pd_decode,
+                params=params, lifecycle=lc_cfg)
+            logger.info(
+                "PD pool ready for %s (%s, %d prefill + %d decode, "
+                "slots=%d each, max_seq=%d)", model.canonical_id,
+                arch_config, pd_prefill, pd_decode, eng_cfg.max_batch,
+                eng_cfg.max_seq_len)
+            return _EngineEntry(config=eng_cfg, tokenizer=tokenizer,
+                                pool=pool, model_family=chat_family)
+        if dp_replicas > 1 and eng_cfg.tp > 1:
+            # one engine, one parallelism axis: a dp pool pins each
+            # replica to ONE device, which a tp mesh cannot share.
+            # Fail at build (clear, typed) instead of letting the
+            # engine's own pinned-device check surface as a 500.
+            raise ValueError(
+                f"engine_options for {model.canonical_id}: dp_replicas="
+                f"{dp_replicas} cannot combine with tp={eng_cfg.tp} "
+                "(a dp pool pins one device per replica; tensor-"
+                "parallel pools are a future rung)")
+        if dp_replicas > 1:
+            pool = DataParallelServingPool(
+                eng_cfg, n_replicas=dp_replicas, params=params,
+                lifecycle=lc_cfg)
+            logger.info(
+                "continuous pool ready for %s (%s, %d replicas, "
+                "slots=%d each, max_seq=%d)", model.canonical_id,
+                arch_config, dp_replicas, eng_cfg.max_batch,
+                eng_cfg.max_seq_len)
+            return _EngineEntry(config=eng_cfg, tokenizer=tokenizer,
+                                pool=pool, model_family=chat_family)
+        scheduler = ContinuousBatchingEngine(eng_cfg, params=params)
+        with startup.stage("engine.thread"):
+            scheduler.start()
+        supervisor = None
+        if lc_cfg.enabled:
+            def _rebuild(old: Any, _cfg=eng_cfg) -> Any:
+                # fresh engine off the spent one's committed params —
+                # O(scheduler start), not O(checkpoint load)
+                return ContinuousBatchingEngine(
+                    _cfg, params=getattr(old, "params", None))
+
+            supervisor = EngineSupervisor(_rebuild, lc_cfg,
+                                          name=model.canonical_id)
+        logger.info("continuous engine ready for %s (%s, slots=%d, max_seq=%d)",
+                    model.canonical_id, arch_config, eng_cfg.max_batch,
+                    eng_cfg.max_seq_len)
+        return _EngineEntry(config=eng_cfg, tokenizer=tokenizer,
+                            scheduler=scheduler, supervisor=supervisor,
+                            model_family=chat_family)
 
     # ------------------------------------------------------------------ chat
     async def chat_stream(
@@ -706,12 +603,7 @@ class LocalTpuWorker(LlmWorkerApi):
         self._note_census(request_id, model.canonical_id, census_text,
                           prompt_ids[:n_prompt], trace)
         queue: asyncio.Queue = asyncio.Queue()
-        req = _Request(
-            prompt_ids=prompt_ids,
-            sampling=sampling,
-            queue=queue,
-            stop_strings=tuple(params.get("stop", ()) or ()),
-        )
+        stop_strings = tuple(params.get("stop", ()) or ())
         # per-request deadline (X-Request-Deadline-Ms header / gateway
         # default TTL, relative ms at gateway entry) → absolute monotonic
         # instant at submit; the scheduler's expiry sweep owns it from here
@@ -727,73 +619,67 @@ class LocalTpuWorker(LlmWorkerApi):
         #: ``_deadline_ms``): keys the scheduler's weighted-fair queue,
         #: per-tenant caps, and per-tenant accounting
         tenant = str(params.get("_tenant_id") or "default")
-        cancel_target = None
-        if entry.pool is not None or entry.scheduler is not None:
-            loop = asyncio.get_running_loop()
-            if entry.pool is None and not entry.scheduler.servable() \
-                    and entry.supervisor is not None:
-                # single-engine self-healing: the scheduler broke (or was
-                # retired) — rebuild it in place off the event loop before
-                # admitting. Concurrent callers land in the supervisor's
-                # backoff window and surface 503 + Retry-After instead of
-                # stacking N rebuilds.
-                try:
-                    entry.scheduler = await loop.run_in_executor(
-                        self._executor, entry.supervisor.ensure,
-                        entry.scheduler)
-                except ReplicaUnavailable as e:
-                    raise ERR.llm.replica_unavailable.error(
-                        str(e), retry_after_s=e.retry_after_s)
-            target = entry.pool if entry.pool is not None else entry.scheduler
-            cancel_target = target
+        loop = asyncio.get_running_loop()
+        if entry.pool is None and not entry.scheduler.servable() \
+                and entry.supervisor is not None:
+            # single-engine self-healing: the scheduler broke (or was
+            # retired) — rebuild it in place off the event loop before
+            # admitting. Concurrent callers land in the supervisor's
+            # backoff window and surface 503 + Retry-After instead of
+            # stacking N rebuilds.
             try:
-                target.submit(
-                    prompt_ids, sampling,
-                    emit=lambda ev: loop.call_soon_threadsafe(
-                        queue.put_nowait, ev),
-                    request_id=request_id,
-                    trace=trace,
-                    deadline=deadline,
-                    tenant=tenant,
-                )
-            except TenantSaturated as e:
-                # the CALLER'S tenant queue is full (its own retry storm) —
-                # a tenant-scoped 429 + Retry-After, distinct from global
-                # saturation so dashboards and clients can tell them apart
-                raise ERR.llm.tenant_saturated.error(
-                    str(e), retry_after_s=e.retry_after_s, tenant=e.tenant)
-            except TenantQuotaExceeded as e:
-                # the request can never fit the tenant's hard KV-page quota
-                raise ERR.llm.tenant_quota_exceeded.error(
-                    str(e), retry_after_s=e.retry_after_s, tenant=e.tenant)
-            except SchedulerSaturated as e:
-                # admission backpressure: the pending queue is at
-                # max_pending. 429 + Retry-After (the gateway's problem
-                # renderer turns retry_after_s into the header) beats
-                # unbounded queue growth under an arrival storm.
-                raise ERR.llm.scheduler_saturated.error(
-                    str(e), retry_after_s=e.retry_after_s)
-            except ValueError as e:
-                # e.g. seed on the dense scheduler: a client-fixable request
-                # shape, not a server fault
-                raise ERR.llm.unsupported_param.error(str(e))
-            except RuntimeError as e:
-                # "no healthy replicas" (pool) / a break-or-close racing the
-                # servable() probe: a transient capacity hole while the
-                # lifecycle supervisor rebuilds — 503 + Retry-After, not 500
+                entry.scheduler = await loop.run_in_executor(
+                    self._executor, entry.supervisor.ensure,
+                    entry.scheduler)
+            except ReplicaUnavailable as e:
                 raise ERR.llm.replica_unavailable.error(
-                    str(e), retry_after_s=1.0)
-            # stamp the owning model onto the flight record (the scheduler
-            # emits the lifecycle events but does not know which registry
-            # entry owns it) — the doctor's per-model SLO overrides and the
-            # live table's model column read this
-            from ...modkit.flight_recorder import annotate_request
+                    str(e), retry_after_s=e.retry_after_s)
+        target = entry.pool if entry.pool is not None else entry.scheduler
+        try:
+            target.submit(
+                prompt_ids, sampling,
+                emit=lambda ev: loop.call_soon_threadsafe(
+                    queue.put_nowait, ev),
+                request_id=request_id,
+                trace=trace,
+                deadline=deadline,
+                tenant=tenant,
+            )
+        except TenantSaturated as e:
+            # the CALLER'S tenant queue is full (its own retry storm) —
+            # a tenant-scoped 429 + Retry-After, distinct from global
+            # saturation so dashboards and clients can tell them apart
+            raise ERR.llm.tenant_saturated.error(
+                str(e), retry_after_s=e.retry_after_s, tenant=e.tenant)
+        except TenantQuotaExceeded as e:
+            # the request can never fit the tenant's hard KV-page quota
+            raise ERR.llm.tenant_quota_exceeded.error(
+                str(e), retry_after_s=e.retry_after_s, tenant=e.tenant)
+        except SchedulerSaturated as e:
+            # admission backpressure: the pending queue is at
+            # max_pending. 429 + Retry-After (the gateway's problem
+            # renderer turns retry_after_s into the header) beats
+            # unbounded queue growth under an arrival storm.
+            raise ERR.llm.scheduler_saturated.error(
+                str(e), retry_after_s=e.retry_after_s)
+        except ValueError as e:
+            # e.g. seed on the dense scheduler: a client-fixable request
+            # shape, not a server fault
+            raise ERR.llm.unsupported_param.error(str(e))
+        except RuntimeError as e:
+            # "no healthy replicas" (pool) / a break-or-close racing the
+            # servable() probe: a transient capacity hole while the
+            # lifecycle supervisor rebuilds — 503 + Retry-After, not 500
+            raise ERR.llm.replica_unavailable.error(
+                str(e), retry_after_s=1.0)
+        # stamp the owning model onto the flight record (the scheduler
+        # emits the lifecycle events but does not know which registry
+        # entry owns it) — the doctor's per-model SLO overrides and the
+        # live table's model column read this
+        from ...modkit.flight_recorder import annotate_request
 
-            annotate_request(request_id, model=model.canonical_id,
-                             tenant=tenant)
-        else:
-            assert entry.batcher is not None
-            await entry.batcher.submit(req)
+        annotate_request(request_id, model=model.canonical_id,
+                         tenant=tenant)
 
         # incremental streaming detokenizer: decode only the unstable tail (tokens
         # whose text may still change via BPE/utf-8 merges), flushing it into
@@ -819,17 +705,10 @@ class LocalTpuWorker(LlmWorkerApi):
         #: (generator dropped mid-stream: client disconnect, gateway
         #: timeout aclose, half-consumed stream)
         stream_done = False
-        max_stop_len = max((len(s) for s in req.stop_strings), default=0)
+        max_stop_len = max((len(s) for s in stop_strings), default=0)
         try:
             while True:
-                item = await queue.get()
-                if item is _STREAM_END:
-                    stream_done = True
-                    break
-                if isinstance(item, Exception):
-                    stream_done = True
-                    raise ProblemError.internal(f"generation failed: {item}")
-                ev: StepEvent = item
+                ev: StepEvent = await queue.get()
                 if ev.finished == "error":
                     stream_done = True
                     raise ProblemError.internal("generation failed in scheduler")
@@ -895,10 +774,10 @@ class LocalTpuWorker(LlmWorkerApi):
                 full_text = stable_text + tail_text
                 delta = full_text[len(sent_text):]
                 # stop-string scan over the recent window only
-                if req.stop_strings and not stop_hit:
+                if stop_strings and not stop_hit:
                     window_start = max(0, len(sent_text) - max_stop_len)
                     window = full_text[window_start:]
-                    hit_rel = min((window.find(s) for s in req.stop_strings
+                    hit_rel = min((window.find(s) for s in stop_strings
                                    if window.find(s) >= 0), default=-1)
                     if hit_rel >= 0:
                         delta = full_text[len(sent_text):window_start + hit_rel]
@@ -929,13 +808,11 @@ class LocalTpuWorker(LlmWorkerApi):
                         # drain remaining events of this request without emitting
                         while True:
                             tail = await queue.get()
-                            if tail is _STREAM_END or (
-                                isinstance(tail, StepEvent) and tail.finished
-                            ):
+                            if tail.finished:
                                 break
                     return
         finally:
-            if not stream_done and cancel_target is not None:
+            if not stream_done:
                 # HTTP-layer abandonment: the generator was dropped before
                 # the engine reached a terminal (client disconnect closing
                 # the SSE stream, the gateway's ttft/total-timeout aclose, a
@@ -949,8 +826,7 @@ class LocalTpuWorker(LlmWorkerApi):
                 # counts true socket-level disconnects and is bumped once,
                 # at the gateway's SSE writer, never here
                 try:
-                    cancel_target.cancel(request_id,
-                                         reason="client_disconnect")
+                    target.cancel(request_id, reason="client_disconnect")
                 except Exception:  # noqa: BLE001 — teardown must not raise
                     logger.exception("cancel-on-teardown failed for %s",
                                      request_id)

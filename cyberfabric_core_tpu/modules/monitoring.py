@@ -527,8 +527,8 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
         # accept-length histogram counters directly (the _depth_hist
         # advisory-snapshot pattern of the lookahead gauges above — one
         # dict copy per scrape, no stats() build); stats()["speculative"]
-        # renders the SAME counters for REST/BENCH_SPEC.json, so the
-        # surfaces agree by construction
+        # renders the SAME counters for REST, so the surfaces agree by
+        # construction
         self.registry.counter(
             "llm_spec_tokens_proposed_total",
             "Draft tokens proposed to the scheduler's ragged verify spans"

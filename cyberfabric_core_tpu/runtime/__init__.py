@@ -5,14 +5,12 @@ external providers (modules/llm-gateway/docs/DESIGN.md:317-346 provider adapters
 the BASELINE north star demands be native TPU: prefill/decode as XLA computations.
 """
 
-from .engine import EngineConfig, GenerationResult, InferenceEngine, SamplingParams
+from .engine import EngineConfig, SamplingParams
 from .tokenizer import ByteTokenizer, Tokenizer, load_tokenizer
 
 __all__ = [
     "ByteTokenizer",
     "EngineConfig",
-    "GenerationResult",
-    "InferenceEngine",
     "SamplingParams",
     "Tokenizer",
     "load_tokenizer",

@@ -139,12 +139,10 @@ def serving_programs(
             return _plain_sds(shape, dt, sharding=repl_sharding)
         return _plain_sds(shape, dt)
 
-    # program-shape knob, part of the AOT cache key: the serving engine
-    # resolves config.resolve_use_flash() AND mesh is None (tp meshes take
-    # the jnp attention path — the flash kernel cannot auto-partition under
-    # GSPMD, tp_sharded_program's documented discipline), so the compiled
-    # set must key on the same pair or the artifact mismatches a
-    # use_flash=False serving config (AK01)
+    # program-shape knob, part of the AOT cache key: flash only where mesh
+    # is None (tp meshes take the jnp attention path — the flash kernel
+    # cannot auto-partition under GSPMD, tp_sharded_program's documented
+    # discipline), so the compiled set keys on the pair (AK01)
     flash = use_flash and mesh is None
 
     def prefill(params, ids, lengths, rng, temp, top_p, top_k, rope_t):
@@ -553,8 +551,7 @@ def main(argv: Optional[list[str]] = None) -> int:
                          "compiled set (0 = off, matching the default)")
     ap.add_argument("--use-flash", action=argparse.BooleanOptionalAction,
                     default=True,
-                    help="resolve_use_flash() of the serving config — part "
-                         "of the AOT key: flash vs jnp attention are "
+                    help="part of the AOT key: flash vs jnp attention are "
                          "different compiled programs")
     ap.add_argument("--prefix-cache-pages", type=int, default=0,
                     help="prefix_cache_pages of the serving config: pool "

@@ -3,8 +3,8 @@
 BASELINE config #2 ("64 concurrent /v1/chat/completions streams") is served by this
 scheduler: requests are admitted into free slots of a device-resident KV pool
 mid-flight, decode runs lockstep chunks across ALL active slots, finished slots
-free immediately for the next waiting request. Unlike the lockstep batcher
-(worker._DynamicBatcher), a long generation never blocks a short one.
+free immediately for the next waiting request: a long generation never blocks
+a short one.
 
 Device programs (``runtime/programs.py``; jitted, the page pools donated):
 - mixed_step:         every decode row's next token and one prefilling slot's
@@ -528,8 +528,7 @@ class TenantFairQueue:
     tenants — an idle tenant cannot bank credit and then monopolize the
     engine with a burst (the standard VTC refresh rule).
 
-    ``fair=False`` degrades to one global FIFO (the tenant-blind baseline
-    the ``bench.py --fairness-guard`` A/B pins against).
+    ``fair=False`` degrades to one global FIFO (the tenant-blind baseline).
 
     Threading: ``put``/``remove_if``/``drain_all`` may run on any thread
     (one lock acquire); ``pop_fair`` and ``charge`` run only on the
@@ -851,8 +850,8 @@ class ContinuousBatchingEngine:
             else:
                 if quant_bits is not None and not isinstance(
                         params.get("embed"), dict):
-                    # same pass-in semantics as InferenceEngine: a provided
-                    # unquantized tree gets quantized, never silently served bf16
+                    # a provided unquantized tree gets quantized, never
+                    # silently served bf16
                     from .quant import quantize_llama_params
 
                     params = quantize_llama_params(params, bits=quant_bits)
@@ -2136,8 +2135,7 @@ class ContinuousBatchingEngine:
             "mesh": self.mesh_info(),
             # batched speculative decoding: rounds that carried draft spans,
             # draft tokens proposed vs device-accepted, tokens emitted via
-            # spec rounds, and the acceptance-length histogram the perf
-            # claim rests on (BENCH_SPEC.json reads this surface)
+            # spec rounds, and the acceptance-length histogram
             "speculative": speculative,
             "prefix_cache": self.pool.stats() if self.pool is not None else None,
             "state_rows_in_use": self.state_rows_in_use(),
@@ -4558,9 +4556,8 @@ class ContinuousBatchingEngine:
         """llm.decode_chunk spans for SAMPLED in-flight requests — called
         before the emit loop (a mid-chunk finish clears the slot state). The
         guard is one bool attribute per slot: an unsampled or traceless
-        request pays nothing here (the disarmed-failpoint pattern; the
-        bench.py --trace-guard A/B holds this under 1% tok/s). Mixed rounds
-        pass ``rows`` (their decode rows only) and ``tokens=1``. ``depth`` is
+        request pays nothing here (the disarmed-failpoint pattern). Mixed
+        rounds pass ``rows`` (their decode rows only) and ``tokens=1``. ``depth`` is
         the ring depth still in flight at this round's drain. Speculative
         rounds pass ``row_tokens`` (per-slot variable advance) and
         ``row_attrs`` (spec_proposed/spec_accepted stamps — the depth-style

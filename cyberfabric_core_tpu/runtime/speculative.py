@@ -1,40 +1,33 @@
-"""Speculative decoding: n-gram prompt-lookup drafting + single-pass verify.
+"""Speculative decoding for the continuous scheduler: n-gram prompt-lookup
+drafting on the host, greedy acceptance on the device.
 
-TPU-first design of the standard draft/verify loop (the technique vLLM ships
-as "prompt lookup decoding" / ngram speculation; no reference counterpart —
-the reference delegates inference to external providers, SURVEY §0):
+The technique vLLM ships as "prompt lookup decoding" / ngram speculation (no
+reference counterpart: the reference delegates inference to external
+providers, SURVEY §0):
 
 - **Drafting is free**: instead of a draft model, the proposer looks the
   trailing n-gram of the sequence up in its own history (prompt + generated
   text repeats itself: quotes, code identifiers, RAG copies). Host-side, no
   device work at all.
-- **Verification is one fused forward**: the k drafted tokens plus the last
-  committed token run as ONE [B, k+1] forward with the standard per-position
-  causal mask — on a bandwidth-bound decode, weights dominate HBM traffic,
-  so verifying k+1 positions costs nearly the same as decoding one token.
-  Greedy acceptance: drafts match while ``draft[i] == argmax[i-1]``; the
-  verify output at the last accepted position is a free "bonus" token, so
-  every call commits between 1 and k+1 tokens.
-- **Static shapes**: the verify program is jitted once for a fixed k
-  (XLA-friendly); when the sequence window can no longer fit k+1 slots the
-  engine falls back to its single-step tail decoder.
-- **Cache rollback is free**: rejected positions' KV entries sit beyond the
-  committed length, are masked out of attention (`ops/attention.py:48`), and
-  get overwritten by the next verify pass at the same offsets.
+- **Verification rides the mixed step**: a speculating slot's k drafts plus
+  its last committed token are ONE ragged span of ``programs.spec_mixed_step``;
+  on a bandwidth-bound decode, weights dominate HBM traffic, so verifying k+1
+  positions costs nearly the same as decoding one token. Greedy acceptance:
+  drafts match while ``draft[i] == argmax[i-1]``; the verify output at the
+  last accepted position is a free "bonus" token, so every span commits
+  between 1 and k+1 tokens.
+- **A rejected suffix never commits**: its KV sits beyond the committed
+  length, is masked out of attention, and is rewritten before any later read.
 
-Greedy only (temperature 0): lossless — emitted tokens are bit-identical to
-plain decode (pinned by tests/test_speculative.py parity tests).
+Greedy only (temperature 0): lossless, emitted tokens are bit-identical to
+plain decode (pinned by tests/test_scheduler_spec.py).
 """
 
 from __future__ import annotations
 
-from typing import Callable, Optional
+from typing import Optional
 
-import jax
 import jax.numpy as jnp
-
-from ..models import llama
-from ..models.configs import ModelConfig
 
 
 class NgramProposer:
@@ -97,256 +90,16 @@ class NgramProposer:
         return result
 
 
-def span_verify_logits(params, model_config: ModelConfig, cache, tokens,
-                       lengths, rope_tables):
-    """THE shared verify forward: run a [B, T] draft span (tokens[:, 0] is
-    the last committed token, whose KV is not yet in cache; tokens[:, 1:]
-    the drafts) at positions lengths..lengths+T-1 against the cache and
-    return (per-position logits [B*T, V], updated cache). Both legacy
-    verify builders (greedy + acceptance-sampling) and the continuous
-    scheduler's ragged spec program share this prologue's semantics —
-    logits[:, i] is the model's next-token distribution after consuming
-    tokens[:, :i+1] — so acceptance math can never drift between paths."""
-    B, T = tokens.shape
-    positions = lengths[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-    hidden, cache = llama.forward(
-        params, model_config, tokens, positions, cache, lengths, rope_tables)
-    H = hidden.shape[-1]
-    logits = llama.lm_head_logits(
-        params, model_config, hidden.reshape(B * T, H))
-    return logits, cache
-
-
 def greedy_accept_counts(outs: jnp.ndarray, drafts: jnp.ndarray,
                          draft_lens: jnp.ndarray) -> jnp.ndarray:
     """Device-side greedy acceptance: ``outs`` [N, S] is the per-position
     argmax of a verify span (S = k+1), ``drafts`` [N, S-1] the proposed
     tokens, ``draft_lens`` [N] how many are real (the rest padding). Returns
     [N] — the number of leading drafts equal to the model's own argmax
-    continuation (``accept_length``'s vectorized twin; one source of truth
-    for the scheduler's on-device accept and any batched host caller)."""
+    continuation: the scheduler's on-device accept
+    (``programs.spec_mixed_step``)."""
     S = outs.shape[1]
     pos = jnp.arange(S - 1, dtype=jnp.int32)[None, :]
     match = (drafts == outs[:, :-1]) & (pos < draft_lens[:, None])
     return jnp.sum(jnp.cumprod(match.astype(jnp.int32), axis=1),
                    axis=1).astype(jnp.int32)
-
-
-def build_verify_fn(model_config: ModelConfig, k: int,
-                    rope_tables) -> Callable:
-    """Jit the [B, k+1] greedy verify forward.
-
-    Inputs: tokens[:, 0] is the last committed token (its KV is not yet in
-    cache), tokens[:, 1:] are the k drafts. The forward writes all k+1 KV
-    entries at positions lengths..lengths+k and returns the per-position
-    argmax — out[:, i] is the model's next token after consuming
-    tokens[:, :i+1]. The caller accepts the longest matching draft prefix and
-    treats later cache entries as garbage (masked, then overwritten).
-    """
-
-    def verify(params, k_cache, v_cache, tokens, lengths):
-        B, T = tokens.shape
-        logits, cache = span_verify_logits(
-            params, model_config, (k_cache, v_cache), tokens, lengths,
-            rope_tables)
-        out = jnp.argmax(logits, axis=-1).astype(jnp.int32).reshape(B, T)
-        return out, cache[0], cache[1]
-
-    return jax.jit(verify, donate_argnums=(1, 2))
-
-
-def accept_length(drafts: list[int], outs: list[int]) -> int:
-    """Greedy acceptance: number of leading drafts equal to the model's own
-    argmax continuation (outs[i] is the model token after draft prefix i)."""
-    a = 0
-    for i, d in enumerate(drafts):
-        if d != outs[i]:
-            break
-        a += 1
-    return a
-
-
-# --------------------------------------------------------- draft-model mode
-
-class DraftModel:
-    """A small model proposing k tokens per round for a big target to verify
-    (round-3 verdict item 8: prompt-lookup gets ~1.0 tokens/step on
-    non-repetitive text; a real draft model speculates everywhere).
-
-    TPU-first shape discipline: ONE jitted T=1 step (static shapes) runs k
-    times per round — the draft is chosen small enough that k sequential
-    tiny forwards cost less than the one big forward they amortize. The
-    draft keeps its own KV cache aligned with the COMMITTED sequence: the
-    drafting steps themselves write KV for consumed tokens, so after the
-    target accepts ``a`` drafts the draft cache is already valid through
-    position L+a (rejected entries sit beyond the committed length, masked
-    and later overwritten — the same rollback-free trick as the target).
-    """
-
-    def __init__(self, cfg: ModelConfig, params, *, max_seq: int,
-                 dtype=jnp.float32, k: int = 8) -> None:
-        self.cfg = cfg
-        self.params = params
-        self.k = k
-        self.max_seq = max_seq
-        self.dtype = dtype
-        self.rope = llama.rope_frequencies(cfg.head_dim, cfg.max_position,
-                                           cfg.rope_theta)
-        self.cache = llama.init_cache(cfg, 1, max_seq, dtype)
-        self.len = 0  # committed positions present in the draft cache
-        rope = self.rope
-
-        def step(params, k_cache, v_cache, token, pos, key, temp, top_p,
-                 top_k):
-            """Consume ``token`` at ``pos``; return (next draft token SAMPLED
-            from the warped draft distribution — acceptance sampling is only
-            distribution-preserving when drafts are draws from p_draft, not
-            argmax picks — plus the distribution row [V]) + updated cache."""
-            from ..ops.sampling import warped_probs
-
-            hidden, cache = llama.forward(
-                params, cfg, token[None, :], pos[None, :],
-                (k_cache, v_cache), pos[:1], rope)
-            logits = llama.lm_head_logits(params, cfg, hidden[:, -1, :])
-            probs = warped_probs(logits, temp, top_p, top_k)
-            key, sub = jax.random.split(key)
-            nxt = jax.random.categorical(
-                sub, jnp.log(jnp.maximum(probs, 1e-38)), axis=-1
-            ).astype(jnp.int32)
-            return nxt, probs[0], key, cache[0], cache[1]
-
-        self._step = jax.jit(step, donate_argnums=(1, 2))
-        self._key = jax.random.PRNGKey(0)
-
-        def prefill(params, k_cache, v_cache, ids, lengths):
-            # straight into the PERSISTENT draft cache (prefill_collect would
-            # build its own prompt-sized cache and drop these entries)
-            B, T = ids.shape
-            positions = jnp.broadcast_to(
-                jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
-            _, cache = llama.forward(params, cfg, ids, positions,
-                                     (k_cache, v_cache),
-                                     jnp.zeros((B,), jnp.int32), rope)
-            return cache[0], cache[1]
-
-        self._prefill = jax.jit(prefill, donate_argnums=(1, 2))
-
-    def reseed(self, key) -> None:
-        self._key = key
-
-    def reset(self, prompt_ids: list[int], key) -> None:
-        """Per-request re-init (jitted programs persist across requests)."""
-        self.cache = llama.init_cache(self.cfg, 1, self.max_seq, self.dtype)
-        self.len = 0
-        self._key = key
-        self.prefill(prompt_ids)
-
-    def prefill(self, prompt_ids: list[int]) -> None:
-        # bucketed like the target engine: a per-length jit signature would
-        # recompile on every new prompt length (seconds of TTFT on TPU).
-        # Padded positions write garbage KV beyond len — masked (causal /
-        # kv-length) until the sequential consume steps overwrite them.
-        if len(prompt_ids) > self.max_seq:
-            # public class: the engine guards this, direct callers deserve a
-            # clear error instead of an opaque JAX shape failure at
-            # ids.at[...].set (round-4 advisory)
-            raise ValueError(
-                f"prompt of {len(prompt_ids)} tokens exceeds the draft "
-                f"model's max_seq {self.max_seq}")
-        n = max(1, len(prompt_ids))
-        bucket = 16
-        while bucket < n:
-            bucket *= 2
-        bucket = min(bucket, self.max_seq)
-        ids = jnp.zeros((1, bucket), jnp.int32)
-        ids = ids.at[0, :len(prompt_ids)].set(jnp.asarray(prompt_ids))
-        kc, vc = self._prefill(self.params, self.cache[0], self.cache[1],
-                               ids, jnp.asarray([len(prompt_ids)], jnp.int32))
-        self.cache = (kc, vc)
-        self.len = len(prompt_ids)
-
-    def consume(self, tokens: list[int], temp, top_p, top_k) -> None:
-        """Advance the draft cache over already-committed tokens (the target's
-        bonus token, and on full acceptance the last draft) without drafting."""
-        for tok in tokens:
-            _, _, self._key, kc, vc = self._step(
-                self.params, self.cache[0], self.cache[1],
-                jnp.asarray([tok], jnp.int32),
-                jnp.asarray([self.len], jnp.int32), self._key, temp, top_p,
-                top_k)
-            self.cache = (kc, vc)
-            self.len += 1
-
-    def propose(self, last_tok: int, temp, top_p, top_k):
-        """k draft tokens sampled from the draft distribution (+ each
-        position's warped distribution row, device-resident for acceptance
-        sampling). Consumes last_tok plus the first k-1 drafts; self.len
-        advances only as the caller commits."""
-        drafts: list[int] = []
-        dists = []
-        tok = last_tok
-        pos = self.len
-        for _ in range(self.k):
-            nxt, dist, self._key, kc, vc = self._step(
-                self.params, self.cache[0], self.cache[1],
-                jnp.asarray([tok], jnp.int32),
-                jnp.asarray([pos], jnp.int32), self._key, temp, top_p, top_k)
-            self.cache = (kc, vc)
-            tok = int(nxt[0])
-            drafts.append(tok)
-            dists.append(dist)
-            pos += 1
-        return drafts, dists
-
-
-def build_verify_accept_fn(model_config: ModelConfig, k: int,
-                           rope_tables) -> Callable:
-    """Jit the fused verify + ACCEPTANCE-SAMPLING pass (Leviathan et al.):
-
-    target logits for the k+1 positions are warped with the request's
-    sampling params; draft i is accepted with probability
-    min(1, p_target(d_i)/p_draft(d_i)); the first rejection resamples from
-    the normalized residual (p_target - p_draft)+, preserving the target
-    distribution EXACTLY. temperature=0 degenerates to greedy equality
-    acceptance (warped_probs renders delta distributions), so the greedy
-    path is bit-lossless. Everything stays on device — only (accept_count,
-    next_token) cross to the host per round."""
-
-    def verify(params, k_cache, v_cache, tokens, lengths, draft_dists,
-               key, temp, top_p, top_k):
-        from ..ops.sampling import warped_probs
-
-        B, T = tokens.shape  # B == 1, T == k + 1
-        logits, cache = span_verify_logits(
-            params, model_config, (k_cache, v_cache), tokens, lengths,
-            rope_tables)  # [k+1, V]
-        t_probs = warped_probs(logits, jnp.broadcast_to(temp, (T,)),
-                               jnp.broadcast_to(top_p, (T,)),
-                               jnp.broadcast_to(top_k, (T,)))  # [k+1, V]
-        drafts = tokens[0, 1:]                                # [k]
-        p_t = t_probs[jnp.arange(k), drafts]                  # [k]
-        p_d = draft_dists[jnp.arange(k), drafts]              # [k]
-        key, u_key, r_key = jax.random.split(key, 3)
-        u = jax.random.uniform(u_key, (k,))
-        ratio = p_t / jnp.maximum(p_d, 1e-20)
-        ok = u < jnp.minimum(1.0, ratio)
-        accept = jnp.cumprod(ok.astype(jnp.int32))            # prefix accepts
-        a = jnp.sum(accept).astype(jnp.int32)                 # 0..k
-
-        # next token: residual resample at the first rejection, or the bonus
-        # sample from position k when everything was accepted
-        residual = jnp.maximum(t_probs[:k] - draft_dists, 0.0)   # [k, V]
-        res_row = residual[jnp.minimum(a, k - 1)]
-        res_mass = jnp.sum(res_row)
-        # degenerate residual (identical dists): fall back to the target row
-        safe_row = jnp.where(res_mass > 1e-12,
-                             res_row / jnp.maximum(res_mass, 1e-20),
-                             t_probs[jnp.minimum(a, k - 1)])
-        rej_tok = jax.random.categorical(r_key, jnp.log(
-            jnp.maximum(safe_row, 1e-38)))
-        bonus_tok = jax.random.categorical(r_key, jnp.log(
-            jnp.maximum(t_probs[k], 1e-38)))
-        nxt = jnp.where(a == k, bonus_tok, rej_tok).astype(jnp.int32)
-        return a, nxt, key, cache[0], cache[1]
-
-    return jax.jit(verify, donate_argnums=(1, 2))

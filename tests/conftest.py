@@ -176,6 +176,55 @@ def retrace():
     step_programs.cache_clear()
 
 
+def run_request(sched, prompt, sampling, timeout=120.0):
+    """One request through a scheduler (or a pool): ``(tokens, finish)``."""
+    import threading
+
+    done = threading.Event()
+    tokens: list[int] = []
+    finish: list[str] = []
+
+    def emit(ev):
+        if ev.token_id >= 0:
+            tokens.append(ev.token_id)
+        if ev.finished:
+            finish.append(ev.finished)
+            done.set()
+
+    sched.submit(prompt, sampling, emit)
+    assert done.wait(timeout), "request did not finish"
+    return tokens, finish[0]
+
+
+def greedy_oracle(params, cfg, prompt, max_tokens, stop_ids=()):
+    """The plain reference of greedy decoding: ``llama.forward`` over a dense
+    cache, the prompt in one pass and a token a pass after it, no engine, no
+    pages, no kernel. Returns ``(tokens, finish)`` as the scheduler emits
+    them: a stop token is the last token, ``finish`` is ``stop`` or
+    ``length``. The request is taken to fit the served window."""
+    import jax.numpy as jnp
+
+    from cyberfabric_core_tpu.models import llama
+    from cyberfabric_core_tpu.runtime.programs import serving_rope_tables
+
+    total = len(prompt) + max_tokens
+    rope = serving_rope_tables(cfg, total)
+    cache = llama.init_cache(cfg, 1, total, params["final_norm"].dtype)
+    ids, start, tokens = list(prompt), 0, []
+    while True:
+        positions = jnp.arange(start, start + len(ids), dtype=jnp.int32)[None]
+        hidden, cache = llama.forward(
+            params, cfg, jnp.asarray([ids], jnp.int32), positions, cache,
+            jnp.asarray([start], jnp.int32), rope)
+        tokens.append(int(jnp.argmax(
+            llama.lm_head_logits(params, cfg, hidden[:, -1]), axis=-1)[0]))
+        if tokens[-1] in stop_ids:
+            return tokens, "stop"
+        if len(tokens) == max_tokens:
+            return tokens, "length"
+        start, ids = start + len(ids), tokens[-1:]
+
+
 #: A worker keeps every program it compiled (``runtime/programs.py``'s memo,
 #: jax's own caches), and a compiled program is hundreds of memory mappings of
 #: its code: three scheduler files leave 31 390 of the 65 530 the kernel

@@ -262,3 +262,20 @@ def test_DE13_fixture_fails():
         'if __name__ == "__main__":\n    print("ok: CLI surface")\n',
         relpath="modules/p.py", tier="modules", select=("DE13",))
     assert [(f.rule, f.line) for f in bad] == [("DE13", 1)]
+
+
+def test_the_gates_name_no_file_that_is_not_in_the_tree():
+    """``make safety`` and CI run files by name: a ``.py`` file or a
+    ``tests/`` path the Makefile or the workflow names exists, so a deletion
+    that leaves a target behind fails here and not on the next release."""
+    import re
+
+    root = PKG.parent
+    named = re.compile(r"(?<![\w./-])((?:[\w-]+/)*[\w-]+\.py|tests/[\w./-]+)")
+    missing = []
+    for gate in ("Makefile", ".github/workflows/ci.yml"):
+        paths = set(named.findall((root / gate).read_text()))
+        assert paths, f"{gate} names no file: the pattern went stale"
+        missing += [f"{gate}: {p}" for p in sorted(paths)
+                    if not (root / p).exists()]
+    assert missing == []

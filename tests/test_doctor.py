@@ -362,7 +362,7 @@ def test_submit_after_idle_gap_restarts_round_stall_clock():
     from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
 
     cfg = EngineConfig(model="tiny-llama", max_seq_len=64, max_batch=2,
-                       decode_chunk=4, use_flash=False)
+                       decode_chunk=4)
     eng = ContinuousBatchingEngine(cfg, seed=0)
     try:
         eng.last_round_at -= 300.0  # fake a long idle gap

@@ -31,7 +31,7 @@ def _config(home_dir):
                  "engine_options": {"model_config": "tiny-llama",
                                     "max_seq_len": 128, "max_batch": 2}},
             ]}},
-            "llm_gateway": {"config": {"worker": {"batch_window_ms": 2}}},
+            "llm_gateway": {},
         }}
 
 

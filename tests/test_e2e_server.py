@@ -51,7 +51,7 @@ BASE_CONFIG = {
             ],
             "aliases": {"default-chat": "local::tiny-llama"},
         }},
-        "llm_gateway": {"config": {"worker": {"batch_window_ms": 2}}},
+        "llm_gateway": {},
         "file_storage": {},
         "credstore": {},
         "file_parser": {},

@@ -376,7 +376,7 @@ def test_JP03_functional_update_passes():
 
 def test_JP_method_sharing_local_def_name_not_marked():
     # regression: jax.jit(prefill) on a LOCAL def must not mark the METHOD
-    # prefill (speculative.py pattern) — methods are referenced as self.name
+    # prefill of the same class — methods are referenced as self.name
     ok = lint(
         "import jax\n"
         "class Draft:\n"

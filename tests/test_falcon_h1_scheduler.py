@@ -25,7 +25,7 @@ BUDGET = 32          # the prefill budget: a snapshot boundary every 32 tokens
 
 def _cfg(**over):
     base = dict(model="tiny-falcon-h1", max_seq_len=256, max_batch=4,
-                decode_chunk=4, use_flash=False, prefix_cache_pages=80,
+                decode_chunk=4, prefix_cache_pages=80,
                 prefix_page_size=16, prefill_budget_tokens=BUDGET)
     base.update(over)
     return EngineConfig(**base)
@@ -305,14 +305,6 @@ def test_a_finished_row_freezes_and_an_idle_row_is_untouched():
 def test_a_mode_that_cannot_carry_state_is_refused_at_build(over, what):
     with pytest.raises(ValueError, match=what):
         ContinuousBatchingEngine(_cfg(**over), seed=0)
-
-
-def test_the_lockstep_engine_refuses_the_architecture():
-    from cyberfabric_core_tpu.runtime.engine import InferenceEngine
-
-    with pytest.raises(ValueError, match="recurrent state"):
-        InferenceEngine(EngineConfig(model="tiny-falcon-h1", max_seq_len=64,
-                                     max_batch=1))
 
 
 def test_the_pd_page_export_refuses_a_pool_with_state():

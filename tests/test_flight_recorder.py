@@ -250,7 +250,7 @@ def test_gauge_labeled_set_function():
 
 def _cfg(**over):
     base = dict(model="tiny-llama", max_seq_len=128, max_batch=2,
-                decode_chunk=4, use_flash=False,
+                decode_chunk=4,
                 prefix_cache_pages=64, prefix_page_size=8)
     base.update(over)
     return EngineConfig(**base)

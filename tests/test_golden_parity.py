@@ -380,7 +380,7 @@ def test_golden_checkpoint_served_over_http(tmp_path):
                                         "max_seq_len": 128, "max_batch": 2,
                                         "dtype": "float32"}},
                 ]}},
-                "llm_gateway": {"config": {"worker": {"batch_window_ms": 2}}},
+                "llm_gateway": {},
             }})
         registry = ModuleRegistry.discover_and_build(
             enabled=cfg_app.module_names())

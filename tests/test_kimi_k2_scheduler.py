@@ -30,7 +30,7 @@ SERIES = _moe_series(("assignments", "local", "touched")) + (
 
 def _cfg(**over):
     base = dict(model="tiny-kimi-share4", max_seq_len=128, max_batch=4,
-                decode_chunk=4, use_flash=False, prefix_cache_pages=80,
+                decode_chunk=4, prefix_cache_pages=80,
                 prefix_page_size=16, prefill_budget_tokens=32,
                 quantization="int8")
     base.update(over)

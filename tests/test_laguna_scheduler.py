@@ -35,7 +35,7 @@ SERIES = _moe_series(laguna.STEP_COUNTERS) + (
 
 def _cfg(**over):
     base = dict(model=MODEL, max_seq_len=128, max_batch=4, decode_chunk=4,
-                use_flash=False, prefix_cache_pages=140,
+                prefix_cache_pages=140,
                 prefix_page_size=PAGE, prefill_budget_tokens=BUDGET,
                 quantization="int8")
     base.update(over)

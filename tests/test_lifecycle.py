@@ -292,8 +292,7 @@ def test_engine_supervisor_benches_crash_on_first_use_loop():
 
 def _tiny_cfg(**kw):
     base = dict(model="tiny-llama", max_seq_len=64, max_batch=2,
-                decode_chunk=4, prefix_cache_pages=64, prefix_page_size=16,
-                use_flash=False)
+                decode_chunk=4, prefix_cache_pages=64, prefix_page_size=16)
     base.update(kw)
     return EngineConfig(**base)
 

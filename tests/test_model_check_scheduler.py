@@ -44,7 +44,7 @@ from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
 def _make_engine(slots: int = 2, max_seq: int = 64,
                  budget: int = 512):
     cfg = EngineConfig(model="tiny-llama", max_seq_len=max_seq,
-                       max_batch=slots, decode_chunk=4, use_flash=False,
+                       max_batch=slots, decode_chunk=4,
                        prefix_page_size=16, prefill_budget_tokens=budget)
     eng = ContinuousBatchingEngine(cfg, seed=0)
     eng.start = lambda: None  # drive synchronously — no scheduler thread

@@ -9,7 +9,9 @@ from cyberfabric_core_tpu.models import get_config
 from cyberfabric_core_tpu.models import bert, llama
 from cyberfabric_core_tpu.ops.rope import rope_frequencies
 from cyberfabric_core_tpu.ops.sampling import sample_token
-from cyberfabric_core_tpu.runtime import EngineConfig, InferenceEngine, SamplingParams
+from conftest import run_request
+from cyberfabric_core_tpu.runtime import EngineConfig, SamplingParams
+from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
 
 CFG = get_config("tiny-llama")
 
@@ -123,35 +125,59 @@ def test_sampling_greedy_and_temperature():
     assert list(np.asarray(toks)) == [1, 1, 1]
 
 
-def test_engine_generate_deterministic():
-    eng = InferenceEngine(EngineConfig(model="tiny-llama", max_seq_len=64, max_batch=2))
-    out = eng.generate([[1, 5, 9]], SamplingParams(max_tokens=8))
-    assert len(out) == 1
-    r = out[0]
-    assert r.completion_tokens <= 8 and r.prompt_tokens == 3
-    assert r.finish_reason in ("stop", "length")
+@pytest.fixture(scope="module")
+def engine():
+    eng = ContinuousBatchingEngine(EngineConfig(
+        model="tiny-llama", max_seq_len=64, max_batch=3, decode_chunk=4),
+        seed=0)
+    yield eng
+    eng.shutdown()
+
+
+def _engine_run(model, prompt=(5, 6, 7), max_tokens=6, **over):
+    """A short greedy answer of a fresh engine of ``model``, and its params."""
+    eng = ContinuousBatchingEngine(EngineConfig(
+        model=model, max_seq_len=64, max_batch=2, decode_chunk=4, **over),
+        seed=0)
+    try:
+        return run_request(eng, list(prompt),
+                           SamplingParams(max_tokens=max_tokens)), eng.params
+    finally:
+        eng.shutdown()
+
+
+def test_engine_generate_deterministic(engine):
+    tokens, finish = run_request(engine, [1, 5, 9],
+                                 SamplingParams(max_tokens=8))
+    assert 1 <= len(tokens) <= 8
+    assert finish in ("stop", "length")
     # deterministic under greedy
-    out2 = eng.generate([[1, 5, 9]], SamplingParams(max_tokens=8))
-    assert out2[0].token_ids == r.token_ids
+    assert run_request(engine, [1, 5, 9],
+                       SamplingParams(max_tokens=8)) == (tokens, finish)
 
 
-def test_engine_batch_matches_single():
-    """Lockstep batching must not change greedy results vs solo runs."""
-    eng = InferenceEngine(EngineConfig(model="tiny-llama", max_seq_len=64, max_batch=3))
-    solo = [eng.generate([p], SamplingParams(max_tokens=6))[0].token_ids
-            for p in ([1, 5], [1, 7, 9, 11], [1])]
-    batched = eng.generate([[1, 5], [1, 7, 9, 11], [1]], SamplingParams(max_tokens=6))
-    assert [r.token_ids for r in batched] == solo
+def test_engine_batch_matches_single(engine):
+    """Sharing the batch must not change greedy results vs solo runs."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    def answer(prompt):
+        return run_request(engine, prompt, SamplingParams(max_tokens=6))
+
+    prompts = ([1, 5], [1, 7, 9, 11], [1])
+    solo = [answer(p) for p in prompts]
+    with ThreadPoolExecutor(len(prompts)) as together:
+        assert list(together.map(answer, prompts)) == solo
 
 
-def test_engine_stop_tokens():
-    eng = InferenceEngine(EngineConfig(model="tiny-llama", max_seq_len=64))
-    base = eng.generate([[1, 5, 9]], SamplingParams(max_tokens=8))[0]
-    assert len(base.token_ids) >= 2
-    stop_at = base.token_ids[1]
-    r = eng.generate([[1, 5, 9]], SamplingParams(max_tokens=8, stop_token_ids=(stop_at,)))[0]
-    assert r.finish_reason == "stop"
-    assert r.token_ids == base.token_ids[:1]
+def test_engine_stop_tokens(engine):
+    base, _ = run_request(engine, [1, 5, 9], SamplingParams(max_tokens=8))
+    assert len(base) >= 2
+    stop_at = base[1]
+    tokens, finish = run_request(engine, [1, 5, 9], SamplingParams(
+        max_tokens=8, stop_token_ids=(stop_at,)))
+    assert finish == "stop"
+    # the stop token is the last one emitted; the worker keeps it from the text
+    assert tokens == base[:2]
 
 
 def test_bert_embeddings():
@@ -171,19 +197,17 @@ def test_bert_embeddings():
     np.testing.assert_allclose(np.asarray(emb[0]), np.asarray(emb2[0]), rtol=1e-5, atol=1e-5)
 
 
-def test_seeded_sampling_reproducible():
+def test_seeded_sampling_reproducible(engine):
     """SamplingParams.seed: same seed -> same sampled tokens across calls."""
-    eng = InferenceEngine(EngineConfig(model="tiny-llama", max_seq_len=64,
-                                       decode_chunk=4))
     p = SamplingParams(max_tokens=8, temperature=0.9, top_p=0.95, seed=1234)
-    a = eng.generate([[1, 5, 9]], p)[0].token_ids
+    a, _ = run_request(engine, [1, 5, 9], p)
     # interleave an unrelated request to perturb engine rng state
-    eng.generate([[2, 2]], SamplingParams(max_tokens=3, temperature=0.7))
-    b = eng.generate([[1, 5, 9]], p)[0].token_ids
+    run_request(engine, [2, 2], SamplingParams(max_tokens=3, temperature=0.7))
+    b, _ = run_request(engine, [1, 5, 9], p)
     assert a == b
     # different seed diverges (overwhelmingly likely at temp 0.9)
-    c = eng.generate([[1, 5, 9]], SamplingParams(max_tokens=8, temperature=0.9,
-                                                 top_p=0.95, seed=999))[0].token_ids
+    c, _ = run_request(engine, [1, 5, 9], SamplingParams(
+        max_tokens=8, temperature=0.9, top_p=0.95, seed=999))
     assert c != a
 
 
@@ -233,17 +257,12 @@ def test_qwen2_attention_bias_family():
 
 def test_qwen2_engine_and_quant():
     """tiny-qwen2 runs through the engine incl. int8 (biases unquantized)."""
-    eng = InferenceEngine(EngineConfig(model="tiny-qwen2", max_seq_len=64,
-                                       decode_chunk=4, use_flash=False))
-    [res] = eng.generate([[5, 6, 7]], SamplingParams(max_tokens=6))
-    assert len(res.token_ids) == 6
+    (tokens, _), _ = _engine_run("tiny-qwen2")
+    assert len(tokens) == 6
 
-    q = InferenceEngine(EngineConfig(model="tiny-qwen2", max_seq_len=64,
-                                     decode_chunk=4, use_flash=False,
-                                     quantization="int8"))
-    assert not isinstance(q.params["layers"]["bq"], dict)  # bias not quantized
-    [res_q] = q.generate([[5, 6, 7]], SamplingParams(max_tokens=6))
-    assert len(res_q.token_ids) == 6
+    (tokens_q, _), params_q = _engine_run("tiny-qwen2", quantization="int8")
+    assert not isinstance(params_q["layers"]["bq"], dict)  # bias not quantized
+    assert len(tokens_q) == 6
 
 
 def test_gemma_family_knobs():
@@ -282,7 +301,5 @@ def test_gemma_family_knobs():
     # softcap bounds the logits
     assert np.abs(base).max() <= cfg.final_logit_softcap + 1e-3
 
-    eng = InferenceEngine(EngineConfig(model="tiny-gemma", max_seq_len=64,
-                                       decode_chunk=4, use_flash=False))
-    [res] = eng.generate([[5, 6, 7]], SamplingParams(max_tokens=6))
-    assert len(res.token_ids) == 6
+    (tokens, _), _ = _engine_run("tiny-gemma")
+    assert len(tokens) == 6

@@ -32,7 +32,7 @@ SERIES = _moe_series(motif.STEP_COUNTERS) + (
 
 def _cfg(**over):
     base = dict(model=MODEL, max_seq_len=256, max_batch=4, decode_chunk=4,
-                use_flash=False, prefix_cache_pages=80, prefix_page_size=PAGE,
+                prefix_cache_pages=80, prefix_page_size=PAGE,
                 prefill_budget_tokens=BUDGET)
     base.update(over)
     return EngineConfig(**base)

@@ -29,7 +29,7 @@ def _cfg(**over):
     # one set of shapes and one tree (int8) for every engine of this file:
     # the step programs compile once (``runtime/programs.py`` keeps them)
     base = dict(model="tiny-ouro", max_seq_len=256, max_batch=4,
-                decode_chunk=4, use_flash=False, prefix_cache_pages=65,
+                decode_chunk=4, prefix_cache_pages=65,
                 prefix_page_size=PAGE, prefill_budget_tokens=32,
                 quantization="int8")
     base.update(over)

@@ -239,7 +239,7 @@ def test_work_list_is_built_once_a_step(model, program):
     n, width = 4, 32
     eng = ContinuousBatchingEngine(EngineConfig(
         model=model, max_seq_len=128, max_batch=n, decode_chunk=3,
-        use_flash=False, prefix_cache_pages=16, prefix_page_size=16,
+        prefix_cache_pages=16, prefix_page_size=16,
         prefill_budget_tokens=width), seed=0)
     try:
         if program == "paged_decode_chunk":
@@ -285,7 +285,7 @@ def test_mixed_step_computes_the_tokens_it_has(model):
     n, width = 16, 256
     eng = ContinuousBatchingEngine(EngineConfig(
         model=model, max_seq_len=512, max_batch=n, decode_chunk=4,
-        use_flash=False, prefix_cache_pages=48, prefix_page_size=16,
+        prefix_cache_pages=48, prefix_page_size=16,
         prefill_budget_tokens=width), seed=0)
     try:
         jaxpr = jax.make_jaxpr(eng._mixed_step_fn)(

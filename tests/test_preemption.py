@@ -15,7 +15,7 @@ from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
 
 def _cfg():
     return EngineConfig(model="tiny-llama", max_seq_len=128, max_batch=2,
-                        decode_chunk=4, use_flash=False,
+                        decode_chunk=4,
                         prefix_cache_pages=64, prefix_page_size=8)
 
 
@@ -93,7 +93,7 @@ def test_suspended_request_outranks_new_admissions():
     """A resumed request takes the freed slot before queued new work."""
     sched = ContinuousBatchingEngine(
         EngineConfig(model="tiny-llama", max_seq_len=128, max_batch=1,
-                     decode_chunk=4, use_flash=False,
+                     decode_chunk=4,
                      prefix_cache_pages=64, prefix_page_size=8), seed=0)
     try:
         pool = sched.pool
@@ -189,7 +189,7 @@ def test_infeasible_suspended_request_sheds_even_under_load():
     sustained load `active` never empties, so idleness-gated shedding would
     hang its client stream forever while thrashing restore/release."""
     cfg = EngineConfig(model="tiny-llama", max_seq_len=256, max_batch=2,
-                       decode_chunk=4, use_flash=False,
+                       decode_chunk=4,
                        prefix_cache_pages=8, prefix_page_size=8)
     sched = ContinuousBatchingEngine(cfg, seed=0)
     try:

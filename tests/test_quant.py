@@ -6,7 +6,9 @@ import numpy as np
 import pytest
 
 from cyberfabric_core_tpu.models import get_config, llama
-from cyberfabric_core_tpu.runtime.engine import EngineConfig, InferenceEngine, SamplingParams
+from conftest import run_request
+from cyberfabric_core_tpu.runtime.engine import EngineConfig, SamplingParams
+from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
 from cyberfabric_core_tpu.runtime.quant import (
     dequantize_weight,
     init_params_quantized,
@@ -51,15 +53,18 @@ def test_quantized_forward_close_to_fp():
 
 
 def test_quantized_engine_generates():
-    eng = InferenceEngine(EngineConfig(model="tiny-llama", max_seq_len=64,
-                                       max_batch=2, quantization="int8",
-                                       decode_chunk=4, dtype="float32"))
-    out = eng.generate([[1, 5, 9]], SamplingParams(max_tokens=8))[0]
-    assert out.completion_tokens >= 1
-    assert all(0 <= t < CFG.vocab_size for t in out.token_ids)
-    # deterministic under greedy
-    out2 = eng.generate([[1, 5, 9]], SamplingParams(max_tokens=8))[0]
-    assert out2.token_ids == out.token_ids
+    eng = ContinuousBatchingEngine(EngineConfig(
+        model="tiny-llama", max_seq_len=64, max_batch=2, quantization="int8",
+        decode_chunk=4, dtype="float32"), seed=0)
+    try:
+        out, _ = run_request(eng, [1, 5, 9], SamplingParams(max_tokens=8))
+        assert len(out) >= 1
+        assert all(0 <= t < CFG.vocab_size for t in out)
+        # deterministic under greedy
+        out2, _ = run_request(eng, [1, 5, 9], SamplingParams(max_tokens=8))
+        assert out2 == out
+    finally:
+        eng.shutdown()
 
 
 def test_init_params_quantized_structure():
@@ -121,8 +126,11 @@ def test_int4_engine_and_structure():
     assert p4["layers"]["wq"]["q"].dtype == jnp.int4
     assert p4["embed"]["qe"].dtype == jnp.int8  # embed stays int8 by design
 
-    eng = InferenceEngine(EngineConfig(model="tiny-llama", max_seq_len=64,
-                                       decode_chunk=4, use_flash=False,
-                                       quantization="int4"))
-    [r] = eng.generate([[5, 6, 7]], SamplingParams(max_tokens=6))
-    assert len(r.token_ids) == 6
+    eng = ContinuousBatchingEngine(EngineConfig(
+        model="tiny-llama", max_seq_len=64, decode_chunk=4,
+        quantization="int4"), seed=0)
+    try:
+        tokens, _ = run_request(eng, [5, 6, 7], SamplingParams(max_tokens=6))
+    finally:
+        eng.shutdown()
+    assert len(tokens) == 6

@@ -14,7 +14,7 @@ from cyberfabric_core_tpu.runtime.replicas import (DataParallelServingPool,
 
 def _cfg(**kw):
     base = dict(model="tiny-llama", max_seq_len=128, max_batch=2,
-                decode_chunk=4, use_flash=False)
+                decode_chunk=4)
     base.update(kw)
     return EngineConfig(**base)
 

@@ -1,67 +1,48 @@
 """Continuous batching scheduler tests (CPU backend, tiny model).
 
-Key invariants: slot reuse mid-flight, greedy parity with the lockstep engine,
-no token corruption when requests join/leave, capacity finishing.
+Key invariants: slot reuse mid-flight, greedy parity with the plain oracle
+(``conftest.greedy_oracle``), no token corruption when requests join/leave,
+capacity finishing.
 """
 
-import queue
 import threading
 import time
 
 import pytest
 
-from cyberfabric_core_tpu.runtime.engine import EngineConfig, InferenceEngine, SamplingParams
+from conftest import greedy_oracle, run_request
+from cyberfabric_core_tpu.runtime.engine import EngineConfig, SamplingParams
 from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
 
 
 @pytest.fixture(scope="module")
-def engines():
+def sched():
     cfg = EngineConfig(model="tiny-llama", max_seq_len=96, max_batch=3,
                        decode_chunk=4)
     sched = ContinuousBatchingEngine(cfg, seed=0)
-    ref = InferenceEngine(cfg, seed=0)
-    # identical params (same seed/init path)
-    yield sched, ref
+    yield sched
     sched.shutdown()
 
 
-def run_request(sched, prompt, sampling, timeout=120.0):
-    q: "queue.Queue" = queue.Queue()
-    done = threading.Event()
-    tokens: list[int] = []
-    finish: list[str] = []
-
-    def emit(ev):
-        if ev.token_id >= 0:
-            tokens.append(ev.token_id)
-        if ev.finished:
-            finish.append(ev.finished)
-            done.set()
-
-    sched.submit(prompt, sampling, emit)
-    assert done.wait(timeout), "request did not finish"
-    return tokens, finish[0]
+def oracle(sched, prompt, sampling):
+    """The plain greedy continuation on the engine's own params."""
+    return greedy_oracle(sched.params, sched.model_config, prompt,
+                         sampling.max_tokens,
+                         (*sampling.stop_token_ids,
+                          *sched.config.eos_token_ids))
 
 
-def test_single_request_matches_lockstep(engines):
-    sched, ref = engines
+def test_single_request_matches_the_plain_oracle(sched):
     prompt = [1, 5, 9, 13]
     sampling = SamplingParams(max_tokens=10)
-    expected = ref.generate([prompt], sampling)[0]
-    tokens, finish = run_request(sched, prompt, sampling)
-    # lockstep result drops the stop token from visible output; scheduler emits
-    # raw tokens — compare modulo trailing stop token
-    if finish == "stop":
-        tokens = tokens[:-1]
-    assert tokens == expected.token_ids
-    assert finish == expected.finish_reason
+    assert run_request(sched, prompt, sampling) == oracle(
+        sched, prompt, sampling)
 
 
-def test_concurrent_requests_and_slot_reuse(engines):
-    sched, ref = engines
+def test_concurrent_requests_and_slot_reuse(sched):
     prompts = [[1, 5], [1, 7, 9], [2, 4, 6, 8], [3], [9, 9, 1]]
     sampling = SamplingParams(max_tokens=6)
-    expected = [ref.generate([p], sampling)[0].token_ids for p in prompts]
+    expected = [oracle(sched, p, sampling) for p in prompts]
 
     results: dict[int, list[int]] = {i: [] for i in range(len(prompts))}
     finishes: dict[int, str] = {}
@@ -85,12 +66,11 @@ def test_concurrent_requests_and_slot_reuse(engines):
     assert done.wait(180), f"finished only {len(finishes)}/{len(prompts)}"
 
     for i in range(len(prompts)):
-        got = results[i][:-1] if finishes[i] == "stop" else results[i]
-        assert got == expected[i], f"request {i} diverged"
+        assert (results[i], finishes[i]) == expected[i], \
+            f"request {i} diverged"
 
 
-def test_capacity_finish(engines):
-    sched, _ = engines
+def test_capacity_finish(sched):
     long_prompt = list(range(3, 88))  # 85 tokens in a 96 window, chunk 4
     tokens, finish = run_request(sched, long_prompt,
                                  SamplingParams(max_tokens=500))
@@ -98,12 +78,38 @@ def test_capacity_finish(engines):
     assert 1 <= len(tokens) <= 96 - 85
 
 
-def test_stats(engines):
-    sched, _ = engines
+def test_stats(sched):
     s = sched.stats()
     assert s["requests_completed"] >= 7
     assert s["tokens_emitted"] > 10
     assert s["slots"] == 3
+
+
+@pytest.mark.parametrize("model,quant", [
+    ("tiny-llama", "none"), ("tiny-qwen2", "none"), ("tiny-gemma", "none"),
+    ("tiny-llama", "int8"), ("tiny-llama", "int4"), ("tiny-qwen2", "int8"),
+    ("tiny-moe", "none")])
+def test_greedy_streams_are_the_plain_oracles(model, quant):
+    """Every dense preset and quantization the engine serves, against the
+    plain forward on the engine's own params, for a prompt that spans two
+    pages and two prefill chunks. In float32: two chunks
+    round a bfloat16 sum otherwise than one pass, and a near tie then picks
+    another token."""
+    eng = ContinuousBatchingEngine(EngineConfig(
+        model=model, max_seq_len=64, max_batch=2, decode_chunk=4,
+        dtype="float32", quantization=quant, prefix_page_size=16,
+        prefill_budget_tokens=16), seed=0)
+    sampling = SamplingParams(max_tokens=6)
+    try:
+        if quant != "none":
+            assert isinstance(eng.params["layers"]["wq"], dict)
+            # a bias stays as it was made
+            assert not isinstance(eng.params["layers"].get("bq"), dict)
+        prompt = list(range(3, 24))
+        assert run_request(eng, prompt, sampling) == oracle(
+            eng, prompt, sampling)
+    finally:
+        eng.shutdown()
 
 
 _SMALL = dict(model="tiny-llama", max_seq_len=64, max_batch=2, decode_chunk=4,
@@ -156,7 +162,7 @@ def test_quantized_continuous_scheduler_decodes(quant):
     from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
 
     cfg = EngineConfig(model="tiny-llama", max_seq_len=64, max_batch=2,
-                       decode_chunk=4, use_flash=False, quantization=quant,
+                       decode_chunk=4, quantization=quant,
                        prefix_cache_pages=20, prefix_page_size=16)
     sched = ContinuousBatchingEngine(cfg, seed=0)
     try:

@@ -35,7 +35,7 @@ STAGES = {"admit_ms": ("admit",),
 
 
 def _manual(model="tiny-llama", **over):
-    base = dict(model=model, max_seq_len=128, max_batch=4, use_flash=False,
+    base = dict(model=model, max_seq_len=128, max_batch=4,
                 prefix_cache_pages=80, prefix_page_size=16,
                 **MODELS.get(model, {}))
     base.update(over)

@@ -21,7 +21,7 @@ from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
 
 def _cfg(**over):
     base = dict(model="tiny-llama", max_seq_len=256, max_batch=4,
-                decode_chunk=4, use_flash=False,
+                decode_chunk=4,
                 prefix_cache_pages=80, prefix_page_size=16)
     base.update(over)
     return EngineConfig(**base)

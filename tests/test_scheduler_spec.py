@@ -29,11 +29,13 @@ import jax.numpy as jnp
 
 from cyberfabric_core_tpu.runtime import EngineConfig, SamplingParams
 from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
+from cyberfabric_core_tpu.runtime.speculative import (NgramProposer,
+                                                      greedy_accept_counts)
 
 
 def _cfg(**over):
     base = dict(model="tiny-llama", max_seq_len=256, max_batch=4,
-                decode_chunk=4, use_flash=False,
+                decode_chunk=4,
                 prefix_cache_pages=80, prefix_page_size=16,
                 prefill_budget_tokens=24)
     base.update(over)
@@ -337,12 +339,54 @@ def test_aot_serving_set_gains_spec_variant():
     assert not any(name.startswith("spec-verify") for name in base)
 
 
-def test_shared_accept_builder_matches_host_accept_length():
-    """Dedup satellite: the device-side greedy_accept_counts and the legacy
-    host accept_length agree on every (drafts, outs) shape."""
-    from cyberfabric_core_tpu.runtime.speculative import (accept_length,
-                                                          greedy_accept_counts)
+def test_proposer_matches_longest_recent_ngram():
+    p = NgramProposer(max_n=3, min_n=1, k=4)
+    p.extend([1, 2, 3, 9, 1, 2, 3])
+    # tail trigram (1,2,3) matched its earlier occurrence -> continues with 9…
+    assert p.propose() == [9, 1, 2, 3]
 
+
+def test_proposer_prefers_most_recent_occurrence():
+    p = NgramProposer(max_n=2, min_n=1, k=2)
+    p.extend([7, 1, 7, 2, 7])
+    # unigram (7,): latest EARLIER occurrence is index 2 -> follows with 2, 7
+    assert p.propose() == [2, 7]
+
+
+def test_proposer_no_match_returns_none():
+    p = NgramProposer(max_n=3, min_n=2, k=4)
+    p.extend([1, 2, 3, 4, 5])
+    assert p.propose() is None
+
+
+def test_proposer_short_continuation_truncates():
+    p = NgramProposer(max_n=1, min_n=1, k=8)
+    p.extend([5, 6, 5])
+    assert p.propose() == [6, 5]  # only two tokens follow the match
+
+
+def accept_length(drafts: list[int], outs: list[int]) -> int:
+    """The host oracle of greedy acceptance: number of leading drafts equal
+    to the model's own argmax continuation (outs[i] is the model token after
+    draft prefix i)."""
+    a = 0
+    for i, d in enumerate(drafts):
+        if d != outs[i]:
+            break
+        a += 1
+    return a
+
+
+def test_accept_length():
+    assert accept_length([1, 2, 3], [1, 2, 3, 4]) == 3
+    assert accept_length([1, 9, 3], [1, 2, 3, 4]) == 1
+    assert accept_length([9, 2, 3], [1, 2, 3, 4]) == 0
+    assert accept_length([], [4]) == 0
+
+
+def test_shared_accept_builder_matches_host_accept_length():
+    """The device-side greedy_accept_counts and the host oracle agree on
+    every (drafts, outs) shape."""
     rng = np.random.default_rng(0)
     S = 5
     for _ in range(50):

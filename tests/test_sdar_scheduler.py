@@ -33,7 +33,7 @@ W = CFG.block_length
 
 def _cfg(**over):
     base = dict(model="tiny-sdar", max_seq_len=128, max_batch=4,
-                decode_chunk=10, use_flash=False, prefix_cache_pages=80,
+                decode_chunk=10, prefix_cache_pages=80,
                 prefix_page_size=16, prefill_budget_tokens=32)
     base.update(over)
     return EngineConfig(**base)

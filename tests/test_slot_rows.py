@@ -28,7 +28,7 @@ STEP_PROGRAMS = {"mixed_step", "paged_decode_chunk"}
 
 def _cfg(model, **over):
     base = dict(model=model, max_seq_len=128, max_batch=2, decode_chunk=4,
-                use_flash=False, prefix_cache_pages=80, prefix_page_size=16,
+                prefix_cache_pages=80, prefix_page_size=16,
                 prefill_budget_tokens=32)
     if "kimi" in model:
         base["quantization"] = "int8"

@@ -33,7 +33,7 @@ SERIES = _moe_series(solar_open2.STEP_COUNTERS) + (
 
 def _cfg(**over):
     base = dict(model=ONE_PERIOD, max_seq_len=256, max_batch=4,
-                decode_chunk=4, use_flash=False, prefix_cache_pages=80,
+                decode_chunk=4, prefix_cache_pages=80,
                 prefix_page_size=16, prefill_budget_tokens=BUDGET)
     base.update(over)
     return EngineConfig(**base)

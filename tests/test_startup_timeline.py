@@ -277,7 +277,7 @@ def test_a_pass_by_hand_reads_what_its_thread_compiled(retrace):
     """The same through ``_loop_pass`` by hand: the compile's seconds ride
     the thread that compiled, and ``take`` zeroes them."""
     eng = ContinuousBatchingEngine(EngineConfig(
-        model="tiny-llama", max_seq_len=128, max_batch=2, use_flash=False,
+        model="tiny-llama", max_seq_len=128, max_batch=2,
         prefix_cache_pages=40, prefix_page_size=16, decode_chunk=4), seed=0)
     eng.start = lambda: None
     startup.take_compiled()             # the build's own compiles

@@ -19,7 +19,7 @@ from cyberfabric_core_tpu.runtime.programs import (
 from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
 
 BASE = dict(model="tiny-llama", max_seq_len=128, max_batch=2, decode_chunk=4,
-            use_flash=False, prefix_cache_pages=80, prefix_page_size=16,
+            prefix_cache_pages=80, prefix_page_size=16,
             prefill_budget_tokens=32)
 
 
@@ -154,3 +154,51 @@ def test_a_program_lowers_and_runs_with_no_engine():
             llama.lm_head_logits(params, cfg, hidden[:, -1]), axis=-1)[0]))
         ids.append(want[-1])
     assert got == want
+
+
+def test_every_field_of_the_engine_config_is_read_by_the_package():
+    """An ``EngineConfig`` field nothing reads is an option that drives
+    nothing: every field is loaded from a configuration (``config.x``,
+    ``self.config.x``, ``entry.config.x``, ``eng_cfg.x``) somewhere in the
+    package outside the class itself, or by one of the class's own methods
+    that something outside it calls. The worker's ``_engine_config`` only
+    WRITES fields (keywords of the constructor), which is no read."""
+    import ast
+    import pathlib
+
+    import cyberfabric_core_tpu
+
+    fields = {f.name for f in dataclasses.fields(EngineConfig)}
+    root = pathlib.Path(cyberfabric_core_tpu.__file__).parent
+    own = {}        # a method of EngineConfig -> the names it loads from self
+    seen = set()    # names loaded from a configuration outside the class
+
+    def is_config(node):
+        name = node.attr if isinstance(node, ast.Attribute) else getattr(
+            node, "id", None)
+        return name in ("config", "eng_cfg")
+
+    for path in root.rglob("*.py"):
+        tree = ast.parse(path.read_text())
+        inside = set()
+        for cls in ast.walk(tree):
+            if isinstance(cls, ast.ClassDef) and cls.name == "EngineConfig":
+                for fn in cls.body:
+                    if isinstance(fn, ast.FunctionDef):
+                        own[fn.name] = {
+                            n.attr for n in ast.walk(fn)
+                            if isinstance(n, ast.Attribute)
+                            and getattr(n.value, "id", None) == "self"}
+                        inside |= set(map(id, ast.walk(fn)))
+        seen |= {node.attr for node in ast.walk(tree)
+                 if isinstance(node, ast.Attribute) and id(node) not in inside
+                 and isinstance(node.ctx, ast.Load) and is_config(node.value)}
+    assert own, "EngineConfig was not found under the package"
+    reached, todo = set(), list(seen & set(own))
+    while todo:
+        method = todo.pop()
+        if method not in reached:
+            reached.add(method)
+            seen |= own[method] & fields
+            todo += own[method] & set(own)
+    assert sorted(fields - seen) == []

@@ -175,13 +175,21 @@ def test_sampled_flag_round_trip_and_emit_span():
 
 
 def test_engine_decode_cost_analysis():
-    from cyberfabric_core_tpu.runtime.engine import EngineConfig, InferenceEngine
+    """XLA's cost analysis of the served decode program, lowered from an
+    engine's own operands (a lowering runs nothing and donates nothing)."""
+    from cyberfabric_core_tpu.runtime.engine import EngineConfig
+    from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
 
-    eng = InferenceEngine(EngineConfig(model="tiny-llama", max_seq_len=64,
-                                       max_batch=2, decode_chunk=2,
-                                       dtype="float32"), seed=0)
-    out = eng.decode_cost_analysis()
-    assert out["batch"] == 2 and out["decode_chunk"] == 2
-    # CPU XLA reports flops; derived per-token numbers follow
-    if "flops" in out:
-        assert out["flops"] > 0 and out["flops_per_token"] > 0
+    eng = ContinuousBatchingEngine(EngineConfig(
+        model="tiny-llama", max_seq_len=64, max_batch=2, decode_chunk=2,
+        dtype="float32"), seed=0)
+    try:
+        compiled = eng._paged_decode_fn.lower(
+            eng.params, *eng.pool.cache_operands(), eng._rows_dev,
+            eng._last_tokens, eng._lengths_dev, eng._active_dev,
+            eng._finished_dev, eng._slot_keys).compile()
+    finally:
+        eng.shutdown()
+    out = xla_cost_summary(compiled)
+    # the CPU's XLA reports flops; a backend with no cost model gives {}
+    assert out.get("flops", 1.0) > 0 and out.get("bytes_accessed", 1.0) > 0

@@ -29,7 +29,7 @@ from cyberfabric_core_tpu.runtime.scheduler import (ContinuousBatchingEngine,
                                                     TenantFairQueue, _Pending)
 
 TINY = dict(model="tiny-llama", max_seq_len=64, max_batch=2, decode_chunk=4,
-            prefix_cache_pages=64, prefix_page_size=16, use_flash=False)
+            prefix_cache_pages=64, prefix_page_size=16)
 
 
 def _req(rid: str, tenant: str = "default", enq: float = 0.0) -> _Pending:
