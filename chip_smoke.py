@@ -569,12 +569,10 @@ def kernel_checks(jax, spec: dict, cfg) -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from cyberfabric_core_tpu.models.llama import decode_page_group
     from cyberfabric_core_tpu.ops.attention import attention_with_cache
     from cyberfabric_core_tpu.ops.flash_attention import flash_self_attention
     from cyberfabric_core_tpu.ops.paged_attention import (
-        decode_work_list, paged_decode_attention, paged_gather_dense,
-        ragged_paged_attention)
+        paged_decode_attention, paged_gather_dense, ragged_paged_attention)
     from cyberfabric_core_tpu.ops.platform import default_interpret
 
     interpret = default_interpret()
@@ -615,20 +613,19 @@ def kernel_checks(jax, spec: dict, cfg) -> None:
     k_dense, v_dense = paged_gather_dense(k_pool, v_pool, table, D, layer)
     cap = pmax * page
 
-    # paged decode in the 2D-dot form a real compile uses, a page a program
-    # and the group of pages the serving path takes at these widths
+    # paged decode in the 2D-dot form a real compile uses, a page a trip
+    # and the trip the serving path takes at these widths
     lens = jnp.asarray(([1, page - 1, page, page + 1, cap // 3, cap // 2,
                          cap - 1, cap] * B)[:B], jnp.int32)
     q = rnd(B, Hq, D)
     ref = reference(q[:, None], k_dense, v_dense, (lens - 1)[:, None], lens)
-    for group in sorted({1, decode_page_group(cfg, page, pmax,
-                                              k_pool.dtype.itemsize)}):
+    for trip in (1, None):
         out = paged_decode_attention(
-            q, k_pool, v_pool,
-            decode_work_list(table, lens, page, window, group), layer,
-            interpret=interpret, sliding_window=window, two_d_dots=True)
-        check_close(f"paged decode (two_d_dots, {group} pages a program) "
-                    f"B={B} lens {lens.tolist()}", out, ref[:, 0])
+            q, k_pool, v_pool, table, lens, layer, interpret=interpret,
+            sliding_window=window, two_d_dots=True, trip=trip)
+        check_close(f"paged decode (two_d_dots, {trip or 'the shipped'} "
+                    f"pages a trip) B={B} lens {lens.tolist()}", out,
+                    ref[:, 0])
 
     # ragged mixed: decode rows, prefill chunks and idle rows in one call
     for width in MIXED_WIDTHS:

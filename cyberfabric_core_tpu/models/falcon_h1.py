@@ -285,8 +285,7 @@ def forward_paged_decode(
     pid, off = _decode_targets(page_table, lengths, write_mask,
                                pools[0].shape[2])
     attend = _decode_attend(cfg, interpret, None)
-    work = decode_work(cfg, page_table, lengths + 1, pools[0],
-                       cfg.sliding_window)
+    work = decode_work(page_table, lengths + 1)
 
     h = _embed_scale(embed_lookup(params["embed"], input_ids,
                                   params["final_norm"].dtype), cfg)
@@ -349,8 +348,8 @@ def forward_paged_mixed(
     interpret = _one_device(mesh, interpret)
     cos_t, sin_t = rope_tables
     R, Qc = input_ids.shape
-    lay = mixed_layout(cfg, input_ids, page_table, hist, q_lens, write_mask,
-                       rows, decode, pools[0], cfg.sliding_window)
+    lay = mixed_layout(input_ids, page_table, hist, q_lens, write_mask, rows,
+                       decode, pools[0])
     nd = lay.n_dec
     lane_attend = _ragged_attend(cfg, interpret, None)
     decode_attend = _decode_attend(cfg, interpret, None)

@@ -314,8 +314,7 @@ def forward_paged_decode(
     page_size = pool.shape[2]
     positions = lengths[None, :]
     pid, off = _decode_targets(page_table, lengths, write_mask, page_size)
-    work = decode_work(cfg, page_table, lengths + 1, pool,
-                       cfg.sliding_window)
+    work = decode_work(page_table, lengths + 1)
     scale = attention_scale(cfg)
     h = embed_lookup(params["embed"], input_ids.reshape(1, B),
                      params["final_norm"].dtype)
@@ -360,8 +359,8 @@ def forward_paged_mixed(
     cos_t, sin_t = rope_tables
     R, Qc = input_ids.shape
     (pool,) = pools
-    lay = mixed_layout(cfg, input_ids, page_table, hist, q_lens, write_mask,
-                       rows, decode, pool, cfg.sliding_window)
+    lay = mixed_layout(input_ids, page_table, hist, q_lens, write_mask, rows,
+                       decode, pool)
     nd = lay.n_dec
     rank, scale = cfg.kv_lora_rank, attention_scale(cfg)
     h = embed_lookup(params["embed"], lay.ids, params["final_norm"].dtype)

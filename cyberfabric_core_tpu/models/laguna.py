@@ -29,11 +29,11 @@ D]`` and the second ``[window layers, window pages, page, Hkv D]``, and
 group's, under the SAME logical page index (``motif._tables``). A layer
 writes and reads the pair of its kind at its index AMONG THE LAYERS OF ITS
 KIND (``cfg.full_layers_before``). One program holds two instances of each
-K/V kernel (``ops/paged_attention.py``), each with its own work list
-(``llama.decode_work`` by the kind's window and query heads: a window
-layer's list starts at its span's first page) and its own name in a device
-trace (``_NAMES``); the scheduler gives back the window pages left of a
-row's window and the table then names scratch there, which no list reaches.
+K/V kernel (``ops/paged_attention.py``), each over its own group's table
+(``llama.decode_work``; a window layer's walk starts at its span's first
+page) and under its own name in a device trace (``_NAMES``); the scheduler
+gives back the window pages left of a row's window and the table then names
+scratch there, which no walk reaches.
 
 ``params``: ``full`` and ``window`` stack the attention matrices of their
 kind (``wq`` and ``wo`` differ in shape), ``dense`` the leading layers'
@@ -245,7 +245,7 @@ def _kernels(cfg: ModelConfig, interpret: bool):
     def decode(full: bool):
         def attend(qq, kk, vv, work, ly):
             return paged_decode_attention(
-                qq, kk, vv, work, ly, interpret=interpret,
+                qq, kk, vv, *work, ly, interpret=interpret,
                 sliding_window=_window(cfg, full),
                 name=_NAMES[full] + "_decode_attention")
         return attend
@@ -294,15 +294,13 @@ def forward_paged_decode(
     page_size = pools[0].shape[2]
     positions = lengths[None, :]
     decode, _ = _kernels(cfg, interpret)
-    # (write targets, the work list the kernel walks) of the full group and
-    # of the window group, each by its kind's window and query heads
+    # (write targets, what the kernel walks) of the full group and of the
+    # window group
     group = {}
-    for full, table, pool in zip((True, False), _tables(page_table),
-                                 pools[::2]):
+    for full, table in zip((True, False), _tables(page_table)):
         group[full] = (
             _decode_targets(table, lengths, write_mask, page_size),
-            decode_work(cfg, table, lengths + 1, pool, _window(cfg, full),
-                        _heads(cfg, full)))
+            decode_work(table, lengths + 1))
 
     def attend(full, i, q, k, v, pools):
         (pid, off), work = group[full]
@@ -339,9 +337,8 @@ def forward_paged_mixed(
     groups. Returns (hidden, pools, aux)."""
     interpret = _one_device(mesh, interpret)
     dec, ragged = _kernels(cfg, interpret)
-    lays = {full: mixed_layout(cfg, input_ids, table, hist, q_lens,
-                               write_mask, rows, decode, pool,
-                               _window(cfg, full), _heads(cfg, full))
+    lays = {full: mixed_layout(input_ids, table, hist, q_lens,
+                               write_mask, rows, decode, pool)
             for full, table, pool in zip((True, False), _tables(page_table),
                                          pools[::2])}
     lay = lays[True]            # ids, positions and the split are both's

@@ -19,7 +19,7 @@ safetensors format).
 
 from __future__ import annotations
 
-from typing import TYPE_CHECKING, Any, NamedTuple, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -29,9 +29,6 @@ from ..ops.norms import rms_norm
 from ..ops.platform import default_interpret as _default_interpret
 from ..ops.rope import apply_rope, rope_frequencies
 from .configs import ModelConfig
-
-if TYPE_CHECKING:
-    from ..ops.paged_attention import DecodeWork
 
 Params = dict[str, Any]
 KVCache = tuple[jnp.ndarray, jnp.ndarray]  # (k, v): [L, B, S, Hkv, D]
@@ -586,13 +583,13 @@ class DecodeGroup(NamedTuple):
 def _decode_attend(cfg: ModelConfig, interpret: bool, mesh):
     """``attend(q [B, Hq, D], k_pool, v_pool, work, layer)`` over the stacked
     pools, ``work`` the step's :func:`decode_work`. The kernel takes the
-    pools whole and picks the layer in its index map: a ``k_pool[layer]`` in
+    pools whole and copies a layer's pages itself: a ``k_pool[layer]`` in
     front of it would materialise 1/L of the pool."""
-    from ..ops.paged_attention import DecodeWork, paged_decode_attention
+    from ..ops.paged_attention import paged_decode_attention
 
     def attend(qq, kk, vv, work, ly):
         return paged_decode_attention(
-            qq, kk, vv, work, ly, interpret=interpret,
+            qq, kk, vv, *work, ly, interpret=interpret,
             sliding_window=cfg.sliding_window,
             scale=cfg.attention_multiplier or None)
 
@@ -601,49 +598,33 @@ def _decode_attend(cfg: ModelConfig, interpret: bool, mesh):
     from jax.sharding import PartitionSpec as P
 
     return _shard_mapped_attn(
-        mesh, attend, P(None, "tp", None),
-        (DecodeWork(*[P()] * len(DecodeWork._fields)),))
+        mesh, attend, P(None, "tp", None), ((P(None, None), P(None)),))
 
 
 def decode_page_group(cfg: ModelConfig, page_size: int, n_pages: int,
-                      itemsize: int, heads: int | None = None) -> int:
-    """Pages ``cfg``'s decode kernel takes at a time, over a table of
-    ``n_pages`` slots a row whose pool holds ``itemsize`` bytes a number: a
-    program's of the K/V kernel (what :func:`decode_work` builds its list
-    with), a trip's of the latent kernel's walk over the layers that attend
-    over everything. What the scheduler counts a row's groups of pages by.
-    ``heads``: the query heads of the kind of layer asked about, where the
-    two kinds of a model differ (``cfg.num_heads`` otherwise)."""
+                      itemsize: int, window: int | None, tp: int = 1) -> int:
+    """Pages a trip of ``cfg``'s decode kernel takes, over a table of
+    ``n_pages`` slots a row whose pool holds ``itemsize`` bytes a number,
+    in the layers behind ``window`` (None: those that attend over
+    everything), a shard of ``tp``'s: the kernel's own rule, by the shapes
+    it sees. What the scheduler counts a row's groups of pages by."""
     if cfg.is_latent:
         from ..ops.mla_attention import trip_pages
 
-        return trip_pages(page_size,
-                          None if cfg.window_layers else cfg.sliding_window)
-    from ..ops.paged_attention import decode_page_group as by_shapes
+        return trip_pages(page_size, window)
+    from ..ops.paged_attention import decode_trip_pages
 
-    return by_shapes(page_size, cfg.num_kv_heads * cfg.head_dim, itemsize,
-                     (heads or cfg.num_heads) * cfg.block_length, n_pages)
+    return decode_trip_pages(
+        page_size, cfg.num_kv_heads // tp * cfg.head_dim, itemsize, n_pages,
+        window)
 
 
-def decode_work(cfg: ModelConfig, page_table, lengths, pool,
-                window: int | None, heads: int | None = None):
-    """What the decode kernel walks in one step: ``lengths`` [B] counts the
-    tokens the step itself writes, ``pool`` is the cache the kernel reads.
-    The K/V kernel's work list (the pool's page size and its bytes a number
-    pick the group); the latent kernel walks a row's span itself and takes
-    the table and the lengths. The same for every layer of one ``window``
-    (``cfg.sliding_window``; None for the layers that attend over
-    everything, where that is some layers' alone) and one count of query
-    ``heads`` (:func:`decode_page_group`), so it is built here, outside the
-    scan over layers."""
-    if cfg.is_latent:
-        return page_table, lengths
-    from ..ops.paged_attention import decode_work_list
-
-    page_size = pool.shape[2]
-    group = decode_page_group(cfg, page_size, page_table.shape[1],
-                              pool.dtype.itemsize, heads)
-    return decode_work_list(page_table, lengths, page_size, window, group)
+def decode_work(page_table, lengths):
+    """What the decode kernel walks in one step: the page table and
+    ``lengths`` [B], which counts the tokens the step itself writes. The
+    kernel walks a row's span itself, so this is the same for every layer
+    and every window."""
+    return page_table, lengths
 
 
 def _ragged_attend(cfg: ModelConfig, interpret: bool, mesh):
@@ -725,8 +706,7 @@ def forward_paged_decode(
     positions = lengths[:, None]
     pid, off = _decode_targets(page_table, lengths, write_mask, page_size)
     attend = _decode_attend(cfg, interpret, mesh)
-    work = decode_work(cfg, page_table, lengths + 1, pools[0],
-                       cfg.sliding_window)
+    work = decode_work(page_table, lengths + 1)
 
     h = _embed_scale(embed_lookup(params["embed"], input_ids, params["final_norm"].dtype), cfg)
 
@@ -777,13 +757,11 @@ class MixedLayout(NamedTuple):
     #                            (None: no group)
 
 
-def mixed_layout(cfg: ModelConfig, input_ids, page_table, hist, q_lens,
-                 write_mask, rows, decode: DecodeGroup | None,
-                 pool, window: int | None,
-                 heads: int | None = None) -> MixedLayout:
+def mixed_layout(input_ids, page_table, hist, q_lens, write_mask, rows,
+                 decode: DecodeGroup | None, pool) -> MixedLayout:
     """Lay a mixed step's tokens out (see :func:`forward_paged_mixed`);
-    ``pool`` is the cache the attention kernels read, ``page_table``,
-    ``window`` and ``heads`` its page group's (:func:`decode_work`)."""
+    ``pool`` is the cache the attention kernels read, ``page_table`` its
+    page group's."""
     page_size = pool.shape[2]
     R, Qc = input_ids.shape
     lane_table = page_table if rows is None else page_table[rows]
@@ -803,8 +781,7 @@ def mixed_layout(cfg: ModelConfig, input_ids, page_table, hist, q_lens,
     if decode is not None:
         n_dec = decode.tokens.size
         width = decode.tokens.shape[1] if decode.tokens.ndim == 2 else None
-        work = decode_work(cfg, page_table, decode.lengths + (width or 1),
-                           pool, window, heads)
+        work = decode_work(page_table, decode.lengths + (width or 1))
         d_pid, d_off = _decode_targets(page_table, decode.lengths, decode.run,
                                        page_size, width)
         d_pos = decode.lengths if width is None else (
@@ -822,8 +799,8 @@ def mixed_attention(lay: MixedLayout, q, k_pool, v_pool, hist, q_lens, layer,
     """The one place a mixed step's token row is split: ``q`` [1, N, Hq, D]
     → attention output [1, N, Hq*D]. The lane goes through the ragged kernel
     on its own rows of the page table, the decode group through the decode
-    kernel over the layout's work list, each after the step's k/v is in the
-    pool."""
+    kernel over the layout's table and lengths, each after the step's k/v is
+    in the pool."""
     R, Qc = lay.lanes
     nd = lay.n_dec
     lane = lane_attend(q[0, nd:].reshape(R, Qc, *q.shape[2:]), k_pool, v_pool,
@@ -898,8 +875,8 @@ def forward_paged_mixed(
         interpret = _default_interpret()
     cos_t, sin_t = rope_tables
     pools, caller_shape = _merged_pools(pools)
-    lay = mixed_layout(cfg, input_ids, page_table, hist, q_lens, write_mask,
-                       rows, decode, pools[0], cfg.sliding_window)
+    lay = mixed_layout(input_ids, page_table, hist, q_lens, write_mask, rows,
+                       decode, pools[0])
     lane_attend = _ragged_attend(cfg, interpret, mesh)
     decode_attend = _decode_attend(cfg, interpret, mesh)
 

@@ -512,11 +512,10 @@ def forward_paged_decode(
     # (write targets, what the kernel walks) of the full group and of the
     # window group
     group = {}
-    for full, table, pool in zip((True, False), _tables(page_table), pools):
+    for full, table in zip((True, False), _tables(page_table)):
         group[full] = (
             _decode_targets(table, lengths, write_mask, page_size),
-            decode_work(cfg, table, lengths + 1, pool,
-                        None if full else cfg.sliding_window))
+            decode_work(table, lengths + 1))
 
     def attend(full, lp, i, x, pools):
         (pid, off), work = group[full]
@@ -559,9 +558,8 @@ def forward_paged_mixed(
     interpret = _one_device(mesh, interpret)
     cos_t, sin_t = rope_tables
     R, Qc = input_ids.shape
-    lays = {full: mixed_layout(cfg, input_ids, table, hist, q_lens,
-                               write_mask, rows, decode, pool,
-                               None if full else cfg.sliding_window)
+    lays = {full: mixed_layout(input_ids, table, hist, q_lens,
+                               write_mask, rows, decode, pool)
             for full, table, pool in zip((True, False), _tables(page_table),
                                          pools)}
     lay = lays[True]            # ids, positions and the split are both's

@@ -19,8 +19,9 @@ deep: cache layer ``t * L + l``. The weights' stack has ``L`` layers. The two
 indices meet in ONE place, ``_run_passes``: an outer ``lax.scan`` over the
 passes whose body is the scan over the layers (one compiled layer body, one
 compiled pass, whatever ``R`` and ``L`` are), the pools in both carries. The
-page table, the write targets and a decode step's work list are a step's, the
-same for all ``R x L`` kernel calls, so they are built once, outside both.
+page table, the write targets and what a decode step's kernel walks are a
+step's, the same for all ``R x L`` kernel calls, so they are built once,
+outside both.
 
 **The exit.** ``p_t = lam_t prod_{s<t}(1 - lam_s)`` for ``t < R`` and ``p_R``
 the rest; a token leaves at the first pass whose cumulated ``p`` reaches
@@ -236,8 +237,7 @@ def forward_paged_decode(
                                pools[0].shape[2])
     kernel = _decode_attend(cfg, interpret, None)
     # the step's, not a layer's: one list serves all R x L kernel calls
-    work = decode_work(cfg, page_table, lengths + 1, pools[0],
-                       cfg.sliding_window)
+    work = decode_work(page_table, lengths + 1)
 
     def attend(lp, x, layer, caches):
         k_pool, v_pool = caches
@@ -278,8 +278,8 @@ def forward_paged_mixed(
     interpret = _one_device(mesh, interpret)
     cos_t, sin_t = rope_tables
     pools, caller_shape = _merged_pools(pools)
-    lay = mixed_layout(cfg, input_ids, page_table, hist, q_lens, write_mask,
-                       rows, decode, pools[0], cfg.sliding_window)
+    lay = mixed_layout(input_ids, page_table, hist, q_lens, write_mask, rows,
+                       decode, pools[0])
     lane_attend = _ragged_attend(cfg, interpret, None)
     decode_attend = _decode_attend(cfg, interpret, None)
     n = lay.pid.shape[0]

@@ -74,7 +74,7 @@ def _block_attend(interpret: bool, width: int):
 
     def attend(qq, kk, vv, work, ly):
         out = paged_block_attention(
-            qq.reshape(-1, width, *qq.shape[1:]), kk, vv, work, ly,
+            qq.reshape(-1, width, *qq.shape[1:]), kk, vv, *work, ly,
             interpret=interpret)
         return out.reshape(qq.shape)
 
@@ -142,8 +142,7 @@ def forward_paged_decode(
                                pools[0].shape[2], W)
     pid, off = pid.reshape(-1), off.reshape(-1)
     attend = _block_attend(interpret, W)
-    work = decode_work(cfg, page_table, lengths + W, pools[0],
-                       cfg.sliding_window)
+    work = decode_work(page_table, lengths + W)
     h = embed_lookup(params["embed"], input_ids.reshape(1, B * W),
                      params["final_norm"].dtype)
 
@@ -191,8 +190,8 @@ def forward_paged_mixed(
     interpret = _one_device(mesh, interpret)
     cos_t, sin_t = rope_tables
     R, Qc = input_ids.shape
-    lay = mixed_layout(cfg, input_ids, page_table, hist, q_lens, write_mask,
-                       rows, decode, pools[0], cfg.sliding_window)
+    lay = mixed_layout(input_ids, page_table, hist, q_lens, write_mask, rows,
+                       decode, pools[0])
     nd = lay.n_dec
     lane_attend = _ragged_attend(cfg, interpret, None)
     block_attend = _block_attend(interpret, cfg.block_length)

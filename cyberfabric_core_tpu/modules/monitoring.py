@@ -444,14 +444,15 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                  "slot's device rows (restore_row at a resume or a handoff "
                  "import; none in steady serving)"),
                 ("llm_attn_pages_walked_total",
-                 "Pages the paged decode kernel's grid walked: every page "
+                 "Pages the paged decode kernel walked: every page "
                  "that holds tokens a row's query reads, summed over steps "
                  "and layers"),
                 ("llm_attn_page_groups_total",
                  "Groups of pages the decode kernel took them in, every row at "
-                 "least one: a grid program each of the K/V kernel, a trip "
-                 "each of the latent kernel's one program a row (a key block "
-                 "of ops/mla_attention.py: trip_pages pages, one score dot)"),
+                 "least one: a trip each of a decode kernel's one program a "
+                 "row (a key block of ops/paged_attention.py: "
+                 "decode_trip_pages or ops/mla_attention.py: trip_pages "
+                 "pages)"),
                 ("llm_ragged_pages_walked_total",
                  "Pages the ragged kernel's programs copied for the prompt "
                  "chunks of mixed steps (latent pages; K/V pages counted "
@@ -475,9 +476,8 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                 ("llm_attn_window_page_groups_total",
                  "Groups the decode kernel took the WINDOW layers' pages in "
                  "(llm_attn_page_groups_total then counts the layers that "
-                 "attend over everything): a grid program each of the K/V "
-                 "kernel's second work list, its group picked by the window "
-                 "layers' query heads; one trip a row of the latent kernel"),
+                 "attend over everything): one trip a row where a trip is "
+                 "the pages a window spans"),
                 ("llm_window_pages_freed_total",
                  "Window-group pages rows gave back while running: each "
                  "lay left of the window of its row's committed length"),
@@ -733,16 +733,15 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
         # query heads by the kind of attention layer, as the weights were
         # built (one number twice where a model has one kind of layer). What
         # an operator reads them for: heads over kv heads is the query rows
-        # a kv head, which picks the decode kernel's page group
-        # (``decode_page_group``), so the two say what
-        # llm_attn_pages_walked_total / llm_attn_page_groups_total and the
-        # window pair can reach when every group is full
+        # of a kv head's slab in the decode kernel, a layer's attention
+        # work beside llm_attn_pages_walked_total /
+        # llm_attn_page_groups_total and the window pair
         for name, kind, text in (
                 ("llm_attn_full_heads", 0,
                  "Query heads of a layer that attends over a row's whole "
-                 "length; over the kv heads, the query rows that pick the "
-                 "decode kernel's page group: the most pages a program of "
-                 "llm_attn_page_groups_total can walk"),
+                 "length; over the kv heads, the query rows of a kv head's "
+                 "slab in the decode kernel, whose trips "
+                 "llm_attn_page_groups_total counts"),
                 ("llm_attn_window_heads", 1,
                  "Query heads of a layer behind a sliding window (as "
                  "llm_attn_full_heads where the model has one kind); the "

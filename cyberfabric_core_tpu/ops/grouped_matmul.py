@@ -21,7 +21,7 @@ are converted block by block inside the kernel and their per-channel scale is
 applied to the f32 result.
 
 **The row tile follows from the call's shapes** (``row_tile(M, E)``, evaluated
-when the call is traced, like ``ops/paged_attention.py: decode_page_group``):
+when the call is traced, like ``ops/paged_attention.py: decode_trip_pages``):
 64 rows where the mean group has at most 64 (``M <= 64 E``), ``ROW_TILE`` =
 128 above. A work item multiplies a whole tile by its expert's block, whatever
 rows of it are real, and converts an int8 block first: over sdar's 1.57 MB

@@ -65,9 +65,7 @@ from .page_walk import (TRIP_PAGES, _VMEM_LIMIT, _Walk, _block_sizes,
 def trip_pages(page_size: int, sliding_window: int | None) -> int:
     """Pages a trip of the decode kernel takes, from shapes: ``TRIP_PAGES``,
     and no more than a window spans."""
-    if sliding_window is None:
-        return TRIP_PAGES
-    return min(TRIP_PAGES, _window_pages(sliding_window, page_size))
+    return page_walk.decode_trip_pages(page_size, sliding_window)
 
 
 def _attend_trip(q, ring_ref, slot, k_start, visible, acc_ref, m_ref, l_ref,

@@ -1,9 +1,9 @@
 """A kernel's walk over the pages of a paged cache, inside the program.
 
-What the kernels that copy their pages themselves share: the latent kernels
-(``ops/mla_attention.py``: one pool, a page read once as key and as value)
-and the ragged K/V kernel (``ops/paged_attention.py``: two pools that share
-a page id). The pools are left where they live and the page table, the spans
+What the four kernels that copy their pages themselves share: the latent
+kernels (``ops/mla_attention.py``: one pool, a page read once as key and as
+value) and the K/V kernels (``ops/paged_attention.py``, the decode kernel
+since PR 57: two pools that share a page id). The pools are left where they live and the page table, the spans
 and the layer are scalar-prefetch operands. A call's work is a sequence of
 ITEMS, one a program, in order: a decode kernel's rows (``grid=(B,)``), a
 ragged kernel's (lane, block of queries) pairs
@@ -47,8 +47,8 @@ def page_span(length, page_size: int, n_pages: int,
     """(first, last) logical page a decode query at position ``length - 1``
     reads, of a table of ``n_pages`` slots: up to the page of its own token,
     from the page that holds the window's first key. An empty slot has the
-    span (0, 0). Scalars in the kernel, ``[B]`` arrays in the work list,
-    NumPy arrays where the host counts what the grid walked."""
+    span (0, 0). Scalars in the kernel, NumPy arrays where the host counts
+    what a kernel walked."""
     last = ((length - 1) // page_size).clip(0, n_pages - 1)
     return _span_first(length, page_size, last, sliding_window), last
 
@@ -115,6 +115,17 @@ def _window_pages(sliding_window: int, page_size: int,
     """The most pages the window of ``queries`` consecutive positions spans
     (``ModelConfig.window_pages``, for a kernel that has no configuration)."""
     return (sliding_window + queries - 3) // page_size + 2
+
+
+def decode_trip_pages(page_size: int, sliding_window: int | None,
+                      most: int = TRIP_PAGES) -> int:
+    """Pages a trip of a decode kernel takes, from shapes: ``TRIP_PAGES``
+    (no more than ``most``, the kernel's own bound), and no more than a
+    window spans."""
+    pages = min(TRIP_PAGES, most)
+    if sliding_window is None:
+        return pages
+    return min(pages, _window_pages(sliding_window, page_size))
 
 
 def _block_sizes(trip: int) -> tuple[int, ...]:
@@ -251,12 +262,15 @@ class _Walk:
         return lax.select(lax.ge(slot, self.blocks),
                           lax.sub(slot, self.blocks), slot)
 
-    def open(self):
-        """The call's first program: the first ``blocks - 1`` trips go out."""
-        # a row of a key block no trip has written yet must hold numbers: a
-        # zero probability times a NaN is a NaN in the value dot. After this
-        # a block holds zeros or pages of some span, which the mask drops
-        for ring_ref in self.rings:
+    def open(self, fill: tuple | None = None):
+        """The call's first program: the first ``blocks - 1`` trips go out.
+        ``fill``: the rings read as VALUES (absent: every ring)."""
+        # a row of a key block no trip has written yet must hold numbers
+        # where it is a value: a zero probability times a NaN is a NaN in
+        # the value dot (a key's score is replaced under the mask, whatever
+        # it is). After this a block holds zeros or pages of some span,
+        # which the mask drops
+        for ring_ref in self.rings if fill is None else fill:
             ring_ref[...] = jnp.zeros_like(ring_ref)
         nxt = (self._next_busy(jnp.int32(0)), jnp.int32(0))
         if self.unroll:

@@ -2,40 +2,33 @@
 the ragged mixed-batch kernel of a prompt's chunks.
 
 The decode hot loop reads each sequence's KV history through a page table
-instead of a dense per-slot cache. The grid is one program for every GROUP
-of consecutive pages that hold tokens a slot's query reads, and no other:
+instead of a dense per-slot cache. Neither kernel has a grid over the table:
+a program walks the pages its queries read itself, through
+``ops/page_walk.py``'s ``_Walk`` (the latent kernels' walk, here over K and V
+pages that share a page id, a ring each). The pools are left where they
+live; the page table, the lengths and the layer ride in as scalar prefetch;
+a **trip** of consecutive pages of a span lands in one key block of each
+ring and is attended over as ONE block (:func:`_attend_block`): one mask by
+position, and a kv head at a time one score dot, one online-softmax update
+and one value dot over THAT head's query rows, which the wrappers hand over
+as one slab a kv head. No program and no copy exists for a slot of the table
+outside a span, and the rings run on across the programs of a call, so a
+row's last trips are in flight with the next row's first.
 
-1. once a step, outside the scan over layers, ``decode_work_list`` flattens
-   the slots' page spans into one list of (slot, first logical page,
-   ``group`` physical pages) items, slots in order and a slot's groups
-   ascending from its span's first page (a sliding window leaves a slot's
-   first pages out; an empty slot keeps one item, which computes nothing and
-   finalises to zeros). The list rides in scalar prefetch (SMEM) and its
-   length is the grid's bound, known only when the step runs: a table of
-   ``B x Pmax`` slots of which a sixth holds tokens launches a sixth of the
-   programs. ``decode_page_group`` picks the group from the page's bytes
-   and the query rows: 4 pages at 8 kv heads of 128, 8 at 4;
-2. the pools are passed once for every page of a group, each with a
-   one-page BlockSpec whose index map reads that page from the list, so the
-   pipeline DMAs exactly the pages the sequences own, each once, and
-   prefetches across the boundary between two slots. Where a slot's last
-   group runs past its span, the spare operand names the page the same
-   operand held in the item before and moves no bytes;
-3. a program joins its pages' rows into ONE block of keys: one score dot,
-   one mask (by position, so a spare operand's rows never count), one
-   online-softmax update (f32 m/l/acc scratch) and one value dot a kv head,
-   with MXU tiles that 128 and more keys fill; the accumulators are
-   initialised at a slot's first group and finalised at its last.
+The decode kernel (``paged_decode_attention``; since PR 57, ROADMAP S17,
+closed: a grid program a group of four pages before): a program is a ROW,
+``grid=(B,)``; its span is :func:`page_span` on scalars in the program (up to
+the page of its own token, from the page that holds the window's first key;
+a row at length 0 has no trips and writes zeros); a trip is
+:func:`decode_trip_pages` pages (16 of 64 tokens at 8 kv heads of 128; the 9
+a window of 512 spans, so a window layer's row is ONE trip; what
+``llm_attn_page_groups_total`` counts).
 
-The ragged kernel (``ragged_paged_attention``: the lanes of a mixed step)
-has no grid over the table at all since PR 55 (ROADMAP S18, closed): a
-program is a (lane, block of 64 queries) and walks the pages its queries see
-itself, through ``ops/page_walk.py``'s ``_Walk`` (the latent kernels' walk,
-here over K and V pages that share a page id, a ring each): the spans are
-worked out once a call and ride in as scalar prefetch, a trip of up to 16
-pages is ONE key block with one score dot, one online-softmax update and one
-value dot a kv head over its ``G x 64`` query rows, and no program and no
-copy exists for a slot of the table outside a span.
+The ragged kernel (``ragged_paged_attention``: the lanes of a mixed step;
+since PR 55, ROADMAP S18, closed): a program is a (lane, block of 64
+queries); the spans are worked out once a call and ride in as scalar
+prefetch; a trip is up to 16 pages, one key block a kv head over its ``G x
+64`` query rows.
 
 Why this beats the dense path (VERDICT r1 weak #3/#6): attention reads scale
 with the *tokens actually present* (sum of per-slot lengths), not
@@ -46,10 +39,9 @@ table, exactly the PAPERS.md "ragged paged attention for TPU" direction.
 
 Both kernels take the STACKED pool as the pool keeps it — ``[L, N, page,
 Hkv*D]``, head-major on the merged minor axis (runtime/paged.py) — and the
-layer as one more scalar-prefetch operand: a block (the decode kernel's) or
-a DMA (the ragged kernel's) is a page ``(page, Hkv*D)`` at ``(layer,
-page_table[b, jj])``, so nothing pool-sized is sliced, reshaped or copied
-in front of the call. (On a tiled TPU layout a merge of
+layer as one more scalar-prefetch operand: a DMA is a page ``(page, Hkv*D)``
+at ``(layer, page_table[b, jj])``, so nothing pool-sized is sliced, reshaped
+or copied in front of the call. (On a tiled TPU layout a merge of
 the two minor dimensions is a physical copy, and a Mosaic call takes whole
 buffers, so ``pool[layer]`` materialises: PERF.md section 6, PR 25.)
 
@@ -61,7 +53,6 @@ llm-gateway local worker (BASELINE config #2: 64 concurrent streams).
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -70,310 +61,291 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import page_walk
-from .page_walk import (_LANES, _NEG_INF, _VMEM_LIMIT, _Walk,
-                        _online_softmax_step, _span_first, _walk_scratch,
-                        page_span, ragged_span)
+from .page_walk import (_LANES, _VMEM_LIMIT, _Walk, _online_softmax_step,
+                        _walk_scratch, page_span, ragged_span)
 
 
-def _banded_weighted_v_2d(p, row_bands, v_of):
-    """The decode kernel's p@v as unrolled 2D dots — no rank-3 transpose, no
-    batched dot_general (Mosaic's dot supports only 2D operands): per-band
-    [rows, page] x [page, D] dots against ``v_of(kv)``, a kv head's [page,
-    D] value slice — a REF-level lane slice of the minor-merged [1, 1, page,
-    Hkv*D] block (the pool is stored merged): value-level bf16 lane slices
-    at non-zero tile offsets are an unlowerable relayout, ref-level sliced
-    LOADS are not. The per-band results concatenate in f32 (bf16 sublane
-    concats are an unsupported multi-row shift) back to head-major rows;
-    each output element is the same contraction the batched dot computes.
-    ``row_bands`` lists (row_start, rows, kv_head); ``p`` is f32, so its
-    sublane band slices lower (32-bit shifts are implemented, 16-bit are
-    not)."""
-    outs = [jax.lax.dot_general(
-        p[s:s + n], v_of(kv).astype(p.dtype), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) for s, n, kv in row_bands]
-    return jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
+def _whole_lane_tiles(kv_lanes: int, interpret) -> None:
+    """A walking kernel copies a page as the pool holds it, and Mosaic takes
+    a DMA only of whole lane tiles: every served model's ``Hkv * D`` (a
+    shard's under ``tp``) is a multiple of 128; a toy's 32 runs in interpret
+    mode alone."""
+    if not interpret and kv_lanes % _LANES:
+        raise ValueError(
+            f"a pool row of {kv_lanes} numbers is not whole lane tiles of "
+            f"{_LANES}: the paged kernels cannot copy its pages on the chip")
 
 
-def _paged_kernel(row_ref, page_ref, phys_ref, len_ref, last_ref, layer_ref,
-                  q_ref, *rest, page_size: int, group: int,
-                  sliding_window: int | None = None,
-                  two_d_dots: bool = False, scale: float | None = None):
-    """One work item: a GROUP of consecutive pages of one slot.
+def _attend_block(q_ref, q_at: tuple, k_ring, v_ring, slot, k_start, visible,
+                  acc_ref, m_ref, l_ref, *, n_keys: int, scale: float | None,
+                  two_d_dots: bool, first: bool, head_loop: bool):
+    """One trip of a walk over K and V pages: the first ``n_keys`` rows of
+    key block ``slot`` of the two rings are ONE block of keys whose first
+    sits at position ``k_start``, attended over by ``q_ref[q_at]`` [Hkv, GQ,
+    D] (indexed as it is loaded: a view of a block whose heads are no whole
+    lane tiles does not lower), a kv head's query rows one slab (the decode kernel's ``G`` rows padded to
+    whole sublane tiles; the ragged kernel's ``G x Qb``), over that head's
+    slab of the accumulators ``acc_ref`` [Hkv * GQ, D], ``m_ref`` / ``l_ref``
+    [Hkv * GQ, LANES]. ``visible``: the mask of a block of key positions
+    [rows, keys], the one thing the two kernels differ in (a decode row is
+    one position, a q-block one a query). ``first``: the first trip of its
+    walk, which reads nothing from the accumulators.
+
+    A kv head's score dot is ``[GQ, D] x [keys, D]`` over ITS query rows and
+    its value dot f32 ``p`` against ``v`` cast up. ``two_d_dots`` (the
+    Mosaic-lowerable form: its dot supports only 2D operands) takes a head's
+    keys and values as REF-level lane slices of the minor-merged ring block
+    (value-level bf16 lane slices at non-zero tile offsets are an
+    unlowerable relayout, ref-level sliced LOADS are not); the batched form
+    is one dot over every kv head, which Mosaic cannot lower and interpret
+    mode keeps for tier-1 wall-clock. Bitwise identical: each output element
+    is the same contraction.
+
+    ``head_loop`` says how the 2D form goes through the heads. True (the
+    ragged kernel: a head's ``G x Qb`` rows are hundreds, every head's
+    scores at once would be megabytes): a ``fori_loop`` whose body is one
+    head's score dot, online-softmax update on its slab and value dot,
+    traced once. False (the decode kernel: every head's rows are 64-128):
+    the heads' score dots written out one after another, ONE update over
+    all their rows, then the heads' value dots. In a loop a head's update
+    (vector unit) waits for its own score dot and its value dot (matrix
+    unit) for the update, head after head; written out, the dots of one
+    kind are independent and follow each other through the matrix unit. The
+    decode kernel's arithmetic alone read 517 -> 214 us a call at laguna's
+    full layers, 206 -> 76 at its window layers, 53 -> 22 at ouro's shape
+    (PERF.md section 5, PR 57)."""
+    Hkv, GQ, D = q_ref.shape[len(q_at):]
+    keys = pl.ds(0, n_keys)
+    sm_scale = 1.0 / (D ** 0.5) if scale is None else scale
+    rows = GQ if two_d_dots and head_loop else Hkv * GQ
+    mask = visible(k_start + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, n_keys), 1))
+    nt, nn = (((1,), (1,)), ((), ())), (((1,), (0,)), ((), ()))
+    if not two_d_dots:
+        k = k_ring[slot, keys].reshape(n_keys, Hkv, D)
+        scores = jax.lax.dot_general(
+            q_ref[q_at], jnp.transpose(k, (1, 2, 0)),
+            (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)         # [Hkv, GQ, keys]
+
+        def weigh(p):
+            v = v_ring[slot, keys].reshape(n_keys, Hkv, D)
+            pg = p.reshape(Hkv, GQ, n_keys)
+            return jax.lax.dot_general(
+                pg, jnp.transpose(v, (1, 0, 2)).astype(pg.dtype),
+                (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32).reshape(rows, D)
+
+        _online_softmax_step(scores.reshape(rows, n_keys) * sm_scale, mask,
+                             weigh, acc_ref, m_ref, l_ref, first)
+        return
+
+    if not head_loop:
+        def lanes(kv):
+            return pl.ds(kv * D, D)
+
+        scores = jnp.concatenate([jax.lax.dot_general(
+            q_ref[(*q_at, kv)], k_ring[slot, keys, lanes(kv)], nt,
+            preferred_element_type=jnp.float32) for kv in range(Hkv)], axis=0)
+        _online_softmax_step(
+            scores * sm_scale, mask,
+            lambda p: jnp.concatenate([jax.lax.dot_general(
+                p[kv * GQ:(kv + 1) * GQ],
+                v_ring[slot, keys, lanes(kv)].astype(p.dtype), nn,
+                preferred_element_type=jnp.float32)
+                for kv in range(Hkv)], axis=0),
+            acc_ref, m_ref, l_ref, first)
+        return
+
+    def one_head(kv, _):
+        if isinstance(kv, int):
+            head, slab = pl.ds(kv * D, D), pl.ds(kv * GQ, GQ)
+        else:
+            head = pl.ds(pl.multiple_of(lax.mul(kv, D), _LANES), D)
+            slab = pl.ds(pl.multiple_of(lax.mul(kv, GQ), 8), GQ)
+        scores = jax.lax.dot_general(
+            q_ref[(*q_at, kv)], k_ring[slot, keys, head], nt,
+            preferred_element_type=jnp.float32)         # [GQ, keys]
+        _online_softmax_step(
+            scores * sm_scale, mask,
+            lambda p: jax.lax.dot_general(
+                p, v_ring[slot, keys, head].astype(p.dtype), nn,
+                preferred_element_type=jnp.float32),
+            acc_ref.at[slab], m_ref.at[slab], l_ref.at[slab], first)
+
+    if D % _LANES:
+        # a head's lanes at an offset that is no whole lane tile: only a
+        # static slice lowers (phi-3's head of 96)
+        for kv in range(Hkv):
+            one_head(kv, None)
+    else:
+        lax.fori_loop(0, Hkv, one_head, None)
+
+
+#: K bytes (and as many of V) a key block of the decode kernel's rings holds
+#: at most: 16 pages of 64 tokens at 8 kv heads of 128 in bfloat16, 12 MB of
+#: rings, what the ragged kernel holds; ouro's 16 kv heads take 8 pages
+_TRIP_BYTES = 2 << 20
+
+
+def decode_trip_pages(page_size: int, kv_lanes: int, itemsize: int,
+                      n_pages: int, sliding_window: int | None) -> int:
+    """Pages a trip of the decode kernel takes, from shapes
+    (``page_walk.decode_trip_pages``: ``TRIP_PAGES``, and no more than the
+    window spans, so that a window layer's row is ONE trip that reads no
+    accumulator), no more than a row of the table has and no more than
+    ``_TRIP_BYTES`` of K a key block (``kv_lanes`` is ``Hkv * D``, a pool
+    row's numbers: a shard's own under ``tp``). What the scheduler counts a
+    row's trips by (``llm_attn_page_groups_total``)."""
+    return page_walk.decode_trip_pages(
+        page_size, sliding_window,
+        min(n_pages, max(1, _TRIP_BYTES // (page_size * kv_lanes * itemsize))))
+
+
+def _decode_kernel(pt_ref, len_ref, layer_ref, q_ref, k_pool_ref, v_pool_ref,
+                   o_ref, k_ring, v_ring, sem, walk_ref, acc_ref, m_ref,
+                   l_ref, *, page_size: int, trip: int,
+                   sliding_window: int | None, two_d_dots: bool,
+                   scale: float | None):
+    """One slot: the program walks its row's span itself (``ops/page_walk.py``:
+    :class:`_Walk`, an item a row; K and V of a page are copied together,
+    each into its own ring).
 
     Refs:
-      row_ref, page_ref: [N] int32 SMEM (scalar prefetch) — the item's slot
-        and the first logical page of its group (:class:`DecodeWork`)
-      phys_ref: [N*group] int32 SMEM — read by the index maps only
+      pt_ref: [B, Pmax] int32 SMEM — the page table
       len_ref: [B] int32 SMEM — valid kv length per slot (incl. current token)
-      last_ref: [B] int32 SMEM — the last page of each slot's span
-      layer_ref: [1] int32 SMEM — read by the index maps only
-      q_ref:   [1, Hq, D] VMEM; then ``group`` key refs and ``group`` value
-        refs, [1, 1, page, Hkv*D] VMEM each: the group's pages, in order
-      o_ref:   [1, Hq, D] VMEM
-      acc_ref: [Hq, D] f32; m_ref/l_ref: [Hq, LANES] f32
+      layer_ref: [1] int32 SMEM
+      q_ref:   [1, Hkv, Gp, D] VMEM — a kv head's ``G`` query rows one slab,
+        padded to whole sublane tiles (the wrapper's layout)
+      k_pool_ref, v_pool_ref: the whole stacked pools, where they live
+      o_ref:   [1, Hkv, Gp, D] VMEM
+      k_ring, v_ring, sem, walk_ref, acc_ref, m_ref, l_ref:
+        :func:`_walk_scratch` of two pools at ``Hkv * Gp`` rows
 
-    The group's keys are ONE block of ``group * page`` rows: one score dot,
-    one mask, one online-softmax update and one value dot a kv head. A
-    slot's last group may hold fewer pages than ``group``; what its other
-    operands hold lies past the slot's length and is masked by position.
+    A trip's pages are ONE key block (:func:`_attend_block`): one score dot,
+    one online-softmax update and one value dot a kv head over ITS query
+    rows, under one mask by position. A row at length 0 has no trips and
+    writes zeros."""
+    b = pl.program_id(0)
+    n_rows, n_pages = pt_ref.shape
+    walk = _Walk(pt_ref, layer_ref, (k_pool_ref, v_pool_ref),
+                 (k_ring, v_ring), sem, walk_ref, n_items=n_rows, trip=trip,
+                 sizes=kv_block_sizes(trip), page_size=page_size,
+                 idle=lambda row: len_ref[row] == 0,
+                 span=lambda row: page_span(len_ref[row], page_size, n_pages,
+                                            sliding_window),
+                 row=lambda row: row, unroll=False)
 
-    ``two_d_dots`` replaces the batched GQA dot_generals (and their rank-3
-    operand transposes) with unrolled per-kv-head 2D dots — the form Mosaic
-    can lower (its dot supports only 2D tensors); bitwise-identical to the
-    batched form, which interpret mode keeps for tier-1 wall-clock.
-    """
-    k_refs, v_refs = rest[:group], rest[group:2 * group]
-    o_ref, acc_ref, m_ref, l_ref = rest[2 * group:]
-    i = pl.program_id(0)
-    j = page_ref[i]
-    length = len_ref[row_ref[i]]
-    last = last_ref[row_ref[i]]
-    keys = group * page_size
+    @pl.when(b == 0)
+    def _open():
+        walk.open(fill=(v_ring,))
 
-    @pl.when(j == _span_first(length, page_size, last, sliding_window))
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    length = len_ref[b]
 
-    k_start = j * page_size
+    @pl.when(length == 0)
+    def _idle():
+        o_ref[...] = jnp.zeros_like(o_ref)
 
-    def rows_of(refs, lanes):
-        """The group's pages as one block of ``keys`` rows (a lane slice of
-        each, taken at the ref: see :func:`_banded_weighted_v_2d`; a page of 16
-        rows or a multiple is whole 16-bit sublane tiles, so the join is no
-        multi-row shift)."""
-        parts = [r[0, 0, :, lanes] for r in refs]
-        return parts[0] if group == 1 else jnp.concatenate(parts, axis=0)
+    # the slot's own tokens, and nothing past the table (a length past it)
+    bound = lax.min(length, n_pages * page_size)
 
-    @pl.when(k_start < length)      # every item but an empty slot's
-    def _compute():
-        q = q_ref[0]          # [Hq, D]
-        Hq, D = q.shape
-        Hkv = k_refs[0].shape[3] // D
-        G = Hq // Hkv
-        head = lambda kv: slice(kv * D, (kv + 1) * D)  # noqa: E731
+    def visible(k_pos):
+        mask = k_pos < bound
+        if sliding_window is not None:      # the query sits at length - 1
+            mask &= k_pos >= length - sliding_window
+        return mask
 
-        if two_d_dots:
-            # merged kv blocks ([1, 1, page, Hkv*D]): each head is a REF-level
-            # lane slice. q's rows are head-major but a bf16 SUBLANE band
-            # slice is itself an unlowerable multi-row shift — so each kv
-            # head dots the FULL q block against its key slice and the band
-            # rows are carved out of the f32 result (32-bit sublane slices
-            # lower fine). The retained elements are the same contractions
-            # the batched dot computes: bitwise identical, a little
-            # redundant MXU work on a tiny [Hq, D] operand.
-            per_head = [jax.lax.dot_general(
-                q, rows_of(k_refs, head(kv)), (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)[kv * G:(kv + 1) * G]
-                for kv in range(Hkv)]
-            scores = jnp.concatenate(per_head, axis=0) if Hkv > 1 \
-                else per_head[0]                     # [Hq, keys]
-        else:
-            k = rows_of(k_refs, slice(None)).reshape(keys, Hkv, D)
-            qg = q.reshape(Hkv, G, D)
-            kt = jnp.transpose(k, (1, 2, 0))        # [Hkv, D, keys]
-            scores = jax.lax.dot_general(
-                qg, kt, (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)  # [Hkv, G, keys]
-            scores = scores.reshape(Hq, keys)
-        scores = scores * (1.0 / (D ** 0.5) if scale is None else scale)
+    @pl.when(length > 0)
+    def _busy():
+        def attend(slot, k_start, *, pages, first):
+            _attend_block(q_ref, (0,), k_ring, v_ring, slot, k_start,
+                          visible, acc_ref, m_ref, l_ref,
+                          n_keys=pages * page_size, scale=scale,
+                          two_d_dots=two_d_dots, first=first,
+                          head_loop=False)
 
-        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (Hq, keys), 1)
-        # by position: the slot's own tokens, and nothing of a page past its
-        # span (a last group's spare operands; a length past the table)
-        mask = k_pos < jnp.minimum(length, (last + 1) * page_size)
-        if sliding_window is not None:
-            mask = mask & (k_pos > length - 1 - sliding_window)
-        scores = jnp.where(mask, scores, _NEG_INF)
-
-        m_prev = m_ref[...]
-        m_blk = jnp.max(scores, axis=1, keepdims=True)      # [Hq, 1]
-        m_new = jnp.maximum(m_prev, jax.lax.broadcast_in_dim(
-            m_blk, m_prev.shape, (0, 1)))
-        m_ref[...] = m_new
-        correction = jnp.exp(m_prev - m_new)                # [Hq, LANES]
-        p = jnp.exp(scores - m_new[:, :1])                  # [Hq, keys]
-        p = jnp.where(mask, p, 0.0)
-        l_blk = jnp.sum(p, axis=1, keepdims=True)
-        l_ref[...] = l_ref[...] * correction + jax.lax.broadcast_in_dim(
-            l_blk, m_prev.shape, (0, 1))
-        if two_d_dots:
-            pv = _banded_weighted_v_2d(
-                p, [(kv * G, G, kv) for kv in range(Hkv)],
-                lambda kv: rows_of(v_refs, head(kv)))
-        else:
-            v = rows_of(v_refs, slice(None)).reshape(keys, Hkv, D)
-            pg = p.reshape(Hkv, G, keys)
-            vt = jnp.transpose(v, (1, 0, 2))                # [Hkv, keys, D]
-            pv = jax.lax.dot_general(
-                pg, vt.astype(pg.dtype), (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32).reshape(Hq, D)
-        acc_ref[...] = acc_ref[...] * correction[:, :1] + pv
-
-    @pl.when(j + group > last)
-    def _finalize():
+        walk.run(b, attend)
         denom = jnp.maximum(l_ref[...][:, :1], 1e-30)
-        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / denom).reshape(
+            o_ref.shape[1:]).astype(o_ref.dtype)
 
 
-class DecodeWork(NamedTuple):
-    """The decode kernel's grid, flattened: one item for every GROUP of
-    consecutive pages that hold tokens a slot's query reads. Slots in order,
-    a slot's groups ascending from its span's first page, every slot at
-    least one item (an empty slot's computes nothing and finalises to
-    zeros). With ``N = B * ceil(Pmax / group)`` the arrays are as long as a
-    full table needs; the grid runs the first ``n_items``."""
-    row: jnp.ndarray       # [N] int32 the item's slot
-    page: jnp.ndarray      # [N] int32 the first logical page of its group
-    phys: jnp.ndarray      # [N*group] int32 the group's physical pages
-    lengths: jnp.ndarray   # [B] int32 valid kv length (incl. current token)
-    last: jnp.ndarray      # [B] int32 the last page of the slot's span
-    n_items: jnp.ndarray   # [] int32 items in use: the grid's bound
-
-    @property
-    def group(self) -> int:
-        """Pages an item takes (static: read off the arrays' shapes)."""
-        return self.phys.shape[0] // self.row.shape[0]
-
-
-#: K and V bytes one program should move. A program costs 0.30-0.35 us
-#: before it has moved a byte (the grid step, the m/l/acc round trip, tiles
-#: the MXU latches half full), what 1 MB takes to arrive four times over; a
-#: larger group buys under a tenth at a full table, and where rows hold a few
-#: pages it computes more keys that the mask then drops (PERF.md, PR 38)
-_GROUP_BYTES = 1 << 20
-#: VMEM a group may take, of the 16 MB a v5e kernel gets by default
-_GROUP_VMEM = 8 << 20
-
-
-def decode_page_group(page_size: int, kv_lanes: int, itemsize: int,
-                      q_rows: int, n_pages: int) -> int:
-    """Pages one program of the decode kernel takes: the largest power of
-    two (at most 8: 16 bought under 1% at a full table in three shapes of
-    four; and no more than a row of the table has) whose K and V bytes stay
-    within ``_GROUP_BYTES`` and whose blocks fit ``_GROUP_VMEM``.
-    ``kv_lanes`` is ``Hkv * D``, a pool row's numbers; ``q_rows`` the query
-    rows of a slot (``Hq``; an open block folds its width in). At 8 kv heads
-    of 128 in bfloat16 that is 4 pages, at 4 kv heads 8."""
-    page_bytes = page_size * kv_lanes * itemsize
-    # two pools, double-buffered, and as much again where a kv head's rows
-    # are joined and V is cast up; the f32 scores, their mask and ``p``
-    vmem = 4 * page_bytes + 3 * 4 * q_rows * page_size
-    group = 1
-    while (group < 8 and 2 * group <= n_pages
-           and 4 * group * page_bytes <= _GROUP_BYTES
-           and 2 * group * vmem <= _GROUP_VMEM):
-        group *= 2
-    return group
-
-
-def decode_work_list(page_table: jnp.ndarray, lengths: jnp.ndarray,
-                     page_size: int, sliding_window: int | None = None,
-                     group: int = 1) -> DecodeWork:
-    """The work list of one decode step (:class:`DecodeWork`) from
-    ``page_table`` [B, Pmax] and ``lengths`` [B] (incl. the current token),
-    a slot's span (:func:`page_span`) taken ``group`` pages at a time. It is
-    the same for every layer: build it once a step, outside the scan over
-    layers.
-
-    A slot's last group may run past its span. Such an operand names the
-    page the SAME operand held in the item before (the pipeline fetches a
-    block only where its index changed, so it moves no bytes), and the
-    kernel masks what it holds by position."""
-    B, Pmax = page_table.shape
-    lengths = jnp.asarray(lengths, jnp.int32)
-    first, last = page_span(lengths, page_size, Pmax, sliding_window)
-    groups = (last - first) // group + 1                    # [B], >= 1
-    ends = jnp.cumsum(groups)
-    item = jnp.arange(B * -(-Pmax // group), dtype=jnp.int32)
-    # items past n_items are never run; they name the last slot's last group
-    row = jnp.minimum(
-        jnp.sum(item[:, None] >= ends[None, :], axis=1, dtype=jnp.int32),
-        B - 1)
-    page = first[row] + group * jnp.minimum(
-        item - (ends - groups)[row], groups[row] - 1)
-    lane = jnp.arange(group, dtype=jnp.int32)[None, :]
-    pages = page[:, None] + lane                            # [N, group]
-    in_span = pages <= last[row][:, None]
-    phys = jnp.asarray(page_table, jnp.int32)[
-        row[:, None], jnp.minimum(pages, last[row][:, None])]
-    # the newest item at or before this one whose operand held a page (the
-    # first item, where none has yet)
-    held_at = jax.lax.cummax(jnp.where(in_span, item[:, None], 0), axis=0)
-    phys = phys[held_at, lane]
-    return DecodeWork(row, page, phys.reshape(-1), lengths, last, ends[-1])
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "sliding_window",
-                                             "two_d_dots", "scale", "name"))
+@functools.partial(jax.jit, static_argnames=(
+    "interpret", "sliding_window", "two_d_dots", "scale", "name", "trip"))
 def paged_decode_attention(
     q: jnp.ndarray,           # [B, Hq, D] — one query token per slot
     k_pool: jnp.ndarray,      # [L, N, page, Hkv*D] — the stacked page pool
     v_pool: jnp.ndarray,
-    work: DecodeWork,         # decode_work_list(page_table, lengths, ...)
+    page_table: jnp.ndarray,  # [B, Pmax] int32 physical page ids
+    lengths: jnp.ndarray,     # [B] int32 valid length (incl. current token)
     layer: jnp.ndarray | int = 0,  # scalar int32 — which layer's pages
-    interpret: bool = False,
+    *,
+    interpret: bool | pltpu.InterpretParams = False,
     sliding_window: int | None = None,
     two_d_dots: bool | None = None,
     scale: float | None = None,
     name: str | None = None,      # the call site's, in a device trace
+    trip: int | None = None,      # a test's or a probe's pages a trip
 ) -> jnp.ndarray:
     """Returns [B, Hq, D] attention over each slot's paged history in layer
-    ``layer`` of the pool. The pool operands reach the ``pallas_call`` as
-    they are passed, once for every page of a group (``work.group``): the
-    layer and the page are both picked by the blocks' index maps, so the
-    pipeline DMAs whole pages and nothing pool-sized is sliced or copied.
-    ``sliding_window`` is the one ``work`` was built with; ``scale`` is the
-    softmax scale where the model gives one (absent: ``D^-1/2``); ``name`` is
-    what a device trace calls this call site's kernel (absent: the kernel's
-    own name), for a model that calls it for two kinds of layer.
+    ``layer`` of the pool. One program a slot, in order; the pools stay
+    where they live and the programs copy the pages of a row's span
+    (:func:`page_span`: up to the page of its own token, from the page that
+    holds the window's first key) themselves, K and V of a page together,
+    :func:`decode_trip_pages` at a time. ``q`` goes in with a kv head's
+    ``G`` query rows one slab, padded to whole sublane tiles, and the output
+    comes back the same way (request-sized reshapes, here). At ``trip=1``
+    the sums run in the order of the grid this kernel had up to PR 56 at a
+    page a program and give its bytes; at the shipped trip it is another
+    order of the same sums.
 
-    ``two_d_dots`` (default: on exactly when compiling for real — Mosaic's
-    dot supports only 2D tensors) selects the unrolled per-kv-head 2D-dot
-    body, which lane-slices the merged block; interpret mode keeps the
-    batched form for tier-1 wall-clock and un-merges the loaded block, which
-    Mosaic could not lower and a CPU does for nothing. The two are
+    ``scale`` is the softmax scale where the model gives one (absent:
+    ``D^-1/2``); ``name`` is what a device trace calls this call site's
+    kernel (absent: the kernel's own name), for a model that calls it for
+    two kinds of layer.
+
+    ``two_d_dots`` (default: on exactly when compiling for real) runs the kv
+    heads' 2D dots one after another over ref-level lane slices, where the
+    batched form is one dot over every kv head, which Mosaic cannot lower;
     bitwise-identical (golden-pinned)."""
     if two_d_dots is None:
         two_d_dots = not interpret
     B, Hq, D = q.shape
     _, _, page_size, HD = k_pool.shape
-    group = work.group
+    _whole_lane_tiles(HD, interpret)
+    Hkv = HD // D
+    G = Hq // Hkv
+    Gp = -(-G // 8) * 8
+    trip = trip or decode_trip_pages(page_size, HD, k_pool.dtype.itemsize,
+                                     page_table.shape[1], sliding_window)
+    slabs = (B, Hkv, Gp, D)
+    q = q.reshape(B, Hkv, G, D)
+    if Gp != G:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
 
-    def page_spec(g: int) -> pl.BlockSpec:
-        return pl.BlockSpec(
-            (1, 1, page_size, HD),
-            lambda i, row, page, phys, ln, last, ly: (
-                ly[0], phys[i * group + g], 0, 0))
+    def at_row(i, *_):
+        return (i, 0, 0, 0)
 
-    q_spec = pl.BlockSpec(
-        (1, Hq, D), lambda i, row, page, phys, ln, last, ly: (row[i], 0, 0))
-    pages = [page_spec(g) for g in range(group)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
-        grid=(work.n_items,),
-        in_specs=[q_spec, *pages, *pages],
-        out_specs=q_spec,
-        scratch_shapes=[
-            pltpu.VMEM((Hq, D), jnp.float32),
-            pltpu.VMEM((Hq, _LANES), jnp.float32),
-            pltpu.VMEM((Hq, _LANES), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_paged_kernel, page_size=page_size, group=group,
+    block_spec = pl.BlockSpec((1, *slabs[1:]), at_row)
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, page_size=page_size, trip=trip,
                           sliding_window=sliding_window,
                           two_d_dots=two_d_dots, scale=scale),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(B,),
+            in_specs=[block_spec, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=block_spec,
+            scratch_shapes=_walk_scratch(trip, page_size, HD, k_pool.dtype,
+                                         Hkv * Gp, D, pools=2)),
+        out_shape=jax.ShapeDtypeStruct(slabs, q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-        ),
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret, name=name,
-    )(work.row, work.page, work.phys, work.lengths, work.last,
-      jnp.asarray(layer, jnp.int32).reshape(1), q,
-      *([k_pool] * group), *([v_pool] * group))
+    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q, k_pool, v_pool)
+    return out[:, :, :G].reshape(B, Hq, D)
 
 
 #: accumulator bytes a q-block may hold (acc, m and l: three rows of 128
@@ -417,14 +389,15 @@ def ragged_trip_pages(page_size: int, sliding_window: int | None,
                                        RAGGED_TRIP_KEYS)
 
 
-def ragged_block_sizes(trip: int) -> tuple[int, ...]:
-    """The key blocks a trip of the ragged kernel is attended over as, in
-    pages: the trip, and 4 pages (256 keys) for what a short lane, a
+def kv_block_sizes(trip: int) -> tuple[int, ...]:
+    """The key blocks a trip of either K/V kernel is attended over as, in
+    pages: the trip, and 4 pages (256 keys) for what a short row or lane, a
     q-block with little behind it or a span's last trip holds. Two and not
     the latent kernels' four (2 / 4 / 8 / 16): each size is two traced bodies
-    of the attend in every program the kernel sits in, and with four the
+    of the attend in every program a kernel sits in, and with four the
     16-row cells paid for them at every start (qwen2 ``jax_trace_lower_s``
-    11.7 -> 13.4 s on a warm traced pair; PERF.md, PR 55)."""
+    11.7 -> 13.4 s on a warm traced pair; PERF.md, PR 55); the decode
+    kernel reads the same within 1-6% at one, two or four (PR 57)."""
     return tuple(sorted({min(4, trip), trip}))
 
 
@@ -472,14 +445,9 @@ def _ragged_kernel(pt_ref, first_ref, last_ref, hist_ref, qlen_ref, layer_ref,
     ``sliding_window`` keys of it. Accumulator rows are flat ``r = h*Qb +
     qi`` (h = kv*G + g), the decode kernel's head grouping.
 
-    A trip's pages are ONE key block: one score dot, one online-softmax
-    update and one value dot a kv head, over its slab of the accumulators,
-    under one mask by query position. ``two_d_dots`` (the Mosaic-lowerable
-    form) takes a head's keys and values as REF-level lane slices of the
-    minor-merged ring block (:func:`_banded_weighted_v_2d` says why) and runs
-    the heads one after another; the batched form is one dot over every kv
-    head, which Mosaic cannot lower and interpret mode keeps for tier-1
-    wall-clock. Bitwise identical.
+    A trip's pages are ONE key block (:func:`_attend_block`): one score dot,
+    one online-softmax update and one value dot a kv head, over its slab of
+    the accumulators, under one mask by query position.
 
     ``block`` (a power of two; 1 = causal): the mask of a model that
     generates by diffusion over blocks — causal between blocks of that many
@@ -492,14 +460,14 @@ def _ragged_kernel(pt_ref, first_ref, last_ref, hist_ref, qlen_ref, layer_ref,
     walk = _Walk(pt_ref, layer_ref, (k_pool_ref, v_pool_ref),
                  (k_ring, v_ring), sem, walk_ref,
                  n_items=first_ref.shape[0], trip=trip,
-                 sizes=ragged_block_sizes(trip), page_size=page_size,
+                 sizes=kv_block_sizes(trip), page_size=page_size,
                  idle=lambda item: last_ref[item] < first_ref[item],
                  span=lambda item: (first_ref[item], last_ref[item]),
                  row=lambda item: lax.div(item, q_blocks), unroll=False)
 
     @pl.when(item == 0)
     def _open():
-        walk.open()
+        walk.open(fill=(v_ring,))
 
     hist, qlen = hist_ref[b], qlen_ref[b]
     q0 = qb * q_block
@@ -523,60 +491,10 @@ def _ragged_kernel(pt_ref, first_ref, last_ref, hist_ref, qlen_ref, layer_ref,
         return mask
 
     def attend(slot, k_start, *, pages, first):
-        keys = pl.ds(0, pages * page_size)
-        n_keys = pages * page_size
-        sm_scale = 1.0 / (D ** 0.5) if scale is None else scale
-        rows = GQ if two_d_dots else Hkv * GQ
-        mask = visible(k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, n_keys), 1))
-        if not two_d_dots:
-            k = k_ring[slot, keys].reshape(n_keys, Hkv, D)
-            scores = jax.lax.dot_general(
-                q_ref[0, 0], jnp.transpose(k, (1, 2, 0)),
-                (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)     # [Hkv, GQ, keys]
-
-            def weigh(p):
-                v = v_ring[slot, keys].reshape(n_keys, Hkv, D)
-                pg = p.reshape(Hkv, GQ, n_keys)
-                return jax.lax.dot_general(
-                    pg, jnp.transpose(v, (1, 0, 2)).astype(pg.dtype),
-                    (((2,), (1,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32).reshape(rows, D)
-
-            _online_softmax_step(scores.reshape(rows, n_keys) * sm_scale,
-                                 mask, weigh, acc_ref, m_ref, l_ref, first)
-            return
-
-        def one_head(kv, _):
-            if isinstance(kv, int):
-                head, slab = pl.ds(kv * D, D), pl.ds(kv * GQ, GQ)
-            else:
-                head = pl.ds(pl.multiple_of(lax.mul(kv, D), _LANES), D)
-                slab = pl.ds(pl.multiple_of(lax.mul(kv, GQ), 8), GQ)
-            scores = jax.lax.dot_general(
-                q_ref[0, 0, kv], k_ring[slot, keys, head],
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)     # [GQ, keys]
-            _online_softmax_step(
-                scores * sm_scale, mask,
-                lambda p: jax.lax.dot_general(
-                    p, v_ring[slot, keys, head].astype(p.dtype),
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32),
-                acc_ref.at[slab], m_ref.at[slab], l_ref.at[slab], first)
-
-        if D % _LANES:
-            # a head's lanes at an offset that is no whole lane tile: only a
-            # static slice lowers (phi-3's head of 96)
-            for kv in range(Hkv):
-                one_head(kv, None)
-        else:
-            # a loop: the body is a kv head's G x Qb rows of dots, long
-            # enough not to feel it, and traced and lowered once a kernel
-            # where unrolled heads cost 8-16 bodies in every program the
-            # kernel sits in (set-up: PERF.md, PRs 53 and 55)
-            lax.fori_loop(0, Hkv, one_head, None)
+        _attend_block(q_ref, (0, 0), k_ring, v_ring, slot, k_start, visible,
+                      acc_ref, m_ref, l_ref, n_keys=pages * page_size,
+                      scale=scale, two_d_dots=two_d_dots, first=first,
+                      head_loop=True)
 
     @pl.when(q0 < qlen)
     def _busy():
@@ -650,6 +568,7 @@ def ragged_paged_attention(
         raise ValueError(f"block {block} must be a power of two")
     R, Qc, Hq, D = q.shape
     _, _, page_size, HD = k_pool.shape
+    _whole_lane_tiles(HD, interpret)
     Hkv = HD // D
     G = Hq // Hkv
     if Qc % 8:
@@ -699,23 +618,22 @@ def ragged_paged_attention(
     return out[:, :Qc] if width != Qc else out
 
 
-def paged_block_attention(q, k_pool, v_pool, work: DecodeWork, layer=0,
-                          interpret: bool = False):
+def paged_block_attention(q, k_pool, v_pool, page_table, lengths, layer=0,
+                          interpret: bool = False, trip: int | None = None):
     """The open block of a model that generates by diffusion over blocks:
     ``q`` [B, W, Hq, D], the block's W queries a row, every one of which sees
-    all of ``work.lengths`` keys (the row's kept history and the block
-    itself, which the caller has written). That is
-    :func:`paged_decode_attention` with the block folded into the GQA group
-    axis, W x G query rows a kv head, so the pages are walked once a row and
-    not once a position. No sliding window: ``work`` is built without one.
-    Returns [B, W, Hq, D]."""
+    all of ``lengths`` keys (the row's kept history and the block itself,
+    which the caller has written). That is :func:`paged_decode_attention`
+    with the block folded into the GQA group axis, W x G query rows a kv
+    head's slab, so the pages are walked once a row and not once a position.
+    No sliding window. Returns [B, W, Hq, D]."""
     B, W, Hq, D = q.shape
     Hkv = k_pool.shape[3] // D
     G = Hq // Hkv
     folded = q.reshape(B, W, Hkv, G, D).transpose(0, 2, 1, 3, 4)
     out = paged_decode_attention(
-        folded.reshape(B, Hkv * W * G, D), k_pool, v_pool, work, layer,
-        interpret=interpret)
+        folded.reshape(B, Hkv * W * G, D), k_pool, v_pool, page_table,
+        lengths, layer, interpret=interpret, trip=trip)
     return out.reshape(B, Hkv, W, G, D).transpose(0, 2, 1, 3, 4).reshape(
         B, W, Hq, D)
 

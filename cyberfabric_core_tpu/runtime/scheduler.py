@@ -3446,10 +3446,9 @@ class ContinuousBatchingEngine:
     def _count_attn_pages(self, kept: np.ndarray, grew: np.ndarray) -> dict:
         """/metrics of the decode kernel's walk over a drained dispatch: the
         pages it walked (those that hold tokens a row's query reads) and the
-        groups it took them in (``decode_page_group``: a grid program each
-        of the K/V kernel, the group the work list was built with; a trip
-        each of the latent kernel, which runs one program a row), beside the
-        page table's slots, summed over forwards and layers. ``kept`` [B]:
+        groups it took them in (``decode_page_group``: a trip each of the
+        kernel's walk, which runs one program a row), beside the page
+        table's slots, summed over forwards and layers. ``kept`` [B]:
         each row's length going in; ``grew`` [B, forwards]: whether that
         forward added a step's tokens to it (a frozen row stops growing). A
         row that does not run sits at length 0 on the device and is counted
@@ -3469,12 +3468,14 @@ class ContinuousBatchingEngine:
         cfg, page = self.model_config, self.config.prefix_page_size
         # a model with a window page group: the layers that attend over
         # everything here, its window layers under a pair of their own
-        first, last = page_span(lengths, page, slots,
-                                None if self._two_groups
-                                else cfg.sliding_window)
+        window = None if self._two_groups else cfg.sliding_window
+        first, last = page_span(lengths, page, slots, window)
         layers = cfg.kv_layers                   # the layers that attend
-        group = decode_page_group(cfg, page, slots,
-                                  jnp.dtype(self.dtype).itemsize)
+        itemsize = jnp.dtype(self.dtype).itemsize
+        # the shard the kernel sees: the kv heads split over ``tp`` only
+        # where the pool does (a replicated pool: the whole of them)
+        tp = self.tp if self._attn_mesh is not None else 1
+        group = decode_page_group(cfg, page, slots, itemsize, window, tp)
         walked = int((last - first + 1).sum()) * layers
         bump_counter("llm_attn_pages_walked_total", n=walked)
         bump_counter("llm_attn_page_groups_total",
@@ -3485,13 +3486,10 @@ class ContinuousBatchingEngine:
             return {}
         first, last = page_span(lengths, page, slots, cfg.sliding_window)
         window_walked = int((last - first + 1).sum()) * cfg.window_layers
-        # the window layers' groups: the K/V kernel's second work list is
-        # grid programs too, its group picked by those layers' query heads;
-        # a latent window layer takes its span in one trip a program
-        window_groups = lengths.size if cfg.is_latent else int(
-            ((last - first) // decode_page_group(
-                cfg, page, slots, jnp.dtype(self.dtype).itemsize,
-                cfg.window_heads) + 1).sum())
+        # the window layers' trips: one a row where the trip is what the
+        # window spans
+        window_groups = int(((last - first) // decode_page_group(
+            cfg, page, slots, itemsize, cfg.sliding_window, tp) + 1).sum())
         bump_counter("llm_attn_window_pages_walked_total", n=window_walked)
         bump_counter("llm_attn_window_page_groups_total",
                      n=window_groups * cfg.window_layers)

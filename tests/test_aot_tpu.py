@@ -93,22 +93,23 @@ def test_flash_kernel_compiles_at_mistral_7b_shapes(one_chip, batch):
     (128, 4, 16, 32, None),     # sdar: 4 x 8 query rows folded on a kv head
     (48, 8, 64, 128, None),     # laguna's full layers: 6 queries a kv head
     (72, 8, 64, 128, 512),      # its window layers: 9, a window that binds
+    (16, 16, 8, 13, None),      # ouro: one query row a kv head, 8-page trips
+    (32, 2, 64, 64, None),      # nemotron: 16 query rows on each of 2 kv heads
 ], ids=["mistral-7b", "qwen2-7b", "sdar-folded", "laguna-full",
-        "laguna-window"])
+        "laguna-window", "ouro", "nemotron"])
 def test_paged_decode_kernel_compiles_at_served_shapes(
         one_chip, heads, kv_heads, batch, pmax, window):
-    """The grid over the pages in use, a slot's several to a program (the
-    group these shapes are served with): a work list in scalar prefetch and
-    a grid bound that is known only when the step runs."""
+    """A program a row that walks its pages itself (the trip these shapes
+    are served with): the pools left where they live, the table and the
+    lengths in scalar prefetch, a kv head's query rows one padded slab."""
     from cyberfabric_core_tpu.ops.paged_attention import (
-        decode_page_group, decode_work_list, paged_decode_attention)
+        paged_decode_attention)
 
     pool = one_chip((2, batch * pmax * 5 // 4 + 1, _PAGE, kv_heads * _D),
                     jnp.bfloat16)
-    group = decode_page_group(_PAGE, kv_heads * _D, 2, heads, pmax)
     _compiles_with_mosaic(
         lambda q, k, v, pt, n, layer: paged_decode_attention(
-            q, k, v, decode_work_list(pt, n, _PAGE, window, group), layer,
+            q, k, v, pt, n, layer,
             interpret=False, sliding_window=window, two_d_dots=True),
         one_chip((batch, heads, _D), jnp.bfloat16), pool, pool,
         one_chip((batch, pmax), jnp.int32), one_chip((batch,), jnp.int32),
@@ -224,16 +225,31 @@ def test_chip_smoke_fails_without_a_tpu():
 
 # ---- whole programs (minutes each)
 
+@pytest.fixture()
+def lane_wide_tiny(monkeypatch):
+    """tiny-llama with kv rows of one whole lane tile (2 kv heads of 64): a
+    kernel that copies its pages itself takes a page as the pool holds it,
+    and Mosaic refuses a DMA of 32 lanes (no served model is that narrow)."""
+    import dataclasses
+
+    from cyberfabric_core_tpu.models.configs import MODEL_CONFIGS
+
+    name = "tiny-llama-d64"
+    monkeypatch.setitem(MODEL_CONFIGS, name, dataclasses.replace(
+        MODEL_CONFIGS["tiny-llama"], name=name, head_dim=64))
+    return name
+
+
 @slow
 @pytest.mark.parametrize("quant", ["none", "int8", "int4"])
-def test_serving_set_compiles_for_v5e(quant):
+def test_serving_set_compiles_for_v5e(quant, lane_wide_tiny):
     """Flash prefill + fused paged-decode chunk lower for the TPU target in
     every quantization rung, with real Mosaic kernels in the module."""
     _topo_or_skip()
     from cyberfabric_core_tpu.runtime.aot_tpu import aot_compile
 
     report = aot_compile(
-        "tiny-llama", quantization=quant, topology="v5e:2x2",
+        lane_wide_tiny, quantization=quant, topology="v5e:2x2",
         prefill_bucket=64, decode_chunk=4, max_batch=2, max_seq_len=128)
     names = {p["name"] for p in report["programs"]}
     assert names == {"prefill-flash-b1x64", "paged-decode-k4x2"}
@@ -289,7 +305,7 @@ def test_serialize_without_out_dir_is_a_clear_error():
 
 
 @slow
-def test_serialized_executable_roundtrip(tmp_path):
+def test_serialized_executable_roundtrip(tmp_path, lane_wide_tiny):
     """serialize=True writes deserializable TPU executables with digests —
     what a TPU host loads to skip compilation entirely."""
     _topo_or_skip()
@@ -301,7 +317,7 @@ def test_serialized_executable_roundtrip(tmp_path):
     from cyberfabric_core_tpu.runtime.aot_tpu import read_serialized
 
     report = aot_compile(
-        "tiny-llama", quantization="int8", topology="v5e:2x2",
+        lane_wide_tiny, quantization="int8", topology="v5e:2x2",
         prefill_bucket=32, decode_chunk=2, max_batch=2, max_seq_len=64,
         out_dir=tmp_path, serialize=True)
     manifest = json.loads((tmp_path / "aot_manifest.json").read_text())
@@ -1327,7 +1343,7 @@ def test_the_loop_is_a_loop_in_the_lowered_programs():
     ``while``, inside the chunk's), not ``loop_steps`` unrolled copies: each
     layer matrix is multiplied at ONE site, and the pool is donated."""
     from cyberfabric_core_tpu.ops.paged_attention import (
-        ragged_block_sizes, ragged_trip_pages)
+        kv_block_sizes, ragged_trip_pages)
 
     cfg, pool, programs = _ouro_programs(
         "tiny-ouro", "benchmark/tests/rehearsal/configs/tiny-ouro.json", None)
@@ -1343,7 +1359,7 @@ def test_the_loop_is_a_loop_in_the_lowered_programs():
         # walk holds a score and a value dot for every block size of a
         # trip, for a q-block's first trip and for its later ones.)
         walk = 0 if name == "paged_decode_chunk" else 4 * len(
-            ragged_block_sizes(ragged_trip_pages(pool.shape[2], None, 32)))
+            kv_block_sizes(ragged_trip_pages(pool.shape[2], None, 32)))
         dots = len(re.findall(r"stablehlo\.dot_general", text))
         assert dots < 3 * 7 + walk, (name, dots, walk)
         assert text.count("tf.aliasing_output") >= 2, name

@@ -15,7 +15,7 @@ from cyberfabric_core_tpu.models import get_config, llama
 from cyberfabric_core_tpu.models.llama import _moe_mlp, _moe_mlp_dense
 from cyberfabric_core_tpu.ops import ssd
 from cyberfabric_core_tpu.ops.paged_attention import (
-    decode_work_list, paged_decode_attention, ragged_paged_attention)
+    paged_decode_attention, ragged_paged_attention)
 
 B, H, P, N, G = 3, 16, 8, 16, 1
 
@@ -139,15 +139,15 @@ def test_both_paged_kernels_take_the_scale_they_are_handed():
                         jnp.int32)
     lens = jnp.asarray([27, 9], jnp.int32)
     q = jax.random.normal(k[2], (rows, Hq, D))
-    work = decode_work_list(table, lens, page, None)
-    given = paged_decode_attention(q, *pools, work, interpret=True,
+    work = table, lens
+    given = paged_decode_attention(q, *pools, *work, interpret=True,
                                    scale=1.0 / D)
-    as_was = paged_decode_attention(q * D ** -0.5, *pools, work,
+    as_was = paged_decode_attention(q * D ** -0.5, *pools, *work,
                                     interpret=True)
     np.testing.assert_allclose(np.asarray(given), np.asarray(as_was),
                                atol=1e-5, rtol=1e-5)
     assert not np.allclose(np.asarray(given), np.asarray(
-        paged_decode_attention(q, *pools, work, interpret=True)), atol=1e-3)
+        paged_decode_attention(q, *pools, *work, interpret=True)), atol=1e-3)
     span = jax.random.normal(k[3], (rows, 8, Hq, D))
     hist, q_lens = jnp.asarray([16, 0], jnp.int32), jnp.asarray([8, 5],
                                                                 jnp.int32)

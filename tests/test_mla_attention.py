@@ -162,11 +162,21 @@ def test_trip_pages_is_what_the_scheduler_counts_groups_by():
     assert _block_sizes(16) == (2, 4, 8, 16)
     assert _block_sizes(3) == (2, 3) and _block_sizes(1) == (1,)
     kimi = get_config("kimi-k2.5-share32-15l")
-    assert decode_page_group(kimi, 64, 48, 2) == trip_pages(64, None)
+    assert decode_page_group(kimi, 64, 48, 2, None) == trip_pages(64, None)
     motif = get_config("motif-3-beta-share32-27l")
     assert motif.window_pages(64) == trip_pages(64, motif.sliding_window)
     # the pair the group counter goes with counts the FULL layers' spans
-    assert decode_page_group(motif, 64, 128, 2) == 16
+    assert decode_page_group(motif, 64, 128, 2, None) == 16
+    assert decode_page_group(motif, 64, 128, 2, motif.sliding_window) == 3
+    # the K/V twin: the kernel's own rule by the shapes a shard sees
+    from cyberfabric_core_tpu.ops.paged_attention import decode_trip_pages
+    mistral = get_config("mistral-7b")
+    assert decode_page_group(mistral, 64, 32, 2, mistral.sliding_window) == \
+        decode_trip_pages(64, 8 * 128, 2, 32, mistral.sliding_window) == 16
+    laguna = get_config("laguna-s-2.1-share8-12l")
+    assert decode_page_group(laguna, 64, 128, 2, None) == 16
+    assert decode_page_group(laguna, 64, 128, 2, laguna.sliding_window) == \
+        laguna.window_pages(64) == 9
 
 
 def _ragged_dense(q, rows, hist, qlen, window=None):

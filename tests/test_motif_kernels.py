@@ -117,17 +117,16 @@ def test_a_window_layers_copies_do_not_grow_with_context(counted_copies):
     a row of 8 191 tokens starts 3 (of 8 192, whose window starts on a page:
     2), where a full layer's starts 128 in 8 trips; a ragged program's ONE
     trip is the pages a q-block's windows span (4), whatever the table's.
-    ``decode_work`` hands the latent kernel the table and the lengths: it
-    walks them itself."""
+    ``decode_work`` hands a kernel the table and the lengths: it walks them
+    itself."""
     cfg = get_config("motif-3-beta-share32-27l")
     assert cfg.window_pages(PAGE) == 3 and cfg.window_pages(PAGE, 32) == 4
     assert mla.trip_pages(PAGE, cfg.sliding_window) == 3
     pool = jnp.zeros((1, 4, PAGE, 128), jnp.bfloat16)
     table = jnp.ones((5, 128), jnp.int32)
     lengths = jnp.asarray([1, 129, 4097, 8191, 8192], jnp.int32)
-    for window in (cfg.sliding_window, None):
-        work = decode_work(cfg, table, lengths, pool, window)
-        assert work[0] is table and work[1] is lengths
+    work = decode_work(table, lengths)      # the same for both kinds
+    assert work[0] is table and work[1] is lengths
     q = jnp.zeros((5, 8, 128), jnp.bfloat16)
     first, last = page_span(np.asarray(lengths), PAGE, 128,
                             cfg.sliding_window)

@@ -19,8 +19,7 @@ from cyberfabric_core_tpu.ops.grouped_matmul import (BLOCK_BYTES, ROW_TILE,
                                                      _col_tile, group_items,
                                                      row_tile)
 from cyberfabric_core_tpu.ops.paged_attention import (
-    decode_page_group, decode_work_list, paged_decode_attention,
-    ragged_paged_attention)
+    decode_trip_pages, paged_decode_attention, ragged_paged_attention)
 from cyberfabric_core_tpu.runtime.quant import quantize_weight
 
 B, H, P, N, G = 3, 32, 8, 16, 2          # 16 heads a group, as served
@@ -59,8 +58,8 @@ def test_the_tiles_follow_from_the_shapes_and_the_others_keep_theirs():
     assert row_tile(1408, 128) == 64
     tile, *_ = group_items(sizes, 1408, row_tile(1408, 128))
     assert tile.shape == (22 + 127,)
-    assert decode_page_group(64, 2 * 128, 2, 32, 64) == 8       # nemotron
-    assert decode_page_group(64, 8 * 128, 2, 32, 64) == 4       # mistral
+    assert decode_trip_pages(64, 2 * 128, 2, 64, None) == 16    # nemotron
+    assert decode_trip_pages(64, 8 * 128, 2, 32, 4096) == 16    # mistral
 
 
 #: the grouped matmul calls the benchmark's four MoE cells run (rows of the
@@ -207,9 +206,9 @@ def test_both_paged_kernels_at_sixteen_queries_a_kv_head():
 
     lens = jnp.asarray([27, 9], jnp.int32)
     q = jax.random.normal(k[2], (rows, Hq, D))
-    for group in (1, 2):
-        work = decode_work_list(table, lens, page, None, group=group)
-        got = paged_decode_attention(q, *pools, work, interpret=True)
+    for trip in (1, 2):
+        got = paged_decode_attention(q, *pools, table, lens, interpret=True,
+                                     trip=trip)
         for r, n in enumerate([27, 9]):
             np.testing.assert_allclose(np.asarray(got[r]),
                                        formula(np.asarray(q[r]), r, n),

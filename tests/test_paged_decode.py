@@ -225,14 +225,13 @@ def _mixed_step_operands(eng, width):
 @pytest.mark.parametrize("program", ["paged_decode_chunk", "mixed_step"])
 @pytest.mark.parametrize("model", ["tiny-llama", "tiny-falcon-h1",
                                    "tiny-sdar"])
-def test_work_list_is_built_once_a_step(model, program):
-    """The decode kernel's grid is laid out from the rows' lengths, which
-    are the same for every layer: in the scheduler's own programs the one
-    cumsum over the rows sits outside the scan over layers, and the kernel
-    inside it takes the list (a bound, a slot and a first page for every
-    group of pages a full table holds, the groups' pages, the rows' lengths
-    and last pages) as it was built."""
-    from cyberfabric_core_tpu.models.llama import decode_page_group
+def test_the_decode_kernel_takes_the_table_and_the_lengths(model, program):
+    """The decode kernel walks a row's pages itself: in the scheduler's own
+    programs nothing is laid out from the rows' lengths in front of it (no
+    cumsum over the rows, inside the scan over layers or outside), and the
+    kernel inside the scan is a program a row that takes the page table,
+    the lengths and the layer as scalar-prefetch operands and both pools
+    whole, as the carry holds them."""
     from cyberfabric_core_tpu.runtime import EngineConfig
     from cyberfabric_core_tpu.runtime.scheduler import ContinuousBatchingEngine
 
@@ -257,15 +256,15 @@ def test_work_list_is_built_once_a_step(model, program):
                     and e.outvars[0].aval.dtype == jnp.int32]
 
         body = layers.params["jaxpr"].jaxpr
-        assert len(over_rows(jaxpr.jaxpr)) == 1 and not over_rows(body)
+        assert not over_rows(jaxpr.jaxpr) and not over_rows(body)
         slots = eng.page_table.shape[1]
-        group = decode_page_group(eng.model_config, 16, slots,
-                                  jnp.dtype(eng.dtype).itemsize)
-        items = n * -(-slots // group)
         decode, = [e for e in _find(body, "pallas_call")
-                   if e.params["grid_mapping"].num_dynamic_grid_bounds]
-        assert group > 1 and [v.aval.shape for v in decode.invars[:6]] == [
-            (), (items,), (items,), (items * group,), (n,), (n,)]
+                   if e.params["grid_mapping"].grid == (n,)]
+        pool = eng.pool.cache_operands()[0].shape
+        pool = (*pool[:3], pool[3] * pool[4]) if len(pool) == 5 else pool
+        assert [v.aval.shape for v in decode.invars[:3]] == [
+            (n, slots), (n,), (1,)]
+        assert [v.aval.shape for v in decode.invars[4:6]] == [pool, pool]
     finally:
         eng.shutdown()
 

@@ -8,6 +8,11 @@ checkout, interpret mode on the CPU). Here the same pages sit in layer 1 of
 a three-layer ``[L, N, page, Hkv*D]`` pool whose other layers hold other
 numbers, and each kernel body must return its bytes: storing the pool in the
 kernels' block shape and picking the layer in the index map moved no bit.
+Since PR 57 the decode kernel walks a row's pages inside one program, a kv
+head over its own query rows: at a page a trip it gives those bytes too, but
+for two digests at ONE query row a kv head (one element by one bfloat16 step:
+this CPU sums a matrix-vector and a matrix product in different orders),
+re-pinned under ``repinned`` in the file.
 """
 
 import functools
@@ -109,21 +114,18 @@ def _stacked(case: dict, which: str) -> jnp.ndarray:
 @pytest.mark.parametrize("name", CASES)
 def test_merged_stacked_pool_is_bitwise_the_parent(name, body, goldens):
     from cyberfabric_core_tpu.ops.paged_attention import (
-        decode_work_list, paged_decode_attention, ragged_paged_attention)
+        paged_decode_attention, ragged_paged_attention)
 
     case = case_inputs(name)
     if case["kernel"] == "decode":
-        # the grid over the pages in use (PR 32) walks a row's pages in the
-        # parent's order, so the digests of the (B, Pmax) grid still hold
-        fn = paged_decode_attention
-        rows = (decode_work_list(case["table"], *case["rows"], PAGE,
-                                 case["window"]),)
+        # the walk inside the program (PR 57) at a page a trip sums a row's
+        # pages in the grid's order, a kv head over its own query rows
+        fn = functools.partial(paged_decode_attention, trip=1)
     else:
         # the walk inside the program (PR 55) at a page a trip and the
         # grid's 8 queries a program sums in the grid's order
         fn = functools.partial(ragged_paged_attention, trip=1, q_block=8)
-        rows = (case["table"], *case["rows"])
     out = fn(case["q"], _stacked(case, "k_pool"), _stacked(case, "v_pool"),
-             *rows, LAYER, interpret=True,
+             case["table"], *case["rows"], LAYER, interpret=True,
              sliding_window=case["window"], two_d_dots=body == "two_d_dots")
     assert digest(out) == goldens[name][body]

@@ -17,7 +17,7 @@ import pytest
 
 from cyberfabric_core_tpu.ops.attention import attention_with_cache
 from cyberfabric_core_tpu.ops.paged_attention import (
-    decode_work_list, paged_decode_attention, paged_gather_dense,
+    paged_decode_attention, paged_gather_dense,
     ragged_paged_attention)
 
 
@@ -112,9 +112,8 @@ def test_ragged_decode_rows_bit_identical_to_decode_kernel():
     k_pool, v_pool, pt = _build_pool(kp, B, page, Pmax, Hkv, D, N)
     hist = jnp.asarray([0, 9, 33, 80], jnp.int32)
 
-    dec = paged_decode_attention(q1, k_pool, v_pool,
-                                 decode_work_list(pt, hist + 1, page),
-                                 interpret=True)
+    dec = paged_decode_attention(q1, k_pool, v_pool, pt, hist + 1,
+                                 interpret=True, trip=1)
     q = jnp.zeros((B, 8, Hq, D), jnp.float32).at[:, 0].set(q1)
     rag = ragged_paged_attention(q, k_pool, v_pool, pt, hist,
                                  jnp.ones((B,), jnp.int32), interpret=True,
@@ -226,11 +225,11 @@ def test_two_d_dot_rewrite_bitwise_decode_kernel():
     k_pool, v_pool, pt = _build_pool(kp, B, page, Pmax, Hkv, D, N)
     lengths = jnp.asarray([1, 10, 34, 81], jnp.int32)
 
-    work = decode_work_list(pt, lengths, page)
-    batched = paged_decode_attention(q, k_pool, v_pool, work,
-                                     interpret=True, two_d_dots=False)
-    two_d = paged_decode_attention(q, k_pool, v_pool, work,
-                                   interpret=True, two_d_dots=True)
+    batched = paged_decode_attention(q, k_pool, v_pool, pt, lengths,
+                                     interpret=True, two_d_dots=False,
+                                     trip=1)
+    two_d = paged_decode_attention(q, k_pool, v_pool, pt, lengths,
+                                   interpret=True, two_d_dots=True, trip=1)
     np.testing.assert_array_equal(np.asarray(two_d), np.asarray(batched))
 
 
@@ -416,12 +415,12 @@ def test_the_q_block_and_the_trip_come_from_shapes():
     long as the accumulators of that many rows would pass their share of
     VMEM; a trip is the pages of 1 024 keys (no more than 16), and no more
     than a q-block's windows span."""
-    from cyberfabric_core_tpu.ops.paged_attention import (ragged_block_sizes,
+    from cyberfabric_core_tpu.ops.paged_attention import (kv_block_sizes,
                                                           ragged_q_block,
                                                           ragged_trip_pages)
 
     # a trip is attended over as a block of 4 pages or as the whole trip
-    assert [ragged_block_sizes(t) for t in (1, 2, 4, 10, 16)] == [
+    assert [kv_block_sizes(t) for t in (1, 2, 4, 10, 16)] == [
         (1,), (2,), (4,), (4, 10), (4, 16)]
     assert [ragged_q_block(w, 72) for w in (8, 16, 24, 64, 512)] == \
         [8, 16, 24, 64, 64]

@@ -591,7 +591,7 @@ def test_attn_pages_walked_is_what_the_rows_lengths_say(model):
     pages the running row's tokens lie on, its own included, and one program
     for each of the three idle rows; a row the device froze inside a chunk
     stays at the length it reached. ``llm_attn_page_groups_total`` counts the
-    same steps' programs: a row's pages a group at a time."""
+    same steps' trips: a row's pages a trip at a time."""
     from cyberfabric_core_tpu.models.llama import decode_page_group
 
     names = ("llm_attn_pages_walked_total", "llm_attn_pages_offered_total",
@@ -608,7 +608,8 @@ def test_attn_pages_walked_is_what_the_rows_lengths_say(model):
         layers, mixed = eng.model_config.num_layers, eng.mixed_rounds
         chunks = eng.decode_rounds - mixed
         group = decode_page_group(eng.model_config, page, slots,
-                                  np.dtype(eng.dtype).itemsize)
+                                  np.dtype(eng.dtype).itemsize,
+                                  eng.model_config.sliding_window)
     finally:
         eng.shutdown()
     assert col.finishes[0] == "length" and len(col.tokens[0]) == answer
@@ -638,12 +639,12 @@ def test_attn_pages_counted_by_step_from_kept_lengths(model, over):
     """The count itself, for a token step, a block step and a latent page:
     a forward reads the row's kept length and the step's own tokens; a
     forward that added to the length moves the ones after it; a row that
-    does not run is at 0 on the device. The programs counted are the work
-    lists' own ``n_items`` (the list each forward's program builds, from the
-    same table and lengths), and the pages walked do not depend on how they
-    are grouped."""
-    from cyberfabric_core_tpu.models.llama import decode_work
+    does not run is at 0 on the device. The groups counted are the trips of
+    the kernel's own rule (tests/test_paged_attention.py and
+    tests/test_mla_attention.py count the trips a call runs), and the pages
+    walked do not depend on how they are grouped."""
     from cyberfabric_core_tpu.ops.mla_attention import trip_pages
+    from cyberfabric_core_tpu.ops.paged_attention import decode_trip_pages
 
     names = ("llm_attn_pages_walked_total", "llm_attn_page_groups_total",
              "llm_attn_pages_offered_total")
@@ -667,16 +668,10 @@ def test_attn_pages_counted_by_step_from_kept_lengths(model, over):
     for f in range(3):
         pages = np.minimum(-(-(lengths + step) // page), slots)
         walked += int(pages.sum())
-        if cfg.is_latent:
-            # the latent kernel walks the table itself, a trip of pages at
-            # a time (tests/test_mla_attention.py counts the trips it runs)
-            group = trip_pages(page, cfg.sliding_window)
-            programs += int((-(-pages // group)).sum())
-        else:
-            work = decode_work(cfg, eng.page_table, lengths + step, pool,
-                               cfg.sliding_window)
-            programs += int(work.n_items)
-            group = work.phys.shape[0] // work.row.shape[0]
+        group = trip_pages(page, cfg.sliding_window) if cfg.is_latent else \
+            decode_trip_pages(page, pool.shape[3], pool.dtype.itemsize, slots,
+                              cfg.sliding_window)
+        programs += int((-(-pages // group)).sum())
         lengths = lengths + step * grew[:, f]
     assert got == [walked * layers, programs * layers,
                    4 * 3 * slots * layers]

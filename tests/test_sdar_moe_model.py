@@ -17,7 +17,7 @@ from benchmark import sdar_reference, sdar_weights
 from benchmark.adapters import sdar as adapter
 from cyberfabric_core_tpu.models import get_config, sdar_moe
 from cyberfabric_core_tpu.ops.paged_attention import (
-    decode_work_list, paged_block_attention, paged_gather_dense,
+    paged_block_attention, paged_gather_dense,
     ragged_paged_attention)
 from cyberfabric_core_tpu.ops.sampling import block_unmask
 
@@ -125,22 +125,20 @@ def test_ragged_kernel_block_mask_against_a_dense_mask(block):
                                    atol=2e-5)
 
 
-@pytest.mark.parametrize("block,group", [(1, 1), (4, 1), (8, 1), (4, 2),
-                                         (4, 4), (8, 4)])
-def test_decode_kernel_block_fold_against_a_dense_mask(block, group):
+@pytest.mark.parametrize("block,trip", [(1, 1), (4, 1), (8, 1), (4, 2),
+                                        (4, 4), (8, 4)])
+def test_decode_kernel_block_fold_against_a_dense_mask(block, trip):
     """The open block's kernel: ``block`` queries a row, each seeing the
     kept keys and the whole block; 1 is the decode kernel as it was. The
-    folded rows take a row's pages ``group`` at a time like any others."""
+    folded rows take a row's pages ``trip`` at a time like any others."""
     rng = np.random.default_rng(10 + block)
     k_pool, v_pool = _pool(rng)
     table = jnp.asarray([[3, 5, 7, 9], [2, 4, 6, 8]], jnp.int32)
     kept = np.array([8, 16])
     q = rng.standard_normal((2, block, 4, 16)).astype(np.float32)
     out = np.asarray(paged_block_attention(
-        jnp.asarray(q), k_pool, v_pool,
-        decode_work_list(table, jnp.asarray(kept + block), k_pool.shape[2],
-                         group=group),
-        0, interpret=True))
+        jnp.asarray(q), k_pool, v_pool, table, jnp.asarray(kept + block),
+        0, interpret=True, trip=trip))
     kd, vd = (np.asarray(a) for a in paged_gather_dense(k_pool, v_pool, table,
                                                         16))
     for r in range(2):

@@ -16,8 +16,7 @@ from cyberfabric_core_tpu.ops import kda, ssd
 from cyberfabric_core_tpu.ops.grouped_matmul import (BLOCK_BYTES, _col_tile,
                                                      group_items, row_tile)
 from cyberfabric_core_tpu.ops.paged_attention import (
-    decode_page_group, decode_work_list, paged_decode_attention,
-    ragged_paged_attention)
+    decode_trip_pages, paged_decode_attention, ragged_paged_attention)
 
 B, H, K, V = 3, 8, 8, 16            # keys narrower than values: a transposed
                                     # state would not even have the shape
@@ -69,9 +68,9 @@ def test_the_tiles_follow_from_the_shapes_and_the_others_keep_theirs():
     assert row_tile(256, 40) == 64 and row_tile(2304, 40) == 64
     items, *_ = group_items(jnp.zeros((40,), jnp.int32), 256, 64)
     assert items.shape == (256 // 64 + 39,)
-    assert decode_page_group(64, 8 * 128, 2, 48, 64) == 4       # solar-open2
-    assert decode_page_group(64, 8 * 128, 2, 32, 64) == 4       # mistral
-    assert llama.decode_page_group(cfg, 64, 48, 2) == 4
+    assert decode_trip_pages(64, 8 * 128, 2, 48, None) == 16    # solar-open2
+    assert decode_trip_pages(64, 8 * 128, 2, 32, 4096) == 16    # mistral
+    assert llama.decode_page_group(cfg, 64, 48, 2, None) == 16
 
 
 def test_state_kernel_equals_its_twin_and_keeps_masked_rows_bit_for_bit():
@@ -226,9 +225,7 @@ def test_both_paged_kernels_at_eight_queries_a_kv_head():
 
     lens = jnp.asarray([37, 9], jnp.int32)
     q = jax.random.normal(k[2], (rows, Hq, D))
-    work = decode_work_list(table, lens, page, None, group=decode_page_group(
-        page, Hkv * D, 4, Hq // Hkv, pmax))
-    got = paged_decode_attention(q, *pools, work, interpret=True)
+    got = paged_decode_attention(q, *pools, table, lens, interpret=True)
     for r, n in enumerate([37, 9]):
         np.testing.assert_allclose(np.asarray(got[r]),
                                    formula(np.asarray(q[r]), r, n),

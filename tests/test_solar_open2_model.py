@@ -461,12 +461,18 @@ def _primitives(jaxpr) -> list[str]:
 #: trips' copies, waits and key blocks and the spans are worked out in front
 #: of the call; they were (3465, "f7ce34d2268058cb") and (3225,
 #: "c9671f2367cb4e41"). Their decode steps, and both of kimi's, held there:
-#: the shared walk moved modules and traces to what it traced to)
+#: the shared walk moved modules and traces to what it traced to; granite's
+#: and nemotron's decode AND mixed steps: PR 57, whose K/V decode kernel
+#: walks a row's pages inside one program through that ``_Walk`` too, so the
+#: work list in front of the scan over layers went and the kernel's body
+#: holds the trips' copies, waits and key blocks; they were (2786,
+#: "2e0c6431a4b078a3") / (3865, "191fd682605baa0e") and (2546,
+#: "c1f6a78f45ff9309") / (3625, "91b1392f16fa319b"); kimi's two held)
 TRACED_AT_THE_PARENT = {
-    "tiny-granite-hybrid-4l": [(2786, "2e0c6431a4b078a3"),
-                               (3865, "191fd682605baa0e")],
-    "tiny-nemotron-h-share4-8l": [(2546, "c1f6a78f45ff9309"),
-                                  (3625, "91b1392f16fa319b")],
+    "tiny-granite-hybrid-4l": [(2919, "b1a166d8016957ef"),
+                               (3996, "e1c1abd1c6861aa7")],
+    "tiny-nemotron-h-share4-8l": [(2679, "a28e024a7ae83f03"),
+                                  (3756, "6733ae1807696238")],
     "tiny-kimi-share4": [(3881, "4800cad6e270bfdb"),
                          (5451, "d4074a4d09f51c13")],
 }
