@@ -21,7 +21,8 @@ _DECODERS = {"llama": "llama", "falcon_h1": "falcon_h1",
              "sdar_moe": "sdar_moe", "kimi_k2": "kimi_k2",
              "granite_hybrid": "granite_hybrid", "nemotron_h": "nemotron_h",
              "solar_open2": "solar_open2", "motif": "motif",
-             "ouro": "ouro", "laguna": "laguna"}
+             "ouro": "ouro", "laguna": "laguna",
+             "glm_moe_dsa": "glm_dsa"}
 
 
 def decoder_module(cfg: ModelConfig) -> ModuleType:

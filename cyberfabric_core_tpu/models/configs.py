@@ -48,6 +48,10 @@ class ModelConfig:
     #: "ouro" (a llama stack run ``loop_steps`` times a token with the SAME
     #: weights, every branch normed on both sides, the final norm after every
     #: pass and an exit gate that reads it; a pass caches its own K and V) |
+    #: "glm_moe_dsa" (kimi_k2's block with a learned indexer in front of the
+    #: attention: ``index_heads`` small heads score every cached key of a
+    #: row against an index key cached beside the latent row, and a query
+    #: attends over the ``index_topk`` keys that score highest) |
     #: "bert" (encoder)
     architecture: str
     vocab_size: int
@@ -129,6 +133,15 @@ class ModelConfig:
     rope_beta_slow: float = 1.0
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 0.0
+    # glm_moe_dsa: attention over a chosen set (0 = over every cached key).
+    # index_n_heads, index_head_dim, index_topk as published: a query's
+    # index_heads heads of index_head_dim score each key's ONE index key
+    # (cached beside the latent row, ``index_lanes`` numbers a token a
+    # layer), and the query attends over the index_topk keys that score
+    # highest among those it may see (all of them while it sees no more)
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
     # falcon_h1: the state-space mixer beside attention (0 heads = no mixer).
     # Names follow the published config: mamba_d_ssm, mamba_n_heads,
     # mamba_d_head, mamba_d_state, mamba_n_groups, mamba_d_conv,
@@ -273,6 +286,13 @@ class ModelConfig:
                 f"{self.name}: {self.window_heads} query heads of a window "
                 f"layer are not whole groups over {self.num_kv_heads} kv "
                 "heads")
+        if self.index_topk and not (self.is_latent and self.index_heads
+                                    and self.index_head_dim
+                                    and not self.sliding_window):
+            raise ValueError(
+                f"{self.name}: index_topk {self.index_topk} selects among a "
+                "latent page's rows: it needs kv_lora_rank, index_heads and "
+                "index_head_dim, and goes with no sliding window")
         if self.remasking not in ("low_confidence_static",
                                   "low_confidence_dynamic"):
             raise ValueError(f"unknown remasking {self.remasking!r}")
@@ -319,7 +339,7 @@ class ModelConfig:
         (a score decides WHICH experts run, not only how much)."""
         return self.architecture in ("sdar_moe", "kimi_k2", "granite_hybrid",
                                      "nemotron_h", "solar_open2", "motif",
-                                     "laguna")
+                                     "laguna", "glm_moe_dsa")
 
     @property
     def is_latent(self) -> bool:
@@ -343,6 +363,18 @@ class ModelConfig:
         axis as the minor one for a pool of 3073 pages, and every step then
         copies the pool twice)."""
         return -(-self.latent_width // 128) * 128
+
+    @property
+    def is_sparse(self) -> bool:
+        """A query attends over a chosen set of the keys it may see: the
+        cache holds an index key a token a layer beside the latent row."""
+        return self.index_topk > 0
+
+    @property
+    def index_lanes(self) -> int:
+        """Lanes a token's index key takes in the index pool
+        (``index_head_dim`` in whole lane tiles; 0: no indexer)."""
+        return -(-self.index_head_dim // 128) * 128 if self.is_sparse else 0
 
     @property
     def experts_local(self) -> int:
@@ -446,8 +478,9 @@ class ModelConfig:
     @property
     def _page_row_numbers(self) -> int:
         """Numbers a token stores in one cache layer: a latent row in whole
-        lane tiles, or K and V of every kv head."""
-        return (self.latent_lanes if self.is_latent
+        lane tiles (and the index key beside it, where a query attends over
+        a chosen set), or K and V of every kv head."""
+        return (self.latent_lanes + self.index_lanes if self.is_latent
                 else 2 * self.num_kv_heads * self.head_dim)
 
     @property
@@ -503,10 +536,45 @@ class ModelConfig:
                 "f_a": (h, r), "f_b": (r, dk), "g_a": (h, r), "g_b": (r, dv),
                 "w_beta": (h, self.ssm_heads)}
 
+    def latent_matrices(self) -> dict[str, tuple[int, int]]:
+        """A latent attention layer's matrices, (contraction, outputs) each:
+        the two query projections, the compressed row and rotary key, the up
+        projection of K and V, the output; and the indexer's three where a
+        query attends over a chosen set (``index_w`` float32, never
+        quantised, as a router is)."""
+        h, hq = self.hidden_size, self.num_heads
+        out = {"wq_a": (h, self.q_lora_rank),
+               "wq_b": (self.q_lora_rank, hq * self.head_dim),
+               "wkv_a": (h, self.latent_width),
+               "wkv_b": (self.kv_lora_rank,
+                         hq * (self.qk_nope_head_dim + self.v_head_dim)),
+               "wo": (hq * self.v_head_dim, h)}
+        if self.is_sparse:
+            out.update({
+                "index_wq": (self.q_lora_rank,
+                             self.index_heads * self.index_head_dim),
+                "index_wk": (h, self.index_head_dim),
+                "index_w": (h, self.index_heads)})
+        return out
+
     def param_count(self) -> int:
         """Approximate parameter count (for HBM budgeting). A layer counts
         ONCE however many passes run it (``loop_steps``)."""
         h, i, v, l = self.hidden_size, self.intermediate_size, self.vocab_size, self.num_layers
+        if self.is_sparse:
+            # latent attention's five matrices and two inner norms, the
+            # indexer (its three matrices, the index key's norm and bias),
+            # a layer's two norms; a dense MLP, or the experts with their
+            # router and its selection bias beside the shared expert
+            layer = (sum(k * n for k, n in self.latent_matrices().values())
+                     + self.q_lora_rank + self.kv_lora_rank
+                     + 2 * self.index_head_dim + 2 * h)
+            expert = 3 * h * self.moe_intermediate_size
+            return (l * layer + self.first_k_dense * 3 * h * i
+                    + self.moe_layers * (
+                        self.num_experts * (expert + h + 1)
+                        + self.shared_experts * expert)
+                    + 2 * v * h + h)
 
         def attention(heads: int) -> int:
             """One attention layer of ``heads`` query heads: q, k, v, o, the
@@ -588,6 +656,30 @@ class ModelConfig:
         def gate(heads: int) -> int:
             return mat(h, heads) if self.head_gate else 0
 
+        if self.is_sparse:
+            # the latent stack with an indexer: a layer's attention and
+            # indexer (``index_w`` float32), the dense MLPs, the shared
+            # expert and the float32 router and bias, the held experts
+            latent = self.latent_matrices()
+            index_w = latent.pop("index_w")
+            return {
+                "attention": self.num_layers * sum(
+                    mat(k, n) for name, (k, n) in latent.items()
+                    if not name.startswith("index_")),
+                "indexer": self.num_layers * (
+                    sum(mat(k, n) for name, (k, n) in latent.items()
+                        if name.startswith("index_"))
+                    + 4 * index_w[0] * index_w[1]),
+                "dense_mlp": self.first_k_dense * (
+                    2 * mat(h, self.intermediate_size)
+                    + mat(self.intermediate_size, h)),
+                "moe_dense": self.moe_layers * (
+                    2 * mat(h, shared) + mat(shared, h)
+                    + 4 * (h + 1) * self.num_experts),
+                "experts": self.moe_layers * self.experts_local * (
+                    2 * mat(w, i) + mat(i, w)),
+                "vocab": 2 * self.vocab_rows * (h * itemsize + scale),
+            }
         return {
             "mamba": self.layer_types.count("mamba") * (
                 mat(h, self.ssm_proj_dim) + mat(self.ssm_inner, h)),
@@ -818,6 +910,40 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         routed_scaling_factor=2.5,
         rope_factor=4.0, rope_original_max=64, rope_beta_fast=32.0,
         rope_beta_slow=1.0, rope_mscale=1.0, rope_mscale_all_dim=1.0,
+    ),
+    # GLM-5, config.json as published (model_type glm_moe_dsa, zai-org,
+    # 744 B): kimi_k2's block (64 heads of latent attention, 192 + 64 / 256,
+    # q_lora 2048, kv_lora 512, plain rotary at 1e6) with a learned indexer
+    # in front of the attention (32 heads of 128 pick the 2048 keys a query
+    # attends), three leading dense layers of 12288, then 256 sigmoid-routed
+    # experts of 2048 top-8 (scale 2.5, a selection bias) beside one shared
+    # expert; untied head. The multi-token-prediction layer
+    # (num_nextn_predict_layers 1) is a draft module and not part of the
+    # served model
+    "glm-5": ModelConfig(
+        name="glm-5", architecture="glm_moe_dsa", vocab_size=154880,
+        hidden_size=6144, intermediate_size=12288, num_layers=78,
+        num_heads=64, num_kv_heads=64, head_dim=256, max_position=202752,
+        rope_theta=1e6, rms_norm_eps=1e-5, num_experts=256,
+        experts_per_token=8, q_lora_rank=2048, kv_lora_rank=512,
+        qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+        first_k_dense=3, moe_intermediate_size=2048, shared_experts=1,
+        routed_scaling_factor=2.5, index_heads=32, index_head_dim=128,
+        index_topk=2048,
+    ),
+    # CPU-test preset of the same block: two dense layers then three expert
+    # layers, 8 experts top-3, latent 32 + 8, 4 index heads of 16 that pick
+    # 12 keys: with pages of 4 the selection binds after three pages
+    "tiny-glm-dsa": ModelConfig(
+        name="tiny-glm-dsa", architecture="glm_moe_dsa", vocab_size=512,
+        hidden_size=64, intermediate_size=128, num_layers=5, num_heads=4,
+        num_kv_heads=4, head_dim=32, max_position=1024, rope_theta=10000.0,
+        rms_norm_eps=1e-5, num_experts=8, experts_per_token=3,
+        q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=24,
+        qk_rope_head_dim=8, v_head_dim=32, first_k_dense=2,
+        moe_intermediate_size=32, shared_experts=1,
+        routed_scaling_factor=2.5, index_heads=4, index_head_dim=16,
+        index_topk=12,
     ),
     # granite-4.0-h-small, config.json as published (model_type
     # granitemoehybrid, 32B-A9B): 40 layers of which those at 5, 15, 25 and
@@ -1155,6 +1281,22 @@ MODEL_CONFIGS["laguna-s-2.1-share8-12l"] = dataclasses.replace(
 MODEL_CONFIGS["tiny-laguna-share4"] = dataclasses.replace(
     MODEL_CONFIGS["tiny-laguna"], name="tiny-laguna-share4", experts_held=4,
     expert_offset=4, vocab_held=256)
+
+
+# share 0 of the first pipeline stage of a deployment of glm-5 whose layers
+# are each shared by 16 chips: ONE of the three leading dense layers and the
+# 6 expert layers after them, experts 0-15 of each layer's 256, rows 0-19359
+# of the vocabulary (an 8-way split); attention and the indexer are
+# data-parallel, so every head and the whole indexer are here
+MODEL_CONFIGS["glm-5-share16-7l"] = dataclasses.replace(
+    MODEL_CONFIGS["glm-5"], name="glm-5-share16-7l", num_layers=7,
+    first_k_dense=1, experts_held=16, expert_offset=0, vocab_held=19360,
+    max_position=16384)
+
+# a share of the tiny preset: experts 4-7 of 8, half the vocabulary
+MODEL_CONFIGS["tiny-glm-dsa-share4"] = dataclasses.replace(
+    MODEL_CONFIGS["tiny-glm-dsa"], name="tiny-glm-dsa-share4",
+    experts_held=4, expert_offset=4, vocab_held=256)
 
 
 def get_config(name: str) -> ModelConfig:

@@ -87,10 +87,12 @@ LatentPool = tuple[jnp.ndarray]     # (latent,): [L, N, page, rank + rope]
 Aux = dict[str, jnp.ndarray]
 
 
-def _one_device(mesh: Any, interpret: bool | None) -> bool:
+def _one_device(mesh: Any, interpret: bool | None,
+                architecture: str = "kimi_k2") -> bool:
     if mesh is not None:
-        raise ValueError("kimi_k2 serves on one device: a latent page has no "
-                         "head axis to shard and the expert layer no ep axis")
+        raise ValueError(f"{architecture} serves on one device: a latent "
+                         "page has no head axis to shard and the expert "
+                         "layer no ep axis")
     return _default_interpret() if interpret is None else interpret
 
 
@@ -179,17 +181,25 @@ def _split_ukv(lp: dict, cfg: ModelConfig):
             None if s is None else s.reshape(Hq, width))
 
 
+def compressed_query(lp: dict, x: jnp.ndarray, cfg: ModelConfig):
+    """``c_q`` [1, N, q_lora_rank]: the normed down projection every query
+    head (and an indexer's) is projected up from."""
+    return rms_norm(_proj(x, lp["wq_a"]).astype(x.dtype), lp["q_a_norm"],
+                    cfg.rms_norm_eps)
+
+
 def latent_and_query(lp: dict, x: jnp.ndarray, cfg: ModelConfig, positions,
-                     cos_t, sin_t):
+                     cos_t, sin_t, c_q: jnp.ndarray | None = None):
     """One layer's attention inputs from normed ``x`` [1, N, H]: the latent
     rows to cache (``c`` normed, ``k_r`` rotated) and the absorbed queries
     ``[N, Hq, .]``, both ``cfg.latent_lanes`` wide: ``rank + rope`` numbers
-    and zeros up to whole lane tiles."""
+    and zeros up to whole lane tiles. ``c_q``: :func:`compressed_query` of
+    ``x`` where the caller already has it."""
     N = x.shape[1]
     Hq, nope = cfg.num_heads, cfg.qk_nope_head_dim
     rank = cfg.kv_lora_rank
-    c_q = rms_norm(_proj(x, lp["wq_a"]).astype(x.dtype), lp["q_a_norm"],
-                   cfg.rms_norm_eps)
+    if c_q is None:
+        c_q = compressed_query(lp, x, cfg)
     q = _proj(c_q, lp["wq_b"]).reshape(1, N, Hq, cfg.head_dim)
     ckv = _proj(x, lp["wkv_a"]).astype(x.dtype)
     c = rms_norm(ckv[..., :rank], lp["kv_a_norm"], cfg.rms_norm_eps)

@@ -503,7 +503,7 @@ def forward_paged_decode(
     pools, aux)."""
     from ..ops.mla_attention import mla_decode_attention
 
-    interpret = _one_device(mesh, interpret)
+    interpret = _one_device(mesh, interpret, "motif")
     cos_t, sin_t = rope_tables
     B = input_ids.shape[0]
     page_size = pools[0].shape[2]
@@ -555,7 +555,7 @@ def forward_paged_mixed(
     over both page groups). Returns (hidden, pools, aux)."""
     from ..ops.mla_attention import mla_decode_attention, mla_ragged_attention
 
-    interpret = _one_device(mesh, interpret)
+    interpret = _one_device(mesh, interpret, "motif")
     cos_t, sin_t = rope_tables
     R, Qc = input_ids.shape
     lays = {full: mixed_layout(input_ids, table, hist, q_lens,

@@ -668,7 +668,37 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                  "values), summed over the decode rows that ran"),
                 ("llm_loop_exit_rows_total",
                  "Those decode rows: llm_loop_exit_pass_sum_total over this "
-                 "is the mean exit pass the gate asks for")):
+                 "is the mean exit pass the gate asks for"),
+                # a model whose queries attend over a chosen set
+                ("llm_dsa_keys_scored_total",
+                 "Keys the index passes scored (a decode row its length, a "
+                 "chunk's query its visible keys), summed over layers and "
+                 "forwards"),
+                ("llm_dsa_keys_selected_total",
+                 "Keys attended behind them: a query's visible keys, at "
+                 "most index_topk"),
+                ("llm_dsa_queries_total",
+                 "Queries scored and attended, summed over layers and "
+                 "forwards"),
+                ("llm_dsa_queries_binding_total",
+                 "Those of them that saw more than index_topk keys, so that "
+                 "the selection left some out"),
+                ("llm_dsa_decode_keys_scored_total",
+                 "Keys the index passes scored over the forwards of decode "
+                 "chunks alone (a mixed step's prompt chunk is left out)"),
+                ("llm_dsa_decode_keys_selected_total",
+                 "Keys attended over the forwards of decode chunks alone"),
+                ("llm_dsa_decode_queries_total",
+                 "Decode queries scored and attended, over layers and the "
+                 "forwards of decode chunks alone"),
+                ("llm_dsa_decode_queries_binding_total",
+                 "Those of them that saw more than index_topk keys: over "
+                 "the decode queries, 1 where every running row is past "
+                 "index_topk"),
+                ("llm_dsa_decode_calls_total",
+                 "Index passes of decode chunks' forwards (steps x layers): "
+                 "the two decode-only totals over this are one call's keys "
+                 "scored and attended, the whole batch")):
             self.registry.counter(name, text).inc(0.0)
 
         def _state_stat(key: str) -> float:
@@ -718,7 +748,17 @@ class MonitoringModule(Module, RestApiCapability, RunnableCapability):
                  "row at rest where the pool frees)"),
                 ("llm_cache_bytes", "cache_bytes",
                  "Bytes of the page pool (both page groups where the model "
-                 "has a window group) plus the recurrent-state slab")):
+                 "has a window group; the index keys beside the latent rows "
+                 "where it has an indexer) plus the recurrent-state slab"),
+                ("llm_index_topk", "index_topk",
+                 "Keys a query attends at most, chosen by its index heads' "
+                 "scores (0: a query attends every key it may see)"),
+                ("llm_index_heads", "index_heads",
+                 "Index heads that score a key for the selection (0: no "
+                 "indexer)"),
+                ("llm_index_cache_bytes", "index_cache_bytes",
+                 "Bytes of the index keys cached beside the latent rows, "
+                 "part of llm_cache_bytes (0: no indexer)")):
             self.registry.gauge(name, text).set_function(
                 lambda key=key: _state_stat(key))
 

@@ -35,6 +35,14 @@ both dots run at full 128-wide MXU tiles and the accumulator, 4-5 MB at
 64-80 heads, is rescaled once a trip; ``llm_ragged_trips_total`` counts
 them).
 
+**A chosen set** (``keep``; ``ops/dsa.py``): both kernels mask by position,
+and also by choice where the caller hands them the keys each query attends
+(1 or 0 a query and key, laid out by the kernel's own trips). The walk does
+not change: every page of the span is copied and scored, and a key that was
+not chosen is masked like one the position rules out. (Gathering a decode
+row's chosen rows in front of the kernel instead costs 29 ns a row gathered
+whatever it moves, 1.9 ms a call at 32 rows of 2048: PERF.md, PR 58.)
+
 **A window** (``sliding_window``, static; ``models/motif.py``'s window
 layers): a query at ``t`` sees the keys ``t - window < s <= t``. Both kernels
 start an item at the first page of that span (``page_walk._span_first``)
@@ -93,14 +101,18 @@ def _attend_trip(q, ring_ref, slot, k_start, visible, acc_ref, m_ref, l_ref,
         acc_ref, m_ref, l_ref, first)
 
 
-def _decode_kernel(pt_ref, len_ref, layer_ref, q_ref, pool_ref, o_ref,
-                   ring_ref, sem, walk_ref, acc_ref, m_ref, l_ref, *,
+def _decode_kernel(pt_ref, len_ref, layer_ref, q_ref, pool_ref, *rest,
                    page_size: int, trip: int, rank: int, scale: float,
-                   sliding_window: int | None):
+                   sliding_window: int | None, chosen: bool = False):
     """One slot: the program walks its row's span itself (:class:`_Walk`, an
     item a row). pt_ref [B, Pmax] / len_ref [B] / layer_ref [1] SMEM; q_ref
     [1, Hq, lanes]; pool_ref the whole stacked pool, where it lives; o_ref
-    [1, Hq, rank]; the rest :func:`_walk_scratch`."""
+    [1, Hq, rank]; the rest :func:`_walk_scratch`. ``chosen`` (static):
+    ahead of o_ref comes keep_ref [1, trips, trip keys] int32, row ``j`` the
+    keys of the row's ``j``-th trip that the query attends (1) or leaves out
+    (0)."""
+    keep_ref = rest[0] if chosen else None
+    o_ref, ring_ref, sem, walk_ref, acc_ref, m_ref, l_ref = rest[chosen:]
     b = pl.program_id(0)
     n_rows, n_pages = pt_ref.shape
     walk = _Walk(pt_ref, layer_ref, (pool_ref,), (ring_ref,), sem, walk_ref,
@@ -130,7 +142,16 @@ def _decode_kernel(pt_ref, len_ref, layer_ref, q_ref, pool_ref, o_ref,
     @pl.when(length > 0)
     def _busy():
         def attend(slot, k_start, *, pages, first):
-            _attend_trip(q_ref[0], ring_ref, slot, k_start, visible, acc_ref,
+            seen = visible
+            if chosen:      # without a window a span starts at page 0
+                tile = keep_ref[0, pl.ds(lax.div(k_start, trip * page_size),
+                                         1), :]
+                kept = tile[:, : pages * page_size] > 0     # [1, keys]
+
+                def seen(k_pos):
+                    return visible(k_pos) & kept
+
+            _attend_trip(q_ref[0], ring_ref, slot, k_start, seen, acc_ref,
                          m_ref, l_ref, pages=pages, page_size=page_size,
                          rank=rank, scale=scale, first=first)
 
@@ -155,12 +176,19 @@ def mla_decode_attention(
     sliding_window: int | None = None,
     name: str | None = None,             # the call site's, in a device trace
     trip: int | None = None,             # a test's or a probe's pages a trip
+    keep: jnp.ndarray | None = None,     # [B, Pmax * page] 1 / 0 a key
 ) -> jnp.ndarray:
     """Returns ``[B, Hq, rank]``: each head's softmax-weighted sum of the
     compressed rows of its slot's pages in layer ``layer`` (the caller
     applies ``W_uv``). One program a slot, in order; the pool stays where it
     lives and the programs copy the pages of a row's span (``page_span``)
-    themselves, :func:`trip_pages` at a time."""
+    themselves, :func:`trip_pages` at a time.
+
+    ``keep`` (``ops/dsa.py: keep_mask``; None: every key a position allows):
+    the keys each row's query attends. The walk is the same (every page of
+    the span is copied and scored); a key that was not chosen is masked like
+    one past the row's length. The call without it lowers as it did before
+    the operand existed."""
     B, Hq, width = q.shape
     _, _, page_size, _ = pool.shape
     trip = trip or trip_pages(page_size, sliding_window)
@@ -168,14 +196,23 @@ def mla_decode_attention(
     def at_row(i, pt, ln, ly):
         return (i, 0, 0)
 
+    chosen, extra = (), {}
+    if keep is not None:
+        if sliding_window is not None:
+            raise ValueError("a chosen set goes with no sliding window")
+        chosen, extra = (_by_trips(keep, page_table.shape[1], page_size,
+                                   trip).astype(jnp.int32),), {"chosen": True}
+
     return pl.pallas_call(
         functools.partial(_decode_kernel, page_size=page_size, trip=trip,
                           rank=rank, scale=scale,
-                          sliding_window=sliding_window),
+                          sliding_window=sliding_window, **extra),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(B,),
             in_specs=[pl.BlockSpec((1, Hq, width), at_row),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      *[pl.BlockSpec((1, *c.shape[1:]), at_row)
+                        for c in chosen]],
             out_specs=pl.BlockSpec((1, Hq, rank), at_row),
             scratch_shapes=_walk_scratch(trip, page_size, width, pool.dtype,
                                          Hq, rank)),
@@ -184,7 +221,17 @@ def mla_decode_attention(
             dimension_semantics=("arbitrary",)),
         interpret=interpret, name=name,
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), q, pool)
+      jnp.asarray(layer, jnp.int32).reshape(1), q, pool, *chosen)
+
+
+def _by_trips(keep: jnp.ndarray, n_pages: int, page_size: int,
+              trip: int) -> jnp.ndarray:
+    """``keep`` [..., Pmax * page] as ``[..., trips, trip keys]``: the keys of
+    a span's ``j``-th trip a tile (zeros past the table's last page)."""
+    trips = -(-n_pages // trip)
+    tile = trip * page_size
+    pad = [(0, 0)] * (keep.ndim - 1) + [(0, trips * tile - keep.shape[-1])]
+    return jnp.pad(keep, pad).reshape(*keep.shape[:-1], trips, tile)
 
 
 def ragged_q_block(width: int) -> int:
@@ -223,10 +270,9 @@ def ragged_walk(hist, q_lens, width: int, page_size: int, n_pages: int,
 
 
 def _ragged_kernel(pt_ref, first_ref, last_ref, hist_ref, qlen_ref, layer_ref,
-                   q_ref, pool_ref, o_ref, ring_ref, sem, walk_ref, acc_ref,
-                   m_ref, l_ref, *, page_size: int, q_block: int,
+                   q_ref, pool_ref, *rest, page_size: int, q_block: int,
                    q_blocks: int, trip: int, rank: int, scale: float,
-                   sliding_window: int | None):
+                   sliding_window: int | None, chosen: bool = False):
     """One (lane, q-block): the program walks the pages its queries see
     itself (:class:`_Walk`, an item a (lane, q-block), lanes in order).
     q_ref [1, Hq, Qb, lanes], head-major, so its rows flatten to ``r = h*Qb
@@ -236,7 +282,13 @@ def _ragged_kernel(pt_ref, first_ref, last_ref, hist_ref, qlen_ref, layer_ref,
     :func:`ragged_span`; one that reads nothing has ``last < first``) /
     hist_ref [R] / qlen_ref [R] / layer_ref [1] SMEM; pool_ref the whole
     stacked pool, where it lives; o_ref [1, Hq, Qb, rank]; the rest
-    :func:`_walk_scratch` at ``Hq * Qb`` rows."""
+    :func:`_walk_scratch` at ``Hq * Qb`` rows. ``chosen`` (static): ahead of
+    o_ref comes keep_ref [1, 1, trips, Qb, trip keys] int8, tile ``j`` the
+    keys of the span's ``j``-th trip that each query attends (1) or leaves
+    out (0): a key is visible where its position says so AND it was
+    chosen."""
+    keep_ref = rest[0] if chosen else None
+    o_ref, ring_ref, sem, walk_ref, acc_ref, m_ref, l_ref = rest[chosen:]
     b, qb = pl.program_id(0), pl.program_id(1)
     Hq, lanes = q_ref.shape[1], q_ref.shape[3]
     item = b * q_blocks + qb
@@ -269,8 +321,19 @@ def _ragged_kernel(pt_ref, first_ref, last_ref, hist_ref, qlen_ref, layer_ref,
     @pl.when(q0 < qlen)
     def _busy():
         def attend(slot, k_start, *, pages, first):
+            seen = visible
+            if chosen:      # without a window a span starts at page 0
+                tile = keep_ref[0, 0, lax.div(k_start, trip * page_size)]
+                kept = jnp.broadcast_to(
+                    tile[:, : pages * page_size].astype(jnp.int32)[None],
+                    (Hq, q_block, pages * page_size)).reshape(
+                        Hq * q_block, pages * page_size) > 0
+
+                def seen(k_pos):
+                    return visible(k_pos) & kept
+
             _attend_trip(q_ref[0].reshape(Hq * q_block, lanes), ring_ref,
-                         slot, k_start, visible, acc_ref, m_ref, l_ref,
+                         slot, k_start, seen, acc_ref, m_ref, l_ref,
                          pages=pages, page_size=page_size, rank=rank,
                          scale=scale, first=first)
 
@@ -297,6 +360,7 @@ def mla_ragged_attention(
     sliding_window: int | None = None,
     name: str | None = None,
     trip: int | None = None,             # a test's or a probe's pages a trip
+    keep: jnp.ndarray | None = None,     # [R, Qc, Pmax * page] int8
 ) -> jnp.ndarray:
     """A prompt's chunk over latent pages, absorbed like the decode kernel:
     each lane's span of ``q_lens`` queries attends causally over its own
@@ -307,7 +371,14 @@ def mla_ragged_attention(
     the pages of a q-block's span themselves, :func:`ragged_trip_pages` at a
     time. The spans (:func:`ragged_span`) are worked out here, once a call,
     and ride in as scalar-prefetch operands: a program reads its own and the
-    next ones' (whose copies it starts) instead of computing them."""
+    next ones' (whose copies it starts) instead of computing them.
+
+    ``keep`` (``ops/dsa.py: keep_mask``; None: every key a position allows):
+    the keys each query attends, 1 or 0 a (query, key), read inside the mask
+    by position. It rides in laid out by the kernel's own trips (``[R,
+    q-blocks, trips, Qb, trip keys]``, a program's block its q-block's
+    tiles); the call without it lowers as it did before the operand
+    existed."""
     R, Hq, Qc, width = q.shape
     _, _, page_size, _ = pool.shape
     q_block = ragged_q_block(Qc)
@@ -322,15 +393,28 @@ def mla_ragged_attention(
     def at_block(b, qb, *_):
         return (b, 0, qb, 0)
 
+    chosen, extra = (), {}
+    if keep is not None:
+        if sliding_window is not None:
+            raise ValueError("a chosen set goes with no sliding window")
+        tiles = _by_trips(keep.astype(jnp.int8), page_table.shape[1],
+                          page_size, trip)
+        chosen = (tiles.reshape(R, Qc // q_block, q_block, *tiles.shape[2:]
+                                ).transpose(0, 1, 3, 2, 4),)
+        extra = {"chosen": True}
+
     return pl.pallas_call(
         functools.partial(_ragged_kernel, page_size=page_size,
                           q_block=q_block, q_blocks=Qc // q_block, trip=trip,
                           rank=rank, scale=scale,
-                          sliding_window=sliding_window),
+                          sliding_window=sliding_window, **extra),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6, grid=(R, Qc // q_block),
             in_specs=[pl.BlockSpec((1, Hq, q_block, width), at_block),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      *[pl.BlockSpec((1, 1, *c.shape[2:]),
+                                     lambda b, qb, *_: (b, qb, 0, 0, 0))
+                        for c in chosen]],
             out_specs=pl.BlockSpec((1, Hq, q_block, rank), at_block),
             scratch_shapes=_walk_scratch(trip, page_size, width, pool.dtype,
                                          Hq * q_block, rank)),
@@ -340,4 +424,5 @@ def mla_ragged_attention(
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret, name=name,
     )(page_table.astype(jnp.int32), first.reshape(-1), last.reshape(-1),
-      hist, q_lens, jnp.asarray(layer, jnp.int32).reshape(1), q, pool)
+      hist, q_lens, jnp.asarray(layer, jnp.int32).reshape(1), q, pool,
+      *chosen)

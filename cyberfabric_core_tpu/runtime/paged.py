@@ -9,13 +9,19 @@ tiled TPU layout merging them in front of the kernel is a copy of the pool),
 indexed by the native radix prefix cache (runtime/native.py — C++ fabric_host).
 
 **The layout is the configuration's.** A model of latent attention
-(``ModelConfig.is_latent``: kimi_k2) caches one compressed row and one shared
-rotary key a token, so its pool is ONE array ``latent_pool`` [L, num_pages,
+(``ModelConfig.is_latent``) caches one compressed row and one shared rotary
+key a token, so its chain is the array ``latent_pool`` [L, num_pages,
 page_size, latent_lanes] (``latent_width`` numbers in whole lane tiles) with
-no kv-head axis and no V pool; ``pools``,
-``cache_operands``/``adopt``, the preemption movers and ``stats()`` follow
-``_pool_names``. The allocator, the radix tree, the refcounts and the
-admission protocol count pages, whatever a page holds.
+no kv-head axis and no V pool; where a query attends over a chosen set
+(``ModelConfig.is_sparse``) the chain is TWO arrays, as the K/V chain is:
+``index_pool`` [L, num_pages, page_size, index_lanes], a token's index key,
+rides ``latent_pool`` under the same page ids, tree, refcounts and
+allocator, as ``v_pool`` rides ``k_pool`` (a token's index key depends on
+its prefix alone, like its latent row, so a prefix hit hands on both). The
+arrays of a chain differ in their minor dimension only (``_page_tails``);
+``pools``, ``cache_operands``/``adopt``, the preemption movers and
+``stats()`` follow ``_pool_names``. The allocator, the radix tree, the
+refcounts and the admission protocol count pages, whatever a page holds.
 
 **Two page groups** (``ModelConfig.window_layers``: a stack whose layers
 attend over everything in one layer of four and over the last
@@ -141,16 +147,20 @@ class PrefixKVPool:
                     f"{model_config.name}: a latent page has no kv-head axis "
                     "to shard over tp")
             #: the pool arrays by attribute name, and a page's minor
-            #: dimensions as the movers hand them out
+            #: dimensions in each as the movers hand them out
             self._pool_names: tuple[str, ...] = ("latent_pool",)
-            self._page_tail: tuple[int, ...] = (model_config.latent_lanes,)
+            self._page_tails: tuple[tuple[int, ...], ...] = (
+                (model_config.latent_lanes,),)
+            if model_config.is_sparse:
+                self._pool_names += ("index_pool",)
+                self._page_tails += ((model_config.index_lanes,),)
         else:
             self._pool_names = ("k_pool", "v_pool")
-            self._page_tail = (model_config.num_kv_heads,
-                               model_config.head_dim)
-        shape = (L, num_pages, page_size, int(np.prod(self._page_tail)))
-        for name in self._pool_names:
-            pool = jnp.zeros(shape, dtype)
+            self._page_tails = ((model_config.num_kv_heads,
+                                 model_config.head_dim),) * 2
+        shape = (L, num_pages, page_size, int(np.prod(self._page_tails[0])))
+        for name, tail in zip(self._pool_names, self._page_tails):
+            pool = jnp.zeros((*shape[:3], int(np.prod(tail))), dtype)
             setattr(self, name, pool if sharding is None
                     else jax.device_put(pool, sharding))
         #: the window page group (module docstring): its pool follows the
@@ -302,8 +312,9 @@ class PrefixKVPool:
 
     @property
     def pools(self) -> tuple:
-        """The pool arrays: K and V, or the one latent pool; then the window
-        group's, as many again, where the model has one."""
+        """The pool arrays: K and V, or the latent pool (and the index pool
+        beside it); then the window group's, as many again, where the model
+        has one."""
         return tuple(getattr(self, name) for name in self._pool_names)
 
     @property
@@ -318,6 +329,11 @@ class PrefixKVPool:
 
     def pool_bytes(self) -> int:
         return sum(int(p.size) * p.dtype.itemsize for p in self.pools)
+
+    def index_pool_bytes(self) -> int:
+        """The index pool's part of :meth:`pool_bytes` (0: none)."""
+        pool = getattr(self, "index_pool", None)
+        return 0 if pool is None else int(pool.size) * pool.dtype.itemsize
 
     def window_pool_bytes(self) -> int:
         """The window group's part of :meth:`pool_bytes`."""
@@ -556,15 +572,15 @@ class PrefixKVPool:
         requests — SURVEY §5 checkpoint/resume; the serving analogue of the
         reference's suspend path). One gather per pool; the transfer is the
         chain's actual bytes, not the window. Returns [kv_layers, n, page,
-        Hkv, D] each (the PD wire format; a host reshape is a view; a latent pool:
-        its one array as [L, n, page, latent_lanes]), and where the
+        Hkv, D] each (the PD wire format; a host reshape is a view; a latent
+        chain: [L, n, page, latent_lanes], and [L, n, page, index_lanes]
+        where it has an index pool), and where the
         model has recurrent state one more entry: slot ``state_row``'s row
         of the slab, so that the request resumes exactly."""
         idx = jnp.asarray(chain, jnp.int32)
-        out = (self.cfg.kv_layers, len(chain), self.page_size,
-               *self._page_tail)
-        host_kv = tuple(np.asarray(pool[:, idx]).reshape(out)
-                        for pool in self.pools[: self._n_chain_pools])
+        out = (self.cfg.kv_layers, len(chain), self.page_size)
+        host_kv = tuple(np.asarray(pool[:, idx]).reshape(*out, *tail)
+                        for pool, tail in zip(self.pools, self._page_tails))
         if self.state is not None:
             if state_row is None:
                 raise ValueError("a model with recurrent state is saved with "
@@ -674,7 +690,12 @@ class PrefixKVPool:
             "native": self.tree.native,
             # what a page holds: the layout is the configuration's
             "page_layout": ("latent" if self.cfg.is_latent else "kv"),
-            "page_shape": [self.page_size, *self._page_tail],
+            "page_shape": [self.page_size, *self._page_tails[0]],
+            # the index keys cached beside the latent rows (0, 0: none)
+            "index_lanes": self.cfg.index_lanes,
+            "index_topk": self.cfg.index_topk,
+            "index_heads": self.cfg.index_heads if self.cfg.is_sparse else 0,
+            "index_cache_bytes": self.index_pool_bytes(),
             "cache_bytes_per_token": self.cfg.cache_bytes_per_token(
                 jnp.dtype(self.dtype).itemsize),
             # and in the window group, while it lies inside its row's window

@@ -42,6 +42,10 @@ _MATMUL_LEAVES = {"wq", "wk", "wv", "wo", "gate", "up", "down",
                   # hyper-connections' maps, PolyNorm's coefficients and the
                   # router stay float32
                   "w_lam",
+                  # glm_moe_dsa: the indexer's query and key projections;
+                  # its weight a head (index_w) stays float32 like a router,
+                  # the index key's LayerNorm as it is
+                  "index_wq", "index_wk",
                   # falcon_h1's mixer: W_in and W_out like any matrix; its
                   # conv, A_log, D, dt_bias and norm weights stay f32
                   "ssm_in", "ssm_out"}

@@ -180,17 +180,38 @@ _MOE_SERIES_OF = {"assignments": "llm_moe_assignments_total",
                   "compact": "llm_moe_layer_forwards_compact_total",
                   "forwards": "llm_moe_layer_forwards_total",
                   "decode_local": "llm_moe_decode_assignments_local_total",
-                  "item_rows": "llm_moe_item_rows_total"}
+                  "item_rows": "llm_moe_item_rows_total",
+                  # a model whose queries attend over a chosen set: the keys
+                  # its index passes scored (a query's visible keys), the
+                  # keys attended, the queries and those of them that saw
+                  # more than ``index_topk`` keys, over layers and forwards
+                  "keys_scored": "llm_dsa_keys_scored_total",
+                  "keys_selected": "llm_dsa_keys_selected_total",
+                  "queries": "llm_dsa_queries_total",
+                  "queries_binding": "llm_dsa_queries_binding_total"}
 _MOE_DRAIN_SERIES = ("llm_moe_experts_offered_total",
                      "llm_moe_decode_experts_touched_total",
                      "llm_moe_decode_experts_offered_total")
+#: and of decode chunks' forwards alone, as the decode-only experts are kept
+#: apart: the keys scored and attended, the queries and those the selection
+#: bound, and the index passes (steps x layers), so that a reader can price
+#: ONE call of a decode step
+_DSA_DECODE_SERIES = {
+    "keys_scored": "llm_dsa_decode_keys_scored_total",
+    "keys_selected": "llm_dsa_decode_keys_selected_total",
+    "queries": "llm_dsa_decode_queries_total",
+    "queries_binding": "llm_dsa_decode_queries_binding_total"}
+_DSA_DECODE_CALLS = "llm_dsa_decode_calls_total"
 
 
 def _moe_series(counters: tuple) -> tuple:
     """The /metrics series of a model whose module has these
     ``STEP_COUNTERS`` (none for none)."""
+    if not counters:
+        return ()
     return (tuple(_MOE_SERIES_OF[n] for n in counters) + _MOE_DRAIN_SERIES
-            if counters else ())
+            + ((*_DSA_DECODE_SERIES.values(), _DSA_DECODE_CALLS)
+               if "keys_scored" in counters else ()))
 
 
 #: /metrics of a model whose stack runs several times a token
@@ -3541,8 +3562,17 @@ class ContinuousBatchingEngine:
             bump_counter("llm_moe_decode_experts_touched_total",
                          n=counts["touched"])
             bump_counter("llm_moe_decode_experts_offered_total", n=offered)
-        return drained, ({"local_assignments": counts["local"]}
-                         if "local" in counts else {})
+        said = ({"local_assignments": counts["local"]}
+                if "local" in counts else {})
+        if "keys_scored" in counts:
+            if decode:
+                for name, series in _DSA_DECODE_SERIES.items():
+                    bump_counter(series, n=counts[name])
+                bump_counter(_DSA_DECODE_CALLS,
+                             n=forwards * self.model_config.num_layers)
+            said.update(keys_scored=counts["keys_scored"],
+                        keys_selected=counts["keys_selected"])
+        return drained, said
 
     def _count_loop(self, counts: dict[str, int],
                     forwards: int) -> dict[str, Any]:

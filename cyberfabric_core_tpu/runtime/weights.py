@@ -127,10 +127,10 @@ def load_llama_params(
     """
     if cfg.is_latent:
         raise ValueError(
-            f"{cfg.name}: no map from a published kimi_k2 checkpoint's tensor "
-            "names onto the two stacks of models/kimi_k2.py yet (and its "
-            "rotary pairs are interleaved, this tree's are halves): served "
-            "on synthetic weights only")
+            f"{cfg.name}: no map from a published {cfg.architecture} "
+            "checkpoint's tensor names onto the two stacks of a latent "
+            "model's tree yet (and its rotary pairs are interleaved, this "
+            "tree's are halves): served on synthetic weights only")
     idx = SafetensorsIndex(Path(model_dir))
     shardings = shardings or {}
     from .quant import _MATMUL_LEAVES, _quantize_embed, quantize_weight

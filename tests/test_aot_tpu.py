@@ -1409,10 +1409,12 @@ def test_scheduler_programs_compile_for_v5e_at_ouro():
         assert live <= V5E_HBM_BYTES, (name, live)
 
 
-def _laguna_programs(conf_file: str, sharding):
+def _laguna_programs(conf_file: str, sharding, pools_of=None):
     """The scheduler's own two programs for a laguna configuration file's
-    served shapes, and their abstract operands: (cfg, the four pools,
-    {name: (fn, args)})."""
+    served shapes, and their abstract operands: (cfg, params, the four
+    pools, {name: (fn, args)}). ``pools_of(cfg, eng, sds, pages, page)``:
+    another architecture's cache operands (absent: laguna's two page groups
+    of K and V)."""
     import json
 
     from cyberfabric_core_tpu.models import decoder_module, get_config
@@ -1438,18 +1440,21 @@ def _laguna_programs(conf_file: str, sharding):
     eng.n_slots, eng.pmax = n, max_seq // page
     eng.spec_k, eng._spec_w = 0, 1
     eng.mesh = eng._attn_mesh = None
-    assert eng._tw == 2 * eng.pmax
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     params = jax.tree.map(lambda x: sds(x.shape, x.dtype),
                           abstract_params(cfg, jnp.bfloat16, "int8"))
-    lanes = cfg.num_kv_heads * cfg.head_dim
-    full = sds((cfg.kv_layers, pages, page, lanes), jnp.bfloat16)
-    window = sds((cfg.window_layers, eng._window_pages(), page, lanes),
-                 jnp.bfloat16)
-    pools = (full, full, window, window)
+    if pools_of is None:
+        assert eng._tw == 2 * eng.pmax
+        lanes = cfg.num_kv_heads * cfg.head_dim
+        full = sds((cfg.kv_layers, pages, page, lanes), jnp.bfloat16)
+        window = sds((cfg.window_layers, eng._window_pages(), page, lanes),
+                     jnp.bfloat16)
+        pools = (full, full, window, window)
+    else:
+        pools = pools_of(cfg, eng, sds, pages, page)
     eng.pool = types.SimpleNamespace(cache_operands=lambda: pools)
     if sharding is None:            # the CPU's: interpreted kernels
         eng._build_programs()
@@ -1559,6 +1564,142 @@ def test_scheduler_programs_compile_for_v5e_at_laguna():
         for kernel in kernels:
             assert kernel in text, (name, kernel)
         for pool in (pools[0], pools[2]):
+            _assert_whole_array_untouched(text, pool, name)
+        assert mem.alias_size_in_bytes >= sum(
+            int(np.prod(p.shape)) * 2 for p in pools), name
+        assert live <= V5E_HBM_BYTES, (name, live)
+
+
+# ---- attention over a chosen set (models/glm_dsa.py, ops/dsa.py)
+
+def test_dsa_kernels_compile_at_served_shapes(one_chip):
+    """The two index kernels of ``ops/dsa.py`` (one program a decode row over
+    its index pages of 128 lanes; one a block of 32 queries, 1 024 query rows
+    against key blocks of 1 024) and both latent kernels under the one
+    operand they gain (``keep``: [32, 16384] of decode rows as int32 tiles a
+    trip, [1, 512, 16384] of a chunk as int8 tiles a q-block and trip), at
+    glm-5's served shapes: 32 slots of 16 384 tokens, 32 index heads, 64
+    heads on a latent page of 640 lanes."""
+    from cyberfabric_core_tpu.ops import dsa
+    from cyberfabric_core_tpu.ops.mla_attention import (mla_decode_attention,
+                                                        mla_ragged_attention)
+
+    batch, pmax, rank = 32, 256, 512
+    index = one_chip((2, batch * pmax + 1, _PAGE, 128), jnp.bfloat16)
+    latent = one_chip((2, batch * pmax + 1, _PAGE, 640), jnp.bfloat16)
+    table, lens = one_chip((batch, pmax), jnp.int32), one_chip((batch,),
+                                                               jnp.int32)
+    layer, lane = one_chip((), jnp.int32), one_chip((1,), jnp.int32)
+    _compiles_with_mosaic(
+        lambda q, w, p, pt, n, ly: dsa.index_scores(q, w, p, pt, n, ly),
+        one_chip((batch, 32, 128), jnp.bfloat16),
+        one_chip((batch, 32), jnp.float32), index, table, lens, layer)
+    _compiles_with_mosaic(
+        lambda q, w, p, pt, h, n, ly: dsa.index_scores_ragged(
+            q, w, p, pt, h, n, ly),
+        one_chip((1, 512, 32, 128), jnp.bfloat16),
+        one_chip((1, 512, 32), jnp.float32), index,
+        one_chip((1, pmax), jnp.int32), lane, lane, layer)
+    _compiles_with_mosaic(
+        lambda q, p, pt, n, ly, keep: mla_decode_attention(
+            q, p, pt, n, ly, rank=rank, scale=0.0625, interpret=False,
+            keep=keep, name="dsa_sparse_decode_attention"),
+        one_chip((batch, 64, 640), jnp.bfloat16), latent, table, lens, layer,
+        one_chip((batch, pmax * _PAGE), jnp.int8))
+    _compiles_with_mosaic(
+        lambda q, p, pt, h, n, ly, keep: mla_ragged_attention(
+            q, p, pt, h, n, ly, rank=rank, scale=0.0625, interpret=False,
+            keep=keep, name="dsa_ragged_attention"),
+        one_chip((1, 64, 512, 640), jnp.bfloat16), latent,
+        one_chip((1, pmax), jnp.int32), lane, lane, layer,
+        one_chip((1, 512, pmax * _PAGE), jnp.int8))
+
+
+def _glm_dsa_programs(conf_file: str, sharding):
+    """The scheduler's own two programs for a glm_moe_dsa configuration
+    file's served shapes (:func:`_laguna_programs` over the latent chain's
+    two arrays): (cfg, params, the two pools, {name: (fn, args)})."""
+    def pools(cfg, eng, sds, pages, page):
+        return tuple(sds((cfg.kv_layers, pages, page, lanes), jnp.bfloat16)
+                     for lanes in (cfg.latent_lanes, cfg.index_lanes))
+
+    return _laguna_programs(conf_file, sharding, pools)
+
+
+def test_glm_dsa_programs_take_two_pools_and_hold_the_named_parts():
+    """The tiny twin of the served compile below, lowered and not compiled
+    (seconds): ``tiny-glm-dsa-share4``'s two programs take TWO donated pools
+    (the latent rows and the index keys), hold the index pass, the top-k and
+    the latent kernels under the chosen keys by the names a device trace
+    shows, and keep the indexer's head weights float32."""
+    cfg, params, pools, programs = _glm_dsa_programs(
+        "benchmark/tests/rehearsal/configs/tiny-glm-dsa.json", None)
+    assert (cfg.kv_layers, cfg.index_topk) == (5, 12)
+    assert pools[0].shape[:3] == pools[1].shape[:3]
+    assert params["layers"]["index_wq"]["q"].dtype == jnp.int8
+    assert params["dense"]["index_w"].dtype == jnp.float32
+    for name, (fn, args) in programs.items():
+        lowered = fn.lower(*args)
+        text = lowered.as_text(debug_info=True)     # the scopes' names
+        scopes = ["glm_dsa_layer", "dsa_index_scores", "dsa_topk",
+                  "dsa_sparse_decode_attention"]
+        if name != "paged_decode_chunk":
+            scopes += ["dsa_index_scores_ragged", "dsa_ragged_attention"]
+        for scope in scopes:
+            assert scope in text, (name, scope)
+        assert lowered.as_text().count("tf.aliasing_output") == 2, name
+
+
+@pytest.mark.slow
+def test_scheduler_programs_compile_for_v5e_at_glm_dsa():
+    """The scheduler's own ``paged_decode_chunk`` and ``mixed_step`` at 512
+    for glm-5-share16-7l int8 at the served shapes of
+    ``benchmark/configs/glm-5-int8.json`` (32 slots of 16 384, 8193 pages in
+    both arrays of the latent chain, 16 held experts in 6 expert layers, 8
+    steps a chunk), on one described chip: each holds the index kernels, the
+    latent kernels under their chosen-set names and the ``grouped_matmul``
+    Mosaic call, donates both pools and copies neither, fits the 15.75 GiB
+    the compiler budgets. A compile, not a chip run (4-6 minutes)."""
+    import time
+
+    from jax.sharding import SingleDeviceSharding
+
+    from cyberfabric_core_tpu.ops.platform import compiled_kernels
+    from cyberfabric_core_tpu.parallel.feasibility import V5E_HBM_BYTES
+
+    topo = _topo_or_skip()
+    cfg, params, pools, programs = _glm_dsa_programs(
+        "benchmark/configs/glm-5-int8.json",
+        SingleDeviceSharding(topo.devices[0]))
+    assert pools[0].shape == (7, 8193, 64, 640)
+    assert pools[1].shape == (7, 8193, 64, 128)
+    assert params["layers"]["moe_gate"]["q"].shape == (6, 16, 6144, 2048)
+    assert params["layers"]["index_wq"]["q"].shape == (6, 2048, 32 * 128)
+    assert params["lm_head"]["q"].shape == (6144, 19360)
+    for name, (fn, args) in programs.items():
+        started = time.monotonic()
+        with compiled_kernels():
+            compiled = fn.lower(*args).compile()
+        took = time.monotonic() - started
+        mem = compiled.memory_analysis()
+        live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+        print(f"{name}: argument {mem.argument_size_in_bytes / 1e9:.2f} "
+              f"output {mem.output_size_in_bytes / 1e9:.2f} aliased "
+              f"{mem.alias_size_in_bytes / 1e9:.2f} temp "
+              f"{mem.temp_size_in_bytes / 1e9:.2f} GB, compiled in "
+              f"{took:.0f} s")
+        text = compiled.as_text()
+        if os.environ.get("AOT_DUMP_DIR"):
+            Path(os.environ["AOT_DUMP_DIR"],
+                 f"glm-dsa-{name}.hlo.txt").write_text(text)
+        kernels = ["grouped_matmul", "dsa_index_scores",
+                   "dsa_sparse_decode_attention"]
+        if name != "paged_decode_chunk":
+            kernels += ["dsa_index_scores_ragged", "dsa_ragged_attention"]
+        for kernel in kernels:
+            assert kernel in text, (name, kernel)
+        for pool in pools:
             _assert_whole_array_untouched(text, pool, name)
         assert mem.alias_size_in_bytes >= sum(
             int(np.prod(p.shape)) * 2 for p in pools), name

@@ -19,7 +19,8 @@ def test_the_real_files_names_resolve_and_only_add(monkeypatch):
     ``benchmark/``). Here it reads each list up to ouro's own entry, and what
     later PRs appended (PR 53: the three ragged latent kernel metrics; PR 54:
     laguna's configuration, cell, eight metrics and the window layers' pages
-    a program) is held to come after it, in one piece and in the order it
+    a program; PR 58: the configuration of attention over a chosen set, its
+    cell and its twelve metrics) is held to come after it, in one piece and in the order it
     was added. The same for a metric's ``workloads`` list that ouro's cell
     was appended to and a later cell after it."""
     raw = (test_ouro.REPO / "BENCHMARK.json").read_text()
@@ -35,8 +36,9 @@ def test_the_real_files_names_resolve_and_only_add(monkeypatch):
     configs, since_configs = cut("configs", test_ouro.REAL)
     cells, since_cells = cut("workloads", test_ouro.REAL_CELL)
     metrics, since = cut("per_layer", test_ouro.NEW_METRICS[-1])
-    assert since_configs == ["laguna-s-2.1-int8"]
-    assert since_cells == ["laguna-s-2.1-int8.longtail-closed-64"]
+    assert since_configs == ["laguna-s-2.1-int8", "glm-5-int8"]
+    assert since_cells == ["laguna-s-2.1-int8.longtail-closed-64",
+                           "glm-5-int8.longctx-closed-32"]
     assert since == ["mla_ragged_attention_us",
                      "gdla_full_ragged_attention_us",
                      "gdla_window_ragged_attention_us",
@@ -49,7 +51,17 @@ def test_the_real_files_names_resolve_and_only_add(monkeypatch):
                      "gqa_window_ragged_attention_us",
                      "gqa_window_moe_step_roofline",
                      "attn_window_pages_per_program",
-                     "ragged_paged_attention_us"]      # PR 55: ouro's cell too
+                     "ragged_paged_attention_us",      # PR 55: ouro's cell too
+                     # PR 58: none of them lists ouro's cell
+                     "dsa_index_scores_us", "dsa_topk_us",
+                     "dsa_sparse_decode_attention_us",
+                     "dsa_ragged_attention_us", "dsa_kernels_time_share",
+                     "dsa_selected_share", "dsa_binding_share",
+                     "dsa_decode_keys_scored_per_call",
+                     "dsa_decode_keys_selected_per_call",
+                     "dsa_index_scores_roofline",
+                     "dsa_sparse_decode_attention_roofline",
+                     "dsa_moe_step_roofline"]
     later = {}
 
     def as_ouro_left(metric: dict) -> dict:
