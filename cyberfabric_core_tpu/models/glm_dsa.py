@@ -23,22 +23,25 @@ tile). The latent row is NOT widened to hold it: the index pass reads 256 B
 a key, not the row's 1 536.
 
 **The three steps of a decode row** (``ops/dsa.py``): the index pass over
-the row's index pages (``dsa_index_scores``), the exact top-k
-(``dsa_topk``), and the latent decode kernel over the row's span under the
-keys the query chose (``dsa_sparse_decode_attention``: it attends the chosen
-rows and no others, and reads every page of the span). **A chunk** is the
-same three steps over its queries: ``dsa_index_scores_ragged``, each query's
-top-k, and the ragged latent kernel under the keys each query chose
+the row's index pages (``dsa_index_scores``), the exact selection
+(``dsa_select`` inside the scope ``dsa_topk``: the ``index_topk``-th score
+and its tie's position by bisection, no sort; then the mask), and the latent
+decode kernel over the row's span under the keys the query chose
+(``dsa_sparse_decode_attention``: it attends the chosen rows and no others,
+and reads every page of the span). **A chunk** is the same three steps over
+its queries: ``dsa_index_scores_ragged``, each query's selection, and the
+ragged latent kernel under the keys each query chose
 (``dsa_ragged_attention``). One operand, ``keep``, is all either kernel
 gains.
 
 Every entry point also returns ``aux``: kimi_k2's (the experts chosen and
 its ``STEP_COUNTERS``), the keys each query chose (``aux["chosen"]`` [L, N,
-index_topk], positions by falling score, -1 past the count: what the
-benchmark's judge attends over and holds against the reference's own scores)
-and this module's counters, summed over the layers: the keys the index
-passes scored, the keys attended, the queries and those of them that saw
-more than ``index_topk`` keys.
+index_topk], positions rising, -1 past the count: what the benchmark's judge
+attends over and holds against the reference's own scores; made from the
+mask, and not computed by a program that does not return it: the served
+steps read the counters alone) and this module's counters, summed over the
+layers: the keys the index passes scored, the keys attended, the queries and
+those of them that saw more than ``index_topk`` keys.
 """
 
 from __future__ import annotations
@@ -162,6 +165,16 @@ def _finish(aux: Aux, chosen: jnp.ndarray, counts: jnp.ndarray) -> Aux:
             **{n: counts[i] for i, n in enumerate(DSA_COUNTERS)}}
 
 
+def _chosen_keys(cfg: ModelConfig, scores: jnp.ndarray, span,
+                 interpret: bool) -> jnp.ndarray:
+    """``[.., S]`` int8: the ``index_topk`` keys of largest ``scores`` a
+    query (every key it sees while they are fewer), none at or past
+    ``span``."""
+    _, kth, kth_at = dsa.select(scores, cfg.index_topk, span,
+                                interpret=interpret)
+    return dsa.keep_mask(scores, kth, kth_at)
+
+
 def _decode_attend(cfg, q, qi, w, pools, table, lengths, layer, scale,
                    interpret):
     """The three steps of decode rows ``q`` [B, Hq, lanes] of ``lengths``
@@ -171,14 +184,12 @@ def _decode_attend(cfg, q, qi, w, pools, table, lengths, layer, scale,
 
     scores = dsa.index_scores(qi, w, index_pool, table, lengths, layer,
                               interpret=interpret)
-    chosen, _, kth, kth_at = dsa.select(scores, cfg.index_topk,
-                                        span=jnp.max(lengths))
+    keep = _chosen_keys(cfg, scores, jnp.max(lengths), interpret)
     o = mla_decode_attention(
         q, latent_pool, table, lengths, layer, rank=cfg.kv_lora_rank,
-        scale=scale, interpret=interpret,
-        keep=dsa.keep_mask(scores, kth, kth_at),
+        scale=scale, interpret=interpret, keep=keep,
         name="dsa_sparse_decode_attention")
-    return o, chosen
+    return o, dsa.chosen_positions(keep, cfg.index_topk)
 
 
 # ------------------------------------------------------------------ forwards
@@ -277,17 +288,16 @@ def forward_paged_mixed(
                 qi[nd:].reshape(R, Qc, *qi.shape[1:]),
                 w[nd:].reshape(R, Qc, -1), pools[1], lay.lane_table, hist,
                 q_lens, layer, interpret=interpret)
-            picked, _, kth, kth_at = dsa.select(
-                scores, topk, span=jnp.max(jnp.where(q_lens > 0,
-                                                     hist + q_lens, 0)))
+            keep = _chosen_keys(
+                cfg, scores, jnp.max(jnp.where(q_lens > 0, hist + q_lens, 0)),
+                interpret)
             lane_q = q[nd:].reshape(R, Qc, *q.shape[1:]).transpose(0, 2, 1, 3)
             lane = mla_ragged_attention(
                 lane_q, pools[0], lay.lane_table, hist, q_lens, layer,
-                rank=rank, scale=scale, interpret=interpret,
-                keep=dsa.keep_mask(scores, kth, kth_at),
+                rank=rank, scale=scale, interpret=interpret, keep=keep,
                 name="dsa_ragged_attention")
             o = lane.transpose(0, 2, 1, 3).reshape(R * Qc, -1, rank)
-            picked = picked.reshape(R * Qc, -1)
+            picked = dsa.chosen_positions(keep, topk).reshape(R * Qc, -1)
             if nd:
                 dec, dec_picked = _decode_attend(
                     cfg, q[:nd], qi[:nd], w[:nd], pools, *lay.work, layer,

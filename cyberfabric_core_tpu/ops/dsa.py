@@ -1,5 +1,5 @@
-"""Attention over a chosen set: the index pass, the exact top-k, and the mask
-by which the chosen keys reach the latent attention kernels.
+"""Attention over a chosen set: the index pass, the exact selection, and the
+mask by which the chosen keys reach the latent attention kernels.
 
 A model whose queries attend over a chosen set (``ModelConfig.is_sparse``;
 ``models/glm_dsa.py``) caches, a token a layer, ONE index key ``kI`` of
@@ -27,8 +27,19 @@ their own tile of the output (``[.., trip, .., trip keys]``, the tile a
 dynamic index on a leading dimension), which the wrapper lays out as ``[..,
 S]``.
 
-**The top-k is exact** (:func:`select`, ``jax.lax.top_k``: ties go to the
-lower position): an approximate set would be another result.
+**The selection is exact and sorts nothing** (:func:`select`, the kernel
+``dsa_select``): an approximate set would be another result, and what a step
+needs of a row of scores is not its order but two numbers, the
+``index_topk``-th largest score and the position that breaks the tie there
+(the lower position wins, ``jax.lax.top_k``'s order among equals).
+``lax.top_k`` at 2 048 of 16 384 sorts the row: 0.32-0.45 ms for a decode
+step's 32 rows, 0.5-4.5 ms for a chunk's 512 queries by its span, 12-18% of
+the glm cell's device time. The kernel reads a block of rows into VMEM once
+and bisects on the scores' bits, 32 rounds of a compare and a count along the
+row, then 14 more over the position's: 13-22 us for the decode rows, 0.11-0.27
+ms for a chunk (PERF.md, PR 59). The list of chosen positions, which the
+benchmark's judge reads and no served step does, is made from the mask
+(:func:`chosen_positions`) and is not computed where it is not returned.
 
 **The attention behind it does not gather.** Both latent kernels
 (``ops/mla_attention.py``) walk a span as they do and take one more operand,
@@ -275,44 +286,165 @@ def index_scores_ragged(
         R, Qc, -1)[..., : n_pages * page_size]
 
 
-def _prefixes(keys: int, topk: int, most: int = 3) -> tuple[int, ...]:
-    """The prefixes of a row of ``keys`` scores a top-k may be taken over,
-    rising: ``keys`` halved while a prefix still holds ``2 topk``, at most
-    ``most`` of them. A sort's cost is its width's (``lax.top_k`` at 2 048 of
-    16 384 sorts the row: 4.5 ms for 512 queries), whatever lies in it."""
-    sizes = [keys]
-    while len(sizes) < most and sizes[-1] % 2 == 0 \
-            and sizes[-1] // 2 >= 2 * topk:
-        sizes.append(sizes[-1] // 2)
-    return tuple(reversed(sizes))
+#: rows a program of the selection takes at most (64 rows of 16 384 float32
+#: scores are 4 MB, twice in flight) and the scores one step of a round
+#: compares: 32 vector registers, 512 lanes of 64 rows or 1 024 of a decode
+#: step's 32, which is also the grain a span is rounded up to. A round costs
+#: a register about a cycle and a step a few more; at 64 registers a step
+#: they spill (PERF.md, PR 59, has the probe)
+SELECT_ROWS = 64
+SELECT_STEP = 32 * 1024
+
+_INT_MIN = -2 ** 31
 
 
-def select(scores: jnp.ndarray, topk: int, span=None):
-    """The EXACT ``topk`` largest of ``scores`` [..., S] a query:
-    (positions [..., topk] int32 by falling score, -1 past the keys the
-    query sees, count [...] int32 of those it keeps, the k-th score and the
-    k-th position [...]: a key is kept where its score is over the k-th, or
-    equal to it at a position no later, which is ``lax.top_k``'s order among
-    equals). ``topk`` over ``S``: every key the query sees. ``span`` (a
-    traced scalar; None: ``S``): no query sees a key at or past it, so the
-    top-k is taken over the shortest of :func:`_prefixes` that holds it:
-    the same set, a narrower sort."""
+def _score_of(key):
+    """The float32 whose ORDER KEY is ``key`` (int32, any shape): a float's
+    bits as a signed integer, a negative float's lower 31 bits flipped, rise
+    with the float. A key between the infinities' and the integer's ends is
+    a NaN, which no score compares at or over."""
+    bits = jnp.where(key >= 0, key, key ^ 0x7FFFFFFF)
+    return lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def _select_kernel(span_ref, s_ref, kth_ref, at_ref, count_ref, *, k: int,
+                   lanes: int):
+    """A block of rows: s_ref [rows, S] f32 in VMEM; out, a row each over 128
+    lanes, the k-th score, the k-th position and the count kept. Every round
+    is one pass over the lane blocks under ``span_ref[0]``: a compare and a
+    count a row, nothing written."""
+    rows, width = s_ref.shape
+    blocks = pl.cdiv(jnp.minimum(span_ref[0], width), lanes)
+    lane = lax.broadcasted_iota(jnp.int32, (rows, lanes), 1)
+
+    def count(hit):
+        """[rows, 1] f32: the keys under the span where ``hit(block [rows,
+        lanes], its first lane)`` holds (a count is at most 16 384: exact)."""
+        def step(j, acc):
+            start = pl.multiple_of(j * lanes, lanes)
+            took = jnp.where(hit(s_ref[:, pl.ds(start, lanes)], start),
+                             1.0, 0.0)
+            return acc + sum(took[:, i: i + 128]
+                             for i in range(0, lanes, 128))
+
+        acc = lax.fori_loop(0, blocks, step,
+                            jnp.zeros((rows, 128), jnp.float32))
+        return jnp.sum(acc, axis=1, keepdims=True)
+
+    # the largest order key that k scores reach, a bit a round from the top
+    # (the sign first): the k-th largest score, exactly
+    def score_round(i, key):
+        cand = key ^ lax.shift_left(jnp.int32(1), 31 - i)
+        thr = _score_of(cand)
+        return jnp.where(count(lambda s, _: s >= thr) >= k, cand, key)
+
+    kth = _score_of(lax.fori_loop(
+        0, 32, score_round, jnp.full((rows, 1), _INT_MIN, jnp.int32)))
+    # the rounds end on the LARGEST key that compares equal to the k-th
+    # score: among what the device reads as zero (either sign, and the
+    # denormals it flushes) that is the largest denormal; hand on +0.0
+    kth = jnp.where(kth == 0.0, 0.0, kth)
+    binding = kth > NEG / 2         # NaN: the span holds under k lanes
+    floor = jnp.where(binding, kth, NEG / 2)
+    over = count(lambda s, _: s > floor)
+    need = k - over                 # of the keys AT the k-th score
+
+    # the position of the ``need``-th of them by rising position: the
+    # largest ``at`` with fewer than ``need`` of them before it
+    at_bits = max(1, (width - 1).bit_length())
+
+    def position_round(i, at):
+        cand = at | lax.shift_left(jnp.int32(1), at_bits - 1 - i)
+        before = count(lambda s, start: (s == kth) & (lane < cand - start))
+        return jnp.where(before < need, cand, at)
+
+    at = lax.fori_loop(0, at_bits, position_round,
+                       jnp.zeros((rows, 1), jnp.int32))
+    kth_ref[...] = jnp.broadcast_to(jnp.where(binding, kth, NEG),
+                                    kth_ref.shape)
+    at_ref[...] = jnp.broadcast_to(jnp.where(binding, at, -1), at_ref.shape)
+    count_ref[...] = jnp.broadcast_to(
+        jnp.where(binding, k, over.astype(jnp.int32)), count_ref.shape)
+
+
+@functools.partial(jax.jit, static_argnames=("topk", "interpret", "rows",
+                                             "lanes"))
+def select(
+    scores: jnp.ndarray,      # [..., S] float32, NEG where a query sees none
+    topk: int,
+    span: jnp.ndarray | int | None = None,
+    *,
+    interpret: bool | pltpu.InterpretParams = False,
+    rows: int | None = None,
+    lanes: int | None = None,
+):
+    """The EXACT ``topk`` largest of ``scores`` a query, as the two numbers
+    :func:`keep_mask` takes: (count [...] int32 of the keys it keeps, the
+    k-th score and the k-th position [...]). A key is kept where its score
+    is over the k-th, or equal to it at a position no later: ``lax.top_k``'s
+    order among equals, zeros of both signs equal (as ``keep_mask`` compares
+    them). A query that sees fewer than ``topk`` keys keeps them all: its
+    k-th score reads :data:`NEG`, its k-th position -1. ``span`` (a traced
+    scalar; None: ``S``): no query sees a key at or past it, and no round
+    reads one. ``rows`` and ``lanes`` pick a program's block and a round's
+    step in a test or a probe.
+
+    **Nothing is sorted.** What a step needs of a row of 16 384 scores is a
+    threshold, and ``lax.top_k`` at 2 048 of 16 384 SORTS the row: 0.42 ms
+    for a decode step's 32 rows, 1.5-4.5 ms for a chunk's 512 queries, 12-18%
+    of the glm cell's device time (PERF.md, PRs 58-59). Here a program holds
+    ``rows`` rows in VMEM, read from HBM once, and finds a row's k-th score
+    by bisection on the scores' bits: 32 rounds from the sign down, each a
+    compare with a candidate and a count along the row, build the largest
+    order key that ``topk`` scores reach; as many rounds as a position has
+    bits then find where the last kept key of that score sits."""
+    lead, width = scores.shape[:-1], scores.shape[-1]
+    k = min(topk, width)
+    flat = scores.reshape(-1, width)
+    if width % 128:          # whole lane tiles: a test's width, no served one
+        flat = jnp.pad(flat, ((0, 0), (0, -width % 128)),
+                       constant_values=NEG)
+    n, padded = flat.shape
+    rows = rows or min(SELECT_ROWS, -(-n // 8) * 8)
+    if lanes is None:        # whole lane tiles, a power of two of them
+        lanes = 128
+        while 2 * lanes * rows <= SELECT_STEP and padded % (2 * lanes) == 0:
+            lanes *= 2
+    span = width if span is None else span
     with jax.named_scope("dsa_topk"):
-        k = min(topk, scores.shape[-1])
+        out = pl.pallas_call(
+            functools.partial(_select_kernel, k=k, lanes=lanes),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(pl.cdiv(n, rows),),
+                in_specs=[pl.BlockSpec((rows, padded), lambda i, *_: (i, 0))],
+                out_specs=[pl.BlockSpec((rows, 128), lambda i, *_: (i, 0))
+                           for _ in range(3)]),
+            out_shape=[jax.ShapeDtypeStruct((n, 128), dtype)
+                       for dtype in (jnp.float32, jnp.int32, jnp.int32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=_VMEM_LIMIT),
+            interpret=interpret, name="dsa_select",
+        )(jnp.asarray(span, jnp.int32).reshape(1), flat)
+    kth, at, count = (x[:, 0].reshape(lead) for x in out)
+    return count, kth, at
 
-        def over(width: int, scores):
-            values, positions = lax.top_k(scores[..., :width], k)
-            seen = values > NEG / 2
-            positions = jnp.where(seen, positions, -1).astype(jnp.int32)
-            return (positions, jnp.sum(seen, axis=-1, dtype=jnp.int32),
-                    values[..., -1], positions[..., -1])
 
-        sizes = _prefixes(scores.shape[-1], k)
-        if span is None or len(sizes) == 1:
-            return over(sizes[-1], scores)
-        at = sum((span > width).astype(jnp.int32) for width in sizes[:-1])
-        return lax.switch(at, [functools.partial(over, width)
-                               for width in sizes], scores)
+def chosen_positions(keep: jnp.ndarray, topk: int) -> jnp.ndarray:
+    """``[..., topk]`` int32: the positions :func:`keep_mask` kept, rising,
+    -1 past their count. Made from the mask by ops nothing else reads, so a
+    program that does not return it does not compute it: the served steps
+    take the mask alone, the benchmark's judge reads the list. It is a sort
+    of the row's positions, what the selection itself cost before it
+    bisected: on the chip a prefix count and a scatter, or ``nonzero``, take
+    nine and fourteen times a sort's 4.4 ms for 512 rows of 16 384 (PERF.md,
+    PR 59)."""
+    with jax.named_scope("dsa_chosen_positions"):
+        pos = lax.broadcasted_iota(jnp.int32, keep.shape, keep.ndim - 1)
+        width = keep.shape[-1]
+        first, _ = lax.top_k(jnp.where(keep != 0, width - pos, 0),
+                             min(topk, width))
+        return jnp.where(first > 0, width - first, -1)
 
 
 def keep_mask(scores: jnp.ndarray, kth_score: jnp.ndarray,

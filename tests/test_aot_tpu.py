@@ -1575,7 +1575,8 @@ def test_scheduler_programs_compile_for_v5e_at_laguna():
 def test_dsa_kernels_compile_at_served_shapes(one_chip):
     """The two index kernels of ``ops/dsa.py`` (one program a decode row over
     its index pages of 128 lanes; one a block of 32 queries, 1 024 query rows
-    against key blocks of 1 024) and both latent kernels under the one
+    against key blocks of 1 024), the selection (``dsa_select``: blocks of
+    32 and of 64 rows of 16 384 scores) and both latent kernels under the one
     operand they gain (``keep``: [32, 16384] of decode rows as int32 tiles a
     trip, [1, 512, 16384] of a chunk as int8 tiles a q-block and trip), at
     glm-5's served shapes: 32 slots of 16 384 tokens, 32 index heads, 64
@@ -1600,6 +1601,10 @@ def test_dsa_kernels_compile_at_served_shapes(one_chip):
         one_chip((1, 512, 32, 128), jnp.bfloat16),
         one_chip((1, 512, 32), jnp.float32), index,
         one_chip((1, pmax), jnp.int32), lane, lane, layer)
+    for queries in (batch, 512):    # decode rows; a chunk's queries
+        _compiles_with_mosaic(
+            lambda s, span: dsa.select(s, 2048, span),
+            one_chip((queries, pmax * _PAGE), jnp.float32), layer)
     _compiles_with_mosaic(
         lambda q, p, pt, n, ly, keep: mla_decode_attention(
             q, p, pt, n, ly, rank=rank, scale=0.0625, interpret=False,
@@ -1626,12 +1631,29 @@ def _glm_dsa_programs(conf_file: str, sharding):
     return _laguna_programs(conf_file, sharding, pools)
 
 
+def _sorts_in(text: str) -> list[tuple[str, str]]:
+    """(result shapes, op name) of every ``sort`` and ``TopK`` instruction
+    of an optimised HLO text."""
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) (sort|custom-call)\(", line)
+        if m and (m.group(2) == "sort" or 'custom_call_target="TopK"' in line):
+            name = re.search(r'op_name="([^"]*)"', line)
+            found.append((m.group(1), name.group(1) if name else ""))
+    return found
+
+
 def test_glm_dsa_programs_take_two_pools_and_hold_the_named_parts():
-    """The tiny twin of the served compile below, lowered and not compiled
-    (seconds): ``tiny-glm-dsa-share4``'s two programs take TWO donated pools
-    (the latent rows and the index keys), hold the index pass, the top-k and
-    the latent kernels under the chosen keys by the names a device trace
-    shows, and keep the indexer's head weights float32."""
+    """The tiny twin of the served compile below, on the CPU (interpreted
+    kernels): ``tiny-glm-dsa-share4``'s two programs take TWO donated pools
+    (the latent rows and the index keys), hold the index pass, the selection
+    and the latent kernels under the chosen keys by the names a device trace
+    shows, and keep the indexer's head weights float32. Nothing reads
+    ``aux["chosen"]``, so no op of the position list is even lowered (the
+    trace drops a scan's carry that feeds only itself), and COMPILED they
+    hold no sort inside a layer's attention (the selection is a bisection,
+    ``dsa_select``): what is left to sort is the experts' routing and the
+    sampler's row of logits."""
     cfg, params, pools, programs = _glm_dsa_programs(
         "benchmark/tests/rehearsal/configs/tiny-glm-dsa.json", None)
     assert (cfg.kv_layers, cfg.index_topk) == (5, 12)
@@ -1642,12 +1664,16 @@ def test_glm_dsa_programs_take_two_pools_and_hold_the_named_parts():
         lowered = fn.lower(*args)
         text = lowered.as_text(debug_info=True)     # the scopes' names
         scopes = ["glm_dsa_layer", "dsa_index_scores", "dsa_topk",
-                  "dsa_sparse_decode_attention"]
+                  "dsa_select", "dsa_sparse_decode_attention"]
         if name != "paged_decode_chunk":
             scopes += ["dsa_index_scores_ragged", "dsa_ragged_attention"]
         for scope in scopes:
             assert scope in text, (name, scope)
         assert lowered.as_text().count("tf.aliasing_output") == 2, name
+        assert "dsa_chosen_positions" not in text, name
+        sorts = _sorts_in(lowered.compile().as_text())
+        assert sorts and not [s for s in sorts if "glm_dsa_layer" in s[1]], (
+            name, sorts)
 
 
 @pytest.mark.slow
@@ -1657,8 +1683,9 @@ def test_scheduler_programs_compile_for_v5e_at_glm_dsa():
     ``benchmark/configs/glm-5-int8.json`` (32 slots of 16 384, 8193 pages in
     both arrays of the latent chain, 16 held experts in 6 expert layers, 8
     steps a chunk), on one described chip: each holds the index kernels, the
-    latent kernels under their chosen-set names and the ``grouped_matmul``
-    Mosaic call, donates both pools and copies neither, fits the 15.75 GiB
+    selection's (``dsa_select``: no sort of a row of scores), the latent
+    kernels under their chosen-set names and the ``grouped_matmul`` Mosaic
+    call, donates both pools and copies neither, fits the 15.75 GiB
     the compiler budgets. A compile, not a chip run (4-6 minutes)."""
     import time
 
@@ -1693,12 +1720,14 @@ def test_scheduler_programs_compile_for_v5e_at_glm_dsa():
         if os.environ.get("AOT_DUMP_DIR"):
             Path(os.environ["AOT_DUMP_DIR"],
                  f"glm-dsa-{name}.hlo.txt").write_text(text)
-        kernels = ["grouped_matmul", "dsa_index_scores",
+        kernels = ["grouped_matmul", "dsa_index_scores", "dsa_select",
                    "dsa_sparse_decode_attention"]
         if name != "paged_decode_chunk":
             kernels += ["dsa_index_scores_ragged", "dsa_ragged_attention"]
         for kernel in kernels:
             assert kernel in text, (name, kernel)
+        # nothing reads aux["chosen"]: no sort in a layer's attention
+        assert not [s for s in _sorts_in(text) if "glm_dsa_layer" in s[1]]
         for pool in pools:
             _assert_whole_array_untouched(text, pool, name)
         assert mem.alias_size_in_bytes >= sum(

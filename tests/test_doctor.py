@@ -550,6 +550,10 @@ def test_health_surfaces_over_rest():
                                                            _stop_stack)
     from cyberfabric_core_tpu.modkit.flight_recorder import default_recorder
 
+    # the process's recorder: a file this worker ran before may have left
+    # requests in flight, and the table below is held to be empty
+    default_recorder.reset()
+
     async def go():
         rt, base = await _boot_stack(
             ["monitoring"],

@@ -90,38 +90,115 @@ def test_index_scores_of_a_chunk_match_plain_numpy(width):
             assert (got[r, qi, seen:] == dsa.NEG).all()
 
 
+def _select(scores, topk, span=None, **kw):
+    """``dsa.select`` in interpret mode and the set it keeps: (the positions
+    kept a row, count, k-th score, k-th position, the mask)."""
+    scores = jnp.asarray(scores, jnp.float32)
+    count, kth, kth_at = dsa.select(
+        scores, topk, None if span is None else jnp.asarray(span),
+        interpret=True, **kw)
+    keep = dsa.keep_mask(scores, kth, kth_at)
+    return (np.asarray(dsa.chosen_positions(keep, topk)), np.asarray(count),
+            np.asarray(kth), np.asarray(kth_at), np.asarray(keep))
+
+
 def test_select_is_exact_counts_and_breaks_ties_by_position():
-    """The top-k is ``lax.top_k``'s: exact, by falling score, equal scores by
-    rising position; a query that sees fewer keys than ``topk`` keeps them
-    all and pads with -1; ``keep_mask`` is the same set as a mask."""
+    """The top-k is ``lax.top_k``'s: exact, equal scores by rising position;
+    a query that sees fewer keys than ``topk`` keeps them all and pads with
+    -1; ``keep_mask`` is the same set as a mask, ``chosen_positions`` the
+    same set as a list."""
     scores = np.full((4, 24), dsa.NEG, np.float32)
     rng = np.random.default_rng(4)
     scores[0, :20] = rng.normal(size=20)
     scores[1, :5] = rng.normal(size=5)           # sees 5 < topk
     scores[2, :20] = 1.0                         # all equal: the first 8
-    picked, count, kth, kth_at = dsa.select(jnp.asarray(scores), 8)
-    picked, count = np.asarray(picked), np.asarray(count)
+    picked, count, kth, kth_at, keep = _select(scores, 8)
     assert count.tolist() == [8, 5, 8, 0]
     assert set(picked[0]) == set(np.argsort(-scores[0, :20])[:8])
-    assert sorted(picked[1][:5]) == [0, 1, 2, 3, 4] and (picked[1][5:] == -1).all()
-    assert picked[2].tolist() == list(range(8))
-    assert (picked[3] == -1).all()
-    keep = np.asarray(dsa.keep_mask(jnp.asarray(scores), kth, kth_at))
+    assert kth[0] == np.sort(scores[0, :20])[-8] and kth[1] == dsa.NEG
+    assert picked[1].tolist() == [0, 1, 2, 3, 4, -1, -1, -1]
+    assert picked[2].tolist() == list(range(8)) and kth_at[2] == 7
+    assert (picked[3] == -1).all() and kth_at[3] == -1
     assert keep.sum(axis=1).tolist() == [8, 5, 8, 0]
     for r in range(4):
         assert set(np.flatnonzero(keep[r])) == set(picked[r][picked[r] >= 0])
-    # under a span the sort is over a prefix that holds it: the same answer
+    # under a span no round reads a key past it: the same answer
     wide = np.full((3, 256), dsa.NEG, np.float32)
     wide[:, :70] = rng.normal(size=(3, 70))
-    assert dsa._prefixes(256, 8) == (64, 128, 256)
-    assert dsa._prefixes(16384, 2048) == (4096, 8192, 16384)
-    whole = dsa.select(jnp.asarray(wide), 8)
-    for span in (70, 64, 129, 256):
-        if span < 70:
-            continue
-        part = dsa.select(jnp.asarray(wide), 8, span=jnp.asarray(span))
-        for a, b in zip(whole, part):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    whole = _select(wide, 8)
+    for span in (70, 129, 256):
+        for a, b in zip(whole, _select(wide, 8, span)):
+            np.testing.assert_array_equal(a, b)
+
+
+def _top_k_keeps(scores, topk):
+    """(count, mask) of ``lax.top_k`` + ``keep_mask``: what ``select`` did
+    while it sorted."""
+    scores = jnp.asarray(scores, jnp.float32)
+    values, positions = jax.lax.top_k(scores, min(topk, scores.shape[-1]))
+    seen = values > dsa.NEG / 2
+    positions = jnp.where(seen, positions, -1)
+    return (np.asarray(seen.sum(-1)),
+            np.asarray(dsa.keep_mask(scores, values[..., -1],
+                                     positions[..., -1])))
+
+
+def _select_cases():
+    rng = np.random.default_rng(9)
+    S = 1024
+    plain = rng.normal(size=(40, S)).astype(np.float32)
+    cut = plain.copy()
+    for r, n in enumerate(rng.integers(0, S, 40)):
+        cut[r, n:] = dsa.NEG
+    few = np.full((8, S), dsa.NEG, np.float32)
+    few[:, :30] = rng.normal(size=(8, 30))
+    few[3] = dsa.NEG                                  # a row that sees none
+    inside = np.full((5, 2048), dsa.NEG, np.float32)
+    inside[:, :700] = rng.normal(size=(5, 700))
+    return {
+        "random rows": (plain, 64, None, {}),
+        "many ties": (rng.integers(-3, 4, (16, S)), 100, None, {}),
+        "an all-equal row": (np.ones((8, S)), 100, None, {}),
+        "rows cut by NEG": (cut, 64, S, {}),
+        "rows that see under k and none": (few, 64, 30, {}),
+        "negative scores": (-np.abs(plain), 64, None, {}),
+        "N not a whole block": (plain[:13], 64, None, {"rows": 8}),
+        "blocks of 8 rows, steps of 128 lanes": (plain, 64, None,
+                                                 {"rows": 8, "lanes": 128}),
+        "a span that ends inside a lane block": (inside, 64, 700, {}),
+        "a width of no whole lane tile": (plain[:4, :160], 12, None, {}),
+        "topk over the width": (plain[:4, :160], 200, None, {}),
+    }
+
+
+@pytest.mark.parametrize("case", list(_select_cases()))
+def test_dsa_select_keeps_what_top_k_keeps(case):
+    """The bisection kernel against the sort it replaced: the same count and
+    the same mask, key for key, ties to the lower position."""
+    scores, topk, span, kw = _select_cases()[case]
+    _, count, _, _, keep = _select(scores, topk, span, **kw)
+    want_count, want_keep = _top_k_keeps(scores, topk)
+    np.testing.assert_array_equal(count, want_count)
+    np.testing.assert_array_equal(keep, want_keep)
+
+
+def test_dsa_select_keeps_exactly_k_among_zeros_of_both_signs():
+    """A k-th score that is a zero among zeros of both signs: IEEE compares
+    them equal, as ``keep_mask`` does, so exactly ``topk`` are kept, the
+    zeros among them by rising position whatever their sign (the sort ordered
+    ``-0.0`` under ``+0.0`` and kept more)."""
+    rng = np.random.default_rng(10)
+    scores = np.where(rng.random((8, 1024)) < 0.5, 0.0, -0.0).astype(
+        np.float32)
+    scores[:, ::7], scores[:, ::11] = -1.0, 2.0
+    picked, count, kth, _, keep = _select(scores, 200)
+    assert (count == 200).all() and (keep.sum(-1) == 200).all()
+    assert (kth == 0).all()
+    for r in range(8):
+        twos, zeros = np.flatnonzero(scores[r] == 2), np.flatnonzero(
+            scores[r] == 0)
+        want = np.sort(np.concatenate([twos, zeros[: 200 - len(twos)]]))
+        assert picked[r].tolist() == want.tolist()
 
 
 def test_the_decode_kernel_attends_the_chosen_rows_and_no_others():
@@ -140,8 +217,8 @@ def test_the_decode_kernel_attends_the_chosen_rows_and_no_others():
     scores = np.full((B, S), dsa.NEG, np.float32)
     for b, n in enumerate(lengths):
         scores[b, :n] = rng.normal(size=n)
-    picked, _, kth, kth_at = dsa.select(jnp.asarray(scores), topk)
-    keep = dsa.keep_mask(jnp.asarray(scores), kth, kth_at)
+    picked, _, _, _, keep = _select(scores, topk)
+    keep = jnp.asarray(keep)
     args = (q, pool, table, jnp.asarray(lengths), 1)
     kw = dict(rank=rank, scale=0.2, interpret=True)
     got = np.asarray(mla_decode_attention(*args, **kw, keep=keep), np.float32)

@@ -61,7 +61,8 @@ def test_the_real_files_names_resolve_and_only_add(monkeypatch):
                      "dsa_decode_keys_selected_per_call",
                      "dsa_index_scores_roofline",
                      "dsa_sparse_decode_attention_roofline",
-                     "dsa_moe_step_roofline"]
+                     "dsa_moe_step_roofline",
+                     "dsa_select_us"]                  # PR 59: glm's alone
     later = {}
 
     def as_ouro_left(metric: dict) -> dict:
